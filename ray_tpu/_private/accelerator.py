@@ -1,0 +1,131 @@
+"""The host's TPU chips as seen by a process that must not take them, and
+the compile cache of the process that does.
+
+A chip belongs to one process at a time: initializing the TPU runtime claims
+it, so the driver and the raylet learn what the host holds from its device
+nodes (no ``import jax``) and leave the chip free for the worker that leases
+it. That worker — or a script that runs the model in-process — keeps its
+compiled programs in the one directory named here.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import glob
+import os
+from typing import Any, Dict, Optional
+
+_DEV_ROOT = "/dev"
+_PCI_ROOT = "/sys/bus/pci/devices"
+_GOOGLE_PCI_VENDOR = "0x1ae0"
+# v2/v3, an unnamed part, v4, v5p, v5e, v6e, 7x
+_TPU_PCI_DEVICES = frozenset(
+    {"0x0027", "0x0056", "0x005e", "0x0062", "0x0063", "0x006f", "0x0076"}
+)
+
+_REPO_ROOT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
+_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+
+def _read(path: str) -> str:
+    try:
+        with open(path) as f:
+            return f.read().strip()
+    except OSError:
+        return ""
+
+
+def _pci_tpu_functions() -> int:
+    return sum(
+        1
+        for vendor in glob.glob(os.path.join(_PCI_ROOT, "*", "vendor"))
+        if _read(vendor) == _GOOGLE_PCI_VENDOR
+        and _read(os.path.join(os.path.dirname(vendor), "device"))
+        in _TPU_PCI_DEVICES
+    )
+
+
+def detect_tpu_chips() -> int:
+    """TPU chips this machine (or container) was given, from device nodes.
+
+    Up to v4 each chip is a ``/dev/accel<N>`` node. From v5e on the chips
+    are vfio devices: sysfs lists every chip of the host, but a machine
+    that was handed only some of them has only their ``/dev/vfio/<group>``
+    nodes, so the nodes bound the count.
+    """
+    accel = glob.glob(os.path.join(_DEV_ROOT, "accel[0-9]*"))
+    if accel:
+        return len(accel)
+    vfio = glob.glob(os.path.join(_DEV_ROOT, "vfio", "[0-9]*"))
+    return min(len(vfio), _pci_tpu_functions())
+
+
+def compile_cache_dir() -> str:
+    """Where compiled programs persist: ``JAX_COMPILATION_CACHE_DIR`` when
+    the deployment sets it, else one fixed directory in the checkout. The
+    path is part of the cache key, so it is never derived from a pid, a
+    time or the session directory."""
+    return os.environ.get(_CACHE_ENV) or os.path.join(_REPO_ROOT, ".jax_cache")
+
+
+@dataclasses.dataclass
+class CompileCacheStats:
+    """This process's persistent-cache traffic since ``enable_compile_cache``."""
+
+    dir: str
+    requests: int = 0
+    hits: int = 0
+    writes: int = 0
+
+    def _on_event(self, event: str, **_kw) -> None:
+        if event == "/jax/compilation_cache/compile_requests_use_cache":
+            self.requests += 1
+        elif event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.writes += 1
+
+
+# jax's cache configuration is process-wide, and so is the count of its use
+_stats: Optional[CompileCacheStats] = None
+
+
+def enable_compile_cache() -> CompileCacheStats:
+    """Point this process's jax at :func:`compile_cache_dir` and count its
+    cache traffic from here on; later calls return the same counts. Call it
+    where a process first takes the chip, before anything compiles. With
+    the variable set jax has already read it, and no other path is set in
+    code."""
+    global _stats
+    if _stats is None:
+        import jax
+
+        if not os.environ.get(_CACHE_ENV):
+            jax.config.update("jax_compilation_cache_dir", compile_cache_dir())
+        _stats = CompileCacheStats(dir=jax.config.jax_compilation_cache_dir)
+        jax.monitoring.register_event_listener(_stats._on_event)
+    return _stats
+
+
+def compile_cache_stats() -> Optional[Dict[str, Any]]:
+    """The counts, or None in a process that never enabled the cache."""
+    return None if _stats is None else dataclasses.asdict(_stats)
+
+
+def device_report() -> Dict[str, Any]:
+    """What the calling process's jax sees — for the process that runs the
+    model to hand back through an actor call (calling this takes the chip)."""
+    import jax
+
+    devices = jax.devices()
+    memory = devices[0].memory_stats() or {}
+    return {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+        "pid": os.getpid(),
+        "peak_bytes_in_use": memory.get("peak_bytes_in_use"),
+        "bytes_limit": memory.get("bytes_limit"),
+    }

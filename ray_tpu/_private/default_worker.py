@@ -36,6 +36,13 @@ def main():
     if token:
         rpc_mod.configure_auth(token)
 
+    if os.environ.get("JAX_PLATFORMS") == "tpu":
+        # the raylet pins the platform of every worker it spawns: this one
+        # holds a TPU lease and is about to take the chip
+        from ray_tpu._private.accelerator import enable_compile_cache
+
+        enable_compile_cache()
+
     worker_id = WorkerID.from_hex(os.environ["RAYTPU_WORKER_ID"])
     raylet_addr = (os.environ["RAYTPU_RAYLET_HOST"], int(os.environ["RAYTPU_RAYLET_PORT"]))
     gcs_addr = (os.environ["RAYTPU_GCS_HOST"], int(os.environ["RAYTPU_GCS_PORT"]))

@@ -626,8 +626,16 @@ class GcsServer:
     def _health_loop(self):
         period = GlobalConfig.health_check_period_s
         threshold = GlobalConfig.health_check_failure_threshold
+        last_tick = time.monotonic()
         while not self._stopped.wait(period):
             now = time.monotonic()
+            # how long this process itself did not run. Heartbeats that
+            # arrived meanwhile are still unread (an in-process raylet could
+            # not even send any), so that time is not silence from the
+            # nodes: found on a v5e host, where the whole driver process
+            # freezes for ~5 s while a worker starts the TPU runtime.
+            stalled = now - last_tick - period
+            last_tick = now
             window = GlobalConfig.degraded_window_s
             dead: List[Tuple[NodeInfo, str]] = []
             degraded: List[NodeInfo] = []
@@ -636,6 +644,8 @@ class GcsServer:
                 for info in self._nodes.values():
                     if not info.alive:
                         continue
+                    if stalled > period:
+                        info.last_heartbeat += stalled
                     if now - info.last_heartbeat > period * threshold:
                         info.alive = False
                         info.state = "DEAD"

@@ -637,6 +637,12 @@ def _get_poller():
     return _Poller.get()
 
 
+def transport_name() -> str:
+    """The transport this process's RPC sockets ride: "native" or "python"
+    (the latter also when the native library failed to build or load)."""
+    return "native" if isinstance(_get_poller(), _NativePoller) else "python"
+
+
 def _encode_frame_parts(obj) -> list:
     """Encode (kind, msg_id, method, payload) into wire parts: the shared
     frame codec for both senders. Small parts are pre-joined; large

@@ -96,10 +96,11 @@ class JobSupervisor:
     def run(self) -> str:
         """Blocking: returns the terminal status."""
         env = dict(os.environ)
+        # this supervisor is a CPU worker, pinned to JAX_PLATFORMS=cpu by
+        # its raylet; the job's driver is not, unless its env_vars say so
+        env.pop("JAX_PLATFORMS", None)
         env.update(self.env_vars)
         env["RAYTPU_ADDRESS"] = self.gcs_address
-        # the job driver must not inherit this worker's claim on the chip
-        env.pop("JAX_PLATFORMS", None)
         self._set_status(JobStatus.RUNNING)
         log_file = self._open_job_log()
         try:

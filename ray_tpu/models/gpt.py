@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.ops.attention import dot_product_attention
+from ray_tpu.ops.ring import mesh_attention
 
 
 @dataclasses.dataclass(frozen=True)
@@ -204,7 +204,7 @@ def _dense(features: Tuple[int, ...], logical_axes: Tuple[str, ...], cfg: GPTCon
 
 class Attention(nn.Module):
     cfg: GPTConfig
-    mesh: Any = None  # set when the seq axis is sharded (sp > 1)
+    mesh: Any = None  # the step's device mesh, when it has one
 
     @nn.compact
     def __call__(self, x: jax.Array, positions: jax.Array) -> jax.Array:
@@ -217,20 +217,15 @@ class Attention(nn.Module):
         k = _rotary(k, positions, cfg.rotary_dim)
         # [b, t, h, d] → [b, h, t, d] for the fused kernel
         qh, kh, vh = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
-        if self.mesh is not None and self.mesh.shape.get("sp", 1) > 1:
-            # context parallelism: ring/ulysses over the sp axis
-            # (first-class long-context support — SURVEY.md §5)
-            from ray_tpu.ops.ring import sequence_parallel_attention
-
-            out = sequence_parallel_attention(
-                qh, kh, vh, self.mesh, impl=cfg.seq_parallel_impl, causal=True,
-                use_pallas=cfg.attn_use_pallas,
-            ).transpose(0, 2, 1, 3)
-        else:
-            out = dot_product_attention(
-                qh, kh, vh, causal=True, use_pallas=cfg.attn_use_pallas,
-                block_q=cfg.attn_block_q, block_k=cfg.attn_block_k,
-            ).transpose(0, 2, 1, 3)
+        # the fused kernel, per shard under shard_map when the mesh has more
+        # than one device (batch on dp/fsdp, heads on tp); with sp > 1
+        # context parallelism: ring/ulysses over the sp axis (first-class
+        # long-context support — SURVEY.md §5)
+        out = mesh_attention(
+            qh, kh, vh, self.mesh, impl=cfg.seq_parallel_impl, causal=True,
+            use_pallas=cfg.attn_use_pallas,
+            block_q=cfg.attn_block_q, block_k=cfg.attn_block_k,
+        ).transpose(0, 2, 1, 3)
         # tag for remat_policy="attn": saving exactly this tensor lets the
         # backward pass skip replaying the flash-attention forward kernel
         # while everything cheaper (LN, rotary, gelu) still rematerializes
@@ -328,7 +323,7 @@ class GPT(nn.Module):
 
     cfg: GPTConfig
     return_hidden: bool = False
-    mesh: Any = None  # enables ring/ulysses attention when sp > 1
+    mesh: Any = None  # the step's device mesh: attention is shard_mapped over it
 
     @nn.compact
     def __call__(self, tokens: jax.Array, positions: Optional[jax.Array] = None):

@@ -134,8 +134,7 @@ def make_train_step(
     donate: bool = True,
 ) -> Callable:
     """Build `step(state, tokens) -> (state, metrics)`, jitted with shardings."""
-    # ring/ulysses attention activates when the mesh shards the sequence
-    model = GPT(cfg, return_hidden=True, mesh=_sp_mesh(mesh))
+    model = GPT(cfg, return_hidden=True, mesh=mesh)
     active_rules = list(rules if rules is not None else shd.DEFAULT_RULES)
 
     moe = cfg.moe_num_experts > 0
@@ -194,15 +193,11 @@ def make_train_step(
     return jax.jit(step, donate_argnums=(0,) if donate else (), **kwargs)
 
 
-def _sp_mesh(mesh: Optional[Mesh]) -> Optional[Mesh]:
-    return mesh if (mesh is not None and mesh.shape.get("sp", 1) > 1) else None
-
-
 def make_eval_step(cfg: GPTConfig, mesh: Optional[Mesh] = None) -> Callable:
-    """Pass the training mesh so sp>1 eval uses the same ring/ulysses path
-    (dense attention would all-gather full K/V and OOM at the context
+    """Pass the training mesh so eval shards attention the same way (with
+    sp>1, dense attention would all-gather full K/V and OOM at the context
     lengths the sp axis exists for)."""
-    model = GPT(cfg, return_hidden=True, mesh=_sp_mesh(mesh))
+    model = GPT(cfg, return_hidden=True, mesh=mesh)
 
     @jax.jit
     def eval_step(params, tokens):
@@ -216,7 +211,7 @@ def make_eval_step(cfg: GPTConfig, mesh: Optional[Mesh] = None) -> Callable:
 
 def make_forward(cfg: GPTConfig, mesh: Optional[Mesh] = None) -> Callable:
     """Jittable pure forward (logits) — used by __graft_entry__.entry()."""
-    model = GPT(cfg, mesh=_sp_mesh(mesh))
+    model = GPT(cfg, mesh=mesh)
 
     def forward(params, tokens):
         return model.apply({"params": params}, tokens)

@@ -1,8 +1,8 @@
 """ctypes binding for the native C++ arena allocator (object_store.cc).
 
 Compiled on demand with g++ (no pybind11 in the image — the C ABI + ctypes
-route per the build constraints); the .so is cached next to the source and
-rebuilt when the source is newer. `NativeArena` matches the `_PyArena`
+route per the build constraints); the .so is cached next to the source,
+keyed by the source's hash (``native.build``). `NativeArena` matches the `_PyArena`
 interface (allocate/free/allocated_bytes) so `PlasmaStore` can swap it in
 transparently (ray_tpu/_private/object_store.py:_make_arena).
 """
@@ -10,37 +10,17 @@ transparently (ray_tpu/_private/object_store.py:_make_arena).
 from __future__ import annotations
 
 import ctypes
-import os
-import subprocess
-import threading
 
-_HERE = os.path.dirname(os.path.abspath(__file__))
-_SRC = os.path.join(_HERE, "object_store.cc")
-_LIB = os.path.join(_HERE, "libraytpu_store.so")
+from ray_tpu import native
 
-_build_lock = threading.Lock()
 _lib = None
-
-
-def _build() -> str:
-    with _build_lock:
-        if os.path.exists(_LIB) and os.path.getmtime(_LIB) >= os.path.getmtime(_SRC):
-            return _LIB
-        tmp = _LIB + f".tmp.{os.getpid()}"
-        subprocess.run(
-            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", tmp, _SRC],
-            check=True,
-            capture_output=True,
-        )
-        os.replace(tmp, _LIB)  # atomic: concurrent builders race safely
-        return _LIB
 
 
 def _load() -> ctypes.CDLL:
     global _lib
     if _lib is not None:
         return _lib
-    lib = ctypes.CDLL(_build())
+    lib = ctypes.CDLL(native.build("libraytpu_store", ".so", ["object_store.cc"]))
     lib.arena_create.argtypes = [ctypes.c_uint64]
     lib.arena_create.restype = ctypes.c_void_p
     lib.arena_allocate.argtypes = [ctypes.c_void_p, ctypes.c_uint64]

@@ -10,38 +10,20 @@ any build/import failure callers fall back to the pure-Python poller.
 
 from __future__ import annotations
 
-import os
-import subprocess
 import sysconfig
-import threading
 
-_HERE = os.path.dirname(os.path.abspath(__file__))
-_SRCS = [os.path.join(_HERE, "rpc_ext.cc"), os.path.join(_HERE, "rpc_core.cc")]
-_SUFFIX = sysconfig.get_config_var("EXT_SUFFIX") or ".so"
-_LIB = os.path.join(_HERE, "_rtrpc" + _SUFFIX)
+from ray_tpu import native
 
-_build_lock = threading.Lock()
 _mod = None
 
 
 def _build() -> str:
-    with _build_lock:
-        if os.path.exists(_LIB) and all(
-            os.path.getmtime(_LIB) >= os.path.getmtime(s) for s in _SRCS
-        ):
-            return _LIB
-        tmp = _LIB + f".tmp.{os.getpid()}"
-        include = sysconfig.get_paths()["include"]
-        subprocess.run(
-            [
-                "g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
-                f"-I{include}", "-o", tmp, *_SRCS,
-            ],
-            check=True,
-            capture_output=True,
-        )
-        os.replace(tmp, _LIB)  # atomic: concurrent builders race safely
-        return _LIB
+    return native.build(
+        "_rtrpc",
+        sysconfig.get_config_var("EXT_SUFFIX") or ".so",
+        ["rpc_ext.cc", "rpc_core.cc"],
+        ["-pthread", f"-I{sysconfig.get_paths()['include']}"],
+    )
 
 
 def load():
@@ -49,10 +31,10 @@ def load():
     global _mod
     if _mod is not None:
         return _mod
-    _build()
     import importlib.util
 
-    spec = importlib.util.spec_from_file_location("ray_tpu.native._rtrpc", _LIB)
+    # the init symbol comes from the module name, not the hashed file name
+    spec = importlib.util.spec_from_file_location("ray_tpu.native._rtrpc", _build())
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     _mod = mod

@@ -26,10 +26,9 @@ NEG_INF = -1e30
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    # a backend that fails to initialize must surface here, not quietly
+    # become the O(T^2) XLA path
+    return jax.devices()[0].platform == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -560,24 +559,27 @@ def dot_product_attention(
     """Fused attention over [batch, heads, seq, head_dim] inputs.
 
     Differentiable everywhere: forward and backward both run as Pallas
-    flash kernels (custom_vjp), with an O(T²) XLA fallback for CPU tests
-    and shapes the kernel cannot tile.
+    flash kernels (custom_vjp) on TPU. ``use_pallas=None`` picks the kernel
+    on TPU wherever it can tile the shape and the O(T²) XLA path otherwise
+    (CPU tests, ``segment_ids``, odd shapes); ``use_pallas=True`` demands
+    the kernel and raises for a shape it cannot tile.
     """
     scale_val = float(scale) if scale is not None else 1.0 / float(np.sqrt(q.shape[-1]))
-    use = use_pallas if use_pallas is not None else _on_tpu()
-    import os as _os
-
-    # tuning hook: sweep kernel tile sizes without touching call sites
-    block_q = int(_os.environ.get("RAYTPU_FLASH_BLOCK_Q", block_q))
-    block_k = int(_os.environ.get("RAYTPU_FLASH_BLOCK_K", block_k))
     d = q.shape[-1]
-    if (
-        use
-        and segment_ids is None
+    supported = (
+        segment_ids is None
         and (d % 128 == 0 or d == 64)
         and q.shape[-2] % 8 == 0
         and k.shape[-2] % 8 == 0
-    ):
+    )
+    if use_pallas and not supported:
+        raise ValueError(
+            f"flash attention cannot tile q{q.shape} k{k.shape}"
+            + (" with segment_ids" if segment_ids is not None else "")
+            + ": needs head_dim % 128 == 0 (or 64), seq lens % 8 == 0"
+        )
+    use = use_pallas if use_pallas is not None else _on_tpu()
+    if use and supported:
         return flash_attention(
             q, k, v, causal, scale_val, block_q, block_k, False
         )

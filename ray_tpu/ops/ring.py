@@ -1,4 +1,5 @@
-"""Sequence/context parallelism: ring attention and Ulysses over the sp axis.
+"""Attention under a device mesh: the fused kernel per shard, plus ring
+attention and Ulysses over the sp axis (sequence/context parallelism).
 
 First-class long-context components (SURVEY.md §5: the reference has no
 sequence parallelism anywhere — long-model support was delegated to
@@ -14,8 +15,10 @@ DeepSpeed/Alpa; here they are native ops):
   local_heads % sp == 0; rides the custom-vjp flash kernels.
 
 Both are exact (tested against dense attention on the CPU mesh) and
-differentiable. ``sequence_parallel_attention`` is the mesh-level wrapper
-the model calls; with sp == 1 it falls through to the fused kernel.
+differentiable. ``mesh_attention`` is the wrapper the model calls; with
+sp == 1 each device runs the fused kernel on its batch/head shard (a Mosaic
+kernel cannot be partitioned by GSPMD, so the ``shard_map`` is what makes it
+legal on more than one chip).
 """
 
 from __future__ import annotations
@@ -26,6 +29,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ray_tpu.ops.attention import (
@@ -33,11 +37,6 @@ from ray_tpu.ops.attention import (
     dot_product_attention,
     merge_attention,
 )
-
-try:  # jax>=0.6 top-level; older versions keep it in experimental
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 
 def ring_attention_local(
@@ -91,6 +90,8 @@ def ulysses_attention_local(
     causal: bool = True,
     scale: Optional[float] = None,
     use_pallas: Optional[bool] = None,
+    block_q: int = 512,
+    block_k: int = 512,
 ):
     """Shard-local Ulysses attention (call under shard_map).
 
@@ -111,51 +112,57 @@ def ulysses_attention_local(
     out = dot_product_attention(
         swap_in(q), swap_in(k), swap_in(v),
         causal=causal, scale=scale, use_pallas=use_pallas,
+        block_q=block_q, block_k=block_k,
     )
     return swap_out(out)
 
 
-def sequence_parallel_attention(
+def mesh_attention(
     q: jax.Array,
     k: jax.Array,
     v: jax.Array,
-    mesh: Mesh,
+    mesh: Optional[Mesh],
     *,
     impl: str = "ring",
     sp_axis: str = "sp",
     causal: bool = True,
     scale: Optional[float] = None,
     use_pallas: Optional[bool] = None,
+    block_q: int = 512,
+    block_k: int = 512,
     batch_axes=("dp", "fsdp"),
     head_axis: str = "tp",
 ) -> jax.Array:
-    """Mesh-level context-parallel attention over [b, h, T, d] arrays whose
-    sequence dim is sharded on ``sp_axis`` (batch on dp/fsdp, heads on tp).
+    """Attention over [b, h, T, d] arrays on the step's mesh: batch sharded
+    on dp/fsdp, heads on tp, sequence on ``sp_axis``.
 
-    With sp == 1 this is the plain fused kernel; otherwise the chosen
-    implementation runs under shard_map so the collectives (ppermute ring
-    or all_to_all) ride the ICI mesh explicitly.
+    Runs under shard_map over those axes. With sp == 1 every device runs the
+    fused kernel on its own batch/head shard; otherwise the chosen
+    implementation's collectives (ppermute ring or all_to_all) ride the ICI
+    mesh explicitly. No mesh, or a one-device mesh, needs no wrapper.
     """
+    fused = functools.partial(
+        dot_product_attention, causal=causal, scale=scale,
+        use_pallas=use_pallas, block_q=block_q, block_k=block_k,
+    )
+    if mesh is None or mesh.size == 1:
+        return fused(q, k, v)
     sp = mesh.shape.get(sp_axis, 1)
     if sp == 1:
-        return dot_product_attention(
-            q, k, v, causal=causal, scale=scale, use_pallas=use_pallas
-        )
-    spec = P(batch_axes, head_axis, sp_axis, None)
-    if impl == "ring":
+        local = fused
+    elif impl == "ring":
         local = functools.partial(
             ring_attention_local, axis_name=sp_axis, sp=sp, causal=causal, scale=scale
         )
     elif impl == "ulysses":
         local = functools.partial(
             ulysses_attention_local, axis_name=sp_axis, sp=sp, causal=causal,
-            scale=scale, use_pallas=use_pallas,
+            scale=scale, use_pallas=use_pallas, block_q=block_q, block_k=block_k,
         )
     else:
         raise ValueError(f"unknown sequence-parallel impl {impl!r}")
-    kwargs = dict(mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
-    try:  # kw renamed across jax versions (check_rep -> check_vma)
-        fn = shard_map(lambda a, b, c: local(a, b, c), check_vma=False, **kwargs)
-    except TypeError:  # pragma: no cover
-        fn = shard_map(lambda a, b, c: local(a, b, c), check_rep=False, **kwargs)
-    return fn(q, k, v)
+    spec = P(batch_axes, head_axis, sp_axis, None)
+    return shard_map(
+        lambda a, b, c: local(a, b, c),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,
+    )(q, k, v)

@@ -89,35 +89,28 @@ def make_mesh(
     devices = list(devices if devices is not None else jax.devices())
     sizes = spec.resolve(len(devices))
     shape = tuple(sizes[a] for a in AXIS_ORDER)
-    if spec.num_slices > 1:
+    if spec.num_slices > 1 and sizes["dp"] % spec.num_slices != 0:
+        raise ValueError(
+            f"dp={sizes['dp']} must be divisible by num_slices={spec.num_slices}"
+        )
+    if devices[0].platform != "tpu":
+        # CPU fixtures have no topology to honour: a plain reshape (which
+        # also emulates the slice split, outermost dp = DCN)
+        dev_array = np.asarray(devices).reshape(shape)
+    elif spec.num_slices > 1:
+        # real topology: a shape it cannot host is an error, never a silent
+        # reshape that would put tp/fsdp collectives on DCN
         dcn_shape = tuple(
             spec.num_slices if a == "dp" else 1 for a in AXIS_ORDER
         )
-        if sizes["dp"] % spec.num_slices != 0:
-            raise ValueError(
-                f"dp={sizes['dp']} must be divisible by num_slices={spec.num_slices}"
-            )
-        per_slice = tuple(
-            s // d for s, d in zip(shape, dcn_shape)
+        per_slice = tuple(s // d for s, d in zip(shape, dcn_shape))
+        dev_array = mesh_utils.create_hybrid_device_mesh(
+            per_slice, dcn_shape, devices=devices, allow_split_physical_axes=True
         )
-        if hasattr(devices[0], "slice_index"):
-            # real multi-slice topology: configuration errors must surface
-            # (a silent reshape would put tp/fsdp collectives on DCN)
-            dev_array = mesh_utils.create_hybrid_device_mesh(
-                per_slice, dcn_shape, devices=devices, allow_split_physical_axes=True
-            )
-        else:
-            # virtual CPU fixtures have no slice_index attribute: emulate
-            # the slice split with a plain reshape (outermost dp = DCN)
-            dev_array = np.asarray(devices).reshape(shape)
     else:
-        try:
-            dev_array = mesh_utils.create_device_mesh(
-                shape, devices=devices, allow_split_physical_axes=True
-            )
-        except (ValueError, NotImplementedError):
-            # CPU fixtures / odd shapes: fall back to a plain reshape.
-            dev_array = np.asarray(devices).reshape(shape)
+        dev_array = mesh_utils.create_device_mesh(
+            shape, devices=devices, allow_split_physical_axes=True
+        )
     return Mesh(dev_array, AXIS_ORDER)
 
 

@@ -20,45 +20,12 @@ pp hops on ICI neighbors.
 
 from __future__ import annotations
 
-import functools
-import inspect
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax>=0.8
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-# Partial-manual compat: jax>=0.8 spells "manual over pp only" as
-# ``axis_names={"pp"}`` and tracks replication via varying-manual-axes
-# (lax.pcast). Older jax (0.4.x) spells it ``auto = all_axes - {"pp"}``,
-# but XLA rejects the resulting program (PartitionId under SPMD
-# partitioning), so there is no cheap fallback — partial-manual pipeline
-# parallelism requires the modern API. Tests gate on this flag.
-_HAS_AXIS_NAMES = "axis_names" in inspect.signature(shard_map).parameters
-_HAS_PCAST = hasattr(lax, "pcast")
-PARTIAL_MANUAL_SUPPORTED = _HAS_AXIS_NAMES and _HAS_PCAST
-
-
-def _shard_map_manual(fn, mesh: Mesh, in_specs, out_specs, manual: frozenset):
-    """`shard_map` manual over ``manual`` axes only (jax>=0.8)."""
-    if not PARTIAL_MANUAL_SUPPORTED:
-        raise NotImplementedError(
-            "pipeline parallelism needs partial-manual shard_map "
-            "(axis_names= and lax.pcast), which this jax "
-            f"({jax.__version__}) lacks — upgrade to jax>=0.8"
-        )
-    # vma checking must stay ON: with it off, partial-manual mode
-    # requires every mesh axis in out_specs (defeating auto sharding)
-    return shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        axis_names=manual,
-    )
 
 
 def stage_split(tree: Any, num_stages: int) -> Any:
@@ -165,10 +132,7 @@ def pipeline_apply(
 
         # the carry becomes pp-varying inside the loop (each stage computes
         # its own activations); mark the zero init accordingly for vma
-        # (older jax has no vma tracking — identity is correct there)
         def _varying(t):
-            if not _HAS_PCAST:
-                return t
             return jax.tree.map(
                 lambda v: lax.pcast(v, ("pp",), to="varying"), t
             )
@@ -180,12 +144,14 @@ def pipeline_apply(
             lambda b: lax.psum(jnp.where(stage == S - 1, b, 0), "pp"), out_buf
         )
 
-    return _shard_map_manual(
+    # vma checking must stay ON: with it off, partial-manual mode
+    # requires every mesh axis in out_specs (defeating auto sharding)
+    return shard_map(
         per_stage,
-        mesh,
+        mesh=mesh,
         in_specs=(param_spec, mb_spec),
         out_specs=mb_spec,
-        manual=frozenset({"pp"}),
+        axis_names=frozenset({"pp"}),
     )(stage_params, x_mb)
 
 

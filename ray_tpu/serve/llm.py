@@ -43,7 +43,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
-from ray_tpu._private import internal_metrics
+from ray_tpu._private import accelerator, internal_metrics
 from ray_tpu.serve import batching
 from ray_tpu.serve.handle import BackPressureError
 from ray_tpu.serve.multiplex import _MultiplexWrapper
@@ -413,6 +413,8 @@ class LLMEngine:
 
     def stats(self) -> Dict[str, Any]:
         return {
+            "device": accelerator.device_report(),
+            "compile_cache": accelerator.compile_cache_stats(),
             "kv_blocks_total": self.pool.num_blocks,
             "kv_blocks_in_use": self.pool.in_use(),
             "kv_blocks_freed_total": self.pool.freed_total,

@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import PEAK_FLOPS
+from bench import chip_peak_flops
 from ray_tpu.models.gpt import gpt_125m, gpt_1b, train_step_flops
 from ray_tpu.models.training import (
     default_optimizer,
@@ -17,8 +17,6 @@ from ray_tpu.models.training import (
     make_train_step,
 )
 from ray_tpu.parallel.mesh import MeshSpec
-
-PEAK = PEAK_FLOPS["tpu"]
 
 
 def run(cfg_name, batch, seq, iters=10):
@@ -39,7 +37,7 @@ def run(cfg_name, batch, seq, iters=10):
         float(np.asarray(m["loss"]))
         dt = time.perf_counter() - t0
     flops = train_step_flops(cfg, batch, seq) * iters / dt
-    print(f"{cfg_name} b={batch} seq={seq}: {batch*seq*iters/dt:.0f} tok/s  mfu={flops/PEAK:.4f}", flush=True)
+    print(f"{cfg_name} b={batch} seq={seq}: {batch*seq*iters/dt:.0f} tok/s  mfu={flops/chip_peak_flops():.4f}", flush=True)
 
 
 if __name__ == "__main__":

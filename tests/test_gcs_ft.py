@@ -128,3 +128,56 @@ def test_cluster_survives_gcs_restart(tmp_path):
         except Exception:
             pass
         node.stop()
+
+
+def test_stalled_checker_is_not_a_silent_node():
+    """Time in which the GCS's own process did not run is no silence from
+    the nodes (their heartbeats are unread, and an in-process raylet could
+    send none) — but a node that stays silent afterwards still dies."""
+    import threading
+
+    from ray_tpu._private.config import GlobalConfig
+    from ray_tpu._private.ids import NodeID
+
+    saved = dict(GlobalConfig._values)
+    GlobalConfig.initialize(
+        {"health_check_period_s": 0.1, "health_check_failure_threshold": 5}
+    )
+    gcs = GcsServer()
+    client = RpcClient(gcs.address)
+    try:
+        node_id = NodeID.from_random()
+        client.call("register_node", (node_id, ("127.0.0.1", 1), {"CPU": 1.0}, {}))
+
+        def alive():
+            (view,) = client.call("get_nodes")
+            return view["alive"]
+
+        # freeze the checker once for twice the failure window (0.5 s), as a
+        # frozen process would; no heartbeat arrives meanwhile either
+        real_wait, ticks, freeze = gcs._stopped.wait, [], threading.Event()
+
+        def wait(timeout):
+            if freeze.is_set() and not ticks:
+                time.sleep(1.0)
+            if freeze.is_set():
+                ticks.append(time.monotonic())
+            return real_wait(timeout)
+
+        gcs._stopped.wait = wait
+        assert client.call("heartbeat", (node_id, {"CPU": 1.0}))
+        freeze.set()
+        deadline = time.monotonic() + 10
+        while len(ticks) < 3 and time.monotonic() < deadline:
+            time.sleep(0.02)
+        # two checks have run since the freeze, with no heartbeat in between
+        assert len(ticks) >= 3 and alive()
+        deadline = time.monotonic() + 10
+        while alive() and time.monotonic() < deadline:
+            time.sleep(0.05)
+        assert not alive(), "a node that stays silent must still be declared dead"
+    finally:
+        client.close()
+        gcs.stop()
+        with GlobalConfig._lock:
+            GlobalConfig._values = saved

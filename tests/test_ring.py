@@ -7,7 +7,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.ops.attention import _attention_xla, blockwise_attention
-from ray_tpu.ops.ring import sequence_parallel_attention
+from ray_tpu.ops.ring import mesh_attention
 
 
 def _rand(shape, seed):
@@ -26,7 +26,7 @@ def test_seq_parallel_matches_dense(impl, sp):
     ref = _attention_xla(q, k, v, causal=True)
     mesh = _mesh(sp)
     out = jax.jit(
-        lambda q, k, v: sequence_parallel_attention(q, k, v, mesh, impl=impl)
+        lambda q, k, v: mesh_attention(q, k, v, mesh, impl=impl)
     )(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=1e-4)
 
@@ -37,7 +37,7 @@ def test_seq_parallel_grads_match_dense(impl):
     mesh = _mesh(sp=4)
 
     def loss_sp(q, k, v):
-        o = sequence_parallel_attention(q, k, v, mesh, impl=impl)
+        o = mesh_attention(q, k, v, mesh, impl=impl)
         return jnp.sum(o * jnp.sin(o))
 
     def loss_ref(q, k, v):
@@ -58,7 +58,7 @@ def test_seq_parallel_with_tp_and_dp():
     ref = _attention_xla(q, k, v, causal=True)
     mesh = _mesh(sp=2, tp=2, dp=2)
     out = jax.jit(
-        lambda q, k, v: sequence_parallel_attention(q, k, v, mesh, impl="ring")
+        lambda q, k, v: mesh_attention(q, k, v, mesh, impl="ring")
     )(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=1e-4)
 
@@ -74,11 +74,6 @@ def test_blockwise_attention_matches_dense():
     np.testing.assert_allclose(np.asarray(g_out), np.asarray(g_ref), atol=5e-5, rtol=1e-3)
 
 
-@pytest.mark.skipif(
-    tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 8),
-    reason="sp=2 ring loss drifts ~0.3% from dense on jax 0.4.x "
-    "(older shard_map/attention numerics) — beyond the 2e-4 parity bar",
-)
 def test_gpt_with_ring_matches_dense():
     """Full model: sp=2 sharded train-step loss == single-device loss."""
     from ray_tpu.models.gpt import GPT, gpt_nano

@@ -10,7 +10,6 @@ import numpy as np
 import pytest
 
 import ray_tpu
-from ray_tpu.parallel.pipeline import PARTIAL_MANUAL_SUPPORTED
 from ray_tpu.train import (
     Checkpoint,
     CheckpointConfig,
@@ -212,10 +211,6 @@ def test_jax_distributed_multiprocess_bringup(ray_start_regular):
     assert result.metrics["total"] == 6.0
 
 
-@pytest.mark.skipif(
-    not PARTIAL_MANUAL_SUPPORTED,
-    reason="pp train step needs partial-manual shard_map (jax>=0.8)",
-)
 def test_north_star_pp_fsdp_tp_gang_failure_resume(ray_start_regular, tmp_path):
     """The SURVEY §7 step-5/6 composition in one assertion chain
     (VERDICT r3 next #8): gang-schedule a WorkerGroup on a placement
