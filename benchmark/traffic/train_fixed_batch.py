@@ -1,0 +1,244 @@
+"""Train traffic: one seeded batch, steps back to back for the window,
+through ``JaxTrainer`` and ``session.report`` — the train step does the work.
+
+``run`` is the parent's side and never touches jax; ``train_loop`` runs in the
+worker that holds the chip(s): it builds the sharded state from the seed in one
+jitted call, takes the batch's loss under those weights from the
+configuration's plain reference, compiles the step once, warms it (its first
+loss is the one the reference gave), measures whole steps for the
+window by its own clock around a fetched loss, and (``--trace 1``) traces a
+few of them.
+"""
+
+from __future__ import annotations
+
+import importlib
+import math
+import os
+import time
+from typing import Any, Dict, List
+
+from benchmark import chip, yardstick
+from benchmark import model as model_mod
+from benchmark.tracing import SubWindowTrace
+
+ANNOTATION = "bench.train_step"
+
+
+def train_loop(config: Dict[str, Any]) -> None:
+    """``train_loop_per_worker``: everything on the device happens here."""
+    import jax
+    import numpy as np
+
+    from ray_tpu._private import accelerator
+    from ray_tpu.models.training import (
+        default_optimizer,
+        init_sharded_state,
+        make_train_step,
+    )
+    from ray_tpu.parallel.mesh import MeshSpec
+    from ray_tpu.train import session
+
+    job, chips = config["job"], config["chips"]
+    cfg = model_mod.gpt_config(config["model"])
+    batch = tuple(job["batch"])
+    device = accelerator.device_report()
+    devices = jax.devices()[:chips]
+    mesh = MeshSpec(**job["mesh"]).build(devices)
+    opt = default_optimizer(learning_rate=job["learning_rate"])
+    t0 = time.perf_counter()
+    state, shardings = init_sharded_state(
+        cfg, mesh, opt, jax.random.PRNGKey(config["seed"]), batch
+    )
+    jax.block_until_ready(state)
+    init_s = time.perf_counter() - t0
+    step = make_train_step(cfg, opt, mesh, state_shardings_tree=shardings)
+    tokens = jax.random.randint(
+        jax.random.PRNGKey(config["seed"] + 1), batch, 0, cfg.vocab_size
+    )
+    reference = config["reference"]
+    t0 = time.perf_counter()
+    reference_loss = importlib.import_module(
+        f"benchmark.reference.{reference['module']}"
+    ).program_loss(
+        state.params, tokens, config["model"], reference["program_layer_norm_epsilon"]
+    )
+    reference_s = time.perf_counter() - t0
+
+    def one_step():
+        nonlocal state
+        t = time.perf_counter()
+        state, m = compiled(state, tokens)
+        loss = float(np.asarray(m["loss"]))       # waits for the step
+        return {
+            "loss": loss, "grad_norm": float(np.asarray(m["grad_norm"])),
+            "step_s": time.perf_counter() - t,
+        }
+
+    with mesh:
+        t0 = time.perf_counter()
+        compiled = step.lower(state, tokens).compile()
+        compile_s = time.perf_counter() - t0
+        memory = compiled.memory_analysis()
+        flash_kernels = compiled.as_text().count("tpu_custom_call")
+        warm = [one_step() for _ in range(job["warmup_steps"])]
+        cache0 = accelerator.compile_cache_stats()
+
+        trace = SubWindowTrace(ANNOTATION) if config["trace"] else None
+        trace_from = config["seconds"] * job["trace_from"]
+        steps: List[Dict[str, Any]] = []
+        report_s: List[float] = []
+        window_start = chip.now()
+        t_start = time.perf_counter()
+        while True:
+            elapsed = time.perf_counter() - t_start
+            if elapsed >= config["seconds"] and not (trace and trace.running):
+                break
+            if trace and trace.started_at is None and elapsed >= trace_from:
+                trace.start()
+            if trace and trace.running:
+                with trace.unit():
+                    m = one_step()
+                if trace.units >= job["trace_steps"]:
+                    trace.stop()
+            else:
+                m = one_step()
+            steps.append(m)
+            t = time.perf_counter()
+            session.report({"step": len(steps), "loss": m["loss"]})
+            report_s.append(time.perf_counter() - t)
+        window_s = time.perf_counter() - t_start
+        cache1 = accelerator.compile_cache_stats()
+
+    session.report({
+        "summary": True, "device": device, "init_s": init_s, "compile_s": compile_s,
+        "flash_kernels": flash_kernels, "mesh": dict(mesh.shape),
+        "reference_loss": reference_loss, "reference_s": reference_s,
+        "warm": warm, "steps": steps, "report_s": report_s,
+        "window_start": window_start, "window_s": window_s,
+        "compile_cache": [cache0, cache1],
+        "compiled_bytes": None if memory is None else {
+            "argument": memory.argument_size_in_bytes,
+            "temp": memory.temp_size_in_bytes,
+            "output": memory.output_size_in_bytes,
+            "alias": memory.alias_size_in_bytes,
+        },
+        "peak_bytes_per_device": [
+            (d.memory_stats() or {}).get("peak_bytes_in_use") for d in devices
+        ],
+        "trace": trace.result() if trace else None,
+    })
+
+
+def run(cell, seed: int, seconds: float, traced: bool, started: float) -> Dict[str, Any]:
+    """Start the cluster, run the job, and hand back what the readers read."""
+    from ray_tpu import train
+
+    config, job = cell.config, cell.config["job"]
+    model = model_mod.published_keys(config)
+    with chip.cluster(cell.chips) as worker:
+        on_tpu = chip.PLATFORM == "tpu"
+        result = train.JaxTrainer(
+            train_loop,
+            train_loop_config={
+                "model": model, "job": job, "reference": config["reference"],
+                "chips": cell.chips, "seed": seed,
+                "seconds": seconds, "trace": traced,
+            },
+            scaling_config=train.ScalingConfig(
+                num_workers=1, use_tpu=on_tpu,
+                tpu_per_worker=cell.chips if on_tpu else 0,
+            ),
+            run_config=train.RunConfig(
+                name="bench", storage_path=os.path.join(worker.session_dir, "bench_train"),
+            ),
+        ).fit()
+    if result.error is not None:
+        raise result.error
+    s = result.metrics
+    if not s.get("summary"):
+        raise RuntimeError("the train worker ended without its summary")
+    chip.check_device(s["device"], cell.chips, "the train worker")
+
+    steps, warm = s["steps"], s["warm"]
+    reported = [m for m in result.metrics_history if "step" in m and "summary" not in m]
+    losses = [m["loss"] for m in warm + steps]
+    finite = all(
+        math.isfinite(m["loss"]) and math.isfinite(m["grad_norm"]) for m in warm + steps
+    )
+    cache0, cache1 = s["compile_cache"]
+    problems = []
+    if not finite:
+        problems.append("a loss or grad norm is not finite")
+    limit = config["reference"]["max_loss_error"]
+    loss_error = abs(warm[0]["loss"] - s["reference_loss"]) / s["reference_loss"]
+    if not loss_error <= limit:
+        problems.append(
+            f"the first step's loss {warm[0]['loss']} differs from the reference's "
+            f"{s['reference_loss']} by {loss_error:.2e} of it, over the limit {limit}"
+        )
+    if not warm[-1]["loss"] < warm[0]["loss"]:
+        problems.append(f"loss did not fall over the warm-up steps: {[m['loss'] for m in warm]}")
+    if s["flash_kernels"] < job["min_flash_kernels"]:
+        problems.append(
+            f"{s['flash_kernels']} tpu_custom_call in the compiled step, want >= "
+            f"{job['min_flash_kernels']}: the flash kernel did not run"
+        )
+    if cache0 != cache1:
+        problems.append(f"something compiled inside the window: {cache0} -> {cache1}")
+    if len(reported) != len(steps):
+        problems.append(f"{len(reported)} reports came back for {len(steps)} steps")
+    for p in problems:
+        chip.say(f"NOT CORRECT: {p}")
+
+    batch = job["batch"]
+    step_s = [m["step_s"] for m in steps]
+    compiled = s["compiled_bytes"]
+    chip.say(
+        f"train worker: {s['device']}; mesh {s['mesh']}; init {s['init_s']:.2f}s, step "
+        f"compile {s['compile_s']:.2f}s, tpu_custom_call x{s['flash_kernels']}; compile "
+        f"cache {cache1}"
+    )
+    chip.say(
+        f"reference {config['reference']['module']} on the initial weights, float32, "
+        f"{s['reference_s']:.2f}s: loss {s['reference_loss']:.6f}; the step's first loss "
+        f"{warm[0]['loss']:.6f} differs by {loss_error:.2e} of it (limit {limit})"
+    )
+    chip.say(
+        f"steps taken {len(steps)} in {s['window_s']:.3f}s (asked {seconds}s); step_s "
+        f"median {yardstick.median(step_s):.4f} p95 {yardstick.percentile(step_s, 0.95):.4f} "
+        f"max {max(step_s):.4f}; losses {losses[0]:.4f} -> {losses[-1]:.4f}"
+    )
+    chip.say(
+        f"memory: peak_bytes_in_use per device {s['peak_bytes_per_device']}; the compiler "
+        f"sizes the step at {compiled} (arguments + temporaries + outputs - aliased = "
+        f"{None if compiled is None else compiled['argument'] + compiled['temp'] + compiled['output'] - compiled['alias']} B per device)"
+    )
+    trace = s["trace"]
+    if trace:
+        chip.say(
+            f"traced sub-window: {trace['units']} steps, {trace['window_s']:.3f}s from "
+            f"{trace['started_at'] - s['window_start']:.2f}s into the window; profiler "
+            f"start+stop {trace['overhead_s']:.2f}s, xplane {trace['xplane_bytes']} B, "
+            f"reduced in {trace['reduce_s']:.2f}s; per device {trace['per_device']}"
+        )
+    peaks = [p for p in s["peak_bytes_per_device"] if p]
+    return {
+        "kind": "train",
+        "correct": not problems,
+        "attempted": len(steps), "failed": 0 if finite else len(steps),
+        "setup_s": s["window_start"] - started,
+        "window_s": s["window_s"],
+        # tracing's own cost is no part of the step: leave it out of the rate
+        "work_s": s["window_s"] - (trace["overhead_s"] if trace else 0.0),
+        "steps": len(steps), "step_s": step_s, "report_s": s["report_s"],
+        "tokens_per_step": batch[0] * batch[1],
+        "flops_per_step": yardstick.train_step_flops(model, batch[0], batch[1]),
+        "chips": cell.chips, "device_kind": s["device"]["kind"],
+        "trace": trace,
+        "device": {
+            "platform": s["device"]["platform"], "kind": s["device"]["kind"],
+            "count": s["device"]["count"],
+            "memory_peak_bytes": max(peaks) if peaks else None,
+        },
+    }
