@@ -55,10 +55,9 @@ from ray_tpu import trace
 
 
 def timeline(filename=None, *, address=None):
-    """Chrome-tracing dump of all task execution — always on, no
-    ``tracing_enabled`` opt-in needed (reference: ray.timeline). Lazy
-    import: util.state pulls the RPC layer, which drivers that only
-    ``import ray_tpu`` must not pay for."""
+    """Chrome-tracing dump of all task execution — always on, no opt-in
+    (reference: ray.timeline). Lazy import: util.state pulls the RPC layer,
+    which drivers that only ``import ray_tpu`` must not pay for."""
     from ray_tpu.util.state import timeline as _timeline
 
     return _timeline(filename, address=address)

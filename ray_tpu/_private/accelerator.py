@@ -5,7 +5,8 @@ A chip belongs to one process at a time: initializing the TPU runtime claims
 it, so the driver and the raylet learn what the host holds from its device
 nodes (no ``import jax``) and leave the chip free for the worker that leases
 it. That worker — or a script that runs the model in-process — keeps its
-compiled programs in the one directory named here.
+compiled programs in the one directory named here, and writes its host spans
+onto the profiler's clock through :func:`span`.
 """
 
 from __future__ import annotations
@@ -112,6 +113,17 @@ def enable_compile_cache() -> CompileCacheStats:
 def compile_cache_stats() -> Optional[Dict[str, Any]]:
     """The counts, or None in a process that never enabled the cache."""
     return None if _stats is None else dataclasses.asdict(_stats)
+
+
+def span(name: str):
+    """A host span on the device trace's clock: a context manager that a
+    running ``jax.profiler`` session records on the calling thread, and that
+    costs one no-op enter/exit outside a session. The program names
+    ``jax.profiler`` here and nowhere else; jax is imported in the call
+    because processes that must stay off jax load this module too."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(name)
 
 
 def device_report() -> Dict[str, Any]:

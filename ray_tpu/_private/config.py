@@ -137,9 +137,6 @@ class _Config:
         # --- task events / observability ---
         "task_events_enabled": True,
         "log_to_driver": True,  # stream worker stdout/stderr to the driver
-        # opt-in distributed tracing: span context propagates through
-        # nested task submits (reference: util/tracing/tracing_helper.py)
-        "tracing_enabled": False,
         # head-based trace sampling rate in [0, 1] for the distributed
         # tracing plane (_private/trace.py): 0 disables the plane entirely
         # (hot-path hooks cost one attribute read); > 0 mints a TraceContext

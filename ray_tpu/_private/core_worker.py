@@ -1125,7 +1125,7 @@ class CoreWorker:
             retries_left=spec["max_retries_initial"],
             resubmits_left=GlobalConfig.lineage_max_resubmits,
             attempt=0,
-            trace=self._trace_ctx(task_id),
+            trace=self._trace_ctx(),
         )
         with self._pending_lock:
             self._pending[task_id] = spec
@@ -1916,7 +1916,7 @@ class CoreWorker:
             deps=deps,
             nested=nested,
             seq_no=seq,
-            trace=self._trace_ctx(task_id),
+            trace=self._trace_ctx(),
         )
         with self._pending_lock:
             self._pending[task_id] = spec
@@ -2209,40 +2209,29 @@ class CoreWorker:
     # task events + tracing
     # ------------------------------------------------------------------
 
-    def _trace_ctx(self, task_id: TaskID) -> Optional[Dict[str, Any]]:
-        """Span context for a task submitted from the current frame
-        (reference: util/tracing/tracing_helper.py — span context rides
-        inside task metadata so nested submits form one trace).
-
-        Two generations coexist. The distributed tracing plane
-        (_private/trace.py, RAYTPU_TRACE_SAMPLE) pre-allocates the task's
-        span id at submit so the executor closes exactly that span and the
-        assembled tree links parent spans across processes. The legacy
-        task-event form (tracing_enabled) keeps trace_id/parent_id with
-        span id == task id for util/tracing.py consumers; both ride in the
-        same spec dict."""
-        parent = getattr(self._task_ctx, "task_id", None) or self._current_task_id
-        if _trace._active:
-            ctx = _trace.current()
-            if ctx is None:
-                # trace root: a submit with no inherited context starts a
-                # new trace (sampling drawn here, once per trace). Multi-
-                # submit workloads share one trace by opening a root span
-                # via ray_tpu.trace.start(), which installs the context.
-                ctx = _trace.mint()
-            return {
-                "trace_id": ctx.trace_id,
-                "parent_id": parent.hex() if parent is not None else None,
-                "span_id": _trace.new_span_id(),
-                "parent_span_id": ctx.span_id,
-                "sampled": ctx.sampled,
-            }
-        if not GlobalConfig.tracing_enabled:
+    def _trace_ctx(self) -> Optional[Dict[str, Any]]:
+        """Span context for a task submitted from the current frame, or
+        None while the distributed tracing plane (_private/trace.py,
+        RAYTPU_TRACE_SAMPLE) is off. The context rides inside the task spec
+        so nested submits form one trace: the task's span id is allocated
+        here, at submit, so the executor closes exactly that span and the
+        assembled tree links parent spans across processes."""
+        if not _trace._active:
             return None
-        trace_id = getattr(self._task_ctx, "trace_id", None) or task_id.hex()
+        parent = getattr(self._task_ctx, "task_id", None) or self._current_task_id
+        ctx = _trace.current()
+        if ctx is None:
+            # trace root: a submit with no inherited context starts a
+            # new trace (sampling drawn here, once per trace). Multi-
+            # submit workloads share one trace by opening a root span
+            # via ray_tpu.trace.start(), which installs the context.
+            ctx = _trace.mint()
         return {
-            "trace_id": trace_id,
+            "trace_id": ctx.trace_id,
             "parent_id": parent.hex() if parent is not None else None,
+            "span_id": _trace.new_span_id(),
+            "parent_span_id": ctx.span_id,
+            "sampled": ctx.sampled,
         }
 
     def _emit_event(self, task_id: TaskID, state: str, name: str,

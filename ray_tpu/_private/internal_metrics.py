@@ -159,6 +159,11 @@ CATALOG: Dict[str, Tuple[str, str, Tuple[str, ...]]] = {
     "ray_tpu_llm_ttft_seconds": (
         "histogram", "time from enqueue to a request's first sampled token",
         ("deployment",)),
+    "ray_tpu_llm_queue_seconds": (
+        "histogram",
+        "time from enqueue to admission into the engine's batch with the "
+        "request's KV blocks (the part of ttft spent waiting, not prefilling)",
+        ("deployment",)),
     # -- rpc ----------------------------------------------------------
     "ray_tpu_rpc_pump_failures": (
         "counter", "native poller pump-thread crashes (streams torn down)", ()),

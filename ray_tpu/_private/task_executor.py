@@ -282,10 +282,8 @@ class TaskExecutor:
 
         token_tid = getattr(self.core._task_ctx, "task_id", None)
         token_name = getattr(self.core._task_ctx, "task_name", None)
-        token_trace = getattr(self.core._task_ctx, "trace_id", None)
         self.core._task_ctx.task_id = task_id
         self.core._task_ctx.task_name = name
-        self.core._task_ctx.trace_id = (trace or {}).get("trace_id")
         # distributed tracing plane: the submit site pre-allocated this
         # task's span id — install the context (so nested submits / RPCs /
         # object ops become children) and close exactly that span on exit
@@ -342,7 +340,6 @@ class TaskExecutor:
             print(f"::task_end {marker}", flush=True)
             self.core._task_ctx.task_id = token_tid
             self.core._task_ctx.task_name = token_name
-            self.core._task_ctx.trace_id = token_trace
             if t_ctx is not None:
                 _trace.record_span(
                     t_ctx.trace_id, t_ctx.span_id,

@@ -438,6 +438,10 @@ def make_extend_fn(cfg: GPTConfig):
     K/V chunks [layers, b, tc, heads, head_dim] for the caller to page
     back into its block pool. Deterministic given identical shapes, which
     is what makes cached-prefix decode bitwise-equal to uncached decode.
+
+    Its parts carry the scopes ``extend.embed``, ``extend.attention``,
+    ``extend.mlp`` and ``extend.logits``: names in the compiled program's
+    metadata that a device trace can be grouped by after any refactor.
     """
     if cfg.moe_num_experts:
         raise NotImplementedError("KV-cache decode does not support MoE MLPs")
@@ -452,12 +456,14 @@ def make_extend_fn(cfg: GPTConfig):
         y = y * p["scale"].astype(jnp.float32) + p["bias"].astype(jnp.float32)
         return y.astype(dtype)
 
+    @jax.named_scope("extend.mlp")
     def _mlp(x, p):
         y = jnp.einsum("btd,df->btf", x, p["wi"]["kernel"].astype(dtype))
         y = nn.gelu(y + p["wi"]["bias"].astype(dtype))
         y = jnp.einsum("btf,fd->btd", y, p["wo"]["kernel"].astype(dtype))
         return y + p["wo"]["bias"].astype(dtype)
 
+    @jax.named_scope("extend.attention")
     def _attend(p, hidden, positions, kc, vc):
         q = jnp.einsum("btd,dhk->bthk", hidden, p["q"]["kernel"].astype(dtype))
         k = jnp.einsum("btd,dhk->bthk", hidden, p["k"]["kernel"].astype(dtype))
@@ -497,8 +503,9 @@ def make_extend_fn(cfg: GPTConfig):
             lengths[:, None].astype(jnp.int32)
             + jnp.arange(tc, dtype=jnp.int32)[None, :]
         )
-        emb = params["wte"]["embedding"].astype(dtype)
-        x = emb[jnp.clip(tokens, 0, cfg.vocab_size - 1)]
+        with jax.named_scope("extend.embed"):
+            emb = params["wte"]["embedding"].astype(dtype)
+            x = emb[jnp.clip(tokens, 0, cfg.vocab_size - 1)]
         layers = stacked_layer_params(params, cfg)
 
         def body(carry, xs):
@@ -507,15 +514,16 @@ def make_extend_fn(cfg: GPTConfig):
             return y, (k, v)
 
         x, (k_new, v_new) = jax.lax.scan(body, x, (layers, k_cache, v_cache))
-        x = _ln(x, params["ln_f"])
-        if cfg.tie_embeddings:
-            kernel, bias = emb.T, None
-        else:
-            kernel = params["lm_head"]["kernel"].astype(dtype)
-            bias = params["lm_head"]["bias"]
-        logits = (x @ kernel).astype(jnp.float32)
-        if bias is not None:
-            logits = logits + bias.astype(jnp.float32)
+        with jax.named_scope("extend.logits"):
+            x = _ln(x, params["ln_f"])
+            if cfg.tie_embeddings:
+                kernel, bias = emb.T, None
+            else:
+                kernel = params["lm_head"]["kernel"].astype(dtype)
+                bias = params["lm_head"]["bias"]
+            logits = (x @ kernel).astype(jnp.float32)
+            if bias is not None:
+                logits = logits + bias.astype(jnp.float32)
         return logits, x.astype(jnp.float32), k_new, v_new
 
     return extend

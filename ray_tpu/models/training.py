@@ -133,12 +133,17 @@ def make_train_step(
     state_shardings_tree: Any = None,
     donate: bool = True,
 ) -> Callable:
-    """Build `step(state, tokens) -> (state, metrics)`, jitted with shardings."""
+    """Build `step(state, tokens) -> (state, metrics)`, jitted with shardings.
+
+    Its parts carry the scopes ``train.forward``, ``train.loss`` and
+    ``train.optimizer`` (the backward pass inherits the forward's): names in
+    the compiled program's metadata that a device trace can be grouped by."""
     model = GPT(cfg, return_hidden=True, mesh=mesh)
     active_rules = list(rules if rules is not None else shd.DEFAULT_RULES)
 
     moe = cfg.moe_num_experts > 0
 
+    @jax.named_scope("train.forward")
     def _apply(params, tokens):
         """Run the model; with MoE also collect the per-layer aux losses
         (sown into the 'losses' collection by MoeMlp)."""
@@ -160,15 +165,18 @@ def make_train_step(
         else:
             (hidden, kernel, bias), aux = _apply(params, tokens)
         # Blockwise xent: never materializes the [b, t, vocab] logits.
-        loss = blockwise_next_token_loss(
-            hidden, kernel, bias, tokens, chunk=cfg.ce_chunk
-        )
+        with jax.named_scope("train.loss"):
+            loss = blockwise_next_token_loss(
+                hidden, kernel, bias, tokens, chunk=cfg.ce_chunk
+            )
         return loss + cfg.moe_aux_weight * aux
 
     def step(state: TrainState, tokens: jax.Array):
         loss, grads = jax.value_and_grad(loss_fn)(state.params, tokens)
-        updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("train.optimizer"):
+            updates, new_opt = optimizer.update(
+                grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
         metrics = {
             "loss": loss,
             "grad_norm": optax.global_norm(grads),

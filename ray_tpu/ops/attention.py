@@ -276,6 +276,7 @@ def _flash_attention_tpu(
     )
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bhi, qi, ki: (bhi, qi, 0)),
@@ -502,6 +503,7 @@ def _flash_attention_tpu_bwd(
     kv_spec_dq = pl.BlockSpec((1, block_k, d), lambda bhi, i, j: (bhi, j, 0))
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, **common),
+        name="flash_bwd_dq",
         grid=(bh, pl.cdiv(t_q, block_q), pl.cdiv(t_kv, block_k)),
         in_specs=[q_spec, kv_spec_dq, kv_spec_dq, q_spec, row_spec, row_spec],
         out_specs=q_spec,
@@ -516,6 +518,7 @@ def _flash_attention_tpu_bwd(
     kv_spec2 = pl.BlockSpec((1, block_k, d), lambda bhi, j, i: (bhi, j, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, **common),
+        name="flash_bwd_dkv",
         grid=(bh, pl.cdiv(t_kv, block_k), pl.cdiv(t_q, block_q)),
         in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2, row_spec2],
         out_specs=[kv_spec2, kv_spec2],

@@ -28,13 +28,15 @@ drained, flusher thread stopped — when the instance is collected or
 
 from __future__ import annotations
 
+import contextlib
 import functools
+import sys
 import threading
 import time
 import weakref
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
-from ray_tpu._private import internal_metrics
+from ray_tpu._private import accelerator, internal_metrics
 
 
 def bucket_pad_size(n: int, bucket_sizes: Sequence[int]) -> int:
@@ -206,6 +208,16 @@ class _Sequence:
                 pass
 
 
+def _idle_span():
+    """Waiting for requests, as a span on the profiler's clock — so a profile
+    of a replica shows it as ``serve.batch_idle`` and not as a hole. Only in
+    a process that already runs jax: the batcher imports it for no
+    deployment."""
+    if "jax" in sys.modules:
+        return accelerator.span("serve.batch_idle")
+    return contextlib.nullcontext()
+
+
 def _caller_cancelled() -> bool:
     """True when the task running the current thread was cooperatively
     cancelled (``ray_tpu.cancel(force=False)`` — the async proxy's
@@ -288,7 +300,8 @@ class _ContinuousBatcher:
         while True:
             with self.cv:
                 while not self.queue and not active and not self._stop:
-                    self.cv.wait()
+                    with _idle_span():
+                        self.cv.wait()
                 if self._stop and not self.queue and not active:
                     return
                 if not active and self.timeout > 0 and not self._stop:

@@ -29,10 +29,17 @@ serving setup from PAPERS.md):
   object plane via :func:`ray_tpu.serve.register_model` and streamed to
   replicas on miss through the PR 9 multiplex LRU, so thousands of model
   ids share one resident base model.
+* phases — every part of an engine step runs inside ``LLMEngine._phase``:
+  a span ``llm.<phase>`` on the profiler's clock (recorded while a
+  profiler session runs in the replica, so a device idle gap names the
+  host phase under it; ``accelerator.span``) and, always, wall time and counts that
+  ``stats()`` / ``kv_stats`` return beside the bytes, lanes and cache
+  slots each device call moved.
 """
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import math
 import queue as queue_mod
@@ -356,8 +363,23 @@ class _SeqState:
     __slots__ = (
         "prompt", "max_new", "eos", "model_id", "adapter", "lease", "blocks",
         "pos", "length", "out", "last_token", "cached_tokens", "hashes",
-        "ttft_s", "stream_q", "cancel_ev", "return_logits", "logits",
+        "ttft_s", "queue_s", "stream_q", "cancel_ev", "return_logits",
+        "logits",
     )
+
+
+#: the phases of an engine step, as ``phase_s`` / ``phase_n`` key them and as
+#: the profiler sees them (``llm.<phase>``). ``step`` holds ``admit``,
+#: ``prefill`` and ``decode``; those two hold the six phases of a device call.
+#: The leaves partition a step: what is in none of them is a missing phase.
+LEAF_PHASES = (
+    "admit", "kv_gather", "upload", "dispatch", "fetch", "kv_scatter", "sample",
+)
+PHASES = ("step", "prefill", "decode") + LEAF_PHASES
+#: what one ``_phase`` may cost outside a profiler session, where its span is
+#: a no-op (2.7 us on the sandbox's CPU): under 0.3 ms for the <= 30 phases of
+#: a step. ``tests/test_llm_spans.py`` holds the engine to it.
+PHASE_BUDGET_NS = 10_000.0
 
 
 class LLMEngine:
@@ -406,12 +428,32 @@ class LLMEngine:
         #: fault injection: stretch every engine step (chaos / cancellation
         #: tests need the decode window to outlive a few control RPCs)
         self.step_delay_s = float(step_delay_s)
+        # counters: always on, read as deltas through ``stats()``. Bytes are
+        # computed from the sizes of the arrays handed over, not measured.
         self.steps = 0
+        self.admitted = 0
+        self.queue_s = 0.0              # sum of enqueue -> admitted
+        self.prefill_tokens = 0
         self.decode_tokens = 0
+        self.cache_tokens = 0           # live tokens copied into padded caches
+        self.cache_slots = 0            # lanes x cache bucket of those caches
+        self.h2d_bytes = 0
+        self.d2h_bytes = 0
+        self.lanes_used = 0             # real lanes of the device calls
+        self.lane_slots = 0             # their lane buckets
+        self.phase_s: Dict[str, float] = dict.fromkeys(PHASES, 0.0)
+        self.phase_n: Dict[str, int] = dict.fromkeys(PHASES, 0)
+        #: the slowest step since ``stats()`` was last read (so two reads
+        #: bound a window, as they do for the counters): when it ended, how
+        #: long it took, its lanes and its own seconds per phase. Time in
+        #: ``fetch`` is the device or the runtime; anywhere else, the host.
+        self.slowest_step: Optional[Dict[str, Any]] = None
 
     # -- public stats ------------------------------------------------------
 
     def stats(self) -> Dict[str, Any]:
+        # a read takes ``slowest_step`` with it: the next starts from here
+        slowest, self.slowest_step = self.slowest_step, None
         return {
             "device": accelerator.device_report(),
             "compile_cache": accelerator.compile_cache_stats(),
@@ -424,20 +466,48 @@ class LLMEngine:
             "prefix_cached_blocks": len(self.prefix) if self.prefix else 0,
             "adapters_resident": self._mux.loaded_ids(),
             "steps": self.steps,
+            "admitted": self.admitted,
+            "queue_s": self.queue_s,
+            "prefill_tokens": self.prefill_tokens,
             "decode_tokens": self.decode_tokens,
+            "cache_tokens": self.cache_tokens,
+            "cache_slots": self.cache_slots,
+            "h2d_bytes": self.h2d_bytes,
+            "d2h_bytes": self.d2h_bytes,
+            "lanes_used": self.lanes_used,
+            "lane_slots": self.lane_slots,
+            "phase_s": dict(self.phase_s),
+            "phase_n": dict(self.phase_n),
+            "slowest_step": slowest,
         }
+
+    @contextlib.contextmanager
+    def _phase(self, name: str):
+        """One phase of a step: a span ``llm.<name>`` on the profiler's clock,
+        recorded while a profiler session runs (``accelerator.span``), and
+        always its wall time and a count in ``phase_s`` / ``phase_n``."""
+        t0 = time.perf_counter()
+        try:
+            with accelerator.span("llm." + name):
+                yield
+        finally:
+            self.phase_s[name] += time.perf_counter() - t0
+            self.phase_n[name] += 1
 
     # -- scheduling --------------------------------------------------------
 
     def step(self, seqs: List[Any]) -> None:
+        before, lanes0 = dict(self.phase_s), self.lanes_used
         try:
-            if self.step_delay_s:
-                time.sleep(self.step_delay_s)
-            self._admit(seqs)
-            self._sweep_cancelled(seqs)
-            self._prefill_step(seqs)
-            self._decode_step(seqs)
-            self.steps += 1
+            with self._phase("step"):
+                if self.step_delay_s:
+                    time.sleep(self.step_delay_s)
+                with self._phase("admit"):
+                    self._admit(seqs)
+                    self._sweep_cancelled(seqs)
+                self._prefill_step(seqs)
+                self._decode_step(seqs)
+                self.steps += 1
         except BaseException:
             # a crashed forward poisons the batch (the batcher fails every
             # caller) — the leases must not ride down with it
@@ -446,6 +516,16 @@ class LLMEngine:
                 if isinstance(st, _SeqState) and st.lease is not None:
                     st.lease.release()
             raise
+        wall_s = self.phase_s["step"] - before["step"]
+        if self.slowest_step is None or wall_s > self.slowest_step["wall_s"]:
+            self.slowest_step = {
+                "at": time.time(), "wall_s": wall_s,
+                "lanes": self.lanes_used - lanes0,
+                "phase_s": {
+                    k: v - before[k] for k, v in self.phase_s.items()
+                    if v > before[k]
+                },
+            }
 
     def _admit(self, seqs) -> None:
         for s in seqs:
@@ -466,6 +546,7 @@ class LLMEngine:
             st.out = []
             st.last_token = None
             st.ttft_s = None
+            st.queue_s = None
             if not st.prompt or st.max_new < 1:
                 s.fail(ValueError("payload needs a non-empty 'prompt'"))
                 continue
@@ -511,6 +592,9 @@ class LLMEngine:
                         f"loading adapter {st.model_id!r} failed: {e!r}"))
                     continue
             s.state = st
+            st.queue_s = time.monotonic() - s.enqueued_at
+            self.admitted += 1
+            self.queue_s += st.queue_s
 
     def _sweep_cancelled(self, seqs) -> None:
         for s in seqs:
@@ -534,29 +618,38 @@ class LLMEngine:
         ]
         if not pending:
             return
-        lanes = pending[:self.prefill_lanes]
-        states = [s.state for s in lanes]
-        chunks = [
-            min(self.prefill_chunk, len(st.prompt) - st.pos) for st in states
-        ]
-        tc = batching.bucket_pad_size(max(chunks), self.prefill_token_buckets)
-        logits, hidden, k_new, v_new, b = self._run_extend(
-            states, [st.prompt[st.pos:st.pos + c]
-                     for st, c in zip(states, chunks)], tc)
-        for i, (s, st, c) in enumerate(zip(lanes, states, chunks)):
-            self._scatter(st, k_new[:, i, :c], v_new[:, i, :c])
-            st.pos += c
-            st.length += c
+        with self._phase("prefill"):
+            lanes = pending[:self.prefill_lanes]
+            states = [s.state for s in lanes]
+            chunks = [
+                min(self.prefill_chunk, len(st.prompt) - st.pos)
+                for st in states
+            ]
+            tc = batching.bucket_pad_size(
+                max(chunks), self.prefill_token_buckets)
+            logits, hidden, k_new, v_new = self._run_extend(
+                states, [st.prompt[st.pos:st.pos + c]
+                         for st, c in zip(states, chunks)], tc)
+            with self._phase("kv_scatter"):
+                for i, (st, c) in enumerate(zip(states, chunks)):
+                    self._scatter(st, k_new[:, i, :c], v_new[:, i, :c])
+                    st.pos += c
+                    st.length += c
+            fed = sum(chunks)
+            self.prefill_tokens += fed
             internal_metrics.inc(
-                "ray_tpu_llm_prefill_tokens_total", c,
+                "ray_tpu_llm_prefill_tokens_total", fed,
                 {"deployment": self.deployment},
             )
-            if st.pos >= len(st.prompt):
-                if self.prefix is not None:
-                    # cache every full prompt block (first writer wins)
-                    self.prefix.insert(
-                        st.hashes, st.blocks[:len(st.hashes)])
-                self._emit(s, st, logits[i, c - 1], hidden[i, c - 1])
+            with self._phase("sample"):
+                for i, (s, st, c) in enumerate(zip(lanes, states, chunks)):
+                    if st.pos < len(st.prompt):
+                        continue
+                    if self.prefix is not None:
+                        # cache every full prompt block (first writer wins)
+                        self.prefix.insert(
+                            st.hashes, st.blocks[:len(st.hashes)])
+                    self._emit(s, st, logits[i, c - 1], hidden[i, c - 1])
 
     def _decode_step(self, seqs) -> None:
         decoding = [
@@ -566,31 +659,38 @@ class LLMEngine:
         max_lanes = self.lane_buckets[-1]
         while decoding:
             lanes, decoding = decoding[:max_lanes], decoding[max_lanes:]
-            states = []
-            for s in lanes:
-                st = s.state
-                # grow the cache for the token about to be written
-                need_blocks = (st.length // self.block_size) + 1
-                try:
-                    if need_blocks > len(st.blocks):
-                        st.lease.add(self.pool.allocate(
-                            need_blocks - len(st.blocks)))
-                    self.pool.ensure_private(
-                        st.blocks, st.length // self.block_size)
-                except NoKVBlocksError as e:
-                    st.lease.release()
-                    s.fail(BackPressureError(str(e), retry_after_s=0.05))
-                    continue
-                states.append((s, st))
-            if not states:
+            with self._phase("decode"):
+                self._decode_lanes(lanes)
+
+    def _decode_lanes(self, lanes) -> None:
+        states = []
+        for s in lanes:
+            st = s.state
+            # grow the cache for the token about to be written
+            need_blocks = (st.length // self.block_size) + 1
+            try:
+                if need_blocks > len(st.blocks):
+                    st.lease.add(self.pool.allocate(
+                        need_blocks - len(st.blocks)))
+                self.pool.ensure_private(
+                    st.blocks, st.length // self.block_size)
+            except NoKVBlocksError as e:
+                st.lease.release()
+                s.fail(BackPressureError(str(e), retry_after_s=0.05))
                 continue
-            sts = [st for _, st in states]
-            logits, hidden, k_new, v_new, b = self._run_extend(
-                sts, [[st.last_token] for st in sts], 1)
-            for i, (s, st) in enumerate(states):
+            states.append((s, st))
+        if not states:
+            return
+        sts = [st for _, st in states]
+        logits, hidden, k_new, v_new = self._run_extend(
+            sts, [[st.last_token] for st in sts], 1)
+        with self._phase("kv_scatter"):
+            for i, st in enumerate(sts):
                 self._scatter(st, k_new[:, i, :1], v_new[:, i, :1])
                 st.length += 1
-                self.decode_tokens += 1
+        self.decode_tokens += len(sts)
+        with self._phase("sample"):
+            for i, (s, st) in enumerate(states):
                 self._emit(s, st, logits[i, 0], hidden[i, 0])
 
     # -- device call + paging ---------------------------------------------
@@ -602,24 +702,31 @@ class LLMEngine:
         t_max = max(
             st.length + len(ch) for st, ch in zip(states, token_chunks))
         t_cap = batching.bucket_pad_size(t_max, self.cache_buckets)
-        tokens = np.zeros((b, tc), np.int32)
-        lengths = np.zeros((b,), np.int32)
-        for i, (st, ch) in enumerate(zip(states, token_chunks)):
-            tokens[i, :len(ch)] = ch
-            lengths[i] = st.length
-        k_cache, v_cache = self._gather(states, b, t_cap)
-        logits, hidden, k_new, v_new = self._extend(
-            self._params, jnp.asarray(tokens), jnp.asarray(lengths),
-            k_cache, v_cache,
-        )
-        return (
-            np.asarray(logits), np.asarray(hidden),
-            np.asarray(k_new), np.asarray(v_new), b,
-        )
+        with self._phase("kv_gather"):
+            tokens = np.zeros((b, tc), np.int32)
+            lengths = np.zeros((b,), np.int32)
+            for i, (st, ch) in enumerate(zip(states, token_chunks)):
+                tokens[i, :len(ch)] = ch
+                lengths[i] = st.length
+            host = (tokens, lengths) + self._gather(states, b, t_cap)
+        with self._phase("upload"):
+            self.h2d_bytes += sum(a.nbytes for a in host)
+            args = [jnp.asarray(a) for a in host]
+            del host        # freeing the padded pair is part of this phase
+        with self._phase("dispatch"):
+            out = self._extend(self._params, *args)
+            del args
+            self.lanes_used += len(states)
+            self.lane_slots += b
+        with self._phase("fetch"):
+            # waits for the upload and the device, then copies back
+            out = tuple(np.asarray(o) for o in out)
+            self.d2h_bytes += sum(o.nbytes for o in out)
+        return out
 
     def _gather(self, states, b: int, t_cap: int):
-        import jax.numpy as jnp
-
+        """The padded K/V pair ``[layers, b, t_cap, heads, head_dim]`` of the
+        lanes' caches, built on the host from their blocks."""
         cfg, bs = self.cfg, self.block_size
         k = np.zeros(
             (cfg.num_layers, b, t_cap, cfg.num_heads, cfg.head_dim),
@@ -633,7 +740,9 @@ class LLMEngine:
                 blk = st.blocks[j]
                 k[:, i, lo:hi] = self.pool.k_data[blk][:, :hi - lo]
                 v[:, i, lo:hi] = self.pool.v_data[blk][:, :hi - lo]
-        return jnp.asarray(k), jnp.asarray(v)
+        self.cache_tokens += sum(st.length for st in states)
+        self.cache_slots += b * t_cap
+        return k, v
 
     def _scatter(self, st: _SeqState, k_new, v_new) -> None:
         bs = self.block_size
@@ -665,6 +774,10 @@ class LLMEngine:
                 "ray_tpu_llm_ttft_seconds", st.ttft_s,
                 {"deployment": self.deployment},
             )
+            internal_metrics.observe(
+                "ray_tpu_llm_queue_seconds", st.queue_s,
+                {"deployment": self.deployment},
+            )
         if st.stream_q is not None:
             st.stream_q.put(("tok", tok))
         if len(st.out) >= st.max_new or (st.eos is not None
@@ -676,6 +789,7 @@ class LLMEngine:
         result: Dict[str, Any] = {
             "tokens": st.out,
             "ttft_s": st.ttft_s,
+            "queue_s": st.queue_s,
             "prefix_cached_tokens": st.cached_tokens,
             "prefill_tokens": len(st.prompt) - st.cached_tokens,
             "model_id": st.model_id,
@@ -699,9 +813,12 @@ class LLMServer:
     ``{"prompt": [token ids], "max_new_tokens": n, "model_id": "lora:x",
     "eos_token": id, "return_logits": bool}``
 
-    Results carry ``tokens``, ``ttft_s``, ``prefix_cached_tokens`` and
-    ``prefill_tokens``. Deploy with ``slo_ttft_p99_s=...`` to get the
-    auto-registered ``serve-<name>-ttft-p99`` SLO rule."""
+    Results carry ``tokens``, ``ttft_s`` (replica enqueue -> first token),
+    ``queue_s`` (enqueue -> admitted with its KV blocks, so ``ttft_s -
+    queue_s`` is the prefill the request itself needed),
+    ``prefix_cached_tokens`` and ``prefill_tokens``. Deploy with
+    ``slo_ttft_p99_s=...`` to get the auto-registered
+    ``serve-<name>-ttft-p99`` SLO rule."""
 
     def __init__(self, cfg=None, **engine_kwargs):
         self._engine = LLMEngine(cfg, **engine_kwargs)

@@ -436,8 +436,8 @@ def timeline(
 ) -> List[Dict[str, Any]]:
     """Chrome-tracing dump of ALL task execution (reference:
     _private/state.py:416 chrome_tracing_dump; view in ui.perfetto.dev).
-    Always on — task events flow to the GCS regardless of the
-    ``tracing_enabled`` opt-in, so this works on any live cluster.
+    Always on — task events flow to the GCS with no opt-in, so this works
+    on any live cluster.
 
     One ``pid`` lane per node, one ``tid`` row per worker.
     RUNNING→FINISHED/FAILED event pairs become complete ("X") slices on the

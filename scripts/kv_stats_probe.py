@@ -1,0 +1,217 @@
+"""One run of a serve cell through the benchmark's own ``run_cell``, keeping
+what the benchmark drops: the two ``kv_stats`` reads around the run (lead-in,
+window, lead-out, drain), a poll of ``kv_stats`` beside it, each result's
+``queue_s``, and the reduced trace. Prints the split of an engine step by
+``phase_s``, the bytes, fills and queue time, and ``breakdown.idle_gaps``
+beside them: the numbers of PERF.md section 5's serve paragraph.
+
+    chiprun -- python3 scripts/kv_stats_probe.py --seed 2147484227 --trace 1 --poll 0.5
+
+Reads a parent without the counters as far as it goes. ``--root`` names
+another checkout to run (it must hold ``benchmark/`` and ``ray_tpu/``).
+"""
+
+import argparse
+import json
+import os
+import statistics
+import sys
+import threading
+import time
+
+LEAF = ("admit", "kv_gather", "upload", "dispatch", "fetch", "kv_scatter", "sample")
+NOT_COUNTERS = ("device", "compile_cache", "adapters_resident")
+
+
+class _Kept:
+    """A response whose answer is also handed to ``keep``."""
+
+    def __init__(self, response, keep):
+        self._response, self._keep = response, keep
+
+    def result(self, *args, **kwargs):
+        got = self._response.result(*args, **kwargs)
+        self._keep(got)
+        return got
+
+
+class _KvStats:
+    def __init__(self, method, snaps):
+        self._method, self._snaps = method, snaps
+
+    def remote(self, *args, **kwargs):
+        return _Kept(
+            self._method.remote(*args, **kwargs),
+            lambda got: self._snaps.append({"t": time.time(), "stats": got}),
+        )
+
+
+class _Recorder:
+    """The handle, keeping every ``kv_stats`` answer and every result's ``queue_s``."""
+
+    def __init__(self, handle, snaps, queued):
+        self._handle, self._snaps, self._queued = handle, snaps, queued
+
+    def remote(self, *args, **kwargs):
+        return _Kept(
+            self._handle.remote(*args, **kwargs),
+            lambda got: self._queued.append(got["queue_s"]) if "queue_s" in got else None,
+        )
+
+    def __getattr__(self, name):
+        real = getattr(self._handle, name)
+        return _KvStats(real, self._snaps) if name == "kv_stats" else real
+
+
+def probe(root, workload, seed, seconds, traced, poll_s=0.0):
+    """Run the cell from ``root``; returns the last line and what was kept."""
+    root = os.path.abspath(root)
+    sys.path.insert(0, root)
+    from benchmark import contract, run as run_mod
+    from benchmark.traffic import serve_open_loop
+
+    snaps, polled, queued, stop = [], [], [], threading.Event()
+    real_deploy = serve_open_loop.deploy
+
+    def deploy(cell, seed):
+        out = real_deploy(cell, seed)
+        handle = out[0]
+
+        def poll():
+            while not stop.wait(poll_s):
+                try:
+                    got = handle.kv_stats.remote().result(timeout=30.0)
+                except Exception as e:  # noqa: BLE001 — the probe must not end the run
+                    polled.append({"t": time.time(), "error": repr(e)})
+                    continue
+                polled.append({"t": time.time(), "stats": {
+                    k: v for k, v in got.items() if k not in NOT_COUNTERS
+                }})
+        if poll_s > 0:
+            threading.Thread(target=poll, daemon=True).start()
+        return (_Recorder(handle, snaps, queued),) + tuple(out[1:])
+
+    serve_open_loop.deploy = deploy
+    try:
+        line, cell, run = run_mod.run_cell(root, workload, seed, seconds, traced, time.time())
+    finally:
+        stop.set()
+        serve_open_loop.deploy = real_deploy
+    return {
+        "root": root, "workload": workload, "seed": seed, "traced": traced,
+        "line": line,
+        "problems": contract.violations(line, cell.metrics(traced), traced),
+        "trace": run.get("trace"), "setup_s": run.get("setup_s"),
+        "records": [{k: v for k, v in r.items() if k != "tokens"} for r in run.get("records", [])],
+        "queue_s": queued, "snaps": snaps, "polled": polled,
+    }
+
+
+def delta(after, before):
+    out = {}
+    for k, v in after.items():
+        if isinstance(v, dict) and isinstance(before.get(k), dict):
+            out[k] = {p: v[p] - before[k].get(p, 0) for p in v if isinstance(v[p], (int, float))}
+        elif isinstance(v, (int, float)) and not isinstance(v, bool) and k in before:
+            out[k] = v - before[k]
+    return out
+
+
+def say_split(d, label, say=print):
+    """The split of the steps between two ``kv_stats`` reads, from their delta."""
+    if "phase_s" not in d:
+        say(f"  {label}: no phase_s (a parent of PR 24):",
+            {k: d[k] for k in ("steps", "decode_tokens") if k in d})
+        return
+    ph, n, steps = d["phase_s"], d["phase_n"], max(d["steps"], 1)
+    step, calls, decoded = ph["step"], max(n["dispatch"], 1), max(d["decode_tokens"], 1)
+    if step <= 0:
+        say(f"  {label}: no step")
+        return
+    say(f"  {label}: {d['steps']} steps, {1e3 * step / steps:.2f} ms a step, "
+        f"leaf phases / step {sum(ph[k] for k in LEAF) / step:.4f}")
+    for k in ("prefill", "decode") + LEAF:
+        say(f"     {k:10s} {ph[k]:8.3f} s {100 * ph[k] / step:6.2f} %  n={n[k]:<5d}"
+            f"{1e3 * ph[k] / max(n[k], 1):8.2f} ms each")
+    say(f"     h2d {d['h2d_bytes'] / 1e9:.3f} GB: {d['h2d_bytes'] / calls / 1e6:.1f} MB a call, "
+        f"{d['h2d_bytes'] / steps / 1e6:.1f} a step, {d['h2d_bytes'] / decoded / 1e6:.1f} a decode "
+        f"token; d2h {d['d2h_bytes'] / steps / 1e6:.1f} MB a step (from array sizes)")
+    say(f"     lane_fill {d['lanes_used']}/{d['lane_slots']} = "
+        f"{d['lanes_used'] / max(d['lane_slots'], 1):.3f}; cache_fill {d['cache_tokens']}/"
+        f"{d['cache_slots']} = {d['cache_tokens'] / max(d['cache_slots'], 1):.3f}")
+    say(f"     admitted {d['admitted']}, queue_s mean {d['queue_s'] / max(d['admitted'], 1):.4f}; "
+        f"prefill {d['prefill_tokens']} tokens in {n['prefill']} calls; decode "
+        f"{d['decode_tokens']} tokens in {n['decode']} calls")
+    if ph["upload"] > 0 and ph["fetch"] > 0:
+        say(f"     upload {d['h2d_bytes'] / ph['upload'] / 1e9:.2f} GB/s; fetch (with the wait for "
+            f"the device) {d['d2h_bytes'] / ph['fetch'] / 1e9:.3f} GB/s")
+
+
+def report(kept, say=print):
+    line, trace = kept["line"], kept["trace"]
+    say(f"{kept['workload']} seed {kept['seed']} traced {kept['traced']}: correct "
+        f"{line['correct']}, failed {line['failed']} of {line['attempted']}, set-up "
+        f"{kept['setup_s']:.1f} s, problems {kept['problems']}")
+    say("  metrics", {k: round(v["value"], 4) for k, v in line["metrics"].items()})
+    done = [r for r in kept["records"] if "ttft_s" in r]
+    if done:
+        say(f"  latency mean {statistics.mean(r['done'] - r['due'] for r in done):.3f} s; ttft_s "
+            f"median {statistics.median(r['ttft_s'] for r in done):.3f} max "
+            f"{max(r['ttft_s'] for r in done):.3f}")
+    if kept["queue_s"]:
+        q = sorted(kept["queue_s"])
+        say(f"  queue_s of {len(q)} results (lead-in and lead-out too): median "
+            f"{statistics.median(q):.4f} max {q[-1]:.4f}")
+    if trace and "busy_s" in trace:
+        w, eng = trace["window_s"], trace["engine"]
+        say(f"  traced sub-window {w:.3f} s, busy {trace['busy_s']:.3f} s, idle share "
+            f"{1 - trace['busy_s'] / w:.4f}; engine.step_ms "
+            f"{1e3 * eng['in_step_s'] / max(eng['steps'], 1):.2f} over {eng['steps']} steps")
+        for name, t in trace["idle_gaps"]:
+            say(f"     idle gap {name:56s} {t:8.4f} s {100 * t / w:6.2f} % of the sub-window")
+        say("  device_ops:", [(n, round(t, 4)) for n, t in trace["device_ops"]])
+    snaps = kept["snaps"]
+    if len(snaps) >= 2:         # the generator's reads: the last two are stats0 and stats1
+        s0, s1 = snaps[-2]["stats"], snaps[-1]["stats"]
+        say_split(delta(s1, s0), "stats1 - stats0 (lead-in, window, lead-out, drain)", say)
+        say("  compile_cache", s0.get("compile_cache"), "->", s1.get("compile_cache"))
+    # a read takes slowest_step with it, so the run's slowest is over every read
+    slow = [x["stats"].get("slowest_step") for x in snaps[-1:] + kept["polled"] if "stats" in x]
+    slow = [s for s in slow if s]
+    if slow:
+        say("  slowest_step since stats0:", json.dumps(max(slow, key=lambda s: s["wall_s"])))
+    polled = [x for x in kept["polled"] if "stats" in x]
+    if trace and polled and "started_at" in trace:
+        a = max((x for x in polled if x["t"] <= trace["started_at"]), key=lambda x: x["t"], default=None)
+        b = min((x for x in polled if x["t"] >= trace["stopped_at"]), key=lambda x: x["t"], default=None)
+        if a and b:
+            say_split(
+                delta(b["stats"], a["stats"]),
+                f"polled, around the traced sub-window ({a['t'] - trace['started_at']:+.2f} s .. "
+                f"{b['t'] - trace['stopped_at']:+.2f} s)", say,
+            )
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--root", default=".")
+    ap.add_argument("--workload", default="gptj-serve-chat-steady")
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, default=51.0)
+    ap.add_argument("--trace", type=int, default=1)
+    ap.add_argument("--poll", type=float, default=0.0, help="seconds between kv_stats polls; 0: none")
+    ap.add_argument("--out", default="chiprun_out", help="where probe_<seed>_t<trace>.json goes")
+    args = ap.parse_args()
+    out = os.path.abspath(args.out)
+    kept = probe(args.root, args.workload, args.seed, args.seconds, bool(args.trace), args.poll)
+    os.makedirs(out, exist_ok=True)
+    path = os.path.join(out, f"probe_{args.seed}_t{args.trace}.json")
+    with open(path, "w") as f:
+        json.dump(kept, f, default=str)
+    report(kept)
+    print("[probe] kept in", path)
+    print("[probe] LINE " + json.dumps(kept["line"]))
+
+
+if __name__ == "__main__":
+    main()

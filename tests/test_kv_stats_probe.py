@@ -1,0 +1,49 @@
+"""``scripts/kv_stats_probe.py`` against the benchmark's tiny serve cell on CPU
+workers: the tool that PERF.md's serve breakdown is read with must keep
+working as the engine's counters and the benchmark's flow change."""
+
+import importlib.util
+import os
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(REPO, "tests", "benchmark"))
+
+import bench_helpers  # noqa: E402
+from benchmark import chip, yardstick  # noqa: E402
+from ray_tpu.serve import llm  # noqa: E402
+
+
+def _load():
+    spec = importlib.util.spec_from_file_location(
+        "kv_stats_probe", os.path.join(REPO, "scripts", "kv_stats_probe.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def test_probe_keeps_the_reads_the_benchmark_drops(tmp_path, monkeypatch):
+    monkeypatch.setattr(chip, "PLATFORM", "cpu")
+    monkeypatch.setitem(yardstick.PEAKS, "cpu", {"bf16_flops": 1e12})
+    root = bench_helpers.copy_benchmark(tmp_path)
+    bench_helpers.add_tiny_cells(root)
+    probe = _load()
+    assert probe.LEAF == llm.LEAF_PHASES
+    kept = probe.probe(root, "tiny-serve-cell", 2**31 + 5, 1.5, False, poll_s=0.2)
+    assert kept["line"]["correct"] and kept["line"]["failed"] == 0
+    stats0, stats1 = (s["stats"] for s in kept["snaps"][-2:])
+    d = probe.delta(stats1, stats0)
+    lead = bench_helpers.TINY_CHAT["lead_in_requests"] + bench_helpers.TINY_CHAT["lead_out_requests"]
+    assert d["steps"] > 0 and d["admitted"] == len(kept["records"]) + lead
+    assert sum(d["phase_s"][p] for p in probe.LEAF) == pytest.approx(d["phase_s"]["step"], rel=0.1)
+    assert d["phase_n"]["dispatch"] == d["phase_n"]["prefill"] + d["phase_n"]["decode"]
+    # every result's queue_s arrives: the window's requests, lead-in and lead-out
+    assert len(kept["queue_s"]) == len(kept["records"]) + lead
+    assert all(q >= 0 for q in kept["queue_s"])
+    assert any("stats" in p for p in kept["polled"])
+    said = []
+    probe.report(kept, say=lambda *a: said.append(" ".join(map(str, a))))
+    text = "\n".join(said)
+    assert "kv_gather" in text and "lane_fill" in text and "slowest_step" in text

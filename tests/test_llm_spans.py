@@ -1,0 +1,415 @@
+"""The serving engine's step on the profiler's clock: the ``llm.*`` spans as a
+real ``jax.profiler`` session records them and as the benchmark's reducer
+labels idle gaps with them, the counters beside them against what the shapes
+give, queue time per request, the slowest step's own record, what the spans
+cost outside a session, and the stable device names (kernels, scopes)."""
+
+import contextlib
+import dataclasses
+import glob
+import os
+import sys
+import time
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import trace_reduce
+from ray_tpu._private import internal_metrics
+from ray_tpu.models import gpt
+from ray_tpu.models.training import (
+    abstract_state,
+    default_optimizer,
+    make_train_step,
+)
+from ray_tpu.serve import batching, llm
+
+NANO = gpt.gpt_nano()
+# wide enough that a device call outweighs the python between the phases
+WIDE = dataclasses.replace(
+    NANO, num_layers=4, embed_dim=256, num_heads=4, head_dim=64, mlp_dim=1024,
+    vocab_size=2048,
+)
+ENGINE = dict(
+    num_blocks=64, block_size=16, prefill_chunk=32, prefill_lanes=2,
+    lane_buckets=(1, 2, 4), prefill_token_buckets=(16, 32),
+    cache_buckets=(64, 128), prefix_caching=False, deployment="spans",
+)
+CALL_PHASES = tuple(p for p in llm.LEAF_PHASES if p != "admit")     # of one device call, in order
+
+# Three requests of 20, 40 and 9 prompt tokens, 3 new tokens each, through
+# ENGINE (two prefill lanes, chunks of 32): the device calls as (lanes, lane
+# bucket, tokens fed, cache bucket, tokens resident in the lanes' caches).
+#   step 1: prefill A+B (chunks 20, 32); decode A
+#   step 2: prefill B+C (chunks 8, 9);   decode A, B, C (A finishes)
+#   step 3:                              decode B, C (both finish)
+LENGTHS, NEW = (20, 40, 9), 3
+CALLS = [
+    (2, 2, 32, 64, 0), (1, 1, 1, 64, 20),
+    (2, 2, 16, 64, 32), (3, 4, 1, 64, 21 + 40 + 9),
+    (2, 2, 1, 64, 41 + 10),
+]
+
+
+def _requests(lengths=LENGTHS, new=NEW, vocab=NANO.vocab_size):
+    return [
+        batching._Sequence({
+            "prompt": [(7 * i + j) % vocab for j in range(n)],
+            "max_new_tokens": new,
+        })
+        for i, n in enumerate(lengths)
+    ]
+
+
+def _drive(eng, seqs):
+    """Step until every sequence is done; returns the number of steps."""
+    steps = 0
+    while not all(s.done for s in seqs):
+        eng.step([s for s in seqs if not s.done])
+        steps += 1
+        assert steps < 200
+    return steps
+
+
+def _delta(after, before, key):
+    if isinstance(after[key], dict):
+        return {k: after[key][k] - before[key][k] for k in after[key]}
+    return after[key] - before[key]
+
+
+@pytest.fixture(scope="module")
+def engine():
+    """A warm ``gpt_nano`` engine: every shape of the known workload compiled."""
+    eng = llm.LLMEngine(NANO, **ENGINE)
+    _drive(eng, _requests())
+    return eng
+
+
+@pytest.fixture(scope="module")
+def xplane(engine, tmp_path_factory):
+    """The known workload under a real profiler session, while a batcher
+    waits for work on a thread of its own; the path of the trace."""
+    idle = batching._ContinuousBatcher(
+        lambda seqs: [s.finish(len(s.item)) for s in seqs], 4, 0.0, None, name="idle",
+    )
+    where = str(tmp_path_factory.mktemp("profile"))
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(where, profiler_options=options)
+    try:
+        assert idle.submit("ab") == 2       # the wait that follows starts in the session
+        _drive(engine, _requests())
+        assert idle.submit("abc") == 3      # ... and ends in it
+    finally:
+        jax.profiler.stop_trace()
+        idle.shutdown()
+    found = glob.glob(os.path.join(where, "plugins", "profile", "*", "*.xplane.pb"))
+    assert len(found) == 1
+    return found[0]
+
+
+@pytest.fixture(scope="module")
+def planes(xplane):
+    """The trace as the benchmark's loader reads it (host lines merged by
+    name: every python thread is ``python``)."""
+    return trace_reduce.load_xplane(xplane)
+
+
+def _spans(events, name):
+    return [(s, s + d) for n, s, d in events if n == name]
+
+
+def _engine_line(planes):
+    lines = [
+        events for events in planes[trace_reduce.HOST_PLANE].values()
+        if any(n.startswith("llm.") for n, _, _ in events)
+    ]
+    assert len(lines) == 1
+    return lines[0]
+
+
+# -- (a) the spans, nested as the table says, on one thread -----------------
+
+
+def test_every_phase_is_a_span_nested_in_its_parent(planes):
+    events = _engine_line(planes)
+    names = {n for n, _, _ in events if n.startswith("llm.")}
+    assert names == {"llm." + p for p in llm.PHASES}
+
+    def inside(child, parents):
+        return any(a <= child[0] and child[1] <= b for a, b in parents)
+
+    steps = _spans(events, "llm.step")
+    assert len(steps) == 3
+    calls = _spans(events, "llm.prefill") + _spans(events, "llm.decode")
+    assert len(calls) == len(CALLS)
+    for top in ("admit", "prefill", "decode"):
+        assert all(inside(s, steps) for s in _spans(events, "llm." + top)), top
+    assert len(_spans(events, "llm.admit")) == 3
+    for phase in CALL_PHASES:
+        spans = _spans(events, "llm." + phase)
+        assert len(spans) == len(CALLS), phase        # one of each per device call
+        assert all(inside(s, calls) for s in spans), phase
+    # within a device call the phases follow one another
+    order = [n for n, _, _ in sorted(events, key=lambda e: e[1]) if n[4:] in CALL_PHASES]
+    assert order == ["llm." + p for p in CALL_PHASES] * len(CALLS)
+
+
+def test_spans_are_on_the_thread_that_does_the_work(xplane):
+    """The engine's on the thread that steps it, the batcher's wait for work
+    on the batcher's."""
+    from jax.profiler import ProfileData
+
+    host = next(
+        p for p in ProfileData.from_file(xplane).planes if p.name == trace_reduce.HOST_PLANE
+    )
+    threads = [{e.name for e in line.events} for line in host.lines]
+    engine = [names for names in threads if any(n.startswith("llm.") for n in names)]
+    waiting = [names for names in threads if "serve.batch_idle" in names]
+    assert len(engine) == 1 and len(waiting) == 1 and not engine[0] & waiting[0]
+
+
+def test_the_batcher_does_not_import_jax_to_say_it_is_idle(monkeypatch):
+    monkeypatch.delitem(sys.modules, "jax")
+    assert isinstance(batching._idle_span(), contextlib.nullcontext)
+    assert "jax" not in sys.modules
+
+
+# -- (b) what the benchmark's reducer makes of them --------------------------
+
+
+def test_reducer_labels_gaps_with_the_phase_under_them(planes):
+    """A synthetic device that is busy except during the middle third of every
+    leaf phase: each gap's midpoint lies in one leaf, and the reducer must
+    name that leaf, not its ``prefill`` / ``decode`` / ``step`` or ``python``."""
+    events = [e for e in _engine_line(planes) if e[0].startswith("llm.")]
+    lo, hi = trace_reduce.annotation_window(
+        {trace_reduce.HOST_PLANE: {"engine": events}}, "llm.step"
+    )
+    holes, want = [], {}
+    for name, start, dur in events:
+        if name[4:] in llm.LEAF_PHASES:
+            holes.append((start + dur / 3, start + 2 * dur / 3))
+            label = "llm.step:" + name
+            want[label] = want.get(label, 0.0) + dur / 3 / 1e9
+    ops = [
+        (f"fusion.{i}", a, b - a)
+        for i, (a, b) in enumerate(trace_reduce.subtract([(lo, hi)], sorted(holes)))
+    ]
+    reduced = trace_reduce.reduce({
+        trace_reduce.HOST_PLANE: {"engine": events},
+        "/device:TPU:0": {trace_reduce.OPS_LINE: ops},
+    }, "llm.step")
+    gaps = dict(reduced["idle_gaps"])
+    assert set(gaps) == {"llm.step:llm." + p for p in llm.LEAF_PHASES}
+    assert gaps == pytest.approx(want, rel=1e-6)
+    assert reduced["window_s"] - reduced["busy_s"] == pytest.approx(sum(want.values()))
+
+
+# -- (c) the counters against what the shapes give ---------------------------
+
+
+def test_counters_equal_what_the_shapes_give(engine):
+    cfg, before = engine.cfg, engine.stats()
+    assert _drive(engine, _requests()) == 3
+    after = engine.stats()
+    kv_row = 2 * cfg.num_layers * cfg.num_heads * cfg.head_dim * 4   # K+V of one token, f32
+    want = {
+        "steps": 3, "admitted": 3,
+        "prefill_tokens": sum(LENGTHS),
+        "decode_tokens": len(LENGTHS) * (NEW - 1),
+        "lanes_used": sum(c[0] for c in CALLS),
+        "lane_slots": sum(c[1] for c in CALLS),
+        "cache_tokens": sum(c[4] for c in CALLS),
+        "cache_slots": sum(b * cap for _, b, _, cap, _ in CALLS),
+        "h2d_bytes": sum(
+            b * tc * 4 + b * 4 + b * cap * kv_row for _, b, tc, cap, _ in CALLS
+        ),
+        "d2h_bytes": sum(
+            b * tc * (cfg.vocab_size + cfg.embed_dim) * 4 + b * tc * kv_row
+            for _, b, tc, _, _ in CALLS
+        ),
+    }
+    assert {k: _delta(after, before, k) for k in want} == want
+    assert want["lanes_used"] < want["lane_slots"]
+    calls = _delta(after, before, "phase_n")
+    assert calls == {
+        "step": 3, "admit": 3, "prefill": 2, "decode": 3,
+        **dict.fromkeys(CALL_PHASES, len(CALLS)),
+    }
+    assert _delta(after, before, "queue_s") > 0
+
+
+def test_leaf_phases_add_up_to_the_step():
+    eng = llm.LLMEngine(WIDE, **ENGINE)
+    requests = lambda: _requests((60, 50, 40, 30), 8, WIDE.vocab_size)  # noqa: E731
+    _drive(eng, requests())
+    before = eng.stats()
+    _drive(eng, requests())
+    spent = _delta(eng.stats(), before, "phase_s")
+    assert all(v > 0 for v in spent.values())
+    leaves = sum(spent[p] for p in llm.LEAF_PHASES)
+    assert leaves == pytest.approx(spent["step"], rel=0.03)
+    assert spent["prefill"] + spent["decode"] + spent["admit"] <= spent["step"]
+
+
+# -- (d) queue time per request ----------------------------------------------
+
+
+def _observed(name):
+    series = internal_metrics.get(name)._snapshot()["series"]
+    return series.get((("deployment", ENGINE["deployment"]),), {"count": 0, "sum": 0.0})
+
+
+def test_queue_time_is_in_every_result_and_counts_the_wait_for_a_slot(engine):
+    first, held = _requests((20, 9))
+    before, seen0 = engine.stats(), _observed("ray_tpu_llm_queue_seconds")
+    engine.step([first])                    # ``held`` waits for a slot meanwhile
+    engine.step([first])
+    waited = _delta(engine.stats(), before, "phase_s")["step"]
+    _drive(engine, [first, held])
+    after = engine.stats()
+    results = [s._result for s in (first, held)]
+    for r in results:
+        assert 0 <= r["queue_s"] <= r["ttft_s"]
+    assert results[1]["queue_s"] >= waited > results[0]["queue_s"]
+    assert _delta(after, before, "admitted") == 2
+    assert _delta(after, before, "queue_s") == pytest.approx(
+        sum(r["queue_s"] for r in results)
+    )
+    # observed where the time to the first token is, once a request
+    seen = _observed("ray_tpu_llm_queue_seconds")
+    assert seen["count"] - seen0["count"] == 2
+    assert seen["sum"] - seen0["sum"] == pytest.approx(sum(r["queue_s"] for r in results))
+
+
+# -- (e) the slowest step's own record ---------------------------------------
+
+
+@pytest.mark.parametrize(
+    "phase, method, nth",
+    # the third device call and the fourth lane scattered open the second step
+    [("dispatch", "_extend", 2), ("kv_scatter", "_scatter", 3)],
+)
+def test_slowest_step_names_the_step_and_the_phase_that_stalled(
+    engine, monkeypatch, phase, method, nth
+):
+    engine.stats()                          # a read starts the record anew
+    real, calls, planted = getattr(engine, method), [], {}
+
+    def stalls_once(*args):
+        calls.append(None)
+        if len(calls) == nth + 1:
+            planted["at"] = time.time()
+            time.sleep(0.25)
+        return real(*args)
+
+    monkeypatch.setattr(engine, method, stalls_once)
+    _drive(engine, _requests())
+    slow = engine.stats()["slowest_step"]
+    assert 0.25 <= slow["phase_s"][phase] <= slow["wall_s"] == slow["phase_s"]["step"]
+    assert max(llm.LEAF_PHASES, key=lambda p: slow["phase_s"].get(p, 0.0)) == phase
+    assert planted["at"] <= slow["at"] <= planted["at"] + slow["wall_s"] + 0.05
+    assert slow["lanes"] == CALLS[2][0] + CALLS[3][0]       # the second step's calls
+
+
+def test_two_reads_bound_the_slowest_step(engine, monkeypatch):
+    """A stall shorter than an earlier one shows once the earlier was read."""
+    real, stall = engine._extend, [0.3]
+
+    def stalls(*args):
+        time.sleep(stall.pop() if stall else 0.0)
+        return real(*args)
+
+    monkeypatch.setattr(engine, "_extend", stalls)
+    engine.stats()
+    _drive(engine, _requests())
+    assert engine.stats()["slowest_step"]["wall_s"] >= 0.3
+    stall.append(0.1)
+    _drive(engine, _requests())
+    assert 0.1 <= engine.stats()["slowest_step"]["wall_s"] < 0.3
+    assert engine.stats()["slowest_step"] is None           # no step since
+
+
+# -- (f) what the spans cost outside a session -------------------------------
+
+
+def test_phases_cost_less_than_their_budget_outside_a_session(engine, monkeypatch):
+    """1,000 engine steps with ``_phase`` as it is and 1,000 with it swapped
+    for a bare no-op, turn about, the device call and the upload stubbed so
+    that a step is the engine's own python: the difference per step stays
+    under ``PHASE_BUDGET_NS`` for each phase the step opens."""
+    cfg = engine.cfg
+    made = {}
+
+    def instant(params, tokens, lengths, k_cache, v_cache):
+        b, tc = tokens.shape
+        if (b, tc) not in made:
+            made[b, tc] = (
+                np.zeros((b, tc, cfg.vocab_size), np.float32),
+                np.zeros((b, tc, cfg.embed_dim), np.float32),
+            ) + (np.zeros((cfg.num_layers, b, tc, cfg.num_heads, cfg.head_dim), np.float32),) * 2
+        return made[b, tc]
+
+    monkeypatch.setattr(engine, "_extend", instant)
+    monkeypatch.setattr(jnp, "asarray", lambda a: a)
+    as_it_is, nothing = engine._phase, contextlib.nullcontext()
+
+    def thousand_steps(phase):
+        engine._phase = phase
+        steps0, t0, seqs = engine.steps, time.perf_counter_ns(), []
+        while engine.steps - steps0 < 1000:
+            seqs = [s for s in seqs if not s.done] or _requests((9, 9), 60)
+            engine.step(seqs)
+        spent = time.perf_counter_ns() - t0
+        for s in seqs:
+            s._release()                    # the unfinished give their blocks back
+        return spent
+
+    try:
+        thousand_steps(as_it_is)
+        before = engine.stats()
+        runs = [
+            (thousand_steps(as_it_is), thousand_steps(lambda name: nothing))
+            for _ in range(5)
+        ]
+    finally:
+        del engine._phase
+    opened = sum(_delta(engine.stats(), before, "phase_n").values()) / 5000
+    assert 8 <= opened <= 30
+    with_phases, without = (min(r[i] for r in runs) for i in (0, 1))
+    assert (with_phases - without) / 1000 < opened * llm.PHASE_BUDGET_NS
+
+
+# -- (g) stable device names --------------------------------------------------
+
+
+def test_train_step_names_its_kernels_and_scopes():
+    cfg = dataclasses.replace(NANO, num_heads=1, head_dim=64, attn_use_pallas=True)
+    optimizer = default_optimizer()
+    tokens = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+    _, state = abstract_state(cfg, optimizer, tokens)
+    step = make_train_step(cfg, optimizer, donate=False)
+    text = jax.make_jaxpr(step)(nn.meta.unbox(state), tokens).pretty_print(name_stack=True)
+    for name in (
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+        "train.forward", "train.loss", "train.optimizer",
+    ):
+        assert name in text, name
+    # the backward pass runs under the forward's scope
+    assert "transpose(jvp(train.forward))" in text
+
+
+def test_extend_names_its_scopes():
+    params = jax.eval_shape(lambda: llm.make_params(NANO))
+    kv = jax.ShapeDtypeStruct((NANO.num_layers, 2, 64, NANO.num_heads, NANO.head_dim), jnp.float32)
+    text = gpt.make_extend_fn(NANO).lower(
+        params, jax.ShapeDtypeStruct((2, 8), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.int32), kv, kv,
+    ).as_text(debug_info=True)
+    for scope in ("extend.embed", "extend.attention", "extend.mlp", "extend.logits"):
+        assert f"{scope}/" in text, scope
