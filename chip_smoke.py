@@ -73,11 +73,13 @@ def chip_plan() -> Plan:
         batch=(4, 2048),
         steps=6,
         # one cache bucket and two lane buckets keep the compiled shapes to
-        # six; the engine round-trips the whole padded cache through the
-        # host every step (0.46 MB per lane-token at this width), so lanes
-        # and capacity stay small enough for the phase to take minutes
+        # six. The KV pool lives on the chip beside 12.1 GB of weights, and
+        # every call builds the padded pair there too (1.88 GB at 4 lanes x
+        # 1024): 160 blocks are 1.17 GB, 15.5 of the chip's 16.9 GB in all,
+        # where 320 would leave 0.2. The requests below never hold more than
+        # 73 blocks at once
         engine=dict(
-            num_blocks=320, block_size=16, prefill_chunk=128, prefill_lanes=2,
+            num_blocks=160, block_size=16, prefill_chunk=128, prefill_lanes=2,
             lane_buckets=(1, 4), prefill_token_buckets=(32, 128),
             cache_buckets=(1024,),
         ),

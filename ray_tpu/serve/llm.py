@@ -8,12 +8,18 @@ admission control, multiplexing) this module composes on an actual model
 serving setup from PAPERS.md):
 
 * :class:`KVBlockPool` — the KV cache is paged into fixed-size token
-  blocks in one host-side arena; sequences lease blocks on admission and
-  a :class:`KVLease` frees them **exactly once** on finish / cancel /
-  shed / step poison (the same accounting discipline the handle enforces
-  for concurrency slots). ``ray_tpu_llm_kv_blocks_in_use`` tracks the
-  pool; exhaustion sheds with :class:`~ray_tpu.serve.handle.
+  blocks in two arenas that live on the device; sequences lease blocks on
+  admission and a :class:`KVLease` frees them **exactly once** on finish /
+  cancel / shed / step poison (the same accounting discipline the handle
+  enforces for concurrency slots). ``ray_tpu_llm_kv_blocks_in_use`` tracks
+  the pool; exhaustion sheds with :class:`~ray_tpu.serve.handle.
   BackPressureError` *before* anything is written.
+* a device call never moves K/V through the host: the host sends a block
+  table, tokens and lengths (a few KB); one jitted program gathers the
+  padded pair ``extend`` takes from the arenas, ``extend`` runs, and one
+  donating program pages the new K/V back into the arenas and picks each
+  lane's last valid row of logits and hidden, which is all that comes
+  home. Every such program is compiled when the engine is built.
 * prefill/decode split — prefill runs as its own bucketed extend call
   (prompt chunks padded via :func:`~ray_tpu.serve.batching.
   bucket_pad_size`), decode as a tc=1 call; every engine iteration runs
@@ -40,6 +46,7 @@ serving setup from PAPERS.md):
 from __future__ import annotations
 
 import contextlib
+import functools
 import hashlib
 import math
 import queue as queue_mod
@@ -91,38 +98,186 @@ def make_params(cfg=None, seed: int = 0):
 # ---------------------------------------------------------------------------
 
 
-class KVBlockPool:
-    """Fixed-size token blocks of K/V storage in one refcounted host arena.
+@functools.lru_cache(maxsize=None)
+def _paging_programs():
+    """The three jitted programs that touch a pool's arenas ``[layers,
+    num_blocks, block_size, heads, head_dim]``; jax is imported here because
+    processes that must stay off it load this module too. They are shaped by
+    their arguments alone, so every pool of a process shares them."""
+    import types
 
-    Layout: ``k_data``/``v_data`` are ``[num_blocks, layers, block_size,
-    heads, head_dim]``; a sequence owns an ordered list of block ids whose
-    concatenation is its cache. Blocks are refcounted so the prefix cache
-    can share full prompt blocks across sequences; a block returns to the
-    free list when its last reference drops."""
+    import jax
+    import jax.numpy as jnp
+
+    # Both loops move one slab at a time with a dynamic slice and an in-place
+    # dynamic update. Written as ``arena[:, table]`` / ``.at[:, slots].set``
+    # the TPU compiler first copies a whole arena into a temporary, on every
+    # call: 1.17 GB at GPT-J's serve sizes, where 1 GB is free beside the
+    # weights (``tests/test_chip_compile.py`` holds the programs to this).
+
+    @jax.jit
+    @jax.named_scope("paging.gather")
+    def gather(k_data, v_data, table):
+        layers, _, block, heads, hd = k_data.shape
+        b, n = table.shape
+        flat = table.reshape(-1)
+        # every block of the pair is written below; one buffer each, because
+        # the compiler copies a value that starts both loop carries
+        empty = functools.partial(
+            jax.lax.empty, (layers, b * n, block, heads, hd), k_data.dtype)
+
+        def copy_block(i, pairs):
+            return tuple(
+                jax.lax.dynamic_update_slice_in_dim(
+                    out, jax.lax.dynamic_slice_in_dim(arena, flat[i], 1, axis=1),
+                    i, axis=1)
+                for out, arena in zip(pairs, (k_data, v_data)))
+
+        pairs = jax.lax.fori_loop(0, b * n, copy_block, (empty(), empty()))
+        return tuple(p.reshape(layers, b, n * block, heads, hd) for p in pairs)
+
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    @jax.named_scope("paging.page_back")
+    def page_back(k_data, v_data, k_new, v_new, rows, slots, count, outputs, last):
+        layers, blocks, block, heads, hd = k_data.shape
+        b, tc = k_new.shape[1:3]
+        news = tuple(x.reshape(layers, b * tc, heads, hd) for x in (k_new, v_new))
+
+        def write_token(i, arenas):
+            return tuple(
+                jax.lax.dynamic_update_slice_in_dim(
+                    tokens, jax.lax.dynamic_slice_in_dim(new, rows[i], 1, axis=1),
+                    slots[i], axis=1)
+                for tokens, new in zip(arenas, news))
+
+        # ``count`` is traced: a loop the compiler cannot unroll, whatever the
+        # shapes (unrolled at one token it re-lays both arenas out and back)
+        arenas = jax.lax.fori_loop(0, count, write_token, tuple(
+            a.reshape(layers, blocks * block, heads, hd) for a in (k_data, v_data)))
+        picked = tuple(
+            jnp.stack([
+                jax.lax.dynamic_index_in_dim(o[i], last[i], 0, keepdims=False)
+                for i in range(b)
+            ]) for o in outputs)
+        return tuple(a.reshape(k_data.shape) for a in arenas) + (picked,)
+
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    @jax.named_scope("paging.clone")
+    def clone(k_data, v_data, src, dst):
+        def copy(arena):
+            one = jax.lax.dynamic_slice_in_dim(arena, src, 1, axis=1)
+            return jax.lax.dynamic_update_slice_in_dim(arena, one, dst, axis=1)
+
+        return copy(k_data), copy(v_data)
+
+    return types.SimpleNamespace(gather=gather, page_back=page_back, clone=clone)
+
+
+class KVBlockPool:
+    """Fixed-size token blocks of K/V storage in two refcounted arenas that
+    live on the device.
+
+    Layout: ``k_data``/``v_data`` are device arrays ``[layers, num_blocks,
+    block_size, heads, head_dim]`` in the model's dtype; a sequence owns an
+    ordered list of block ids whose concatenation is its cache, so the
+    blocks a table names, side by side, are the padded pair ``extend``
+    takes. Blocks are refcounted so the prefix cache can share full prompt
+    blocks across sequences; a block returns to the free list when its last
+    reference drops. Allocation, refcounts and leases are host bookkeeping;
+    the arenas are only ever touched by the three programs of
+    :func:`_paging_programs` (``page_back`` and ``clone`` donate them), each
+    compiled by :meth:`warm` before a request is served."""
 
     def __init__(self, cfg, *, num_blocks: int = 128, block_size: int = 16,
                  deployment: str = "llm"):
+        import jax
+        import jax.numpy as jnp
+
         self.cfg = cfg
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.deployment = deployment
-        try:
-            dt = np.dtype(np.float32 if cfg.dtype is None else cfg.dtype)
-        except TypeError:
-            import jax.numpy as jnp
-
-            dt = np.dtype(jnp.zeros((), cfg.dtype).dtype.name)
+        self.dtype = jnp.dtype(jnp.float32 if cfg.dtype is None else cfg.dtype)
         shape = (
-            self.num_blocks, cfg.num_layers, self.block_size,
+            cfg.num_layers, self.num_blocks, self.block_size,
             cfg.num_heads, cfg.head_dim,
         )
-        self.k_data = np.zeros(shape, dt)
-        self.v_data = np.zeros(shape, dt)
+        try:
+            self.k_data = jnp.zeros(shape, self.dtype)
+            self.v_data = jnp.zeros(shape, self.dtype)
+            jax.block_until_ready((self.k_data, self.v_data))
+        except Exception as e:  # noqa: BLE001 — the runtime's out-of-memory
+            each = math.prod(shape) * self.dtype.itemsize
+            raise MemoryError(
+                f"the KV pool does not fit on the device: {self.num_blocks} "
+                f"blocks of {self.block_size} tokens x {cfg.num_layers} layers "
+                f"x {cfg.num_heads} heads x {cfg.head_dim} in {self.dtype} are "
+                f"{each} bytes an arena, {2 * each} for K and V, beside "
+                f"{accelerator.device_report()}: {e!r}"
+            ) from e
         self._free: List[int] = list(range(self.num_blocks))
         self._ref: Dict[int, int] = {}
         self._lock = threading.RLock()
         self._evict_cb: Optional[Callable[[int], None]] = None
         self.freed_total = 0
+
+    # -- the arenas: device programs only ----------------------------------
+
+    def gather(self, table):
+        """The padded pair ``[layers, b, n * block_size, heads, head_dim]`` of
+        the lanes whose block ids are the rows of ``table`` ``[b, n]`` (int32,
+        every entry a valid block), built on the device."""
+        return _paging_programs().gather(self.k_data, self.v_data, table)
+
+    def page_back(self, k_new, v_new, rows, slots, count, outputs, last):
+        """Write token ``rows[i]`` (an index into lanes x tokens) of ``k_new``
+        / ``v_new`` ``[layers, b, tc, heads, head_dim]`` into the arenas at
+        token slot ``slots[i]`` (block x block_size + offset) for the first
+        ``count`` entries of ``rows`` / ``slots`` ``[b * tc]`` and, in the
+        same program, pick row ``last[i]`` of lane ``i`` from each of
+        ``outputs`` ``[b, tc, ...]``. The arenas are donated: nothing is
+        copied but the new rows."""
+        self.k_data, self.v_data, picked = _paging_programs().page_back(
+            self.k_data, self.v_data, k_new, v_new, rows, slots, count,
+            outputs, last)
+        return picked
+
+    def clone_block(self, src: int, dst: int) -> None:
+        """Copy block ``src`` onto block ``dst``, on the device."""
+        self.k_data, self.v_data = _paging_programs().clone(
+            self.k_data, self.v_data, np.int32(src), np.int32(dst))
+
+    def read_block(self, b: int):
+        """Block ``b`` on the host: K and V ``[layers, block_size, heads,
+        head_dim]``. For tests and debugging, not for the step path (eager
+        indexing compiles)."""
+        return np.asarray(self.k_data[:, b]), np.asarray(self.v_data[:, b])
+
+    def warm(self, extend_shapes: Dict[Any, Any], cache_buckets) -> None:
+        """Compile every paging program the engine's buckets allow, on zeros
+        made on the device: the gather per (lanes, cache bucket), the
+        page-back per (lanes, tokens) of ``extend_shapes`` (that extend
+        call's output shapes), the clone. The page-back writes no token
+        here, so the arenas keep their contents."""
+        import jax
+        import jax.numpy as jnp
+
+        for b in sorted({b for b, _ in extend_shapes}):
+            for cap in cache_buckets:
+                jax.block_until_ready(self.gather(
+                    np.zeros((b, cap // self.block_size), np.int32)))
+        for (b, tc), (logits, hidden, k_new, _) in extend_shapes.items():
+            new = jnp.zeros(k_new.shape, k_new.dtype)
+            outputs = tuple(jnp.zeros(o.shape, o.dtype) for o in (logits, hidden))
+            rows, count, last = jax.device_put((
+                np.zeros((b * tc,), np.int32), np.int32(0), np.zeros((b,), np.int32),
+            ))
+            jax.block_until_ready(
+                self.page_back(new, new, rows, rows, count, outputs, last))
+        self.clone_block(0, 0)
+        jax.block_until_ready((self.k_data, self.v_data))
+
+    # -- host bookkeeping ---------------------------------------------------
 
     def set_evict_cb(self, cb: Callable[[int], None]) -> None:
         """Hook called (under the pool lock) with the shortfall when an
@@ -184,8 +339,7 @@ class KVBlockPool:
             if self._ref.get(b, 0) <= 1:
                 return b
             new = self.allocate(1)[0]
-            self.k_data[new] = self.k_data[b]
-            self.v_data[new] = self.v_data[b]
+            self.clone_block(b, new)
             self._decref_locked(b)
             blocks[idx] = new
             self._gauge_locked()
@@ -373,7 +527,7 @@ class _SeqState:
 #: ``prefill`` and ``decode``; those two hold the six phases of a device call.
 #: The leaves partition a step: what is in none of them is a missing phase.
 LEAF_PHASES = (
-    "admit", "kv_gather", "upload", "dispatch", "fetch", "kv_scatter", "sample",
+    "admit", "kv_gather", "upload", "dispatch", "kv_scatter", "fetch", "sample",
 )
 PHASES = ("step", "prefill", "decode") + LEAF_PHASES
 #: what one ``_phase`` may cost outside a profiler session, where its span is
@@ -415,16 +569,20 @@ class LLMEngine:
         self.cache_buckets = sorted(cache_buckets)
         self.default_max_new_tokens = int(default_max_new_tokens)
         self.max_context = min(self.cfg.max_seq_len, self.cache_buckets[-1])
+        if any(cap % self.block_size for cap in self.cache_buckets):
+            raise ValueError(
+                f"cache buckets {self.cache_buckets} must be whole blocks of "
+                f"{self.block_size} tokens: the padded pair is a block table")
         self.pool = KVBlockPool(
             self.cfg, num_blocks=num_blocks, block_size=block_size,
             deployment=deployment,
         )
+        self._warm_paging()
         self.prefix: Optional[PrefixCache] = (
             PrefixCache(self.pool, deployment) if prefix_caching else None
         )
         loader = adapter_loader or _fetch_lora
         self._mux = _MultiplexWrapper(loader, None, int(max_adapters))
-        self._np_dtype = self.pool.k_data.dtype
         #: fault injection: stretch every engine step (chaos / cancellation
         #: tests need the decode window to outlive a few control RPCs)
         self.step_delay_s = float(step_delay_s)
@@ -435,7 +593,7 @@ class LLMEngine:
         self.queue_s = 0.0              # sum of enqueue -> admitted
         self.prefill_tokens = 0
         self.decode_tokens = 0
-        self.cache_tokens = 0           # live tokens copied into padded caches
+        self.cache_tokens = 0           # live tokens gathered into padded caches
         self.cache_slots = 0            # lanes x cache bucket of those caches
         self.h2d_bytes = 0
         self.d2h_bytes = 0
@@ -448,6 +606,30 @@ class LLMEngine:
         #: long it took, its lanes and its own seconds per phase. Time in
         #: ``fetch`` is the device or the runtime; anywhere else, the host.
         self.slowest_step: Optional[Dict[str, Any]] = None
+
+    def _warm_paging(self) -> None:
+        """Compile the pool's programs for every shape the buckets allow, so
+        that nothing around ``extend`` compiles once requests arrive.
+        ``extend`` is only traced here, for the shapes it hands to the
+        page-back: the first call of each of its own shapes still compiles."""
+        import jax
+
+        cfg = self.cfg
+
+        def extend_outputs(b, tc):
+            kv = jax.ShapeDtypeStruct(
+                (cfg.num_layers, b, self.cache_buckets[0], cfg.num_heads,
+                 cfg.head_dim), self.pool.dtype)
+            return jax.eval_shape(
+                self._extend, self._params,
+                jax.ShapeDtypeStruct((b, tc), np.int32),
+                jax.ShapeDtypeStruct((b,), np.int32), kv, kv)
+
+        self.pool.warm({
+            (b, tc): extend_outputs(b, tc)
+            for b in self.lane_buckets
+            for tc in [1] + self.prefill_token_buckets
+        }, self.cache_buckets)
 
     # -- public stats ------------------------------------------------------
 
@@ -627,14 +809,12 @@ class LLMEngine:
             ]
             tc = batching.bucket_pad_size(
                 max(chunks), self.prefill_token_buckets)
-            logits, hidden, k_new, v_new = self._run_extend(
+            logits, hidden = self._run_extend(
                 states, [st.prompt[st.pos:st.pos + c]
                          for st, c in zip(states, chunks)], tc)
-            with self._phase("kv_scatter"):
-                for i, (st, c) in enumerate(zip(states, chunks)):
-                    self._scatter(st, k_new[:, i, :c], v_new[:, i, :c])
-                    st.pos += c
-                    st.length += c
+            for st, c in zip(states, chunks):
+                st.pos += c
+                st.length += c
             fed = sum(chunks)
             self.prefill_tokens += fed
             internal_metrics.inc(
@@ -642,14 +822,14 @@ class LLMEngine:
                 {"deployment": self.deployment},
             )
             with self._phase("sample"):
-                for i, (s, st, c) in enumerate(zip(lanes, states, chunks)):
+                for i, (s, st) in enumerate(zip(lanes, states)):
                     if st.pos < len(st.prompt):
                         continue
                     if self.prefix is not None:
                         # cache every full prompt block (first writer wins)
                         self.prefix.insert(
                             st.hashes, st.blocks[:len(st.hashes)])
-                    self._emit(s, st, logits[i, c - 1], hidden[i, c - 1])
+                    self._emit(s, st, logits[i], hidden[i])
 
     def _decode_step(self, seqs) -> None:
         decoding = [
@@ -682,22 +862,25 @@ class LLMEngine:
         if not states:
             return
         sts = [st for _, st in states]
-        logits, hidden, k_new, v_new = self._run_extend(
+        logits, hidden = self._run_extend(
             sts, [[st.last_token] for st in sts], 1)
-        with self._phase("kv_scatter"):
-            for i, st in enumerate(sts):
-                self._scatter(st, k_new[:, i, :1], v_new[:, i, :1])
-                st.length += 1
+        for st in sts:
+            st.length += 1
         self.decode_tokens += len(sts)
         with self._phase("sample"):
             for i, (s, st) in enumerate(states):
-                self._emit(s, st, logits[i, 0], hidden[i, 0])
+                self._emit(s, st, logits[i], hidden[i])
 
     # -- device call + paging ---------------------------------------------
 
     def _run_extend(self, states, token_chunks, tc: int):
-        import jax.numpy as jnp
+        """One device call: feed each lane its chunk over its paged cache,
+        page the new K/V back, and return the lanes' last valid rows of
+        logits ``[b, vocab]`` and hidden ``[b, embed]`` on the host. The pool,
+        the padded pair and ``extend``'s outputs never leave the device."""
+        import jax
 
+        bs = self.block_size
         b = batching.bucket_pad_size(len(states), self.lane_buckets)
         t_max = max(
             st.length + len(ch) for st, ch in zip(states, token_chunks))
@@ -705,57 +888,49 @@ class LLMEngine:
         with self._phase("kv_gather"):
             tokens = np.zeros((b, tc), np.int32)
             lengths = np.zeros((b,), np.int32)
+            # padding names block 0: whatever it holds lies past a frontier
+            table = np.zeros((b, t_cap // bs), np.int32)
+            # page-back: token rows[i] of the call goes to arena slot slots[i]
+            rows = np.zeros((b * tc,), np.int32)
+            slots = np.zeros((b * tc,), np.int32)
+            last = np.zeros((b,), np.int32)
+            fed = 0
             for i, (st, ch) in enumerate(zip(states, token_chunks)):
-                tokens[i, :len(ch)] = ch
+                n = len(ch)
+                tokens[i, :n] = ch
                 lengths[i] = st.length
-            host = (tokens, lengths) + self._gather(states, b, t_cap)
+                blocks = np.asarray(
+                    st.blocks[:math.ceil((st.length + n) / bs)], np.int32)
+                table[i, :len(blocks)] = blocks
+                pos = st.length + np.arange(n)
+                rows[fed:fed + n] = i * tc + np.arange(n)
+                slots[fed:fed + n] = blocks[pos // bs] * bs + pos % bs
+                last[i] = n - 1
+                fed += n
+            # slots past a lane's frontier hold what the pool holds there:
+            # zeros or finite model output, which extend's mask weighs 0
+            k_cache, v_cache = self.pool.gather(table)
+            self.cache_tokens += sum(st.length for st in states)
+            self.cache_slots += b * t_cap
         with self._phase("upload"):
-            self.h2d_bytes += sum(a.nbytes for a in host)
-            args = [jnp.asarray(a) for a in host]
-            del host        # freeing the padded pair is part of this phase
+            small = (tokens, lengths, rows, slots, np.int32(fed), last)
+            self.h2d_bytes += table.nbytes + sum(a.nbytes for a in small)
+            tokens, lengths, rows, slots, count, last = jax.device_put(small)
         with self._phase("dispatch"):
-            out = self._extend(self._params, *args)
-            del args
+            logits, hidden, k_new, v_new = self._extend(
+                self._params, tokens, lengths, k_cache, v_cache)
+            del k_cache, v_cache    # the pair is freed when extend has run
             self.lanes_used += len(states)
             self.lane_slots += b
+        with self._phase("kv_scatter"):
+            picked = self.pool.page_back(
+                k_new, v_new, rows, slots, count, (logits, hidden), last)
+            del logits, hidden, k_new, v_new
         with self._phase("fetch"):
-            # waits for the upload and the device, then copies back
-            out = tuple(np.asarray(o) for o in out)
-            self.d2h_bytes += sum(o.nbytes for o in out)
-        return out
-
-    def _gather(self, states, b: int, t_cap: int):
-        """The padded K/V pair ``[layers, b, t_cap, heads, head_dim]`` of the
-        lanes' caches, built on the host from their blocks."""
-        cfg, bs = self.cfg, self.block_size
-        k = np.zeros(
-            (cfg.num_layers, b, t_cap, cfg.num_heads, cfg.head_dim),
-            self._np_dtype,
-        )
-        v = np.zeros_like(k)
-        for i, st in enumerate(states):
-            for j in range(math.ceil(st.length / bs)):
-                lo = j * bs
-                hi = min(st.length, lo + bs)
-                blk = st.blocks[j]
-                k[:, i, lo:hi] = self.pool.k_data[blk][:, :hi - lo]
-                v[:, i, lo:hi] = self.pool.v_data[blk][:, :hi - lo]
-        self.cache_tokens += sum(st.length for st in states)
-        self.cache_slots += b * t_cap
-        return k, v
-
-    def _scatter(self, st: _SeqState, k_new, v_new) -> None:
-        bs = self.block_size
-        n = k_new.shape[1]
-        j = 0
-        while j < n:
-            pos = st.length + j
-            blk_idx, off = pos // bs, pos % bs
-            run = min(bs - off, n - j)
-            blk = st.blocks[blk_idx]
-            self.pool.k_data[blk][:, off:off + run] = k_new[:, j:j + run]
-            self.pool.v_data[blk][:, off:off + run] = v_new[:, j:j + run]
-            j += run
+            # waits for the device, then copies the sampled rows home
+            picked = tuple(np.asarray(r) for r in picked)
+            self.d2h_bytes += sum(r.nbytes for r in picked)
+        return picked
 
     # -- sampling / completion --------------------------------------------
 

@@ -19,7 +19,7 @@ import sys
 import threading
 import time
 
-LEAF = ("admit", "kv_gather", "upload", "dispatch", "fetch", "kv_scatter", "sample")
+LEAF = ("admit", "kv_gather", "upload", "dispatch", "kv_scatter", "fetch", "sample")
 NOT_COUNTERS = ("device", "compile_cache", "adapters_resident")
 
 
@@ -117,6 +117,14 @@ def delta(after, before):
     return out
 
 
+def _size(n):
+    """Bytes in the unit that keeps three figures: the pool's traffic fell from GB to KB."""
+    for unit, scale in (("GB", 1e9), ("MB", 1e6), ("KB", 1e3)):
+        if n >= scale:
+            return f"{n / scale:.3g} {unit}"
+    return f"{n:.0f} B"
+
+
 def say_split(d, label, say=print):
     """The split of the steps between two ``kv_stats`` reads, from their delta."""
     if "phase_s" not in d:
@@ -133,18 +141,15 @@ def say_split(d, label, say=print):
     for k in ("prefill", "decode") + LEAF:
         say(f"     {k:10s} {ph[k]:8.3f} s {100 * ph[k] / step:6.2f} %  n={n[k]:<5d}"
             f"{1e3 * ph[k] / max(n[k], 1):8.2f} ms each")
-    say(f"     h2d {d['h2d_bytes'] / 1e9:.3f} GB: {d['h2d_bytes'] / calls / 1e6:.1f} MB a call, "
-        f"{d['h2d_bytes'] / steps / 1e6:.1f} a step, {d['h2d_bytes'] / decoded / 1e6:.1f} a decode "
-        f"token; d2h {d['d2h_bytes'] / steps / 1e6:.1f} MB a step (from array sizes)")
+    say(f"     h2d {_size(d['h2d_bytes'])}: {_size(d['h2d_bytes'] / calls)} a call, "
+        f"{_size(d['h2d_bytes'] / steps)} a step, {_size(d['h2d_bytes'] / decoded)} a decode "
+        f"token; d2h {_size(d['d2h_bytes'] / steps)} a step (from array sizes)")
     say(f"     lane_fill {d['lanes_used']}/{d['lane_slots']} = "
         f"{d['lanes_used'] / max(d['lane_slots'], 1):.3f}; cache_fill {d['cache_tokens']}/"
         f"{d['cache_slots']} = {d['cache_tokens'] / max(d['cache_slots'], 1):.3f}")
     say(f"     admitted {d['admitted']}, queue_s mean {d['queue_s'] / max(d['admitted'], 1):.4f}; "
         f"prefill {d['prefill_tokens']} tokens in {n['prefill']} calls; decode "
         f"{d['decode_tokens']} tokens in {n['decode']} calls")
-    if ph["upload"] > 0 and ph["fetch"] > 0:
-        say(f"     upload {d['h2d_bytes'] / ph['upload'] / 1e9:.2f} GB/s; fetch (with the wait for "
-            f"the device) {d['d2h_bytes'] / ph['fetch'] / 1e9:.3f} GB/s")
 
 
 def report(kept, say=print):

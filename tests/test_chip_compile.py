@@ -30,6 +30,7 @@ from ray_tpu.models.training import (
 from ray_tpu.ops.attention import dot_product_attention
 from ray_tpu.parallel import sharding as shd
 from ray_tpu.parallel.mesh import MeshSpec
+from ray_tpu.serve import llm
 
 HBM_BYTES = 16909336064  # bytes_limit of one v5e chip, as its memory_stats() reports
 
@@ -111,16 +112,8 @@ def test_gptj_width_train_step_compiles(v5e, spec, n_devices):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
-@pytest.mark.parametrize("lanes,tc", [(4, 1), (1, 128)], ids=["decode", "prefill"])
-def test_gptj_full_depth_extend_compiles(v5e, lanes, tc):
-    """The server's step at full depth 28 in bf16, over a 1024-token cache:
-    the weights alone are 11.3 GiB of the chip's 15.75."""
-    cfg = _gptj(28)
-    one = SingleDeviceSharding(v5e[0])
-
-    def shaped(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-
+def _extend_at(cfg, shaped, lanes, tc, cap):
+    """``extend`` compiled for ``lanes`` x ``tc`` tokens over a ``cap`` cache."""
     params = jax.tree.map(
         lambda x: shaped(x.shape, x.dtype),
         jax.eval_shape(
@@ -129,8 +122,76 @@ def test_gptj_full_depth_extend_compiles(v5e, lanes, tc):
             )
         ),
     )
-    cache = shaped((cfg.num_layers, lanes, 1024, cfg.num_heads, cfg.head_dim), cfg.dtype)
-    compiled = gpt.make_extend_fn(cfg).lower(
+    cache = shaped((cfg.num_layers, lanes, cap, cfg.num_heads, cfg.head_dim), cfg.dtype)
+    return gpt.make_extend_fn(cfg).lower(
         params, shaped((lanes, tc), jnp.int32), shaped((lanes,), jnp.int32), cache, cache
     ).compile()
-    assert _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.fixture
+def shaped(v5e):
+    one = SingleDeviceSharding(v5e[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+
+@pytest.mark.parametrize("lanes,tc", [(4, 1), (1, 128)], ids=["decode", "prefill"])
+def test_gptj_full_depth_extend_compiles(shaped, lanes, tc):
+    """The server's step at full depth 28 in bf16, over a 1024-token cache:
+    the weights alone are 11.3 GiB of the chip's 15.75."""
+    assert _device_bytes(_extend_at(_gptj(28), shaped, lanes, tc, 1024)) < HBM_BYTES
+
+
+def test_gptj_serve_paging_programs_compile_beside_the_weights(shaped):
+    """The serve configuration's pool on the chip (320 blocks of 16 tokens, 28
+    layers, bf16: 1.17 GB an arena) and the programs around ``extend``, for
+    every shape the configuration's buckets allow: no program holds a
+    temporary the size of an arena (the compiler's own gather and scatter
+    do), the page-back and the clone alias both arenas (the donation took; an
+    update that did not would hold a second copy), and at the largest shape (4
+    lanes, 128 tokens, 512 cache) weights + pool + pair + outputs stay under
+    the chip's limit in each program."""
+    cfg, blocks, block = _gptj(28), 320, 16
+    lanes, tokens, caches = (1, 4), (1, 32, 128), (256, 512)
+    arena = shaped((cfg.num_layers, blocks, block, cfg.num_heads, cfg.head_dim), cfg.dtype)
+    arena_bytes = 2 * cfg.num_layers * blocks * block * cfg.num_heads * cfg.head_dim
+    programs = llm._paging_programs()
+
+    def pair_bytes(b, cap):
+        return 2 * 2 * cfg.num_layers * b * cap * cfg.num_heads * cfg.head_dim
+
+    def gather(b, cap):
+        return programs.gather.lower(
+            arena, arena, shaped((b, cap // block), jnp.int32)).compile()
+
+    def page_back(b, tc):
+        new = shaped((cfg.num_layers, b, tc, cfg.num_heads, cfg.head_dim), cfg.dtype)
+        index = shaped((b * tc,), jnp.int32)
+        return programs.page_back.lower(
+            arena, arena, new, new, index, index, shaped((), jnp.int32),
+            (shaped((b, tc, cfg.vocab_size), jnp.float32),
+             shaped((b, tc, cfg.embed_dim), jnp.float32)),
+            shaped((b,), jnp.int32),
+        ).compile()
+
+    for b in lanes:
+        for cap in caches:
+            memory = gather(b, cap).memory_analysis()
+            assert 0 <= memory.output_size_in_bytes - pair_bytes(b, cap) < 4096
+            assert memory.temp_size_in_bytes < 2**20, (b, cap)
+        for tc in tokens:
+            memory = page_back(b, tc).memory_analysis()
+            assert memory.alias_size_in_bytes == 2 * arena_bytes, (b, tc)
+            assert memory.temp_size_in_bytes < 2**20, (b, tc)
+    clone = programs.clone.lower(
+        arena, arena, shaped((), jnp.int32), shaped((), jnp.int32)).compile()
+    assert clone.memory_analysis().alias_size_in_bytes == 2 * arena_bytes
+    assert clone.memory_analysis().temp_size_in_bytes < 2**20
+
+    b, tc, cap = lanes[-1], tokens[-1], caches[-1]
+    extend = _extend_at(cfg, shaped, b, tc, cap)
+    weights_bytes = extend.memory_analysis().argument_size_in_bytes - pair_bytes(b, cap)
+    assert 12.0e9 < weights_bytes < 12.2e9
+    assert weights_bytes + _device_bytes(gather(b, cap)) < HBM_BYTES
+    assert _device_bytes(extend) + 2 * arena_bytes < HBM_BYTES
+    # the pair may still be alive (extend has been dispatched, not awaited)
+    assert weights_bytes + pair_bytes(b, cap) + _device_bytes(page_back(b, tc)) < HBM_BYTES

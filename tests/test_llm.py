@@ -18,6 +18,7 @@ from ray_tpu.serve import batching
 from ray_tpu.serve.llm import (
     KVBlockPool,
     KVLease,
+    LLMEngine,
     LLMServer,
     NoKVBlocksError,
     PrefixCache,
@@ -101,20 +102,65 @@ def test_kv_lease_releases_exactly_once():
     assert pool.in_use() == 0
 
 
+def _fill_block(pool, block, k_value, v_value):
+    """Write constants into one block of the device arenas."""
+    pool.k_data = pool.k_data.at[:, block].set(k_value)
+    pool.v_data = pool.v_data.at[:, block].set(v_value)
+
+
 def test_kv_pool_copy_on_write():
+    import jax
+
     pool = KVBlockPool(CFG, num_blocks=8, block_size=4)
+    assert isinstance(pool.k_data, jax.Array) and isinstance(pool.v_data, jax.Array)
     (shared,) = pool.allocate(1)
-    pool.k_data[shared][:] = 7.0
+    _fill_block(pool, shared, 7.0, -7.0)
     pool.incref([shared])             # second holder (e.g. prefix cache)
     blocks = [shared]
     new = pool.ensure_private(blocks, 0)
     assert new != shared and blocks[0] == new
-    assert np.all(pool.k_data[new] == 7.0)       # contents cloned
+    k, v = pool.read_block(new)
+    assert k.shape == (CFG.num_layers, 4, CFG.num_heads, CFG.head_dim)
+    assert np.all(k == 7.0) and np.all(v == -7.0)    # contents cloned
     assert pool.refcount(shared) == 1            # our ref moved off it
-    pool.k_data[new][:] = 9.0
-    assert np.all(pool.k_data[shared] == 7.0)    # original untouched
+    _fill_block(pool, new, 9.0, -9.0)
+    k, v = pool.read_block(shared)
+    assert np.all(k == 7.0) and np.all(v == -7.0)    # original untouched
     # unshared block: no clone
     assert pool.ensure_private(blocks, 0) == new
+
+
+def test_clone_block_copies_one_block_on_the_device():
+    """The clone moves exactly one block of both arenas and nothing else,
+    without the arenas leaving the device."""
+    import jax
+    import jax.numpy as jnp
+
+    pool = KVBlockPool(CFG, num_blocks=6, block_size=4)
+    rng = np.random.RandomState(0)
+    k0 = rng.standard_normal(pool.k_data.shape).astype(np.float32)
+    v0 = rng.standard_normal(pool.v_data.shape).astype(np.float32)
+    pool.k_data, pool.v_data = jnp.asarray(k0), jnp.asarray(v0)
+    pool.clone_block(4, 1)
+    assert isinstance(pool.k_data, jax.Array)
+    k0[:, 1], v0[:, 1] = k0[:, 4], v0[:, 4]
+    assert np.array_equal(np.asarray(pool.k_data), k0)
+    assert np.array_equal(np.asarray(pool.v_data), v0)
+    k, v = pool.read_block(1)
+    assert np.array_equal(k, k0[:, 4]) and np.array_equal(v, v0[:, 4])
+
+
+def test_pool_that_does_not_fit_fails_at_construction_with_its_sizes(monkeypatch):
+    import jax.numpy as jnp
+
+    def refuses(shape, dtype):
+        raise RuntimeError("RESOURCE_EXHAUSTED: out of memory")
+
+    monkeypatch.setattr(jnp, "zeros", refuses)
+    with pytest.raises(MemoryError) as ei:
+        KVBlockPool(CFG, num_blocks=8, block_size=4)
+    each = CFG.num_layers * 8 * 4 * CFG.num_heads * CFG.head_dim * 4
+    assert f"{each} bytes an arena" in str(ei.value) and "8 blocks" in str(ei.value)
 
 
 # ---------------------------------------------------------------------------
@@ -254,6 +300,182 @@ def test_ttft_reported_and_concurrent_batching(llm_server):
     for r in out:
         assert r["ttft_s"] is not None and 0 < r["ttft_s"] < 60
         assert len(r["tokens"]) == 8
+
+
+# ---------------------------------------------------------------------------
+# the pool on the device: paging is bitwise a host-built pair, clones before
+# a shared write, and compiles nothing once the engine is built
+# ---------------------------------------------------------------------------
+
+_PAGING = dict(
+    num_blocks=48, block_size=16, prefill_chunk=32,
+    lane_buckets=(1, 2, 4), prefill_token_buckets=(16, 32),
+    cache_buckets=(64, 128),
+)
+
+
+def _sequences(lengths, new):
+    return [
+        batching._Sequence({"prompt": _prompt(70 + i, n), "max_new_tokens": new})
+        for i, n in enumerate(lengths)
+    ]
+
+
+def _drive(eng, seqs):
+    steps = 0
+    while not all(s.done for s in seqs):
+        eng.step([s for s in seqs if not s.done])
+        steps += 1
+        assert steps < 200
+    for s in seqs:
+        assert s._error is None, s._error
+
+
+def _paged(pool, blocks, n):
+    """The first ``n`` tokens of the cache that ``blocks`` page, on the host."""
+    k, v = zip(*(pool.read_block(b) for b in blocks))
+    return np.concatenate(k, axis=1)[:, :n], np.concatenate(v, axis=1)[:, :n]
+
+
+def test_paged_device_calls_are_bitwise_a_zero_padded_host_pair():
+    """Every device call of a mixed workload (1 to 4 lanes, lengths that end
+    inside a block, a pool that starts full of finite garbage) against the
+    same ``extend`` fed a zero-padded pair built on the host from a mirror of
+    each lane's cache: the sampled rows and the pool's live contents are the
+    same bits."""
+    import jax.numpy as jnp
+
+    eng = LLMEngine(CFG, prefix_caching=False, prefill_lanes=2, **_PAGING)
+    pool, rng = eng.pool, np.random.RandomState(7)
+    pool.k_data = jnp.asarray(rng.standard_normal(pool.k_data.shape), pool.dtype)
+    pool.v_data = jnp.asarray(rng.standard_normal(pool.v_data.shape), pool.dtype)
+    shape = (CFG.num_layers, 0, CFG.num_heads, CFG.head_dim)
+    mirror, calls, real = {}, [], eng._run_extend
+
+    def checked(states, chunks, tc):
+        b = batching.bucket_pad_size(len(states), eng.lane_buckets)
+        cap = batching.bucket_pad_size(
+            max(st.length + len(ch) for st, ch in zip(states, chunks)), eng.cache_buckets)
+        k = np.zeros((CFG.num_layers, b, cap, CFG.num_heads, CFG.head_dim), pool.dtype)
+        v = np.zeros_like(k)
+        tokens, lengths = np.zeros((b, tc), np.int32), np.zeros((b,), np.int32)
+        for i, (st, ch) in enumerate(zip(states, chunks)):
+            _, mk, mv = mirror.setdefault(id(st), (st, np.zeros(shape), np.zeros(shape)))
+            assert mk.shape[1] == st.length
+            k[:, i, :st.length], v[:, i, :st.length] = mk, mv
+            tokens[i, :len(ch)], lengths[i] = ch, st.length
+        logits, hidden, k_new, v_new = (
+            np.asarray(o) for o in eng._extend(eng._params, tokens, lengths, k, v))
+        rows = real(states, chunks, tc)
+        assert rows[0].shape == (b, CFG.vocab_size) and rows[1].shape == (b, CFG.embed_dim)
+        for i, (st, ch) in enumerate(zip(states, chunks)):
+            n = len(ch)
+            assert np.array_equal(rows[0][i], logits[i, n - 1])
+            assert np.array_equal(rows[1][i], hidden[i, n - 1])
+            _, mk, mv = mirror[id(st)]
+            mk = np.concatenate([mk, k_new[:, i, :n]], axis=1)
+            mv = np.concatenate([mv, v_new[:, i, :n]], axis=1)
+            mirror[id(st)] = (st, mk, mv)
+            pk, pv = _paged(pool, st.blocks, st.length + n)
+            assert np.array_equal(pk, mk) and np.array_equal(pv, mv)
+        calls.append((len(states), tc, cap, [st.length % eng.block_size for st in states]))
+        return rows
+
+    eng._run_extend = checked
+    _drive(eng, _sequences((20, 40, 9, 33, 70), 5))
+    assert {c[0] for c in calls} >= {1, 2, 3, 4}
+    assert {c[1] for c in calls} == {1, 16, 32} and {c[2] for c in calls} == {64, 128}
+    assert any(r for c in calls for r in c[3])      # frontiers inside a block
+
+
+def test_engine_clones_a_shared_tail_block_on_the_device_before_writing_it():
+    eng = LLMEngine(CFG, prefix_caching=False, **_PAGING)
+    (seq,) = _sequences((20,), 6)
+    eng.step([seq])                             # prefill and the first decode
+    st, bs = seq.state, eng.block_size
+    assert st.length == 21 and not seq.done
+    tail = st.blocks[st.length // bs]
+    eng.pool.incref([tail])                     # a second holder appears
+    k0, v0 = eng.pool.read_block(tail)
+    assert k0[:, :5].any() and not k0[:, 5:].any()
+    eng.step([seq])                             # writes token 21: must clone
+    clone = st.blocks[1]
+    assert clone != tail and eng.pool.refcount(tail) == 1
+    k1, v1 = eng.pool.read_block(tail)
+    assert np.array_equal(k0, k1) and np.array_equal(v0, v1)    # original intact
+    kc, vc = eng.pool.read_block(clone)
+    assert np.array_equal(kc[:, :5], k0[:, :5]) and np.array_equal(vc[:, :5], v0[:, :5])
+    assert kc[:, 5].any() and not kc[:, 6:].any()
+    _drive(eng, [seq])
+    eng.pool.free([tail])
+    assert eng.pool.in_use() == 0
+    undisturbed = _sequences((20,), 6)
+    _drive(eng, undisturbed)
+    assert seq._result["tokens"] == undisturbed[0]._result["tokens"]
+
+
+def test_nothing_compiles_once_the_engine_is_built_and_extend_is_warm():
+    """Every (lanes, tokens, cache) bucket combination, a clone and a whole
+    request with a prefix hit, after construction and the benchmark's kind of
+    warm-up of ``extend``: not one compile request reaches the backend."""
+    import itertools
+    import math
+
+    import jax
+    import jax.numpy as jnp
+    from jax._src.dispatch import BACKEND_COMPILE_EVENT
+
+    from ray_tpu.serve.llm import _SeqState
+
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, secs, **kw: compiles.append(event)
+        if event == BACKEND_COMPILE_EVENT else None)
+    eng = LLMEngine(CFG, prefix_caching=True, prefill_lanes=4, **_PAGING)
+    combos = list(itertools.product(
+        eng.lane_buckets, [1] + eng.prefill_token_buckets, eng.cache_buckets))
+    for b, tc, cap in combos:
+        kv = jnp.zeros((CFG.num_layers, b, cap, CFG.num_heads, CFG.head_dim), eng.pool.dtype)
+        jax.block_until_ready(eng._extend(
+            eng._params, jnp.zeros((b, tc), jnp.int32), jnp.zeros((b,), jnp.int32), kv, kv))
+    assert compiles                             # the listener hears a compile
+    del compiles[:]
+
+    seen, real = set(), eng._run_extend
+
+    def recorded(states, chunks, tc):
+        rows = real(states, chunks, tc)
+        seen.add((rows[0].shape[0], tc, eng.cache_slots - recorded.slots0))
+        recorded.slots0 = eng.cache_slots
+        return rows
+
+    recorded.slots0 = eng.cache_slots
+    eng._run_extend = recorded
+    bs = eng.block_size
+    for b, tc, cap in combos:
+        lanes = b if b <= 2 else b - 1          # a padded lane where there can be one
+        chunk = 1 if tc == 1 else tc - 1
+        states = []
+        for _ in range(lanes):
+            st = _SeqState()
+            st.length = cap - tc - 3            # ends inside a block
+            st.blocks = eng.pool.allocate(math.ceil((st.length + chunk) / bs))
+            states.append(st)
+        rows = eng._run_extend(states, [[1] * chunk] * lanes, tc)
+        assert np.isfinite(rows[0][:lanes]).all()
+        for st in states:
+            eng.pool.free(st.blocks)
+    assert seen == {(b, tc, b * cap) for b, tc, cap in combos}
+    blocks = eng.pool.allocate(1)
+    eng.pool.incref(blocks)
+    assert eng.pool.ensure_private(blocks, 0) != eng.pool.free(blocks)     # the clone ran
+    eng.pool.free(blocks)
+    first, again = (_sequences((40,), 4) for _ in range(2))
+    _drive(eng, first)
+    _drive(eng, again)
+    assert again[0]._result["prefix_cached_tokens"] == 32
+    assert again[0]._result["tokens"] == first[0]._result["tokens"]
+    assert compiles == []
 
 
 # ---------------------------------------------------------------------------

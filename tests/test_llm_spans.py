@@ -217,7 +217,7 @@ def test_counters_equal_what_the_shapes_give(engine):
     cfg, before = engine.cfg, engine.stats()
     assert _drive(engine, _requests()) == 3
     after = engine.stats()
-    kv_row = 2 * cfg.num_layers * cfg.num_heads * cfg.head_dim * 4   # K+V of one token, f32
+    block = ENGINE["block_size"]
     want = {
         "steps": 3, "admitted": 3,
         "prefill_tokens": sum(LENGTHS),
@@ -226,13 +226,14 @@ def test_counters_equal_what_the_shapes_give(engine):
         "lane_slots": sum(c[1] for c in CALLS),
         "cache_tokens": sum(c[4] for c in CALLS),
         "cache_slots": sum(b * cap for _, b, _, cap, _ in CALLS),
+        # int32 up: tokens [b, tc], the page-back's rows and slots [b * tc] and
+        # their count, lengths and last rows [b], the block table [b, cap /
+        # block]; the pair never leaves the device
         "h2d_bytes": sum(
-            b * tc * 4 + b * 4 + b * cap * kv_row for _, b, tc, cap, _ in CALLS
+            4 * (3 * b * tc + 1 + 2 * b + b * cap // block) for _, b, tc, cap, _ in CALLS
         ),
-        "d2h_bytes": sum(
-            b * tc * (cfg.vocab_size + cfg.embed_dim) * 4 + b * tc * kv_row
-            for _, b, tc, _, _ in CALLS
-        ),
+        # f32 down: one row of logits and one of hidden a lane
+        "d2h_bytes": sum(b * (cfg.vocab_size + cfg.embed_dim) * 4 for _, b, _, _, _ in CALLS),
     }
     assert {k: _delta(after, before, k) for k in want} == want
     assert want["lanes_used"] < want["lane_slots"]
@@ -291,24 +292,25 @@ def test_queue_time_is_in_every_result_and_counts_the_wait_for_a_slot(engine):
 
 
 @pytest.mark.parametrize(
-    "phase, method, nth",
-    # the third device call and the fourth lane scattered open the second step
-    [("dispatch", "_extend", 2), ("kv_scatter", "_scatter", 3)],
+    "phase, owner, method",
+    # the call of extend and the dispatch of the page-back program
+    [("dispatch", "engine", "_extend"), ("kv_scatter", "pool", "page_back")],
 )
 def test_slowest_step_names_the_step_and_the_phase_that_stalled(
-    engine, monkeypatch, phase, method, nth
+    engine, monkeypatch, phase, owner, method
 ):
     engine.stats()                          # a read starts the record anew
-    real, calls, planted = getattr(engine, method), [], {}
+    owner = engine if owner == "engine" else engine.pool
+    real, calls, planted = getattr(owner, method), [], {}
 
     def stalls_once(*args):
         calls.append(None)
-        if len(calls) == nth + 1:
+        if len(calls) == 3:                 # the third device call opens the second step
             planted["at"] = time.time()
             time.sleep(0.25)
         return real(*args)
 
-    monkeypatch.setattr(engine, method, stalls_once)
+    monkeypatch.setattr(owner, method, stalls_once)
     _drive(engine, _requests())
     slow = engine.stats()["slowest_step"]
     assert 0.25 <= slow["phase_s"][phase] <= slow["wall_s"] == slow["phase_s"]["step"]
@@ -340,23 +342,19 @@ def test_two_reads_bound_the_slowest_step(engine, monkeypatch):
 
 def test_phases_cost_less_than_their_budget_outside_a_session(engine, monkeypatch):
     """1,000 engine steps with ``_phase`` as it is and 1,000 with it swapped
-    for a bare no-op, turn about, the device call and the upload stubbed so
-    that a step is the engine's own python: the difference per step stays
+    for a bare no-op, turn about, the device programs and the upload stubbed
+    so that a step is the engine's own python: the difference per step stays
     under ``PHASE_BUDGET_NS`` for each phase the step opens."""
     cfg = engine.cfg
-    made = {}
-
-    def instant(params, tokens, lengths, k_cache, v_cache):
-        b, tc = tokens.shape
-        if (b, tc) not in made:
-            made[b, tc] = (
-                np.zeros((b, tc, cfg.vocab_size), np.float32),
-                np.zeros((b, tc, cfg.embed_dim), np.float32),
-            ) + (np.zeros((cfg.num_layers, b, tc, cfg.num_heads, cfg.head_dim), np.float32),) * 2
-        return made[b, tc]
-
-    monkeypatch.setattr(engine, "_extend", instant)
-    monkeypatch.setattr(jnp, "asarray", lambda a: a)
+    rows = {
+        b: (np.zeros((b, cfg.vocab_size), np.float32), np.zeros((b, cfg.embed_dim), np.float32))
+        for b in engine.lane_buckets
+    }
+    monkeypatch.setattr(engine, "_extend", lambda *args: (None,) * 4)
+    monkeypatch.setattr(engine.pool, "gather", lambda table: (None, None))
+    monkeypatch.setattr(
+        engine.pool, "page_back", lambda *args: rows[len(args[-1])])
+    monkeypatch.setattr(jax, "device_put", lambda a: a)
     as_it_is, nothing = engine._phase, contextlib.nullcontext()
 
     def thousand_steps(phase):
