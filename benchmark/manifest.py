@@ -7,6 +7,9 @@ by adding files and entries and edits none that is there:
 
 * ``configs/<config>.json`` — the configuration's ``file``: the published
   sizes as run, and how the job or the engine is sized on the chip;
+* ``models/<model_type>.py`` — the architecture the configuration's
+  ``model_type`` names (``models/__init__.py`` says what it holds), and
+  ``reference/<module>.py`` — the plain reference its ``reference.module`` names;
 * ``traffic/<traffic>.json`` — the mix's parameters, with ``generator`` naming
   the module ``traffic/<generator>.py`` that reads them;
 * ``metrics/<metric>.py`` — one reader per metric: ``read(run)`` returns the
@@ -29,6 +32,12 @@ class ManifestError(Exception):
     """The manifest or a file it names is missing or does not fit it."""
 
 
+def published_keys(config: Dict[str, Any]) -> Dict[str, Any]:
+    """The top level of a configuration's file without its nested groups: the
+    published ``config.json`` keys as run, and the benchmark's two dtypes."""
+    return {k: v for k, v in config.items() if not isinstance(v, (dict, list))}
+
+
 @dataclasses.dataclass(frozen=True)
 class Cell:
     name: str
@@ -36,6 +45,8 @@ class Cell:
     why: str
     config_name: str
     config: Dict[str, Any]       # the configuration's file
+    architecture: str            # module of the file's ``model_type``, by name
+    reference: str               # module of the file's plain reference, by name
     traffic_name: str
     traffic: Dict[str, Any]      # the mix's file
     end_to_end: List[Dict[str, Any]]
@@ -88,26 +99,35 @@ class Manifest:
         traffic_rel = os.path.join(
             self.data["paths"][0], "traffic", entry["traffic"] + ".json"
         )
+        file = self._read_json(config["file"])
         return Cell(
             name=name, chips=int(entry["chips"]), why=entry["why"],
-            config_name=config["name"], config=self._read_json(config["file"]),
+            config_name=config["name"], config=file,
+            architecture=self._module(
+                "models", file.get("model_type"), f"{config['file']} model_type"),
+            reference=self._module(
+                "reference", file.get("reference", {}).get("module"),
+                f"{config['file']} reference.module"),
             traffic_name=entry["traffic"], traffic=self._read_json(traffic_rel),
             end_to_end=self._metrics_of("end_to_end", name),
             per_layer=self._metrics_of("per_layer", name),
         )
 
+    def _module(self, folder: str, stem: Optional[str], named_by: str) -> str:
+        """The name of the module ``<folder>/<stem>.py`` of the benchmark's
+        package. By name, because the worker that holds the chip imports it too."""
+        if not stem or not os.path.isfile(os.path.join(self.home, folder, stem + ".py")):
+            raise ManifestError(
+                f"{named_by} is {stem!r}, and there is no "
+                f"{os.path.join(self.data['paths'][0], folder, str(stem))}.py"
+            )
+        return f"{os.path.basename(self.home)}.{folder}.{stem}"
+
     def generator(self, cell: Cell):
         """The module that turns the cell's traffic file into load."""
-        kind = cell.traffic.get("generator")
-        if not kind or not os.path.isfile(
-            os.path.join(self.home, "traffic", kind + ".py")
-        ):
-            raise ManifestError(
-                f"traffic {cell.traffic_name!r} names generator {kind!r}, and "
-                f"there is no traffic/{kind}.py"
-            )
-        # by module name, because the worker that runs the job imports it too
-        return importlib.import_module(f"{os.path.basename(self.home)}.traffic.{kind}")
+        return importlib.import_module(self._module(
+            "traffic", cell.traffic.get("generator"),
+            f"traffic {cell.traffic_name!r} generator"))
 
     def reader(self, metric: str) -> Callable[[Dict[str, Any]], Optional[float]]:
         path = os.path.join(self.home, "metrics", metric + ".py")

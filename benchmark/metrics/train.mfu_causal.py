@@ -1,8 +1,8 @@
 """Model FLOP/s utilization: the operations the forward and backward passes
-require (``yardstick.train_step_flops``: 6 per matmul parameter per token plus
-causal attention; recomputation not counted) x steps / the window / (chips x
-the chip's published bf16 peak). In a traced run the profiler's own start and
-stop are left out of the window."""
+require (``train_step_flops`` of the cell's ``models/<model_type>.py``: causal
+attention counted over the half of the square the mask leaves, recomputation
+not counted) x steps / the window / (chips x the chip's published bf16 peak).
+In a traced run the profiler's own start and stop are left out of the window."""
 
 from benchmark.yardstick import peak
 

@@ -178,20 +178,29 @@ def _program_hidden(program, tokens, model, eps):
     return x
 
 
-def program_logits(program, tokens, model, eps, last: int):
+def _program_eps(config) -> float:
+    """The program's LayerNorm epsilon (see the departures above), which the
+    configuration's file states under ``reference``."""
+    return config["reference"]["program_layer_norm_epsilon"]
+
+
+def program_logits(program, tokens, config, last: int):
     """Float32 logits [last, vocab] of the last ``last`` positions of one
-    sequence ``tokens`` [seq], from the program's own weights."""
-    x = _program_hidden(program, jnp.asarray(tokens)[None], model, eps)[:, -last:]
+    sequence ``tokens`` [seq], from the program's own weights; ``config`` is
+    the configuration's file."""
+    eps = _program_eps(config)
+    x = _program_hidden(program, jnp.asarray(tokens)[None], config, eps)[:, -last:]
     return _program_head(x, program["ln_f"], program["lm_head"], eps)[0]
 
 
-def program_loss(program, tokens, model, eps) -> float:
+def program_loss(program, tokens, config) -> float:
     """:func:`loss` of ``tokens`` [batch, seq] from the program's own weights,
     one sequence at a time (rows are equally long, so the mean of the rows'
-    means is the batch's mean)."""
+    means is the batch's mean); ``config`` is the configuration's file."""
+    eps = _program_eps(config)
     rows = []
     for row in tokens:
-        x = _program_hidden(program, row[None], model, eps)
+        x = _program_hidden(program, row[None], config, eps)
         rows.append(float(
             _program_row_loss(x, program["ln_f"], program["lm_head"], row[None], eps)
         ))

@@ -30,6 +30,9 @@ if ROOT not in sys.path:
 from benchmark import chip, contract, manifest  # noqa: E402
 
 
+SAID_GROUPS = 24      # kernels and scopes a traced run prints; readers get every one
+
+
 def run_cell(
     root: str, workload: str, seed: int, seconds: float, traced: bool,
     started: Optional[float] = None,
@@ -56,6 +59,12 @@ def run_cell(
     if trace and "busy_s" in trace:
         device.update(busy_s=trace["busy_s"], window_s=trace["window_s"])
         breakdown = {k: trace[k] for k in contract.BREAKDOWN_KEYS}
+        for key in ("ops_by_kernel", "ops_by_scope"):
+            chip.say(
+                f"{key}, device seconds of the traced sub-window's {trace['window_s']:.3f} "
+                f"({len(trace[key])} in all, the largest {SAID_GROUPS}): "
+                + json.dumps(trace[key][:SAID_GROUPS])
+            )
     line = contract.build(
         correct=run["correct"], attempted=run["attempted"], failed=run["failed"],
         values=values, wanted=cell.metrics(traced), device=device, breakdown=breakdown,

@@ -42,7 +42,7 @@ def main(argv=None) -> int:
     readers = {n: book.reader(n) for n in ("ttft_p95_s", "tpot_p95_s")}
     rows = []
     with chip.cluster(cell.chips):
-        handle, _, _, problems, params, model = serve_open_loop.deploy(cell, args.seed)
+        handle, _, _, problems, params = serve_open_loop.deploy(cell, args.seed)
         for rate in (float(r) for r in args.rates.split(",")):
             requests = serve_open_loop.schedule(
                 {**params, "rate_rps": rate, "lead_in_requests": 0, "lead_out_requests": 0}, args.seed, args.seconds
@@ -54,7 +54,7 @@ def main(argv=None) -> int:
             )
             drained_s = chip.now() - start - args.seconds
             stats1 = handle.kv_stats.remote().result(timeout=120.0)
-            problems += serve_open_loop.check_completions(requests, model["vocab_size"])
+            problems += serve_open_loop.check_completions(requests, params["vocab_size"])
             run = {"kind": "serve", "window_s": args.seconds, "records": requests,
                    "drain_limit_s": params["drain_limit_s"]}
             row = {

@@ -1,33 +1,49 @@
 """From a profiler trace to numbers: device busy time, time per operation,
-exposed collective time, and idle gaps by what the host was doing.
+per kernel and per scope, exposed collective time, and idle gaps by what the
+host was doing.
 
 The process that holds the chip writes an ``.xplane.pb`` (``jax.profiler``);
-:func:`load_xplane` keeps the lines the reduction reads as plain lists, and
-:func:`reduce` works on those lists alone, so it is checked in the tests on a
-recorded trace kept as JSON beside them.
+:func:`load_xplane` keeps the lines the reduction reads as plain lists,
+:func:`load_scopes` the scope each instruction ran under, and :func:`reduce`
+works on those alone, so it is checked in the tests on recorded traces kept as
+JSON beside them.
 
 What a TPU trace holds (seen on a v5e, jax 0.9.0): one plane per chip named
 ``/device:TPU:<n>`` whose line ``XLA Ops`` has one event per HLO instruction
 run by the core — nested where an instruction (``while``) runs others — and
 whose line ``Async XLA Ops`` has one event from each ``*-start`` to its
-``*-done``; one plane ``/host:CPU`` with a line per host thread that carries
+``*-done``, and whose line ``XLA Modules`` has one event per run of a compiled
+program (``jit_step(<id>)``), which says whose instruction an event is; one plane ``/host:CPU`` with a line per host thread that carries
 ``TraceAnnotation`` spans and the runtime's own (transfers, dispatch). All
-share one clock, nanoseconds from the trace's start.
+share one clock, nanoseconds from the trace's start. An event's name is its
+HLO instruction (``fusion.12``; a Pallas kernel's is the ``name=`` of its
+``pallas_call`` with a number, ``flash_fwd.18``); the ``jax.named_scope`` it
+ran under is in its metadata's ``tf_op`` and in the ``op_name`` of its module's
+HLO proto (``jit(step)/jvp(train.forward)/GPT/.../dot_general``), see
+``xplane_wire``. An instruction's name is unique in its module only: two
+programs of one trace both have a ``fusion.5``.
 """
 
 from __future__ import annotations
 
+import bisect
 import re
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):\d+$")
-OPS_LINE, ASYNC_LINE = "XLA Ops", "Async XLA Ops"
+OPS_LINE, ASYNC_LINE, MODULES_LINE = "XLA Ops", "Async XLA Ops", "XLA Modules"
 HOST_PLANE = "/host:CPU"
 COLLECTIVE = re.compile(
     r"all-gather|all-reduce|reduce-scatter|collective-permute|all-to-all"
 )
 # host events that only say "the profiler is running" or "python is running"
 _HOST_NOISE = re.compile(r"^\$|ThreadpoolListener|PythonRefManager")
+
+# a component of an op_name that is a named scope of the program: a dotted name
+# (``train.forward``, ``extend.mlp``, ``paging.gather``), perhaps inside the
+# transformations it was traced under (``transpose(jvp(train.forward))``)
+_SCOPE = re.compile(r"^(?:\w+\()*[A-Za-z_]\w*(?:\.[A-Za-z_]\w*)+\)*$")
+NO_SCOPE = "(no scope)"
 
 Event = Tuple[str, float, float]          # name, start ns, duration ns
 Segment = Tuple[str, float, float]        # name, start ns, end ns
@@ -41,7 +57,7 @@ def short_name(name: str) -> str:
 
 def load_xplane(path: str) -> Dict[str, Dict[str, List[Event]]]:
     """``{plane: {line: [(name, start_ns, duration_ns), ...]}}`` for the device
-    planes' operation lines and every host line."""
+    planes' operation and module lines and every host line."""
     from jax.profiler import ProfileData
 
     planes: Dict[str, Dict[str, List[Event]]] = {}
@@ -51,7 +67,7 @@ def load_xplane(path: str) -> Dict[str, Dict[str, List[Event]]]:
             continue
         lines: Dict[str, List[Event]] = {}
         for line in plane.lines:
-            if device and line.name not in (OPS_LINE, ASYNC_LINE):
+            if device and line.name not in (OPS_LINE, ASYNC_LINE, MODULES_LINE):
                 continue
             events = [
                 (short_name(e.name), float(e.start_ns), float(e.duration_ns))
@@ -63,6 +79,47 @@ def load_xplane(path: str) -> Dict[str, Dict[str, List[Event]]]:
         if lines:
             planes[plane.name] = lines
     return planes
+
+
+def load_scopes(path: str) -> Dict[str, Dict[str, str]]:
+    """``{module: {instruction: the op_name it was traced under}}`` from the
+    HLO protos a trace carries. Under the module ``""``, for a module whose
+    proto the trace lacks: the ``tf_op`` of the device planes' event metadata
+    by instruction name alone."""
+    from benchmark import xplane_wire
+
+    planes = xplane_wire.planes_metadata(path)
+    out: Dict[str, Dict[str, str]] = {}
+    for plane, metadata in planes.items():
+        for name, stats in metadata.items():
+            for blob in stats.values():
+                if isinstance(blob, bytes):
+                    for module, names in xplane_wire.hlo_op_names(blob).items():
+                        out.setdefault(module, {}).update(names)
+            op = stats.get("tf_op")
+            if DEVICE_PLANE.match(plane) and isinstance(op, str) and op:
+                out.setdefault("", {})[short_name(name)] = op
+    return out
+
+
+def module_of(event: str) -> str:
+    """``jit_step(8392355915514388644)`` -> ``jit_step``."""
+    return re.sub(r"\(\d+\)$", "", event)
+
+
+def kernel_of(instruction: str) -> str:
+    """``flash_fwd.18`` -> ``flash_fwd``: the instruction without the number
+    the compiler gave this copy of it."""
+    return re.sub(r"\.\d+$", "", instruction)
+
+
+def scope_of(op_name: str) -> str:
+    """The innermost named scope of an ``op_name``, as it stands there (a
+    scope's backward pass is ``transpose(jvp(<scope>))``), or ``NO_SCOPE``."""
+    for part in reversed(op_name.rstrip(":").split("/")):
+        if _SCOPE.match(part):
+            return part
+    return NO_SCOPE
 
 
 def union_length(intervals: Iterable[Tuple[float, float]]) -> float:
@@ -158,7 +215,8 @@ def _host_label(host: Dict[str, List[Event]], annotation: str, at: float) -> str
 
 
 def reduce(
-    planes: Dict[str, Dict[str, List[Event]]], annotation: str, top: int = 10
+    planes: Dict[str, Dict[str, List[Event]]], annotation: str, top: int = 10,
+    scopes: Optional[Dict[str, Dict[str, str]]] = None,
 ) -> Optional[Dict[str, Any]]:
     """Reduce the traced sub-window (see :func:`annotation_window`).
 
@@ -167,20 +225,33 @@ def reduce(
     their operations' intervals; ``per_device`` lists each device's own.
     ``collective_exposed_s`` is, per device, the time inside collective
     operations (from ``-start`` to ``-done`` when asynchronous) during which
-    no other operation ran on that device."""
+    no other operation ran on that device. ``device_ops`` and ``idle_gaps``
+    hold the ``top`` largest; ``ops_by_kernel`` (:func:`kernel_of`) and
+    ``ops_by_scope`` (:func:`scope_of` of what ``scopes``, which
+    :func:`load_scopes` gives, holds for the instruction in the module that was
+    running) hold every one, largest first, the same self time summed."""
     window = annotation_window(planes, annotation)
     devices = sorted(p for p in planes if DEVICE_PLANE.match(p))
     if window is None or not devices:
         return None
     lo, hi = window
     host = planes.get(HOST_PLANE, {})
-    per_device, busy_of, op_totals, gap_totals = [], {}, {}, {}
+    scopes = scopes or {}
+    per_device, busy_of, op_totals, scope_totals, gap_totals = [], {}, {}, {}, {}
     for name in devices:
         lines = planes[name]
         ops = clip(self_segments(lines.get(OPS_LINE, [])), lo, hi)
         busy_of[name] = merge((a, b) for _, a, b in ops)
+        runs = sorted((s, s + d, module_of(n)) for n, s, d in lines.get(MODULES_LINE, []))
+        starts = [r[0] for r in runs]
         for n, a, b in ops:
             op_totals[n] = op_totals.get(n, 0.0) + (b - a)
+            at = bisect.bisect_right(starts, a) - 1
+            module = runs[at][2] if at >= 0 and a < runs[at][1] else ""
+            scope = scope_of(
+                scopes.get(module, {}).get(n) or scopes.get("", {}).get(n, "")
+            )
+            scope_totals[scope] = scope_totals.get(scope, 0.0) + (b - a)
         collective = merge(
             [(a, b) for n, a, b in ops if COLLECTIVE.search(n)]
             + [
@@ -202,10 +273,14 @@ def reduce(
         label = _host_label(host, annotation, (a + b) / 2)
         gap_totals[label] = gap_totals.get(label, 0.0) + (b - a)
 
-    def ranked(totals: Dict[str, float], scale: float) -> List[List[Any]]:
+    def ranked(totals: Dict[str, float], scale: float, most=top) -> List[List[Any]]:
         return [
-            [n, t / scale] for n, t in sorted(totals.items(), key=lambda kv: -kv[1])[:top]
+            [n, t / scale] for n, t in sorted(totals.items(), key=lambda kv: -kv[1])[:most]
         ]
+
+    by_kernel: Dict[str, float] = {}
+    for instruction, t in op_totals.items():
+        by_kernel[kernel_of(instruction)] = by_kernel.get(kernel_of(instruction), 0.0) + t
 
     n = len(devices)
     return {
@@ -217,5 +292,7 @@ def reduce(
         "collective_exposed_s": max(d["collective_exposed_s"] for d in per_device),
         "per_device": per_device,
         "device_ops": ranked(op_totals, 1e9 * n),
+        "ops_by_kernel": ranked(by_kernel, 1e9 * n, None),
+        "ops_by_scope": ranked(scope_totals, 1e9 * n, None),
         "idle_gaps": ranked(gap_totals, 1e9),
     }

@@ -73,7 +73,8 @@ class SubWindowTrace:
                 return None
             t0 = time.perf_counter()
             reduced = trace_reduce.reduce(
-                trace_reduce.load_xplane(found[0]), self.annotation
+                trace_reduce.load_xplane(found[0]), self.annotation,
+                scopes=trace_reduce.load_scopes(found[0]),
             )
             if reduced is not None:
                 reduced.update(

@@ -11,12 +11,12 @@ itself ran is reported beside them.
 
 from __future__ import annotations
 
+import json
 import time
 from concurrent.futures import ThreadPoolExecutor, wait
 from typing import Any, Dict, List
 
 from benchmark import chip, yardstick
-from benchmark import model as model_mod
 
 WAITERS = 32       # threads blocked on results; more than can be in flight unshed
 
@@ -65,19 +65,20 @@ def schedule(params: Dict[str, Any], seed: int, seconds: float) -> List[Dict[str
 
 
 def _gate(
-    handle, params: Dict[str, Any], seed: int, model: Dict[str, Any],
-    reference: Dict[str, Any],
+    handle, params: Dict[str, Any], seed: int, reference: Dict[str, Any]
 ) -> List[str]:
     """One seeded prompt asked twice: uncached, then from the prefix cache,
     must give equal tokens and bitwise-equal logits; and the logits must be
-    those of the configuration's plain reference, which the replica runs in
-    float32 on the served weights over the prompt and the tokens it gave."""
+    those of the configuration's plain reference (``reference`` is the group
+    of that name in its file), which the replica runs in float32 on the
+    served weights over the prompt and the tokens it gave."""
     import numpy as np
 
     rng = np.random.default_rng(seed + 1)
     n, new = params["gate_prompt_tokens"], params["gate_new_tokens"]
+    vocab = params["vocab_size"]
     ask = {
-        "prompt": [int(t) for t in rng.integers(0, model["vocab_size"], size=n)],
+        "prompt": [int(t) for t in rng.integers(0, vocab, size=n)],
         "max_new_tokens": new, "return_logits": True,
     }
     first = handle.remote(ask).result(timeout=600.0)
@@ -98,20 +99,20 @@ def _gate(
             f"{again['tokens']}, max |dlogit| "
             f"{float(np.abs(again['logits'] - first['logits']).max())}"
         )
-    if first["logits"].shape != (new, model["vocab_size"]) or not np.isfinite(
+    if first["logits"].shape != (new, vocab) or not np.isfinite(
         first["logits"]
     ).all():
         problems.append(f"bad logits {first['logits'].shape}")
         return problems
     t0 = time.perf_counter()
     want = handle.reference_logits.remote(
-        reference, ask["prompt"] + first["tokens"][:-1], new
+        ask["prompt"] + first["tokens"][:-1], new
     ).result(timeout=600.0)
     error = yardstick.logits_error(first["logits"], want)
     chip.say(
         f"reference {reference['module']} on the served weights, float32, {n + new - 1} "
         f"tokens in {time.perf_counter() - t0:.2f}s: the server's {new} x "
-        f"{model['vocab_size']} logits differ by {error:.5f} of the reference's standard "
+        f"{vocab} logits differ by {error:.5f} of the reference's standard "
         f"deviation {float(np.std(want)):.4f} (max |d| {float(np.abs(first['logits'] - want).max()):.5f}, "
         f"limit {reference['max_logits_error']}); argmax "
         f"{[int(t) for t in want.argmax(-1)]} vs served tokens {first['tokens']}"
@@ -126,23 +127,21 @@ def _gate(
 
 def deploy(cell, seed: int):
     """Put the replica on the chip, warm every shape, and pass the gate.
-    Returns ``(handle, device, warm, problems, params, model)``; call inside
-    ``chip.cluster``."""
+    Returns ``(handle, device, warm, problems, params)``, ``params`` the
+    traffic file's with the served vocabulary and the engine's block size;
+    call inside ``chip.cluster``."""
     from ray_tpu import serve
 
     from benchmark.server import BenchLLMServer
 
     config, params = cell.config, dict(cell.traffic)
-    model = model_mod.published_keys(config)
     engine = config["engine"]
-    params.setdefault("vocab_size", model["vocab_size"])
-    params["block_size"] = engine["block_size"]
     t0 = time.perf_counter()
     handle = serve.run(
         serve.deployment(
             BenchLLMServer, name="bench",
             ray_actor_options={"num_tpus": 1} if chip.PLATFORM == "tpu" else None,
-        ).bind(model, seed=seed, **engine),
+        ).bind(cell.architecture, cell.reference, config, seed=seed, **engine),
         timeout=600.0,
     )
     # the first call returns once the replica has built its weights
@@ -150,20 +149,22 @@ def deploy(cell, seed: int):
     chip.check_device(device, cell.chips, "the serve replica")
     up_s = time.perf_counter() - t0
     warm = handle.warm.remote().result(timeout=1100.0)
+    params.setdefault("vocab_size", warm["vocab_size"])
+    params["block_size"] = engine["block_size"]
     chip.say(
         f"serve replica: {device}; {warm['model']}; up in {up_s:.1f}s (weights "
         f"{warm['weights_s']:.1f}s), {warm['shapes']} shapes warmed in "
         f"{warm['warm_s']:.1f}s; engine {engine}"
     )
-    problems = _gate(handle, params, seed, model, config["reference"])
-    return handle, device, warm, problems, params, model
+    problems = _gate(handle, params, seed, config["reference"])
+    return handle, device, warm, problems, params
 
 
 def offer(handle, requests: List[Dict[str, Any]], window_start: float, deadline: float) -> None:
     """Send each request when it is due (``window_start`` + its ``due``, on
     ``chip.now()``'s clock) whatever became of the earlier ones, and wait for
     the answers until ``deadline``. Fills each request's ``sent`` and, when it
-    completes, ``done``, ``ttft_s`` and ``tokens``; else ``error``."""
+    completes, ``done``, ``ttft_s``, ``queue_s`` and ``tokens``; else ``error``."""
     from ray_tpu.serve.handle import BackPressureError
 
     def finish(rec, response):
@@ -171,6 +172,7 @@ def offer(handle, requests: List[Dict[str, Any]], window_start: float, deadline:
             result = response.result(timeout=max(0.1, deadline - chip.now()))
             rec["done"] = chip.now() - window_start
             rec["ttft_s"] = result["ttft_s"]
+            rec["queue_s"] = result.get("queue_s")
             rec["tokens"] = result["tokens"]
         except Exception as e:  # noqa: BLE001 — any failure is a failed request
             rec["error"] = repr(e)
@@ -207,9 +209,29 @@ def check_completions(requests, vocab: int) -> List[str]:
     return problems
 
 
+def counter_deltas(after: Dict[str, Any], before: Dict[str, Any]) -> Dict[str, Any]:
+    """What the engine counted between two ``kv_stats`` reads: the difference
+    of every number in them, and of every number in a group of numbers
+    (``phase_s``, ``phase_n``), under its own name; whatever a later engine
+    adds comes with it. What is not a number (the device, a list) is left out."""
+    out: Dict[str, Any] = {}
+    for k, v in after.items():
+        if isinstance(v, dict) and isinstance(before.get(k), dict):
+            group = counter_deltas(v, before[k])
+            if group:
+                out[k] = group
+        elif _is_number(v) and _is_number(before.get(k)):
+            out[k] = v - before[k]
+    return out
+
+
+def _is_number(v) -> bool:
+    return isinstance(v, (int, float)) and not isinstance(v, bool)
+
+
 def run(cell, seed: int, seconds: float, traced: bool, started: float) -> Dict[str, Any]:
     with chip.cluster(cell.chips):
-        handle, device, warm, problems, params, model = deploy(cell, seed)
+        handle, device, warm, problems, params = deploy(cell, seed)
         requests = schedule(params, seed, seconds)
         stats0 = handle.kv_stats.remote().result(timeout=60.0)
         lead = -min(r["due"] for r in requests)
@@ -223,7 +245,7 @@ def run(cell, seed: int, seconds: float, traced: bool, started: float) -> Dict[s
         stats1 = handle.kv_stats.remote().result(timeout=120.0)
         trace = handle.trace_result.remote().result(timeout=300.0) if traced else None
 
-    problems += check_completions(requests, model["vocab_size"])
+    problems += check_completions(requests, params["vocab_size"])
     measured = [r for r in requests if r["measured"]]
     failed = [r for r in measured if not r["ok"]]
     if stats1["kv_blocks_in_use"] != stats1["prefix_cached_blocks"]:
@@ -241,10 +263,7 @@ def run(cell, seed: int, seconds: float, traced: bool, started: float) -> Dict[s
     for r in failed[:5]:
         chip.say(f"failed request {r['index']}: {r.get('error', 'unfinished')}")
 
-    counters = {
-        k: stats1[k] - stats0[k]
-        for k in ("steps", "decode_tokens", "prefix_hits", "prefix_misses")
-    }
+    counters = counter_deltas(stats1, stats0)
     late = [r["sent"] - r["due"] for r in measured]
     compiled = warm["compiled"]
     chip.say(
@@ -254,7 +273,7 @@ def run(cell, seed: int, seconds: float, traced: bool, started: float) -> Dict[s
         f"generator lateness median {yardstick.median(late) * 1e3:.2f} ms max {max(late) * 1e3:.2f} ms"
     )
     chip.say(
-        f"engine over lead-in, window, lead-out and drain: {counters}; kv blocks in use "
+        f"engine over lead-in, window, lead-out and drain: {json.dumps(counters)}; kv blocks in use "
         f"{stats1['kv_blocks_in_use']}; compile cache {stats1['compile_cache']}"
     )
     chip.say(

@@ -19,7 +19,7 @@ import time
 from typing import Any, Dict, List
 
 from benchmark import chip, yardstick
-from benchmark import model as model_mod
+from benchmark.manifest import published_keys
 from benchmark.tracing import SubWindowTrace
 
 ANNOTATION = "bench.train_step"
@@ -39,8 +39,11 @@ def train_loop(config: Dict[str, Any]) -> None:
     from ray_tpu.parallel.mesh import MeshSpec
     from ray_tpu.train import session
 
-    job, chips = config["job"], config["chips"]
-    cfg = model_mod.gpt_config(config["model"])
+    file, chips = config["file"], config["chips"]
+    job = file["job"]
+    cfg = importlib.import_module(config["architecture"]).program_config(
+        published_keys(file)
+    )
     batch = tuple(job["batch"])
     device = accelerator.device_report()
     devices = jax.devices()[:chips]
@@ -56,12 +59,9 @@ def train_loop(config: Dict[str, Any]) -> None:
     tokens = jax.random.randint(
         jax.random.PRNGKey(config["seed"] + 1), batch, 0, cfg.vocab_size
     )
-    reference = config["reference"]
     t0 = time.perf_counter()
-    reference_loss = importlib.import_module(
-        f"benchmark.reference.{reference['module']}"
-    ).program_loss(
-        state.params, tokens, config["model"], reference["program_layer_norm_epsilon"]
+    reference_loss = importlib.import_module(config["reference"]).program_loss(
+        state.params, tokens, file
     )
     reference_s = time.perf_counter() - t0
 
@@ -70,8 +70,11 @@ def train_loop(config: Dict[str, Any]) -> None:
         t = time.perf_counter()
         state, m = compiled(state, tokens)
         loss = float(np.asarray(m["loss"]))       # waits for the step
+        # every other scalar the step reports, for whichever reader wants it,
+        # in one fetch (a fetch apiece took 0.3 ms of a step: PERF.md, PR 26)
+        rest = jax.device_get({k: v for k, v in m.items() if k != "loss" and np.ndim(v) == 0})
         return {
-            "loss": loss, "grad_norm": float(np.asarray(m["grad_norm"])),
+            **{k: float(v) for k, v in rest.items()}, "loss": loss,
             "step_s": time.perf_counter() - t,
         }
 
@@ -135,14 +138,14 @@ def run(cell, seed: int, seconds: float, traced: bool, started: float) -> Dict[s
     from ray_tpu import train
 
     config, job = cell.config, cell.config["job"]
-    model = model_mod.published_keys(config)
+    architecture = importlib.import_module(cell.architecture)
     with chip.cluster(cell.chips) as worker:
         on_tpu = chip.PLATFORM == "tpu"
         result = train.JaxTrainer(
             train_loop,
             train_loop_config={
-                "model": model, "job": job, "reference": config["reference"],
-                "chips": cell.chips, "seed": seed,
+                "file": config, "architecture": cell.architecture,
+                "reference": cell.reference, "chips": cell.chips, "seed": seed,
                 "seconds": seconds, "trace": traced,
             },
             scaling_config=train.ScalingConfig(
@@ -163,13 +166,11 @@ def run(cell, seed: int, seconds: float, traced: bool, started: float) -> Dict[s
     steps, warm = s["steps"], s["warm"]
     reported = [m for m in result.metrics_history if "step" in m and "summary" not in m]
     losses = [m["loss"] for m in warm + steps]
-    finite = all(
-        math.isfinite(m["loss"]) and math.isfinite(m["grad_norm"]) for m in warm + steps
-    )
+    finite = all(math.isfinite(v) for m in warm + steps for v in m.values())
     cache0, cache1 = s["compile_cache"]
     problems = []
     if not finite:
-        problems.append("a loss or grad norm is not finite")
+        problems.append("a step reported a number that is not finite")
     limit = config["reference"]["max_loss_error"]
     loss_error = abs(warm[0]["loss"] - s["reference_loss"]) / s["reference_loss"]
     if not loss_error <= limit:
@@ -233,7 +234,10 @@ def run(cell, seed: int, seconds: float, traced: bool, started: float) -> Dict[s
         "work_s": s["window_s"] - (trace["overhead_s"] if trace else 0.0),
         "steps": len(steps), "step_s": step_s, "report_s": s["report_s"],
         "tokens_per_step": batch[0] * batch[1],
-        "flops_per_step": yardstick.train_step_flops(model, batch[0], batch[1]),
+        "flops_per_step": architecture.train_step_flops(
+            published_keys(config), batch[0], batch[1]
+        ),
+        "step_metrics": steps,
         "chips": cell.chips, "device_kind": s["device"]["kind"],
         "trace": trace,
         "device": {

@@ -1,11 +1,12 @@
-"""The arithmetic every cell is measured with: the chip's peaks, the
-operations a train step requires, and percentiles. Kept with the benchmark so
-that no PR that claims a gain can change it."""
+"""The arithmetic every cell is measured with: the chip's peaks, a kernel's
+share of its roofline, percentiles, and how far two arrays of logits are
+apart. Kept with the benchmark so that no PR that claims a gain can change it.
+What one architecture's train step requires is in ``models/<model_type>.py``."""
 
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Sequence
+from typing import Dict, Sequence
 
 # Published peaks of one chip, keyed by jax's ``device_kind`` (Google Cloud
 # documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s of HBM bandwidth,
@@ -24,28 +25,17 @@ def peak(device_kind: str, what: str) -> float:
     return PEAKS[device_kind][what]
 
 
-def matmul_params(model: Dict[str, Any]) -> int:
-    """Parameters that a token is multiplied with: q, k, v, o, the two MLP
-    matrices of every layer, and the output head. The input embedding is a
-    gather; biases and layer norms are not matrix multiplications."""
-    d = model["n_embd"]
-    f = model["n_inner"] or 4 * d
-    per_layer = 4 * d * d + 2 * d * f
-    return model["n_layer"] * per_layer + d * model["vocab_size"]
-
-
-def train_step_flops(model: Dict[str, Any], batch: int, seq: int) -> float:
-    """Operations the forward and backward passes of one step require:
-    6 per matmul parameter per token, and causal attention, in which a query
-    sees on average (seq + 1) / 2 keys: 2 matmuls (QK^T, PV) x 2 ops x 3
-    (forward + backward) = 12 per query-key pair per feature, over the half of
-    the square the mask leaves. Recomputation (remat) is not counted."""
-    tokens = batch * seq
-    heads_x_dim = model["n_embd"]     # n_head * head_dim
-    attention = (
-        12.0 * model["n_layer"] * batch * heads_x_dim * seq * (seq + 1) / 2.0
+def roofline_share(flops: float, bytes_moved: float, seconds: float, device_kind: str) -> float:
+    """A kernel's share of its roofline, in percent: the time the chip needs
+    at its peaks for ``flops`` operations or for ``bytes_moved`` bytes of HBM
+    traffic, whichever is longer, over the ``seconds`` the kernel took. The
+    reader brings its own count of operations and bytes; a share over 100
+    says that count is too high or the time leaves out part of the work."""
+    at_peak = max(
+        flops / peak(device_kind, "bf16_flops"),
+        bytes_moved / peak(device_kind, "hbm_bytes_per_s"),
     )
-    return 6.0 * matmul_params(model) * tokens + attention
+    return 100.0 * at_peak / seconds
 
 
 def percentile(values: Sequence[float], q: float) -> float:

@@ -78,7 +78,7 @@ class _GateHandle:
         self.reference_logits = self
 
     def remote(self, *args):
-        if len(args) == 3:                      # reference_logits(reference, tokens, last)
+        if len(args) == 2:                      # reference_logits(tokens, last)
             return _Answer(self.want)
         self.asked += 1
         cached = 0 if self.asked == 1 else 32
@@ -96,10 +96,59 @@ def test_the_gate_holds_the_servers_logits_to_the_reference(off, passes):
     served = want + np.float32(off)            # a constant shift: error = off / std = off
     problems = serve_open_loop._gate(
         _GateHandle(served, want),
-        {"gate_prompt_tokens": 40, "gate_new_tokens": 3, "block_size": 16}, 5,
-        {"vocab_size": 256},
+        {"gate_prompt_tokens": 40, "gate_new_tokens": 3, "block_size": 16,
+         "vocab_size": 256}, 5,
         {"module": "gptj_reference", "program_layer_norm_epsilon": 1e-6,
          "max_logits_error": 0.05},
     )
     assert (problems == []) == passes
     assert passes or "over the limit 0.05" in problems[0]
+
+
+def test_counters_are_the_deltas_of_every_number_the_engine_reports():
+    before = {
+        "steps": 10, "queue_s": 0.5, "phase_s": {"step": 1.0, "fetch": 0.5}, "new_later": 1,
+        "device": {"kind": "cpu", "peak_bytes_in_use": 100}, "adapters_resident": [],
+        "slowest_step": None, "prefix_hits": 0, "flag": True,
+    }
+    after = {
+        "steps": 25, "queue_s": 0.75, "phase_s": {"step": 2.5, "fetch": 1.0, "moe": 0.1},
+        "new_later": 4, "device": {"kind": "cpu", "peak_bytes_in_use": 130},
+        "adapters_resident": ["x"], "slowest_step": {"wall_s": 0.1}, "prefix_hits": 0,
+        "flag": True, "only_after": 3,
+    }
+    assert serve_open_loop.counter_deltas(after, before) == {
+        "steps": 15, "queue_s": 0.25, "phase_s": {"step": 1.5, "fetch": 0.5}, "new_later": 3,
+        "device": {"peak_bytes_in_use": 30}, "prefix_hits": 0,
+    }
+
+
+def test_the_server_takes_the_engines_own_warm_up_where_it_has_one(monkeypatch):
+    """Until ``LLMEngine`` owns its warm-up the benchmark builds ``extend``'s
+    arguments itself; an engine with a ``warm()`` is asked instead."""
+    from ray_tpu.serve import llm
+
+    from benchmark import server
+
+    tiny = bench_helpers.tiny("gptj")
+    engine = next(c["engine"] for c in tiny["cells"] if "engine" in c)
+    replica = server.BenchLLMServer(
+        "benchmark.models.gptj", "benchmark.reference.gptj_reference",
+        {**tiny["model"], "reference": tiny["reference"]}, seed=3, **engine,
+    )
+    warm = replica.warm()
+    # lanes (1, 4) x tokens (1, 8, 32) x cache (128)
+    assert warm["shapes"] == 6 and warm["warm_s"] > 0 and warm["weights_s"] > 0
+    assert warm["vocab_size"] == tiny["model"]["vocab_size"] and "depth 2" in warm["model"]
+    assert warm["compiled"] is None or warm["compiled"]["shape"] == [4, 32, 128]
+    monkeypatch.setattr(
+        llm.LLMEngine, "warm", lambda self: {"shapes": 99, "warm_s": 0.25, "compiled": None},
+        raising=False,
+    )
+    monkeypatch.setattr(replica._engine, "_extend", None)             # not called
+    own = replica.warm()
+    assert (own["shapes"], own["warm_s"], own["compiled"]) == (99, 0.25, None)
+    assert own["weights_s"] == warm["weights_s"] and own["vocab_size"] == warm["vocab_size"]
+    # the reference reads the very weights the engine serves
+    logits = replica.reference_logits([1, 2, 3, 4, 5], 2)
+    assert logits.shape == (2, tiny["model"]["vocab_size"])
