@@ -73,6 +73,25 @@ class GPTConfig:
     def qkv_dim(self) -> int:
         return self.num_heads * self.head_dim
 
+    # -- what the serving engine asks of an architecture's configuration
+    # (``serve/llm.py``): the heads its cache stores, its ``extend`` and its
+    # seeded weights; ``models/cohere2_moe.py`` answers the same three --------
+
+    @property
+    def kv_heads(self) -> int:
+        """K/V heads a cache stores: one per query head."""
+        return self.num_heads
+
+    def make_extend_fn(self):
+        return make_extend_fn(self)
+
+    def init_params(self, seed: int = 0):
+        """Deterministically initialized, unboxed params: every replica builds
+        bitwise-identical base weights from the same seed."""
+        variables = GPT(self).init(
+            jax.random.PRNGKey(seed), jnp.zeros((1, 8), jnp.int32))
+        return unboxed_params(variables)
+
     def num_params(self) -> int:
         """Exact parameter count (for MFU math)."""
         d, h, hd, f, v = (
@@ -125,13 +144,15 @@ def gpt_j_6b(**kw) -> GPTConfig:
 # ---------------------------------------------------------------------------
 
 
-def _rotary(x: jax.Array, positions: jax.Array, rotary_dim: int) -> jax.Array:
-    """Apply RoPE to the first ``rotary_dim`` features of [b, t, h, d]."""
+def _rotary(x: jax.Array, positions: jax.Array, rotary_dim: int,
+            base: float = 10000.0) -> jax.Array:
+    """Apply RoPE of frequency base ``base`` to the first ``rotary_dim``
+    features of [b, t, h, d]."""
     if rotary_dim <= 0:
         return x
     rot, keep = x[..., :rotary_dim], x[..., rotary_dim:]
     half = rotary_dim // 2
-    freqs = 1.0 / (10000.0 ** (jnp.arange(half, dtype=jnp.float32) / half))
+    freqs = 1.0 / (base ** (jnp.arange(half, dtype=jnp.float32) / half))
     angles = positions[:, :, None].astype(jnp.float32) * freqs  # [b, t, half]
     sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
     cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)

@@ -9,6 +9,16 @@ inserts the all-to-all, and a load-balancing auxiliary loss sown into the
 
 Expert weights carry the ("expert", "embed", "mlp") logical axes: ep shards
 the expert dim, tp can still shard the mlp dim inside each expert.
+
+Beside it, for serving (``models/cohere2_moe.py``): a **dropless** layer for
+one chip's share of an expert-parallel deployment. :func:`sigmoid_top_k`
+scores every routed expert, :func:`held_experts_ffn` is told which experts
+live here and computes their part of the result for the tokens routed to
+them: the token-expert pairs are sorted by expert and run through a grouped
+matmul (:func:`grouped_matmul`: one kernel that walks the groups and reads an
+expert's weights only if it has rows). No capacity, no dropped token; shapes
+are static from the worst case, in which every choice of every token is held
+here. What the absent experts would add is left out.
 """
 
 from __future__ import annotations
@@ -19,6 +29,103 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+
+def sigmoid_top_k(h: jax.Array, router: jax.Array, k: int):
+    """Route tokens ``h`` [n, d] over every expert the ``router`` [d, R]
+    scores: sigmoid scores in float32 (at the highest matmul precision: the
+    TPU's default would round ``h`` and the router to bfloat16 and move the
+    k-th choice), the ``k`` largest, normalised over the chosen ``k``.
+    Returns ``(weights [n, k] float32, experts [n, k] int32)``."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        h.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    top, experts = jax.lax.top_k(scores, k)
+    return top / top.sum(-1, keepdims=True), experts.astype(jnp.int32)
+
+
+#: (rows, contraction, columns) tile of the TPU's grouped matmul. Measured on a
+#: v5e at Command A+'s widths, one layer's 16 held experts (PERF.md, PR 28):
+#: 2.7 ms for a 256-token chunk and 1.6 ms for 8 decode tokens, where
+#: ``jax.lax.ragged_dot``'s own kernel took 5.3 and 1.8 ms; row tiles of 16
+#: lose at 256 tokens (4.4 ms), of 256 and more too (3.4 to 4.8 ms).
+GMM_TILING = (32, 4096, 512)
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def grouped_matmul(rows, w, group_sizes, interpret: bool = False):
+    """``rows`` [m, k], sorted by group, times ``w`` [groups, k, n]: row ``i``
+    is multiplied with the matrix of its group, the first ``group_sizes[0]``
+    rows with ``w[0]`` and so on; rows past the last group are undefined. On
+    the TPU this is the megablox kernel (``jax.experimental``), which visits
+    only the row tiles of groups that have rows; elsewhere XLA's ragged dot."""
+    if not (_on_tpu() or interpret):
+        return jax.lax.ragged_dot(rows, w, group_sizes)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    m, (tm, tk, tn) = rows.shape[0], GMM_TILING
+    padded = jnp.pad(rows, ((0, -m % tm), (0, 0)))
+    out = gmm(
+        padded, w, group_sizes, preferred_element_type=rows.dtype,
+        tiling=(tm, min(tk, w.shape[1]), min(tn, w.shape[2])), interpret=interpret)
+    return out[:m]
+
+
+def held_experts_ffn(x, weights, experts, valid, wi, wo, offset: int = 0, layer=None):
+    """The part of a routed expert layer that the experts held here give.
+
+    ``x`` [n, d] are the tokens, ``weights`` / ``experts`` [n, k] what
+    :func:`sigmoid_top_k` chose for each, ``valid`` [n] marks real tokens
+    (padding computes nothing and reads no expert). ``wi`` [E, d, 2f] holds,
+    for each of the ``E`` experts ``offset .. offset + E - 1``, the gate and
+    the up projection side by side, ``wo`` [E, f, d] the down projection; an
+    expert is ``wo(silu(gate x) * up x)``. Returns ``(y [n, d] float32,
+    counters int32 [4])``: the weighted sum over each token's held choices,
+    and (real tokens, token-expert pairs computed here, held experts with at
+    least one token, the busiest held expert's pairs).
+
+    With ``layer`` (an index, traced or not) ``wi`` and ``wo`` are a stack of
+    layers ``[L, E, ...]`` of which that one is used **in place**: the grouped
+    matmul gets the whole stack as ``L x E`` groups, all empty but this
+    layer's. Sliced out of the stack, as a scan over layers would hand them
+    over, the TPU compiler copies the layer's experts (1.6 GB at Command A+'s
+    widths) on every call, because the kernel wants a whole operand.
+
+    Every pair whose expert is held is computed: the pairs are sorted by
+    expert, those of absent experts and of padding last and in no group, so
+    the grouped matmul neither computes them nor reads weights for them."""
+    n, k = experts.shape
+    num_held, f = wo.shape[-3], wo.shape[-2]
+    local = experts - offset
+    held = (local >= 0) & (local < num_held) & valid[:, None]
+    key = jnp.where(held, local, num_held).reshape(n * k)
+    order = jnp.argsort(key, stable=True)
+    group_sizes = (
+        key[:, None] == jnp.arange(num_held, dtype=key.dtype)[None, :]
+    ).sum(0, dtype=jnp.int32)
+    counted = group_sizes
+    if layer is not None:
+        group_sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((wi.shape[0] * num_held,), jnp.int32), group_sizes,
+            (layer * num_held,))
+        wi, wo = (w.reshape((-1,) + w.shape[2:]) for w in (wi, wo))
+    rows = x[order // k]                                         # [n k, d]
+    gate_up = grouped_matmul(rows, wi.astype(x.dtype), group_sizes)
+    act = jax.nn.silu(gate_up[:, :f]) * gate_up[:, f:]
+    out = grouped_matmul(act, wo.astype(x.dtype), group_sizes)
+    # back to (token, choice) order; rows of no group hold nothing defined
+    place = jnp.zeros_like(order).at[order].set(jnp.arange(n * k, dtype=order.dtype))
+    per_pair = jnp.where(
+        held[:, :, None], out[place].reshape(n, k, -1).astype(jnp.float32), 0.0)
+    y = jnp.einsum("nk,nkd->nd", jnp.where(held, weights, 0.0), per_pair)
+    counters = jnp.stack([
+        valid.sum(dtype=jnp.int32), counted.sum(dtype=jnp.int32),
+        (counted > 0).sum(dtype=jnp.int32), counted.max(),
+    ])
+    return y, counters
 
 
 class MoeMlp(nn.Module):

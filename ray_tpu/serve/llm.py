@@ -1,6 +1,9 @@
 """LLM inference engine on the serve plane: paged KV-cache, prefill/decode
-split, prefix caching, and LoRA-scale multiplexing over the real
-``ray_tpu.models.gpt`` forward pass.
+split, prefix caching, and LoRA-scale multiplexing over a real model's
+forward pass: ``ray_tpu.models.gpt``, or any architecture whose configuration
+answers what the engine asks of it (``make_extend_fn()``, ``init_params(seed)``
+and ``kv_heads``, beside the sizes ``num_layers``, ``head_dim``, ``embed_dim``,
+``vocab_size``, ``max_seq_len`` and ``dtype``; ``models/cohere2_moe.py``).
 
 What PR 9 proved with synthetic step functions (continuous batching,
 admission control, multiplexing) this module composes on an actual model
@@ -77,20 +80,12 @@ class NoKVBlocksError(RuntimeError):
 
 
 def make_params(cfg=None, seed: int = 0):
-    """Deterministically initialized, unboxed gpt params for ``cfg``
+    """Deterministically initialized params of ``cfg``'s architecture
     (default ``gpt_nano``) — every replica builds bitwise-identical base
     weights from the same seed."""
-    import jax
-    import jax.numpy as jnp
-
     from ray_tpu.models import gpt
 
-    cfg = cfg or gpt.gpt_nano()
-    model = gpt.GPT(cfg)
-    variables = model.init(
-        jax.random.PRNGKey(seed), jnp.zeros((1, 8), jnp.int32)
-    )
-    return gpt.unboxed_params(variables)
+    return (cfg or gpt.gpt_nano()).init_params(seed)
 
 
 # ---------------------------------------------------------------------------
@@ -178,7 +173,9 @@ class KVBlockPool:
     live on the device.
 
     Layout: ``k_data``/``v_data`` are device arrays ``[layers, num_blocks,
-    block_size, heads, head_dim]`` in the model's dtype; a sequence owns an
+    block_size, kv_heads, head_dim]`` in the model's dtype (``kv_heads`` is
+    the configuration's: the heads a cache stores, fewer than the query heads
+    where attention is grouped); a sequence owns an
     ordered list of block ids whose concatenation is its cache, so the
     blocks a table names, side by side, are the padded pair ``extend``
     takes. Blocks are refcounted so the prefix cache can share full prompt
@@ -200,7 +197,7 @@ class KVBlockPool:
         self.dtype = jnp.dtype(jnp.float32 if cfg.dtype is None else cfg.dtype)
         shape = (
             cfg.num_layers, self.num_blocks, self.block_size,
-            cfg.num_heads, cfg.head_dim,
+            cfg.kv_heads, cfg.head_dim,
         )
         try:
             self.k_data = jnp.zeros(shape, self.dtype)
@@ -211,7 +208,7 @@ class KVBlockPool:
             raise MemoryError(
                 f"the KV pool does not fit on the device: {self.num_blocks} "
                 f"blocks of {self.block_size} tokens x {cfg.num_layers} layers "
-                f"x {cfg.num_heads} heads x {cfg.head_dim} in {self.dtype} are "
+                f"x {cfg.kv_heads} K/V heads x {cfg.head_dim} in {self.dtype} are "
                 f"{each} bytes an arena, {2 * each} for K and V, beside "
                 f"{accelerator.device_report()}: {e!r}"
             ) from e
@@ -266,7 +263,7 @@ class KVBlockPool:
             for cap in cache_buckets:
                 jax.block_until_ready(self.gather(
                     np.zeros((b, cap // self.block_size), np.int32)))
-        for (b, tc), (logits, hidden, k_new, _) in extend_shapes.items():
+        for (b, tc), (logits, hidden, k_new, *_) in extend_shapes.items():
             new = jnp.zeros(k_new.shape, k_new.dtype)
             outputs = tuple(jnp.zeros(o.shape, o.dtype) for o in (logits, hidden))
             rows, count, last = jax.device_put((
@@ -530,6 +527,11 @@ LEAF_PHASES = (
     "admit", "kv_gather", "upload", "dispatch", "kv_scatter", "fetch", "sample",
 )
 PHASES = ("step", "prefill", "decode") + LEAF_PHASES
+#: what an expert layer counts over the real tokens of a device call, in the
+#: order ``moe.held_experts_ffn`` hands them over: tokens, token-expert pairs
+#: computed here, held experts with at least one token, the busiest held
+#: expert's pairs; each summed over the expert layers
+MOE_COUNTERS = ("moe_tokens", "moe_assignments", "moe_experts_hit", "moe_load_max")
 #: what one ``_phase`` may cost outside a profiler session, where its span is
 #: a no-op (2.7 us on the sandbox's CPU): under 0.3 ms for the <= 30 phases of
 #: a step. ``tests/test_llm_spans.py`` holds the engine to it.
@@ -559,7 +561,7 @@ class LLMEngine:
         self.cfg = cfg or gpt.gpt_nano()
         self._params = params if params is not None else make_params(
             self.cfg, seed)
-        self._extend = gpt.make_extend_fn(self.cfg)
+        self._extend = self.cfg.make_extend_fn()
         self.deployment = deployment
         self.block_size = int(block_size)
         self.prefill_chunk = int(prefill_chunk)
@@ -599,6 +601,17 @@ class LLMEngine:
         self.d2h_bytes = 0
         self.lanes_used = 0             # real lanes of the device calls
         self.lane_slots = 0             # their lane buckets
+        # what an expert layer counted, summed over the device calls (0 for
+        # a model without one)
+        self.moe: Dict[str, int] = dict.fromkeys(MOE_COUNTERS, 0)
+        # cache slots gathered for layers whose queries see a window only
+        # (``cfg.sliding_window``, ``cfg.sliding_layers``; 0 without them), and
+        # those of them that hold a token too old for any query of the call to
+        # see: what a window-aware allocator would neither keep nor gather
+        self.window_slots = 0
+        self.window_slots_outside = 0
+        self._window = getattr(self.cfg, "sliding_window", None)
+        self._window_layers = sum(getattr(self.cfg, "sliding_layers", ()))
         self.phase_s: Dict[str, float] = dict.fromkeys(PHASES, 0.0)
         self.phase_n: Dict[str, int] = dict.fromkeys(PHASES, 0)
         #: the slowest step since ``stats()`` was last read (so two reads
@@ -617,19 +630,62 @@ class LLMEngine:
         cfg = self.cfg
 
         def extend_outputs(b, tc):
-            kv = jax.ShapeDtypeStruct(
-                (cfg.num_layers, b, self.cache_buckets[0], cfg.num_heads,
-                 cfg.head_dim), self.pool.dtype)
             return jax.eval_shape(
-                self._extend, self._params,
-                jax.ShapeDtypeStruct((b, tc), np.int32),
-                jax.ShapeDtypeStruct((b,), np.int32), kv, kv)
+                self._extend, *self._extend_args(
+                    jax.ShapeDtypeStruct, b, tc, self.cache_buckets[0]))
 
         self.pool.warm({
             (b, tc): extend_outputs(b, tc)
             for b in self.lane_buckets
             for tc in [1] + self.prefill_token_buckets
         }, self.cache_buckets)
+
+    def _extend_args(self, make, b: int, tc: int, cap: int):
+        """``extend``'s arguments for ``b`` lanes, ``tc`` tokens and a cache of
+        ``cap``, each made by ``make(shape, dtype)``."""
+        cfg = self.cfg
+        kv = make(
+            (cfg.num_layers, b, cap, cfg.kv_heads, cfg.head_dim), self.pool.dtype)
+        return (
+            self._params, make((b, tc), np.int32), make((b,), np.int32), kv, kv)
+
+    def warm(self) -> Dict[str, Any]:
+        """Run ``extend`` once in every shape the buckets allow, on zeros made
+        on the device, so that no request meets a compile: a decode call (one
+        token) in every lane bucket, a prefill call in every lane bucket that
+        ``prefill_lanes`` can fill, each over every cache bucket. Returns how
+        many ``shapes``, the seconds it took (``warm_s``) and, where the
+        compiler says, the bytes of the largest one (``compiled``)."""
+        import jax
+        import jax.numpy as jnp
+
+        t0 = time.perf_counter()
+        prefill_b = batching.bucket_pad_size(self.prefill_lanes, self.lane_buckets)
+        shapes = [
+            (b, tc, cap)
+            for b in self.lane_buckets
+            for tc in [1] + self.prefill_token_buckets
+            for cap in self.cache_buckets
+            if tc == 1 or b <= prefill_b
+        ]
+        for shape in shapes:
+            jax.block_until_ready(
+                self._extend(*self._extend_args(jnp.zeros, *shape)))
+        warm_s = time.perf_counter() - t0
+        largest = max(shapes, key=lambda s: (s[0] * s[2], s[1]))
+        memory = self._extend.lower(
+            *self._extend_args(jax.ShapeDtypeStruct, *largest)
+        ).compile().memory_analysis()
+        return {
+            "shapes": len(shapes), "warm_s": warm_s,
+            "compiled": None if memory is None else {
+                "shape": list(largest),
+                "argument_bytes": memory.argument_size_in_bytes,
+                "temp_bytes": memory.temp_size_in_bytes,
+                "output_bytes": memory.output_size_in_bytes,
+                "alias_bytes": memory.alias_size_in_bytes,
+            },
+        }
 
     # -- public stats ------------------------------------------------------
 
@@ -658,6 +714,9 @@ class LLMEngine:
             "d2h_bytes": self.d2h_bytes,
             "lanes_used": self.lanes_used,
             "lane_slots": self.lane_slots,
+            **self.moe,
+            "window_slots": self.window_slots,
+            "window_slots_outside": self.window_slots_outside,
             "phase_s": dict(self.phase_s),
             "phase_n": dict(self.phase_n),
             "slowest_step": slowest,
@@ -886,7 +945,9 @@ class LLMEngine:
             st.length + len(ch) for st, ch in zip(states, token_chunks))
         t_cap = batching.bucket_pad_size(t_max, self.cache_buckets)
         with self._phase("kv_gather"):
-            tokens = np.zeros((b, tc), np.int32)
+            # a negative id is padding: a model may skip it (an expert
+            # layer does), and none may let it change a real token
+            tokens = np.full((b, tc), -1, np.int32)
             lengths = np.zeros((b,), np.int32)
             # padding names block 0: whatever it holds lies past a frontier
             table = np.zeros((b, t_cap // bs), np.int32)
@@ -912,12 +973,18 @@ class LLMEngine:
             k_cache, v_cache = self.pool.gather(table)
             self.cache_tokens += sum(st.length for st in states)
             self.cache_slots += b * t_cap
+            if self._window_layers:
+                # live tokens older than the oldest position the lane's first
+                # query sees, length - window + 1
+                self.window_slots += self._window_layers * b * t_cap
+                self.window_slots_outside += self._window_layers * sum(
+                    max(0, st.length - self._window + 1) for st in states)
         with self._phase("upload"):
             small = (tokens, lengths, rows, slots, np.int32(fed), last)
             self.h2d_bytes += table.nbytes + sum(a.nbytes for a in small)
             tokens, lengths, rows, slots, count, last = jax.device_put(small)
         with self._phase("dispatch"):
-            logits, hidden, k_new, v_new = self._extend(
+            logits, hidden, k_new, v_new, *counted = self._extend(
                 self._params, tokens, lengths, k_cache, v_cache)
             del k_cache, v_cache    # the pair is freed when extend has run
             self.lanes_used += len(states)
@@ -930,6 +997,11 @@ class LLMEngine:
             # waits for the device, then copies the sampled rows home
             picked = tuple(np.asarray(r) for r in picked)
             self.d2h_bytes += sum(r.nbytes for r in picked)
+            for counters in counted:    # an expert layer's ride home too
+                counters = np.asarray(counters)
+                self.d2h_bytes += counters.nbytes
+                for name, n in zip(MOE_COUNTERS, counters):
+                    self.moe[name] += int(n)
         return picked
 
     # -- sampling / completion --------------------------------------------

@@ -147,6 +147,15 @@ def say_split(d, label, say=print):
     say(f"     lane_fill {d['lanes_used']}/{d['lane_slots']} = "
         f"{d['lanes_used'] / max(d['lane_slots'], 1):.3f}; cache_fill {d['cache_tokens']}/"
         f"{d['cache_slots']} = {d['cache_tokens'] / max(d['cache_slots'], 1):.3f}")
+    if d.get("moe_tokens"):             # an expert layer's counters (PR 28)
+        say(f"     experts: {d['moe_assignments']} pairs of {d['moe_tokens']} token-layers "
+            f"computed here ({d['moe_assignments'] / d['moe_tokens']:.3f} a token), "
+            f"{d['moe_experts_hit'] / calls:.2f} held experts hit a call (summed over layers), "
+            f"busiest expert {d['moe_load_max'] / max(d['moe_assignments'], 1):.3f} of the pairs")
+    if d.get("window_slots"):
+        say(f"     window: {d['window_slots_outside']}/{d['window_slots']} = "
+            f"{d['window_slots_outside'] / d['window_slots']:.3f} of the slots gathered for "
+            f"sliding layers hold a token no query of the call could see")
     say(f"     admitted {d['admitted']}, queue_s mean {d['queue_s'] / max(d['admitted'], 1):.4f}; "
         f"prefill {d['prefill_tokens']} tokens in {n['prefill']} calls; decode "
         f"{d['decode_tokens']} tokens in {n['decode']} calls")

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.models import gpt
+from ray_tpu.models import cohere2_moe, gpt
 from ray_tpu.models.training import (
     abstract_state,
     default_optimizer,
@@ -139,6 +139,33 @@ def test_gptj_full_depth_extend_compiles(shaped, lanes, tc):
     """The server's step at full depth 28 in bf16, over a 1024-token cache:
     the weights alone are 11.3 GiB of the chip's 15.75."""
     assert _device_bytes(_extend_at(_gptj(28), shaped, lanes, tc, 1024)) < HBM_BYTES
+
+
+@pytest.mark.parametrize(
+    "lanes,tc,cap", [(8, 1, 8192), (2, 256, 8192)], ids=["decode", "prefill"])
+def test_command_a_plus_share_extend_compiles_and_copies_no_expert(
+    shaped, lanes, tc, cap, monkeypatch
+):
+    """The served share of Command A+ at its published widths (one period, 16
+    of 128 experts, an eighth of the vocabulary: 9.47 GB of weights) over the
+    largest cache bucket: it fits beside a 0.8 GB pool, and its temporaries stay
+    under a layer's routed experts (1.6 GB), which a scan that sliced them out
+    of the stack copied on every call (``moe.held_experts_ffn``, ``layer``)."""
+    from ray_tpu.models import moe
+
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)     # the chip's grouped matmul
+    cfg = cohere2_moe.Cohere2MoeConfig(vocab_size=32768, num_layers=4, num_experts=16)
+    params = jax.tree.map(
+        lambda x: shaped(x.shape, x.dtype), jax.eval_shape(lambda: cfg.init_params(0)))
+    cache = shaped((cfg.num_layers, lanes, cap, cfg.kv_heads, cfg.head_dim), cfg.dtype)
+    compiled = cfg.make_extend_fn().lower(
+        params, shaped((lanes, tc), jnp.int32), shaped((lanes,), jnp.int32), cache, cache
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2      # the kernel is there
+    memory = compiled.memory_analysis()
+    assert 9.4e9 < memory.argument_size_in_bytes < 10.6e9
+    assert memory.temp_size_in_bytes < 1.0e9
+    assert _device_bytes(compiled) + 2 * 0.41e9 < HBM_BYTES
 
 
 def test_gptj_serve_paging_programs_compile_beside_the_weights(shaped):
