@@ -17,12 +17,16 @@ serving setup from PAPERS.md):
   enforces for concurrency slots). ``ray_tpu_llm_kv_blocks_in_use`` tracks
   the pool; exhaustion sheds with :class:`~ray_tpu.serve.handle.
   BackPressureError` *before* anything is written.
-* a device call never moves K/V through the host: the host sends a block
-  table, tokens and lengths (a few KB); one jitted program gathers the
-  padded pair ``extend`` takes from the arenas, ``extend`` runs, and one
-  donating program pages the new K/V back into the arenas and picks each
-  lane's last valid row of logits and hidden, which is all that comes
-  home. Every such program is compiled when the engine is built.
+* a device call never moves K/V through the host, and crosses the link
+  twice: everything the host decides for the call (tokens, lengths, block
+  table, page-back slots) goes up as one int32 buffer (:func:`_sections`);
+  one jitted program gathers the padded pair ``extend`` takes from the
+  arenas, ``extend`` runs, and one donating program pages the new K/V back
+  into the arenas, picks each lane's last valid row of logits and hidden
+  and takes the argmax of the logits row: the sampled ids (and an expert
+  layer's counters) are one int32 array, which is all that comes home. The
+  picked rows follow only for a lane that asked for its logits or has an
+  adapter. Every such program is compiled when the engine is built.
 * prefill/decode split — prefill runs as its own bucketed extend call
   (prompt chunks padded via :func:`~ray_tpu.serve.batching.
   bucket_pad_size`), decode as a tc=1 call; every engine iteration runs
@@ -93,6 +97,31 @@ def make_params(cfg=None, seed: int = 0):
 # ---------------------------------------------------------------------------
 
 
+#: A device call's small operands, one int32 buffer ``[lanes, width]``: a lane's
+#: row holds its cache length, the index of its last fed token and (lane 0
+#: alone) the count of tokens to page back, then four sections of one width
+#: (:func:`_sections`). The width is the engine's, whatever the call's buckets:
+#: a program is shaped by the lanes and by its own bucket, as it was.
+_LENGTH, _LAST, _COUNT, _SCALARS = 0, 1, 2, 3
+
+
+def _operand_width(tokens: int, blocks: int) -> int:
+    """The width of the operand buffer of an engine whose widest call feeds
+    ``tokens`` a lane over a cache of ``blocks`` blocks."""
+    return _SCALARS + 4 * max(tokens, blocks)
+
+
+def _sections(operands):
+    """``tokens``, ``rows``, ``slots``, ``table`` of an operand buffer (numpy
+    on the host: views to write through; traced on the device): the token ids
+    a lane is fed (-1 is padding), the page-back's token rows and arena slots
+    (flat over lanes x tokens, laid ``[lanes, tokens]``), the lane's block ids.
+    A call with ``tc`` tokens over ``n`` blocks uses ``[:, :tc]`` and ``[:, :n]``."""
+    each = (operands.shape[1] - _SCALARS) // 4
+    return tuple(
+        operands[:, _SCALARS + i * each:_SCALARS + (i + 1) * each] for i in range(4))
+
+
 @functools.lru_cache(maxsize=None)
 def _paging_programs():
     """The three jitted programs that touch a pool's arenas ``[layers,
@@ -110,12 +139,12 @@ def _paging_programs():
     # call: 1.17 GB at GPT-J's serve sizes, where 1 GB is free beside the
     # weights (``tests/test_chip_compile.py`` holds the programs to this).
 
-    @jax.jit
+    @functools.partial(jax.jit, static_argnums=3)
     @jax.named_scope("paging.gather")
-    def gather(k_data, v_data, table):
+    def gather(k_data, v_data, operands, n):
         layers, _, block, heads, hd = k_data.shape
-        b, n = table.shape
-        flat = table.reshape(-1)
+        b = operands.shape[0]
+        flat = _sections(operands)[3][:, :n].reshape(-1)
         # every block of the pair is written below; one buffer each, because
         # the compiler copies a value that starts both loop carries
         empty = functools.partial(
@@ -133,10 +162,12 @@ def _paging_programs():
 
     @functools.partial(jax.jit, donate_argnums=(0, 1))
     @jax.named_scope("paging.page_back")
-    def page_back(k_data, v_data, k_new, v_new, rows, slots, count, outputs, last):
+    def page_back(k_data, v_data, k_new, v_new, operands, outputs, counted):
         layers, blocks, block, heads, hd = k_data.shape
         b, tc = k_new.shape[1:3]
         news = tuple(x.reshape(layers, b * tc, heads, hd) for x in (k_new, v_new))
+        rows, slots = (x[:, :tc].reshape(-1) for x in _sections(operands)[1:3])
+        last = operands[:, _LAST]
 
         def write_token(i, arenas):
             return tuple(
@@ -145,16 +176,20 @@ def _paging_programs():
                     slots[i], axis=1)
                 for tokens, new in zip(arenas, news))
 
-        # ``count`` is traced: a loop the compiler cannot unroll, whatever the
+        # the count is traced: a loop the compiler cannot unroll, whatever the
         # shapes (unrolled at one token it re-lays both arenas out and back)
-        arenas = jax.lax.fori_loop(0, count, write_token, tuple(
+        arenas = jax.lax.fori_loop(0, operands[0, _COUNT], write_token, tuple(
             a.reshape(layers, blocks * block, heads, hd) for a in (k_data, v_data)))
         picked = tuple(
             jnp.stack([
                 jax.lax.dynamic_index_in_dim(o[i], last[i], 0, keepdims=False)
                 for i in range(b)
             ]) for o in outputs)
-        return tuple(a.reshape(k_data.shape) for a in arenas) + (picked,)
+        with jax.named_scope("paging.sample"):
+            # greedy, as ``np.argmax`` is: the first of equal maxima
+            ids = jnp.argmax(picked[0], axis=-1).astype(jnp.int32)
+            home = jnp.concatenate([ids, *(c.astype(jnp.int32) for c in counted)])
+        return tuple(a.reshape(k_data.shape) for a in arenas) + (home, picked)
 
     @functools.partial(jax.jit, donate_argnums=(0, 1))
     @jax.named_scope("paging.clone")
@@ -220,24 +255,27 @@ class KVBlockPool:
 
     # -- the arenas: device programs only ----------------------------------
 
-    def gather(self, table):
+    def gather(self, operands, n: int):
         """The padded pair ``[layers, b, n * block_size, heads, head_dim]`` of
-        the lanes whose block ids are the rows of ``table`` ``[b, n]`` (int32,
-        every entry a valid block), built on the device."""
-        return _paging_programs().gather(self.k_data, self.v_data, table)
+        the lanes whose block ids are the first ``n`` of each row of the
+        ``table`` section of ``operands`` (a device array ``[b, width]``,
+        :func:`_sections`; every entry a valid block), built on the device."""
+        return _paging_programs().gather(self.k_data, self.v_data, operands, n)
 
-    def page_back(self, k_new, v_new, rows, slots, count, outputs, last):
+    def page_back(self, k_new, v_new, operands, outputs, counted):
         """Write token ``rows[i]`` (an index into lanes x tokens) of ``k_new``
         / ``v_new`` ``[layers, b, tc, heads, head_dim]`` into the arenas at
         token slot ``slots[i]`` (block x block_size + offset) for the first
-        ``count`` entries of ``rows`` / ``slots`` ``[b * tc]`` and, in the
-        same program, pick row ``last[i]`` of lane ``i`` from each of
-        ``outputs`` ``[b, tc, ...]``. The arenas are donated: nothing is
-        copied but the new rows."""
-        self.k_data, self.v_data, picked = _paging_programs().page_back(
-            self.k_data, self.v_data, k_new, v_new, rows, slots, count,
-            outputs, last)
-        return picked
+        ``count`` entries of the ``rows`` / ``slots`` sections of ``operands``
+        and, in the same program, pick row ``last[i]`` of lane ``i`` from each
+        of ``outputs`` ``[b, tc, ...]`` (logits first) and sample it. Returns
+        one int32 array, the ``b`` greedy ids followed by the int32 arrays of
+        ``counted``, and the picked rows, all on the device. The arenas are
+        donated: nothing is copied but the new rows."""
+        self.k_data, self.v_data, home, picked = _paging_programs().page_back(
+            self.k_data, self.v_data, k_new, v_new, operands, outputs,
+            tuple(counted))
+        return home, picked
 
     def clone_block(self, src: int, dst: int) -> None:
         """Copy block ``src`` onto block ``dst``, on the device."""
@@ -259,18 +297,20 @@ class KVBlockPool:
         import jax
         import jax.numpy as jnp
 
+        width = _operand_width(
+            max(tc for _, tc in extend_shapes),
+            max(cache_buckets) // self.block_size)
         for b in sorted({b for b, _ in extend_shapes}):
+            operands = jnp.zeros((b, width), jnp.int32)
             for cap in cache_buckets:
-                jax.block_until_ready(self.gather(
-                    np.zeros((b, cap // self.block_size), np.int32)))
-        for (b, tc), (logits, hidden, k_new, *_) in extend_shapes.items():
+                jax.block_until_ready(
+                    self.gather(operands, cap // self.block_size))
+        for (b, tc), (logits, hidden, k_new, _, *counted) in extend_shapes.items():
             new = jnp.zeros(k_new.shape, k_new.dtype)
-            outputs = tuple(jnp.zeros(o.shape, o.dtype) for o in (logits, hidden))
-            rows, count, last = jax.device_put((
-                np.zeros((b * tc,), np.int32), np.int32(0), np.zeros((b,), np.int32),
-            ))
-            jax.block_until_ready(
-                self.page_back(new, new, rows, rows, count, outputs, last))
+            jax.block_until_ready(self.page_back(
+                new, new, jnp.zeros((b, width), jnp.int32),
+                tuple(jnp.zeros(o.shape, o.dtype) for o in (logits, hidden)),
+                tuple(jnp.zeros(c.shape, c.dtype) for c in counted)))
         self.clone_block(0, 0)
         jax.block_until_ready((self.k_data, self.v_data))
 
@@ -510,6 +550,21 @@ def _fetch_lora(model_id: str):
 # ---------------------------------------------------------------------------
 
 
+def _operand_extend(extend):
+    """``extend`` as a step calls it: the tokens and lengths are read on the
+    device from the call's operand buffer (:func:`_sections`), ``tc`` tokens a
+    lane. One program per (lanes, tokens, cache), as ``extend`` alone has."""
+    import jax
+
+    @functools.partial(jax.jit, static_argnames="tc")
+    def extend_call(params, operands, k_cache, v_cache, *, tc):
+        return extend(
+            params, _sections(operands)[0][:, :tc], operands[:, _LENGTH],
+            k_cache, v_cache)
+
+    return extend_call
+
+
 class _SeqState:
     __slots__ = (
         "prompt", "max_new", "eos", "model_id", "adapter", "lease", "blocks",
@@ -524,7 +579,7 @@ class _SeqState:
 #: ``prefill`` and ``decode``; those two hold the six phases of a device call.
 #: The leaves partition a step: what is in none of them is a missing phase.
 LEAF_PHASES = (
-    "admit", "kv_gather", "upload", "dispatch", "kv_scatter", "fetch", "sample",
+    "admit", "upload", "kv_gather", "dispatch", "kv_scatter", "fetch", "sample",
 )
 PHASES = ("step", "prefill", "decode") + LEAF_PHASES
 #: what an expert layer counts over the real tokens of a device call, in the
@@ -562,6 +617,7 @@ class LLMEngine:
         self._params = params if params is not None else make_params(
             self.cfg, seed)
         self._extend = self.cfg.make_extend_fn()
+        self._extend_call = _operand_extend(self._extend)
         self.deployment = deployment
         self.block_size = int(block_size)
         self.prefill_chunk = int(prefill_chunk)
@@ -579,6 +635,9 @@ class LLMEngine:
             self.cfg, num_blocks=num_blocks, block_size=block_size,
             deployment=deployment,
         )
+        self._operand_width = _operand_width(
+            self.prefill_token_buckets[-1],
+            self.cache_buckets[-1] // self.block_size)
         self._warm_paging()
         self.prefix: Optional[PrefixCache] = (
             PrefixCache(self.pool, deployment) if prefix_caching else None
@@ -599,6 +658,9 @@ class LLMEngine:
         self.cache_slots = 0            # lanes x cache bucket of those caches
         self.h2d_bytes = 0
         self.d2h_bytes = 0
+        self.h2d_transfers = 0          # host -> device puts
+        self.d2h_transfers = 0          # blocking device -> host copies
+        self.ids_only_calls = 0         # calls that brought home the ids alone
         self.lanes_used = 0             # real lanes of the device calls
         self.lane_slots = 0             # their lane buckets
         # what an expert layer counted, summed over the device calls (0 for
@@ -627,12 +689,11 @@ class LLMEngine:
         page-back: the first call of each of its own shapes still compiles."""
         import jax
 
-        cfg = self.cfg
-
         def extend_outputs(b, tc):
             return jax.eval_shape(
-                self._extend, *self._extend_args(
-                    jax.ShapeDtypeStruct, b, tc, self.cache_buckets[0]))
+                functools.partial(self._extend_call, tc=tc),
+                *self._extend_args(
+                    jax.ShapeDtypeStruct, b, self.cache_buckets[0]))
 
         self.pool.warm({
             (b, tc): extend_outputs(b, tc)
@@ -640,41 +701,46 @@ class LLMEngine:
             for tc in [1] + self.prefill_token_buckets
         }, self.cache_buckets)
 
-    def _extend_args(self, make, b: int, tc: int, cap: int):
-        """``extend``'s arguments for ``b`` lanes, ``tc`` tokens and a cache of
+    def _extend_args(self, make, b: int, cap: int):
+        """The arrays ``_extend_call`` takes for ``b`` lanes and a cache of
         ``cap``, each made by ``make(shape, dtype)``."""
         cfg = self.cfg
         kv = make(
             (cfg.num_layers, b, cap, cfg.kv_heads, cfg.head_dim), self.pool.dtype)
-        return (
-            self._params, make((b, tc), np.int32), make((b,), np.int32), kv, kv)
+        return self._params, make((b, self._operand_width), np.int32), kv, kv
 
-    def warm(self) -> Dict[str, Any]:
-        """Run ``extend`` once in every shape the buckets allow, on zeros made
-        on the device, so that no request meets a compile: a decode call (one
-        token) in every lane bucket, a prefill call in every lane bucket that
-        ``prefill_lanes`` can fill, each over every cache bucket. Returns how
-        many ``shapes``, the seconds it took (``warm_s``) and, where the
-        compiler says, the bytes of the largest one (``compiled``)."""
-        import jax
-        import jax.numpy as jnp
-
-        t0 = time.perf_counter()
+    def extend_shapes(self) -> List[tuple]:
+        """Every (lanes, tokens, cache) a step can ask ``extend`` for, one
+        program each: a decode call (one token) in every lane bucket, a
+        prefill call in every lane bucket that ``prefill_lanes`` can fill, each
+        over every cache bucket."""
         prefill_b = batching.bucket_pad_size(self.prefill_lanes, self.lane_buckets)
-        shapes = [
+        return [
             (b, tc, cap)
             for b in self.lane_buckets
             for tc in [1] + self.prefill_token_buckets
             for cap in self.cache_buckets
             if tc == 1 or b <= prefill_b
         ]
-        for shape in shapes:
-            jax.block_until_ready(
-                self._extend(*self._extend_args(jnp.zeros, *shape)))
+
+    def warm(self) -> Dict[str, Any]:
+        """Run ``extend`` once in every shape of :meth:`extend_shapes`, as a
+        step calls it, on zeros made on the device, so that no request meets
+        a compile. Returns how many ``shapes``, the seconds it took
+        (``warm_s``) and, where the compiler says, the bytes of the largest one
+        (``compiled``)."""
+        import jax
+        import jax.numpy as jnp
+
+        t0 = time.perf_counter()
+        shapes = self.extend_shapes()
+        for b, tc, cap in shapes:
+            jax.block_until_ready(self._extend_call(
+                *self._extend_args(jnp.zeros, b, cap), tc=tc))
         warm_s = time.perf_counter() - t0
-        largest = max(shapes, key=lambda s: (s[0] * s[2], s[1]))
-        memory = self._extend.lower(
-            *self._extend_args(jax.ShapeDtypeStruct, *largest)
+        b, tc, cap = largest = max(shapes, key=lambda s: (s[0] * s[2], s[1]))
+        memory = self._extend_call.lower(
+            *self._extend_args(jax.ShapeDtypeStruct, b, cap), tc=tc
         ).compile().memory_analysis()
         return {
             "shapes": len(shapes), "warm_s": warm_s,
@@ -712,6 +778,9 @@ class LLMEngine:
             "cache_slots": self.cache_slots,
             "h2d_bytes": self.h2d_bytes,
             "d2h_bytes": self.d2h_bytes,
+            "h2d_transfers": self.h2d_transfers,
+            "d2h_transfers": self.d2h_transfers,
+            "ids_only_calls": self.ids_only_calls,
             "lanes_used": self.lanes_used,
             "lane_slots": self.lane_slots,
             **self.moe,
@@ -868,7 +937,7 @@ class LLMEngine:
             ]
             tc = batching.bucket_pad_size(
                 max(chunks), self.prefill_token_buckets)
-            logits, hidden = self._run_extend(
+            sampled = self._run_extend(
                 states, [st.prompt[st.pos:st.pos + c]
                          for st, c in zip(states, chunks)], tc)
             for st, c in zip(states, chunks):
@@ -888,7 +957,7 @@ class LLMEngine:
                         # cache every full prompt block (first writer wins)
                         self.prefix.insert(
                             st.hashes, st.blocks[:len(st.hashes)])
-                    self._emit(s, st, logits[i], hidden[i])
+                    self._emit(s, st, *sampled[i])
 
     def _decode_step(self, seqs) -> None:
         decoding = [
@@ -921,22 +990,26 @@ class LLMEngine:
         if not states:
             return
         sts = [st for _, st in states]
-        logits, hidden = self._run_extend(
+        sampled = self._run_extend(
             sts, [[st.last_token] for st in sts], 1)
         for st in sts:
             st.length += 1
         self.decode_tokens += len(sts)
         with self._phase("sample"):
             for i, (s, st) in enumerate(states):
-                self._emit(s, st, logits[i], hidden[i])
+                self._emit(s, st, *sampled[i])
 
     # -- device call + paging ---------------------------------------------
 
     def _run_extend(self, states, token_chunks, tc: int):
         """One device call: feed each lane its chunk over its paged cache,
-        page the new K/V back, and return the lanes' last valid rows of
-        logits ``[b, vocab]`` and hidden ``[b, embed]`` on the host. The pool,
-        the padded pair and ``extend``'s outputs never leave the device."""
+        page the new K/V back and sample each lane's last valid row, all on
+        the device. One buffer goes up and one int32 array comes home: the
+        greedy ids and, behind them, what an expert layer counted. Returns a
+        lane's ``(id, logits row, hidden row)``; the rows ``[vocab]`` and
+        ``[embed]`` are None unless a lane of the call needs them on the host:
+        one that returns its logits, or one whose adapter changes them (the
+        hidden rows come home for an adapter alone)."""
         import jax
 
         bs = self.block_size
@@ -944,33 +1017,40 @@ class LLMEngine:
         t_max = max(
             st.length + len(ch) for st, ch in zip(states, token_chunks))
         t_cap = batching.bucket_pad_size(t_max, self.cache_buckets)
-        with self._phase("kv_gather"):
+        with self._phase("upload"):
+            # zeros are padding: block 0, whatever it holds, lies past a
+            # frontier, and nothing is paged back past the count
+            operands = np.zeros((b, self._operand_width), np.int32)
+            tokens, rows, slots, table = _sections(operands)
             # a negative id is padding: a model may skip it (an expert
             # layer does), and none may let it change a real token
-            tokens = np.full((b, tc), -1, np.int32)
-            lengths = np.zeros((b,), np.int32)
-            # padding names block 0: whatever it holds lies past a frontier
-            table = np.zeros((b, t_cap // bs), np.int32)
+            tokens[:, :tc] = -1
             # page-back: token rows[i] of the call goes to arena slot slots[i]
-            rows = np.zeros((b * tc,), np.int32)
-            slots = np.zeros((b * tc,), np.int32)
-            last = np.zeros((b,), np.int32)
+            to_rows = np.zeros((b * tc,), np.int32)
+            to_slots = np.zeros((b * tc,), np.int32)
             fed = 0
             for i, (st, ch) in enumerate(zip(states, token_chunks)):
                 n = len(ch)
                 tokens[i, :n] = ch
-                lengths[i] = st.length
+                operands[i, _LENGTH] = st.length
+                operands[i, _LAST] = n - 1
                 blocks = np.asarray(
                     st.blocks[:math.ceil((st.length + n) / bs)], np.int32)
                 table[i, :len(blocks)] = blocks
                 pos = st.length + np.arange(n)
-                rows[fed:fed + n] = i * tc + np.arange(n)
-                slots[fed:fed + n] = blocks[pos // bs] * bs + pos % bs
-                last[i] = n - 1
+                to_rows[fed:fed + n] = i * tc + np.arange(n)
+                to_slots[fed:fed + n] = blocks[pos // bs] * bs + pos % bs
                 fed += n
+            rows[:, :tc] = to_rows.reshape(b, tc)
+            slots[:, :tc] = to_slots.reshape(b, tc)
+            operands[0, _COUNT] = fed
+            self.h2d_bytes += operands.nbytes
+            self.h2d_transfers += 1
+            operands = jax.device_put(operands)
+        with self._phase("kv_gather"):
             # slots past a lane's frontier hold what the pool holds there:
             # zeros or finite model output, which extend's mask weighs 0
-            k_cache, v_cache = self.pool.gather(table)
+            k_cache, v_cache = self.pool.gather(operands, t_cap // bs)
             self.cache_tokens += sum(st.length for st in states)
             self.cache_slots += b * t_cap
             if self._window_layers:
@@ -979,38 +1059,49 @@ class LLMEngine:
                 self.window_slots += self._window_layers * b * t_cap
                 self.window_slots_outside += self._window_layers * sum(
                     max(0, st.length - self._window + 1) for st in states)
-        with self._phase("upload"):
-            small = (tokens, lengths, rows, slots, np.int32(fed), last)
-            self.h2d_bytes += table.nbytes + sum(a.nbytes for a in small)
-            tokens, lengths, rows, slots, count, last = jax.device_put(small)
         with self._phase("dispatch"):
-            logits, hidden, k_new, v_new, *counted = self._extend(
-                self._params, tokens, lengths, k_cache, v_cache)
+            logits, hidden, k_new, v_new, *counted = self._extend_call(
+                self._params, operands, k_cache, v_cache, tc=tc)
             del k_cache, v_cache    # the pair is freed when extend has run
             self.lanes_used += len(states)
             self.lane_slots += b
         with self._phase("kv_scatter"):
-            picked = self.pool.page_back(
-                k_new, v_new, rows, slots, count, (logits, hidden), last)
+            home, picked = self.pool.page_back(
+                k_new, v_new, operands, (logits, hidden), counted)
             del logits, hidden, k_new, v_new
         with self._phase("fetch"):
-            # waits for the device, then copies the sampled rows home
-            picked = tuple(np.asarray(r) for r in picked)
-            self.d2h_bytes += sum(r.nbytes for r in picked)
-            for counters in counted:    # an expert layer's ride home too
-                counters = np.asarray(counters)
-                self.d2h_bytes += counters.nbytes
-                for name, n in zip(MOE_COUNTERS, counters):
-                    self.moe[name] += int(n)
-        return picked
+            # waits for the device; then the ids are home
+            home = np.asarray(home)
+            fetched = [home]
+            for name, n in zip(MOE_COUNTERS, home[b:]):     # an expert layer's
+                self.moe[name] += int(n)
+            adapted = any(st.adapter is not None for st in states)
+            logits = hidden = None
+            if adapted or any(st.return_logits for st in states):
+                logits = np.asarray(picked[0])
+                fetched.append(logits)
+                if adapted:
+                    hidden = np.asarray(picked[1])
+                    fetched.append(hidden)
+            else:
+                self.ids_only_calls += 1
+            self.d2h_transfers += len(fetched)
+            self.d2h_bytes += sum(a.nbytes for a in fetched)
+        return [
+            (int(home[i]), None if logits is None else logits[i],
+             None if hidden is None else hidden[i])
+            for i in range(len(states))
+        ]
 
     # -- sampling / completion --------------------------------------------
 
-    def _emit(self, s, st: _SeqState, logits_row, hidden_row) -> None:
+    def _emit(self, s, st: _SeqState, tok: int, logits_row, hidden_row) -> None:
+        """Lane ``st`` sampled ``tok`` on the device. The rows are there where
+        the call brought them home (``_run_extend``)."""
         if st.adapter is not None:
             a, bmat, scale = st.adapter
             logits_row = logits_row + scale * (hidden_row @ a) @ bmat
-        tok = int(np.argmax(logits_row))
+            tok = int(np.argmax(logits_row))    # the delta may move the maximum
         st.out.append(tok)
         st.last_token = tok
         if st.logits is not None:

@@ -19,7 +19,7 @@ import sys
 import threading
 import time
 
-LEAF = ("admit", "kv_gather", "upload", "dispatch", "kv_scatter", "fetch", "sample")
+LEAF = ("admit", "upload", "kv_gather", "dispatch", "kv_scatter", "fetch", "sample")
 NOT_COUNTERS = ("device", "compile_cache", "adapters_resident")
 
 
@@ -144,6 +144,10 @@ def say_split(d, label, say=print):
     say(f"     h2d {_size(d['h2d_bytes'])}: {_size(d['h2d_bytes'] / calls)} a call, "
         f"{_size(d['h2d_bytes'] / steps)} a step, {_size(d['h2d_bytes'] / decoded)} a decode "
         f"token; d2h {_size(d['d2h_bytes'] / steps)} a step (from array sizes)")
+    if "h2d_transfers" in d:            # crossings of the host-device link (PR 29)
+        say(f"     transfers a call: {d['h2d_transfers'] / calls:.2f} up, "
+            f"{d['d2h_transfers'] / calls:.2f} down; ids-only calls {d['ids_only_calls']}/"
+            f"{n['dispatch']} = {d['ids_only_calls'] / calls:.3f}")
     say(f"     lane_fill {d['lanes_used']}/{d['lane_slots']} = "
         f"{d['lanes_used'] / max(d['lane_slots'], 1):.3f}; cache_fill {d['cache_tokens']}/"
         f"{d['cache_slots']} = {d['cache_tokens'] / max(d['cache_slots'], 1):.3f}")

@@ -10,6 +10,7 @@ Code that asks ``jax.devices()`` sees the CPU here and would take its XLA
 path, so the tests steer it with ``attn_use_pallas=True``.
 """
 
+import dataclasses
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs under /tmp
@@ -113,7 +114,8 @@ def test_gptj_width_train_step_compiles(v5e, spec, n_devices):
 
 
 def _extend_at(cfg, shaped, lanes, tc, cap):
-    """``extend`` compiled for ``lanes`` x ``tc`` tokens over a ``cap`` cache."""
+    """``extend`` as a step calls it (tokens and lengths read from the call's
+    operand buffer), compiled for ``lanes`` x ``tc`` tokens over a ``cap`` cache."""
     params = jax.tree.map(
         lambda x: shaped(x.shape, x.dtype),
         jax.eval_shape(
@@ -123,9 +125,9 @@ def _extend_at(cfg, shaped, lanes, tc, cap):
         ),
     )
     cache = shaped((cfg.num_layers, lanes, cap, cfg.num_heads, cfg.head_dim), cfg.dtype)
-    return gpt.make_extend_fn(cfg).lower(
-        params, shaped((lanes, tc), jnp.int32), shaped((lanes,), jnp.int32), cache, cache
-    ).compile()
+    operands = shaped((lanes, llm._operand_width(tc, cap // 16)), jnp.int32)
+    return llm._operand_extend(gpt.make_extend_fn(cfg)).lower(
+        params, operands, cache, cache, tc=tc).compile()
 
 
 @pytest.fixture
@@ -158,9 +160,9 @@ def test_command_a_plus_share_extend_compiles_and_copies_no_expert(
     params = jax.tree.map(
         lambda x: shaped(x.shape, x.dtype), jax.eval_shape(lambda: cfg.init_params(0)))
     cache = shaped((cfg.num_layers, lanes, cap, cfg.kv_heads, cfg.head_dim), cfg.dtype)
-    compiled = cfg.make_extend_fn().lower(
-        params, shaped((lanes, tc), jnp.int32), shaped((lanes,), jnp.int32), cache, cache
-    ).compile()
+    operands = shaped((lanes, llm._operand_width(256, 8192 // 256)), jnp.int32)
+    compiled = llm._operand_extend(cfg.make_extend_fn()).lower(
+        params, operands, cache, cache, tc=tc).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 2      # the kernel is there
     memory = compiled.memory_analysis()
     assert 9.4e9 < memory.argument_size_in_bytes < 10.6e9
@@ -182,22 +184,23 @@ def test_gptj_serve_paging_programs_compile_beside_the_weights(shaped):
     arena = shaped((cfg.num_layers, blocks, block, cfg.num_heads, cfg.head_dim), cfg.dtype)
     arena_bytes = 2 * cfg.num_layers * blocks * block * cfg.num_heads * cfg.head_dim
     programs = llm._paging_programs()
+    # a call's operands: one int32 buffer, as wide as the widest buckets ask
+    width = llm._operand_width(tokens[-1], caches[-1] // block)
 
     def pair_bytes(b, cap):
         return 2 * 2 * cfg.num_layers * b * cap * cfg.num_heads * cfg.head_dim
 
     def gather(b, cap):
         return programs.gather.lower(
-            arena, arena, shaped((b, cap // block), jnp.int32)).compile()
+            arena, arena, shaped((b, width), jnp.int32), cap // block).compile()
 
     def page_back(b, tc):
         new = shaped((cfg.num_layers, b, tc, cfg.num_heads, cfg.head_dim), cfg.dtype)
-        index = shaped((b * tc,), jnp.int32)
         return programs.page_back.lower(
-            arena, arena, new, new, index, index, shaped((), jnp.int32),
+            arena, arena, new, new, shaped((b, width), jnp.int32),
             (shaped((b, tc, cfg.vocab_size), jnp.float32),
              shaped((b, tc, cfg.embed_dim), jnp.float32)),
-            shaped((b,), jnp.int32),
+            (),
         ).compile()
 
     for b in lanes:
@@ -209,6 +212,9 @@ def test_gptj_serve_paging_programs_compile_beside_the_weights(shaped):
             memory = page_back(b, tc).memory_analysis()
             assert memory.alias_size_in_bytes == 2 * arena_bytes, (b, tc)
             assert memory.temp_size_in_bytes < 2**20, (b, tc)
+            # what it returns beside the arenas: the ids and the picked rows
+            rows_bytes = 4 * b * (1 + cfg.vocab_size + cfg.embed_dim)
+            assert 0 <= memory.output_size_in_bytes - 2 * arena_bytes - rows_bytes < 4096
     clone = programs.clone.lower(
         arena, arena, shaped((), jnp.int32), shaped((), jnp.int32)).compile()
     assert clone.memory_analysis().alias_size_in_bytes == 2 * arena_bytes
@@ -222,3 +228,38 @@ def test_gptj_serve_paging_programs_compile_beside_the_weights(shaped):
     assert _device_bytes(extend) + 2 * arena_bytes < HBM_BYTES
     # the pair may still be alive (extend has been dispatched, not awaited)
     assert weights_bytes + pair_bytes(b, cap) + _device_bytes(page_back(b, tc)) < HBM_BYTES
+
+
+@pytest.mark.parametrize(
+    "name,extends,pagings",
+    [("gptj-6b-serve", 12, 11), ("command-a-plus-serve-ep8", 20, 25)],
+)
+def test_a_serve_configuration_compiles_no_more_programs_than_it_did(name, extends, pagings):
+    """The programs an engine with the configuration's buckets compiles (a tiny
+    model: the count is the buckets'), against the count before a call's
+    operands went up as one buffer (PR 28): built, it has compiled one gather
+    per (lanes, cache), one page-back per (lanes, tokens) and the clone;
+    ``warm()`` compiles one ``extend`` per shape of ``extend_shapes()``
+    (``tests/test_llm.py`` holds it to that). ``setup_s`` is mostly these
+    compiles."""
+    import json
+
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "configs", name + ".json")) as f:
+        sizes = json.load(f)["engine"]
+    context = max(sizes["cache_buckets"])
+    cfg = (
+        cohere2_moe.cohere2_moe_nano(max_seq_len=context)
+        if name.startswith("command-a-plus")
+        else dataclasses.replace(gpt.gpt_nano(), max_seq_len=context)
+    )
+    llm._paging_programs.cache_clear()      # this engine's programs alone
+    try:
+        eng = llm.LLMEngine(cfg, **sizes)
+        assert len(set(eng.extend_shapes())) == len(eng.extend_shapes()) <= extends
+        programs = llm._paging_programs()
+        assert sum(
+            p._cache_size() for p in (programs.gather, programs.page_back, programs.clone)
+        ) <= pagings
+    finally:
+        llm._paging_programs.cache_clear()

@@ -47,3 +47,7 @@ def test_probe_keeps_the_reads_the_benchmark_drops(tmp_path, monkeypatch):
     probe.report(kept, say=lambda *a: said.append(" ".join(map(str, a))))
     text = "\n".join(said)
     assert "kv_gather" in text and "lane_fill" in text and "slowest_step" in text
+    # the tiny cell asks for no logits and no adapter: one buffer up, one array down
+    calls = d["phase_n"]["dispatch"]
+    assert d["h2d_transfers"] == d["d2h_transfers"] == d["ids_only_calls"] == calls
+    assert f"transfers a call: 1.00 up, 1.00 down; ids-only calls {calls}/{calls}" in text

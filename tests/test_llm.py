@@ -314,9 +314,9 @@ _PAGING = dict(
 )
 
 
-def _sequences(lengths, new):
+def _sequences(lengths, new, **asks):
     return [
-        batching._Sequence({"prompt": _prompt(70 + i, n), "max_new_tokens": new})
+        batching._Sequence({"prompt": _prompt(70 + i, n), "max_new_tokens": new, **asks})
         for i, n in enumerate(lengths)
     ]
 
@@ -341,11 +341,15 @@ def test_paged_device_calls_are_bitwise_a_zero_padded_host_pair():
     """Every device call of a mixed workload (1 to 4 lanes, lengths that end
     inside a block, a pool that starts full of finite garbage) against the
     same ``extend`` fed a zero-padded pair built on the host from a mirror of
-    each lane's cache: the sampled rows and the pool's live contents are the
-    same bits."""
+    each lane's cache: the sampled rows (which an adapter that adds nothing
+    brings home), the ids sampled from them on the device and the pool's live
+    contents are the same bits."""
     import jax.numpy as jnp
 
-    eng = LLMEngine(CFG, prefix_caching=False, prefill_lanes=2, **_PAGING)
+    nothing = (
+        np.zeros((CFG.embed_dim, 1), np.float32), np.zeros((1, CFG.vocab_size), np.float32), 0.0)
+    eng = LLMEngine(
+        CFG, prefix_caching=False, prefill_lanes=2, adapter_loader=lambda mid: nothing, **_PAGING)
     pool, rng = eng.pool, np.random.RandomState(7)
     pool.k_data = jnp.asarray(rng.standard_normal(pool.k_data.shape), pool.dtype)
     pool.v_data = jnp.asarray(rng.standard_normal(pool.v_data.shape), pool.dtype)
@@ -366,12 +370,14 @@ def test_paged_device_calls_are_bitwise_a_zero_padded_host_pair():
             tokens[i, :len(ch)], lengths[i] = ch, st.length
         logits, hidden, k_new, v_new = (
             np.asarray(o) for o in eng._extend(eng._params, tokens, lengths, k, v))
-        rows = real(states, chunks, tc)
-        assert rows[0].shape == (b, CFG.vocab_size) and rows[1].shape == (b, CFG.embed_dim)
+        sampled = real(states, chunks, tc)
+        assert len(sampled) == len(states)
         for i, (st, ch) in enumerate(zip(states, chunks)):
             n = len(ch)
-            assert np.array_equal(rows[0][i], logits[i, n - 1])
-            assert np.array_equal(rows[1][i], hidden[i, n - 1])
+            tok, logits_row, hidden_row = sampled[i]
+            assert np.array_equal(logits_row, logits[i, n - 1])
+            assert np.array_equal(hidden_row, hidden[i, n - 1])
+            assert tok == np.argmax(logits[i, n - 1])
             _, mk, mv = mirror[id(st)]
             mk = np.concatenate([mk, k_new[:, i, :n]], axis=1)
             mv = np.concatenate([mv, v_new[:, i, :n]], axis=1)
@@ -379,10 +385,10 @@ def test_paged_device_calls_are_bitwise_a_zero_padded_host_pair():
             pk, pv = _paged(pool, st.blocks, st.length + n)
             assert np.array_equal(pk, mk) and np.array_equal(pv, mv)
         calls.append((len(states), tc, cap, [st.length % eng.block_size for st in states]))
-        return rows
+        return sampled
 
     eng._run_extend = checked
-    _drive(eng, _sequences((20, 40, 9, 33, 70), 5))
+    _drive(eng, _sequences((20, 40, 9, 33, 70), 5, model_id="lora:nothing"))
     assert {c[0] for c in calls} >= {1, 2, 3, 4}
     assert {c[1] for c in calls} == {1, 16, 32} and {c[2] for c in calls} == {64, 128}
     assert any(r for c in calls for r in c[3])      # frontiers inside a block
@@ -415,14 +421,14 @@ def test_engine_clones_a_shared_tail_block_on_the_device_before_writing_it():
 
 
 def test_nothing_compiles_once_the_engine_is_built_and_extend_is_warm():
-    """Every (lanes, tokens, cache) bucket combination, a clone and a whole
-    request with a prefix hit, after construction and the benchmark's kind of
-    warm-up of ``extend``: not one compile request reaches the backend."""
+    """Every (lanes, tokens, cache) bucket combination, with and without the
+    rows coming home, a clone and a whole request with a prefix hit, after
+    construction and the engine's warm-up of ``extend``: not one compile
+    request reaches the backend."""
     import itertools
     import math
 
     import jax
-    import jax.numpy as jnp
     from jax._src.dispatch import BACKEND_COMPILE_EVENT
 
     from ray_tpu.serve.llm import _SeqState
@@ -434,35 +440,35 @@ def test_nothing_compiles_once_the_engine_is_built_and_extend_is_warm():
     eng = LLMEngine(CFG, prefix_caching=True, prefill_lanes=4, **_PAGING)
     combos = list(itertools.product(
         eng.lane_buckets, [1] + eng.prefill_token_buckets, eng.cache_buckets))
-    for b, tc, cap in combos:
-        kv = jnp.zeros((CFG.num_layers, b, cap, CFG.num_heads, CFG.head_dim), eng.pool.dtype)
-        jax.block_until_ready(eng._extend(
-            eng._params, jnp.zeros((b, tc), jnp.int32), jnp.zeros((b,), jnp.int32), kv, kv))
+    assert eng.extend_shapes() == combos
+    assert eng.warm()["shapes"] == eng._extend_call._cache_size() == len(combos)
     assert compiles                             # the listener hears a compile
     del compiles[:]
 
     seen, real = set(), eng._run_extend
 
     def recorded(states, chunks, tc):
-        rows = real(states, chunks, tc)
-        seen.add((rows[0].shape[0], tc, eng.cache_slots - recorded.slots0))
-        recorded.slots0 = eng.cache_slots
-        return rows
+        sampled = real(states, chunks, tc)
+        seen.add((eng.lane_slots - recorded.lanes0, tc, eng.cache_slots - recorded.slots0))
+        recorded.lanes0, recorded.slots0 = eng.lane_slots, eng.cache_slots
+        return sampled
 
-    recorded.slots0 = eng.cache_slots
+    recorded.lanes0, recorded.slots0 = eng.lane_slots, eng.cache_slots
     eng._run_extend = recorded
     bs = eng.block_size
     for b, tc, cap in combos:
         lanes = b if b <= 2 else b - 1          # a padded lane where there can be one
         chunk = 1 if tc == 1 else tc - 1
         states = []
-        for _ in range(lanes):
+        for i in range(lanes):
             st = _SeqState()
             st.length = cap - tc - 3            # ends inside a block
             st.blocks = eng.pool.allocate(math.ceil((st.length + chunk) / bs))
+            st.adapter, st.return_logits = None, i == 0 and cap == eng.cache_buckets[0]
             states.append(st)
-        rows = eng._run_extend(states, [[1] * chunk] * lanes, tc)
-        assert np.isfinite(rows[0][:lanes]).all()
+        sampled = eng._run_extend(states, [[1] * chunk] * lanes, tc)
+        assert len(sampled) == lanes and all(0 <= tok < CFG.vocab_size for tok, _, _ in sampled)
+        assert all((row is not None) == states[0].return_logits for _, row, _ in sampled)
         for st in states:
             eng.pool.free(st.blocks)
     assert seen == {(b, tc, b * cap) for b, tc, cap in combos}

@@ -214,7 +214,7 @@ def test_reducer_labels_gaps_with_the_phase_under_them(planes):
 
 
 def test_counters_equal_what_the_shapes_give(engine):
-    cfg, before = engine.cfg, engine.stats()
+    before = engine.stats()
     assert _drive(engine, _requests()) == 3
     after = engine.stats()
     block = ENGINE["block_size"]
@@ -226,14 +226,16 @@ def test_counters_equal_what_the_shapes_give(engine):
         "lane_slots": sum(c[1] for c in CALLS),
         "cache_tokens": sum(c[4] for c in CALLS),
         "cache_slots": sum(b * cap for _, b, _, cap, _ in CALLS),
-        # int32 up: tokens [b, tc], the page-back's rows and slots [b * tc] and
-        # their count, lengths and last rows [b], the block table [b, cap /
-        # block]; the pair never leaves the device
-        "h2d_bytes": sum(
-            4 * (3 * b * tc + 1 + 2 * b + b * cap // block) for _, b, tc, cap, _ in CALLS
-        ),
-        # f32 down: one row of logits and one of hidden a lane
-        "d2h_bytes": sum(b * (cfg.vocab_size + cfg.embed_dim) * 4 for _, b, _, _, _ in CALLS),
+        # int32 up, one buffer a call: a lane's length, last row and count,
+        # then tokens, the page-back's rows and slots and the block table, each
+        # as wide as the widest of them in any bucket (32 tokens; 128 / 16
+        # blocks); the pair never leaves the device
+        "h2d_bytes": sum(4 * b * (3 + 4 * max(32, 128 // block)) for _, b, _, _, _ in CALLS),
+        "h2d_transfers": len(CALLS),
+        # int32 down, one array a call: the id a lane sampled on the device
+        "d2h_bytes": sum(4 * b for _, b, _, _, _ in CALLS),
+        "d2h_transfers": len(CALLS),
+        "ids_only_calls": len(CALLS),
     }
     assert {k: _delta(after, before, k) for k in want} == want
     assert want["lanes_used"] < want["lane_slots"]
@@ -294,7 +296,7 @@ def test_queue_time_is_in_every_result_and_counts_the_wait_for_a_slot(engine):
 @pytest.mark.parametrize(
     "phase, owner, method",
     # the call of extend and the dispatch of the page-back program
-    [("dispatch", "engine", "_extend"), ("kv_scatter", "pool", "page_back")],
+    [("dispatch", "engine", "_extend_call"), ("kv_scatter", "pool", "page_back")],
 )
 def test_slowest_step_names_the_step_and_the_phase_that_stalled(
     engine, monkeypatch, phase, owner, method
@@ -303,12 +305,12 @@ def test_slowest_step_names_the_step_and_the_phase_that_stalled(
     owner = engine if owner == "engine" else engine.pool
     real, calls, planted = getattr(owner, method), [], {}
 
-    def stalls_once(*args):
+    def stalls_once(*args, **kwargs):
         calls.append(None)
         if len(calls) == 3:                 # the third device call opens the second step
             planted["at"] = time.time()
             time.sleep(0.25)
-        return real(*args)
+        return real(*args, **kwargs)
 
     monkeypatch.setattr(owner, method, stalls_once)
     _drive(engine, _requests())
@@ -321,13 +323,13 @@ def test_slowest_step_names_the_step_and_the_phase_that_stalled(
 
 def test_two_reads_bound_the_slowest_step(engine, monkeypatch):
     """A stall shorter than an earlier one shows once the earlier was read."""
-    real, stall = engine._extend, [0.3]
+    real, stall = engine._extend_call, [0.3]
 
-    def stalls(*args):
+    def stalls(*args, **kwargs):
         time.sleep(stall.pop() if stall else 0.0)
-        return real(*args)
+        return real(*args, **kwargs)
 
-    monkeypatch.setattr(engine, "_extend", stalls)
+    monkeypatch.setattr(engine, "_extend_call", stalls)
     engine.stats()
     _drive(engine, _requests())
     assert engine.stats()["slowest_step"]["wall_s"] >= 0.3
@@ -345,15 +347,12 @@ def test_phases_cost_less_than_their_budget_outside_a_session(engine, monkeypatc
     for a bare no-op, turn about, the device programs and the upload stubbed
     so that a step is the engine's own python: the difference per step stays
     under ``PHASE_BUDGET_NS`` for each phase the step opens."""
-    cfg = engine.cfg
-    rows = {
-        b: (np.zeros((b, cfg.vocab_size), np.float32), np.zeros((b, cfg.embed_dim), np.float32))
-        for b in engine.lane_buckets
-    }
-    monkeypatch.setattr(engine, "_extend", lambda *args: (None,) * 4)
-    monkeypatch.setattr(engine.pool, "gather", lambda table: (None, None))
+    ids = {b: np.zeros((b,), np.int32) for b in engine.lane_buckets}
+    monkeypatch.setattr(engine, "_extend_call", lambda *args, tc: (None,) * 4)
+    monkeypatch.setattr(engine.pool, "gather", lambda operands, n: (None, None))
     monkeypatch.setattr(
-        engine.pool, "page_back", lambda *args: rows[len(args[-1])])
+        engine.pool, "page_back",
+        lambda k_new, v_new, operands, outputs, counted: (ids[len(operands)], None))
     monkeypatch.setattr(jax, "device_put", lambda a: a)
     as_it_is, nothing = engine._phase, contextlib.nullcontext()
 

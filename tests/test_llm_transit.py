@@ -1,0 +1,190 @@
+"""What crosses the host-device link in a serving call: one int32 buffer up,
+one int32 array down (the ids sampled on the device, an expert layer's
+counters behind them), and the picked rows only for a lane that needs them on
+the host. The ids are the ones the host would have picked from those rows."""
+
+import numpy as np
+import pytest
+
+from ray_tpu.models import cohere2_moe, gpt
+from ray_tpu.serve import batching, llm
+
+ENGINE = dict(
+    num_blocks=64, block_size=16, prefill_chunk=32, prefill_lanes=2,
+    lane_buckets=(1, 2, 4), prefill_token_buckets=(16, 32),
+    cache_buckets=(64, 128), prefix_caching=False,
+)
+CONFIGS = {"gpt": gpt.gpt_nano, "cohere2_moe": cohere2_moe.cohere2_moe_nano}
+LENGTHS, NEW = (20, 40, 9, 33), 5
+
+
+@pytest.fixture(scope="module", params=sorted(CONFIGS))
+def engine(request):
+    cfg = CONFIGS[request.param]()
+    adapter = llm.random_lora(cfg, rank=4, seed=3, scale=4.0)
+    return llm.LLMEngine(
+        cfg, adapter_loader=lambda mid: (adapter["A"], adapter["B"], adapter["scale"]),
+        **ENGINE)
+
+
+def _requests(cfg, seed, asks=({},) * len(LENGTHS)):
+    rng = np.random.RandomState(seed)
+    return [
+        batching._Sequence({
+            "prompt": [int(t) for t in rng.randint(0, cfg.vocab_size, n)],
+            "max_new_tokens": NEW, **ask,
+        })
+        for n, ask in zip(LENGTHS, asks)
+    ]
+
+
+def _drive(eng, seqs):
+    steps = 0
+    while not all(s.done for s in seqs):
+        eng.step([s for s in seqs if not s.done])
+        steps += 1
+        assert steps < 200
+    for s in seqs:
+        assert s._error is None, s._error
+    return [s._result for s in seqs]
+
+
+def _calls(eng, monkeypatch):
+    """Every device call from here on: its lanes' states, what it returned and
+    what it added to the engine's counters."""
+    kept, real = [], eng._run_extend
+    counted = ("h2d_transfers", "d2h_transfers", "ids_only_calls", "h2d_bytes", "d2h_bytes")
+
+    def kept_call(states, chunks, tc):
+        before = {k: getattr(eng, k) for k in counted}
+        sampled = real(states, chunks, tc)
+        kept.append({
+            "states": list(states), "sampled": sampled,
+            # does the lane sample a token here, or does its prompt go on
+            "emits": [st.pos + len(ch) >= len(st.prompt) for st, ch in zip(states, chunks)],
+            **{k: getattr(eng, k) - v for k, v in before.items()},
+        })
+        return sampled
+
+    monkeypatch.setattr(eng, "_run_extend", kept_call)
+    return kept
+
+
+@pytest.mark.parametrize("seed", [11, 2**31 + 7])
+def test_ids_sampled_on_the_device_are_the_argmax_of_the_rows_a_twin_brings_home(engine, seed):
+    plain = _drive(engine, _requests(engine.cfg, seed))
+    twins = _drive(
+        engine, _requests(engine.cfg, seed, ({"return_logits": True},) * len(LENGTHS)))
+    for got, twin in zip(plain, twins):
+        assert "logits" not in got and twin["logits"].shape == (NEW, engine.cfg.vocab_size)
+        assert got["tokens"] == twin["tokens"] == [int(np.argmax(r)) for r in twin["logits"]]
+
+
+@pytest.mark.parametrize("tc,counted", [(1, False), (4, True)], ids=["decode", "chunk+counters"])
+def test_a_tie_goes_to_the_first_index_as_on_the_host(engine, tc, counted):
+    """Rows with their maximum at two, at three and at every index, through
+    the page-back program itself (it writes no token: the count is 0)."""
+    import jax.numpy as jnp
+
+    cfg, b = engine.cfg, 4
+    rng = np.random.RandomState(5)
+    logits = rng.standard_normal((b, tc, cfg.vocab_size)).astype(np.float32)
+    last = rng.randint(0, tc, b)
+    top = logits.max() + 1.0
+    logits[0, last[0], [7, 3]] = top                    # written 7 first: 3 wins
+    logits[1, last[1], [cfg.vocab_size - 1, 100, 200]] = top
+    logits[2, last[2]] = 0.0                            # all equal: 0 wins
+    operands = np.zeros((b, engine._operand_width), np.int32)
+    operands[:, llm._LAST] = last
+    new = jnp.zeros((cfg.num_layers, b, tc, cfg.kv_heads, cfg.head_dim), engine.pool.dtype)
+    counters = (jnp.asarray([5, 6, 7, 8], jnp.int32),) if counted else ()
+    home, picked = engine.pool.page_back(
+        new, new, jnp.asarray(operands),
+        (jnp.asarray(logits), jnp.zeros((b, tc, cfg.embed_dim), jnp.float32)), counters)
+    home, rows = np.asarray(home), np.asarray(picked[0])
+    assert home.dtype == np.int32
+    assert np.array_equal(rows, logits[np.arange(b), last])
+    assert home[:b].tolist() == np.argmax(rows, axis=-1).tolist()
+    assert home[:3].tolist() == [3, 100, 0]
+    assert home[b:].tolist() == ([5, 6, 7, 8] if counted else [])
+
+
+def test_a_mixed_call_gives_each_lane_what_it_gave_before(engine, monkeypatch):
+    """An adapter lane, a lane that returns its logits and two plain lanes in
+    the same calls: each lane's token is what the host made of the rows, as
+    it was when every row came home."""
+    cfg = engine.cfg
+    asks = ({"model_id": "lora:a", "return_logits": True}, {"return_logits": True}, {}, {})
+    calls = _calls(engine, monkeypatch)
+    seqs = _requests(cfg, 23, asks)
+    results = _drive(engine, seqs)
+    a, bmat, scale = seqs[0].state.adapter
+    want = {id(s.state): ([], []) for s in seqs}        # tokens, logits rows
+    mixed = 0
+    for call in calls:
+        adapted = any(st.adapter is not None for st in call["states"])
+        asked = any(st.return_logits for st in call["states"])
+        mixed += adapted and len(call["states"]) > 2
+        for st, (tok, row, hidden), emits in zip(call["states"], call["sampled"], call["emits"]):
+            assert (row is not None) == (adapted or asked)
+            assert (hidden is not None) == adapted
+            if not emits:
+                continue
+            if row is not None:
+                assert tok == np.argmax(row)            # the device's id is the host's
+            if st.adapter is not None:
+                delta = scale * (hidden @ a) @ bmat
+                assert np.abs(delta).max() > 0
+                row = row + delta
+                tok = int(np.argmax(row))
+            want[id(st)][0].append(tok)
+            want[id(st)][1].append(row)
+    assert mixed                                        # the lanes did share calls
+    for s, got, ask in zip(seqs, results, asks):
+        tokens, rows = want[id(s.state)]
+        assert got["tokens"] == tokens and len(tokens) == NEW
+        assert ("logits" in got) == bool(ask.get("return_logits"))
+        if "logits" in got:
+            assert np.array_equal(got["logits"], np.stack(rows))
+    # the adapter moved the lane: without it the same prompt decodes otherwise
+    base = _drive(engine, _requests(cfg, 23)[:1])[0]
+    assert base["tokens"] != results[0]["tokens"]
+    # and the plain lanes decode as they do with no such lane beside them
+    alone = _drive(engine, _requests(cfg, 23))
+    assert [r["tokens"] for r in alone[2:]] == [r["tokens"] for r in results[2:]]
+
+
+def test_a_call_crosses_the_link_once_each_way_unless_a_lane_needs_its_rows(engine, monkeypatch):
+    cfg, b_of = engine.cfg, lambda call: batching.bucket_pad_size(
+        len(call["states"]), engine.lane_buckets)
+    # an expert layer's four counters ride home behind the ids
+    counters = 4 * len(llm.MOE_COUNTERS) * isinstance(cfg, cohere2_moe.Cohere2MoeConfig)
+    calls = _calls(engine, monkeypatch)
+    before = engine.stats()
+    _drive(engine, _requests(cfg, 31))
+    after = engine.stats()
+    assert calls and all(
+        (c["h2d_transfers"], c["d2h_transfers"], c["ids_only_calls"]) == (1, 1, 1) for c in calls)
+    for c in calls:
+        assert c["h2d_bytes"] == 4 * b_of(c) * engine._operand_width
+        assert c["d2h_bytes"] == 4 * b_of(c) + counters
+    dispatched = after["phase_n"]["dispatch"] - before["phase_n"]["dispatch"]
+    assert len(calls) == dispatched
+    for k in ("h2d_transfers", "d2h_transfers", "ids_only_calls"):
+        assert after[k] - before[k] == dispatched, k
+
+    del calls[:]
+    asks = ({"return_logits": True}, {}, {}, {"model_id": "lora:a"})
+    seqs = _requests(cfg, 31, asks)
+    _drive(engine, seqs)
+    kinds = set()
+    for c in calls:
+        adapted = any(st.adapter is not None for st in c["states"])
+        asked = any(st.return_logits for st in c["states"])
+        rows = 2 if adapted else 1 if asked else 0      # logits and hidden; logits; neither
+        kinds.add(rows)
+        assert (c["h2d_transfers"], c["d2h_transfers"]) == (1, 1 + rows)
+        assert c["ids_only_calls"] == (rows == 0)
+        assert c["d2h_bytes"] == 4 * b_of(c) + counters + 4 * b_of(c) * (
+            (rows > 0) * cfg.vocab_size + (rows > 1) * cfg.embed_dim)
+    assert kinds == {0, 1, 2}
