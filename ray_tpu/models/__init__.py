@@ -3,12 +3,10 @@
 from ray_tpu.models.gpt import (
     GPT,
     GPTConfig,
-    gpt_125m,
     gpt_1b,
     gpt_j_6b,
     gpt_nano,
     next_token_loss,
-    train_step_flops,
 )
 from ray_tpu.models.training import (
     TrainState,
@@ -25,11 +23,9 @@ __all__ = [
     "GPT",
     "GPTConfig",
     "gpt_nano",
-    "gpt_125m",
     "gpt_1b",
     "gpt_j_6b",
     "next_token_loss",
-    "train_step_flops",
     "TrainState",
     "default_optimizer",
     "init_params",

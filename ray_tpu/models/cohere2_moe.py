@@ -97,10 +97,6 @@ class Cohere2MoeConfig:
         return init_params(self, seed)
 
 
-def init_params(self, seed: int = 0):
-        return init_params(self, seed)
-
-
 def cohere2_moe_nano(**kw) -> Cohere2MoeConfig:
     """A tiny one for the tests: two periods of (sliding, sliding, full)."""
     sizes = dict(

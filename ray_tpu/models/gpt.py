@@ -7,14 +7,19 @@ gptj_deepspeed_fine_tuning.ipynb; release/train_tests) — but TPU-first:
 - flax.linen modules whose every parameter carries *logical* axis names
   (see ray_tpu.parallel.sharding), so one model definition runs DP, FSDP,
   TP, SP and any mix by switching rule tables;
-- bfloat16 activations/compute, float32 params & optimizer state;
+- bfloat16 activations/compute; params and optimizer state in
+  ``param_dtype`` (float32 unless the configuration says otherwise);
 - `nn.scan` over layers (one XLA While loop, compiles O(1) in depth) with
   `nn.remat` so long-context activations are rematerialized;
 - fused attention from ray_tpu.ops (Pallas flash kernel on TPU).
 
+``GPTConfig`` says what the model is; how a step runs (kernel or XLA, tile
+sizes, what the remat saves, the loss's chunk) is the code's to decide.
 `gpt_j_6b()` matches the reference benchmark model's shape (28 layers,
-d_model 4096, 16 heads × 256, rotary_dim 64, vocab 50400, parallel
-residual); `gpt_nano`/`gpt_125m` are for tests and single-chip benches.
+d_model 4096, 16 heads × 256, rotary_dim 64, vocab 50400, one LayerNorm
+feeding attention and MLP in parallel); `gpt_nano` is for tests, `gpt_1b`
+for the multichip dry run. ``make_extend_fn`` is the serving engine's
+KV-cache forward of the same block.
 """
 
 from __future__ import annotations
@@ -42,26 +47,8 @@ class GPTConfig:
     rotary_dim: int = 64
     dtype: Any = jnp.bfloat16          # activation/compute dtype
     param_dtype: Any = jnp.float32     # master parameter dtype
-    parallel_residual: bool = True     # GPT-J style single-LN parallel block
     tie_embeddings: bool = False
-    remat: bool = True
-    # what the layer-remat saves for the backward pass:
-    #   "nothing"  - full remat (lowest HBM, recomputes the whole block)
-    #   "dots"     - jax.checkpoint_policies.dots_with_no_batch_dims_saveable:
-    #                matmul outputs are saved, elementwise ops recompute
-    #                (trades HBM for skipping the fwd matmul replay)
-    #   "attn"     - save tensors tagged with checkpoint_name "attn_out"
-    #                (the flash-attention output: the priciest recompute)
-    remat_policy: str = "nothing"
-    scan_layers: bool = True
-    attn_use_pallas: Optional[bool] = None  # None → auto (TPU only)
-    # flash-attention kernel tile sizes (v5e sweep on the 1B/2048 bench:
-    # 1024/1024 is ~6% faster than 512/512; 2048 overflows VMEM)
-    attn_block_q: int = 512
-    attn_block_k: int = 512
-    # blockwise cross-entropy chunk length (sequence rows per scanned
-    # [b, chunk, vocab] logits block)
-    ce_chunk: int = 256
+    remat: bool = True                 # rematerialize each layer in the backward pass
     seq_parallel_impl: str = "ring"         # "ring" | "ulysses" (used when sp>1)
     # mixture-of-experts (0 = dense MLP); experts shard over the ep axis
     moe_num_experts: int = 0
@@ -108,7 +95,7 @@ class GPTConfig:
         per_layer = (
             4 * d * h * hd          # q,k,v,o
             + mlp_params
-            + (2 * d if self.parallel_residual else 4 * d)  # ln scale+bias
+            + 2 * d                 # ln scale+bias
         )
         head = 0 if self.tie_embeddings else d * v + v
         return v * d + self.num_layers * per_layer + 2 * d + head
@@ -118,13 +105,6 @@ def gpt_nano(**kw) -> GPTConfig:
     return GPTConfig(
         vocab_size=256, num_layers=2, num_heads=4, head_dim=16, embed_dim=64,
         mlp_dim=256, max_seq_len=128, rotary_dim=16, dtype=jnp.float32, **kw
-    )
-
-
-def gpt_125m(**kw) -> GPTConfig:
-    return GPTConfig(
-        vocab_size=50304, num_layers=12, num_heads=12, head_dim=64,
-        embed_dim=768, mlp_dim=3072, max_seq_len=2048, rotary_dim=32, **kw
     )
 
 
@@ -243,16 +223,8 @@ class Attention(nn.Module):
         # context parallelism: ring/ulysses over the sp axis (first-class
         # long-context support — SURVEY.md §5)
         out = mesh_attention(
-            qh, kh, vh, self.mesh, impl=cfg.seq_parallel_impl, causal=True,
-            use_pallas=cfg.attn_use_pallas,
-            block_q=cfg.attn_block_q, block_k=cfg.attn_block_k,
+            qh, kh, vh, self.mesh, impl=cfg.seq_parallel_impl, causal=True
         ).transpose(0, 2, 1, 3)
-        # tag for remat_policy="attn": saving exactly this tensor lets the
-        # backward pass skip replaying the flash-attention forward kernel
-        # while everything cheaper (LN, rotary, gelu) still rematerializes
-        from jax.ad_checkpoint import checkpoint_name
-
-        out = checkpoint_name(out, "attn_out")
         return _dense((cfg.embed_dim,), ("heads", "kv", "embed"), cfg, "o", use_bias=False)(
             out
         )
@@ -280,6 +252,8 @@ def _layer_norm(cfg: GPTConfig, name: str):
 
 
 class Block(nn.Module):
+    """GPT-J's block: one LayerNorm feeds attention and MLP side by side."""
+
     cfg: GPTConfig
     mesh: Any = None
 
@@ -294,14 +268,10 @@ class Block(nn.Module):
     def __call__(self, x: jax.Array, positions: jax.Array) -> jax.Array:
         cfg = self.cfg
         x = nn.with_logical_constraint(x, ("batch", "seq", "act_embed"))
-        if cfg.parallel_residual:
-            hidden = _layer_norm(cfg, "ln")(x)
-            x = x + Attention(cfg, self.mesh, name="attn")(hidden, positions) + self._mlp()(
-                hidden
-            )
-        else:
-            x = x + Attention(cfg, self.mesh, name="attn")(_layer_norm(cfg, "ln1")(x), positions)
-            x = x + self._mlp()(_layer_norm(cfg, "ln2")(x))
+        hidden = _layer_norm(cfg, "ln")(x)
+        x = x + Attention(cfg, self.mesh, name="attn")(hidden, positions) + self._mlp()(
+            hidden
+        )
         return nn.with_logical_constraint(x, ("batch", "seq", "act_embed"))
 
 
@@ -312,27 +282,16 @@ class ScannedBlocks(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array, positions: jax.Array) -> jax.Array:
         cfg = self.cfg
-        block = Block
-        if cfg.remat:
-            policy = None
-            if cfg.remat_policy == "dots":
-                policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-            elif cfg.remat_policy == "attn":
-                policy = jax.checkpoint_policies.save_only_these_names("attn_out")
-            block = nn.remat(
-                Block, prevent_cse=not cfg.scan_layers, policy=policy
-            )
-        if cfg.scan_layers:
-            x, _ = nn.scan(
-                lambda mdl, carry, _: (mdl(carry, positions), None),
-                variable_axes={"params": 0, "losses": 0},
-                split_rngs={"params": True},
-                length=cfg.num_layers,
-                metadata_params={nn.PARTITION_NAME: "layers"},
-            )(block(cfg, self.mesh, name="layers"), x, None)
-        else:
-            for i in range(cfg.num_layers):
-                x = block(cfg, self.mesh, name=f"layer_{i}")(x, positions)
+        # full remat (nothing saved but a layer's input); under a scan the
+        # loop already keeps XLA from merging the replay into the forward
+        block = nn.remat(Block, prevent_cse=False) if cfg.remat else Block
+        x, _ = nn.scan(
+            lambda mdl, carry, _: (mdl(carry, positions), None),
+            variable_axes={"params": 0, "losses": 0},
+            split_rngs={"params": True},
+            length=cfg.num_layers,
+            metadata_params={nn.PARTITION_NAME: "layers"},
+        )(block(cfg, self.mesh, name="layers"), x, None)
         return x
 
 
@@ -429,22 +388,6 @@ def unboxed_params(variables):
     return nn.meta.unbox(tree)
 
 
-def stacked_layer_params(params, cfg: GPTConfig):
-    """The [num_layers, ...]-stacked per-layer param subtree. scan_layers
-    configs already store it stacked; per-layer trees are stacked here."""
-    blocks = params["blocks"]
-    if "layers" in blocks:
-        return blocks["layers"]
-    per = [blocks[f"layer_{i}"] for i in range(cfg.num_layers)]
-    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *per)
-
-
-def init_kv_cache(cfg: GPTConfig, batch: int, capacity: int):
-    """Zeroed K/V cache tensors [layers, batch, capacity, heads, head_dim]."""
-    shape = (cfg.num_layers, batch, capacity, cfg.num_heads, cfg.head_dim)
-    return jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype)
-
-
 def make_extend_fn(cfg: GPTConfig):
     """A jitted ``extend(params, tokens, lengths, k_cache, v_cache)``.
 
@@ -508,14 +451,9 @@ def make_extend_fn(cfg: GPTConfig):
         return out, k, v
 
     def _block(x, p, positions, kc, vc):
-        if cfg.parallel_residual:
-            hidden = _ln(x, p["ln"])
-            a, k, v = _attend(p["attn"], hidden, positions, kc, vc)
-            return x + a + _mlp(hidden, p["mlp"]), k, v
-        hidden = _ln(x, p["ln1"])
+        hidden = _ln(x, p["ln"])
         a, k, v = _attend(p["attn"], hidden, positions, kc, vc)
-        x = x + a
-        return x + _mlp(_ln(x, p["ln2"]), p["mlp"]), k, v
+        return x + a + _mlp(hidden, p["mlp"]), k, v
 
     @jax.jit
     def extend(params, tokens, lengths, k_cache, v_cache):
@@ -527,7 +465,7 @@ def make_extend_fn(cfg: GPTConfig):
         with jax.named_scope("extend.embed"):
             emb = params["wte"]["embedding"].astype(dtype)
             x = emb[jnp.clip(tokens, 0, cfg.vocab_size - 1)]
-        layers = stacked_layer_params(params, cfg)
+        layers = params["blocks"]["layers"]    # stacked [num_layers, ...] by the scan
 
         def body(carry, xs):
             p, kc, vc = xs
@@ -551,7 +489,7 @@ def make_extend_fn(cfg: GPTConfig):
 
 
 # ---------------------------------------------------------------------------
-# loss / flops helpers
+# loss helpers
 # ---------------------------------------------------------------------------
 
 
@@ -617,16 +555,3 @@ def blockwise_next_token_loss(
 
     total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (xs, targets, valid))
     return total / jnp.maximum(valid.sum(), 1.0)
-
-
-def train_step_flops(cfg: GPTConfig, batch: int, seq: int) -> float:
-    """Approximate FLOPs of one fwd+bwd step (6·matmul_params·tokens +
-    attention). The input embedding is a gather, not a matmul, so it is
-    excluded; a tied lm_head *is* a matmul, so the table counts once then."""
-    tokens = batch * seq
-    matmul_params = cfg.num_params() - cfg.vocab_size * cfg.embed_dim
-    if cfg.tie_embeddings:
-        matmul_params += cfg.vocab_size * cfg.embed_dim
-    matmul = 6.0 * matmul_params * tokens
-    attn = 12.0 * cfg.num_layers * batch * cfg.num_heads * seq * seq * cfg.head_dim
-    return matmul + attn

@@ -30,6 +30,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import backend
+
 
 def sigmoid_top_k(h: jax.Array, router: jax.Array, k: int):
     """Route tokens ``h`` [n, d] over every expert the ``router`` [d, R]
@@ -52,17 +54,13 @@ def sigmoid_top_k(h: jax.Array, router: jax.Array, k: int):
 GMM_TILING = (32, 4096, 512)
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def grouped_matmul(rows, w, group_sizes, interpret: bool = False):
     """``rows`` [m, k], sorted by group, times ``w`` [groups, k, n]: row ``i``
     is multiplied with the matrix of its group, the first ``group_sizes[0]``
     rows with ``w[0]`` and so on; rows past the last group are undefined. On
     the TPU this is the megablox kernel (``jax.experimental``), which visits
     only the row tiles of groups that have rows; elsewhere XLA's ragged dot."""
-    if not (_on_tpu() or interpret):
+    if not (backend.on_tpu() or interpret):
         return jax.lax.ragged_dot(rows, w, group_sizes)
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 

@@ -166,9 +166,7 @@ def make_train_step(
             (hidden, kernel, bias), aux = _apply(params, tokens)
         # Blockwise xent: never materializes the [b, t, vocab] logits.
         with jax.named_scope("train.loss"):
-            loss = blockwise_next_token_loss(
-                hidden, kernel, bias, tokens, chunk=cfg.ce_chunk
-            )
+            loss = blockwise_next_token_loss(hidden, kernel, bias, tokens)
         return loss + cfg.moe_aux_weight * aux
 
     def step(state: TrainState, tokens: jax.Array):
@@ -210,9 +208,7 @@ def make_eval_step(cfg: GPTConfig, mesh: Optional[Mesh] = None) -> Callable:
     @jax.jit
     def eval_step(params, tokens):
         hidden, kernel, bias = model.apply({"params": params}, tokens)
-        return blockwise_next_token_loss(
-            hidden, kernel, bias, tokens, chunk=cfg.ce_chunk
-        )
+        return blockwise_next_token_loss(hidden, kernel, bias, tokens)
 
     return eval_step
 

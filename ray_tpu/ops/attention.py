@@ -22,13 +22,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu.ops import backend
+
 NEG_INF = -1e30
 
-
-def _on_tpu() -> bool:
-    # a backend that fails to initialize must surface here, not quietly
-    # become the O(T^2) XLA path
-    return jax.devices()[0].platform == "tpu"
+# flash-attention kernel tiles (v5e sweep at 2048 tokens: 1024/1024 was ~6%
+# faster on a 1B model, 2048 overflows VMEM; no cell has run anything but 512)
+BLOCK_Q = 512
+BLOCK_K = 512
 
 
 # ---------------------------------------------------------------------------
@@ -77,8 +78,8 @@ def attention_with_lse(
     kv_offset=0,
 ) -> Tuple[jax.Array, jax.Array]:
     """(out, logsumexp) over [b,h,t_q,d] — the merge-ready block primitive
-    for ring/blockwise attention (online-softmax combining across kv
-    blocks). ``kv_offset`` is the global position of k/v's first row when
+    for ring attention (online-softmax combining across kv blocks).
+    ``kv_offset`` is the global position of k/v's first row when
     the block is a slice of a longer sequence; with the default, a shorter
     q is treated as the suffix of the context (chunked-prefill layout).
     Differentiable end to end (plain XLA ops)."""
@@ -105,57 +106,12 @@ def attention_with_lse(
 def merge_attention(o, lse, o_new, lse_new, valid=True):
     """Online-softmax merge of two normalized partial attentions
     (o in f32, lse from attention_with_lse); the single source of the
-    logaddexp rule shared by ring and blockwise attention."""
+    logaddexp rule of ring attention."""
     valid = jnp.asarray(valid)
     lse_out = jnp.where(valid, jnp.logaddexp(lse, lse_new), lse)
     w_old = jnp.exp(lse - lse_out)[..., None]
     w_new = jnp.where(valid, jnp.exp(lse_new - lse_out), 0.0)[..., None]
     return o * w_old + o_new.astype(jnp.float32) * w_new, lse_out
-
-
-def blockwise_attention(
-    q: jax.Array,
-    k: jax.Array,
-    v: jax.Array,
-    *,
-    causal: bool = True,
-    scale: Optional[float] = None,
-    chunk: int = 512,
-) -> jax.Array:
-    """Memory-efficient attention without Pallas: lax.scan over kv chunks
-    with an online-softmax carry; each chunk rematerializes in the backward
-    (jax.checkpoint). Peak memory holds one [b,h,t_q,chunk] block instead
-    of the full [b,h,t_q,t_kv] logits — the XLA-only long-context fallback
-    (SURVEY.md §5 blockwise attention)."""
-    b, h, t_q, d = q.shape
-    t_kv = k.shape[-2]
-    scale_val = float(scale) if scale is not None else 1.0 / float(np.sqrt(d))
-    if t_kv % chunk != 0 or t_kv <= chunk:
-        return _attention_xla(q, k, v, causal=causal, scale=scale_val)
-    nc = t_kv // chunk
-    ks = k.reshape(b, h, nc, chunk, d).transpose(2, 0, 1, 3, 4)
-    vs = v.reshape(b, h, nc, chunk, d).transpose(2, 0, 1, 3, 4)
-    q_off = t_kv - t_q  # q rows are the suffix of the context
-
-    @jax.checkpoint
-    def chunk_update(carry, idx, k_c, v_c):
-        o, m = carry  # o normalized-so-far [b,h,t_q,d] f32, m lse [b,h,t_q]
-        o_c, lse_c = attention_with_lse(
-            q, k_c, v_c, causal=causal, scale=scale_val,
-            kv_offset=idx * chunk - q_off,
-        )
-        return merge_attention(o, m, o_c, lse_c)
-
-    def body(carry, xs):
-        idx, k_c, v_c = xs
-        return chunk_update(carry, idx, k_c, v_c), None
-
-    init = (
-        jnp.zeros((b, h, t_q, d), jnp.float32),
-        jnp.full((b, h, t_q), NEG_INF, jnp.float32),
-    )
-    (o, _m), _ = jax.lax.scan(body, init, (jnp.arange(nc), ks, vs))
-    return o.astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -248,8 +204,8 @@ def _flash_attention_tpu(
     *,
     causal: bool,
     scale: float,
-    block_q: int = 512,
-    block_k: int = 512,
+    block_q: int = BLOCK_Q,
+    block_k: int = BLOCK_K,
     interpret: bool = False,
 ):
     """Returns (out [b,h,t_q,d], lse [b,h,t_q] float32)."""
@@ -544,9 +500,7 @@ def _flash_attention_tpu_bwd(
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(
-    jax.jit, static_argnames=("causal", "scale", "use_pallas", "block_q", "block_k")
-)
+@functools.partial(jax.jit, static_argnames=("causal", "scale", "use_pallas"))
 def dot_product_attention(
     q: jax.Array,
     k: jax.Array,
@@ -556,8 +510,6 @@ def dot_product_attention(
     scale: Optional[float] = None,
     segment_ids: Optional[jax.Array] = None,
     use_pallas: Optional[bool] = None,
-    block_q: int = 512,
-    block_k: int = 512,
 ) -> jax.Array:
     """Fused attention over [batch, heads, seq, head_dim] inputs.
 
@@ -581,10 +533,10 @@ def dot_product_attention(
             + (" with segment_ids" if segment_ids is not None else "")
             + ": needs head_dim % 128 == 0 (or 64), seq lens % 8 == 0"
         )
-    use = use_pallas if use_pallas is not None else _on_tpu()
+    use = use_pallas if use_pallas is not None else backend.on_tpu()
     if use and supported:
         return flash_attention(
-            q, k, v, causal, scale_val, block_q, block_k, False
+            q, k, v, causal, scale_val, BLOCK_Q, BLOCK_K, False
         )
     return _attention_xla(q, k, v, causal=causal, scale=scale_val, segment_ids=segment_ids)
 

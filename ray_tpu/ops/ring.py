@@ -89,9 +89,6 @@ def ulysses_attention_local(
     sp: int,
     causal: bool = True,
     scale: Optional[float] = None,
-    use_pallas: Optional[bool] = None,
-    block_q: int = 512,
-    block_k: int = 512,
 ):
     """Shard-local Ulysses attention (call under shard_map).
 
@@ -110,10 +107,7 @@ def ulysses_attention_local(
         return jax.lax.all_to_all(x, axis_name, split_axis=2, concat_axis=1, tiled=True)
 
     out = dot_product_attention(
-        swap_in(q), swap_in(k), swap_in(v),
-        causal=causal, scale=scale, use_pallas=use_pallas,
-        block_q=block_q, block_k=block_k,
-    )
+        swap_in(q), swap_in(k), swap_in(v), causal=causal, scale=scale)
     return swap_out(out)
 
 
@@ -127,9 +121,6 @@ def mesh_attention(
     sp_axis: str = "sp",
     causal: bool = True,
     scale: Optional[float] = None,
-    use_pallas: Optional[bool] = None,
-    block_q: int = 512,
-    block_k: int = 512,
     batch_axes=("dp", "fsdp"),
     head_axis: str = "tp",
 ) -> jax.Array:
@@ -141,10 +132,7 @@ def mesh_attention(
     implementation's collectives (ppermute ring or all_to_all) ride the ICI
     mesh explicitly. No mesh, or a one-device mesh, needs no wrapper.
     """
-    fused = functools.partial(
-        dot_product_attention, causal=causal, scale=scale,
-        use_pallas=use_pallas, block_q=block_q, block_k=block_k,
-    )
+    fused = functools.partial(dot_product_attention, causal=causal, scale=scale)
     if mesh is None or mesh.size == 1:
         return fused(q, k, v)
     sp = mesh.shape.get(sp_axis, 1)
@@ -156,8 +144,7 @@ def mesh_attention(
         )
     elif impl == "ulysses":
         local = functools.partial(
-            ulysses_attention_local, axis_name=sp_axis, sp=sp, causal=causal,
-            scale=scale, use_pallas=use_pallas, block_q=block_q, block_k=block_k,
+            ulysses_attention_local, axis_name=sp_axis, sp=sp, causal=causal, scale=scale
         )
     else:
         raise ValueError(f"unknown sequence-parallel impl {impl!r}")

@@ -175,8 +175,8 @@ def make_pp_train_step(
     under GSPMD exactly as in the non-pipelined step. Pass
     ``state_shardings_tree`` from ``init_sharded_state(..., rules=
     shd.pp_rules())`` so params/opt-state are pp×fsdp×tp sharded at rest.
-    Requires ``cfg.scan_layers=True`` (stacked [num_layers, ...] block
-    params) and ``num_layers % pp == 0``.
+    Requires ``num_layers % pp == 0`` (the stacked [num_layers, ...] block
+    params are split over the stages).
     """
     import flax.linen as nn
     import optax
@@ -185,8 +185,6 @@ def make_pp_train_step(
     from ray_tpu.models.training import TrainState
     from ray_tpu.parallel import sharding as shd
 
-    if not cfg.scan_layers:
-        raise ValueError("pipeline parallelism requires cfg.scan_layers=True")
     S = int(mesh.shape.get("pp", 1))
     block = Block(cfg)
     active_rules = list(rules if rules is not None else shd.pp_rules())
@@ -223,9 +221,7 @@ def make_pp_train_step(
         y = (y - mean) * lax.rsqrt(var + 1e-6)
         y = y * ln["scale"].astype(y.dtype) + ln["bias"].astype(y.dtype)
         head = params["lm_head"]
-        return blockwise_next_token_loss(
-            y, head["kernel"], head["bias"], tokens, chunk=cfg.ce_chunk
-        )
+        return blockwise_next_token_loss(y, head["kernel"], head["bias"], tokens)
 
     def loss_fn(params, tokens):
         # install the logical rule table so Block's with_logical_constraint

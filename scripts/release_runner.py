@@ -29,9 +29,7 @@ def run_workload(name: str, spec: dict) -> dict:
     script = os.path.join(REPO, spec["script"])
     argv = [sys.executable, script, *spec.get("args", [])]
     env = dict(os.environ)
-    if not spec.get("tpu"):
-        # CPU-only workloads must not claim the TPU chip
-        env["JAX_PLATFORMS"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"     # host workloads: none may claim a chip
     env.update({k: str(v) for k, v in (spec.get("env") or {}).items()})
     t0 = time.perf_counter()
     try:

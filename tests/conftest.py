@@ -66,3 +66,23 @@ def ray_start_small_store():
     )
     yield worker
     ray_tpu.shutdown()
+
+
+@pytest.fixture
+def built_for_tpu(monkeypatch):
+    """``built_for_tpu(True)``: what this test traces from here on takes the
+    TPU's kernels, as on the chip (``False``: XLA's, as on the CPU). It answers
+    for ``ops/backend.on_tpu``, the one place the package asks.
+    ``dot_product_attention`` is a jit of its own whose cache does not key on
+    the answer, so what was traced under another answer is dropped, before
+    and after."""
+    import jax
+
+    from ray_tpu.ops import backend
+
+    def answer(on: bool) -> None:
+        jax.clear_caches()
+        monkeypatch.setattr(backend, "on_tpu", lambda: on)
+
+    yield answer
+    jax.clear_caches()

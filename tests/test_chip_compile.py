@@ -7,7 +7,8 @@ described (not attached) ``v5e:2x2``: it refuses what the chip would refuse
 these say nothing about results or speed; that is ``chip_smoke.py``'s job.
 
 Code that asks ``jax.devices()`` sees the CPU here and would take its XLA
-path, so the tests steer it with ``attn_use_pallas=True``.
+path, so the tests answer for the package's one probe (``built_for_tpu``,
+``tests/conftest.py``).
 """
 
 import dataclasses
@@ -56,10 +57,7 @@ def v5e():
 
 
 def _gptj(depth):
-    return gpt.gpt_j_6b(
-        num_layers=depth, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
-        attn_use_pallas=True,
-    )
+    return gpt.gpt_j_6b(num_layers=depth, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
 
 
 def _device_bytes(compiled):
@@ -93,10 +91,11 @@ def test_flash_kernels_compile(v5e, head_dim, backward):
     [(MeshSpec(), 1), (MeshSpec(dp=-1, fsdp=2, tp=2), 4)],
     ids=["one-chip", "fsdp2xtp2"],
 )
-def test_gptj_width_train_step_compiles(v5e, spec, n_devices):
+def test_gptj_width_train_step_compiles(v5e, spec, n_devices, built_for_tpu):
     """The whole step at GPT-J's widths, depth 2. On the mesh the flash
     kernel is only legal under shard_map ("Mosaic kernels cannot be
     automatically partitioned")."""
+    built_for_tpu(True)
     cfg, batch = _gptj(2), (2, 2048)
     mesh = spec.build(v5e[:n_devices])
     opt = default_optimizer(1e-4)
@@ -146,16 +145,14 @@ def test_gptj_full_depth_extend_compiles(shaped, lanes, tc):
 @pytest.mark.parametrize(
     "lanes,tc,cap", [(8, 1, 8192), (2, 256, 8192)], ids=["decode", "prefill"])
 def test_command_a_plus_share_extend_compiles_and_copies_no_expert(
-    shaped, lanes, tc, cap, monkeypatch
+    shaped, lanes, tc, cap, built_for_tpu
 ):
     """The served share of Command A+ at its published widths (one period, 16
     of 128 experts, an eighth of the vocabulary: 9.47 GB of weights) over the
     largest cache bucket: it fits beside a 0.8 GB pool, and its temporaries stay
     under a layer's routed experts (1.6 GB), which a scan that sliced them out
     of the stack copied on every call (``moe.held_experts_ffn``, ``layer``)."""
-    from ray_tpu.models import moe
-
-    monkeypatch.setattr(moe, "_on_tpu", lambda: True)     # the chip's grouped matmul
+    built_for_tpu(True)     # the chip's grouped matmul
     cfg = cohere2_moe.Cohere2MoeConfig(vocab_size=32768, num_layers=4, num_experts=16)
     params = jax.tree.map(
         lambda x: shaped(x.shape, x.dtype), jax.eval_shape(lambda: cfg.init_params(0)))

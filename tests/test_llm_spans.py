@@ -385,8 +385,9 @@ def test_phases_cost_less_than_their_budget_outside_a_session(engine, monkeypatc
 # -- (g) stable device names --------------------------------------------------
 
 
-def test_train_step_names_its_kernels_and_scopes():
-    cfg = dataclasses.replace(NANO, num_heads=1, head_dim=64, attn_use_pallas=True)
+def test_train_step_names_its_kernels_and_scopes(built_for_tpu):
+    built_for_tpu(True)
+    cfg = dataclasses.replace(NANO, num_heads=1, head_dim=64)
     optimizer = default_optimizer()
     tokens = jax.ShapeDtypeStruct((2, 128), jnp.int32)
     _, state = abstract_state(cfg, optimizer, tokens)

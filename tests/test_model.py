@@ -5,13 +5,18 @@ python/ray/train/tests/; sharding logic is what the driver's
 dryrun_multichip validates on more devices.)
 """
 
+import dataclasses
+
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models.gpt import GPT, gpt_nano, next_token_loss, train_step_flops
+from ray_tpu.models.cohere2_moe import cohere2_moe_nano
+from ray_tpu.models.gpt import GPT, GPTConfig, gpt_nano, next_token_loss
 from ray_tpu.models.training import (
+    abstract_state,
     default_optimizer,
     init_sharded_state,
     make_train_step,
@@ -85,13 +90,69 @@ def test_sharded_train_step_loss_decreases():
     assert len(wi.sharding.device_set) > 1
 
 
-def test_unscanned_matches_scanned_shapes():
-    cfg = gpt_nano(scan_layers=False, remat=False)
-    params = init_params(cfg, jax.random.PRNGKey(0), (1, 8))
-    assert "layer_0" in params["blocks"]
-
-
 def test_flops_positive():
-    cfg = gpt_nano()
-    assert train_step_flops(cfg, 4, 128) > 0
-    assert cfg.num_params() > 0
+    assert gpt_nano().num_params() > 0
+
+
+@pytest.mark.parametrize(
+    "cfg",
+    [gpt_nano(), gpt_nano(tie_embeddings=True), cohere2_moe_nano()],
+    ids=["gpt_nano", "gpt_nano-tied", "cohere2_moe_nano"],
+)
+def test_num_params_counts_the_initialised_tree(cfg):
+    """``describe`` and the MFU reader of the benchmark divide by it."""
+    tree = jax.eval_shape(lambda: cfg.init_params(0))
+    assert cfg.num_params() == sum(x.size for x in jax.tree.leaves(tree))
+
+
+def test_gptconfig_holds_what_the_model_is_and_no_run_switch():
+    """How a step runs (kernel or XLA, tiles, what the remat saves, the loss's
+    chunk, scanned or unrolled) is the code's to decide, from the platform and
+    the shapes: a new field here has to be a key of the model, or argue with
+    this test."""
+    model_keys = {
+        "vocab_size", "num_layers", "num_heads", "head_dim", "embed_dim", "mlp_dim",
+        "max_seq_len", "rotary_dim", "dtype", "param_dtype", "tie_embeddings",
+    }
+    kept = {"remat", "seq_parallel_impl"}
+    moe = {"moe_num_experts", "moe_top_k", "moe_capacity_factor", "moe_aux_weight"}
+    assert {f.name for f in dataclasses.fields(GPTConfig)} == model_keys | kept | moe
+    for gone in (
+        "remat_policy", "scan_layers", "attn_use_pallas", "attn_block_q", "attn_block_k",
+        "ce_chunk", "parallel_residual",
+    ):
+        with pytest.raises(TypeError):
+            GPTConfig(**{gone: None})
+
+
+def _train_step_jaxpr():
+    cfg = dataclasses.replace(gpt_nano(), num_heads=1, head_dim=64)
+    optimizer = default_optimizer()
+    tokens = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+    _, state = abstract_state(cfg, optimizer, tokens)
+    step = make_train_step(cfg, optimizer, donate=False)
+    return str(jax.make_jaxpr(step)(nn.meta.unbox(state), tokens))
+
+
+def _command_a_plus_extend_jaxpr():
+    cfg = cohere2_moe_nano()
+    kv = jax.ShapeDtypeStruct((cfg.num_layers, 2, 64, cfg.kv_heads, cfg.head_dim), cfg.dtype)
+    return str(jax.make_jaxpr(cfg.make_extend_fn())(
+        jax.eval_shape(lambda: cfg.init_params(0)), jax.ShapeDtypeStruct((2, 8), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.int32), kv, kv))
+
+
+@pytest.mark.parametrize(
+    "program,kernel",
+    [(_train_step_jaxpr, "flash_fwd"), (_command_a_plus_extend_jaxpr, "name=gmm")],
+    ids=["train-step", "command-a-plus-extend"],
+)
+def test_one_probe_decides_every_kernel(built_for_tpu, program, kernel):
+    """``ops/backend.on_tpu`` alone puts the flash kernels into the train step
+    and the grouped matmul into Command A+'s ``extend``, and alone takes them
+    out: no field, argument or second probe stands beside it."""
+    built_for_tpu(True)
+    assert kernel in program()
+    built_for_tpu(False)
+    text = program()
+    assert kernel not in text and "pallas_call" not in text
