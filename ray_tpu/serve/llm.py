@@ -27,6 +27,13 @@ serving setup from PAPERS.md):
   layer's counters) are one int32 array, which is all that comes home. The
   picked rows follow only for a lane that asked for its logits or has an
   adapter. Every such program is compiled when the engine is built.
+* the chip never waits for the host between calls: a call is *launched*
+  (upload, gather, ``extend``, page-back: nothing waits for the device) and
+  *landed* a call later (its ids fetched, its tokens emitted), once its
+  successor has been launched. The array a call leaves for the host is the
+  next call's argument too: it reads a lane's last token from it on the
+  device (``_FROM`` in the operand buffer), so one call is in flight whenever
+  there is work (:class:`LLMEngine`; ``calls_ahead``, ``tokens_fed_on_device``).
 * prefill/decode split — prefill runs as its own bucketed extend call
   (prompt chunks padded via :func:`~ray_tpu.serve.batching.
   bucket_pad_size`), decode as a tc=1 call; every engine iteration runs
@@ -98,11 +105,13 @@ def make_params(cfg=None, seed: int = 0):
 
 
 #: A device call's small operands, one int32 buffer ``[lanes, width]``: a lane's
-#: row holds its cache length, the index of its last fed token and (lane 0
-#: alone) the count of tokens to page back, then four sections of one width
-#: (:func:`_sections`). The width is the engine's, whatever the call's buckets:
-#: a program is shaped by the lanes and by its own bucket, as it was.
-_LENGTH, _LAST, _COUNT, _SCALARS = 0, 1, 2, 3
+#: row holds its cache length, the index of its last fed token, (lane 0 alone)
+#: the count of tokens to page back and where its first token is (its lane in
+#: the call before, whose ids are still on the device; -1: the host knew it and
+#: it stands in the buffer), then four sections of one width (:func:`_sections`).
+#: The width is the engine's, whatever the call's buckets: a program is shaped
+#: by the lanes and by its own bucket, as it was.
+_LENGTH, _LAST, _COUNT, _FROM, _SCALARS = 0, 1, 2, 3, 4
 
 
 def _operand_width(tokens: int, blocks: int) -> int:
@@ -160,9 +169,9 @@ def _paging_programs():
         pairs = jax.lax.fori_loop(0, b * n, copy_block, (empty(), empty()))
         return tuple(p.reshape(layers, b, n * block, heads, hd) for p in pairs)
 
-    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    @functools.partial(jax.jit, donate_argnums=(0, 1), static_argnums=7)
     @jax.named_scope("paging.page_back")
-    def page_back(k_data, v_data, k_new, v_new, operands, outputs, counted):
+    def page_back(k_data, v_data, k_new, v_new, operands, outputs, counted, width):
         layers, blocks, block, heads, hd = k_data.shape
         b, tc = k_new.shape[1:3]
         news = tuple(x.reshape(layers, b * tc, heads, hd) for x in (k_new, v_new))
@@ -188,7 +197,10 @@ def _paging_programs():
         with jax.named_scope("paging.sample"):
             # greedy, as ``np.argmax`` is: the first of equal maxima
             ids = jnp.argmax(picked[0], axis=-1).astype(jnp.int32)
-            home = jnp.concatenate([ids, *(c.astype(jnp.int32) for c in counted)])
+            # the next call reads a lane's token here too: the ids have one
+            # width whatever the lanes, so ``extend`` has no program more for it
+            home = jnp.concatenate([
+                jnp.pad(ids, (0, width - b)), *(c.astype(jnp.int32) for c in counted)])
         return tuple(a.reshape(k_data.shape) for a in arenas) + (home, picked)
 
     @functools.partial(jax.jit, donate_argnums=(0, 1))
@@ -262,19 +274,20 @@ class KVBlockPool:
         :func:`_sections`; every entry a valid block), built on the device."""
         return _paging_programs().gather(self.k_data, self.v_data, operands, n)
 
-    def page_back(self, k_new, v_new, operands, outputs, counted):
+    def page_back(self, k_new, v_new, operands, outputs, counted, width: int):
         """Write token ``rows[i]`` (an index into lanes x tokens) of ``k_new``
         / ``v_new`` ``[layers, b, tc, heads, head_dim]`` into the arenas at
         token slot ``slots[i]`` (block x block_size + offset) for the first
         ``count`` entries of the ``rows`` / ``slots`` sections of ``operands``
         and, in the same program, pick row ``last[i]`` of lane ``i`` from each
         of ``outputs`` ``[b, tc, ...]`` (logits first) and sample it. Returns
-        one int32 array, the ``b`` greedy ids followed by the int32 arrays of
-        ``counted``, and the picked rows, all on the device. The arenas are
-        donated: nothing is copied but the new rows."""
+        one int32 array, for the host and for the next call to read on the
+        device (the ``b`` greedy ids padded to ``width``, then the int32
+        arrays of ``counted``), and the picked rows: all on the device. The
+        arenas are donated: nothing is copied but the new rows."""
         self.k_data, self.v_data, home, picked = _paging_programs().page_back(
             self.k_data, self.v_data, k_new, v_new, operands, outputs,
-            tuple(counted))
+            tuple(counted), width)
         return home, picked
 
     def clone_block(self, src: int, dst: int) -> None:
@@ -300,6 +313,7 @@ class KVBlockPool:
         width = _operand_width(
             max(tc for _, tc in extend_shapes),
             max(cache_buckets) // self.block_size)
+        lanes = max(b for b, _ in extend_shapes)
         for b in sorted({b for b, _ in extend_shapes}):
             operands = jnp.zeros((b, width), jnp.int32)
             for cap in cache_buckets:
@@ -310,7 +324,7 @@ class KVBlockPool:
             jax.block_until_ready(self.page_back(
                 new, new, jnp.zeros((b, width), jnp.int32),
                 tuple(jnp.zeros(o.shape, o.dtype) for o in (logits, hidden)),
-                tuple(jnp.zeros(c.shape, c.dtype) for c in counted)))
+                tuple(jnp.zeros(c.shape, c.dtype) for c in counted), lanes))
         self.clone_block(0, 0)
         jax.block_until_ready((self.k_data, self.v_data))
 
@@ -553,30 +567,64 @@ def _fetch_lora(model_id: str):
 def _operand_extend(extend):
     """``extend`` as a step calls it: the tokens and lengths are read on the
     device from the call's operand buffer (:func:`_sections`), ``tc`` tokens a
-    lane. One program per (lanes, tokens, cache), as ``extend`` alone has."""
+    lane, and a lane's first token from ``home`` (what the call before left
+    for the host, its lanes' ids first) where its row names a lane there
+    (``_FROM``): the host need not have seen a token to feed it. One program
+    per (lanes, tokens, cache), as ``extend`` alone has."""
     import jax
+    import jax.numpy as jnp
 
     @functools.partial(jax.jit, static_argnames="tc")
-    def extend_call(params, operands, k_cache, v_cache, *, tc):
+    def extend_call(params, operands, home, k_cache, v_cache, *, tc):
+        tokens, source = _sections(operands)[0][:, :tc], operands[:, _FROM]
+        first = jnp.where(source < 0, tokens[:, 0], home[jnp.maximum(source, 0)])
         return extend(
-            params, _sections(operands)[0][:, :tc], operands[:, _LENGTH],
+            params, tokens.at[:, 0].set(first), operands[:, _LENGTH],
             k_cache, v_cache)
 
     return extend_call
 
 
 class _SeqState:
+    """A sequence as the host keeps it. ``pos``, ``length`` and ``sent`` say
+    what has been launched (prompt tokens fed, tokens in the cache, tokens
+    asked of the device); ``out`` and ``last_token`` what has landed. Between
+    the two the lane is in ``call``, as lane ``lane`` of it."""
+
     __slots__ = (
         "prompt", "max_new", "eos", "model_id", "adapter", "lease", "blocks",
-        "pos", "length", "out", "last_token", "cached_tokens", "hashes",
-        "ttft_s", "queue_s", "stream_q", "cancel_ev", "return_logits",
-        "logits",
+        "pos", "length", "sent", "call", "lane", "out", "last_token",
+        "cached_tokens", "hashes", "ttft_s", "queue_s", "stream_q",
+        "cancel_ev", "return_logits", "logits",
     )
+
+    @property
+    def on_host(self) -> bool:
+        """The host makes this lane's token from the rows of logits and hidden
+        (it returns them, or an adapter changes them): nothing can be fed to
+        it before its call has landed."""
+        return self.return_logits or self.adapter is not None
+
+
+class _Call:
+    """A device call between its launch and its landing: what it left on the
+    device (``home``, for the host and for the next call, and the ``picked``
+    rows; both gone once it has landed), its ``lanes`` as ``(sequence,
+    state)`` and which of them sample a token (``emits``)."""
+
+    __slots__ = ("home", "picked", "lanes", "emits", "sampled")
+
+    def __init__(self, home, picked, lanes, emits):
+        self.home, self.picked, self.lanes, self.emits = home, picked, lanes, emits
+        self.sampled: List[tuple] = []
 
 
 #: the phases of an engine step, as ``phase_s`` / ``phase_n`` key them and as
 #: the profiler sees them (``llm.<phase>``). ``step`` holds ``admit``,
-#: ``prefill`` and ``decode``; those two hold the six phases of a device call.
+#: ``prefill`` and ``decode``; those two hold the four phases of a call's launch
+#: (``upload`` to ``kv_scatter``, none of which waits for the device) and, after
+#: them, the two of the landing of the call before (``fetch``, which waits, and
+#: ``sample``); a step with nothing to launch holds a landing alone.
 #: The leaves partition a step: what is in none of them is a missing phase.
 LEAF_PHASES = (
     "admit", "upload", "kv_gather", "dispatch", "kv_scatter", "fetch", "sample",
@@ -597,10 +645,21 @@ class LLMEngine:
     """The scheduler + paged-attention runtime behind ``LLMServer``.
 
     ``step(seqs)`` is a continuous-batching step function: each call
-    admits new sequences (allocating their KV lease or shedding), runs at
+    admits new sequences (allocating their KV lease or shedding), launches at
     most one bucketed prefill chunk and one tc=1 decode over every
     decoding lane, and finishes/streams tokens. All shapes reaching the
-    jitted extend fn are drawn from the configured buckets."""
+    jitted extend fn are drawn from the configured buckets.
+
+    The device runs a call behind the host: a call is landed (its ids fetched,
+    its tokens emitted) only after its successor has been launched, so one
+    call is in flight when ``step`` returns, and the next launch reads a
+    lane's token from that call's ids on the device. The host lands every
+    call but the newest before it launches, so a lane's last token is either
+    on the host or in that one call. What the host must see first, it waits
+    for: the call of a lane whose rows it needs (:attr:`_SeqState.on_host`),
+    a call whose buffers leave the device no room for the next one's
+    (:meth:`_fits`), a call that may free the blocks an allocation lacks, and
+    the last call when there is nothing to launch."""
 
     def __init__(self, cfg=None, params=None, *, deployment: str = "llm",
                  num_blocks: int = 128, block_size: int = 16,
@@ -611,6 +670,8 @@ class LLMEngine:
                  max_adapters: int = 4, adapter_loader=None,
                  prefix_caching: bool = True, default_max_new_tokens: int = 16,
                  step_delay_s: float = 0.0, seed: int = 0):
+        import jax.numpy as jnp
+
         from ray_tpu.models import gpt
 
         self.cfg = cfg or gpt.gpt_nano()
@@ -663,6 +724,8 @@ class LLMEngine:
         self.ids_only_calls = 0         # calls that brought home the ids alone
         self.lanes_used = 0             # real lanes of the device calls
         self.lane_slots = 0             # their lane buckets
+        self.calls_ahead = 0            # calls launched while another was in flight
+        self.tokens_fed_on_device = 0   # lanes whose token the call before left there
         # what an expert layer counted, summed over the device calls (0 for
         # a model without one)
         self.moe: Dict[str, int] = dict.fromkeys(MOE_COUNTERS, 0)
@@ -681,6 +744,15 @@ class LLMEngine:
         #: long it took, its lanes and its own seconds per phase. Time in
         #: ``fetch`` is the device or the runtime; anywhere else, the host.
         self.slowest_step: Optional[Dict[str, Any]] = None
+        #: the call launched last, until its successor is (or nothing can be)
+        self._flight: Optional[_Call] = None
+        #: what the first call of an idle engine is handed for ``home``
+        self._no_home = jnp.zeros((self._home_width,), jnp.int32)
+        (self._device,) = self._no_home.devices()
+        #: whether the runtime counts the device's bytes (the CPU's does not)
+        self._counts_bytes = "bytes_limit" in (self._device.memory_stats() or {})
+        #: the temporaries of the largest ``extend``, once ``warm`` has asked
+        self._temp_bytes = 0
 
     def _warm_paging(self) -> None:
         """Compile the pool's programs for every shape the buckets allow, so
@@ -689,17 +761,29 @@ class LLMEngine:
         page-back: the first call of each of its own shapes still compiles."""
         import jax
 
+        #: how wide a call's ``home`` is: the ids, as wide as the widest lane
+        #: bucket, then (below) what ``extend`` counted
+        self._home_width = self.lane_buckets[-1]
+
         def extend_outputs(b, tc):
             return jax.eval_shape(
                 functools.partial(self._extend_call, tc=tc),
                 *self._extend_args(
                     jax.ShapeDtypeStruct, b, self.cache_buckets[0]))
 
-        self.pool.warm({
+        outputs = {
             (b, tc): extend_outputs(b, tc)
             for b in self.lane_buckets
             for tc in [1] + self.prefill_token_buckets
-        }, self.cache_buckets)
+        }
+        _, _, _, _, *counted = outputs[self.lane_buckets[0], 1]
+        self._home_width += sum(math.prod(c.shape) for c in counted)
+        #: the bytes ``extend`` hands back for (lanes, tokens), for ``_fits``
+        self._output_bytes = {
+            shape: sum(math.prod(o.shape) * o.dtype.itemsize for o in outs)
+            for shape, outs in outputs.items()
+        }
+        self.pool.warm(outputs, self.cache_buckets)
 
     def _extend_args(self, make, b: int, cap: int):
         """The arrays ``_extend_call`` takes for ``b`` lanes and a cache of
@@ -707,7 +791,9 @@ class LLMEngine:
         cfg = self.cfg
         kv = make(
             (cfg.num_layers, b, cap, cfg.kv_heads, cfg.head_dim), self.pool.dtype)
-        return self._params, make((b, self._operand_width), np.int32), kv, kv
+        return (
+            self._params, make((b, self._operand_width), np.int32),
+            make((self._home_width,), np.int32), kv, kv)
 
     def extend_shapes(self) -> List[tuple]:
         """Every (lanes, tokens, cache) a step can ask ``extend`` for, one
@@ -728,7 +814,8 @@ class LLMEngine:
         step calls it, on zeros made on the device, so that no request meets
         a compile. Returns how many ``shapes``, the seconds it took
         (``warm_s``) and, where the compiler says, the bytes of the largest one
-        (``compiled``)."""
+        (``compiled``), whose temporaries ``_fits`` then counts for any call:
+        compiling every shape a second time to ask each would double this."""
         import jax
         import jax.numpy as jnp
 
@@ -742,6 +829,8 @@ class LLMEngine:
         memory = self._extend_call.lower(
             *self._extend_args(jax.ShapeDtypeStruct, b, cap), tc=tc
         ).compile().memory_analysis()
+        if memory is not None:
+            self._temp_bytes = memory.temp_size_in_bytes
         return {
             "shapes": len(shapes), "warm_s": warm_s,
             "compiled": None if memory is None else {
@@ -783,6 +872,8 @@ class LLMEngine:
             "ids_only_calls": self.ids_only_calls,
             "lanes_used": self.lanes_used,
             "lane_slots": self.lane_slots,
+            "calls_ahead": self.calls_ahead,
+            "tokens_fed_on_device": self.tokens_fed_on_device,
             **self.moe,
             "window_slots": self.window_slots,
             "window_slots_outside": self.window_slots_outside,
@@ -812,17 +903,29 @@ class LLMEngine:
             with self._phase("step"):
                 if self.step_delay_s:
                     time.sleep(self.step_delay_s)
-                with self._phase("admit"):
-                    self._admit(seqs)
-                    self._sweep_cancelled(seqs)
+                if self._admit_phase(seqs):
+                    # too few blocks while a call is in flight: a sequence
+                    # that ends in it gives its own back. Land it, ask again.
+                    self._land()
+                    self._admit_phase(seqs)
+                flight = self._flight
                 self._prefill_step(seqs)
                 self._decode_step(seqs)
+                newest = self._flight
+                if newest is not None and (
+                        newest is flight or all(s.done for s, _ in newest.lanes)):
+                    # nothing was launched, so there is nothing to wait behind;
+                    # or no lane of the call is left to ask for another step
+                    # (an ``eos_token`` landed while it was launched)
+                    self._land()
                 self.steps += 1
         except BaseException:
             # a crashed forward poisons the batch (the batcher fails every
-            # caller) — the leases must not ride down with it
-            for s in seqs:
-                st = s.state
+            # caller) — the leases must not ride down with it, nor those of
+            # the call in flight, which is dropped: nothing will land it
+            flight, self._flight = self._flight, None
+            for st in [s.state for s in seqs] + [
+                    st for _, st in (flight.lanes if flight else ())]:
                 if isinstance(st, _SeqState) and st.lease is not None:
                     st.lease.release()
             raise
@@ -837,7 +940,16 @@ class LLMEngine:
                 },
             }
 
-    def _admit(self, seqs) -> None:
+    def _admit_phase(self, seqs) -> bool:
+        with self._phase("admit"):
+            short = self._admit(seqs)
+            self._sweep_cancelled(seqs)
+        return short
+
+    def _admit(self, seqs) -> bool:
+        """Give every new sequence its state and its blocks, or fail it. True
+        where one found too few blocks while a call is in flight and was left
+        as it came: the landing may free some (``step`` asks again)."""
         for s in seqs:
             if s.state is not None or s.done:
                 continue
@@ -855,6 +967,8 @@ class LLMEngine:
             st.logits = [] if st.return_logits else None
             st.out = []
             st.last_token = None
+            st.sent = 0
+            st.call = st.lane = None
             st.ttft_s = None
             st.queue_s = None
             if not st.prompt or st.max_new < 1:
@@ -870,7 +984,6 @@ class LLMEngine:
             lease = KVLease(self.pool)
             st.lease = lease
             st.blocks = lease.blocks
-            s.on_release = lease.release
             bs = self.block_size
             st.hashes = (
                 chain_hashes(st.prompt, bs) if self.prefix is not None else []
@@ -888,8 +1001,11 @@ class LLMEngine:
                 lease.add(self.pool.allocate(need))
             except NoKVBlocksError as e:
                 lease.release()
+                if self._flight is not None:
+                    return True
                 s.fail(BackPressureError(str(e), retry_after_s=0.05))
                 continue
+            s.on_release = lease.release
             st.pos = len(cached) * bs       # prompt tokens already cached
             st.length = st.pos              # tokens resident in the cache
             st.cached_tokens = st.pos
@@ -905,6 +1021,7 @@ class LLMEngine:
             st.queue_s = time.monotonic() - s.enqueued_at
             self.admitted += 1
             self.queue_s += st.queue_s
+        return False
 
     def _sweep_cancelled(self, seqs) -> None:
         for s in seqs:
@@ -929,95 +1046,117 @@ class LLMEngine:
         if not pending:
             return
         with self._phase("prefill"):
-            lanes = pending[:self.prefill_lanes]
-            states = [s.state for s in lanes]
+            lanes = [(s, s.state) for s in pending[:self.prefill_lanes]]
             chunks = [
-                min(self.prefill_chunk, len(st.prompt) - st.pos)
-                for st in states
+                st.prompt[st.pos:st.pos + self.prefill_chunk] for _, st in lanes
             ]
             tc = batching.bucket_pad_size(
-                max(chunks), self.prefill_token_buckets)
-            sampled = self._run_extend(
-                states, [st.prompt[st.pos:st.pos + c]
-                         for st, c in zip(states, chunks)], tc)
-            for st, c in zip(states, chunks):
-                st.pos += c
-                st.length += c
-            fed = sum(chunks)
-            self.prefill_tokens += fed
-            internal_metrics.inc(
-                "ray_tpu_llm_prefill_tokens_total", fed,
-                {"deployment": self.deployment},
-            )
-            with self._phase("sample"):
-                for i, (s, st) in enumerate(zip(lanes, states)):
-                    if st.pos < len(st.prompt):
-                        continue
-                    if self.prefix is not None:
-                        # cache every full prompt block (first writer wins)
-                        self.prefix.insert(
-                            st.hashes, st.blocks[:len(st.hashes)])
-                    self._emit(s, st, *sampled[i])
+                max(map(len, chunks)), self.prefill_token_buckets)
+            # a lane whose prompt ends in this chunk samples its first token
+            self._launch(lanes, chunks, tc, [
+                st.pos + len(ch) >= len(st.prompt)
+                for (_, st), ch in zip(lanes, chunks)])
 
     def _decode_step(self, seqs) -> None:
+        # a lane is not launched past ``max_new``, which the host knows by
+        # count; past an ``eos_token`` it may be, once: the id is in flight
         decoding = [
             s for s in self._live(seqs)
             if s.state.pos >= len(s.state.prompt)
+            and s.state.sent < s.state.max_new
         ]
         max_lanes = self.lane_buckets[-1]
         while decoding:
-            lanes, decoding = decoding[:max_lanes], decoding[max_lanes:]
+            group, decoding = decoding[:max_lanes], decoding[max_lanes:]
             with self._phase("decode"):
-                self._decode_lanes(lanes)
+                self._decode_lanes(group)
 
-    def _decode_lanes(self, lanes) -> None:
-        states = []
-        for s in lanes:
+    def _decode_lanes(self, group) -> None:
+        lanes = []
+        for s in group:
             st = s.state
-            # grow the cache for the token about to be written
-            need_blocks = (st.length // self.block_size) + 1
             try:
-                if need_blocks > len(st.blocks):
-                    st.lease.add(self.pool.allocate(
-                        need_blocks - len(st.blocks)))
-                self.pool.ensure_private(
-                    st.blocks, st.length // self.block_size)
+                try:
+                    self._grow(st)
+                except NoKVBlocksError:
+                    if self._flight is None:
+                        raise
+                    # a sequence that ends in the call in flight gives its
+                    # blocks back: land it, ask again
+                    self._land()
+                    if not s.done:
+                        self._grow(st)
             except NoKVBlocksError as e:
                 st.lease.release()
                 s.fail(BackPressureError(str(e), retry_after_s=0.05))
                 continue
-            states.append((s, st))
-        if not states:
-            return
-        sts = [st for _, st in states]
-        sampled = self._run_extend(
-            sts, [[st.last_token] for st in sts], 1)
-        for st in sts:
-            st.length += 1
-        self.decode_tokens += len(sts)
-        with self._phase("sample"):
-            for i, (s, st) in enumerate(states):
-                self._emit(s, st, *sampled[i])
+            lanes.append((s, st))
+        # such a landing may have ended a lane (its ``eos_token``)
+        lanes = [(s, st) for s, st in lanes if not s.done]
+        if lanes:
+            self._launch(lanes, [None] * len(lanes), 1, [True] * len(lanes))
 
-    # -- device call + paging ---------------------------------------------
+    def _grow(self, st: _SeqState) -> None:
+        """Room in ``st``'s cache for the token about to be written: a block
+        more where its last is full, and that block its own (copy-on-write)."""
+        at = st.length // self.block_size
+        if at >= len(st.blocks):
+            st.lease.add(self.pool.allocate(at + 1 - len(st.blocks)))
+        self.pool.ensure_private(st.blocks, at)
 
-    def _run_extend(self, states, token_chunks, tc: int):
-        """One device call: feed each lane its chunk over its paged cache,
-        page the new K/V back and sample each lane's last valid row, all on
-        the device. One buffer goes up and one int32 array comes home: the
-        greedy ids and, behind them, what an expert layer counted. Returns a
-        lane's ``(id, logits row, hidden row)``; the rows ``[vocab]`` and
-        ``[embed]`` are None unless a lane of the call needs them on the host:
-        one that returns its logits, or one whose adapter changes them (the
-        hidden rows come home for an adapter alone)."""
+    # -- device call + paging: the launch, then (a call later) the landing --
+
+    def _launch(self, lanes, chunks, tc: int, emits) -> Optional[_Call]:
+        """Launch one device call and land the one before it. Each lane
+        ``(sequence, state)`` is fed its chunk over its paged cache (``None``:
+        its last token, wherever that is), the new K/V is paged back and each
+        lane's last valid row sampled, all on the device: one buffer goes up
+        and nothing here waits for the device. The call is what is in flight
+        from here on; its lanes' ``pos``, ``length`` and ``sent`` and the
+        engine's token counts count it at once. None where the call before had
+        to land first and ended every lane."""
         import jax
 
-        bs = self.block_size
-        b = batching.bucket_pad_size(len(states), self.lane_buckets)
-        t_max = max(
-            st.length + len(ch) for st, ch in zip(states, token_chunks))
-        t_cap = batching.bucket_pad_size(t_max, self.cache_buckets)
+        bs, flight = self.block_size, self._flight
+
+        def shape():
+            t_max = max(
+                st.length + (1 if ch is None else len(ch))
+                for (_, st), ch in zip(lanes, chunks))
+            return (
+                batching.bucket_pad_size(len(lanes), self.lane_buckets),
+                batching.bucket_pad_size(t_max, self.cache_buckets))
+
+        b, t_cap = shape()
+        if flight is not None and (
+                any(st.on_host and st.call is flight for _, st in lanes)
+                or not self._fits(b, tc, t_cap)):
+            # the old order, for this call: the host makes these lanes' token,
+            # or the device has no room for two calls' buffers
+            self._land()
+            flight = None
+            # that landing may have ended a lane (its ``eos_token``): its lease
+            # is back with the pool, and nothing more is written through it
+            kept = [
+                (lane, ch, emit) for lane, ch, emit in zip(lanes, chunks, emits)
+                if not lane[0].done]
+            if not kept:
+                return None
+            if len(kept) < len(lanes):
+                lanes, chunks, emits = zip(*kept)
+                b, t_cap = shape()
+        states, decode = [st for _, st in lanes], chunks[0] is None
         with self._phase("upload"):
+            # a lane's last token is on the host if its call has landed, else
+            # in the one call in flight: the next program reads it there
+            sources = [
+                st.lane if ch is None and st.call is not None else -1
+                for st, ch in zip(states, chunks)
+            ]
+            chunks = [
+                ch if ch is not None else [0 if lane >= 0 else st.last_token]
+                for st, ch, lane in zip(states, chunks, sources)
+            ]
             # zeros are padding: block 0, whatever it holds, lies past a
             # frontier, and nothing is paged back past the count
             operands = np.zeros((b, self._operand_width), np.int32)
@@ -1025,11 +1164,14 @@ class LLMEngine:
             # a negative id is padding: a model may skip it (an expert
             # layer does), and none may let it change a real token
             tokens[:, :tc] = -1
+            operands[:, _FROM] = -1
+            operands[:len(states), _FROM] = sources
+            self.tokens_fed_on_device += sum(lane >= 0 for lane in sources)
             # page-back: token rows[i] of the call goes to arena slot slots[i]
             to_rows = np.zeros((b * tc,), np.int32)
             to_slots = np.zeros((b * tc,), np.int32)
             fed = 0
-            for i, (st, ch) in enumerate(zip(states, token_chunks)):
+            for i, (st, ch) in enumerate(zip(states, chunks)):
                 n = len(ch)
                 tokens[i, :n] = ch
                 operands[i, _LENGTH] = st.length
@@ -1061,43 +1203,115 @@ class LLMEngine:
                     max(0, st.length - self._window + 1) for st in states)
         with self._phase("dispatch"):
             logits, hidden, k_new, v_new, *counted = self._extend_call(
-                self._params, operands, k_cache, v_cache, tc=tc)
+                self._params, operands,
+                self._no_home if flight is None else flight.home,
+                k_cache, v_cache, tc=tc)
             del k_cache, v_cache    # the pair is freed when extend has run
             self.lanes_used += len(states)
             self.lane_slots += b
+            self.calls_ahead += flight is not None
         with self._phase("kv_scatter"):
             home, picked = self.pool.page_back(
-                k_new, v_new, operands, (logits, hidden), counted)
-            del logits, hidden, k_new, v_new
+                k_new, v_new, operands, (logits, hidden), counted,
+                self.lane_buckets[-1])
+            del logits, hidden, k_new, v_new, operands
+            call = _Call(home, picked, lanes, emits)
+            for i, (st, ch, emit) in enumerate(zip(states, chunks, emits)):
+                st.call, st.lane = call, i
+                st.length += len(ch)
+                st.sent += emit
+                if not decode:
+                    st.pos += len(ch)
+            if decode:
+                self.decode_tokens += fed
+            else:
+                self.prefill_tokens += fed
+                internal_metrics.inc(
+                    "ray_tpu_llm_prefill_tokens_total", fed,
+                    {"deployment": self.deployment},
+                )
+        if flight is not None:
+            self._land()
+        self._flight = call
+        return call
+
+    def _fits(self, b: int, tc: int, t_cap: int) -> bool:
+        """Whether the device has room for what a call of this shape takes at
+        its launch, beside what the call in flight still holds: the padded
+        pair, ``extend``'s outputs and its temporaries (those of the largest
+        shape, where ``warm`` has asked the compiler). Outputs are allocated
+        when a program is launched, not when it runs. By the runtime's own
+        count of free bytes, and of the largest free block where it keeps one
+        (free bytes in pieces hold no pair); a backend that counts none (the
+        CPU's) always has room."""
+        if not self._counts_bytes:
+            return True
+        memory, cfg = self._device.memory_stats(), self.cfg
+        pair = 2 * cfg.num_layers * b * t_cap * cfg.kv_heads * cfg.head_dim
+        need = (
+            pair * self.pool.dtype.itemsize + self._output_bytes[b, tc]
+            + self._temp_bytes)
+        free = memory["bytes_limit"] - memory["bytes_in_use"]
+        return need <= min(free, memory.get("largest_free_block_bytes", free))
+
+    def _land(self) -> _Call:
+        """Land the call in flight: wait for the device, bring home one int32
+        array (the greedy ids and, behind them, what an expert layer counted)
+        and emit each lane's token. ``sampled`` holds a lane's ``(id, logits
+        row, hidden row)``; the rows ``[vocab]`` and ``[embed]`` are None unless
+        a lane of the call needs them on the host: one that returns its logits,
+        or one whose adapter changes them (the hidden rows come home for an
+        adapter alone). A forward that raised on the device raises here."""
+        call, self._flight = self._flight, None
+        states = [st for _, st in call.lanes]
         with self._phase("fetch"):
             # waits for the device; then the ids are home
-            home = np.asarray(home)
+            home = np.asarray(call.home)
             fetched = [home]
-            for name, n in zip(MOE_COUNTERS, home[b:]):     # an expert layer's
+            for name, n in zip(MOE_COUNTERS, home[self.lane_buckets[-1]:]):  # an expert layer's
                 self.moe[name] += int(n)
             adapted = any(st.adapter is not None for st in states)
             logits = hidden = None
             if adapted or any(st.return_logits for st in states):
-                logits = np.asarray(picked[0])
+                logits = np.asarray(call.picked[0])
                 fetched.append(logits)
                 if adapted:
-                    hidden = np.asarray(picked[1])
+                    hidden = np.asarray(call.picked[1])
                     fetched.append(hidden)
             else:
                 self.ids_only_calls += 1
+            call.home = call.picked = None      # nothing reads them on the device now
             self.d2h_transfers += len(fetched)
             self.d2h_bytes += sum(a.nbytes for a in fetched)
-        return [
-            (int(home[i]), None if logits is None else logits[i],
-             None if hidden is None else hidden[i])
-            for i in range(len(states))
-        ]
+            call.sampled = [
+                (int(home[i]), None if logits is None else logits[i],
+                 None if hidden is None else hidden[i])
+                for i in range(len(states))
+            ]
+        with self._phase("sample"):
+            for (s, st), emits, sampled in zip(call.lanes, call.emits, call.sampled):
+                if st.call is call:
+                    st.call = None
+                # a lane that ended while the call was in flight (its
+                # ``eos_token`` landed, it was cancelled or shed) drops the id.
+                # Its blocks went back then, with this call's writes to them
+                # still to come: device programs run in launch order, so the
+                # next owner's own writes come after these, and until then it
+                # masks every slot past its frontier.
+                if not emits or s.done or st.lease.released:
+                    continue
+                if not st.out and self.prefix is not None:
+                    # its first token: the prompt is whole in the cache. Cache
+                    # every full prompt block (first writer wins)
+                    self.prefix.insert(st.hashes, st.blocks[:len(st.hashes)])
+                self._emit(s, st, *sampled)
+        return call
 
     # -- sampling / completion --------------------------------------------
 
     def _emit(self, s, st: _SeqState, tok: int, logits_row, hidden_row) -> None:
         """Lane ``st`` sampled ``tok`` on the device. The rows are there where
-        the call brought them home (``_run_extend``)."""
+        the call brought them home (``_land``)."""
         if st.adapter is not None:
             a, bmat, scale = st.adapter
             logits_row = logits_row + scale * (hidden_row @ a) @ bmat

@@ -8,10 +8,19 @@ beside them: the numbers of PERF.md section 5's serve paragraph.
     chiprun -- python3 scripts/kv_stats_probe.py --seed 2147484227 --trace 1 --poll 0.5
 
 Reads a parent without the counters as far as it goes. ``--root`` names
-another checkout to run (it must hold ``benchmark/`` and ``ray_tpu/``).
+another checkout to run (it must hold ``benchmark/`` and ``ray_tpu/``; run the
+script **from** that checkout, so that its workers import that tree too).
+
+``--fixed`` runs no cell: it builds the cell's engine in this process and
+steps it through one cycle of the cell's requests, request ``i`` admitted
+after ``4 i`` device-calling steps, so that every checkout makes the same
+device calls in the same order whatever its speed, and keeps the ids each
+request was given: two checkouts that serve the same tokens print the same
+``ids sha256`` (give each its own ``--out``).
 """
 
 import argparse
+import hashlib
 import json
 import os
 import statistics
@@ -107,6 +116,60 @@ def probe(root, workload, seed, seconds, traced, poll_s=0.0):
     }
 
 
+def fixed_schedule(root, workload, seed, every=4):
+    """One cycle of the cell's requests through its engine, in this process,
+    on a schedule counted in steps; the ids, what the engine counted and the
+    device's peak memory."""
+    root = os.path.abspath(root)
+    sys.path.insert(0, root)
+    from benchmark import manifest
+    from benchmark.server import BenchLLMServer
+    from benchmark.traffic import serve_open_loop
+    from ray_tpu.serve import batching
+
+    cell = manifest.Manifest(root).cell(workload)
+    server = BenchLLMServer(
+        cell.architecture, cell.reference, cell.config, seed=seed, **cell.config["engine"])
+    eng = server._engine
+    warm = server.warm()
+    params = dict(cell.traffic, vocab_size=warm["vocab_size"])
+    turns = len(params["prompt_tokens"])
+    cycle = sorted((
+        r for r in serve_open_loop.schedule(params, seed, (turns + 1) / params["rate_rps"])
+        if 0 <= r["index"] < turns), key=lambda r: r["index"])
+    waiting = [
+        batching._Sequence({"prompt": r["prompt"], "max_new_tokens": r["n_out"]}) for r in cycle
+    ]
+    seqs, active, calling_steps = list(waiting), [], 0
+    before, t0 = eng.stats(), time.perf_counter()
+    while waiting or active:
+        while waiting and (not active or calling_steps >= every * (len(seqs) - len(waiting))):
+            active.append(waiting.pop(0))
+        # by the count every checkout keeps, a parent of PR 31 too
+        calls = eng.phase_n["dispatch"]
+        eng.step(active)
+        calling_steps += eng.phase_n["dispatch"] > calls
+        active = [s for s in active if not s.done]
+    wall_s, after = time.perf_counter() - t0, eng.stats()
+    errors = [repr(s._error) for s in seqs if s._error is not None]
+    ids = [s._result["tokens"] if s._error is None else None for s in seqs]
+    return {
+        "root": root, "workload": workload, "seed": seed, "wall_s": wall_s,
+        "errors": errors, "ids": ids,
+        "sha256": hashlib.sha256(json.dumps(ids).encode()).hexdigest(),
+        "delta": delta(after, before), "device": after["device"], "warm": warm,
+    }
+
+
+def report_fixed(kept, say=print):
+    d = kept["delta"]
+    say(f"{kept['workload']} seed {kept['seed']} fixed schedule: {len(kept['ids'])} requests, "
+        f"{sum(len(t) for t in kept['ids'] if t)} ids, errors {kept['errors']}, "
+        f"{kept['wall_s']:.2f} s; device {kept['device']}")
+    say_split(d, "the whole schedule", say)
+    say(f"  ids sha256 {kept['sha256']}")
+
+
 def delta(after, before):
     out = {}
     for k, v in after.items():
@@ -148,6 +211,10 @@ def say_split(d, label, say=print):
         say(f"     transfers a call: {d['h2d_transfers'] / calls:.2f} up, "
             f"{d['d2h_transfers'] / calls:.2f} down; ids-only calls {d['ids_only_calls']}/"
             f"{n['dispatch']} = {d['ids_only_calls'] / calls:.3f}")
+    if "calls_ahead" in d:              # calls launched behind a call in flight (PR 31)
+        say(f"     run-ahead share {d['calls_ahead']}/{n['dispatch']} = "
+            f"{d['calls_ahead'] / calls:.3f} of the calls were launched while another was in "
+            f"flight; tokens fed on the device {d['tokens_fed_on_device']}/{d['lanes_used']} lanes")
     say(f"     lane_fill {d['lanes_used']}/{d['lane_slots']} = "
         f"{d['lanes_used'] / max(d['lane_slots'], 1):.3f}; cache_fill {d['cache_tokens']}/"
         f"{d['cache_slots']} = {d['cache_tokens'] / max(d['cache_slots'], 1):.3f}")
@@ -219,8 +286,18 @@ def main():
     ap.add_argument("--trace", type=int, default=1)
     ap.add_argument("--poll", type=float, default=0.0, help="seconds between kv_stats polls; 0: none")
     ap.add_argument("--out", default="chiprun_out", help="where probe_<seed>_t<trace>.json goes")
+    ap.add_argument("--fixed", action="store_true", help="the fixed schedule, in this process")
     args = ap.parse_args()
     out = os.path.abspath(args.out)
+    if args.fixed:
+        kept = fixed_schedule(args.root, args.workload, args.seed)
+        os.makedirs(out, exist_ok=True)
+        path = os.path.join(out, f"fixed_{args.workload}_{args.seed}.json")
+        with open(path, "w") as f:
+            json.dump(kept, f, default=str)
+        report_fixed(kept)
+        print("[probe] kept in", path)
+        return
     kept = probe(args.root, args.workload, args.seed, args.seconds, bool(args.trace), args.poll)
     os.makedirs(out, exist_ok=True)
     path = os.path.join(out, f"probe_{args.seed}_t{args.trace}.json")

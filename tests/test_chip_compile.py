@@ -126,7 +126,7 @@ def _extend_at(cfg, shaped, lanes, tc, cap):
     cache = shaped((cfg.num_layers, lanes, cap, cfg.num_heads, cfg.head_dim), cfg.dtype)
     operands = shaped((lanes, llm._operand_width(tc, cap // 16)), jnp.int32)
     return llm._operand_extend(gpt.make_extend_fn(cfg)).lower(
-        params, operands, cache, cache, tc=tc).compile()
+        params, operands, shaped((lanes,), jnp.int32), cache, cache, tc=tc).compile()
 
 
 @pytest.fixture
@@ -159,7 +159,7 @@ def test_command_a_plus_share_extend_compiles_and_copies_no_expert(
     cache = shaped((cfg.num_layers, lanes, cap, cfg.kv_heads, cfg.head_dim), cfg.dtype)
     operands = shaped((lanes, llm._operand_width(256, 8192 // 256)), jnp.int32)
     compiled = llm._operand_extend(cfg.make_extend_fn()).lower(
-        params, operands, cache, cache, tc=tc).compile()
+        params, operands, shaped((8,), jnp.int32), cache, cache, tc=tc).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 2      # the kernel is there
     memory = compiled.memory_analysis()
     assert 9.4e9 < memory.argument_size_in_bytes < 10.6e9
@@ -197,7 +197,7 @@ def test_gptj_serve_paging_programs_compile_beside_the_weights(shaped):
             arena, arena, new, new, shaped((b, width), jnp.int32),
             (shaped((b, tc, cfg.vocab_size), jnp.float32),
              shaped((b, tc, cfg.embed_dim), jnp.float32)),
-            (),
+            (), lanes[-1],
         ).compile()
 
     for b in lanes:
@@ -209,8 +209,9 @@ def test_gptj_serve_paging_programs_compile_beside_the_weights(shaped):
             memory = page_back(b, tc).memory_analysis()
             assert memory.alias_size_in_bytes == 2 * arena_bytes, (b, tc)
             assert memory.temp_size_in_bytes < 2**20, (b, tc)
-            # what it returns beside the arenas: the ids and the picked rows
-            rows_bytes = 4 * b * (1 + cfg.vocab_size + cfg.embed_dim)
+            # what it returns beside the arenas: the ids, as wide as the widest
+            # lane bucket (the next call reads them too), and the picked rows
+            rows_bytes = 4 * (lanes[-1] + b * (cfg.vocab_size + cfg.embed_dim))
             assert 0 <= memory.output_size_in_bytes - 2 * arena_bytes - rows_bytes < 4096
     clone = programs.clone.lower(
         arena, arena, shaped((), jnp.int32), shaped((), jnp.int32)).compile()

@@ -51,3 +51,32 @@ def test_probe_keeps_the_reads_the_benchmark_drops(tmp_path, monkeypatch):
     calls = d["phase_n"]["dispatch"]
     assert d["h2d_transfers"] == d["d2h_transfers"] == d["ids_only_calls"] == calls
     assert f"transfers a call: 1.00 up, 1.00 down; ids-only calls {calls}/{calls}" in text
+    # ... and every call is landed, most of them behind their successor
+    assert d["phase_n"]["fetch"] == d["phase_n"]["sample"] == calls
+    assert 0 < d["calls_ahead"] < calls and 0 < d["tokens_fed_on_device"] <= d["decode_tokens"]
+    assert (f"run-ahead share {d['calls_ahead']}/{calls} = {d['calls_ahead'] / calls:.3f}" in text
+            and f"tokens fed on the device {d['tokens_fed_on_device']}/{d['lanes_used']}" in text)
+
+
+@pytest.mark.parametrize("cell", [c for _, c in bench_helpers.twins("serve", 1)])
+def test_fixed_schedule_makes_the_same_calls_and_ids_every_time(tmp_path, monkeypatch, cell):
+    """One cycle of the cell's requests, stepped in this process on a schedule
+    counted in steps: the ids and the engine's counts are the same in two
+    runs, every request gets its length, and nothing is left in flight."""
+    monkeypatch.setattr(chip, "PLATFORM", "cpu")
+    root = bench_helpers.copy_benchmark(tmp_path)
+    bench_helpers.add_tiny_cells(root)
+    probe = _load()
+    first, again = (probe.fixed_schedule(root, cell, 2**31 + 9) for _ in range(2))
+    assert first["errors"] == [] and first["sha256"] == again["sha256"]
+    assert [len(t) for t in first["ids"]] == bench_helpers.TINY_CHAT["output_tokens"]
+    counted = ("steps", "decode_tokens", "prefill_tokens", "calls_ahead", "tokens_fed_on_device")
+    assert [first["delta"][k] for k in counted] == [again["delta"][k] for k in counted]
+    d = first["delta"]
+    assert d["phase_n"]["fetch"] == d["phase_n"]["dispatch"]
+    assert d["kv_blocks_in_use"] == d["prefix_cached_blocks"]       # nothing leaked
+    # a call is launched behind every call but the first of a busy stretch
+    assert d["phase_n"]["dispatch"] / 2 < d["calls_ahead"] < d["phase_n"]["dispatch"]
+    said = []
+    probe.report_fixed(first, say=lambda *a: said.append(" ".join(map(str, a))))
+    assert f"ids sha256 {first['sha256']}" in "\n".join(said) and "run-ahead share" in "\n".join(said)

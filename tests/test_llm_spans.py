@@ -38,20 +38,28 @@ ENGINE = dict(
     lane_buckets=(1, 2, 4), prefill_token_buckets=(16, 32),
     cache_buckets=(64, 128), prefix_caching=False, deployment="spans",
 )
-CALL_PHASES = tuple(p for p in llm.LEAF_PHASES if p != "admit")     # of one device call, in order
+# of one device call, in order: the launch, which waits for nothing, and (a call later) the landing
+LAUNCH_PHASES, LANDING_PHASES = ("upload", "kv_gather", "dispatch", "kv_scatter"), ("fetch", "sample")
+CALL_PHASES = LAUNCH_PHASES + LANDING_PHASES
+assert CALL_PHASES == tuple(p for p in llm.LEAF_PHASES if p != "admit")
 
 # Three requests of 20, 40 and 9 prompt tokens, 3 new tokens each, through
 # ENGINE (two prefill lanes, chunks of 32): the device calls as (lanes, lane
-# bucket, tokens fed, cache bucket, tokens resident in the lanes' caches).
-#   step 1: prefill A+B (chunks 20, 32); decode A
-#   step 2: prefill B+C (chunks 8, 9);   decode A, B, C (A finishes)
-#   step 3:                              decode B, C (both finish)
+# bucket, tokens fed, cache bucket, tokens resident in the lanes' caches). A
+# call is landed after the next is launched; "<-" marks a lane whose token the
+# call reads on the device, from the call still in flight.
+#   step 1: launch prefill A+B (chunks 20, 32); launch decode A<-, land the chunk
+#   step 2: launch prefill B+C (chunks 8, 9), land the decode; launch decode
+#           A, B<-, C<- (A's last), land the chunk
+#   step 3: launch decode B<-, C<- (their last), land the decode before (A finishes)
+#   step 4: nothing to launch: land the last call (B and C finish)
 LENGTHS, NEW = (20, 40, 9), 3
 CALLS = [
     (2, 2, 32, 64, 0), (1, 1, 1, 64, 20),
     (2, 2, 16, 64, 32), (3, 4, 1, 64, 21 + 40 + 9),
     (2, 2, 1, 64, 41 + 10),
 ]
+STEPS, AHEAD, FED_ON_DEVICE = 4, len(CALLS) - 1, 1 + 2 + 2
 
 
 def _requests(lengths=LENGTHS, new=NEW, vocab=NANO.vocab_size):
@@ -144,19 +152,27 @@ def test_every_phase_is_a_span_nested_in_its_parent(planes):
         return any(a <= child[0] and child[1] <= b for a, b in parents)
 
     steps = _spans(events, "llm.step")
-    assert len(steps) == 3
+    assert len(steps) == STEPS
     calls = _spans(events, "llm.prefill") + _spans(events, "llm.decode")
     assert len(calls) == len(CALLS)
     for top in ("admit", "prefill", "decode"):
         assert all(inside(s, steps) for s in _spans(events, "llm." + top)), top
-    assert len(_spans(events, "llm.admit")) == 3
+    assert len(_spans(events, "llm.admit")) == STEPS
     for phase in CALL_PHASES:
         spans = _spans(events, "llm." + phase)
         assert len(spans) == len(CALLS), phase        # one of each per device call
-        assert all(inside(s, calls) for s in spans), phase
-    # within a device call the phases follow one another
-    order = [n for n, _, _ in sorted(events, key=lambda e: e[1]) if n[4:] in CALL_PHASES]
-    assert order == ["llm." + p for p in CALL_PHASES] * len(CALLS)
+        assert all(inside(s, steps) for s in spans), phase
+        # a launch is its call's own; a landing follows the next call's launch,
+        # but for the last, which has the last step to itself
+        held = spans if phase in LAUNCH_PHASES else spans[:-1]
+        assert all(inside(s, calls) for s in held), phase
+        assert phase in LAUNCH_PHASES or not inside(spans[-1], calls)
+    # a launch's phases follow one another, and so do a landing's: the first
+    # call's launch, then every other launch with the landing of the call
+    # before, then the last landing
+    order = [n[4:] for n, _, _ in sorted(events, key=lambda e: e[1]) if n[4:] in CALL_PHASES]
+    assert tuple(order) == (
+        LAUNCH_PHASES + (LAUNCH_PHASES + LANDING_PHASES) * (len(CALLS) - 1) + LANDING_PHASES)
 
 
 def test_spans_are_on_the_thread_that_does_the_work(xplane):
@@ -215,33 +231,37 @@ def test_reducer_labels_gaps_with_the_phase_under_them(planes):
 
 def test_counters_equal_what_the_shapes_give(engine):
     before = engine.stats()
-    assert _drive(engine, _requests()) == 3
+    assert _drive(engine, _requests()) == STEPS
     after = engine.stats()
     block = ENGINE["block_size"]
     want = {
-        "steps": 3, "admitted": 3,
+        "steps": STEPS, "admitted": 3,
         "prefill_tokens": sum(LENGTHS),
         "decode_tokens": len(LENGTHS) * (NEW - 1),
         "lanes_used": sum(c[0] for c in CALLS),
         "lane_slots": sum(c[1] for c in CALLS),
         "cache_tokens": sum(c[4] for c in CALLS),
         "cache_slots": sum(b * cap for _, b, _, cap, _ in CALLS),
-        # int32 up, one buffer a call: a lane's length, last row and count,
-        # then tokens, the page-back's rows and slots and the block table, each
-        # as wide as the widest of them in any bucket (32 tokens; 128 / 16
-        # blocks); the pair never leaves the device
-        "h2d_bytes": sum(4 * b * (3 + 4 * max(32, 128 // block)) for _, b, _, _, _ in CALLS),
+        # int32 up, one buffer a call: a lane's length, last row, count and
+        # where its token is, then tokens, the page-back's rows and slots and
+        # the block table, each as wide as the widest of them in any bucket
+        # (32 tokens; 128 / 16 blocks); the pair never leaves the device
+        "h2d_bytes": sum(4 * b * (4 + 4 * max(32, 128 // block)) for _, b, _, _, _ in CALLS),
         "h2d_transfers": len(CALLS),
-        # int32 down, one array a call: the id a lane sampled on the device
-        "d2h_bytes": sum(4 * b for _, b, _, _, _ in CALLS),
+        # int32 down, one array a call: the id a lane sampled on the device,
+        # as wide as the widest lane bucket whatever the lanes
+        "d2h_bytes": 4 * max(ENGINE["lane_buckets"]) * len(CALLS),
         "d2h_transfers": len(CALLS),
         "ids_only_calls": len(CALLS),
+        # every call but the first is launched behind the one in flight, and a
+        # decode lane reads its token there unless that call has landed
+        "calls_ahead": AHEAD, "tokens_fed_on_device": FED_ON_DEVICE,
     }
     assert {k: _delta(after, before, k) for k in want} == want
     assert want["lanes_used"] < want["lane_slots"]
     calls = _delta(after, before, "phase_n")
     assert calls == {
-        "step": 3, "admit": 3, "prefill": 2, "decode": 3,
+        "step": STEPS, "admit": STEPS, "prefill": 2, "decode": 3,
         **dict.fromkeys(CALL_PHASES, len(CALLS)),
     }
     assert _delta(after, before, "queue_s") > 0
@@ -352,7 +372,7 @@ def test_phases_cost_less_than_their_budget_outside_a_session(engine, monkeypatc
     monkeypatch.setattr(engine.pool, "gather", lambda operands, n: (None, None))
     monkeypatch.setattr(
         engine.pool, "page_back",
-        lambda k_new, v_new, operands, outputs, counted: (ids[len(operands)], None))
+        lambda k_new, v_new, operands, outputs, counted, width: (ids[width], None))
     monkeypatch.setattr(jax, "device_put", lambda a: a)
     as_it_is, nothing = engine._phase, contextlib.nullcontext()
 

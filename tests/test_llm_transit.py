@@ -50,23 +50,38 @@ def _drive(eng, seqs):
 
 
 def _calls(eng, monkeypatch):
-    """Every device call from here on: its lanes' states, what it returned and
-    what it added to the engine's counters."""
-    kept, real = [], eng._run_extend
-    counted = ("h2d_transfers", "d2h_transfers", "ids_only_calls", "h2d_bytes", "d2h_bytes")
+    """Every device call from here on, kept at its launch and filled in at its
+    landing (a call later): its lanes' states, what it brought home and what
+    each half added to the engine's counters."""
+    kept, launch, land = [], eng._launch, eng._land
+    up, down = ("h2d_transfers", "h2d_bytes"), ("d2h_transfers", "ids_only_calls", "d2h_bytes")
 
-    def kept_call(states, chunks, tc):
-        before = {k: getattr(eng, k) for k in counted}
-        sampled = real(states, chunks, tc)
+    def added(keys, before):
+        return {k: getattr(eng, k) - v for k, v in zip(keys, before)}
+
+    def kept_launch(lanes, chunks, tc, emits):
+        before = [getattr(eng, k) for k in up]
+        flight, ahead = eng._flight, eng.calls_ahead
+        states = [st for _, st in lanes]
+        # a lane whose rows the host needs, with those rows still in flight
+        waits = any(st.on_host and st.call is flight for st in states if flight is not None)
+        call = launch(lanes, chunks, tc, emits)        # lands the call before it
         kept.append({
-            "states": list(states), "sampled": sampled,
-            # does the lane sample a token here, or does its prompt go on
-            "emits": [st.pos + len(ch) >= len(st.prompt) for st, ch in zip(states, chunks)],
-            **{k: getattr(eng, k) - v for k, v in before.items()},
+            "call": call, "states": states, "emits": list(emits), "waits": waits,
+            "ahead": eng.calls_ahead - ahead, **added(up, before),
         })
-        return sampled
+        return call
 
-    monkeypatch.setattr(eng, "_run_extend", kept_call)
+    def kept_land():
+        before = [getattr(eng, k) for k in down]
+        call = land()
+        # the newest call is kept only when its launch returns: it lands later
+        next(c for c in kept if c["call"] is call).update(
+            sampled=call.sampled, **added(down, before))
+        return call
+
+    monkeypatch.setattr(eng, "_launch", kept_launch)
+    monkeypatch.setattr(eng, "_land", kept_land)
     return kept
 
 
@@ -100,13 +115,14 @@ def test_a_tie_goes_to_the_first_index_as_on_the_host(engine, tc, counted):
     counters = (jnp.asarray([5, 6, 7, 8], jnp.int32),) if counted else ()
     home, picked = engine.pool.page_back(
         new, new, jnp.asarray(operands),
-        (jnp.asarray(logits), jnp.zeros((b, tc, cfg.embed_dim), jnp.float32)), counters)
+        (jnp.asarray(logits), jnp.zeros((b, tc, cfg.embed_dim), jnp.float32)), counters, b + 3)
     home, rows = np.asarray(home), np.asarray(picked[0])
     assert home.dtype == np.int32
     assert np.array_equal(rows, logits[np.arange(b), last])
     assert home[:b].tolist() == np.argmax(rows, axis=-1).tolist()
     assert home[:3].tolist() == [3, 100, 0]
-    assert home[b:].tolist() == ([5, 6, 7, 8] if counted else [])
+    # the next call reads the ids here too: one width, whatever the lanes
+    assert home[b:].tolist() == [0] * 3 + ([5, 6, 7, 8] if counted else [])
 
 
 def test_a_mixed_call_gives_each_lane_what_it_gave_before(engine, monkeypatch):
@@ -125,6 +141,8 @@ def test_a_mixed_call_gives_each_lane_what_it_gave_before(engine, monkeypatch):
         adapted = any(st.adapter is not None for st in call["states"])
         asked = any(st.return_logits for st in call["states"])
         mixed += adapted and len(call["states"]) > 2
+        # the host makes these lanes' tokens: nothing is fed to them ahead
+        assert not (call["waits"] and call["ahead"])
         for st, (tok, row, hidden), emits in zip(call["states"], call["sampled"], call["emits"]):
             assert (row is not None) == (adapted or asked)
             assert (hidden is not None) == adapted
@@ -167,7 +185,7 @@ def test_a_call_crosses_the_link_once_each_way_unless_a_lane_needs_its_rows(engi
         (c["h2d_transfers"], c["d2h_transfers"], c["ids_only_calls"]) == (1, 1, 1) for c in calls)
     for c in calls:
         assert c["h2d_bytes"] == 4 * b_of(c) * engine._operand_width
-        assert c["d2h_bytes"] == 4 * b_of(c) + counters
+        assert c["d2h_bytes"] == 4 * engine.lane_buckets[-1] + counters    # one width
     dispatched = after["phase_n"]["dispatch"] - before["phase_n"]["dispatch"]
     assert len(calls) == dispatched
     for k in ("h2d_transfers", "d2h_transfers", "ids_only_calls"):
@@ -185,6 +203,6 @@ def test_a_call_crosses_the_link_once_each_way_unless_a_lane_needs_its_rows(engi
         kinds.add(rows)
         assert (c["h2d_transfers"], c["d2h_transfers"]) == (1, 1 + rows)
         assert c["ids_only_calls"] == (rows == 0)
-        assert c["d2h_bytes"] == 4 * b_of(c) + counters + 4 * b_of(c) * (
+        assert c["d2h_bytes"] == 4 * engine.lane_buckets[-1] + counters + 4 * b_of(c) * (
             (rows > 0) * cfg.vocab_size + (rows > 1) * cfg.embed_dim)
     assert kinds == {0, 1, 2}
