@@ -90,6 +90,14 @@ class Cohere2MoeConfig:
 
     # -- what the serving engine asks of a configuration (``serve/llm.py``) --
 
+    #: what ``extend`` counts, in the order of its last output
+    counters = moe.COUNTERS
+
+    @property
+    def cache_arrays(self):
+        """What a cached token holds, ``(heads, dim)`` per array: K and V."""
+        return ((self.kv_heads, self.head_dim),) * 2
+
     def make_extend_fn(self):
         return make_extend_fn(self)
 

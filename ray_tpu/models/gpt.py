@@ -62,12 +62,18 @@ class GPTConfig:
 
     # -- what the serving engine asks of an architecture's configuration
     # (``serve/llm.py``): the heads its cache stores, its ``extend`` and its
-    # seeded weights; ``models/cohere2_moe.py`` answers the same three --------
+    # seeded weights; ``models/cohere2_moe.py`` and ``models/keye_vl2.py``
+    # answer the same ------------------------------------------------------
 
     @property
     def kv_heads(self) -> int:
         """K/V heads a cache stores: one per query head."""
         return self.num_heads
+
+    @property
+    def cache_arrays(self):
+        """What a cached token holds, ``(heads, dim)`` per array: K and V."""
+        return ((self.kv_heads, self.head_dim),) * 2
 
     def make_extend_fn(self):
         return make_extend_fn(self)

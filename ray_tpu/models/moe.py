@@ -11,7 +11,8 @@ Expert weights carry the ("expert", "embed", "mlp") logical axes: ep shards
 the expert dim, tp can still shard the mlp dim inside each expert.
 
 Beside it, for serving (``models/cohere2_moe.py``): a **dropless** layer for
-one chip's share of an expert-parallel deployment. :func:`sigmoid_top_k`
+one chip's share of an expert-parallel deployment, or for every expert on one
+chip (``models/keye_vl2.py``). :func:`sigmoid_top_k` or :func:`softmax_top_k`
 scores every routed expert, :func:`held_experts_ffn` is told which experts
 live here and computes their part of the result for the tokens routed to
 them: the token-expert pairs are sorted by expert and run through a grouped
@@ -33,17 +34,38 @@ import jax.numpy as jnp
 from ray_tpu.ops import backend
 
 
+#: what :func:`held_experts_ffn` counts over the real tokens of a device call,
+#: in the order it hands them over: tokens, token-expert pairs computed here,
+#: held experts with at least one token, the busiest held expert's pairs. A
+#: model sums them over its expert layers and names them to the serving engine
+#: (``counters`` of its configuration), which sums them over its calls.
+COUNTERS = ("moe_tokens", "moe_assignments", "moe_experts_hit", "moe_load_max")
+
+
+def _top_k_of(scores, k: int):
+    """The ``k`` largest of ``scores`` [n, R], normalised over themselves."""
+    top, experts = jax.lax.top_k(scores, k)
+    return top / top.sum(-1, keepdims=True), experts.astype(jnp.int32)
+
+
+def softmax_top_k(h: jax.Array, router: jax.Array, k: int):
+    """As :func:`sigmoid_top_k` with a softmax over all ``R`` experts for the
+    scores (``norm_topk_prob``: the ``k`` largest probabilities, divided by
+    their sum)."""
+    return _top_k_of(jax.nn.softmax(jnp.dot(
+        h.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1), k)
+
+
 def sigmoid_top_k(h: jax.Array, router: jax.Array, k: int):
     """Route tokens ``h`` [n, d] over every expert the ``router`` [d, R]
     scores: sigmoid scores in float32 (at the highest matmul precision: the
     TPU's default would round ``h`` and the router to bfloat16 and move the
     k-th choice), the ``k`` largest, normalised over the chosen ``k``.
     Returns ``(weights [n, k] float32, experts [n, k] int32)``."""
-    scores = jax.nn.sigmoid(jnp.dot(
+    return _top_k_of(jax.nn.sigmoid(jnp.dot(
         h.astype(jnp.float32), router.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
-    top, experts = jax.lax.top_k(scores, k)
-    return top / top.sum(-1, keepdims=True), experts.astype(jnp.int32)
+        precision=jax.lax.Precision.HIGHEST)), k)
 
 
 #: (rows, contraction, columns) tile of the TPU's grouped matmul. Measured on a

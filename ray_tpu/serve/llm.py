@@ -2,8 +2,10 @@
 split, prefix caching, and LoRA-scale multiplexing over a real model's
 forward pass: ``ray_tpu.models.gpt``, or any architecture whose configuration
 answers what the engine asks of it (``make_extend_fn()``, ``init_params(seed)``
-and ``kv_heads``, beside the sizes ``num_layers``, ``head_dim``, ``embed_dim``,
-``vocab_size``, ``max_seq_len`` and ``dtype``; ``models/cohere2_moe.py``).
+and ``cache_arrays``: what a cached token holds, as ``(heads, dim)`` per array;
+beside the sizes ``num_layers``, ``embed_dim``, ``vocab_size``, ``max_seq_len``
+and ``dtype``; where its ``extend`` counts something, ``counters`` names what;
+``models/cohere2_moe.py``, ``models/keye_vl2.py``).
 
 What PR 9 proved with synthetic step functions (continuous batching,
 admission control, multiplexing) this module composes on an actual model
@@ -11,7 +13,8 @@ admission control, multiplexing) this module composes on an actual model
 serving setup from PAPERS.md):
 
 * :class:`KVBlockPool` — the KV cache is paged into fixed-size token
-  blocks in two arenas that live on the device; sequences lease blocks on
+  blocks in arenas that live on the device, one for each array a cached token
+  holds (K and V; an indexer's keys beside them); sequences lease blocks on
   admission and a :class:`KVLease` frees them **exactly once** on finish /
   cancel / shed / step poison (the same accounting discipline the handle
   enforces for concurrency slots). ``ray_tpu_llm_kv_blocks_in_use`` tracks
@@ -20,8 +23,8 @@ serving setup from PAPERS.md):
 * a device call never moves K/V through the host, and crosses the link
   twice: everything the host decides for the call (tokens, lengths, block
   table, page-back slots) goes up as one int32 buffer (:func:`_sections`);
-  one jitted program gathers the padded pair ``extend`` takes from the
-  arenas, ``extend`` runs, and one donating program pages the new K/V back
+  one jitted program gathers the padded caches ``extend`` takes from the
+  arenas, ``extend`` runs, and one donating program pages the new rows back
   into the arenas, picks each lane's last valid row of logits and hidden
   and takes the argmax of the logits row: the sampled ids (and an expert
   layer's counters) are one int32 array, which is all that comes home. The
@@ -133,10 +136,11 @@ def _sections(operands):
 
 @functools.lru_cache(maxsize=None)
 def _paging_programs():
-    """The three jitted programs that touch a pool's arenas ``[layers,
-    num_blocks, block_size, heads, head_dim]``; jax is imported here because
-    processes that must stay off it load this module too. They are shaped by
-    their arguments alone, so every pool of a process shares them."""
+    """The three jitted programs that touch a pool's arenas, a tuple of arrays
+    ``[layers, num_blocks, block_size, heads, dim]`` that differ in their last
+    two sizes alone; jax is imported here because processes that must stay off
+    it load this module too. They are shaped by their arguments alone, so every
+    pool of a process shares them."""
     import types
 
     import jax
@@ -148,47 +152,56 @@ def _paging_programs():
     # call: 1.17 GB at GPT-J's serve sizes, where 1 GB is free beside the
     # weights (``tests/test_chip_compile.py`` holds the programs to this).
 
-    @functools.partial(jax.jit, static_argnums=3)
+    @functools.partial(jax.jit, static_argnums=2)
     @jax.named_scope("paging.gather")
-    def gather(k_data, v_data, operands, n):
-        layers, _, block, heads, hd = k_data.shape
+    def gather(arenas, operands, n):
+        layers, blocks, block = arenas[0].shape[:3]
         b = operands.shape[0]
         flat = _sections(operands)[3][:, :n].reshape(-1)
-        # every block of the pair is written below; one buffer each, because
-        # the compiler copies a value that starts both loop carries
-        empty = functools.partial(
-            jax.lax.empty, (layers, b * n, block, heads, hd), k_data.dtype)
+        # a block of an arena whose rows are narrower than the chip's 128 lanes
+        # (an indexer's key: 1 x 64) is moved as one flat row: moved as it
+        # lies, the compiler pads every row to the lanes inside the loop and
+        # holds the padded copy, eight times the cache (0.8 GB at four lanes of
+        # 32768); flat it still re-lays the small cache out once (twice its
+        # size, ``tests/test_chip_compile.py``). K and V are moved as they lie.
+        sources = tuple(
+            a.reshape(layers, blocks, -1) if a.shape[-1] % 128 else a for a in arenas)
+        # every block of the caches is written below; one buffer each, because
+        # the compiler copies a value that starts two loop carries
+        empty = tuple(
+            jax.lax.empty((layers, b * n) + a.shape[2:], a.dtype) for a in sources)
 
-        def copy_block(i, pairs):
+        def copy_block(i, caches):
             return tuple(
                 jax.lax.dynamic_update_slice_in_dim(
                     out, jax.lax.dynamic_slice_in_dim(arena, flat[i], 1, axis=1),
                     i, axis=1)
-                for out, arena in zip(pairs, (k_data, v_data)))
+                for out, arena in zip(caches, sources))
 
-        pairs = jax.lax.fori_loop(0, b * n, copy_block, (empty(), empty()))
-        return tuple(p.reshape(layers, b, n * block, heads, hd) for p in pairs)
+        caches = jax.lax.fori_loop(0, b * n, copy_block, empty)
+        return tuple(
+            c.reshape((layers, b, n * block) + a.shape[3:]) for c, a in zip(caches, arenas))
 
-    @functools.partial(jax.jit, donate_argnums=(0, 1), static_argnums=7)
+    @functools.partial(jax.jit, donate_argnums=0, static_argnums=5)
     @jax.named_scope("paging.page_back")
-    def page_back(k_data, v_data, k_new, v_new, operands, outputs, counted, width):
-        layers, blocks, block, heads, hd = k_data.shape
-        b, tc = k_new.shape[1:3]
-        news = tuple(x.reshape(layers, b * tc, heads, hd) for x in (k_new, v_new))
+    def page_back(arenas, news, operands, outputs, counted, width):
+        layers, blocks, block = arenas[0].shape[:3]
+        b, tc = news[0].shape[1:3]
+        news = tuple(x.reshape((layers, b * tc) + x.shape[3:]) for x in news)
         rows, slots = (x[:, :tc].reshape(-1) for x in _sections(operands)[1:3])
         last = operands[:, _LAST]
 
-        def write_token(i, arenas):
+        def write_token(i, tokens_of):
             return tuple(
                 jax.lax.dynamic_update_slice_in_dim(
                     tokens, jax.lax.dynamic_slice_in_dim(new, rows[i], 1, axis=1),
                     slots[i], axis=1)
-                for tokens, new in zip(arenas, news))
+                for tokens, new in zip(tokens_of, news))
 
         # the count is traced: a loop the compiler cannot unroll, whatever the
-        # shapes (unrolled at one token it re-lays both arenas out and back)
-        arenas = jax.lax.fori_loop(0, operands[0, _COUNT], write_token, tuple(
-            a.reshape(layers, blocks * block, heads, hd) for a in (k_data, v_data)))
+        # shapes (unrolled at one token it re-lays the arenas out and back)
+        written = jax.lax.fori_loop(0, operands[0, _COUNT], write_token, tuple(
+            a.reshape((layers, blocks * block) + a.shape[3:]) for a in arenas))
         picked = tuple(
             jnp.stack([
                 jax.lax.dynamic_index_in_dim(o[i], last[i], 0, keepdims=False)
@@ -201,33 +214,36 @@ def _paging_programs():
             # width whatever the lanes, so ``extend`` has no program more for it
             home = jnp.concatenate([
                 jnp.pad(ids, (0, width - b)), *(c.astype(jnp.int32) for c in counted)])
-        return tuple(a.reshape(k_data.shape) for a in arenas) + (home, picked)
+        return tuple(w.reshape(a.shape) for w, a in zip(written, arenas)), home, picked
 
-    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    @functools.partial(jax.jit, donate_argnums=0)
     @jax.named_scope("paging.clone")
-    def clone(k_data, v_data, src, dst):
+    def clone(arenas, src, dst):
         def copy(arena):
             one = jax.lax.dynamic_slice_in_dim(arena, src, 1, axis=1)
             return jax.lax.dynamic_update_slice_in_dim(arena, one, dst, axis=1)
 
-        return copy(k_data), copy(v_data)
+        return tuple(copy(a) for a in arenas)
 
     return types.SimpleNamespace(gather=gather, page_back=page_back, clone=clone)
 
 
 class KVBlockPool:
-    """Fixed-size token blocks of K/V storage in two refcounted arenas that
-    live on the device.
+    """Fixed-size token blocks of per-token state in refcounted arenas that
+    live on the device, one for each array a cached token holds.
 
-    Layout: ``k_data``/``v_data`` are device arrays ``[layers, num_blocks,
-    block_size, kv_heads, head_dim]`` in the model's dtype (``kv_heads`` is
-    the configuration's: the heads a cache stores, fewer than the query heads
-    where attention is grouped); a sequence owns an
+    Layout: ``arenas`` is a tuple of device arrays ``[layers, num_blocks,
+    block_size, heads, dim]`` in the model's dtype, one per entry ``(heads,
+    dim)`` of the configuration's ``cache_arrays``: K and V over the heads a
+    cache stores (fewer than the query heads where attention is grouped), and
+    whatever else the model leaves behind for a token (an indexer's key). A
+    sequence owns an
     ordered list of block ids whose concatenation is its cache, so the
-    blocks a table names, side by side, are the padded pair ``extend``
+    blocks a table names, side by side, are the padded caches ``extend``
     takes. Blocks are refcounted so the prefix cache can share full prompt
     blocks across sequences; a block returns to the free list when its last
-    reference drops. Allocation, refcounts and leases are host bookkeeping;
+    reference drops. Allocation, refcounts and leases are host bookkeeping
+    and per block, whatever a block holds;
     the arenas are only ever touched by the three programs of
     :func:`_paging_programs` (``page_back`` and ``clone`` donate them), each
     compiled by :meth:`warm` before a request is served."""
@@ -242,21 +258,17 @@ class KVBlockPool:
         self.block_size = int(block_size)
         self.deployment = deployment
         self.dtype = jnp.dtype(jnp.float32 if cfg.dtype is None else cfg.dtype)
-        shape = (
-            cfg.num_layers, self.num_blocks, self.block_size,
-            cfg.kv_heads, cfg.head_dim,
-        )
+        slab = (cfg.num_layers, self.num_blocks, self.block_size)
         try:
-            self.k_data = jnp.zeros(shape, self.dtype)
-            self.v_data = jnp.zeros(shape, self.dtype)
-            jax.block_until_ready((self.k_data, self.v_data))
+            self.arenas = tuple(
+                jnp.zeros(slab + tuple(each), self.dtype) for each in cfg.cache_arrays)
+            jax.block_until_ready(self.arenas)
         except Exception as e:  # noqa: BLE001 — the runtime's out-of-memory
-            each = math.prod(shape) * self.dtype.itemsize
             raise MemoryError(
                 f"the KV pool does not fit on the device: {self.num_blocks} "
                 f"blocks of {self.block_size} tokens x {cfg.num_layers} layers "
-                f"x {cfg.kv_heads} K/V heads x {cfg.head_dim} in {self.dtype} are "
-                f"{each} bytes an arena, {2 * each} for K and V, beside "
+                f"x {cfg.cache_arrays} (heads, dim) an array in {self.dtype} are "
+                f"{self.cache_bytes(self.num_blocks * self.block_size)} bytes, beside "
                 f"{accelerator.device_report()}: {e!r}"
             ) from e
         self._free: List[int] = list(range(self.num_blocks))
@@ -265,18 +277,33 @@ class KVBlockPool:
         self._evict_cb: Optional[Callable[[int], None]] = None
         self.freed_total = 0
 
+    @property
+    def k_data(self):
+        """The first arena (K), for a reader of the pool's layout."""
+        return self.arenas[0]
+
+    @property
+    def v_data(self):
+        return self.arenas[1]
+
+    def cache_bytes(self, tokens: int) -> int:
+        """The bytes ``tokens`` cached tokens take, over all layers and arrays."""
+        per_token = sum(heads * dim for heads, dim in self.cfg.cache_arrays)
+        return self.cfg.num_layers * tokens * per_token * self.dtype.itemsize
+
     # -- the arenas: device programs only ----------------------------------
 
     def gather(self, operands, n: int):
-        """The padded pair ``[layers, b, n * block_size, heads, head_dim]`` of
+        """The padded caches ``[layers, b, n * block_size, heads, dim]``, one
+        per arena, of
         the lanes whose block ids are the first ``n`` of each row of the
         ``table`` section of ``operands`` (a device array ``[b, width]``,
         :func:`_sections`; every entry a valid block), built on the device."""
-        return _paging_programs().gather(self.k_data, self.v_data, operands, n)
+        return _paging_programs().gather(self.arenas, operands, n)
 
-    def page_back(self, k_new, v_new, operands, outputs, counted, width: int):
-        """Write token ``rows[i]`` (an index into lanes x tokens) of ``k_new``
-        / ``v_new`` ``[layers, b, tc, heads, head_dim]`` into the arenas at
+    def page_back(self, news, operands, outputs, counted, width: int):
+        """Write token ``rows[i]`` (an index into lanes x tokens) of each of
+        ``news`` ``[layers, b, tc, heads, dim]`` (one per arena) into its arena at
         token slot ``slots[i]`` (block x block_size + offset) for the first
         ``count`` entries of the ``rows`` / ``slots`` sections of ``operands``
         and, in the same program, pick row ``last[i]`` of lane ``i`` from each
@@ -285,21 +312,20 @@ class KVBlockPool:
         device (the ``b`` greedy ids padded to ``width``, then the int32
         arrays of ``counted``), and the picked rows: all on the device. The
         arenas are donated: nothing is copied but the new rows."""
-        self.k_data, self.v_data, home, picked = _paging_programs().page_back(
-            self.k_data, self.v_data, k_new, v_new, operands, outputs,
-            tuple(counted), width)
+        self.arenas, home, picked = _paging_programs().page_back(
+            self.arenas, tuple(news), operands, outputs, tuple(counted), width)
         return home, picked
 
     def clone_block(self, src: int, dst: int) -> None:
         """Copy block ``src`` onto block ``dst``, on the device."""
-        self.k_data, self.v_data = _paging_programs().clone(
-            self.k_data, self.v_data, np.int32(src), np.int32(dst))
+        self.arenas = _paging_programs().clone(
+            self.arenas, np.int32(src), np.int32(dst))
 
     def read_block(self, b: int):
-        """Block ``b`` on the host: K and V ``[layers, block_size, heads,
-        head_dim]``. For tests and debugging, not for the step path (eager
-        indexing compiles)."""
-        return np.asarray(self.k_data[:, b]), np.asarray(self.v_data[:, b])
+        """Block ``b`` on the host: one array ``[layers, block_size, heads,
+        dim]`` per arena (K, V, ...). For tests and debugging, not for the step
+        path (eager indexing compiles)."""
+        return tuple(np.asarray(a[:, b]) for a in self.arenas)
 
     def warm(self, extend_shapes: Dict[Any, Any], cache_buckets) -> None:
         """Compile every paging program the engine's buckets allow, on zeros
@@ -313,20 +339,21 @@ class KVBlockPool:
         width = _operand_width(
             max(tc for _, tc in extend_shapes),
             max(cache_buckets) // self.block_size)
-        lanes = max(b for b, _ in extend_shapes)
+        lanes, held = max(b for b, _ in extend_shapes), len(self.arenas)
         for b in sorted({b for b, _ in extend_shapes}):
             operands = jnp.zeros((b, width), jnp.int32)
             for cap in cache_buckets:
                 jax.block_until_ready(
                     self.gather(operands, cap // self.block_size))
-        for (b, tc), (logits, hidden, k_new, _, *counted) in extend_shapes.items():
-            new = jnp.zeros(k_new.shape, k_new.dtype)
+        for (b, tc), (logits, hidden, *rest) in extend_shapes.items():
+            news, counted = rest[:held], rest[held:]
             jax.block_until_ready(self.page_back(
-                new, new, jnp.zeros((b, width), jnp.int32),
+                tuple(jnp.zeros(x.shape, x.dtype) for x in news),
+                jnp.zeros((b, width), jnp.int32),
                 tuple(jnp.zeros(o.shape, o.dtype) for o in (logits, hidden)),
                 tuple(jnp.zeros(c.shape, c.dtype) for c in counted), lanes))
         self.clone_block(0, 0)
-        jax.block_until_ready((self.k_data, self.v_data))
+        jax.block_until_ready(self.arenas)
 
     # -- host bookkeeping ---------------------------------------------------
 
@@ -575,12 +602,11 @@ def _operand_extend(extend):
     import jax.numpy as jnp
 
     @functools.partial(jax.jit, static_argnames="tc")
-    def extend_call(params, operands, home, k_cache, v_cache, *, tc):
+    def extend_call(params, operands, home, *caches, tc):
         tokens, source = _sections(operands)[0][:, :tc], operands[:, _FROM]
         first = jnp.where(source < 0, tokens[:, 0], home[jnp.maximum(source, 0)])
         return extend(
-            params, tokens.at[:, 0].set(first), operands[:, _LENGTH],
-            k_cache, v_cache)
+            params, tokens.at[:, 0].set(first), operands[:, _LENGTH], *caches)
 
     return extend_call
 
@@ -630,11 +656,6 @@ LEAF_PHASES = (
     "admit", "upload", "kv_gather", "dispatch", "kv_scatter", "fetch", "sample",
 )
 PHASES = ("step", "prefill", "decode") + LEAF_PHASES
-#: what an expert layer counts over the real tokens of a device call, in the
-#: order ``moe.held_experts_ffn`` hands them over: tokens, token-expert pairs
-#: computed here, held experts with at least one token, the busiest held
-#: expert's pairs; each summed over the expert layers
-MOE_COUNTERS = ("moe_tokens", "moe_assignments", "moe_experts_hit", "moe_load_max")
 #: what one ``_phase`` may cost outside a profiler session, where its span is
 #: a no-op (2.7 us on the sandbox's CPU): under 0.3 ms for the <= 30 phases of
 #: a step. ``tests/test_llm_spans.py`` holds the engine to it.
@@ -691,7 +712,7 @@ class LLMEngine:
         if any(cap % self.block_size for cap in self.cache_buckets):
             raise ValueError(
                 f"cache buckets {self.cache_buckets} must be whole blocks of "
-                f"{self.block_size} tokens: the padded pair is a block table")
+                f"{self.block_size} tokens: a padded cache is a block table")
         self.pool = KVBlockPool(
             self.cfg, num_blocks=num_blocks, block_size=block_size,
             deployment=deployment,
@@ -726,9 +747,15 @@ class LLMEngine:
         self.lane_slots = 0             # their lane buckets
         self.calls_ahead = 0            # calls launched while another was in flight
         self.tokens_fed_on_device = 0   # lanes whose token the call before left there
-        # what an expert layer counted, summed over the device calls (0 for
-        # a model without one)
-        self.moe: Dict[str, int] = dict.fromkeys(MOE_COUNTERS, 0)
+        # what ``extend`` counted (one int32 vector behind the caches' new
+        # rows), summed over the device calls under the configuration's names
+        # for it, and what the configuration counts of a call's gathered
+        # caches on the host (``count_gathered``): the engine knows neither
+        self._counter_names = tuple(getattr(self.cfg, "counters", ()))
+        self.counted: Dict[str, int] = dict.fromkeys(self._counter_names, 0)
+        self._count_gathered = getattr(self.cfg, "count_gathered", None)
+        if self._count_gathered is not None:
+            self.counted.update(dict.fromkeys(self._count_gathered(0, 0), 0))
         # cache slots gathered for layers whose queries see a window only
         # (``cfg.sliding_window``, ``cfg.sliding_layers``; 0 without them), and
         # those of them that hold a token too old for any query of the call to
@@ -776,7 +803,7 @@ class LLMEngine:
             for b in self.lane_buckets
             for tc in [1] + self.prefill_token_buckets
         }
-        _, _, _, _, *counted = outputs[self.lane_buckets[0], 1]
+        counted = outputs[self.lane_buckets[0], 1][2 + len(self.pool.arenas):]
         self._home_width += sum(math.prod(c.shape) for c in counted)
         #: the bytes ``extend`` hands back for (lanes, tokens), for ``_fits``
         self._output_bytes = {
@@ -788,12 +815,12 @@ class LLMEngine:
     def _extend_args(self, make, b: int, cap: int):
         """The arrays ``_extend_call`` takes for ``b`` lanes and a cache of
         ``cap``, each made by ``make(shape, dtype)``."""
-        cfg = self.cfg
-        kv = make(
-            (cfg.num_layers, b, cap, cfg.kv_heads, cfg.head_dim), self.pool.dtype)
+        caches = (
+            make((self.cfg.num_layers, b, cap) + tuple(each), self.pool.dtype)
+            for each in self.cfg.cache_arrays)
         return (
             self._params, make((b, self._operand_width), np.int32),
-            make((self._home_width,), np.int32), kv, kv)
+            make((self._home_width,), np.int32), *caches)
 
     def extend_shapes(self) -> List[tuple]:
         """Every (lanes, tokens, cache) a step can ask ``extend`` for, one
@@ -874,7 +901,7 @@ class LLMEngine:
             "lane_slots": self.lane_slots,
             "calls_ahead": self.calls_ahead,
             "tokens_fed_on_device": self.tokens_fed_on_device,
-            **self.moe,
+            **self.counted,
             "window_slots": self.window_slots,
             "window_slots_outside": self.window_slots_outside,
             "phase_s": dict(self.phase_s),
@@ -1192,9 +1219,12 @@ class LLMEngine:
         with self._phase("kv_gather"):
             # slots past a lane's frontier hold what the pool holds there:
             # zeros or finite model output, which extend's mask weighs 0
-            k_cache, v_cache = self.pool.gather(operands, t_cap // bs)
+            caches = self.pool.gather(operands, t_cap // bs)
             self.cache_tokens += sum(st.length for st in states)
             self.cache_slots += b * t_cap
+            if self._count_gathered is not None:
+                for name, n in self._count_gathered(b, t_cap).items():
+                    self.counted[name] += n
             if self._window_layers:
                 # live tokens older than the oldest position the lane's first
                 # query sees, length - window + 1
@@ -1202,19 +1232,18 @@ class LLMEngine:
                 self.window_slots_outside += self._window_layers * sum(
                     max(0, st.length - self._window + 1) for st in states)
         with self._phase("dispatch"):
-            logits, hidden, k_new, v_new, *counted = self._extend_call(
+            logits, hidden, *rest = self._extend_call(
                 self._params, operands,
-                self._no_home if flight is None else flight.home,
-                k_cache, v_cache, tc=tc)
-            del k_cache, v_cache    # the pair is freed when extend has run
+                self._no_home if flight is None else flight.home, *caches, tc=tc)
+            news, counted = rest[:len(caches)], rest[len(caches):]
+            del caches, rest        # the caches are freed when extend has run
             self.lanes_used += len(states)
             self.lane_slots += b
             self.calls_ahead += flight is not None
         with self._phase("kv_scatter"):
             home, picked = self.pool.page_back(
-                k_new, v_new, operands, (logits, hidden), counted,
-                self.lane_buckets[-1])
-            del logits, hidden, k_new, v_new, operands
+                news, operands, (logits, hidden), counted, self.lane_buckets[-1])
+            del logits, hidden, news, operands
             call = _Call(home, picked, lanes, emits)
             for i, (st, ch, emit) in enumerate(zip(states, chunks, emits)):
                 st.call, st.lane = call, i
@@ -1238,18 +1267,17 @@ class LLMEngine:
     def _fits(self, b: int, tc: int, t_cap: int) -> bool:
         """Whether the device has room for what a call of this shape takes at
         its launch, beside what the call in flight still holds: the padded
-        pair, ``extend``'s outputs and its temporaries (those of the largest
+        caches, ``extend``'s outputs and its temporaries (those of the largest
         shape, where ``warm`` has asked the compiler). Outputs are allocated
         when a program is launched, not when it runs. By the runtime's own
         count of free bytes, and of the largest free block where it keeps one
-        (free bytes in pieces hold no pair); a backend that counts none (the
+        (free bytes in pieces hold no cache); a backend that counts none (the
         CPU's) always has room."""
         if not self._counts_bytes:
             return True
-        memory, cfg = self._device.memory_stats(), self.cfg
-        pair = 2 * cfg.num_layers * b * t_cap * cfg.kv_heads * cfg.head_dim
+        memory = self._device.memory_stats()
         need = (
-            pair * self.pool.dtype.itemsize + self._output_bytes[b, tc]
+            self.pool.cache_bytes(b * t_cap) + self._output_bytes[b, tc]
             + self._temp_bytes)
         free = memory["bytes_limit"] - memory["bytes_in_use"]
         return need <= min(free, memory.get("largest_free_block_bytes", free))
@@ -1268,8 +1296,9 @@ class LLMEngine:
             # waits for the device; then the ids are home
             home = np.asarray(call.home)
             fetched = [home]
-            for name, n in zip(MOE_COUNTERS, home[self.lane_buckets[-1]:]):  # an expert layer's
-                self.moe[name] += int(n)
+            # behind the ids: what ``extend`` counted, in its names' order
+            for name, n in zip(self._counter_names, home[self.lane_buckets[-1]:]):
+                self.counted[name] += int(n)
             adapted = any(st.adapter is not None for st in states)
             logits = hidden = None
             if adapted or any(st.return_logits for st in states):

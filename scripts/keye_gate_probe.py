@@ -1,0 +1,162 @@
+"""What the Keye-VL-2.0 cell's gate reads on the chip, outside a benchmark run:
+for each seed, the gate's prompt through the engine uncached and from the
+prefix cache (bitwise equal?), the served logits against the plain reference's
+(``yardstick.logits_error``, and the worst row), against the reference with
+each named omission, and how far the program's selected sets overlap the
+reference's. The builder sets ``reference.max_logits_error`` in the
+configuration's file from these readings, by hand (PERF.md, section 6).
+
+    python3 scripts/keye_gate_probe.py --seeds 1,2,3 --wrong-seeds 1 --overlap-seeds 1 \
+        [--tiny] [--out chiprun_out/keye_gate.jsonl]
+
+One engine serves every seed (its programs compile once); a seed draws the
+weights and the prompt. ``--tiny`` runs the tests' tiny model on whatever
+backend is there; without it the configuration's file at its published widths,
+which needs the chip."""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+
+def say(msg: str) -> None:
+    print(f"[gate] {msg}", flush=True)
+
+
+def selection_of(probe, cfg, params, fed, chunk: int, cap: int):
+    """What each query of ``fed`` selected, bool [layers, seq, seq], by the
+    program: the prompt in chunks of ``chunk`` (the prefill form), what is left
+    a token at a time (the decode form), over caches of ``cap`` slots."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    caches = [
+        jnp.zeros((cfg.num_layers, 1, cap) + tuple(each), cfg.dtype) for each in cfg.cache_arrays]
+    seq, whole = len(fed), len(fed) // chunk * chunk
+    out = np.zeros((cfg.num_layers, seq, seq), bool)
+    at = 0
+    while at < seq:
+        n = chunk if at < whole else 1
+        tokens = jnp.asarray([fed[at:at + n]], jnp.int32)
+        *_, k, v, i, _, selected = probe(params, tokens, jnp.full((1,), at, jnp.int32), *caches)
+        caches = [
+            c.at[:, :, at:at + n].set(new) for c, new in zip(caches, (k, v, i))]
+        out[:, at:at + n] = np.asarray(selected)[:, 0, :, :seq]
+        at += n
+    return out
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--seeds", required=True)
+    ap.add_argument("--wrong-seeds", default="")
+    ap.add_argument("--overlap-seeds", default="")
+    ap.add_argument("--tiny", action="store_true")
+    ap.add_argument("--out", default=None)
+    args = ap.parse_args(argv)
+
+    import jax
+    import numpy as np
+
+    from benchmark import manifest, yardstick
+    from benchmark.models import keye_vl2 as arch
+    from benchmark.reference import keye_vl2_reference as ref
+    from ray_tpu.models import keye_vl2
+    from ray_tpu.serve import batching, llm
+
+    if args.tiny:
+        with open(os.path.join(ROOT, "tests", "benchmark", "tiny", "keye_vl2.json")) as f:
+            tiny = json.load(f)
+        config, prompt_tokens, new = {**tiny["model"], "reference": tiny["reference"]}, 96, 4
+        sizes = dict(num_blocks=32, block_size=16, prefill_chunk=32, lane_buckets=(1,),
+                     prefill_token_buckets=(32,), cache_buckets=(128,))
+    else:
+        book = manifest.Manifest(ROOT)
+        cell = book.cell("keye-vl2-serve-long-context")
+        config = cell.config
+        prompt_tokens, new = cell.traffic["gate_prompt_tokens"], cell.traffic["gate_new_tokens"]
+        sizes = dict(num_blocks=64, block_size=256, prefill_chunk=512, lane_buckets=(1,),
+                     prefill_token_buckets=(512,), cache_buckets=(8192,))
+    cfg = arch.program_config(manifest.published_keys(config))
+    seeds = [int(s) for s in args.seeds.split(",")]
+    wrong_seeds = {int(s) for s in args.wrong_seeds.split(",") if s}
+    overlap_seeds = {int(s) for s in args.overlap_seeds.split(",") if s}
+    say(f"{jax.devices()[0].device_kind}; {arch.describe(cfg)}; engine {sizes}")
+    params = cfg.init_params(seeds[0])
+    engine = llm.LLMEngine(cfg, params, **sizes)
+    probe = keye_vl2.make_probe_fn(cfg)
+    rows = []
+    for seed in seeds:
+        t0 = time.perf_counter()
+        if seed != seeds[0]:
+            engine._params = params = None         # one set of weights on the device at a time
+            engine._params = params = cfg.init_params(seed)
+        rng = np.random.default_rng(seed + 1)
+        prompt = [int(t) for t in rng.integers(0, cfg.vocab_size, size=prompt_tokens)]
+        results = []
+        for _ in range(2):
+            seq = batching._Sequence(
+                {"prompt": prompt, "max_new_tokens": new, "return_logits": True})
+            while not seq.done:
+                engine.step([seq])
+            if seq._error is not None:
+                raise seq._error
+            results.append(seq._result)
+        first, again = results
+        fed = prompt + first["tokens"][:-1]
+        want = np.asarray(ref.program_logits(params, fed, config, new))
+        per_row = [yardstick.logits_error(first["logits"][r], want[r]) for r in range(new)]
+        row = {
+            "seed": seed,
+            "cached_tokens": [first["prefix_cached_tokens"], again["prefix_cached_tokens"]],
+            "bitwise": bool(
+                again["tokens"] == first["tokens"]
+                and np.array_equal(again["logits"], first["logits"])),
+            "error": yardstick.logits_error(first["logits"], want),
+            "worst_row": max(per_row), "median_row": float(np.median(per_row)),
+            "max_abs": float(np.abs(first["logits"] - want).max()),
+            "argmax_agree": int((want.argmax(-1) == np.asarray(first["tokens"])).sum()),
+        }
+        if seed in wrong_seeds:
+            row["wrong"] = {
+                w: yardstick.logits_error(
+                    first["logits"], np.asarray(ref.program_logits(params, fed, config, new, w)))
+                for w in ref.WRONG + (ref.LOWER,)}
+        if seed in overlap_seeds:
+            theirs = ref.program_selection(params, fed, config)
+            ours = selection_of(
+                probe, cfg, params, fed, sizes["prefill_chunk"], sizes["cache_buckets"][-1])
+            choosing = np.arange(len(fed)) >= cfg.topk         # queries with a choice to make
+            shared = (ours & theirs)[:, choosing].sum(-1) / theirs[:, choosing].sum(-1)
+            row["overlap"] = {
+                "queries": int(choosing.sum()), "sizes_equal": bool(
+                    (ours.sum(-1) == theirs.sum(-1)).all()),
+                "mean_by_layer": [float(x) for x in shared.mean(-1)],
+                "min_by_layer": [float(x) for x in shared.min(-1)],
+                "decode_form_mean": float(shared[:, prompt_tokens - cfg.topk:].mean())
+                if len(fed) > prompt_tokens else None,
+            }
+        row["seconds"] = time.perf_counter() - t0
+        say(json.dumps(row))
+        rows.append(row)
+    if args.out:
+        os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
+        with open(args.out, "a") as f:
+            for row in rows:
+                f.write(json.dumps(row) + "\n")
+    errors = [r["error"] for r in rows]
+    say(f"errors over {len(rows)} seeds: min {min(errors):.5f} max {max(errors):.5f}; "
+        f"bitwise {all(r['bitwise'] for r in rows)}")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -223,6 +223,13 @@ def say_split(d, label, say=print):
             f"computed here ({d['moe_assignments'] / d['moe_tokens']:.3f} a token), "
             f"{d['moe_experts_hit'] / calls:.2f} held experts hit a call (summed over layers), "
             f"busiest expert {d['moe_load_max'] / max(d['moe_assignments'], 1):.3f} of the pairs")
+    if d.get("sparse_queries"):         # an indexer's counters (PR 32)
+        say(f"     indexer: {d['sparse_keys_scored']} query-key pairs scored for "
+            f"{d['sparse_queries']} query-layers, {d['sparse_keys_attended']} attended "
+            f"({d['sparse_keys_attended'] / max(d['sparse_keys_scored'], 1):.3f} of the scored); "
+            f"slots read {d['sparse_slots_read']}/{d['sparse_slots_gathered']} = "
+            f"{d['sparse_slots_read'] / max(d['sparse_slots_gathered'], 1):.3f} of the slots "
+            f"gathered, over the layers")
     if d.get("window_slots"):
         say(f"     window: {d['window_slots_outside']}/{d['window_slots']} = "
             f"{d['window_slots_outside'] / d['window_slots']:.3f} of the slots gathered for "

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.models import cohere2_moe, gpt
+from ray_tpu.models import cohere2_moe, gpt, keye_vl2
 from ray_tpu.models.training import (
     abstract_state,
     default_optimizer,
@@ -189,12 +189,12 @@ def test_gptj_serve_paging_programs_compile_beside_the_weights(shaped):
 
     def gather(b, cap):
         return programs.gather.lower(
-            arena, arena, shaped((b, width), jnp.int32), cap // block).compile()
+            (arena, arena), shaped((b, width), jnp.int32), cap // block).compile()
 
     def page_back(b, tc):
         new = shaped((cfg.num_layers, b, tc, cfg.num_heads, cfg.head_dim), cfg.dtype)
         return programs.page_back.lower(
-            arena, arena, new, new, shaped((b, width), jnp.int32),
+            (arena, arena), (new, new), shaped((b, width), jnp.int32),
             (shaped((b, tc, cfg.vocab_size), jnp.float32),
              shaped((b, tc, cfg.embed_dim), jnp.float32)),
             (), lanes[-1],
@@ -214,7 +214,7 @@ def test_gptj_serve_paging_programs_compile_beside_the_weights(shaped):
             rows_bytes = 4 * (lanes[-1] + b * (cfg.vocab_size + cfg.embed_dim))
             assert 0 <= memory.output_size_in_bytes - 2 * arena_bytes - rows_bytes < 4096
     clone = programs.clone.lower(
-        arena, arena, shaped((), jnp.int32), shaped((), jnp.int32)).compile()
+        (arena, arena), shaped((), jnp.int32), shaped((), jnp.int32)).compile()
     assert clone.memory_analysis().alias_size_in_bytes == 2 * arena_bytes
     assert clone.memory_analysis().temp_size_in_bytes < 2**20
 
@@ -228,9 +228,101 @@ def test_gptj_serve_paging_programs_compile_beside_the_weights(shaped):
     assert weights_bytes + pair_bytes(b, cap) + _device_bytes(page_back(b, tc)) < HBM_BYTES
 
 
+def _keye_stage():
+    """The served cut of Keye-VL-2.0's language model and its engine sizes,
+    from the configuration's file."""
+    import json
+
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "configs",
+            "keye-vl2-30b-a3b-serve.json")) as f:
+        config = json.load(f)
+    return keye_vl2.KeyeVL2Config(num_layers=config["num_hidden_layers"]), config
+
+
+@pytest.mark.parametrize("form", ["decode", "prefill"])
+def test_keye_vl2_stage_extend_compiles_at_its_largest_shapes(shaped, form, built_for_tpu):
+    """One pipeline stage of Keye-VL-2.0's language model at its published
+    widths (six layers, every expert, the whole vocabulary: 8.75 GB of weights)
+    over the largest cache bucket, in both forms of the selection: it fits
+    beside the pool and a second call's caches, copies no layer's experts
+    (1.2 GB) and holds the memory the configuration's file states."""
+    built_for_tpu(True)     # the chip's grouped matmul
+    cfg, config = _keye_stage()
+    engine, stated = config["engine"], config["compiled_bytes_per_device"]
+    cap, lanes = engine["cache_buckets"][-1], engine["lane_buckets"][-1]
+    b, tc = (lanes, 1) if form == "decode" else (1, engine["prefill_token_buckets"][-1])
+    params = jax.tree.map(
+        lambda x: shaped(x.shape, x.dtype), jax.eval_shape(lambda: cfg.init_params(0)))
+    caches = [
+        shaped((cfg.num_layers, b, cap) + tuple(each), cfg.dtype) for each in cfg.cache_arrays]
+    operands = shaped(
+        (b, llm._operand_width(engine["prefill_token_buckets"][-1], cap // engine["block_size"])),
+        jnp.int32)
+    compiled = llm._operand_extend(cfg.make_extend_fn()).lower(
+        params, operands, shaped((lanes + len(cfg.counters),), jnp.int32), *caches, tc=tc
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2      # the kernel is there
+    memory = compiled.memory_analysis()
+    per_token = 2 * cfg.num_layers * sum(h * d for h, d in cfg.cache_arrays)
+    assert per_token == 13056
+    weights = memory.argument_size_in_bytes - per_token * b * cap
+    assert 8.7e9 < weights < 8.8e9
+    assert memory.temp_size_in_bytes < 0.6e9
+    assert memory.argument_size_in_bytes == stated[form]["argument"]
+    assert memory.temp_size_in_bytes <= stated[form]["temp"] * 1.05
+    # beside the pool and the caches of the call in flight
+    pool = per_token * engine["num_blocks"] * engine["block_size"]
+    assert _device_bytes(compiled) + pool + per_token * lanes * cap < HBM_BYTES
+
+
+def test_three_arena_paging_programs_compile_without_a_whole_arena_temporary(shaped):
+    """The pool of the Keye-VL-2.0 configuration (512 blocks of 256 tokens, six
+    layers, K and V of 4 x 128 and an indexer key of 1 x 64 a token: 1.71 GB in
+    three arenas of two shapes) and the programs around ``extend``: as for two
+    arenas, no program holds a temporary the size of a K or V arena (0.8 GB),
+    and the page-back and the clone alias all three. The indexer's arena (0.1
+    GB; rows of 64, half the chip's lanes) is re-laid out by the gather and
+    the page-back, at most twice its size: PERF.md section 7."""
+    cfg, config = _keye_stage()
+    engine = config["engine"]
+    blocks, block, tokens = engine["num_blocks"], engine["block_size"], engine["prefill_chunk"]
+    arenas = tuple(
+        shaped((cfg.num_layers, blocks, block) + tuple(each), cfg.dtype)
+        for each in cfg.cache_arrays)
+    per_token = 2 * cfg.num_layers * sum(h * d for h, d in cfg.cache_arrays)
+    arena_bytes = per_token * blocks * block
+    assert arena_bytes == 13056 * 131072
+    narrow = 2 * cfg.num_layers * blocks * block * cfg.index_dim      # the indexer's arena
+    assert narrow * 8 < arena_bytes
+    programs = llm._paging_programs()
+    width = llm._operand_width(tokens, engine["cache_buckets"][-1] // block)
+    lanes = engine["lane_buckets"][-1]
+    for b, cap in ((1, engine["cache_buckets"][0]), (lanes, engine["cache_buckets"][-1])):
+        memory = programs.gather.lower(
+            arenas, shaped((b, width), jnp.int32), cap // block).compile().memory_analysis()
+        assert 0 <= memory.output_size_in_bytes - per_token * b * cap < 4096 * 3
+        assert memory.temp_size_in_bytes < 2 * narrow + 2**20, (b, cap)
+    for b, tc in ((lanes, 1), (1, tokens)):
+        news = tuple(
+            shaped((cfg.num_layers, b, tc) + tuple(each), cfg.dtype) for each in cfg.cache_arrays)
+        memory = programs.page_back.lower(
+            arenas, news, shaped((b, width), jnp.int32),
+            (shaped((b, tc, cfg.vocab_size), jnp.float32),
+             shaped((b, tc, cfg.embed_dim), jnp.float32)),
+            (shaped((len(cfg.counters),), jnp.int32),), lanes,
+        ).compile().memory_analysis()
+        assert memory.alias_size_in_bytes == arena_bytes, (b, tc)
+        assert memory.temp_size_in_bytes < 2 * narrow + 2**20, (b, tc)
+    clone = programs.clone.lower(
+        arenas, shaped((), jnp.int32), shaped((), jnp.int32)).compile().memory_analysis()
+    assert clone.alias_size_in_bytes == arena_bytes and clone.temp_size_in_bytes < 2**20
+
+
 @pytest.mark.parametrize(
     "name,extends,pagings",
-    [("gptj-6b-serve", 12, 11), ("command-a-plus-serve-ep8", 20, 25)],
+    [("gptj-6b-serve", 12, 11), ("command-a-plus-serve-ep8", 20, 25),
+     ("keye-vl2-30b-a3b-serve", 16, 19)],
 )
 def test_a_serve_configuration_compiles_no_more_programs_than_it_did(name, extends, pagings):
     """The programs an engine with the configuration's buckets compiles (a tiny
@@ -249,6 +341,7 @@ def test_a_serve_configuration_compiles_no_more_programs_than_it_did(name, exten
     cfg = (
         cohere2_moe.cohere2_moe_nano(max_seq_len=context)
         if name.startswith("command-a-plus")
+        else keye_vl2.keye_vl2_nano(max_seq_len=context) if name.startswith("keye")
         else dataclasses.replace(gpt.gpt_nano(), max_seq_len=context)
     )
     llm._paging_programs.cache_clear()      # this engine's programs alone

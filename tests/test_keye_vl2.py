@@ -1,0 +1,159 @@
+"""``models/keye_vl2.py`` on the CPU at a tiny size: the two forms of the
+selection give the same keys (ties, zeros of either sign, fewer visible keys
+than ``topk``), a decode lane and a prefill chunk give the same row of logits,
+padding selects nothing, the softmax router, and the configuration's own
+arithmetic. The comparison with the plain reference is the benchmark's
+(``tests/benchmark/test_bench_keye_vl2.py``)."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import keye_vl2, moe
+
+CFG = keye_vl2.keye_vl2_nano()
+
+
+def _scores(case, rng, rows=6, cache=64):
+    scores = rng.standard_normal((rows, cache)).astype(np.float32)
+    if case == "ties":                          # a few values, so the k-th is shared
+        scores = np.round(scores * 2) / 2
+    elif case == "zeros":                       # most scores an exact zero of either sign
+        scores = np.where(rng.random(scores.shape) < 0.7, 0.0, scores).astype(np.float32)
+        scores = np.where(rng.random(scores.shape) < 0.5, -scores, scores)
+    elif case == "all_equal":
+        scores[:] = 1.5
+    return scores
+
+
+@pytest.mark.parametrize("case", ["distinct", "ties", "zeros", "all_equal"])
+@pytest.mark.parametrize("k", [1, 16, 64, 100])
+def test_the_mask_and_the_rows_are_the_same_keys(case, k):
+    """``select_mask`` (a prefill chunk's) against ``select_rows`` (a decode
+    lane's) and against a sort on the host: the ``k`` largest visible scores,
+    ties to the lower position, all the visible ones where they are fewer."""
+    rng = np.random.default_rng(hash((case, k)) % 2**32)
+    scores = _scores(case, rng)
+    seen = rng.integers(0, 65, size=scores.shape[0])
+    seen[0], seen[1] = 0, 64                     # a row that sees nothing, one that sees all
+    visible = np.arange(64)[None, :] < seen[:, None]
+    mask = np.asarray(jax.jit(keye_vl2.select_mask, static_argnums=2)(scores, visible, k))
+    rows, chosen = (np.asarray(x) for x in keye_vl2.select_rows(
+        jnp.asarray(scores), jnp.asarray(visible), k))
+    assert rows.shape == chosen.shape == (scores.shape[0], min(k, 64))
+    for r in range(scores.shape[0]):
+        order = sorted(range(seen[r]), key=lambda s: (-(scores[r, s] + 0.0), s))[:k]
+        assert sorted(np.flatnonzero(mask[r])) == sorted(order), (case, k, r)
+        assert sorted(rows[r][chosen[r]]) == sorted(order), (case, k, r)
+        assert chosen[r].sum() == min(k, seen[r])
+
+
+def _caches(lanes, cache):
+    return [
+        jnp.zeros((CFG.num_layers, lanes, cache) + tuple(each), jnp.float32)
+        for each in CFG.cache_arrays]
+
+
+@pytest.fixture(scope="module")
+def program():
+    return jax.tree_util.tree_map_with_path(
+        lambda path, a: a if path[-1].key == "scale" else a * 8.0, CFG.init_params(5))
+
+
+def test_a_decode_lane_and_a_prefill_chunk_give_the_same_row(program):
+    """50 tokens (topk is 16) in one chunk, against 49 in a chunk and the 50th
+    as a decode lane over the cache they left: the same logits, the same new
+    K, V and indexer key, the same selection."""
+    extend, probe = CFG.make_extend_fn(), keye_vl2.make_probe_fn(CFG)
+    tokens = jnp.asarray(
+        [np.random.default_rng(2).integers(0, CFG.vocab_size, size=50)], jnp.int32)
+    zero = jnp.zeros((1,), jnp.int32)
+    logits, _, k, v, i, _, selected = probe(program, tokens, zero, *_caches(1, 64))
+    plain = extend(program, tokens, zero, *_caches(1, 64))
+    assert len(plain) == 6 and np.array_equal(plain[0], logits)    # the probe changes nothing
+    held = [jnp.pad(x[:, :, :49], ((0, 0), (0, 0), (0, 15), (0, 0), (0, 0))) for x in (k, v, i)]
+    one = probe(program, tokens[:, 49:], jnp.full((1,), 49, jnp.int32), *held)
+    np.testing.assert_allclose(one[0][0, 0], logits[0, 49], atol=2e-5, rtol=2e-5)
+    for new, whole in zip(one[2:5], (k, v, i)):
+        np.testing.assert_allclose(new[:, :, 0], whole[:, :, 49], atol=1e-5, rtol=1e-5)
+    assert np.array_equal(np.asarray(one[-1])[:, 0, 0], np.asarray(selected)[:, 0, 49])
+    assert np.asarray(selected)[:, 0, 49].sum(-1).tolist() == [16] * CFG.num_layers
+    assert i.shape == (CFG.num_layers, 1, 50, 1, CFG.index_dim)
+
+
+def test_a_short_context_is_causal_attention(program):
+    """Up to ``topk`` keys every query reads everything before it: the
+    selection decides nothing, and a configuration that reads more keys than
+    there are gives the same logits."""
+    import dataclasses
+
+    tokens = jnp.asarray(
+        [np.random.default_rng(3).integers(0, CFG.vocab_size, size=16)], jnp.int32)
+    zero = jnp.zeros((1,), jnp.int32)
+    sparse = CFG.make_extend_fn()(program, tokens, zero, *_caches(1, 64))
+    dense = dataclasses.replace(CFG, topk=64).make_extend_fn()(
+        program, tokens, zero, *_caches(1, 64))
+    np.testing.assert_allclose(sparse[0], dense[0], atol=1e-6)
+    longer = jnp.asarray(
+        [np.random.default_rng(3).integers(0, CFG.vocab_size, size=48)], jnp.int32)
+    sparse = CFG.make_extend_fn()(program, longer, zero, *_caches(1, 64))
+    dense = dataclasses.replace(CFG, topk=64).make_extend_fn()(
+        program, longer, zero, *_caches(1, 64))
+    np.testing.assert_allclose(sparse[0][0, :16], dense[0][0, :16], atol=1e-5)
+    assert float(jnp.abs(sparse[0][0, 40:] - dense[0][0, 40:]).max()) > 1e-3
+
+
+def test_padding_changes_no_real_token_and_counts_nothing(program):
+    extend = CFG.make_extend_fn()
+    rng = np.random.default_rng(4)
+    real = rng.integers(0, CFG.vocab_size, size=(2, 24))
+    lengths = jnp.zeros((2,), jnp.int32)
+    whole = extend(program, jnp.asarray(real, jnp.int32), lengths, *_caches(2, 64))
+    padded = np.full((2, 32), -1)
+    padded[:, :24] = real
+    padded[1, 10:] = -1                                      # the second lane is shorter
+    out = extend(program, jnp.asarray(padded, jnp.int32), lengths, *_caches(2, 64))
+    np.testing.assert_allclose(out[0][0, :24], whole[0][0], atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(out[0][1, :10], whole[0][1, :10], atol=2e-5, rtol=2e-5)
+    named = dict(zip(CFG.counters, np.asarray(out[-1]).tolist()))
+    tokens = CFG.num_layers * (24 + 10)
+    assert named["moe_tokens"] == named["sparse_queries"] == tokens
+    assert named["moe_assignments"] == CFG.experts_per_token * tokens
+    assert named["sparse_keys_scored"] == CFG.num_layers * (24 * 25 // 2 + 10 * 11 // 2)
+    assert named["sparse_keys_attended"] == CFG.num_layers * (
+        sum(min(16, t) for t in range(1, 25)) + sum(range(1, 11)))
+    # a slot is read if some query chose it: at least the 16 of the last query
+    assert CFG.num_layers * (16 + 10) <= named["sparse_slots_read"] <= CFG.num_layers * (24 + 10)
+
+
+def test_the_softmax_router_takes_the_largest_probabilities_and_renormalises():
+    keys = jax.random.split(jax.random.PRNGKey(1), 2)
+    h, router = jax.random.normal(keys[0], (10, 32)), jax.random.normal(keys[1], (32, 16))
+    weights, experts = moe.softmax_top_k(h, router, 4)
+    p = np.asarray(jax.nn.softmax(np.asarray(h) @ np.asarray(router), -1))
+    want = np.argsort(-p, axis=-1)[:, :4]
+    assert np.array_equal(np.asarray(experts), want) and experts.dtype == jnp.int32
+    top = np.take_along_axis(p, want, -1)
+    np.testing.assert_allclose(weights, top / top.sum(-1, keepdims=True), rtol=1e-5)
+    # the sigmoid router picks the same experts (both rise with the logit), other weights
+    other, same = moe.sigmoid_top_k(h, router, 4)
+    assert np.array_equal(np.asarray(same), want)
+    assert float(jnp.abs(other - weights).max()) > 1e-3
+
+
+def test_the_configuration_counts_its_parameters_and_states_what_a_token_holds():
+    params = CFG.init_params(0)
+    assert CFG.num_params() == sum(a.size for a in jax.tree.leaves(params))
+    assert CFG.cache_arrays == ((2, 16), (2, 16), (1, 8))
+    assert CFG.counters == moe.COUNTERS + keye_vl2.SPARSE_COUNTERS
+    assert CFG.count_gathered(4, 128) == {"sparse_slots_gathered": 3 * 4 * 128}
+    full = keye_vl2.KeyeVL2Config(num_layers=6)
+    assert full.num_params() == pytest.approx(4.375e9, rel=1e-3)
+    assert full.cache_arrays == ((4, 128), (4, 128), (1, 64))
+    with pytest.raises(ValueError, match="do not divide"):
+        keye_vl2.keye_vl2_nano(kv_heads=3)
+    # seeded: the same seed gives the same weights, another seed others
+    again, other = CFG.init_params(0), CFG.init_params(1)
+    assert all(np.array_equal(a, b) for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(again)))
+    assert not np.array_equal(params["head"]["kernel"], other["head"]["kernel"])

@@ -80,3 +80,10 @@ def test_fixed_schedule_makes_the_same_calls_and_ids_every_time(tmp_path, monkey
     said = []
     probe.report_fixed(first, say=lambda *a: said.append(" ".join(map(str, a))))
     assert f"ids sha256 {first['sha256']}" in "\n".join(said) and "run-ahead share" in "\n".join(said)
+    # what the cell's model counts is printed under its own names: an expert
+    # layer's pairs, an indexer's slots read of the slots gathered
+    assert ("experts:" in "\n".join(said)) == ("moe_tokens" in d)
+    assert ("indexer:" in "\n".join(said)) == ("sparse_queries" in d)
+    if "sparse_queries" in d:
+        assert 0 < d["sparse_slots_read"] <= d["sparse_slots_gathered"]
+        assert f"slots read {d['sparse_slots_read']}/{d['sparse_slots_gathered']}" in "\n".join(said)

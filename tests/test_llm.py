@@ -13,7 +13,7 @@ import pytest
 
 import ray_tpu
 from ray_tpu import serve
-from ray_tpu.models import gpt
+from ray_tpu.models import gpt, keye_vl2
 from ray_tpu.serve import batching
 from ray_tpu.serve.llm import (
     KVBlockPool,
@@ -27,6 +27,10 @@ from ray_tpu.serve.llm import (
 )
 
 CFG = gpt.gpt_nano()
+#: the pool's own tests run over both kinds of per-token state: K and V (two
+#: arenas), and K, V and an indexer's key (three, of two shapes)
+both_kinds_of_state = pytest.mark.parametrize(
+    "cfg", [CFG, keye_vl2.keye_vl2_nano()], ids=["two-arenas", "three-arenas"])
 
 
 def _prompt(seed: int, n: int):
@@ -74,8 +78,9 @@ def _await(predicate, timeout, what):
 # ---------------------------------------------------------------------------
 
 
-def test_kv_pool_allocate_free_refcounts():
-    pool = KVBlockPool(CFG, num_blocks=8, block_size=4)
+@both_kinds_of_state
+def test_kv_pool_allocate_free_refcounts(cfg):
+    pool = KVBlockPool(cfg, num_blocks=8, block_size=4)
     a = pool.allocate(3)
     assert pool.in_use() == 3
     pool.incref(a[:1])
@@ -88,8 +93,9 @@ def test_kv_pool_allocate_free_refcounts():
     assert pool.in_use() == 0         # failed allocation takes nothing
 
 
-def test_kv_lease_releases_exactly_once():
-    pool = KVBlockPool(CFG, num_blocks=8, block_size=4)
+@both_kinds_of_state
+def test_kv_lease_releases_exactly_once(cfg):
+    pool = KVBlockPool(cfg, num_blocks=8, block_size=4)
     lease = KVLease(pool)
     lease.add(pool.allocate(4))
     before = pool.freed_total
@@ -102,55 +108,64 @@ def test_kv_lease_releases_exactly_once():
     assert pool.in_use() == 0
 
 
-def _fill_block(pool, block, k_value, v_value):
-    """Write constants into one block of the device arenas."""
-    pool.k_data = pool.k_data.at[:, block].set(k_value)
-    pool.v_data = pool.v_data.at[:, block].set(v_value)
+def _fill_block(pool, block, value):
+    """Write ``value``, ``-value``, ``value + 1``, ... into one block of the
+    device arenas, an arena each."""
+    pool.arenas = tuple(
+        a.at[:, block].set(v) for a, v in zip(pool.arenas, _fills(pool, value)))
 
 
-def test_kv_pool_copy_on_write():
+def _fills(pool, value):
+    return [(value + i // 2) * (-1) ** i for i in range(len(pool.arenas))]
+
+
+@both_kinds_of_state
+def test_kv_pool_copy_on_write(cfg):
     import jax
 
-    pool = KVBlockPool(CFG, num_blocks=8, block_size=4)
-    assert isinstance(pool.k_data, jax.Array) and isinstance(pool.v_data, jax.Array)
+    pool = KVBlockPool(cfg, num_blocks=8, block_size=4)
+    assert len(pool.arenas) == len(cfg.cache_arrays)
+    assert all(isinstance(a, jax.Array) for a in pool.arenas)
+    assert pool.k_data is pool.arenas[0] and pool.v_data is pool.arenas[1]
     (shared,) = pool.allocate(1)
-    _fill_block(pool, shared, 7.0, -7.0)
+    _fill_block(pool, shared, 7.0)
     pool.incref([shared])             # second holder (e.g. prefix cache)
     blocks = [shared]
     new = pool.ensure_private(blocks, 0)
     assert new != shared and blocks[0] == new
-    k, v = pool.read_block(new)
-    assert k.shape == (CFG.num_layers, 4, CFG.num_heads, CFG.head_dim)
-    assert np.all(k == 7.0) and np.all(v == -7.0)    # contents cloned
+    held = pool.read_block(new)
+    assert [a.shape for a in held] == [
+        (cfg.num_layers, 4) + tuple(each) for each in cfg.cache_arrays]
+    assert all(np.all(a == v) for a, v in zip(held, _fills(pool, 7.0)))   # contents cloned
     assert pool.refcount(shared) == 1            # our ref moved off it
-    _fill_block(pool, new, 9.0, -9.0)
-    k, v = pool.read_block(shared)
-    assert np.all(k == 7.0) and np.all(v == -7.0)    # original untouched
+    _fill_block(pool, new, 9.0)
+    assert all(                                  # original untouched
+        np.all(a == v) for a, v in zip(pool.read_block(shared), _fills(pool, 7.0)))
     # unshared block: no clone
     assert pool.ensure_private(blocks, 0) == new
 
 
-def test_clone_block_copies_one_block_on_the_device():
-    """The clone moves exactly one block of both arenas and nothing else,
+@both_kinds_of_state
+def test_clone_block_copies_one_block_on_the_device(cfg):
+    """The clone moves exactly one block of every arena and nothing else,
     without the arenas leaving the device."""
     import jax
     import jax.numpy as jnp
 
-    pool = KVBlockPool(CFG, num_blocks=6, block_size=4)
+    pool = KVBlockPool(cfg, num_blocks=6, block_size=4)
     rng = np.random.RandomState(0)
-    k0 = rng.standard_normal(pool.k_data.shape).astype(np.float32)
-    v0 = rng.standard_normal(pool.v_data.shape).astype(np.float32)
-    pool.k_data, pool.v_data = jnp.asarray(k0), jnp.asarray(v0)
+    was = [rng.standard_normal(a.shape).astype(np.float32) for a in pool.arenas]
+    pool.arenas = tuple(jnp.asarray(a) for a in was)
     pool.clone_block(4, 1)
-    assert isinstance(pool.k_data, jax.Array)
-    k0[:, 1], v0[:, 1] = k0[:, 4], v0[:, 4]
-    assert np.array_equal(np.asarray(pool.k_data), k0)
-    assert np.array_equal(np.asarray(pool.v_data), v0)
-    k, v = pool.read_block(1)
-    assert np.array_equal(k, k0[:, 4]) and np.array_equal(v, v0[:, 4])
+    assert all(isinstance(a, jax.Array) for a in pool.arenas)
+    for a in was:
+        a[:, 1] = a[:, 4]
+    assert all(np.array_equal(np.asarray(a), w) for a, w in zip(pool.arenas, was))
+    assert all(np.array_equal(a, w[:, 4]) for a, w in zip(pool.read_block(1), was))
 
 
-def test_pool_that_does_not_fit_fails_at_construction_with_its_sizes(monkeypatch):
+@both_kinds_of_state
+def test_pool_that_does_not_fit_fails_at_construction_with_its_sizes(monkeypatch, cfg):
     import jax.numpy as jnp
 
     def refuses(shape, dtype):
@@ -158,9 +173,10 @@ def test_pool_that_does_not_fit_fails_at_construction_with_its_sizes(monkeypatch
 
     monkeypatch.setattr(jnp, "zeros", refuses)
     with pytest.raises(MemoryError) as ei:
-        KVBlockPool(CFG, num_blocks=8, block_size=4)
-    each = CFG.num_layers * 8 * 4 * CFG.num_heads * CFG.head_dim * 4
-    assert f"{each} bytes an arena" in str(ei.value) and "8 blocks" in str(ei.value)
+        KVBlockPool(cfg, num_blocks=8, block_size=4)
+    held = cfg.num_layers * 8 * 4 * sum(heads * dim for heads, dim in cfg.cache_arrays) * 4
+    assert f"are {held} bytes" in str(ei.value) and "8 blocks" in str(ei.value)
+    assert str(cfg.cache_arrays) in str(ei.value)
 
 
 # ---------------------------------------------------------------------------
@@ -179,8 +195,9 @@ def test_chain_hashes_commit_to_prefix():
     assert c[0] != a[0] and c[1] != a[1]
 
 
-def test_prefix_cache_match_insert_evict():
-    pool = KVBlockPool(CFG, num_blocks=4, block_size=4)
+@both_kinds_of_state
+def test_prefix_cache_match_insert_evict(cfg):
+    pool = KVBlockPool(cfg, num_blocks=4, block_size=4)
     cache = PrefixCache(pool)
     hashes = chain_hashes(list(range(8)), 4)
     blocks = pool.allocate(2)
@@ -332,12 +349,15 @@ def _drive(eng, seqs):
 
 
 def _paged(pool, blocks, n):
-    """The first ``n`` tokens of the cache that ``blocks`` page, on the host."""
-    k, v = zip(*(pool.read_block(b) for b in blocks))
-    return np.concatenate(k, axis=1)[:, :n], np.concatenate(v, axis=1)[:, :n]
+    """The first ``n`` tokens of the caches that ``blocks`` page, on the host:
+    one array per arena."""
+    return [
+        np.concatenate(held, axis=1)[:, :n]
+        for held in zip(*(pool.read_block(b) for b in blocks))]
 
 
-def test_paged_device_calls_are_bitwise_a_zero_padded_host_pair():
+@both_kinds_of_state
+def test_paged_device_calls_are_bitwise_a_zero_padded_host_pair(cfg):
     """Every device call of a mixed workload (1 to 4 lanes, lengths that end
     inside a block, a pool that starts full of finite garbage) against the
     same ``extend`` fed a zero-padded pair built on the host from a mirror of
@@ -347,13 +367,17 @@ def test_paged_device_calls_are_bitwise_a_zero_padded_host_pair():
     import jax.numpy as jnp
 
     nothing = (
-        np.zeros((CFG.embed_dim, 1), np.float32), np.zeros((1, CFG.vocab_size), np.float32), 0.0)
+        np.zeros((cfg.embed_dim, 1), np.float32), np.zeros((1, cfg.vocab_size), np.float32), 0.0)
     eng = LLMEngine(
-        CFG, prefix_caching=False, prefill_lanes=2, adapter_loader=lambda mid: nothing, **_PAGING)
+        cfg, prefix_caching=False, prefill_lanes=2, adapter_loader=lambda mid: nothing, **_PAGING)
     pool, rng = eng.pool, np.random.RandomState(7)
-    pool.k_data = jnp.asarray(rng.standard_normal(pool.k_data.shape), pool.dtype)
-    pool.v_data = jnp.asarray(rng.standard_normal(pool.v_data.shape), pool.dtype)
-    shape = (CFG.num_layers, 0, CFG.num_heads, CFG.head_dim)
+    pool.arenas = tuple(
+        jnp.asarray(rng.standard_normal(a.shape), pool.dtype) for a in pool.arenas)
+    held = len(pool.arenas)
+
+    def empty():
+        return [np.zeros((cfg.num_layers, 0) + tuple(each)) for each in cfg.cache_arrays]
+
     mirror, calls, wanted, launch, land = {}, [], {}, eng._launch, eng._land
 
     def launched(lanes, chunks, tc, emits):
@@ -367,23 +391,27 @@ def test_paged_device_calls_are_bitwise_a_zero_padded_host_pair():
         b = batching.bucket_pad_size(len(states), eng.lane_buckets)
         cap = batching.bucket_pad_size(
             max(st.length + len(ch) for st, ch in zip(states, chunks)), eng.cache_buckets)
-        k = np.zeros((CFG.num_layers, b, cap, CFG.num_heads, CFG.head_dim), pool.dtype)
-        v = np.zeros_like(k)
-        tokens, lengths = np.zeros((b, tc), np.int32), np.zeros((b,), np.int32)
+        caches = [
+            np.zeros((cfg.num_layers, b, cap) + tuple(each), pool.dtype)
+            for each in cfg.cache_arrays]
+        # a negative id is padding, as the engine marks it
+        tokens, lengths = np.full((b, tc), -1, np.int32), np.zeros((b,), np.int32)
         for i, (st, ch) in enumerate(zip(states, chunks)):
-            _, mk, mv = mirror.setdefault(id(st), (st, np.zeros(shape), np.zeros(shape)))
-            assert mk.shape[1] == st.length
-            k[:, i, :st.length], v[:, i, :st.length] = mk, mv
+            _, mine = mirror.setdefault(id(st), (st, empty()))
+            assert mine[0].shape[1] == st.length
+            for cache, m in zip(caches, mine):
+                cache[:, i, :st.length] = m
             tokens[i, :len(ch)], lengths[i] = ch, st.length
-        want = [np.asarray(o) for o in eng._extend(eng._params, tokens, lengths, k, v)]
+        want = [np.asarray(o) for o in eng._extend(eng._params, tokens, lengths, *caches)]
         call = launch(lanes, chunks, tc, emits)
         wanted[id(call)] = (call, states, chunks, want)
-        calls.append((len(states), tc, cap, [len(mirror[id(st)][1][0]) % eng.block_size for st in states]))
+        calls.append((len(states), tc, cap, [mirror[id(st)][1][0].shape[1] % eng.block_size for st in states]))
         return call
 
     def landed():
         call = land()
-        _, states, chunks, (logits, hidden, k_new, v_new) = wanted.pop(id(call))
+        _, states, chunks, (logits, hidden, *rest) = wanted.pop(id(call))
+        news = rest[:held]
         assert len(call.sampled) == len(states)
         for i, (st, ch) in enumerate(zip(states, chunks)):
             n = len(ch)
@@ -391,14 +419,15 @@ def test_paged_device_calls_are_bitwise_a_zero_padded_host_pair():
             assert np.array_equal(logits_row, logits[i, n - 1])
             assert np.array_equal(hidden_row, hidden[i, n - 1])
             assert tok == np.argmax(logits[i, n - 1])
-            _, mk, mv = mirror[id(st)]
-            mk = np.concatenate([mk, k_new[:, i, :n]], axis=1)
-            mv = np.concatenate([mv, v_new[:, i, :n]], axis=1)
-            mirror[id(st)] = (st, mk, mv)
+            mine = [
+                np.concatenate([m, new[:, i, :n]], axis=1)
+                for m, new in zip(mirror[id(st)][1], news)]
+            mirror[id(st)] = (st, mine)
             # nothing was fed to the lane since (its call had to land first)
-            assert st.length == mk.shape[1]
-            pk, pv = _paged(pool, st.blocks, st.length)
-            assert np.array_equal(pk, mk) and np.array_equal(pv, mv)
+            assert st.length == mine[0].shape[1]
+            paged = _paged(pool, st.blocks, st.length)
+            assert len(paged) == held and all(
+                np.array_equal(p, m) for p, m in zip(paged, mine))
         return call
 
     eng._launch, eng._land = launched, landed
@@ -409,24 +438,25 @@ def test_paged_device_calls_are_bitwise_a_zero_padded_host_pair():
     assert any(r for c in calls for r in c[3])      # frontiers inside a block
 
 
-def test_engine_clones_a_shared_tail_block_on_the_device_before_writing_it():
-    eng = LLMEngine(CFG, prefix_caching=False, **_PAGING)
+@both_kinds_of_state
+def test_engine_clones_a_shared_tail_block_on_the_device_before_writing_it(cfg):
+    eng = LLMEngine(cfg, prefix_caching=False, **_PAGING)
     (seq,) = _sequences((20,), 6)
     eng.step([seq])                             # prefill and the first decode
     st, bs = seq.state, eng.block_size
     assert st.length == 21 and not seq.done
     tail = st.blocks[st.length // bs]
     eng.pool.incref([tail])                     # a second holder appears
-    k0, v0 = eng.pool.read_block(tail)
-    assert k0[:, :5].any() and not k0[:, 5:].any()
+    was = eng.pool.read_block(tail)
+    assert all(a[:, :5].any() and not a[:, 5:].any() for a in was)
     eng.step([seq])                             # writes token 21: must clone
     clone = st.blocks[1]
     assert clone != tail and eng.pool.refcount(tail) == 1
-    k1, v1 = eng.pool.read_block(tail)
-    assert np.array_equal(k0, k1) and np.array_equal(v0, v1)    # original intact
-    kc, vc = eng.pool.read_block(clone)
-    assert np.array_equal(kc[:, :5], k0[:, :5]) and np.array_equal(vc[:, :5], v0[:, :5])
-    assert kc[:, 5].any() and not kc[:, 6:].any()
+    assert all(                                                 # original intact
+        np.array_equal(a, b) for a, b in zip(was, eng.pool.read_block(tail)))
+    for a, c in zip(was, eng.pool.read_block(clone)):
+        assert np.array_equal(c[:, :5], a[:, :5])
+        assert c[:, 5].any() and not c[:, 6:].any()
     _drive(eng, [seq])
     eng.pool.free([tail])
     assert eng.pool.in_use() == 0
@@ -510,8 +540,9 @@ def _leaked(stats):
     return stats["kv_blocks_in_use"] - stats["prefix_cached_blocks"]
 
 
-def test_kv_exhaustion_sheds_without_leak():
-    srv = LLMServer(CFG, num_blocks=2, block_size=16, prefix_caching=False,
+@both_kinds_of_state
+def test_kv_exhaustion_sheds_without_leak(cfg):
+    srv = LLMServer(cfg, num_blocks=2, block_size=16, prefix_caching=False,
                     cache_buckets=(64,))
     try:
         with pytest.raises(serve.BackPressureError) as ei:

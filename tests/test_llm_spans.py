@@ -372,7 +372,7 @@ def test_phases_cost_less_than_their_budget_outside_a_session(engine, monkeypatc
     monkeypatch.setattr(engine.pool, "gather", lambda operands, n: (None, None))
     monkeypatch.setattr(
         engine.pool, "page_back",
-        lambda k_new, v_new, operands, outputs, counted, width: (ids[width], None))
+        lambda news, operands, outputs, counted, width: (ids[width], None))
     monkeypatch.setattr(jax, "device_put", lambda a: a)
     as_it_is, nothing = engine._phase, contextlib.nullcontext()
 
@@ -431,3 +431,62 @@ def test_extend_names_its_scopes():
     ).as_text(debug_info=True)
     for scope in ("extend.embed", "extend.attention", "extend.mlp", "extend.logits"):
         assert f"{scope}/" in text, scope
+
+
+@pytest.mark.parametrize("tc", [1, 8], ids=["decode", "prefill"])
+def test_an_indexers_extend_names_its_scopes_inside_attentions(tc):
+    """Both forms of the selection (a decode lane's row gather, a prefill
+    chunk's mask) run under ``extend.attention.select``, the indexer under
+    ``extend.attention.index``, both nested in ``extend.attention``: the
+    benchmark's readers tell the three apart by the innermost name."""
+    from ray_tpu.models import keye_vl2
+
+    cfg = keye_vl2.keye_vl2_nano()
+    params = jax.eval_shape(lambda: cfg.init_params(0))
+    caches = [
+        jax.ShapeDtypeStruct((cfg.num_layers, 2, 64) + tuple(each), jnp.float32)
+        for each in cfg.cache_arrays]
+    text = cfg.make_extend_fn().lower(
+        params, jax.ShapeDtypeStruct((2, tc), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.int32), *caches,
+    ).as_text(debug_info=True)
+    for scope in (
+        "extend.embed", "extend.attention", "extend.attention/extend.attention.index",
+        "extend.attention/extend.attention.select", "extend.moe.route", "extend.moe.experts",
+        "extend.logits",
+    ):
+        assert f"{scope}/" in text, scope
+
+
+def test_the_engine_sums_what_extend_counts_under_the_configurations_names():
+    """The engine knows no counter of a model: it sums the int32 vector behind
+    ``extend``'s new rows under ``cfg.counters``, and what ``cfg.count_gathered``
+    gives for a call's padded caches; a model that counts nothing adds no key."""
+    import dataclasses as dc
+
+    from ray_tpu.models import keye_vl2
+    from ray_tpu.serve import batching
+
+    sizes = dict(
+        num_blocks=16, block_size=16, prefill_chunk=32, lane_buckets=(1,),
+        prefill_token_buckets=(32,), cache_buckets=(64,))
+    cfg = keye_vl2.keye_vl2_nano()
+    renamed = type("Renamed", (keye_vl2.KeyeVL2Config,), {
+        "counters": tuple("x_" + n for n in cfg.counters),
+        "count_gathered": lambda self, lanes, cache: {"x_gathered": lanes * cache},
+    })(**dc.asdict(cfg))
+    got = {}
+    for c in (cfg, renamed):
+        eng = llm.LLMEngine(c, **sizes)
+        seq = batching._Sequence({"prompt": list(range(1, 41)), "max_new_tokens": 3})
+        while not seq.done:
+            eng.step([seq])
+        got[type(c).__name__] = eng.stats()
+    plain, other = got["KeyeVL2Config"], got["Renamed"]
+    assert set(cfg.counters) | {"sparse_slots_gathered"} <= set(plain)
+    assert not set(plain) & set(renamed.counters)
+    assert [other[n] for n in renamed.counters] == [plain[n] for n in cfg.counters]
+    # 40 prompt tokens in chunks of 32 + 8, then two decode calls; three layers
+    assert plain["sparse_queries"] == plain["moe_tokens"] == 3 * 42
+    assert plain["sparse_slots_gathered"] == 3 * other["x_gathered"] == 3 * 4 * 64
+    assert not any(k.startswith(("moe_", "sparse_")) for k in llm.LLMEngine(NANO, **sizes).stats())

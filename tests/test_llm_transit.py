@@ -6,7 +6,7 @@ the host. The ids are the ones the host would have picked from those rows."""
 import numpy as np
 import pytest
 
-from ray_tpu.models import cohere2_moe, gpt
+from ray_tpu.models import cohere2_moe, gpt, keye_vl2
 from ray_tpu.serve import batching, llm
 
 ENGINE = dict(
@@ -14,7 +14,10 @@ ENGINE = dict(
     lane_buckets=(1, 2, 4), prefill_token_buckets=(16, 32),
     cache_buckets=(64, 128), prefix_caching=False,
 )
-CONFIGS = {"gpt": gpt.gpt_nano, "cohere2_moe": cohere2_moe.cohere2_moe_nano}
+CONFIGS = {
+    "gpt": gpt.gpt_nano, "cohere2_moe": cohere2_moe.cohere2_moe_nano,
+    "keye_vl2": keye_vl2.keye_vl2_nano,
+}
 LENGTHS, NEW = (20, 40, 9, 33), 5
 
 
@@ -111,10 +114,12 @@ def test_a_tie_goes_to_the_first_index_as_on_the_host(engine, tc, counted):
     logits[2, last[2]] = 0.0                            # all equal: 0 wins
     operands = np.zeros((b, engine._operand_width), np.int32)
     operands[:, llm._LAST] = last
-    new = jnp.zeros((cfg.num_layers, b, tc, cfg.kv_heads, cfg.head_dim), engine.pool.dtype)
+    news = [
+        jnp.zeros((cfg.num_layers, b, tc) + tuple(each), engine.pool.dtype)
+        for each in cfg.cache_arrays]
     counters = (jnp.asarray([5, 6, 7, 8], jnp.int32),) if counted else ()
     home, picked = engine.pool.page_back(
-        new, new, jnp.asarray(operands),
+        news, jnp.asarray(operands),
         (jnp.asarray(logits), jnp.zeros((b, tc, cfg.embed_dim), jnp.float32)), counters, b + 3)
     home, rows = np.asarray(home), np.asarray(picked[0])
     assert home.dtype == np.int32
@@ -175,8 +180,11 @@ def test_a_mixed_call_gives_each_lane_what_it_gave_before(engine, monkeypatch):
 def test_a_call_crosses_the_link_once_each_way_unless_a_lane_needs_its_rows(engine, monkeypatch):
     cfg, b_of = engine.cfg, lambda call: batching.bucket_pad_size(
         len(call["states"]), engine.lane_buckets)
-    # an expert layer's four counters ride home behind the ids
-    counters = 4 * len(llm.MOE_COUNTERS) * isinstance(cfg, cohere2_moe.Cohere2MoeConfig)
+    # what ``extend`` counts rides home behind the ids: an expert layer's four
+    # counters, an indexer's four more, none for a model that counts nothing
+    counters = 4 * len(getattr(cfg, "counters", ()))
+    assert len(getattr(cfg, "counters", ())) == {
+        gpt.GPTConfig: 0, cohere2_moe.Cohere2MoeConfig: 4, keye_vl2.KeyeVL2Config: 8}[type(cfg)]
     calls = _calls(engine, monkeypatch)
     before = engine.stats()
     _drive(engine, _requests(cfg, 31))
