@@ -26,9 +26,11 @@ keys. A decode lane (one query) takes the top ``topk`` of its scores and
 gathers those rows of K and V from the padded cache. A prefill chunk would move
 ``tokens x topk`` rows that way (thirty times the cache at 512 tokens), so it
 finds each query's ``topk``-th largest score instead (a bisection on the
-scores' bits: 32 passes over them, no sort) and attends densely under the
-mask ``I >= that score``, with the ties at that score counted off from the
-lowest position.
+scores' bits: 32 passes over them, no sort) and attends under the mask ``I >=
+that score``, with the ties at that score counted off from the lowest
+position: on the chip in one kernel that keeps the scores in VMEM and stops
+at the lane's last live key (``ops/attention.masked_attention``), off it
+densely, 32 queries at a time.
 
 The embedding is not tied to the output head. Text positions only: the
 published rotation splits its frequencies over three position streams
@@ -46,6 +48,7 @@ import numpy as np
 
 from ray_tpu.models import moe
 from ray_tpu.models.gpt import _rotary
+from ray_tpu.ops import attention, backend
 
 #: queries scored or attended at a time in a prefill chunk: the float32 scores
 #: of one block are ``lanes x heads x QUERY_BLOCK x cache`` (134 MB a lane for
@@ -349,7 +352,12 @@ def _make_extend(cfg: KeyeVL2Config, probe: bool):
                     jnp.where(mask[:, None, None], logit, f32(-1e30)), axis=-1)
                 return jnp.einsum("bhgqk,bkhd->bqhgd", weight.astype(dtype), vc)
 
-            out = _by_block(attend_block, q, selected)
+            if backend.on_tpu():
+                # no key past the lane's farthest real query is in any mask
+                live = jnp.where(valid, positions + 1, 0).max(1)
+                out = attention.masked_attention(q, kc, vc, selected, live, scale=scale)
+            else:
+                out = _by_block(attend_block, q, selected)
         out = jnp.einsum(
             "bqhd,hde->bqe", out.reshape(b, tc, cfg.num_heads, cfg.head_dim),
             p["o"]["kernel"].astype(dtype))

@@ -569,3 +569,144 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
+
+
+# ---------------------------------------------------------------------------
+# Pallas kernel for serving: attention under a given mask (forward only)
+# ---------------------------------------------------------------------------
+
+# a chunk of 512 queries meets each tile of its K/V head once, for all its query
+# heads. On a v5e, 4 x 8 heads x 512 queries over 32768 keys: 3.77 ms at 512 x
+# 512, 2.42 at 512 x 1024, 2.45 at 512 x 2048, 2.44 at 512 x 4096 (PERF.md, PR 33)
+MASKED_BLOCK_Q = 512
+MASKED_BLOCK_K = 1024
+MASKED_VMEM_BYTES = 64 * 2**20     # of the chip's 128 MiB; the default scope is 16
+
+
+def _masked_fwd_kernel(
+    blocks_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, acc_ref, m_ref, l_ref,
+    *, scale, groups
+):
+    from jax.experimental import pallas as pl
+
+    ki = pl.program_id(3)
+
+    @pl.when(ki == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    @pl.when(ki < blocks_ref[pl.program_id(0)])
+    def _run():
+        k, v = k_ref[0, 0], v_ref[0, 0]                   # [block_k, d]
+        keep = mask_ref[0].astype(jnp.int32) > 0          # [block_q, block_k]
+
+        def head(g, carry):
+            s = jax.lax.dot_general(
+                q_ref[0, 0, g], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(keep, s, NEG_INF)
+            # a row that has met no key of its own yet sits at NEG_INF and
+            # sums rubbish; its first key sets alpha to an exact 0, and a
+            # block it has no key in leaves it as it was (alpha 1, p 0): a
+            # row's result depends on its own keys alone
+            m_prev = m_ref[g]                              # [block_q, 1]
+            m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_ref[g] = alpha * l_ref[g] + p.sum(-1, keepdims=True)
+            acc_ref[g] = acc_ref[g] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[g] = m_new
+            return carry
+
+        # the query heads of this K/V head, one after another over the same K,
+        # V and mask tiles. A loop and not eight copies: unrolled, the heads
+        # overlap (2.19 ms for 2.45) but are 1.9 MB of code a program, which
+        # the device holds beside the weights
+        jax.lax.fori_loop(0, groups, head, None)
+
+    @pl.when(ki == pl.num_programs(3) - 1)
+    def _finish():
+        l = l_ref[:]
+        o_ref[0, 0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+def masked_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    mask: jax.Array,
+    kv_len: jax.Array,
+    *,
+    scale: Optional[float] = None,
+    block_q: int = MASKED_BLOCK_Q,
+    block_k: int = MASKED_BLOCK_K,
+    interpret: bool = False,
+) -> jax.Array:
+    """``softmax(where(mask, q . k * scale, -1e30)) . v`` per query head, for
+    a serving call over its padded caches, as one kernel: the scores of a tile
+    stay in VMEM (float32; running maximum, sum and accumulator too), the
+    operands go to the MXU as they come and the weights in ``v``'s type.
+
+    ``q`` [b, t, kv, groups, d] are the queries, ``groups`` query heads to a
+    K/V head, which share its tiles of ``k`` and ``v`` [b, s, kv, d] and of
+    ``mask`` [b, t, s] (bool, no head axis: what a query may read, whatever
+    decided it). ``kv_len`` [b] int32 promises that no query of lane ``i``
+    reads a key at or past ``kv_len[i]``: key blocks past it are neither
+    fetched nor computed, whatever they hold. Returns [b, t, kv, groups, d] in
+    ``q``'s type. A query whose mask is empty gets finite rubbish."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, t, kv, groups, d = q.shape
+    s = k.shape[1]
+    scale = float(scale) if scale is not None else 1.0 / float(np.sqrt(d))
+    block_q, block_k = min(block_q, t), min(block_k, s)
+    # whole tiles: padded queries read nothing, padded keys are read by nobody
+    pad_q, pad_k = -t % block_q, -s % block_k
+    if pad_q or pad_k:
+        q = jnp.pad(q, ((0, 0), (0, pad_q)) + ((0, 0),) * 3)
+        k, v = (jnp.pad(x, ((0, 0), (0, pad_k), (0, 0), (0, 0))) for x in (k, v))
+        mask = jnp.pad(mask, ((0, 0), (0, pad_q), (0, pad_k)))
+    nq, nk = (t + pad_q) // block_q, (s + pad_k) // block_k
+    blocks = jnp.clip((kv_len.astype(jnp.int32) + block_k - 1) // block_k, 0, nk)
+
+    def kv_block(bi, ki, blocks):
+        # past the lane's last live block: the block already there, no fetch
+        return jnp.maximum(jnp.minimum(ki, blocks[bi] - 1), 0)
+
+    q_spec = pl.BlockSpec(
+        (1, 1, groups, block_q, d), lambda bi, hi, qi, ki, blocks: (bi, hi, 0, qi, 0))
+    kv_spec = pl.BlockSpec(
+        (1, 1, block_k, d), lambda bi, hi, qi, ki, blocks: (bi, hi, kv_block(bi, ki, blocks), 0))
+    out = pl.pallas_call(
+        functools.partial(_masked_fwd_kernel, scale=scale, groups=groups),
+        name="masked_attention",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, kv, nq, nk),
+            in_specs=[
+                q_spec, kv_spec, kv_spec,
+                pl.BlockSpec(
+                    (1, block_q, block_k),
+                    lambda bi, hi, qi, ki, blocks: (bi, qi, kv_block(bi, ki, blocks))),
+            ],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((groups, block_q, d), jnp.float32),
+                pltpu.VMEM((groups, block_q, 1), jnp.float32),
+                pltpu.VMEM((groups, block_q, 1), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, kv, groups, t + pad_q, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=MASKED_VMEM_BYTES),
+        interpret=interpret,
+    )(
+        blocks, q.transpose(0, 2, 3, 1, 4), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
+        mask.astype(jnp.int8))
+    return out.transpose(0, 3, 1, 2, 4)[:, :t]

@@ -262,7 +262,21 @@ def test_keye_vl2_stage_extend_compiles_at_its_largest_shapes(shaped, form, buil
     compiled = llm._operand_extend(cfg.make_extend_fn()).lower(
         params, operands, shaped((lanes + len(cfg.counters),), jnp.int32), *caches, tc=tc
     ).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 2      # the kernel is there
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    experts = [line for line in kernels if "extend.moe.experts" in line]
+    assert len(experts) >= 2                                     # the kernel is there
+    if form == "prefill":
+        # the chunk's attend is one kernel more, straight under the scope the
+        # readers count, and no array of the dense form's shapes is left:
+        # 32 queries' float32 scores over the cache, and their weights
+        (attend,) = [line for line in kernels if line not in experts]
+        assert "/extend.attention/masked_attention/" in attend
+        groups = cfg.num_heads // cfg.kv_heads
+        for scores in ("f32", "bf16"):
+            assert f"{scores}[1,{cfg.kv_heads},{groups},{keye_vl2.QUERY_BLOCK},{cap}]" not in text
+    else:
+        assert kernels == experts
     memory = compiled.memory_analysis()
     per_token = 2 * cfg.num_layers * sum(h * d for h, d in cfg.cache_arrays)
     assert per_token == 13056
@@ -270,6 +284,7 @@ def test_keye_vl2_stage_extend_compiles_at_its_largest_shapes(shaped, form, buil
     assert 8.7e9 < weights < 8.8e9
     assert memory.temp_size_in_bytes < 0.6e9
     assert memory.argument_size_in_bytes == stated[form]["argument"]
+    # the parent's, from the file: without the dense attend's scores a chunk holds less
     assert memory.temp_size_in_bytes <= stated[form]["temp"] * 1.05
     # beside the pool and the caches of the call in flight
     pool = per_token * engine["num_blocks"] * engine["block_size"]

@@ -1,8 +1,9 @@
 """``models/keye_vl2.py`` on the CPU at a tiny size: the two forms of the
 selection give the same keys (ties, zeros of either sign, fewer visible keys
 than ``topk``), a decode lane and a prefill chunk give the same row of logits,
-padding selects nothing, the softmax router, and the configuration's own
-arithmetic. The comparison with the plain reference is the benchmark's
+padding selects nothing, the chip's attention kernel (interpreted) against the
+dense form, the softmax router, and the configuration's own arithmetic. The
+comparison with the plain reference is the benchmark's
 (``tests/benchmark/test_bench_keye_vl2.py``)."""
 
 import jax
@@ -11,6 +12,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import keye_vl2, moe
+from ray_tpu.ops import attention
 
 CFG = keye_vl2.keye_vl2_nano()
 
@@ -125,6 +127,89 @@ def test_padding_changes_no_real_token_and_counts_nothing(program):
         sum(min(16, t) for t in range(1, 25)) + sum(range(1, 11)))
     # a slot is read if some query chose it: at least the 16 of the last query
     assert CFG.num_layers * (16 + 10) <= named["sparse_slots_read"] <= CFG.num_layers * (24 + 10)
+
+
+def _dense_attend(q, k, v, mask, scale):
+    """What a prefill chunk computes off the chip (``_attend``'s ``attend_block``)."""
+    logit = jnp.einsum("bqhgd,bkhd->bhgqk", q, k, preferred_element_type=jnp.float32) * scale
+    weight = jax.nn.softmax(jnp.where(mask[:, None, None], logit, -1e30), axis=-1)
+    return jnp.einsum("bhgqk,bkhd->bqhgd", weight.astype(v.dtype), v)
+
+
+def _chunk(rng, starts, tokens, cache=64):
+    """A call at the tiny model's widths (8 query heads over 2 K/V heads of 16):
+    lane ``i`` holds ``tokens`` queries from position ``starts[i]``, each
+    reading about half the keys before it."""
+    lanes, kv, groups, dim = len(starts), CFG.kv_heads, CFG.num_heads // CFG.kv_heads, CFG.head_dim
+    q = rng.standard_normal((lanes, tokens, kv, groups, dim)).astype(np.float32)
+    k, v = (rng.standard_normal((lanes, cache, kv, dim)).astype(np.float32) for _ in range(2))
+    positions = np.asarray(starts)[:, None] + np.arange(tokens)[None, :]
+    mask = (np.arange(cache)[None, None, :] <= positions[:, :, None]) & (
+        rng.random((lanes, tokens, cache)) < 0.5)
+    mask[np.arange(lanes)[:, None], np.arange(tokens)[None, :], positions] = True   # itself
+    return q, k, v, mask, positions
+
+
+KERNEL_CASES = {
+    # starts of the lanes, queries, query tile, key tile
+    "grouped_heads_whole_blocks": ((5,), 32, 16, 16),
+    "chunk_is_no_whole_number_of_query_blocks": ((5,), 24, 16, 16),
+    "cache_is_no_whole_number_of_key_blocks": ((5,), 32, 16, 48),
+    "one_lane_short_one_long": ((0, 30), 32, 16, 16),
+    "one_tile_for_everything": ((3, 11), 16, 512, 2048),
+}
+
+
+@pytest.mark.parametrize("empty_row", [False, True], ids=["", "an_all_false_row"])
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+def test_the_attention_kernel_computes_the_dense_form(case, empty_row):
+    """``ops/attention.masked_attention``, interpreted, against the dense form
+    under the same mask. Keys past each lane's live bound hold NaN: the bound
+    engages and nothing past it is read. A query with an empty mask gets
+    finite values and changes no other."""
+    starts, tokens, block_q, block_k = KERNEL_CASES[case]
+    q, k, v, mask, positions = _chunk(np.random.default_rng(len(case)), starts, tokens)
+    if empty_row:
+        mask[0, 3] = False
+    kv_len = positions.max(1) + 1
+    dirty_k, dirty_v = k.copy(), v.copy()
+    step = min(block_k, k.shape[1])
+    for lane, n in enumerate(kv_len):
+        for dirty in (dirty_k, dirty_v):
+            dirty[lane, -(-n // step) * step:] = np.nan
+    if step < k.shape[1]:
+        assert np.isnan(dirty_k).any()           # the short lane does leave blocks out
+    out = np.asarray(attention.masked_attention(
+        jnp.asarray(q), jnp.asarray(dirty_k), jnp.asarray(dirty_v), jnp.asarray(mask),
+        jnp.asarray(kv_len, jnp.int32), scale=0.25, block_q=block_q, block_k=block_k,
+        interpret=True))
+    want = np.asarray(_dense_attend(
+        jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), jnp.asarray(mask), 0.25))
+    assert out.shape == want.shape == q.shape and np.isfinite(out).all()
+    real = np.ones(out.shape[:2], bool)
+    real[0, 3] = not empty_row
+    np.testing.assert_allclose(out[real], want[real], atol=2e-6, rtol=2e-6)
+
+
+@pytest.mark.parametrize("block_k", [16, 64])
+def test_a_query_gets_the_same_bits_at_any_row_of_a_chunk(block_k):
+    """The same token is row 31 of one chunk and row 15 of another (a prompt
+    served from the prefix cache starts its chunks elsewhere), beside other
+    queries and under another live bound: its output is bitwise the same."""
+    q, k, v, mask, positions = _chunk(np.random.default_rng(7), (8,), 32)
+
+    def run(q, mask, positions):
+        return np.asarray(attention.masked_attention(
+            jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), jnp.asarray(mask),
+            jnp.asarray(positions.max(1) + 1, jnp.int32), scale=0.25, block_q=16,
+            block_k=block_k, interpret=True))
+
+    first = run(q, mask, positions)
+    other_q, _, _, other_mask, _ = _chunk(np.random.default_rng(8), (8 + 32,), 16)
+    moved = run(
+        np.concatenate([q[:, 16:], other_q], 1), np.concatenate([mask[:, 16:], other_mask], 1),
+        positions + 16)
+    assert np.array_equal(first[:, 16:], moved[:, :16])
 
 
 def test_the_softmax_router_takes_the_largest_probabilities_and_renormalises():
