@@ -131,14 +131,14 @@ def gpt_j_6b(**kw) -> GPTConfig:
 
 
 def _rotary(x: jax.Array, positions: jax.Array, rotary_dim: int,
-            base: float = 10000.0) -> jax.Array:
-    """Apply RoPE of frequency base ``base`` to the first ``rotary_dim``
-    features of [b, t, h, d]."""
+            base: float = 10000.0, freqs=None) -> jax.Array:
+    """Apply RoPE of frequency base ``base``, or at the ``rotary_dim / 2`` given ``freqs``
+    (``models/kimi_k2.py``'s are YaRN's), to the first ``rotary_dim`` features of [b, t, h, d]."""
     if rotary_dim <= 0:
         return x
     rot, keep = x[..., :rotary_dim], x[..., rotary_dim:]
     half = rotary_dim // 2
-    freqs = 1.0 / (base ** (jnp.arange(half, dtype=jnp.float32) / half))
+    freqs = 1.0 / (base ** (jnp.arange(half, dtype=jnp.float32) / half)) if freqs is None else freqs
     angles = positions[:, :, None].astype(jnp.float32) * freqs  # [b, t, half]
     sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
     cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)

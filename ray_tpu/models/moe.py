@@ -11,9 +11,9 @@ Expert weights carry the ("expert", "embed", "mlp") logical axes: ep shards
 the expert dim, tp can still shard the mlp dim inside each expert.
 
 Beside it, for serving (``models/cohere2_moe.py``): a **dropless** layer for
-one chip's share of an expert-parallel deployment, or for every expert on one
-chip (``models/keye_vl2.py``). :func:`sigmoid_top_k` or :func:`softmax_top_k`
-scores every routed expert, :func:`held_experts_ffn` is told which experts
+one chip's share of an expert-parallel deployment (``models/kimi_k2.py`` too) or every expert on
+one chip (``models/keye_vl2.py``). :func:`sigmoid_top_k`, :func:`softmax_top_k` or
+:func:`sigmoid_bias_top_k` scores every routed expert, :func:`held_experts_ffn` is told which experts
 live here and computes their part of the result for the tokens routed to
 them: the token-expert pairs are sorted by expert and run through a grouped
 matmul (:func:`grouped_matmul`: one kernel that walks the groups and reads an
@@ -42,9 +42,24 @@ from ray_tpu.ops import backend
 COUNTERS = ("moe_tokens", "moe_assignments", "moe_experts_hit", "moe_load_max")
 
 
-def _top_k_of(scores, k: int):
-    """The ``k`` largest of ``scores`` [n, R], normalised over themselves."""
-    top, experts = jax.lax.top_k(scores, k)
+def _logits(h, router):
+    """Every expert's logit for the tokens ``h`` [n, d] under the ``router``
+    [d, R], in float32 at the highest matmul precision: the TPU's default
+    would round ``h`` and the router to bfloat16 and move the k-th choice."""
+    return jnp.dot(
+        h.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST)
+
+
+def _top_k_of(scores, k: int, bias=None):
+    """The ``k`` largest of ``scores`` [n, R], normalised over themselves; with
+    a ``bias`` [R], the ``k`` whose ``scores + bias`` are largest, which the
+    bias chooses and does not weigh."""
+    if bias is None:
+        top, experts = jax.lax.top_k(scores, k)
+    else:
+        _, experts = jax.lax.top_k(scores + bias.astype(scores.dtype), k)
+        top = jnp.take_along_axis(scores, experts, axis=-1)
     return top / top.sum(-1, keepdims=True), experts.astype(jnp.int32)
 
 
@@ -52,20 +67,24 @@ def softmax_top_k(h: jax.Array, router: jax.Array, k: int):
     """As :func:`sigmoid_top_k` with a softmax over all ``R`` experts for the
     scores (``norm_topk_prob``: the ``k`` largest probabilities, divided by
     their sum)."""
-    return _top_k_of(jax.nn.softmax(jnp.dot(
-        h.astype(jnp.float32), router.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST), axis=-1), k)
+    return _top_k_of(jax.nn.softmax(_logits(h, router), axis=-1), k)
 
 
 def sigmoid_top_k(h: jax.Array, router: jax.Array, k: int):
     """Route tokens ``h`` [n, d] over every expert the ``router`` [d, R]
-    scores: sigmoid scores in float32 (at the highest matmul precision: the
-    TPU's default would round ``h`` and the router to bfloat16 and move the
-    k-th choice), the ``k`` largest, normalised over the chosen ``k``.
-    Returns ``(weights [n, k] float32, experts [n, k] int32)``."""
-    return _top_k_of(jax.nn.sigmoid(jnp.dot(
-        h.astype(jnp.float32), router.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST)), k)
+    scores: sigmoid scores in float32, the ``k`` largest, normalised over the
+    chosen ``k``. Returns ``(weights [n, k] float32, experts [n, k] int32)``."""
+    return _top_k_of(jax.nn.sigmoid(_logits(h, router)), k)
+
+
+def sigmoid_bias_top_k(h: jax.Array, router: jax.Array, bias: jax.Array, k: int, scale: float):
+    """As :func:`sigmoid_top_k`, but a ``bias`` [R] on the scores decides which
+    ``k`` are chosen and weighs nothing (``topk_method`` ``noaux_tc``: the
+    correction that balances the experts' load without a loss): the weights
+    are the chosen experts' own scores over their sum, times ``scale``
+    (``routed_scaling_factor``)."""
+    weights, experts = _top_k_of(jax.nn.sigmoid(_logits(h, router)), k, bias)
+    return weights * scale, experts
 
 
 #: (rows, contraction, columns) tile of the TPU's grouped matmul. Measured on a

@@ -581,6 +581,18 @@ flash_attention.defvjp(_flash_fwd, _flash_bwd)
 MASKED_BLOCK_Q = 512
 MASKED_BLOCK_K = 1024
 MASKED_VMEM_BYTES = 64 * 2**20     # of the chip's 128 MiB; the default scope is 16
+# room for the float32 accumulator of the query heads that meet a tile together:
+# 8 heads x 512 queries x a value of 512, beside 19 MiB of their queries and
+# results, each held twice; 16 such heads would take 55 MiB of the 64
+MASKED_ACC_BYTES = 8 * 2**20
+
+
+def _heads_a_tile(groups: int, block_q: int, dv: int) -> int:
+    """How many of a K/V head's ``groups`` query heads meet a tile of it
+    together: the most that divide ``groups`` and whose accumulator fits."""
+    return max(
+        h for h in range(1, groups + 1)
+        if groups % h == 0 and (h == 1 or h * block_q * dv * 4 <= MASKED_ACC_BYTES))
 
 
 def _masked_fwd_kernel(
@@ -652,19 +664,27 @@ def masked_attention(
     operands go to the MXU as they come and the weights in ``v``'s type.
 
     ``q`` [b, t, kv, groups, d] are the queries, ``groups`` query heads to a
-    K/V head, which share its tiles of ``k`` and ``v`` [b, s, kv, d] and of
-    ``mask`` [b, t, s] (bool, no head axis: what a query may read, whatever
-    decided it). ``kv_len`` [b] int32 promises that no query of lane ``i``
-    reads a key at or past ``kv_len[i]``: key blocks past it are neither
-    fetched nor computed, whatever they hold. Returns [b, t, kv, groups, d] in
-    ``q``'s type. A query whose mask is empty gets finite rubbish."""
+    K/V head, which share its tiles of ``k`` [b, s, kv, d] and ``v`` [b, s, kv,
+    dv] and of ``mask`` [b, t, s] (bool, no head axis: what a query may read,
+    whatever decided it). ``dv`` need not be ``d``: a latent cache is scored
+    over all of a row and summed over its first features (``models/kimi_k2.py``:
+    one row of 576 under 64 query heads, 512 of it the value). As many query
+    heads meet a tile together as their float32 accumulator (heads x ``block_q``
+    x ``dv``) has room for in VMEM (:func:`_heads_a_tile`: all of Keye's 8, 8 of
+    a latent's 64), and a K/V head's tiles are fetched once for each such block
+    of its heads. ``kv_len`` [b] int32 promises that no query of lane ``i`` reads a
+    key at or past ``kv_len[i]``: key blocks past it are neither fetched nor
+    computed, whatever they hold. Returns [b, t, kv, groups, dv] in ``q``'s
+    type. A query whose mask is empty gets finite rubbish."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, t, kv, groups, d = q.shape
-    s = k.shape[1]
+    s, dv = k.shape[1], v.shape[-1]
     scale = float(scale) if scale is not None else 1.0 / float(np.sqrt(d))
     block_q, block_k = min(block_q, t), min(block_k, s)
+    heads = _heads_a_tile(groups, block_q, dv)
+    tiles = groups // heads         # the grid's head axis: these blocks, K/V head by K/V head
     # whole tiles: padded queries read nothing, padded keys are read by nobody
     pad_q, pad_k = -t % block_q, -s % block_k
     if pad_q or pad_k:
@@ -678,35 +698,40 @@ def masked_attention(
         # past the lane's last live block: the block already there, no fetch
         return jnp.maximum(jnp.minimum(ki, blocks[bi] - 1), 0)
 
-    q_spec = pl.BlockSpec(
-        (1, 1, groups, block_q, d), lambda bi, hi, qi, ki, blocks: (bi, hi, 0, qi, 0))
-    kv_spec = pl.BlockSpec(
-        (1, 1, block_k, d), lambda bi, hi, qi, ki, blocks: (bi, hi, kv_block(bi, ki, blocks), 0))
+    def q_spec(width):
+        return pl.BlockSpec(
+            (1, 1, heads, block_q, width), lambda bi, hi, qi, ki, blocks: (bi, hi, 0, qi, 0))
+
+    def kv_spec(width):
+        return pl.BlockSpec(
+            (1, 1, block_k, width),
+            lambda bi, hi, qi, ki, blocks: (bi, hi // tiles, kv_block(bi, ki, blocks), 0))
+
     out = pl.pallas_call(
-        functools.partial(_masked_fwd_kernel, scale=scale, groups=groups),
+        functools.partial(_masked_fwd_kernel, scale=scale, groups=heads),
         name="masked_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(b, kv, nq, nk),
+            grid=(b, kv * tiles, nq, nk),
             in_specs=[
-                q_spec, kv_spec, kv_spec,
+                q_spec(d), kv_spec(d), kv_spec(dv),
                 pl.BlockSpec(
                     (1, block_q, block_k),
                     lambda bi, hi, qi, ki, blocks: (bi, qi, kv_block(bi, ki, blocks))),
             ],
-            out_specs=q_spec,
+            out_specs=q_spec(dv),
             scratch_shapes=[
-                pltpu.VMEM((groups, block_q, d), jnp.float32),
-                pltpu.VMEM((groups, block_q, 1), jnp.float32),
-                pltpu.VMEM((groups, block_q, 1), jnp.float32),
+                pltpu.VMEM((heads, block_q, dv), jnp.float32),
+                pltpu.VMEM((heads, block_q, 1), jnp.float32),
+                pltpu.VMEM((heads, block_q, 1), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, kv, groups, t + pad_q, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kv * tiles, heads, t + pad_q, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=MASKED_VMEM_BYTES),
         interpret=interpret,
     )(
-        blocks, q.transpose(0, 2, 3, 1, 4), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
-        mask.astype(jnp.int8))
-    return out.transpose(0, 3, 1, 2, 4)[:, :t]
+        blocks, q.transpose(0, 2, 3, 1, 4).reshape(b, kv * tiles, heads, t + pad_q, d),
+        k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), mask.astype(jnp.int8))
+    return out.reshape(b, kv, groups, t + pad_q, dv).transpose(0, 3, 1, 2, 4)[:, :t]

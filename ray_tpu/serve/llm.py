@@ -5,7 +5,7 @@ answers what the engine asks of it (``make_extend_fn()``, ``init_params(seed)``
 and ``cache_arrays``: what a cached token holds, as ``(heads, dim)`` per array;
 beside the sizes ``num_layers``, ``embed_dim``, ``vocab_size``, ``max_seq_len``
 and ``dtype``; where its ``extend`` counts something, ``counters`` names what;
-``models/cohere2_moe.py``, ``models/keye_vl2.py``).
+``models/cohere2_moe.py``, ``models/keye_vl2.py``, ``models/kimi_k2.py``).
 
 What PR 9 proved with synthetic step functions (continuous batching,
 admission control, multiplexing) this module composes on an actual model
@@ -14,7 +14,8 @@ serving setup from PAPERS.md):
 
 * :class:`KVBlockPool` — the KV cache is paged into fixed-size token
   blocks in arenas that live on the device, one for each array a cached token
-  holds (K and V; an indexer's keys beside them); sequences lease blocks on
+  holds (K and V; an indexer's keys beside them; or no K and V at all but
+  one latent and one rotary key for all heads); sequences lease blocks on
   admission and a :class:`KVLease` frees them **exactly once** on finish /
   cancel / shed / step poison (the same accounting discipline the handle
   enforces for concurrency slots). ``ray_tpu_llm_kv_blocks_in_use`` tracks
@@ -163,9 +164,17 @@ def _paging_programs():
         # lies, the compiler pads every row to the lanes inside the loop and
         # holds the padded copy, eight times the cache (0.8 GB at four lanes of
         # 32768); flat it still re-lays the small cache out once (twice its
-        # size, ``tests/test_chip_compile.py``). K and V are moved as they lie.
-        sources = tuple(
-            a.reshape(layers, blocks, -1) if a.shape[-1] % 128 else a for a in arenas)
+        # size, ``tests/test_chip_compile.py``). K and V are moved as they lie;
+        # an arena of one wide row a token (a latent's: 1 x 640) without its
+        # heads axis, which the compiler lays out behind the block's tokens: a
+        # block moved with that axis in place leaves the loop in another
+        # layout than the caches have, and the copy is twice the caches.
+        def as_moved(a):
+            if a.shape[-1] % 128:
+                return a.reshape(layers, blocks, -1)
+            return a.reshape(a.shape[:3] + a.shape[4:]) if a.shape[3] == 1 else a
+
+        sources = tuple(as_moved(a) for a in arenas)
         # every block of the caches is written below; one buffer each, because
         # the compiler copies a value that starts two loop carries
         empty = tuple(

@@ -1,13 +1,19 @@
-"""What the Keye-VL-2.0 cell's gate reads on the chip, outside a benchmark run:
-for each seed, the gate's prompt through the engine uncached and from the
-prefix cache (bitwise equal?), the served logits against the plain reference's
-(``yardstick.logits_error``, and the worst row), against the reference with
-each named omission, and how far the program's selected sets overlap the
-reference's. The builder sets ``reference.max_logits_error`` in the
-configuration's file from these readings, by hand (PERF.md, section 6).
+"""What a serve cell's gate reads on the chip, outside a benchmark run: for each
+seed, the gate's prompt through the engine uncached and from the prefix cache
+(bitwise equal?), the served logits against the plain reference's
+(``yardstick.logits_error`` over all the new tokens and over the first 16, 32,
+... of them, and the worst row), and against the reference with each omission
+it names (``WRONG`` and ``LOWER``). The architecture and the reference are the
+modules the cell's configuration names (``benchmark/manifest.py``), the tiny
+model is ``tests/benchmark/tiny/<model_type>.json``. Where the reference tells
+what each query selected (``program_selection``: learned sparse attention) and
+the program has a probe for it (``make_probe_fn``), ``--overlap-seeds`` says
+how far the two sets overlap. The builder sets ``reference.max_logits_error``
+in the configuration's file from these readings, by hand (PERF.md, section 6).
 
-    python3 scripts/keye_gate_probe.py --seeds 1,2,3 --wrong-seeds 1 --overlap-seeds 1 \
-        [--tiny] [--out chiprun_out/keye_gate.jsonl]
+    python3 scripts/gate_probe.py --cell kimi-k2-serve-long-context --seeds 1,2,3 \
+        --wrong-seeds 1 [--overlap-seeds 1] [--new-tokens 64] [--tiny] \
+        [--out chiprun_out/gate.jsonl]
 
 One engine serves every seed (its programs compile once); a seed draws the
 weights and the prompt. ``--tiny`` runs the tests' tiny model on whatever
@@ -56,35 +62,39 @@ def selection_of(probe, cfg, params, fed, chunk: int, cap: int):
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--cell", required=True)
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--wrong-seeds", default="")
     ap.add_argument("--overlap-seeds", default="")
+    ap.add_argument("--new-tokens", type=int, default=None, help="in place of the cell's own")
     ap.add_argument("--tiny", action="store_true")
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
+
+    import importlib
 
     import jax
     import numpy as np
 
     from benchmark import manifest, yardstick
-    from benchmark.models import keye_vl2 as arch
-    from benchmark.reference import keye_vl2_reference as ref
-    from ray_tpu.models import keye_vl2
     from ray_tpu.serve import batching, llm
 
+    cell = manifest.Manifest(ROOT).cell(args.cell)
+    arch = importlib.import_module(cell.architecture)
+    ref = importlib.import_module(cell.reference)
     if args.tiny:
-        with open(os.path.join(ROOT, "tests", "benchmark", "tiny", "keye_vl2.json")) as f:
+        with open(os.path.join(
+                ROOT, "tests", "benchmark", "tiny", cell.config["model_type"] + ".json")) as f:
             tiny = json.load(f)
         config, prompt_tokens, new = {**tiny["model"], "reference": tiny["reference"]}, 96, 4
         sizes = dict(num_blocks=32, block_size=16, prefill_chunk=32, lane_buckets=(1,),
                      prefill_token_buckets=(32,), cache_buckets=(128,))
     else:
-        book = manifest.Manifest(ROOT)
-        cell = book.cell("keye-vl2-serve-long-context")
         config = cell.config
         prompt_tokens, new = cell.traffic["gate_prompt_tokens"], cell.traffic["gate_new_tokens"]
         sizes = dict(num_blocks=64, block_size=256, prefill_chunk=512, lane_buckets=(1,),
                      prefill_token_buckets=(512,), cache_buckets=(8192,))
+    new = args.new_tokens or new
     cfg = arch.program_config(manifest.published_keys(config))
     seeds = [int(s) for s in args.seeds.split(",")]
     wrong_seeds = {int(s) for s in args.wrong_seeds.split(",") if s}
@@ -92,7 +102,8 @@ def main(argv=None) -> int:
     say(f"{jax.devices()[0].device_kind}; {arch.describe(cfg)}; engine {sizes}")
     params = cfg.init_params(seeds[0])
     engine = llm.LLMEngine(cfg, params, **sizes)
-    probe = keye_vl2.make_probe_fn(cfg)
+    if overlap_seeds:
+        probe = importlib.import_module(type(cfg).__module__).make_probe_fn(cfg)
     rows = []
     for seed in seeds:
         t0 = time.perf_counter()
@@ -111,25 +122,34 @@ def main(argv=None) -> int:
                 raise seq._error
             results.append(seq._result)
         first, again = results
+        served_s = time.perf_counter() - t0
         fed = prompt + first["tokens"][:-1]
         want = np.asarray(ref.program_logits(params, fed, config, new))
         per_row = [yardstick.logits_error(first["logits"][r], want[r]) for r in range(new)]
+        firsts = [n for n in (16, 32, 64, 128) if n < new]
+
+        def by_rows(other):
+            return {n: yardstick.logits_error(first["logits"][:n], other[:n]) for n in firsts}
+
         row = {
             "seed": seed,
             "cached_tokens": [first["prefix_cached_tokens"], again["prefix_cached_tokens"]],
             "bitwise": bool(
                 again["tokens"] == first["tokens"]
                 and np.array_equal(again["logits"], first["logits"])),
-            "error": yardstick.logits_error(first["logits"], want),
+            "error": yardstick.logits_error(first["logits"], want), "error_of_first": by_rows(want),
             "worst_row": max(per_row), "median_row": float(np.median(per_row)),
             "max_abs": float(np.abs(first["logits"] - want).max()),
+            "std": float(np.std(want)),
             "argmax_agree": int((want.argmax(-1) == np.asarray(first["tokens"])).sum()),
+            "served_s": served_s, "reference_s": time.perf_counter() - t0 - served_s,
         }
         if seed in wrong_seeds:
-            row["wrong"] = {
-                w: yardstick.logits_error(
-                    first["logits"], np.asarray(ref.program_logits(params, fed, config, new, w)))
-                for w in ref.WRONG + (ref.LOWER,)}
+            row["wrong"], row["wrong_of_first"] = {}, {}
+            for w in ref.WRONG + (ref.LOWER,):
+                other = np.asarray(ref.program_logits(params, fed, config, new, w))
+                row["wrong"][w] = yardstick.logits_error(first["logits"], other)
+                row["wrong_of_first"][w] = by_rows(other)
         if seed in overlap_seeds:
             theirs = ref.program_selection(params, fed, config)
             ours = selection_of(
@@ -147,10 +167,9 @@ def main(argv=None) -> int:
         row["seconds"] = time.perf_counter() - t0
         say(json.dumps(row))
         rows.append(row)
-    if args.out:
-        os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
-        with open(args.out, "a") as f:
-            for row in rows:
+        if args.out:
+            os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
+            with open(args.out, "a") as f:
                 f.write(json.dumps(row) + "\n")
     errors = [r["error"] for r in rows]
     say(f"errors over {len(rows)} seeds: min {min(errors):.5f} max {max(errors):.5f}; "

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.models import cohere2_moe, gpt, keye_vl2
+from ray_tpu.models import cohere2_moe, gpt, keye_vl2, kimi_k2
 from ray_tpu.models.training import (
     abstract_state,
     default_optimizer,
@@ -334,10 +334,133 @@ def test_three_arena_paging_programs_compile_without_a_whole_arena_temporary(sha
     assert clone.alias_size_in_bytes == arena_bytes and clone.temp_size_in_bytes < 2**20
 
 
+def _kimi_share():
+    """The served cut of Kimi-K2-Instruct (layer 0 and six expert layers, 12 of
+    384 experts, an eighth of the vocabulary) and its engine sizes, from the
+    configuration's file."""
+    import json
+
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "configs",
+            "kimi-k2-instruct-serve-ep32.json")) as f:
+        config = json.load(f)
+    return kimi_k2.KimiK2Config(
+        vocab_size=config["vocab_size"], num_layers=config["num_hidden_layers"],
+        num_experts=config["n_routed_experts"]), config
+
+
+@pytest.mark.parametrize("form", ["decode", "prefill"])
+def test_kimi_k2_share_extend_compiles_at_its_largest_shapes(shaped, form, built_for_tpu):
+    """One chip's share of Kimi K2 at its published widths (9.70 GB of weights)
+    over the largest cache bucket: a decode call attends in the absorbed form
+    in XLA, a prefill chunk in the attention kernel (one row of 640 under 64
+    query heads, eight at a time, its first 512 the value), and no
+    float32 score of a chunk over the cache is left in the program (4.3 GB a
+    lane if it were); it fits beside the pool and a second call's caches, copies
+    no layer's experts (1.1 GB) and holds the memory the configuration's file
+    states."""
+    built_for_tpu(True)     # the chip's grouped matmul and attention kernel
+    cfg, config = _kimi_share()
+    engine, stated = config["engine"], config["compiled_bytes_per_device"]
+    cap, lanes = engine["cache_buckets"][-1], engine["lane_buckets"][-1]
+    b, tc = (lanes, 1) if form == "decode" else (1, engine["prefill_token_buckets"][-1])
+    assert stated[form]["shape"] == [b, tc, cap]
+    params = jax.tree.map(
+        lambda x: shaped(x.shape, x.dtype), jax.eval_shape(lambda: cfg.init_params(0)))
+    caches = [
+        shaped((cfg.num_layers, b, cap) + tuple(each), cfg.dtype) for each in cfg.cache_arrays]
+    operands = shaped(
+        (b, llm._operand_width(engine["prefill_token_buckets"][-1], cap // engine["block_size"])),
+        jnp.int32)
+    compiled = llm._operand_extend(cfg.make_extend_fn()).lower(
+        params, operands, shaped((lanes + len(cfg.counters),), jnp.int32), *caches, tc=tc
+    ).compile()
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    experts = [line for line in kernels if "extend.moe.experts" in line]
+    assert len(experts) >= 2                                     # the kernel is there
+    if form == "prefill":
+        # layer 0's attend and the scanned layers': straight under the scope the readers count
+        attends = [line for line in kernels if line not in experts]
+        assert len(attends) == 2 and all(
+            "/extend.attention/masked_attention/" in line for line in attends)
+        # ... and no float32 array over the cache as large as 32 queries' scores
+        import math
+        import re
+
+        over_cache = [
+            math.prod(map(int, dims.split(",")))
+            for dims in re.findall(r"f32\[([0-9,]+)\]", text) if str(cap) in dims.split(",")]
+        assert max(over_cache, default=0) < cfg.num_heads * kimi_k2.QUERY_BLOCK * cap
+    else:
+        assert kernels == experts
+        assert f"f32[{lanes},{cfg.num_heads},1,{cap}]" in text     # a decode lane's scores
+    memory = compiled.memory_analysis()
+    per_token = 2 * cfg.num_layers * sum(h * d for h, d in cfg.cache_arrays)
+    assert per_token == 7 * 1280
+    weights = memory.argument_size_in_bytes - per_token * b * cap
+    assert 9.69e9 < weights < 9.71e9
+    assert memory.argument_size_in_bytes == stated[form]["argument"]
+    assert memory.temp_size_in_bytes <= stated[form]["temp"] * 1.05 < 0.4e9
+    # beside the pool and the caches of the call in flight
+    pool = per_token * engine["num_blocks"] * engine["block_size"]
+    assert _device_bytes(compiled) + pool + per_token * lanes * cap < HBM_BYTES
+
+
+def test_an_arena_of_latent_rows_pages_without_a_whole_arena_temporary(shaped):
+    """The pool of the Kimi K2 configuration (640 blocks of 256 tokens, seven
+    layers, one row of 1 x 640 a token: 1.47 GB in one arena) and the programs
+    around ``extend``. The rows are moved without their heads axis and as they
+    lie: no program holds a temporary the size of the arena or of a call's
+    caches (1.17 GB), and the page-back and the clone alias the arena. What the
+    64 spare features of a row buy: a row of 576, one arena or two (512 and
+    64), is re-laid out by every gather."""
+    cfg, config = _kimi_share()
+    engine = config["engine"]
+    blocks, block, tokens = engine["num_blocks"], engine["block_size"], engine["prefill_chunk"]
+    per_token = 2 * cfg.num_layers * cfg.row_dim
+    arena_bytes = per_token * blocks * block
+    assert (cfg.row_dim, per_token, blocks * block) == (640, 8960, 163840)
+    programs = llm._paging_programs()
+    width = llm._operand_width(tokens, engine["cache_buckets"][-1] // block)
+    lanes = engine["lane_buckets"][-1]
+
+    def arenas_of(*rows):
+        return tuple(shaped((cfg.num_layers, blocks, block, 1, dim), cfg.dtype) for dim in rows)
+
+    arenas = arenas_of(cfg.row_dim)
+    for b, cap in ((1, engine["cache_buckets"][0]), (lanes, engine["cache_buckets"][-1])):
+        memory = programs.gather.lower(
+            arenas, shaped((b, width), jnp.int32), cap // block).compile().memory_analysis()
+        assert 0 <= memory.output_size_in_bytes - per_token * b * cap < 4096
+        assert memory.temp_size_in_bytes < 2**20, (b, cap)
+    for b, tc in ((lanes, 1), (1, tokens)):
+        news = (shaped((cfg.num_layers, b, tc, 1, cfg.row_dim), cfg.dtype),)
+        memory = programs.page_back.lower(
+            arenas, news, shaped((b, width), jnp.int32),
+            (shaped((b, tc, cfg.vocab_size), jnp.float32),
+             shaped((b, tc, cfg.embed_dim), jnp.float32)),
+            (shaped((len(cfg.counters),), jnp.int32),), lanes,
+        ).compile().memory_analysis()
+        assert memory.alias_size_in_bytes == arena_bytes, (b, tc)
+        assert memory.temp_size_in_bytes < 2**20, (b, tc)
+    clone = programs.clone.lower(
+        arenas, shaped((), jnp.int32), shaped((), jnp.int32)).compile().memory_analysis()
+    assert clone.alias_size_in_bytes == arena_bytes and clone.temp_size_in_bytes < 2**20
+    # a row of 576 in one arena: the compiler lays it out with the block's tokens
+    # innermost, and the gather re-lays all of it out, twice over; in two arenas
+    # the 64-wide one is re-laid out instead
+    small = (shaped((1, width), jnp.int32), engine["cache_buckets"][0] // block)
+    one = programs.gather.lower(arenas_of(576), *small).compile().memory_analysis()
+    assert one.temp_size_in_bytes >= 2 * 0.9 * arena_bytes
+    two = programs.gather.lower(arenas_of(512, 64), *small).compile().memory_analysis()
+    assert two.temp_size_in_bytes >= arena_bytes // 10
+
+
 @pytest.mark.parametrize(
     "name,extends,pagings",
     [("gptj-6b-serve", 12, 11), ("command-a-plus-serve-ep8", 20, 25),
-     ("keye-vl2-30b-a3b-serve", 16, 19)],
+     ("keye-vl2-30b-a3b-serve", 16, 19), ("kimi-k2-instruct-serve-ep32", 16, 19)],
 )
 def test_a_serve_configuration_compiles_no_more_programs_than_it_did(name, extends, pagings):
     """The programs an engine with the configuration's buckets compiles (a tiny
@@ -357,6 +480,7 @@ def test_a_serve_configuration_compiles_no_more_programs_than_it_did(name, exten
         cohere2_moe.cohere2_moe_nano(max_seq_len=context)
         if name.startswith("command-a-plus")
         else keye_vl2.keye_vl2_nano(max_seq_len=context) if name.startswith("keye")
+        else kimi_k2.kimi_k2_nano(max_seq_len=context) if name.startswith("kimi")
         else dataclasses.replace(gpt.gpt_nano(), max_seq_len=context)
     )
     llm._paging_programs.cache_clear()      # this engine's programs alone

@@ -458,6 +458,36 @@ def test_an_indexers_extend_names_its_scopes_inside_attentions(tc):
         assert f"{scope}/" in text, scope
 
 
+@pytest.mark.parametrize("tc", [1, 8], ids=["decode", "prefill"])
+def test_a_latent_attentions_extend_names_its_scopes(tc):
+    """Both forms of call run the down-projections, the rotations, the
+    absorption and the un-absorption under ``extend.attention.latent``, nested
+    in ``extend.attention`` (cache updates, the attend, ``W_o``); the dense
+    layer's MLP under ``extend.mlp``, the expert layers' three parts under
+    ``extend.moe.*``: the benchmark's readers tell them apart by the innermost."""
+    from ray_tpu.models import kimi_k2
+
+    cfg = kimi_k2.kimi_k2_nano()
+    params = jax.eval_shape(lambda: cfg.init_params(0))
+    caches = [
+        jax.ShapeDtypeStruct((cfg.num_layers, 2, 64) + tuple(each), jnp.float32)
+        for each in cfg.cache_arrays]
+    text = cfg.make_extend_fn().lower(
+        params, jax.ShapeDtypeStruct((2, tc), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.int32), *caches,
+    ).as_text(debug_info=True)
+    for scope in (
+        "extend.embed", "extend.attention", "extend.attention/extend.attention.latent",
+        "extend.mlp", "extend.moe.route", "extend.moe.experts", "extend.moe.shared",
+        "extend.logits",
+    ):
+        assert f"{scope}/" in text, scope
+    # the scores and the weighted sum of latents stand straight under extend.attention
+    assert any(
+        "extend.attention/" in line and "extend.attention.latent" not in line
+        for line in text.splitlines() if "dot_general" in line)
+
+
 def test_the_engine_sums_what_extend_counts_under_the_configurations_names():
     """The engine knows no counter of a model: it sums the int32 vector behind
     ``extend``'s new rows under ``cfg.counters``, and what ``cfg.count_gathered``
