@@ -1567,6 +1567,8 @@ class CoreWorker:
                     self._drain_actor(spec["actor_id"])
                 elif spec.get("__action__") == "lease":
                     self._acquire_lease(spec["sig"])
+                elif self._park_until_deps(spec):
+                    pass  # back in this queue when the argument lands
                 elif spec.get("actor_id") is not None and spec.get("method") is not None:
                     if spec.get("ordered", True):
                         self._enqueue_actor_task(spec)
@@ -1576,6 +1578,24 @@ class CoreWorker:
                     self._submit_one(spec)
             except Exception as e:  # noqa: BLE001
                 self._fail_task(spec.get("spec", spec), e)
+
+    def _park_until_deps(self, spec: Dict[str, Any]) -> bool:
+        """True if ``spec`` takes a result this process still has in flight:
+        the spec then re-enters the queue when that result lands, instead of
+        a submitter waiting for it in ``_resolve_deps``. The pool is small
+        and the lease actions share its queue: a burst of calls that each
+        take the one before (``f.remote(f.remote(...))``) could park every
+        submitter behind the lease action of the first, for good."""
+        if spec.get("_cancelled"):
+            return False  # its ref is resolved; the usual path drops it
+        for oid in (*(spec.get("deps") or ()), *(spec.get("nested") or ())):
+            # ownership before the store read, as in _resolve_deps
+            if self._owns(oid) and not self.memory_store.contains(oid):
+                self.memory_store.add_waiter(
+                    oid, lambda: self._submit_queue.put(spec)
+                )
+                return True
+        return False
 
     def _submit_one(self, spec: Dict[str, Any]):
         """Lease a worker and push the task asynchronously. The submitter

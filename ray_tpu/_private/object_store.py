@@ -590,18 +590,25 @@ class PlasmaStore:
                     continue  # restored or deleted before the flush
             os.makedirs(self._spill_dir, exist_ok=True)
             path = os.path.join(self._spill_dir, oid.hex())
-            with open(path, "wb") as f:
+            # a name of this thread's own: while it writes, with no lock
+            # held, the object may be restored and spilled again, and the
+            # synchronous spill writes ``path`` itself. Unlinking ``path``
+            # on the way out then deleted the only copy of the object
+            # (a reader's restore raised FileNotFoundError for good).
+            tmp = path + ".flush"
+            with open(tmp, "wb") as f:
                 f.write(data)
             with self._cv:
                 cur = self._entries.get(oid)
                 if cur is e and e.spill_data is data and not e.resident:
+                    os.replace(tmp, path)
                     e.spill_path = path
                     e.spill_data = None
                     self._spill_pending_bytes -= e.size
                 else:
                     # restored or deleted while we were writing
                     try:
-                        os.unlink(path)
+                        os.unlink(tmp)
                     except OSError:
                         pass
 

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.ops.ring import mesh_attention
+from ray_tpu.parallel import ring_dense
 
 
 @dataclasses.dataclass(frozen=True)
@@ -166,9 +167,12 @@ class _DenseND(nn.Module):
     use_bias: bool = True
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
+    mesh: Any = None  # the step's device mesh, when it has one
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
+    def __call__(self, x: jax.Array, beside: Optional[jax.Array] = None) -> jax.Array:
+        """``beside``, of the output's shape, is added to the product before
+        the bias is."""
         n_in = len(self.logical_axes) - len(self.features)
         in_shape = x.shape[-n_in:]
         kernel = self.param(
@@ -179,11 +183,14 @@ class _DenseND(nn.Module):
             in_shape + tuple(self.features),
             self.param_dtype,
         )
-        y = jax.lax.dot_general(
-            x.astype(self.dtype),
-            kernel.astype(self.dtype),
-            ((tuple(range(x.ndim - n_in, x.ndim)), tuple(range(n_in))), ((), ())),
+        # a plain dot_general, but for the kernel's gradient on a mesh whose
+        # fsdp axis shards it: that is reduced a shard at a time round the axis
+        y = ring_dense.dense(
+            x.astype(self.dtype), kernel.astype(self.dtype), n_in,
+            self.mesh, self.logical_axes, nn.get_logical_axis_rules(),
         )
+        if beside is not None:
+            y = y + beside
         if self.use_bias:
             bias = self.param(
                 "bias",
@@ -198,13 +205,14 @@ class _DenseND(nn.Module):
 
 
 def _dense(features: Tuple[int, ...], logical_axes: Tuple[str, ...], cfg: GPTConfig,
-           name: str, use_bias: bool = True):
+           name: str, use_bias: bool = True, mesh: Any = None):
     return _DenseND(
         features=tuple(features) if isinstance(features, tuple) else (features,),
         logical_axes=logical_axes,
         use_bias=use_bias,
         dtype=cfg.dtype,
         param_dtype=cfg.param_dtype,
+        mesh=mesh,
         name=name,
     )
 
@@ -217,9 +225,9 @@ class Attention(nn.Module):
     def __call__(self, x: jax.Array, positions: jax.Array) -> jax.Array:
         cfg = self.cfg
         h, hd = cfg.num_heads, cfg.head_dim
-        q = _dense((h, hd), ("embed", "heads", "kv"), cfg, "q", use_bias=False)(x)
-        k = _dense((h, hd), ("embed", "heads", "kv"), cfg, "k", use_bias=False)(x)
-        v = _dense((h, hd), ("embed", "heads", "kv"), cfg, "v", use_bias=False)(x)
+        q = _dense((h, hd), ("embed", "heads", "kv"), cfg, "q", use_bias=False, mesh=self.mesh)(x)
+        k = _dense((h, hd), ("embed", "heads", "kv"), cfg, "k", use_bias=False, mesh=self.mesh)(x)
+        v = _dense((h, hd), ("embed", "heads", "kv"), cfg, "v", use_bias=False, mesh=self.mesh)(x)
         q = _rotary(q, positions, cfg.rotary_dim)
         k = _rotary(k, positions, cfg.rotary_dim)
         # [b, t, h, d] → [b, h, t, d] for the fused kernel
@@ -231,20 +239,22 @@ class Attention(nn.Module):
         out = mesh_attention(
             qh, kh, vh, self.mesh, impl=cfg.seq_parallel_impl, causal=True
         ).transpose(0, 2, 1, 3)
-        return _dense((cfg.embed_dim,), ("heads", "kv", "embed"), cfg, "o", use_bias=False)(
-            out
-        )
+        return _dense(
+            (cfg.embed_dim,), ("heads", "kv", "embed"), cfg, "o", use_bias=False, mesh=self.mesh
+        )(out)
 
 
 class Mlp(nn.Module):
     cfg: GPTConfig
+    mesh: Any = None
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
+    def __call__(self, x: jax.Array, beside: Optional[jax.Array] = None) -> jax.Array:
+        """The MLP of ``x``; ``beside`` joins ``wo``'s product under its bias."""
         cfg = self.cfg
-        x = _dense((cfg.mlp_dim,), ("embed", "mlp"), cfg, "wi")(x)
+        x = _dense((cfg.mlp_dim,), ("embed", "mlp"), cfg, "wi", mesh=self.mesh)(x)
         x = nn.gelu(x)
-        return _dense((cfg.embed_dim,), ("mlp", "embed"), cfg, "wo")(x)
+        return _dense((cfg.embed_dim,), ("mlp", "embed"), cfg, "wo", mesh=self.mesh)(x, beside)
 
 
 def _layer_norm(cfg: GPTConfig, name: str):
@@ -258,27 +268,36 @@ def _layer_norm(cfg: GPTConfig, name: str):
 
 
 class Block(nn.Module):
-    """GPT-J's block: one LayerNorm feeds attention and MLP side by side."""
+    """GPT-J's block: one LayerNorm feeds attention and MLP side by side.
+
+    Attention's output and the MLP's last product are summed before anything
+    else is added to either: ``x + ((attn + mlp's product) + mlp's bias)``.
+    Under tp both are partial sums of a matmul whose contraction is sharded,
+    so their sum is reduced once a layer, where ``x + attn + mlp`` with a bias
+    between the two took two all-reduces. An expert layer's output is no such
+    product and keeps the plain sum.
+
+    The constraints name the step's mesh: flax applies one without a mesh only
+    under ``jax.set_mesh``, and the one on the output is what keeps the
+    compiler from laying the sum out along ``wo``'s bias (embed over fsdp)."""
 
     cfg: GPTConfig
     mesh: Any = None
 
-    def _mlp(self):
-        if self.cfg.moe_num_experts > 0:
-            from ray_tpu.models.moe import MoeMlp
-
-            return MoeMlp(self.cfg, name="mlp")
-        return Mlp(self.cfg, name="mlp")
-
     @nn.compact
     def __call__(self, x: jax.Array, positions: jax.Array) -> jax.Array:
         cfg = self.cfg
-        x = nn.with_logical_constraint(x, ("batch", "seq", "act_embed"))
+        axes = ("batch", "seq", "act_embed")
+        x = nn.with_logical_constraint(x, axes, mesh=self.mesh)
         hidden = _layer_norm(cfg, "ln")(x)
-        x = x + Attention(cfg, self.mesh, name="attn")(hidden, positions) + self._mlp()(
-            hidden
-        )
-        return nn.with_logical_constraint(x, ("batch", "seq", "act_embed"))
+        attn = Attention(cfg, self.mesh, name="attn")(hidden, positions)
+        if cfg.moe_num_experts > 0:
+            from ray_tpu.models.moe import MoeMlp
+
+            x = x + attn + MoeMlp(cfg, name="mlp")(hidden)
+        else:
+            x = x + Mlp(cfg, self.mesh, name="mlp")(hidden, beside=attn)
+        return nn.with_logical_constraint(x, axes, mesh=self.mesh)
 
 
 class ScannedBlocks(nn.Module):

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from ray_tpu._private import accelerator
 from ray_tpu.models.gpt import GPT, GPTConfig, blockwise_next_token_loss
 from ray_tpu.parallel import sharding as shd
 
@@ -162,6 +163,11 @@ def make_train_step(
             # no-ops when no rules are set).
             with nn.logical_axis_rules(active_rules):
                 (hidden, kernel, bias), aux = _apply(params, tokens)
+                # the head's kernel as the loss reads it: gathered over fsdp
+                # once a step and its gradient reduce-scattered once, where
+                # every chunk of the loss gathered it (twice, with the replay)
+                # and reduced its share of the gradient
+                kernel = nn.with_logical_constraint(kernel, ("act_embed", "vocab"), mesh=mesh)
         else:
             (hidden, kernel, bias), aux = _apply(params, tokens)
         # Blockwise xent: never materializes the [b, t, vocab] logits.
@@ -189,7 +195,9 @@ def make_train_step(
         return jax.jit(step, donate_argnums=(0,) if donate else ())
 
     data_sharding = shd.batch_sharding(mesh, ndim=2, rules=rules)
-    kwargs = {}
+    # where the collectives sit in the schedule is the compiler's; what it is
+    # told follows from the mesh (nothing on one device or off the TPU)
+    kwargs = {"compiler_options": accelerator.compiler_options(mesh)}
     if state_shardings_tree is not None:
         kwargs["in_shardings"] = (state_shardings_tree, data_sharding)
         kwargs["out_shardings"] = (

@@ -39,6 +39,55 @@ def test_deep_chain(ray_start_regular):
     assert ray_tpu.get(ref) == 20
 
 
+def _lease_actions_wait(core, monkeypatch):
+    """Hold back every lease action until the returned event is set, as a
+    submitter thread that the scheduler skips for a moment does: whatever
+    the caller submits meanwhile is queued ahead of the first lease."""
+    import threading
+
+    gate = threading.Event()
+    ensure = core._ensure_lease_requests
+
+    def late(sig):
+        gate.wait(30)
+        ensure(sig)
+
+    monkeypatch.setattr(core, "_ensure_lease_requests", late)
+    return gate
+
+
+def test_burst_of_dependent_tasks_cannot_starve_the_first_lease(
+    ray_start_regular, monkeypatch
+):
+    # 20 calls that each take the one before, all queued before the first
+    # task's lease action: more than the pool has submitters (this hung
+    # test_deep_chain whenever the machine was busy)
+    gate = _lease_actions_wait(ray_start_regular.core, monkeypatch)
+    ref = echo.remote(0)
+    for _ in range(20):
+        ref = add.remote(ref, 1)
+    gate.set()
+    assert ray_tpu.get(ref, timeout=60) == 20
+
+
+def test_actor_calls_waiting_for_a_task_cannot_starve_its_lease(
+    ray_start_regular, monkeypatch
+):
+    @ray_tpu.remote(num_cpus=0)
+    class Adder:
+        def plus(self, a, b):
+            return a + b
+
+    actors = [Adder.remote() for _ in range(10)]
+    assert ray_tpu.get([a.plus.remote(0, 0) for a in actors], timeout=60) == [0] * 10
+    gate = _lease_actions_wait(ray_start_regular.core, monkeypatch)
+    ref = echo.remote(5)
+    outs = [a.plus.remote(ref, i) for i, a in enumerate(actors)]
+    time.sleep(0.5)  # every actor's drain action is queued, and taken if it can be
+    gate.set()
+    assert ray_tpu.get(outs, timeout=60) == [5 + i for i in range(10)]
+
+
 def test_put_get_roundtrip(ray_start_regular):
     for value in [1, "hello", {"a": [1, 2, 3]}, (None, True)]:
         assert ray_tpu.get(ray_tpu.put(value)) == value

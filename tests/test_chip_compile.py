@@ -13,6 +13,7 @@ path, so the tests answer for the package's one probe (``built_for_tpu``,
 
 import dataclasses
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs under /tmp
 
@@ -22,6 +23,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from ray_tpu._private import accelerator
 from ray_tpu.models import cohere2_moe, gpt, keye_vl2, kimi_k2
 from ray_tpu.models.training import (
     abstract_state,
@@ -86,29 +88,110 @@ def test_flash_kernels_compile(v5e, head_dim, backward):
     assert text.count("tpu_custom_call") >= (3 if backward else 1)
 
 
-@pytest.mark.parametrize(
-    "spec,n_devices",
-    [(MeshSpec(), 1), (MeshSpec(dp=-1, fsdp=2, tp=2), 4)],
-    ids=["one-chip", "fsdp2xtp2"],
-)
-def test_gptj_width_train_step_compiles(v5e, spec, n_devices, built_for_tpu):
-    """The whole step at GPT-J's widths, depth 2. On the mesh the flash
-    kernel is only legal under shard_map ("Mosaic kernels cannot be
-    automatically partitioned")."""
-    built_for_tpu(True)
-    cfg, batch = _gptj(2), (2, 2048)
-    mesh = spec.build(v5e[:n_devices])
-    opt = default_optimizer(1e-4)
-    _, abstract = abstract_state(cfg, opt, jax.ShapeDtypeStruct(batch, jnp.int32))
-    shardings = nn.meta.unbox(state_shardings(mesh, abstract))
-    state = jax.tree.map(
-        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
-        nn.meta.unbox(abstract), shardings,
-    )
-    tokens = jax.ShapeDtypeStruct(batch, jnp.int32, sharding=shd.batch_sharding(mesh))
-    step = make_train_step(cfg, opt, mesh, state_shardings_tree=shardings)
-    compiled = step.lower(state, tokens).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+_SPECS = {"one-chip": (MeshSpec(), 1), "fsdp2xtp2": (MeshSpec(dp=-1, fsdp=2, tp=2), 4)}
+_STEPS = {}     # compiled once a module: 12 s each
+
+
+def _gptj_step(v5e, built_for_tpu, mesh_id):
+    """The whole train step at GPT-J's widths, depth 2, batch 2 x 2048, compiled
+    for the described chips as ``make_train_step`` builds it (its compiler
+    options are the mesh's): ``(mesh, compiled, text)``."""
+    if mesh_id not in _STEPS:
+        built_for_tpu(True)
+        spec, n_devices = _SPECS[mesh_id]
+        cfg, batch = _gptj(2), (2, 2048)
+        mesh = spec.build(v5e[:n_devices])
+        opt = default_optimizer(1e-4)
+        _, abstract = abstract_state(cfg, opt, jax.ShapeDtypeStruct(batch, jnp.int32))
+        shardings = nn.meta.unbox(state_shardings(mesh, abstract))
+        state = jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+            nn.meta.unbox(abstract), shardings,
+        )
+        tokens = jax.ShapeDtypeStruct(batch, jnp.int32, sharding=shd.batch_sharding(mesh))
+        step = make_train_step(cfg, opt, mesh, state_shardings_tree=shardings)
+        compiled = step.lower(state, tokens).compile()
+        _STEPS[mesh_id] = mesh, compiled, compiled.as_text()
+    return _STEPS[mesh_id]
+
+
+@pytest.mark.parametrize("mesh_id", list(_SPECS))
+def test_gptj_width_train_step_compiles(v5e, mesh_id, built_for_tpu):
+    """On the mesh the flash kernel is only legal under shard_map ("Mosaic
+    kernels cannot be automatically partitioned")."""
+    _, compiled, text = _gptj_step(v5e, built_for_tpu, mesh_id)
+    assert text.count("tpu_custom_call") >= 3
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+_COLLECTIVE = re.compile(r"all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all")
+
+
+def _layer_bodies(text):
+    """The instructions of the scanned layer's forward and backward loop
+    bodies: the two ``while`` bodies that call the flash kernels (one forward
+    kernel; the backward replays it and adds dq and dk/dv)."""
+    computations, lines = {}, None
+    for line in text.splitlines():
+        opened = re.match(r"%?([\w.\-]+) \(.*\{$", line)
+        if opened:
+            lines = computations[opened.group(1)] = []
+        elif line.startswith("}"):
+            lines = None
+        elif lines is not None:
+            lines.append(line.strip())
+    bodies = [
+        computations[name] for name in set(re.findall(r"body=%?([\w.\-]+)", text))
+        if any("tpu_custom_call" in line for line in computations[name])
+    ]
+    forward, backward = sorted(
+        bodies, key=lambda body: sum("tpu_custom_call" in line for line in body))
+    return forward, backward
+
+
+def test_one_chip_step_holds_no_collective_and_gets_no_option(v5e, built_for_tpu):
+    mesh, _, text = _gptj_step(v5e, built_for_tpu, "one-chip")
+    assert accelerator.compiler_options(mesh) == {}
+    assert not _COLLECTIVE.search(text)
+
+
+def test_forward_layer_sends_one_tp_all_reduce_of_one_tensor(v5e, built_for_tpu):
+    """``Block`` adds attention's output and the MLP's product on each chip and
+    the sum is reduced over the tp pairs once: one ``[batch, seq, embed]``
+    operand (a device's share of 2 x 2048 tokens: one row), not a tuple of two,
+    and no other reduction or exchange of an activation in the forward body."""
+    _, _, text = _gptj_step(v5e, built_for_tpu, "fsdp2xtp2")
+    forward, _ = _layer_bodies(text)
+    moved = [
+        line for line in forward
+        if re.search(r" (all-reduce|all-to-all|reduce-scatter)(-start)?\(", line)]
+    (reduce,) = moved
+    assert re.match(r"%?[\w.\-]+ = bf16\[(1,)?2048,4096\]\S* all-reduce\(%?[\w.\-]+\)", reduce), reduce
+    assert "replica_groups=[2,2]<=[4]" in reduce        # tp is the mesh's innermost axis
+
+
+def test_backward_layer_reduces_no_gradient_behind_its_matmul(v5e, built_for_tpu):
+    """The parent's backward body held six synchronous fused all-reduce + slice
+    (``all-reduce-scatter``), one behind each weight gradient's matmul; no
+    compiler setting made one asynchronous. ``ring_dense`` multiplies a
+    gradient a shard at a time and sends each partial sum on (one
+    ``collective-permute`` a weight on an fsdp axis of two) while the next
+    shard multiplies; the head's gradient is reduced once after the loss's
+    loop, not once a chunk inside it. The weights' all-gathers are matmuls in
+    chunks too (the mesh's compiler options), so neither body of a layer nor
+    the loss's loops hold a synchronous all-gather of a weight."""
+    mesh, compiled, text = _gptj_step(v5e, built_for_tpu, "fsdp2xtp2")
+    assert accelerator.compiler_options(mesh)
+    assert "all-reduce-scatter" not in text and " reduce-scatter(" not in text
+    forward, backward = _layer_bodies(text)
+    sends = [line for line in backward if " collective-permute-start(" in line]
+    gradients = [line for line in sends if "/shard_map/ppermute" in line]
+    assert len(gradients) == 6          # one hop a weight on an fsdp axis of two
+    assert all("source_target_pairs={{0,2},{2,0},{1,3},{3,1}}" in line for line in gradients)
+    assert len(sends) >= 6 + 6          # ... and every weight arriving in chunks
+    for body in (forward, backward):
+        gathers = [line for line in body if re.search(r" all-gather(-start)?\(", line)]
+        assert all(re.search(r" = \w+\[(1,)?4096\]", line) for line in gathers), gathers
     assert _device_bytes(compiled) < HBM_BYTES
 
 
@@ -386,7 +469,6 @@ def test_kimi_k2_share_extend_compiles_at_its_largest_shapes(shaped, form, built
             "/extend.attention/masked_attention/" in line for line in attends)
         # ... and no float32 array over the cache as large as 32 queries' scores
         import math
-        import re
 
         over_cache = [
             math.prod(map(int, dims.split(",")))

@@ -156,3 +156,96 @@ def test_one_probe_decides_every_kernel(built_for_tpu, program, kernel):
     built_for_tpu(False)
     text = program()
     assert kernel not in text and "pallas_call" not in text
+
+
+def _block_and_its_parts(moe, dtype):
+    """A ``Block`` with every bias drawn at random (they initialise to zero,
+    and ``mlp/wo/bias`` is what the sum is arranged around), its input, and
+    ``x + attn(h) + mlp(h)`` computed from the parts the plain way."""
+    from ray_tpu.models import gpt
+    from ray_tpu.models.moe import MoeMlp
+
+    cfg = dataclasses.replace(
+        gpt_nano(), dtype=dtype, **(dict(moe_num_experts=4, moe_top_k=2) if moe else {}))
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 16, cfg.embed_dim), dtype)
+    positions = jnp.broadcast_to(jnp.arange(16, dtype=jnp.int32), (2, 16))
+    block = gpt.Block(cfg)
+    params = nn.meta.unbox(block.init(jax.random.PRNGKey(1), x, positions)["params"])
+    keys = iter(jax.random.split(jax.random.PRNGKey(2), 64))
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, leaf: 0.1 * jax.random.normal(next(keys), leaf.shape, leaf.dtype)
+        if path[-1].key == "bias" else leaf, params)
+    out = block.apply({"params": params}, x, positions, mutable=["losses"])[0]
+    hidden = gpt._layer_norm(cfg, "ln").apply({"params": params["ln"]}, x)
+    attn = gpt.Attention(cfg).apply({"params": params["attn"]}, hidden, positions)
+    mlp = (MoeMlp(cfg) if moe else gpt.Mlp(cfg)).apply(
+        {"params": params["mlp"]}, hidden, mutable=["losses"])[0]
+    return out, x + attn + mlp, params
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("moe", [False, True], ids=["dense", "moe"])
+def test_block_is_x_plus_attention_plus_mlp(moe, dtype):
+    """The block sums attention's output and the MLP's product before the
+    MLP's bias (one tp all-reduce a layer): the same mathematics as
+    ``x + attn(h) + mlp(h)``, to the rounding of a different order of adds."""
+    out, plain, params = _block_and_its_parts(moe, dtype)
+    assert out.dtype == plain.dtype == dtype
+    if not moe:
+        assert float(jnp.abs(params["mlp"]["wo"]["bias"]).max()) > 0.01
+    tolerance = dict(rtol=1e-5, atol=1e-5) if dtype == jnp.float32 else dict(rtol=2e-2, atol=2e-2)
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(plain, np.float32), **tolerance)
+
+
+def test_param_tree_of_a_layer_is_where_checkpoints_and_extend_find_it():
+    params = init_params(gpt_nano(), jax.random.PRNGKey(0), (1, 8))
+    layer = params["blocks"]["layers"]
+    paths = {
+        "/".join(k.key for k in path) for path, _ in jax.tree_util.tree_leaves_with_path(layer)}
+    assert paths == {
+        "ln/scale", "ln/bias",
+        "attn/q/kernel", "attn/k/kernel", "attn/v/kernel", "attn/o/kernel",
+        "mlp/wi/kernel", "mlp/wi/bias", "mlp/wo/kernel", "mlp/wo/bias",
+    }
+
+
+class _DescribedMesh:
+    """What ``compiler_options`` reads of a mesh: its devices' platform and
+    its axis sizes (``tests/test_chip_compile.py`` hands it the TPU compiler's
+    own described devices)."""
+
+    def __init__(self, platform, **axes):
+        import types
+
+        self.shape = {"dp": 1, "fsdp": 1, "tp": 1, **axes}
+        self.devices = np.empty(tuple(self.shape.values()), object)
+        self.devices[...] = types.SimpleNamespace(platform=platform)
+
+
+@pytest.mark.parametrize(
+    "mesh,some",
+    [
+        (lambda: None, False),
+        (lambda: MeshSpec().build(jax.devices()[:1]), False),
+        (lambda: MeshSpec(dp=-1).build(), False),
+        (lambda: MeshSpec(dp=-1, fsdp=2, tp=2).build(), False),
+        (lambda: _DescribedMesh("tpu"), False),
+        (lambda: _DescribedMesh("tpu", dp=4), False),
+        (lambda: _DescribedMesh("tpu", dp=2, tp=2), False),
+        (lambda: _DescribedMesh("cpu", fsdp=2, tp=2), False),
+        (lambda: _DescribedMesh("tpu", fsdp=2, tp=2), True),
+        (lambda: _DescribedMesh("tpu", fsdp=4), True),
+    ],
+    ids=["no-mesh", "one-device", "cpu-dp-only", "cpu-fsdp-tp", "tpu-one-device", "tpu-dp-only",
+         "tpu-dp-tp", "cpu-described-fsdp", "tpu-fsdp-tp", "tpu-fsdp"],
+)
+def test_compiler_options_are_read_from_the_mesh(mesh, some):
+    """Options for the TPU compiler only where the mesh's own devices are TPUs
+    and it has an fsdp axis to reduce gradients over; the CPU compiler refuses
+    an ``xla_tpu_*`` name ("No such compile option")."""
+    from ray_tpu._private import accelerator
+
+    options = accelerator.compiler_options(mesh())
+    assert bool(options) == some
+    assert all(name.startswith("xla_tpu_") for name in options)

@@ -95,3 +95,61 @@ def test_plasma_store_uses_native_arena(tmp_path):
     store.delete(oid)
     assert store._arena.allocated_bytes() == 0
     store.close()
+
+
+def test_late_flush_does_not_unlink_a_newer_spill(tmp_path, monkeypatch):
+    """The flusher writes a spilled object with no lock held. Meanwhile the
+    object is restored and spilled again, this time synchronously (the
+    backpressure path), to the file of its id. The flusher, finding its copy
+    stale, must drop its own file and not that one: the object's only copy
+    (``test_spill_workload_completes`` then waited for a restore that raised
+    FileNotFoundError on every retry)."""
+    import os
+    import threading
+    import time
+
+    from ray_tpu._private import object_store
+    from ray_tpu._private.ids import ObjectID
+
+    in_flush, go_on = threading.Event(), threading.Event()
+    makedirs = os.makedirs
+
+    def held(*args, **kwargs):
+        if threading.current_thread().name.endswith("spill-flush") and not go_on.is_set():
+            in_flush.set()
+            go_on.wait(10)
+        return makedirs(*args, **kwargs)
+
+    monkeypatch.setattr(object_store.os, "makedirs", held)
+    store = object_store.PlasmaStore(str(tmp_path), capacity=1 << 20, name="race")
+    try:
+        oid, data = ObjectID.from_random(), bytes(range(256)) * 1024
+        store.put_bytes(oid, data)
+        with store._cv:
+            store._spill_locked(oid, store._entries[oid])  # queued for the flusher
+        assert in_flush.wait(10)  # the flusher holds its copy, nothing written yet
+        assert store.get_locations([oid], timeout=5) is not None  # restored
+        store.release(oid)
+        with store._cv:
+            pending = store._spill_pending_bytes
+            store._spill_pending_bytes = store.capacity  # producers outrun the disk
+            store._spill_locked(oid, store._entries[oid])  # written under the lock
+            store._spill_pending_bytes = pending
+        assert store._entries[oid].spill_path is not None
+        go_on.set()
+        # one flusher, one queue: once it has written a later object it has
+        # passed its verdict on this one
+        later = ObjectID.from_random()
+        store.put_bytes(later, b"y" * 4096)
+        with store._cv:
+            store._spill_locked(later, store._entries[later])
+        deadline = time.monotonic() + 10
+        while store._entries[later].spill_path is None and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert store._entries[later].spill_path is not None
+        off, size = store.get_locations([oid], timeout=5)[oid]
+        assert bytes(store.view(off, size)) == data
+        store.release(oid)
+    finally:
+        go_on.set()
+        store.close()
