@@ -59,11 +59,22 @@ serving setup from PAPERS.md):
   host phase under it; ``accelerator.span``) and, always, wall time and counts that
   ``stats()`` / ``kv_stats`` return beside the bytes, lanes and cache
   slots each device call moved.
+* a record per device call — ``llm.dispatch`` says what the call is (its
+  number, ``prefill`` or ``decode``, its lanes, tokens and cache tokens
+  beside the slots of its padded shape, whether a call was in flight) and
+  ``llm.fetch`` which call it lands and what ``extend`` counted in it; always
+  on, ``stats()["calls"]`` sums the same per form of call, with the time the
+  device spent on each (``busy_s``).
+* the counters a second time, over recorded steps — while a profiler session
+  runs, ``stats()["traced"]`` takes every counter of every step that begins
+  and ends in it, a call's own counts with the step that launched it: a trace
+  taken in a live replica comes with the exact work of the steps it holds.
 """
 
 from __future__ import annotations
 
 import contextlib
+import copy
 import functools
 import hashlib
 import math
@@ -645,13 +656,23 @@ class _Call:
     """A device call between its launch and its landing: what it left on the
     device (``home``, for the host and for the next call, and the ``picked``
     rows; both gone once it has landed), its ``lanes`` as ``(sequence,
-    state)`` and which of them sample a token (``emits``)."""
+    state)`` and which of them sample a token (``emits``). And its record:
+    its number ``seq`` among the engine's calls, its ``form`` (``prefill`` or
+    ``decode``), its padded ``shape`` ``(lanes, tokens, cache)``, the ``step``
+    that launched it and whether that step was ``recorded`` by a profiler
+    session (known at that step's end), and the instant its launch returned
+    (``launched_at``, on the engine's clock: ``LLMEngine._now``)."""
 
-    __slots__ = ("home", "picked", "lanes", "emits", "sampled")
+    __slots__ = (
+        "home", "picked", "lanes", "emits", "sampled",
+        "seq", "form", "shape", "step", "recorded", "launched_at",
+    )
 
-    def __init__(self, home, picked, lanes, emits):
+    def __init__(self, home, picked, lanes, emits, *, seq, form, shape, step, launched_at):
         self.home, self.picked, self.lanes, self.emits = home, picked, lanes, emits
         self.sampled: List[tuple] = []
+        self.seq, self.form, self.shape, self.step = seq, form, shape, step
+        self.recorded, self.launched_at = False, launched_at
 
 
 #: the phases of an engine step, as ``phase_s`` / ``phase_n`` key them and as
@@ -665,6 +686,8 @@ LEAF_PHASES = (
     "admit", "upload", "kv_gather", "dispatch", "kv_scatter", "fetch", "sample",
 )
 PHASES = ("step", "prefill", "decode") + LEAF_PHASES
+#: the two forms of device call, as ``stats()["calls"]`` keys them
+FORMS = ("prefill", "decode")
 #: what one ``_phase`` may cost outside a profiler session, where its span is
 #: a no-op (2.7 us on the sandbox's CPU): under 0.3 ms for the <= 30 phases of
 #: a step. ``tests/test_llm_spans.py`` holds the engine to it.
@@ -743,17 +766,11 @@ class LLMEngine:
         self.steps = 0
         self.admitted = 0
         self.queue_s = 0.0              # sum of enqueue -> admitted
-        self.prefill_tokens = 0
-        self.decode_tokens = 0
-        self.cache_tokens = 0           # live tokens gathered into padded caches
-        self.cache_slots = 0            # lanes x cache bucket of those caches
         self.h2d_bytes = 0
         self.d2h_bytes = 0
         self.h2d_transfers = 0          # host -> device puts
         self.d2h_transfers = 0          # blocking device -> host copies
         self.ids_only_calls = 0         # calls that brought home the ids alone
-        self.lanes_used = 0             # real lanes of the device calls
-        self.lane_slots = 0             # their lane buckets
         self.calls_ahead = 0            # calls launched while another was in flight
         self.tokens_fed_on_device = 0   # lanes whose token the call before left there
         # what ``extend`` counted (one int32 vector behind the caches' new
@@ -775,6 +792,25 @@ class LLMEngine:
         self._window_layers = sum(getattr(self.cfg, "sliding_layers", ()))
         self.phase_s: Dict[str, float] = dict.fromkeys(PHASES, 0.0)
         self.phase_n: Dict[str, int] = dict.fromkeys(PHASES, 0)
+        #: per form of call, over the device calls: how many; their real lanes
+        #: and their lane buckets; the tokens fed and lanes x token bucket; the
+        #: live tokens gathered into padded caches and lanes x cache bucket;
+        #: and ``busy_s``, a call's landing less the later of its own launch's
+        #: return and the landing before it: what the device spent on it as
+        #: the host sees it, exact while the device is never idle between
+        #: calls and an upper bound otherwise. The totals over both forms are
+        #: the properties below (``lanes_used`` ... ``decode_tokens``).
+        self.calls: Dict[str, Dict[str, Any]] = {
+            form: dict(
+                n=0, lanes_used=0, lane_slots=0, tokens=0, token_slots=0,
+                cache_tokens=0, cache_slots=0, busy_s=0.0)
+            for form in FORMS}
+        self._landed_at = 0.0           # the instant of the last landing, by ``_now``
+        #: the seconds the host spent outside ``step`` after the device had
+        #: finished the call in flight (a batcher waiting, a profiler starting
+        #: or stopping: seconds at a time): none of a call's ``busy_s``
+        self._away_s = 0.0
+        self._left_at = 0.0             # when ``step`` last returned
         #: the slowest step since ``stats()`` was last read (so two reads
         #: bound a window, as they do for the counters): when it ended, how
         #: long it took, its lanes and its own seconds per phase. Time in
@@ -789,6 +825,15 @@ class LLMEngine:
         self._counts_bytes = "bytes_limit" in (self._device.memory_stats() or {})
         #: the temporaries of the largest ``extend``, once ``warm`` has asked
         self._temp_bytes = 0
+        #: ``_work()`` over the *recorded* steps alone: those at whose first and
+        #: last instruction a profiler session recorded (``accelerator.
+        #: recording``). Cumulative and never reset, read as a delta like the
+        #: rest. What a step's host counts is that step's; what a landing
+        #: learns of its call (``extend``'s counters, ``busy_s``) goes to the
+        #: step that launched the call, recorded or not, whenever it lands.
+        self.traced = self._work()
+        #: ``_work()`` as the step under way found it, while a session records
+        self._step_began: Optional[Dict[str, Any]] = None
 
     def _warm_paging(self) -> None:
         """Compile the pool's programs for every shape the buckets allow, so
@@ -880,6 +925,16 @@ class LLMEngine:
 
     # -- public stats ------------------------------------------------------
 
+    lanes_used = property(lambda self: self._over_forms("lanes_used"))
+    lane_slots = property(lambda self: self._over_forms("lane_slots"))
+    cache_tokens = property(lambda self: self._over_forms("cache_tokens"))
+    cache_slots = property(lambda self: self._over_forms("cache_slots"))
+    prefill_tokens = property(lambda self: self.calls["prefill"]["tokens"])
+    decode_tokens = property(lambda self: self.calls["decode"]["tokens"])
+
+    def _over_forms(self, key: str) -> int:
+        return sum(self.calls[form][key] for form in FORMS)
+
     def stats(self) -> Dict[str, Any]:
         # a read takes ``slowest_step`` with it: the next starts from here
         slowest, self.slowest_step = self.slowest_step, None
@@ -888,12 +943,21 @@ class LLMEngine:
             "compile_cache": accelerator.compile_cache_stats(),
             "kv_blocks_total": self.pool.num_blocks,
             "kv_blocks_in_use": self.pool.in_use(),
-            "kv_blocks_freed_total": self.pool.freed_total,
             "prefix_hits": self.prefix.hits if self.prefix else 0,
             "prefix_misses": self.prefix.misses if self.prefix else 0,
             "prefix_evictions": self.prefix.evictions if self.prefix else 0,
             "prefix_cached_blocks": len(self.prefix) if self.prefix else 0,
             "adapters_resident": self._mux.loaded_ids(),
+            **self._work(),
+            "traced": copy.deepcopy(self.traced),
+            "slowest_step": slowest,
+        }
+
+    def _work(self) -> Dict[str, Any]:
+        """Every counter that counts work, its groups copied: what ``stats()``
+        returns of them, what ``traced`` keeps a second time, and what a
+        recorded step is the difference of."""
+        return {
             "steps": self.steps,
             "admitted": self.admitted,
             "queue_s": self.queue_s,
@@ -915,18 +979,19 @@ class LLMEngine:
             "window_slots_outside": self.window_slots_outside,
             "phase_s": dict(self.phase_s),
             "phase_n": dict(self.phase_n),
-            "slowest_step": slowest,
+            "calls": {form: dict(counts) for form, counts in self.calls.items()},
         }
 
     @contextlib.contextmanager
-    def _phase(self, name: str):
+    def _phase(self, name: str, **what):
         """One phase of a step: a span ``llm.<name>`` on the profiler's clock,
-        recorded while a profiler session runs (``accelerator.span``), and
+        recorded while a profiler session runs (``accelerator.span``; ``what``
+        is its metadata, and the span is yielded for ``set_metadata``), and
         always its wall time and a count in ``phase_s`` / ``phase_n``."""
         t0 = time.perf_counter()
         try:
-            with accelerator.span("llm." + name):
-                yield
+            with accelerator.span("llm." + name, **what) as span:
+                yield span
         finally:
             self.phase_s[name] += time.perf_counter() - t0
             self.phase_n[name] += 1
@@ -934,7 +999,10 @@ class LLMEngine:
     # -- scheduling --------------------------------------------------------
 
     def step(self, seqs: List[Any]) -> None:
-        before, lanes0 = dict(self.phase_s), self.lanes_used
+        if self._flight is not None and self._flight.home.is_ready():
+            self._away_s += time.perf_counter() - self._left_at
+        before, lanes0, this = dict(self.phase_s), self.lanes_used, self.steps
+        self._step_began = self._work() if accelerator.recording() else None
         try:
             with self._phase("step"):
                 if self.step_delay_s:
@@ -959,7 +1027,7 @@ class LLMEngine:
             # a crashed forward poisons the batch (the batcher fails every
             # caller) — the leases must not ride down with it, nor those of
             # the call in flight, which is dropped: nothing will land it
-            flight, self._flight = self._flight, None
+            flight, self._flight, self._step_began = self._flight, None, None
             for st in [s.state for s in seqs] + [
                     st for _, st in (flight.lanes if flight else ())]:
                 if isinstance(st, _SeqState) and st.lease is not None:
@@ -975,6 +1043,20 @@ class LLMEngine:
                     if v > before[k]
                 },
             }
+        began, self._step_began = self._step_began, None
+        if began is not None:
+            recorded = accelerator.recording()
+            if recorded:
+                _add_difference(self.traced, self._work(), began)
+            if self._flight is not None and self._flight.step == this:
+                # every other call this step launched has landed in it
+                self._flight.recorded = recorded
+        self._left_at = time.perf_counter()
+
+    def _now(self) -> float:
+        """The clock of a call's launch and landing: the host's, less the
+        time it was away from ``step`` with the device done (``_away_s``)."""
+        return time.perf_counter() - self._away_s
 
     def _admit_phase(self, seqs) -> bool:
         with self._phase("admit"):
@@ -1182,7 +1264,10 @@ class LLMEngine:
                 lanes, chunks, emits = zip(*kept)
                 b, t_cap = shape()
         states, decode = [st for _, st in lanes], chunks[0] is None
+        form = "decode" if decode else "prefill"
+        seq = self._over_forms("n") + 1
         with self._phase("upload"):
+            cached = sum(st.length for st in states)    # live tokens in the lanes' caches
             # a lane's last token is on the host if its call has landed, else
             # in the one call in flight: the next program reads it there
             sources = [
@@ -1229,8 +1314,6 @@ class LLMEngine:
             # slots past a lane's frontier hold what the pool holds there:
             # zeros or finite model output, which extend's mask weighs 0
             caches = self.pool.gather(operands, t_cap // bs)
-            self.cache_tokens += sum(st.length for st in states)
-            self.cache_slots += b * t_cap
             if self._count_gathered is not None:
                 for name, n in self._count_gathered(b, t_cap).items():
                     self.counted[name] += n
@@ -1240,30 +1323,37 @@ class LLMEngine:
                 self.window_slots += self._window_layers * b * t_cap
                 self.window_slots_outside += self._window_layers * sum(
                     max(0, st.length - self._window + 1) for st in states)
-        with self._phase("dispatch"):
+        # what the call is: on its span, and summed under its form
+        what = dict(
+            lanes=len(states), lane_slots=b, tokens=fed, token_slots=b * tc,
+            cache_tokens=cached, cache_slots=b * t_cap)
+        with self._phase(
+                "dispatch", call=seq, form=form, ahead=int(flight is not None), **what):
             logits, hidden, *rest = self._extend_call(
                 self._params, operands,
                 self._no_home if flight is None else flight.home, *caches, tc=tc)
             news, counted = rest[:len(caches)], rest[len(caches):]
             del caches, rest        # the caches are freed when extend has run
-            self.lanes_used += len(states)
-            self.lane_slots += b
+            counts = self.calls[form]
+            counts["n"] += 1
+            counts["lanes_used"] += what.pop("lanes")
+            for key, n in what.items():
+                counts[key] += n
             self.calls_ahead += flight is not None
         with self._phase("kv_scatter"):
             home, picked = self.pool.page_back(
                 news, operands, (logits, hidden), counted, self.lane_buckets[-1])
             del logits, hidden, news, operands
-            call = _Call(home, picked, lanes, emits)
+            call = _Call(
+                home, picked, lanes, emits, seq=seq, form=form, shape=(b, tc, t_cap),
+                step=self.steps, launched_at=self._now())
             for i, (st, ch, emit) in enumerate(zip(states, chunks, emits)):
                 st.call, st.lane = call, i
                 st.length += len(ch)
                 st.sent += emit
                 if not decode:
                     st.pos += len(ch)
-            if decode:
-                self.decode_tokens += fed
-            else:
-                self.prefill_tokens += fed
+            if not decode:
                 internal_metrics.inc(
                     "ray_tpu_llm_prefill_tokens_total", fed,
                     {"deployment": self.deployment},
@@ -1301,13 +1391,33 @@ class LLMEngine:
         adapter alone). A forward that raised on the device raises here."""
         call, self._flight = self._flight, None
         states = [st for _, st in call.lanes]
-        with self._phase("fetch"):
+        with self._phase("fetch", call=call.seq) as span:
             # waits for the device; then the ids are home
             home = np.asarray(call.home)
             fetched = [home]
+            now = self._now()
+            busy_s = now - max(call.launched_at, self._landed_at)
+            self._landed_at = now
             # behind the ids: what ``extend`` counted, in its names' order
-            for name, n in zip(self._counter_names, home[self.lane_buckets[-1]:]):
-                self.counted[name] += int(n)
+            counted = {
+                name: int(n)
+                for name, n in zip(self._counter_names, home[self.lane_buckets[-1]:])}
+            if counted:
+                span.set_metadata(**counted)
+            # what a landing learns of its call is the launching step's: this
+            # one's own record takes it as it takes everything (``step``); an
+            # earlier step's call is kept out of this step's record, and goes
+            # to ``traced`` where that step was recorded
+            books = [(self.counted, self.calls)]
+            if call.step != self.steps:
+                if self._step_began is not None:
+                    books.append((self._step_began, self._step_began["calls"]))
+                if call.recorded:
+                    books.append((self.traced, self.traced["calls"]))
+            for flat, calls in books:
+                for name, n in counted.items():
+                    flat[name] += n
+                calls[call.form]["busy_s"] += busy_s
             adapted = any(st.adapter is not None for st in states)
             logits = hidden = None
             if adapted or any(st.return_logits for st in states):
@@ -1389,6 +1499,15 @@ class LLMEngine:
         s.finish(result)
         if st.stream_q is not None:
             st.stream_q.put(("end", result))
+
+
+def _add_difference(into: Dict[str, Any], after: Dict[str, Any], before: Dict[str, Any]) -> None:
+    """``into += after - before``, number by number, through groups of numbers."""
+    for key, value in after.items():
+        if isinstance(value, dict):
+            _add_difference(into[key], value, before[key])
+        else:
+            into[key] += value - before[key]
 
 
 # ---------------------------------------------------------------------------

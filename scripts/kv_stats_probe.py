@@ -2,8 +2,11 @@
 what the benchmark drops: the two ``kv_stats`` reads around the run (lead-in,
 window, lead-out, drain), a poll of ``kv_stats`` beside it, each result's
 ``queue_s``, and the reduced trace. Prints the split of an engine step by
-``phase_s``, the bytes, fills and queue time, and ``breakdown.idle_gaps``
-beside them: the numbers of PERF.md section 5's serve paragraph.
+``phase_s``, the bytes, fills and queue time, the two forms of device call
+(how many, the device's time on each, what of their padded shapes was real)
+and the same split over the steps the profiler session recorded, and
+``breakdown.idle_gaps`` beside them: the numbers of PERF.md section 5's serve
+paragraph.
 
     chiprun -- python3 scripts/kv_stats_probe.py --seed 2147484227 --trace 1 --poll 0.5
 
@@ -174,7 +177,7 @@ def delta(after, before):
     out = {}
     for k, v in after.items():
         if isinstance(v, dict) and isinstance(before.get(k), dict):
-            out[k] = {p: v[p] - before[k].get(p, 0) for p in v if isinstance(v[p], (int, float))}
+            out[k] = delta(v, before[k])        # a group of numbers, or of groups
         elif isinstance(v, (int, float)) and not isinstance(v, bool) and k in before:
             out[k] = v - before[k]
     return out
@@ -237,6 +240,15 @@ def say_split(d, label, say=print):
     say(f"     admitted {d['admitted']}, queue_s mean {d['queue_s'] / max(d['admitted'], 1):.4f}; "
         f"prefill {d['prefill_tokens']} tokens in {n['prefill']} calls; decode "
         f"{d['decode_tokens']} tokens in {n['decode']} calls")
+    for form, c in d.get("calls", {}).items():      # a record per form of call (PR 37)
+        say(f"     {form} calls: n={c['n']}, {1e3 * c['busy_s'] / max(c['n'], 1):.2f} ms each on "
+            f"the device ({100 * c['busy_s'] / step:.1f} % of the steps' time); lanes "
+            f"{c['lanes_used']}/{c['lane_slots']} = {c['lanes_used'] / max(c['lane_slots'], 1):.3f}, "
+            f"tokens {c['tokens']}/{c['token_slots']} = "
+            f"{c['tokens'] / max(c['token_slots'], 1):.3f}, cache {c['cache_tokens']}/"
+            f"{c['cache_slots']} = {c['cache_tokens'] / max(c['cache_slots'], 1):.3f} of their slots")
+    if d.get("traced", {}).get("steps"):            # the same, over recorded steps alone
+        say_split(d["traced"], f"of them, the {d['traced']['steps']} steps a profiler session recorded", say)
 
 
 def report(kept, say=print):

@@ -31,7 +31,7 @@ def test_probe_keeps_the_reads_the_benchmark_drops(tmp_path, monkeypatch):
     bench_helpers.add_tiny_cells(root)
     probe = _load()
     assert probe.LEAF == llm.LEAF_PHASES
-    kept = probe.probe(root, "tiny-serve-cell", 2**31 + 5, 1.5, False, poll_s=0.2)
+    kept = probe.probe(root, "tiny-serve-cell", 2**31 + 5, 1.5, True, poll_s=0.2)
     assert kept["line"]["correct"] and kept["line"]["failed"] == 0
     stats0, stats1 = (s["stats"] for s in kept["snaps"][-2:])
     d = probe.delta(stats1, stats0)
@@ -56,6 +56,17 @@ def test_probe_keeps_the_reads_the_benchmark_drops(tmp_path, monkeypatch):
     assert 0 < d["calls_ahead"] < calls and 0 < d["tokens_fed_on_device"] <= d["decode_tokens"]
     assert (f"run-ahead share {d['calls_ahead']}/{calls} = {d['calls_ahead'] / calls:.3f}" in text
             and f"tokens fed on the device {d['tokens_fed_on_device']}/{d['lanes_used']}" in text)
+    # the two forms of call, from the same two reads, and the split of the recorded steps
+    by_form = d["calls"]
+    assert [by_form[form]["n"] for form in llm.FORMS] == [d["phase_n"]["prefill"], d["phase_n"]["decode"]]
+    for form in llm.FORMS:
+        c = by_form[form]
+        assert f"{form} calls: n={c['n']}, {1e3 * c['busy_s'] / c['n']:.2f} ms each" in text
+        assert f"lanes {c['lanes_used']}/{c['lane_slots']}" in text
+    recorded = d["traced"]["steps"]
+    assert 0 < recorded == kept["trace"]["engine"]["steps"] < d["steps"]
+    assert f"of them, the {recorded} steps a profiler session recorded: {recorded} steps" in text
+    assert f"decode calls: n={d['traced']['calls']['decode']['n']}," in text
 
 
 @pytest.mark.parametrize("cell", [c for _, c in bench_helpers.twins("serve", 1)])

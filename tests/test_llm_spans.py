@@ -1,8 +1,10 @@
 """The serving engine's step on the profiler's clock: the ``llm.*`` spans as a
 real ``jax.profiler`` session records them and as the benchmark's reducer
 labels idle gaps with them, the counters beside them against what the shapes
-give, queue time per request, the slowest step's own record, what the spans
-cost outside a session, and the stable device names (kernels, scopes)."""
+give, per form of call and a second time over the steps a session recorded,
+the record of each device call on its spans, queue time per request, the
+slowest step's own record, what the spans cost outside a session, and the
+stable device names (kernels, scopes)."""
 
 import contextlib
 import dataclasses
@@ -10,6 +12,7 @@ import glob
 import os
 import sys
 import time
+import types
 
 import flax.linen as nn
 import jax
@@ -60,6 +63,20 @@ CALLS = [
     (2, 2, 1, 64, 41 + 10),
 ]
 STEPS, AHEAD, FED_ON_DEVICE = 4, len(CALLS) - 1, 1 + 2 + 2
+PREFILL, DECODE = [CALLS[0], CALLS[2]], [CALLS[1], CALLS[3], CALLS[4]]
+# the step that launches each call, and the one that lands it
+LAUNCHED_IN, LANDED_IN = (1, 1, 2, 2, 3), (1, 2, 2, 3, 4)
+
+
+def _by_form(calls, tokens):
+    """``stats()["calls"][form]`` less ``busy_s`` for these ``CALLS``, which fed ``tokens``."""
+    return {
+        "n": len(calls), "lanes_used": sum(c[0] for c in calls),
+        "lane_slots": sum(c[1] for c in calls), "tokens": tokens,
+        "token_slots": sum(b * tc for _, b, tc, _, _ in calls),
+        "cache_tokens": sum(c[4] for c in calls),
+        "cache_slots": sum(b * cap for _, b, _, cap, _ in calls),
+    }
 
 
 def _requests(lengths=LENGTHS, new=NEW, vocab=NANO.vocab_size):
@@ -83,9 +100,48 @@ def _drive(eng, seqs):
 
 
 def _delta(after, before, key):
+    """``after[key] - before[key]``, through groups of numbers."""
     if isinstance(after[key], dict):
-        return {k: after[key][k] - before[key][k] for k in after[key]}
+        return {k: _delta(after[key], before[key], k) for k in after[key]}
     return after[key] - before[key]
+
+
+def _key_tree(stats):
+    """The keys of ``stats()``, groups within groups; ``slowest_step`` is a
+    record of one step (None before the first), not a group of counters."""
+    return {
+        k: _key_tree(v) if isinstance(v, dict) and k != "slowest_step" else None
+        for k, v in stats.items()}
+
+
+@contextlib.contextmanager
+def _session(where):
+    """A real profiler session, as the benchmark's: host spans from annotations alone."""
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(where), profiler_options=options)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+
+
+def _xplane_of(where):
+    found = glob.glob(os.path.join(str(where), "plugins", "profile", "*", "*.xplane.pb"))
+    assert len(found) == 1
+    return found[0]
+
+
+def _recorded(path, name):
+    """``(start, end, metadata)`` of every host span ``name``, in order."""
+    from jax.profiler import ProfileData
+
+    host = next(
+        p for p in ProfileData.from_file(path).planes if p.name == trace_reduce.HOST_PLANE)
+    return sorted(
+        (e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+        for line in host.lines for e in line.events if e.name == name)
 
 
 @pytest.fixture(scope="module")
@@ -97,27 +153,29 @@ def engine():
 
 
 @pytest.fixture(scope="module")
-def xplane(engine, tmp_path_factory):
+def session(engine, tmp_path_factory):
     """The known workload under a real profiler session, while a batcher
-    waits for work on a thread of its own; the path of the trace."""
+    waits for work on a thread of its own: the path of the trace, and the
+    engine's ``stats()`` just outside the session on either side."""
     idle = batching._ContinuousBatcher(
         lambda seqs: [s.finish(len(s.item)) for s in seqs], 4, 0.0, None, name="idle",
     )
-    where = str(tmp_path_factory.mktemp("profile"))
-    options = jax.profiler.ProfileOptions()
-    options.python_tracer_level = 0
-    options.host_tracer_level = 2
-    jax.profiler.start_trace(where, profiler_options=options)
+    where = tmp_path_factory.mktemp("profile")
+    before = engine.stats()
     try:
-        assert idle.submit("ab") == 2       # the wait that follows starts in the session
-        _drive(engine, _requests())
-        assert idle.submit("abc") == 3      # ... and ends in it
+        with _session(where):
+            assert idle.submit("ab") == 2       # the wait that follows starts in the session
+            _drive(engine, _requests())
+            assert idle.submit("abc") == 3      # ... and ends in it
     finally:
-        jax.profiler.stop_trace()
         idle.shutdown()
-    found = glob.glob(os.path.join(where, "plugins", "profile", "*", "*.xplane.pb"))
-    assert len(found) == 1
-    return found[0]
+    return types.SimpleNamespace(
+        xplane=_xplane_of(where), before=before, after=engine.stats())
+
+
+@pytest.fixture(scope="module")
+def xplane(session):
+    return session.xplane
 
 
 @pytest.fixture(scope="module")
@@ -265,6 +323,146 @@ def test_counters_equal_what_the_shapes_give(engine):
         **dict.fromkeys(CALL_PHASES, len(CALLS)),
     }
     assert _delta(after, before, "queue_s") > 0
+    # the two forms of call, by hand; the totals are their sums
+    by_form = _delta(after, before, "calls")
+    busy = {form: by_form[form].pop("busy_s") for form in llm.FORMS}
+    assert by_form == {
+        "prefill": _by_form(PREFILL, sum(LENGTHS)),
+        "decode": _by_form(DECODE, len(LENGTHS) * (NEW - 1)),
+    }
+    for total in ("lanes_used", "lane_slots", "cache_tokens", "cache_slots"):
+        assert want[total] == sum(by_form[form][total] for form in llm.FORMS), total
+    assert (want["prefill_tokens"], want["decode_tokens"]) == tuple(
+        by_form[form]["tokens"] for form in llm.FORMS)
+    # a call's time on the device ends at its landing and begins no earlier
+    # than the landing before it: the calls' times do not overlap
+    assert all(v > 0 for v in busy.values())
+    assert sum(busy.values()) <= _delta(after, before, "phase_s")["step"]
+
+
+def test_a_host_away_from_the_engine_is_not_the_devices_time(engine):
+    """The call in flight when ``step`` returns is landed by the next step,
+    whenever that comes: where the device had finished it by then (a batcher
+    that waits, a profiler that starts: here, a sleep), the wait is not its."""
+    seqs, before = _requests(), engine.stats()
+    t0 = time.perf_counter()
+    while not all(s.done for s in seqs):
+        engine.step([s for s in seqs if not s.done])
+        time.sleep(0.2)
+    wall_s, spent = time.perf_counter() - t0, _delta(engine.stats(), before, "calls")
+    assert wall_s > STEPS * 0.2 > 0.2 > sum(spent[form]["busy_s"] for form in llm.FORMS) > 0
+
+
+def test_kv_stats_has_every_key_from_construction():
+    """Two reads bound a window only for a key both hold: every counter, every
+    group and every key of a group is there, at zero, before the first step."""
+    eng = llm.LLMEngine(NANO, **ENGINE)
+    built = eng.stats()
+    assert all(v == 0 for v in built["calls"]["decode"].values())
+    assert built["traced"]["steps"] == 0 and built["traced"]["calls"] == built["calls"]
+    assert set(built["traced"]) == set(eng._work()) < set(built)
+    _drive(eng, _requests())
+    assert _key_tree(eng.stats()) == _key_tree(built)
+
+
+# -- (c') the same counters over the steps a session recorded, and the calls' records
+
+
+def test_traced_is_the_counters_over_exactly_the_recorded_steps(engine, session, planes):
+    before, after = session.before, session.after
+    traced = {k: _delta(after["traced"], before["traced"], k) for k in after["traced"]}
+    whole = {k: _delta(after, before, k) for k in traced}
+    assert traced["steps"] == STEPS == len(_spans(_engine_line(planes), "llm.step"))
+    assert traced["calls"]["decode"]["n"] == len(DECODE)
+
+    def flat(group):
+        return [x for v in group.values() for x in (flat(v) if isinstance(v, dict) else [v])]
+
+    # seconds are summed step by step here and all at once there
+    for seconds in ("phase_s", "calls", "queue_s"):
+        assert flat({"": traced.pop(seconds)}) == pytest.approx(
+            flat({"": whole.pop(seconds)}), rel=1e-9)
+    assert traced == whole
+    # and steps outside a session leave it as it is
+    _drive(engine, _requests())
+    assert engine.stats()["traced"] == after["traced"]
+
+
+def test_a_calls_counts_go_to_the_step_that_launched_it(tmp_path):
+    """What ``extend`` counts on the device comes home a step after the call's
+    launch. A session that starts between the two leaves the call out of
+    ``traced``; a call launched in a session's last step is in it, though it
+    lands after the session has stopped. 40 prompt tokens in chunks of 32 + 8,
+    then two decode calls, three layers: 3 x 42 queries, 3 x 32 in the first call."""
+    from ray_tpu.models import keye_vl2
+
+    eng = llm.LLMEngine(
+        keye_vl2.keye_vl2_nano(), num_blocks=16, block_size=16, prefill_chunk=32,
+        lane_buckets=(1,), prefill_token_buckets=(32,), cache_buckets=(64,),
+        prefix_caching=False)
+    built = eng.stats()
+
+    def request():
+        return [batching._Sequence({"prompt": list(range(1, 41)), "max_new_tokens": 3})]
+
+    def counted(after, before):
+        return tuple(
+            _delta(book(after), book(before), "sparse_queries")
+            for book in (lambda s: s, lambda s: s["traced"]))
+
+    # launched before the session, landed in it
+    seqs, before = request(), built
+    eng.step(seqs)
+    assert eng._flight.shape == (1, 32, 64) and not eng._flight.recorded
+    with _session(tmp_path / "late"):
+        steps = _drive(eng, seqs)
+    after = eng.stats()
+    assert counted(after, before) == (3 * 42, 3 * 42 - 3 * 32)
+    assert _delta(after["traced"], before["traced"], "steps") == steps == 3
+    assert _delta(after["traced"], before["traced"], "calls")["prefill"]["n"] == 1
+    # ... and each landing's span says what its call counted, under the configuration's names
+    fetched = [what for _, _, what in _recorded(_xplane_of(tmp_path / "late"), "llm.fetch")]
+    assert [what["call"] for what in fetched] == [1, 2, 3, 4]
+    assert [what["sparse_queries"] for what in fetched] == [3 * 32, 3 * 8, 3, 3]
+    assert set(eng.cfg.counters) <= set(fetched[0])
+
+    # launched in the session's last step, landed after it
+    seqs, before = request(), after
+    with _session(tmp_path / "early"):
+        eng.step(seqs)
+    assert eng._flight.recorded and eng.stats()["traced"]["sparse_queries"] == before[
+        "traced"]["sparse_queries"]
+    _drive(eng, seqs)
+    after = eng.stats()
+    assert counted(after, before) == (3 * 42, 3 * 32)
+    assert _delta(after["traced"], before["traced"], "steps") == 1
+    assert _delta(after["traced"], before["traced"], "calls")["prefill"]["busy_s"] > 0
+    assert _key_tree(after) == _key_tree(built)
+
+
+def test_dispatch_and_fetch_say_which_call_and_what_it_is(xplane, planes):
+    dispatched = _recorded(xplane, "llm.dispatch")
+    fetched = _recorded(xplane, "llm.fetch")
+    steps = _spans(_engine_line(planes), "llm.step")
+    first = dispatched[0][2]["call"]
+    assert [what for _, _, what in dispatched] == [
+        {
+            "call": first + i, "form": "prefill" if call in PREFILL else "decode",
+            "lanes": call[0], "lane_slots": call[1], "tokens": tokens,
+            "token_slots": call[1] * call[2], "cache_tokens": call[4],
+            "cache_slots": call[1] * call[3], "ahead": int(i > 0),
+        }
+        for i, (call, tokens) in enumerate(zip(CALLS, (20 + 32, 1, 8 + 9, 3, 2)))
+    ]
+    # a landing names the call it lands and nothing else (``gpt_nano`` counts nothing)
+    assert [what for _, _, what in fetched] == [{"call": first + i} for i in range(len(CALLS))]
+
+    def step_of(span):
+        return 1 + next(i for i, (a, b) in enumerate(steps) if a <= span[0] and span[1] <= b)
+
+    assert tuple(map(step_of, dispatched)) == LAUNCHED_IN
+    assert tuple(map(step_of, fetched)) == LANDED_IN
+    assert sum(a != b for a, b in zip(LAUNCHED_IN, LANDED_IN)) == STEPS - 1
 
 
 def test_leaf_phases_add_up_to_the_step():
@@ -363,11 +561,19 @@ def test_two_reads_bound_the_slowest_step(engine, monkeypatch):
 
 
 def test_phases_cost_less_than_their_budget_outside_a_session(engine, monkeypatch):
-    """1,000 engine steps with ``_phase`` as it is and 1,000 with it swapped
-    for a bare no-op, turn about, the device programs and the upload stubbed
-    so that a step is the engine's own python: the difference per step stays
-    under ``PHASE_BUDGET_NS`` for each phase the step opens."""
-    ids = {b: np.zeros((b,), np.int32) for b in engine.lane_buckets}
+    """1,000 engine steps with ``_phase`` as it is (a call's ``dispatch`` and
+    ``fetch`` with their metadata) and 1,000 with it swapped for a bare no-op
+    and the step's ``recording()`` for a constant, turn about, the device
+    programs and the upload stubbed so that a step is the engine's own python:
+    the difference per step stays under ``PHASE_BUDGET_NS`` for each phase the
+    step opens."""
+    class Home(np.ndarray):
+        """What a call leaves for the host, already there."""
+
+        def is_ready(self):
+            return True
+
+    ids = {b: np.zeros((b,), np.int32).view(Home) for b in engine.lane_buckets}
     monkeypatch.setattr(engine, "_extend_call", lambda *args, tc: (None,) * 4)
     monkeypatch.setattr(engine.pool, "gather", lambda operands, n: (None, None))
     monkeypatch.setattr(
@@ -375,14 +581,18 @@ def test_phases_cost_less_than_their_budget_outside_a_session(engine, monkeypatc
         lambda news, operands, outputs, counted, width: (ids[width], None))
     monkeypatch.setattr(jax, "device_put", lambda a: a)
     as_it_is, nothing = engine._phase, contextlib.nullcontext()
+    asks, never = llm.accelerator.recording, lambda: False
 
-    def thousand_steps(phase):
+    def thousand_steps(phase, recording=asks):
         engine._phase = phase
-        steps0, t0, seqs = engine.steps, time.perf_counter_ns(), []
+        monkeypatch.setattr(llm.accelerator, "recording", recording)
+        # this thread's own time: a stubbed step waits for nothing, and the
+        # other workers of a loaded machine are not the spans' cost
+        steps0, t0, seqs = engine.steps, time.thread_time_ns(), []
         while engine.steps - steps0 < 1000:
             seqs = [s for s in seqs if not s.done] or _requests((9, 9), 60)
             engine.step(seqs)
-        spent = time.perf_counter_ns() - t0
+        spent = time.thread_time_ns() - t0
         for s in seqs:
             s._release()                    # the unfinished give their blocks back
         return spent
@@ -391,7 +601,7 @@ def test_phases_cost_less_than_their_budget_outside_a_session(engine, monkeypatc
         thousand_steps(as_it_is)
         before = engine.stats()
         runs = [
-            (thousand_steps(as_it_is), thousand_steps(lambda name: nothing))
+            (thousand_steps(as_it_is), thousand_steps(lambda name, **what: nothing, never))
             for _ in range(5)
         ]
     finally:
