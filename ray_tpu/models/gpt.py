@@ -14,7 +14,11 @@ gptj_deepspeed_fine_tuning.ipynb; release/train_tests) — but TPU-first:
 - fused attention from ray_tpu.ops (Pallas flash kernel on TPU).
 
 ``GPTConfig`` says what the model is; how a step runs (kernel or XLA, tile
-sizes, what the remat saves, the loss's chunk) is the code's to decide.
+sizes, what the remat saves, the loss's chunk) is the code's to decide. It has
+decided to keep what is cheap to hold and dear to make again: a layer's remat
+saves its input and the attention kernel's output and logsumexp (the backward
+kernels' own residuals) and replays the rest; the loss keeps no logits and
+makes its gradients from each chunk's logits while it has them.
 `gpt_j_6b()` matches the reference benchmark model's shape (28 layers,
 d_model 4096, 16 heads × 256, rotary_dim 64, vocab 50400, one LayerNorm
 feeding attention and MLP in parallel); `gpt_nano` is for tests, `gpt_1b`
@@ -25,6 +29,7 @@ KV-cache forward of the same block.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Optional, Tuple
 
 import flax.linen as nn
@@ -32,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu.ops.attention import FLASH_RESIDUALS
 from ray_tpu.ops.ring import mesh_attention
 from ray_tpu.parallel import ring_dense
 
@@ -307,9 +313,13 @@ class ScannedBlocks(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array, positions: jax.Array) -> jax.Array:
         cfg = self.cfg
-        # full remat (nothing saved but a layer's input); under a scan the
-        # loop already keeps XLA from merging the replay into the forward
-        block = nn.remat(Block, prevent_cse=False) if cfg.remat else Block
+        # remat keeps a layer's input and the attention kernel's own residuals
+        # (its output, the size of the input, and its logsumexp), so the
+        # backward runs dq and dk/dv without running the forward kernel again;
+        # everything else is replayed. Under a scan the loop already keeps XLA
+        # from merging the replay into the forward
+        keep = jax.checkpoint_policies.save_only_these_names(*FLASH_RESIDUALS)
+        block = nn.remat(Block, prevent_cse=False, policy=keep) if cfg.remat else Block
         x, _ = nn.scan(
             lambda mdl, carry, _: (mdl(carry, positions), None),
             variable_axes={"params": 0, "losses": 0},
@@ -541,12 +551,23 @@ def blockwise_next_token_loss(
 ) -> jax.Array:
     """Mean next-token cross-entropy without materializing [b, t, vocab].
 
-    Scans over sequence chunks; each chunk's logits are computed, reduced to
-    (logsumexp, target-logit) and rematerialized in the backward pass
-    (jax.checkpoint), so peak HBM holds one [b, chunk, vocab] block instead
-    of three full-size f32 logit tensors. This is the XLA-friendly
-    equivalent of a fused cross-entropy kernel.
+    Scans over sequence chunks; each chunk's float32 logits are computed and
+    reduced to (logsumexp, target-logit), so peak HBM holds one
+    [b, chunk, vocab] block instead of three full-size f32 logit tensors.
+    Differentiated, it makes its gradients where it makes its logits
+    (``jax.custom_vjp``): from the same block, ``softmax - onehot`` and with it
+    the chunk's share of the gradients of ``hidden``, ``head_kernel`` and
+    ``head_bias``, the last two summed in float32 over the chunks. The head is
+    multiplied three times a chunk, which is what the mathematics needs, where
+    a rematerialized chunk multiplied it four times; no logits are kept. This
+    is the XLA-friendly equivalent of a fused cross-entropy kernel.
     """
+    return _blockwise_loss(chunk, hidden, head_kernel, head_bias, tokens, mask)
+
+
+def _loss_chunks(hidden, tokens, mask, chunk):
+    """Positions 0 .. t-2 of ``hidden``, their targets and their weights, padded
+    to whole chunks and laid out [chunks, b, chunk, ...] for a scan."""
     b, t, d = hidden.shape
     xs = hidden[:, :-1]
     targets = tokens[:, 1:]
@@ -561,22 +582,85 @@ def blockwise_next_token_loss(
     xs = xs.reshape(b, nc, chunk, d).swapaxes(0, 1)        # [nc, b, chunk, d]
     targets = targets.reshape(b, nc, chunk).swapaxes(0, 1)
     valid = valid.reshape(b, nc, chunk).swapaxes(0, 1)
+    return xs, targets, valid
 
-    compute_dtype = hidden.dtype
 
-    @jax.checkpoint
-    def chunk_nll(x_c, t_c, m_c):
-        logits = (x_c.astype(compute_dtype) @ head_kernel.astype(compute_dtype)).astype(
-            jnp.float32
-        )
-        if head_bias is not None:
-            logits = logits + head_bias.astype(jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        tl = jnp.take_along_axis(logits, t_c[..., None], axis=-1)[..., 0]
-        return ((lse - tl) * m_c).sum()
+def _chunk_logits(x_c, kernel, head_bias):
+    """One chunk's float32 logits; ``kernel`` is in the compute dtype."""
+    logits = (x_c @ kernel).astype(jnp.float32)
+    if head_bias is not None:
+        logits = logits + head_bias.astype(jnp.float32)
+    return logits
+
+
+def _chunk_nll(logits, t_c, m_c):
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    tl = jnp.take_along_axis(logits, t_c[..., None], axis=-1)[..., 0]
+    return ((lse - tl) * m_c).sum(), lse
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _blockwise_loss(chunk, hidden, head_kernel, head_bias, tokens, mask):
+    xs, targets, valid = _loss_chunks(hidden, tokens, mask, chunk)
+    kernel = head_kernel.astype(hidden.dtype)
 
     def body(acc, args):
-        return acc + chunk_nll(*args), None
+        x_c, t_c, m_c = args
+        return acc + _chunk_nll(_chunk_logits(x_c, kernel, head_bias), t_c, m_c)[0], None
 
     total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (xs, targets, valid))
     return total / jnp.maximum(valid.sum(), 1.0)
+
+
+def _blockwise_loss_fwd(chunk, hidden, head_kernel, head_bias, tokens, mask):
+    b, t, d = hidden.shape
+    compute_dtype = hidden.dtype
+    xs, targets, valid = _loss_chunks(hidden, tokens, mask, chunk)
+    kernel = head_kernel.astype(compute_dtype)
+    denom = jnp.maximum(valid.sum(), 1.0)
+    vocab = jnp.arange(kernel.shape[-1], dtype=targets.dtype)
+
+    def body(carry, args):
+        total, d_kernel, d_bias = carry
+        x_c, t_c, m_c = args
+        logits = _chunk_logits(x_c, kernel, head_bias)
+        nll, lse = _chunk_nll(logits, t_c, m_c)
+        d_logits = (jnp.exp(logits - lse[..., None]) - (vocab == t_c[..., None])) * (
+            m_c / denom)[..., None]
+        if head_bias is not None:
+            d_bias = d_bias + d_logits.sum((0, 1))
+        # the cotangent of the logits' ``astype(float32)``: both products with
+        # it read it in the compute dtype, as autodiff's did
+        d_logits = d_logits.astype(compute_dtype)
+        d_x = jnp.einsum("bcv,dv->bcd", d_logits, kernel)
+        d_kernel = d_kernel + jnp.einsum(
+            "bcd,bcv->dv", x_c, d_logits, preferred_element_type=jnp.float32)
+        return (total + nll, d_kernel, d_bias), d_x
+
+    (total, d_kernel, d_bias), d_xs = jax.lax.scan(
+        body,
+        (jnp.zeros((), jnp.float32), jnp.zeros(kernel.shape, jnp.float32),
+         None if head_bias is None else jnp.zeros(head_bias.shape, jnp.float32)),
+        (xs, targets, valid),
+    )
+    # back to [b, t, d]: the padding goes, the last position predicts nothing
+    d_hidden = d_xs.swapaxes(0, 1).reshape(b, -1, d)[:, : t - 1]
+    d_hidden = jnp.pad(d_hidden, ((0, 0), (0, 1), (0, 0)))
+    # in the parameters' dtypes here and now: a cast left to whoever reads the
+    # gradient keeps the float32 sum alive until the optimizer does
+    gradients = jax.lax.optimization_barrier((
+        d_hidden,
+        d_kernel.astype(head_kernel.dtype),
+        None if head_bias is None else d_bias.astype(head_bias.dtype),
+    ))
+    return total / denom, gradients
+
+
+def _blockwise_loss_bwd(chunk, gradients, g):
+    # tokens and mask have no cotangent (None is a zero)
+    return tuple(
+        None if each is None else (g * each).astype(each.dtype) for each in gradients
+    ) + (None, None)
+
+
+_blockwise_loss.defvjp(_blockwise_loss_fwd, _blockwise_loss_bwd)

@@ -21,6 +21,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops import backend
 
@@ -551,11 +552,21 @@ def flash_attention(q, k, v, causal, scale, block_q, block_k, interpret):
     return out
 
 
+# what the forward kernel returns, by name: a remat that keeps both
+# (``models/gpt.py`` ``ScannedBlocks``) hands them to the backward kernels, and
+# one that keeps neither, or only one, runs the forward kernel again for them
+FLASH_RESIDUALS = ("flash_out", "flash_lse")
+
+
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
+    """The forward rule: the kernel's output and logsumexp, named as the kernel
+    lays them out ([b, h, t, d] and [b, h, t], before the model transposes
+    anything) and both of them: it makes them in one call."""
     out, lse = _flash_attention_tpu(
         q, k, v, causal=causal, scale=scale, block_q=block_q, block_k=block_k,
         interpret=interpret,
     )
+    out, lse = (checkpoint_name(x, name) for x, name in zip((out, lse), FLASH_RESIDUALS))
     return out, (q, k, v, out, lse)
 
 

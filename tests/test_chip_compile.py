@@ -92,14 +92,15 @@ _SPECS = {"one-chip": (MeshSpec(), 1), "fsdp2xtp2": (MeshSpec(dp=-1, fsdp=2, tp=
 _STEPS = {}     # compiled once a module: 12 s each
 
 
-def _gptj_step(v5e, built_for_tpu, mesh_id):
-    """The whole train step at GPT-J's widths, depth 2, batch 2 x 2048, compiled
-    for the described chips as ``make_train_step`` builds it (its compiler
-    options are the mesh's): ``(mesh, compiled, text)``."""
-    if mesh_id not in _STEPS:
+def _gptj_step(v5e, built_for_tpu, mesh_id, depth=2, batch=(2, 2048)):
+    """The whole train step at GPT-J's widths, depth 2, batch 2 x 2048 unless
+    told otherwise, compiled for the described chips as ``make_train_step``
+    builds it (its compiler options are the mesh's): ``(mesh, compiled, text)``."""
+    key = (mesh_id, depth, batch)
+    if key not in _STEPS:
         built_for_tpu(True)
         spec, n_devices = _SPECS[mesh_id]
-        cfg, batch = _gptj(2), (2, 2048)
+        cfg = _gptj(depth)
         mesh = spec.build(v5e[:n_devices])
         opt = default_optimizer(1e-4)
         _, abstract = abstract_state(cfg, opt, jax.ShapeDtypeStruct(batch, jnp.int32))
@@ -111,8 +112,8 @@ def _gptj_step(v5e, built_for_tpu, mesh_id):
         tokens = jax.ShapeDtypeStruct(batch, jnp.int32, sharding=shd.batch_sharding(mesh))
         step = make_train_step(cfg, opt, mesh, state_shardings_tree=shardings)
         compiled = step.lower(state, tokens).compile()
-        _STEPS[mesh_id] = mesh, compiled, compiled.as_text()
-    return _STEPS[mesh_id]
+        _STEPS[key] = mesh, compiled, compiled.as_text()
+    return _STEPS[key]
 
 
 @pytest.mark.parametrize("mesh_id", list(_SPECS))
@@ -124,13 +125,20 @@ def test_gptj_width_train_step_compiles(v5e, mesh_id, built_for_tpu):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
+def test_one_chip_step_fits_the_chip_at_the_cells_own_size(v5e, built_for_tpu):
+    """``gptj-train-1chip-fixed-batch`` as it runs: depth 6, 4 x 2048 tokens,
+    bf16 parameters and moments. The layers' kept kernel outputs and the loss's
+    float32 sum of the head's gradient have to fit beside 9.7 GB of state."""
+    _, compiled, text = _gptj_step(v5e, built_for_tpu, "one-chip", depth=6, batch=(4, 2048))
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
 _COLLECTIVE = re.compile(r"all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all")
 
 
-def _layer_bodies(text):
-    """The instructions of the scanned layer's forward and backward loop
-    bodies: the two ``while`` bodies that call the flash kernels (one forward
-    kernel; the backward replays it and adds dq and dk/dv)."""
+def _computations(text):
+    """The instructions of each computation of a compiled program, by name."""
     computations, lines = {}, None
     for line in text.splitlines():
         opened = re.match(r"%?([\w.\-]+) \(.*\{$", line)
@@ -140,13 +148,56 @@ def _layer_bodies(text):
             lines = None
         elif lines is not None:
             lines.append(line.strip())
-    bodies = [
-        computations[name] for name in set(re.findall(r"body=%?([\w.\-]+)", text))
-        if any("tpu_custom_call" in line for line in computations[name])
-    ]
-    forward, backward = sorted(
-        bodies, key=lambda body: sum("tpu_custom_call" in line for line in body))
+    return computations
+
+
+def _loop_bodies(text):
+    computations = _computations(text)
+    return [computations[name] for name in set(re.findall(r"body=%?([\w.\-]+)", text))]
+
+
+def _kernels(body):
+    return sum('custom_call_target="tpu_custom_call"' in line for line in body)
+
+
+def _layer_bodies(text):
+    """The instructions of the scanned layer's forward and backward loop
+    bodies: the two ``while`` bodies that call the flash kernels. The forward's
+    holds the forward kernel; the backward's holds dq and dk/dv and no third:
+    the forward kernel's output and logsumexp are kept, not made again."""
+    forward, backward = sorted(filter(_kernels, _loop_bodies(text)), key=_kernels)
     return forward, backward
+
+
+@pytest.mark.parametrize("mesh_id", list(_SPECS))
+def test_backward_layer_runs_two_kernels(v5e, mesh_id, built_for_tpu):
+    """dq and dk/dv. A third would be the forward kernel run again for the
+    output and logsumexp that the layer's remat keeps (under ``shard_map`` on
+    the mesh as on one chip: the names are in the kernel's forward rule)."""
+    forward, backward = _layer_bodies(_gptj_step(v5e, built_for_tpu, mesh_id)[2])
+    assert (_kernels(forward), _kernels(backward)) == (1, 2)
+
+
+def test_loss_loop_reduces_no_head_gradient_a_chunk(v5e, built_for_tpu):
+    """The loss's loop carries each chip's partial sum of the head's gradient
+    (float32, ``[embed, vocab / tp]``) and the sum over the batch's axes is
+    taken once, behind the loop: the body holds the reductions over the
+    vocabulary's shards (maximum, sum and target logit, the hidden state's
+    gradient) and none of an ``[embed, vocab / tp]`` operand; a chunk's logits
+    are multiplied once, and so is each of the two gradients."""
+    _, _, text = _gptj_step(v5e, built_for_tpu, "fsdp2xtp2")
+    cfg = _gptj(2)
+    head = f"[{cfg.embed_dim},{cfg.vocab_size // 2}]"
+    (loss,) = [
+        body for body in _loop_bodies(text)
+        if any("train.loss" in line for line in body) and any(f"f32{head}" in line for line in body)]
+    reductions = [line for line in loss if re.search(r" all-reduce(-start)?\(", line)]
+    assert reductions and not any(head in line.split(" all-reduce")[0] for line in reductions)
+    assert not any(re.search(r" (reduce-scatter|all-gather|all-to-all)(-start)?\(", line) for line in loss)
+    behind = [
+        line for line in text.splitlines()
+        if re.search(r" all-reduce(-start)?\(", line) and head in line.split(" all-reduce")[0]]
+    assert len(behind) == 1 and "train.loss" in behind[0]
 
 
 def test_one_chip_step_holds_no_collective_and_gets_no_option(v5e, built_for_tpu):

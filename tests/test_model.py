@@ -14,7 +14,8 @@ import numpy as np
 import pytest
 
 from ray_tpu.models.cohere2_moe import cohere2_moe_nano
-from ray_tpu.models.gpt import GPT, GPTConfig, gpt_nano, next_token_loss
+from ray_tpu.models.gpt import (
+    GPT, GPTConfig, blockwise_next_token_loss, gpt_nano, next_token_loss)
 from ray_tpu.models.training import (
     abstract_state,
     default_optimizer,
@@ -125,13 +126,37 @@ def test_gptconfig_holds_what_the_model_is_and_no_run_switch():
             GPTConfig(**{gone: None})
 
 
-def _train_step_jaxpr():
-    cfg = dataclasses.replace(gpt_nano(), num_heads=1, head_dim=64)
+def _train_step_program(**changes):
+    cfg = dataclasses.replace(gpt_nano(), num_heads=1, head_dim=64, **changes)
     optimizer = default_optimizer()
     tokens = jax.ShapeDtypeStruct((2, 128), jnp.int32)
     _, state = abstract_state(cfg, optimizer, tokens)
     step = make_train_step(cfg, optimizer, donate=False)
-    return str(jax.make_jaxpr(step)(nn.meta.unbox(state), tokens))
+    return jax.make_jaxpr(step)(nn.meta.unbox(state), tokens)
+
+
+def _train_step_jaxpr():
+    return str(_train_step_program())
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold (scan
+    and remat bodies, custom rules, jits), depth first."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            for each in param if isinstance(param, (list, tuple)) else (param,):
+                inner = getattr(each, "jaxpr", each)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+def _products_with(jaxpr, size):
+    """The ``dot_general``s with an operand that has a dimension of ``size``."""
+    return [
+        eqn for eqn in _equations(jaxpr)
+        if eqn.primitive.name == "dot_general"
+        and any(size in var.aval.shape for var in eqn.invars)]
 
 
 def _command_a_plus_extend_jaxpr():
@@ -156,6 +181,129 @@ def test_one_probe_decides_every_kernel(built_for_tpu, program, kernel):
     built_for_tpu(False)
     text = program()
     assert kernel not in text and "pallas_call" not in text
+
+
+def test_the_train_step_multiplies_the_head_three_times():
+    """Logits, the hidden state's gradient and the kernel's: the loss makes its
+    gradients from the logits it has, where a rematerialized chunk multiplied a
+    fourth time to have them again."""
+    vocab = 384     # no other dimension of the step
+    assert len(_products_with(_train_step_program(vocab_size=vocab).jaxpr, vocab)) == 3
+
+
+def test_the_backward_layer_runs_no_forward_kernel(built_for_tpu):
+    """The layer's remat keeps the forward kernel's output and logsumexp, so
+    the step holds the kernel once (the forward's), and dq and dk/dv once."""
+    built_for_tpu(True)
+    kernels = sorted(
+        eqn.params["name"] for eqn in _equations(_train_step_program().jaxpr)
+        if eqn.primitive.name == "pallas_call")
+    assert kernels == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+
+
+def test_gradient_with_the_kernels_residuals_kept_is_the_gradient_without_remat(
+        built_for_tpu, monkeypatch):
+    """What the policy keeps is what the backward kernels would have been given
+    anyway: the same gradient as with no remat at all, to float32 rounding. The
+    kernels run interpreted, so that the names are in the program."""
+    from ray_tpu.ops import attention
+
+    built_for_tpu(True)
+    real = attention.flash_attention
+    monkeypatch.setattr(
+        attention, "flash_attention",
+        lambda q, k, v, causal, scale, block_q, block_k, interpret: real(
+            q, k, v, causal, scale, 32, 32, True))
+    cfg = dataclasses.replace(gpt_nano(), num_heads=2, head_dim=64)
+    params = init_params(cfg, jax.random.PRNGKey(0), (2, 64))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 64), 0, cfg.vocab_size)
+
+    def gradient(remat):
+        model = GPT(dataclasses.replace(cfg, remat=remat))
+        program = jax.make_jaxpr(jax.grad(
+            lambda p: next_token_loss(model.apply({"params": p}, tokens), tokens)))(params)
+        names = [
+            eqn.params["name"] for eqn in _equations(program.jaxpr)
+            if eqn.primitive.name in ("pallas_call", "name")]
+        return jax.core.eval_jaxpr(program.jaxpr, program.consts, *jax.tree.leaves(params)), names
+
+    kept, names = gradient(remat=True)
+    plain, _ = gradient(remat=False)
+    assert names.count("flash_fwd") == 1 and {"flash_out", "flash_lse"} <= set(names)
+    for a, b in zip(kept, plain):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+_LOSS_CHUNK = 8
+
+
+@pytest.mark.parametrize("head", ["bias", "no-bias", "tied"])
+@pytest.mark.parametrize("length", [2 * _LOSS_CHUNK + 1, 2 * _LOSS_CHUNK + 5], ids=["whole", "pads"])
+@pytest.mark.parametrize("masked", ["no-mask", "stretch", "all"])
+def test_blockwise_loss_and_its_gradients_are_the_full_logits(masked, length, head):
+    """Value and gradients of the loss that makes its gradients chunk by chunk
+    against autodiff of the plain loss over the whole float32 logits, under a
+    cotangent other than 1."""
+    b, d, vocab = 2, 16, 40
+    keys = jax.random.split(jax.random.PRNGKey(length), 4)
+    hidden = jax.random.normal(keys[0], (b, length, d), jnp.float32)
+    # the tied head is the embedding, transposed where the model transposes it
+    weight = 0.3 * jax.random.normal(keys[1], (vocab, d) if head == "tied" else (d, vocab))
+    bias = 0.1 * jax.random.normal(keys[2], (vocab,)) if head == "bias" else None
+    tokens = jax.random.randint(keys[3], (b, length), 0, vocab)
+    mask = {
+        "no-mask": None,
+        "stretch": jnp.ones((b, length), jnp.int32).at[:, 3:_LOSS_CHUNK + 2].set(0),
+        "all": jnp.zeros((b, length), jnp.int32),
+    }[masked]
+
+    def kernel_of(weight):
+        return weight.T if head == "tied" else weight
+
+    def blockwise(hidden, weight, bias):
+        return 2.5 * blockwise_next_token_loss(
+            hidden, kernel_of(weight), bias, tokens, mask, chunk=_LOSS_CHUNK)
+
+    def plain(hidden, weight, bias):
+        logits = hidden @ kernel_of(weight)
+        return 2.5 * next_token_loss(logits if bias is None else logits + bias, tokens, mask)
+
+    wrt = (0, 1, 2) if bias is not None else (0, 1)
+    value, gradients = jax.value_and_grad(blockwise, wrt)(hidden, weight, bias)
+    expected, expected_gradients = jax.value_and_grad(plain, wrt)(hidden, weight, bias)
+    np.testing.assert_allclose(value, expected, rtol=1e-6, atol=1e-6)
+    if masked == "all":
+        assert float(value) == 0.0
+    for got, want in zip(gradients, expected_gradients):
+        assert got.shape == want.shape and got.dtype == want.dtype
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    if masked == "all":
+        assert all(float(jnp.abs(g).max()) == 0.0 for g in gradients)
+    else:
+        assert float(jnp.abs(gradients[1]).max()) > 1e-3
+    # undifferentiated (an eval step), it is the forward scan: the logits' product alone
+    undifferentiated = jax.make_jaxpr(blockwise)(hidden, weight, bias)
+    assert len(_products_with(undifferentiated.jaxpr, vocab)) == 1
+    assert len(_products_with(jax.make_jaxpr(jax.grad(blockwise, wrt))(
+        hidden, weight, bias).jaxpr, vocab)) == 3
+
+
+def test_blockwise_loss_sums_the_head_gradient_in_float32():
+    """With a bfloat16 head the chunks' shares of the kernel's gradient are
+    added in float32 and rounded once: nearer the float32 gradient than a sum
+    rounded a chunk at a time can be relied on to be, and in the kernel's dtype."""
+    b, t, d, vocab = 2, 8 * _LOSS_CHUNK + 1, 16, 40
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    hidden = jax.random.normal(keys[0], (b, t, d), jnp.bfloat16)
+    kernel = (0.3 * jax.random.normal(keys[1], (d, vocab))).astype(jnp.bfloat16)
+    tokens = jax.random.randint(keys[2], (b, t), 0, vocab)
+    got = jax.grad(
+        lambda k: blockwise_next_token_loss(hidden, k, None, tokens, chunk=_LOSS_CHUNK))(kernel)
+    want = jax.grad(lambda k: next_token_loss(
+        hidden.astype(jnp.float32) @ k, tokens))(kernel.astype(jnp.float32))
+    assert got.dtype == jnp.bfloat16
+    # one rounding of the sum to bfloat16, and the cotangent's rounding in each product
+    np.testing.assert_allclose(np.asarray(got, np.float32), want, rtol=2e-2, atol=2e-4)
 
 
 def _block_and_its_parts(moe, dtype):
