@@ -1,0 +1,482 @@
+"""granite-4.0-h (``model_type`` ``granitemoehybrid``): the serving path behind
+``serve/llm.py`` of a model whose layers are mostly **recurrent**.
+
+Every layer is ``h += r * Mixer(RMSNorm(h))``, ``h += r * MLP(RMSNorm(h))``
+(``r`` the ``residual_multiplier``; the MLP gated, ``W_out (silu(g) * u)``). The
+layers come in periods of ``period`` with one attention layer at
+``attention_at`` and Mamba-2 layers around it, and differ from the engine's
+other architectures in what a *sequence* leaves behind:
+
+* a **Mamba-2** layer caches nothing per token. For every head ``h`` it keeps
+  one state ``S`` (``ssm_head_dim x ssm_state``, float32) and the last
+  ``conv_width - 1`` inputs of its causal depthwise convolution: per token ``t``
+  ``[z, xBC, dt] = W_in r``, ``xBC <- silu(conv(xBC))``, ``[x, B, C] = xBC`` (``B``
+  and ``C`` shared by all heads: one group), ``D_t = softplus(dt + dt_bias)``,
+  ``A = -exp(A_log)``, ``S_t = exp(D_t A) S_{t-1} + D_t x_t (x) B_t``, ``y_t = S_t
+  C_t + D x_t``, then ``W_out RMSNorm(y * silu(z))``. The configuration names the
+  two arrays (``state_arrays``); the engine keeps a slot of each for a sequence,
+  hands ``extend`` its lanes' states and takes the new ones back;
+* a decode lane does one step of the recurrence. A prefill chunk computes it in
+  sub-chunks of ``ssm_chunk`` tokens (the chunked, "SSD" form: inside a
+  sub-chunk a masked matmul, between them the state), one ``lax.scan`` body for
+  every sub-chunk, so that the same tokens from the same state give the same
+  bits wherever in a call they lie. The states between sub-chunks are what a
+  prefix cache can restore: ``extend`` hands back the one ``snap_at`` tokens in
+  (a whole number of sub-chunks), beside the state at the chunk's end;
+* a padded token (id < 0) has ``D_t = 0``: it neither decays nor feeds a state,
+  and the convolution's tail skips it; a lane of length 0 starts from zeros,
+  whatever its slot holds;
+* the **attention** layers are grouped (``num_heads`` over ``kv_heads``), with
+  no position encoding at all and a softmax of ``attention_multiplier * q . k``.
+  A token caches K and V of those layers only (``cache_layers``), each as one
+  row of ``kv_heads x head_dim`` values: whole 128-lane tiles, where a last axis
+  of ``head_dim`` 64 would be re-laid out in every gather;
+* the embedding is scaled by ``embedding_multiplier`` and tied to the head,
+  whose logits are divided by ``logits_scaling``.
+
+State, norms, softmax, ``D_t``, ``exp(D_t A)`` and every accumulation are
+float32; weights and the operands of the matmuls ``dtype``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ray_tpu.ops import attention, backend
+
+#: queries attended at a time in a prefill chunk off the chip
+QUERY_BLOCK = 32
+
+#: what ``extend`` counts over the real lanes and tokens of a device call,
+#: summed over the Mamba layers: tokens through the recurrence, and states read
+#: and written once (a lane, a layer)
+SSM_COUNTERS = ("ssm_tokens", "ssm_state_passes")
+
+
+@dataclasses.dataclass(frozen=True)
+class GraniteMoeHybridConfig:
+    vocab_size: int = 100352
+    num_layers: int = 40
+    period: int = 10                # layers a period: one attention, the others Mamba-2
+    attention_at: int = 5           # where in a period the attention layer stands
+    embed_dim: int = 2048
+    mlp_dim: int = 8192
+    num_heads: int = 32
+    kv_heads: int = 8
+    head_dim: int = 64
+    ssm_heads: int = 64
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_chunk: int = 256            # tokens a sub-chunk of the chunked recurrence
+    conv_width: int = 4
+    embedding_multiplier: float = 12.0
+    residual_multiplier: float = 0.22
+    attention_multiplier: float = 0.015625
+    logits_scaling: float = 8.0
+    norm_eps: float = 1e-5
+    max_seq_len: int = 131072
+    dtype: Any = jnp.bfloat16       # activation/compute dtype
+    param_dtype: Any = jnp.bfloat16
+    state_dtype: Any = jnp.float32  # the recurrent state, on the device and in ``extend``
+
+    def __post_init__(self):
+        if self.num_layers % self.period or not 0 <= self.attention_at < self.period:
+            raise ValueError(
+                f"{self.num_layers} layers are no whole periods of {self.period} with the "
+                f"attention layer at {self.attention_at}")
+        if self.num_heads % self.kv_heads:
+            raise ValueError(f"{self.num_heads} query heads over {self.kv_heads} K/V heads")
+
+    @property
+    def periods(self) -> int:
+        return self.num_layers // self.period
+
+    @property
+    def ssm_layers(self) -> int:
+        return self.periods * (self.period - 1)
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels through the convolution: ``x`` and one group's ``B`` and ``C``."""
+        return self.ssm_inner + 2 * self.ssm_state
+
+    @property
+    def layer_types(self):
+        return tuple(
+            "attention" if i % self.period == self.attention_at else "mamba"
+            for i in range(self.num_layers))
+
+    def num_params(self) -> int:
+        d, inner = self.embed_dim, self.ssm_inner
+        mlp = 3 * d * self.mlp_dim
+        mamba = (
+            d * (inner + self.conv_dim + self.ssm_heads) + (self.conv_width + 1) * self.conv_dim
+            + 3 * self.ssm_heads + inner + inner * d)
+        attn = d * self.head_dim * (2 * self.num_heads + 2 * self.kv_heads)
+        return (
+            self.vocab_size * d + self.ssm_layers * mamba + self.periods * attn
+            + self.num_layers * (mlp + 2 * d) + d)
+
+    # -- what the serving engine asks of a configuration (``serve/llm.py``) --
+
+    #: what ``extend`` counts, in the order of its last output
+    counters = SSM_COUNTERS
+
+    @property
+    def cache_layers(self) -> int:
+        """The layers a token is cached in: the attention layers alone."""
+        return self.periods
+
+    @property
+    def cache_arrays(self):
+        """What a cached token holds, ``(heads, dim)`` per array: K and V, all
+        K/V heads of each side by side in one row."""
+        row = self.kv_heads * self.head_dim
+        return ((1, row), (1, row))
+
+    @property
+    def state_arrays(self):
+        """What a sequence holds, ``(layers, shape, dtype)`` per array: a Mamba
+        layer's state, and the inputs its convolution still needs."""
+        return (
+            (self.ssm_layers, (self.ssm_heads, self.ssm_head_dim, self.ssm_state),
+             self.state_dtype),
+            (self.ssm_layers, (self.conv_width - 1, self.conv_dim), self.dtype),
+        )
+
+    @property
+    def state_chunk(self) -> int:
+        """Tokens between the states ``extend`` can hand back (``snap_at``)."""
+        return self.ssm_chunk
+
+    def make_extend_fn(self):
+        return make_extend_fn(self)
+
+    def init_params(self, seed: int = 0):
+        return init_params(self, seed)
+
+
+def granite_hybrid_nano(**kw) -> GraniteMoeHybridConfig:
+    """A tiny one for the tests: two periods of three Mamba-2 layers and one
+    attention layer, sub-chunks of 8 tokens."""
+    sizes = dict(
+        vocab_size=256, num_layers=8, period=4, attention_at=2, embed_dim=64, mlp_dim=96,
+        num_heads=4, kv_heads=2, head_dim=16, ssm_heads=8, ssm_head_dim=16, ssm_state=16,
+        ssm_chunk=8, max_seq_len=256, dtype=jnp.float32, param_dtype=jnp.float32,
+    )
+    return GraniteMoeHybridConfig(**{**sizes, **kw})
+
+
+def init_params(cfg: GraniteMoeHybridConfig, seed: int = 0):
+    """Seeded weights, made on the device in one jitted call: under
+    ``periods`` one tree for each layer of a period (``mamba``: a tuple of the
+    Mamba layers', ``attn``, ``mlp``: a tuple of every layer's), each leaf
+    stacked ``[periods, ...]`` for ``extend``'s scan, which so reads a layer's
+    weights where they lie. Matrices normal with stddev 0.02 and norm scales 1; a
+    Mamba layer's own as the published Mamba-2 initialiser has them: ``A_log = log(uniform(1, 16))``,
+    ``dt_bias`` the inverse softplus of a step log-uniform in (0.001, 0.1), ``D``
+    1 (float32, all three), the convolution's kernel and bias uniform within
+    ``conv_width^-0.5``. ``W_in`` is stored as its three parts (``z``, ``xBC``,
+    ``dt``), the gate and the up projection of an MLP side by side."""
+    d, inner, heads = cfg.embed_dim, cfg.ssm_inner, cfg.ssm_heads
+    P, M, L = cfg.periods, cfg.period - 1, cfg.period
+    kv = cfg.kv_heads * cfg.head_dim
+    mamba = {
+        "in_z": (P, d, inner), "in_xbc": (P, d, cfg.conv_dim), "in_dt": (P, d, heads),
+        "out": (P, inner, d)}
+    attn = {
+        "q": (P, d, cfg.num_heads * cfg.head_dim), "k": (P, d, kv), "v": (P, d, kv),
+        "o": (P, cfg.num_heads * cfg.head_dim, d)}
+    mlp = {"wi": (P, d, 2 * cfg.mlp_dim), "wo": (P, cfg.mlp_dim, d)}
+    bound = cfg.conv_width ** -0.5
+
+    def normal(key, shape):
+        # drawn in the type they are served in: no float32 copy of 6 GB
+        return jax.random.normal(key, shape, cfg.param_dtype) * jnp.asarray(0.02, cfg.param_dtype)
+
+    def drawn(key, shapes):
+        return {n: normal(k, s) for (n, s), k in zip(
+            shapes.items(), jax.random.split(key, len(shapes)))}
+
+    def ones(*shape):
+        return {"scale": jnp.ones(shape, cfg.param_dtype)}
+
+    def mamba_layer(key):
+        k_w, k_conv, k_bias, k_a, k_dt = jax.random.split(key, 5)
+        step = jnp.exp(jax.random.uniform(
+            k_dt, (P, heads), jnp.float32, np.log(0.001), np.log(0.1)))
+        return {
+            "ln": ones(P, d),
+            **{n: {"kernel": w} for n, w in drawn(k_w, mamba).items()},
+            "conv": {
+                "kernel": jax.random.uniform(
+                    k_conv, (P, cfg.conv_width, cfg.conv_dim), jnp.float32, -bound, bound
+                ).astype(cfg.param_dtype),
+                "bias": jax.random.uniform(
+                    k_bias, (P, cfg.conv_dim), jnp.float32, -bound, bound
+                ).astype(cfg.param_dtype),
+            },
+            "A_log": jnp.log(jax.random.uniform(k_a, (P, heads), jnp.float32, 1.0, 16.0)),
+            "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+            "D": jnp.ones((P, heads), jnp.float32),
+            "norm": ones(P, inner),
+        }
+
+    @jax.jit
+    def init(rng):
+        k_wte, k_attn, *keys = jax.random.split(rng, 2 + M + L)
+        return {
+            "wte": {"embedding": normal(k_wte, (cfg.vocab_size, d))},
+            "periods": {
+                "mamba": tuple(mamba_layer(k) for k in keys[:M]),
+                "attn": {
+                    "ln": ones(P, d),
+                    **{n: {"kernel": w} for n, w in drawn(k_attn, attn).items()}},
+                "mlp": tuple({"ln": ones(P, d), **drawn(k, mlp)} for k in keys[M:]),
+            },
+            "ln_f": ones(d),
+        }
+
+    return jax.block_until_ready(init(jax.random.PRNGKey(seed)))
+
+
+def ssm_step(state, x, dt, a, b, c):
+    """One token of the recurrence, every head: ``state`` [lanes, heads, p, n]
+    float32, ``x`` [lanes, heads, p], ``dt`` [lanes, heads] (0 for a padded
+    token), ``a`` [heads] (negative), ``b``, ``c`` [lanes, n], all float32.
+    Returns ``y`` [lanes, heads, p] (without ``D x``) and the new state."""
+    decay = jnp.exp(dt * a)[..., None, None]
+    state = decay * state + (dt[..., None] * x)[..., None] * b[:, None, None, :]
+    return (state * c[:, None, None, :]).sum(-1), state
+
+
+def ssm_chunked(state, x, dt, a, b, c, chunk: int, dtype):
+    """The recurrence over ``t`` tokens in sub-chunks of ``chunk`` (``t`` a whole
+    number of them), as matmuls: ``x`` [lanes, t, heads, p], ``dt`` [lanes, t,
+    heads] float32 (0 for a padded token), ``b``, ``c`` [lanes, t, n]. Inside a
+    sub-chunk ``y_t = sum_{s<=t} exp(a_t - a_s) (c_t . b_s) dt_s x_s`` with ``a``
+    the running sum of ``dt A``; from the state before it ``exp(a_t) S c_t``.
+    The matmuls take ``dtype`` operands and sum in float32; the state's own
+    read-out is float32 throughout. Returns ``y`` [lanes, t, heads, p] float32,
+    the last state, and the state after each sub-chunk ``[t / chunk, lanes,
+    ...]``. One ``lax.scan`` body: a sub-chunk's result does not depend on where
+    in the call it lies."""
+    lanes, t, heads, p = x.shape
+    n, f32 = b.shape[-1], jnp.float32
+    nc = t // chunk
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+
+    def split(v):                   # [lanes, t, ...] -> [nc, lanes, chunk, ...]
+        return jnp.moveaxis(v.reshape((lanes, nc, chunk) + v.shape[2:]), 1, 0)
+
+    def one(state, xs):
+        xq, dtq, bq, cq = xs        # [lanes, chunk, heads, p], [.., heads], [.., n], [.., n]
+        run = jnp.cumsum(dtq * a, axis=1).transpose(0, 2, 1)        # [lanes, heads, chunk]
+        fed = (dtq[..., None] * xq.astype(f32)).transpose(0, 2, 1, 3)   # [lanes, heads, chunk, p]
+        pair = jnp.einsum("bqn,bsn->bqs", cq, bq, preferred_element_type=f32)
+        # masked before the exponential: a later token's difference is positive
+        span = jnp.where(causal, run[..., :, None] - run[..., None, :], -jnp.inf)
+        weight = (pair[:, None] * jnp.exp(span)).astype(dtype)      # [lanes, heads, q, s]
+        y = jnp.einsum("bhqs,bhsp->bhqp", weight, fed.astype(dtype), preferred_element_type=f32)
+        y = y + jnp.exp(run)[..., None] * jnp.einsum(
+            "bhpn,bqn->bhqp", state, cq.astype(f32), precision=jax.lax.Precision.HIGHEST)
+        to_end = jnp.exp(run[..., -1:] - run)                       # [lanes, heads, chunk]
+        state = jnp.exp(run[..., -1])[..., None, None] * state + jnp.einsum(
+            "bhsp,bsn->bhpn", (fed * to_end[..., None]).astype(dtype), bq,
+            preferred_element_type=f32)
+        return state, (y.transpose(0, 2, 1, 3), state)
+
+    state, (y, between) = jax.lax.scan(one, state, tuple(map(split, (x, dt, b, c))))
+    return jnp.moveaxis(y, 0, 1).reshape(lanes, t, heads, p), state, between
+
+
+def make_extend_fn(cfg: GraniteMoeHybridConfig):
+    """A jitted ``extend(params, tokens, lengths, k_cache, v_cache, ssm, conv,
+    snap_at)``: the contract of ``gpt.make_extend_fn`` over the attention
+    layers' caches (``[cache_layers, lanes, cache, 1, kv_heads x head_dim]``)
+    and the lanes' states (``cfg.state_arrays``: ``[ssm_layers, lanes, ...]``).
+    Returns ``(logits, hidden, k rows, v rows, ssm, conv, [ssm and conv at
+    snap_at], counters)``: the new states are those after each lane's last real
+    token; a call of more than one token a lane also hands back the states
+    after ``snap_at[lane]`` tokens (a whole number of sub-chunks, at least one:
+    0 reads as one). A lane of length 0 starts from zeros whatever it is handed;
+    a negative token id is padding and changes no state. ``counters``
+    (``cfg.counters``) over real lanes and tokens.
+
+    Scopes: ``extend.embed``; ``extend.ssm`` (projections, convolution, gate,
+    norm) with ``extend.ssm.scan`` inside it (the recurrence alone);
+    ``extend.attention``; ``extend.mlp``; ``extend.logits``."""
+    dtype, f32 = cfg.dtype, jnp.float32
+    heads, p_dim, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
+    inner, tail = cfg.ssm_inner, cfg.conv_width - 1
+    groups = cfg.num_heads // cfg.kv_heads
+    scale, res = float(cfg.attention_multiplier), cfg.residual_multiplier
+
+    def _rms(x, p):
+        xf = x.astype(f32)
+        return xf * jax.lax.rsqrt((xf * xf).mean(-1, keepdims=True) + cfg.norm_eps) * (
+            p["scale"].astype(f32))
+
+    def _by_block(fn, *per_query):
+        b, tc = per_query[0].shape[:2]
+        size = QUERY_BLOCK if tc % QUERY_BLOCK == 0 else tc
+        split = tuple(
+            x.reshape((b, tc // size, size) + x.shape[2:]).swapaxes(0, 1) for x in per_query)
+        out = jax.lax.map(lambda block: fn(*block), split)
+        return out.swapaxes(0, 1).reshape((b, tc) + out.shape[3:])
+
+    def _layer(stack, at):
+        return jax.lax.dynamic_index_in_dim(stack, at, 0, keepdims=False)
+
+    def _put(stack, new, at):
+        return jax.lax.dynamic_update_index_in_dim(stack, new.astype(stack.dtype), at, 0)
+
+    @jax.named_scope("extend.ssm")
+    def _mamba(p, hidden, valid, fresh, snap_at, states, kept, at):
+        """``states`` are the lanes' own before this call, every Mamba layer's
+        (``[ssm_layers, lanes, heads, p, n]`` and ``[ssm_layers, lanes, tail,
+        conv_dim]``); layer ``at``'s are read and, after the lane's last real
+        token, written where they lie, and into ``kept`` (a chunk) those after
+        ``snap_at`` tokens. Returns the mixer's output, ``states`` and ``kept``."""
+        b, tc = valid.shape
+        with jax.named_scope("extend.ssm.scan"):
+            # the state's read and its write are the recurrence's own traffic
+            state = jnp.where(fresh[:, None, None, None], 0.0, _layer(states[0], at).astype(f32))
+        conv = jnp.where(fresh[:, None, None], 0, _layer(states[1], at)).astype(dtype)
+        z = hidden @ p["in_z"]["kernel"].astype(dtype)
+        xbc = hidden @ p["in_xbc"]["kernel"].astype(dtype)
+        dt = jnp.dot(hidden, p["in_dt"]["kernel"].astype(dtype), preferred_element_type=f32)
+        dt = jnp.where(valid[..., None], jax.nn.softplus(dt + p["dt_bias"]), 0.0)
+        # the convolution over the lane's last inputs and this call's
+        seen = jnp.concatenate([conv, xbc], axis=1)                 # [b, tail + tc, conv_dim]
+        kernel = p["conv"]["kernel"].astype(f32)
+        mixed = p["conv"]["bias"].astype(f32) + sum(
+            kernel[k] * seen[:, k:k + tc].astype(f32) for k in range(cfg.conv_width))
+        mixed = jax.nn.silu(mixed).astype(dtype)
+        # what the next call's first tokens need: the inputs of the last real ones
+        lane = jnp.arange(b)[:, None]
+        after = jnp.arange(tail)[None, :]
+        conv_new = seen[lane, valid.sum(1, dtype=jnp.int32)[:, None] + after]
+        x = mixed[..., :inner].reshape(b, tc, heads, p_dim)
+        bm, cm = mixed[..., inner:inner + n], mixed[..., inner + n:]
+        a = -jnp.exp(p["A_log"])
+        with jax.named_scope("extend.ssm.scan"):
+            if tc == 1:
+                y, state_new = ssm_step(
+                    state, x[:, 0].astype(f32), dt[:, 0], a, bm[:, 0].astype(f32),
+                    cm[:, 0].astype(f32))
+                y = y[:, None]
+            else:
+                y, state_new, between = ssm_chunked(
+                    state, x, dt, a, bm, cm, cfg.ssm_chunk, dtype)
+                chunk = jnp.clip(snap_at // cfg.ssm_chunk - 1, 0, tc // cfg.ssm_chunk - 1)
+                kept = (_put(kept[0], jnp.take_along_axis(
+                    between, chunk[None, :, None, None, None], axis=0)[0], at), kept[1])
+            states = (_put(states[0], state_new, at), states[1])
+        states = (states[0], _put(states[1], conv_new, at))
+        if tc > 1:
+            kept = (kept[0], _put(
+                kept[1], seen[lane, jnp.maximum(snap_at, cfg.ssm_chunk)[:, None] + after], at))
+        y = y + p["D"][:, None] * x.astype(f32)
+        y = y.reshape(b, tc, inner) * jax.nn.silu(z.astype(f32))
+        out = jnp.dot(
+            _rms(y, p["norm"]).astype(dtype), p["out"]["kernel"].astype(dtype),
+            preferred_element_type=f32)
+        return out, states, kept
+
+    @jax.named_scope("extend.attention")
+    def _attend(p, hidden, positions, visible, live, kc, vc):
+        b, tc = positions.shape
+        cap = kc.shape[1]
+        q = (hidden @ p["q"]["kernel"].astype(dtype)).reshape(
+            b, tc, cfg.kv_heads, groups, cfg.head_dim)
+        k = (hidden @ p["k"]["kernel"].astype(dtype))[:, :, None]   # one row for all K/V heads
+        v = (hidden @ p["v"]["kernel"].astype(dtype))[:, :, None]
+        lane = jnp.arange(b)[:, None]
+        # out-of-capacity writes drop instead of clamping onto slot T-1
+        kc = kc.at[lane, positions].set(k, mode="drop").reshape(b, cap, cfg.kv_heads, -1)
+        vc = vc.at[lane, positions].set(v, mode="drop").reshape(b, cap, cfg.kv_heads, -1)
+
+        def attend_block(qb, mask):             # [b, n, kv, g, hd], [b, n, cache]
+            logit = jnp.einsum(
+                "bqhgd,bkhd->bhgqk", qb, kc, preferred_element_type=f32) * scale
+            weight = jax.nn.softmax(
+                jnp.where(mask[:, None, None], logit, f32(-1e30)), axis=-1)
+            return jnp.einsum("bhgqk,bkhd->bqhgd", weight.astype(dtype), vc)
+
+        if tc > 1 and backend.on_tpu():
+            out = attention.masked_attention(q, kc, vc, visible, live, scale=scale)
+        else:
+            out = attend_block(q, visible) if tc == 1 else _by_block(attend_block, q, visible)
+        out = jnp.dot(
+            out.reshape(b, tc, -1), p["o"]["kernel"].astype(dtype), preferred_element_type=f32)
+        return out, (k, v)
+
+    @jax.named_scope("extend.mlp")
+    def _mlp(x, p):
+        gate_up = _rms(x, p["ln"]).astype(dtype) @ p["wi"].astype(dtype)
+        return jnp.dot(
+            jax.nn.silu(gate_up[..., :cfg.mlp_dim]) * gate_up[..., cfg.mlp_dim:],
+            p["wo"].astype(dtype), preferred_element_type=f32)
+
+    def _add(x, out):
+        return x + (res * out).astype(dtype)
+
+    @jax.jit
+    def extend(params, tokens, lengths, k_cache, v_cache, ssm, conv, snap_at):
+        b, tc = tokens.shape
+        positions = (
+            lengths[:, None].astype(jnp.int32) + jnp.arange(tc, dtype=jnp.int32)[None, :])
+        valid, fresh = tokens >= 0, lengths == 0
+        kpos = jnp.arange(k_cache.shape[2], dtype=jnp.int32)
+        visible = (kpos[None, None, :] <= positions[:, :, None]) & valid[:, :, None]
+        live = jnp.where(valid, positions + 1, 0).max(1)
+        with jax.named_scope("extend.embed"):
+            x = params["wte"]["embedding"].astype(dtype)[jnp.clip(tokens, 0, cfg.vocab_size - 1)]
+            x = x * jnp.asarray(cfg.embedding_multiplier, dtype)
+
+        def body(carry, xs):
+            # the lanes' states are carried whole and each layer's is read and
+            # written where it lies: sliced as scanned inputs and stacked as
+            # outputs they are copied twice over, 0.6 GB at eight lanes
+            x, states, kept = carry
+            p, kc, vc, period = xs
+            rows, m = None, 0
+            for i in range(cfg.period):
+                if i == cfg.attention_at:
+                    out, rows = _attend(
+                        p["attn"], _rms(x, p["attn"]["ln"]).astype(dtype), positions,
+                        visible, live, kc, vc)
+                else:
+                    layer = p["mamba"][m]
+                    out, states, kept = _mamba(
+                        layer, _rms(x, layer["ln"]).astype(dtype), valid, fresh, snap_at,
+                        states, kept, period * (cfg.period - 1) + m)
+                    m += 1
+                x = _add(x, out)
+                x = _add(x, _mlp(x, p["mlp"][i]))
+            return (x, states, kept), rows
+
+        kept = tuple(jnp.zeros_like(s) for s in (ssm, conv)) if tc > 1 else ()
+        (x, news, kept), rows = jax.lax.scan(
+            body, (x, (ssm, conv), kept), (
+                params["periods"], k_cache, v_cache, jnp.arange(cfg.periods, dtype=jnp.int32)))
+        with jax.named_scope("extend.logits"):
+            x = _rms(x, params["ln_f"])
+            logits = jnp.dot(
+                x.astype(dtype), params["wte"]["embedding"].astype(dtype).T,
+                preferred_element_type=f32) / cfg.logits_scaling
+        counters = cfg.ssm_layers * jnp.stack([
+            valid.sum(dtype=jnp.int32), valid.any(1).sum(dtype=jnp.int32)])
+        return (logits, x, *rows, *news, *kept, counters)
+
+    return extend

@@ -5,7 +5,10 @@ answers what the engine asks of it (``make_extend_fn()``, ``init_params(seed)``
 and ``cache_arrays``: what a cached token holds, as ``(heads, dim)`` per array;
 beside the sizes ``num_layers``, ``embed_dim``, ``vocab_size``, ``max_seq_len``
 and ``dtype``; where its ``extend`` counts something, ``counters`` names what;
-``models/cohere2_moe.py``, ``models/keye_vl2.py``, ``models/kimi_k2.py``).
+where it keeps state per sequence and not per token, ``state_arrays`` names
+that, ``state_chunk`` how often a state can be kept, and ``cache_layers`` in how
+many layers a token is cached; ``models/cohere2_moe.py``, ``models/keye_vl2.py``,
+``models/kimi_k2.py``, ``models/granitemoehybrid.py``).
 
 What PR 9 proved with synthetic step functions (continuous batching,
 admission control, multiplexing) this module composes on an actual model
@@ -48,6 +51,17 @@ serving setup from PAPERS.md):
   (shared blocks are refcounted and cloned before any write), skipping
   their prefill FLOPs entirely. Reused KV is bitwise-identical to a
   fresh prefill because the extend fn is deterministic per shape.
+* state per sequence — a recurrent layer leaves nothing behind per token but
+  one state per sequence. The pool holds slots of it beside the block arenas
+  (``state_arrays``): a sequence takes one at admission and gives it back with
+  its lease, a call gathers its lanes' states by slot, ``extend`` continues
+  from them and the new ones are scattered back, all on the device and in
+  launch order, so a lane's state follows its token across the call in flight.
+  Cached blocks are only as good as the state at their end: ``extend`` hands
+  back the state at the block boundary the engine names (the reusable end of
+  the prompt, as a rule inside its last chunk), the prefix cache keeps it as a
+  snapshot with the chain, copies it to the sequence's slot on a hit and drops
+  it with the chain on eviction.
 * LoRA multiplexing — base weights load once per replica; per-model
   low-rank logit deltas ``(A [d,r], B [r,vocab])`` are registered on the
   object plane via :func:`ray_tpu.serve.register_model` and streamed to
@@ -127,12 +141,18 @@ def make_params(cfg=None, seed: int = 0):
 #: The width is the engine's, whatever the call's buckets: a program is shaped
 #: by the lanes and by its own bucket, as it was.
 _LENGTH, _LAST, _COUNT, _FROM, _SCALARS = 0, 1, 2, 3, 4
+#: Behind the sections, for a configuration with per-sequence state alone
+#: (fewer than four, so :func:`_sections` cuts the same with them or without):
+#: the lane's state slot, after how many of the call's tokens ``extend`` is to
+#: hand back a state for the prefix cache (0: none), and the slot that state
+#: goes to. Counted from the buffer's end.
+_SLOT, _SNAP_AT, _SNAP_SLOT, _STATE_COLUMNS = -3, -2, -1, 3
 
 
-def _operand_width(tokens: int, blocks: int) -> int:
+def _operand_width(tokens: int, blocks: int, stateful: bool = False) -> int:
     """The width of the operand buffer of an engine whose widest call feeds
     ``tokens`` a lane over a cache of ``blocks`` blocks."""
-    return _SCALARS + 4 * max(tokens, blocks)
+    return _SCALARS + 4 * max(tokens, blocks) + (_STATE_COLUMNS if stateful else 0)
 
 
 def _sections(operands):
@@ -248,6 +268,53 @@ def _paging_programs():
     return types.SimpleNamespace(gather=gather, page_back=page_back, clone=clone)
 
 
+@functools.lru_cache(maxsize=None)
+def _state_programs():
+    """The three jitted programs that touch a pool's state arenas, a tuple of
+    arrays ``[layers, slots, ...]``: what a sequence holds where a model keeps
+    state per sequence and not per token. As :func:`_paging_programs`: loops of
+    one dynamic slice and one in-place update a lane, shaped by their arguments
+    alone. Slot 0 is nobody's: a padded lane reads and writes it."""
+    import types
+
+    import jax
+
+    def move(into, source, count, take, put):
+        """``into[:, put(i)] = source[:, take(i)]`` for ``i < count``."""
+        def copy(i, out):
+            return jax.lax.dynamic_update_slice_in_dim(
+                out, jax.lax.dynamic_slice_in_dim(source, take(i), 1, axis=1), put(i), axis=1)
+
+        return jax.lax.fori_loop(0, count, copy, into)
+
+    @jax.jit
+    @jax.named_scope("paging.state_gather")
+    def gather(arenas, operands):
+        b, slots = operands.shape[0], operands[:, _SLOT]
+        return tuple(
+            move(jax.lax.empty((a.shape[0], b) + a.shape[2:], a.dtype), a, b,
+                 lambda i: slots[i], lambda i: i)
+            for a in arenas)
+
+    @functools.partial(jax.jit, donate_argnums=0)
+    @jax.named_scope("paging.state_scatter")
+    def scatter(arenas, news, kept, operands):
+        def put(arenas, states, slots):
+            return tuple(
+                move(a, new, operands.shape[0], lambda i: i, lambda i: slots[i])
+                for a, new in zip(arenas, states))
+
+        arenas = put(arenas, news, operands[:, _SLOT])
+        return put(arenas, kept, operands[:, _SNAP_SLOT]) if kept else arenas
+
+    @functools.partial(jax.jit, donate_argnums=0)
+    @jax.named_scope("paging.state_copy")
+    def copy(arenas, src, dst):
+        return tuple(move(a, a, 1, lambda i: src, lambda i: dst) for a in arenas)
+
+    return types.SimpleNamespace(gather=gather, scatter=scatter, copy=copy)
+
+
 class KVBlockPool:
     """Fixed-size token blocks of per-token state in refcounted arenas that
     live on the device, one for each array a cached token holds.
@@ -266,10 +333,19 @@ class KVBlockPool:
     and per block, whatever a block holds;
     the arenas are only ever touched by the three programs of
     :func:`_paging_programs` (``page_back`` and ``clone`` donate them), each
-    compiled by :meth:`warm` before a request is served."""
+    compiled by :meth:`warm` before a request is served.
+
+    Where a model keeps state per **sequence** (a recurrent layer's; the
+    configuration's ``state_arrays``, ``(layers, shape, dtype)`` each), the pool
+    holds that too: ``states``, one device array ``[layers, state_slots, ...]``
+    each, a slot a sequence and a slot a snapshot the prefix cache keeps, under
+    the same lock, leases and shedding as the blocks, touched by the programs of
+    :func:`_state_programs` alone. Slot 0 is never handed out. And where its
+    tokens are cached in some layers only (``cache_layers``), the arenas hold
+    those."""
 
     def __init__(self, cfg, *, num_blocks: int = 128, block_size: int = 16,
-                 deployment: str = "llm"):
+                 state_slots: int = 0, deployment: str = "llm"):
         import jax
         import jax.numpy as jnp
 
@@ -278,23 +354,36 @@ class KVBlockPool:
         self.block_size = int(block_size)
         self.deployment = deployment
         self.dtype = jnp.dtype(jnp.float32 if cfg.dtype is None else cfg.dtype)
-        slab = (cfg.num_layers, self.num_blocks, self.block_size)
+        self.layers = getattr(cfg, "cache_layers", cfg.num_layers)
+        self.state_arrays = tuple(getattr(cfg, "state_arrays", ()))
+        self.state_slots = int(state_slots) if self.state_arrays else 0
+        #: the bytes one sequence's state takes, over all layers and arrays
+        self.state_bytes = sum(
+            layers * math.prod(shape) * jnp.dtype(dtype).itemsize
+            for layers, shape, dtype in self.state_arrays)
+        slab = (self.layers, self.num_blocks, self.block_size)
         try:
             self.arenas = tuple(
                 jnp.zeros(slab + tuple(each), self.dtype) for each in cfg.cache_arrays)
-            jax.block_until_ready(self.arenas)
+            self.states = tuple(
+                jnp.zeros((layers, self.state_slots) + tuple(shape), dtype)
+                for layers, shape, dtype in self.state_arrays)
+            jax.block_until_ready((self.arenas, self.states))
         except Exception as e:  # noqa: BLE001 — the runtime's out-of-memory
             raise MemoryError(
                 f"the KV pool does not fit on the device: {self.num_blocks} "
-                f"blocks of {self.block_size} tokens x {cfg.num_layers} layers "
+                f"blocks of {self.block_size} tokens x {self.layers} layers "
                 f"x {cfg.cache_arrays} (heads, dim) an array in {self.dtype} are "
-                f"{self.cache_bytes(self.num_blocks * self.block_size)} bytes, beside "
-                f"{accelerator.device_report()}: {e!r}"
+                f"{self.cache_bytes(self.num_blocks * self.block_size)} bytes, and "
+                f"{self.state_slots} slots of state {self.state_slots * self.state_bytes}, "
+                f"beside {accelerator.device_report()}: {e!r}"
             ) from e
         self._free: List[int] = list(range(self.num_blocks))
         self._ref: Dict[int, int] = {}
+        self._free_slots: List[int] = list(range(self.state_slots - 1, 0, -1))
         self._lock = threading.RLock()
         self._evict_cb: Optional[Callable[[int], None]] = None
+        self._evict_slot_cb: Optional[Callable[[], bool]] = None
         self.freed_total = 0
 
     @property
@@ -309,7 +398,7 @@ class KVBlockPool:
     def cache_bytes(self, tokens: int) -> int:
         """The bytes ``tokens`` cached tokens take, over all layers and arrays."""
         per_token = sum(heads * dim for heads, dim in self.cfg.cache_arrays)
-        return self.cfg.num_layers * tokens * per_token * self.dtype.itemsize
+        return self.layers * tokens * per_token * self.dtype.itemsize
 
     # -- the arenas: device programs only ----------------------------------
 
@@ -341,6 +430,23 @@ class KVBlockPool:
         self.arenas = _paging_programs().clone(
             self.arenas, np.int32(src), np.int32(dst))
 
+    def gather_states(self, operands):
+        """The states ``[layers, b, ...]``, one per state arena, of the lanes
+        whose slots stand in ``operands`` (``_SLOT``), built on the device."""
+        return _state_programs().gather(self.states, operands)
+
+    def scatter_states(self, news, kept, operands) -> None:
+        """Write lane ``i`` of each of ``news`` ``[layers, b, ...]`` into its
+        slot and, where the call made any, of ``kept`` into the slot
+        ``operands`` names for it (``_SNAP_SLOT``; 0: nobody's). The arenas are
+        donated: nothing is copied but the lanes' states."""
+        self.states = _state_programs().scatter(
+            self.states, tuple(news), tuple(kept), operands)
+
+    def copy_state(self, src: int, dst: int) -> None:
+        """Copy slot ``src`` onto slot ``dst``, on the device."""
+        self.states = _state_programs().copy(self.states, np.int32(src), np.int32(dst))
+
     def read_block(self, b: int):
         """Block ``b`` on the host: one array ``[layers, block_size, heads,
         dim]`` per arena (K, V, ...). For tests and debugging, not for the step
@@ -351,29 +457,45 @@ class KVBlockPool:
         """Compile every paging program the engine's buckets allow, on zeros
         made on the device: the gather per (lanes, cache bucket), the
         page-back per (lanes, tokens) of ``extend_shapes`` (that extend
-        call's output shapes), the clone. The page-back writes no token
-        here, so the arenas keep their contents."""
+        call's output shapes), the clone; where the pool holds states, their
+        gather and scatter per shape (into slot 0, nobody's) and the copy. The
+        page-back writes no token here, so the arenas keep their contents."""
         import jax
         import jax.numpy as jnp
 
         width = _operand_width(
             max(tc for _, tc in extend_shapes),
-            max(cache_buckets) // self.block_size)
+            max(cache_buckets) // self.block_size, bool(self.states))
         lanes, held = max(b for b, _ in extend_shapes), len(self.arenas)
+
+        def zeros(shapes):
+            return tuple(jnp.zeros(x.shape, x.dtype) for x in shapes)
+
         for b in sorted({b for b, _ in extend_shapes}):
             operands = jnp.zeros((b, width), jnp.int32)
             for cap in cache_buckets:
                 jax.block_until_ready(
                     self.gather(operands, cap // self.block_size))
         for (b, tc), (logits, hidden, *rest) in extend_shapes.items():
-            news, counted = rest[:held], rest[held:]
+            news, (states, kept, counted) = rest[:held], self.split_states(rest[held:], tc)
+            operands = jnp.zeros((b, width), jnp.int32)
+            if states:
+                jax.block_until_ready(self.gather_states(operands))
+                self.scatter_states(zeros(states), zeros(kept), operands)
             jax.block_until_ready(self.page_back(
-                tuple(jnp.zeros(x.shape, x.dtype) for x in news),
-                jnp.zeros((b, width), jnp.int32),
-                tuple(jnp.zeros(o.shape, o.dtype) for o in (logits, hidden)),
-                tuple(jnp.zeros(c.shape, c.dtype) for c in counted), lanes))
+                zeros(news), operands, zeros((logits, hidden)), zeros(counted), lanes))
         self.clone_block(0, 0)
-        jax.block_until_ready(self.arenas)
+        if self.states:
+            self.copy_state(0, 0)
+        jax.block_until_ready((self.arenas, self.states))
+
+    def split_states(self, rest, tc: int):
+        """What ``extend`` returns behind its caches' new rows, apart: the
+        lanes' new states, the states it kept for the prefix cache (a call of
+        more than one token a lane makes them), and what it counted."""
+        n = len(self.states)
+        kept = n if tc > 1 else 0
+        return rest[:n], rest[n:n + kept], rest[n + kept:]
 
     # -- host bookkeeping ---------------------------------------------------
 
@@ -396,6 +518,30 @@ class KVBlockPool:
                 self._ref[b] = 1
             self._gauge_locked()
             return out
+
+    def set_evict_slot_cb(self, cb: Callable[[], bool]) -> None:
+        """Hook called (under the pool lock) when no state slot is free: the
+        prefix cache drops a snapshot here, and says whether it had one."""
+        self._evict_slot_cb = cb
+
+    def take_slot(self) -> int:
+        """A state slot, the prefix cache's oldest snapshot's where none is
+        free; like a block, it goes back through a lease or :meth:`free_slot`."""
+        with self._lock:
+            if not self._free_slots and self._evict_slot_cb is not None:
+                self._evict_slot_cb()
+            if not self._free_slots:
+                raise NoKVBlocksError(
+                    f"need a state slot, none free of {self.state_slots - 1}")
+            return self._free_slots.pop()
+
+    def free_slot(self, slot: int) -> None:
+        with self._lock:
+            self._free_slots.append(slot)
+
+    def slots_in_use(self) -> int:
+        with self._lock:
+            return max(self.state_slots - 1, 0) - len(self._free_slots)
 
     def incref(self, blocks: Sequence[int]) -> None:
         with self._lock:
@@ -460,6 +606,9 @@ class KVLease:
     def __init__(self, pool: KVBlockPool):
         self.pool = pool
         self.blocks: List[int] = []
+        #: the sequence's state slots (its own; a snapshot's until the prefix
+        #: cache takes it), where the model keeps state per sequence
+        self.slots: List[int] = []
         self._released = False
         self._lock = threading.Lock()
 
@@ -471,6 +620,26 @@ class KVLease:
                 return
             self.blocks.extend(blocks)
 
+    def add_slot(self) -> int:
+        """Take a state slot of the pool for this lease (NoKVBlocksError where
+        there is none)."""
+        slot = self.pool.take_slot()
+        with self._lock:
+            if self._released:
+                self.pool.free_slot(slot)
+            else:
+                self.slots.append(slot)
+        return slot
+
+    def give_slot(self, slot: int) -> bool:
+        """Hand ``slot`` over to another owner; False where the lease has gone
+        (and the slot with it)."""
+        with self._lock:
+            if self._released or slot not in self.slots:
+                return False
+            self.slots.remove(slot)
+            return True
+
     @property
     def released(self) -> bool:
         return self._released
@@ -481,7 +650,10 @@ class KVLease:
                 return
             self._released = True
             blocks, self.blocks = list(self.blocks), []
+            slots, self.slots = self.slots, []
         self.pool.free(blocks)
+        for slot in slots:
+            self.pool.free_slot(slot)
 
 
 # ---------------------------------------------------------------------------
@@ -508,20 +680,32 @@ def chain_hashes(prompt: Sequence[int], block_size: int) -> List[bytes]:
 class PrefixCache:
     """hash -> block id, LRU-ordered. The cache holds its own reference on
     every cached block; entries whose block is otherwise idle (refcount 1)
-    are evictable when the pool runs dry."""
+    are evictable when the pool runs dry.
+
+    Where the pool holds state per sequence, cached blocks are only as good as
+    the state at their end: a chain is inserted with a **snapshot** (a state
+    slot the cache then owns, keyed by the chain's last hash), a hit ends at the
+    last block of the chain that has one, and eviction is by snapshot, oldest
+    first: its slot goes back, and each block of its chain that no other
+    snapshot's chain holds. Such a cache holds no block without a snapshot."""
 
     def __init__(self, pool: KVBlockPool, deployment: str = "llm"):
         self.pool = pool
         self.deployment = deployment
         self._map: "OrderedDict[bytes, int]" = OrderedDict()
+        #: last hash of a chain -> (its state slot, the chain's hashes), LRU
+        self._snap: "OrderedDict[bytes, tuple]" = OrderedDict()
+        self._held: Dict[bytes, int] = {}       # hash -> snapshots whose chain has it
         self.hits = 0
         self.misses = 0
         self.evictions = 0
         pool.set_evict_cb(self._evict_for)
+        pool.set_evict_slot_cb(self._evict_snapshot)
 
     def match(self, hashes: Sequence[bytes]) -> List[int]:
-        """Block ids of the longest cached prefix chain, increfed for the
-        caller (release through the caller's lease)."""
+        """Block ids of the longest cached prefix chain (where the pool holds
+        states: the longest that ends in a snapshot, :meth:`snapshot`),
+        increfed for the caller (release through the caller's lease)."""
         with self.pool._lock:
             out: List[int] = []
             for h in hashes:
@@ -530,6 +714,11 @@ class PrefixCache:
                     break
                 self._map.move_to_end(h)
                 out.append(b)
+            if self.pool.states:
+                out = out[:max(
+                    (i + 1 for i in range(len(out)) if hashes[i] in self._snap), default=0)]
+                if out:
+                    self._snap.move_to_end(hashes[len(out) - 1])
             if out:
                 self.pool.incref(out)
                 self.hits += len(out)
@@ -541,10 +730,25 @@ class PrefixCache:
                 self.misses += len(hashes) - len(out)
             return out
 
-    def insert(self, hashes: Sequence[bytes], blocks: Sequence[int]) -> None:
+    def snapshot(self, h: bytes) -> int:
+        """The slot of the state after the chain that ends in hash ``h``."""
+        return self._snap[h][0]
+
+    def insert(self, hashes: Sequence[bytes], blocks: Sequence[int],
+               snapshot: Optional[int] = None) -> bool:
         """Cache a freshly prefilled chain. First writer wins per hash;
-        the cache takes its own reference on each newly cached block."""
+        the cache takes its own reference on each newly cached block. Where
+        the pool holds states, ``snapshot`` is the slot of the state after the
+        chain's last block; True where the cache now owns it."""
         with self.pool._lock:
+            if self.pool.states:
+                if snapshot is None or not hashes or hashes[-1] in self._snap or any(
+                        h not in self._map and self.pool._ref.get(b, 0) <= 0
+                        for h, b in zip(hashes, blocks)):
+                    return False
+                self._snap[hashes[-1]] = (snapshot, tuple(hashes))
+                for h in hashes:
+                    self._held[h] = self._held.get(h, 0) + 1
             for h, b in zip(hashes, blocks):
                 if h in self._map:
                     continue
@@ -552,9 +756,29 @@ class PrefixCache:
                     continue  # lease already released (cancelled mid-insert)
                 self._map[h] = b
                 self.pool.incref([b])
+            return snapshot is not None
+
+    def _evict_snapshot(self) -> bool:
+        # called under the pool lock by KVBlockPool.take_slot, and below
+        if not self._snap:
+            return False
+        _, (slot, chain) = self._snap.popitem(last=False)
+        self.pool.free_slot(slot)
+        for h in chain:
+            self._held[h] -= 1
+            if not self._held[h]:
+                del self._held[h]
+                self.pool._decref_locked(self._map.pop(h))
+                self.evictions += 1
+        return True
 
     def _evict_for(self, shortfall: int) -> None:
         # called under the pool lock by KVBlockPool.allocate
+        if self.pool.states:
+            target = len(self.pool._free) + shortfall
+            while len(self.pool._free) < target and self._evict_snapshot():
+                pass
+            return
         freed = 0
         for h in list(self._map):
             if freed >= shortfall:
@@ -569,6 +793,10 @@ class PrefixCache:
     def __len__(self) -> int:
         with self.pool._lock:
             return len(self._map)
+
+    def snapshots(self) -> int:
+        with self.pool._lock:
+            return len(self._snap)
 
 
 # ---------------------------------------------------------------------------
@@ -611,22 +839,28 @@ def _fetch_lora(model_id: str):
 # ---------------------------------------------------------------------------
 
 
-def _operand_extend(extend):
+def _operand_extend(extend, caches: int = 0, states: int = 0):
     """``extend`` as a step calls it: the tokens and lengths are read on the
     device from the call's operand buffer (:func:`_sections`), ``tc`` tokens a
     lane, and a lane's first token from ``home`` (what the call before left
     for the host, its lanes' ids first) where its row names a lane there
     (``_FROM``): the host need not have seen a token to feed it. One program
-    per (lanes, tokens, cache), as ``extend`` alone has."""
+    per (lanes, tokens, cache), as ``extend`` alone has. Where the model keeps
+    state per sequence, its ``states`` arrays follow the ``caches`` (donated:
+    they are the gather's, and the new ones take their place) and ``extend`` is
+    told where to keep a state for the prefix cache (``_SNAP_AT``)."""
     import jax
     import jax.numpy as jnp
 
-    @functools.partial(jax.jit, static_argnames="tc")
-    def extend_call(params, operands, home, *caches, tc):
+    donated = dict(donate_argnums=tuple(range(3 + caches, 3 + caches + states))) if states else {}
+
+    @functools.partial(jax.jit, static_argnames="tc", **donated)
+    def extend_call(params, operands, home, *arrays, tc):
         tokens, source = _sections(operands)[0][:, :tc], operands[:, _FROM]
         first = jnp.where(source < 0, tokens[:, 0], home[jnp.maximum(source, 0)])
+        keep = (operands[:, _SNAP_AT],) if states else ()
         return extend(
-            params, tokens.at[:, 0].set(first), operands[:, _LENGTH], *caches)
+            params, tokens.at[:, 0].set(first), operands[:, _LENGTH], *arrays, *keep)
 
     return extend_call
 
@@ -641,7 +875,7 @@ class _SeqState:
         "prompt", "max_new", "eos", "model_id", "adapter", "lease", "blocks",
         "pos", "length", "sent", "call", "lane", "out", "last_token",
         "cached_tokens", "hashes", "ttft_s", "queue_s", "stream_q",
-        "cancel_ev", "return_logits", "logits",
+        "cancel_ev", "return_logits", "logits", "slot", "snapshot",
     )
 
     @property
@@ -686,6 +920,10 @@ LEAF_PHASES = (
     "admit", "upload", "kv_gather", "dispatch", "kv_scatter", "fetch", "sample",
 )
 PHASES = ("step", "prefill", "decode") + LEAF_PHASES
+#: where an engine keeps state per sequence, inside ``admit`` and ``kv_scatter``:
+#: a cached prefix's state copied to the sequence's slot, and the states a call
+#: made written to their slots, a snapshot for the prefix cache among them
+STATE_PHASES = ("state_restore", "state_snapshot")
 #: the two forms of device call, as ``stats()["calls"]`` keys them
 FORMS = ("prefill", "decode")
 #: what one ``_phase`` may cost outside a profiler session, where its span is
@@ -722,7 +960,8 @@ class LLMEngine:
                  cache_buckets: Sequence[int] = (32, 64, 128),
                  max_adapters: int = 4, adapter_loader=None,
                  prefix_caching: bool = True, default_max_new_tokens: int = 16,
-                 step_delay_s: float = 0.0, seed: int = 0):
+                 step_delay_s: float = 0.0, seed: int = 0,
+                 state_slots: Optional[int] = None):
         import jax.numpy as jnp
 
         from ray_tpu.models import gpt
@@ -731,7 +970,11 @@ class LLMEngine:
         self._params = params if params is not None else make_params(
             self.cfg, seed)
         self._extend = self.cfg.make_extend_fn()
-        self._extend_call = _operand_extend(self._extend)
+        #: per-sequence state (a recurrent layer's), where the model has any
+        state_arrays = tuple(getattr(self.cfg, "state_arrays", ()))
+        self._stateful = bool(state_arrays)
+        self._extend_call = _operand_extend(
+            self._extend, len(self.cfg.cache_arrays), len(state_arrays))
         self.deployment = deployment
         self.block_size = int(block_size)
         self.prefill_chunk = int(prefill_chunk)
@@ -745,13 +988,26 @@ class LLMEngine:
             raise ValueError(
                 f"cache buckets {self.cache_buckets} must be whole blocks of "
                 f"{self.block_size} tokens: a padded cache is a block table")
+        if self._stateful:
+            # a kept state lies between two sub-chunks of the recurrence and at
+            # a block's end, and every chunk starts at one
+            chunk = self.cfg.state_chunk
+            if self.block_size % chunk or self.prefill_chunk % self.block_size or any(
+                    tc % chunk for tc in self.prefill_token_buckets):
+                raise ValueError(
+                    f"blocks of {self.block_size}, prefill chunks of {self.prefill_chunk} in "
+                    f"buckets {self.prefill_token_buckets}: the model keeps a state every "
+                    f"{chunk} tokens, and a block and a chunk must end at one")
+            if state_slots is None:
+                # a slot a sequence the batcher can hold, as many snapshots, nobody's
+                state_slots = 4 * self.lane_buckets[-1] + 1
         self.pool = KVBlockPool(
             self.cfg, num_blocks=num_blocks, block_size=block_size,
-            deployment=deployment,
+            state_slots=state_slots or 0, deployment=deployment,
         )
         self._operand_width = _operand_width(
             self.prefill_token_buckets[-1],
-            self.cache_buckets[-1] // self.block_size)
+            self.cache_buckets[-1] // self.block_size, self._stateful)
         self._warm_paging()
         self.prefix: Optional[PrefixCache] = (
             PrefixCache(self.pool, deployment) if prefix_caching else None
@@ -790,8 +1046,14 @@ class LLMEngine:
         self.window_slots_outside = 0
         self._window = getattr(self.cfg, "sliding_window", None)
         self._window_layers = sum(getattr(self.cfg, "sliding_layers", ()))
-        self.phase_s: Dict[str, float] = dict.fromkeys(PHASES, 0.0)
-        self.phase_n: Dict[str, int] = dict.fromkeys(PHASES, 0)
+        phases = PHASES + (STATE_PHASES if self._stateful else ())
+        self.phase_s: Dict[str, float] = dict.fromkeys(phases, 0.0)
+        self.phase_n: Dict[str, int] = dict.fromkeys(phases, 0)
+        # states the prefix cache gave back to a sequence, and the bytes of
+        # state copied by anything but the model itself: into a call's lanes,
+        # back into their slots, into a snapshot's, from one (0 without states)
+        self.state_restores = 0
+        self.state_bytes_moved = 0
         #: per form of call, over the device calls: how many; their real lanes
         #: and their lane buckets; the tokens fed and lanes x token bucket; the
         #: live tokens gathered into padded caches and lanes x cache bucket;
@@ -857,7 +1119,8 @@ class LLMEngine:
             for b in self.lane_buckets
             for tc in [1] + self.prefill_token_buckets
         }
-        counted = outputs[self.lane_buckets[0], 1][2 + len(self.pool.arenas):]
+        counted = self.pool.split_states(
+            outputs[self.lane_buckets[0], 1][2 + len(self.pool.arenas):], 1)[2]
         self._home_width += sum(math.prod(c.shape) for c in counted)
         #: the bytes ``extend`` hands back for (lanes, tokens), for ``_fits``
         self._output_bytes = {
@@ -870,11 +1133,14 @@ class LLMEngine:
         """The arrays ``_extend_call`` takes for ``b`` lanes and a cache of
         ``cap``, each made by ``make(shape, dtype)``."""
         caches = (
-            make((self.cfg.num_layers, b, cap) + tuple(each), self.pool.dtype)
+            make((self.pool.layers, b, cap) + tuple(each), self.pool.dtype)
             for each in self.cfg.cache_arrays)
+        states = (
+            make((layers, b) + tuple(shape), dtype)
+            for layers, shape, dtype in self.pool.state_arrays)
         return (
             self._params, make((b, self._operand_width), np.int32),
-            make((self._home_width,), np.int32), *caches)
+            make((self._home_width,), np.int32), *caches, *states)
 
     def extend_shapes(self) -> List[tuple]:
         """Every (lanes, tokens, cache) a step can ask ``extend`` for, one
@@ -948,6 +1214,11 @@ class LLMEngine:
             "prefix_evictions": self.prefix.evictions if self.prefix else 0,
             "prefix_cached_blocks": len(self.prefix) if self.prefix else 0,
             "adapters_resident": self._mux.loaded_ids(),
+            **({
+                "state_slots_total": self.pool.state_slots - 1,
+                "state_slots_in_use": self.pool.slots_in_use(),
+                "state_snapshots": self.prefix.snapshots() if self.prefix else 0,
+            } if self._stateful else {}),
             **self._work(),
             "traced": copy.deepcopy(self.traced),
             "slowest_step": slowest,
@@ -975,6 +1246,10 @@ class LLMEngine:
             "calls_ahead": self.calls_ahead,
             "tokens_fed_on_device": self.tokens_fed_on_device,
             **self.counted,
+            **({
+                "state_restores": self.state_restores,
+                "state_bytes_moved": self.state_bytes_moved,
+            } if self._stateful else {}),
             "window_slots": self.window_slots,
             "window_slots_outside": self.window_slots_outside,
             "phase_s": dict(self.phase_s),
@@ -1109,13 +1384,26 @@ class LLMEngine:
             # never reuse the whole prompt: the last prompt token must be
             # fed through prefill to produce the first sampled token
             reuse_cap = (len(st.prompt) - 1) // bs
-            cached = (
-                self.prefix.match(st.hashes[:reuse_cap])
-                if self.prefix is not None else []
-            )
-            lease.add(cached)
-            need = math.ceil(len(st.prompt) / bs) - len(cached)
+            st.slot = st.snapshot = None
             try:
+                if self._stateful:
+                    # before the match: taking a slot may cost a snapshot
+                    st.slot = lease.add_slot()
+                    # all of a cached chain or nothing: its state is the one at
+                    # its end, which is where this prompt's would be kept too
+                    st.hashes = st.hashes[:reuse_cap]
+                cached = (
+                    self.prefix.match(st.hashes[:reuse_cap])
+                    if self.prefix is not None else []
+                )
+                lease.add(cached)
+                if cached and self._stateful:
+                    with self._phase("state_restore", tokens=len(cached) * bs):
+                        self.pool.copy_state(
+                            self.prefix.snapshot(st.hashes[len(cached) - 1]), st.slot)
+                        self.state_restores += 1
+                        self.state_bytes_moved += self.pool.state_bytes
+                need = math.ceil(len(st.prompt) / bs) - len(cached)
                 lease.add(self.pool.allocate(need))
             except NoKVBlocksError as e:
                 lease.release()
@@ -1307,6 +1595,8 @@ class LLMEngine:
             rows[:, :tc] = to_rows.reshape(b, tc)
             slots[:, :tc] = to_slots.reshape(b, tc)
             operands[0, _COUNT] = fed
+            if self._stateful:
+                self._state_operands(operands, states, chunks, decode)
             self.h2d_bytes += operands.nbytes
             self.h2d_transfers += 1
             operands = jax.device_put(operands)
@@ -1314,6 +1604,9 @@ class LLMEngine:
             # slots past a lane's frontier hold what the pool holds there:
             # zeros or finite model output, which extend's mask weighs 0
             caches = self.pool.gather(operands, t_cap // bs)
+            if self._stateful:
+                caches += self.pool.gather_states(operands)
+                self.state_bytes_moved += b * self.pool.state_bytes
             if self._count_gathered is not None:
                 for name, n in self._count_gathered(b, t_cap).items():
                     self.counted[name] += n
@@ -1332,7 +1625,9 @@ class LLMEngine:
             logits, hidden, *rest = self._extend_call(
                 self._params, operands,
                 self._no_home if flight is None else flight.home, *caches, tc=tc)
-            news, counted = rest[:len(caches)], rest[len(caches):]
+            held = len(self.pool.arenas)
+            news, (new_states, kept, counted) = (
+                rest[:held], self.pool.split_states(rest[held:], tc))
             del caches, rest        # the caches are freed when extend has run
             counts = self.calls[form]
             counts["n"] += 1
@@ -1341,6 +1636,12 @@ class LLMEngine:
                 counts[key] += n
             self.calls_ahead += flight is not None
         with self._phase("kv_scatter"):
+            if self._stateful:
+                with self._phase(
+                        "state_snapshot", kept=sum(st.snapshot is not None for st in states)):
+                    self.pool.scatter_states(new_states, kept, operands)
+                    self.state_bytes_moved += b * (1 + bool(kept)) * self.pool.state_bytes
+                del new_states, kept
             home, picked = self.pool.page_back(
                 news, operands, (logits, hidden), counted, self.lane_buckets[-1])
             del logits, hidden, news, operands
@@ -1363,6 +1664,23 @@ class LLMEngine:
         self._flight = call
         return call
 
+    def _state_operands(self, operands, states, chunks, decode: bool) -> None:
+        """The state columns of a call's operand buffer: each lane's slot and,
+        for the chunk of a prompt that crosses the end of the blocks a later
+        request may reuse, how far in that end lies and the slot its state goes
+        to: one more of the sequence's lease, until the prefix cache takes it.
+        Without a free slot the prompt is not cached."""
+        for i, (st, ch) in enumerate(zip(states, chunks)):
+            operands[i, _SLOT] = st.slot
+            at = len(st.hashes) * self.block_size - st.length
+            if decode or self.prefix is None or not 0 < at <= len(ch):
+                continue
+            try:
+                st.snapshot = st.lease.add_slot()
+            except NoKVBlocksError:
+                continue
+            operands[i, _SNAP_AT], operands[i, _SNAP_SLOT] = at, st.snapshot
+
     def _fits(self, b: int, tc: int, t_cap: int) -> bool:
         """Whether the device has room for what a call of this shape takes at
         its launch, beside what the call in flight still holds: the padded
@@ -1376,8 +1694,8 @@ class LLMEngine:
             return True
         memory = self._device.memory_stats()
         need = (
-            self.pool.cache_bytes(b * t_cap) + self._output_bytes[b, tc]
-            + self._temp_bytes)
+            self.pool.cache_bytes(b * t_cap) + b * self.pool.state_bytes
+            + self._output_bytes[b, tc] + self._temp_bytes)
         free = memory["bytes_limit"] - memory["bytes_in_use"]
         return need <= min(free, memory.get("largest_free_block_bytes", free))
 
@@ -1450,8 +1768,13 @@ class LLMEngine:
                     continue
                 if not st.out and self.prefix is not None:
                     # its first token: the prompt is whole in the cache. Cache
-                    # every full prompt block (first writer wins)
-                    self.prefix.insert(st.hashes, st.blocks[:len(st.hashes)])
+                    # every full prompt block (first writer wins); with the
+                    # state at their end, where the model keeps one
+                    kept = st.snapshot if (
+                        st.snapshot is not None and st.lease.give_slot(st.snapshot)) else None
+                    if not self.prefix.insert(
+                            st.hashes, st.blocks[:len(st.hashes)], kept) and kept is not None:
+                        self.pool.free_slot(kept)
                 self._emit(s, st, *sampled)
         return call
 

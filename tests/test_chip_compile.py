@@ -12,6 +12,7 @@ path, so the tests answer for the package's one probe (``built_for_tpu``,
 """
 
 import dataclasses
+import math
 import os
 import re
 
@@ -24,7 +25,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu._private import accelerator
-from ray_tpu.models import cohere2_moe, gpt, keye_vl2, kimi_k2
+from ray_tpu.models import cohere2_moe, gpt, granitemoehybrid, keye_vl2, kimi_k2
 from ray_tpu.models.training import (
     abstract_state,
     default_optimizer,
@@ -590,10 +591,104 @@ def test_an_arena_of_latent_rows_pages_without_a_whole_arena_temporary(shaped):
     assert two.temp_size_in_bytes >= arena_bytes // 10
 
 
+def _granite_whole():
+    """granite-4.0-h-micro as served, whole, and its engine sizes, from the
+    configuration's file."""
+    import json
+
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "configs",
+            "granite-4.0-h-micro-serve.json")) as f:
+        config = json.load(f)
+    return granitemoehybrid.GraniteMoeHybridConfig(), config
+
+
+@pytest.mark.parametrize("form", ["decode", "prefill"])
+def test_granite_hybrid_extend_compiles_at_its_largest_shapes(shaped, form, built_for_tpu):
+    """The whole published model (6.38 GB of weights) over the largest cache
+    bucket: a decode call of eight lanes over their gathered states (0.61 GB,
+    donated: the new states take their place), a prefill chunk through the
+    chunked recurrence and, in the four attention layers, the attention kernel.
+    No layer's weights are copied for the scan and no lane's states stacked
+    (temporaries of 1.5 and 2.9 GB when they were); it holds the memory the
+    configuration's file states and fits beside the pool, the slots and a second
+    call's caches and states."""
+    built_for_tpu(True)
+    cfg, config = _granite_whole()
+    engine, stated = config["engine"], config["compiled_bytes_per_device"]
+    cap, lanes = engine["cache_buckets"][-1], engine["lane_buckets"][-1]
+    b, tc = (lanes, 1) if form == "decode" else (1, engine["prefill_token_buckets"][-1])
+    assert stated[form]["shape"] == [b, tc, cap]
+    params = jax.tree.map(
+        lambda x: shaped(x.shape, x.dtype), jax.eval_shape(lambda: cfg.init_params(0)))
+    caches = [
+        shaped((cfg.cache_layers, b, cap) + tuple(each), cfg.dtype) for each in cfg.cache_arrays]
+    states = [shaped((layers, b) + tuple(shape), dtype) for layers, shape, dtype in cfg.state_arrays]
+    operands = shaped(
+        (b, llm._operand_width(
+            engine["prefill_token_buckets"][-1], cap // engine["block_size"], True)), jnp.int32)
+    compiled = llm._operand_extend(cfg.make_extend_fn(), len(caches), len(states)).lower(
+        params, operands, shaped((lanes + len(cfg.counters),), jnp.int32), *caches, *states, tc=tc
+    ).compile()
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    if form == "prefill":
+        assert kernels and all("/extend.attention/masked_attention/" in k for k in kernels)
+    else:
+        assert not kernels
+    memory = compiled.memory_analysis()
+    state_bytes = sum(
+        layers * math.prod(shape) * jnp.dtype(dtype).itemsize
+        for layers, shape, dtype in cfg.state_arrays)
+    assert state_bytes == 76_437_504
+    weights = memory.argument_size_in_bytes - b * (cap * 8192 + state_bytes + 2**17)
+    assert 6.38e9 < weights < 6.39e9
+    assert memory.argument_size_in_bytes == stated[form]["argument"]
+    assert memory.temp_size_in_bytes <= stated[form]["temp"] * 1.05 < 0.2e9
+    # the lanes' new states are written over the ones handed in
+    assert memory.alias_size_in_bytes >= b * state_bytes
+    resident = engine["num_blocks"] * engine["block_size"] * 8192 + (
+        engine["state_slots"] * state_bytes)
+    assert _device_bytes(compiled) + resident + lanes * (cap * 8192 + state_bytes) < HBM_BYTES
+
+
+def test_state_slots_are_read_and_written_without_a_whole_arena_temporary(shaped):
+    """The state arenas of the granite configuration (49 slots of 76.4 MB:
+    3.75 GB) and the three programs that touch them: the gather's output is the
+    lanes' states and nothing more, the scatter and the copy alias the arenas,
+    and none holds a temporary of any size."""
+    cfg, config = _granite_whole()
+    engine = config["engine"]
+    slots, lanes = engine["state_slots"], engine["lane_buckets"][-1]
+    arenas = tuple(
+        shaped((layers, slots) + tuple(shape), dtype) for layers, shape, dtype in cfg.state_arrays)
+    arena_bytes = slots * 76_437_504
+    programs = llm._state_programs()
+    width = llm._operand_width(
+        engine["prefill_chunk"], engine["cache_buckets"][-1] // engine["block_size"], True)
+    for b in (1, lanes):
+        operands = shaped((b, width), jnp.int32)
+        news = tuple(
+            shaped((layers, b) + tuple(shape), dtype) for layers, shape, dtype in cfg.state_arrays)
+        memory = programs.gather.lower(arenas, operands).compile().memory_analysis()
+        # (the compiler pads the convolution's three rows: 0.14 % more than the values)
+        assert 0 <= memory.output_size_in_bytes - b * 76_437_504 < b * 2**17
+        assert memory.temp_size_in_bytes < 2**20, b
+        memory = programs.scatter.lower(
+            arenas, news, news if b == 1 else (), operands).compile().memory_analysis()
+        assert 0 <= memory.alias_size_in_bytes - arena_bytes < slots * 2**17, b
+        assert memory.temp_size_in_bytes < 2**20, b
+    copy = programs.copy.lower(
+        arenas, shaped((), jnp.int32), shaped((), jnp.int32)).compile().memory_analysis()
+    assert 0 <= copy.alias_size_in_bytes - arena_bytes < slots * 2**17
+    assert copy.temp_size_in_bytes < 2**20
+
+
 @pytest.mark.parametrize(
     "name,extends,pagings",
     [("gptj-6b-serve", 12, 11), ("command-a-plus-serve-ep8", 20, 25),
-     ("keye-vl2-30b-a3b-serve", 16, 19), ("kimi-k2-instruct-serve-ep32", 16, 19)],
+     ("keye-vl2-30b-a3b-serve", 16, 19), ("kimi-k2-instruct-serve-ep32", 16, 19),
+     ("granite-4.0-h-micro-serve", 20, 25)],
 )
 def test_a_serve_configuration_compiles_no_more_programs_than_it_did(name, extends, pagings):
     """The programs an engine with the configuration's buckets compiles (a tiny
@@ -614,6 +709,8 @@ def test_a_serve_configuration_compiles_no_more_programs_than_it_did(name, exten
         if name.startswith("command-a-plus")
         else keye_vl2.keye_vl2_nano(max_seq_len=context) if name.startswith("keye")
         else kimi_k2.kimi_k2_nano(max_seq_len=context) if name.startswith("kimi")
+        else granitemoehybrid.granite_hybrid_nano(max_seq_len=context, ssm_chunk=256)
+        if name.startswith("granite")
         else dataclasses.replace(gpt.gpt_nano(), max_seq_len=context)
     )
     llm._paging_programs.cache_clear()      # this engine's programs alone
