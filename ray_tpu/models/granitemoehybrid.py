@@ -14,15 +14,22 @@ other architectures in what a *sequence* leaves behind:
   and ``C`` shared by all heads: one group), ``D_t = softplus(dt + dt_bias)``,
   ``A = -exp(A_log)``, ``S_t = exp(D_t A) S_{t-1} + D_t x_t (x) B_t``, ``y_t = S_t
   C_t + D x_t``, then ``W_out RMSNorm(y * silu(z))``. The configuration names the
-  two arrays (``state_arrays``); the engine keeps a slot of each for a sequence,
-  hands ``extend`` its lanes' states and takes the new ones back;
-* a decode lane does one step of the recurrence. A prefill chunk computes it in
+  two arrays (``state_arrays``); the engine keeps a slot of each for a sequence
+  in arenas ``[ssm_layers, slots, ...]`` and hands ``extend`` the arenas
+  themselves with the lanes' slot ids: a layer reads a lane's state where the
+  pool keeps it and writes the new one to the same place, and nothing else of an
+  arena is touched (slot 0 is nobody's: a padded lane's);
+* a decode lane does one step of the recurrence: on the chip one kernel
+  (:func:`ssm_step_slots`) that fetches a lane's state from its slot, updates
+  it and reads it out in VMEM and writes it back, one read and one write of the
+  state; elsewhere the same addressing in ``jax.numpy``. A prefill chunk computes it in
   sub-chunks of ``ssm_chunk`` tokens (the chunked, "SSD" form: inside a
   sub-chunk a masked matmul, between them the state), one ``lax.scan`` body for
   every sub-chunk, so that the same tokens from the same state give the same
   bits wherever in a call they lie. The states between sub-chunks are what a
-  prefix cache can restore: ``extend`` hands back the one ``snap_at`` tokens in
-  (a whole number of sub-chunks), beside the state at the chunk's end;
+  prefix cache can restore: ``extend`` writes the one ``snap_at`` tokens in (a
+  whole number of sub-chunks) to the slot it is told (``snap_slots``), beside
+  the state at the chunk's end in the lane's own;
 * a padded token (id < 0) has ``D_t = 0``: it neither decays nor feeds a state,
   and the convolution's tail skips it; a lane of length 0 starts from zeros,
   whatever its slot holds;
@@ -41,6 +48,7 @@ float32; weights and the operands of the matmuls ``dtype``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -259,6 +267,101 @@ def ssm_step(state, x, dt, a, b, c):
     return (state * c[:, None, None, :]).sum(-1), state
 
 
+#: heads of a lane's state that one grid step of :func:`ssm_step_slots` moves:
+#: 32 x 64 x 128 float32 = 1 MB in and 1 MB out, each buffered twice in VMEM
+SSM_STEP_HEADS = 32
+
+
+def _ssm_step_kernel(slots, real, fresh, at, fed, decay, b, c, state, y, out, *, heads):
+    """One block of ``heads`` heads of one lane: ``fed`` [p, heads] (``dt x``,
+    a head a column), ``decay`` [1, heads], ``b``, ``c`` [1, n], ``state`` and
+    ``out`` [heads, p, n] (one block of the arena, aliased), ``y`` [p, heads]."""
+    from jax.experimental import pallas as pl
+
+    lane = pl.program_id(0)
+
+    @pl.when(real[lane] == 0)
+    def _():
+        # a padded lane: slot 0's first block goes back as it came
+        out[...] = state[...]
+        y[...] = jnp.zeros_like(y)
+
+    @pl.when(real[lane] != 0)
+    def _():
+        start_over = fresh[lane] != 0
+        column = jax.lax.broadcasted_iota(jnp.int32, y.shape, 1)
+        read = jnp.zeros(y.shape, jnp.float32)
+        for h in range(heads):
+            old = jnp.where(start_over, 0.0, state[h])               # [p, n]
+            new = decay[:, h:h + 1] * old + fed[:, h:h + 1] * b[...]
+            out[h] = new
+            read = jnp.where(
+                column == h, (new * c[...]).sum(-1, keepdims=True), read)
+        y[...] = read
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "interpret"))
+def ssm_step_slots(arena, at, slots, real, fresh, x, dt, a, b, c, *,
+                   heads: int = SSM_STEP_HEADS, interpret: bool = False):
+    """:func:`ssm_step` on states where the pool keeps them, as one kernel:
+    ``arena`` [layers, slots, heads, p, n] float32 holds every sequence's state
+    of every layer, and lane ``i``'s of layer ``at`` is ``arena[at, slots[i]]``.
+    A grid step fetches ``heads`` heads of it, updates them and reads them out
+    in VMEM (float32) and writes them back where they came from: the state is
+    read once and written once, and the arena is the kernel's output too
+    (``input_output_aliases``), untouched wherever no lane points. ``real``
+    [lanes] says which lanes hold a token: the others neither read nor write
+    their slot (each step of theirs revisits the first block of slot 0, which is
+    nobody's: it is fetched once and written back as it came); ``fresh`` which
+    start from zeros whatever their slot holds. ``x`` [lanes, heads, p], ``dt``
+    [lanes, heads] (0 for a padded token), ``a`` [heads], ``b``, ``c`` [lanes,
+    n], float32. Returns ``y`` [lanes, heads, p] and the arena. A jit of its
+    own: the nine layers of a period, and every ``extend`` shape of a lane
+    bucket, share one trace of the kernel and one lowering a program (traced
+    layer by layer the 20 shapes' set-up took a minute longer on the chip's host)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    lanes, all_heads, p = x.shape
+    n = b.shape[-1]
+    heads = min(heads, all_heads)
+    blocks = all_heads // heads
+    real = real.astype(jnp.int32)
+
+    def by_block(v):                # [lanes, all_heads, w] -> [lanes, blocks, w, heads]
+        return v.reshape(lanes, blocks, heads, -1).swapaxes(2, 3)
+
+    def small(width):
+        return pl.BlockSpec((None, None, width, heads), lambda i, h, *_: (i, h, 0, 0))
+
+    row = pl.BlockSpec((None, 1, n), lambda i, h, *_: (i, 0, 0))
+    state = pl.BlockSpec(
+        (None, None, heads, p, n),
+        lambda i, h, slots, real, fresh, at: (at[0], slots[i], real[i] * h, 0, 0))
+    y, arena = pl.pallas_call(
+        functools.partial(_ssm_step_kernel, heads=heads),
+        name="ssm_step",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(lanes, blocks),
+            in_specs=[small(p), small(1), row, row, state],
+            out_specs=[small(p), state],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((lanes, blocks, p, heads), jnp.float32),
+            jax.ShapeDtypeStruct(arena.shape, arena.dtype)],
+        input_output_aliases={8: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(
+        jnp.where(real != 0, slots, 0).astype(jnp.int32), real, fresh.astype(jnp.int32),
+        jnp.reshape(at, (1,)).astype(jnp.int32),
+        by_block(dt[..., None] * x), by_block(jnp.exp(dt * a)[..., None]),
+        b[:, None], c[:, None], arena)
+    return y.swapaxes(2, 3).reshape(lanes, all_heads, p), arena
+
+
 def ssm_chunked(state, x, dt, a, b, c, chunk: int, dtype):
     """The recurrence over ``t`` tokens in sub-chunks of ``chunk`` (``t`` a whole
     number of them), as matmuls: ``x`` [lanes, t, heads, p], ``dt`` [lanes, t,
@@ -301,20 +404,24 @@ def ssm_chunked(state, x, dt, a, b, c, chunk: int, dtype):
 
 def make_extend_fn(cfg: GraniteMoeHybridConfig):
     """A jitted ``extend(params, tokens, lengths, k_cache, v_cache, ssm, conv,
-    snap_at)``: the contract of ``gpt.make_extend_fn`` over the attention
-    layers' caches (``[cache_layers, lanes, cache, 1, kv_heads x head_dim]``)
-    and the lanes' states (``cfg.state_arrays``: ``[ssm_layers, lanes, ...]``).
-    Returns ``(logits, hidden, k rows, v rows, ssm, conv, [ssm and conv at
-    snap_at], counters)``: the new states are those after each lane's last real
-    token; a call of more than one token a lane also hands back the states
-    after ``snap_at[lane]`` tokens (a whole number of sub-chunks, at least one:
-    0 reads as one). A lane of length 0 starts from zeros whatever it is handed;
-    a negative token id is padding and changes no state. ``counters``
-    (``cfg.counters``) over real lanes and tokens.
+    slots, snap_at, snap_slots)``: the contract of ``gpt.make_extend_fn`` over
+    the attention layers' caches (``[cache_layers, lanes, cache, 1, kv_heads x
+    head_dim]``), and the pool's state arenas themselves (``cfg.state_arrays``:
+    ``[ssm_layers, state slots, ...]``; a caller that keeps them donates them)
+    with each lane's slot in them (``slots`` [lanes]). Returns ``(logits,
+    hidden, k rows, v rows, ssm, conv, counters)``: the arenas with, in each
+    lane's slot, the states after its last real token; a call of more than one
+    token a lane also writes the states after ``snap_at[lane]`` tokens (a whole
+    number of sub-chunks, at least one: 0 reads as one) to slot
+    ``snap_slots[lane]`` (0, nobody's, where none is to be kept). No other slot
+    is touched. A lane of length 0 starts from zeros whatever its slot holds; a
+    negative token id is padding and changes no state; a lane of padding alone
+    points at slot 0. ``counters`` (``cfg.counters``) over real lanes and tokens.
 
     Scopes: ``extend.embed``; ``extend.ssm`` (projections, convolution, gate,
-    norm) with ``extend.ssm.scan`` inside it (the recurrence alone);
-    ``extend.attention``; ``extend.mlp``; ``extend.logits``."""
+    norm) with ``extend.ssm.scan`` inside it (the recurrence alone, with the
+    state's read and its write; a decode call's is the kernel ``ssm_step`` on
+    the chip); ``extend.attention``; ``extend.mlp``; ``extend.logits``."""
     dtype, f32 = cfg.dtype, jnp.float32
     heads, p_dim, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
     inner, tail = cfg.ssm_inner, cfg.conv_width - 1
@@ -334,24 +441,45 @@ def make_extend_fn(cfg: GraniteMoeHybridConfig):
         out = jax.lax.map(lambda block: fn(*block), split)
         return out.swapaxes(0, 1).reshape((b, tc) + out.shape[3:])
 
+    # An arena is read and written one slot at a time, with a dynamic slice and
+    # an in-place dynamic update: indexed with the slots (``arena[at, slots]``)
+    # the TPU compiler first copies all of it (3.75 GB: ``serve/llm.py``
+    # ``_paging_programs``; ``tests/test_chip_compile.py`` holds ``extend`` to this).
+
+    def _take(arena, slots, at=0, layers=1):
+        """``arena[at:at + layers, slots]``: [layers, lanes, ...]."""
+        return jnp.concatenate([
+            jax.lax.dynamic_slice(
+                arena, (at, slots[i]) + (0,) * (arena.ndim - 2), (layers, 1) + arena.shape[2:])
+            for i in range(slots.shape[0])], axis=1)
+
+    def _put(arena, slots, new, at=0):
+        """``arena[at:at + len(new), slots] = new``, lane by lane where the arena lies."""
+        new = new.astype(arena.dtype)
+        for i in range(slots.shape[0]):
+            arena = jax.lax.dynamic_update_slice(
+                arena, new[:, i:i + 1], (at, slots[i]) + (0,) * (arena.ndim - 2))
+        return arena
+
     def _layer(stack, at):
         return jax.lax.dynamic_index_in_dim(stack, at, 0, keepdims=False)
 
-    def _put(stack, new, at):
+    def _set_layer(stack, new, at):
         return jax.lax.dynamic_update_index_in_dim(stack, new.astype(stack.dtype), at, 0)
 
     @jax.named_scope("extend.ssm")
-    def _mamba(p, hidden, valid, fresh, snap_at, states, kept, at):
-        """``states`` are the lanes' own before this call, every Mamba layer's
-        (``[ssm_layers, lanes, heads, p, n]`` and ``[ssm_layers, lanes, tail,
-        conv_dim]``); layer ``at``'s are read and, after the lane's last real
-        token, written where they lie, and into ``kept`` (a chunk) those after
-        ``snap_at`` tokens. Returns the mixer's output, ``states`` and ``kept``."""
+    def _mamba(p, hidden, valid, fresh, slots, snap_at, snap_slots, ssm, tails, at):
+        """``ssm`` holds every sequence's state of every Mamba layer
+        (``[ssm_layers, state slots, heads, p, n]``): lane ``i``'s of layer ``at``
+        is read from slot ``slots[i]`` and, after the lane's last real token,
+        written there, and into slot ``snap_slots[i]`` (a chunk) the one after
+        ``snap_at[i]`` tokens. ``tails`` are the lanes' own convolution inputs
+        (``[ssm_layers, lanes, tail, conv_dim]``: a 1/80 of a state, taken from
+        their slots before the layers and put back behind them): layer ``at``'s
+        are read and the new ones written, and for a chunk the kept ones beside
+        them. Returns the mixer's output, ``ssm`` and ``tails``."""
         b, tc = valid.shape
-        with jax.named_scope("extend.ssm.scan"):
-            # the state's read and its write are the recurrence's own traffic
-            state = jnp.where(fresh[:, None, None, None], 0.0, _layer(states[0], at).astype(f32))
-        conv = jnp.where(fresh[:, None, None], 0, _layer(states[1], at)).astype(dtype)
+        conv = jnp.where(fresh[:, None, None], 0, _layer(tails[0], at)).astype(dtype)
         z = hidden @ p["in_z"]["kernel"].astype(dtype)
         xbc = hidden @ p["in_xbc"]["kernel"].astype(dtype)
         dt = jnp.dot(hidden, p["in_dt"]["kernel"].astype(dtype), preferred_element_type=f32)
@@ -365,33 +493,39 @@ def make_extend_fn(cfg: GraniteMoeHybridConfig):
         # what the next call's first tokens need: the inputs of the last real ones
         lane = jnp.arange(b)[:, None]
         after = jnp.arange(tail)[None, :]
-        conv_new = seen[lane, valid.sum(1, dtype=jnp.int32)[:, None] + after]
+        tails = tuple(
+            _set_layer(stack, seen[lane, upto[:, None] + after], at)
+            for stack, upto in zip(tails, (
+                valid.sum(1, dtype=jnp.int32), jnp.maximum(snap_at, cfg.ssm_chunk))))
         x = mixed[..., :inner].reshape(b, tc, heads, p_dim)
         bm, cm = mixed[..., inner:inner + n], mixed[..., inner + n:]
         a = -jnp.exp(p["A_log"])
         with jax.named_scope("extend.ssm.scan"):
+            # the state's read and its write are the recurrence's own traffic
+            def before():
+                return jnp.where(fresh[:, None, None, None], 0.0, _take(ssm, slots, at)[0])
+
             if tc == 1:
-                y, state_new = ssm_step(
-                    state, x[:, 0].astype(f32), dt[:, 0], a, bm[:, 0].astype(f32),
-                    cm[:, 0].astype(f32))
+                step = (
+                    x[:, 0].astype(f32), dt[:, 0], a, bm[:, 0].astype(f32), cm[:, 0].astype(f32))
+                if backend.on_tpu():
+                    y, ssm = ssm_step_slots(ssm, at, slots, valid[:, 0], fresh, *step)
+                else:
+                    y, state = ssm_step(before(), *step)
+                    ssm = _put(ssm, slots, state[None], at)
                 y = y[:, None]
             else:
-                y, state_new, between = ssm_chunked(
-                    state, x, dt, a, bm, cm, cfg.ssm_chunk, dtype)
+                y, state, between = ssm_chunked(before(), x, dt, a, bm, cm, cfg.ssm_chunk, dtype)
                 chunk = jnp.clip(snap_at // cfg.ssm_chunk - 1, 0, tc // cfg.ssm_chunk - 1)
-                kept = (_put(kept[0], jnp.take_along_axis(
-                    between, chunk[None, :, None, None, None], axis=0)[0], at), kept[1])
-            states = (_put(states[0], state_new, at), states[1])
-        states = (states[0], _put(states[1], conv_new, at))
-        if tc > 1:
-            kept = (kept[0], _put(
-                kept[1], seen[lane, jnp.maximum(snap_at, cfg.ssm_chunk)[:, None] + after], at))
+                ssm = _put(ssm, snap_slots, jnp.take_along_axis(
+                    between, chunk[None, :, None, None, None], axis=0), at)
+                ssm = _put(ssm, slots, state[None], at)
         y = y + p["D"][:, None] * x.astype(f32)
         y = y.reshape(b, tc, inner) * jax.nn.silu(z.astype(f32))
         out = jnp.dot(
             _rms(y, p["norm"]).astype(dtype), p["out"]["kernel"].astype(dtype),
             preferred_element_type=f32)
-        return out, states, kept
+        return out, ssm, tails
 
     @jax.named_scope("extend.attention")
     def _attend(p, hidden, positions, visible, live, kc, vc):
@@ -432,7 +566,7 @@ def make_extend_fn(cfg: GraniteMoeHybridConfig):
         return x + (res * out).astype(dtype)
 
     @jax.jit
-    def extend(params, tokens, lengths, k_cache, v_cache, ssm, conv, snap_at):
+    def extend(params, tokens, lengths, k_cache, v_cache, ssm, conv, slots, snap_at, snap_slots):
         b, tc = tokens.shape
         positions = (
             lengths[:, None].astype(jnp.int32) + jnp.arange(tc, dtype=jnp.int32)[None, :])
@@ -445,10 +579,9 @@ def make_extend_fn(cfg: GraniteMoeHybridConfig):
             x = x * jnp.asarray(cfg.embedding_multiplier, dtype)
 
         def body(carry, xs):
-            # the lanes' states are carried whole and each layer's is read and
-            # written where it lies: sliced as scanned inputs and stacked as
-            # outputs they are copied twice over, 0.6 GB at eight lanes
-            x, states, kept = carry
+            # the state arena is carried whole and each layer's slots are read
+            # and written where they lie
+            x, ssm, tails = carry
             p, kc, vc, period = xs
             rows, m = None, 0
             for i in range(cfg.period):
@@ -458,18 +591,26 @@ def make_extend_fn(cfg: GraniteMoeHybridConfig):
                         visible, live, kc, vc)
                 else:
                     layer = p["mamba"][m]
-                    out, states, kept = _mamba(
-                        layer, _rms(x, layer["ln"]).astype(dtype), valid, fresh, snap_at,
-                        states, kept, period * (cfg.period - 1) + m)
+                    out, ssm, tails = _mamba(
+                        layer, _rms(x, layer["ln"]).astype(dtype), valid, fresh, slots,
+                        snap_at, snap_slots, ssm, tails, period * (cfg.period - 1) + m)
                     m += 1
                 x = _add(x, out)
                 x = _add(x, _mlp(x, p["mlp"][i]))
-            return (x, states, kept), rows
+            return (x, ssm, tails), rows
 
-        kept = tuple(jnp.zeros_like(s) for s in (ssm, conv)) if tc > 1 else ()
-        (x, news, kept), rows = jax.lax.scan(
-            body, (x, (ssm, conv), kept), (
+        # the convolution's inputs are small (26 KB a lane and layer, where the
+        # state is 2 MB): every layer's leave their slots in one slice a lane and
+        # go back in one update a lane; layer by layer the compiler would move
+        # that whole arena to VMEM and back around the scan
+        own = _take(conv, slots, layers=conv.shape[0])
+        tails = (own, own) if tc > 1 else (own,)    # the new ones; a chunk's kept ones
+        (x, ssm, tails), rows = jax.lax.scan(
+            body, (x, ssm, tails), (
                 params["periods"], k_cache, v_cache, jnp.arange(cfg.periods, dtype=jnp.int32)))
+        if tc > 1:
+            conv = _put(conv, snap_slots, tails[1])
+        conv = _put(conv, slots, tails[0])
         with jax.named_scope("extend.logits"):
             x = _rms(x, params["ln_f"])
             logits = jnp.dot(
@@ -477,6 +618,6 @@ def make_extend_fn(cfg: GraniteMoeHybridConfig):
                 preferred_element_type=f32) / cfg.logits_scaling
         counters = cfg.ssm_layers * jnp.stack([
             valid.sum(dtype=jnp.int32), valid.any(1).sum(dtype=jnp.int32)])
-        return (logits, x, *rows, *news, *kept, counters)
+        return (logits, x, *rows, ssm, conv, counters)
 
     return extend

@@ -54,14 +54,21 @@ serving setup from PAPERS.md):
 * state per sequence — a recurrent layer leaves nothing behind per token but
   one state per sequence. The pool holds slots of it beside the block arenas
   (``state_arrays``): a sequence takes one at admission and gives it back with
-  its lease, a call gathers its lanes' states by slot, ``extend`` continues
-  from them and the new ones are scattered back, all on the device and in
-  launch order, so a lane's state follows its token across the call in flight.
-  Cached blocks are only as good as the state at their end: ``extend`` hands
-  back the state at the block boundary the engine names (the reusable end of
-  the prompt, as a rule inside its last chunk), the prefix cache keeps it as a
-  snapshot with the chain, copies it to the sequence's slot on a hit and drops
-  it with the chain on eviction.
+  its lease. Nothing copies a state into or out of a call: ``extend`` is handed
+  the state arenas themselves (donated, and the ones it returns take their
+  place, as ``page_back`` treats the block arenas) and each lane's slot
+  (``extend(params, tokens, lengths, *caches, *states, slots, snap_at,
+  snap_slots)``), reads a lane's state where the pool keeps it and writes the
+  new one to the same place, on the device and in launch order, so a lane's
+  state follows its token across the call in flight. A lane that ended in the
+  call in flight has had its slot advanced once more by the call behind it: the
+  slot is free by then, and its next owner starts from zeros or from a copy
+  launched later. Cached blocks are only as good as the state at their end:
+  ``extend`` writes the state at the block boundary the engine names (the
+  reusable end of the prompt, as a rule inside its last chunk) to a slot of its
+  own, the prefix cache keeps that as a snapshot with the chain, copies it to
+  the sequence's slot on a hit (the one copy of a state the engine makes:
+  ``state_bytes_moved``) and drops it with the chain on eviction.
 * LoRA multiplexing — base weights load once per replica; per-model
   low-rank logit deltas ``(A [d,r], B [r,vocab])`` are registered on the
   object plane via :func:`ray_tpu.serve.register_model` and streamed to
@@ -144,8 +151,8 @@ _LENGTH, _LAST, _COUNT, _FROM, _SCALARS = 0, 1, 2, 3, 4
 #: Behind the sections, for a configuration with per-sequence state alone
 #: (fewer than four, so :func:`_sections` cuts the same with them or without):
 #: the lane's state slot, after how many of the call's tokens ``extend`` is to
-#: hand back a state for the prefix cache (0: none), and the slot that state
-#: goes to. Counted from the buffer's end.
+#: keep a state for the prefix cache (0: none), and the slot that state goes to
+#: (0, nobody's, where none). Counted from the buffer's end.
 _SLOT, _SNAP_AT, _SNAP_SLOT, _STATE_COLUMNS = -3, -2, -1, 3
 
 
@@ -270,49 +277,25 @@ def _paging_programs():
 
 @functools.lru_cache(maxsize=None)
 def _state_programs():
-    """The three jitted programs that touch a pool's state arenas, a tuple of
-    arrays ``[layers, slots, ...]``: what a sequence holds where a model keeps
-    state per sequence and not per token. As :func:`_paging_programs`: loops of
-    one dynamic slice and one in-place update a lane, shaped by their arguments
-    alone. Slot 0 is nobody's: a padded lane reads and writes it."""
+    """The one jitted program beside ``extend`` that touches a pool's state
+    arenas, a tuple of arrays ``[layers, slots, ...]``: what a sequence holds
+    where a model keeps state per sequence and not per token. As
+    :func:`_paging_programs`: one dynamic slice and one in-place update, shaped
+    by its arguments alone. (A call's lanes' states are read and written by
+    ``extend`` itself, where they lie.)"""
     import types
 
     import jax
 
-    def move(into, source, count, take, put):
-        """``into[:, put(i)] = source[:, take(i)]`` for ``i < count``."""
-        def copy(i, out):
-            return jax.lax.dynamic_update_slice_in_dim(
-                out, jax.lax.dynamic_slice_in_dim(source, take(i), 1, axis=1), put(i), axis=1)
-
-        return jax.lax.fori_loop(0, count, copy, into)
-
-    @jax.jit
-    @jax.named_scope("paging.state_gather")
-    def gather(arenas, operands):
-        b, slots = operands.shape[0], operands[:, _SLOT]
-        return tuple(
-            move(jax.lax.empty((a.shape[0], b) + a.shape[2:], a.dtype), a, b,
-                 lambda i: slots[i], lambda i: i)
-            for a in arenas)
-
-    @functools.partial(jax.jit, donate_argnums=0)
-    @jax.named_scope("paging.state_scatter")
-    def scatter(arenas, news, kept, operands):
-        def put(arenas, states, slots):
-            return tuple(
-                move(a, new, operands.shape[0], lambda i: i, lambda i: slots[i])
-                for a, new in zip(arenas, states))
-
-        arenas = put(arenas, news, operands[:, _SLOT])
-        return put(arenas, kept, operands[:, _SNAP_SLOT]) if kept else arenas
-
     @functools.partial(jax.jit, donate_argnums=0)
     @jax.named_scope("paging.state_copy")
     def copy(arenas, src, dst):
-        return tuple(move(a, a, 1, lambda i: src, lambda i: dst) for a in arenas)
+        return tuple(
+            jax.lax.dynamic_update_slice_in_dim(
+                a, jax.lax.dynamic_slice_in_dim(a, src, 1, axis=1), dst, axis=1)
+            for a in arenas)
 
-    return types.SimpleNamespace(gather=gather, scatter=scatter, copy=copy)
+    return types.SimpleNamespace(copy=copy)
 
 
 class KVBlockPool:
@@ -339,10 +322,11 @@ class KVBlockPool:
     configuration's ``state_arrays``, ``(layers, shape, dtype)`` each), the pool
     holds that too: ``states``, one device array ``[layers, state_slots, ...]``
     each, a slot a sequence and a slot a snapshot the prefix cache keeps, under
-    the same lock, leases and shedding as the blocks, touched by the programs of
-    :func:`_state_programs` alone. Slot 0 is never handed out. And where its
-    tokens are cached in some layers only (``cache_layers``), the arenas hold
-    those."""
+    the same lock, leases and shedding as the blocks, touched by the model's
+    ``extend`` (which is handed them, donated, with its lanes' slots, and whose
+    returned arenas take their place) and the copy of :func:`_state_programs`
+    alone. Slot 0 is never handed out: a padded lane's. And where its tokens are
+    cached in some layers only (``cache_layers``), the arenas hold those."""
 
     def __init__(self, cfg, *, num_blocks: int = 128, block_size: int = 16,
                  state_slots: int = 0, deployment: str = "llm"):
@@ -430,19 +414,6 @@ class KVBlockPool:
         self.arenas = _paging_programs().clone(
             self.arenas, np.int32(src), np.int32(dst))
 
-    def gather_states(self, operands):
-        """The states ``[layers, b, ...]``, one per state arena, of the lanes
-        whose slots stand in ``operands`` (``_SLOT``), built on the device."""
-        return _state_programs().gather(self.states, operands)
-
-    def scatter_states(self, news, kept, operands) -> None:
-        """Write lane ``i`` of each of ``news`` ``[layers, b, ...]`` into its
-        slot and, where the call made any, of ``kept`` into the slot
-        ``operands`` names for it (``_SNAP_SLOT``; 0: nobody's). The arenas are
-        donated: nothing is copied but the lanes' states."""
-        self.states = _state_programs().scatter(
-            self.states, tuple(news), tuple(kept), operands)
-
     def copy_state(self, src: int, dst: int) -> None:
         """Copy slot ``src`` onto slot ``dst``, on the device."""
         self.states = _state_programs().copy(self.states, np.int32(src), np.int32(dst))
@@ -458,15 +429,15 @@ class KVBlockPool:
         made on the device: the gather per (lanes, cache bucket), the
         page-back per (lanes, tokens) of ``extend_shapes`` (that extend
         call's output shapes), the clone; where the pool holds states, their
-        gather and scatter per shape (into slot 0, nobody's) and the copy. The
-        page-back writes no token here, so the arenas keep their contents."""
+        copy. The page-back writes no token here, so the arenas keep their
+        contents."""
         import jax
         import jax.numpy as jnp
 
         width = _operand_width(
             max(tc for _, tc in extend_shapes),
             max(cache_buckets) // self.block_size, bool(self.states))
-        lanes, held = max(b for b, _ in extend_shapes), len(self.arenas)
+        lanes = max(b for b, _ in extend_shapes)
 
         def zeros(shapes):
             return tuple(jnp.zeros(x.shape, x.dtype) for x in shapes)
@@ -477,11 +448,8 @@ class KVBlockPool:
                 jax.block_until_ready(
                     self.gather(operands, cap // self.block_size))
         for (b, tc), (logits, hidden, *rest) in extend_shapes.items():
-            news, (states, kept, counted) = rest[:held], self.split_states(rest[held:], tc)
+            news, _, counted = self.split_outputs(rest)
             operands = jnp.zeros((b, width), jnp.int32)
-            if states:
-                jax.block_until_ready(self.gather_states(operands))
-                self.scatter_states(zeros(states), zeros(kept), operands)
             jax.block_until_ready(self.page_back(
                 zeros(news), operands, zeros((logits, hidden)), zeros(counted), lanes))
         self.clone_block(0, 0)
@@ -489,13 +457,12 @@ class KVBlockPool:
             self.copy_state(0, 0)
         jax.block_until_ready((self.arenas, self.states))
 
-    def split_states(self, rest, tc: int):
-        """What ``extend`` returns behind its caches' new rows, apart: the
-        lanes' new states, the states it kept for the prefix cache (a call of
-        more than one token a lane makes them), and what it counted."""
-        n = len(self.states)
-        kept = n if tc > 1 else 0
-        return rest[:n], rest[n:n + kept], rest[n + kept:]
+    def split_outputs(self, rest):
+        """What ``extend`` returns behind the logits and the hidden rows, apart:
+        its caches' new rows, the state arenas (where the pool holds any) and
+        what it counted."""
+        rows, states = len(self.arenas), len(self.arenas) + len(self.states)
+        return tuple(rest[:rows]), tuple(rest[rows:states]), tuple(rest[states:])
 
     # -- host bookkeeping ---------------------------------------------------
 
@@ -846,9 +813,10 @@ def _operand_extend(extend, caches: int = 0, states: int = 0):
     for the host, its lanes' ids first) where its row names a lane there
     (``_FROM``): the host need not have seen a token to feed it. One program
     per (lanes, tokens, cache), as ``extend`` alone has. Where the model keeps
-    state per sequence, its ``states`` arrays follow the ``caches`` (donated:
-    they are the gather's, and the new ones take their place) and ``extend`` is
-    told where to keep a state for the prefix cache (``_SNAP_AT``)."""
+    state per sequence, the pool's ``states`` arenas follow the ``caches``
+    (donated: the ones ``extend`` returns take their place) and ``extend`` is told
+    each lane's slot in them and where to keep a state for the prefix cache
+    (``_SLOT``, ``_SNAP_AT``, ``_SNAP_SLOT``)."""
     import jax
     import jax.numpy as jnp
 
@@ -858,9 +826,10 @@ def _operand_extend(extend, caches: int = 0, states: int = 0):
     def extend_call(params, operands, home, *arrays, tc):
         tokens, source = _sections(operands)[0][:, :tc], operands[:, _FROM]
         first = jnp.where(source < 0, tokens[:, 0], home[jnp.maximum(source, 0)])
-        keep = (operands[:, _SNAP_AT],) if states else ()
+        where = tuple(
+            operands[:, at] for at in (_SLOT, _SNAP_AT, _SNAP_SLOT)) if states else ()
         return extend(
-            params, tokens.at[:, 0].set(first), operands[:, _LENGTH], *arrays, *keep)
+            params, tokens.at[:, 0].set(first), operands[:, _LENGTH], *arrays, *where)
 
     return extend_call
 
@@ -920,10 +889,10 @@ LEAF_PHASES = (
     "admit", "upload", "kv_gather", "dispatch", "kv_scatter", "fetch", "sample",
 )
 PHASES = ("step", "prefill", "decode") + LEAF_PHASES
-#: where an engine keeps state per sequence, inside ``admit`` and ``kv_scatter``:
-#: a cached prefix's state copied to the sequence's slot, and the states a call
-#: made written to their slots, a snapshot for the prefix cache among them
-STATE_PHASES = ("state_restore", "state_snapshot")
+#: where an engine keeps state per sequence, inside ``admit``: a cached prefix's
+#: state copied to the sequence's slot (a call's own states ``extend`` reads and
+#: writes where they lie: no phase of the host's)
+STATE_PHASES = ("state_restore",)
 #: the two forms of device call, as ``stats()["calls"]`` keys them
 FORMS = ("prefill", "decode")
 #: what one ``_phase`` may cost outside a profiler session, where its span is
@@ -1050,8 +1019,8 @@ class LLMEngine:
         self.phase_s: Dict[str, float] = dict.fromkeys(phases, 0.0)
         self.phase_n: Dict[str, int] = dict.fromkeys(phases, 0)
         # states the prefix cache gave back to a sequence, and the bytes of
-        # state copied by anything but the model itself: into a call's lanes,
-        # back into their slots, into a snapshot's, from one (0 without states)
+        # state copied by anything but the model itself: from a snapshot's slot
+        # to a sequence's, which is all the store copies (0 without states)
         self.state_restores = 0
         self.state_bytes_moved = 0
         #: per form of call, over the device calls: how many; their real lanes
@@ -1119,25 +1088,26 @@ class LLMEngine:
             for b in self.lane_buckets
             for tc in [1] + self.prefill_token_buckets
         }
-        counted = self.pool.split_states(
-            outputs[self.lane_buckets[0], 1][2 + len(self.pool.arenas):], 1)[2]
+        counted = self.pool.split_outputs(outputs[self.lane_buckets[0], 1][2:])[2]
         self._home_width += sum(math.prod(c.shape) for c in counted)
         #: the bytes ``extend`` hands back for (lanes, tokens), for ``_fits``
-        self._output_bytes = {
-            shape: sum(math.prod(o.shape) * o.dtype.itemsize for o in outs)
-            for shape, outs in outputs.items()
-        }
+        #: (the state arenas it returns are the ones it was handed: no more bytes)
+        self._output_bytes = {}
+        for shape, (logits, hidden, *rest) in outputs.items():
+            rows, _, counts = self.pool.split_outputs(rest)
+            self._output_bytes[shape] = sum(
+                math.prod(o.shape) * o.dtype.itemsize for o in (logits, hidden, *rows, *counts))
         self.pool.warm(outputs, self.cache_buckets)
 
-    def _extend_args(self, make, b: int, cap: int):
+    def _extend_args(self, make, b: int, cap: int, states=None):
         """The arrays ``_extend_call`` takes for ``b`` lanes and a cache of
-        ``cap``, each made by ``make(shape, dtype)``."""
+        ``cap``, each made by ``make(shape, dtype)``; for the pool's state arenas
+        ``states`` where given (a call that runs is handed the arenas)."""
         caches = (
             make((self.pool.layers, b, cap) + tuple(each), self.pool.dtype)
             for each in self.cfg.cache_arrays)
-        states = (
-            make((layers, b) + tuple(shape), dtype)
-            for layers, shape, dtype in self.pool.state_arrays)
+        if states is None:
+            states = (make(s.shape, s.dtype) for s in self.pool.states)
         return (
             self._params, make((b, self._operand_width), np.int32),
             make((self._home_width,), np.int32), *caches, *states)
@@ -1158,8 +1128,9 @@ class LLMEngine:
 
     def warm(self) -> Dict[str, Any]:
         """Run ``extend`` once in every shape of :meth:`extend_shapes`, as a
-        step calls it, on zeros made on the device, so that no request meets
-        a compile. Returns how many ``shapes``, the seconds it took
+        step calls it, on zeros made on the device (and the pool's own state
+        arenas, of which zeros name slot 0 alone), so that no request meets a
+        compile. Returns how many ``shapes``, the seconds it took
         (``warm_s``) and, where the compiler says, the bytes of the largest one
         (``compiled``), whose temporaries ``_fits`` then counts for any call:
         compiling every shape a second time to ask each would double this."""
@@ -1169,8 +1140,9 @@ class LLMEngine:
         t0 = time.perf_counter()
         shapes = self.extend_shapes()
         for b, tc, cap in shapes:
-            jax.block_until_ready(self._extend_call(
-                *self._extend_args(jnp.zeros, b, cap), tc=tc))
+            _, _, *rest = jax.block_until_ready(self._extend_call(
+                *self._extend_args(jnp.zeros, b, cap, self.pool.states), tc=tc))
+            self.pool.states = self.pool.split_outputs(rest)[1]
         warm_s = time.perf_counter() - t0
         b, tc, cap = largest = max(shapes, key=lambda s: (s[0] * s[2], s[1]))
         memory = self._extend_call.lower(
@@ -1604,9 +1576,6 @@ class LLMEngine:
             # slots past a lane's frontier hold what the pool holds there:
             # zeros or finite model output, which extend's mask weighs 0
             caches = self.pool.gather(operands, t_cap // bs)
-            if self._stateful:
-                caches += self.pool.gather_states(operands)
-                self.state_bytes_moved += b * self.pool.state_bytes
             if self._count_gathered is not None:
                 for name, n in self._count_gathered(b, t_cap).items():
                     self.counted[name] += n
@@ -1622,12 +1591,13 @@ class LLMEngine:
             cache_tokens=cached, cache_slots=b * t_cap)
         with self._phase(
                 "dispatch", call=seq, form=form, ahead=int(flight is not None), **what):
+            # the state arenas go in donated, and the ones that come back (each
+            # lane's slot advanced, a kept state in its own) take their place
             logits, hidden, *rest = self._extend_call(
                 self._params, operands,
-                self._no_home if flight is None else flight.home, *caches, tc=tc)
-            held = len(self.pool.arenas)
-            news, (new_states, kept, counted) = (
-                rest[:held], self.pool.split_states(rest[held:], tc))
+                self._no_home if flight is None else flight.home, *caches,
+                *self.pool.states, tc=tc)
+            news, self.pool.states, counted = self.pool.split_outputs(rest)
             del caches, rest        # the caches are freed when extend has run
             counts = self.calls[form]
             counts["n"] += 1
@@ -1636,12 +1606,6 @@ class LLMEngine:
                 counts[key] += n
             self.calls_ahead += flight is not None
         with self._phase("kv_scatter"):
-            if self._stateful:
-                with self._phase(
-                        "state_snapshot", kept=sum(st.snapshot is not None for st in states)):
-                    self.pool.scatter_states(new_states, kept, operands)
-                    self.state_bytes_moved += b * (1 + bool(kept)) * self.pool.state_bytes
-                del new_states, kept
             home, picked = self.pool.page_back(
                 news, operands, (logits, hidden), counted, self.lane_buckets[-1])
             del logits, hidden, news, operands
@@ -1693,9 +1657,7 @@ class LLMEngine:
         if not self._counts_bytes:
             return True
         memory = self._device.memory_stats()
-        need = (
-            self.pool.cache_bytes(b * t_cap) + b * self.pool.state_bytes
-            + self._output_bytes[b, tc] + self._temp_bytes)
+        need = self.pool.cache_bytes(b * t_cap) + self._output_bytes[b, tc] + self._temp_bytes
         free = memory["bytes_limit"] - memory["bytes_in_use"]
         return need <= min(free, memory.get("largest_free_block_bytes", free))
 

@@ -603,85 +603,99 @@ def _granite_whole():
     return granitemoehybrid.GraniteMoeHybridConfig(), config
 
 
+#: one sequence's state, all 36 Mamba layers: 64 x 64 x 128 float32 and 3 x 4352 bfloat16
+GRANITE_STATE_BYTES = 36 * (64 * 64 * 128 * 4 + 3 * 4352 * 2)
+
+
+def _granite_arenas(shaped, cfg, slots):
+    return tuple(
+        shaped((layers, slots) + tuple(shape), dtype) for layers, shape, dtype in cfg.state_arrays)
+
+
 @pytest.mark.parametrize("form", ["decode", "prefill"])
 def test_granite_hybrid_extend_compiles_at_its_largest_shapes(shaped, form, built_for_tpu):
     """The whole published model (6.38 GB of weights) over the largest cache
-    bucket: a decode call of eight lanes over their gathered states (0.61 GB,
-    donated: the new states take their place), a prefill chunk through the
-    chunked recurrence and, in the four attention layers, the attention kernel.
-    No layer's weights are copied for the scan and no lane's states stacked
-    (temporaries of 1.5 and 2.9 GB when they were); it holds the memory the
-    configuration's file states and fits beside the pool, the slots and a second
-    call's caches and states."""
+    bucket, on the pool's state arenas themselves (49 slots of 76.4 MB: 3.75
+    GB, donated): a decode call of eight lanes, whose recurrence is the kernel
+    ``ssm_step`` straight under ``extend.ssm.scan`` (a lane's state fetched from
+    its slot and written back there), a prefill chunk through the chunked
+    recurrence and, in the four attention layers, the attention kernel. The
+    arenas are aliased, all of them, and the program holds no temporary the size
+    of one lane's state a lane, let alone an arena's; no layer's weights are
+    copied for the scan (1.5 GB of temporaries when they were). It fits beside
+    the pool's blocks and a second call's caches. (The byte counts are this
+    test's own: the configuration file's ``compiled_bytes_per_device`` dates
+    from the store that copied, and is the benchmark's to bring up to date.)"""
     built_for_tpu(True)
     cfg, config = _granite_whole()
-    engine, stated = config["engine"], config["compiled_bytes_per_device"]
-    cap, lanes = engine["cache_buckets"][-1], engine["lane_buckets"][-1]
+    engine = config["engine"]
+    cap, lanes, slots = engine["cache_buckets"][-1], engine["lane_buckets"][-1], engine["state_slots"]
     b, tc = (lanes, 1) if form == "decode" else (1, engine["prefill_token_buckets"][-1])
-    assert stated[form]["shape"] == [b, tc, cap]
     params = jax.tree.map(
         lambda x: shaped(x.shape, x.dtype), jax.eval_shape(lambda: cfg.init_params(0)))
     caches = [
         shaped((cfg.cache_layers, b, cap) + tuple(each), cfg.dtype) for each in cfg.cache_arrays]
-    states = [shaped((layers, b) + tuple(shape), dtype) for layers, shape, dtype in cfg.state_arrays]
+    arenas = _granite_arenas(shaped, cfg, slots)
     operands = shaped(
         (b, llm._operand_width(
             engine["prefill_token_buckets"][-1], cap // engine["block_size"], True)), jnp.int32)
-    compiled = llm._operand_extend(cfg.make_extend_fn(), len(caches), len(states)).lower(
-        params, operands, shaped((lanes + len(cfg.counters),), jnp.int32), *caches, *states, tc=tc
+    compiled = llm._operand_extend(cfg.make_extend_fn(), len(caches), len(arenas)).lower(
+        params, operands, shaped((lanes + len(cfg.counters),), jnp.int32), *caches, *arenas, tc=tc
     ).compile()
     text = compiled.as_text()
     kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     if form == "prefill":
         assert kernels and all("/extend.attention/masked_attention/" in k for k in kernels)
     else:
-        assert not kernels
+        # nine Mamba layers a period, in the one scan body
+        assert len(kernels) == 9 and all(
+            "/extend.ssm.scan/jit(ssm_step_slots)/ssm_step/" in k for k in kernels)
     memory = compiled.memory_analysis()
-    state_bytes = sum(
-        layers * math.prod(shape) * jnp.dtype(dtype).itemsize
-        for layers, shape, dtype in cfg.state_arrays)
-    assert state_bytes == 76_437_504
-    weights = memory.argument_size_in_bytes - b * (cap * 8192 + state_bytes + 2**17)
+    assert GRANITE_STATE_BYTES == 76_437_504
+    # (the compiler pads the convolution's three rows: 0.14 % more than the values)
+    arena_bytes = slots * GRANITE_STATE_BYTES
+    assert 0 <= memory.alias_size_in_bytes - arena_bytes < slots * 2**17
+    weights = memory.argument_size_in_bytes - memory.alias_size_in_bytes - b * (cap * 8192 + 2**17)
     assert 6.38e9 < weights < 6.39e9
-    assert memory.argument_size_in_bytes == stated[form]["argument"]
-    assert memory.temp_size_in_bytes <= stated[form]["temp"] * 1.05 < 0.2e9
-    # the lanes' new states are written over the ones handed in
-    assert memory.alias_size_in_bytes >= b * state_bytes
-    resident = engine["num_blocks"] * engine["block_size"] * 8192 + (
-        engine["state_slots"] * state_bytes)
-    assert _device_bytes(compiled) + resident + lanes * (cap * 8192 + state_bytes) < HBM_BYTES
+    # 82 MB and 9 MB; the lanes' convolution inputs, 0.9 MB a lane, are the
+    # states' only part in them
+    assert memory.temp_size_in_bytes < {"decode": 0.09e9, "prefill": 0.02e9}[form]
+    assert memory.temp_size_in_bytes < b * GRANITE_STATE_BYTES
+    resident = engine["num_blocks"] * engine["block_size"] * 8192
+    assert _device_bytes(compiled) + resident + lanes * cap * 8192 < HBM_BYTES
 
 
-def test_state_slots_are_read_and_written_without_a_whole_arena_temporary(shaped):
+def test_state_slots_are_read_and_written_without_a_whole_arena_temporary(shaped, built_for_tpu):
     """The state arenas of the granite configuration (49 slots of 76.4 MB:
-    3.75 GB) and the three programs that touch them: the gather's output is the
-    lanes' states and nothing more, the scatter and the copy alias the arenas,
-    and none holds a temporary of any size."""
+    3.75 GB) and the two programs that touch them: the copy (a prefix hit's
+    restore) aliases the arenas and holds no temporary of any size; a decode
+    ``extend`` of one lane aliases them and holds less than a lane's state."""
+    built_for_tpu(True)
     cfg, config = _granite_whole()
     engine = config["engine"]
-    slots, lanes = engine["state_slots"], engine["lane_buckets"][-1]
-    arenas = tuple(
-        shaped((layers, slots) + tuple(shape), dtype) for layers, shape, dtype in cfg.state_arrays)
-    arena_bytes = slots * 76_437_504
-    programs = llm._state_programs()
-    width = llm._operand_width(
-        engine["prefill_chunk"], engine["cache_buckets"][-1] // engine["block_size"], True)
-    for b in (1, lanes):
-        operands = shaped((b, width), jnp.int32)
-        news = tuple(
-            shaped((layers, b) + tuple(shape), dtype) for layers, shape, dtype in cfg.state_arrays)
-        memory = programs.gather.lower(arenas, operands).compile().memory_analysis()
-        # (the compiler pads the convolution's three rows: 0.14 % more than the values)
-        assert 0 <= memory.output_size_in_bytes - b * 76_437_504 < b * 2**17
-        assert memory.temp_size_in_bytes < 2**20, b
-        memory = programs.scatter.lower(
-            arenas, news, news if b == 1 else (), operands).compile().memory_analysis()
-        assert 0 <= memory.alias_size_in_bytes - arena_bytes < slots * 2**17, b
-        assert memory.temp_size_in_bytes < 2**20, b
-    copy = programs.copy.lower(
+    slots = engine["state_slots"]
+    arenas = _granite_arenas(shaped, cfg, slots)
+    arena_bytes = slots * GRANITE_STATE_BYTES
+    copy = llm._state_programs().copy.lower(
         arenas, shaped((), jnp.int32), shaped((), jnp.int32)).compile().memory_analysis()
     assert 0 <= copy.alias_size_in_bytes - arena_bytes < slots * 2**17
     assert copy.temp_size_in_bytes < 2**20
+    cap = engine["cache_buckets"][0]
+    params = jax.tree.map(
+        lambda x: shaped(x.shape, x.dtype), jax.eval_shape(lambda: cfg.init_params(0)))
+    caches = [
+        shaped((cfg.cache_layers, 1, cap) + tuple(each), cfg.dtype) for each in cfg.cache_arrays]
+    operands = shaped(
+        (1, llm._operand_width(
+            engine["prefill_chunk"], engine["cache_buckets"][-1] // engine["block_size"], True)),
+        jnp.int32)
+    lanes = engine["lane_buckets"][-1]
+    memory = llm._operand_extend(cfg.make_extend_fn(), len(caches), len(arenas)).lower(
+        params, operands, shaped((lanes + len(cfg.counters),), jnp.int32), *caches, *arenas, tc=1
+    ).compile().memory_analysis()
+    assert 0 <= memory.alias_size_in_bytes - arena_bytes < slots * 2**17
+    # 64 MB at one lane and 72 at eight: none of it a lane's state
+    assert memory.temp_size_in_bytes < GRANITE_STATE_BYTES
 
 
 @pytest.mark.parametrize(

@@ -1,11 +1,16 @@
 """``models/granitemoehybrid.py`` and the engine's state store on the CPU at a
-tiny size: the chunked recurrence against the token-by-token one, a prompt in
+tiny size: the chunked recurrence against the token-by-token one, the decode
+kernel (interpreted) against ``ssm_step``, ``extend`` on the state arenas where
+the pool keeps them (scrambled slots against slots in order, bit for bit; padded
+lanes; a kept state in the slot it is told), a prompt in
 chunks and decode through ``LLMEngine`` against the plain reference's full
 forward pass (logits), state carried from chunk to chunk, a snapshot taken in
 the middle of a chunk and restored, lanes that join and leave, a lane's state
 behind its token with a call in flight, what is left after a drain and after an
 eviction, a pool too small for one more slot, and the configuration's own
 arithmetic. float32 throughout."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -87,9 +92,18 @@ def _empty_engine(eng):
     assert eng.pool.in_use() == 0 and eng.pool.slots_in_use() == 0
 
 
-def _states(lanes):
+def _arenas(slots, seed=None):
+    """The pool's state arenas ``[layers, slots, ...]``: zeros, or drawn."""
+    rng = np.random.default_rng(seed)
     return tuple(
-        jnp.zeros((layers, lanes) + shape, dtype) for layers, shape, dtype in CFG.state_arrays)
+        jnp.asarray(
+            rng.normal(size=(layers, slots) + shape) if seed is not None
+            else np.zeros((layers, slots) + shape), dtype)
+        for layers, shape, dtype in CFG.state_arrays)
+
+
+def _ints(*values):
+    return jnp.asarray(values, jnp.int32)
 
 
 def _caches(lanes, cap):
@@ -137,22 +151,159 @@ def test_the_chunked_recurrence_is_the_token_by_token_one(lanes):
     np.testing.assert_array_equal(np.asarray(between[3][0]), np.asarray(last[0]))
 
 
+@pytest.mark.parametrize("heads", [8, 2])
+def test_the_decode_kernel_is_ssm_step_on_the_slots_it_is_given(heads):
+    """Interpreted: four lanes of a layer of an arena of seven slots, one of
+    them padding, one fresh: ``y`` and the lanes' slots are ``ssm_step``'s, and
+    every other slot of every layer is bit for bit what it was."""
+    rng = np.random.default_rng(heads)
+    layers, slots, all_heads, p, n = 3, 7, 8, 16, 128
+    arena = jnp.asarray(rng.normal(size=(layers, slots, all_heads, p, n)), jnp.float32)
+    at, where = 1, _ints(5, 2, 0, 6)
+    real, fresh = np.array([1, 1, 0, 1], bool), np.array([0, 1, 1, 0], bool)
+    x = jnp.asarray(rng.normal(size=(4, all_heads, p)), jnp.float32)
+    dt = jnp.asarray(rng.uniform(0.01, 0.5, size=(4, all_heads)) * real[:, None], jnp.float32)
+    a = -jnp.asarray(rng.uniform(1, 16, size=(all_heads,)), jnp.float32)
+    b, c = (jnp.asarray(rng.normal(size=(4, n)), jnp.float32) for _ in range(2))
+    y, after = hybrid.ssm_step_slots(
+        arena, jnp.int32(at), where, jnp.asarray(real), jnp.asarray(fresh), x, dt, a, b, c,
+        heads=heads, interpret=True)
+    want_y, want = hybrid.ssm_step(
+        jnp.where(fresh[:, None, None, None], 0.0, arena[at, where]), x, dt, a, b, c)
+    lanes = np.flatnonzero(real)
+    np.testing.assert_allclose(y[lanes], want_y[lanes], rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(after[at, where[lanes]], want[lanes], rtol=1e-6, atol=1e-6)
+    assert not np.asarray(y[2]).any()
+    untouched = np.ones((layers, slots), bool)
+    untouched[at, np.asarray(where)[lanes]] = False
+    np.testing.assert_array_equal(np.asarray(after)[untouched], np.asarray(arena)[untouched])
+
+
+def _in_place(program, extend, tokens, lengths, slots, snap_at, snap_slots, arenas, seed=3):
+    """``extend`` over drawn caches on ``arenas`` with the lanes in ``slots``."""
+    rng = np.random.default_rng(seed)
+    caches = tuple(
+        jnp.asarray(rng.normal(size=c.shape), c.dtype) for c in _caches(len(slots), 64))
+    return extend(
+        program, jnp.asarray(tokens, jnp.int32), _ints(*lengths), *caches, *arenas,
+        _ints(*slots), _ints(*snap_at), _ints(*snap_slots))
+
+
+def _moved(arenas, pairs, slots=9):
+    """Arenas of zeros but for slot ``dst`` holding ``arenas``' ``src``."""
+    src, dst = (list(x) for x in zip(*pairs))
+    return tuple(jnp.zeros_like(a[:, :slots]).at[:, dst].set(a[:, src]) for a in arenas)
+
+
+FORMS = {
+    # three lanes and one of padding: a decode call, and a chunk that keeps the
+    # states 8 and 16 tokens in of its first two lanes
+    "decode": dict(
+        tokens=[[11], [12], [13], [-1]], lengths=(5, 9, 3, 0), snap_at=(0,) * 4,
+        snap_slots=(0,) * 4),
+    "chunk": dict(
+        tokens=[_prompt(1, 16), _prompt(2, 16), _prompt(3, 11) + [-1] * 5, [-1] * 16],
+        lengths=(8, 0, 16, 0), snap_at=(8, 16, 0, 0), snap_slots=(4, 8, 0, 0)),
+}
+
+
+@pytest.mark.parametrize("form", FORMS)
+def test_lanes_in_scrambled_slots_are_bit_for_bit_lanes_in_order(program, form):
+    """What the parent made of gather -> ``extend`` -> scatter: the lanes'
+    states in slots 7, 2, 5 of nine give the bits they give in slots 1, 2, 3,
+    as do the kept states wherever they are told to go, and no slot that no lane
+    names is written."""
+    call = FORMS[form]
+    extend = CFG.make_extend_fn()
+    drawn = _arenas(9, seed=4)
+    scrambled, kept = (7, 2, 5, 0), call["snap_slots"]
+    out = _in_place(program, extend, **{**call, "slots": scrambled}, arenas=drawn)
+    in_order = _moved(drawn, zip(scrambled[:3], (1, 2, 3)))
+    moved_to = tuple({4: 5, 8: 6, 0: 0}[k] for k in kept)
+    want = _in_place(
+        program, extend, **{**call, "slots": (1, 2, 3, 0), "snap_slots": moved_to},
+        arenas=in_order)
+    for got, expected in zip(out[:4], want[:4]):          # logits, hidden, K and V rows
+        np.testing.assert_array_equal(np.asarray(got)[:3], np.asarray(expected)[:3])
+    written = {0, *scrambled, *kept}
+    for got, expected, before in zip(out[4:6], want[4:6], drawn):
+        got, expected, before = (np.asarray(x) for x in (got, expected, before))
+        np.testing.assert_array_equal(got[:, scrambled[:3]], expected[:, [1, 2, 3]])
+        for mine, theirs in zip(kept, moved_to):
+            if mine:
+                np.testing.assert_array_equal(got[:, mine], expected[:, theirs])
+                assert np.abs(got[:, mine] - before[:, mine]).max() > 1e-3
+        rest = [s for s in range(9) if s not in written]
+        np.testing.assert_array_equal(got[:, rest], before[:, rest])
+        assert np.abs(got[:, scrambled[:3]] - before[:, scrambled[:3]]).max() > 1e-3
+    assert list(np.asarray(out[-1])) == list(np.asarray(want[-1]))
+
+
+@pytest.mark.parametrize("path", ["xla", "kernel"])
+def test_padded_lanes_leave_every_slot_but_slot_0_untouched(program, path, built_for_tpu,
+                                                            monkeypatch):
+    """One real lane in the four-lane bucket, through ``extend``'s decode form
+    both ways (the chip's kernel, interpreted): slot 6 moves, slot 0 is
+    nobody's, the seven others hold their bits; and both ways agree."""
+    if path == "kernel":
+        monkeypatch.setattr(
+            hybrid, "ssm_step_slots", functools.partial(hybrid.ssm_step_slots, interpret=True))
+    built_for_tpu(path == "kernel")
+    drawn = _arenas(9, seed=6)
+    call = dict(
+        tokens=[[21], [-1], [-1], [-1]], lengths=(5, 0, 0, 0), slots=(6, 0, 0, 0),
+        snap_at=(0,) * 4, snap_slots=(0,) * 4)
+    out = _in_place(program, CFG.make_extend_fn(), **call, arenas=drawn)
+    for got, before in zip(out[4:6], drawn):
+        got, before = np.asarray(got), np.asarray(before)
+        rest = [s for s in range(1, 9) if s != 6]
+        np.testing.assert_array_equal(got[:, rest], before[:, rest])
+        assert np.abs(got[:, 6] - before[:, 6]).max() > 1e-3
+    built_for_tpu(False)
+    plain = _in_place(program, CFG.make_extend_fn(), **call, arenas=drawn)
+    np.testing.assert_allclose(out[0][0], plain[0][0], rtol=1e-5, atol=1e-5)
+    for got, expected in zip(out[4:6], plain[4:6]):
+        np.testing.assert_allclose(got[:, 6], expected[:, 6], rtol=1e-5, atol=1e-6)
+
+
+def test_a_kept_state_lands_in_its_slot_and_a_copy_of_it_continues_bitwise(program):
+    """A chunk of 16 tokens keeps the state 8 tokens in, in slot 3; copied to
+    slot 2 (what a prefix hit does) and fed the other 8 tokens over the first 8's
+    K and V, it ends in the bits the whole chunk left in slot 1."""
+    extend = CFG.make_extend_fn()
+    tokens = np.asarray([_prompt(9, 16)], np.int32)
+    logits, _, k, v, *whole, _ = extend(
+        program, jnp.asarray(tokens), _ints(0), *_caches(1, 64), *_arenas(5, seed=8),
+        _ints(1), _ints(8), _ints(3))
+    whole = [np.asarray(a) for a in whole]            # the copy donates its arenas
+    copied = llm._state_programs().copy(
+        tuple(map(jnp.asarray, whole)), np.int32(3), np.int32(2))
+    caches = tuple(c.at[:, :, :8].set(rows[:, :, :8]) for c, rows in zip(_caches(1, 64), (k, v)))
+    rest = np.concatenate([tokens[:, 8:], np.full((1, 8), -1, np.int32)], 1)
+    again, _, _, _, *after, _ = extend(
+        program, jnp.asarray(rest), _ints(8), *caches, *copied, _ints(2), _ints(0), _ints(0))
+    for got, want in zip(after, whole):
+        np.testing.assert_array_equal(np.asarray(got[:, 2]), np.asarray(want[:, 1]))
+        np.testing.assert_array_equal(np.asarray(got[:, 3]), np.asarray(want[:, 3]))
+    np.testing.assert_allclose(again[0, :8], logits[0, 8:], rtol=2e-5, atol=2e-5)
+
+
 def test_a_padded_token_changes_no_state_and_a_fresh_lane_starts_from_zeros(program):
     extend = CFG.make_extend_fn()
     tokens = jnp.asarray([_prompt(1, 16)], jnp.int32)
-    full = extend(
-        program, tokens, jnp.zeros((1,), jnp.int32), *_caches(1, 64), *_states(1),
-        jnp.asarray([8], jnp.int32))
-    # 11 real tokens in the bucket of 16, from a slot full of rubbish
-    rubbish = tuple(jnp.full_like(s, 3.0) for s in _states(1))
+    where = (_ints(1), _ints(8), _ints(2))      # the lane's slot; keep the state 8 tokens in, in slot 2
+    full = extend(program, tokens, _ints(0), *_caches(1, 64), *_arenas(4), *where)
+    # 11 real tokens in the bucket of 16, from slots full of rubbish
+    rubbish = tuple(jnp.full_like(s, 3.0) for s in _arenas(4))
     cut = extend(
-        program, tokens.at[:, 11:].set(-1), jnp.zeros((1,), jnp.int32), *_caches(1, 64),
-        *rubbish, jnp.asarray([8], jnp.int32))
+        program, tokens.at[:, 11:].set(-1), _ints(0), *_caches(1, 64), *rubbish, *where)
     np.testing.assert_allclose(cut[0][:, :11], full[0][:, :11], rtol=1e-5, atol=1e-6)
     # the state after 8 tokens is the same either way; after the last real one it is not
-    for kept_cut, kept_full in zip(cut[6:8], full[6:8]):
-        np.testing.assert_array_equal(np.asarray(kept_cut), np.asarray(kept_full))
-    assert np.abs(np.asarray(cut[4]) - np.asarray(full[4])).max() > 1e-4
+    for of_cut, of_full in zip(cut[4:6], full[4:6]):
+        np.testing.assert_array_equal(np.asarray(of_cut[:, 2]), np.asarray(of_full[:, 2]))
+        # and the slot nobody named holds what it held
+        assert (np.asarray(of_cut[:, 3]) == 3.0).all() and not np.asarray(of_full[:, 3]).any()
+    assert np.abs(np.asarray(cut[4][:, 1]) - np.asarray(full[4][:, 1])).max() > 1e-4
     assert dict(zip(CFG.counters, np.asarray(cut[-1]).tolist())) == {
         "ssm_tokens": 11 * CFG.ssm_layers, "ssm_state_passes": CFG.ssm_layers}
 
@@ -177,8 +328,7 @@ def test_a_prompt_over_three_chunks_carries_its_state(program, engine):
     extend = CFG.make_extend_fn()
     tokens = jnp.asarray([_prompt(8, 45) + [-1] * 3], jnp.int32)
     logits, *_ = extend(
-        program, tokens, jnp.zeros((1,), jnp.int32), *_caches(1, 64), *_states(1),
-        jnp.zeros((1,), jnp.int32))
+        program, tokens, _ints(0), *_caches(1, 64), *_arenas(2), _ints(1), _ints(0), _ints(0))
     np.testing.assert_allclose(out["logits"][0], logits[0, 44], rtol=2e-4, atol=2e-4)
 
 
@@ -257,7 +407,7 @@ def test_after_a_drain_only_snapshots_hold_slots_and_an_eviction_frees_one(engin
     stats = engine.stats()
     assert stats["state_slots_in_use"] == stats["state_snapshots"] == 3
     assert stats["kv_blocks_in_use"] == stats["prefix_cached_blocks"] == 2 + 3 + 4
-    assert stats["state_bytes_moved"] > 0 and stats["ssm_state_passes"] > 0
+    assert stats["ssm_state_passes"] > 0
     with engine.pool._lock:
         assert engine.prefix._evict_snapshot()
     stats = engine.stats()
@@ -291,6 +441,44 @@ def test_snapshots_make_room_for_sequences_and_a_pool_without_room_sheds(program
     assert stats["kv_blocks_in_use"] == stats["prefix_cached_blocks"]
     # the first four prompts' snapshots went to the sequences
     assert _served(eng, 80, 20, 2)["prefix_cached_tokens"] == 0
+
+
+def test_a_lane_that_ends_with_its_next_call_in_flight_leaves_its_neighbours_alone(engine):
+    """Three decode together; the first meets its ``eos_token`` while the call
+    behind is in flight, which advances its slot once more: by then the slot is
+    free, and the two beside it read what they would have read alone. A fourth
+    that takes the freed slot starts from zeros."""
+    _empty_engine(engine)
+    asks = [(100 + i, 9 + 7 * i, 14) for i in range(3)]
+    alone = [_served(engine, *ask) for ask in asks]
+    late = _served(engine, 104, 21, 5)
+    _empty_engine(engine)
+    eos = alone[0]["tokens"][4]
+    seqs = [_ask(*asks[0], eos_token=eos)] + [_ask(*ask) for ask in asks[1:]]
+    joins = _ask(104, 21, 5)
+    before = engine.stats()["calls_ahead"]
+    _drive(engine, seqs, lambda step: seqs.append(joins) if step == 9 else None)
+    assert engine.stats()["calls_ahead"] > before
+    assert seqs[0]._result["tokens"] == alone[0]["tokens"][:alone[0]["tokens"].index(eos) + 1]
+    for s, want in zip(seqs[1:], alone[1:] + [late]):
+        assert s._error is None and s._result["tokens"] == want["tokens"]
+        np.testing.assert_allclose(s._result["logits"], want["logits"], rtol=1e-5, atol=1e-6)
+
+
+def test_the_store_copies_a_state_for_a_prefix_hit_and_for_nothing_else(engine):
+    """``state_bytes_moved`` is the restores' alone: a request of three chunks
+    and six decode calls copies nothing; its repeat, one state."""
+    _empty_engine(engine)
+    start = engine.stats()
+    _served(engine, 110, 45, 6)
+    first = engine.stats()
+    assert first["state_bytes_moved"] == start["state_bytes_moved"]
+    assert first["ssm_state_passes"] - start["ssm_state_passes"] == CFG.ssm_layers * (3 + 5)
+    assert set(first["phase_s"]) == set(llm.PHASES) | {"state_restore"}
+    _served(engine, 110, 45, 6)
+    again = engine.stats()
+    assert again["state_bytes_moved"] - first["state_bytes_moved"] == engine.pool.state_bytes
+    assert again["traced"]["state_bytes_moved"] == 0     # no profiler session recorded a step
 
 
 def test_the_engine_asks_for_blocks_and_chunks_that_end_at_a_kept_state(program):
