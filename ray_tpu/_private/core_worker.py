@@ -57,6 +57,12 @@ logger = logging.getLogger(__name__)
 
 PLASMA_MARKER = b"\x00__IN_PLASMA__"
 
+#: the reply to a pushed task is its result, and a task runs as long as it runs
+#: (a train worker's ``run`` lasts the whole job): its slot carries no deadline
+#: (0 disables ``rpc_async_call_timeout_s``, which cut every task and actor call
+#: off at 120 s). A worker that dies closes its connection, which fails the slot.
+TASK_REPLY_TIMEOUT = 0
+
 
 # ---------------------------------------------------------------------------
 # public exception types
@@ -1537,7 +1543,7 @@ class CoreWorker:
             client.call_async(
                 "push_task_batch",
                 {"bid": bid, "tmpls": tmpls or None, "tasks": tasks},
-                on_done,
+                on_done, timeout=TASK_REPLY_TIMEOUT,
             )
 
     def _sweep_idle_leases(self, max_age: float = 1.0):
@@ -2090,10 +2096,11 @@ class CoreWorker:
                     tmpls: Dict[bytes, Dict[str, Any]] = {}
                     wire = self._wire_task(client, spec, tmpls)
                     client.call_async(
-                        "push_task", {"t": wire, "tmpls": tmpls or None}, on_done
+                        "push_task", {"t": wire, "tmpls": tmpls or None}, on_done,
+                        timeout=TASK_REPLY_TIMEOUT,
                     )
             else:
-                client.call_async("push_task", spec, on_done)
+                client.call_async("push_task", spec, on_done, timeout=TASK_REPLY_TIMEOUT)
             return
 
     def kill_actor(self, actor_id: ActorID, no_restart: bool = True):

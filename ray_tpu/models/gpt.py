@@ -30,7 +30,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -40,6 +40,26 @@ import numpy as np
 from ray_tpu.ops.attention import FLASH_RESIDUALS
 from ray_tpu.ops.ring import mesh_attention
 from ray_tpu.parallel import ring_dense
+
+
+class TrainModel(NamedTuple):
+    """What ``models/training.py`` asks of a configuration (``cfg.train_model(mesh)``),
+    as ``serve/llm.py`` asks one for ``make_extend_fn``, ``cache_arrays`` and
+    ``init_params``: the model as the train step needs it, whatever it is built of.
+
+    ``init(rng, tokens)`` gives the parameters (flax ``Partitioned`` boxes where the
+    model names logical axes, plain arrays where it does not); ``apply(params,
+    tokens)`` gives ``((hidden, head_kernel, head_bias), aux, scalars)``: what
+    :func:`blockwise_next_token_loss` takes, an auxiliary loss that enters the step's
+    loss ``aux_weight`` times, and the scalars the step reports beside its own
+    (a dict, empty where the model counts nothing). ``buffers`` names the top-level
+    entries of the parameters that are no parameters: they get no gradient and no
+    optimizer state, and a step hands them on as they are."""
+
+    init: Callable
+    apply: Callable
+    aux_weight: float = 0.0
+    buffers: Tuple[str, ...] = ()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -91,6 +111,24 @@ class GPTConfig:
         variables = GPT(self).init(
             jax.random.PRNGKey(seed), jnp.zeros((1, 8), jnp.int32))
         return unboxed_params(variables)
+
+    def train_model(self, mesh=None) -> TrainModel:
+        """The flax :class:`GPT` as the train step takes a model; with experts
+        (``MoeMlp``) the layers' load-balancing losses, sown into ``losses``,
+        are the auxiliary loss."""
+        model = GPT(self, return_hidden=True, mesh=mesh)
+
+        def init(rng, tokens):
+            return GPT(self).init(rng, tokens)["params"]
+
+        def apply(params, tokens):
+            if self.moe_num_experts > 0:
+                out, mut = model.apply({"params": params}, tokens, mutable=["losses"])
+                aux = sum(jnp.sum(v) for v in jax.tree.leaves(mut["losses"]))
+                return out, aux / self.num_layers, {}
+            return model.apply({"params": params}, tokens), jnp.zeros((), jnp.float32), {}
+
+        return TrainModel(init, apply, self.moe_aux_weight)
 
     def num_params(self) -> int:
         """Exact parameter count (for MFU math)."""

@@ -10,12 +10,13 @@ inserts the all-to-all, and a load-balancing auxiliary loss sown into the
 Expert weights carry the ("expert", "embed", "mlp") logical axes: ep shards
 the expert dim, tp can still shard the mlp dim inside each expert.
 
-Beside it, for serving (``models/cohere2_moe.py``): a **dropless** layer for
-one chip's share of an expert-parallel deployment (``models/kimi_k2.py`` too) or every expert on
-one chip (``models/keye_vl2.py``). :func:`sigmoid_top_k`, :func:`softmax_top_k` or
+Beside it, for serving (``models/cohere2_moe.py``, ``models/kimi_k2.py``,
+``models/keye_vl2.py``) and for the train step (``models/lfm2_moe.py``, under
+``jax.grad``): a **dropless** layer for one chip's share of an expert-parallel
+deployment, or every expert on one chip. :func:`sigmoid_top_k`, :func:`softmax_top_k` or
 :func:`sigmoid_bias_top_k` scores every routed expert, :func:`held_experts_ffn` is told which experts
 live here and computes their part of the result for the tokens routed to
-them: the token-expert pairs are sorted by expert and run through a grouped
+them (:func:`trained_experts_ffn` under ``jax.grad``): the token-expert pairs are sorted by expert and run through a grouped
 matmul (:func:`grouped_matmul`: one kernel that walks the groups and reads an
 expert's weights only if it has rows). No capacity, no dropped token; shapes
 are static from the worst case, in which every choice of every token is held
@@ -95,17 +96,36 @@ def sigmoid_bias_top_k(h: jax.Array, router: jax.Array, bias: jax.Array, k: int,
 GMM_TILING = (32, 4096, 512)
 
 
-def grouped_matmul(rows, w, group_sizes, interpret: bool = False):
+#: the tile of a train step's grouped matmuls and of their two transposes
+#: (``gmm``'s ``custom_vjp``: the input's gradient is a ``gmm`` against the
+#: transposed weights, the weights' a ``tgmm``, both with the forward's tile), at
+#: about a thousand rows an expert: row tiles of 512, an expert's whole
+#: contraction (its ``[k, 512]`` block of weights stays in VMEM from one row
+#: tile of the group to the next). Measured on a v5e, one layer of 32 held
+#: experts of LFM2's widths, 16,384 tokens, forward and backward (PERF.md, PR
+#: 43): 34.2 ms, (512, 1024, 1024) 33.9; on an earlier layout of the layer
+#: (256, 2048, 512) 0.4 ms more, row tiles of 128 or 1024 and column tiles of
+#: 256 3 to 5 ms more; 1024-wide tiles of both kinds run out of VMEM
+GMM_TRAIN_TILING = (512, 2048, 512)
+
+
+def grouped_matmul(rows, w, group_sizes, interpret: bool = False, tiling=GMM_TILING):
     """``rows`` [m, k], sorted by group, times ``w`` [groups, k, n]: row ``i``
     is multiplied with the matrix of its group, the first ``group_sizes[0]``
     rows with ``w[0]`` and so on; rows past the last group are undefined. On
     the TPU this is the megablox kernel (``jax.experimental``), which visits
-    only the row tiles of groups that have rows; elsewhere XLA's ragged dot."""
+    only the row tiles of groups that have rows; elsewhere XLA's ragged dot.
+
+    Differentiable: the kernel is a ``custom_vjp`` whose gradient of ``rows``
+    is a grouped matmul against the transposed weights, with the rows past the
+    last group **undefined again**, and whose gradient of ``w`` (``tgmm``) reads
+    the rows of a group alone. A caller that hands the gradient of ``rows`` on
+    masks those rows (:func:`trained_experts_ffn`)."""
     if not (backend.on_tpu() or interpret):
         return jax.lax.ragged_dot(rows, w, group_sizes)
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-    m, (tm, tk, tn) = rows.shape[0], GMM_TILING
+    m, (tm, tk, tn) = rows.shape[0], tiling
     padded = jnp.pad(rows, ((0, -m % tm), (0, 0)))
     out = gmm(
         padded, w, group_sizes, preferred_element_type=rows.dtype,
@@ -163,6 +183,91 @@ def held_experts_ffn(x, weights, experts, valid, wi, wo, offset: int = 0, layer=
     counters = jnp.stack([
         valid.sum(dtype=jnp.int32), counted.sum(dtype=jnp.int32),
         (counted > 0).sum(dtype=jnp.int32), counted.max(),
+    ])
+    return y, counters
+
+
+# The sort and the un-sort of a trained layer's pairs. ``held`` [k, n] marks the
+# pairs whose expert is held here, pair ``(c, t)`` being token ``t``'s choice ``c``;
+# ``order`` sorts the ``k n`` pairs by expert, those not held last, and ``place`` is
+# its inverse. Both are permutations, so their gradients are gathers, where
+# autodiff, which cannot know that an index hits every row once, scatters and adds
+# row by row. And both keep what the grouped matmul leaves undefined, the rows of
+# the pairs not held, from reaching a token: in the result and in the gradient.
+
+
+@jax.custom_vjp
+def _sorted_rows(x, held, order, place):
+    """The tokens ``x`` [n, d] as the rows of their pairs, sorted: [k n, d]."""
+    return x[order % x.shape[0]]
+
+
+def _sorted_rows_fwd(x, held, order, place):
+    return x[order % x.shape[0]], (held, place)
+
+
+def _sorted_rows_bwd(kept, g):
+    held, place = kept
+    g = jnp.where(held[:, :, None], g[place].reshape(held.shape + g.shape[1:]), 0)
+    return g.sum(0).astype(g.dtype), None, None, None
+
+
+_sorted_rows.defvjp(_sorted_rows_fwd, _sorted_rows_bwd)
+
+
+@jax.custom_vjp
+def _unsorted_rows(out, held, order, place):
+    """The sorted rows ``out`` [k n, d] back pair by pair, [k, n, d], zeros for
+    the pairs not held."""
+    return jnp.where(held[:, :, None], out[place].reshape(held.shape + out.shape[1:]), 0)
+
+
+def _unsorted_rows_fwd(out, held, order, place):
+    return _unsorted_rows(out, held, order, place), order
+
+
+def _unsorted_rows_bwd(order, g):
+    # a row of no group is read by neither of the grouped matmul's gradients
+    return g.reshape((-1,) + g.shape[2:])[order], None, None, None
+
+
+_unsorted_rows.defvjp(_unsorted_rows_fwd, _unsorted_rows_bwd)
+
+
+def trained_experts_ffn(x, weights, experts, wi, wo, offset: int = 0, tiling=GMM_TRAIN_TILING):
+    """:func:`held_experts_ffn` for a train step: the same part of the layer, the
+    same counters, every pair whose expert is held computed, and a gradient for
+    ``x``, ``weights`` (through which the router is trained), ``wi`` and ``wo``;
+    ``experts`` are integers and pass none. Every token is real.
+
+    What differs is what a backward pass and some thousand rows an expert ask
+    for. The grouped matmuls run with ``tiling``. The pairs are laid out
+    **choice by choice** (pair ``c n + t`` is token ``t``'s choice ``c``), so the
+    ``[k n, d]`` rows split into ``[k, n, d]`` without a copy (``[n, k, d]`` pads
+    its ``k`` to a whole tile of 8). The pairs of absent experts are sorted last
+    and lie in no group: the kernel leaves their rows undefined, in its result
+    and in the gradient of its rows alike; between the sort and the un-sort,
+    which mask them, a row meets only its own values."""
+    n, k = experts.shape
+    num_held, f = wo.shape[-3], wo.shape[-2]
+    local = experts.T - offset
+    held = (local >= 0) & (local < num_held)                      # [k, n]
+    key = jnp.where(held, local, num_held).reshape(k * n)
+    order = jnp.argsort(key, stable=True)
+    group_sizes = (
+        key[:, None] == jnp.arange(num_held, dtype=key.dtype)[None, :]
+    ).sum(0, dtype=jnp.int32)
+    place = jnp.zeros_like(order).at[order].set(jnp.arange(k * n, dtype=order.dtype))
+    rows = _sorted_rows(x, held, order, place)                    # [k n, d]
+    gate_up = grouped_matmul(rows, wi.astype(x.dtype), group_sizes, tiling=tiling)
+    act = jax.nn.silu(gate_up[:, :f]) * gate_up[:, f:]
+    out = grouped_matmul(act, wo.astype(x.dtype), group_sizes, tiling=tiling)
+    per_pair = _unsorted_rows(out, held, order, place)            # [k, n, d]
+    # one pass over the bfloat16 rows: the cast, the weight and the sum over the choices fuse
+    y = (weights.T[:, :, None] * per_pair.astype(jnp.float32)).sum(0)
+    counters = jnp.stack([
+        jnp.int32(n), group_sizes.sum(dtype=jnp.int32),
+        (group_sizes > 0).sum(dtype=jnp.int32), group_sizes.max(),
     ])
     return y, counters
 

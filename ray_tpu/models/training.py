@@ -56,16 +56,28 @@ def default_optimizer(
     )
 
 
+def _trained(params: Any, buffers: Tuple[str, ...]) -> Tuple[Any, Any]:
+    """``params`` without and with only the top-level entries a model names as
+    its buffers (``TrainModel.buffers``): what the optimizer sees, and what a
+    step hands on as it is."""
+    if not buffers:
+        return params, {}
+    return (
+        {k: v for k, v in params.items() if k not in buffers},
+        {k: params[k] for k in buffers},
+    )
+
+
 def abstract_state(
-    cfg: GPTConfig, optimizer: optax.GradientTransformation, sample_tokens: jax.ShapeDtypeStruct
+    cfg: Any, optimizer: optax.GradientTransformation, sample_tokens: jax.ShapeDtypeStruct
 ):
-    """Eval-shape the init to get the (boxed) abstract state without FLOPs."""
-    model = GPT(cfg)
+    """Eval-shape the init to get the (boxed) abstract state without FLOPs.
+    ``cfg`` is any configuration that answers ``train_model`` (``gpt.TrainModel``)."""
+    model = cfg.train_model()
 
     def _init(rng):
-        variables = model.init(rng, jnp.zeros(sample_tokens.shape, jnp.int32))
-        params = variables["params"]
-        opt_state = optimizer.init(nn.meta.unbox(params))
+        params = model.init(rng, jnp.zeros(sample_tokens.shape, jnp.int32))
+        opt_state = optimizer.init(nn.meta.unbox(_trained(params, model.buffers)[0]))
         return TrainState(step=jnp.zeros((), jnp.int32), params=params, opt_state=opt_state)
 
     return _init, jax.eval_shape(_init, jax.random.PRNGKey(0))
@@ -100,7 +112,7 @@ def state_shardings(
 
 
 def init_sharded_state(
-    cfg: GPTConfig,
+    cfg: Any,
     mesh: Mesh,
     optimizer: optax.GradientTransformation,
     rng: jax.Array,
@@ -127,7 +139,7 @@ def init_sharded_state(
 
 
 def make_train_step(
-    cfg: GPTConfig,
+    cfg: Any,
     optimizer: optax.GradientTransformation,
     mesh: Optional[Mesh] = None,
     rules: Optional[shd.Rules] = None,
@@ -136,52 +148,51 @@ def make_train_step(
 ) -> Callable:
     """Build `step(state, tokens) -> (state, metrics)`, jitted with shardings.
 
+    ``cfg`` is any configuration that answers ``train_model(mesh)``
+    (``gpt.TrainModel``): its model's forward up to the head, its auxiliary loss
+    and the scalars it counts, which the step reports beside ``loss``,
+    ``grad_norm`` and ``step``; what it names as buffers is handed on untouched.
+
     Its parts carry the scopes ``train.forward``, ``train.loss`` and
-    ``train.optimizer`` (the backward pass inherits the forward's): names in
-    the compiled program's metadata that a device trace can be grouped by."""
-    model = GPT(cfg, return_hidden=True, mesh=mesh)
+    ``train.optimizer`` (the backward pass inherits the forward's; a model may
+    name its layers' own inside ``train.forward``): names in the compiled
+    program's metadata that a device trace can be grouped by."""
+    model = cfg.train_model(mesh)
     active_rules = list(rules if rules is not None else shd.DEFAULT_RULES)
+    _apply = jax.named_scope("train.forward")(model.apply)
 
-    moe = cfg.moe_num_experts > 0
-
-    @jax.named_scope("train.forward")
-    def _apply(params, tokens):
-        """Run the model; with MoE also collect the per-layer aux losses
-        (sown into the 'losses' collection by MoeMlp)."""
-        if moe:
-            out, mut = model.apply(
-                {"params": params}, tokens, mutable=["losses"]
-            )
-            aux = sum(jnp.sum(v) for v in jax.tree.leaves(mut["losses"]))
-            return out, aux / cfg.num_layers
-        return model.apply({"params": params}, tokens), jnp.zeros((), jnp.float32)
-
-    def loss_fn(params, tokens):
+    def loss_fn(trained, buffers, tokens):
+        params = {**trained, **buffers} if model.buffers else trained
         if mesh is not None:
             # Install the logical-axis rule table so the model's
             # with_logical_constraint calls reach XLA (they are silent
             # no-ops when no rules are set).
             with nn.logical_axis_rules(active_rules):
-                (hidden, kernel, bias), aux = _apply(params, tokens)
+                (hidden, kernel, bias), aux, scalars = _apply(params, tokens)
                 # the head's kernel as the loss reads it: gathered over fsdp
                 # once a step and its gradient reduce-scattered once, where
                 # every chunk of the loss gathered it (twice, with the replay)
                 # and reduced its share of the gradient
                 kernel = nn.with_logical_constraint(kernel, ("act_embed", "vocab"), mesh=mesh)
         else:
-            (hidden, kernel, bias), aux = _apply(params, tokens)
+            (hidden, kernel, bias), aux, scalars = _apply(params, tokens)
         # Blockwise xent: never materializes the [b, t, vocab] logits.
         with jax.named_scope("train.loss"):
             loss = blockwise_next_token_loss(hidden, kernel, bias, tokens)
-        return loss + cfg.moe_aux_weight * aux
+        return loss + model.aux_weight * aux, scalars
 
     def step(state: TrainState, tokens: jax.Array):
-        loss, grads = jax.value_and_grad(loss_fn)(state.params, tokens)
+        trained, buffers = _trained(state.params, model.buffers)
+        (loss, scalars), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            trained, buffers, tokens)
         with jax.named_scope("train.optimizer"):
             updates, new_opt = optimizer.update(
-                grads, state.opt_state, state.params)
-            new_params = optax.apply_updates(state.params, updates)
+                grads, state.opt_state, trained)
+            new_params = optax.apply_updates(trained, updates)
+            if model.buffers:
+                new_params = {**new_params, **buffers}
         metrics = {
+            **scalars,
             "loss": loss,
             "grad_norm": optax.global_norm(grads),
             "step": state.step + 1,
@@ -207,15 +218,15 @@ def make_train_step(
     return jax.jit(step, donate_argnums=(0,) if donate else (), **kwargs)
 
 
-def make_eval_step(cfg: GPTConfig, mesh: Optional[Mesh] = None) -> Callable:
+def make_eval_step(cfg: Any, mesh: Optional[Mesh] = None) -> Callable:
     """Pass the training mesh so eval shards attention the same way (with
     sp>1, dense attention would all-gather full K/V and OOM at the context
     lengths the sp axis exists for)."""
-    model = GPT(cfg, return_hidden=True, mesh=mesh)
+    model = cfg.train_model(mesh)
 
     @jax.jit
     def eval_step(params, tokens):
-        hidden, kernel, bias = model.apply({"params": params}, tokens)
+        (hidden, kernel, bias), _, _ = model.apply(params, tokens)
         return blockwise_next_token_loss(hidden, kernel, bias, tokens)
 
     return eval_step

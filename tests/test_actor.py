@@ -129,3 +129,30 @@ def test_max_concurrency_parallel(ray_start_regular):
     ray_tpu.get([s.nap.remote(0.5) for _ in range(4)], timeout=30)
     elapsed = time.time() - t0
     assert elapsed < 1.6, f"calls did not overlap: {elapsed:.2f}s"
+
+
+def test_a_call_outlasts_the_async_rpc_deadline(ray_start_regular, monkeypatch):
+    """A pushed task's reply is its result, and a task runs as long as it runs: a
+    train worker's ``run`` lasts the whole job. The deadline that frees the
+    callback slot of a peer that hangs (``rpc_async_call_timeout_s``, 120 s) used
+    to cut every task and actor call off: ``ActorDiedError ... timed out
+    (reaped)`` for a job of two minutes. Shortened to 1 s here, it fails neither
+    an actor call nor a plain task of 3 s (the reaper ticks once a second)."""
+    from ray_tpu._private.config import GlobalConfig
+
+    monkeypatch.setitem(GlobalConfig._values, "rpc_async_call_timeout_s", 1.0)
+
+    @ray_tpu.remote
+    class Slow:
+        def nap(self, s):
+            time.sleep(s)
+            return s
+
+    @ray_tpu.remote
+    def nap(s):
+        time.sleep(s)
+        return s
+
+    slow = Slow.remote()
+    assert ray_tpu.get(slow.nap.remote(0.01), timeout=30) == 0.01     # the actor is up
+    assert ray_tpu.get([slow.nap.remote(3.0), nap.remote(3.0)], timeout=60) == [3.0, 3.0]

@@ -138,6 +138,49 @@ def test_one_chip_step_fits_the_chip_at_the_cells_own_size(v5e, built_for_tpu):
 _COLLECTIVE = re.compile(r"all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all")
 
 
+def test_lfm2_moe_share_train_step_compiles_and_copies_no_expert_stack(v5e, built_for_tpu):
+    """``lfm2-24b-a2b-train-1chip-fixed-batch`` as it runs: the configuration's
+    file (a dense layer and one period at the published widths, 32 of 64 experts
+    held, 1,375,254,912 parameters: 8.25 GB of bfloat16 weights and moments as
+    arguments), 4 x 4096 tokens. It fits the chip; the three flash kernels and
+    the grouped matmuls of four expert layers are in it (forward, the replay,
+    both gradients: 8 a layer); and no instruction copies, transposes or slices
+    out a layer's stack of experts (604 MB), as a scan over stacked layers or a
+    kernel that wants a whole operand would make it."""
+    import json
+
+    from benchmark.manifest import published_keys
+    from benchmark.models import lfm2_moe as architecture
+
+    built_for_tpu(True)
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "configs",
+            "lfm2-24b-a2b-train-ep2.json")) as f:
+        file = json.load(f)
+    cfg = architecture.program_config(published_keys(file))
+    batch = tuple(file["job"]["batch"])
+    mesh = MeshSpec().build(v5e[:1])
+    opt = default_optimizer(file["job"]["learning_rate"])
+    _, abstract = abstract_state(cfg, opt, jax.ShapeDtypeStruct(batch, jnp.int32))
+    shardings = nn.meta.unbox(state_shardings(mesh, abstract))
+    state = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        nn.meta.unbox(abstract), shardings,
+    )
+    tokens = jax.ShapeDtypeStruct(batch, jnp.int32, sharding=shd.batch_sharding(mesh))
+    step = make_train_step(cfg, opt, mesh, state_shardings_tree=shardings)
+    compiled = step.lower(state, tokens).compile()
+    text = compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > 6 * cfg.num_params()        # weights and two moments
+    assert _device_bytes(compiled) < HBM_BYTES
+    assert text.count('custom_call_target="tpu_custom_call"') == file["job"]["min_flash_kernels"] == 35
+    stack = r"(bf16|f32)\[(1,)?32,(2048,3072|1536,2048)\]"
+    moved = re.findall(
+        rf"= {stack}\S* (?:copy|transpose|dynamic-slice|dynamic-update-slice)\(", text)
+    assert not moved, moved[:3]
+
+
 def _computations(text):
     """The instructions of each computation of a compiled program, by name."""
     computations, lines = {}, None

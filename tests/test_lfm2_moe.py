@@ -1,0 +1,416 @@
+"""LFM2-24B-A2B's block on the train path at a small size, in float32 on the
+CPU, against the plain reference (``benchmark/reference/lfm2_moe_reference.py``,
+which knows nothing of ``models/lfm2_moe.py`` or ``models/moe.py``): loss and
+every parameter's gradient, one whole update, the convolution alone, the bias
+as a buffer, the share tied to the uncut layer, and nothing dropped.
+
+Tolerances: both sides compute in float32 at the CPU's full matmul precision and
+differ in the order of their sums alone (a sort and a ragged dot against a loop
+over experts with dense masks; a blockwise loss against full logits; a remat),
+so a loss agrees to a few float32 roundings (1e-6 of itself) and a gradient to
+1e-5 of its largest entry."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from benchmark.reference import lfm2_moe_reference as reference
+from ray_tpu.models import lfm2_moe, moe
+from ray_tpu.models.gpt import blockwise_next_token_loss
+from ray_tpu.models.training import default_optimizer, init_sharded_state, make_train_step
+from ray_tpu.parallel.mesh import MeshSpec
+
+BATCH = (2, 48)
+
+
+def model_keys(cfg):
+    """The reference's view of ``cfg``: the published keys it reads."""
+    return {
+        "hidden_size": cfg.embed_dim, "num_attention_heads": cfg.num_heads,
+        "num_key_value_heads": cfg.kv_heads, "norm_eps": cfg.norm_eps,
+        "rope_theta": cfg.rope_base, "num_experts_per_tok": cfg.experts_per_token,
+        "routed_scaling_factor": cfg.routed_scale, "router_experts": cfg.router_experts,
+        "expert_offset": cfg.expert_offset,
+    }
+
+
+def program_loss(cfg, params, tokens):
+    (hidden, kernel, bias), aux, counters = lfm2_moe.forward(cfg, params, tokens)
+    return blockwise_next_token_loss(hidden, kernel, bias, tokens) + aux, counters
+
+
+@pytest.fixture(scope="module")
+def nano():
+    cfg = lfm2_moe.lfm2_moe_nano()
+    params = jax.jit(lambda rng: lfm2_moe.init_params(cfg, rng))(jax.random.PRNGKey(7))
+    tokens = jax.random.randint(jax.random.PRNGKey(8), BATCH, 0, cfg.vocab_size)
+    return cfg, params, tokens
+
+
+def close(got, want, rel):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    assert np.abs(got - want).max() <= rel * max(np.abs(want).max(), 1e-30), (
+        np.abs(got - want).max(), np.abs(want).max())
+
+
+def as_reference_tree(cfg, tree):
+    """A tree laid out as the program's parameters, as the reference lays its own out."""
+    return reference.from_program_params({**tree, "expert_bias": jax.tree.map(
+        jnp.zeros_like, lfm2_moe.init_params(cfg, jax.random.PRNGKey(0))["expert_bias"])})
+
+
+def test_loss_and_every_gradient_are_the_references(nano):
+    cfg, params, tokens = nano
+    trained = {k: v for k, v in params.items() if k != "expert_bias"}
+    (loss, counters), grads = jax.value_and_grad(
+        lambda t: program_loss(cfg, {**t, "expert_bias": params["expert_bias"]}, tokens),
+        has_aux=True)(trained)
+    ref_params = reference.from_program_params(params)
+    want, ref_grads = jax.value_and_grad(reference.loss)(ref_params, tokens, model_keys(cfg))
+    assert float(loss) == pytest.approx(float(want), rel=2e-6)
+    # the reference's gradient of a layer's bias is all zeros too: it only chooses
+    got = as_reference_tree(cfg, grads)
+    for (path, g), w in zip(
+            jax.tree_util.tree_leaves_with_path((got["wte"], got["ln_f"], [p for p, _ in got["layers"]])),
+            jax.tree.leaves((ref_grads["wte"], ref_grads["ln_f"], [p for p, _ in ref_grads["layers"]]))):
+        assert np.abs(np.asarray(w)).max() > 0, path        # every parameter is trained
+        close(g, w, 1e-5)
+    assert all(not np.asarray(b).any() for _, b in ref_grads["layers"] if b is not None)
+    tokens_n = BATCH[0] * BATCH[1]
+    assert int(counters["moe_tokens"]) == 4 * tokens_n
+    assert 0 < int(counters["moe_assignments"]) < 4 * tokens_n * cfg.experts_per_token
+
+
+def test_the_convolution_is_three_shifted_products_forward_and_backward(nano):
+    cfg, params, _ = nano
+    p = params["first"][0]
+    r = jax.random.normal(jax.random.PRNGKey(1), (2, 17, cfg.embed_dim))
+
+    def shifted(p, r):
+        """Written out: v_t = w_0 u_(t-2) + w_1 u_(t-1) + w_2 u_t, zeros before the sequence."""
+        gate_in, gate_out, x = jnp.split(r @ p["in"], 3, -1)
+        u = gate_in * x
+        zero = jnp.zeros_like(u[:, :1])
+        u1 = jnp.concatenate([zero, u[:, :-1]], 1)
+        u2 = jnp.concatenate([zero, zero, u[:, :-2]], 1)
+        return (gate_out * (p["conv"][0] * u2 + p["conv"][1] * u1 + p["conv"][2] * u)) @ p["out"]
+
+    close(lfm2_moe.conv_mixer(cfg, p, r), shifted(p, r), 1e-6)
+    close(reference.conv_mixer(r, p), shifted(p, r), 1e-6)
+    up = jax.random.normal(jax.random.PRNGKey(2), r.shape)
+    got = jax.grad(lambda p, r: (lfm2_moe.conv_mixer(cfg, p, r) * up).sum(), (0, 1))(p, r)
+    want = jax.grad(lambda p, r: (shifted(p, r) * up).sum(), (0, 1))(p, r)
+    for name in ("in", "conv", "out"):
+        close(got[0][name], want[0][name], 1e-5)
+    close(got[1], want[1], 1e-5)
+    # causal: an output does not move with a later input
+    later = r.at[:, 9:].add(1.0)
+    np.testing.assert_array_equal(
+        np.asarray(lfm2_moe.conv_mixer(cfg, p, later)[:, :9]),
+        np.asarray(lfm2_moe.conv_mixer(cfg, p, r)[:, :9]))
+
+
+def test_one_update_is_optax_on_the_references_gradients(nano):
+    cfg, _, tokens = nano
+    mesh = MeshSpec().build(jax.devices()[:1])
+    opt = default_optimizer(1e-3)
+    state, shardings = init_sharded_state(cfg, mesh, opt, jax.random.PRNGKey(7), BATCH)
+    before = jax.tree.map(np.asarray, state.params)
+    step = make_train_step(cfg, opt, mesh, state_shardings_tree=shardings)
+    with mesh:
+        state, metrics = step(state, tokens)
+    # the bias is a buffer: no moment holds it, and the step hands it on bit for bit
+    moments = jax.tree_util.tree_leaves_with_path(state.opt_state)
+    assert moments and not any("expert_bias" in jax.tree_util.keystr(path) for path, _ in moments)
+    jax.tree.map(np.testing.assert_array_equal, before["expert_bias"],
+                 jax.tree.map(np.asarray, state.params["expert_bias"]))
+
+    ref_params = reference.from_program_params(before)
+    want_loss, ref_grads = jax.value_and_grad(reference.loss)(
+        ref_params, tokens, model_keys(cfg))
+    assert float(metrics["loss"]) == pytest.approx(float(want_loss), rel=2e-6)
+    trained = {k: v for k, v in ref_grads.items()}
+    trained["layers"] = [p for p, _ in ref_grads["layers"]]
+    weights = {**ref_params, "layers": [p for p, _ in ref_params["layers"]]}
+    assert float(metrics["grad_norm"]) == pytest.approx(float(optax.global_norm(trained)), rel=1e-5)
+    updates, _ = opt.update(trained, opt.init(weights), weights)
+    want = optax.apply_updates(weights, updates)
+    got = reference.from_program_params(jax.tree.map(np.asarray, state.params))
+    # Adam's first step moves every weight by about the learning rate, whatever
+    # its gradient's size, so a gradient's float32 roundings show at 1e-3 of a step
+    for g, w, old in zip(
+            jax.tree.leaves((got["wte"], got["ln_f"], [p for p, _ in got["layers"]])),
+            jax.tree.leaves((want["wte"], want["ln_f"], want["layers"])),
+            jax.tree.leaves((weights["wte"], weights["ln_f"], weights["layers"]))):
+        assert np.abs(np.asarray(w) - np.asarray(old)).max() > 5e-4       # it moved
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=2e-5)
+    assert {*moe.COUNTERS, "loss", "grad_norm", "step"} == set(metrics)
+    assert all(np.asarray(metrics[name]).dtype == np.int32 for name in moe.COUNTERS)
+
+
+def test_the_seeded_bias_moves_the_chosen_set_of_many_tokens(nano):
+    cfg, params, tokens = nano
+    keys = model_keys(cfg)
+    ref_params = reference.from_program_params(params)
+    p, bias = ref_params["layers"][1]
+    r = reference.rms_norm(ref_params["wte"][tokens], p["ln_2"], cfg.norm_eps)
+    _, with_bias = reference.route(r, p["router"], bias, keys)
+    _, without = reference.route(r, p["router"], bias, keys, wrong="no_bias")
+    moved = (np.sort(np.asarray(with_bias), -1) != np.sort(np.asarray(without), -1)).any(-1)
+    assert moved.mean() > 0.1, moved.mean()
+    # and the program's router makes the reference's choices, bias and all
+    weights, chosen = moe.sigmoid_bias_top_k(
+        r.reshape(-1, cfg.embed_dim), p["router"], bias, cfg.experts_per_token, cfg.routed_scale)
+    np.testing.assert_array_equal(np.asarray(chosen).reshape(with_bias.shape), np.asarray(with_bias))
+    # which a loss that ignores the bias does not reproduce
+    assert abs(float(reference.loss(ref_params, tokens, keys, wrong="no_bias"))
+               - float(reference.loss(ref_params, tokens, keys))) > 1e-5
+
+
+def test_the_two_shares_add_up_to_the_uncut_layer_and_so_do_their_gradients(nano):
+    """Experts 0..3 on one chip, 4..7 on the other: the parts they give add up
+    to what the reference gives with all eight, and so do the gradients with
+    respect to the layer's input under one upstream gradient."""
+    cfg, _, _ = nano
+    whole = dataclasses.replace(cfg, num_experts=8)
+    p = jax.jit(lambda rng: lfm2_moe.init_params(whole, rng))(jax.random.PRNGKey(3))["periods"][1]
+    p = jax.tree.map(lambda a: a[0], p)
+    bias = 0.05 * jax.random.normal(jax.random.PRNGKey(4), (8,))
+    r = jax.random.normal(jax.random.PRNGKey(5), (2, 24, cfg.embed_dim))
+    up = jax.random.normal(jax.random.PRNGKey(6), r.shape)
+
+    def share(offset):
+        held = dataclasses.replace(cfg, expert_offset=offset)
+        mine = {**p, "wi": p["wi"][offset:offset + 4], "wo": p["wo"][offset:offset + 4]}
+        return lambda r: lfm2_moe.expert_ffn(held, mine, bias, r)
+
+    def uncut(r):
+        keys = {**model_keys(whole), "expert_offset": 0}
+        weights, chosen = reference.route(r, p["router"], bias, keys)
+        return reference.held_experts(r, weights, chosen, p["wi"], p["wo"], 0, keys)
+
+    (low, low_counts), (high, high_counts) = share(0)(r), share(4)(r)
+    close(low + high, uncut(r), 1e-5)
+    pairs = r.shape[0] * r.shape[1] * cfg.experts_per_token
+    assert int(low_counts[1]) + int(high_counts[1]) == pairs and 0 < int(low_counts[1]) < pairs
+    grads = [jax.grad(lambda r, f=f: (f(r)[0] * up).sum())(r) for f in (share(0), share(4))]
+    close(grads[0] + grads[1], jax.grad(lambda r: (uncut(r) * up).sum())(r), 1e-5)
+
+
+def test_no_pair_is_dropped_under_a_skewed_bias(nano):
+    """A bias that sends most tokens to one held expert: its load passes twice
+    the mean, every pair is still computed, and the loss is still the
+    reference's (which a capacity of 1.25 x the mean would not give)."""
+    cfg, params, tokens = nano
+    skew = jnp.zeros((cfg.router_experts,)).at[1].set(2.0)
+    biased = {**params, "expert_bias": jax.tree.map(
+        lambda b: jnp.broadcast_to(skew, b.shape), params["expert_bias"])}
+    loss, counters = program_loss(cfg, biased, tokens)
+    n = BATCH[0] * BATCH[1]
+    assert int(counters["moe_load_max"]) == 4 * n        # every token, in all four layers
+    assert int(counters["moe_load_max"]) > 2 * int(counters["moe_assignments"]) / cfg.num_experts
+    keys, ref_params = model_keys(cfg), reference.from_program_params(biased)
+    assert float(loss) == pytest.approx(float(reference.loss(ref_params, tokens, keys)), rel=2e-6)
+    dropped = float(reference.loss(ref_params, tokens, keys, wrong="capacity"))
+    assert abs(dropped - float(loss)) > 1e-4 * float(loss)
+
+
+@pytest.mark.parametrize("wrong", reference.WRONG)
+def test_each_left_out_mechanism_moves_the_references_loss(nano, wrong):
+    cfg, params, tokens = nano
+    keys, ref_params = model_keys(cfg), reference.from_program_params(params)
+    if wrong == "capacity":      # an even load drops nothing: skew it
+        skew = jnp.zeros((cfg.router_experts,)).at[1].set(2.0)
+        ref_params["layers"] = [
+            (p, None if b is None else skew) for p, b in ref_params["layers"]]
+    right = float(reference.loss(ref_params, tokens, keys))
+    # by at least twice what the comparison above allows a float32 rounding
+    assert abs(float(reference.loss(ref_params, tokens, keys, wrong=wrong)) - right) > 4e-6 * right
+
+
+def _poisoned_ragged_dot(rows, w, sizes, **_):
+    """A grouped matmul that treats the rows of no group as the TPU's kernel may:
+    NaN in its result and in the gradient of its rows; its gradient of the
+    weights reads the rows of a group alone, as ``tgmm`` does."""
+    in_a_group = (jnp.arange(rows.shape[0]) < sizes.sum())[:, None]
+
+    @jax.custom_vjp
+    def dot(rows, w):
+        return jnp.where(in_a_group, jax.lax.ragged_dot(rows, w, sizes), jnp.nan)
+
+    def fwd(rows, w):
+        return dot(rows, w), (rows, w)
+
+    def bwd(kept, g):
+        rows, w = kept
+        _, vjp = jax.vjp(
+            lambda rows, w: jax.lax.ragged_dot(rows, w, sizes),
+            jnp.where(in_a_group, rows, 0), w)
+        d_rows, d_w = vjp(jnp.where(in_a_group, g, 0))
+        return jnp.where(in_a_group, d_rows, jnp.nan), d_w
+
+    dot.defvjp(fwd, bwd)
+    return dot(rows, w)
+
+
+@pytest.mark.parametrize("kernel", ["interpreted", "poisoned"])
+def test_rows_of_no_group_reach_no_token_through_the_kernels_path(kernel, monkeypatch):
+    """Half the pairs are an absent expert's and lie in no group, where the TPU's
+    grouped matmul leaves its result and the gradient of its rows undefined. The
+    expert layer through the megablox kernel itself (interpreted, a train tile)
+    and through a grouped matmul that writes NaN there gives the ragged dot's
+    result and its gradients of the tokens, the weights and both expert stacks."""
+    n, k, d, f, held = 40, 2, 32, 64, 3
+    x = jax.random.normal(jax.random.PRNGKey(0), (n, d))
+    weights = jax.random.uniform(jax.random.PRNGKey(1), (n, k), minval=0.2)
+    experts = jax.random.randint(jax.random.PRNGKey(2), (n, k), 0, 2 * held)
+    experts = jnp.where(experts == 1, 0, experts)            # a held expert with no pair
+    wi = jax.random.normal(jax.random.PRNGKey(3), (held, d, 2 * f)) * 0.1
+    wo = jax.random.normal(jax.random.PRNGKey(4), (held, f, d)) * 0.1
+    up = jax.random.normal(jax.random.PRNGKey(5), (n, d))
+
+    def through():
+        return jax.value_and_grad(
+            lambda x, weights, wi, wo: (moe.trained_experts_ffn(
+                x, weights, experts, wi, wo, tiling=(16, 32, 128))[0] * up).sum(),
+            (0, 1, 2, 3))(x, weights, wi, wo)
+
+    want = through()
+    plain = moe.grouped_matmul
+    monkeypatch.setattr(moe, "grouped_matmul", {
+        "interpreted": lambda *a, **kw: plain(*a, interpret=True, **kw),
+        "poisoned": _poisoned_ragged_dot}[kernel])
+    got = through()
+    assert 0 < int(((experts >= held).sum())) < n * k
+    close(got[0], want[0], 1e-5)
+    for g, w in zip(got[1], want[1]):
+        assert np.isfinite(np.asarray(g)).all()
+        close(g, w, 1e-5)
+
+
+def test_the_published_layout_scans_nine_periods_and_runs_two_layers_behind_them():
+    cfg = lfm2_moe.Lfm2MoeConfig()
+    assert cfg.num_layers == 40 and cfg.period == (
+        "full_attention", "conv", "conv", "conv") and cfg.periods == 9
+    assert cfg.num_params() == 23_843_661_440
+    small = lfm2_moe.lfm2_moe_nano(
+        layer_types=("conv", "conv") + ("full_attention", "conv", "conv", "conv") * 2 + (
+            "full_attention", "conv"), dense_layers=2)
+    params = jax.jit(lambda rng: lfm2_moe.init_params(small, rng))(jax.random.PRNGKey(0))
+    assert len(params["first"]) == 2 and len(params["tail"]) == 2
+    assert params["periods"][0]["q"].shape[0] == 2
+    assert sum(x.size for x in jax.tree.leaves(params)) == small.num_params()
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (1, 16), 0, small.vocab_size)
+    loss, counters = program_loss(small, params, tokens)
+    keys = model_keys(small)
+    want = reference.loss(reference.from_program_params(params), tokens, keys)
+    assert float(loss) == pytest.approx(float(want), rel=2e-6)
+    assert int(counters["moe_tokens"]) == 10 * 16
+
+
+# -- what the generalisation may not move -----------------------------------------
+
+
+def gpt_only_step(cfg, optimizer):
+    """The train step as it was written for ``GPT`` alone, before a configuration
+    was asked for its model: the same scopes, the same order of operations."""
+    from ray_tpu.models.gpt import GPT
+    from ray_tpu.models.training import TrainState
+
+    model = GPT(cfg, return_hidden=True, mesh=None)
+
+    @jax.named_scope("train.forward")
+    def _apply(params, tokens):
+        if cfg.moe_num_experts > 0:
+            out, mut = model.apply({"params": params}, tokens, mutable=["losses"])
+            aux = sum(jnp.sum(v) for v in jax.tree.leaves(mut["losses"]))
+            return out, aux / cfg.num_layers
+        return model.apply({"params": params}, tokens), jnp.zeros((), jnp.float32)
+
+    def loss_fn(params, tokens):
+        (hidden, kernel, bias), aux = _apply(params, tokens)
+        with jax.named_scope("train.loss"):
+            loss = blockwise_next_token_loss(hidden, kernel, bias, tokens)
+        return loss + cfg.moe_aux_weight * aux
+
+    def step(state, tokens):
+        loss, grads = jax.value_and_grad(loss_fn)(state.params, tokens)
+        with jax.named_scope("train.optimizer"):
+            updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
+        metrics = {
+            "loss": loss, "grad_norm": optax.global_norm(grads), "step": state.step + 1}
+        return TrainState(step=state.step + 1, params=new_params, opt_state=new_opt), metrics
+
+    return jax.jit(step, donate_argnums=(0,))
+
+
+@pytest.mark.parametrize("experts", [0, 4], ids=["dense", "capacity-moe"])
+def test_gpts_step_lowers_to_the_text_of_a_step_written_for_gpt_alone(experts):
+    """Asking the configuration for its model costs GPT nothing: the step
+    ``make_train_step`` builds for a ``GPTConfig`` (dense, and with the
+    capacity-based ``MoeMlp`` and its auxiliary loss) lowers to the very text
+    of the step that names ``GPT``, and reports what that step reports."""
+    import flax.linen as nn
+
+    from ray_tpu.models.gpt import gpt_nano
+    from ray_tpu.models.training import abstract_state
+
+    cfg = gpt_nano(moe_num_experts=experts)
+    opt = default_optimizer(1e-3)
+    _, abstract = abstract_state(cfg, opt, jax.ShapeDtypeStruct((2, 32), jnp.int32))
+    state = nn.meta.unbox(abstract)
+    tokens = jax.ShapeDtypeStruct((2, 32), jnp.int32)
+    ours = make_train_step(cfg, opt).lower(state, tokens)
+    assert ours.as_text() == gpt_only_step(cfg, opt).lower(state, tokens).as_text()
+    assert set(ours.out_info[1]) == {"loss", "grad_norm", "step"}
+
+
+PARENT = "09fadc28c1b85e218da54b57f3ca0e3a0043ae0a"
+
+
+@pytest.mark.parametrize("served", ["cohere2_moe", "keye_vl2", "kimi_k2"])
+def test_a_served_expert_layer_lowers_to_the_parents_text(served, built_for_tpu, monkeypatch):
+    """The train tiling is an argument whose default leaves the
+    serve programs as they are: ``extend`` of each served configuration with
+    experts, lowered for the TPU (the megablox kernel) with this tree's
+    ``models/moe.py`` and with the parent commit's, is one text. (At the
+    published widths and every shape of the cells: ``CHANGES.md``, PR 43.)"""
+    import importlib
+    import os
+    import re
+    import subprocess
+    import sys
+    import types
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    shown = subprocess.run(
+        ["git", "show", f"{PARENT}:ray_tpu/models/moe.py"], cwd=repo, capture_output=True, text=True)
+    if shown.returncode:
+        pytest.skip(f"no git history with the parent commit here: {shown.stderr.strip()[:200]}")
+    parents = types.ModuleType("parents_moe")
+    monkeypatch.setitem(sys.modules, "parents_moe", parents)     # flax's dataclasses look it up
+    exec(compile(shown.stdout, "parents_moe.py", "exec"), parents.__dict__)
+    module = importlib.import_module("ray_tpu.models." + served)
+    cfg = getattr(module, served + "_nano")()
+    params = jax.eval_shape(lambda: cfg.init_params(0))
+    body = re.compile(r'(body\\22: \\22)[A-Za-z0-9+/=]+')    # a kernel's serialized body names call sites
+
+    def text(moe_module, lanes, tc):
+        built_for_tpu(True)
+        monkeypatch.setattr(module, "moe", moe_module)
+        caches = [
+            jax.ShapeDtypeStruct((cfg.num_layers, lanes, 64) + tuple(each), cfg.dtype)
+            for each in cfg.cache_arrays]
+        traced = cfg.make_extend_fn().trace(
+            params, jax.ShapeDtypeStruct((lanes, tc), jnp.int32),
+            jax.ShapeDtypeStruct((lanes,), jnp.int32), *caches)
+        return body.sub(r"\1", traced.lower(lowering_platforms=("tpu",)).as_text())
+
+    for lanes, tc in ((2, 1), (1, 32)):
+        ours = text(moe, lanes, tc)
+        assert "tpu_custom_call" in ours and ours == text(parents, lanes, tc)
