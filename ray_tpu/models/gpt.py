@@ -54,12 +54,16 @@ class TrainModel(NamedTuple):
     loss ``aux_weight`` times, and the scalars the step reports beside its own
     (a dict, empty where the model counts nothing). ``buffers`` names the top-level
     entries of the parameters that are no parameters: they get no gradient and no
-    optimizer state, and a step hands them on as they are."""
+    optimizer state, and a step hands them on as they are. ``scatters(rules, seq)``
+    says whether ``apply`` on ``seq`` tokens a sequence, under the logical axis
+    ``rules``, takes its layers' products apart round the mesh's tp axis
+    (``GPTConfig.scatter_axis``): what the step is compiled with follows from it."""
 
     init: Callable
     apply: Callable
     aux_weight: float = 0.0
     buffers: Tuple[str, ...] = ()
+    scatters: Callable = lambda rules, seq: False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -128,7 +132,21 @@ class GPTConfig:
                 return out, aux / self.num_layers, {}
             return model.apply({"params": params}, tokens), jnp.zeros((), jnp.float32), {}
 
-        return TrainModel(init, apply, self.moe_aux_weight)
+        def scatters(rules, seq):
+            return self.scatter_axis(mesh, rules, seq) is not None
+
+        return TrainModel(init, apply, self.moe_aux_weight, scatters=scatters)
+
+    def scatter_axis(self, mesh, rules, seq: int) -> Optional[str]:
+        """The mesh axis over which the stream between blocks lies scattered along
+        a sequence of ``seq`` tokens (``ring_dense.scatter_axis``: tp, of more than
+        one chip, where it divides ``seq``), or None: ``Block`` runs plain then, as
+        it does with experts, whose output is no partial sum of two products. The
+        one place that decides it: ``Block`` asks here, and so does the train step
+        for what it is compiled with."""
+        if self.moe_num_experts > 0:
+            return None
+        return ring_dense.scatter_axis(mesh, rules, seq)
 
     def num_params(self) -> int:
         """Exact parameter count (for MFU math)."""
@@ -197,6 +215,11 @@ def _rotary(x: jax.Array, positions: jax.Array, rotary_dim: int,
 # ---------------------------------------------------------------------------
 
 
+# the logical axes of a block's kernels
+_QKV_AXES, _O_AXES = ("embed", "heads", "kv"), ("heads", "kv", "embed")
+_WI_AXES, _WO_AXES = ("embed", "mlp"), ("mlp", "embed")
+
+
 class _DenseND(nn.Module):
     """DenseGeneral equivalent that initializes the kernel at its FULL
     shape. flax's DenseGeneral initializes a flattened 2-D kernel and
@@ -212,6 +235,7 @@ class _DenseND(nn.Module):
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
     mesh: Any = None  # the step's device mesh, when it has one
+    bias_axes: Optional[Tuple[str, ...]] = None  # the bias's own, where not the output's
 
     @nn.compact
     def __call__(self, x: jax.Array, beside: Optional[jax.Array] = None) -> jax.Array:
@@ -239,7 +263,7 @@ class _DenseND(nn.Module):
             bias = self.param(
                 "bias",
                 nn.with_logical_partitioning(
-                    nn.initializers.zeros_init(), self.logical_axes[n_in:]
+                    nn.initializers.zeros_init(), self.bias_axes or self.logical_axes[n_in:]
                 ),
                 tuple(self.features),
                 self.param_dtype,
@@ -249,7 +273,8 @@ class _DenseND(nn.Module):
 
 
 def _dense(features: Tuple[int, ...], logical_axes: Tuple[str, ...], cfg: GPTConfig,
-           name: str, use_bias: bool = True, mesh: Any = None):
+           name: str, use_bias: bool = True, mesh: Any = None,
+           bias_axes: Optional[Tuple[str, ...]] = None):
     return _DenseND(
         features=tuple(features) if isinstance(features, tuple) else (features,),
         logical_axes=logical_axes,
@@ -257,8 +282,25 @@ def _dense(features: Tuple[int, ...], logical_axes: Tuple[str, ...], cfg: GPTCon
         dtype=cfg.dtype,
         param_dtype=cfg.param_dtype,
         mesh=mesh,
+        bias_axes=bias_axes,
         name=name,
     )
+
+
+def _attend(cfg: GPTConfig, mesh: Any, q: jax.Array, k: jax.Array, v: jax.Array,
+            positions: jax.Array) -> jax.Array:
+    """Causal attention of [b, t, h, d] projections, rotated here."""
+    q = _rotary(q, positions, cfg.rotary_dim)
+    k = _rotary(k, positions, cfg.rotary_dim)
+    # [b, t, h, d] → [b, h, t, d] for the fused kernel
+    qh, kh, vh = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
+    # the fused kernel, per shard under shard_map when the mesh has more
+    # than one device (batch on dp/fsdp, heads on tp); with sp > 1
+    # context parallelism: ring/ulysses over the sp axis (first-class
+    # long-context support — SURVEY.md §5)
+    return mesh_attention(
+        qh, kh, vh, mesh, impl=cfg.seq_parallel_impl, causal=True
+    ).transpose(0, 2, 1, 3)
 
 
 class Attention(nn.Module):
@@ -269,22 +311,12 @@ class Attention(nn.Module):
     def __call__(self, x: jax.Array, positions: jax.Array) -> jax.Array:
         cfg = self.cfg
         h, hd = cfg.num_heads, cfg.head_dim
-        q = _dense((h, hd), ("embed", "heads", "kv"), cfg, "q", use_bias=False, mesh=self.mesh)(x)
-        k = _dense((h, hd), ("embed", "heads", "kv"), cfg, "k", use_bias=False, mesh=self.mesh)(x)
-        v = _dense((h, hd), ("embed", "heads", "kv"), cfg, "v", use_bias=False, mesh=self.mesh)(x)
-        q = _rotary(q, positions, cfg.rotary_dim)
-        k = _rotary(k, positions, cfg.rotary_dim)
-        # [b, t, h, d] → [b, h, t, d] for the fused kernel
-        qh, kh, vh = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
-        # the fused kernel, per shard under shard_map when the mesh has more
-        # than one device (batch on dp/fsdp, heads on tp); with sp > 1
-        # context parallelism: ring/ulysses over the sp axis (first-class
-        # long-context support — SURVEY.md §5)
-        out = mesh_attention(
-            qh, kh, vh, self.mesh, impl=cfg.seq_parallel_impl, causal=True
-        ).transpose(0, 2, 1, 3)
+        q = _dense((h, hd), _QKV_AXES, cfg, "q", use_bias=False, mesh=self.mesh)(x)
+        k = _dense((h, hd), _QKV_AXES, cfg, "k", use_bias=False, mesh=self.mesh)(x)
+        v = _dense((h, hd), _QKV_AXES, cfg, "v", use_bias=False, mesh=self.mesh)(x)
+        out = _attend(cfg, self.mesh, q, k, v, positions)
         return _dense(
-            (cfg.embed_dim,), ("heads", "kv", "embed"), cfg, "o", use_bias=False, mesh=self.mesh
+            (cfg.embed_dim,), _O_AXES, cfg, "o", use_bias=False, mesh=self.mesh
         )(out)
 
 
@@ -296,17 +328,21 @@ class Mlp(nn.Module):
     def __call__(self, x: jax.Array, beside: Optional[jax.Array] = None) -> jax.Array:
         """The MLP of ``x``; ``beside`` joins ``wo``'s product under its bias."""
         cfg = self.cfg
-        x = _dense((cfg.mlp_dim,), ("embed", "mlp"), cfg, "wi", mesh=self.mesh)(x)
+        x = _dense((cfg.mlp_dim,), _WI_AXES, cfg, "wi", mesh=self.mesh)(x)
         x = nn.gelu(x)
-        return _dense((cfg.embed_dim,), ("mlp", "embed"), cfg, "wo", mesh=self.mesh)(x, beside)
+        # ``wo``'s bias is as long as the embedding: every chip holds it whole, as it
+        # does a LayerNorm's scale and bias (``sharding.DEFAULT_RULES``, "embed_vector")
+        return _dense(
+            (cfg.embed_dim,), _WO_AXES, cfg, "wo", mesh=self.mesh, bias_axes=("embed_vector",)
+        )(x, beside)
 
 
 def _layer_norm(cfg: GPTConfig, name: str):
     return nn.LayerNorm(
         dtype=cfg.dtype,
         param_dtype=cfg.param_dtype,
-        scale_init=nn.with_logical_partitioning(nn.initializers.ones_init(), ("embed",)),
-        bias_init=nn.with_logical_partitioning(nn.initializers.zeros_init(), ("embed",)),
+        scale_init=nn.with_logical_partitioning(nn.initializers.ones_init(), ("embed_vector",)),
+        bias_init=nn.with_logical_partitioning(nn.initializers.zeros_init(), ("embed_vector",)),
         name=name,
     )
 
@@ -321,6 +357,10 @@ class Block(nn.Module):
     between the two took two all-reduces. An expert layer's output is no such
     product and keeps the plain sum.
 
+    Where ``GPTConfig.scatter_axis`` names an axis (tp, of more than one chip)
+    that sum is never all-reduced: the stream between blocks lies scattered
+    along the sequence over the axis, and the block is :meth:`scattered`.
+
     The constraints name the step's mesh: flax applies one without a mesh only
     under ``jax.set_mesh``, and the one on the output is what keeps the
     compiler from laying the sum out along ``wo``'s bias (embed over fsdp)."""
@@ -331,6 +371,9 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array, positions: jax.Array) -> jax.Array:
         cfg = self.cfg
+        axis = cfg.scatter_axis(self.mesh, nn.get_logical_axis_rules(), x.shape[1])
+        if axis is not None and not self.is_initializing():
+            return self.scattered(x, positions, axis)
         axes = ("batch", "seq", "act_embed")
         x = nn.with_logical_constraint(x, axes, mesh=self.mesh)
         hidden = _layer_norm(cfg, "ln")(x)
@@ -342,6 +385,61 @@ class Block(nn.Module):
         else:
             x = x + Mlp(cfg, self.mesh, name="mlp")(hidden, beside=attn)
         return nn.with_logical_constraint(x, axes, mesh=self.mesh)
+
+    def scattered(self, x: jax.Array, positions: jax.Array, axis: str) -> jax.Array:
+        """The same sums with the layer's all-reduce over ``axis`` taken apart
+        (``parallel/ring_dense.py``). A chip normalises its own tokens; q, k, v
+        and ``wi`` multiply them while the next chip's arrive, and those as the
+        ones after them do (``train.tp.gather``); attention sees the whole
+        sequence and the chip's heads, as before; ``o`` and ``wo`` multiply the
+        farthest chip's tokens first and send the partial sum on while they
+        multiply the next chip's, this chip's own last
+        (``train.tp.scatter``); bias and residual are added to those alone.
+        The MLP is token by token, so its activations are never laid out in the
+        sequence's order. The parameters are read where the plain block made
+        them (a block is always initialized plain)."""
+        cfg, mesh, rules = self.cfg, self.mesh, nn.get_logical_axis_rules()
+        axes = ("batch", "act_seq", "act_embed")
+        x = nn.with_logical_constraint(x, axes, mesh=mesh)
+        params = nn.meta.unbox(self.variables["params"])
+        attn, mlp = params["attn"], params["mlp"]
+        hidden = _layer_norm(cfg, "ln")(x)
+        kernels = [
+            (attn["q"]["kernel"], _QKV_AXES), (attn["k"]["kernel"], _QKV_AXES),
+            (attn["v"]["kernel"], _QKV_AXES), (attn["o"]["kernel"], _O_AXES),
+            (mlp["wi"]["kernel"], _WI_AXES), (mlp["wi"]["bias"], _WI_AXES[1:]),
+            (mlp["wo"]["kernel"], _WO_AXES),
+        ]
+
+        def layer(hidden, positions, q, k, v, o, wi, wi_bias, wo):
+            def times(xs, kernel, kernel_axes, n_in=1):
+                return ring_dense.products(xs, kernel, n_in, mesh, kernel_axes, rules)
+
+            # every half of the stream laid out as the stream is (its batch over
+            # the batch's axes), on its way in and, with its gradient, on its way
+            # out, or the compiler may lay one out as ``wo``'s bias
+            own = lambda half: ring_dense.laid_out(half, ("batch", None, "act_embed"), rules, mesh)  # noqa: E731
+            with jax.named_scope("train.tp.gather"):
+                held = [own(half) for half in ring_dense.arriving(hidden, axis)]
+                q, k, v = (
+                    ring_dense.in_order(times(held, each, _QKV_AXES), axis) for each in (q, k, v))
+                inner = tuple(nn.gelu(each) for each in ring_dense.biased(
+                    times(held, wi, _WI_AXES), wi_bias, mesh, _WI_AXES[1:], rules))
+            out = _attend(cfg, mesh, q, k, v, positions)
+            with jax.named_scope("train.tp.scatter"):
+                return ring_dense.home([
+                    own(of_mlp + of_attn) for of_attn, of_mlp in zip(
+                        times(ring_dense.by_hop(out, axis), o, _O_AXES, 2),
+                        times(inner, wo, _WO_AXES))
+                ], axis)
+
+        of = lambda logical: ring_dense.spec_over(axis, logical, rules, mesh)  # noqa: E731
+        out = jax.shard_map(
+            layer, mesh=mesh, out_specs=of(axes), axis_names={axis}, check_vma=False,
+            in_specs=(of(axes), of(("batch", "seq")), *(of(logical) for _, logical in kernels)),
+        )(hidden, positions, *(each.astype(cfg.dtype) for each, _ in kernels))
+        x = x + (out + mlp["wo"]["bias"].astype(cfg.dtype))
+        return nn.with_logical_constraint(x, axes, mesh=mesh)
 
 
 class ScannedBlocks(nn.Module):
@@ -365,6 +463,10 @@ class ScannedBlocks(nn.Module):
             length=cfg.num_layers,
             metadata_params={nn.PARTITION_NAME: "layers"},
         )(block(cfg, self.mesh, name="layers"), x, None)
+        if cfg.scatter_axis(self.mesh, nn.get_logical_axis_rules(), x.shape[1]) is not None:
+            # what the blocks left scattered along the sequence is gathered here,
+            # once: the loss cuts the sequence into chunks of its own
+            x = nn.with_logical_constraint(x, ("batch", "seq", "act_embed"), mesh=self.mesh)
         return x
 
 

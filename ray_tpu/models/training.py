@@ -206,16 +206,54 @@ def make_train_step(
         return jax.jit(step, donate_argnums=(0,) if donate else ())
 
     data_sharding = shd.batch_sharding(mesh, ndim=2, rules=rules)
-    # where the collectives sit in the schedule is the compiler's; what it is
-    # told follows from the mesh (nothing on one device or off the TPU)
-    kwargs = {"compiler_options": accelerator.compiler_options(mesh)}
+    kwargs = {}
     if state_shardings_tree is not None:
         kwargs["in_shardings"] = (state_shardings_tree, data_sharding)
         kwargs["out_shardings"] = (
             state_shardings_tree,
             NamedSharding(mesh, PartitionSpec()),
         )
-    return jax.jit(step, donate_argnums=(0,) if donate else (), **kwargs)
+
+    # where the collectives sit in the schedule is the compiler's; what it is
+    # told follows from the mesh (nothing on one device or off the TPU) and from
+    # what the model does with a sequence of the tokens' length
+    @functools.lru_cache(maxsize=None)
+    def jitted(scattered: bool):
+        # where a layer's products are taken token half by token half round tp
+        # (``gpt.Block.scattered``) a weight's gather in chunks would join both
+        # halves' products in one loop: a half's partial sum could not leave before
+        # the other half is multiplied, and the backward would gather each weight
+        # twice. Whole, the gathers start early and the hops travel beside the
+        # products (0.7053 against 0.6865 s a four-chip GPT-J step: PERF.md
+        # section 6, PR 44). Every other step keeps the mesh's options.
+        options = {} if scattered else accelerator.compiler_options(mesh)
+        return jax.jit(
+            step, donate_argnums=(0,) if donate else (), compiler_options=options, **kwargs)
+
+    if not accelerator.compiler_options(mesh):
+        return jitted(False)
+    return _StepBySequence(jitted, lambda seq: bool(model.scatters(active_rules, seq)))
+
+
+class _StepBySequence:
+    """``step(state, tokens)`` on a mesh whose compiler options wait for the
+    tokens' shape: the call, ``lower`` and ``trace`` of the jit built for what
+    the model does with a sequence of that length (``TrainModel.scatters``)."""
+
+    def __init__(self, jitted: Callable, scatters: Callable):
+        self._jitted, self._scatters = jitted, scatters
+
+    def _for(self, tokens):
+        return self._jitted(self._scatters(tokens.shape[1]))
+
+    def __call__(self, state, tokens):
+        return self._for(tokens)(state, tokens)
+
+    def lower(self, state, tokens):
+        return self._for(tokens).lower(state, tokens)
+
+    def trace(self, state, tokens):
+        return self._for(tokens).trace(state, tokens)
 
 
 def make_eval_step(cfg: Any, mesh: Optional[Mesh] = None) -> Callable:

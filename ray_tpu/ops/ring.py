@@ -148,8 +148,14 @@ def mesh_attention(
         )
     else:
         raise ValueError(f"unknown sequence-parallel impl {impl!r}")
-    spec = P(batch_axes, head_axis, sp_axis, None)
+    # inside a shard_map that has taken some of the axes already (the layer's,
+    # over tp: the heads are a chip's own there) this one covers the others
+    taken = jax.sharding.get_abstract_mesh().manual_axes
+    spec = P(*(
+        tuple(a for a in ((axes,) if isinstance(axes, str) else axes) if a not in taken) or None
+        for axes in (batch_axes, head_axis, sp_axis)), None)
     return shard_map(
         lambda a, b, c: local(a, b, c),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,
+        mesh=None if taken else mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        axis_names=frozenset(mesh.axis_names) - frozenset(taken), check_vma=False,
     )(q, k, v)

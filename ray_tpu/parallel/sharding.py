@@ -23,6 +23,14 @@ Rules = Sequence[Tuple[str, Any]]
 # Activation dims get distinct logical names ("act_*") so one PartitionSpec
 # never consumes the same mesh axis twice (weights shard embed over fsdp;
 # activations keep embed replicated and shard batch over dp+fsdp).
+# "embed_vector" is a vector as long as the embedding (a LayerNorm's scale and
+# bias, the bias of a layer's last product): every chip holds it whole. Sharded
+# over fsdp as "embed" is, its 8 KB are gathered a layer by a synchronous
+# collective that queues behind the weights on the same links (0.5 ms a time in
+# the four-chip GPT-J step: PERF.md section 6, PR 44).
+# "act_seq" is the sequence of the residual stream between layers, which lies
+# scattered over tp wherever ``ring_dense.scatter_axis`` finds a layer's output
+# reduced round that axis: a chip normalises, biases and adds its own tokens.
 DEFAULT_RULES: Rules = (
     ("batch", ("dp", "fsdp")),
     ("seq", "sp"),
@@ -34,7 +42,9 @@ DEFAULT_RULES: Rules = (
     ("expert", "ep"),
     ("layers", None),
     ("stage", "pp"),
+    ("embed_vector", None),
     ("act_embed", None),
+    ("act_seq", "tp"),
     ("act_mlp", "tp"),
     ("act_heads", "tp"),
     ("act_vocab", "tp"),

@@ -250,44 +250,188 @@ def test_one_chip_step_holds_no_collective_and_gets_no_option(v5e, built_for_tpu
     assert not _COLLECTIVE.search(text)
 
 
-def test_forward_layer_sends_one_tp_all_reduce_of_one_tensor(v5e, built_for_tpu):
-    """``Block`` adds attention's output and the MLP's product on each chip and
-    the sum is reduced over the tp pairs once: one ``[batch, seq, embed]``
-    operand (a device's share of 2 x 2048 tokens: one row), not a tuple of two,
-    and no other reduction or exchange of an activation in the forward body."""
+def _tp_hops(body):
+    """The ``collective-permute-start``s of a layer body that carry a chip's
+    share of the stream to its tp neighbour (tp is the mesh's innermost axis:
+    chips 0 and 1, 2 and 3), by the scope they were sent under."""
+    hops = [
+        line for line in body
+        if " collective-permute-start(" in line and re.search(r"train\.tp\.\w+/ppermute", line)]
+    for line in hops:
+        assert re.search(r"source_target_pairs=\{\{[01],[01]\},\{[01],[01]\},\{[23],[23]\},\{[23],[23]\}\}", line), line
+        assert re.search(r"\(bf16\[(1,)?1024,4096\]", line.split(" = ")[1]), line      # half the tokens
+    return sorted(re.search(r"train\.tp\.(\w+)/ppermute", line).group(1) for line in hops)
+
+
+def test_no_layer_body_all_reduces_the_stream_over_the_tp_pairs(v5e, built_for_tpu):
+    """``Block.scattered``: the sum of attention's output and the MLP's product
+    over the tp pair is no all-reduce (the parent's forward body held one of a
+    ``[batch, seq, embed]`` operand, its backward body one of ``d hidden``, both
+    synchronous). The forward sends a chip's normed half to its neighbour in
+    front of q, k, v and ``wi`` and the neighbour's partial sum behind ``o`` and
+    ``wo``; the backward sends the normed half again (the replay), ``d out``'s
+    half in front of ``o``'s and ``wo``'s gradients and ``d hidden``'s partial
+    sum behind q, k, v and ``wi``'s. What is still reduced in a layer body is a
+    vector: the gradients of the biases and of LayerNorm's scale and bias, each
+    summed over a chip's own tokens."""
     _, _, text = _gptj_step(v5e, built_for_tpu, "fsdp2xtp2")
-    forward, _ = _layer_bodies(text)
-    moved = [
-        line for line in forward
-        if re.search(r" (all-reduce|all-to-all|reduce-scatter)(-start)?\(", line)]
-    (reduce,) = moved
-    assert re.match(r"%?[\w.\-]+ = bf16\[(1,)?2048,4096\]\S* all-reduce\(%?[\w.\-]+\)", reduce), reduce
-    assert "replica_groups=[2,2]<=[4]" in reduce        # tp is the mesh's innermost axis
+    forward, backward = _layer_bodies(text)
+    assert _tp_hops(forward) == ["gather", "scatter"]
+    assert _tp_hops(backward) == ["gather", "gather", "scatter"]
+    for body in (forward, backward):
+        for line in body:
+            if re.search(r" (all-reduce|all-to-all|reduce-scatter)(-start)?\(", line):
+                shapes = re.findall(r"\w+\[([\d,]*)\]", line.split(" all-")[0].split(" reduce-")[0])
+                assert all(       # vectors alone: none as long as a token's row of ``wi``
+                    math.prod(map(int, filter(None, dims.split(",")))) <= 8192 for dims in shapes), line
+    assert not [line for line in forward if re.search(r" all-reduce(-start)?\(", line)]
+
+
+def test_four_chip_step_at_the_cells_own_size_is_smaller_than_the_parents(v5e, built_for_tpu):
+    """``gptj-train-4chip-full-depth`` as it runs: depth 28, 4 x 2048 tokens.
+    The layer inputs that the remat keeps are a chip's own half of the tokens
+    (28 x 16.8 MB where they were 33.5), so the step compiles to less than the
+    parent's 16,419,761,664 bytes a device (PERF.md section 7, S7b (8))."""
+    _, compiled, _ = _gptj_step(v5e, built_for_tpu, "fsdp2xtp2", depth=28, batch=(4, 2048))
+    assert _device_bytes(compiled) < 16_419_761_664 - 28 * 8_388_608
+    _STEPS.pop(("fsdp2xtp2", 28, (4, 2048)))      # 28 layers' text: not worth keeping
 
 
 def test_backward_layer_reduces_no_gradient_behind_its_matmul(v5e, built_for_tpu):
-    """The parent's backward body held six synchronous fused all-reduce + slice
-    (``all-reduce-scatter``), one behind each weight gradient's matmul; no
-    compiler setting made one asynchronous. ``ring_dense`` multiplies a
-    gradient a shard at a time and sends each partial sum on (one
+    """PR 36's parent held six synchronous fused all-reduce + slice
+    (``all-reduce-scatter``), one behind each weight gradient's matmul, in the
+    backward body; no compiler setting made one asynchronous. ``ring_dense``
+    multiplies a gradient a shard at a time and sends each partial sum on (one
     ``collective-permute`` a weight on an fsdp axis of two) while the next
-    shard multiplies; the head's gradient is reduced once after the loss's
-    loop, not once a chunk inside it. The weights' all-gathers are matmuls in
-    chunks too (the mesh's compiler options), so neither body of a layer nor
-    the loss's loops hold a synchronous all-gather of a weight."""
+    shard multiplies, inside the layer's ``shard_map`` over tp; the head's
+    gradient is reduced once after the loss's loop, not once a chunk inside it.
+    No loop body holds a fused all-reduce + slice or a reduce-scatter; the one
+    the program has left is the embedding's gradient, once a step."""
     mesh, compiled, text = _gptj_step(v5e, built_for_tpu, "fsdp2xtp2")
-    assert accelerator.compiler_options(mesh)
-    assert "all-reduce-scatter" not in text and " reduce-scatter(" not in text
     forward, backward = _layer_bodies(text)
     sends = [line for line in backward if " collective-permute-start(" in line]
     gradients = [line for line in sends if "/shard_map/ppermute" in line]
-    assert len(gradients) == 6          # one hop a weight on an fsdp axis of two
+    assert len(gradients) == 6 + 1      # one hop a weight on an fsdp axis of two, and ``wi``'s bias
     assert all("source_target_pairs={{0,2},{2,0},{1,3},{3,1}}" in line for line in gradients)
-    assert len(sends) >= 6 + 6          # ... and every weight arriving in chunks
-    for body in (forward, backward):
-        gathers = [line for line in body if re.search(r" all-gather(-start)?\(", line)]
-        assert all(re.search(r" = \w+\[(1,)?4096\]", line) for line in gathers), gathers
+    for body in _loop_bodies(text):
+        assert not [
+            line for line in body
+            if "all-reduce-scatter" in line or re.search(r" reduce-scatter(-start)?\(", line)]
+    assert " reduce-scatter(" not in text
+    (fused,) = [line for line in text.splitlines() if "calls=%all-reduce-scatter" in line]
+    assert "/wte/" in fused
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+# a layer's six kernels as a chip of the fsdp2 x tp2 mesh holds them gathered
+# over fsdp: q, k, v ``[embed, heads / 2, kv]``, o, ``wi`` and ``wo``
+_GATHERED = {"4096,8,256": 3, "8,256,4096": 1, "4096,8192": 1, "8192,4096": 1}
+
+
+def _weight_gathers(body):
+    """The gathers of a layer's kernels over fsdp in a layer body, which is in
+    the order the chip runs it: ``(synchronous, asynchronous)``, the shapes of
+    the ``all-gather`` instructions, and for every ``async-collective-start``
+    ... ``-done`` pair (the compiler's fusion round an all-gather that travels
+    while other instructions run) its shape and how many of the layer's
+    products lie between its two ends."""
+    weight = r"bf16\[1,(%s)\]" % "|".join(_GATHERED)
+    synchronous = [
+        m.group(1) for m in (re.search(rf"= {weight}\S* all-gather\(", line) for line in body) if m]
+    started, asynchronous = {}, []
+    for at, line in enumerate(body):
+        start = re.match(rf"%?async-collective-start([.\d]*) = \(\S+, {weight}", line)
+        done = re.match(r"%?async-collective-done([.\d]*) = ", line)
+        if start:
+            started[start.group(1)] = at, start.group(2)
+        elif done and done.group(1) in started:
+            since, shape = started.pop(done.group(1))
+            asynchronous.append((shape, sum(
+                " fusion(" in between and bool(re.search(r'op_name="[^"]*dot_general"', between))
+                for between in body[since + 1:at])))
+    assert not started
+    return synchronous, asynchronous
+
+
+def test_each_layer_body_gathers_each_weight_once_beside_a_product(v5e, built_for_tpu):
+    """``Block.scattered``'s step is compiled without the mesh's option, so a
+    weight sharded over fsdp arrives whole and not as a matmul in chunks (the
+    parent's layer bodies held twelve and more ``collective-permute`` chunks,
+    which ``train.collective_exposed_share`` read; an ``async-collective-done``
+    is a name its reader does not know: PERF.md section 7, S7b (11)). What
+    keeps a whole gather from being a wait: each body gathers each of the six
+    kernels once (the backward not twice, as it did with chunks); every gather
+    but the layer's first is an ``async-collective-start`` ... ``-done`` pair
+    with a product of the layer between its ends; the backward holds no
+    synchronous gather of a kernel. The forward's first (of q, k, v: two at
+    this size, one at the cell's) are synchronous ``all-gather``s, with nothing
+    of the layer in front of them to travel beside: that wait is a real one
+    (0.36 + 0.46 ms a layer, PERF.md section 5)."""
+    mesh, _, text = _gptj_step(v5e, built_for_tpu, "fsdp2xtp2")
+    assert accelerator.compiler_options(mesh)       # the mesh's, which this step does not take
+    forward, backward = _layer_bodies(text)
+    for body, most_synchronous in ((forward, 2), (backward, 0)):
+        synchronous, asynchronous = _weight_gathers(body)
+        assert len(synchronous) <= most_synchronous and set(synchronous) <= {"4096,8,256"}
+        gathered = synchronous + [shape for shape, _ in asynchronous]
+        assert {shape: gathered.count(shape) for shape in set(gathered)} == _GATHERED
+        assert all(products >= 1 for _, products in asynchronous), asynchronous
+        # and nothing else of more than a vector is gathered synchronously
+        others = [
+            line for line in body
+            if re.search(r" all-gather(-start)?\(", line)
+            and not re.search(r" = bf16\[1,(%s)\]" % "|".join(_GATHERED), line)]
+        assert all(re.search(r" = \w+\[(1,)?\d+\]", line) for line in others), others
+
+
+@pytest.mark.parametrize(
+    "mesh_spec,n_devices,cfg,seq,takes",
+    [
+        (MeshSpec(dp=-1, fsdp=2, tp=2), 4, {}, 64, False),
+        (MeshSpec(dp=-1, fsdp=2, tp=2), 4, {}, 63, True),
+        (MeshSpec(dp=-1, fsdp=2, tp=2), 4, {"moe_num_experts": 4}, 64, True),
+        (MeshSpec(dp=-1, fsdp=4), 4, {}, 64, True),
+        (MeshSpec(dp=-1, tp=2), 2, {}, 64, False),
+        (MeshSpec(), 1, {}, 64, False),
+    ],
+    ids=["scattered", "seq-not-divided", "experts", "fsdp-alone", "tp-alone", "one-chip"],
+)
+def test_a_step_takes_the_meshes_option_unless_its_blocks_scatter(
+        v5e, monkeypatch, mesh_spec, n_devices, cfg, seq, takes):
+    """``accelerator.compiler_options`` reads the mesh alone (the weights'
+    all-gathers as matmuls in chunks wherever fsdp shards them). The one step
+    compiled without it is the one whose blocks take their products apart
+    round tp, and one predicate says which: ``GPTConfig.scatter_axis``, asked
+    by ``Block`` for the path and by ``make_train_step``, with the tokens'
+    shape, for the option. A step with experts, or on a sequence that tp does
+    not divide, runs the plain block and keeps the parent's chunks."""
+    cfg = dataclasses.replace(gpt.gpt_nano(), **cfg)
+    mesh = mesh_spec.build(v5e[:n_devices])
+    compiled_with = []
+    jit = jax.jit
+
+    def seen(f, **kwargs):
+        compiled_with.append(kwargs.get("compiler_options"))
+        return jit(f, **kwargs)
+
+    opt = default_optimizer(1e-4)
+    batch = (4, seq)
+    _, abstract = abstract_state(cfg, opt, jax.ShapeDtypeStruct(batch, jnp.int32))
+    shardings = nn.meta.unbox(state_shardings(mesh, abstract))
+    state = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        nn.meta.unbox(abstract), shardings)
+    tokens = jax.ShapeDtypeStruct(batch, jnp.int32, sharding=shd.batch_sharding(mesh))
+    monkeypatch.setattr(jax, "jit", seen)
+    step = make_train_step(cfg, opt, mesh, state_shardings_tree=shardings)
+    text = step.lower(state, tokens).as_text(debug_info=True)
+    monkeypatch.undo()
+    option = accelerator.compiler_options(mesh)
+    assert bool(option) == (mesh.shape["fsdp"] > 1)
+    assert compiled_with[-1] == (option if takes else {})
+    assert ("train.tp.scatter" in text) == (
+        cfg.scatter_axis(mesh, shd.DEFAULT_RULES, seq) is not None) == (
+        mesh.shape["tp"] > 1 and not takes)
 
 
 def _extend_at(cfg, shaped, lanes, tc, cap):
