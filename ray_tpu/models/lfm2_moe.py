@@ -34,7 +34,7 @@ flash kernel's own residuals (``FLASH_RESIDUALS``) and its input.
 
 Scopes, inside ``train.forward``: ``train.conv``, ``train.attention``,
 ``train.mlp``, ``train.moe.route``, ``train.moe.experts``. The step reports
-``moe.COUNTERS``, summed over the expert layers.
+``moe.TRAINED_COUNTERS``, summed over the expert layers.
 """
 
 from __future__ import annotations
@@ -247,7 +247,7 @@ def dense_mlp(cfg: Lfm2MoeConfig, p, r):
 
 def expert_ffn(cfg: Lfm2MoeConfig, p, bias, r):
     """The held experts' part of the layer for the normed tokens ``r`` [b, t, d]
-    float32, and ``moe.COUNTERS``' four: every pair whose expert is held is
+    float32, and ``moe.TRAINED_COUNTERS``: every pair whose expert is held is
     computed, whatever the experts' loads."""
     b, t, d = r.shape
     flat = r.reshape(b * t, d)
@@ -260,13 +260,17 @@ def expert_ffn(cfg: Lfm2MoeConfig, p, bias, r):
     return y.astype(cfg.dtype).reshape(b, t, d), counters
 
 
+def _nothing_counted():
+    return jnp.zeros((len(moe.TRAINED_COUNTERS),), jnp.int32)
+
+
 def _layer(cfg: Lfm2MoeConfig, kind: str, x, p, bias=None):
     """One layer; ``bias`` is an expert layer's, None says a dense one."""
     mixer = conv_mixer if kind == CONV else attention_mixer
     x = x + mixer(cfg, p, _rms(x, p["ln_1"], cfg.norm_eps).astype(cfg.dtype))
     r = _rms(x, p["ln_2"], cfg.norm_eps)
     if bias is None:
-        return x + dense_mlp(cfg, p, r.astype(cfg.dtype)), jnp.zeros((4,), jnp.int32)
+        return x + dense_mlp(cfg, p, r.astype(cfg.dtype)), _nothing_counted()
     y, counters = expert_ffn(cfg, p, bias, r)
     return x + y, counters
 
@@ -274,7 +278,7 @@ def _layer(cfg: Lfm2MoeConfig, kind: str, x, p, bias=None):
 def forward(cfg: Lfm2MoeConfig, params, tokens):
     """``tokens`` [b, t] through every layer: ``((hidden [b, t, d], the tied head's
     kernel [d, vocab], None), 0.0, counters)``, as ``gpt.TrainModel.apply`` gives
-    them; ``counters`` are ``moe.COUNTERS``, summed over the expert layers."""
+    them; ``counters`` are ``moe.TRAINED_COUNTERS``, summed over the expert layers."""
     # a layer's remat keeps its input and the attention kernel's own residuals
     # and replays the rest, as ``gpt.ScannedBlocks`` does
     layer = jax.checkpoint(
@@ -282,7 +286,7 @@ def forward(cfg: Lfm2MoeConfig, params, tokens):
         policy=jax.checkpoint_policies.save_only_these_names(*FLASH_RESIDUALS))
 
     x = params["wte"].astype(cfg.dtype)[tokens]
-    counted = jnp.zeros((4,), jnp.int32)
+    counted = _nothing_counted()
     for kind, p in zip(cfg.layer_types, params["first"]):
         x, _ = layer(cfg, kind, x, p)
 
@@ -302,4 +306,4 @@ def forward(cfg: Lfm2MoeConfig, params, tokens):
     hidden = _rms(x, params["ln_f"], cfg.norm_eps).astype(cfg.dtype)
     return (
         (hidden, params["wte"].T, None), jnp.zeros((), jnp.float32),
-        dict(zip(moe.COUNTERS, counted)))
+        dict(zip(moe.TRAINED_COUNTERS, counted)))

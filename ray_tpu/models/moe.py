@@ -25,6 +25,7 @@ here. What the absent experts would add is left out.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
@@ -187,13 +188,63 @@ def held_experts_ffn(x, weights, experts, valid, wi, wo, offset: int = 0, layer=
     return y, counters
 
 
-# The sort and the un-sort of a trained layer's pairs. ``held`` [k, n] marks the
-# pairs whose expert is held here, pair ``(c, t)`` being token ``t``'s choice ``c``;
-# ``order`` sorts the ``k n`` pairs by expert, those not held last, and ``place`` is
-# its inverse. Both are permutations, so their gradients are gathers, where
-# autodiff, which cannot know that an index hits every row once, scatters and adds
-# row by row. And both keep what the grouped matmul leaves undefined, the rows of
-# the pairs not held, from reaching a token: in the result and in the gradient.
+# A trained layer's passes between the sort and the un-sort. ``held`` [k, n] marks
+# the pairs whose expert is held here, pair ``(c, t)`` being token ``t``'s choice
+# ``c``; ``order`` sorts the ``k n`` pairs by expert, those not held last, and
+# ``place`` is its inverse. Both are permutations, so their gradients are gathers,
+# where autodiff, which cannot know that an index hits every row once, scatters
+# and adds row by row. And both keep what the grouped matmul leaves undefined, the
+# rows of the pairs not held, from reaching a token: in the result and in the
+# gradient.
+#
+# **The backward's work follows the pairs held.** The shapes are static for the
+# worst case, ``k n`` rows, but the held pairs sort first, and the backward's passes
+# over sorted rows (the un-sort's gradient, the gate's gradient) are loops over
+# blocks of ``row_block`` rows whose trip count is read from ``group_sizes.sum()``
+# on the device. A block's gradient takes the block's place in the buffer of the
+# value it is the gradient of, dead by then; the blocks that hold no pair stay
+# as they were, undefined as the grouped matmul leaves the row tiles of no group.
+# The forward's passes make buffers of their own, and a buffer XLA makes it also
+# clears, a pass over all ``k n`` rows: they stay whole passes.
+
+
+#: blocks a trained layer's sorted rows are walked in: sixteenths of the ``k n`` pairs
+ROW_BLOCKS = 16
+
+
+def row_block(pairs: int, tile: int) -> int:
+    """Rows of one block: a :data:`ROW_BLOCKS`-th of ``pairs`` in whole row tiles."""
+    return min(pairs, -(-pairs // (ROW_BLOCKS * tile)) * tile)
+
+
+def _held_blocks(block: int, held_rows):
+    """Blocks of ``block`` sorted rows that hold a pair: the first ones."""
+    return -(-held_rows // block)
+
+
+def _over_held_blocks(block: int, held_rows, body, *buffers):
+    """``body(start, *buffers) -> buffers`` for every block of ``block`` sorted rows
+    that holds a pair."""
+    return jax.lax.fori_loop(
+        0, _held_blocks(block, held_rows), lambda b, buffers: body(b * block, *buffers), buffers)
+
+
+def _rows_at(rows, start, block: int):
+    """``rows[start:start + block]``; a last block that would pass the end starts
+    earlier (what a dynamic slice does)."""
+    return jax.lax.dynamic_slice_in_dim(rows, start, block, axis=0)
+
+
+def _set_rows(rows, start, values):
+    """``rows`` with the block at ``start`` set to ``values``. The rows that a last
+    block which started earlier shares with the block before it keep what that
+    block wrote: they no longer hold what ``values`` was computed from."""
+    block, total = values.shape[0], rows.shape[0]
+    if total % block:
+        ahead = jnp.minimum(start, total - block) + jnp.arange(block) >= start
+        values = jnp.where(
+            ahead.reshape((block,) + (1,) * (values.ndim - 1)), values, _rows_at(rows, start, block))
+    return jax.lax.dynamic_update_slice_in_dim(rows, values, start, axis=0)
 
 
 @jax.custom_vjp
@@ -215,30 +266,83 @@ def _sorted_rows_bwd(kept, g):
 _sorted_rows.defvjp(_sorted_rows_fwd, _sorted_rows_bwd)
 
 
-@jax.custom_vjp
-def _unsorted_rows(out, held, order, place):
-    """The sorted rows ``out`` [k n, d] back pair by pair, [k, n, d], zeros for
-    the pairs not held."""
-    return jnp.where(held[:, :, None], out[place].reshape(held.shape + out.shape[1:]), 0)
+def _gate_of(gate_up):
+    f = gate_up.shape[1] // 2
+    return jax.nn.silu(gate_up[:, :f]) * gate_up[:, f:]
 
 
-def _unsorted_rows_fwd(out, held, order, place):
-    return _unsorted_rows(out, held, order, place), order
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _gated(block, gate_up, held_rows):
+    """``silu(gate) * up`` of the sorted rows ``gate_up`` [k n, 2f]: [k n, f]."""
+    return _gate_of(gate_up)
 
 
-def _unsorted_rows_bwd(order, g):
-    # a row of no group is read by neither of the grouped matmul's gradients
-    return g.reshape((-1,) + g.shape[2:])[order], None, None, None
+def _gated_fwd(block, gate_up, held_rows):
+    return _gate_of(gate_up), (gate_up, held_rows)
 
 
-_unsorted_rows.defvjp(_unsorted_rows_fwd, _unsorted_rows_bwd)
+def _gated_bwd(block, kept, g):
+    gate_up, held_rows = kept
+
+    def gate(start, gate_up):
+        _, vjp = jax.vjp(_gate_of, _rows_at(gate_up, start, block))
+        return (_set_rows(gate_up, start, vjp(_rows_at(g, start, block))[0]),)
+
+    return _over_held_blocks(block, held_rows, gate, gate_up)[0], None
+
+
+_gated.defvjp(_gated_fwd, _gated_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _combined(block, out, weights, held, order, place, held_rows):
+    """The sorted rows ``out`` [k n, d] back pair by pair, zeros for the pairs not
+    held, and their sum over a token's choices under its ``weights`` [n, k]: [n, d]
+    float32."""
+    per_pair = jnp.where(held[:, :, None], out[place].reshape(held.shape + out.shape[1:]), 0)
+    # one pass over the bfloat16 rows: the cast, the weight and the sum over the choices fuse
+    return (weights.T[:, :, None] * per_pair.astype(jnp.float32)).sum(0)
+
+
+def _combined_fwd(block, out, weights, held, order, place, held_rows):
+    return _combined(block, out, weights, held, order, place, held_rows), (
+        out, weights, held, order, place, held_rows)
+
+
+def _combined_bwd(block, kept, g):
+    """In the sorted rows' own order: a row's gradient is its pair's weight times its
+    token's ``g``, a pair's weight's gradient the row's product with that ``g``; a row
+    of no group is read by neither of the grouped matmul's gradients."""
+    out, weights, held, order, place, held_rows = kept
+    by_pair = weights.T.reshape(-1)
+
+    def unsort(start, out, d_by_row):
+        pairs = _rows_at(order, start, block)
+        g_rows = g[pairs % g.shape[0]]
+        d_rows = (by_pair[pairs][:, None] * g_rows).astype(out.dtype)
+        d_weight = (g_rows * _rows_at(out, start, block).astype(jnp.float32)).sum(-1)
+        return _set_rows(out, start, d_rows), _set_rows(d_by_row, start, d_weight)
+
+    d_out, d_by_row = _over_held_blocks(
+        block, held_rows, unsort, out, jnp.zeros(out.shape[:1], jnp.float32))
+    d_weights = jnp.where(held, d_by_row[place].reshape(held.shape), 0).T
+    return d_out, d_weights.astype(weights.dtype), None, None, None, None
+
+
+_combined.defvjp(_combined_fwd, _combined_bwd)
+
+
+#: what :func:`trained_experts_ffn` counts: :data:`COUNTERS`' four and the sorted rows
+#: its backward's loops walk (the blocks that hold a pair, in rows)
+TRAINED_COUNTERS = COUNTERS + ("moe_rows_visited",)
 
 
 def trained_experts_ffn(x, weights, experts, wi, wo, offset: int = 0, tiling=GMM_TRAIN_TILING):
     """:func:`held_experts_ffn` for a train step: the same part of the layer, the
-    same counters, every pair whose expert is held computed, and a gradient for
-    ``x``, ``weights`` (through which the router is trained), ``wi`` and ``wo``;
-    ``experts`` are integers and pass none. Every token is real.
+    same counters and one more (:data:`TRAINED_COUNTERS`), every pair whose expert
+    is held computed, and a gradient for ``x``, ``weights`` (through which the
+    router is trained), ``wi`` and ``wo``; ``experts`` are integers and pass none.
+    Every token is real.
 
     What differs is what a backward pass and some thousand rows an expert ask
     for. The grouped matmuls run with ``tiling``. The pairs are laid out
@@ -247,9 +351,11 @@ def trained_experts_ffn(x, weights, experts, wi, wo, offset: int = 0, tiling=GMM
     its ``k`` to a whole tile of 8). The pairs of absent experts are sorted last
     and lie in no group: the kernel leaves their rows undefined, in its result
     and in the gradient of its rows alike; between the sort and the un-sort,
-    which mask them, a row meets only its own values."""
+    which mask them, a row meets only its own values. And the backward's passes
+    between them stop at the last block of :func:`row_block` rows that holds a
+    pair (above); with every pair held that is all ``k n`` rows. Nothing is dropped."""
     n, k = experts.shape
-    num_held, f = wo.shape[-3], wo.shape[-2]
+    num_held = wo.shape[-3]
     local = experts.T - offset
     held = (local >= 0) & (local < num_held)                      # [k, n]
     key = jnp.where(held, local, num_held).reshape(k * n)
@@ -258,16 +364,15 @@ def trained_experts_ffn(x, weights, experts, wi, wo, offset: int = 0, tiling=GMM
         key[:, None] == jnp.arange(num_held, dtype=key.dtype)[None, :]
     ).sum(0, dtype=jnp.int32)
     place = jnp.zeros_like(order).at[order].set(jnp.arange(k * n, dtype=order.dtype))
+    held_rows, block = group_sizes.sum(dtype=jnp.int32), row_block(k * n, tiling[0])
     rows = _sorted_rows(x, held, order, place)                    # [k n, d]
     gate_up = grouped_matmul(rows, wi.astype(x.dtype), group_sizes, tiling=tiling)
-    act = jax.nn.silu(gate_up[:, :f]) * gate_up[:, f:]
+    act = _gated(block, gate_up, held_rows)
     out = grouped_matmul(act, wo.astype(x.dtype), group_sizes, tiling=tiling)
-    per_pair = _unsorted_rows(out, held, order, place)            # [k, n, d]
-    # one pass over the bfloat16 rows: the cast, the weight and the sum over the choices fuse
-    y = (weights.T[:, :, None] * per_pair.astype(jnp.float32)).sum(0)
+    y = _combined(block, out, weights, held, order, place, held_rows)
     counters = jnp.stack([
-        jnp.int32(n), group_sizes.sum(dtype=jnp.int32),
-        (group_sizes > 0).sum(dtype=jnp.int32), group_sizes.max(),
+        jnp.int32(n), held_rows, (group_sizes > 0).sum(dtype=jnp.int32), group_sizes.max(),
+        jnp.minimum(_held_blocks(block, held_rows) * block, k * n),
     ])
     return y, counters
 

@@ -146,11 +146,18 @@ def test_lfm2_moe_share_train_step_compiles_and_copies_no_expert_stack(v5e, buil
     the grouped matmuls of four expert layers are in it (forward, the replay,
     both gradients: 8 a layer); and no instruction copies, transposes or slices
     out a layer's stack of experts (604 MB), as a scan over stacked layers or a
-    kernel that wants a whole operand would make it."""
+    kernel that wants a whole operand would make it.
+
+    The backward's passes over sorted rows are loops over the blocks that hold a
+    pair, a block's gradient written where the value it is the gradient of lay: two
+    loops an expert layer, which carry ``gate_up`` [65536, 3072] and ``out``
+    [65536, 2048] and copy neither, and the step stays within 1 % of the parent's
+    16,489,634,304 B."""
     import json
 
     from benchmark.manifest import published_keys
     from benchmark.models import lfm2_moe as architecture
+    from ray_tpu.models import moe
 
     built_for_tpu(True)
     with open(os.path.join(
@@ -175,6 +182,15 @@ def test_lfm2_moe_share_train_step_compiles_and_copies_no_expert_stack(v5e, buil
     assert memory.argument_size_in_bytes > 6 * cfg.num_params()        # weights and two moments
     assert _device_bytes(compiled) < HBM_BYTES
     assert text.count('custom_call_target="tpu_custom_call"') == file["job"]["min_flash_kernels"] == 35
+    assert _device_bytes(compiled) <= 1.01 * 16_489_634_304
+    pairs = batch[0] * batch[1] * cfg.experts_per_token
+    assert moe.row_block(pairs, moe.GMM_TRAIN_TILING[0]) * moe.ROW_BLOCKS == pairs == 65536
+    sorted_rows = r"bf16\[65536,(1536|2048|3072)\]"
+    loops = [line for line in text.splitlines() if re.search(r" while\(", line)]
+    for carried in ("bf16[65536,3072]", "bf16[65536,2048]"):
+        assert sum(carried in line and "train.moe.experts" in line for line in loops) >= 4, carried
+    copied = re.findall(rf"= {sorted_rows}\S* copy\(", text)
+    assert not copied, copied[:3]
     stack = r"(bf16|f32)\[(1,)?32,(2048,3072|1536,2048)\]"
     moved = re.findall(
         rf"= {stack}\S* (?:copy|transpose|dynamic-slice|dynamic-update-slice)\(", text)

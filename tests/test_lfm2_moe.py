@@ -148,8 +148,8 @@ def test_one_update_is_optax_on_the_references_gradients(nano):
             jax.tree.leaves((weights["wte"], weights["ln_f"], weights["layers"]))):
         assert np.abs(np.asarray(w) - np.asarray(old)).max() > 5e-4       # it moved
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=2e-5)
-    assert {*moe.COUNTERS, "loss", "grad_norm", "step"} == set(metrics)
-    assert all(np.asarray(metrics[name]).dtype == np.int32 for name in moe.COUNTERS)
+    assert {*moe.COUNTERS, "moe_rows_visited", "loss", "grad_norm", "step"} == set(metrics)
+    assert all(np.asarray(metrics[name]).dtype == np.int32 for name in moe.TRAINED_COUNTERS)
 
 
 def test_the_seeded_bias_moves_the_chosen_set_of_many_tokens(nano):
@@ -290,6 +290,139 @@ def test_rows_of_no_group_reach_no_token_through_the_kernels_path(kernel, monkey
     for g, w in zip(got[1], want[1]):
         assert np.isfinite(np.asarray(g)).all()
         close(g, w, 1e-5)
+
+
+# -- the work follows the pairs held: sorted rows walked block by block -----------
+
+
+PAIRS_N, PAIRS_K, HELD_EXPERTS, TILE = 32, 2, 3, (16, 32, 128)
+BLOCK, BLOCK_ENDS = 16, (16, 32, 48, 64)
+KERNELS = {
+    "ragged": None,
+    "interpreted": lambda plain: lambda *a, **kw: plain(*a, interpret=True, **kw),
+    "poisoned": lambda plain: _poisoned_ragged_dot,
+}
+
+
+def routing_that_holds(pairs):
+    """``experts`` [n, k] of which exactly ``pairs`` choices, scattered over the
+    tokens, are held experts' (one held expert has none), the rest absent ones'."""
+    total = PAIRS_N * PAIRS_K
+    at = np.random.RandomState(pairs).permutation(total)
+    experts = HELD_EXPERTS + np.arange(total) % HELD_EXPERTS          # absent: 3, 4, 5
+    experts[at[:pairs]] = np.where(np.arange(pairs) % 3, 2, 0)       # held: 0 and 2
+    return jnp.asarray(experts.reshape(PAIRS_N, PAIRS_K), jnp.int32)
+
+
+def blocked_layer(experts, kernel, monkeypatch, blocks=None):
+    """Value, counters and the four gradients of one trained layer through
+    ``kernel``'s grouped matmul, its rows in ``blocks`` blocks (None: the program's own)."""
+    d, f = 32, 64
+    x = jax.random.normal(jax.random.PRNGKey(0), (PAIRS_N, d))
+    weights = jax.random.uniform(jax.random.PRNGKey(1), (PAIRS_N, PAIRS_K), minval=0.2)
+    wi = jax.random.normal(jax.random.PRNGKey(3), (HELD_EXPERTS, d, 2 * f)) * 0.1
+    wo = jax.random.normal(jax.random.PRNGKey(4), (HELD_EXPERTS, f, d)) * 0.1
+    up = jax.random.normal(jax.random.PRNGKey(5), (PAIRS_N, d))
+
+    def scalar(x, weights, wi, wo):
+        y, counters = moe.trained_experts_ffn(x, weights, experts, wi, wo, tiling=TILE)
+        return (y * up).sum(), (y, counters)
+
+    with monkeypatch.context() as m:
+        if KERNELS[kernel]:
+            m.setattr(moe, "grouped_matmul", KERNELS[kernel](moe.grouped_matmul))
+        if blocks:
+            m.setattr(moe, "ROW_BLOCKS", blocks)
+        (_, (y, counters)), grads = jax.value_and_grad(scalar, (0, 1, 2, 3), has_aux=True)(
+            x, weights, wi, wo)
+    return y, dict(zip(moe.TRAINED_COUNTERS, map(int, counters))), grads
+
+
+def test_a_block_is_whole_row_tiles_and_never_more_than_every_pair():
+    assert moe.row_block(PAIRS_N * PAIRS_K, TILE[0]) == BLOCK
+    assert moe.row_block(4 * 16384, moe.GMM_TRAIN_TILING[0]) == 4096      # the cell's: sixteenths
+    assert moe.row_block(80, 16) == 16
+    assert moe.row_block(24, 512) == 24            # fewer rows than a tile: one block
+
+
+@pytest.mark.parametrize("kernel", list(KERNELS))
+@pytest.mark.parametrize("pairs", sorted(
+    {b + by for b in BLOCK_ENDS for by in (-1, 0, 1)} - {PAIRS_N * PAIRS_K + 1}))
+def test_a_blocks_edge_gives_what_every_row_gives(pairs, kernel, monkeypatch):
+    """One pair less than whole blocks, whole blocks, and one pair more: the
+    result, the four counters and the gradients of the tokens, the weights and
+    both expert stacks are those of the layer that walks all ``k n`` rows, the
+    parent's."""
+    experts = routing_that_holds(pairs)
+    y, counters, grads = blocked_layer(experts, kernel, monkeypatch)
+    want_y, want_counters, want_grads = blocked_layer(experts, kernel, monkeypatch, blocks=1)
+    assert want_counters["moe_rows_visited"] == PAIRS_N * PAIRS_K
+    assert counters["moe_rows_visited"] == next(b for b in BLOCK_ENDS if b >= pairs)
+    assert counters["moe_assignments"] == pairs
+    assert {k: counters[k] for k in moe.COUNTERS} == {k: want_counters[k] for k in moe.COUNTERS}
+    # a row meets its own values alone, whatever the rows beside it; the CPU's
+    # matmul rounds a row's sums by the number of rows, so not bit for bit
+    close(y, want_y, 1e-5)
+    for g, w in zip(grads, want_grads):
+        assert np.isfinite(np.asarray(g)).all()
+        close(g, w, 1e-5)
+
+
+@pytest.mark.parametrize("kernel", list(KERNELS))
+def test_every_pair_held_walks_every_row_and_drops_nothing(kernel, monkeypatch):
+    """The worst case the shapes are made for: all chosen experts live here."""
+    experts = routing_that_holds(PAIRS_N * PAIRS_K)
+    assert int((experts < HELD_EXPERTS).sum()) == PAIRS_N * PAIRS_K
+    y, counters, grads = blocked_layer(experts, kernel, monkeypatch)
+    assert counters["moe_assignments"] == counters["moe_rows_visited"] == PAIRS_N * PAIRS_K
+    want_y, _, want_grads = blocked_layer(experts, "ragged", monkeypatch, blocks=1)
+    close(y, want_y, 1e-5)
+    for g, w in zip(grads, want_grads):
+        close(g, w, 1e-5)
+    assert all(np.abs(np.asarray(g)).max() > 0 for g in grads)
+
+
+@pytest.mark.parametrize("pairs", [0, 1, 15, 16, 17, 31, 33, 40, 48, 49, 63, 64])
+def test_rows_visited_are_the_blocks_that_hold_a_pair(pairs, monkeypatch):
+    y, counters, grads = blocked_layer(routing_that_holds(pairs), "ragged", monkeypatch)
+    visited = counters["moe_rows_visited"]
+    assert visited % BLOCK == 0 and visited >= counters["moe_assignments"] == pairs
+    assert visited - pairs < BLOCK                      # under a block's rows in vain
+    if not pairs:
+        assert not np.asarray(y).any() and not any(np.asarray(g).any() for g in grads)
+
+
+@pytest.mark.parametrize("pairs", [70, 80])
+def test_a_last_block_that_starts_early_leaves_the_rows_before_it_alone(pairs, monkeypatch):
+    """80 pairs in blocks of 32 (``ROW_BLOCKS`` 3): the third block is rows 48..79."""
+    n, k, d, f = 40, 2, 32, 64
+    x = jax.random.normal(jax.random.PRNGKey(0), (n, d))
+    weights = jax.random.uniform(jax.random.PRNGKey(1), (n, k), minval=0.2)
+    experts = jnp.where(jnp.arange(n * k).reshape(n, k) < pairs, 0, 3) + jnp.arange(k) % 2
+    wi = jax.random.normal(jax.random.PRNGKey(3), (HELD_EXPERTS, d, 2 * f)) * 0.1
+    wo = jax.random.normal(jax.random.PRNGKey(4), (HELD_EXPERTS, f, d)) * 0.1
+
+    def through(blocks):
+        monkeypatch.setattr(moe, "ROW_BLOCKS", blocks)
+        assert moe.row_block(n * k, TILE[0]) == {3: 32, 1: 80}[blocks]
+        return jax.value_and_grad(
+            lambda *a: (moe.trained_experts_ffn(a[0], a[1], experts, a[2], a[3], tiling=TILE)[0]
+                        ** 2).sum(), (0, 1, 2, 3))(x, weights, wi, wo)
+
+    got, want = through(3), through(1)
+    close(got[0], want[0], 1e-5)
+    for g, w in zip(got[1], want[1]):
+        close(g, w, 1e-5)
+
+
+def test_the_step_sums_the_layers_rows_visited(nano):
+    cfg, params, tokens = nano
+    _, counters = program_loss(cfg, params, tokens)
+    pairs = BATCH[0] * BATCH[1] * cfg.experts_per_token
+    block = moe.row_block(pairs, moe.GMM_TRAIN_TILING[0])
+    visited, held = int(counters["moe_rows_visited"]), int(counters["moe_assignments"])
+    # four expert layers, each under a block's rows in vain
+    assert held <= visited < held + 4 * block and visited <= 4 * pairs
 
 
 def test_the_published_layout_scans_nine_periods_and_runs_two_layers_behind_them():
