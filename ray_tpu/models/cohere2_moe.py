@@ -34,13 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models import moe
-from ray_tpu.models.gpt import _rotary
-
-#: queries attended at a time: the float32 scores of one block are ``lanes x
-#: heads x QUERY_BLOCK x cache`` (134 MB a lane at 128 heads and an 8192
-#: cache; the whole 256-token chunk at once would be 1.07 GB a lane)
-QUERY_BLOCK = 32
+from ray_tpu.models import layers, moe
 
 
 @dataclasses.dataclass(frozen=True)
@@ -136,22 +130,16 @@ def init_params(cfg: Cohere2MoeConfig, seed: int = 0):
 
     @jax.jit
     def init(rng):
-        keys = dict(zip(shapes, jax.random.split(rng, len(shapes))))
-        w = {
-            # drawn in the type they are served in: no float32 copy of 4 GB
-            name: jax.random.normal(keys[name], shape, cfg.param_dtype)
-            * jnp.asarray(0.02, cfg.param_dtype)
-            for name, shape in shapes.items()
-        }
+        w = layers.drawn(jax.random.split(rng, len(shapes)), shapes, cfg.param_dtype)
         return {
             "wte": {"embedding": w["wte"]},
             "blocks": {"layers": {
-                "ln": {"scale": jnp.ones((L, d), cfg.param_dtype)},
+                "ln": layers.ones_scale(cfg.param_dtype, L, d),
                 "attn": {n: {"kernel": w[n]} for n in ("q", "k", "v", "o")},
                 "moe": {"router": w["router"], "wi": w["wi"], "wo": w["wo"]},
                 "shared": {"wi": w["shared_wi"], "wo": w["shared_wo"]},
             }},
-            "ln_f": {"scale": jnp.ones((d,), cfg.param_dtype)},
+            "ln_f": layers.ones_scale(cfg.param_dtype, d),
         }
 
     return jax.block_until_ready(init(jax.random.PRNGKey(seed)))
@@ -174,12 +162,6 @@ def make_extend_fn(cfg: Cohere2MoeConfig):
     scale = 1.0 / float(np.sqrt(cfg.head_dim))
     groups = cfg.num_heads // cfg.kv_heads
 
-    def _ln(x, p):
-        xf = x.astype(jnp.float32)
-        mean = xf.mean(-1, keepdims=True)
-        var = ((xf - mean) ** 2).mean(-1, keepdims=True)
-        return (xf - mean) * jax.lax.rsqrt(var + cfg.norm_eps) * p["scale"].astype(jnp.float32)
-
     @jax.named_scope("extend.attention")
     def _attend(p, hidden, positions, kc, vc, sliding):
         b, tc = positions.shape
@@ -188,14 +170,14 @@ def make_extend_fn(cfg: Cohere2MoeConfig):
         v = jnp.einsum("btd,dhk->bthk", hidden, p["v"]["kernel"].astype(dtype))
 
         def rotated(x):         # a full layer has no position embedding
-            turned = _rotary(x.astype(jnp.float32), positions, cfg.head_dim, cfg.rope_base)
+            turned = layers.rotary(
+                x.astype(jnp.float32), positions, cfg.head_dim, cfg.rope_base)
             return jnp.where(sliding, turned, x.astype(jnp.float32)).astype(dtype)
 
         q, k = rotated(q), rotated(k)
         lane = jnp.arange(b)[:, None]
-        # out-of-capacity writes drop instead of clamping onto slot T-1
-        kc = kc.at[lane, positions].set(k, mode="drop")
-        vc = vc.at[lane, positions].set(v, mode="drop")
+        kc = layers.write_rows(kc, lane, positions, k)
+        vc = layers.write_rows(vc, lane, positions, v)
         kpos = jnp.arange(kc.shape[1], dtype=jnp.int32)
         window = jnp.where(sliding, cfg.sliding_window, kc.shape[1] + tc)
 
@@ -206,10 +188,10 @@ def make_extend_fn(cfg: Cohere2MoeConfig):
             ) * scale
             behind = pos[:, :, None] - kpos[None, None, :]          # [b, n, cache]
             mask = ((behind >= 0) & (behind < window))[:, None, None]
-            w = jax.nn.softmax(jnp.where(mask, scores, jnp.float32(-1e30)), axis=-1)
+            w = jax.nn.softmax(jnp.where(mask, scores, jnp.float32(layers.MASKED)), axis=-1)
             return jnp.einsum("bhgqk,bkhd->bqhgd", w.astype(dtype), vc)
 
-        n = QUERY_BLOCK if tc % QUERY_BLOCK == 0 else tc
+        n = layers.query_block(tc)
         q = q.reshape(b, tc // n, n, cfg.kv_heads, groups, cfg.head_dim)
         out = jax.lax.map(
             attend_block, (q.swapaxes(0, 1), positions.reshape(b, tc // n, n).swapaxes(0, 1)))
@@ -238,33 +220,25 @@ def make_extend_fn(cfg: Cohere2MoeConfig):
 
     @jax.jit
     def extend(params, tokens, lengths, k_cache, v_cache):
-        tc = tokens.shape[1]
-        positions = (
-            lengths[:, None].astype(jnp.int32) + jnp.arange(tc, dtype=jnp.int32)[None, :])
-        valid = tokens >= 0
+        positions, valid = layers.frame(tokens, lengths)
         with jax.named_scope("extend.embed"):
             emb = params["wte"]["embedding"].astype(dtype)
-            x = emb[jnp.clip(tokens, 0, cfg.vocab_size - 1)]
-
-        # the routed experts stay out of the scan: every layer's grouped matmul
-        # reads them in place from the whole stack (``moe.held_experts_ffn``)
-        layers = dict(params["blocks"]["layers"])
-        stacked = layers.pop("moe")
-        experts = {"wi": stacked["wi"], "wo": stacked["wo"]}
+            x = layers.look_up(emb, tokens)
+        scanned, routing, experts = layers.without_experts(params["blocks"]["layers"])
 
         def body(carry, xs):
             p, router, kc, vc, sliding, layer = xs
-            normed = _ln(carry, p["ln"])
+            normed = layers.layer_norm(carry, p["ln"]["scale"], cfg.norm_eps)
             a, k, v = _attend(p["attn"], normed.astype(dtype), positions, kc, vc, sliding)
             f, counters = _ffn(router, experts, layer, p["shared"], normed, valid)
             return carry + a + f, (k, v, counters)
 
         x, (k_new, v_new, counters) = jax.lax.scan(
             body, x, (
-                layers, stacked["router"], k_cache, v_cache,
+                scanned, routing["router"], k_cache, v_cache,
                 jnp.asarray(cfg.sliding_layers), jnp.arange(cfg.num_layers, dtype=jnp.int32)))
         with jax.named_scope("extend.logits"):
-            x = _ln(x, params["ln_f"])
+            x = layers.layer_norm(x, params["ln_f"]["scale"], cfg.norm_eps)
             logits = cfg.logit_scale * jnp.dot(
                 x.astype(dtype), emb.T, preferred_element_type=jnp.float32)
         return logits, x, k_new, v_new, counters.sum(0)

@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu.models import layers
 from ray_tpu.ops.attention import FLASH_RESIDUALS
 from ray_tpu.ops.ring import mesh_attention
 from ray_tpu.parallel import ring_dense
@@ -189,28 +190,6 @@ def gpt_j_6b(**kw) -> GPTConfig:
 
 
 # ---------------------------------------------------------------------------
-# rotary position embedding
-# ---------------------------------------------------------------------------
-
-
-def _rotary(x: jax.Array, positions: jax.Array, rotary_dim: int,
-            base: float = 10000.0, freqs=None) -> jax.Array:
-    """Apply RoPE of frequency base ``base``, or at the ``rotary_dim / 2`` given ``freqs``
-    (``models/kimi_k2.py``'s are YaRN's), to the first ``rotary_dim`` features of [b, t, h, d]."""
-    if rotary_dim <= 0:
-        return x
-    rot, keep = x[..., :rotary_dim], x[..., rotary_dim:]
-    half = rotary_dim // 2
-    freqs = 1.0 / (base ** (jnp.arange(half, dtype=jnp.float32) / half)) if freqs is None else freqs
-    angles = positions[:, :, None].astype(jnp.float32) * freqs  # [b, t, half]
-    sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
-    cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)
-    r1, r2 = rot[..., :half], rot[..., half:]
-    rotated = jnp.concatenate([r1 * cos - r2 * sin, r2 * cos + r1 * sin], axis=-1)
-    return jnp.concatenate([rotated, keep], axis=-1)
-
-
-# ---------------------------------------------------------------------------
 # modules
 # ---------------------------------------------------------------------------
 
@@ -290,8 +269,8 @@ def _dense(features: Tuple[int, ...], logical_axes: Tuple[str, ...], cfg: GPTCon
 def _attend(cfg: GPTConfig, mesh: Any, q: jax.Array, k: jax.Array, v: jax.Array,
             positions: jax.Array) -> jax.Array:
     """Causal attention of [b, t, h, d] projections, rotated here."""
-    q = _rotary(q, positions, cfg.rotary_dim)
-    k = _rotary(k, positions, cfg.rotary_dim)
+    q = layers.rotary(q, positions, cfg.rotary_dim)
+    k = layers.rotary(k, positions, cfg.rotary_dim)
     # [b, t, h, d] → [b, h, t, d] for the fused kernel
     qh, kh, vh = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
     # the fused kernel, per shard under shard_map when the mesh has more
@@ -607,19 +586,17 @@ def make_extend_fn(cfg: GPTConfig):
         q = jnp.einsum("btd,dhk->bthk", hidden, p["q"]["kernel"].astype(dtype))
         k = jnp.einsum("btd,dhk->bthk", hidden, p["k"]["kernel"].astype(dtype))
         v = jnp.einsum("btd,dhk->bthk", hidden, p["v"]["kernel"].astype(dtype))
-        q = _rotary(q, positions, cfg.rotary_dim)
-        k = _rotary(k, positions, cfg.rotary_dim)
-        b = positions.shape[0]
-        lane = jnp.arange(b)[:, None]
-        # out-of-capacity writes drop instead of clamping onto slot T-1
-        kc = kc.at[lane, positions].set(k, mode="drop")
-        vc = vc.at[lane, positions].set(v, mode="drop")
+        q = layers.rotary(q, positions, cfg.rotary_dim)
+        k = layers.rotary(k, positions, cfg.rotary_dim)
+        lane = jnp.arange(positions.shape[0])[:, None]
+        kc = layers.write_rows(kc, lane, positions, k)
+        vc = layers.write_rows(vc, lane, positions, v)
         scores = jnp.einsum(
             "bqhd,bkhd->bhqk", q, kc, preferred_element_type=jnp.float32
         ) * scale
         kpos = jnp.arange(kc.shape[1], dtype=jnp.int32)
         mask = (kpos[None, None, :] <= positions[:, :, None])[:, None, :, :]
-        scores = jnp.where(mask, scores, jnp.float32(-1e30))
+        scores = jnp.where(mask, scores, jnp.float32(layers.MASKED))
         w = jax.nn.softmax(scores, axis=-1).astype(dtype)
         out = jnp.einsum("bhqk,bkhd->bqhd", w, vc)
         out = jnp.einsum("bqhd,hde->bqe", out, p["o"]["kernel"].astype(dtype))
@@ -632,22 +609,18 @@ def make_extend_fn(cfg: GPTConfig):
 
     @jax.jit
     def extend(params, tokens, lengths, k_cache, v_cache):
-        tc = tokens.shape[1]
-        positions = (
-            lengths[:, None].astype(jnp.int32)
-            + jnp.arange(tc, dtype=jnp.int32)[None, :]
-        )
+        positions, _ = layers.frame(tokens, lengths)    # padding is computed like a token here
         with jax.named_scope("extend.embed"):
             emb = params["wte"]["embedding"].astype(dtype)
-            x = emb[jnp.clip(tokens, 0, cfg.vocab_size - 1)]
-        layers = params["blocks"]["layers"]    # stacked [num_layers, ...] by the scan
+            x = layers.look_up(emb, tokens)
+        stacked = params["blocks"]["layers"]    # [num_layers, ...] by the scan
 
         def body(carry, xs):
             p, kc, vc = xs
             y, k, v = _block(carry, p, positions, kc, vc)
             return y, (k, v)
 
-        x, (k_new, v_new) = jax.lax.scan(body, x, (layers, k_cache, v_cache))
+        x, (k_new, v_new) = jax.lax.scan(body, x, (stacked, k_cache, v_cache))
         with jax.named_scope("extend.logits"):
             x = _ln(x, params["ln_f"])
             if cfg.tie_embeddings:

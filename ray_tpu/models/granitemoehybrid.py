@@ -55,10 +55,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu.models import layers
 from ray_tpu.ops import attention, backend
-
-#: queries attended at a time in a prefill chunk off the chip
-QUERY_BLOCK = 32
 
 #: what ``extend`` counts over the real lanes and tokens of a device call,
 #: summed over the Mamba layers: tokens through the recurrence, and states read
@@ -207,16 +205,10 @@ def init_params(cfg: GraniteMoeHybridConfig, seed: int = 0):
     mlp = {"wi": (P, d, 2 * cfg.mlp_dim), "wo": (P, cfg.mlp_dim, d)}
     bound = cfg.conv_width ** -0.5
 
-    def normal(key, shape):
-        # drawn in the type they are served in: no float32 copy of 6 GB
-        return jax.random.normal(key, shape, cfg.param_dtype) * jnp.asarray(0.02, cfg.param_dtype)
-
     def drawn(key, shapes):
-        return {n: normal(k, s) for (n, s), k in zip(
-            shapes.items(), jax.random.split(key, len(shapes)))}
+        return layers.drawn(jax.random.split(key, len(shapes)), shapes, cfg.param_dtype)
 
-    def ones(*shape):
-        return {"scale": jnp.ones(shape, cfg.param_dtype)}
+    ones = functools.partial(layers.ones_scale, cfg.param_dtype)
 
     def mamba_layer(key):
         k_w, k_conv, k_bias, k_a, k_dt = jax.random.split(key, 5)
@@ -243,7 +235,7 @@ def init_params(cfg: GraniteMoeHybridConfig, seed: int = 0):
     def init(rng):
         k_wte, k_attn, *keys = jax.random.split(rng, 2 + M + L)
         return {
-            "wte": {"embedding": normal(k_wte, (cfg.vocab_size, d))},
+            "wte": {"embedding": layers.normal(k_wte, (cfg.vocab_size, d), cfg.param_dtype)},
             "periods": {
                 "mamba": tuple(mamba_layer(k) for k in keys[:M]),
                 "attn": {
@@ -428,18 +420,8 @@ def make_extend_fn(cfg: GraniteMoeHybridConfig):
     groups = cfg.num_heads // cfg.kv_heads
     scale, res = float(cfg.attention_multiplier), cfg.residual_multiplier
 
-    def _rms(x, p):
-        xf = x.astype(f32)
-        return xf * jax.lax.rsqrt((xf * xf).mean(-1, keepdims=True) + cfg.norm_eps) * (
-            p["scale"].astype(f32))
-
-    def _by_block(fn, *per_query):
-        b, tc = per_query[0].shape[:2]
-        size = QUERY_BLOCK if tc % QUERY_BLOCK == 0 else tc
-        split = tuple(
-            x.reshape((b, tc // size, size) + x.shape[2:]).swapaxes(0, 1) for x in per_query)
-        out = jax.lax.map(lambda block: fn(*block), split)
-        return out.swapaxes(0, 1).reshape((b, tc) + out.shape[3:])
+    def _normed(x, p):
+        return layers.rms_norm(x, p["scale"], cfg.norm_eps)
 
     # An arena is read and written one slot at a time, with a dynamic slice and
     # an in-place dynamic update: indexed with the slots (``arena[at, slots]``)
@@ -523,7 +505,7 @@ def make_extend_fn(cfg: GraniteMoeHybridConfig):
         y = y + p["D"][:, None] * x.astype(f32)
         y = y.reshape(b, tc, inner) * jax.nn.silu(z.astype(f32))
         out = jnp.dot(
-            _rms(y, p["norm"]).astype(dtype), p["out"]["kernel"].astype(dtype),
+            _normed(y, p["norm"]).astype(dtype), p["out"]["kernel"].astype(dtype),
             preferred_element_type=f32)
         return out, ssm, tails
 
@@ -536,46 +518,37 @@ def make_extend_fn(cfg: GraniteMoeHybridConfig):
         k = (hidden @ p["k"]["kernel"].astype(dtype))[:, :, None]   # one row for all K/V heads
         v = (hidden @ p["v"]["kernel"].astype(dtype))[:, :, None]
         lane = jnp.arange(b)[:, None]
-        # out-of-capacity writes drop instead of clamping onto slot T-1
-        kc = kc.at[lane, positions].set(k, mode="drop").reshape(b, cap, cfg.kv_heads, -1)
-        vc = vc.at[lane, positions].set(v, mode="drop").reshape(b, cap, cfg.kv_heads, -1)
+        kc = layers.write_rows(kc, lane, positions, k).reshape(b, cap, cfg.kv_heads, -1)
+        vc = layers.write_rows(vc, lane, positions, v).reshape(b, cap, cfg.kv_heads, -1)
 
-        def attend_block(qb, mask):             # [b, n, kv, g, hd], [b, n, cache]
-            logit = jnp.einsum(
-                "bqhgd,bkhd->bhgqk", qb, kc, preferred_element_type=f32) * scale
-            weight = jax.nn.softmax(
-                jnp.where(mask[:, None, None], logit, f32(-1e30)), axis=-1)
-            return jnp.einsum("bhgqk,bkhd->bqhgd", weight.astype(dtype), vc)
+        def attend_block(qb, mask):
+            return layers.plain_attend(qb, kc, vc, mask, scale)
 
         if tc > 1 and backend.on_tpu():
             out = attention.masked_attention(q, kc, vc, visible, live, scale=scale)
         else:
-            out = attend_block(q, visible) if tc == 1 else _by_block(attend_block, q, visible)
+            out = (
+                attend_block(q, visible) if tc == 1
+                else layers.by_query_block(attend_block, q, visible))
         out = jnp.dot(
             out.reshape(b, tc, -1), p["o"]["kernel"].astype(dtype), preferred_element_type=f32)
         return out, (k, v)
 
     @jax.named_scope("extend.mlp")
     def _mlp(x, p):
-        gate_up = _rms(x, p["ln"]).astype(dtype) @ p["wi"].astype(dtype)
-        return jnp.dot(
-            jax.nn.silu(gate_up[..., :cfg.mlp_dim]) * gate_up[..., cfg.mlp_dim:],
-            p["wo"].astype(dtype), preferred_element_type=f32)
+        return layers.gated_mlp(_normed(x, p["ln"]).astype(dtype), p["wi"], p["wo"])
 
     def _add(x, out):
         return x + (res * out).astype(dtype)
 
     @jax.jit
     def extend(params, tokens, lengths, k_cache, v_cache, ssm, conv, slots, snap_at, snap_slots):
-        b, tc = tokens.shape
-        positions = (
-            lengths[:, None].astype(jnp.int32) + jnp.arange(tc, dtype=jnp.int32)[None, :])
-        valid, fresh = tokens >= 0, lengths == 0
-        kpos = jnp.arange(k_cache.shape[2], dtype=jnp.int32)
-        visible = (kpos[None, None, :] <= positions[:, :, None]) & valid[:, :, None]
-        live = jnp.where(valid, positions + 1, 0).max(1)
+        tc = tokens.shape[1]
+        (positions, valid), fresh = layers.frame(tokens, lengths), lengths == 0
+        visible = layers.visible_keys(positions, valid, k_cache.shape[2])
+        live = layers.live_keys(positions, valid)
         with jax.named_scope("extend.embed"):
-            x = params["wte"]["embedding"].astype(dtype)[jnp.clip(tokens, 0, cfg.vocab_size - 1)]
+            x = layers.look_up(params["wte"]["embedding"].astype(dtype), tokens)
             x = x * jnp.asarray(cfg.embedding_multiplier, dtype)
 
         def body(carry, xs):
@@ -587,12 +560,12 @@ def make_extend_fn(cfg: GraniteMoeHybridConfig):
             for i in range(cfg.period):
                 if i == cfg.attention_at:
                     out, rows = _attend(
-                        p["attn"], _rms(x, p["attn"]["ln"]).astype(dtype), positions,
+                        p["attn"], _normed(x, p["attn"]["ln"]).astype(dtype), positions,
                         visible, live, kc, vc)
                 else:
                     layer = p["mamba"][m]
                     out, ssm, tails = _mamba(
-                        layer, _rms(x, layer["ln"]).astype(dtype), valid, fresh, slots,
+                        layer, _normed(x, layer["ln"]).astype(dtype), valid, fresh, slots,
                         snap_at, snap_slots, ssm, tails, period * (cfg.period - 1) + m)
                     m += 1
                 x = _add(x, out)
@@ -612,7 +585,7 @@ def make_extend_fn(cfg: GraniteMoeHybridConfig):
             conv = _put(conv, snap_slots, tails[1])
         conv = _put(conv, slots, tails[0])
         with jax.named_scope("extend.logits"):
-            x = _rms(x, params["ln_f"])
+            x = _normed(x, params["ln_f"])
             logits = jnp.dot(
                 x.astype(dtype), params["wte"]["embedding"].astype(dtype).T,
                 preferred_element_type=f32) / cfg.logits_scaling

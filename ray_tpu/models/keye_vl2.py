@@ -7,7 +7,7 @@ what a query reads and in what a token leaves behind:
 
 * attention is grouped (``num_heads // kv_heads`` query heads read one K/V
   head), q and k are RMS-normed per head and rotated over every feature of a
-  head (``gpt._rotary``'s half-split pairing, which is the family's own);
+  head (``layers.rotary``'s half-split pairing, which is the family's own);
 * a learned **indexer** decides which keys a query reads. Every token leaves
   an indexer key ``kI`` [``index_dim``] beside its K and V; a query ``t`` has
   ``index_heads`` indexer queries ``qI`` and as many weights ``w``, scores
@@ -40,20 +40,15 @@ published rotation splits its frequencies over three position streams
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models import moe
-from ray_tpu.models.gpt import _rotary
+from ray_tpu.models import layers, moe
 from ray_tpu.ops import attention, backend
-
-#: queries scored or attended at a time in a prefill chunk: the float32 scores
-#: of one block are ``lanes x heads x QUERY_BLOCK x cache`` (134 MB a lane for
-#: 32 heads and a 32768 cache)
-QUERY_BLOCK = 32
 
 #: what the indexer counts over the real queries of a device call, summed over
 #: the layers: queries that passed an indexer, live causal query-key pairs it
@@ -151,16 +146,8 @@ def init_params(cfg: KeyeVL2Config, seed: int = 0):
 
     @jax.jit
     def init(rng):
-        keys = dict(zip(shapes, jax.random.split(rng, len(shapes))))
-        w = {
-            # drawn in the type they are served in: no float32 copy of 3.6 GB
-            name: jax.random.normal(keys[name], shape, cfg.param_dtype)
-            * jnp.asarray(0.02, cfg.param_dtype)
-            for name, shape in shapes.items()
-        }
-
-        def ones(*shape):
-            return {"scale": jnp.ones(shape, cfg.param_dtype)}
+        w = layers.drawn(jax.random.split(rng, len(shapes)), shapes, cfg.param_dtype)
+        ones = functools.partial(layers.ones_scale, cfg.param_dtype)
 
         return {
             "wte": {"embedding": w["wte"]},
@@ -265,37 +252,24 @@ def _make_extend(cfg: KeyeVL2Config, probe: bool):
     groups = cfg.num_heads // cfg.kv_heads
     f32 = jnp.float32
 
-    def _rms(x, p):
-        xf = x.astype(f32)
-        return xf * jax.lax.rsqrt((xf * xf).mean(-1, keepdims=True) + cfg.norm_eps) * (
-            p["scale"].astype(f32))
-
     def _project(hidden, p, name):
         return jnp.einsum("btd,dhk->bthk", hidden, p[name]["kernel"].astype(dtype))
-
-    def _by_block(fn, *per_query):
-        """``fn`` over blocks of ``QUERY_BLOCK`` queries (axis 1) of each
-        argument; a chunk that is no whole number of them goes as one."""
-        b, tc = per_query[0].shape[:2]
-        n = QUERY_BLOCK if tc % QUERY_BLOCK == 0 else tc
-        split = tuple(
-            x.reshape((b, tc // n, n) + x.shape[2:]).swapaxes(0, 1) for x in per_query)
-        out = jax.lax.map(lambda block: fn(*block), split)
-        return out.swapaxes(0, 1).reshape((b, tc) + out.shape[3:])
 
     def _index_scores(p, hidden, positions, ic):
         """The new indexer keys [b, tc, 1, index_dim] and, over the cache with
         them written, ``I`` [b, tc, cache] in float32."""
         with jax.named_scope("extend.attention.index"):
-            qi = _rotary(_project(hidden, p, "q").astype(f32), positions, cfg.index_dim,
-                         cfg.rope_base).astype(dtype)
+            qi = layers.rotary(
+                _project(hidden, p, "q").astype(f32), positions, cfg.index_dim,
+                cfg.rope_base).astype(dtype)
             ki = jnp.einsum("btd,dk->btk", hidden, p["k"]["kernel"].astype(dtype))[:, :, None]
-            ki = _rotary(ki.astype(f32), positions, cfg.index_dim, cfg.rope_base).astype(dtype)
+            ki = layers.rotary(
+                ki.astype(f32), positions, cfg.index_dim, cfg.rope_base).astype(dtype)
             w = jnp.einsum(
                 "btd,dh->bth", hidden, p["w"]["kernel"].astype(dtype),
                 preferred_element_type=f32)
             lane = jnp.arange(positions.shape[0])[:, None]
-            ic = ic.at[lane, positions].set(ki, mode="drop")
+            ic = layers.write_rows(ic, lane, positions, ki)
 
             def score_block(qb, wb):            # [b, n, heads, dim], [b, n, heads]
                 dots = jnp.einsum(
@@ -303,24 +277,25 @@ def _make_extend(cfg: KeyeVL2Config, probe: bool):
                 # a float32 sum, not a matmul: the chip would round one to bfloat16
                 return (jax.nn.relu(dots) * wb[..., None]).sum(2)
 
-            return ki, _by_block(score_block, qi, w)
+            return ki, layers.by_query_block(score_block, qi, w)
 
     @jax.named_scope("extend.attention")
     def _attend(p, p_index, hidden, positions, valid, kc, vc, ic):
         b, tc = positions.shape
         cap = kc.shape[1]
-        q = _rotary(_rms(_project(hidden, p, "q"), p["q_norm"]), positions, cfg.head_dim,
-                    cfg.rope_base).astype(dtype)
-        k = _rotary(_rms(_project(hidden, p, "k"), p["k_norm"]), positions, cfg.head_dim,
-                    cfg.rope_base).astype(dtype)
+
+        def normed_and_rotated(name):
+            x = layers.rms_norm(
+                _project(hidden, p, name), p[name + "_norm"]["scale"], cfg.norm_eps)
+            return layers.rotary(x, positions, cfg.head_dim, cfg.rope_base).astype(dtype)
+
+        q, k = normed_and_rotated("q"), normed_and_rotated("k")
         v = _project(hidden, p, "v")
         lane = jnp.arange(b)[:, None]
-        # out-of-capacity writes drop instead of clamping onto slot T-1
-        kc = kc.at[lane, positions].set(k, mode="drop")
-        vc = vc.at[lane, positions].set(v, mode="drop")
+        kc = layers.write_rows(kc, lane, positions, k)
+        vc = layers.write_rows(vc, lane, positions, v)
         ki, scores = _index_scores(p_index, hidden, positions, ic)
-        kpos = jnp.arange(cap, dtype=jnp.int32)
-        visible = (kpos[None, None, :] <= positions[:, :, None]) & valid[:, :, None]
+        visible = layers.visible_keys(positions, valid, cap)
         q = q.reshape(b, tc, cfg.kv_heads, groups, cfg.head_dim)
 
         if tc == 1:
@@ -337,7 +312,7 @@ def _make_extend(cfg: KeyeVL2Config, probe: bool):
             logit = jnp.einsum(
                 "bqhgd,bqkhd->bqhgk", q, k_rows, preferred_element_type=f32) * scale
             weight = jax.nn.softmax(
-                jnp.where(chosen[:, :, None, None], logit, f32(-1e30)), axis=-1)
+                jnp.where(chosen[:, :, None, None], logit, f32(layers.MASKED)), axis=-1)
             out = jnp.einsum("bqhgk,bqkhd->bqhgd", weight.astype(dtype), v_rows)
         else:
             # a prefill chunk: every row of the cache, under each query's mask
@@ -345,19 +320,12 @@ def _make_extend(cfg: KeyeVL2Config, probe: bool):
                 selected = select_mask(scores, visible, cfg.topk)       # [b, tc, cache]
                 slots_read = selected.any(1).sum(dtype=jnp.int32)
 
-            def attend_block(qb, mask):         # [b, n, kv, g, hd], [b, n, cache]
-                logit = jnp.einsum(
-                    "bqhgd,bkhd->bhgqk", qb, kc, preferred_element_type=f32) * scale
-                weight = jax.nn.softmax(
-                    jnp.where(mask[:, None, None], logit, f32(-1e30)), axis=-1)
-                return jnp.einsum("bhgqk,bkhd->bqhgd", weight.astype(dtype), vc)
-
             if backend.on_tpu():
-                # no key past the lane's farthest real query is in any mask
-                live = jnp.where(valid, positions + 1, 0).max(1)
-                out = attention.masked_attention(q, kc, vc, selected, live, scale=scale)
+                out = attention.masked_attention(
+                    q, kc, vc, selected, layers.live_keys(positions, valid), scale=scale)
             else:
-                out = _by_block(attend_block, q, selected)
+                out = layers.by_query_block(
+                    lambda qb, mask: layers.plain_attend(qb, kc, vc, mask, scale), q, selected)
         out = jnp.einsum(
             "bqhd,hde->bqe", out.reshape(b, tc, cfg.num_heads, cfg.head_dim),
             p["o"]["kernel"].astype(dtype))
@@ -380,38 +348,30 @@ def _make_extend(cfg: KeyeVL2Config, probe: bool):
 
     @jax.jit
     def extend(params, tokens, lengths, k_cache, v_cache, i_cache):
-        tc = tokens.shape[1]
-        positions = (
-            lengths[:, None].astype(jnp.int32) + jnp.arange(tc, dtype=jnp.int32)[None, :])
-        valid = tokens >= 0
+        positions, valid = layers.frame(tokens, lengths)
         with jax.named_scope("extend.embed"):
-            x = params["wte"]["embedding"].astype(dtype)[jnp.clip(tokens, 0, cfg.vocab_size - 1)]
-
-        # the experts stay out of the scan: every layer's grouped matmul reads
-        # them in place from the whole stack (``moe.held_experts_ffn``)
-        layers = dict(params["blocks"]["layers"])
-        stacked = layers.pop("moe")
-        experts = {"wi": stacked["wi"], "wo": stacked["wo"]}
+            x = layers.look_up(params["wte"]["embedding"].astype(dtype), tokens)
+        scanned, routing, experts = layers.without_experts(params["blocks"]["layers"])
 
         def body(carry, xs):
             p, router, kc, vc, ic, layer = xs
             a, news, sparse, selected = _attend(
-                p["attn"], p["index"], _rms(carry, p["ln_1"]).astype(dtype), positions,
-                valid, kc, vc, ic)
+                p["attn"], p["index"],
+                layers.rms_norm(carry, p["ln_1"]["scale"], cfg.norm_eps).astype(dtype),
+                positions, valid, kc, vc, ic)
             carry = carry + a
-            f, routed = _ffn(router, experts, layer, _rms(carry, p["ln_2"]), valid)
+            f, routed = _ffn(
+                router, experts, layer,
+                layers.rms_norm(carry, p["ln_2"]["scale"], cfg.norm_eps), valid)
             counters = jnp.concatenate([routed, sparse])
             return carry + f, news + ((counters, selected) if probe else (counters,))
 
         x, (k_new, v_new, i_new, counters, *selected) = jax.lax.scan(
             body, x, (
-                layers, stacked["router"], k_cache, v_cache, i_cache,
+                scanned, routing["router"], k_cache, v_cache, i_cache,
                 jnp.arange(cfg.num_layers, dtype=jnp.int32)))
-        with jax.named_scope("extend.logits"):
-            x = _rms(x, params["ln_f"])
-            logits = jnp.dot(
-                x.astype(dtype), params["head"]["kernel"].astype(dtype),
-                preferred_element_type=f32)
+        logits, x = layers.rms_head(
+            x, params["ln_f"]["scale"], cfg.norm_eps, params["head"]["kernel"], dtype)
         return (logits, x, k_new, v_new, i_new, counters.sum(0), *selected)
 
     return extend

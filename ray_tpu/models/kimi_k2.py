@@ -20,7 +20,7 @@ a token leaves behind and in how a query reads it:
   gather and page-back, 2.6 ms a call (PERF.md, PR 34);
 * queries come through a latent of their own (``q_rank``, RMS-normed); a head
   has ``nope_dim`` features that meet the latent and ``rope_dim`` that are
-  rotated (:func:`gpt._rotary`'s half-split pairing, YaRN's frequencies:
+  rotated (:func:`layers.rotary`'s half-split pairing, YaRN's frequencies:
   :func:`yarn_frequencies`) and meet ``k_rope``;
 * the attend is in the **absorbed** form: ``q' = q_nope W_kvb^K[h]`` lies in the
   latent's space, the score is ``(q' . c_kv + q_rope . k_rope) * s``, the
@@ -48,6 +48,7 @@ not tied to the output head.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any
 
@@ -55,13 +56,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models import moe
-from ray_tpu.models.gpt import _rotary
+from ray_tpu.models import layers, moe
 from ray_tpu.ops import attention, backend
-
-#: queries attended at a time in a prefill chunk off the chip: the float32
-#: scores of one block are ``lanes x heads x QUERY_BLOCK x cache``
-QUERY_BLOCK = 32
 
 #: what the attention counts over the real queries of a device call, summed
 #: over the layers: queries, live causal query-key pairs attended in the
@@ -243,15 +239,8 @@ def init_params(cfg: KimiK2Config, seed: int = 0):
     @jax.jit
     def init(rng):
         *keys, bias_key = jax.random.split(rng, len(shapes) + 1)
-        w = {
-            # drawn in the type they are served in: no float32 copy of 6 GB
-            name: jax.random.normal(key, shape, cfg.param_dtype)
-            * jnp.asarray(0.02, cfg.param_dtype)
-            for (name, shape), key in zip(shapes.items(), keys)
-        }
-
-        def ones(*shape):
-            return {"scale": jnp.ones(shape, cfg.param_dtype)}
+        w = layers.drawn(keys, shapes, cfg.param_dtype)
+        ones = functools.partial(layers.ones_scale, cfg.param_dtype)
 
         def block(prefix, n):
             return {
@@ -301,37 +290,25 @@ def make_extend_fn(cfg: KimiK2Config):
     scale = float(cfg.softmax_scale)
     freqs = jnp.asarray(cfg.rope_frequencies, f32)
 
-    def _rms(x, p):
-        xf = x.astype(f32)
-        return xf * jax.lax.rsqrt((xf * xf).mean(-1, keepdims=True) + cfg.norm_eps) * (
-            p["scale"].astype(f32))
+    def _normed(x, p, name):
+        return layers.rms_norm(x, p[name]["scale"], cfg.norm_eps)
 
     def _kernel(p, name):
         return p[name]["kernel"].astype(dtype)
 
     def _rope(x, positions):
         """``x`` [b, t, heads, rope_dim], rotated in float32."""
-        return _rotary(x.astype(f32), positions, cfg.rope_dim, freqs=freqs).astype(dtype)
-
-    def _by_block(fn, *per_query):
-        """``fn`` over blocks of ``QUERY_BLOCK`` queries (axis 1) of each
-        argument; a chunk that is no whole number of them goes as one."""
-        b, tc = per_query[0].shape[:2]
-        n = QUERY_BLOCK if tc % QUERY_BLOCK == 0 else tc
-        split = tuple(
-            x.reshape((b, tc // n, n) + x.shape[2:]).swapaxes(0, 1) for x in per_query)
-        out = jax.lax.map(lambda block: fn(*block), split)
-        return out.swapaxes(0, 1).reshape((b, tc) + out.shape[3:])
+        return layers.rotary(x.astype(f32), positions, cfg.rope_dim, freqs=freqs).astype(dtype)
 
     @jax.named_scope("extend.attention.latent")
     def _latents(p, hidden, positions):
         """The queries as they meet a cached row [b, t, heads, row_dim] (in the
         latent's space, their rotary features behind, zeros) and the token's
         own row [b, t, 1, row_dim]."""
-        c_q = _rms(hidden @ _kernel(p, "q_a"), p["q_norm"]).astype(dtype)
+        c_q = _normed(hidden @ _kernel(p, "q_a"), p, "q_norm").astype(dtype)
         q = jnp.einsum("btr,rhk->bthk", c_q, _kernel(p, "q_b"))
         both = hidden @ _kernel(p, "kv_a")
-        c_kv = _rms(both[..., :rank], p["kv_norm"]).astype(dtype)
+        c_kv = _normed(both[..., :rank], p, "kv_norm").astype(dtype)
         absorbed = jnp.einsum("bthn,chn->bthc", q[..., :cfg.nope_dim], _kernel(p, "k_up"))
 
         def row(latent, rotary):
@@ -348,13 +325,12 @@ def make_extend_fn(cfg: KimiK2Config):
         a bound past the lane's farthest real query: the same in every layer."""
         b, tc = positions.shape
         q, row = _latents(p, hidden, positions)
-        # out-of-capacity writes drop instead of clamping onto slot T-1
-        kc = kc.at[jnp.arange(b)[:, None], positions].set(row, mode="drop")
+        kc = layers.write_rows(kc, jnp.arange(b)[:, None], positions, row)
 
         def attend_block(qb, mask):             # [b, n, heads, row_dim], [b, n, cache]
             logit = jnp.einsum(
                 "bqhc,bkc->bhqk", qb, kc[:, :, 0], preferred_element_type=f32) * scale
-            weight = jax.nn.softmax(jnp.where(mask[:, None], logit, f32(-1e30)), axis=-1)
+            weight = jax.nn.softmax(jnp.where(mask[:, None], logit, f32(layers.MASKED)), axis=-1)
             # over the whole row: what is behind the latent is cut from the
             # result and not from the cache, which would be copied for it
             return jnp.einsum("bhqk,bkc->bqhc", weight.astype(dtype), kc[:, :, 0])[..., :rank]
@@ -364,18 +340,12 @@ def make_extend_fn(cfg: KimiK2Config):
             attended = attention.masked_attention(
                 q[:, :, None], kc, kc[..., :rank], visible, live, scale=scale)[:, :, 0]
         else:
-            attended = attend_block(q, visible) if tc == 1 else _by_block(attend_block, q, visible)
+            attended = (
+                attend_block(q, visible) if tc == 1
+                else layers.by_query_block(attend_block, q, visible))
         with jax.named_scope("extend.attention.latent"):
             out = jnp.einsum("bthc,chv->bthv", attended, _kernel(p, "v_up"))
         return jnp.einsum("bthv,hvd->btd", out, _kernel(p, "o")), row
-
-    def _gated(x, wi, wo):
-        """``wo (silu(gate x) * up x)``, gate and up side by side in ``wi``."""
-        f = wo.shape[0]
-        gate_up = x @ wi.astype(dtype)
-        return jnp.dot(
-            jax.nn.silu(gate_up[..., :f]) * gate_up[..., f:], wo.astype(dtype),
-            preferred_element_type=f32)
 
     def _experts(p, experts, layer, normed, valid):
         b, tc, d = normed.shape
@@ -390,26 +360,22 @@ def make_extend_fn(cfg: KimiK2Config):
                 x, weights, chosen, valid.reshape(b * tc), experts["wi"], experts["wo"],
                 cfg.expert_offset, layer)
         with jax.named_scope("extend.moe.shared"):
-            shared = _gated(x, p["shared"]["wi"], p["shared"]["wo"])
+            shared = layers.gated_mlp(x, p["shared"]["wi"], p["shared"]["wo"])
         return (routed + shared).astype(dtype).reshape(b, tc, d), counters
 
     def _block(x, p, positions, reads, kc, ffn):
-        a, row = _attend(p["attn"], _rms(x, p["ln_1"]).astype(dtype), positions, *reads, kc)
+        a, row = _attend(p["attn"], _normed(x, p, "ln_1").astype(dtype), positions, *reads, kc)
         x = x + a
-        return x, row, ffn(_rms(x, p["ln_2"]))
+        return x, row, ffn(_normed(x, p, "ln_2"))
 
     @jax.jit
     def extend(params, tokens, lengths, cache):
-        tc = tokens.shape[1]
-        positions = (
-            lengths[:, None].astype(jnp.int32) + jnp.arange(tc, dtype=jnp.int32)[None, :])
-        valid = tokens >= 0
-        kpos = jnp.arange(cache.shape[2], dtype=jnp.int32)
+        positions, valid = layers.frame(tokens, lengths)
         reads = (
-            (kpos[None, None, :] <= positions[:, :, None]) & valid[:, :, None],
-            jnp.where(valid, positions + 1, 0).max(1))
+            layers.visible_keys(positions, valid, cache.shape[2]),
+            layers.live_keys(positions, valid))
         with jax.named_scope("extend.embed"):
-            x = params["wte"]["embedding"].astype(dtype)[jnp.clip(tokens, 0, cfg.vocab_size - 1)]
+            x = layers.look_up(params["wte"]["embedding"].astype(dtype), tokens)
 
         rows = []
         for at in range(cfg.dense_layers):
@@ -417,17 +383,15 @@ def make_extend_fn(cfg: KimiK2Config):
 
             def mlp(normed):
                 with jax.named_scope("extend.mlp"):
-                    return _gated(normed.astype(dtype), p["mlp"]["wi"], p["mlp"]["wo"])
+                    return layers.gated_mlp(
+                        normed.astype(dtype), p["mlp"]["wi"], p["mlp"]["wo"])
 
             x, row, f = _block(x, p, positions, reads, cache[at], mlp)
             x = x + f.astype(dtype)
             rows.append(row)
 
-        # the routed experts stay out of the scan: every layer's grouped matmul
-        # reads them in place from the whole stack (``moe.held_experts_ffn``)
-        layers = dict(params["blocks"]["layers"])
-        layers["moe"] = dict(layers["moe"])
-        experts = {"wi": layers["moe"].pop("wi"), "wo": layers["moe"].pop("wo")}
+        scanned, routing, experts = layers.without_experts(params["blocks"]["layers"])
+        scanned["moe"] = routing        # the router and its bias are a layer's own
 
         def body(carry, xs):
             p, layer = xs
@@ -439,12 +403,9 @@ def make_extend_fn(cfg: KimiK2Config):
             return carry + f, (row, counters)
 
         x, (scanned, routed) = jax.lax.scan(
-            body, x, (layers, jnp.arange(cfg.expert_layers, dtype=jnp.int32)))
-        with jax.named_scope("extend.logits"):
-            x = _rms(x, params["ln_f"])
-            logits = jnp.dot(
-                x.astype(dtype), params["head"]["kernel"].astype(dtype),
-                preferred_element_type=f32)
+            body, x, (scanned, jnp.arange(cfg.expert_layers, dtype=jnp.int32)))
+        logits, x = layers.rms_head(
+            x, params["ln_f"]["scale"], cfg.norm_eps, params["head"]["kernel"], dtype)
         seen = jnp.where(valid, jnp.minimum(positions + 1, cache.shape[2]), 0)
         attended = cfg.num_layers * jnp.stack([
             valid.sum(dtype=jnp.int32), seen.sum(dtype=jnp.int32), jnp.int32(0), jnp.int32(0)])

@@ -1,0 +1,163 @@
+"""The kit the architecture files compose (``gpt.py``, ``cohere2_moe.py``,
+``keye_vl2.py``, ``kimi_k2.py``, ``granitemoehybrid.py``, ``lfm2_moe.py``): every
+decision they share, written once. Plain functions of their arguments: no
+configuration, no ``jit`` and no scope of their own but ``extend.logits`` round
+:func:`rms_head`, because the readers of a device trace key on the path of scopes
+the *caller* builds (``jit(extend)/extend.attention/...``). An architecture imports
+from here and from ``moe.py``, never from a sibling. What only one file does, or
+two do in different operations, stays in its file (``CHANGES.md``, PR 46).
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+#: queries scored or attended at a time in a prefill chunk: the float32 scores of
+#: one block are ``lanes x heads x QUERY_BLOCK x cache`` (134 MB a lane at 128 heads
+#: and an 8192 cache, or at 32 heads and a 32768 cache; a whole 256-token chunk of
+#: the former at once would be 1.07 GB a lane)
+QUERY_BLOCK = 32
+
+#: what a masked score reads as before the float32 softmax
+MASKED = -1e30
+
+
+def rotary(x: jax.Array, positions: jax.Array, rotary_dim: int,
+           base: float = 10000.0, freqs=None) -> jax.Array:
+    """RoPE of frequency base ``base``, or at the ``rotary_dim / 2`` given ``freqs``
+    (``kimi_k2.py``'s are YaRN's), on the first ``rotary_dim`` features of [b, t, h, d];
+    feature ``i`` turns with ``i + rotary_dim / 2`` (the half-split pairing)."""
+    if rotary_dim <= 0:
+        return x
+    rot, keep = x[..., :rotary_dim], x[..., rotary_dim:]
+    half = rotary_dim // 2
+    freqs = 1.0 / (base ** (jnp.arange(half, dtype=jnp.float32) / half)) if freqs is None else freqs
+    angles = positions[:, :, None].astype(jnp.float32) * freqs  # [b, t, half]
+    sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
+    cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)
+    r1, r2 = rot[..., :half], rot[..., half:]
+    rotated = jnp.concatenate([r1 * cos - r2 * sin, r2 * cos + r1 * sin], axis=-1)
+    return jnp.concatenate([rotated, keep], axis=-1)
+
+
+def rms_norm(x, scale, eps):
+    """In float32 whatever comes in, the scale cast: so is :func:`layer_norm`."""
+    xf = x.astype(jnp.float32)
+    return xf * jax.lax.rsqrt((xf * xf).mean(-1, keepdims=True) + eps) * scale.astype(jnp.float32)
+
+
+def layer_norm(x, scale, eps):
+    """Subtracts the mean, scales, and has no bias."""
+    xf = x.astype(jnp.float32)
+    mean = xf.mean(-1, keepdims=True)
+    var = ((xf - mean) ** 2).mean(-1, keepdims=True)
+    return (xf - mean) * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
+
+
+def frame(tokens, lengths):
+    """Where an ``extend`` call's ``tokens`` [b, tc] stand and which are real:
+    ``positions`` [b, tc] (``lengths`` [b] tokens lie before each lane's first) and
+    ``valid`` (a negative id is padding)."""
+    tc = tokens.shape[1]
+    positions = (
+        lengths[:, None].astype(jnp.int32) + jnp.arange(tc, dtype=jnp.int32)[None, :])
+    return positions, tokens >= 0
+
+
+def look_up(table, tokens):
+    """The rows of ``table`` [vocab, d] for ``tokens``; padding reads a row too."""
+    return table[jnp.clip(tokens, 0, table.shape[0] - 1)]
+
+
+def visible_keys(positions, valid, cache: int):
+    """What each query may read of ``cache`` slots, [b, tc, cache]: every slot up to
+    its own, and nothing for padding. That hides what was never written too:
+    anything past a lane's frontier is acausal."""
+    kpos = jnp.arange(cache, dtype=jnp.int32)
+    return (kpos[None, None, :] <= positions[:, :, None]) & valid[:, :, None]
+
+
+def live_keys(positions, valid):
+    """A bound [b] past each lane's farthest real query: no key from there on is in
+    any mask, and ``ops/attention.masked_attention`` stops there."""
+    return jnp.where(valid, positions + 1, 0).max(1)
+
+
+def write_rows(cache, lane, positions, rows):
+    """``rows`` [b, tc, ...] into ``cache`` [b, slots, ...] at ``positions``; ``lane``
+    is ``arange(b)[:, None]``, which a caller with several caches makes once.
+    Out-of-capacity writes drop instead of clamping onto slot T-1."""
+    return cache.at[lane, positions].set(rows, mode="drop")
+
+
+def without_experts(stacked):
+    """A scanned tree of layers as ``(the tree without "moe", what "moe" holds beside
+    the experts, {"wi", "wo"})``. The routed experts stay out of the scan: every
+    layer's grouped matmul reads them in place from the whole stack
+    (``moe.held_experts_ffn``)."""
+    layers = dict(stacked)
+    routing = dict(layers.pop("moe"))
+    return layers, routing, {"wi": routing.pop("wi"), "wo": routing.pop("wo")}
+
+
+def query_block(tc: int) -> int:
+    """Queries a block: a chunk that is no whole number of ``QUERY_BLOCK`` goes as one."""
+    return QUERY_BLOCK if tc % QUERY_BLOCK == 0 else tc
+
+
+def by_query_block(fn, *per_query):
+    """``fn`` over blocks of :func:`query_block` queries (axis 1) of each argument
+    [b, tc, ...], one block after the other; the results side by side again."""
+    b, tc = per_query[0].shape[:2]
+    n = query_block(tc)
+    split = tuple(
+        x.reshape((b, tc // n, n) + x.shape[2:]).swapaxes(0, 1) for x in per_query)
+    out = jax.lax.map(lambda block: fn(*block), split)
+    return out.swapaxes(0, 1).reshape((b, tc) + out.shape[3:])
+
+
+def plain_attend(q, k, v, mask, scale):
+    """Grouped attention densely: ``q`` [b, n, kv, g, hd] (``g`` query heads a K/V
+    head) over ``k``, ``v`` [b, cache, kv, hd] under ``mask`` [b, n, cache]; scores and
+    softmax float32, the weights cast to the values' type. The path off the chip,
+    and every decode lane's on it."""
+    logit = jnp.einsum(
+        "bqhgd,bkhd->bhgqk", q, k, preferred_element_type=jnp.float32) * scale
+    weight = jax.nn.softmax(
+        jnp.where(mask[:, None, None], logit, jnp.float32(MASKED)), axis=-1)
+    return jnp.einsum("bhgqk,bkhd->bqhgd", weight.astype(v.dtype), v)
+
+
+def gated_mlp(x, wi, wo):
+    """``wo (silu(gate x) * up x)``, gate and up side by side in ``wi``; operands in
+    ``x``'s type, the last product summed and handed back in float32."""
+    f = wo.shape[0]
+    gate_up = x @ wi.astype(x.dtype)
+    return jnp.dot(
+        jax.nn.silu(gate_up[..., :f]) * gate_up[..., f:], wo.astype(x.dtype),
+        preferred_element_type=jnp.float32)
+
+
+def rms_head(x, scale, eps, kernel, dtype):
+    """The last norm and an untied head: ``(logits, normed hidden)``, both float32."""
+    with jax.named_scope("extend.logits"):
+        x = rms_norm(x, scale, eps)
+        return jnp.dot(
+            x.astype(dtype), kernel.astype(dtype), preferred_element_type=jnp.float32), x
+
+
+def normal(key, shape, dtype):
+    """A seeded weight: normal with stddev 0.02, drawn in the type it is served in
+    (no float32 copy of gigabytes)."""
+    return jax.random.normal(key, shape, dtype) * jnp.asarray(0.02, dtype)
+
+
+def drawn(keys, shapes, dtype):
+    """``{name: normal}`` for ``shapes`` ``{name: shape}``, a key each in their order."""
+    return {name: normal(key, shape, dtype) for (name, shape), key in zip(shapes.items(), keys)}
+
+
+def ones_scale(dtype, *shape):
+    """A norm's parameters: a scale of ones."""
+    return {"scale": jnp.ones(shape, dtype)}

@@ -9,7 +9,7 @@ FFN(RMSNorm(h))``, and one of two mixers and one of two FFNs:
   (zeros before it, no bias, no activation), ``W_out (C * v)``;
 * mixer ``full_attention``: GQA, ``num_heads`` query heads over ``kv_heads`` K/V
   heads, an RMSNorm of q and of k over each head's features (one learned scale
-  of ``head_dim`` each), rotary over the whole head (:func:`gpt._rotary`'s
+  of ``head_dim`` each), rotary over the whole head (:func:`layers.rotary`'s
   half-split pairing), a causal softmax of ``q.k / sqrt(head_dim)``. The flash
   kernel takes one K/V head a query head, so K and V are **repeated**
   ``num_heads / kv_heads`` times before it (ROADMAP.md, R3b: index maps that
@@ -45,8 +45,8 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import moe
-from ray_tpu.models.gpt import TrainModel, _rotary
+from ray_tpu.models import layers, moe
+from ray_tpu.models.gpt import TrainModel
 from ray_tpu.ops.attention import FLASH_RESIDUALS, dot_product_attention
 
 CONV, ATTENTION = "conv", "full_attention"
@@ -172,8 +172,7 @@ def init_params(cfg: Lfm2MoeConfig, rng) -> Any:
     keys = iter(jax.random.split(rng, 16 * cfg.num_layers + 2))
 
     def normal(shape):
-        return jax.random.normal(next(keys), shape, cfg.param_dtype) * jnp.asarray(
-            0.02, cfg.param_dtype)
+        return layers.normal(next(keys), shape, cfg.param_dtype)
 
     def layer(kind, dense, stack=()):
         mixer, ffn = _layer_shapes(cfg, kind, dense)
@@ -200,11 +199,6 @@ def init_params(cfg: Lfm2MoeConfig, rng) -> Any:
     }
 
 
-def _rms(x, scale, eps):
-    xf = x.astype(jnp.float32)
-    return xf * jax.lax.rsqrt((xf * xf).mean(-1, keepdims=True) + eps) * scale.astype(jnp.float32)
-
-
 @jax.named_scope("train.conv")
 def conv_mixer(cfg: Lfm2MoeConfig, p, r):
     """The gated short convolution of ``r`` [b, t, d]: the taps read the gated
@@ -227,8 +221,8 @@ def attention_mixer(cfg: Lfm2MoeConfig, p, r):
         return (r @ p[name].astype(dtype)).reshape(b, t, n, cfg.head_dim)
 
     def normed_and_rotated(name, n):
-        x = _rms(heads(name, n), p[name + "_norm"], cfg.norm_eps)
-        return _rotary(x, positions, cfg.head_dim, cfg.rope_base).astype(dtype)
+        x = layers.rms_norm(heads(name, n), p[name + "_norm"], cfg.norm_eps)
+        return layers.rotary(x, positions, cfg.head_dim, cfg.rope_base).astype(dtype)
 
     q, k = normed_and_rotated("q", cfg.num_heads), normed_and_rotated("k", cfg.kv_heads)
     # one K/V head a query head for the flash kernel
@@ -267,8 +261,8 @@ def _nothing_counted():
 def _layer(cfg: Lfm2MoeConfig, kind: str, x, p, bias=None):
     """One layer; ``bias`` is an expert layer's, None says a dense one."""
     mixer = conv_mixer if kind == CONV else attention_mixer
-    x = x + mixer(cfg, p, _rms(x, p["ln_1"], cfg.norm_eps).astype(cfg.dtype))
-    r = _rms(x, p["ln_2"], cfg.norm_eps)
+    x = x + mixer(cfg, p, layers.rms_norm(x, p["ln_1"], cfg.norm_eps).astype(cfg.dtype))
+    r = layers.rms_norm(x, p["ln_2"], cfg.norm_eps)
     if bias is None:
         return x + dense_mlp(cfg, p, r.astype(cfg.dtype)), _nothing_counted()
     y, counters = expert_ffn(cfg, p, bias, r)
@@ -303,7 +297,7 @@ def forward(cfg: Lfm2MoeConfig, params, tokens):
     for kind, p, bias in zip(cfg.period, params["tail"], biases["tail"]):
         x, counters = layer(cfg, kind, x, p, bias)
         counted = counted + counters
-    hidden = _rms(x, params["ln_f"], cfg.norm_eps).astype(cfg.dtype)
+    hidden = layers.rms_norm(x, params["ln_f"], cfg.norm_eps).astype(cfg.dtype)
     return (
         (hidden, params["wte"].T, None), jnp.zeros((), jnp.float32),
         dict(zip(moe.TRAINED_COUNTERS, counted)))

@@ -25,7 +25,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu._private import accelerator
-from ray_tpu.models import cohere2_moe, gpt, granitemoehybrid, keye_vl2, kimi_k2
+from ray_tpu.models import cohere2_moe, gpt, granitemoehybrid, keye_vl2, kimi_k2, layers
 from ray_tpu.models.training import (
     abstract_state,
     default_optimizer,
@@ -612,7 +612,7 @@ def test_keye_vl2_stage_extend_compiles_at_its_largest_shapes(shaped, form, buil
         assert "/extend.attention/masked_attention/" in attend
         groups = cfg.num_heads // cfg.kv_heads
         for scores in ("f32", "bf16"):
-            assert f"{scores}[1,{cfg.kv_heads},{groups},{keye_vl2.QUERY_BLOCK},{cap}]" not in text
+            assert f"{scores}[1,{cfg.kv_heads},{groups},{layers.QUERY_BLOCK},{cap}]" not in text
     else:
         assert kernels == experts
     memory = compiled.memory_analysis()
@@ -728,7 +728,7 @@ def test_kimi_k2_share_extend_compiles_at_its_largest_shapes(shaped, form, built
         over_cache = [
             math.prod(map(int, dims.split(",")))
             for dims in re.findall(r"f32\[([0-9,]+)\]", text) if str(cap) in dims.split(",")]
-        assert max(over_cache, default=0) < cfg.num_heads * kimi_k2.QUERY_BLOCK * cap
+        assert max(over_cache, default=0) < cfg.num_heads * layers.QUERY_BLOCK * cap
     else:
         assert kernels == experts
         assert f"f32[{lanes},{cfg.num_heads},1,{cap}]" in text     # a decode lane's scores
