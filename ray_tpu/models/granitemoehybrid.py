@@ -2,8 +2,16 @@
 ``serve/llm.py`` of a model whose layers are mostly **recurrent**.
 
 Every layer is ``h += r * Mixer(RMSNorm(h))``, ``h += r * MLP(RMSNorm(h))``
-(``r`` the ``residual_multiplier``; the MLP gated, ``W_out (silu(g) * u)``). The
-layers come in periods of ``period`` with one attention layer at
+(``r`` the ``residual_multiplier``; the MLP gated, ``W_out (silu(g) * u)``). Where
+the configuration has routed experts (``router_experts``: granite-4.0-h-small;
+micro has none) the second half is ``h += r * (Shared(n) + sum_k w_k
+Expert_k(n))``, ``n = RMSNorm(h)``: the MLP above is the *shared* expert, beside it
+the dropless expert layer of ``models/moe.py``: a softmax router over all
+``router_experts``, the ``experts_per_token`` largest normalised over themselves,
+and the experts **held here** (``num_experts`` of width ``expert_dim``, from
+``expert_offset``: one chip's share of an expert-parallel deployment) computed for
+the tokens routed to them, in place in their stack; what the absent experts would
+add is left out. The layers come in periods of ``period`` with one attention layer at
 ``attention_at`` and Mamba-2 layers around it, and differ from the engine's
 other architectures in what a *sequence* leaves behind:
 
@@ -55,7 +63,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models import layers
+from ray_tpu.models import layers, moe
 from ray_tpu.ops import attention, backend
 
 #: what ``extend`` counts over the real lanes and tokens of a device call,
@@ -71,7 +79,12 @@ class GraniteMoeHybridConfig:
     period: int = 10                # layers a period: one attention, the others Mamba-2
     attention_at: int = 5           # where in a period the attention layer stands
     embed_dim: int = 2048
-    mlp_dim: int = 8192
+    mlp_dim: int = 8192             # the MLP every token passes: with experts, the shared one
+    expert_dim: int = 0             # width of one routed expert
+    router_experts: int = 0         # experts the router scores: 0, and the block has no routed half
+    num_experts: int = 0            # experts held here ...
+    expert_offset: int = 0          # ... from this one on
+    experts_per_token: int = 0
     num_heads: int = 32
     kv_heads: int = 8
     head_dim: int = 64
@@ -97,6 +110,10 @@ class GraniteMoeHybridConfig:
                 f"attention layer at {self.attention_at}")
         if self.num_heads % self.kv_heads:
             raise ValueError(f"{self.num_heads} query heads over {self.kv_heads} K/V heads")
+        if not 0 <= self.expert_offset <= self.router_experts - self.num_experts:
+            raise ValueError(
+                f"experts {self.expert_offset} .. {self.expert_offset + self.num_experts - 1} "
+                f"are not among the {self.router_experts} the router scores")
 
     @property
     def periods(self) -> int:
@@ -123,7 +140,8 @@ class GraniteMoeHybridConfig:
 
     def num_params(self) -> int:
         d, inner = self.embed_dim, self.ssm_inner
-        mlp = 3 * d * self.mlp_dim
+        mlp = 3 * d * self.mlp_dim + d * self.router_experts + (
+            self.num_experts * 3 * d * self.expert_dim)
         mamba = (
             d * (inner + self.conv_dim + self.ssm_heads) + (self.conv_width + 1) * self.conv_dim
             + 3 * self.ssm_heads + inner + inner * d)
@@ -134,8 +152,11 @@ class GraniteMoeHybridConfig:
 
     # -- what the serving engine asks of a configuration (``serve/llm.py``) --
 
-    #: what ``extend`` counts, in the order of its last output
-    counters = SSM_COUNTERS
+    @property
+    def counters(self):
+        """What ``extend`` counts, in the order of its last output: the
+        recurrence's two and, with experts, the expert layers' four."""
+        return SSM_COUNTERS + (moe.COUNTERS if self.router_experts else ())
 
     @property
     def cache_layers(self) -> int:
@@ -173,12 +194,15 @@ class GraniteMoeHybridConfig:
 
 def granite_hybrid_nano(**kw) -> GraniteMoeHybridConfig:
     """A tiny one for the tests: two periods of three Mamba-2 layers and one
-    attention layer, sub-chunks of 8 tokens."""
+    attention layer, sub-chunks of 8 tokens. Given ``router_experts`` it has the
+    routed half too: experts of width 32, four of them held, three a token."""
     sizes = dict(
         vocab_size=256, num_layers=8, period=4, attention_at=2, embed_dim=64, mlp_dim=96,
         num_heads=4, kv_heads=2, head_dim=16, ssm_heads=8, ssm_head_dim=16, ssm_state=16,
         ssm_chunk=8, max_seq_len=256, dtype=jnp.float32, param_dtype=jnp.float32,
     )
+    if kw.get("router_experts"):
+        sizes.update(expert_dim=32, num_experts=4, experts_per_token=3)
     return GraniteMoeHybridConfig(**{**sizes, **kw})
 
 
@@ -192,7 +216,12 @@ def init_params(cfg: GraniteMoeHybridConfig, seed: int = 0):
     ``dt_bias`` the inverse softplus of a step log-uniform in (0.001, 0.1), ``D``
     1 (float32, all three), the convolution's kernel and bias uniform within
     ``conv_width^-0.5``. ``W_in`` is stored as its three parts (``z``, ``xBC``,
-    ``dt``), the gate and the up projection of an MLP side by side."""
+    ``dt``), the gate and the up projection of an MLP side by side. With experts
+    an ``mlp`` tree has the ``router`` ``[periods, embed, router_experts]`` too, and
+    beside ``periods``, out of the scan's reach, ``experts`` holds the held ones of
+    each layer of a period (``wi`` ``[periods, num_experts, embed, 2 expert_dim]``,
+    ``wo`` ``[periods, num_experts, expert_dim, embed]``): the grouped matmul reads
+    them where they lie."""
     d, inner, heads = cfg.embed_dim, cfg.ssm_inner, cfg.ssm_heads
     P, M, L = cfg.periods, cfg.period - 1, cfg.period
     kv = cfg.kv_heads * cfg.head_dim
@@ -203,6 +232,11 @@ def init_params(cfg: GraniteMoeHybridConfig, seed: int = 0):
         "q": (P, d, cfg.num_heads * cfg.head_dim), "k": (P, d, kv), "v": (P, d, kv),
         "o": (P, cfg.num_heads * cfg.head_dim, d)}
     mlp = {"wi": (P, d, 2 * cfg.mlp_dim), "wo": (P, cfg.mlp_dim, d)}
+    if cfg.router_experts:
+        mlp["router"] = (P, d, cfg.router_experts)
+        experts = {
+            "wi": (P, cfg.num_experts, d, 2 * cfg.expert_dim),
+            "wo": (P, cfg.num_experts, cfg.expert_dim, d)}
     bound = cfg.conv_width ** -0.5
 
     def drawn(key, shapes):
@@ -234,6 +268,8 @@ def init_params(cfg: GraniteMoeHybridConfig, seed: int = 0):
     @jax.jit
     def init(rng):
         k_wte, k_attn, *keys = jax.random.split(rng, 2 + M + L)
+        held = {"experts": tuple(
+            drawn(jax.random.fold_in(k, 1), experts) for k in keys[M:])} if cfg.router_experts else {}
         return {
             "wte": {"embedding": layers.normal(k_wte, (cfg.vocab_size, d), cfg.param_dtype)},
             "periods": {
@@ -244,6 +280,7 @@ def init_params(cfg: GraniteMoeHybridConfig, seed: int = 0):
                 "mlp": tuple({"ln": ones(P, d), **drawn(k, mlp)} for k in keys[M:]),
             },
             "ln_f": ones(d),
+            **held,
         }
 
     return jax.block_until_ready(init(jax.random.PRNGKey(seed)))
@@ -413,7 +450,9 @@ def make_extend_fn(cfg: GraniteMoeHybridConfig):
     Scopes: ``extend.embed``; ``extend.ssm`` (projections, convolution, gate,
     norm) with ``extend.ssm.scan`` inside it (the recurrence alone, with the
     state's read and its write; a decode call's is the kernel ``ssm_step`` on
-    the chip); ``extend.attention``; ``extend.mlp``; ``extend.logits``."""
+    the chip); ``extend.attention``; ``extend.mlp``, or with experts
+    ``extend.moe.route`` (the norm and the router), ``extend.moe.experts`` and
+    ``extend.moe.shared``; ``extend.logits``."""
     dtype, f32 = cfg.dtype, jnp.float32
     heads, p_dim, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
     inner, tail = cfg.ssm_inner, cfg.conv_width - 1
@@ -538,6 +577,23 @@ def make_extend_fn(cfg: GraniteMoeHybridConfig):
     def _mlp(x, p):
         return layers.gated_mlp(_normed(x, p["ln"]).astype(dtype), p["wi"], p["wo"])
 
+    def _routed(x, p, held, period, valid):
+        """The second half with experts: the shared MLP and this chip's part of
+        the routed one, of ``held`` (a layer of a period's stack) the ``period``-th
+        in place. Returns it and what the expert layer counted."""
+        b, tc, d = x.shape
+        with jax.named_scope("extend.moe.route"):
+            flat = _normed(x, p["ln"]).reshape(b * tc, d)
+            weights, chosen = moe.softmax_top_k(flat, p["router"], cfg.experts_per_token)
+            flat = flat.astype(dtype)
+        with jax.named_scope("extend.moe.experts"):
+            routed, counted = moe.held_experts_ffn(
+                flat, weights, chosen, valid.reshape(b * tc), held["wi"], held["wo"],
+                cfg.expert_offset, period)
+        with jax.named_scope("extend.moe.shared"):
+            shared = layers.gated_mlp(flat, p["wi"], p["wo"])
+        return (routed + shared).reshape(b, tc, d), counted
+
     def _add(x, out):
         return x + (res * out).astype(dtype)
 
@@ -556,7 +612,7 @@ def make_extend_fn(cfg: GraniteMoeHybridConfig):
             # and written where they lie
             x, ssm, tails = carry
             p, kc, vc, period = xs
-            rows, m = None, 0
+            rows, m, counted = None, 0, ()
             for i in range(cfg.period):
                 if i == cfg.attention_at:
                     out, rows = _attend(
@@ -569,8 +625,12 @@ def make_extend_fn(cfg: GraniteMoeHybridConfig):
                         snap_at, snap_slots, ssm, tails, period * (cfg.period - 1) + m)
                     m += 1
                 x = _add(x, out)
-                x = _add(x, _mlp(x, p["mlp"][i]))
-            return (x, ssm, tails), rows
+                if cfg.router_experts:
+                    out, pairs = _routed(x, p["mlp"][i], params["experts"][i], period, valid)
+                    x, counted = _add(x, out), counted + (pairs,)
+                else:
+                    x = _add(x, _mlp(x, p["mlp"][i]))
+            return (x, ssm, tails), (rows, counted)
 
         # the convolution's inputs are small (26 KB a lane and layer, where the
         # state is 2 MB): every layer's leave their slots in one slice a lane and
@@ -578,7 +638,7 @@ def make_extend_fn(cfg: GraniteMoeHybridConfig):
         # that whole arena to VMEM and back around the scan
         own = _take(conv, slots, layers=conv.shape[0])
         tails = (own, own) if tc > 1 else (own,)    # the new ones; a chunk's kept ones
-        (x, ssm, tails), rows = jax.lax.scan(
+        (x, ssm, tails), (rows, counted) = jax.lax.scan(
             body, (x, ssm, tails), (
                 params["periods"], k_cache, v_cache, jnp.arange(cfg.periods, dtype=jnp.int32)))
         if tc > 1:
@@ -591,6 +651,8 @@ def make_extend_fn(cfg: GraniteMoeHybridConfig):
                 preferred_element_type=f32) / cfg.logits_scaling
         counters = cfg.ssm_layers * jnp.stack([
             valid.sum(dtype=jnp.int32), valid.any(1).sum(dtype=jnp.int32)])
+        if cfg.router_experts:
+            counters = jnp.concatenate([counters, sum(counted).sum(0)])
         return (logits, x, *rows, ssm, conv, counters)
 
     return extend

@@ -11,7 +11,8 @@ Expert weights carry the ("expert", "embed", "mlp") logical axes: ep shards
 the expert dim, tp can still shard the mlp dim inside each expert.
 
 Beside it, for serving (``models/cohere2_moe.py``, ``models/kimi_k2.py``,
-``models/keye_vl2.py``) and for the train step (``models/lfm2_moe.py``, under
+``models/keye_vl2.py`` and, beside a shared MLP in every layer of a period of Mamba-2
+and attention mixers, ``models/granitemoehybrid.py``) and for the train step (``models/lfm2_moe.py``, under
 ``jax.grad``): a **dropless** layer for one chip's share of an expert-parallel
 deployment, or every expert on one chip. :func:`sigmoid_top_k`, :func:`softmax_top_k` or
 :func:`sigmoid_bias_top_k` scores every routed expert, :func:`held_experts_ffn` is told which experts

@@ -868,6 +868,86 @@ def test_granite_hybrid_extend_compiles_at_its_largest_shapes(shaped, form, buil
     assert _device_bytes(compiled) + resident + lanes * cap * 8192 < HBM_BYTES
 
 
+def _granite_small_share():
+    """The served cut of granite-4.0-h-small (one period, 36 of 72 experts, half
+    the vocabulary) and its engine sizes, from the configuration's file."""
+    import json
+
+    from benchmark.manifest import published_keys
+    from benchmark.models import granitemoehybrid_moe
+
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "configs",
+            "granite-4.0-h-small-serve-ep2.json")) as f:
+        config = json.load(f)
+    return granitemoehybrid_moe.program_config(published_keys(config)), config
+
+
+#: one sequence's state, the period's 9 Mamba layers: 128 x 64 x 128 float32 and 3 x 8448 bfloat16
+GRANITE_SMALL_STATE_BYTES = 9 * (128 * 64 * 128 * 4 + 3 * 8448 * 2)
+#: one layer's held experts: 36 of 3 x 4096 x 768 in bfloat16
+GRANITE_SMALL_LAYER_EXPERTS_BYTES = 36 * 3 * 4096 * 768 * 2
+
+
+@pytest.mark.parametrize("form", ["decode", "prefill"])
+def test_granite_small_share_extend_compiles_and_copies_no_layers_experts(
+        shaped, form, built_for_tpu):
+    """One chip's share of granite-4.0-h-small at its published widths (9.51 GB
+    of weights) over the largest cache bucket, on the pool's state arenas
+    themselves (57 slots of 38.2 MB: 2.18 GB, donated and aliased): both
+    families of mechanism in one program. A decode call of eight lanes runs the
+    kernel ``ssm_step`` in each of the nine Mamba layers and the grouped matmul
+    twice in each of the ten expert layers; a prefill chunk the chunked
+    recurrence, the attention kernel and the same grouped matmuls. The held
+    experts are read in place in their stacks: the temporaries stay far under
+    one layer's 680 MB of them, which a scan that sliced them out copied on
+    every call. It holds the memory the configuration's file states and fits
+    beside the pool's blocks and a second call's caches."""
+    built_for_tpu(True)
+    cfg, config = _granite_small_share()
+    engine, stated = config["engine"], config["compiled_bytes_per_device"]
+    cap, lanes, slots = engine["cache_buckets"][-1], engine["lane_buckets"][-1], engine["state_slots"]
+    b, tc = (lanes, 1) if form == "decode" else (1, engine["prefill_token_buckets"][-1])
+    assert stated[form]["shape"] == [b, tc, cap]
+    params = jax.tree.map(
+        lambda x: shaped(x.shape, x.dtype), jax.eval_shape(lambda: cfg.init_params(0)))
+    caches = [
+        shaped((cfg.cache_layers, b, cap) + tuple(each), cfg.dtype) for each in cfg.cache_arrays]
+    arenas = _granite_arenas(shaped, cfg, slots)
+    operands = shaped(
+        (b, llm._operand_width(
+            engine["prefill_token_buckets"][-1], cap // engine["block_size"], True)), jnp.int32)
+    compiled = llm._operand_extend(cfg.make_extend_fn(), len(caches), len(arenas)).lower(
+        params, operands, shaped((lanes + len(cfg.counters),), jnp.int32), *caches, *arenas, tc=tc
+    ).compile()
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    experts = [line for line in kernels if "/extend.moe.experts/" in line]
+    assert len(experts) == 2 * cfg.period                        # two grouped matmuls a layer
+    others = [line for line in kernels if line not in experts]
+    if form == "prefill":
+        assert others and all("/extend.attention/masked_attention/" in k for k in others)
+    else:
+        assert len(others) == 9 and all(
+            "/extend.ssm.scan/jit(ssm_step_slots)/ssm_step/" in k for k in others)
+    memory = compiled.memory_analysis()
+    assert cfg.num_params() == 4_757_211_776 and GRANITE_SMALL_STATE_BYTES == 38_204_928
+    arena_bytes = slots * GRANITE_SMALL_STATE_BYTES
+    assert 0 <= memory.alias_size_in_bytes - arena_bytes < slots * 2**17
+    per_token = 2 * cfg.cache_layers * 8 * 128 * 2
+    assert per_token == 4096
+    weights = memory.argument_size_in_bytes - memory.alias_size_in_bytes - b * (
+        cap * per_token + 2**17)
+    assert 9.51e9 < weights < 9.52e9
+    assert memory.argument_size_in_bytes == stated[form]["argument"]
+    assert memory.temp_size_in_bytes <= stated[form]["temp"] * 1.05
+    # no copy of a layer's experts (a decode call's are its K and V, re-laid out by K/V
+    # head, a chunk's its float32 logits and its pairs' rows)
+    assert memory.temp_size_in_bytes < GRANITE_SMALL_LAYER_EXPERTS_BYTES / 2
+    resident = engine["num_blocks"] * engine["block_size"] * per_token
+    assert _device_bytes(compiled) + resident + lanes * cap * per_token < HBM_BYTES
+
+
 def test_state_slots_are_read_and_written_without_a_whole_arena_temporary(shaped, built_for_tpu):
     """The state arenas of the granite configuration (49 slots of 76.4 MB:
     3.75 GB) and the two programs that touch them: the copy (a prefix hit's
@@ -905,7 +985,7 @@ def test_state_slots_are_read_and_written_without_a_whole_arena_temporary(shaped
     "name,extends,pagings",
     [("gptj-6b-serve", 12, 11), ("command-a-plus-serve-ep8", 20, 25),
      ("keye-vl2-30b-a3b-serve", 16, 19), ("kimi-k2-instruct-serve-ep32", 16, 19),
-     ("granite-4.0-h-micro-serve", 20, 25)],
+     ("granite-4.0-h-micro-serve", 20, 25), ("granite-4.0-h-small-serve-ep2", 20, 25)],
 )
 def test_a_serve_configuration_compiles_no_more_programs_than_it_did(name, extends, pagings):
     """The programs an engine with the configuration's buckets compiles (a tiny
@@ -926,7 +1006,8 @@ def test_a_serve_configuration_compiles_no_more_programs_than_it_did(name, exten
         if name.startswith("command-a-plus")
         else keye_vl2.keye_vl2_nano(max_seq_len=context) if name.startswith("keye")
         else kimi_k2.kimi_k2_nano(max_seq_len=context) if name.startswith("kimi")
-        else granitemoehybrid.granite_hybrid_nano(max_seq_len=context, ssm_chunk=256)
+        else granitemoehybrid.granite_hybrid_nano(
+            max_seq_len=context, ssm_chunk=256, router_experts=8 * name.count("small"))
         if name.startswith("granite")
         else dataclasses.replace(gpt.gpt_nano(), max_seq_len=context)
     )
