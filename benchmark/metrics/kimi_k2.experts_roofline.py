@@ -7,11 +7,12 @@ the weights of every held expert that had a token, and of the shared expert,
 read once per call and expert layer; activations not counted: **lower bounds**)
 over the device seconds under ``extend.moe.experts`` + ``extend.moe.shared``.
 
-The counts are the whole load's, scaled by the share of its time inside engine
-steps that the traced steps took, as ``mla.attend_roofline`` scales its own: an
-**estimate**, which assumes the traced seconds carry the load's own mix of
-calls (PERF.md, section 7, S7b (4)). A sub-window of decode calls alone would
-read high: a 512-token chunk hits all 12 held experts, a decode call few."""
+The counts are ``counters.traced``'s: what ``extend`` counted, and the calls the
+engine dispatched (``phase_n.dispatch``: the shared expert's reads a call), in
+exactly the engine steps the profiler session recorded, **not scaled** from the
+whole load: a 512-token chunk hits all 12 held experts and a decode call few,
+so the reading follows the recorded calls and not the load's mix of them. A
+program that keeps no such record: nothing."""
 
 import json
 import os
@@ -27,14 +28,13 @@ def read(run):
     from benchmark import yardstick
     from benchmark.models import kimi_k2
 
-    trace, counters = run.get("trace") or {}, run.get("counters") or {}
+    trace = run.get("trace") or {}
+    counted = (run.get("counters") or {}).get("traced") or {}
     scopes = dict(map(tuple, trace.get("ops_by_scope") or []))
     seconds = sum(scopes.get(s, 0.0) for s in SCOPES)
-    in_steps = (counters.get("phase_s") or {}).get("step")
-    if not seconds or not in_steps or not counters.get("moe_tokens"):
+    if not seconds or not counted.get("moe_tokens"):
         return None
     with open(CONFIG) as f:
-        work = kimi_k2.experts_work(json.load(f), counters)
-    traced = trace["engine"]["in_step_s"] / in_steps
+        work = kimi_k2.experts_work(json.load(f), counted)
     return yardstick.roofline_share(
-        traced * work["flops"], traced * work["bytes"], seconds, run["device"]["kind"])
+        work["flops"], work["bytes"], seconds, run["device"]["kind"])

@@ -9,13 +9,12 @@ device seconds under ``extend.attention`` (which holds the attend, and the
 cache update and ``W_o`` beside it: more seconds than the counted work took,
 never fewer).
 
-The engine's counters cover the whole load and the trace a second or two of
-it, and the harness keeps no counter per sub-window, so the counts are scaled
-by the share of the load's time inside engine steps that the traced steps took
-(``trace.engine.in_step_s`` / ``counters.phase_s.step``), as
-``moe.experts_roofline`` and ``sparse_attention.roofline`` scale their own: an
-**estimate**, which assumes the traced seconds carry the load's own mix of
-calls (PERF.md, section 7, S7b (4))."""
+The counts are ``counters.traced``'s: the pairs ``extend`` counted and the live
+slots the engine gathered (``cache_tokens``) in exactly the engine steps the
+profiler session recorded, **not scaled** from the whole load: a program that
+attends a chunk in another form changes the recorded calls' pairs and seconds
+together, and the load's mix of calls does not enter. A program that keeps no
+such record: nothing."""
 
 import json
 import os
@@ -31,14 +30,13 @@ def read(run):
     from benchmark import yardstick
     from benchmark.models import kimi_k2
 
-    trace, counters = run.get("trace") or {}, run.get("counters") or {}
+    trace = run.get("trace") or {}
+    counted = (run.get("counters") or {}).get("traced") or {}
     seconds = dict(map(tuple, trace.get("ops_by_scope") or [])).get(SCOPE)
-    in_steps = (counters.get("phase_s") or {}).get("step")
-    if not seconds or not in_steps or not (
-            counters.get("mla_pairs_absorbed") or counters.get("mla_pairs_expanded")):
+    if not seconds or not (
+            counted.get("mla_pairs_absorbed") or counted.get("mla_pairs_expanded")):
         return None
     with open(CONFIG) as f:
-        work = kimi_k2.latent_work(json.load(f), counters)
-    traced = trace["engine"]["in_step_s"] / in_steps
+        work = kimi_k2.latent_work(json.load(f), counted)
     return yardstick.roofline_share(
-        traced * work["flops"], traced * work["bytes"], seconds, run["device"]["kind"])
+        work["flops"], work["bytes"], seconds, run["device"]["kind"])

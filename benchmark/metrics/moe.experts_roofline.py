@@ -6,12 +6,12 @@ the weights of every held expert that had a token, and of the shared experts,
 read once per call and layer; activations not counted) over the device seconds
 under ``extend.moe.experts`` + ``extend.moe.shared``.
 
-The engine's counters cover the whole load and the trace a few seconds of it,
-and the harness keeps no counter per sub-window (``benchmark/server.py``
-``_probe`` holds ``steps`` and ``decode_tokens`` alone), so the counts are
-scaled by the share of the load's time inside engine steps that the traced
-steps took (``trace.engine.in_step_s`` / ``counters.phase_s.step``): an
-estimate, which assumes the traced seconds carry the load's own mix of calls."""
+The counts are ``counters.traced``'s: what ``extend`` counted, and the calls the
+engine dispatched (``phase_n.dispatch``: the shared experts' reads a call), in
+exactly the engine steps the profiler session recorded, **not scaled** from the
+whole load: a chunk hits every held expert and a decode call few, so the
+reading follows the recorded calls and not the load's mix of them. A program
+that keeps no such record: nothing."""
 
 import json
 import os
@@ -27,14 +27,13 @@ def read(run):
     from benchmark import yardstick
     from benchmark.models import cohere2_moe
 
-    trace, counters = run.get("trace") or {}, run.get("counters") or {}
+    trace = run.get("trace") or {}
+    counted = (run.get("counters") or {}).get("traced") or {}
     scopes = dict(map(tuple, trace.get("ops_by_scope") or []))
     seconds = sum(scopes.get(s, 0.0) for s in SCOPES)
-    in_steps = (counters.get("phase_s") or {}).get("step")
-    if not seconds or not in_steps or not counters.get("moe_tokens"):
+    if not seconds or not counted.get("moe_tokens"):
         return None
     with open(CONFIG) as f:
-        work = cohere2_moe.experts_work(json.load(f), counters)
-    traced = trace["engine"]["in_step_s"] / in_steps
+        work = cohere2_moe.experts_work(json.load(f), counted)
     return yardstick.roofline_share(
-        traced * work["flops"], traced * work["bytes"], seconds, run["device"]["kind"])
+        work["flops"], work["bytes"], seconds, run["device"]["kind"])

@@ -9,14 +9,10 @@ and writes not counted: **lower bounds**) over the device seconds under
 (which holds the attend, and the projections, norms, rotary and cache update
 beside it: more seconds than the counted work took, never fewer).
 
-The engine's counters cover the whole load and the trace a second or two of
-it, and the harness keeps no counter per sub-window (``benchmark/server.py``
-``_probe`` holds ``steps`` and ``decode_tokens`` alone), so the counts are
-scaled by the share of the load's time inside engine steps that the traced
-steps took (``trace.engine.in_step_s`` / ``counters.phase_s.step``), as
-``moe.experts_roofline`` scales its own: an **estimate**, which assumes the
-traced seconds carry the load's own mix of calls. The share is far enough from
-100 that a misestimate of half again does not reach it (PERF.md, section 5)."""
+The counts are ``counters.traced``'s: the pairs and slots ``extend`` counted and
+the live slots the engine gathered (``cache_tokens``) in exactly the engine
+steps the profiler session recorded, **not scaled** from the whole load. A
+program that keeps no such record: nothing."""
 
 import json
 import os
@@ -32,15 +28,13 @@ def read(run):
     from benchmark import yardstick
     from benchmark.models import keye_vl2
 
-    trace, counters = run.get("trace") or {}, run.get("counters") or {}
+    trace = run.get("trace") or {}
+    counted = (run.get("counters") or {}).get("traced") or {}
     scopes = dict(map(tuple, trace.get("ops_by_scope") or []))
-    in_steps = (counters.get("phase_s") or {}).get("step")
-    if not all(scopes.get(s) for s in SCOPES[:2]) or not in_steps or not counters.get(
-            "sparse_keys_scored"):
+    if not all(scopes.get(s) for s in SCOPES[:2]) or not counted.get("sparse_keys_scored"):
         return None
     with open(CONFIG) as f:
-        work = keye_vl2.sparse_work(json.load(f), counters)
-    traced = trace["engine"]["in_step_s"] / in_steps
+        work = keye_vl2.sparse_work(json.load(f), counted)
     return yardstick.roofline_share(
-        traced * work["flops"], traced * work["bytes"],
+        work["flops"], work["bytes"],
         sum(scopes.get(s, 0.0) for s in SCOPES), run["device"]["kind"])

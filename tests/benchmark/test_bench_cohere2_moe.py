@@ -237,6 +237,12 @@ def _recorded_run():
             "moe_tokens": 40_000, "moe_assignments": 40_000, "moe_experts_hit": 6_000,
             "window_slots": 8_000, "window_slots_outside": 1_000,
             "phase_n": {"dispatch": 500}, "phase_s": {"step": 20.0},
+            # the recorded steps' own counts: more of them chunks than an eighth of the load's
+            "traced": {
+                "moe_tokens": 8_000, "moe_assignments": 9_000, "moe_experts_hit": 900,
+                "window_slots": 1_200, "window_slots_outside": 100,
+                "phase_n": {"dispatch": 60}, "phase_s": {"step": 2.4},
+            },
         },
         "trace": {
             "busy_s": 2.0, "window_s": 6.0, "engine": {"steps": 50, "in_step_s": 2.5},
@@ -254,10 +260,10 @@ def test_the_four_readers_read_a_recorded_run():
     assert read["extend.moe_share"](run) == pytest.approx(50.0)
     assert read["extend.attention_share"](run) == pytest.approx(25.0)
     assert read["engine.window_outside_share"](run) == pytest.approx(12.5)
-    # an eighth of the load's step time was traced: an eighth of its work, in 0.9 s
+    # the recorded steps' own pairs, tokens, hit experts and calls, in 0.9 s
     expert = 3 * 4096 * 4096
-    flops = 2 * expert * (40_000 + 4 * 40_000) / 8
-    moved = 2 * expert * (6_000 + 4 * 4 * 500) / 8
+    flops = 2 * expert * (9_000 + 4 * 8_000)
+    moved = 2 * expert * (900 + 4 * 4 * 60)
     at_peak = max(flops / 197e12, moved / 819e9)
     assert read["moe.experts_roofline"](run) == pytest.approx(100 * at_peak / 0.9)
     assert 0 < read["moe.experts_roofline"](run) < 100

@@ -200,6 +200,12 @@ def _recorded_run():
             "sparse_keys_scored": 8_000_000_000, "sparse_keys_attended": 1_000_000_000,
             "sparse_slots_read": 30_000_000, "sparse_slots_gathered": 120_000_000,
             "cache_tokens": 10_000_000, "phase_s": {"step": 20.0},
+            # the recorded steps' own counts: more of them chunks than an eighth of the load's
+            "traced": {
+                "sparse_keys_scored": 1_200_000_000, "sparse_keys_attended": 100_000_000,
+                "sparse_slots_read": 4_000_000, "sparse_slots_gathered": 15_000_000,
+                "cache_tokens": 1_500_000, "phase_s": {"step": 2.4},
+            },
         },
         "trace": {
             "busy_s": 2.0, "window_s": 6.0, "engine": {"steps": 50, "in_step_s": 2.5},
@@ -216,9 +222,9 @@ def test_the_three_readers_read_a_recorded_run():
     read = {name: BOOK.reader(name) for name in NEW_METRICS}
     assert read["extend.index_share"](run) == pytest.approx(25.0)
     assert read["engine.sparse_unread_share"](run) == pytest.approx(75.0)
-    # an eighth of the load's step time was traced: an eighth of its work, in 1.0 s
-    flops = (2 * 16 * 64 * 8e9 + 4 * 32 * 128 * 1e9) / 8
-    moved = 2 * (64 * 6 * 10e6 + 2 * 4 * 128 * 30e6) / 8
+    # the recorded steps' own pairs, keys and slots, in 1.0 s
+    flops = 2 * 16 * 64 * 1.2e9 + 4 * 32 * 128 * 1e8
+    moved = 2 * (64 * 6 * 1.5e6 + 2 * 4 * 128 * 4e6)
     at_peak = max(flops / 197e12, moved / 819e9)
     assert read["sparse_attention.roofline"](run) == pytest.approx(100 * at_peak / 1.0)
     assert 0 < read["sparse_attention.roofline"](run) < 100

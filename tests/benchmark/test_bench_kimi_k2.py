@@ -222,6 +222,13 @@ def _recorded_run():
             "cache_tokens": 10_000_000, "phase_s": {"step": 20.0},
             "moe_tokens": 3_000_000, "moe_assignments": 700_000, "moe_experts_hit": 10_000,
             "moe_load_max": 400_000, "phase_n": {"dispatch": 2_000},
+            # the recorded steps' own counts: more of them chunks than an eighth of the load's
+            "traced": {
+                "mla_queries": 300_000, "mla_pairs_absorbed": 1_000_000_000,
+                "mla_pairs_expanded": 0, "mla_rows_expanded": 0, "cache_tokens": 1_500_000,
+                "moe_tokens": 400_000, "moe_assignments": 90_000, "moe_experts_hit": 1_500,
+                "moe_load_max": 50_000, "phase_n": {"dispatch": 200}, "phase_s": {"step": 2.4},
+            },
         },
         "trace": {
             "busy_s": 2.0, "window_s": 6.0, "engine": {"steps": 50, "in_step_s": 2.5},
@@ -235,14 +242,15 @@ def _recorded_run():
 
 def test_the_experts_reader_reads_a_recorded_run():
     run, read = _recorded_run(), BOOK.reader("kimi_k2.experts_roofline")
-    # an eighth of the load's step time was traced: an eighth of its work, in 0.4 + 0.1 s
-    flops = 2 * 44_040_192 * (700_000 + 3_000_000) / 8
-    moved = 2 * 44_040_192 * (10_000 + 6 * 2_000) / 8
+    # the recorded steps' own pairs, tokens, hit experts and calls, in 0.4 + 0.1 s
+    flops = 2 * 44_040_192 * (90_000 + 400_000)
+    moved = 2 * 44_040_192 * (1_500 + 6 * 200)
     assert moved / 819e9 > flops / 197e12                   # the weights bind, as on the chip
     assert read(run) == pytest.approx(100 * moved / 819e9 / 0.5)
     assert 0 < read(run) < 100
-    # a run of a program without the counters or the scopes (the parent's): nothing
+    # a run of a program without the counters, the traced record or the scopes: nothing
     assert read({**run, "counters": {"steps": 5, "phase_s": {"step": 1.0}}}) is None
+    assert read({**run, "counters": {**run["counters"], "traced": None}}) is None
     assert read({**run, "trace": {**run["trace"], "ops_by_scope": [["extend.attention", 1.0]]}}) is None
 
 
@@ -262,14 +270,15 @@ def test_the_two_readers_read_a_recorded_run():
     run = _recorded_run()
     read = {name: BOOK.reader(name) for name in NEW_METRICS}
     assert read["extend.latent_share"](run) == pytest.approx(15.0)
-    # an eighth of the load's step time was traced: an eighth of its work, in 1.0 s
-    flops = 2 * 64 * (576 + 512) * 8e9 / 8
-    moved = 2 * 576 * 7 * 10e6 / 8
+    # the recorded steps' own pairs and live slots, in 1.0 s
+    flops = 2 * 64 * (576 + 512) * 1e9
+    moved = 2 * 576 * 7 * 1.5e6
     at_peak = max(flops / 197e12, moved / 819e9)
     assert read["mla.attend_roofline"](run) == pytest.approx(100 * at_peak / 1.0)
     assert 0 < read["mla.attend_roofline"](run) < 100
     # pairs in the expanded form count their own operations
-    some = {**run, "counters": {**run["counters"], "mla_pairs_expanded": 8_000_000_000}}
+    some = {**run, "counters": {"traced": {
+        **run["counters"]["traced"], "mla_pairs_expanded": 1_000_000_000}}}
     assert read["mla.attend_roofline"](some) == pytest.approx(
         100 * (flops + 2 * 64 * (192 + 128) * 1e9) / 197e12)
     # a run of a program without the counters or the scopes (the parent's): nothing

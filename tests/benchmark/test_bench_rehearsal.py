@@ -59,6 +59,11 @@ def test_train_flow(root, twin, traced):
     assert run["tokens_per_step"] == batch[0] * batch[1]
     # every scalar the step reports reaches the readers, step by step
     assert all({"loss", "grad_norm", "step_s"} <= set(m) for m in run["step_metrics"])
+    # the mix's steps are sent ahead of the one waited for (the traced ones apart), and
+    # every step that was sent is taken, in the order it was sent, before the clock is read
+    assert 1 < run["ahead"] <= cell.traffic["ahead_steps_at_most"]
+    counted = [m["step"] for m in run["step_metrics"]]
+    assert counted == [counted[0] + i for i in range(run["steps"])]
     check_line(root, line, cell, traced)
     if not traced:
         assert line["metrics"]["train_tokens_per_s"]["value"] == pytest.approx(
