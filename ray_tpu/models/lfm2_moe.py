@@ -29,8 +29,11 @@ The leading dense layers run one by one; behind them the layers repeat with a
 period (the published model: attention, conv, conv, conv) and run as **one scan
 over whole periods** whose body holds the period's unlike layers, each with a
 stacked tree of its own; what is left of a last, broken period runs one by one
-behind the scan. Every layer is rematerialized in the backward pass but for the
-flash kernel's own residuals (``FLASH_RESIDUALS``) and its input.
+behind the scan. Every layer is rematerialized in the backward pass but for its
+input, the flash kernel's own residuals (``FLASH_RESIDUALS``) and an expert layer's
+two grouped matmuls' results (``moe.TRAINED_RESIDUALS``): the backward runs neither
+kernel again, asks for the sorted rows and the gate once more (a gather and an
+elementwise pass) and writes its gradients over the two kept results.
 
 Scopes, inside ``train.forward``: ``train.conv``, ``train.attention``,
 ``train.mlp``, ``train.moe.route``, ``train.moe.experts``. The step reports
@@ -273,11 +276,12 @@ def forward(cfg: Lfm2MoeConfig, params, tokens):
     """``tokens`` [b, t] through every layer: ``((hidden [b, t, d], the tied head's
     kernel [d, vocab], None), 0.0, counters)``, as ``gpt.TrainModel.apply`` gives
     them; ``counters`` are ``moe.TRAINED_COUNTERS``, summed over the expert layers."""
-    # a layer's remat keeps its input and the attention kernel's own residuals
-    # and replays the rest, as ``gpt.ScannedBlocks`` does
+    # a layer's remat keeps its input and its kernels' results (the attention's own
+    # residuals, both grouped matmuls') and replays the rest
     layer = jax.checkpoint(
         _layer, static_argnums=(0, 1), prevent_cse=False,
-        policy=jax.checkpoint_policies.save_only_these_names(*FLASH_RESIDUALS))
+        policy=jax.checkpoint_policies.save_only_these_names(
+            *FLASH_RESIDUALS, *moe.TRAINED_RESIDUALS))
 
     x = params["wte"].astype(cfg.dtype)[tokens]
     counted = _nothing_counted()

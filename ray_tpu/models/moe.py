@@ -33,6 +33,7 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops import backend
 
@@ -338,6 +339,40 @@ _combined.defvjp(_combined_fwd, _combined_bwd)
 TRAINED_COUNTERS = COUNTERS + ("moe_rows_visited",)
 
 
+#: what :func:`trained_experts_ffn` names for a remat round it: the first grouped
+#: matmul's result ``gate_up`` [k n, 2f] and the second's ``out`` [k n, d]. A remat
+#: that keeps both (``models/lfm2_moe.py`` ``forward``) hands them to the backward,
+#: which writes its gradients over them; one that keeps neither runs both kernels
+#: again for them
+TRAINED_RESIDUALS = ("moe_gate_up", "moe_out")
+
+
+def _bits_named(x, name: str):
+    """:func:`_named`'s value, and what its tangent rule traces: the name has to be an
+    equation of the layer's own jaxpr for a policy to see it."""
+    bits =jnp.dtype(f"uint{8 * x.dtype.itemsize}")
+    kept = checkpoint_name(jax.lax.bitcast_convert_type(x, bits), name)
+    return jax.lax.bitcast_convert_type(kept, x.dtype)
+
+
+@functools.partial(jax.custom_jvp, nondiff_argnums=(1,))
+def _named(x, name: str):
+    """``x`` under ``name`` for a remat's policy, named **by its bits**: cast to the
+    unsigned integers of its width, named, cast back. The identity on every value,
+    NaN and -0.0 too, and on its tangent (a cast to integers alone would pass none).
+    JAX's remat puts a ``reduce_precision`` to the value's own type on the producer of
+    every floating-point value it keeps (against XLA's excess precision between the
+    forward and the replay): on a kernel's bfloat16 result that rounds nothing, and
+    XLA, which cannot alias through it, copies the value to keep it. Integers get
+    none."""
+    return _bits_named(x, name)
+
+
+@_named.defjvp
+def _named_jvp(name, primals, tangents):
+    return _bits_named(primals[0], name), tangents[0]
+
+
 def trained_experts_ffn(x, weights, experts, wi, wo, offset: int = 0, tiling=GMM_TRAIN_TILING):
     """:func:`held_experts_ffn` for a train step: the same part of the layer, the
     same counters and one more (:data:`TRAINED_COUNTERS`), every pair whose expert
@@ -354,7 +389,8 @@ def trained_experts_ffn(x, weights, experts, wi, wo, offset: int = 0, tiling=GMM
     and in the gradient of its rows alike; between the sort and the un-sort,
     which mask them, a row meets only its own values. And the backward's passes
     between them stop at the last block of :func:`row_block` rows that holds a
-    pair (above); with every pair held that is all ``k n`` rows. Nothing is dropped."""
+    pair (above); with every pair held that is all ``k n`` rows. Nothing is dropped.
+    The two grouped matmuls' results carry :data:`TRAINED_RESIDUALS`' names."""
     n, k = experts.shape
     num_held = wo.shape[-3]
     local = experts.T - offset
@@ -367,9 +403,11 @@ def trained_experts_ffn(x, weights, experts, wi, wo, offset: int = 0, tiling=GMM
     place = jnp.zeros_like(order).at[order].set(jnp.arange(k * n, dtype=order.dtype))
     held_rows, block = group_sizes.sum(dtype=jnp.int32), row_block(k * n, tiling[0])
     rows = _sorted_rows(x, held, order, place)                    # [k n, d]
-    gate_up = grouped_matmul(rows, wi.astype(x.dtype), group_sizes, tiling=tiling)
+    gate_up = _named(
+        grouped_matmul(rows, wi.astype(x.dtype), group_sizes, tiling=tiling), TRAINED_RESIDUALS[0])
     act = _gated(block, gate_up, held_rows)
-    out = grouped_matmul(act, wo.astype(x.dtype), group_sizes, tiling=tiling)
+    out = _named(
+        grouped_matmul(act, wo.astype(x.dtype), group_sizes, tiling=tiling), TRAINED_RESIDUALS[1])
     y = _combined(block, out, weights, held, order, place, held_rows)
     counters = jnp.stack([
         jnp.int32(n), held_rows, (group_sizes > 0).sum(dtype=jnp.int32), group_sizes.max(),

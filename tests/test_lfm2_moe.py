@@ -22,6 +22,7 @@ from benchmark.reference import lfm2_moe_reference as reference
 from ray_tpu.models import lfm2_moe, moe
 from ray_tpu.models.gpt import blockwise_next_token_loss
 from ray_tpu.models.training import default_optimizer, init_sharded_state, make_train_step
+from ray_tpu.ops.attention import FLASH_RESIDUALS
 from ray_tpu.parallel.mesh import MeshSpec
 
 BATCH = (2, 48)
@@ -423,6 +424,103 @@ def test_the_step_sums_the_layers_rows_visited(nano):
     visited, held = int(counters["moe_rows_visited"]), int(counters["moe_assignments"])
     # four expert layers, each under a block's rows in vain
     assert held <= visited < held + 4 * block and visited <= 4 * pairs
+
+
+# -- what a layer's remat keeps: the kernels' results, by name --------------------
+
+
+def _eqns(jaxpr, primitive):
+    """The equations of ``primitive`` in ``jaxpr`` and in every jaxpr under it."""
+    return sum(
+        (eqn.primitive.name == primitive)
+        + sum(_eqns(sub, primitive) for sub in jax.core.jaxprs_in_params(eqn.params))
+        for eqn in jaxpr.eqns)
+
+
+@pytest.fixture
+def replayed(monkeypatch):
+    """``replayed()``: from here on the expert layer names nothing, so a layer's remat
+    keeps ``FLASH_RESIDUALS`` alone and replays both grouped matmuls (the program
+    before the names). ``jax.checkpoint`` keeps the layer's trace: dropped, both ways."""
+    def answer():
+        jax.clear_caches()
+        monkeypatch.setattr(moe, "_named", lambda x, name: x)
+
+    yield answer
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("name", FLASH_RESIDUALS + moe.TRAINED_RESIDUALS)
+def test_a_layers_remat_keeps_each_kernels_result_by_its_name(name, built_for_tpu, capsys):
+    """An attention layer with experts, alone behind the scan (a broken period's), with
+    the TPU's kernels, under ``forward``'s own policy: the flash kernel's two results
+    and both grouped matmuls' are kept, the grouped matmuls' as their bits."""
+    built_for_tpu(True)
+    cfg = lfm2_moe.lfm2_moe_nano(
+        head_dim=64, dtype=jnp.bfloat16,
+        layer_types=(lfm2_moe.CONV, lfm2_moe.ATTENTION, lfm2_moe.CONV, lfm2_moe.CONV,
+                     lfm2_moe.ATTENTION))
+    assert (cfg.periods, len(cfg.period)) == (1, 3)               # the fifth layer runs alone
+    params = jax.eval_shape(lambda: lfm2_moe.init_params(cfg, jax.random.PRNGKey(0)))
+    jax.ad_checkpoint.print_saved_residuals(
+        lambda params, tokens: program_loss(cfg, params, tokens)[0],
+        params, jax.ShapeDtypeStruct(BATCH, jnp.int32))
+    pairs = BATCH[0] * BATCH[1] * cfg.experts_per_token
+    heads = f"{BATCH[0]},{cfg.num_heads},{BATCH[1]}"
+    shape, origin = {     # the flash kernel's are named inside ``dot_product_attention``'s own jit
+        "flash_out": (f"bf16[{heads},64]", "(attention_mixer)"),
+        "flash_lse": (f"f32[{heads}]", "(attention_mixer)"),
+        "moe_gate_up": (f"u16[{pairs},{2 * cfg.expert_dim}]", "named 'moe_gate_up'"),
+        "moe_out": (f"u16[{pairs},{cfg.embed_dim}]", "named 'moe_out'")}[name]
+    kept = [line for line in capsys.readouterr().out.splitlines()
+            if line.startswith(shape + " ") and origin in line]
+    assert len(kept) == 1, kept
+
+
+def _loss_and_gradients(cfg, params, tokens):
+    return jax.jit(jax.value_and_grad(lambda p: program_loss(cfg, p, tokens)[0]))(params)
+
+
+@pytest.mark.parametrize("offset", [0, 4], ids=["first-share", "second-share"])
+def test_kept_or_replayed_the_loss_and_every_gradient_are_the_same_bits(offset, nano, replayed):
+    """In float32, where the CPU computes a value as it stores it (in bfloat16 its
+    fusions carry excess precision from a replay's matmul into the gate: what JAX's
+    ``reduce_precision`` is for, and what a kernel's stored result has none of)."""
+    _, params, tokens = nano
+    cfg = lfm2_moe.lfm2_moe_nano(expert_offset=offset)
+    kept = _loss_and_gradients(cfg, params, tokens)
+    replayed()
+    again = _loss_and_gradients(cfg, params, tokens)
+    assert all(np.isfinite(np.asarray(g)).all() for g in jax.tree.leaves(kept))
+    assert all(np.asarray(layer[w]).any() for layer in kept[1]["periods"] for w in ("wi", "wo"))
+    for got, want in zip(jax.tree.leaves(kept), jax.tree.leaves(again), strict=True):
+        assert got.dtype == want.dtype and np.asarray(got).tobytes() == np.asarray(want).tobytes()
+
+
+@pytest.mark.parametrize("keeps", ["both results", "neither"])
+def test_the_backward_of_a_layer_that_keeps_both_results_runs_no_grouped_matmul_again(
+        keeps, nano, replayed):
+    """Four expert layers: a forward pair, the rows' two gradients and the weights' two
+    each, 6; a remat that keeps neither result makes the forward pair again, 8. No
+    kept value goes through ``reduce_precision``: they are integers to the remat."""
+    cfg, params, tokens = nano
+    if keeps == "neither":
+        replayed()
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: program_loss(cfg, p, tokens)[0]))(params).jaxpr
+    assert _eqns(jaxpr, "ragged_dot_general") == 4 * (6 if keeps == "both results" else 8)
+    assert _eqns(jaxpr, "reduce_precision") == 0
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bfloat16", "float32"])
+def test_a_value_named_by_its_bits_is_the_value_and_so_is_its_gradient(dtype):
+    x = jnp.asarray([1.5, -0.0, 0.0, jnp.nan, -jnp.nan, jnp.inf, -jnp.inf, 1e-39, 3.0], dtype)
+    named = jax.jit(lambda x: moe._named(x, "a_name"))(x)
+    assert named.dtype == x.dtype and np.asarray(named).tobytes() == np.asarray(x).tobytes()
+    up = jnp.asarray([2.0, -0.0, jnp.nan, 1.0, 1.0, jnp.inf, 1.0, 1e-39, -3.0], dtype)
+    _, vjp = jax.vjp(lambda x: moe._named(x, "a_name"), x)
+    (handed,) = vjp(up)
+    assert handed.dtype == up.dtype and np.asarray(handed).tobytes() == np.asarray(up).tobytes()
+    assert "a_name" in str(jax.make_jaxpr(jax.grad(lambda x: moe._named(x, "a_name").sum()))(x))
 
 
 def test_the_published_layout_scans_nine_periods_and_runs_two_layers_behind_them():
