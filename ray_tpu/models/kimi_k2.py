@@ -22,16 +22,23 @@ a token leaves behind and in how a query reads it:
   has ``nope_dim`` features that meet the latent and ``rope_dim`` that are
   rotated (:func:`layers.rotary`'s half-split pairing, YaRN's frequencies:
   :func:`yarn_frequencies`) and meet ``k_rope``;
-* the attend is in the **absorbed** form: ``q' = q_nope W_kvb^K[h]`` lies in the
-  latent's space, the score is ``(q' . c_kv + q_rope . k_rope) * s``, the
-  weighted sum of latents is taken through ``W_kvb^V[h]`` afterwards. No key or
-  value of a head is ever made, whatever the length of the cache. A decode
-  lane does it over its padded cache in ``jax.numpy``; a prefill chunk on the
-  chip in ``ops/attention.masked_attention`` (one row under the 64 query
-  heads, eight at a time, the scores in VMEM), off it 32 queries at a time.
-  The expanded form (``[k_nope ; v] = c_kv W_kvb``) would halve a chunk's
-  attention operations and needs ``W_kvb`` over every live slot of the lane
-  for every chunk, tiled into the kernel: ROADMAP.md, R3;
+* a decode call attends in the **absorbed** form: ``q' = q_nope W_kvb^K[h]``
+  lies in the latent's space, the score is ``(q' . c_kv + q_rope . k_rope) * s``,
+  the weighted sum of latents is taken through ``W_kvb^V[h]`` afterwards. No key
+  or value of a head is made, whatever the length of the cache: a lane does it
+  over its padded cache in ``jax.numpy``, 2 x 64 x (576 + 512) operations a
+  query-key pair. So does a prefill chunk off the chip, 32 queries at a time;
+* a prefill chunk on the chip attends in the **expanded** form (``[k_nope ; v] =
+  c_kv W_kvb``, a head's own 192-wide key and 128-wide value: 2 x 64 x 320
+  operations a pair), in ``ops/attention.latent_attention``: the kernel puts
+  each tile of the lane's live rows through ``W_kvb`` in VMEM, eight heads after
+  one another, so a head's key and value never reach HBM (1.34 GB a layer over
+  32768 slots if they did) and the queries go in as ``W_qb`` leaves them, no
+  absorption before and no un-absorption after. ``W_kvb`` over a slot costs 2 x
+  512 x 256 x 64 operations once a chunk, what 171 queries save: a chunk's 512
+  pay for it three times over (37.8 M operations a slot for 71.3 M, at every
+  cache length), a decode call's one query a slot never would. What is cached
+  is the same row, to the bit, whichever form reads it;
 * the first ``dense_layers`` layers have a gated MLP; the others an expert
   layer (``models/moe.py``): float32 sigmoid scores over all ``router_experts``,
   the ``experts_per_token`` with the largest score + bias chosen
@@ -61,8 +68,9 @@ from ray_tpu.ops import attention, backend
 
 #: what the attention counts over the real queries of a device call, summed
 #: over the layers: queries, live causal query-key pairs attended in the
-#: absorbed and in the expanded form, and latent rows put through ``W_kvb``
-#: (nothing expands here: the last two are 0)
+#: absorbed and in the expanded form (a call's pairs all under the form it
+#: attends in), and latent rows put through ``W_kvb``: the live slots of every
+#: lane of a chunk on the chip, 0 for any other call
 MLA_COUNTERS = ("mla_queries", "mla_pairs_absorbed", "mla_pairs_expanded", "mla_rows_expanded")
 
 
@@ -279,9 +287,11 @@ def make_extend_fn(cfg: KimiK2Config):
     real tokens only. A negative token id marks padding: it computes no expert
     and is not counted.
 
-    Scopes: ``extend.embed``; ``extend.attention`` (cache update, the attend,
+    Scopes: ``extend.embed``; ``extend.attention`` (cache update, the attend
+    (a chunk's on the chip the kernel ``latent_attention`` straight under it),
     ``W_o``) with ``extend.attention.latent`` inside it (both down-projections,
-    their norms, ``W_qb``, the rotations, the absorption and the un-absorption);
+    their norms, ``W_qb``, the rotations and, in the absorbed form, the
+    absorption and the un-absorption);
     ``extend.mlp`` (a dense layer's); ``extend.moe.route``, ``extend.moe.experts``,
     ``extend.moe.shared``; ``extend.logits``.
     """
@@ -289,6 +299,12 @@ def make_extend_fn(cfg: KimiK2Config):
     rank = cfg.kv_rank
     scale = float(cfg.softmax_scale)
     freqs = jnp.asarray(cfg.rope_frequencies, f32)
+
+    def _expands(tc):
+        """Whether a call of ``tc`` tokens a lane attends in the expanded form: a
+        chunk on the chip. (Its 512 queries a slot are three times the 171 at
+        which ``W_kvb`` over the slot is paid for; a decode call's one is not.)"""
+        return tc > 1 and backend.on_tpu()
 
     def _normed(x, p, name):
         return layers.rms_norm(x, p[name]["scale"], cfg.norm_eps)
@@ -301,30 +317,34 @@ def make_extend_fn(cfg: KimiK2Config):
         return layers.rotary(x.astype(f32), positions, cfg.rope_dim, freqs=freqs).astype(dtype)
 
     @jax.named_scope("extend.attention.latent")
-    def _latents(p, hidden, positions):
-        """The queries as they meet a cached row [b, t, heads, row_dim] (in the
-        latent's space, their rotary features behind, zeros) and the token's
-        own row [b, t, 1, row_dim]."""
+    def _latents(p, hidden, positions, expanded):
+        """The queries and the token's own row [b, t, 1, row_dim]. The queries as
+        they meet a cached row [b, t, heads, row_dim] (in the latent's space,
+        their rotary features behind, zeros), or for the ``expanded`` form as
+        ``W_qb`` leaves them: ``(q_nope, q_rope)``, the second rotated."""
         c_q = _normed(hidden @ _kernel(p, "q_a"), p, "q_norm").astype(dtype)
         q = jnp.einsum("btr,rhk->bthk", c_q, _kernel(p, "q_b"))
         both = hidden @ _kernel(p, "kv_a")
         c_kv = _normed(both[..., :rank], p, "kv_norm").astype(dtype)
-        absorbed = jnp.einsum("bthn,chn->bthc", q[..., :cfg.nope_dim], _kernel(p, "k_up"))
 
         def row(latent, rotary):
             spare = jnp.zeros(latent.shape[:-1] + (cfg.row_dim - rank - cfg.rope_dim,), dtype)
             return jnp.concatenate([latent, rotary, spare], -1)
 
-        return (
-            row(absorbed, _rope(q[..., cfg.nope_dim:], positions)),
-            row(c_kv[:, :, None], _rope(both[:, :, None, rank:], positions)))
+        if expanded:
+            q = (q[..., :cfg.nope_dim], _rope(q[..., cfg.nope_dim:], positions))
+        else:
+            absorbed = jnp.einsum("bthn,chn->bthc", q[..., :cfg.nope_dim], _kernel(p, "k_up"))
+            q = row(absorbed, _rope(q[..., cfg.nope_dim:], positions))
+        return q, row(c_kv[:, :, None], _rope(both[:, :, None, rank:], positions))
 
     @jax.named_scope("extend.attention")
     def _attend(p, hidden, positions, visible, live, kc):
         """``visible`` [b, t, cache] is what each query may read, ``live`` [b]
         a bound past the lane's farthest real query: the same in every layer."""
         b, tc = positions.shape
-        q, row = _latents(p, hidden, positions)
+        expanded = _expands(tc)
+        q, row = _latents(p, hidden, positions, expanded)
         kc = layers.write_rows(kc, jnp.arange(b)[:, None], positions, row)
 
         def attend_block(qb, mask):             # [b, n, heads, row_dim], [b, n, cache]
@@ -335,16 +355,17 @@ def make_extend_fn(cfg: KimiK2Config):
             # result and not from the cache, which would be copied for it
             return jnp.einsum("bhqk,bkc->bqhc", weight.astype(dtype), kc[:, :, 0])[..., :rank]
 
-        if tc > 1 and backend.on_tpu():
-            # one row under all the heads, the key as it lies, the value its latent
-            attended = attention.masked_attention(
-                q[:, :, None], kc, kc[..., :rank], visible, live, scale=scale)[:, :, 0]
+        if expanded:
+            # a head's own key and value, made of each tile of rows inside the kernel
+            out = attention.latent_attention(
+                *q, kc[:, :, 0], _kernel(p, "k_up"), _kernel(p, "v_up"), visible, live,
+                scale=scale)
         else:
             attended = (
                 attend_block(q, visible) if tc == 1
                 else layers.by_query_block(attend_block, q, visible))
-        with jax.named_scope("extend.attention.latent"):
-            out = jnp.einsum("bthc,chv->bthv", attended, _kernel(p, "v_up"))
+            with jax.named_scope("extend.attention.latent"):
+                out = jnp.einsum("bthc,chv->bthv", attended, _kernel(p, "v_up"))
         return jnp.einsum("bthv,hvd->btd", out, _kernel(p, "o")), row
 
     def _experts(p, experts, layer, normed, valid):
@@ -407,8 +428,14 @@ def make_extend_fn(cfg: KimiK2Config):
         logits, x = layers.rms_head(
             x, params["ln_f"]["scale"], cfg.norm_eps, params["head"]["kernel"], dtype)
         seen = jnp.where(valid, jnp.minimum(positions + 1, cache.shape[2]), 0)
-        attended = cfg.num_layers * jnp.stack([
-            valid.sum(dtype=jnp.int32), seen.sum(dtype=jnp.int32), jnp.int32(0), jnp.int32(0)])
+        queries, pairs = valid.sum(dtype=jnp.int32), seen.sum(dtype=jnp.int32)
+        if _expands(tokens.shape[1]):
+            # every live slot of a lane goes through W_kvb once a layer
+            slots = jnp.minimum(reads[1], cache.shape[2]).sum(dtype=jnp.int32)
+            by_form = (jnp.int32(0), pairs, slots)
+        else:
+            by_form = (pairs, jnp.int32(0), jnp.int32(0))
+        attended = cfg.num_layers * jnp.stack([queries, *by_form])
         return (
             logits, x, jnp.concatenate([jnp.stack(rows), scanned]),
             jnp.concatenate([routed.sum(0), attended]))

@@ -746,3 +746,181 @@ def masked_attention(
         blocks, q.transpose(0, 2, 3, 1, 4).reshape(b, kv * tiles, heads, t + pad_q, d),
         k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), mask.astype(jnp.int8))
     return out.reshape(b, kv, groups, t + pad_q, dv).transpose(0, 3, 1, 2, 4)[:, :t]
+
+
+# ---------------------------------------------------------------------------
+# Pallas kernel for serving: a chunk over cached latents, in the expanded form
+# ---------------------------------------------------------------------------
+
+
+def _latent_fwd_kernel(
+    blocks_ref, qn_ref, qr_ref, rows_ref, wk_ref, wv_ref, mask_ref, o_ref, acc_ref, m_ref, l_ref,
+    *, scale
+):
+    from jax.experimental import pallas as pl
+
+    ki = pl.program_id(3)
+    heads, nope, rope = qn_ref.shape[2], qn_ref.shape[-1], qr_ref.shape[-1]
+    rank, dv = wk_ref.shape[0], o_ref.shape[-1]
+
+    @pl.when(ki == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    @pl.when(ki < blocks_ref[pl.program_id(0)])
+    def _run():
+        rows = rows_ref[0]                                 # [block_k, row]
+        latent, k_rope = rows[:, :rank], rows[:, rank:rank + rope]
+        keep = mask_ref[0].astype(jnp.int32) > 0          # [block_q, block_k]
+
+        def head(g, carry):
+            # the head's own key and value of this tile, made here and gone
+            # with it: W_kvb's two halves as they are stored, the head's columns
+            k_nope = jnp.dot(
+                latent, wk_ref[:, pl.ds(pl.multiple_of(g * nope, nope), nope)],
+                preferred_element_type=jnp.float32).astype(rows.dtype)
+            v = jnp.dot(
+                latent, wv_ref[:, pl.ds(pl.multiple_of(g * dv, dv), dv)],
+                preferred_element_type=jnp.float32).astype(rows.dtype)
+            s = (
+                jax.lax.dot_general(
+                    qn_ref[0, 0, g], k_nope, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                + jax.lax.dot_general(
+                    qr_ref[0, 0, g], k_rope, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)) * scale
+            s = jnp.where(keep, s, NEG_INF)
+            # the running softmax as ``_masked_fwd_kernel`` keeps it
+            m_prev = m_ref[g]                              # [block_q, 1]
+            m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_ref[g] = alpha * l_ref[g] + p.sum(-1, keepdims=True)
+            acc_ref[g] = acc_ref[g] * alpha + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            m_ref[g] = m_new
+            return carry
+
+        # a loop over the block's heads, as in ``_masked_fwd_kernel`` and for
+        # its reason: one copy of the code a program
+        jax.lax.fori_loop(0, heads, head, None)
+
+    @pl.when(ki == pl.num_programs(3) - 1)
+    def _finish():
+        l = l_ref[:]
+        o_ref[0, 0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+def latent_attention(
+    q_nope: jax.Array,
+    q_rope: jax.Array,
+    rows: jax.Array,
+    k_up: jax.Array,
+    v_up: jax.Array,
+    mask: jax.Array,
+    kv_len: jax.Array,
+    *,
+    scale: float,
+    block_q: int = MASKED_BLOCK_Q,
+    block_k: int = MASKED_BLOCK_K,
+    interpret: bool = False,
+) -> jax.Array:
+    """A prefill chunk over a lane's cached latents in the **expanded** form
+    (``models/kimi_k2.py``), as one kernel: ``softmax(where(mask, (q_nope .
+    k_nope + q_rope . k_rope) * scale, -1e30)) . v`` per head with ``[k_nope ; v]
+    = c_kv W_kvb`` made tile by tile in VMEM, so that a head's key and value
+    never reach HBM (1.34 GB a layer over a cache of 32768 if they did).
+
+    ``q_nope`` [b, t, heads, nope] and ``q_rope`` [b, t, heads, rope] (rotated)
+    are the queries as ``W_qb`` leaves them; ``rows`` [b, s, row] the cache as it
+    lies, a row's first ``rank`` features the normed latent ``c_kv`` and the
+    next ``rope`` the rotated key all heads share; ``k_up`` [rank, heads, nope]
+    and ``v_up`` [rank, heads, dv] the two halves of ``W_kvb`` as the parameters
+    hold them; ``mask`` [b, t, s] and ``kv_len`` [b] as in
+    :func:`masked_attention`: key tiles at or past a lane's ``kv_len`` are neither
+    fetched, expanded nor computed. Operands go to the MXU in ``rows``' type
+    (a head's key and value are rounded to it as the published model's own
+    code rounds them), products are summed and the scores, running maximum, sum
+    and accumulator kept in float32. A block of heads meets a tile together,
+    one after another: as many as :func:`_heads_a_tile` finds room for beside
+    their accumulators' running maximum and sum, which take a lane tile each.
+    Returns [b, t, heads, dv] in ``q_nope``'s type: what goes into ``W_o``.
+
+    Against the absorbed form in :func:`masked_attention` (one 640-wide row
+    under 64 heads, a value of 512) a pair costs 2 x 320 operations a head
+    where it cost 2 x 1088, and a slot 2 x 512 x 256 a head once a chunk: at 512
+    queries 37.8 M operations a slot for 71.3 M. On a v5e, 512 queries x 64
+    heads over 4096, 16384 and 32768 live slots of a 32768 cache: 1.37, 4.52
+    and 8.75 ms where ``masked_attention`` takes 2.42, 7.73 and 14.81 (4.3 us a
+    head and tile of 1024 keys, of which the MXU's passes are 3.4). Tiles of
+    2048 keys, 16 heads a block, the score as one dot over a joined key and the
+    heads unrolled two, four or eight at a time each move that by under 3 %
+    (PERF.md, PR 51)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, t, n_heads, nope = q_nope.shape
+    rope, s, row = q_rope.shape[-1], rows.shape[1], rows.shape[2]
+    rank, dv = k_up.shape[0], v_up.shape[-1]
+    block_q, block_k = min(block_q, t), min(block_k, s)
+    # float32 lanes a head and query: the accumulator's, and a tile each for m and l
+    heads = _heads_a_tile(n_heads, block_q, dv + 2 * 128)
+    tiles = n_heads // heads
+    pad_q, pad_k = -t % block_q, -s % block_k
+    if pad_q or pad_k:
+        q_nope, q_rope = (
+            jnp.pad(x, ((0, 0), (0, pad_q), (0, 0), (0, 0))) for x in (q_nope, q_rope))
+        rows = jnp.pad(rows, ((0, 0), (0, pad_k), (0, 0)))
+        mask = jnp.pad(mask, ((0, 0), (0, pad_q), (0, pad_k)))
+    nq, nk = (t + pad_q) // block_q, (s + pad_k) // block_k
+    blocks = jnp.clip((kv_len.astype(jnp.int32) + block_k - 1) // block_k, 0, nk)
+
+    def kv_block(bi, ki, blocks):
+        # past the lane's last live block: the block already there, no fetch
+        return jnp.maximum(jnp.minimum(ki, blocks[bi] - 1), 0)
+
+    def q_spec(width):
+        return pl.BlockSpec(
+            (1, 1, heads, block_q, width), lambda bi, hi, qi, ki, blocks: (bi, hi, 0, qi, 0))
+
+    def up_spec(width):                 # the block's heads' columns of one half of W_kvb
+        return pl.BlockSpec((rank, heads * width), lambda bi, hi, qi, ki, blocks: (0, hi))
+
+    def by_head(q):
+        return q.transpose(0, 2, 1, 3).reshape(b, tiles, heads, t + pad_q, q.shape[-1])
+
+    out = pl.pallas_call(
+        functools.partial(_latent_fwd_kernel, scale=float(scale)),
+        name="latent_attention",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, tiles, nq, nk),
+            in_specs=[
+                q_spec(nope), q_spec(rope),
+                pl.BlockSpec(
+                    (1, block_k, row),
+                    lambda bi, hi, qi, ki, blocks: (bi, kv_block(bi, ki, blocks), 0)),
+                up_spec(nope), up_spec(dv),
+                pl.BlockSpec(
+                    (1, block_q, block_k),
+                    lambda bi, hi, qi, ki, blocks: (bi, qi, kv_block(bi, ki, blocks))),
+            ],
+            out_specs=q_spec(dv),
+            scratch_shapes=[
+                pltpu.VMEM((heads, block_q, dv), jnp.float32),
+                pltpu.VMEM((heads, block_q, 1), jnp.float32),
+                pltpu.VMEM((heads, block_q, 1), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, tiles, heads, t + pad_q, dv), q_nope.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=MASKED_VMEM_BYTES),
+        interpret=interpret,
+    )(
+        blocks, by_head(q_nope), by_head(q_rope), rows,
+        k_up.reshape(rank, n_heads * nope), v_up.reshape(rank, n_heads * dv),
+        mask.astype(jnp.int8))
+    return out.reshape(b, n_heads, t + pad_q, dv).transpose(0, 2, 1, 3)[:, :t]

@@ -699,12 +699,13 @@ def _kimi_share():
 def test_kimi_k2_share_extend_compiles_at_its_largest_shapes(shaped, form, built_for_tpu):
     """One chip's share of Kimi K2 at its published widths (9.70 GB of weights)
     over the largest cache bucket: a decode call attends in the absorbed form
-    in XLA, a prefill chunk in the attention kernel (one row of 640 under 64
-    query heads, eight at a time, its first 512 the value), and no
-    float32 score of a chunk over the cache is left in the program (4.3 GB a
-    lane if it were); it fits beside the pool and a second call's caches, copies
-    no layer's experts (1.1 GB) and holds the memory the configuration's file
-    states."""
+    in XLA, a prefill chunk in the expanded form in ``latent_attention`` (each
+    tile of 640-wide rows through ``W_kvb`` in VMEM, eight heads at a time), and
+    neither a float32 score of a chunk over the cache (4.3 GB a lane if it were)
+    nor a head's keys or values of the cache's slots (0.54 GB a layer each) is
+    left in the program; it fits beside the pool and a second call's caches,
+    copies no layer's experts (1.1 GB) and holds the memory the configuration's
+    file states."""
     built_for_tpu(True)     # the chip's grouped matmul and attention kernel
     cfg, config = _kimi_share()
     engine, stated = config["engine"], config["compiled_bytes_per_device"]
@@ -729,14 +730,20 @@ def test_kimi_k2_share_extend_compiles_at_its_largest_shapes(shaped, form, built
         # layer 0's attend and the scanned layers': straight under the scope the readers count
         attends = [line for line in kernels if line not in experts]
         assert len(attends) == 2 and all(
-            "/extend.attention/masked_attention/" in line for line in attends)
+            "/extend.attention/latent_attention/" in line for line in attends)
         # ... and no float32 array over the cache as large as 32 queries' scores
         import math
 
-        over_cache = [
-            math.prod(map(int, dims.split(",")))
-            for dims in re.findall(r"f32\[([0-9,]+)\]", text) if str(cap) in dims.split(",")]
-        assert max(over_cache, default=0) < cfg.num_heads * layers.QUERY_BLOCK * cap
+        def over_cache(types):
+            return max((
+                math.prod(map(int, dims.split(",")))
+                for dims in re.findall(rf"(?:{types})\[([0-9,]+)\]", text)
+                if str(cap) in dims.split(",")), default=0)
+
+        assert over_cache("f32") < cfg.num_heads * layers.QUERY_BLOCK * cap
+        # ... nor the heads' keys or values of the cache's slots, in any type
+        # (the expansion never leaves VMEM)
+        assert over_cache("f32|bf16") < cap * cfg.num_heads * cfg.v_dim
     else:
         assert kernels == experts
         assert f"f32[{lanes},{cfg.num_heads},1,{cap}]" in text     # a decode lane's scores

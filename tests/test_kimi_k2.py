@@ -1,10 +1,12 @@
 """``models/kimi_k2.py`` on the CPU at a tiny size: the absorbed attend against
 the expanded form written out here, a decode lane and a prefill chunk and the
-chip's kernel (interpreted) give the same row, padding changes and counts
-nothing, YaRN's frequencies and scale against the closed form at the published
-keys, the router's bias chooses and does not weigh, the attention kernel with
-``d_k != d_v`` and one K/V head under several blocks of query heads, and the
-configuration's own arithmetic. The comparison with the plain reference is the
+chip's chunk (the expanded form's kernel, interpreted) give the same row and
+count their pairs each under its form, padding changes and counts nothing,
+YaRN's frequencies and scale against the closed form at the published keys, the
+router's bias chooses and does not weigh, the attention kernel with ``d_k !=
+d_v`` and one K/V head under several blocks of query heads, the kernel that
+expands each tile of latents against the dense expanded form and against the
+absorbed one, and the configuration's own arithmetic. The comparison with the plain reference is the
 benchmark's (``tests/benchmark/test_bench_kimi_k2.py``)."""
 
 import math
@@ -156,34 +158,90 @@ def test_a_decode_lane_and_a_prefill_chunk_give_the_same_row(program, chunked):
     assert (before["moe_tokens"], named["moe_tokens"]) == (CFG.expert_layers * 24, CFG.expert_layers)
 
 
-def test_the_chips_kernel_gives_the_chunks_rows(program, chunked, monkeypatch):
-    """What a prefill chunk runs on the chip: the same ``extend`` with the
-    attention kernel in its place (interpreted), one latent row under two
-    blocks of four query heads."""
+@pytest.fixture
+def on_the_chip(monkeypatch):
+    """``extend`` as the chip traces it, on the CPU: ``backend.on_tpu`` answers
+    yes, the chunk's attention kernel runs interpreted at small tiles with its
+    64 heads' budget cut to two blocks of four, the grouped matmul is XLA's.
+    Yields the shapes the kernel was called with."""
     from ray_tpu.ops import backend
 
-    _, tokens, want, _, _ = chunked
-    real = attention.masked_attention
+    real = attention.latent_attention
     seen = []
 
-    def interpreted(q, k, v, mask, kv_len, **kw):
-        seen.append((q.shape, k.shape, v.shape))
-        return real(q, k, v, mask, kv_len, interpret=True, block_q=16, block_k=32, **kw)
+    def interpreted(q_nope, q_rope, rows, k_up, v_up, mask, kv_len, **kw):
+        seen.append((q_nope.shape, q_rope.shape, rows.shape, k_up.shape, v_up.shape))
+        return real(
+            q_nope, q_rope, rows, k_up, v_up, mask, kv_len, interpret=True, block_q=16,
+            block_k=32, **kw)
+
+    def never(*a, **kw):
+        raise AssertionError("a latent chunk on the chip attends in latent_attention")
 
     monkeypatch.setattr(backend, "on_tpu", lambda: True)
     monkeypatch.setattr(moe, "grouped_matmul", lambda rows, w, sizes: jax.lax.ragged_dot(rows, w, sizes))
-    monkeypatch.setattr(attention, "masked_attention", interpreted)
-    monkeypatch.setattr(attention, "MASKED_ACC_BYTES", 4 * 16 * RANK * 4)
-    assert attention._heads_a_tile(CFG.num_heads, 16, RANK) == 4 == CFG.num_heads // 2
+    monkeypatch.setattr(attention, "latent_attention", interpreted)
+    monkeypatch.setattr(attention, "masked_attention", never)
+    monkeypatch.setattr(attention, "MASKED_ACC_BYTES", 4 * 16 * (CFG.v_dim + 256) * 4)
     jax.clear_caches()
-    try:
-        got, *_ = CFG.make_extend_fn()(
-            program, tokens[:, :24], jnp.zeros((1,), jnp.int32), _cache(1, 64))
-    finally:
-        jax.clear_caches()
-    assert seen and set(seen) == {
-        ((1, 24, 1, CFG.num_heads, ROW), (1, 64, 1, ROW), (1, 64, 1, RANK))}
+    yield seen
+    jax.clear_caches()
+
+
+def test_the_chips_kernel_gives_the_chunks_rows(program, chunked, on_the_chip):
+    """What a prefill chunk runs on the chip: the same ``extend`` with the
+    expanded form's kernel in its place (interpreted), every tile of rows put
+    through ``W_kvb`` under two blocks of four heads. It gives the absorbed
+    form's logits and rows, and counts its pairs as expanded."""
+    _, tokens, want, held, _ = chunked
+    assert attention._heads_a_tile(CFG.num_heads, 16, CFG.v_dim + 256) == 4 == CFG.num_heads // 2
+    got, _, rows, counters = CFG.make_extend_fn()(
+        program, tokens[:, :24], jnp.zeros((1,), jnp.int32), _cache(1, 64))
+    h = CFG.num_heads
+    assert on_the_chip and set(on_the_chip) == {(
+        (1, 24, h, CFG.nope_dim), (1, 24, h, ROPE), (1, 64, ROW), (RANK, h, CFG.nope_dim),
+        (RANK, h, CFG.v_dim))}
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-5, rtol=3e-5)
+    # the rows are made as off the chip: layer 0's to the bit, the others' of a
+    # stream that differs by the attend's rounding
+    np.testing.assert_array_equal(np.asarray(rows)[0], np.asarray(held)[0, :, :24])
+    np.testing.assert_allclose(np.asarray(rows), np.asarray(held)[:, :, :24], atol=3e-5, rtol=3e-5)
+    named = _named(counters)
+    assert named["mla_queries"] == CFG.num_layers * 24
+    assert named["mla_pairs_expanded"] == CFG.num_layers * 24 * 25 // 2
+    assert named["mla_pairs_absorbed"] == 0
+    assert named["mla_rows_expanded"] == CFG.num_layers * 24
+
+
+def test_on_the_chip_a_decode_call_stays_absorbed_and_a_chunk_counts_its_live_slots(
+        program, chunked, on_the_chip):
+    """Under the chip's answer a decode call is the program it was (no kernel,
+    its pairs counted as absorbed), and a second chunk behind the first counts
+    every live slot of the lane through ``W_kvb``, and no pair of padding."""
+    extend, tokens, _, held, _ = chunked
+    want, _, _, counted = extend(program, tokens[:, 24:], jnp.full((1,), 24, jnp.int32), held)
+    chip = CFG.make_extend_fn()
+    one, _, _, counters = chip(program, tokens[:, 24:], jnp.full((1,), 24, jnp.int32), held)
+    assert not on_the_chip
+    assert _named(counters) == _named(counted)
+    assert _named(counters)["mla_pairs_absorbed"] == CFG.num_layers * 25
+    assert _named(counters)["mla_pairs_expanded"] == _named(counters)["mla_rows_expanded"] == 0
+    np.testing.assert_allclose(np.asarray(one), np.asarray(want), atol=2e-5, rtol=2e-5)
+    # ten more tokens behind the 24 cached, six of the chunk's sixteen padding,
+    # beside a lane of padding alone
+    more = jnp.concatenate([_tokens(10, seed=4), jnp.full((1, 6), -1, jnp.int32)], 1)
+    lanes = jnp.concatenate([more, jnp.full((1, 16), -1, jnp.int32)])
+    both = jnp.concatenate([held, jnp.zeros_like(held)], 1)
+    off, *_ = extend(program, lanes, jnp.asarray([24, 0], jnp.int32), both)
+    got, _, _, counters = chip(program, lanes, jnp.asarray([24, 0], jnp.int32), both)
+    assert on_the_chip and len(set(on_the_chip)) == 1
+    np.testing.assert_allclose(np.asarray(got)[0, :10], np.asarray(off)[0, :10], atol=3e-5, rtol=3e-5)
+    assert np.isfinite(np.asarray(got)).all()
+    named = _named(counters)
+    assert named["mla_queries"] == CFG.num_layers * 10
+    assert named["mla_pairs_expanded"] == CFG.num_layers * sum(range(25, 35))
+    assert named["mla_rows_expanded"] == CFG.num_layers * 34
+    assert named["mla_pairs_absorbed"] == 0
 
 
 def test_padding_changes_no_real_token_and_counts_nothing(program):
@@ -324,6 +382,117 @@ def test_the_attention_kernel_with_a_value_narrower_than_its_key(case, monkeypat
 ])
 def test_as_many_heads_meet_a_tile_as_the_accumulator_holds(groups, dv, heads):
     assert attention._heads_a_tile(groups, attention.MASKED_BLOCK_Q, dv) == heads
+
+
+# -- the kernel that expands a tile of latents ---------------------------------------
+
+
+def _dense_expanded(q_nope, q_rope, rows, k_up, v_up, mask, scale):
+    """The expanded form written out: every head's keys and values of every
+    slot, float32 throughout."""
+    rank, rope = k_up.shape[0], q_rope.shape[-1]
+    f32 = lambda x: jnp.asarray(x, jnp.float32)
+    latent, key = f32(rows)[..., :rank], f32(rows)[..., rank:rank + rope]
+    k = jnp.einsum("bsc,chn->bshn", latent, f32(k_up), precision="highest")
+    v = jnp.einsum("bsc,chv->bshv", latent, f32(v_up), precision="highest")
+    logit = scale * (
+        jnp.einsum("bthn,bshn->bhts", f32(q_nope), k, precision="highest")
+        + jnp.einsum("bthr,bsr->bhts", f32(q_rope), key, precision="highest"))
+    weight = jax.nn.softmax(jnp.where(mask[:, None], logit, -1e30), axis=-1)
+    return jnp.einsum("bhts,bshv->bthv", weight, v, precision="highest")
+
+
+EXPANDED_CASES = {
+    # heads, heads a block, nope, rope, rank, d_v, row, starts, queries, query tile, key tile
+    "two_blocks_of_four_small_tiles": (8, 4, 16, 8, 32, 16, 128, (5,), 24, 8, 16),
+    "one_block_a_lane_cut_short": (4, None, 16, 8, 32, 16, 128, (0, 30), 16, 16, 16),
+    "eight_blocks_of_one_head": (8, 1, 8, 8, 16, 24, 32, (3, 11), 24, 16, 48),
+    "tiles_wider_than_the_call": (16, 8, 16, 16, 32, 8, 64, (7,), 16, 512, 2048),
+}
+
+
+@pytest.mark.parametrize("case", list(EXPANDED_CASES))
+def test_the_latent_kernel_is_the_dense_expanded_form(case, monkeypatch):
+    """``ops/attention.latent_attention``, interpreted, in float32 against the
+    expanded form written out: heads in one or several blocks, a random mask
+    under the causal one, key tiles past a lane's live bound holding NaN (never
+    fetched), a query tile and a key tile that do not divide the call. A query
+    whose mask is empty gets finite rubbish and changes no other row."""
+    heads, block, nope, rope, rank, dv, row, starts, tokens, block_q, block_k = EXPANDED_CASES[case]
+    if block:
+        # room for ``block`` heads' accumulators, maxima and sums, and no more
+        monkeypatch.setattr(
+            attention, "MASKED_ACC_BYTES", block * min(block_q, tokens) * (dv + 256) * 4)
+        assert attention._heads_a_tile(heads, min(block_q, tokens), dv + 256) == block
+    rng = np.random.default_rng(len(case))
+    lanes, cache = len(starts), 64
+    draw = lambda *shape: rng.standard_normal(shape).astype(np.float32)
+    q_nope, q_rope = draw(lanes, tokens, heads, nope), draw(lanes, tokens, heads, rope)
+    k_up, v_up = draw(rank, heads, nope) * rank ** -0.5, draw(rank, heads, dv) * rank ** -0.5
+    rows = draw(lanes, cache, row)
+    positions = np.asarray(starts)[:, None] + np.arange(tokens)[None, :]
+    mask = (np.arange(cache)[None, None, :] <= positions[:, :, None]) & (
+        rng.random((lanes, tokens, cache)) < 0.6)
+    mask[np.arange(lanes)[:, None], np.arange(tokens)[None, :], positions] = True
+    mask[0, 3] = False                               # a query that may read nothing
+    kv_len = positions.max(1) + 1
+    dirty = rows.copy()
+    step = min(block_k, cache)
+    for lane, n in enumerate(kv_len):
+        dirty[lane, -(-n // step) * step:] = np.nan
+    out = np.asarray(attention.latent_attention(
+        *map(jnp.asarray, (q_nope, q_rope, dirty, k_up, v_up, mask)),
+        jnp.asarray(kv_len, jnp.int32), scale=0.2, block_q=block_q, block_k=block_k,
+        interpret=True))
+    want = np.asarray(_dense_expanded(q_nope, q_rope, rows, k_up, v_up, jnp.asarray(mask), 0.2))
+    assert out.shape == want.shape == (lanes, tokens, heads, dv)
+    assert np.isfinite(out).all()
+    real = mask.any(-1)
+    np.testing.assert_allclose(out[real], want[real], atol=5e-6, rtol=5e-6)
+
+
+def test_the_expanded_and_the_absorbed_form_agree_within_bfloat16():
+    """The same rows, queries and ``W_kvb`` in bfloat16 through both kernels: the
+    absorbed form (``q_nope W_kvb^K`` against the row, the summed latents through
+    ``W_kvb^V``) and the expanded one differ by their roundings alone, and each
+    from the float32 expanded form by as much."""
+    heads, nope, rope, rank, dv, row, tokens, cache = 8, 16, 8, 32, 16, 128, 32, 64
+    rng = np.random.default_rng(11)
+    draw = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    q_nope, q_rope = draw(1, tokens, heads, nope), draw(1, tokens, heads, rope)
+    k_up, v_up = draw(rank, heads, nope) * rank ** -0.5, draw(rank, heads, dv) * rank ** -0.5
+    rows = draw(1, cache, row).at[..., rank + rope:].set(0)
+    positions = 20 + np.arange(tokens)[None, :]
+    mask = jnp.asarray(np.arange(cache)[None, None, :] <= positions[:, :, None])
+    kv_len = jnp.asarray([20 + tokens], jnp.int32)
+    expanded = attention.latent_attention(
+        q_nope, q_rope, rows, k_up, v_up, mask, kv_len, scale=0.2, block_q=16, block_k=32,
+        interpret=True)
+    met = jnp.concatenate([
+        jnp.einsum("bthn,chn->bthc", q_nope, k_up), q_rope,
+        jnp.zeros((1, tokens, heads, row - rank - rope), jnp.bfloat16)], -1)
+    summed = attention.masked_attention(
+        met[:, :, None], rows[:, :, None], rows[:, :, None, :rank], mask, kv_len, scale=0.2,
+        block_q=16, block_k=32, interpret=True)[:, :, 0]
+    absorbed = jnp.einsum("bthc,chv->bthv", summed, v_up)
+    assert expanded.dtype == absorbed.dtype == jnp.bfloat16
+    want = np.asarray(_dense_expanded(q_nope, q_rope, rows, k_up, v_up, mask, 0.2))
+    both = [np.asarray(x, np.float32) for x in (expanded, absorbed)]
+    assert 0 < np.abs(both[0] - both[1]).max() < 0.05 * np.abs(want).max()
+    for got in both:
+        assert np.abs(got - want).max() < 0.05 * np.abs(want).max()
+
+
+@pytest.mark.parametrize("heads, block_q, dv, block", [
+    (64, 512, 128, 8),      # the published widths: eight blocks of eight heads
+    (64, 128, 128, 32),     # a shorter chunk leaves room for more
+    (8, 16, 16, 8),         # the tiny model's heads, all at once
+])
+def test_a_block_of_the_latent_kernels_heads_has_room_for_its_running_softmax(
+        heads, block_q, dv, block):
+    """Beside a head's float32 accumulator stand its running maximum and sum, a
+    lane tile (128) each in VMEM whatever their one column."""
+    assert attention._heads_a_tile(heads, block_q, dv + 2 * 128) == block
 
 
 # -- the configuration ---------------------------------------------------------------
