@@ -2,13 +2,15 @@
 split, prefix caching, and LoRA-scale multiplexing over a real model's
 forward pass: ``ray_tpu.models.gpt``, or any architecture whose configuration
 answers what the engine asks of it (``make_extend_fn()``, ``init_params(seed)``
-and ``cache_arrays``: what a cached token holds, as ``(heads, dim)`` per array;
+and ``cache_arrays``: what a cached token holds, as ``(heads, dim)`` per array,
+or ``(heads, dim, tokens a row)`` for an array kept at a coarser grain: a row for
+every so many tokens, which belongs to the last of them (a compressed key);
 beside the sizes ``num_layers``, ``embed_dim``, ``vocab_size``, ``max_seq_len``
 and ``dtype``; where its ``extend`` counts something, ``counters`` names what;
 where it keeps state per sequence and not per token, ``state_arrays`` names
 that, ``state_chunk`` how often a state can be kept, and ``cache_layers`` in how
 many layers a token is cached; ``models/cohere2_moe.py``, ``models/keye_vl2.py``,
-``models/kimi_k2.py``, ``models/granitemoehybrid.py``).
+``models/kimi_k2.py``, ``models/granitemoehybrid.py``, ``models/minicpm_sala.py``).
 
 What PR 9 proved with synthetic step functions (continuous batching,
 admission control, multiplexing) this module composes on an actual model
@@ -156,6 +158,12 @@ _LENGTH, _LAST, _COUNT, _FROM, _SCALARS = 0, 1, 2, 3, 4
 _SLOT, _SNAP_AT, _SNAP_SLOT, _STATE_COLUMNS = -3, -2, -1, 3
 
 
+def cache_grain(each) -> int:
+    """Tokens a row of one entry of a configuration's ``cache_arrays``: its third
+    element where it has one (an array kept at a coarser grain), else 1."""
+    return each[2] if len(each) > 2 else 1
+
+
 def _operand_width(tokens: int, blocks: int, stateful: bool = False) -> int:
     """The width of the operand buffer of an engine whose widest call feeds
     ``tokens`` a lane over a cache of ``blocks`` blocks."""
@@ -226,29 +234,50 @@ def _paging_programs():
                 for out, arena in zip(caches, sources))
 
         caches = jax.lax.fori_loop(0, b * n, copy_block, empty)
+        # an arena at a coarser grain (a row for every so many tokens) has fewer
+        # rows a block, and its padded cache as many fewer
         return tuple(
-            c.reshape((layers, b, n * block) + a.shape[3:]) for c, a in zip(caches, arenas))
+            c.reshape((layers, b, n * a.shape[2]) + a.shape[3:]) for c, a in zip(caches, arenas))
 
     @functools.partial(jax.jit, donate_argnums=0, static_argnums=5)
     @jax.named_scope("paging.page_back")
     def page_back(arenas, news, operands, outputs, counted, width):
         layers, blocks, block = arenas[0].shape[:3]
         b, tc = news[0].shape[1:3]
-        news = tuple(x.reshape((layers, b * tc) + x.shape[3:]) for x in news)
+        news = tuple(x.reshape((layers, b * x.shape[2]) + x.shape[3:]) for x in news)
         rows, slots = (x[:, :tc].reshape(-1) for x in _sections(operands)[1:3])
         last = operands[:, _LAST]
+        # tokens a row of each arena: 1, but for one kept at a coarser grain
+        grains = tuple(block // a.shape[2] for a in arenas)
+
+        def write_coarse(i, tokens, new, grain):
+            """An arena with one row for every ``grain`` tokens: the row belongs to
+            the last of them, so only a token that ends a group writes one, to
+            slot ``slots[i] // grain``. ``new`` holds a lane's rows in the order of
+            their tokens: the one this token ends is as far in as groups end
+            between the lane's first token and this one."""
+            lane, at = rows[i] // tc, rows[i] % tc
+            length = operands[lane, _LENGTH]
+            source = lane * (new.shape[1] // b) + (length + at + 1) // grain - length // grain - 1
+            to = slots[i] // grain
+            return jax.lax.dynamic_update_slice_in_dim(
+                tokens, jnp.where(
+                    (slots[i] + 1) % grain == 0,
+                    jax.lax.dynamic_slice_in_dim(new, jnp.maximum(source, 0), 1, axis=1),
+                    jax.lax.dynamic_slice_in_dim(tokens, to, 1, axis=1)),
+                to, axis=1)
 
         def write_token(i, tokens_of):
             return tuple(
                 jax.lax.dynamic_update_slice_in_dim(
                     tokens, jax.lax.dynamic_slice_in_dim(new, rows[i], 1, axis=1),
-                    slots[i], axis=1)
-                for tokens, new in zip(tokens_of, news))
+                    slots[i], axis=1) if grain == 1 else write_coarse(i, tokens, new, grain)
+                for tokens, new, grain in zip(tokens_of, news, grains))
 
         # the count is traced: a loop the compiler cannot unroll, whatever the
         # shapes (unrolled at one token it re-lays the arenas out and back)
         written = jax.lax.fori_loop(0, operands[0, _COUNT], write_token, tuple(
-            a.reshape((layers, blocks * block) + a.shape[3:]) for a in arenas))
+            a.reshape((layers, blocks * a.shape[2]) + a.shape[3:]) for a in arenas))
         picked = tuple(
             jnp.stack([
                 jax.lax.dynamic_index_in_dim(o[i], last[i], 0, keepdims=False)
@@ -306,7 +335,14 @@ class KVBlockPool:
     block_size, heads, dim]`` in the model's dtype, one per entry ``(heads,
     dim)`` of the configuration's ``cache_arrays``: K and V over the heads a
     cache stores (fewer than the query heads where attention is grouped), and
-    whatever else the model leaves behind for a token (an indexer's key). A
+    whatever else the model leaves behind for a token (an indexer's key). An
+    entry with a third element, ``(heads, dim, tokens a row)``, is kept at that
+    grain (``grains``): its arena is ``[layers, num_blocks, block_size // tokens
+    a row, heads, dim]``, a row for every so many tokens, which belongs to the
+    page of the last of them (a compressed key made of keys that may begin in
+    the page before): ``extend`` hands back the rows whose last token a call
+    brings, in the order of their tokens, the page-back writes each with that
+    token, and gather, clone, sharing and eviction carry it with its page. A
     sequence owns an
     ordered list of block ids whose concatenation is its cache, so the
     blocks a table names, side by side, are the padded caches ``extend``
@@ -341,14 +377,24 @@ class KVBlockPool:
         self.layers = getattr(cfg, "cache_layers", cfg.num_layers)
         self.state_arrays = tuple(getattr(cfg, "state_arrays", ()))
         self.state_slots = int(state_slots) if self.state_arrays else 0
+        #: tokens a row of each arena: an entry of ``cache_arrays`` with a third
+        #: element is kept at that grain (a row for every so many tokens, which
+        #: belongs to the last of them), any other has a row a token
+        self.grains = tuple(map(cache_grain, cfg.cache_arrays))
+        if any(self.block_size % grain for grain in self.grains):
+            raise ValueError(
+                f"blocks of {self.block_size} tokens do not hold whole rows of "
+                f"{self.grains} tokens: {cfg.cache_arrays}")
         #: the bytes one sequence's state takes, over all layers and arrays
         self.state_bytes = sum(
             layers * math.prod(shape) * jnp.dtype(dtype).itemsize
             for layers, shape, dtype in self.state_arrays)
-        slab = (self.layers, self.num_blocks, self.block_size)
         try:
             self.arenas = tuple(
-                jnp.zeros(slab + tuple(each), self.dtype) for each in cfg.cache_arrays)
+                jnp.zeros(
+                    (self.layers, self.num_blocks, self.block_size // grain) + tuple(each[:2]),
+                    self.dtype)
+                for each, grain in zip(cfg.cache_arrays, self.grains))
             self.states = tuple(
                 jnp.zeros((layers, self.state_slots) + tuple(shape), dtype)
                 for layers, shape, dtype in self.state_arrays)
@@ -381,8 +427,10 @@ class KVBlockPool:
 
     def cache_bytes(self, tokens: int) -> int:
         """The bytes ``tokens`` cached tokens take, over all layers and arrays."""
-        per_token = sum(heads * dim for heads, dim in self.cfg.cache_arrays)
-        return self.layers * tokens * per_token * self.dtype.itemsize
+        values = sum(
+            each[0] * each[1] * (tokens // grain)
+            for each, grain in zip(self.cfg.cache_arrays, self.grains))
+        return self.layers * values * self.dtype.itemsize
 
     # -- the arenas: device programs only ----------------------------------
 
@@ -399,7 +447,9 @@ class KVBlockPool:
         ``news`` ``[layers, b, tc, heads, dim]`` (one per arena) into its arena at
         token slot ``slots[i]`` (block x block_size + offset) for the first
         ``count`` entries of the ``rows`` / ``slots`` sections of ``operands``
-        and, in the same program, pick row ``last[i]`` of lane ``i`` from each
+        (of an arena at a coarser grain, ``[layers, b, ceil(tc / grain), heads,
+        dim]``: the row a token ends, where it ends one, into slot ``slots[i] //
+        grain``) and, in the same program, pick row ``last[i]`` of lane ``i`` from each
         of ``outputs`` ``[b, tc, ...]`` (logits first) and sample it. Returns
         one int32 array, for the host and for the next call to read on the
         device (the ``b`` greedy ids padded to ``width``, then the int32
@@ -1104,8 +1154,8 @@ class LLMEngine:
         ``cap``, each made by ``make(shape, dtype)``; for the pool's state arenas
         ``states`` where given (a call that runs is handed the arenas)."""
         caches = (
-            make((self.pool.layers, b, cap) + tuple(each), self.pool.dtype)
-            for each in self.cfg.cache_arrays)
+            make((self.pool.layers, b, cap // grain) + tuple(each[:2]), self.pool.dtype)
+            for each, grain in zip(self.cfg.cache_arrays, self.pool.grains))
         if states is None:
             states = (make(s.shape, s.dtype) for s in self.pool.states)
         return (

@@ -8,7 +8,9 @@ modules the cell's configuration names (``benchmark/manifest.py``), the tiny
 model is ``tests/benchmark/tiny/<model_type>.json``. Where the reference tells
 what each query selected (``program_selection``: learned sparse attention) and
 the program has a probe for it (``make_probe_fn``), ``--overlap-seeds`` says
-how far the two sets overlap. The builder sets ``reference.max_logits_error``
+how far the two sets overlap; where the reference tells the *blocks* each query
+read, a K/V head at a time (``program_blocks``: block-sparse attention), the
+overlap is of those. The builder sets ``reference.max_logits_error``
 in the configuration's file from these readings, by hand (PERF.md, section 6).
 
     python3 scripts/gate_probe.py --cell kimi-k2-serve-long-context --seeds 1,2,3 \
@@ -60,6 +62,45 @@ def selection_of(probe, cfg, params, fed, chunk: int, cap: int):
     return out
 
 
+def blocks_of(probe, cfg, params, fed, chunk: int, cap: int):
+    """The blocks each query of ``fed`` read, bool [sparse layers, seq, kv, blocks],
+    by a program whose sparse layers choose blocks from compressed keys cached at
+    their own grain beside a per-sequence state (``models/minicpm_sala.py``): the
+    prompt in chunks of ``chunk`` (the prefill form), what is left a token at a time
+    (the decode form), over caches of ``cap`` slots and one state slot."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.serve.llm import cache_grain
+
+    caches = [
+        jnp.zeros((cfg.cache_layers, 1, cap // cache_grain(each)) + tuple(each[:2]), cfg.dtype)
+        for each in cfg.cache_arrays]
+    states = [
+        jnp.zeros((layers, 2) + tuple(shape), dtype) for layers, shape, dtype in cfg.state_arrays]
+    seq, whole = len(fed), len(fed) // chunk * chunk
+    size, stride = cfg.select_block, cfg.kernel_stride
+    out = np.zeros((cfg.cache_layers, seq, cfg.kv_heads, cap // size), bool)
+    one, none = jnp.ones((1,), jnp.int32), jnp.zeros((1,), jnp.int32)
+    at = 0
+    while at < seq:
+        n = chunk if at < whole else 1
+        tokens = jnp.asarray([fed[at:at + n]], jnp.int32)
+        _, _, k, v, c, *states, _, selected = probe(
+            params, tokens, jnp.full((1,), at, jnp.int32), *caches, *states, one, none, none)
+        caches[:2] = [x.at[:, :, at:at + n].set(new) for x, new in zip(caches, (k, v))]
+        # row r of the compressed keys is the one whose last key is the r-th token
+        # of the call that ends a group of ``stride``
+        for r in range(c.shape[2]):
+            row = at // stride + r
+            if (row + 1) * stride <= at + n and (row + 1) * stride >= cfg.kernel_size:
+                caches[2] = caches[2].at[:, :, row].set(c[:, :, r])
+        read = np.asarray(selected)[:, 0]                     # [layers, kv, n, cap]
+        out[:, at:at + n] = read.reshape(read.shape[:3] + (-1, size)).any(-1).transpose(0, 2, 1, 3)
+        at += n
+    return out
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--cell", required=True)
@@ -95,6 +136,11 @@ def main(argv=None) -> int:
         sizes = dict(num_blocks=64, block_size=256, prefill_chunk=512, lane_buckets=(1,),
                      prefill_token_buckets=(512,), cache_buckets=(8192,))
     new = args.new_tokens or new
+    if prompt_tokens + new > sizes["cache_buckets"][0]:
+        # a gate past the usual bucket: the cell's own smallest bucket that holds it,
+        # and blocks for the prompt twice
+        cap = min(b for b in config["engine"]["cache_buckets"] if b >= prompt_tokens + new)
+        sizes.update(cache_buckets=(cap,), num_blocks=2 * cap // sizes["block_size"])
     cfg = arch.program_config(manifest.published_keys(config))
     seeds = [int(s) for s in args.seeds.split(",")]
     wrong_seeds = {int(s) for s in args.wrong_seeds.split(",") if s}
@@ -150,7 +196,24 @@ def main(argv=None) -> int:
                 other = np.asarray(ref.program_logits(params, fed, config, new, w))
                 row["wrong"][w] = yardstick.logits_error(first["logits"], other)
                 row["wrong_of_first"][w] = by_rows(other)
-        if seed in overlap_seeds:
+        if seed in overlap_seeds and hasattr(ref, "program_blocks"):
+            theirs = ref.program_blocks(params, fed, config)      # [layers, seq, kv, blocks]
+            ours = blocks_of(
+                probe, cfg, params, fed, sizes["prefill_chunk"], sizes["cache_buckets"][-1]
+            )[..., :theirs.shape[-1]]
+            choosing = np.arange(len(fed)) >= cfg.dense_len       # queries with a choice to make
+            shared = (ours & theirs)[:, choosing].sum(-1) / theirs[:, choosing].sum(-1)
+            row["overlap"] = {
+                "queries": int(choosing.sum()),
+                "sizes_equal": bool((ours.sum(-1) == theirs.sum(-1)).all()),
+                "dense_equal": bool((ours == theirs)[:, ~choosing].all()),
+                "blocks_a_query": float(theirs[:, choosing].sum(-1).mean()),
+                "mean_by_layer": [float(x) for x in shared.mean((1, 2))],
+                "min_by_layer": [float(x) for x in shared.min((1, 2))],
+                "decode_form_mean": float(shared[:, -(len(fed) - prompt_tokens):].mean())
+                if len(fed) > prompt_tokens else None,
+            }
+        elif seed in overlap_seeds:
             theirs = ref.program_selection(params, fed, config)
             ours = selection_of(
                 probe, cfg, params, fed, sizes["prefill_chunk"], sizes["cache_buckets"][-1])

@@ -51,7 +51,7 @@ def serve_programs(cell, cfg, engine):
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.serve import batching
+    from ray_tpu.serve import batching, llm
 
     def abstract(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(tuple(shape), dtype)
@@ -68,8 +68,10 @@ def serve_programs(cell, cfg, engine):
             if tc > 1 and lanes > prefill_lanes:
                 continue
             for cap in sorted(engine["cache_buckets"]):
+                # an array with a third element holds a row for every so many tokens
                 caches = [
-                    abstract((layers, lanes, cap) + tuple(each), cfg.dtype)
+                    abstract((layers, lanes, cap // llm.cache_grain(each)) + tuple(each[:2]),
+                             cfg.dtype)
                     for each in cfg.cache_arrays]
                 where = [abstract((lanes,))] * 3 if states else []
                 say(f"{cell} extend {lanes}x{tc}x{cap}", lowered(extend.trace(

@@ -25,7 +25,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu._private import accelerator
-from ray_tpu.models import cohere2_moe, gpt, granitemoehybrid, keye_vl2, kimi_k2, layers
+from ray_tpu.models import (
+    cohere2_moe, gpt, granitemoehybrid, keye_vl2, kimi_k2, layers, minicpm_sala)
 from ray_tpu.models.training import (
     abstract_state,
     default_optimizer,
@@ -996,11 +997,125 @@ def test_state_slots_are_read_and_written_without_a_whole_arena_temporary(shaped
     assert memory.temp_size_in_bytes < GRANITE_STATE_BYTES
 
 
+def _minicpm_sala_stage():
+    """The served cut of MiniCPM-SALA (one pipeline stage of two: four periods of a
+    block-sparse layer and three lightning layers) and its engine sizes, from the
+    configuration's file."""
+    import json
+
+    from benchmark.manifest import published_keys
+    from benchmark.models import minicpm_sala as arch
+
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "configs",
+            "minicpm-sala-serve-pp2.json")) as f:
+        config = json.load(f)
+    return arch.program_config(published_keys(config)), config
+
+
+#: one sequence's state, the stage's 12 lightning layers: 32 x 128 x 128 float32
+MINICPM_SALA_STATE_BYTES = 12 * 32 * 128 * 128 * 4
+#: the smallest layer's weights: a sparse layer's 253,763,840 parameters in bfloat16
+MINICPM_SALA_LAYER_BYTES = 253_763_840 * 2
+
+
+@pytest.mark.parametrize("form", ["decode", "prefill"])
+def test_minicpm_sala_stage_extend_compiles_and_copies_no_arena_and_no_layer(
+        shaped, form, built_for_tpu):
+    """One pipeline stage of MiniCPM-SALA at its published widths (10.08 GB of weights)
+    over the largest cache bucket, with the compressed keys' cache at its own grain (a
+    row for every 16 tokens) and on the pool's state arena itself (64 slots of 25.2 MB:
+    1.61 GB, donated and aliased). A decode call of four lanes scores the compressed
+    keys, gathers its blocks' rows and steps the recurrence where the slots lie, in
+    XLA alone; a prefill chunk attends under a mask a K/V head in the kernel the other
+    architectures call (once a period: one sparse layer). Neither holds a copy of the
+    state arena or of a layer's weights, and both fit beside the pool's blocks and a
+    second call's caches."""
+    built_for_tpu(True)
+    cfg, config = _minicpm_sala_stage()
+    engine, stated = config["engine"], config["compiled_bytes_per_device"]
+    cap, lanes, slots = engine["cache_buckets"][-1], engine["lane_buckets"][-1], engine["state_slots"]
+    b, tc = (lanes, 1) if form == "decode" else (1, engine["prefill_token_buckets"][-1])
+    assert stated[form]["shape"] == [b, tc, cap]
+    params = jax.tree.map(
+        lambda x: shaped(x.shape, x.dtype), jax.eval_shape(lambda: cfg.init_params(0)))
+    assert cfg.cache_arrays == ((1, 256), (1, 256), (1, 256, 16))
+    caches = [
+        shaped((cfg.cache_layers, b, cap // llm.cache_grain(each)) + each[:2], cfg.dtype)
+        for each in cfg.cache_arrays]
+    arenas = tuple(
+        shaped((layers, slots) + shape, dtype) for layers, shape, dtype in cfg.state_arrays)
+    operands = shaped(
+        (b, llm._operand_width(
+            engine["prefill_token_buckets"][-1], cap // engine["block_size"], True)), jnp.int32)
+    compiled = llm._operand_extend(cfg.make_extend_fn(), len(caches), len(arenas)).lower(
+        params, operands, shaped((lanes + len(cfg.counters),), jnp.int32), *caches, *arenas, tc=tc
+    ).compile()
+    kernels = [
+        line for line in compiled.as_text().splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line]
+    if form == "prefill":
+        assert len(kernels) == 1 and "/extend.attention/masked_attention/" in kernels[0]
+    else:
+        assert not kernels
+    memory = compiled.memory_analysis()
+    assert cfg.num_params() == 5_039_400_832 and MINICPM_SALA_STATE_BYTES == 25_165_824
+    arena_bytes = slots * MINICPM_SALA_STATE_BYTES
+    assert 0 <= memory.alias_size_in_bytes - arena_bytes < slots * 2**17
+    per_token = cfg.cache_layers * (2 * 256 * 2 + 256 * 2 // 16)
+    assert per_token == 4224
+    weights = memory.argument_size_in_bytes - memory.alias_size_in_bytes - b * (
+        cap * per_token + 2**17)
+    assert 10.07e9 < weights < 10.09e9
+    assert memory.argument_size_in_bytes == stated[form]["argument"]
+    assert memory.temp_size_in_bytes <= stated[form]["temp"] * 1.05
+    # no copy of the state arena, of a lane's share of it times the lanes, or of a layer
+    assert memory.temp_size_in_bytes < MINICPM_SALA_LAYER_BYTES / 2
+    assert memory.temp_size_in_bytes < arena_bytes / 8
+    resident = engine["num_blocks"] * engine["block_size"] * per_token
+    assert _device_bytes(compiled) + resident + lanes * cap * per_token < HBM_BYTES
+
+
+def test_a_coarse_arena_pages_without_a_whole_arena_temporary(shaped):
+    """The three arenas of the MiniCPM-SALA stage's pool (K and V a token, compressed
+    keys a row for every 16: 0.69 GB) and the three programs that touch them, compiled
+    beside nothing: the page-back aliases the arenas and its temporaries are far
+    smaller than the smallest arena, the coarse one's conditional write included."""
+    cfg, config = _minicpm_sala_stage()
+    engine = config["engine"]
+    blocks, block = engine["num_blocks"], engine["block_size"]
+    arenas = tuple(
+        shaped((cfg.cache_layers, blocks, block // llm.cache_grain(each)) + each[:2], cfg.dtype)
+        for each in cfg.cache_arrays)
+    arena_bytes = [math.prod(a.shape) * 2 for a in arenas]
+    assert arena_bytes == [335_544_320, 335_544_320, 20_971_520]
+    programs = llm._paging_programs()
+    b, tc, cap = 1, engine["prefill_token_buckets"][-1], engine["cache_buckets"][-1]
+    width = llm._operand_width(tc, cap // block, True)
+    operands = shaped((b, width), jnp.int32)
+    news = tuple(
+        shaped((cfg.cache_layers, b, -(-tc // llm.cache_grain(each))) + each[:2], cfg.dtype)
+        for each in cfg.cache_arrays)
+    outputs = (shaped((b, tc, cfg.vocab_size), jnp.float32), shaped((b, tc, cfg.embed_dim), jnp.float32))
+    counted = (shaped((len(cfg.counters),), jnp.int32),)
+    back = programs.page_back.lower(
+        arenas, news, operands, outputs, counted, 4).compile().memory_analysis()
+    assert back.alias_size_in_bytes >= sum(arena_bytes)
+    assert back.temp_size_in_bytes < min(arena_bytes) / 2
+    gather = programs.gather.lower(arenas, operands, cap // block).compile().memory_analysis()
+    assert gather.temp_size_in_bytes < min(arena_bytes)
+    assert 0 <= gather.output_size_in_bytes - cap * 4224 < 2**12
+    clone = programs.clone.lower(
+        arenas, shaped((), jnp.int32), shaped((), jnp.int32)).compile().memory_analysis()
+    assert clone.temp_size_in_bytes < 2**21
+
+
 @pytest.mark.parametrize(
     "name,extends,pagings",
     [("gptj-6b-serve", 12, 11), ("command-a-plus-serve-ep8", 20, 25),
      ("keye-vl2-30b-a3b-serve", 16, 19), ("kimi-k2-instruct-serve-ep32", 16, 19),
-     ("granite-4.0-h-micro-serve", 20, 25), ("granite-4.0-h-small-serve-ep2", 20, 25)],
+     ("granite-4.0-h-micro-serve", 20, 25), ("granite-4.0-h-small-serve-ep2", 20, 25),
+     ("minicpm-sala-serve-pp2", 16, 19)],
 )
 def test_a_serve_configuration_compiles_no_more_programs_than_it_did(name, extends, pagings):
     """The programs an engine with the configuration's buckets compiles (a tiny
@@ -1024,6 +1139,8 @@ def test_a_serve_configuration_compiles_no_more_programs_than_it_did(name, exten
         else granitemoehybrid.granite_hybrid_nano(
             max_seq_len=context, ssm_chunk=256, router_experts=8 * name.count("small"))
         if name.startswith("granite")
+        else minicpm_sala.minicpm_sala_nano(max_seq_len=context, linear_chunk=256)
+        if name.startswith("minicpm")
         else dataclasses.replace(gpt.gpt_nano(), max_seq_len=context)
     )
     llm._paging_programs.cache_clear()      # this engine's programs alone
