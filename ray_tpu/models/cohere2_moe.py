@@ -18,6 +18,14 @@ with four differences the engine's ``extend`` contract does not see:
   ``expert_offset``) computed without dropping a token, plus the mean of
   ``shared_experts`` gated MLPs that every token passes through.
 
+Attention has two forms, chosen by the call's shape and the build and giving
+the same result. A prefill chunk (more than one query a lane) on the chip
+attends in one kernel, ``ops/attention.masked_attention``, under the layer's own
+mask (causal, and on a sliding layer the window): its 16 query heads meet each
+tile of their K/V head together, the scores stay in VMEM, and it stops at the
+lane's last live key. A decode lane (one query), and every call off the chip,
+attends densely (``layers.plain_attend``; a chunk 32 queries at a time).
+
 The embedding is tied to the output head. As in ``models/gpt.py`` the rotation
 pairs feature ``i`` with ``i + head_dim / 2`` where the published model pairs
 ``2i`` with ``2i + 1``: the same function under a fixed permutation of each
@@ -35,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models import layers, moe
+from ray_tpu.ops import attention, backend
 
 
 @dataclasses.dataclass(frozen=True)
@@ -155,6 +164,13 @@ def make_extend_fn(cfg: Cohere2MoeConfig):
     expert's pairs. A negative token id marks padding: it computes no expert
     and is not counted.
 
+    A call of one query a lane attends densely over its padded caches
+    (``layers.plain_attend``), on the chip and off it; a chunk does so off the
+    chip, 32 queries at a time, and on it attends in
+    ``ops/attention.masked_attention`` up to ``layers.live_keys``: the same
+    mask, the same precisions (bfloat16 operands, float32 scores, maximum, sum
+    and accumulator, weights cast to the values' type).
+
     Scopes: ``extend.embed``, ``extend.attention``, ``extend.moe.route``,
     ``extend.moe.experts``, ``extend.moe.shared``, ``extend.logits``.
     """
@@ -163,7 +179,7 @@ def make_extend_fn(cfg: Cohere2MoeConfig):
     groups = cfg.num_heads // cfg.kv_heads
 
     @jax.named_scope("extend.attention")
-    def _attend(p, hidden, positions, kc, vc, sliding):
+    def _attend(p, hidden, positions, live, kc, vc, sliding):
         b, tc = positions.shape
         q = jnp.einsum("btd,dhk->bthk", hidden, p["q"]["kernel"].astype(dtype))
         k = jnp.einsum("btd,dhk->bthk", hidden, p["k"]["kernel"].astype(dtype))
@@ -178,24 +194,23 @@ def make_extend_fn(cfg: Cohere2MoeConfig):
         lane = jnp.arange(b)[:, None]
         kc = layers.write_rows(kc, lane, positions, k)
         vc = layers.write_rows(vc, lane, positions, v)
-        kpos = jnp.arange(kc.shape[1], dtype=jnp.int32)
+        # the layer's mask, once for the whole chunk: causal, and on a sliding
+        # layer the window's newest positions alone
         window = jnp.where(sliding, cfg.sliding_window, kc.shape[1] + tc)
+        behind = positions[:, :, None] - jnp.arange(kc.shape[1], dtype=jnp.int32)
+        mask = (behind >= 0) & (behind < window)                    # [b, tc, cache]
+        q = q.reshape(b, tc, cfg.kv_heads, groups, cfg.head_dim)
 
-        def attend_block(block):
-            qb, pos = block                    # [b, n, kv, g, hd], [b, n]
-            scores = jnp.einsum(
-                "bqhgd,bkhd->bhgqk", qb, kc, preferred_element_type=jnp.float32
-            ) * scale
-            behind = pos[:, :, None] - kpos[None, None, :]          # [b, n, cache]
-            mask = ((behind >= 0) & (behind < window))[:, None, None]
-            w = jax.nn.softmax(jnp.where(mask, scores, jnp.float32(layers.MASKED)), axis=-1)
-            return jnp.einsum("bhgqk,bkhd->bqhgd", w.astype(dtype), vc)
+        def attend_block(qb, visible):
+            return layers.plain_attend(qb, kc, vc, visible, scale)
 
-        n = layers.query_block(tc)
-        q = q.reshape(b, tc // n, n, cfg.kv_heads, groups, cfg.head_dim)
-        out = jax.lax.map(
-            attend_block, (q.swapaxes(0, 1), positions.reshape(b, tc // n, n).swapaxes(0, 1)))
-        out = out.swapaxes(0, 1).reshape(b, tc, cfg.num_heads, cfg.head_dim)
+        if tc > 1 and backend.on_tpu():
+            out = attention.masked_attention(q, kc, vc, mask, live, scale=scale)
+        else:
+            out = (
+                attend_block(q, mask) if tc == 1
+                else layers.by_query_block(attend_block, q, mask))
+        out = out.reshape(b, tc, cfg.num_heads, cfg.head_dim)
         out = jnp.einsum("bqhd,hde->bqe", out, p["o"]["kernel"].astype(dtype))
         return out, k, v
 
@@ -221,6 +236,7 @@ def make_extend_fn(cfg: Cohere2MoeConfig):
     @jax.jit
     def extend(params, tokens, lengths, k_cache, v_cache):
         positions, valid = layers.frame(tokens, lengths)
+        live = layers.live_keys(positions, valid)
         with jax.named_scope("extend.embed"):
             emb = params["wte"]["embedding"].astype(dtype)
             x = layers.look_up(emb, tokens)
@@ -229,7 +245,8 @@ def make_extend_fn(cfg: Cohere2MoeConfig):
         def body(carry, xs):
             p, router, kc, vc, sliding, layer = xs
             normed = layers.layer_norm(carry, p["ln"]["scale"], cfg.norm_eps)
-            a, k, v = _attend(p["attn"], normed.astype(dtype), positions, kc, vc, sliding)
+            a, k, v = _attend(
+                p["attn"], normed.astype(dtype), positions, live, kc, vc, sliding)
             f, counters = _ffn(router, experts, layer, p["shared"], normed, valid)
             return carry + a + f, (k, v, counters)
 

@@ -489,17 +489,42 @@ def test_gptj_full_depth_extend_compiles(shaped, lanes, tc):
     assert _device_bytes(_extend_at(_gptj(28), shaped, lanes, tc, 1024)) < HBM_BYTES
 
 
+def _experts_kernels_and_a_chunks_attend(text, cfg, lanes, tc, cap):
+    """The kernels of a compiled ``extend`` with an expert layer: the grouped
+    matmuls, and for a chunk (``tc`` > 1) one more, its attend, straight under
+    the scope the readers count, with no array of the dense form's shapes left
+    (32 queries' float32 scores over the cache, and their weights); a decode
+    call holds the experts' alone."""
+    kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    experts = [line for line in kernels if "extend.moe.experts" in line]
+    assert len(experts) >= 2                                     # the kernel is there
+    if tc == 1:
+        assert kernels == experts
+        return
+    (attend,) = [line for line in kernels if line not in experts]
+    assert "/extend.attention/masked_attention/" in attend
+    groups = cfg.num_heads // cfg.kv_heads
+    for scores in ("f32", "bf16"):
+        assert f"{scores}[{lanes},{cfg.kv_heads},{groups},{layers.QUERY_BLOCK},{cap}]" not in text
+
+
 @pytest.mark.parametrize(
-    "lanes,tc,cap", [(8, 1, 8192), (2, 256, 8192)], ids=["decode", "prefill"])
+    "lanes,tc,cap,parent_temp",
+    [(8, 1, 8192, 404638208), (1, 256, 8192, 270579712), (2, 256, 8192, 631700992)],
+    ids=["decode", "prefill", "prefill-two-lanes"])
 def test_command_a_plus_share_extend_compiles_and_copies_no_expert(
-    shaped, lanes, tc, cap, built_for_tpu
+    shaped, lanes, tc, cap, parent_temp, built_for_tpu
 ):
     """The served share of Command A+ at its published widths (one period, 16
     of 128 experts, an eighth of the vocabulary: 9.47 GB of weights) over the
     largest cache bucket: it fits beside a 0.8 GB pool, and its temporaries stay
     under a layer's routed experts (1.6 GB), which a scan that sliced them out
-    of the stack copied on every call (``moe.held_experts_ffn``, ``layer``)."""
-    built_for_tpu(True)     # the chip's grouped matmul
+    of the stack copied on every call (``moe.held_experts_ffn``, ``layer``).
+    A chunk (the cell's one prefill lane, and two) attends in
+    ``masked_attention``; a decode call holds the experts' kernels alone.
+    ``parent_temp`` is what the compiler counted at PR 52 for the same shape,
+    where a chunk attended densely (compile, PR 53)."""
+    built_for_tpu(True)     # the chip's grouped matmul and attend
     cfg = cohere2_moe.Cohere2MoeConfig(vocab_size=32768, num_layers=4, num_experts=16)
     params = jax.tree.map(
         lambda x: shaped(x.shape, x.dtype), jax.eval_shape(lambda: cfg.init_params(0)))
@@ -507,10 +532,11 @@ def test_command_a_plus_share_extend_compiles_and_copies_no_expert(
     operands = shaped((lanes, llm._operand_width(256, 8192 // 256)), jnp.int32)
     compiled = llm._operand_extend(cfg.make_extend_fn()).lower(
         params, operands, shaped((8,), jnp.int32), cache, cache, tc=tc).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 2      # the kernel is there
+    _experts_kernels_and_a_chunks_attend(compiled.as_text(), cfg, lanes, tc, cap)
     memory = compiled.memory_analysis()
     assert 9.4e9 < memory.argument_size_in_bytes < 10.6e9
     assert memory.temp_size_in_bytes < 1.0e9
+    assert memory.temp_size_in_bytes <= parent_temp
     assert _device_bytes(compiled) + 2 * 0.41e9 < HBM_BYTES
 
 
@@ -609,21 +635,7 @@ def test_keye_vl2_stage_extend_compiles_at_its_largest_shapes(shaped, form, buil
     compiled = llm._operand_extend(cfg.make_extend_fn()).lower(
         params, operands, shaped((lanes + len(cfg.counters),), jnp.int32), *caches, tc=tc
     ).compile()
-    text = compiled.as_text()
-    kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
-    experts = [line for line in kernels if "extend.moe.experts" in line]
-    assert len(experts) >= 2                                     # the kernel is there
-    if form == "prefill":
-        # the chunk's attend is one kernel more, straight under the scope the
-        # readers count, and no array of the dense form's shapes is left:
-        # 32 queries' float32 scores over the cache, and their weights
-        (attend,) = [line for line in kernels if line not in experts]
-        assert "/extend.attention/masked_attention/" in attend
-        groups = cfg.num_heads // cfg.kv_heads
-        for scores in ("f32", "bf16"):
-            assert f"{scores}[1,{cfg.kv_heads},{groups},{layers.QUERY_BLOCK},{cap}]" not in text
-    else:
-        assert kernels == experts
+    _experts_kernels_and_a_chunks_attend(compiled.as_text(), cfg, b, tc, cap)
     memory = compiled.memory_analysis()
     per_token = 2 * cfg.num_layers * sum(h * d for h, d in cfg.cache_arrays)
     assert per_token == 13056

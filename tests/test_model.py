@@ -183,6 +183,119 @@ def test_one_probe_decides_every_kernel(built_for_tpu, program, kernel):
     assert kernel not in text and "pallas_call" not in text
 
 
+@pytest.fixture
+def on_the_chip(monkeypatch, built_for_tpu):
+    """Command A+'s ``extend`` as the chip traces it, on the CPU:
+    ``backend.on_tpu`` answers yes, a chunk's attention kernel runs interpreted
+    at small tiles (two key blocks over a 64-slot cache), the grouped matmul is
+    XLA's. Yields the shapes the kernel was called with."""
+    from ray_tpu.models import moe
+    from ray_tpu.ops import attention
+
+    real = attention.masked_attention
+    seen = []
+
+    def interpreted(q, k, v, mask, kv_len, **kw):
+        seen.append((q.shape, k.shape, v.shape, mask.shape, kv_len.shape))
+        return real(q, k, v, mask, kv_len, interpret=True, block_q=16, block_k=32, **kw)
+
+    monkeypatch.setattr(moe, "grouped_matmul", lambda rows, w, sizes: jax.lax.ragged_dot(rows, w, sizes))
+    monkeypatch.setattr(attention, "masked_attention", interpreted)
+    built_for_tpu(True)
+    return seen
+
+
+_RUBBISH = 1e4      # what a cache slot nobody wrote may hold: finite, and far from a key
+
+
+def _command_a_plus_calls(cfg):
+    """Two prefill calls of two lanes over a 64-slot cache, and what is real in
+    each: a chunk from an empty cache (32 tokens: lane 0 has 28, lane 1 has 8 and
+    24 of padding), then a chunk of 16 behind them (lane 0's queries stand at 28 ..
+    43, past ``sliding_window`` = 24; lane 1 has 10 tokens and 6 of padding).
+    Returns ``run(extend)`` -> per call ``(logits, hidden, k_new, v_new)`` and the
+    calls' ``valid``."""
+    params = cfg.init_params(0)
+    # sharper scores than weights of 0.02 give: what a query may not read would
+    # otherwise move nothing
+    attn = params["blocks"]["layers"]["attn"]
+    attn["q"]["kernel"], attn["k"]["kernel"] = attn["q"]["kernel"] * 8, attn["k"]["kernel"] * 8
+    tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (2, 48), 0, cfg.vocab_size))
+    first = np.where(np.arange(32) < np.array([[28], [8]]), tokens[:, :32], -1)
+    second = np.where(np.arange(16) < np.array([[16], [10]]), tokens[:, 32:], -1)
+    held = np.array([28, 8])
+    shape = (cfg.num_layers, 2, 64, cfg.kv_heads, cfg.head_dim)
+
+    def run(extend):
+        empty = jnp.full(shape, _RUBBISH, cfg.dtype)
+        one = extend(params, jnp.asarray(first), jnp.zeros((2,), jnp.int32), empty, empty)
+        caches = []
+        for rows in one[2:4]:       # the real rows alone, as the engine pages them back
+            cache = np.full(shape, _RUBBISH, np.float32)
+            for lane, n in enumerate(held):
+                cache[:, lane, :n] = np.asarray(rows)[:, lane, :n]
+            caches.append(jnp.asarray(cache))
+        two = extend(params, jnp.asarray(second), jnp.asarray(held, jnp.int32), *caches)
+        return one[:4], two[:4]
+
+    return run, (first >= 0, second >= 0)
+
+
+def test_command_a_plus_chunk_attends_in_the_kernel_as_off_the_chip(on_the_chip, built_for_tpu):
+    """What a prefill chunk runs on the chip, ``ops/attention.masked_attention``
+    under the layer's causal-and-window mask up to the lane's live bound
+    (interpreted here), gives the dense form's logits, hidden state and new K/V
+    rows for every real token, through sliding and full layers alike (layer
+    ``i + 1``'s rows are made from layer ``i``'s attend), over a cache whose
+    unwritten slots hold rubbish. And the window is seen: a window that
+    hides nothing gives other logits."""
+    cfg = cohere2_moe_nano()
+    assert cfg.sliding_layers == (True, True, False) * 2
+    run, valid = _command_a_plus_calls(cfg)
+    got = run(cfg.make_extend_fn())
+    groups = cfg.num_heads // cfg.kv_heads
+    # one trace a program: the scan's body holds the kernel once for all six layers
+    assert on_the_chip == [
+        ((2, tc, cfg.kv_heads, groups, cfg.head_dim), (2, 64, cfg.kv_heads, cfg.head_dim),
+         (2, 64, cfg.kv_heads, cfg.head_dim), (2, tc, 64), (2,)) for tc in (32, 16)]
+    built_for_tpu(False)
+    want = run(cfg.make_extend_fn())
+    assert len(on_the_chip) == 2
+    for real, call, dense in zip(valid, got, want):
+        for a, b in zip(call[:2], dense[:2]):           # logits, hidden: [b, tc, ...]
+            np.testing.assert_allclose(np.asarray(a)[real], np.asarray(b)[real], atol=3e-5, rtol=3e-5)
+        for a, b in zip(call[2:], dense[2:]):           # K and V: [layers, b, tc, ...]
+            np.testing.assert_allclose(
+                np.asarray(a)[:, real], np.asarray(b)[:, real], atol=3e-5, rtol=3e-5)
+    wide = cohere2_moe_nano(sliding_window=256)
+    unwindowed = _command_a_plus_calls(wide)[0](wide.make_extend_fn())
+    assert np.abs(np.asarray(unwindowed[1][0])[0, -1] - np.asarray(want[1][0])[0, -1]).max() > 1e-2
+
+
+def test_command_a_plus_decode_call_never_reaches_the_kernel(
+        on_the_chip, built_for_tpu, monkeypatch):
+    """One query a lane (``tc == 1``) attends densely on the chip too
+    (``layers.plain_attend``): the program is the one built off the chip."""
+    from ray_tpu.ops import attention
+
+    def never(*a, **kw):
+        raise AssertionError("a decode call attends in layers.plain_attend")
+
+    monkeypatch.setattr(attention, "masked_attention", never)
+    cfg = cohere2_moe_nano()
+    params = cfg.init_params(0)
+    cache = jax.random.normal(
+        jax.random.PRNGKey(2), (cfg.num_layers, 2, 64, cfg.kv_heads, cfg.head_dim), cfg.dtype)
+    call = (params, jnp.asarray([[5], [7]]), jnp.asarray([40, 3], jnp.int32), cache, cache)
+    got = cfg.make_extend_fn()(*call)
+    built_for_tpu(False)
+    for a, b in zip(got, cfg.make_extend_fn()(*call)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    built_for_tpu(True)
+    with pytest.raises(AssertionError, match="plain_attend"):      # and two queries a lane do
+        cfg.make_extend_fn()(params, jnp.asarray([[5, 6], [7, 8]]), *call[2:])
+
+
 def test_the_train_step_multiplies_the_head_three_times():
     """Logits, the hidden state's gradient and the kernel's: the loss makes its
     gradients from the logits it has, where a rematerialized chunk multiplied a
