@@ -6,7 +6,9 @@ it, so the driver and the raylet learn what the host holds from its device
 nodes (no ``import jax``) and leave the chip free for the worker that leases
 it. That worker — or a script that runs the model in-process — keeps its
 compiled programs in the one directory named here, compiles a program over a
-mesh with the options :func:`compiler_options` reads from that mesh, and
+mesh with the options :func:`compiler_options` reads from that mesh, names
+each program it compiles of one function (:class:`Programs`: a profile's
+module line and the host's record of the call then share one string), and
 writes its host spans onto the profiler's clock through :func:`span`, and
 asks :func:`recording` whether anything keeps them.
 """
@@ -14,9 +16,12 @@ asks :func:`recording` whether anything keeps them.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import glob
 import os
-from typing import Any, Dict, Optional
+import re
+import types
+from typing import Any, Dict, List, Optional
 
 _DEV_ROOT = "/dev"
 _PCI_ROOT = "/sys/bus/pci/devices"
@@ -155,6 +160,71 @@ def recording() -> bool:
     import jax
 
     return jax.profiler.TraceAnnotation.is_enabled()
+
+
+#: a program's name: a component of every ``op_name`` of its instructions
+#: (``jit(<name>)/extend.attention/...``), and a reader of a trace takes any
+#: dotted component for a named scope, so a name has no dot
+_PROGRAM_NAME = re.compile(r"[A-Za-z_][A-Za-z0-9_]*")
+
+
+class Programs:
+    """One function as a family of jitted programs, one ``jax.jit`` a name.
+
+    JAX names a compiled module ``jit_<__name__>`` after the function it was
+    traced from, and an instruction's name is unique in its module only: one
+    ``jax.jit`` called in sixteen shapes is sixteen modules of one name, whose
+    ``fusion.3`` a profile cannot tell apart. A member of the family is the
+    ``jax.jit`` (with the family's options) of a copy of the function whose
+    ``__name__`` is the member's name, made when the name is first asked for,
+    so the device's ``XLA Modules`` event (``jit_<name>(<id>)``), the host's
+    ``PjitFunction(<name>)`` and whatever the caller records of the call under
+    the same name are one string. The caller chooses a name for every set of
+    sizes that shapes the program (letters, digits and ``_``), and calls the
+    family as it would the one ``jax.jit``, with the name first."""
+
+    def __init__(self, fun, **jit_options):
+        self._fun, self._options = fun, jit_options
+        self._members: Dict[str, Any] = {}
+
+    def member(self, name: str):
+        """The family's ``jax.jit`` called ``name``."""
+        try:
+            return self._members[name]
+        except KeyError:
+            program = self._members[name] = self._make(name)
+            return program
+
+    def _make(self, name: str):
+        import jax
+
+        if not _PROGRAM_NAME.fullmatch(name):
+            raise ValueError(
+                f"a program's name is letters, digits and '_' (no dot: a reader of "
+                f"a trace takes a dotted component of an op_name for a scope): {name!r}")
+        fun = self._fun
+        named = types.FunctionType(
+            fun.__code__, fun.__globals__, name, fun.__defaults__, fun.__closure__)
+        named.__kwdefaults__, named.__qualname__ = fun.__kwdefaults__, name
+        # all of it but the name; ``__wrapped__`` keeps the signature a decorator hid,
+        # where ``jax.jit`` looks its static names up
+        functools.update_wrapper(named, fun, assigned=("__module__", "__doc__"))
+        return jax.jit(named, **self._options)
+
+    def __call__(self, name: str, *args, **kwargs):
+        return self.member(name)(*args, **kwargs)
+
+    def lower(self, name: str, *args, **kwargs):
+        return self.member(name).lower(*args, **kwargs)
+
+    def names(self) -> List[str]:
+        """The members made so far, in the order they were first asked for."""
+        return list(self._members)
+
+    def _cache_size(self) -> int:
+        """The programs the family has compiled (traced, where nothing ran):
+        the sum of its members' own counts."""
+        return sum(p._cache_size() for p in self._members.values())
 
 
 def device_report() -> Dict[str, Any]:

@@ -36,6 +36,16 @@ serving setup from PAPERS.md):
   layer's counters) are one int32 array, which is all that comes home. The
   picked rows follow only for a lane that asked for its logits or has an
   adapter. Every such program is compiled when the engine is built.
+* every program under a name of its own — JAX names a compiled module after
+  the function it was traced from, and an instruction's name is unique in its
+  module only, so one ``jax.jit`` run in sixteen shapes is sixteen modules a
+  profile cannot tell apart. The engine's are families
+  (``accelerator.Programs``): a ``jax.jit`` a name, and the name made of the
+  sizes that shape the program where it is called: ``extend_decode_8x1x8192`` /
+  ``extend_prefill_1x256x8192`` (lanes x tokens x cache; :func:`_extend_name`),
+  ``gather_8x8192`` (lanes x cache), ``page_back_8x1`` (lanes x tokens;
+  :class:`KVBlockPool`), ``clone``, ``state_copy``. No name has a dot: it is a
+  component of every ``op_name`` of its program, where a dot marks a scope.
 * the chip never waits for the host between calls: a call is *launched*
   (upload, gather, ``extend``, page-back: nothing waits for the device) and
   *landed* a call later (its ids fetched, its tokens emitted), once its
@@ -83,11 +93,16 @@ serving setup from PAPERS.md):
   ``stats()`` / ``kv_stats`` return beside the bytes, lanes and cache
   slots each device call moved.
 * a record per device call — ``llm.dispatch`` says what the call is (its
-  number, ``prefill`` or ``decode``, its lanes, tokens and cache tokens
+  number, ``prefill`` or ``decode``, the ``program`` it runs as: the name of
+  the module the device then shows, its lanes, tokens and cache tokens
   beside the slots of its padded shape, whether a call was in flight) and
   ``llm.fetch`` which call it lands and what ``extend`` counted in it; always
   on, ``stats()["calls"]`` sums the same per form of call, with the time the
-  device spent on each (``busy_s``).
+  device spent on each (``busy_s``), and ``stats()["programs"]`` the calls and
+  that time per program. A program first called inside traffic (a shape
+  ``warm()`` left out) says ``cold=1`` on its span and is counted, with the
+  seconds its first call took, in ``programs_cold`` / ``programs_cold_s``:
+  which step compiled, and for how long.
 * the counters a second time, over recorded steps — while a profiler session
   runs, ``stats()["traced"]`` takes every counter of every step that begins
   and ends in it, a call's own counts with the step that launched it: a trace
@@ -187,7 +202,14 @@ def _paging_programs():
     ``[layers, num_blocks, block_size, heads, dim]`` that differ in their last
     two sizes alone; jax is imported here because processes that must stay off
     it load this module too. They are shaped by their arguments alone, so every
-    pool of a process shares them."""
+    pool of a process shares them. ``gather`` and ``page_back`` are families
+    (``accelerator.Programs``): called with the program's name first, which the
+    pool makes of the sizes that shape it (``gather_<lanes>x<cache>``,
+    ``page_back_<lanes>x<tokens>``; :meth:`KVBlockPool.gather`), so that each
+    compiled module has a name of its own in a profile. Two pools of one
+    process whose arenas differ share a name where those sizes agree: one
+    ``jax.jit`` holds both programs then, which the name cannot tell apart.
+    ``clone`` has one shape and one name."""
     import types
 
     import jax
@@ -199,7 +221,7 @@ def _paging_programs():
     # call: 1.17 GB at GPT-J's serve sizes, where 1 GB is free beside the
     # weights (``tests/test_chip_compile.py`` holds the programs to this).
 
-    @functools.partial(jax.jit, static_argnums=2)
+    @functools.partial(accelerator.Programs, static_argnums=2)
     @jax.named_scope("paging.gather")
     def gather(arenas, operands, n):
         layers, blocks, block = arenas[0].shape[:3]
@@ -239,7 +261,7 @@ def _paging_programs():
         return tuple(
             c.reshape((layers, b, n * a.shape[2]) + a.shape[3:]) for c, a in zip(caches, arenas))
 
-    @functools.partial(jax.jit, donate_argnums=0, static_argnums=5)
+    @functools.partial(accelerator.Programs, donate_argnums=0, static_argnums=5)
     @jax.named_scope("paging.page_back")
     def page_back(arenas, news, operands, outputs, counted, width):
         layers, blocks, block = arenas[0].shape[:3]
@@ -318,13 +340,13 @@ def _state_programs():
 
     @functools.partial(jax.jit, donate_argnums=0)
     @jax.named_scope("paging.state_copy")
-    def copy(arenas, src, dst):
+    def state_copy(arenas, src, dst):      # the module's name in a profile
         return tuple(
             jax.lax.dynamic_update_slice_in_dim(
                 a, jax.lax.dynamic_slice_in_dim(a, src, 1, axis=1), dst, axis=1)
             for a in arenas)
 
-    return types.SimpleNamespace(copy=copy)
+    return types.SimpleNamespace(copy=state_copy)
 
 
 class KVBlockPool:
@@ -440,7 +462,8 @@ class KVBlockPool:
         the lanes whose block ids are the first ``n`` of each row of the
         ``table`` section of ``operands`` (a device array ``[b, width]``,
         :func:`_sections`; every entry a valid block), built on the device."""
-        return _paging_programs().gather(self.arenas, operands, n)
+        return _paging_programs().gather(
+            f"gather_{operands.shape[0]}x{n * self.block_size}", self.arenas, operands, n)
 
     def page_back(self, news, operands, outputs, counted, width: int):
         """Write token ``rows[i]`` (an index into lanes x tokens) of each of
@@ -455,7 +478,9 @@ class KVBlockPool:
         device (the ``b`` greedy ids padded to ``width``, then the int32
         arrays of ``counted``), and the picked rows: all on the device. The
         arenas are donated: nothing is copied but the new rows."""
+        lanes, tokens = outputs[0].shape[:2]
         self.arenas, home, picked = _paging_programs().page_back(
+            f"page_back_{lanes}x{tokens}",
             self.arenas, tuple(news), operands, outputs, tuple(counted), width)
         return home, picked
 
@@ -862,7 +887,12 @@ def _operand_extend(extend, caches: int = 0, states: int = 0):
     lane, and a lane's first token from ``home`` (what the call before left
     for the host, its lanes' ids first) where its row names a lane there
     (``_FROM``): the host need not have seen a token to feed it. One program
-    per (lanes, tokens, cache), as ``extend`` alone has. Where the model keeps
+    per (lanes, tokens, cache), as ``extend`` alone has, and each under a name
+    of its own: what is returned is a family (``accelerator.Programs``), called
+    with the program's name first, which the engine makes of those three sizes
+    (:func:`_extend_name`), so a profile's module line, the ``PjitFunction``
+    event on the host and the engine's record of the call (``llm.dispatch``'s
+    ``program``, ``stats()["programs"]``) are one string. Where the model keeps
     state per sequence, the pool's ``states`` arenas follow the ``caches``
     (donated: the ones ``extend`` returns take their place) and ``extend`` is told
     each lane's slot in them and where to keep a state for the prefix cache
@@ -872,7 +902,7 @@ def _operand_extend(extend, caches: int = 0, states: int = 0):
 
     donated = dict(donate_argnums=tuple(range(3 + caches, 3 + caches + states))) if states else {}
 
-    @functools.partial(jax.jit, static_argnames="tc", **donated)
+    @functools.partial(accelerator.Programs, static_argnames="tc", **donated)
     def extend_call(params, operands, home, *arrays, tc):
         tokens, source = _sections(operands)[0][:, :tc], operands[:, _FROM]
         first = jnp.where(source < 0, tokens[:, 0], home[jnp.maximum(source, 0)])
@@ -882,6 +912,14 @@ def _operand_extend(extend, caches: int = 0, states: int = 0):
             params, tokens.at[:, 0].set(first), operands[:, _LENGTH], *arrays, *where)
 
     return extend_call
+
+
+def _extend_name(b: int, tc: int, cap: int) -> str:
+    """The name of the ``extend`` program for ``b`` lanes of ``tc`` tokens over a
+    cache of ``cap``: ``extend_decode_8x1x8192``, ``extend_prefill_1x256x8192``.
+    From the sizes that shape the program alone (one token a lane is the decode
+    program, whoever calls it), so no two programs share a name and none has two."""
+    return f"extend_{'decode' if tc == 1 else 'prefill'}_{b}x{tc}x{cap}"
 
 
 class _SeqState:
@@ -911,20 +949,23 @@ class _Call:
     rows; both gone once it has landed), its ``lanes`` as ``(sequence,
     state)`` and which of them sample a token (``emits``). And its record:
     its number ``seq`` among the engine's calls, its ``form`` (``prefill`` or
-    ``decode``), its padded ``shape`` ``(lanes, tokens, cache)``, the ``step``
+    ``decode``), its padded ``shape`` ``(lanes, tokens, cache)`` and the name of
+    the ``program`` that shape runs as (:func:`_extend_name`), the ``step``
     that launched it and whether that step was ``recorded`` by a profiler
     session (known at that step's end), and the instant its launch returned
     (``launched_at``, on the engine's clock: ``LLMEngine._now``)."""
 
     __slots__ = (
         "home", "picked", "lanes", "emits", "sampled",
-        "seq", "form", "shape", "step", "recorded", "launched_at",
+        "seq", "form", "shape", "program", "step", "recorded", "launched_at",
     )
 
-    def __init__(self, home, picked, lanes, emits, *, seq, form, shape, step, launched_at):
+    def __init__(
+            self, home, picked, lanes, emits, *, seq, form, shape, program, step, launched_at):
         self.home, self.picked, self.lanes, self.emits = home, picked, lanes, emits
         self.sampled: List[tuple] = []
         self.seq, self.form, self.shape, self.step = seq, form, shape, step
+        self.program = program
         self.recorded, self.launched_at = False, launched_at
 
 
@@ -1086,6 +1127,15 @@ class LLMEngine:
                 n=0, lanes_used=0, lane_slots=0, tokens=0, token_slots=0,
                 cache_tokens=0, cache_slots=0, busy_s=0.0)
             for form in FORMS}
+        #: the same ``n`` and ``busy_s`` per program, by its name (the module a
+        #: profile shows, ``llm.dispatch``'s ``program``): a group for every
+        #: program ``warm`` ran, at zero from then on, and for every other from
+        #: its first call, which is counted in ``programs_cold``, with the wall
+        #: seconds that call's dispatch took (its trace and its compile or cache
+        #: load, inside traffic) in ``programs_cold_s``
+        self.programs: Dict[str, Dict[str, Any]] = {}
+        self.programs_cold = 0
+        self.programs_cold_s = 0.0
         self._landed_at = 0.0           # the instant of the last landing, by ``_now``
         #: the seconds the host spent outside ``step`` after the device had
         #: finished the call in flight (a batcher waiting, a profiler starting
@@ -1128,10 +1178,10 @@ class LLMEngine:
         self._home_width = self.lane_buckets[-1]
 
         def extend_outputs(b, tc):
+            cap = self.cache_buckets[0]
             return jax.eval_shape(
-                functools.partial(self._extend_call, tc=tc),
-                *self._extend_args(
-                    jax.ShapeDtypeStruct, b, self.cache_buckets[0]))
+                functools.partial(self._extend_call, _extend_name(b, tc, cap), tc=tc),
+                *self._extend_args(jax.ShapeDtypeStruct, b, cap))
 
         outputs = {
             (b, tc): extend_outputs(b, tc)
@@ -1180,7 +1230,8 @@ class LLMEngine:
         """Run ``extend`` once in every shape of :meth:`extend_shapes`, as a
         step calls it, on zeros made on the device (and the pool's own state
         arenas, of which zeros name slot 0 alone), so that no request meets a
-        compile. Returns how many ``shapes``, the seconds it took
+        compile; each program has its group in ``stats()["programs"]`` from
+        here on. Returns how many ``shapes``, the seconds it took
         (``warm_s``) and, where the compiler says, the bytes of the largest one
         (``compiled``), whose temporaries ``_fits`` then counts for any call:
         compiling every shape a second time to ask each would double this."""
@@ -1190,13 +1241,16 @@ class LLMEngine:
         t0 = time.perf_counter()
         shapes = self.extend_shapes()
         for b, tc, cap in shapes:
+            name = _extend_name(b, tc, cap)
             _, _, *rest = jax.block_until_ready(self._extend_call(
-                *self._extend_args(jnp.zeros, b, cap, self.pool.states), tc=tc))
+                name, *self._extend_args(jnp.zeros, b, cap, self.pool.states), tc=tc))
             self.pool.states = self.pool.split_outputs(rest)[1]
+            if name not in self.programs:
+                self._new_program(name)
         warm_s = time.perf_counter() - t0
         b, tc, cap = largest = max(shapes, key=lambda s: (s[0] * s[2], s[1]))
         memory = self._extend_call.lower(
-            *self._extend_args(jax.ShapeDtypeStruct, b, cap), tc=tc
+            _extend_name(b, tc, cap), *self._extend_args(jax.ShapeDtypeStruct, b, cap), tc=tc
         ).compile().memory_analysis()
         if memory is not None:
             self._temp_bytes = memory.temp_size_in_bytes
@@ -1210,6 +1264,12 @@ class LLMEngine:
                 "alias_bytes": memory.alias_size_in_bytes,
             },
         }
+
+    def _new_program(self, name: str) -> None:
+        """A group at zero for the program ``name``, in ``programs`` and in
+        ``traced``'s: two reads bound a window only for a group both hold."""
+        for book in (self.programs, self.traced["programs"]):
+            book[name] = dict(n=0, busy_s=0.0)
 
     # -- public stats ------------------------------------------------------
 
@@ -1229,15 +1289,12 @@ class LLMEngine:
         return {
             "device": accelerator.device_report(),
             "compile_cache": accelerator.compile_cache_stats(),
-            "kv_blocks_total": self.pool.num_blocks,
             "kv_blocks_in_use": self.pool.in_use(),
             "prefix_hits": self.prefix.hits if self.prefix else 0,
             "prefix_misses": self.prefix.misses if self.prefix else 0,
-            "prefix_evictions": self.prefix.evictions if self.prefix else 0,
             "prefix_cached_blocks": len(self.prefix) if self.prefix else 0,
             "adapters_resident": self._mux.loaded_ids(),
             **({
-                "state_slots_total": self.pool.state_slots - 1,
                 "state_slots_in_use": self.pool.slots_in_use(),
                 "state_snapshots": self.prefix.snapshots() if self.prefix else 0,
             } if self._stateful else {}),
@@ -1277,6 +1334,9 @@ class LLMEngine:
             "phase_s": dict(self.phase_s),
             "phase_n": dict(self.phase_n),
             "calls": {form: dict(counts) for form, counts in self.calls.items()},
+            "programs": {name: dict(counts) for name, counts in self.programs.items()},
+            "programs_cold": self.programs_cold,
+            "programs_cold_s": self.programs_cold_s,
         }
 
     @contextlib.contextmanager
@@ -1576,6 +1636,7 @@ class LLMEngine:
         states, decode = [st for _, st in lanes], chunks[0] is None
         form = "decode" if decode else "prefill"
         seq = self._over_forms("n") + 1
+        program = _extend_name(b, tc, t_cap)
         with self._phase("upload"):
             cached = sum(st.length for st in states)    # live tokens in the lanes' caches
             # a lane's last token is on the host if its call has landed, else
@@ -1639,16 +1700,26 @@ class LLMEngine:
         what = dict(
             lanes=len(states), lane_slots=b, tokens=fed, token_slots=b * tc,
             cache_tokens=cached, cache_slots=b * t_cap)
+        # a program nothing has run yet (a shape the warm-up left out, an engine
+        # that was never warmed): this call traces and compiles it, and says so
+        cold = program not in self.programs
         with self._phase(
-                "dispatch", call=seq, form=form, ahead=int(flight is not None), **what):
+                "dispatch", call=seq, form=form, program=program,
+                ahead=int(flight is not None), **what, **({"cold": 1} if cold else {})):
+            t0 = time.perf_counter()
             # the state arenas go in donated, and the ones that come back (each
             # lane's slot advanced, a kept state in its own) take their place
             logits, hidden, *rest = self._extend_call(
-                self._params, operands,
+                program, self._params, operands,
                 self._no_home if flight is None else flight.home, *caches,
                 *self.pool.states, tc=tc)
             news, self.pool.states, counted = self.pool.split_outputs(rest)
             del caches, rest        # the caches are freed when extend has run
+            if cold:
+                self._new_program(program)
+                self.programs_cold += 1
+                self.programs_cold_s += time.perf_counter() - t0
+            self.programs[program]["n"] += 1
             counts = self.calls[form]
             counts["n"] += 1
             counts["lanes_used"] += what.pop("lanes")
@@ -1661,7 +1732,7 @@ class LLMEngine:
             del logits, hidden, news, operands
             call = _Call(
                 home, picked, lanes, emits, seq=seq, form=form, shape=(b, tc, t_cap),
-                step=self.steps, launched_at=self._now())
+                program=program, step=self.steps, launched_at=self._now())
             for i, (st, ch, emit) in enumerate(zip(states, chunks, emits)):
                 st.call, st.lane = call, i
                 st.length += len(ch)
@@ -1738,16 +1809,16 @@ class LLMEngine:
             # one's own record takes it as it takes everything (``step``); an
             # earlier step's call is kept out of this step's record, and goes
             # to ``traced`` where that step was recorded
-            books = [(self.counted, self.calls)]
+            books = [(self.counted, self.calls, self.programs)]
             if call.step != self.steps:
-                if self._step_began is not None:
-                    books.append((self._step_began, self._step_began["calls"]))
-                if call.recorded:
-                    books.append((self.traced, self.traced["calls"]))
-            for flat, calls in books:
+                for work in (self._step_began, self.traced if call.recorded else None):
+                    if work is not None:
+                        books.append((work, work["calls"], work["programs"]))
+            for flat, calls, programs in books:
                 for name, n in counted.items():
                     flat[name] += n
                 calls[call.form]["busy_s"] += busy_s
+                programs[call.program]["busy_s"] += busy_s
             adapted = any(st.adapter is not None for st in states)
             logits = hidden = None
             if adapted or any(st.return_logits for st in states):
@@ -1837,12 +1908,13 @@ class LLMEngine:
 
 
 def _add_difference(into: Dict[str, Any], after: Dict[str, Any], before: Dict[str, Any]) -> None:
-    """``into += after - before``, number by number, through groups of numbers."""
+    """``into += after - before``, number by number, through groups of numbers;
+    a group that ``before`` lacks (a program first called since) counts from zero."""
     for key, value in after.items():
         if isinstance(value, dict):
-            _add_difference(into[key], value, before[key])
+            _add_difference(into[key], value, before.get(key, {}))
         else:
-            into[key] += value - before[key]
+            into[key] += value - before.get(key, 0)
 
 
 # ---------------------------------------------------------------------------

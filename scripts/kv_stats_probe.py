@@ -23,6 +23,7 @@ request was given: two checkouts that serve the same tokens print the same
 """
 
 import argparse
+import contextlib
 import hashlib
 import json
 import os
@@ -119,45 +120,61 @@ def probe(root, workload, seed, seconds, traced, poll_s=0.0):
     }
 
 
-def fixed_schedule(root, workload, seed, every=4):
-    """One cycle of the cell's requests through its engine, in this process,
-    on a schedule counted in steps; the ids, what the engine counted and the
-    device's peak memory."""
+def cell_engine(root, workload, seed):
+    """The cell's engine in this process, warm, with what ``warm`` reported and
+    one cycle of the cell's requests as its generator makes them."""
     root = os.path.abspath(root)
     sys.path.insert(0, root)
     from benchmark import manifest
     from benchmark.server import BenchLLMServer
     from benchmark.traffic import serve_open_loop
-    from ray_tpu.serve import batching
 
     cell = manifest.Manifest(root).cell(workload)
     server = BenchLLMServer(
         cell.architecture, cell.reference, cell.config, seed=seed, **cell.config["engine"])
-    eng = server._engine
     warm = server.warm()
     params = dict(cell.traffic, vocab_size=warm["vocab_size"])
     turns = len(params["prompt_tokens"])
     cycle = sorted((
         r for r in serve_open_loop.schedule(params, seed, (turns + 1) / params["rate_rps"])
         if 0 <= r["index"] < turns), key=lambda r: r["index"])
+    return server._engine, warm, cycle
+
+
+def step_through(eng, cycle, every=4, around=contextlib.nullcontext):
+    """The requests of ``cycle`` through ``eng``, request ``i`` admitted after
+    ``every * i`` device-calling steps, each step inside ``around()``; the
+    sequences, done."""
+    from ray_tpu.serve import batching
+
     waiting = [
         batching._Sequence({"prompt": r["prompt"], "max_new_tokens": r["n_out"]}) for r in cycle
     ]
     seqs, active, calling_steps = list(waiting), [], 0
-    before, t0 = eng.stats(), time.perf_counter()
     while waiting or active:
         while waiting and (not active or calling_steps >= every * (len(seqs) - len(waiting))):
             active.append(waiting.pop(0))
         # by the count every checkout keeps, a parent of PR 31 too
         calls = eng.phase_n["dispatch"]
-        eng.step(active)
+        with around():
+            eng.step(active)
         calling_steps += eng.phase_n["dispatch"] > calls
         active = [s for s in active if not s.done]
+    return seqs
+
+
+def fixed_schedule(root, workload, seed, every=4):
+    """One cycle of the cell's requests through its engine, in this process,
+    on a schedule counted in steps; the ids, what the engine counted and the
+    device's peak memory."""
+    eng, warm, cycle = cell_engine(root, workload, seed)
+    before, t0 = eng.stats(), time.perf_counter()
+    seqs = step_through(eng, cycle, every)
     wall_s, after = time.perf_counter() - t0, eng.stats()
     errors = [repr(s._error) for s in seqs if s._error is not None]
     ids = [s._result["tokens"] if s._error is None else None for s in seqs]
     return {
-        "root": root, "workload": workload, "seed": seed, "wall_s": wall_s,
+        "root": os.path.abspath(root), "workload": workload, "seed": seed, "wall_s": wall_s,
         "errors": errors, "ids": ids,
         "sha256": hashlib.sha256(json.dumps(ids).encode()).hexdigest(),
         "delta": delta(after, before), "device": after["device"], "warm": warm,

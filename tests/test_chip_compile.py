@@ -473,7 +473,7 @@ def _extend_at(cfg, shaped, lanes, tc, cap):
     cache = shaped((cfg.num_layers, lanes, cap, cfg.num_heads, cfg.head_dim), cfg.dtype)
     operands = shaped((lanes, llm._operand_width(tc, cap // 16)), jnp.int32)
     return llm._operand_extend(gpt.make_extend_fn(cfg)).lower(
-        params, operands, shaped((lanes,), jnp.int32), cache, cache, tc=tc).compile()
+        llm._extend_name(lanes, tc, cap), params, operands, shaped((lanes,), jnp.int32), cache, cache, tc=tc).compile()
 
 
 @pytest.fixture
@@ -531,7 +531,8 @@ def test_command_a_plus_share_extend_compiles_and_copies_no_expert(
     cache = shaped((cfg.num_layers, lanes, cap, cfg.kv_heads, cfg.head_dim), cfg.dtype)
     operands = shaped((lanes, llm._operand_width(256, 8192 // 256)), jnp.int32)
     compiled = llm._operand_extend(cfg.make_extend_fn()).lower(
-        params, operands, shaped((8,), jnp.int32), cache, cache, tc=tc).compile()
+        llm._extend_name(lanes, tc, cap), params, operands, shaped((8,), jnp.int32),
+        cache, cache, tc=tc).compile()
     _experts_kernels_and_a_chunks_attend(compiled.as_text(), cfg, lanes, tc, cap)
     memory = compiled.memory_analysis()
     assert 9.4e9 < memory.argument_size_in_bytes < 10.6e9
@@ -562,12 +563,12 @@ def test_gptj_serve_paging_programs_compile_beside_the_weights(shaped):
 
     def gather(b, cap):
         return programs.gather.lower(
-            (arena, arena), shaped((b, width), jnp.int32), cap // block).compile()
+            f"gather_{b}x{cap}", (arena, arena), shaped((b, width), jnp.int32), cap // block).compile()
 
     def page_back(b, tc):
         new = shaped((cfg.num_layers, b, tc, cfg.num_heads, cfg.head_dim), cfg.dtype)
         return programs.page_back.lower(
-            (arena, arena), (new, new), shaped((b, width), jnp.int32),
+            f"page_back_{b}x{tc}", (arena, arena), (new, new), shaped((b, width), jnp.int32),
             (shaped((b, tc, cfg.vocab_size), jnp.float32),
              shaped((b, tc, cfg.embed_dim), jnp.float32)),
             (), lanes[-1],
@@ -633,7 +634,8 @@ def test_keye_vl2_stage_extend_compiles_at_its_largest_shapes(shaped, form, buil
         (b, llm._operand_width(engine["prefill_token_buckets"][-1], cap // engine["block_size"])),
         jnp.int32)
     compiled = llm._operand_extend(cfg.make_extend_fn()).lower(
-        params, operands, shaped((lanes + len(cfg.counters),), jnp.int32), *caches, tc=tc
+        llm._extend_name(b, tc, cap), params, operands,
+        shaped((lanes + len(cfg.counters),), jnp.int32), *caches, tc=tc
     ).compile()
     _experts_kernels_and_a_chunks_attend(compiled.as_text(), cfg, b, tc, cap)
     memory = compiled.memory_analysis()
@@ -674,14 +676,15 @@ def test_three_arena_paging_programs_compile_without_a_whole_arena_temporary(sha
     lanes = engine["lane_buckets"][-1]
     for b, cap in ((1, engine["cache_buckets"][0]), (lanes, engine["cache_buckets"][-1])):
         memory = programs.gather.lower(
-            arenas, shaped((b, width), jnp.int32), cap // block).compile().memory_analysis()
+            f"gather_{b}x{cap}", arenas, shaped((b, width), jnp.int32), cap // block
+        ).compile().memory_analysis()
         assert 0 <= memory.output_size_in_bytes - per_token * b * cap < 4096 * 3
         assert memory.temp_size_in_bytes < 2 * narrow + 2**20, (b, cap)
     for b, tc in ((lanes, 1), (1, tokens)):
         news = tuple(
             shaped((cfg.num_layers, b, tc) + tuple(each), cfg.dtype) for each in cfg.cache_arrays)
         memory = programs.page_back.lower(
-            arenas, news, shaped((b, width), jnp.int32),
+            f"page_back_{b}x{tc}", arenas, news, shaped((b, width), jnp.int32),
             (shaped((b, tc, cfg.vocab_size), jnp.float32),
              shaped((b, tc, cfg.embed_dim), jnp.float32)),
             (shaped((len(cfg.counters),), jnp.int32),), lanes,
@@ -708,6 +711,54 @@ def _kimi_share():
         num_experts=config["n_routed_experts"]), config
 
 
+def _kimi_extend_at(shaped, cfg, engine, b, tc, cap):
+    """The configuration's ``extend`` as a step calls it, compiled for ``b``
+    lanes of ``tc`` tokens over a cache of ``cap``, under the engine's name for it."""
+    params = jax.tree.map(
+        lambda x: shaped(x.shape, x.dtype), jax.eval_shape(lambda: cfg.init_params(0)))
+    caches = [
+        shaped((cfg.num_layers, b, cap) + tuple(each), cfg.dtype) for each in cfg.cache_arrays]
+    operands = shaped(
+        (b, llm._operand_width(engine["prefill_token_buckets"][-1], cap // engine["block_size"])),
+        jnp.int32)
+    home = shaped((engine["lane_buckets"][-1] + len(cfg.counters),), jnp.int32)
+    return llm._operand_extend(cfg.make_extend_fn()).lower(
+        llm._extend_name(b, tc, cap), params, operands, home, *caches, tc=tc).compile()
+
+
+def test_every_compiled_program_has_a_module_name_of_its_own(shaped, built_for_tpu):
+    """What a profile of the chip shows on its ``XLA Modules`` line: two of
+    Kimi's ``extend`` shapes and two of its pool's gathers are four modules of
+    four names, each the one the engine records for the call (``llm.dispatch``'s
+    ``program``; ``stats()["programs"]``), none with a dot (a reader of a trace
+    takes a dotted component of an ``op_name`` for a scope)."""
+    built_for_tpu(True)
+    cfg, config = _kimi_share()
+    engine = config["engine"]
+    block, small = engine["block_size"], engine["cache_buckets"][0]
+    arenas = (shaped((cfg.num_layers, engine["num_blocks"], block, 1, cfg.row_dim), cfg.dtype),)
+    width = llm._operand_width(engine["prefill_chunk"], engine["cache_buckets"][-1] // block)
+    texts = [
+        _kimi_extend_at(shaped, cfg, engine, b, tc, small).as_text()
+        for b, tc in ((2, 1), (1, engine["prefill_token_buckets"][0]))
+    ] + [
+        llm._paging_programs().gather.lower(
+            f"gather_{b}x{small}", arenas, shaped((b, width), jnp.int32), small // block
+        ).compile().as_text()
+        for b in (1, 2)
+    ]
+    names = [re.match(r"HloModule (jit_\w+),", text)[1] for text in texts]
+    assert names == [
+        f"jit_extend_decode_2x1x{small}",
+        f"jit_extend_prefill_1x{engine['prefill_token_buckets'][0]}x{small}",
+        f"jit_gather_1x{small}", f"jit_gather_2x{small}"]
+    assert len(set(names)) == 4
+    # every instruction's op_name starts with its program's name: no component of it a scope
+    for name, text in zip(names, texts):
+        inner = name[len("jit_"):]
+        assert f'op_name="jit({inner})/' in text and "." not in inner
+
+
 @pytest.mark.parametrize("form", ["decode", "prefill"])
 def test_kimi_k2_share_extend_compiles_at_its_largest_shapes(shaped, form, built_for_tpu):
     """One chip's share of Kimi K2 at its published widths (9.70 GB of weights)
@@ -725,17 +776,9 @@ def test_kimi_k2_share_extend_compiles_at_its_largest_shapes(shaped, form, built
     cap, lanes = engine["cache_buckets"][-1], engine["lane_buckets"][-1]
     b, tc = (lanes, 1) if form == "decode" else (1, engine["prefill_token_buckets"][-1])
     assert stated[form]["shape"] == [b, tc, cap]
-    params = jax.tree.map(
-        lambda x: shaped(x.shape, x.dtype), jax.eval_shape(lambda: cfg.init_params(0)))
-    caches = [
-        shaped((cfg.num_layers, b, cap) + tuple(each), cfg.dtype) for each in cfg.cache_arrays]
-    operands = shaped(
-        (b, llm._operand_width(engine["prefill_token_buckets"][-1], cap // engine["block_size"])),
-        jnp.int32)
-    compiled = llm._operand_extend(cfg.make_extend_fn()).lower(
-        params, operands, shaped((lanes + len(cfg.counters),), jnp.int32), *caches, tc=tc
-    ).compile()
+    compiled = _kimi_extend_at(shaped, cfg, engine, b, tc, cap)
     text = compiled.as_text()
+    assert text.startswith(f"HloModule jit_extend_{form}_{b}x{tc}x{cap},")
     kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     experts = [line for line in kernels if "extend.moe.experts" in line]
     assert len(experts) >= 2                                     # the kernel is there
@@ -795,14 +838,16 @@ def test_an_arena_of_latent_rows_pages_without_a_whole_arena_temporary(shaped):
 
     arenas = arenas_of(cfg.row_dim)
     for b, cap in ((1, engine["cache_buckets"][0]), (lanes, engine["cache_buckets"][-1])):
-        memory = programs.gather.lower(
-            arenas, shaped((b, width), jnp.int32), cap // block).compile().memory_analysis()
+        compiled = programs.gather.lower(
+            f"gather_{b}x{cap}", arenas, shaped((b, width), jnp.int32), cap // block).compile()
+        assert compiled.as_text().startswith(f"HloModule jit_gather_{b}x{cap},")
+        memory = compiled.memory_analysis()
         assert 0 <= memory.output_size_in_bytes - per_token * b * cap < 4096
         assert memory.temp_size_in_bytes < 2**20, (b, cap)
     for b, tc in ((lanes, 1), (1, tokens)):
         news = (shaped((cfg.num_layers, b, tc, 1, cfg.row_dim), cfg.dtype),)
         memory = programs.page_back.lower(
-            arenas, news, shaped((b, width), jnp.int32),
+            f"page_back_{b}x{tc}", arenas, news, shaped((b, width), jnp.int32),
             (shaped((b, tc, cfg.vocab_size), jnp.float32),
              shaped((b, tc, cfg.embed_dim), jnp.float32)),
             (shaped((len(cfg.counters),), jnp.int32),), lanes,
@@ -816,9 +861,11 @@ def test_an_arena_of_latent_rows_pages_without_a_whole_arena_temporary(shaped):
     # innermost, and the gather re-lays all of it out, twice over; in two arenas
     # the 64-wide one is re-laid out instead
     small = (shaped((1, width), jnp.int32), engine["cache_buckets"][0] // block)
-    one = programs.gather.lower(arenas_of(576), *small).compile().memory_analysis()
+    one = programs.gather.lower(
+        "gather_rows_of_576", arenas_of(576), *small).compile().memory_analysis()
     assert one.temp_size_in_bytes >= 2 * 0.9 * arena_bytes
-    two = programs.gather.lower(arenas_of(512, 64), *small).compile().memory_analysis()
+    two = programs.gather.lower(
+        "gather_rows_of_512_and_64", arenas_of(512, 64), *small).compile().memory_analysis()
     assert two.temp_size_in_bytes >= arena_bytes // 10
 
 
@@ -871,7 +918,8 @@ def test_granite_hybrid_extend_compiles_at_its_largest_shapes(shaped, form, buil
         (b, llm._operand_width(
             engine["prefill_token_buckets"][-1], cap // engine["block_size"], True)), jnp.int32)
     compiled = llm._operand_extend(cfg.make_extend_fn(), len(caches), len(arenas)).lower(
-        params, operands, shaped((lanes + len(cfg.counters),), jnp.int32), *caches, *arenas, tc=tc
+        llm._extend_name(b, tc, cap), params, operands,
+        shaped((lanes + len(cfg.counters),), jnp.int32), *caches, *arenas, tc=tc
     ).compile()
     text = compiled.as_text()
     kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
@@ -946,7 +994,8 @@ def test_granite_small_share_extend_compiles_and_copies_no_layers_experts(
         (b, llm._operand_width(
             engine["prefill_token_buckets"][-1], cap // engine["block_size"], True)), jnp.int32)
     compiled = llm._operand_extend(cfg.make_extend_fn(), len(caches), len(arenas)).lower(
-        params, operands, shaped((lanes + len(cfg.counters),), jnp.int32), *caches, *arenas, tc=tc
+        llm._extend_name(b, tc, cap), params, operands,
+        shaped((lanes + len(cfg.counters),), jnp.int32), *caches, *arenas, tc=tc
     ).compile()
     text = compiled.as_text()
     kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
@@ -1002,7 +1051,8 @@ def test_state_slots_are_read_and_written_without_a_whole_arena_temporary(shaped
         jnp.int32)
     lanes = engine["lane_buckets"][-1]
     memory = llm._operand_extend(cfg.make_extend_fn(), len(caches), len(arenas)).lower(
-        params, operands, shaped((lanes + len(cfg.counters),), jnp.int32), *caches, *arenas, tc=1
+        llm._extend_name(1, 1, cap), params, operands,
+        shaped((lanes + len(cfg.counters),), jnp.int32), *caches, *arenas, tc=1
     ).compile().memory_analysis()
     assert 0 <= memory.alias_size_in_bytes - arena_bytes < slots * 2**17
     # 64 MB at one lane and 72 at eight: none of it a lane's state
@@ -1061,7 +1111,8 @@ def test_minicpm_sala_stage_extend_compiles_and_copies_no_arena_and_no_layer(
         (b, llm._operand_width(
             engine["prefill_token_buckets"][-1], cap // engine["block_size"], True)), jnp.int32)
     compiled = llm._operand_extend(cfg.make_extend_fn(), len(caches), len(arenas)).lower(
-        params, operands, shaped((lanes + len(cfg.counters),), jnp.int32), *caches, *arenas, tc=tc
+        llm._extend_name(b, tc, cap), params, operands,
+        shaped((lanes + len(cfg.counters),), jnp.int32), *caches, *arenas, tc=tc
     ).compile()
     kernels = [
         line for line in compiled.as_text().splitlines()
@@ -1111,10 +1162,12 @@ def test_a_coarse_arena_pages_without_a_whole_arena_temporary(shaped):
     outputs = (shaped((b, tc, cfg.vocab_size), jnp.float32), shaped((b, tc, cfg.embed_dim), jnp.float32))
     counted = (shaped((len(cfg.counters),), jnp.int32),)
     back = programs.page_back.lower(
-        arenas, news, operands, outputs, counted, 4).compile().memory_analysis()
+        f"page_back_{b}x{tc}", arenas, news, operands, outputs, counted, 4
+    ).compile().memory_analysis()
     assert back.alias_size_in_bytes >= sum(arena_bytes)
     assert back.temp_size_in_bytes < min(arena_bytes) / 2
-    gather = programs.gather.lower(arenas, operands, cap // block).compile().memory_analysis()
+    gather = programs.gather.lower(
+        f"gather_{b}x{cap}", arenas, operands, cap // block).compile().memory_analysis()
     assert gather.temp_size_in_bytes < min(arena_bytes)
     assert 0 <= gather.output_size_in_bytes - cap * 4224 < 2**12
     clone = programs.clone.lower(

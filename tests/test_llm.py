@@ -22,6 +22,7 @@ from ray_tpu.serve.llm import (
     LLMServer,
     NoKVBlocksError,
     PrefixCache,
+    _extend_name,
     chain_hashes,
     random_lora,
 )
@@ -486,7 +487,10 @@ def test_nothing_compiles_once_the_engine_is_built_and_extend_is_warm():
     combos = list(itertools.product(
         eng.lane_buckets, [1] + eng.prefill_token_buckets, eng.cache_buckets))
     assert eng.extend_shapes() == combos
+    # one compiled program a shape, each a member of the family under its own name
     assert eng.warm()["shapes"] == eng._extend_call._cache_size() == len(combos)
+    assert set(eng.stats()["programs"]) == {_extend_name(*shape) for shape in combos} <= set(
+        eng._extend_call.names())
     assert compiles                             # the listener hears a compile
     del compiles[:]
 

@@ -2,14 +2,17 @@
 real ``jax.profiler`` session records them and as the benchmark's reducer
 labels idle gaps with them, the counters beside them against what the shapes
 give, per form of call and a second time over the steps a session recorded,
-the record of each device call on its spans, queue time per request, the
-slowest step's own record, what the spans cost outside a session, and the
-stable device names (kernels, scopes)."""
+the record of each device call on its spans and the name of the program it
+runs as (a module name of its own for every shape, the same string on the
+span, on the host's ``PjitFunction`` event and in ``stats()["programs"]``),
+queue time per request, the slowest step's own record, what the spans cost
+outside a session, and the stable device names (kernels, scopes)."""
 
 import contextlib
 import dataclasses
 import glob
 import os
+import re
 import sys
 import time
 import types
@@ -133,15 +136,26 @@ def _xplane_of(where):
     return found[0]
 
 
-def _recorded(path, name):
-    """``(start, end, metadata)`` of every host span ``name``, in order."""
+def _host_events(path):
     from jax.profiler import ProfileData
 
     host = next(
         p for p in ProfileData.from_file(path).planes if p.name == trace_reduce.HOST_PLANE)
+    return [e for line in host.lines for e in line.events]
+
+
+def _recorded(path, name):
+    """``(start, end, metadata)`` of every host span ``name``, in order."""
     return sorted(
         (e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
-        for line in host.lines for e in line.events if e.name == name)
+        for e in _host_events(path) if e.name == name)
+
+
+def _ran(path, pattern):
+    """``(start, end, name)`` of every host event whose name ``pattern`` matches whole."""
+    return sorted(
+        (e.start_ns, e.start_ns + e.duration_ns, e.name)
+        for e in _host_events(path) if re.fullmatch(pattern, e.name))
 
 
 @pytest.fixture(scope="module")
@@ -355,11 +369,17 @@ def test_a_host_away_from_the_engine_is_not_the_devices_time(engine):
 
 def test_kv_stats_has_every_key_from_construction():
     """Two reads bound a window only for a key both hold: every counter, every
-    group and every key of a group is there, at zero, before the first step."""
+    group and every key of a group is there, at zero, before the first step;
+    the group of every program the buckets allow once ``warm()`` has run them."""
     eng = llm.LLMEngine(NANO, **ENGINE)
+    assert eng.stats()["programs"] == {} == eng.stats()["traced"]["programs"]
+    eng.warm()
     built = eng.stats()
     assert all(v == 0 for v in built["calls"]["decode"].values())
     assert built["traced"]["steps"] == 0 and built["traced"]["calls"] == built["calls"]
+    assert built["traced"]["programs"] == built["programs"] == {
+        llm._extend_name(*shape): {"n": 0, "busy_s": 0.0} for shape in eng.extend_shapes()}
+    assert (built["programs_cold"], built["programs_cold_s"]) == (0, 0.0)
     assert set(built["traced"]) == set(eng._work()) < set(built)
     _drive(eng, _requests())
     assert _key_tree(eng.stats()) == _key_tree(built)
@@ -379,7 +399,7 @@ def test_traced_is_the_counters_over_exactly_the_recorded_steps(engine, session,
         return [x for v in group.values() for x in (flat(v) if isinstance(v, dict) else [v])]
 
     # seconds are summed step by step here and all at once there
-    for seconds in ("phase_s", "calls", "queue_s"):
+    for seconds in ("phase_s", "calls", "programs", "queue_s"):
         assert flat({"": traced.pop(seconds)}) == pytest.approx(
             flat({"": whole.pop(seconds)}), rel=1e-9)
     assert traced == whole
@@ -400,6 +420,7 @@ def test_a_calls_counts_go_to_the_step_that_launched_it(tmp_path):
         keye_vl2.keye_vl2_nano(), num_blocks=16, block_size=16, prefill_chunk=32,
         lane_buckets=(1,), prefill_token_buckets=(32,), cache_buckets=(64,),
         prefix_caching=False)
+    eng.warm()
     built = eng.stats()
 
     def request():
@@ -451,6 +472,7 @@ def test_dispatch_and_fetch_say_which_call_and_what_it_is(xplane, planes):
             "lanes": call[0], "lane_slots": call[1], "tokens": tokens,
             "token_slots": call[1] * call[2], "cache_tokens": call[4],
             "cache_slots": call[1] * call[3], "ahead": int(i > 0),
+            "program": llm._extend_name(*call[1:4]),
         }
         for i, (call, tokens) in enumerate(zip(CALLS, (20 + 32, 1, 8 + 9, 3, 2)))
     ]
@@ -463,6 +485,123 @@ def test_dispatch_and_fetch_say_which_call_and_what_it_is(xplane, planes):
     assert tuple(map(step_of, dispatched)) == LAUNCHED_IN
     assert tuple(map(step_of, fetched)) == LANDED_IN
     assert sum(a != b for a, b in zip(LAUNCHED_IN, LANDED_IN)) == STEPS - 1
+
+
+# -- (c'') every program under a name of its own, and the call's record says which
+
+
+def _module(lowered):
+    """``jit_extend_decode_1x1x64`` of a lowered program's text."""
+    return re.match(r"module @(\w+) ", lowered.as_text())[1]
+
+
+def test_two_shapes_are_two_modules_and_one_shape_twice_is_one():
+    eng = llm.LLMEngine(NANO, **ENGINE)
+    family = eng._extend_call
+
+    def lowered(b, tc, cap):
+        return family.lower(
+            llm._extend_name(b, tc, cap), *eng._extend_args(jax.ShapeDtypeStruct, b, cap), tc=tc)
+
+    assert _module(lowered(1, 1, 64)) == "jit_extend_decode_1x1x64"
+    assert _module(lowered(2, 16, 128)) == "jit_extend_prefill_2x16x128"
+    made = family.names()
+    assert _module(lowered(1, 1, 64)) == "jit_extend_decode_1x1x64" and family.names() == made
+    assert family.member("extend_decode_1x1x64") is family.member("extend_decode_1x1x64")
+    assert len(set(made)) == len(made) and "extend_decode_1x1x64" in made
+    # the pool's: a gather per (lanes, cache), a page-back per (lanes, tokens),
+    # compiled when the engine was built; the state store's copy by its own name
+    paging = llm._paging_programs()
+    assert {f"gather_{b}x{cap}" for b in (1, 2, 4) for cap in (64, 128)} <= set(
+        paging.gather.names())
+    assert {f"page_back_{b}x{tc}" for b in (1, 2, 4) for tc in (1, 16, 32)} <= set(
+        paging.page_back.names())
+    operands = jax.ShapeDtypeStruct((2, eng._operand_width), jnp.int32)
+    assert _module(paging.gather.lower("gather_2x64", eng.pool.arenas, operands, 4)) == (
+        "jit_gather_2x64")
+    assert llm._state_programs().copy.__name__ == "state_copy"
+    # a name is a component of every op_name of its program, and the reducer
+    # takes any dotted component for a scope: no name has a dot, none can
+    for name in made + paging.gather.names() + paging.page_back.names():
+        assert re.fullmatch(r"[A-Za-z0-9_]+", name), name
+        assert trace_reduce.scope_of(f"jit({name})/dot_general") == trace_reduce.NO_SCOPE
+    with pytest.raises(ValueError, match="no dot"):
+        family.member("extend.decode_1x1x64")
+
+
+def test_a_dispatch_names_the_program_its_call_runs_as(engine, xplane):
+    """One string on the engine's span, on the runtime's own event of the call
+    under it (``PjitFunction(<name>)``: what a chip's ``XLA Modules`` line shows
+    as ``jit_<name>(<id>)``) and in the lowered module."""
+    dispatched = _recorded(xplane, "llm.dispatch")
+    ran = _ran(xplane, r"PjitFunction\(extend_\w+\)")
+    assert len(dispatched) == len(CALLS)
+    # (the runtime writes the event of a call twice, one inside the other)
+    assert all(any(a <= at and until <= b for a, b, _ in dispatched) for at, until, _ in ran)
+    for (start, end, what), call in zip(dispatched, CALLS):
+        assert {event for at, until, event in ran if start <= at and until <= end} == {
+            f"PjitFunction({what['program']})"}
+        assert what["program"] == llm._extend_name(*call[1:4]) and "cold" not in what
+        b, tc, cap = call[1:4]
+        assert _module(engine._extend_call.lower(
+            what["program"], *engine._extend_args(jax.ShapeDtypeStruct, b, cap), tc=tc)
+        ) == "jit_" + what["program"]
+    # every other program of a step has a name of its own too
+    others = {name for _, _, name in _ran(xplane, r"PjitFunction\(\w+\)")}
+    assert {"PjitFunction(gather_2x64)", "PjitFunction(page_back_2x32)"} <= others
+    assert not {"PjitFunction(extend_call)", "PjitFunction(gather)"} & others
+
+
+def test_programs_split_the_calls_by_name_and_traced_holds_recorded_steps_alone(
+        engine, session):
+    eng = llm.LLMEngine(NANO, **ENGINE)
+    eng.warm()
+    before = eng.stats()
+    _drive(eng, _requests())
+    after = eng.stats()
+    ran = _delta(after, before, "programs")
+    assert {name: counts["n"] for name, counts in ran.items() if counts["n"]} == {
+        "extend_prefill_2x32x64": 1, "extend_prefill_2x16x64": 1, "extend_decode_1x1x64": 1,
+        "extend_decode_4x1x64": 1, "extend_decode_2x1x64": 1}
+    calls = _delta(after, before, "calls")
+    assert sum(c["busy_s"] for c in ran.values()) == pytest.approx(
+        sum(calls[form]["busy_s"] for form in llm.FORMS), rel=1e-9)
+    assert all((c["busy_s"] > 0) == (c["n"] > 0) for c in ran.values())
+    assert _delta(after, before, "programs_cold") == 0
+    # no session recorded a step of that engine
+    assert after["traced"]["programs"] == before["traced"]["programs"]
+    assert not any(c["n"] for c in after["traced"]["programs"].values())
+    # the module's engine, under its session: the recorded steps' calls and no other
+    traced = _delta(session.after["traced"], session.before["traced"], "programs")
+    assert sum(c["n"] for c in traced.values()) == len(CALLS)
+    assert sum(c["n"] for c in _delta(session.before, engine.stats(), "programs").values()) < 0
+    assert engine.stats()["traced"]["programs"] == session.after["traced"]["programs"]
+
+
+def test_a_shape_the_warm_up_left_out_is_counted_cold_once_with_its_seconds(tmp_path):
+    eng = llm.LLMEngine(NANO, **ENGINE)
+    left_out = (4, 1, 64)                   # CALLS[3]'s program
+    shapes = [s for s in eng.extend_shapes() if s != left_out]
+    eng.extend_shapes = lambda: shapes
+    eng.warm()
+    before = eng.stats()
+    assert llm._extend_name(*left_out) not in before["programs"]
+    with _session(tmp_path):
+        _drive(eng, _requests())
+        inside = eng.stats()
+        _drive(eng, _requests())
+    after = eng.stats()
+    assert _delta(inside, before, "programs_cold") == 1 == _delta(after, before, "programs_cold")
+    assert 0 < _delta(inside, before, "programs_cold_s") == _delta(
+        after, before, "programs_cold_s") <= _delta(inside, before, "phase_s")["dispatch"]
+    assert after["traced"]["programs_cold"] == 1
+    assert after["traced"]["programs_cold_s"] == pytest.approx(after["programs_cold_s"])
+    assert after["programs"][llm._extend_name(*left_out)]["n"] == 2 == after["traced"][
+        "programs"][llm._extend_name(*left_out)]["n"]
+    cold = [
+        what.get("cold") for _, _, what in _recorded(_xplane_of(tmp_path), "llm.dispatch")]
+    assert cold == [None] * 3 + [1] + [None] * 6
+    assert _key_tree(after) == _key_tree(inside) != _key_tree(before)
 
 
 def test_leaf_phases_add_up_to_the_step():
