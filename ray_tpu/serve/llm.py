@@ -221,45 +221,63 @@ def _paging_programs():
     # call: 1.17 GB at GPT-J's serve sizes, where 1 GB is free beside the
     # weights (``tests/test_chip_compile.py`` holds the programs to this).
 
+    def lies_tokens_last(a):
+        """Whether the runtime lays the arena out with a block's tokens along the
+        lanes: rows that are no whole number of the chip's 128 lanes (an
+        indexer's key: 1 x 64). Both programs then move and write it as it
+        lies, ``[layers, blocks, heads, dim, block]``."""
+        return bool(a.shape[-1] % 128)
+
     @functools.partial(accelerator.Programs, static_argnums=2)
     @jax.named_scope("paging.gather")
     def gather(arenas, operands, n):
         layers, blocks, block = arenas[0].shape[:3]
         b = operands.shape[0]
         flat = _sections(operands)[3][:, :n].reshape(-1)
-        # a block of an arena whose rows are narrower than the chip's 128 lanes
-        # (an indexer's key: 1 x 64) is moved as one flat row: moved as it
-        # lies, the compiler pads every row to the lanes inside the loop and
-        # holds the padded copy, eight times the cache (0.8 GB at four lanes of
-        # 32768); flat it still re-lays the small cache out once (twice its
-        # size, ``tests/test_chip_compile.py``). K and V are moved as they lie;
+        # an arena that lies with its tokens last (``lies_tokens_last``), and so
+        # its padded cache, are moved as they lie, a block's slab to its place
+        # along the cache's tokens (moved as ``[.., block, heads, dim]`` the
+        # compiler pads every row to the lanes inside the loop and holds the
+        # padded copy, eight times the cache; moved as one flat row a block it
+        # re-laid the whole arena out twice on every call, 2 x 100 MB at Keye's
+        # sizes, and the cache once more). K and V are moved as they lie;
         # an arena of one wide row a token (a latent's: 1 x 640) without its
         # heads axis, which the compiler lays out behind the block's tokens: a
         # block moved with that axis in place leaves the loop in another
         # layout than the caches have, and the copy is twice the caches.
         def as_moved(a):
-            if a.shape[-1] % 128:
-                return a.reshape(layers, blocks, -1)
+            if lies_tokens_last(a):
+                return a.transpose(0, 1, 3, 4, 2)
             return a.reshape(a.shape[:3] + a.shape[4:]) if a.shape[3] == 1 else a
 
+        narrow = tuple(map(lies_tokens_last, arenas))
         sources = tuple(as_moved(a) for a in arenas)
         # every block of the caches is written below; one buffer each, because
         # the compiler copies a value that starts two loop carries
         empty = tuple(
-            jax.lax.empty((layers, b * n) + a.shape[2:], a.dtype) for a in sources)
+            jax.lax.empty(
+                (layers, b) + a.shape[2:4] + (n * a.shape[4],) if lies
+                else (layers, b * n) + a.shape[2:], a.dtype)
+            for a, lies in zip(sources, narrow))
 
         def copy_block(i, caches):
+            zero = jnp.zeros((), flat.dtype)
             return tuple(
-                jax.lax.dynamic_update_slice_in_dim(
+                jax.lax.dynamic_update_slice(
+                    out, jax.lax.dynamic_slice_in_dim(arena, flat[i], 1, axis=1),
+                    (zero, i // n, zero, zero, i % n * arena.shape[4])) if lies
+                else jax.lax.dynamic_update_slice_in_dim(
                     out, jax.lax.dynamic_slice_in_dim(arena, flat[i], 1, axis=1),
                     i, axis=1)
-                for out, arena in zip(caches, sources))
+                for out, arena, lies in zip(caches, sources, narrow))
 
         caches = jax.lax.fori_loop(0, b * n, copy_block, empty)
         # an arena at a coarser grain (a row for every so many tokens) has fewer
         # rows a block, and its padded cache as many fewer
         return tuple(
-            c.reshape((layers, b, n * a.shape[2]) + a.shape[3:]) for c, a in zip(caches, arenas))
+            c.transpose(0, 1, 4, 2, 3) if lies
+            else c.reshape((layers, b, n * a.shape[2]) + a.shape[3:])
+            for c, a, lies in zip(caches, arenas, narrow))
 
     @functools.partial(accelerator.Programs, donate_argnums=0, static_argnums=5)
     @jax.named_scope("paging.page_back")
@@ -271,6 +289,7 @@ def _paging_programs():
         last = operands[:, _LAST]
         # tokens a row of each arena: 1, but for one kept at a coarser grain
         grains = tuple(block // a.shape[2] for a in arenas)
+        narrow = tuple(lies_tokens_last(a) and grain == 1 for a, grain in zip(arenas, grains))
 
         def write_coarse(i, tokens, new, grain):
             """An arena with one row for every ``grain`` tokens: the row belongs to
@@ -289,17 +308,35 @@ def _paging_programs():
                     jax.lax.dynamic_slice_in_dim(tokens, to, 1, axis=1)),
                 to, axis=1)
 
+        def write_narrow(i, arena, new):
+            """A token's row into an arena that lies with its tokens last
+            (``lies_tokens_last``): flat over its blocks the program re-laid all of it
+            out on the way in and again on the way out (2 x 100 MB at Keye's
+            sizes, for one row written: 0.9 ms of every call). The token's block
+            is read, the row put in its place along the lanes, and written back
+            (a row handed to the update as the model leaves it, dim innermost,
+            makes the compiler re-lay the arena out to match the row)."""
+            row = jax.lax.dynamic_slice_in_dim(new, rows[i], 1, axis=1)     # [layers, 1, heads, dim]
+            at, offset = slots[i] // block, slots[i] % block
+            held = jax.lax.dynamic_slice_in_dim(arena, at, 1, axis=1)       # the token's block
+            held = jnp.where(jnp.arange(block) == offset, row[..., None], held)
+            return jax.lax.dynamic_update_slice_in_dim(arena, held, at, axis=1)
+
         def write_token(i, tokens_of):
             return tuple(
-                jax.lax.dynamic_update_slice_in_dim(
+                write_coarse(i, tokens, new, grain) if grain > 1
+                else write_narrow(i, tokens, new) if lies
+                else jax.lax.dynamic_update_slice_in_dim(
                     tokens, jax.lax.dynamic_slice_in_dim(new, rows[i], 1, axis=1),
-                    slots[i], axis=1) if grain == 1 else write_coarse(i, tokens, new, grain)
-                for tokens, new, grain in zip(tokens_of, news, grains))
+                    slots[i], axis=1)
+                for tokens, new, grain, lies in zip(tokens_of, news, grains, narrow))
 
         # the count is traced: a loop the compiler cannot unroll, whatever the
         # shapes (unrolled at one token it re-lays the arenas out and back)
         written = jax.lax.fori_loop(0, operands[0, _COUNT], write_token, tuple(
-            a.reshape((layers, blocks * a.shape[2]) + a.shape[3:]) for a in arenas))
+            a.transpose(0, 1, 3, 4, 2) if lies
+            else a.reshape((layers, blocks * a.shape[2]) + a.shape[3:])
+            for a, lies in zip(arenas, narrow)))
         picked = tuple(
             jnp.stack([
                 jax.lax.dynamic_index_in_dim(o[i], last[i], 0, keepdims=False)
@@ -312,7 +349,9 @@ def _paging_programs():
             # width whatever the lanes, so ``extend`` has no program more for it
             home = jnp.concatenate([
                 jnp.pad(ids, (0, width - b)), *(c.astype(jnp.int32) for c in counted)])
-        return tuple(w.reshape(a.shape) for w, a in zip(written, arenas)), home, picked
+        return tuple(
+            w.transpose(0, 1, 4, 2, 3) if lies else w.reshape(a.shape)
+            for w, a, lies in zip(written, arenas, narrow)), home, picked
 
     @functools.partial(jax.jit, donate_argnums=0)
     @jax.named_scope("paging.clone")

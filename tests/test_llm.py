@@ -29,9 +29,26 @@ from ray_tpu.serve.llm import (
 
 CFG = gpt.gpt_nano()
 #: the pool's own tests run over both kinds of per-token state: K and V (two
-#: arenas), and K, V and an indexer's key (three, of two shapes)
+#: arenas), and K, V and an indexer's key (three, of two shapes); and over both
+#: ways the paging programs move an arena: a row that is no whole number of the
+#: chip's 128 lanes (every arena of the two nano presets) goes a block's tokens
+#: last, a whole one (K and V at a head of 128, as on the chip) as it is shaped
 both_kinds_of_state = pytest.mark.parametrize(
-    "cfg", [CFG, keye_vl2.keye_vl2_nano()], ids=["two-arenas", "three-arenas"])
+    "cfg", [
+        CFG, keye_vl2.keye_vl2_nano(),
+        keye_vl2.keye_vl2_nano(num_heads=4, kv_heads=2, head_dim=128)],
+    ids=["two-arenas", "three-arenas", "three-arenas-whole-lanes"])
+
+
+def test_the_pools_presets_cover_both_ways_an_arena_is_moved():
+    """``both_kinds_of_state``'s presets against what the paging programs ask of an
+    arena (its last size, a whole number of 128 lanes or not): the two nano
+    presets' arenas all go tokens last, the third's K and V as they are shaped
+    beside an indexer's key that goes tokens last, which is the chip's Keye."""
+    whole = [
+        [not dim % 128 for _, dim in cfg.cache_arrays]
+        for cfg in both_kinds_of_state.args[1]]
+    assert whole == [[False, False], [False, False, False], [True, True, False]]
 
 
 def _prompt(seed: int, n: int):
