@@ -1,6 +1,7 @@
 """The kit the architecture files compose (``gpt.py``, ``cohere2_moe.py``,
 ``keye_vl2.py``, ``kimi_k2.py``, ``granitemoehybrid.py``, ``lfm2_moe.py``,
-``minicpm_sala.py``): every
+``minicpm_sala.py``, ``mimo_v2_flash.py``, whose per-sequence state is made of
+cached rows: a window's K and V): every
 decision they share, written once. Plain functions of their arguments: no
 configuration, no ``jit`` and no scope of their own but ``extend.logits`` round
 :func:`rms_head`, because the readers of a device trace key on the path of scopes

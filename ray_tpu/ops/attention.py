@@ -607,18 +607,28 @@ def _heads_a_tile(groups: int, block_q: int, dv: int) -> int:
 
 
 def _masked_fwd_kernel(
-    blocks_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, acc_ref, m_ref, l_ref,
-    *, scale, groups
+    blocks_ref, *refs, scale, groups, sinks=False
 ):
     from jax.experimental import pallas as pl
 
+    if sinks:
+        sinks_ref, *refs = refs
+    q_ref, k_ref, v_ref, mask_ref, o_ref, acc_ref, m_ref, l_ref = refs
     ki = pl.program_id(3)
+    first = pl.program_id(1) * groups if sinks else None    # this tile's first query head
 
     @pl.when(ki == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+        if sinks:
+            # a head's sink is a key every query sees, with a logit of its own and
+            # no value: the maximum starts there and the sum at exp(sink - sink)
+            for g in range(groups):
+                m_ref[g] = jnp.full(m_ref.shape[1:], sinks_ref[first + g], jnp.float32)
+            l_ref[:] = jnp.ones_like(l_ref)
+        else:
+            m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[:] = jnp.zeros_like(l_ref)
 
     @pl.when(ki < blocks_ref[pl.program_id(0)])
     def _run():
@@ -665,6 +675,7 @@ def masked_attention(
     kv_len: jax.Array,
     *,
     scale: Optional[float] = None,
+    sinks: Optional[jax.Array] = None,
     block_q: int = MASKED_BLOCK_Q,
     block_k: int = MASKED_BLOCK_K,
     interpret: bool = False,
@@ -685,8 +696,12 @@ def masked_attention(
     a latent's 64), and a K/V head's tiles are fetched once for each such block
     of its heads. ``kv_len`` [b] int32 promises that no query of lane ``i`` reads a
     key at or past ``kv_len[i]``: key blocks past it are neither fetched nor
-    computed, whatever they hold. Returns [b, t, kv, groups, dv] in ``q``'s
-    type. A query whose mask is empty gets finite rubbish."""
+    computed, whatever they hold. ``sinks`` [kv, groups] float32, where given, is a
+    learned logit a query head that stands in every query's denominator and
+    brings no value (``exp(sink)`` beside the keys' ``exp(q . k * scale)``): the
+    running maximum starts at it and the running sum at 1, nothing else; a query
+    whose mask is empty then gets zeros. Returns [b, t, kv, groups, dv] in ``q``'s
+    type. Without sinks a query whose mask is empty gets finite rubbish."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -705,30 +720,36 @@ def masked_attention(
     nq, nk = (t + pad_q) // block_q, (s + pad_k) // block_k
     blocks = jnp.clip((kv_len.astype(jnp.int32) + block_k - 1) // block_k, 0, nk)
 
+    # what is prefetched to scalar memory: the lanes' live blocks and, where
+    # given, the sinks, flat in the order of the query heads
+    prefetched = (blocks,) if sinks is None else (
+        blocks, sinks.astype(jnp.float32).reshape(kv * groups))
+
     def kv_block(bi, ki, blocks):
         # past the lane's last live block: the block already there, no fetch
         return jnp.maximum(jnp.minimum(ki, blocks[bi] - 1), 0)
 
     def q_spec(width):
         return pl.BlockSpec(
-            (1, 1, heads, block_q, width), lambda bi, hi, qi, ki, blocks: (bi, hi, 0, qi, 0))
+            (1, 1, heads, block_q, width), lambda bi, hi, qi, ki, *_: (bi, hi, 0, qi, 0))
 
     def kv_spec(width):
         return pl.BlockSpec(
             (1, 1, block_k, width),
-            lambda bi, hi, qi, ki, blocks: (bi, hi // tiles, kv_block(bi, ki, blocks), 0))
+            lambda bi, hi, qi, ki, blocks, *_: (bi, hi // tiles, kv_block(bi, ki, blocks), 0))
 
     out = pl.pallas_call(
-        functools.partial(_masked_fwd_kernel, scale=scale, groups=heads),
+        functools.partial(
+            _masked_fwd_kernel, scale=scale, groups=heads, sinks=sinks is not None),
         name="masked_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(prefetched),
             grid=(b, kv * tiles, nq, nk),
             in_specs=[
                 q_spec(d), kv_spec(d), kv_spec(dv),
                 pl.BlockSpec(
                     (1, block_q, block_k),
-                    lambda bi, hi, qi, ki, blocks: (bi, qi, kv_block(bi, ki, blocks))),
+                    lambda bi, hi, qi, ki, blocks, *_: (bi, qi, kv_block(bi, ki, blocks))),
             ],
             out_specs=q_spec(dv),
             scratch_shapes=[
@@ -743,7 +764,7 @@ def masked_attention(
             vmem_limit_bytes=MASKED_VMEM_BYTES),
         interpret=interpret,
     )(
-        blocks, q.transpose(0, 2, 3, 1, 4).reshape(b, kv * tiles, heads, t + pad_q, d),
+        *prefetched, q.transpose(0, 2, 3, 1, 4).reshape(b, kv * tiles, heads, t + pad_q, d),
         k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), mask.astype(jnp.int8))
     return out.reshape(b, kv, groups, t + pad_q, dv).transpose(0, 3, 1, 2, 4)[:, :t]
 

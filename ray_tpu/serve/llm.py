@@ -9,8 +9,12 @@ beside the sizes ``num_layers``, ``embed_dim``, ``vocab_size``, ``max_seq_len``
 and ``dtype``; where its ``extend`` counts something, ``counters`` names what;
 where it keeps state per sequence and not per token, ``state_arrays`` names
 that, ``state_chunk`` how often a state can be kept, and ``cache_layers`` in how
-many layers a token is cached; ``models/cohere2_moe.py``, ``models/keye_vl2.py``,
-``models/kimi_k2.py``, ``models/granitemoehybrid.py``, ``models/minicpm_sala.py``).
+many layers a token is cached (``cached_layers``: in which); ``models/cohere2_moe.py``,
+``models/keye_vl2.py``, ``models/kimi_k2.py``, ``models/granitemoehybrid.py``,
+``models/minicpm_sala.py``, ``models/mimo_v2_flash.py``). A state need not be a
+recurrence's: ``mimo_v2_flash.py``'s is made of **cached rows**, the newest 128 rows
+of K and V of each layer that sees a window and nothing else, which the engine
+keeps, hands over, snapshots and restores as it does any state, and never pages.
 
 What PR 9 proved with synthetic step functions (continuous batching,
 admission control, multiplexing) this module composes on an actual model
@@ -64,7 +68,8 @@ serving setup from PAPERS.md):
   their prefill FLOPs entirely. Reused KV is bitwise-identical to a
   fresh prefill because the extend fn is deterministic per shape.
 * state per sequence — a recurrent layer leaves nothing behind per token but
-  one state per sequence. The pool holds slots of it beside the block arenas
+  one state per sequence (and a layer that sees a window only, the window's
+  rows: a state of a fixed size too). The pool holds slots of it beside the block arenas
   (``state_arrays``): a sequence takes one at admission and gives it back with
   its lease. Nothing copies a state into or out of a call: ``extend`` is handed
   the state arenas themselves (donated, and the ones it returns take their
@@ -1140,11 +1145,18 @@ class LLMEngine:
         # cache slots gathered for layers whose queries see a window only
         # (``cfg.sliding_window``, ``cfg.sliding_layers``; 0 without them), and
         # those of them that hold a token too old for any query of the call to
-        # see: what a window-aware allocator would neither keep nor gather
+        # see: what a window-aware allocator would neither keep nor gather.
+        # Counted from what a call gathers: the sliding layers among those a
+        # token is cached in (``cfg.cached_layers``; every layer without it), so
+        # a model that keeps its windows as state (``models/mimo_v2_flash.py``)
+        # reads 0 here, with sliding layers and all
         self.window_slots = 0
         self.window_slots_outside = 0
         self._window = getattr(self.cfg, "sliding_window", None)
-        self._window_layers = sum(getattr(self.cfg, "sliding_layers", ()))
+        sliding = tuple(getattr(self.cfg, "sliding_layers", ()))
+        self._window_layers = sum(
+            s and c for s, c in zip(
+                sliding, getattr(self.cfg, "cached_layers", (True,) * len(sliding))))
         phases = PHASES + (STATE_PHASES if self._stateful else ())
         self.phase_s: Dict[str, float] = dict.fromkeys(phases, 0.0)
         self.phase_n: Dict[str, int] = dict.fromkeys(phases, 0)

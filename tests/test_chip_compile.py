@@ -26,7 +26,7 @@ from jax.sharding import SingleDeviceSharding
 
 from ray_tpu._private import accelerator
 from ray_tpu.models import (
-    cohere2_moe, gpt, granitemoehybrid, keye_vl2, kimi_k2, layers, minicpm_sala)
+    cohere2_moe, gpt, granitemoehybrid, keye_vl2, kimi_k2, layers, mimo_v2_flash, minicpm_sala)
 from ray_tpu.models.training import (
     abstract_state,
     default_optimizer,
@@ -1179,12 +1179,103 @@ def test_a_coarse_arena_pages_without_a_whole_arena_temporary(shaped):
     assert clone.temp_size_in_bytes < 2**21
 
 
+def _mimo_v2_flash_share():
+    """The served cut of MiMo-V2-Flash (one chip of ep16 x pp8: layer 0 and one period
+    of five sliding layers and a full one, 16 of 256 experts) and its engine sizes,
+    from the configuration's file."""
+    import json
+
+    from benchmark.manifest import published_keys
+    from benchmark.models import mimo_v2_flash as arch
+
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "configs",
+            "mimo-v2-flash-serve-ep16.json")) as f:
+        config = json.load(f)
+    return arch.program_config(published_keys(config)), config
+
+
+#: one sequence's windows, the cut's 5 sliding layers: 128 rows of 8 x (192 + 128) bfloat16
+MIMO_WINDOW_BYTES = 5 * 128 * 8 * (192 + 128) * 2
+#: one expert layer's 16 held experts in bfloat16
+MIMO_EXPERTS_BYTES = 402_653_184 * 2
+
+
+@pytest.mark.parametrize("form", ["decode", "prefill"])
+def test_mimo_v2_flash_share_extend_compiles_and_copies_no_arena_and_no_expert(
+        shaped, form, built_for_tpu):
+    """One chip's share of MiMo-V2-Flash at its published widths (6.86 GB of weights)
+    over the largest cache bucket, the two full layers' rows gathered (5,120 B a token)
+    and the five sliding layers' windows read and written where the pool's state
+    arenas lie (512 slots of 3.28 MB: 1.68 GB, donated and aliased). A decode call of
+    eight lanes attends in XLA alone, its kernels the grouped matmuls of the six
+    expert layers (in the period's scan and beside it); a prefill chunk attends in the
+    kernel the other architectures call, three call sites (layer 0, the period's full
+    layer, the scanned sliding layers' with ``sinks``), K wider than V. Neither holds
+    a copy of a window arena (left free, the compiler carries all of it through the
+    scan in another layout: 1.68 GB in and out a call) or of a layer's experts, and both
+    fit beside the pool's blocks and a second call's caches."""
+    built_for_tpu(True)
+    cfg, config = _mimo_v2_flash_share()
+    engine, stated = config["engine"], config["compiled_bytes_per_device"]
+    cap, lanes, slots = engine["cache_buckets"][-1], engine["lane_buckets"][-1], engine["state_slots"]
+    b, tc = (lanes, 1) if form == "decode" else (1, engine["prefill_token_buckets"][-1])
+    assert stated[form]["shape"] == [b, tc, cap]
+    params = jax.tree.map(
+        lambda x: shaped(x.shape, x.dtype), jax.eval_shape(lambda: cfg.init_params(0)))
+    assert cfg.cache_arrays == ((1, 768), (1, 512)) and cfg.cache_layers == 2
+    caches = [shaped((cfg.cache_layers, b, cap) + each[:2], cfg.dtype) for each in cfg.cache_arrays]
+    arenas = tuple(
+        shaped((layers, slots) + shape, dtype) for layers, shape, dtype in cfg.state_arrays)
+    assert [a.shape for a in arenas] == [(5, slots, 128, 1536), (5, slots, 128, 1024)]
+    operands = shaped(
+        (b, llm._operand_width(
+            engine["prefill_token_buckets"][-1], cap // engine["block_size"], True)), jnp.int32)
+    compiled = llm._operand_extend(cfg.make_extend_fn(), len(caches), len(arenas)).lower(
+        llm._extend_name(b, tc, cap), params, operands,
+        shaped((lanes + len(cfg.counters),), jnp.int32), *caches, *arenas, tc=tc
+    ).compile()
+    text = compiled.as_text()
+    kernels = [
+        line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    attends = [k for k in kernels if "/masked_attention/" in k]
+    assert len(kernels) - len(attends) == 4 and all("/extend.moe.experts/" in k for k in kernels
+                                                    if k not in attends)
+    if form == "prefill":
+        assert len(attends) == 3
+        assert sum("/extend.attention.window/masked_attention/" in k for k in attends) == 1
+        assert sum("/extend.attention/masked_attention/" in k for k in attends) == 2
+    else:
+        assert not attends
+    # the arenas keep their layout through both scans: a row's features innermost
+    assert set(re.findall(r"bf16\[5,%d,128,\d+\]\{([\d,]+)" % slots, text)) == {"3,2,1,0"}
+    memory = compiled.memory_analysis()
+    assert cfg.num_params() == 3_429_955_392 and MIMO_WINDOW_BYTES == 3_276_800
+    arena_bytes = slots * MIMO_WINDOW_BYTES
+    assert 0 <= memory.alias_size_in_bytes - arena_bytes < 2**20
+    per_token = cfg.cache_layers * (768 + 512) * 2
+    assert per_token == 5120
+    weights = memory.argument_size_in_bytes - memory.alias_size_in_bytes - b * (
+        cap * per_token + 2**17)
+    assert 6.85e9 < weights < 6.87e9
+    assert memory.argument_size_in_bytes == stated[form]["argument"]
+    assert memory.temp_size_in_bytes <= stated[form]["temp"] * 1.05
+    # no copy of a window arena or of a layer's experts; a decode call's temporaries are
+    # its lanes' K and V written into and re-laid out by K/V head, a little over the
+    # gathered caches themselves
+    assert memory.temp_size_in_bytes < min(MIMO_EXPERTS_BYTES, arena_bytes * 0.6) + (
+        b * cap * per_token if form == "decode" else 0)
+    resident = engine["num_blocks"] * engine["block_size"] * per_token
+    assert _device_bytes(compiled) + resident < HBM_BYTES
+    assert 2 * cfg.num_params() + resident + arena_bytes >= 0.60 * HBM_BYTES
+
+
 @pytest.mark.parametrize(
     "name,extends,pagings",
     [("gptj-6b-serve", 12, 11), ("command-a-plus-serve-ep8", 20, 25),
      ("keye-vl2-30b-a3b-serve", 16, 19), ("kimi-k2-instruct-serve-ep32", 16, 19),
      ("granite-4.0-h-micro-serve", 20, 25), ("granite-4.0-h-small-serve-ep2", 20, 25),
-     ("minicpm-sala-serve-pp2", 16, 19)],
+     ("minicpm-sala-serve-pp2", 16, 19), ("mimo-v2-flash-serve-ep16", 20, 25)],
 )
 def test_a_serve_configuration_compiles_no_more_programs_than_it_did(name, extends, pagings):
     """The programs an engine with the configuration's buckets compiles (a tiny
@@ -1210,6 +1301,7 @@ def test_a_serve_configuration_compiles_no_more_programs_than_it_did(name, exten
         if name.startswith("granite")
         else minicpm_sala.minicpm_sala_nano(max_seq_len=context, linear_chunk=256)
         if name.startswith("minicpm")
+        else mimo_v2_flash.mimo_v2_flash_nano(max_seq_len=context) if name.startswith("mimo")
         else dataclasses.replace(gpt.gpt_nano(), max_seq_len=context)
     )
     llm._paging_programs.cache_clear()      # this engine's programs alone

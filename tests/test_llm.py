@@ -13,7 +13,7 @@ import pytest
 
 import ray_tpu
 from ray_tpu import serve
-from ray_tpu.models import gpt, keye_vl2
+from ray_tpu.models import cohere2_moe, gpt, keye_vl2, mimo_v2_flash
 from ray_tpu.serve import batching
 from ray_tpu.serve.llm import (
     KVBlockPool,
@@ -789,3 +789,72 @@ def test_loadgen_reports_ttft_percentiles(serve_session):
         assert res[key] == res[key] and res[key] > 0, (key, res)
     # TTFT is streaming-aware: first token lands well before completion
     assert res["ttft_p50_s"] <= res["latency_p99_s"]
+
+
+# ---------------------------------------------------------------------------
+# a state made of cached rows: the window store (models/mimo_v2_flash.py)
+# ---------------------------------------------------------------------------
+
+_WINDOWED = dict(
+    num_blocks=32, block_size=8, prefill_chunk=16, prefill_lanes=1, lane_buckets=(1, 2),
+    prefill_token_buckets=(16,), cache_buckets=(32, 64), prefix_caching=False)
+
+
+@pytest.mark.parametrize(
+    "cfg,gathered", [
+        (cohere2_moe.cohere2_moe_nano(), 4), (mimo_v2_flash.mimo_v2_flash_nano(), 0),
+        (CFG, 0)],
+    ids=["rows-under-a-mask", "rows-as-state", "no-window"])
+def test_window_slots_are_counted_from_what_a_call_gathers(cfg, gathered):
+    """``window_slots`` counts the gathered slots of layers that see a window only: all
+    four sliding layers of a model that caches every token in every layer, none of a
+    model that names its sliding layers and keeps their windows as state (nothing is
+    gathered for them), none of a model without windows."""
+    eng = LLMEngine(cfg, **_WINDOWED)
+    seq = batching._Sequence({"prompt": _prompt(3, 40), "max_new_tokens": 4})
+    while not seq.done:
+        eng.step([seq])
+    assert seq._error is None, seq._error
+    stats = eng.stats()
+    assert eng._window_layers == gathered
+    gathered_slots = stats["cache_slots"]                   # lanes x cache bucket, every call
+    assert stats["window_slots"] == gathered * gathered_slots
+    assert (stats["window_slots_outside"] > 0) == bool(gathered)
+    assert eng.pool.layers == getattr(cfg, "cache_layers", cfg.num_layers)
+
+
+def test_a_window_slot_is_freed_exactly_once_with_its_lease():
+    """The window store's slots ride the lease that the blocks ride: one a sequence at
+    admission, back once on finish, on a second release nothing more; a cancelled
+    sequence's goes back too, and nothing of a sliding layer is in the block arenas."""
+    cfg = mimo_v2_flash.mimo_v2_flash_nano()
+    eng = LLMEngine(cfg, **{**_WINDOWED, "state_slots": 4})
+    pool = eng.pool
+    assert [a.shape[0] for a in pool.arenas] == [cfg.cache_layers] * 2 == [3, 3]
+    assert [s.shape[:2] for s in pool.states] == [(4, 4), (4, 4)]
+    lease = KVLease(pool)
+    slot = lease.add_slot()
+    lease.add(pool.allocate(2))
+    assert slot != 0 and pool.slots_in_use() == 1 and pool.in_use() == 2
+    lease.release()
+    lease.release()
+    assert pool.slots_in_use() == 0 and pool.in_use() == 0
+    assert sorted(pool._free_slots) == [1, 2, 3]            # once: no slot twice in the list
+    cancel = threading.Event()
+    seqs = [
+        batching._Sequence({"prompt": _prompt(5, 30), "max_new_tokens": 6}),
+        batching._Sequence({"prompt": _prompt(6, 20), "max_new_tokens": 40, "_cancel": cancel})]
+    steps = 0
+    while not all(s.done for s in seqs):
+        if steps == 6:
+            cancel.set()
+        eng.step([s for s in seqs if not s.done])
+        steps += 1
+    assert seqs[0]._error is None and seqs[1]._error is not None
+    assert pool.slots_in_use() == 0 and pool.in_use() == 0
+    assert sorted(pool._free_slots) == [1, 2, 3]
+    # a fourth sequence finds no slot and is shed before anything is written
+    waiting = [batching._Sequence({"prompt": _prompt(i, 12), "max_new_tokens": 30}) for i in range(4)]
+    eng.step(waiting)
+    shed = [s for s in waiting if s.done]
+    assert len(shed) == 1 and "state slot" in str(shed[0]._error)
