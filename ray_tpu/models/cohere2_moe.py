@@ -172,7 +172,9 @@ def make_extend_fn(cfg: Cohere2MoeConfig):
     and accumulator, weights cast to the values' type).
 
     Scopes: ``extend.embed``, ``extend.attention``, ``extend.moe.route``,
-    ``extend.moe.experts``, ``extend.moe.shared``, ``extend.logits``.
+    ``extend.moe.experts``, ``extend.moe.shared``, ``extend.logits`` (the last norm and
+    the head, of the rows that are read: ``last=``, ``layers.read_rows``; every row
+    without it).
     """
     dtype = cfg.dtype
     scale = 1.0 / float(np.sqrt(cfg.head_dim))
@@ -234,7 +236,7 @@ def make_extend_fn(cfg: Cohere2MoeConfig):
         return (routed + shared).astype(dtype).reshape(b, tc, d), counters
 
     @jax.jit
-    def extend(params, tokens, lengths, k_cache, v_cache):
+    def extend(params, tokens, lengths, k_cache, v_cache, *, last=None):
         positions, valid = layers.frame(tokens, lengths)
         live = layers.live_keys(positions, valid)
         with jax.named_scope("extend.embed"):
@@ -254,10 +256,14 @@ def make_extend_fn(cfg: Cohere2MoeConfig):
             body, x, (
                 scanned, routing["router"], k_cache, v_cache,
                 jnp.asarray(cfg.sliding_layers), jnp.arange(cfg.num_layers, dtype=jnp.int32)))
+
+        def head(rows):
+            rows = layers.layer_norm(rows, params["ln_f"]["scale"], cfg.norm_eps)
+            return cfg.logit_scale * jnp.dot(
+                rows.astype(dtype), emb.T, preferred_element_type=jnp.float32), rows
+
         with jax.named_scope("extend.logits"):
-            x = layers.layer_norm(x, params["ln_f"]["scale"], cfg.norm_eps)
-            logits = cfg.logit_scale * jnp.dot(
-                x.astype(dtype), emb.T, preferred_element_type=jnp.float32)
+            logits, x = layers.read_rows(x, last, head)
         return logits, x, k_new, v_new, counters.sum(0)
 
     return extend

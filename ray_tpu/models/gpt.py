@@ -543,7 +543,7 @@ def unboxed_params(variables):
 
 
 def make_extend_fn(cfg: GPTConfig):
-    """A jitted ``extend(params, tokens, lengths, k_cache, v_cache)``.
+    """A jitted ``extend(params, tokens, lengths, k_cache, v_cache, *, last=None)``.
 
     ``tokens`` [b, tc] are the next tokens of each lane whose cache already
     holds ``lengths`` [b] tokens; their K/V are written at absolute
@@ -551,11 +551,23 @@ def make_extend_fn(cfg: GPTConfig):
     updated cache under the mask ``key_pos <= query_pos`` (which also
     hides never-written padding — anything past a lane's frontier is
     acausal by construction). Returns ``(logits, hidden, k_new, v_new)``:
-    f32 logits and final-hidden for every fed position (the engine gathers
-    each lane's last *valid* one; hidden feeds LoRA deltas), plus the new
+    f32 logits and final-hidden (hidden feeds LoRA deltas), plus the new
     K/V chunks [layers, b, tc, heads, head_dim] for the caller to page
     back into its block pool. Deterministic given identical shapes, which
     is what makes cached-prefix decode bitwise-equal to uncached decode.
+
+    Which rows get the last norm and the head is the caller's to say, and
+    every architecture's ``extend`` takes it the same way
+    (``layers.read_rows``). Without ``last``: every fed position, logits
+    [b, tc, vocab] and hidden [b, tc, d] (a test that compares all positions
+    with a plain forward; ``scripts/program_digest.py``). With ``last`` [b],
+    as the serve engine always calls it: row ``last[i]`` of lane ``i`` alone
+    (its last *valid* token where the lane emits), picked from the residual
+    stream before the norm, so logits [b, vocab] and hidden [b, d]; ``-1``
+    marks a lane nobody reads, and a chunk in which that is every lane (a
+    prompt's chunks but its last) runs no head: zeros of the two shapes, by a
+    ``cond`` on the operand, in the same one program the shape has. A call
+    of one token a lane (decode) reads each lane's row and has no ``cond``.
 
     Its parts carry the scopes ``extend.embed``, ``extend.attention``,
     ``extend.mlp`` and ``extend.logits``: names in the compiled program's
@@ -608,7 +620,7 @@ def make_extend_fn(cfg: GPTConfig):
         return x + a + _mlp(hidden, p["mlp"]), k, v
 
     @jax.jit
-    def extend(params, tokens, lengths, k_cache, v_cache):
+    def extend(params, tokens, lengths, k_cache, v_cache, *, last=None):
         positions, _ = layers.frame(tokens, lengths)    # padding is computed like a token here
         with jax.named_scope("extend.embed"):
             emb = params["wte"]["embedding"].astype(dtype)
@@ -621,16 +633,21 @@ def make_extend_fn(cfg: GPTConfig):
             return y, (k, v)
 
         x, (k_new, v_new) = jax.lax.scan(body, x, (stacked, k_cache, v_cache))
-        with jax.named_scope("extend.logits"):
-            x = _ln(x, params["ln_f"])
+
+        def head(rows):
+            rows = _ln(rows, params["ln_f"])
             if cfg.tie_embeddings:
                 kernel, bias = emb.T, None
             else:
                 kernel = params["lm_head"]["kernel"].astype(dtype)
                 bias = params["lm_head"]["bias"]
-            logits = (x @ kernel).astype(jnp.float32)
+            logits = (rows @ kernel).astype(jnp.float32)
             if bias is not None:
                 logits = logits + bias.astype(jnp.float32)
+            return logits, rows
+
+        with jax.named_scope("extend.logits"):
+            logits, x = layers.read_rows(x, last, head)
         return logits, x.astype(jnp.float32), k_new, v_new
 
     return extend

@@ -452,7 +452,8 @@ def make_extend_fn(cfg: GraniteMoeHybridConfig):
     state's read and its write; a decode call's is the kernel ``ssm_step`` on
     the chip); ``extend.attention``; ``extend.mlp``, or with experts
     ``extend.moe.route`` (the norm and the router), ``extend.moe.experts`` and
-    ``extend.moe.shared``; ``extend.logits``."""
+    ``extend.moe.shared``; ``extend.logits`` (the last norm and the head, of the rows
+    that are read: ``last=``, ``layers.read_rows``; every row without it)."""
     dtype, f32 = cfg.dtype, jnp.float32
     heads, p_dim, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
     inner, tail = cfg.ssm_inner, cfg.conv_width - 1
@@ -598,7 +599,8 @@ def make_extend_fn(cfg: GraniteMoeHybridConfig):
         return x + (res * out).astype(dtype)
 
     @jax.jit
-    def extend(params, tokens, lengths, k_cache, v_cache, ssm, conv, slots, snap_at, snap_slots):
+    def extend(params, tokens, lengths, k_cache, v_cache, ssm, conv, slots, snap_at, snap_slots, *,
+               last=None):
         tc = tokens.shape[1]
         (positions, valid), fresh = layers.frame(tokens, lengths), lengths == 0
         visible = layers.visible_keys(positions, valid, k_cache.shape[2])
@@ -644,11 +646,15 @@ def make_extend_fn(cfg: GraniteMoeHybridConfig):
         if tc > 1:
             conv = _put(conv, snap_slots, tails[1])
         conv = _put(conv, slots, tails[0])
+
+        def head(rows):
+            rows = _normed(rows, params["ln_f"])
+            return jnp.dot(
+                rows.astype(dtype), params["wte"]["embedding"].astype(dtype).T,
+                preferred_element_type=f32) / cfg.logits_scaling, rows
+
         with jax.named_scope("extend.logits"):
-            x = _normed(x, params["ln_f"])
-            logits = jnp.dot(
-                x.astype(dtype), params["wte"]["embedding"].astype(dtype).T,
-                preferred_element_type=f32) / cfg.logits_scaling
+            logits, x = layers.read_rows(x, last, head)
         counters = cfg.ssm_layers * jnp.stack([
             valid.sum(dtype=jnp.int32), valid.any(1).sum(dtype=jnp.int32)])
         if cfg.router_experts:

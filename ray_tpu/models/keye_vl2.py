@@ -234,7 +234,8 @@ def make_extend_fn(cfg: KeyeVL2Config):
     cache update, the attend) with ``extend.attention.index`` (the indexer's
     projections and scores) and ``extend.attention.select`` (top-k or
     threshold, row gather or mask) inside it, ``extend.moe.route``,
-    ``extend.moe.experts``, ``extend.logits``.
+    ``extend.moe.experts``, ``extend.logits`` (the last norm and the head, of the rows
+    that are read: ``last=``, ``layers.read_rows``; every row without it).
     """
     return _make_extend(cfg, probe=False)
 
@@ -347,7 +348,7 @@ def _make_extend(cfg: KeyeVL2Config, probe: bool):
         return routed.astype(dtype).reshape(b, tc, d), counters
 
     @jax.jit
-    def extend(params, tokens, lengths, k_cache, v_cache, i_cache):
+    def extend(params, tokens, lengths, k_cache, v_cache, i_cache, *, last=None):
         positions, valid = layers.frame(tokens, lengths)
         with jax.named_scope("extend.embed"):
             x = layers.look_up(params["wte"]["embedding"].astype(dtype), tokens)
@@ -371,7 +372,7 @@ def _make_extend(cfg: KeyeVL2Config, probe: bool):
                 scanned, routing["router"], k_cache, v_cache, i_cache,
                 jnp.arange(cfg.num_layers, dtype=jnp.int32)))
         logits, x = layers.rms_head(
-            x, params["ln_f"]["scale"], cfg.norm_eps, params["head"]["kernel"], dtype)
+            x, params["ln_f"]["scale"], cfg.norm_eps, params["head"]["kernel"], dtype, last)
         return (logits, x, k_new, v_new, i_new, counters.sum(0), *selected)
 
     return extend

@@ -293,7 +293,8 @@ def make_extend_fn(cfg: KimiK2Config):
     their norms, ``W_qb``, the rotations and, in the absorbed form, the
     absorption and the un-absorption);
     ``extend.mlp`` (a dense layer's); ``extend.moe.route``, ``extend.moe.experts``,
-    ``extend.moe.shared``; ``extend.logits``.
+    ``extend.moe.shared``; ``extend.logits`` (the last norm and the head, of the rows
+    that are read: ``last=``, ``layers.read_rows``; every row without it).
     """
     dtype, f32 = cfg.dtype, jnp.float32
     rank = cfg.kv_rank
@@ -390,7 +391,7 @@ def make_extend_fn(cfg: KimiK2Config):
         return x, row, ffn(_normed(x, p, "ln_2"))
 
     @jax.jit
-    def extend(params, tokens, lengths, cache):
+    def extend(params, tokens, lengths, cache, *, last=None):
         positions, valid = layers.frame(tokens, lengths)
         reads = (
             layers.visible_keys(positions, valid, cache.shape[2]),
@@ -426,7 +427,7 @@ def make_extend_fn(cfg: KimiK2Config):
         x, (scanned, routed) = jax.lax.scan(
             body, x, (scanned, jnp.arange(cfg.expert_layers, dtype=jnp.int32)))
         logits, x = layers.rms_head(
-            x, params["ln_f"]["scale"], cfg.norm_eps, params["head"]["kernel"], dtype)
+            x, params["ln_f"]["scale"], cfg.norm_eps, params["head"]["kernel"], dtype, last)
         seen = jnp.where(valid, jnp.minimum(positions + 1, cache.shape[2]), 0)
         queries, pairs = valid.sum(dtype=jnp.int32), seen.sum(dtype=jnp.int32)
         if _expands(tokens.shape[1]):

@@ -141,12 +141,53 @@ def gated_mlp(x, wi, wo):
         preferred_element_type=jnp.float32)
 
 
-def rms_head(x, scale, eps, kernel, dtype):
-    """The last norm and an untied head: ``(logits, normed hidden)``, both float32."""
-    with jax.named_scope("extend.logits"):
-        x = rms_norm(x, scale, eps)
+#: copies of a read row that go through the head under :func:`read_rows`' ``cond``: a
+#: sublane tile. A product of one row the TPU compiler turns into a multiply-reduce over
+#: the kernel, and under a conditional it first re-lays out a kernel that the runtime
+#: keeps columns-major (a vocabulary that is no whole number of the chip's 128 lanes:
+#: MiniCPM-SALA's 73,448, GPT-J's 50,400): 600 MB copied, and held, by every call whose
+#: head runs (2.65 ms where all 512 rows took 1.70; ``PERF.md``, PR 57). Eight rows stay
+#: a matmul, which reads the kernel once as it lies (0.82 ms)
+READ_TILE = 8
+
+
+def read_rows(x, last, head):
+    """``head`` (the last norm and the head: ``(logits, hidden)`` of rows ``[b, n, d]``
+    of the residual stream) of the rows of ``x`` [b, tc, d] that are read.
+    Without ``last``: every row, ``[b, tc, ...]`` each (a caller that compares all
+    positions). With ``last`` [b] (the serve engine, always): lane ``i``'s row
+    ``last[i]`` alone, picked before the norm, ``[b, vocab]`` and ``[b, d]``; a negative
+    ``last[i]`` says nobody reads lane ``i``, and where that is every lane of a chunk
+    (a prompt's chunks but its last) ``head`` does not run and both are zeros: decided
+    on the device from the operand, in the one program the shape has. A call of one
+    token a lane is the decode call's: each lane's row is read, whatever ``last`` says,
+    and nothing is decided."""
+    if last is None:
+        return head(x)
+    if x.shape[1] == 1:
+        return tuple(out[:, 0] for out in head(x))
+    rows = jnp.take_along_axis(x, jnp.maximum(last, 0)[:, None, None], axis=1)
+
+    def read(rows):
+        tile = jnp.broadcast_to(rows, (rows.shape[0], READ_TILE, rows.shape[2]))
+        return tuple(out[:, 0] for out in head(tile))
+
+    return jax.lax.cond(
+        (last >= 0).any(), read,
+        lambda rows: tuple(jnp.zeros(o.shape, o.dtype) for o in jax.eval_shape(read, rows)),
+        rows)
+
+
+def rms_head(x, scale, eps, kernel, dtype, last=None):
+    """The last norm and an untied head, of the rows :func:`read_rows` reads:
+    ``(logits, normed hidden)``, both float32."""
+    def head(rows):
+        rows = rms_norm(rows, scale, eps)
         return jnp.dot(
-            x.astype(dtype), kernel.astype(dtype), preferred_element_type=jnp.float32), x
+            rows.astype(dtype), kernel.astype(dtype), preferred_element_type=jnp.float32), rows
+
+    with jax.named_scope("extend.logits"):
+        return read_rows(x, last, head)
 
 
 def normal(key, shape, dtype):

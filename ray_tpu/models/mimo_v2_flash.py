@@ -299,7 +299,9 @@ def make_extend_fn(cfg: MiMoV2FlashConfig):
     :func:`layers.plain_attend`); ``extend.attention.window`` (a sliding layer's
     projections, rotation, the slot's read, the attend under the sink (the same
     kernel with ``sinks``, or densely), the slot's write and the snapshot's);
-    ``extend.moe.route``, ``extend.moe.experts``; ``extend.logits``."""
+    ``extend.moe.route``, ``extend.moe.experts``; ``extend.logits`` (the last norm and
+    the head, of the rows that are read: ``last=``, ``layers.read_rows``; every row
+    without it)."""
     dtype, f32 = cfg.dtype, jnp.float32
     hd, vd, window = cfg.head_dim, cfg.v_dim, cfg.sliding_window
     scale = 1.0 / float(np.sqrt(hd))
@@ -488,7 +490,7 @@ def make_extend_fn(cfg: MiMoV2FlashConfig):
 
     @jax.jit
     def extend(params, tokens, lengths, k_cache, v_cache, k_window, v_window, slots, snap_at,
-               snap_slots):
+               snap_slots, *, last=None):
         positions, valid = layers.frame(tokens, lengths)
         lengths = lengths.astype(jnp.int32)
         where = (slots, snap_at, snap_slots)
@@ -548,7 +550,7 @@ def make_extend_fn(cfg: MiMoV2FlashConfig):
         k_new, v_new = (
             jnp.concatenate([first[None], behind]) for first, behind in zip(first_rows, rows))
         logits, x = layers.rms_head(
-            x, params["ln_f"]["scale"], cfg.norm_eps, params["head"]["kernel"], dtype)
+            x, params["ln_f"]["scale"], cfg.norm_eps, params["head"]["kernel"], dtype, last)
         seen = jnp.where(valid, positions + 1, 0)
         attended = jnp.stack([
             cfg.cache_layers * jnp.minimum(seen, k_cache.shape[2]).sum(dtype=jnp.int32),

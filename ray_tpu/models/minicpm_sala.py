@@ -403,7 +403,8 @@ def make_extend_fn(cfg: MiniCPMSALAConfig):
     read and its writes); ``extend.attention`` (projections, norms, cache update, the
     attend, the gate) with ``extend.attention.index`` (compressing, scoring, pooling)
     and ``extend.attention.select`` (top-k, the mask or the gather) inside it;
-    ``extend.mlp``; ``extend.logits``."""
+    ``extend.mlp``; ``extend.logits`` (the last norm and the head, of the rows that are
+    read: ``last=``, ``layers.read_rows``; every row without it)."""
     return _make_extend(cfg, probe=False)
 
 
@@ -661,7 +662,7 @@ def _make_extend(cfg: MiniCPMSALAConfig, probe: bool):
 
     @jax.jit
     def extend(params, tokens, lengths, k_cache, v_cache, c_cache, states, slots, snap_at,
-               snap_slots):
+               snap_slots, *, last=None):
         (positions, valid), fresh = layers.frame(tokens, lengths), lengths == 0
         lengths = lengths.astype(jnp.int32)
         with jax.named_scope("extend.embed"):
@@ -704,7 +705,7 @@ def _make_extend(cfg: MiniCPMSALAConfig, probe: bool):
                 by_period(c_cache), jnp.arange(cfg.periods, dtype=jnp.int32)))
         news = tuple(n.reshape((cfg.sparse_layers,) + n.shape[2:]) for n in news)
         logits, x = layers.rms_head(
-            x, params["ln_f"]["scale"], cfg.norm_eps, params["head"]["kernel"], dtype)
+            x, params["ln_f"]["scale"], cfg.norm_eps, params["head"]["kernel"], dtype, last)
         logits = logits / (cfg.embed_dim / cfg.dim_model_base)
         counters = jnp.concatenate([
             counted.sum(0), cfg.linear_layers * jnp.stack([

@@ -32,14 +32,19 @@ serving setup from PAPERS.md):
   BackPressureError` *before* anything is written.
 * a device call never moves K/V through the host, and crosses the link
   twice: everything the host decides for the call (tokens, lengths, block
-  table, page-back slots) goes up as one int32 buffer (:func:`_sections`);
-  one jitted program gathers the padded caches ``extend`` takes from the
-  arenas, ``extend`` runs, and one donating program pages the new rows back
-  into the arenas, picks each lane's last valid row of logits and hidden
-  and takes the argmax of the logits row: the sampled ids (and an expert
-  layer's counters) are one int32 array, which is all that comes home. The
-  picked rows follow only for a lane that asked for its logits or has an
-  adapter. Every such program is compiled when the engine is built.
+  table, page-back slots, the row of logits each lane reads) goes up as one
+  int32 buffer (:func:`_sections`); one jitted program gathers the padded
+  caches ``extend`` takes from the arenas; ``extend`` runs, and makes the last
+  norm, the head and the float32 hidden row for **the one row a lane that is
+  read** (``_LAST``: a lane's last valid token where the lane emits; a chunk in
+  which no lane does, as a prompt's chunks but its last, runs no head at all:
+  ``models/layers.read_rows``), so it hands back ``[lanes, vocabulary]`` and
+  ``[lanes, d]`` whatever the tokens; and one donating program pages the new
+  rows back into the arenas and takes the argmax of each lane's row: the
+  sampled ids (and an expert layer's counters) are one int32 array, which is
+  all that comes home. The rows follow only for a lane that asked for its
+  logits or has an adapter. Every such program is compiled when the engine is
+  built.
 * every program under a name of its own — JAX names a compiled module after
   the function it was traced from, and an instruction's name is unique in its
   module only, so one ``jax.jit`` run in sixteen shapes is sixteen modules a
@@ -100,7 +105,8 @@ serving setup from PAPERS.md):
 * a record per device call — ``llm.dispatch`` says what the call is (its
   number, ``prefill`` or ``decode``, the ``program`` it runs as: the name of
   the module the device then shows, its lanes, tokens and cache tokens
-  beside the slots of its padded shape, whether a call was in flight) and
+  beside the slots of its padded shape, ``heads``: the lanes whose row of
+  logits is read, 0 where the head did not run; whether a call was in flight) and
   ``llm.fetch`` which call it lands and what ``extend`` counted in it; always
   on, ``stats()["calls"]`` sums the same per form of call, with the time the
   device spent on each (``busy_s``), and ``stats()["programs"]`` the calls and
@@ -163,7 +169,9 @@ def make_params(cfg=None, seed: int = 0):
 
 
 #: A device call's small operands, one int32 buffer ``[lanes, width]``: a lane's
-#: row holds its cache length, the index of its last fed token, (lane 0 alone)
+#: row holds its cache length, the index of the fed token whose row of logits
+#: is read (its last, where the lane emits; -1 where nobody reads one: a lane
+#: whose prompt goes on, a padded lane), (lane 0 alone)
 #: the count of tokens to page back and where its first token is (its lane in
 #: the call before, whose ids are still on the device; -1: the host knew it and
 #: it stands in the buffer), then four sections of one width (:func:`_sections`).
@@ -286,12 +294,11 @@ def _paging_programs():
 
     @functools.partial(accelerator.Programs, donate_argnums=0, static_argnums=5)
     @jax.named_scope("paging.page_back")
-    def page_back(arenas, news, operands, outputs, counted, width):
+    def page_back(arenas, news, operands, logits, counted, width):
         layers, blocks, block = arenas[0].shape[:3]
         b, tc = news[0].shape[1:3]
         news = tuple(x.reshape((layers, b * x.shape[2]) + x.shape[3:]) for x in news)
         rows, slots = (x[:, :tc].reshape(-1) for x in _sections(operands)[1:3])
-        last = operands[:, _LAST]
         # tokens a row of each arena: 1, but for one kept at a coarser grain
         grains = tuple(block // a.shape[2] for a in arenas)
         narrow = tuple(lies_tokens_last(a) and grain == 1 for a, grain in zip(arenas, grains))
@@ -342,21 +349,16 @@ def _paging_programs():
             a.transpose(0, 1, 3, 4, 2) if lies
             else a.reshape((layers, blocks * a.shape[2]) + a.shape[3:])
             for a, lies in zip(arenas, narrow)))
-        picked = tuple(
-            jnp.stack([
-                jax.lax.dynamic_index_in_dim(o[i], last[i], 0, keepdims=False)
-                for i in range(b)
-            ]) for o in outputs)
         with jax.named_scope("paging.sample"):
             # greedy, as ``np.argmax`` is: the first of equal maxima
-            ids = jnp.argmax(picked[0], axis=-1).astype(jnp.int32)
+            ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             # the next call reads a lane's token here too: the ids have one
             # width whatever the lanes, so ``extend`` has no program more for it
             home = jnp.concatenate([
                 jnp.pad(ids, (0, width - b)), *(c.astype(jnp.int32) for c in counted)])
         return tuple(
             w.transpose(0, 1, 4, 2, 3) if lies else w.reshape(a.shape)
-            for w, a, lies in zip(written, arenas, narrow)), home, picked
+            for w, a, lies in zip(written, arenas, narrow)), home
 
     @functools.partial(jax.jit, donate_argnums=0)
     @jax.named_scope("paging.clone")
@@ -509,24 +511,25 @@ class KVBlockPool:
         return _paging_programs().gather(
             f"gather_{operands.shape[0]}x{n * self.block_size}", self.arenas, operands, n)
 
-    def page_back(self, news, operands, outputs, counted, width: int):
+    def page_back(self, news, operands, logits, counted, width: int):
         """Write token ``rows[i]`` (an index into lanes x tokens) of each of
         ``news`` ``[layers, b, tc, heads, dim]`` (one per arena) into its arena at
         token slot ``slots[i]`` (block x block_size + offset) for the first
         ``count`` entries of the ``rows`` / ``slots`` sections of ``operands``
         (of an arena at a coarser grain, ``[layers, b, ceil(tc / grain), heads,
         dim]``: the row a token ends, where it ends one, into slot ``slots[i] //
-        grain``) and, in the same program, pick row ``last[i]`` of lane ``i`` from each
-        of ``outputs`` ``[b, tc, ...]`` (logits first) and sample it. Returns
+        grain``) and, in the same program, sample each lane's row of ``logits``
+        ``[b, vocab]``: the one row a lane ``extend`` made, the row the lane reads
+        (zeros for a lane that reads none: its id is never read either). Returns
         one int32 array, for the host and for the next call to read on the
         device (the ``b`` greedy ids padded to ``width``, then the int32
-        arrays of ``counted``), and the picked rows: all on the device. The
-        arenas are donated: nothing is copied but the new rows."""
-        lanes, tokens = outputs[0].shape[:2]
-        self.arenas, home, picked = _paging_programs().page_back(
+        arrays of ``counted``), on the device. The arenas are donated: nothing
+        is copied but the new rows."""
+        lanes, tokens = news[0].shape[1:3]
+        self.arenas, home = _paging_programs().page_back(
             f"page_back_{lanes}x{tokens}",
-            self.arenas, tuple(news), operands, outputs, tuple(counted), width)
-        return home, picked
+            self.arenas, tuple(news), operands, logits, tuple(counted), width)
+        return home
 
     def clone_block(self, src: int, dst: int) -> None:
         """Copy block ``src`` onto block ``dst``, on the device."""
@@ -566,11 +569,12 @@ class KVBlockPool:
             for cap in cache_buckets:
                 jax.block_until_ready(
                     self.gather(operands, cap // self.block_size))
-        for (b, tc), (logits, hidden, *rest) in extend_shapes.items():
+        for (b, tc), (logits, _, *rest) in extend_shapes.items():
             news, _, counted = self.split_outputs(rest)
             operands = jnp.zeros((b, width), jnp.int32)
             jax.block_until_ready(self.page_back(
-                zeros(news), operands, zeros((logits, hidden)), zeros(counted), lanes))
+                zeros(news), operands, jnp.zeros(logits.shape, logits.dtype), zeros(counted),
+                lanes))
         self.clone_block(0, 0)
         if self.states:
             self.copy_state(0, 0)
@@ -936,7 +940,11 @@ def _operand_extend(extend, caches: int = 0, states: int = 0):
     with the program's name first, which the engine makes of those three sizes
     (:func:`_extend_name`), so a profile's module line, the ``PjitFunction``
     event on the host and the engine's record of the call (``llm.dispatch``'s
-    ``program``, ``stats()["programs"]``) are one string. Where the model keeps
+    ``program``, ``stats()["programs"]``) are one string. ``extend`` is told which
+    row of each lane is read (``last``, the buffer's ``_LAST`` column) and returns
+    logits ``[lanes, vocabulary]`` and hidden rows ``[lanes, d]`` for those rows
+    alone, zeros from a chunk in which no lane reads one (decided on the device:
+    still one program a shape). Where the model keeps
     state per sequence, the pool's ``states`` arenas follow the ``caches``
     (donated: the ones ``extend`` returns take their place) and ``extend`` is told
     each lane's slot in them and where to keep a state for the prefix cache
@@ -953,7 +961,8 @@ def _operand_extend(extend, caches: int = 0, states: int = 0):
         where = tuple(
             operands[:, at] for at in (_SLOT, _SNAP_AT, _SNAP_SLOT)) if states else ()
         return extend(
-            params, tokens.at[:, 0].set(first), operands[:, _LENGTH], *arrays, *where)
+            params, tokens.at[:, 0].set(first), operands[:, _LENGTH], *arrays, *where,
+            last=operands[:, _LAST])
 
     return extend_call
 
@@ -989,8 +998,9 @@ class _SeqState:
 
 class _Call:
     """A device call between its launch and its landing: what it left on the
-    device (``home``, for the host and for the next call, and the ``picked``
-    rows; both gone once it has landed), its ``lanes`` as ``(sequence,
+    device (``home``, for the host and for the next call, and ``picked``: the
+    row of logits and of hidden a lane that ``extend`` made; both gone once it
+    has landed), its ``lanes`` as ``(sequence,
     state)`` and which of them sample a token (``emits``). And its record:
     its number ``seq`` among the engine's calls, its ``form`` (``prefill`` or
     ``decode``), its padded ``shape`` ``(lanes, tokens, cache)`` and the name of
@@ -1166,7 +1176,8 @@ class LLMEngine:
         self.state_restores = 0
         self.state_bytes_moved = 0
         #: per form of call, over the device calls: how many; their real lanes
-        #: and their lane buckets; the tokens fed and lanes x token bucket; the
+        #: and their lane buckets; the lanes whose row of logits was read
+        #: (``heads``); the tokens fed and lanes x token bucket; the
         #: live tokens gathered into padded caches and lanes x cache bucket;
         #: and ``busy_s``, a call's landing less the later of its own launch's
         #: return and the landing before it: what the device spent on it as
@@ -1175,7 +1186,7 @@ class LLMEngine:
         #: the properties below (``lanes_used`` ... ``decode_tokens``).
         self.calls: Dict[str, Dict[str, Any]] = {
             form: dict(
-                n=0, lanes_used=0, lane_slots=0, tokens=0, token_slots=0,
+                n=0, lanes_used=0, lane_slots=0, heads=0, tokens=0, token_slots=0,
                 cache_tokens=0, cache_slots=0, busy_s=0.0)
             for form in FORMS}
         #: the same ``n`` and ``busy_s`` per program, by its name (the module a
@@ -1648,12 +1659,13 @@ class LLMEngine:
     def _launch(self, lanes, chunks, tc: int, emits) -> Optional[_Call]:
         """Launch one device call and land the one before it. Each lane
         ``(sequence, state)`` is fed its chunk over its paged cache (``None``:
-        its last token, wherever that is), the new K/V is paged back and each
-        lane's last valid row sampled, all on the device: one buffer goes up
-        and nothing here waits for the device. The call is what is in flight
-        from here on; its lanes' ``pos``, ``length`` and ``sent`` and the
-        engine's token counts count it at once. None where the call before had
-        to land first and ended every lane."""
+        its last token, wherever that is), the new K/V is paged back and the
+        last valid row of each lane that ``emits`` made into logits and
+        sampled (``heads`` of the call's record: how many such lanes), all on
+        the device: one buffer goes up and nothing here waits for the device.
+        The call is what is in flight from here on; its lanes' ``pos``,
+        ``length`` and ``sent`` and the engine's token counts count it at once.
+        None where the call before had to land first and ended every lane."""
         import jax
 
         bs, flight = self.block_size, self._flight
@@ -1707,7 +1719,7 @@ class LLMEngine:
             # a negative id is padding: a model may skip it (an expert
             # layer does), and none may let it change a real token
             tokens[:, :tc] = -1
-            operands[:, _FROM] = -1
+            operands[:, _FROM] = operands[:, _LAST] = -1
             operands[:len(states), _FROM] = sources
             self.tokens_fed_on_device += sum(lane >= 0 for lane in sources)
             # page-back: token rows[i] of the call goes to arena slot slots[i]
@@ -1718,7 +1730,8 @@ class LLMEngine:
                 n = len(ch)
                 tokens[i, :n] = ch
                 operands[i, _LENGTH] = st.length
-                operands[i, _LAST] = n - 1
+                if emits[i]:
+                    operands[i, _LAST] = n - 1
                 blocks = np.asarray(
                     st.blocks[:math.ceil((st.length + n) / bs)], np.int32)
                 table[i, :len(blocks)] = blocks
@@ -1749,8 +1762,8 @@ class LLMEngine:
                     max(0, st.length - self._window + 1) for st in states)
         # what the call is: on its span, and summed under its form
         what = dict(
-            lanes=len(states), lane_slots=b, tokens=fed, token_slots=b * tc,
-            cache_tokens=cached, cache_slots=b * t_cap)
+            lanes=len(states), lane_slots=b, heads=sum(emits), tokens=fed,
+            token_slots=b * tc, cache_tokens=cached, cache_slots=b * t_cap)
         # a program nothing has run yet (a shape the warm-up left out, an engine
         # that was never warmed): this call traces and compiles it, and says so
         cold = program not in self.programs
@@ -1778,11 +1791,11 @@ class LLMEngine:
                 counts[key] += n
             self.calls_ahead += flight is not None
         with self._phase("kv_scatter"):
-            home, picked = self.pool.page_back(
-                news, operands, (logits, hidden), counted, self.lane_buckets[-1])
-            del logits, hidden, news, operands
+            home = self.pool.page_back(
+                news, operands, logits, counted, self.lane_buckets[-1])
+            del news, operands
             call = _Call(
-                home, picked, lanes, emits, seq=seq, form=form, shape=(b, tc, t_cap),
+                home, (logits, hidden), lanes, emits, seq=seq, form=form, shape=(b, tc, t_cap),
                 program=program, step=self.steps, launched_at=self._now())
             for i, (st, ch, emit) in enumerate(zip(states, chunks, emits)):
                 st.call, st.lane = call, i

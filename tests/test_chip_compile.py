@@ -64,6 +64,22 @@ def _gptj(depth):
     return gpt.gpt_j_6b(num_layers=depth, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
 
 
+def _all_rows_bytes(cfg, lanes, tc):
+    """The float32 logits and hidden rows of every fed position, which a chunk's
+    ``extend`` handed back until PR 57. The compiler kept a chunk's temporaries in
+    that buffer while it was free and counted them as output, so a bound on a
+    prefill program's temporaries taken from the all-rows form has these in it."""
+    return 4 * lanes * tc * (cfg.vocab_size + cfg.embed_dim) if tc > 1 else 0
+
+
+def _holds_no_more_than_stated(memory, stated):
+    """Temporaries and outputs together against a configuration file's figures (the
+    all-rows form's: ``_all_rows_bytes``), the temporaries with the 5 % a compiler's
+    release may add."""
+    return (memory.temp_size_in_bytes + memory.output_size_in_bytes
+            <= stated["temp"] * 1.05 + stated["output"])
+
+
 def _device_bytes(compiled):
     m = compiled.memory_analysis()
     return (
@@ -537,7 +553,8 @@ def test_command_a_plus_share_extend_compiles_and_copies_no_expert(
     memory = compiled.memory_analysis()
     assert 9.4e9 < memory.argument_size_in_bytes < 10.6e9
     assert memory.temp_size_in_bytes < 1.0e9
-    assert memory.temp_size_in_bytes <= parent_temp
+    # a chunk's bound is the all-rows form's
+    assert memory.temp_size_in_bytes <= parent_temp + _all_rows_bytes(cfg, lanes, tc)
     assert _device_bytes(compiled) + 2 * 0.41e9 < HBM_BYTES
 
 
@@ -569,9 +586,7 @@ def test_gptj_serve_paging_programs_compile_beside_the_weights(shaped):
         new = shaped((cfg.num_layers, b, tc, cfg.num_heads, cfg.head_dim), cfg.dtype)
         return programs.page_back.lower(
             f"page_back_{b}x{tc}", (arena, arena), (new, new), shaped((b, width), jnp.int32),
-            (shaped((b, tc, cfg.vocab_size), jnp.float32),
-             shaped((b, tc, cfg.embed_dim), jnp.float32)),
-            (), lanes[-1],
+            shaped((b, cfg.vocab_size), jnp.float32), (), lanes[-1],
         ).compile()
 
     for b in lanes:
@@ -584,9 +599,8 @@ def test_gptj_serve_paging_programs_compile_beside_the_weights(shaped):
             assert memory.alias_size_in_bytes == 2 * arena_bytes, (b, tc)
             assert memory.temp_size_in_bytes < 2**20, (b, tc)
             # what it returns beside the arenas: the ids, as wide as the widest
-            # lane bucket (the next call reads them too), and the picked rows
-            rows_bytes = 4 * (lanes[-1] + b * (cfg.vocab_size + cfg.embed_dim))
-            assert 0 <= memory.output_size_in_bytes - 2 * arena_bytes - rows_bytes < 4096
+            # lane bucket (the next call reads them too); it picks no row
+            assert 0 <= memory.output_size_in_bytes - 2 * arena_bytes - 4 * lanes[-1] < 4096
     clone = programs.clone.lower(
         (arena, arena), shaped((), jnp.int32), shaped((), jnp.int32)).compile()
     assert clone.memory_analysis().alias_size_in_bytes == 2 * arena_bytes
@@ -614,6 +628,34 @@ def _keye_stage():
     return keye_vl2.KeyeVL2Config(num_layers=config["num_hidden_layers"]), config
 
 
+_KEYE_COMPILED = {}
+
+
+def _keye_extend_at_its_largest(shaped, built_for_tpu, form):
+    """``(compiled, b, tc, cap)``: the stage's ``extend`` as a step calls it, in its
+    largest decode or prefill shape; compiled once a form for the tests below."""
+    built_for_tpu(True)     # the chip's grouped matmul
+    cfg, config = _keye_stage()
+    engine = config["engine"]
+    cap, lanes = engine["cache_buckets"][-1], engine["lane_buckets"][-1]
+    b, tc = (lanes, 1) if form == "decode" else (1, engine["prefill_token_buckets"][-1])
+    if form not in _KEYE_COMPILED:
+        params = jax.tree.map(
+            lambda x: shaped(x.shape, x.dtype), jax.eval_shape(lambda: cfg.init_params(0)))
+        caches = [
+            shaped((cfg.num_layers, b, cap) + tuple(each), cfg.dtype)
+            for each in cfg.cache_arrays]
+        operands = shaped(
+            (b, llm._operand_width(
+                engine["prefill_token_buckets"][-1], cap // engine["block_size"])),
+            jnp.int32)
+        _KEYE_COMPILED[form] = llm._operand_extend(cfg.make_extend_fn()).lower(
+            llm._extend_name(b, tc, cap), params, operands,
+            shaped((lanes + len(cfg.counters),), jnp.int32), *caches, tc=tc
+        ).compile()
+    return _KEYE_COMPILED[form], b, tc, cap
+
+
 @pytest.mark.parametrize("form", ["decode", "prefill"])
 def test_keye_vl2_stage_extend_compiles_at_its_largest_shapes(shaped, form, built_for_tpu):
     """One pipeline stage of Keye-VL-2.0's language model at its published
@@ -621,22 +663,10 @@ def test_keye_vl2_stage_extend_compiles_at_its_largest_shapes(shaped, form, buil
     over the largest cache bucket, in both forms of the selection: it fits
     beside the pool and a second call's caches, copies no layer's experts
     (1.2 GB) and holds the memory the configuration's file states."""
-    built_for_tpu(True)     # the chip's grouped matmul
+    compiled, b, tc, cap = _keye_extend_at_its_largest(shaped, built_for_tpu, form)
     cfg, config = _keye_stage()
     engine, stated = config["engine"], config["compiled_bytes_per_device"]
-    cap, lanes = engine["cache_buckets"][-1], engine["lane_buckets"][-1]
-    b, tc = (lanes, 1) if form == "decode" else (1, engine["prefill_token_buckets"][-1])
-    params = jax.tree.map(
-        lambda x: shaped(x.shape, x.dtype), jax.eval_shape(lambda: cfg.init_params(0)))
-    caches = [
-        shaped((cfg.num_layers, b, cap) + tuple(each), cfg.dtype) for each in cfg.cache_arrays]
-    operands = shaped(
-        (b, llm._operand_width(engine["prefill_token_buckets"][-1], cap // engine["block_size"])),
-        jnp.int32)
-    compiled = llm._operand_extend(cfg.make_extend_fn()).lower(
-        llm._extend_name(b, tc, cap), params, operands,
-        shaped((lanes + len(cfg.counters),), jnp.int32), *caches, tc=tc
-    ).compile()
+    lanes = engine["lane_buckets"][-1]
     _experts_kernels_and_a_chunks_attend(compiled.as_text(), cfg, b, tc, cap)
     memory = compiled.memory_analysis()
     per_token = 2 * cfg.num_layers * sum(h * d for h, d in cfg.cache_arrays)
@@ -645,11 +675,43 @@ def test_keye_vl2_stage_extend_compiles_at_its_largest_shapes(shaped, form, buil
     assert 8.7e9 < weights < 8.8e9
     assert memory.temp_size_in_bytes < 0.6e9
     assert memory.argument_size_in_bytes == stated[form]["argument"]
-    # the parent's, from the file: without the dense attend's scores a chunk holds less
-    assert memory.temp_size_in_bytes <= stated[form]["temp"] * 1.05
+    # the file's, which state the all-rows form (0.2 GB of a chunk's temporaries
+    # lay in the logits' buffer there)
+    assert _holds_no_more_than_stated(memory, stated[form])
     # beside the pool and the caches of the call in flight
     pool = per_token * engine["num_blocks"] * engine["block_size"]
     assert _device_bytes(compiled) + pool + per_token * lanes * cap < HBM_BYTES
+
+
+def test_keye_vl2_prefill_makes_logits_for_the_row_it_reads_and_decode_is_as_it_was(
+        shaped, built_for_tpu):
+    """The engine's ``(1, 512, 32768)`` prefill program makes the head's product for
+    the one row ``last`` names (PR 57): no float32 value of ``[lanes, tokens,
+    vocabulary]`` anywhere in it, and what it hands back is smaller than the
+    all-rows form's (the file's) by the 311 MB of those logits at least; the head's
+    kernel goes into the ``cond`` as the parameter it is, uncopied. The ``(4, 1,
+    32768)`` decode program, which has no ``cond`` at its head, holds no more
+    temporaries than the file states."""
+    cfg, config = _keye_stage()
+    stated = config["compiled_bytes_per_device"]
+    prefill, b, tc, _ = _keye_extend_at_its_largest(shaped, built_for_tpu, "prefill")
+    text = prefill.as_text()
+    assert f"f32[{b},{tc},{cfg.vocab_size}]" not in text
+    assert f"f32[{b},{cfg.vocab_size}]" in text
+    all_rows_logits = 4 * b * tc * cfg.vocab_size
+    assert all_rows_logits == 311_164_928
+    assert prefill.memory_analysis().output_size_in_bytes <= (
+        stated["prefill"]["output"] - all_rows_logits)
+    (head,) = [
+        line for line in text.splitlines()
+        if " conditional(" in line and "extend.logits/cond" in line]
+    kernel = f"bf16[{cfg.embed_dim},{cfg.vocab_size}]"
+    assert not [
+        line for line in text.splitlines()
+        if re.search(rf"= {re.escape(kernel)}\S* (copy|copy-start|transpose)\(", line)], head
+    decode, *_ = _keye_extend_at_its_largest(shaped, built_for_tpu, "decode")
+    assert "extend.logits/cond" not in decode.as_text()
+    assert decode.memory_analysis().temp_size_in_bytes <= stated["decode"]["temp"]
 
 
 def test_three_arena_paging_programs_compile_without_a_whole_arena_temporary(shaped):
@@ -687,8 +749,7 @@ def test_three_arena_paging_programs_compile_without_a_whole_arena_temporary(sha
             shaped((cfg.num_layers, b, tc) + tuple(each), cfg.dtype) for each in cfg.cache_arrays)
         memory = programs.page_back.lower(
             f"page_back_{b}x{tc}", arenas, news, shaped((b, width), jnp.int32),
-            (shaped((b, tc, cfg.vocab_size), jnp.float32),
-             shaped((b, tc, cfg.embed_dim), jnp.float32)),
+            shaped((b, cfg.vocab_size), jnp.float32),
             (shaped((len(cfg.counters),), jnp.int32),), lanes,
         ).compile().memory_analysis()
         assert memory.alias_size_in_bytes == arena_bytes, (b, tc)
@@ -852,8 +913,7 @@ def test_an_arena_of_latent_rows_pages_without_a_whole_arena_temporary(shaped):
         news = (shaped((cfg.num_layers, b, tc, 1, cfg.row_dim), cfg.dtype),)
         memory = programs.page_back.lower(
             f"page_back_{b}x{tc}", arenas, news, shaped((b, width), jnp.int32),
-            (shaped((b, tc, cfg.vocab_size), jnp.float32),
-             shaped((b, tc, cfg.embed_dim), jnp.float32)),
+            shaped((b, cfg.vocab_size), jnp.float32),
             (shaped((len(cfg.counters),), jnp.int32),), lanes,
         ).compile().memory_analysis()
         assert memory.alias_size_in_bytes == arena_bytes, (b, tc)
@@ -1021,10 +1081,11 @@ def test_granite_small_share_extend_compiles_and_copies_no_layers_experts(
         cap * per_token + 2**17)
     assert 9.51e9 < weights < 9.52e9
     assert memory.argument_size_in_bytes == stated[form]["argument"]
-    assert memory.temp_size_in_bytes <= stated[form]["temp"] * 1.05
+    assert _holds_no_more_than_stated(memory, stated[form])
     # no copy of a layer's experts (a decode call's are its K and V, re-laid out by K/V
-    # head, a chunk's its float32 logits and its pairs' rows)
-    assert memory.temp_size_in_bytes < GRANITE_SMALL_LAYER_EXPERTS_BYTES / 2
+    # head, a chunk's its pairs' rows and what lay in the all-rows logits' buffer)
+    assert memory.temp_size_in_bytes < (
+        GRANITE_SMALL_LAYER_EXPERTS_BYTES / 2 + _all_rows_bytes(cfg, b, tc))
     resident = engine["num_blocks"] * engine["block_size"] * per_token
     assert _device_bytes(compiled) + resident + lanes * cap * per_token < HBM_BYTES
 
@@ -1135,7 +1196,7 @@ def test_minicpm_sala_stage_extend_compiles_and_copies_no_arena_and_no_layer(
         cap * per_token + 2**17)
     assert 10.07e9 < weights < 10.09e9
     assert memory.argument_size_in_bytes == stated[form]["argument"]
-    assert memory.temp_size_in_bytes <= stated[form]["temp"] * 1.05
+    assert _holds_no_more_than_stated(memory, stated[form])
     # no copy of the state arena, of a lane's share of it times the lanes, or of a layer
     assert memory.temp_size_in_bytes < MINICPM_SALA_LAYER_BYTES / 2
     assert memory.temp_size_in_bytes < arena_bytes / 8
@@ -1163,10 +1224,10 @@ def test_a_coarse_arena_pages_without_a_whole_arena_temporary(shaped):
     news = tuple(
         shaped((cfg.cache_layers, b, -(-tc // llm.cache_grain(each))) + each[:2], cfg.dtype)
         for each in cfg.cache_arrays)
-    outputs = (shaped((b, tc, cfg.vocab_size), jnp.float32), shaped((b, tc, cfg.embed_dim), jnp.float32))
+    logits = shaped((b, cfg.vocab_size), jnp.float32)
     counted = (shaped((len(cfg.counters),), jnp.int32),)
     back = programs.page_back.lower(
-        f"page_back_{b}x{tc}", arenas, news, operands, outputs, counted, 4
+        f"page_back_{b}x{tc}", arenas, news, operands, logits, counted, 4
     ).compile().memory_analysis()
     assert back.alias_size_in_bytes >= sum(arena_bytes)
     assert back.temp_size_in_bytes < min(arena_bytes) / 2
@@ -1259,7 +1320,7 @@ def test_mimo_v2_flash_share_extend_compiles_and_copies_no_arena_and_no_expert(
         cap * per_token + 2**17)
     assert 6.85e9 < weights < 6.87e9
     assert memory.argument_size_in_bytes == stated[form]["argument"]
-    assert memory.temp_size_in_bytes <= stated[form]["temp"] * 1.05
+    assert _holds_no_more_than_stated(memory, stated[form])
     # no copy of a window arena or of a layer's experts; a decode call's temporaries are
     # its lanes' K and V written into and re-laid out by K/V head, a little over the
     # gathered caches themselves

@@ -420,7 +420,12 @@ def test_paged_device_calls_are_bitwise_a_zero_padded_host_pair(cfg):
             for cache, m in zip(caches, mine):
                 cache[:, i, :st.length] = m
             tokens[i, :len(ch)], lengths[i] = ch, st.length
-        want = [np.asarray(o) for o in eng._extend(eng._params, tokens, lengths, *caches)]
+        # the row the engine says each lane reads: its last, where it emits
+        last = np.full((b,), -1, np.int32)
+        last[:len(states)] = [len(ch) - 1 if emit else -1 for ch, emit in zip(chunks, emits)]
+        want = [
+            np.asarray(o)
+            for o in eng._extend(eng._params, tokens, lengths, *caches, last=last)]
         call = launch(lanes, chunks, tc, emits)
         wanted[id(call)] = (call, states, chunks, want)
         calls.append((len(states), tc, cap, [mirror[id(st)][1][0].shape[1] % eng.block_size for st in states]))
@@ -434,9 +439,9 @@ def test_paged_device_calls_are_bitwise_a_zero_padded_host_pair(cfg):
         for i, (st, ch) in enumerate(zip(states, chunks)):
             n = len(ch)
             tok, logits_row, hidden_row = call.sampled[i]
-            assert np.array_equal(logits_row, logits[i, n - 1])
-            assert np.array_equal(hidden_row, hidden[i, n - 1])
-            assert tok == np.argmax(logits[i, n - 1])
+            assert np.array_equal(logits_row, logits[i])
+            assert np.array_equal(hidden_row, hidden[i])
+            assert tok == np.argmax(logits[i])
             mine = [
                 np.concatenate([m, new[:, i, :n]], axis=1)
                 for m, new in zip(mirror[id(st)][1], news)]

@@ -67,6 +67,9 @@ CALLS = [
 ]
 STEPS, AHEAD, FED_ON_DEVICE = 4, len(CALLS) - 1, 1 + 2 + 2
 PREFILL, DECODE = [CALLS[0], CALLS[2]], [CALLS[1], CALLS[3], CALLS[4]]
+# the lanes of each call whose row of logits is read (``heads``): those that emit. A's
+# prompt ends in the first chunk and B's does not; every decode lane emits
+HEADS = (1, 1, 2, 3, 2)
 # the step that launches each call, and the one that lands it
 LAUNCHED_IN, LANDED_IN = (1, 1, 2, 2, 3), (1, 2, 2, 3, 4)
 
@@ -75,7 +78,8 @@ def _by_form(calls, tokens):
     """``stats()["calls"][form]`` less ``busy_s`` for these ``CALLS``, which fed ``tokens``."""
     return {
         "n": len(calls), "lanes_used": sum(c[0] for c in calls),
-        "lane_slots": sum(c[1] for c in calls), "tokens": tokens,
+        "lane_slots": sum(c[1] for c in calls),
+        "heads": sum(HEADS[CALLS.index(c)] for c in calls), "tokens": tokens,
         "token_slots": sum(b * tc for _, b, tc, _, _ in calls),
         "cache_tokens": sum(c[4] for c in calls),
         "cache_slots": sum(b * cap for _, b, _, cap, _ in calls),
@@ -469,12 +473,12 @@ def test_dispatch_and_fetch_say_which_call_and_what_it_is(xplane, planes):
     assert [what for _, _, what in dispatched] == [
         {
             "call": first + i, "form": "prefill" if call in PREFILL else "decode",
-            "lanes": call[0], "lane_slots": call[1], "tokens": tokens,
+            "lanes": call[0], "lane_slots": call[1], "heads": heads, "tokens": tokens,
             "token_slots": call[1] * call[2], "cache_tokens": call[4],
             "cache_slots": call[1] * call[3], "ahead": int(i > 0),
             "program": llm._extend_name(*call[1:4]),
         }
-        for i, (call, tokens) in enumerate(zip(CALLS, (20 + 32, 1, 8 + 9, 3, 2)))
+        for i, (call, tokens, heads) in enumerate(zip(CALLS, (20 + 32, 1, 8 + 9, 3, 2), HEADS))
     ]
     # a landing names the call it lands and nothing else (``gpt_nano`` counts nothing)
     assert [what for _, _, what in fetched] == [{"call": first + i} for i in range(len(CALLS))]
@@ -485,6 +489,25 @@ def test_dispatch_and_fetch_say_which_call_and_what_it_is(xplane, planes):
     assert tuple(map(step_of, dispatched)) == LAUNCHED_IN
     assert tuple(map(step_of, fetched)) == LANDED_IN
     assert sum(a != b for a, b in zip(LAUNCHED_IN, LANDED_IN)) == STEPS - 1
+
+
+def test_heads_says_how_many_lanes_of_a_call_have_their_row_of_logits_read(tmp_path):
+    """A prompt of 70 tokens alone (three chunks of 32, 32 and 6) and 3 new tokens,
+    recorded: ``heads`` is on every ``llm.dispatch`` span, 0 for the prompt's chunks
+    but its last (no lane emits: the head does not run), the emitting lanes
+    otherwise, and ``stats()["calls"]`` sums it a form, in ``traced`` too."""
+    engine = llm.LLMEngine(NANO, **ENGINE)      # the module's keeps its session's record
+    before = engine.stats()
+    with _session(tmp_path):
+        _drive(engine, _requests((70,)))
+    after = engine.stats()
+    dispatched = [what for _, _, what in _recorded(_xplane_of(tmp_path), "llm.dispatch")]
+    assert [(what["form"], what["lanes"], what["heads"]) for what in dispatched] == [
+        ("prefill", 1, 0), ("prefill", 1, 0), ("prefill", 1, 1), ("decode", 1, 1),
+        ("decode", 1, 1)]
+    for work in (_delta(after, before, "calls"), _delta(after["traced"], before["traced"], "calls")):
+        assert (work["prefill"]["n"], work["prefill"]["heads"]) == (3, 1)
+        assert work["decode"]["heads"] == work["decode"]["lanes_used"] == NEW - 1
 
 
 # -- (c'') every program under a name of its own, and the call's record says which
@@ -717,7 +740,7 @@ def test_phases_cost_less_than_their_budget_outside_a_session(engine, monkeypatc
     monkeypatch.setattr(engine.pool, "gather", lambda operands, n: (None, None))
     monkeypatch.setattr(
         engine.pool, "page_back",
-        lambda news, operands, outputs, counted, width: (ids[width], None))
+        lambda news, operands, logits, counted, width: ids[width])
     monkeypatch.setattr(jax, "device_put", lambda a: a)
     as_it_is, nothing = engine._phase, contextlib.nullcontext()
     asks, never = llm.accelerator.recording, lambda: False

@@ -101,29 +101,25 @@ def test_ids_sampled_on_the_device_are_the_argmax_of_the_rows_a_twin_brings_home
 @pytest.mark.parametrize("tc,counted", [(1, False), (4, True)], ids=["decode", "chunk+counters"])
 def test_a_tie_goes_to_the_first_index_as_on_the_host(engine, tc, counted):
     """Rows with their maximum at two, at three and at every index, through
-    the page-back program itself (it writes no token: the count is 0)."""
+    the page-back program itself (it writes no token: the count is 0), which
+    samples the one row a lane that ``extend`` made."""
     import jax.numpy as jnp
 
     cfg, b = engine.cfg, 4
     rng = np.random.RandomState(5)
-    logits = rng.standard_normal((b, tc, cfg.vocab_size)).astype(np.float32)
-    last = rng.randint(0, tc, b)
-    top = logits.max() + 1.0
-    logits[0, last[0], [7, 3]] = top                    # written 7 first: 3 wins
-    logits[1, last[1], [cfg.vocab_size - 1, 100, 200]] = top
-    logits[2, last[2]] = 0.0                            # all equal: 0 wins
+    rows = rng.standard_normal((b, cfg.vocab_size)).astype(np.float32)
+    top = rows.max() + 1.0
+    rows[0, [7, 3]] = top                               # written 7 first: 3 wins
+    rows[1, [cfg.vocab_size - 1, 100, 200]] = top
+    rows[2] = 0.0                                       # all equal: 0 wins
     operands = np.zeros((b, engine._operand_width), np.int32)
-    operands[:, llm._LAST] = last
     news = [
         jnp.zeros((cfg.num_layers, b, tc) + tuple(each), engine.pool.dtype)
         for each in cfg.cache_arrays]
     counters = (jnp.asarray([5, 6, 7, 8], jnp.int32),) if counted else ()
-    home, picked = engine.pool.page_back(
-        news, jnp.asarray(operands),
-        (jnp.asarray(logits), jnp.zeros((b, tc, cfg.embed_dim), jnp.float32)), counters, b + 3)
-    home, rows = np.asarray(home), np.asarray(picked[0])
+    home = np.asarray(engine.pool.page_back(
+        news, jnp.asarray(operands), jnp.asarray(rows), counters, b + 3))
     assert home.dtype == np.int32
-    assert np.array_equal(rows, logits[np.arange(b), last])
     assert home[:b].tolist() == np.argmax(rows, axis=-1).tolist()
     assert home[:3].tolist() == [3, 100, 0]
     # the next call reads the ids here too: one width, whatever the lanes

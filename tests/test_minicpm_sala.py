@@ -352,15 +352,15 @@ def test_the_pool_keeps_the_compressed_keys_at_their_own_grain(program):
     width = llm._operand_width(8, 4, True)
     operands = np.zeros((1, width), np.int32)
     tokens, rows, slots, table = llm._sections(operands)
-    operands[0, llm._LENGTH], operands[0, llm._LAST], operands[0, llm._COUNT] = 6, 4, 5
+    operands[0, llm._LENGTH], operands[0, llm._COUNT] = 6, 5
     table[0, :2] = [4, 2]
     rows[0, :5] = np.arange(5)
     slots[0, :5] = [4 * 8 + 6, 4 * 8 + 7, 2 * 8 + 0, 2 * 8 + 1, 2 * 8 + 2]
     rng = np.random.default_rng(0)
     news = tuple(
         jnp.asarray(rng.normal(size=(2, 1, n, 1, 32)), jnp.float32) for n in (8, 8, 4))
-    logits = jnp.zeros((1, 8, CFG.vocab_size), jnp.float32)
-    pool.page_back(news, jnp.asarray(operands), (logits, logits[..., :4]), (), 1)
+    pool.page_back(
+        news, jnp.asarray(operands), jnp.zeros((1, CFG.vocab_size), jnp.float32), (), 1)
     k4, v4, c4 = pool.read_block(4)
     k2, _, c2 = pool.read_block(2)
     np.testing.assert_array_equal(k4[:, 6:], np.asarray(news[0])[:, 0, :2])
