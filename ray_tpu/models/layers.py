@@ -1,7 +1,8 @@
 """The kit the architecture files compose (``gpt.py``, ``cohere2_moe.py``,
 ``keye_vl2.py``, ``kimi_k2.py``, ``granitemoehybrid.py``, ``lfm2_moe.py``,
 ``minicpm_sala.py``, ``mimo_v2_flash.py``, whose per-sequence state is made of
-cached rows: a window's K and V): every
+cached rows: a window's K and V; ``qwen3_next.py``, whose norms are zero-centred: it
+hands :func:`rms_norm` the scale ``1 + g``): every
 decision they share, written once. Plain functions of their arguments: no
 configuration, no ``jit`` and no scope of their own but ``extend.logits`` round
 :func:`rms_head`, because the readers of a device trace key on the path of scopes
@@ -44,7 +45,9 @@ def rotary(x: jax.Array, positions: jax.Array, rotary_dim: int,
 
 
 def rms_norm(x, scale, eps):
-    """In float32 whatever comes in, the scale cast: so is :func:`layer_norm`."""
+    """In float32 whatever comes in, the scale cast: so is :func:`layer_norm`. The plain
+    form, ``x / rms(x) * scale``: every architecture file's; a zero-centred norm
+    (``qwen3_next.py``) is this with ``1 + g`` for the scale, made by its caller."""
     xf = x.astype(jnp.float32)
     return xf * jax.lax.rsqrt((xf * xf).mean(-1, keepdims=True) + eps) * scale.astype(jnp.float32)
 

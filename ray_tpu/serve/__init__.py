@@ -99,6 +99,23 @@ class Deployment:
         return Application(self, init_args, init_kwargs)
 
 
+#: a replica's executing slots where neither the deployment nor its callable says
+MAX_CONCURRENT_QUERIES = 8
+
+
+def _executing_slots(dep: Deployment, init_args, init_kwargs) -> int:
+    """A replica's executing slots: ``max_concurrent_queries`` where the
+    deployment names it; else ``MAX_CONCURRENT_QUERIES``, or what its callable,
+    bound with these arguments, says it runs at once (``concurrent_queries``)
+    where that is more."""
+    asked = dep.config.get("max_concurrent_queries")
+    if asked is not None:
+        return int(asked)
+    own = getattr(dep.func_or_class, "concurrent_queries", None)
+    return max(
+        MAX_CONCURRENT_QUERIES, int(own(*init_args, **init_kwargs)) if own is not None else 0)
+
+
 class Application:
     def __init__(self, deployment_obj: Deployment, init_args, init_kwargs):
         self.deployment = deployment_obj
@@ -133,7 +150,7 @@ def deployment(
     user_config: Any = None,
     autoscaling_config: Optional[Dict[str, Any]] = None,
     ray_actor_options: Optional[Dict[str, Any]] = None,
-    max_concurrent_queries: int = 8,
+    max_concurrent_queries: Optional[int] = None,
     max_queued_requests: Optional[int] = None,
     drain_grace_s: float = 30.0,
     slo_p99_s: Optional[float] = None,
@@ -143,7 +160,11 @@ def deployment(
     """``@serve.deployment`` decorator (reference: serve/api.py deployment).
 
     ``max_concurrent_queries`` is the per-replica executing-slot count
-    (the replica actor's concurrency); ``max_queued_requests`` bounds the
+    (the replica actor's concurrency). Left out, it is ``MAX_CONCURRENT_QUERIES``
+    (8), or what the callable says it runs at once as it is bound where that is
+    more (``concurrent_queries(*init_args, **init_kwargs)``, a static method:
+    ``serve.llm.LLMServer`` answers with its engine's largest lane bucket).
+    ``max_queued_requests`` bounds the
     admission queue beyond those slots — excess requests shed with
     :class:`BackPressureError` (503 + Retry-After at the proxy). ``None``
     defaults the queue allowance to one full round of executing slots.
@@ -221,6 +242,7 @@ def _deploy_tree(app: Application, controller, timeout: float,
         "init_args": init_args,
         "init_kwargs": init_kwargs,
         **dep.config,
+        "max_concurrent_queries": _executing_slots(dep, app.init_args, app.init_kwargs),
     }
     ray_tpu.get(controller.deploy.remote(dep_name, spec), timeout=timeout)
     handle = DeploymentHandle(dep_name)
@@ -344,8 +366,8 @@ def build(target, name: Optional[str] = None) -> Dict[str, Any]:
             "user_config": dep.config.get("user_config"),
             "autoscaling_config": dep.config.get("autoscaling"),
             "resources": dep.config.get("resources"),
-            "max_concurrent_queries": dep.config.get(
-                "max_concurrent_queries", 8),
+            "max_concurrent_queries": _executing_slots(
+                dep, app.init_args, app.init_kwargs),
             "max_queued_requests": dep.config.get("max_queued_requests"),
             "drain_grace_s": dep.config.get("drain_grace_s", 30.0),
         })
@@ -393,7 +415,7 @@ def apply(config: Dict[str, Any], *, timeout: float = 60.0) -> DeploymentHandle:
             "user_config": d.get("user_config"),
             "autoscaling": d.get("autoscaling_config"),
             "resources": d.get("resources"),
-            "max_concurrent_queries": d.get("max_concurrent_queries", 8),
+            "max_concurrent_queries": d.get("max_concurrent_queries", MAX_CONCURRENT_QUERIES),
             "max_queued_requests": d.get("max_queued_requests"),
             "drain_grace_s": d.get("drain_grace_s", 30.0),
         }

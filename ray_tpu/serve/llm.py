@@ -11,10 +11,16 @@ where it keeps state per sequence and not per token, ``state_arrays`` names
 that, ``state_chunk`` how often a state can be kept, and ``cache_layers`` in how
 many layers a token is cached (``cached_layers``: in which); ``models/cohere2_moe.py``,
 ``models/keye_vl2.py``, ``models/kimi_k2.py``, ``models/granitemoehybrid.py``,
-``models/minicpm_sala.py``, ``models/mimo_v2_flash.py``). A state need not be a
-recurrence's: ``mimo_v2_flash.py``'s is made of **cached rows**, the newest 128 rows
-of K and V of each layer that sees a window and nothing else, which the engine
-keeps, hands over, snapshots and restores as it does any state, and never pages.
+``models/minicpm_sala.py``, ``models/mimo_v2_flash.py``, ``models/qwen3_next.py``). A
+state need not be a recurrence's: ``mimo_v2_flash.py``'s is made of **cached rows**,
+the newest 128 rows of K and V of each layer that sees a window and nothing else, which
+the engine keeps, hands over, snapshots and restores as it does any state, and never
+pages. Nor need a recurrence be additive: ``qwen3_next.py``'s gated delta rule writes
+what its state does not hold yet for the key, a 2 MB state a layer and sequence beside a
+convolution's tail, through the same slots, snapshots and restores and nothing new in
+this file; its cell is the first to decode sixteen lanes a call (``lane_buckets`` up to
+16 is this engine's own default, ``LANE_BUCKETS``: a deployment takes that many executing
+slots from :meth:`LLMServer.concurrent_queries`).
 
 What PR 9 proved with synthetic step functions (continuous batching,
 admission control, multiplexing) this module composes on an actual model
@@ -130,6 +136,7 @@ import math
 import queue as queue_mod
 import threading
 import time
+import weakref
 from collections import OrderedDict
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
@@ -142,11 +149,26 @@ from ray_tpu.serve.multiplex import _MultiplexWrapper
 
 __all__ = [
     "KVBlockPool", "KVLease", "NoKVBlocksError", "PrefixCache",
-    "LLMEngine", "LLMServer", "make_params", "register_lora", "random_lora",
+    "LLMEngine", "LLMServer", "live_engines", "make_params", "register_lora", "random_lora",
 ]
 
 _STREAM_KEY = "_stream"
 _CANCEL_KEY = "_cancel"
+
+#: the lanes a decode call is padded to where an engine is given no buckets
+LANE_BUCKETS = (1, 2, 4, 8, 16)
+#: the sequences one ``LLMServer.generate`` step is handed: a decode call's most lanes
+MAX_LANES = 16
+
+_LIVE: "weakref.WeakSet[LLMEngine]" = weakref.WeakSet()
+
+
+def live_engines() -> List["LLMEngine"]:
+    """The engines alive in this process, for a probe that runs beside one and
+    is not handed it: a benchmark's plain reference reads the state a replica
+    holds back through here (:meth:`LLMEngine.held_snapshot`,
+    :attr:`LLMEngine.last_finished`, :meth:`KVBlockPool.read_state`)."""
+    return list(_LIVE)
 
 
 class NoKVBlocksError(RuntimeError):
@@ -545,6 +567,12 @@ class KVBlockPool:
         dim]`` per arena (K, V, ...). For tests and debugging, not for the step
         path (eager indexing compiles)."""
         return tuple(np.asarray(a[:, b]) for a in self.arenas)
+
+    def read_state(self, slot: int):
+        """State slot ``slot`` on the host: one array ``[layers, ...]`` per entry
+        of ``state_arrays``, in the dtype the pool keeps it in. Like
+        :meth:`read_block`, for tests and probes and not for the step path."""
+        return tuple(np.asarray(a[:, slot]) for a in self.states)
 
     def warm(self, extend_shapes: Dict[Any, Any], cache_buckets) -> None:
         """Compile every paging program the engine's buckets allow, on zeros
@@ -1069,7 +1097,7 @@ class LLMEngine:
     def __init__(self, cfg=None, params=None, *, deployment: str = "llm",
                  num_blocks: int = 128, block_size: int = 16,
                  prefill_chunk: int = 32, prefill_lanes: int = 4,
-                 lane_buckets: Sequence[int] = (1, 2, 4, 8, 16),
+                 lane_buckets: Sequence[int] = LANE_BUCKETS,
                  prefill_token_buckets: Sequence[int] = (8, 16, 32),
                  cache_buckets: Sequence[int] = (32, 64, 128),
                  max_adapters: int = 4, adapter_loader=None,
@@ -1084,6 +1112,7 @@ class LLMEngine:
         self._params = params if params is not None else make_params(
             self.cfg, seed)
         self._extend = self.cfg.make_extend_fn()
+        _LIVE.add(self)
         #: per-sequence state (a recurrent layer's), where the model has any
         state_arrays = tuple(getattr(self.cfg, "state_arrays", ()))
         self._stateful = bool(state_arrays)
@@ -1135,6 +1164,11 @@ class LLMEngine:
         # computed from the sizes of the arrays handed over, not measured.
         self.steps = 0
         self.admitted = 0
+        #: ``(tokens, slot)`` of the sequence that finished last: the tokens its
+        #: state holds (all it was fed: the prompt and all but the newest of its
+        #: own) and the slot that state lies in, as the sequence left it until
+        #: the slot is taken again. None before any, and without states
+        self.last_finished: Optional[tuple] = None
         self.queue_s = 0.0              # sum of enqueue -> admitted
         self.h2d_bytes = 0
         self.d2h_bytes = 0
@@ -1364,6 +1398,25 @@ class LLMEngine:
             "traced": copy.deepcopy(self.traced),
             "slowest_step": slowest,
         }
+
+    @property
+    def params(self):
+        """The weights being served, as they were handed over."""
+        return self._params
+
+    def held_snapshot(self, prompt: Sequence[int]) -> Optional[tuple]:
+        """``(tokens, slot)``: how many of ``prompt``'s tokens a request for it
+        would find cached with their state, and the slot of that snapshot
+        (:meth:`KVBlockPool.read_state` reads it); None where the prefix cache
+        holds none for it. Takes nothing and touches no order of eviction: for
+        tests and probes."""
+        if self.prefix is None or not self._stateful:
+            return None
+        hashes = chain_hashes(prompt, self.block_size)[:(len(prompt) - 1) // self.block_size]
+        for n in range(len(hashes), 0, -1):
+            with contextlib.suppress(KeyError):
+                return n * self.block_size, self.prefix.snapshot(hashes[n - 1])
+        return None
 
     def _work(self) -> Dict[str, Any]:
         """Every counter that counts work, its groups copied: what ``stats()``
@@ -1955,6 +2008,8 @@ class LLMEngine:
             self._finish(s, st)
 
     def _finish(self, s, st: _SeqState) -> None:
+        if st.slot is not None:
+            self.last_finished = (len(st.prompt) + len(st.out) - 1, st.slot)
         st.lease.release()
         result: Dict[str, Any] = {
             "tokens": st.out,
@@ -2003,7 +2058,15 @@ class LLMServer:
     def __init__(self, cfg=None, **engine_kwargs):
         self._engine = LLMEngine(cfg, **engine_kwargs)
 
-    @batching.continuous_batch(max_batch_size=16, batch_wait_timeout_s=0.001)
+    @staticmethod
+    def concurrent_queries(*_, lane_buckets: Sequence[int] = LANE_BUCKETS, **__) -> int:
+        """The requests a replica bound with these engine sizes runs at once
+        (``serve.deployment`` reads it where it names no ``max_concurrent_queries``,
+        and never goes under its own default for it): a request a decode lane, so
+        the engine's largest lane bucket, as far as ``generate`` batches."""
+        return min(max(lane_buckets), MAX_LANES)
+
+    @batching.continuous_batch(max_batch_size=MAX_LANES, batch_wait_timeout_s=0.001)
     def generate(self, seqs):
         self._engine.step(seqs)
 

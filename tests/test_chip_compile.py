@@ -26,7 +26,8 @@ from jax.sharding import SingleDeviceSharding
 
 from ray_tpu._private import accelerator
 from ray_tpu.models import (
-    cohere2_moe, gpt, granitemoehybrid, keye_vl2, kimi_k2, layers, mimo_v2_flash, minicpm_sala)
+    cohere2_moe, gpt, granitemoehybrid, keye_vl2, kimi_k2, layers, mimo_v2_flash, minicpm_sala,
+    qwen3_next)
 from ray_tpu.models.training import (
     abstract_state,
     default_optimizer,
@@ -1331,12 +1332,101 @@ def test_mimo_v2_flash_share_extend_compiles_and_copies_no_arena_and_no_expert(
     assert 2 * cfg.num_params() + resident + arena_bytes >= 0.60 * HBM_BYTES
 
 
+def _qwen3_next_share():
+    """The served cut of Qwen3-Next (one chip of ep4 x pp6: two periods of three delta
+    layers and a full one, 128 of 512 experts) and its engine sizes, from the
+    configuration's file."""
+    import json
+
+    from benchmark.manifest import published_keys
+    from benchmark.models import qwen3_next as arch
+
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "configs",
+            "qwen3-next-80b-a3b-serve-ep4.json")) as f:
+        config = json.load(f)
+    return arch.program_config(published_keys(config)), config
+
+
+#: one sequence's state, the cut's 6 delta layers: 32 heads of 128 x 128 float32 and the
+#: convolution's last 3 inputs of 8,192 channels in bfloat16
+QWEN3_NEXT_STATE_BYTES = 6 * (32 * 128 * 128 * 4 + 3 * 8192 * 2)
+#: one layer's 128 held experts in bfloat16
+QWEN3_NEXT_EXPERTS_BYTES = 402_653_184 * 2
+
+
+@pytest.mark.parametrize("form", ["decode", "prefill"])
+def test_qwen3_next_share_extend_compiles_and_copies_no_arena_and_no_expert(
+        shaped, form, built_for_tpu):
+    """One chip's share of Qwen3-Next at its published widths (7.33 GB of weights) over
+    the largest cache bucket, the two full layers' rows gathered (4,096 B a token) and
+    the six delta layers' state and convolution tail read and written where the pool's
+    state arenas lie (160 slots of 12.9 MB: 2.06 GB, donated and aliased). A decode
+    call of sixteen lanes attends in XLA alone, its kernels the grouped matmuls of the
+    eight expert layers (two a layer, a period's four layers in the scan's body); a
+    prefill chunk of 1,024 attends at a head of 256 in the kernel the other architectures
+    call, and runs the delta rule's sixteen sub-chunks as a loop of its own. Neither
+    holds a copy of a state arena or of a layer's experts, no array of a delta layer
+    grows with the context, and both fit beside the pool's blocks and a second call's
+    caches."""
+    built_for_tpu(True)
+    cfg, config = _qwen3_next_share()
+    engine, stated = config["engine"], config["compiled_bytes_per_device"]
+    cap, lanes, slots = engine["cache_buckets"][-1], engine["lane_buckets"][-1], engine["state_slots"]
+    b, tc = (lanes, 1) if form == "decode" else (1, engine["prefill_token_buckets"][-1])
+    assert stated[form]["shape"] == [b, tc, cap] and (lanes, tc in (1, 1024)) == (16, True)
+    params = jax.tree.map(
+        lambda x: shaped(x.shape, x.dtype), jax.eval_shape(lambda: cfg.init_params(0)))
+    assert cfg.cache_arrays == ((1, 512), (1, 512)) and cfg.cache_layers == 2
+    caches = [shaped((cfg.cache_layers, b, cap) + each[:2], cfg.dtype) for each in cfg.cache_arrays]
+    arenas = tuple(
+        shaped((layers, slots) + shape, dtype) for layers, shape, dtype in cfg.state_arrays)
+    assert [a.shape for a in arenas] == [(6, slots, 32, 128, 128), (6, slots, 3, 8192)]
+    operands = shaped(
+        (b, llm._operand_width(
+            engine["prefill_token_buckets"][-1], cap // engine["block_size"], True)), jnp.int32)
+    compiled = llm._operand_extend(cfg.make_extend_fn(), len(caches), len(arenas)).lower(
+        llm._extend_name(b, tc, cap), params, operands,
+        shaped((lanes + len(cfg.counters),), jnp.int32), *caches, *arenas, tc=tc
+    ).compile()
+    text = compiled.as_text()
+    kernels = [
+        line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    attends = [k for k in kernels if "/masked_attention/" in k]
+    assert len(kernels) - len(attends) == 8 and all("/extend.moe.experts/" in k for k in kernels
+                                                    if k not in attends)
+    assert len(attends) == (1 if form == "prefill" else 0)
+    assert all("/extend.attention/masked_attention/" in k for k in attends)
+    # the state arena keeps its layout through the scan: a head's 128 x 128 innermost
+    assert set(re.findall(r"f32\[6,%d,32,128,128\]\{([\d,]+)" % slots, text)) == {"4,3,2,1,0"}
+    memory = compiled.memory_analysis()
+    assert cfg.num_params() == 3_667_251_328 and QWEN3_NEXT_STATE_BYTES == 12_877_824
+    arena_bytes = slots * QWEN3_NEXT_STATE_BYTES
+    assert 0 <= memory.alias_size_in_bytes - arena_bytes < 2**20
+    per_token = cfg.cache_layers * (512 + 512) * 2
+    assert per_token == 4096
+    weights = memory.argument_size_in_bytes - memory.alias_size_in_bytes - b * (
+        cap * per_token + 2**17)
+    assert 7.32e9 < weights < 7.35e9
+    assert memory.argument_size_in_bytes == stated[form]["argument"]
+    assert _holds_no_more_than_stated(memory, stated[form])
+    # no copy of a state arena or of a layer's experts; a decode call's temporaries are
+    # its lanes' K and V written into and re-laid out by K/V head, under the gathered
+    # caches themselves, and its sixteen lanes' states (2 MB a lane and layer) in flight
+    assert memory.temp_size_in_bytes < min(QWEN3_NEXT_EXPERTS_BYTES, arena_bytes / 4) + (
+        b * cap * per_token if form == "decode" else 0)
+    resident = engine["num_blocks"] * engine["block_size"] * per_token
+    assert _device_bytes(compiled) + resident + lanes * cap * per_token < HBM_BYTES
+    assert 2 * cfg.num_params() + resident + arena_bytes >= 0.60 * HBM_BYTES
+
+
 @pytest.mark.parametrize(
     "name,extends,pagings",
     [("gptj-6b-serve", 12, 11), ("command-a-plus-serve-ep8", 20, 25),
      ("keye-vl2-30b-a3b-serve", 16, 19), ("kimi-k2-instruct-serve-ep32", 16, 19),
      ("granite-4.0-h-micro-serve", 20, 25), ("granite-4.0-h-small-serve-ep2", 20, 25),
-     ("minicpm-sala-serve-pp2", 16, 19), ("mimo-v2-flash-serve-ep16", 20, 25)],
+     ("minicpm-sala-serve-pp2", 16, 19), ("mimo-v2-flash-serve-ep16", 20, 25),
+     ("qwen3-next-80b-a3b-serve-ep4", 18, 26)],
 )
 def test_a_serve_configuration_compiles_no_more_programs_than_it_did(name, extends, pagings):
     """The programs an engine with the configuration's buckets compiles (a tiny
@@ -1363,6 +1453,8 @@ def test_a_serve_configuration_compiles_no_more_programs_than_it_did(name, exten
         else minicpm_sala.minicpm_sala_nano(max_seq_len=context, linear_chunk=256)
         if name.startswith("minicpm")
         else mimo_v2_flash.mimo_v2_flash_nano(max_seq_len=context) if name.startswith("mimo")
+        else qwen3_next.qwen3_next_nano(max_seq_len=context, delta_chunk=64)
+        if name.startswith("qwen3")
         else dataclasses.replace(gpt.gpt_nano(), max_seq_len=context)
     )
     llm._paging_programs.cache_clear()      # this engine's programs alone

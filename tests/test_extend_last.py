@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import (
-    cohere2_moe, gpt, granitemoehybrid, keye_vl2, kimi_k2, mimo_v2_flash, minicpm_sala)
+    cohere2_moe, gpt, granitemoehybrid, keye_vl2, kimi_k2, mimo_v2_flash, minicpm_sala, qwen3_next)
 from ray_tpu.serve import batching, llm
 
 LANES, TOKENS, CACHE, SLOTS = 2, 16, 32, 4
@@ -25,6 +25,7 @@ ARCHITECTURES = {
     "granite_hybrid_moe": lambda: granitemoehybrid.granite_hybrid_nano(router_experts=8),
     "minicpm_sala": minicpm_sala.minicpm_sala_nano,
     "mimo_v2_flash": mimo_v2_flash.mimo_v2_flash_nano,
+    "qwen3_next": qwen3_next.qwen3_next_nano,
 }
 
 

@@ -13,9 +13,11 @@ import pytest
 
 import ray_tpu
 from ray_tpu import serve
-from ray_tpu.models import cohere2_moe, gpt, keye_vl2, mimo_v2_flash
+from ray_tpu.models import cohere2_moe, gpt, keye_vl2, mimo_v2_flash, qwen3_next
 from ray_tpu.serve import batching
 from ray_tpu.serve.llm import (
+    LANE_BUCKETS,
+    MAX_LANES,
     KVBlockPool,
     KVLease,
     LLMEngine,
@@ -863,3 +865,107 @@ def test_a_window_slot_is_freed_exactly_once_with_its_lease():
     eng.step(waiting)
     shed = [s for s in waiting if s.done]
     assert len(shed) == 1 and "state slot" in str(shed[0]._error)
+
+
+# ---------------------------------------------------------------------------
+# a state that a delta rule writes, and sixteen lanes (models/qwen3_next.py)
+# ---------------------------------------------------------------------------
+
+_DELTA = dict(
+    num_blocks=160, block_size=8, prefill_chunk=16, prefill_lanes=1, lane_buckets=(1, 16),
+    prefill_token_buckets=(16,), cache_buckets=(64,), prefix_caching=False)
+
+
+def test_a_delta_state_slot_is_freed_exactly_once_with_its_lease():
+    """The delta layers' state and convolution tail ride the lease that the blocks
+    ride: one slot a sequence at admission, back once on finish, on a second release
+    nothing more; a cancelled sequence's goes back too, and a delta layer has nothing in
+    the block arenas."""
+    cfg = qwen3_next.qwen3_next_nano()
+    eng = LLMEngine(cfg, **{**_DELTA, "lane_buckets": (1, 2), "state_slots": 4})
+    pool = eng.pool
+    assert [a.shape[0] for a in pool.arenas] == [cfg.cache_layers] * 2 == [2, 2]
+    assert [s.shape[:2] for s in pool.states] == [(6, 4), (6, 4)]
+    assert pool.state_bytes == 6 * (8 * 16 * 16 + 3 * 256) * 4
+    lease = KVLease(pool)
+    slot = lease.add_slot()
+    lease.add(pool.allocate(2))
+    assert slot != 0 and pool.slots_in_use() == 1 and pool.in_use() == 2
+    lease.release()
+    lease.release()
+    assert pool.slots_in_use() == 0 and pool.in_use() == 0
+    assert sorted(pool._free_slots) == [1, 2, 3]            # once: no slot twice in the list
+    cancel = threading.Event()
+    seqs = [
+        batching._Sequence({"prompt": _prompt(5, 30), "max_new_tokens": 6}),
+        batching._Sequence({"prompt": _prompt(6, 20), "max_new_tokens": 40, "_cancel": cancel})]
+    steps = 0
+    while not all(s.done for s in seqs):
+        if steps == 6:
+            cancel.set()
+        eng.step([s for s in seqs if not s.done])
+        steps += 1
+    assert seqs[0]._error is None and seqs[1]._error is not None
+    assert pool.slots_in_use() == 0 and pool.in_use() == 0
+    assert sorted(pool._free_slots) == [1, 2, 3]
+
+
+def test_sixteen_lanes_decode_in_one_call_and_each_gets_what_it_gets_alone():
+    """Sixteen sequences of unlike lengths through the engine's own default of lane
+    buckets up to 16: once all have their prompt in, a decode call carries all sixteen
+    (``extend_decode_16x1x64``), a gather moves rows for the two full layers alone, and
+    every sequence's tokens are those it gets with the engine to itself."""
+    cfg = qwen3_next.qwen3_next_nano()
+    eng = LLMEngine(cfg, **_DELTA)
+    asks = [
+        {"prompt": _prompt(40 + i, 6 + 2 * i), "max_new_tokens": 20, "return_logits": True}
+        for i in range(16)]
+    alone = []
+    for ask in asks[::5]:
+        seq = batching._Sequence(dict(ask))
+        while not seq.done:
+            eng.step([seq])
+        alone.append(seq._result)
+    before = eng.stats()
+    seqs = [batching._Sequence(dict(ask)) for ask in asks]
+    while not all(s.done for s in seqs):
+        eng.step([s for s in seqs if not s.done])
+    assert all(s._error is None for s in seqs)
+    after = eng.stats()
+    name = _extend_name(16, 1, 64)
+    assert name == "extend_decode_16x1x64" and name not in before["programs"]
+    assert after["programs"][name]["n"] > 0
+    decode = {
+        k: after["calls"]["decode"][k] - before["calls"]["decode"][k]
+        for k in ("n", "lanes_used", "lane_slots")}
+    assert decode["lanes_used"] == 16 * 19 and decode["lanes_used"] / decode["n"] > 6
+    assert eng.pool.layers == cfg.cache_layers == 2         # what a gather moves rows for
+    for got, want in zip(seqs[::5], alone):
+        assert got._result["tokens"] == want["tokens"]
+        np.testing.assert_allclose(got._result["logits"], want["logits"], rtol=2e-4, atol=2e-5)
+    assert eng.pool.slots_in_use() == 0 and eng.pool.in_use() == 0
+
+
+def test_a_deployment_takes_its_executing_slots_from_what_its_callable_runs_at_once():
+    """``serve.deployment`` without ``max_concurrent_queries``: the callable's own
+    answer for the arguments it is bound with where that is past the default's 8
+    (``LLMServer``: its engine's largest lane bucket, the engine's own default buckets
+    where it is given none, never past what ``generate`` batches), 8 for a callable that
+    has none, and the deployment's own number where it names one."""
+    assert LLMServer.concurrent_queries() == max(LANE_BUCKETS) == MAX_LANES == 16
+    assert LLMServer.concurrent_queries(None, lane_buckets=(1, 2, 4)) == 4
+    assert LLMServer.concurrent_queries("a", "b", {}, seed=3, lane_buckets=[1, 2, 4, 8, 16]) == 16
+    assert LLMServer.concurrent_queries(lane_buckets=(1, 32)) == 16
+
+    class Wide(LLMServer):
+        pass
+
+    app = serve.deployment(Wide, name="wide").bind(None, lane_buckets=(1, 16), num_blocks=8)
+    assert serve._executing_slots(app.deployment, app.init_args, app.init_kwargs) == 16
+    narrow = serve.deployment(Wide, name="narrow").bind(None, lane_buckets=(1, 4))
+    assert serve._executing_slots(narrow.deployment, narrow.init_args, narrow.init_kwargs) == 8
+    named = serve.deployment(Wide, name="named", max_concurrent_queries=3).bind(
+        None, lane_buckets=(1, 16))
+    assert serve._executing_slots(named.deployment, named.init_args, named.init_kwargs) == 3
+    plain = serve.deployment(lambda x: x, name="plain").bind()
+    assert serve._executing_slots(plain.deployment, plain.init_args, plain.init_kwargs) == 8
