@@ -184,6 +184,8 @@ def make_extend_fn(cfg: Cohere2MoeConfig):
     def _attend(p, hidden, positions, live, kc, vc, sliding):
         b, tc = positions.shape
         q = jnp.einsum("btd,dhk->bthk", hidden, p["q"]["kernel"].astype(dtype))
+        if tc > 1 and backend.on_tpu():
+            q = attention.laid_out_by_head(q)
         k = jnp.einsum("btd,dhk->bthk", hidden, p["k"]["kernel"].astype(dtype))
         v = jnp.einsum("btd,dhk->bthk", hidden, p["v"]["kernel"].astype(dtype))
 

@@ -606,6 +606,31 @@ def _heads_a_tile(groups: int, block_q: int, dv: int) -> int:
         if groups % h == 0 and (h == 1 or h * block_q * dv * 4 <= MASKED_ACC_BYTES))
 
 
+# K and V are handed to the kernel copied by K/V head, ``[b, kv, s, width]``. Measured on a
+# v5e (PERF.md, PR 59: a layer's slice, its rows written, the attend; Command A+'s chunk,
+# 8 x 16 heads x 256 queries over 8,192 slots, and Keye's, 4 x 8 x 512 over 32,768) that copy
+# is kept in VMEM and costs nothing that shows: 0.767 and 2.256 ms copied, 0.757 and 2.173
+# with a head read as a column block of the rows ``[b, s, kv x width]`` (itself a re-layout
+# of ``[b, s, kv, width]``, whose device tile is heads x lanes), 0.763 and 2.177 with all
+# heads' tile fetched and a head's sublane rows picked in VMEM. Neither in-place form moved
+# a chunk (22.05 -> 22.00 ms), so neither was kept.
+
+
+def laid_out_by_head(x: jax.Array) -> jax.Array:
+    """``x`` [b, t, heads, d] as it is, held on the device with its heads outermost
+    ([b, heads, t, d] in memory), which is how :func:`masked_attention` takes its
+    queries. For a projection's result, before the rotation: left to choose, the
+    compiler carries the kernel's layout back through the elementwise rotation to the
+    product and there picks one with the chunk's tokens in the lanes, for which it
+    re-lays the layer's **weights** out on every call (Command A+: 134 MB of ``q``
+    kernel copied a layer and chunk, ``copy.114``, 0.45 ms of a 5 ms layer). Told
+    here, the product writes by head from the weights as they lie: the same product,
+    the same bits (``PERF.md``, PR 59)."""
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    return with_layout_constraint(x, Layout(major_to_minor=(0, 2, 1, 3)))
+
+
 def _masked_fwd_kernel(
     blocks_ref, *refs, scale, groups, sinks=False
 ):
@@ -688,7 +713,9 @@ def masked_attention(
     ``q`` [b, t, kv, groups, d] are the queries, ``groups`` query heads to a
     K/V head, which share its tiles of ``k`` [b, s, kv, d] and ``v`` [b, s, kv,
     dv] and of ``mask`` [b, t, s] (bool, no head axis: what a query may read,
-    whatever decided it). ``dv`` need not be ``d``: a latent cache is scored
+    whatever decided it); both are copied by K/V head, [b, kv, s, width], for the
+    kernel, which costs nothing that shows (the note above
+    :func:`laid_out_by_head`). ``dv`` need not be ``d``: a latent cache is scored
     over all of a row and summed over its first features (``models/kimi_k2.py``:
     one row of 576 under 64 query heads, 512 of it the value). As many query
     heads meet a tile together as their float32 accumulator (heads x ``block_q``

@@ -540,7 +540,12 @@ def test_command_a_plus_share_extend_compiles_and_copies_no_expert(
     A chunk (the cell's one prefill lane, and two) attends in
     ``masked_attention``; a decode call holds the experts' kernels alone.
     ``parent_temp`` is what the compiler counted at PR 52 for the same shape,
-    where a chunk attended densely (compile, PR 53)."""
+    where a chunk attended densely (compile, PR 53). Since PR 59 a chunk's ``q``
+    projection writes by head from its kernel as it lies
+    (``ops/attention.laid_out_by_head``): the 134 MB copy of the layer's ``q`` kernel,
+    which every chunk made of every layer and which was half of a one-lane chunk's
+    temporaries (272,467,456 B at PR 58), is gone; the slice of the stack that the
+    product reads is still made."""
     built_for_tpu(True)     # the chip's grouped matmul and attend
     cfg = cohere2_moe.Cohere2MoeConfig(vocab_size=32768, num_layers=4, num_experts=16)
     params = jax.tree.map(
@@ -550,8 +555,17 @@ def test_command_a_plus_share_extend_compiles_and_copies_no_expert(
     compiled = llm._operand_extend(cfg.make_extend_fn()).lower(
         llm._extend_name(lanes, tc, cap), params, operands, shaped((8,), jnp.int32),
         cache, cache, tc=tc).compile()
-    _experts_kernels_and_a_chunks_attend(compiled.as_text(), cfg, lanes, tc, cap)
+    text = compiled.as_text()
+    _experts_kernels_and_a_chunks_attend(text, cfg, lanes, tc, cap)
     memory = compiled.memory_analysis()
+    if tc > 1:
+        q_kernel = rf"bf16\[1,{cfg.embed_dim},{cfg.num_heads},{cfg.head_dim}\]"
+        assert re.search(rf"= {q_kernel}\S* fusion\(", text)             # the slice
+        # no copy of it as an instruction of its own (one fused into the product,
+        # of a fusion's parameter, re-lays the kernel out as the product reads it)
+        assert not re.search(rf"= {q_kernel}\S* (copy|transpose)\(%(?!param_)", text)
+        q_kernel_bytes = 2 * cfg.embed_dim * cfg.num_heads * cfg.head_dim
+        assert memory.temp_size_in_bytes <= {1: 272467456}.get(lanes, parent_temp) - q_kernel_bytes
     assert 9.4e9 < memory.argument_size_in_bytes < 10.6e9
     assert memory.temp_size_in_bytes < 1.0e9
     # a chunk's bound is the all-rows form's

@@ -6,12 +6,14 @@ dense form, the softmax router, and the configuration's own arithmetic. The
 comparison with the plain reference is the benchmark's
 (``tests/benchmark/test_bench_keye_vl2.py``)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import keye_vl2, moe
+from ray_tpu.models import keye_vl2, layers, moe
 from ray_tpu.ops import attention
 
 CFG = keye_vl2.keye_vl2_nano()
@@ -210,6 +212,72 @@ def test_a_query_gets_the_same_bits_at_any_row_of_a_chunk(block_k):
         np.concatenate([q[:, 16:], other_q], 1), np.concatenate([mask[:, 16:], other_mask], 1),
         positions + 16)
     assert np.array_equal(first[:, 16:], moved[:, :16])
+
+
+SERVED_WIDTHS = {
+    # K/V heads, key width, value width
+    "command_a_plus_8x128": (8, 128, 128),
+    "qwen3_next_2x256": (2, 256, 256),
+    "mimo_4x192_over_128": (4, 192, 128),
+    "one_latent_576_over_512": (1, 576, 512),
+}
+
+
+@pytest.mark.parametrize("groups, sunk, dtype", [
+    (2, False, jnp.float32), (4, False, jnp.float32), (2, True, jnp.float32),
+    (4, True, jnp.float32), (4, True, jnp.bfloat16),
+], ids=["one-tile", "two-tiles", "one-tile-sinks", "two-tiles-sinks", "bf16-two-tiles-sinks"])
+@pytest.mark.parametrize("case", list(SERVED_WIDTHS))
+def test_the_kernel_computes_the_dense_form_at_the_served_widths(
+        case, groups, sunk, dtype, monkeypatch):
+    """``masked_attention``, interpreted, against ``layers.plain_attend`` at the K
+    and V widths the serve configurations hold: a lane that stops short of the cache
+    (NaN past its live blocks), a cache that is no whole number of key tiles, a K/V
+    head's query heads in one tile of the grid and in two, with and without sinks."""
+    kv, d, dv = SERVED_WIDTHS[case]
+    lanes, tokens, cache, block_q, block_k = 2, 16, 40, 8, 16
+    if groups == 4:     # an accumulator with room for two heads' tile of queries
+        monkeypatch.setattr(attention, "MASKED_ACC_BYTES", 2 * block_q * dv * 4)
+    assert attention._heads_a_tile(groups, block_q, dv) == 2
+    rng = np.random.default_rng(kv * d + groups)
+    q = jnp.asarray(rng.standard_normal((lanes, tokens, kv, groups, d)), dtype)
+    k = jnp.asarray(rng.standard_normal((lanes, cache, kv, d)), dtype)
+    v = jnp.asarray(rng.standard_normal((lanes, cache, kv, dv)), dtype)
+    kv_len = np.asarray([cache, 21])
+    mask = (rng.random((lanes, tokens, cache)) < 0.5) & (
+        np.arange(cache)[None, None] < kv_len[:, None, None])
+    mask[:, :, 0] = True
+    sinks = jnp.asarray(2.0 * rng.standard_normal((kv, groups)), jnp.float32) if sunk else None
+    dirty_k, dirty_v = (x.at[1, -(-21 // block_k) * block_k:].set(np.nan) for x in (k, v))
+    out = np.asarray(attention.masked_attention(
+        q, dirty_k, dirty_v, jnp.asarray(mask), jnp.asarray(kv_len, jnp.int32), scale=0.125,
+        sinks=sinks, block_q=block_q, block_k=block_k, interpret=True).astype(jnp.float32))
+    if sunk:
+        want = _sunk_dense_attend(q, k, v, mask, 0.125, sinks)
+    else:
+        want = layers.plain_attend(q, k, v, jnp.asarray(mask), 0.125)
+    assert out.shape == (lanes, tokens, kv, groups, dv)
+    close = 1e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(out, np.asarray(want, np.float32), atol=close, rtol=close)
+
+
+def _sunk_dense_attend(q, k, v, mask, scale, sinks):
+    """``layers.plain_attend`` with a learned logit a query head in the denominator."""
+    logit = jnp.einsum("bqhgd,bkhd->bhgqk", q, k, preferred_element_type=jnp.float32) * scale
+    logit = jnp.where(jnp.asarray(mask)[:, None, None], logit, -1e30)
+    sink = jnp.broadcast_to(sinks[None, :, :, None, None], logit.shape[:-1] + (1,))
+    weight = jax.nn.softmax(jnp.concatenate([logit, sink], -1), -1)[..., :k.shape[1]]
+    return jnp.einsum("bhgqk,bkhd->bqhgd", weight, v)
+
+
+def test_laid_out_by_head_asks_for_heads_outermost_and_changes_no_value():
+    """``ops/attention.laid_out_by_head`` (Command A+'s chunk, PR 59) is a constraint
+    on where a projection's result lies, [b, heads, t, d] in memory, and nothing else."""
+    x = jnp.arange(2 * 3 * 4 * 8, dtype=jnp.float32).reshape(2, 3, 4, 8)
+    told = jax.jit(lambda x: attention.laid_out_by_head(2 * x) + 1)
+    assert np.array_equal(np.asarray(told(x)), np.asarray(2 * x + 1))
+    constraint, = re.findall(r"@LayoutConstraint.*", told.lower(x).as_text())
+    assert "result_layouts = [dense<[3, 1, 2, 0]>" in constraint      # minor to major
 
 
 def test_the_softmax_router_takes_the_largest_probabilities_and_renormalises():
