@@ -50,6 +50,13 @@ def _await(predicate, timeout, what):
     raise AssertionError(f"timed out waiting for {what}")
 
 
+def _await_started(started):
+    """The task's first act is ``open(started, "w")``: a cancel sent before it
+    runs takes a queued task, which no code of the task ever sees (under load a
+    lease takes seconds, not the 0.8 s these tests used to sleep)."""
+    _await(lambda: os.path.exists(started), 60, "the task to start")
+
+
 def test_cancel_resolves_within_1s(cluster):
     """A running (sleeping) task cancels cooperatively: the ref resolves
     to TaskCancelledError immediately — no worker round-trip on the
@@ -80,7 +87,8 @@ def test_was_cancelled_cooperative_exit(cluster, tmp_path):
     marker = str(tmp_path / "saw_cancel")
 
     @ray_tpu.remote
-    def poller(path):
+    def poller(path, started):
+        open(started, "w").close()
         ctx = ray_tpu.get_runtime_context()
         for _ in range(400):
             if ctx.was_cancelled():
@@ -90,8 +98,9 @@ def test_was_cancelled_cooperative_exit(cluster, tmp_path):
             time.sleep(0.05)
         return "never-cancelled"
 
-    ref = poller.remote(marker)
-    time.sleep(0.8)
+    started = str(tmp_path / "started")
+    ref = poller.remote(marker, started)
+    _await_started(started)
     assert ray_tpu.cancel(ref) is True
     _await(lambda: os.path.exists(marker), 10, "cooperative exit marker")
     with pytest.raises(ray_tpu.TaskCancelledError):
@@ -104,8 +113,9 @@ def test_force_cancel_interrupts_running_thread(cluster, tmp_path):
     marker = str(tmp_path / "interrupted")
 
     @ray_tpu.remote
-    def stubborn(path):
+    def stubborn(path, started):
         try:
+            open(started, "w").close()
             for _ in range(400):  # never polls was_cancelled()
                 time.sleep(0.05)
         except ray_tpu.TaskCancelledError:
@@ -114,8 +124,9 @@ def test_force_cancel_interrupts_running_thread(cluster, tmp_path):
             raise
         return "ran to completion"
 
-    ref = stubborn.remote(marker)
-    time.sleep(0.8)
+    started = str(tmp_path / "started")
+    ref = stubborn.remote(marker, started)
+    _await_started(started)
     assert ray_tpu.cancel(ref, force=True) is True
     _await(lambda: os.path.exists(marker), 10, "force-interrupt marker")
     with pytest.raises(ray_tpu.TaskCancelledError):
@@ -221,8 +232,9 @@ def test_cancel_rpc_retries_through_injected_drop(cluster, tmp_path):
     marker = str(tmp_path / "interrupted")
 
     @ray_tpu.remote
-    def stubborn(path):
+    def stubborn(path, started):
         try:
+            open(started, "w").close()
             for _ in range(600):
                 time.sleep(0.05)
         except ray_tpu.TaskCancelledError:
@@ -230,8 +242,9 @@ def test_cancel_rpc_retries_through_injected_drop(cluster, tmp_path):
             raise
         return "done"
 
-    ref = stubborn.remote(marker)
-    time.sleep(0.8)
+    started = str(tmp_path / "started")
+    ref = stubborn.remote(marker, started)
+    _await_started(started)
     fi.arm(
         {
             "seed": 0,

@@ -21,15 +21,9 @@ from ray_tpu._private.rpc import RpcClient, RpcServer
 
 @pytest.fixture(autouse=True)
 def _reset_trace_plane():
-    from ray_tpu._private.config import GlobalConfig
-
-    # ``_system_config={"trace_sample": 1.0}`` outlives ``shutdown``: a later
-    # test of this process that re-reads the config (``SimCluster``'s exit)
-    # would find the plane switched on
-    saved = dict(GlobalConfig._values)
+    # ``_system_config={"trace_sample": 1.0}`` outlives ``shutdown``; the
+    # conftest's ``_system_config_ends_with_the_test`` puts the config back
     yield
-    GlobalConfig._values.clear()
-    GlobalConfig._values.update(saved)
     fi.disarm()
     _tr.disable()
     _tr.clear()
