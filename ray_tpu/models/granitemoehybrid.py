@@ -446,6 +446,12 @@ def make_extend_fn(cfg: GraniteMoeHybridConfig):
     is touched. A lane of length 0 starts from zeros whatever its slot holds; a
     negative token id is padding and changes no state; a lane of padding alone
     points at slot 0. ``counters`` (``cfg.counters``) over real lanes and tokens.
+    With ``table=`` [lanes, n] (a call of one token a lane, from an engine that reads
+    the keyword off the signature: ``serve/llm.reads_pages``) ``k_cache`` and
+    ``v_cache`` are the pool's block arenas themselves, ``[cache_layers, blocks,
+    block, 1, kv_heads x head_dim]``, and an attention layer attends over the lanes'
+    pages where they lie (:func:`layers.paged_attend`: on the chip
+    ``ops/attention.paged_attention``).
 
     Scopes: ``extend.embed``; ``extend.ssm`` (projections, convolution, gate,
     norm) with ``extend.ssm.scan`` inside it (the recurrence alone, with the
@@ -550,26 +556,31 @@ def make_extend_fn(cfg: GraniteMoeHybridConfig):
         return out, ssm, tails
 
     @jax.named_scope("extend.attention")
-    def _attend(p, hidden, positions, visible, live, kc, vc):
+    def _attend(p, hidden, positions, visible, live, kc, vc, paged=None):
+        """``kc``, ``vc`` the layer's slab of the padded caches; or, with ``paged`` (the
+        layer's index and the lanes' block table), the pool's arenas themselves."""
         b, tc = positions.shape
-        cap = kc.shape[1]
         q = (hidden @ p["q"]["kernel"].astype(dtype)).reshape(
             b, tc, cfg.kv_heads, groups, cfg.head_dim)
         k = (hidden @ p["k"]["kernel"].astype(dtype))[:, :, None]   # one row for all K/V heads
         v = (hidden @ p["v"]["kernel"].astype(dtype))[:, :, None]
-        lane = jnp.arange(b)[:, None]
-        kc = layers.write_rows(kc, lane, positions, k).reshape(b, cap, cfg.kv_heads, -1)
-        vc = layers.write_rows(vc, lane, positions, v).reshape(b, cap, cfg.kv_heads, -1)
-
-        def attend_block(qb, mask):
-            return layers.plain_attend(qb, kc, vc, mask, scale)
-
-        if tc > 1 and backend.on_tpu():
-            out = attention.masked_attention(q, kc, vc, visible, live, scale=scale)
+        if paged is not None:
+            out = layers.paged_attend(q, k, v, kc, vc, *paged, positions, visible, scale)
         else:
-            out = (
-                attend_block(q, visible) if tc == 1
-                else layers.by_query_block(attend_block, q, visible))
+            cap = kc.shape[1]
+            lane = jnp.arange(b)[:, None]
+            kc = layers.write_rows(kc, lane, positions, k).reshape(b, cap, cfg.kv_heads, -1)
+            vc = layers.write_rows(vc, lane, positions, v).reshape(b, cap, cfg.kv_heads, -1)
+
+            def attend_block(qb, mask):
+                return layers.plain_attend(qb, kc, vc, mask, scale)
+
+            if tc > 1 and backend.on_tpu():
+                out = attention.masked_attention(q, kc, vc, visible, live, scale=scale)
+            else:
+                out = (
+                    attend_block(q, visible) if tc == 1
+                    else layers.by_query_block(attend_block, q, visible))
         out = jnp.dot(
             out.reshape(b, tc, -1), p["o"]["kernel"].astype(dtype), preferred_element_type=f32)
         return out, (k, v)
@@ -600,10 +611,11 @@ def make_extend_fn(cfg: GraniteMoeHybridConfig):
 
     @jax.jit
     def extend(params, tokens, lengths, k_cache, v_cache, ssm, conv, slots, snap_at, snap_slots, *,
-               last=None):
+               last=None, table=None):
         tc = tokens.shape[1]
         (positions, valid), fresh = layers.frame(tokens, lengths), lengths == 0
-        visible = layers.visible_keys(positions, valid, k_cache.shape[2])
+        paged = table is not None
+        visible = layers.visible_keys(positions, valid, layers.cache_slots(k_cache, table))
         live = layers.live_keys(positions, valid)
         with jax.named_scope("extend.embed"):
             x = layers.look_up(params["wte"]["embedding"].astype(dtype), tokens)
@@ -614,12 +626,14 @@ def make_extend_fn(cfg: GraniteMoeHybridConfig):
             # and written where they lie
             x, ssm, tails = carry
             p, kc, vc, period = xs
+            if paged:       # the arenas themselves, this period's layer found by the kernel
+                kc, vc = k_cache, v_cache
             rows, m, counted = None, 0, ()
             for i in range(cfg.period):
                 if i == cfg.attention_at:
                     out, rows = _attend(
                         p["attn"], _normed(x, p["attn"]["ln"]).astype(dtype), positions,
-                        visible, live, kc, vc)
+                        visible, live, kc, vc, (period, table) if paged else None)
                 else:
                     layer = p["mamba"][m]
                     out, ssm, tails = _mamba(
@@ -642,7 +656,8 @@ def make_extend_fn(cfg: GraniteMoeHybridConfig):
         tails = (own, own) if tc > 1 else (own,)    # the new ones; a chunk's kept ones
         (x, ssm, tails), (rows, counted) = jax.lax.scan(
             body, (x, ssm, tails), (
-                params["periods"], k_cache, v_cache, jnp.arange(cfg.periods, dtype=jnp.int32)))
+                params["periods"], *((None, None) if paged else (k_cache, v_cache)),
+                jnp.arange(cfg.periods, dtype=jnp.int32)))
         if tc > 1:
             conv = _put(conv, snap_slots, tails[1])
         conv = _put(conv, slots, tails[0])

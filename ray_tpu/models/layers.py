@@ -16,6 +16,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import attention, backend
+
 #: queries scored or attended at a time in a prefill chunk: the float32 scores of
 #: one block are ``lanes x heads x QUERY_BLOCK x cache`` (134 MB a lane at 128 heads
 #: and an 8192 cache, or at 32 heads and a 32768 cache; a whole 256-token chunk of
@@ -83,6 +85,14 @@ def visible_keys(positions, valid, cache: int):
     return (kpos[None, None, :] <= positions[:, :, None]) & valid[:, :, None]
 
 
+def cache_slots(cache, table=None):
+    """The slots a lane's cache has in an ``extend`` call: those of the padded caches
+    ``cache`` [layers, lanes, slots, ...], or, where the call is handed a block
+    ``table`` [lanes, n] with the pool's arena [layers, blocks, block, ...] in the
+    caches' place, its ``n`` pages'."""
+    return cache.shape[2] * (1 if table is None else table.shape[1])
+
+
 def live_keys(positions, valid):
     """A bound [b] past each lane's farthest real query: no key from there on is in
     any mask, and ``ops/attention.masked_attention`` stops there."""
@@ -126,12 +136,40 @@ def plain_attend(q, k, v, mask, scale):
     """Grouped attention densely: ``q`` [b, n, kv, g, hd] (``g`` query heads a K/V
     head) over ``k``, ``v`` [b, cache, kv, hd] under ``mask`` [b, n, cache]; scores and
     softmax float32, the weights cast to the values' type. The path off the chip,
-    and every decode lane's on it."""
+    and on it a decode lane's whose model is handed padded caches (one handed the
+    pool's pages attends in :func:`paged_attend`)."""
     logit = jnp.einsum(
         "bqhgd,bkhd->bhgqk", q, k, preferred_element_type=jnp.float32) * scale
     weight = jax.nn.softmax(
         jnp.where(mask[:, None, None], logit, jnp.float32(MASKED)), axis=-1)
     return jnp.einsum("bhgqk,bkhd->bqhgd", weight.astype(v.dtype), v)
+
+
+def paged_attend(q, k, v, k_pages, v_pages, at, table, positions, visible, scale):
+    """A decode call's attend (one token a lane) **through the block table**: ``q`` [b, 1,
+    kv, g, hd] over the rows that ``table`` [b, n] names in layer ``at`` of the pool's
+    arenas ``k_pages``, ``v_pages`` ``[layers, blocks, block, 1, kv x width]`` as they lie,
+    and over the call's own rows ``k``, ``v`` [b, 1, 1, kv x width], which stand at
+    ``positions`` [b, 1] and are in no page yet (the engine pages them back after the
+    call). ``visible`` [b, 1, n x block] is :func:`visible_keys` of the lanes' padded
+    caches. On the chip ``ops/attention.paged_attention``: a lane's live pages are read
+    once where they lie, nothing is gathered, written or re-laid out. Off it the same
+    contract densely: the table's pages taken from the arena, the own row written among
+    them, :func:`plain_attend`, which is bit for bit what a call over gathered caches
+    computes. Returns [b, 1, kv, g, value width] in ``q``'s type."""
+    b, _, kv = q.shape[:3]
+    if backend.on_tpu():
+        return attention.paged_attention(
+            q[:, 0], k_pages, v_pages, at, table, positions[:, 0], k[:, 0, 0], v[:, 0, 0],
+            scale=scale)[:, None]
+    lane = jnp.arange(b)[:, None]
+
+    def taken(pages, new):
+        slab = jax.lax.dynamic_index_in_dim(pages, at, 0, keepdims=False)
+        cache = slab[table].reshape((b, -1) + slab.shape[2:])
+        return write_rows(cache, lane, positions, new).reshape(b, cache.shape[1], kv, -1)
+
+    return plain_attend(q, taken(k_pages, k), taken(v_pages, v), visible, scale)
 
 
 def gated_mlp(x, wi, wo):

@@ -292,11 +292,17 @@ def make_extend_fn(cfg: MiMoV2FlashConfig):
     none is to be kept). No other slot is touched, and of a decode lane's slot one
     row. A negative token id is padding and changes no ring; a lane of padding alone
     points at slot 0. ``counters`` (``cfg.counters``) over real lanes and tokens.
+    With ``table=`` [lanes, n] (a call of one token a lane, from an engine that reads
+    the keyword off the signature: ``serve/llm.reads_pages``) ``k_cache`` and
+    ``v_cache`` are the pool's block arenas themselves, ``[cache_layers, blocks,
+    block, 1, kv_heads x features]``, and a full layer attends over the lanes' pages
+    where they lie (:func:`layers.paged_attend`); the same rows come back.
 
     Scopes: ``extend.embed``; ``extend.dense`` (layer 0's MLP); ``extend.attention``
     (a full layer's projections, rotation, cache update and attend: a chunk's on the
-    chip ``ops/attention.masked_attention``, a decode lane's and any off the chip
-    :func:`layers.plain_attend`); ``extend.attention.window`` (a sliding layer's
+    chip ``ops/attention.masked_attention``, a decode lane's through the block table
+    ``ops/attention.paged_attention``, any off the chip :func:`layers.plain_attend`);
+    ``extend.attention.window`` (a sliding layer's
     projections, rotation, the slot's read, the attend under the sink (the same
     kernel with ``sinks``, or densely), the slot's write and the snapshot's);
     ``extend.moe.route``, ``extend.moe.experts``; ``extend.logits`` (the last norm and
@@ -336,11 +342,16 @@ def make_extend_fn(cfg: MiMoV2FlashConfig):
         return x.reshape(x.shape[:2] + (-1,))
 
     @jax.named_scope("extend.attention")
-    def _attend_full(p, hidden, positions, visible, live, kc, vc):
+    def _attend_full(p, hidden, positions, visible, live, kc, vc, paged=None):
+        """``kc``, ``vc`` the layer's slab of the padded caches; or, with ``paged`` (the
+        layer's index and the lanes' block table), the pool's arenas themselves."""
         b, tc = positions.shape
-        cap = kc.shape[1]
         q, k, v = _project(p, hidden, positions, cfg.rope_base)
         k, v = _rows(k)[:, :, None], _rows(v)[:, :, None]       # [b, tc, 1, kv x width]
+        if paged is not None:
+            out = layers.paged_attend(q, k, v, kc, vc, *paged, positions, visible, scale)
+            return _out(p, out), k, v
+        cap = kc.shape[1]
         lane = jnp.arange(b)[:, None]
         keys = layers.write_rows(kc, lane, positions, k).reshape(b, cap, cfg.kv_heads, hd)
         values = layers.write_rows(vc, lane, positions, v).reshape(b, cap, cfg.kv_heads, vd)
@@ -490,24 +501,24 @@ def make_extend_fn(cfg: MiMoV2FlashConfig):
 
     @jax.jit
     def extend(params, tokens, lengths, k_cache, v_cache, k_window, v_window, slots, snap_at,
-               snap_slots, *, last=None):
+               snap_slots, *, last=None, table=None):
         positions, valid = layers.frame(tokens, lengths)
         lengths = lengths.astype(jnp.int32)
         where = (slots, snap_at, snap_slots)
-        reads = (
-            layers.visible_keys(positions, valid, k_cache.shape[2]),
-            layers.live_keys(positions, valid))
+        cap = layers.cache_slots(k_cache, table)
+        reads = (layers.visible_keys(positions, valid, cap), layers.live_keys(positions, valid))
         with jax.named_scope("extend.embed"):
             x = layers.look_up(params["wte"]["embedding"].astype(dtype), tokens)
         experts = params["experts"]
 
         def full_layer(x, p, at, ffn):
-            # the layer's slab of the caches where it lies
-            kc, vc = (
+            # the layer's slab of the caches where it lies; the arenas' the kernel finds
+            kc, vc = (k_cache, v_cache) if table is not None else (
                 jax.lax.dynamic_index_in_dim(c, at, 0, keepdims=False)
                 for c in (k_cache, v_cache))
             a, k, v = _attend_full(
-                p["attn"], _normed(x, p, "ln_1").astype(dtype), positions, *reads, kc, vc)
+                p["attn"], _normed(x, p, "ln_1").astype(dtype), positions, *reads, kc, vc,
+                None if table is None else (at, table))
             x = x + a
             f, counted = ffn(_normed(x, p, "ln_2"))
             return x + f, (k, v), counted
@@ -553,7 +564,7 @@ def make_extend_fn(cfg: MiMoV2FlashConfig):
             x, params["ln_f"]["scale"], cfg.norm_eps, params["head"]["kernel"], dtype, last)
         seen = jnp.where(valid, positions + 1, 0)
         attended = jnp.stack([
-            cfg.cache_layers * jnp.minimum(seen, k_cache.shape[2]).sum(dtype=jnp.int32),
+            cfg.cache_layers * jnp.minimum(seen, cap).sum(dtype=jnp.int32),
             cfg.window_layers * jnp.minimum(seen, window).sum(dtype=jnp.int32)])
         return (
             logits, x, k_new, v_new, k_window, v_window,

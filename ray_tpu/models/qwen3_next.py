@@ -425,14 +425,19 @@ def make_extend_fn(cfg: Qwen3NextConfig):
     ``snap_slots[lane]`` (0, nobody's, where none is to be kept). No other slot is
     touched. A lane of length 0 starts from zeros whatever its slot holds; a negative
     token id is padding and changes no state; a lane of padding alone points at slot 0.
-    ``counters`` (``cfg.counters``) over real lanes and tokens.
+    ``counters`` (``cfg.counters``) over real lanes and tokens. With ``table=`` [lanes, n]
+    (a call of one token a lane, from an engine that reads the keyword off the signature:
+    ``serve/llm.reads_pages``) ``k_cache`` and ``v_cache`` are the pool's block arenas
+    themselves, ``[cache_layers, blocks, block, 1, kv_heads x head_dim]``, and a full
+    layer attends over the lanes' pages where they lie (:func:`layers.paged_attend`).
 
     Scopes: ``extend.embed``; ``extend.delta`` (projections, convolution, norms, gate,
     out) with ``extend.delta.scan`` inside it (the recurrence alone in either form, with
     the state's read and its writes); ``extend.attention`` (a full layer's projections,
     norms, rotation, cache update, attend and gate: a chunk's attend on the chip
-    ``ops/attention.masked_attention``, a decode lane's and any off the chip
-    :func:`layers.plain_attend`); ``extend.moe.route`` (the router), ``extend.moe.experts``
+    ``ops/attention.masked_attention``, a decode lane's through the block table
+    ``ops/attention.paged_attention``, any off the chip :func:`layers.plain_attend`);
+    ``extend.moe.route`` (the router), ``extend.moe.experts``
     and ``extend.moe.shared`` (with its gate); ``extend.logits`` (the last norm and the
     head, of the rows that are read: ``last=``, ``layers.read_rows``; every row without
     it)."""
@@ -538,9 +543,10 @@ def make_extend_fn(cfg: Qwen3NextConfig):
         return out, arena, tails
 
     @jax.named_scope("extend.attention")
-    def _attend(p, hidden, positions, visible, live, kc, vc):
+    def _attend(p, hidden, positions, visible, live, kc, vc, paged=None):
+        """``kc``, ``vc`` the layer's slab of the padded caches; or, with ``paged`` (the
+        layer's index and the lanes' block table), the pool's arenas themselves."""
         b, tc = positions.shape
-        cap = kc.shape[1]
         both = (hidden @ p["q"]["kernel"].astype(dtype)).reshape(b, tc, cfg.num_heads, 2 * hd)
         q, gate = both[..., :hd], both[..., hd:]
 
@@ -553,16 +559,21 @@ def make_extend_fn(cfg: Qwen3NextConfig):
             (hidden @ p["k"]["kernel"].astype(dtype)).reshape(b, tc, kv, hd), "k_norm")
         k = k.reshape(b, tc, 1, kv * hd)                            # one row for all K/V heads
         v = (hidden @ p["v"]["kernel"].astype(dtype))[:, :, None]
-        lane = jnp.arange(b)[:, None]
-        keys = layers.write_rows(kc, lane, positions, k).reshape(b, cap, kv, hd)
-        values = layers.write_rows(vc, lane, positions, v).reshape(b, cap, kv, hd)
-        if tc > 1 and backend.on_tpu():
-            out = attention.masked_attention(q, keys, values, visible, live, scale=scale)
-        elif tc == 1:
-            out = layers.plain_attend(q, keys, values, visible, scale)
+        if paged is not None:
+            out = layers.paged_attend(q, k, v, kc, vc, *paged, positions, visible, scale)
         else:
-            out = layers.by_query_block(
-                lambda qb, mask: layers.plain_attend(qb, keys, values, mask, scale), q, visible)
+            cap = kc.shape[1]
+            lane = jnp.arange(b)[:, None]
+            keys = layers.write_rows(kc, lane, positions, k).reshape(b, cap, kv, hd)
+            values = layers.write_rows(vc, lane, positions, v).reshape(b, cap, kv, hd)
+            if tc > 1 and backend.on_tpu():
+                out = attention.masked_attention(q, keys, values, visible, live, scale=scale)
+            elif tc == 1:
+                out = layers.plain_attend(q, keys, values, visible, scale)
+            else:
+                out = layers.by_query_block(
+                    lambda qb, mask: layers.plain_attend(qb, keys, values, mask, scale),
+                    q, visible)
         gated = out.reshape(b, tc, cfg.num_heads, hd).astype(f32) * jax.nn.sigmoid(
             gate.astype(f32))
         out = jnp.dot(
@@ -594,10 +605,11 @@ def make_extend_fn(cfg: Qwen3NextConfig):
 
     @jax.jit
     def extend(params, tokens, lengths, k_cache, v_cache, delta, conv, slots, snap_at,
-               snap_slots, *, last=None):
+               snap_slots, *, last=None, table=None):
         tc = tokens.shape[1]
         (positions, valid), fresh = layers.frame(tokens, lengths), lengths == 0
-        visible = layers.visible_keys(positions, valid, k_cache.shape[2])
+        paged = table is not None
+        visible = layers.visible_keys(positions, valid, layers.cache_slots(k_cache, table))
         live = layers.live_keys(positions, valid)
         with jax.named_scope("extend.embed"):
             x = layers.look_up(params["wte"]["embedding"].astype(dtype), tokens)
@@ -608,13 +620,15 @@ def make_extend_fn(cfg: Qwen3NextConfig):
             # written where they lie
             x, delta, tails = carry
             p, kc, vc, period = xs
+            if paged:       # the arenas themselves, this period's layer found by the kernel
+                kc, vc = k_cache, v_cache
             rows, counted = None, []
             for i in range(cfg.period):
                 if i == per_period:
                     layer = p["full"]
                     out, rows = _attend(
                         layer, _normed(x, layer["ln"]).astype(dtype), positions, visible, live,
-                        kc, vc)
+                        kc, vc, (period, table) if paged else None)
                 else:
                     layer = p["delta"][i]
                     out, delta, tails = _delta(
@@ -633,7 +647,8 @@ def make_extend_fn(cfg: Qwen3NextConfig):
         tails = (own, own) if tc > 1 else (own,)    # the new ones; a chunk's kept ones
         (x, delta, tails), (rows, counted) = jax.lax.scan(
             body, (x, delta, tails), (
-                params["periods"], k_cache, v_cache, jnp.arange(cfg.periods, dtype=jnp.int32)))
+                params["periods"], *((None, None) if paged else (k_cache, v_cache)),
+                jnp.arange(cfg.periods, dtype=jnp.int32)))
         if tc > 1:
             conv = _put(conv, snap_slots, tails[1])
         conv = _put(conv, slots, tails[0])

@@ -972,3 +972,149 @@ def latent_attention(
         k_up.reshape(rank, n_heads * nope), v_up.reshape(rank, n_heads * dv),
         mask.astype(jnp.int8))
     return out.reshape(b, n_heads, t + pad_q, dv).transpose(0, 2, 1, 3)[:, :t]
+
+
+# ---------------------------------------------------------------------------
+# Pallas kernel for serving: a decode lane over its pages, where the pool keeps them
+# ---------------------------------------------------------------------------
+
+
+def _paged_fwd_kernel(
+    at_ref, table_ref, pages_ref, lengths_ref, q_ref, k_ref, v_ref, k_own_ref, v_own_ref,
+    o_ref, acc_ref, m_ref, l_ref, *, scale
+):
+    from jax.experimental import pallas as pl
+
+    lane, page = pl.program_id(0), pl.program_id(1)
+    block = k_ref.shape[0]
+
+    @pl.when(page == 0)
+    def _init():
+        # the call's own row, which no page holds yet, is the key every lane starts
+        # from: the maximum at its score, the sum at exp(0), the accumulator at its value
+        own = (q_ref[0].astype(jnp.float32) * k_own_ref[0].astype(jnp.float32)).sum(
+            -1, keepdims=True) * scale
+        m_ref[:] = own
+        l_ref[:] = jnp.ones_like(l_ref)
+        acc_ref[:] = jnp.broadcast_to(v_own_ref[0].astype(jnp.float32), acc_ref.shape)
+
+    @pl.when(page < pages_ref[lane])
+    def _run():
+        k, v = k_ref[:], v_ref[:]                         # [block, kv x width]
+        s = jax.lax.dot_general(
+            q_ref[0], k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
+        slot = page * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(slot < lengths_ref[lane], s, NEG_INF)
+        m_prev = m_ref[:]                                  # [heads, 1]
+        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[:] = alpha * l_ref[:] + p.sum(-1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_ref[:] = m_new
+
+    @pl.when(page == pl.num_programs(1) - 1)
+    def _finish():
+        o_ref[0] = (acc_ref[:] / l_ref[:]).astype(o_ref.dtype)
+
+
+def paged_attention(
+    q: jax.Array,
+    k_pages: jax.Array,
+    v_pages: jax.Array,
+    at,
+    table: jax.Array,
+    lengths: jax.Array,
+    k_own: jax.Array,
+    v_own: jax.Array,
+    *,
+    scale: Optional[float] = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """A decode call's attend through the block table, as one kernel: each lane's one
+    query a head over the ``lengths[lane]`` cached rows that the first pages of
+    ``table[lane]`` hold, **where the pool keeps them**, and over the call's own row,
+    which is in no page yet. Nothing is gathered and nothing written before the attend.
+
+    ``q`` [lanes, kv, groups, d]; ``k_pages`` [layers, blocks, block, 1, kv x d] and
+    ``v_pages`` [layers, blocks, block, 1, kv x dv] the pool's arenas as they lie (all
+    K/V heads of a row side by side) and ``at`` the layer's index in them (traced: a
+    scan's layer); ``table`` [lanes, n] int32 the lanes' block ids and ``lengths``
+    [lanes] int32, both prefetched to scalar memory, where the index map reads them;
+    ``k_own`` [lanes, kv x d] and ``v_own`` [lanes, kv x dv] the row the call brings for
+    each lane, at position ``lengths[lane]``. A grid step is one page of one lane: all
+    K/V heads' rows of it, fetched once as one block (256 x 768 bfloat16 at MiMo's
+    sizes: 393 KB). A step past the lane's last live page names the page already there
+    (no fetch) and computes nothing, so an entry of ``table`` past it is never
+    followed, whatever it names; a slot at or past ``lengths[lane]`` inside the last
+    page is masked, and must hold something finite (the pool's arenas do: zeros, or
+    what a model wrote). The running maximum, sum and accumulator start from the own
+    row the way :func:`masked_attention`'s start from a sink.
+
+    All query heads meet a page in one product: the queries go in **block-diagonal**,
+    ``[kv x groups, kv x d]`` with a head's ``d`` features in its K/V head's columns
+    and zeros elsewhere, so ``q . page^T`` scores every head against its own K/V head's
+    columns of the rows as they lie, whatever ``d`` is (MiMo's 192 is no whole number
+    of the chip's 128 lanes: no slice, no re-layout); ``p . page`` gives every head all
+    heads' values, of which its own columns are picked from the result. The MXU's
+    time on a decode lane is loading a page as the stationary operand, the same
+    tiles either way; the zeros cost passes of at most 64 rows. Scores, softmax and
+    accumulator float32, the weights in ``v``'s type, as :func:`masked_attention`.
+    Returns [lanes, kv, groups, dv] in ``q``'s type. A lane of length 0 (padding)
+    gets its own row's value."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    lanes, kv, groups, d = q.shape
+    block, n = k_pages.shape[2], table.shape[1]
+    dv = v_pages.shape[-1] // kv
+    heads = kv * groups
+    scale = float(scale) if scale is not None else 1.0 / float(np.sqrt(d))
+    lengths = lengths.astype(jnp.int32)
+    pages = jnp.clip((lengths + block - 1) // block, 0, n)
+    # a head's features in its K/V head's columns of a row, zeros in the others'
+    diagonal = jnp.einsum("bhgd,hk->bhgkd", q, jnp.eye(kv, dtype=q.dtype)).reshape(
+        lanes, heads, kv * d)
+
+    def page_of(lane, page, at, table, pages, lengths):
+        # past the lane's last live page: the page already there, no fetch
+        live = jnp.maximum(jnp.minimum(page, pages[lane] - 1), 0)
+        return at[0], table[lane * n + live], 0, 0
+
+    def per_lane(rows, width):
+        return pl.BlockSpec((1, rows, width), lambda lane, page, *_: (lane, 0, 0))
+
+    def paged(width):
+        return pl.BlockSpec((None, None, block, width), page_of)
+
+    out = pl.pallas_call(
+        functools.partial(_paged_fwd_kernel, scale=scale),
+        name="paged_attention",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(lanes, n),
+            in_specs=[
+                per_lane(heads, kv * d), paged(kv * d), paged(kv * dv),
+                per_lane(1, kv * d), per_lane(1, kv * dv)],
+            out_specs=per_lane(heads, kv * dv),
+            scratch_shapes=[
+                pltpu.VMEM((heads, kv * dv), jnp.float32),
+                pltpu.VMEM((heads, 1), jnp.float32),
+                pltpu.VMEM((heads, 1), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((lanes, heads, kv * dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(
+        jnp.asarray(at, jnp.int32).reshape(1), table.astype(jnp.int32).reshape(lanes * n),
+        pages, lengths, diagonal,
+        # the arenas without their axis of one, which the device keeps behind a block's
+        # tokens: the same bytes
+        k_pages.reshape(k_pages.shape[:3] + k_pages.shape[4:]),
+        v_pages.reshape(v_pages.shape[:3] + v_pages.shape[4:]),
+        k_own.reshape(lanes, 1, kv * d), v_own.reshape(lanes, 1, kv * dv))
+    # a head's own columns of what it summed over all heads' values
+    out = out.reshape(lanes, kv, groups, kv, dv)
+    return jnp.stack([out[:, h, :, h] for h in range(kv)], axis=1)

@@ -40,7 +40,9 @@ serving setup from PAPERS.md):
   twice: everything the host decides for the call (tokens, lengths, block
   table, page-back slots, the row of logits each lane reads) goes up as one
   int32 buffer (:func:`_sections`); one jitted program gathers the padded
-  caches ``extend`` takes from the arenas; ``extend`` runs, and makes the last
+  caches ``extend`` takes from the arenas (not for a decode call of a model
+  whose ``extend`` reads a lane's pages where the pool keeps them, below);
+  ``extend`` runs, and makes the last
   norm, the head and the float32 hidden row for **the one row a lane that is
   read** (``_LAST``: a lane's last valid token where the lane emits; a chunk in
   which no lane does, as a prompt's chunks but its last, runs no head at all:
@@ -51,6 +53,17 @@ serving setup from PAPERS.md):
   all that comes home. The rows follow only for a lane that asked for its
   logits or has an adapter. Every such program is compiled when the engine is
   built.
+* a decode call attends through the block table — where a model's ``extend``
+  offers ``table=`` (:func:`reads_pages`: ``models/mimo_v2_flash.py``,
+  ``models/qwen3_next.py``, ``models/granitemoehybrid.py``), a call of one token
+  a lane is handed the pool's arenas themselves (not donated: the page-back
+  donates them after, in launch order) and the ``table`` section of the operand
+  buffer it already uploads, and runs no gather: each full-attention layer reads a
+  lane's live pages once where they lie (``ops/attention.paged_attention``), the
+  call's own row beside them, and nothing of the call grows with ``lanes x
+  bucket`` but the grid's dead steps. Same programs' names
+  (``extend_decode_<b>x1x<cap>``); ``llm.dispatch`` and
+  ``stats()["calls"][form]`` say ``paged``. A chunk gathers as ever.
 * every program under a name of its own — JAX names a compiled module after
   the function it was traced from, and an instruction's name is unique in its
   module only, so one ``jax.jit`` run in sixteen shapes is sixteen modules a
@@ -112,7 +125,9 @@ serving setup from PAPERS.md):
   number, ``prefill`` or ``decode``, the ``program`` it runs as: the name of
   the module the device then shows, its lanes, tokens and cache tokens
   beside the slots of its padded shape, ``heads``: the lanes whose row of
-  logits is read, 0 where the head did not run; whether a call was in flight) and
+  logits is read, 0 where the head did not run; ``paged``: 1 where it read its
+  lanes' pages through the block table and ran no gather; whether a call was in
+  flight) and
   ``llm.fetch`` which call it lands and what ``extend`` counted in it; always
   on, ``stats()["calls"]`` sums the same per form of call, with the time the
   device spent on each (``busy_s``), and ``stats()["programs"]`` the calls and
@@ -132,6 +147,7 @@ import contextlib
 import copy
 import functools
 import hashlib
+import inspect
 import math
 import queue as queue_mod
 import threading
@@ -574,9 +590,11 @@ class KVBlockPool:
         :meth:`read_block`, for tests and probes and not for the step path."""
         return tuple(np.asarray(a[:, slot]) for a in self.states)
 
-    def warm(self, extend_shapes: Dict[Any, Any], cache_buckets) -> None:
+    def warm(self, extend_shapes: Dict[Any, Any], cache_buckets, gathered=None) -> None:
         """Compile every paging program the engine's buckets allow, on zeros
-        made on the device: the gather per (lanes, cache bucket), the
+        made on the device: the gather per (lanes, cache bucket), for the
+        lanes in ``gathered`` where given (an engine whose decode calls read
+        pages gathers for its chunks' lanes alone), the
         page-back per (lanes, tokens) of ``extend_shapes`` (that extend
         call's output shapes), the clone; where the pool holds states, their
         copy. The page-back writes no token here, so the arenas keep their
@@ -592,7 +610,7 @@ class KVBlockPool:
         def zeros(shapes):
             return tuple(jnp.zeros(x.shape, x.dtype) for x in shapes)
 
-        for b in sorted({b for b, _ in extend_shapes}):
+        for b in sorted({b for b, _ in extend_shapes}) if gathered is None else gathered:
             operands = jnp.zeros((b, width), jnp.int32)
             for cap in cache_buckets:
                 jax.block_until_ready(
@@ -976,23 +994,37 @@ def _operand_extend(extend, caches: int = 0, states: int = 0):
     state per sequence, the pool's ``states`` arenas follow the ``caches``
     (donated: the ones ``extend`` returns take their place) and ``extend`` is told
     each lane's slot in them and where to keep a state for the prefix cache
-    (``_SLOT``, ``_SNAP_AT``, ``_SNAP_SLOT``)."""
+    (``_SLOT``, ``_SNAP_AT``, ``_SNAP_SLOT``). With ``pages`` (static, as ``tc`` is) the
+    ``caches`` are the pool's arenas themselves, not donated, and ``extend`` is told the
+    lanes' first ``pages`` block ids (``table=``, the buffer's ``table`` section): a decode
+    call of a model that reads a lane's pages where the pool keeps them
+    (:func:`reads_pages`)."""
     import jax
     import jax.numpy as jnp
 
     donated = dict(donate_argnums=tuple(range(3 + caches, 3 + caches + states))) if states else {}
 
-    @functools.partial(accelerator.Programs, static_argnames="tc", **donated)
-    def extend_call(params, operands, home, *arrays, tc):
+    @functools.partial(accelerator.Programs, static_argnames=("tc", "pages"), **donated)
+    def extend_call(params, operands, home, *arrays, tc, pages=0):
         tokens, source = _sections(operands)[0][:, :tc], operands[:, _FROM]
         first = jnp.where(source < 0, tokens[:, 0], home[jnp.maximum(source, 0)])
         where = tuple(
             operands[:, at] for at in (_SLOT, _SNAP_AT, _SNAP_SLOT)) if states else ()
+        table = dict(table=_sections(operands)[3][:, :pages]) if pages else {}
         return extend(
             params, tokens.at[:, 0].set(first), operands[:, _LENGTH], *arrays, *where,
-            last=operands[:, _LAST])
+            last=operands[:, _LAST], **table)
 
     return extend_call
+
+
+def reads_pages(extend) -> bool:
+    """Whether a decode call of ``extend`` attends through the block table: what the
+    model's ``extend`` offers, a ``table=`` keyword (``models/mimo_v2_flash.py``,
+    ``models/qwen3_next.py``, ``models/granitemoehybrid.py``). Such a call is handed
+    the pool's arenas where another is handed padded caches, and runs no gather.
+    Nothing else decides it: no key of a configuration, no keyword of the engine."""
+    return "table" in inspect.signature(extend).parameters
 
 
 def _extend_name(b: int, tc: int, cap: int) -> str:
@@ -1118,6 +1150,8 @@ class LLMEngine:
         self._stateful = bool(state_arrays)
         self._extend_call = _operand_extend(
             self._extend, len(self.cfg.cache_arrays), len(state_arrays))
+        #: whether a decode call reads the lanes' pages in the arenas (no gather)
+        self._reads_pages = reads_pages(self._extend)
         self.deployment = deployment
         self.block_size = int(block_size)
         self.prefill_chunk = int(prefill_chunk)
@@ -1212,8 +1246,11 @@ class LLMEngine:
         #: per form of call, over the device calls: how many; their real lanes
         #: and their lane buckets; the lanes whose row of logits was read
         #: (``heads``); the tokens fed and lanes x token bucket; the
-        #: live tokens gathered into padded caches and lanes x cache bucket;
-        #: and ``busy_s``, a call's landing less the later of its own launch's
+        #: live tokens in the lanes' caches and lanes x cache bucket (gathered
+        #: into padded caches, or read through the block table: ``paged``, the
+        #: calls that ran no gather; every decode call of a model whose
+        #: ``extend`` reads pages, none of any other); and ``busy_s``, a call's
+        #: landing less the later of its own launch's
         #: return and the landing before it: what the device spent on it as
         #: the host sees it, exact while the device is never idle between
         #: calls and an upper bound otherwise. The totals over both forms are
@@ -1221,7 +1258,7 @@ class LLMEngine:
         self.calls: Dict[str, Dict[str, Any]] = {
             form: dict(
                 n=0, lanes_used=0, lane_slots=0, heads=0, tokens=0, token_slots=0,
-                cache_tokens=0, cache_slots=0, busy_s=0.0)
+                cache_tokens=0, cache_slots=0, paged=0, busy_s=0.0)
             for form in FORMS}
         #: the same ``n`` and ``busy_s`` per program, by its name (the module a
         #: profile shows, ``llm.dispatch``'s ``program``): a group for every
@@ -1276,8 +1313,9 @@ class LLMEngine:
         def extend_outputs(b, tc):
             cap = self.cache_buckets[0]
             return jax.eval_shape(
-                functools.partial(self._extend_call, _extend_name(b, tc, cap), tc=tc),
-                *self._extend_args(jax.ShapeDtypeStruct, b, cap))
+                functools.partial(
+                    self._extend_call, _extend_name(b, tc, cap), **self._extend_statics(tc, cap)),
+                *self._extend_args(jax.ShapeDtypeStruct, b, tc, cap))
 
         outputs = {
             (b, tc): extend_outputs(b, tc)
@@ -1293,17 +1331,31 @@ class LLMEngine:
             rows, _, counts = self.pool.split_outputs(rest)
             self._output_bytes[shape] = sum(
                 math.prod(o.shape) * o.dtype.itemsize for o in (logits, hidden, *rows, *counts))
-        self.pool.warm(outputs, self.cache_buckets)
+        # a call that reads pages gathers nothing: no gather for its lanes alone
+        self.pool.warm(outputs, self.cache_buckets, sorted({
+            b for b, tc, _ in self.extend_shapes() if not self._paged(tc)}))
 
-    def _extend_args(self, make, b: int, cap: int, states=None):
-        """The arrays ``_extend_call`` takes for ``b`` lanes and a cache of
-        ``cap``, each made by ``make(shape, dtype)``; for the pool's state arenas
-        ``states`` where given (a call that runs is handed the arenas)."""
-        caches = (
-            make((self.pool.layers, b, cap // grain) + tuple(each[:2]), self.pool.dtype)
-            for each, grain in zip(self.cfg.cache_arrays, self.pool.grains))
-        if states is None:
-            states = (make(s.shape, s.dtype) for s in self.pool.states)
+    def _paged(self, tc: int) -> bool:
+        """Whether a call of ``tc`` tokens a lane reads the pool's pages where they
+        lie: a decode call of a model whose ``extend`` offers it."""
+        return tc == 1 and self._reads_pages
+
+    def _extend_statics(self, tc: int, cap: int) -> Dict[str, int]:
+        """What shapes ``_extend_call``'s program beside its arrays."""
+        return dict(tc=tc, pages=cap // self.block_size) if self._paged(tc) else dict(tc=tc)
+
+    def _extend_args(self, make, b: int, tc: int, cap: int, held: bool = False):
+        """The arrays ``_extend_call`` takes for ``b`` lanes of ``tc`` tokens over a
+        cache of ``cap``, each made by ``make(shape, dtype)``; with ``held`` the
+        pool's own state arenas (a call that runs is handed them) and, for a call
+        that reads pages, its block arenas in the caches' place."""
+        if self._paged(tc):
+            caches = self.pool.arenas if held else (make(a.shape, a.dtype) for a in self.pool.arenas)
+        else:
+            caches = (
+                make((self.pool.layers, b, cap // grain) + tuple(each[:2]), self.pool.dtype)
+                for each, grain in zip(self.cfg.cache_arrays, self.pool.grains))
+        states = self.pool.states if held else (make(s.shape, s.dtype) for s in self.pool.states)
         return (
             self._params, make((b, self._operand_width), np.int32),
             make((self._home_width,), np.int32), *caches, *states)
@@ -1329,7 +1381,8 @@ class LLMEngine:
         compile; each program has its group in ``stats()["programs"]`` from
         here on. Returns how many ``shapes``, the seconds it took
         (``warm_s``) and, where the compiler says, the bytes of the largest one
-        (``compiled``), whose temporaries ``_fits`` then counts for any call:
+        (``compiled``: by lanes x cache, among the shapes that are handed padded
+        caches), whose temporaries ``_fits`` then counts for any call:
         compiling every shape a second time to ask each would double this."""
         import jax
         import jax.numpy as jnp
@@ -1339,14 +1392,19 @@ class LLMEngine:
         for b, tc, cap in shapes:
             name = _extend_name(b, tc, cap)
             _, _, *rest = jax.block_until_ready(self._extend_call(
-                name, *self._extend_args(jnp.zeros, b, cap, self.pool.states), tc=tc))
+                name, *self._extend_args(jnp.zeros, b, tc, cap, held=True),
+                **self._extend_statics(tc, cap)))
             self.pool.states = self.pool.split_outputs(rest)[1]
             if name not in self.programs:
                 self._new_program(name)
         warm_s = time.perf_counter() - t0
-        b, tc, cap = largest = max(shapes, key=lambda s: (s[0] * s[2], s[1]))
+        # of the shapes that hold padded caches (a call that reads pages holds none,
+        # and next to no temporaries: ``_fits`` would count too little for a chunk)
+        b, tc, cap = largest = max(
+            shapes, key=lambda s: (not self._paged(s[1]), s[0] * s[2], s[1]))
         memory = self._extend_call.lower(
-            _extend_name(b, tc, cap), *self._extend_args(jax.ShapeDtypeStruct, b, cap), tc=tc
+            _extend_name(b, tc, cap), *self._extend_args(jax.ShapeDtypeStruct, b, tc, cap),
+            **self._extend_statics(tc, cap)
         ).compile().memory_analysis()
         if memory is not None:
             self._temp_bytes = memory.temp_size_in_bytes
@@ -1800,23 +1858,29 @@ class LLMEngine:
             self.h2d_bytes += operands.nbytes
             self.h2d_transfers += 1
             operands = jax.device_put(operands)
-        with self._phase("kv_gather"):
-            # slots past a lane's frontier hold what the pool holds there:
-            # zeros or finite model output, which extend's mask weighs 0
-            caches = self.pool.gather(operands, t_cap // bs)
-            if self._count_gathered is not None:
-                for name, n in self._count_gathered(b, t_cap).items():
-                    self.counted[name] += n
-            if self._window_layers:
-                # live tokens older than the oldest position the lane's first
-                # query sees, length - window + 1
-                self.window_slots += self._window_layers * b * t_cap
-                self.window_slots_outside += self._window_layers * sum(
-                    max(0, st.length - self._window + 1) for st in states)
+        paged = self._paged(tc)
+        if paged:
+            # the call reads its lanes' pages where the pool keeps them: it is handed
+            # the arenas and its table, and there is no gather and no phase of one
+            caches = self.pool.arenas
+        else:
+            with self._phase("kv_gather"):
+                # slots past a lane's frontier hold what the pool holds there:
+                # zeros or finite model output, which extend's mask weighs 0
+                caches = self.pool.gather(operands, t_cap // bs)
+                if self._count_gathered is not None:
+                    for name, n in self._count_gathered(b, t_cap).items():
+                        self.counted[name] += n
+                if self._window_layers:
+                    # live tokens older than the oldest position the lane's first
+                    # query sees, length - window + 1
+                    self.window_slots += self._window_layers * b * t_cap
+                    self.window_slots_outside += self._window_layers * sum(
+                        max(0, st.length - self._window + 1) for st in states)
         # what the call is: on its span, and summed under its form
         what = dict(
             lanes=len(states), lane_slots=b, heads=sum(emits), tokens=fed,
-            token_slots=b * tc, cache_tokens=cached, cache_slots=b * t_cap)
+            token_slots=b * tc, cache_tokens=cached, cache_slots=b * t_cap, paged=int(paged))
         # a program nothing has run yet (a shape the warm-up left out, an engine
         # that was never warmed): this call traces and compiles it, and says so
         cold = program not in self.programs
@@ -1829,7 +1893,7 @@ class LLMEngine:
             logits, hidden, *rest = self._extend_call(
                 program, self._params, operands,
                 self._no_home if flight is None else flight.home, *caches,
-                *self.pool.states, tc=tc)
+                *self.pool.states, **self._extend_statics(tc, t_cap))
             news, self.pool.states, counted = self.pool.split_outputs(rest)
             del caches, rest        # the caches are freed when extend has run
             if cold:
@@ -1895,7 +1959,8 @@ class LLMEngine:
         if not self._counts_bytes:
             return True
         memory = self._device.memory_stats()
-        need = self.pool.cache_bytes(b * t_cap) + self._output_bytes[b, tc] + self._temp_bytes
+        caches = 0 if self._paged(tc) else self.pool.cache_bytes(b * t_cap)
+        need = caches + self._output_bytes[b, tc] + self._temp_bytes
         free = memory["bytes_limit"] - memory["bytes_in_use"]
         return need <= min(free, memory.get("largest_free_block_bytes", free))
 

@@ -6,7 +6,9 @@ prints one ``name sha256`` line a program:
 * a serve cell: ``cfg.make_extend_fn()`` at every (lanes, tokens, cache) that
   ``LLMEngine.extend_shapes`` lists for the configuration file's engine sizes,
   over abstract parameters, caches and state arenas (no engine, no pool; the
-  paging programs are ``serve/llm.py``'s and are not here);
+  paging programs are ``serve/llm.py``'s and are not here); a decode call of a model
+  whose ``extend`` reads pages (``llm.reads_pages``) as the engine calls it, over the
+  pool's arenas and a block table;
 * a train cell: the step at the cell's batch and mesh, on as many virtual
   devices as the cell has chips;
 * ``init_params``: the leaves that the architecture's tiny preset draws from
@@ -63,19 +65,27 @@ def serve_programs(cell, cfg, engine):
         abstract((n, engine["state_slots"]) + tuple(shape), dtype)
         for n, shape, dtype in getattr(cfg, "state_arrays", ())]
     prefill_lanes = batching.bucket_pad_size(engine["prefill_lanes"], engine["lane_buckets"])
+    # (a checkout from before PR 61 has no such question: nothing reads pages there)
+    reads_pages = getattr(llm, "reads_pages", lambda extend: False)(extend)
     for lanes in sorted(engine["lane_buckets"]):
         for tc in [1] + sorted(engine["prefill_token_buckets"]):
             if tc > 1 and lanes > prefill_lanes:
                 continue
             for cap in sorted(engine["cache_buckets"]):
                 # an array with a third element holds a row for every so many tokens
+                paged = tc == 1 and reads_pages
+                block = engine["block_size"]
                 caches = [
-                    abstract((layers, lanes, cap // llm.cache_grain(each)) + tuple(each[:2]),
-                             cfg.dtype)
+                    abstract(
+                        (layers, engine["num_blocks"], block) + tuple(each[:2]) if paged
+                        else (layers, lanes, cap // llm.cache_grain(each)) + tuple(each[:2]),
+                        cfg.dtype)
                     for each in cfg.cache_arrays]
                 where = [abstract((lanes,))] * 3 if states else []
+                table = dict(table=abstract((lanes, cap // block))) if paged else {}
                 say(f"{cell} extend {lanes}x{tc}x{cap}", lowered(extend.trace(
-                    params, abstract((lanes, tc)), abstract((lanes,)), *caches, *states, *where)))
+                    params, abstract((lanes, tc)), abstract((lanes,)), *caches, *states, *where,
+                    **table)))
 
 
 def train_program(cell, cfg, job, chips):

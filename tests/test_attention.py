@@ -5,11 +5,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from ray_tpu.models import layers
 from ray_tpu.ops.attention import (
     _attention_xla,
     _flash_attention_tpu,
     dot_product_attention,
     flash_attention,
+    paged_attention,
 )
 
 
@@ -118,3 +120,75 @@ def test_flash_backward_ragged_q_blocks():
         lambda k: flash_attention(q, k, v, True, scale, 64, 64, True).sum(), 0
     )(k)
     np.testing.assert_allclose(g_out, g_ref, atol=5e-5, rtol=1e-3)
+
+
+# -- a decode lane over its pages, where the pool keeps them -------------------
+
+
+#: (K/V heads, query heads a K/V head, key width, value width): the adopting
+#: configurations' full-attention layers at their published head shapes
+PAGED_SHAPES = {
+    "mimo-v2-flash": (4, 16, 192, 128),     # keys no whole number of the chip's 128 lanes
+    "qwen3-next": (2, 8, 256, 256),
+    "granite-4h-micro": (8, 4, 64, 64),      # a head of half a lane tile
+    "granite-4h-small": (8, 4, 128, 128),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", list(PAGED_SHAPES))
+def test_paged_attention_reads_a_lanes_live_pages_and_its_own_row(shape, dtype):
+    """``paged_attention`` (interpreted) against ``layers.plain_attend`` over the same
+    rows laid side by side with the own row written among them, in one call of five
+    lanes over layer 1 of two: a lane that ends mid-page, a padded lane of length 0
+    (its own row alone), a lane that fills every page but one slot, a lane of exactly
+    one page, and a lane that shares its first page with lane 0 (a cached prefix). Every
+    table entry past a lane's live pages names a block of NaN, as does all of layer 0:
+    the result is finite and equal, so they are neither fetched into the sum nor read.
+    Slots past a lane's length inside its last page hold other rows' finite values,
+    which the mask weighs 0."""
+    kv, groups, d, dv = PAGED_SHAPES[shape]
+    block, n, blocks, lanes, at = 16, 4, 12, 5, 1
+    rng = np.random.default_rng(61)
+    nan = blocks - 1
+    k_pages = rng.normal(size=(2, blocks, block, 1, kv * d)).astype(np.float32)
+    v_pages = rng.normal(size=(2, blocks, block, 1, kv * dv)).astype(np.float32)
+    for pages in (k_pages, v_pages):
+        pages[0], pages[:, nan] = np.nan, np.nan
+    lengths = np.array([2 * block + 5, 0, n * block - 1, block, block + 3], np.int32)
+    table = np.full((lanes, n), nan, np.int32)
+    table[0, :3], table[2], table[3, :1], table[4, :2] = [3, 1, 7], [9, 2, 5, 6], [4], [3, 8]
+    q = jnp.asarray(rng.normal(size=(lanes, kv, groups, d)), dtype)
+    k_own = jnp.asarray(rng.normal(size=(lanes, kv * d)), dtype)
+    v_own = jnp.asarray(rng.normal(size=(lanes, kv * dv)), dtype)
+    k_pages, v_pages = jnp.asarray(k_pages, dtype), jnp.asarray(v_pages, dtype)
+    out = paged_attention(
+        q, k_pages, v_pages, jnp.int32(at), jnp.asarray(table), jnp.asarray(lengths),
+        k_own, v_own, interpret=True)
+    assert out.shape == (lanes, kv, groups, dv) and out.dtype == dtype
+    assert bool(jnp.isfinite(out.astype(jnp.float32)).all())
+
+    # the plain form: the live pages side by side (a dead entry reads block 0, finite,
+    # under the mask), one more page of room for the own row of a full lane
+    live = np.arange(n)[None] < -(-lengths[:, None] // block)
+    cap = (n + 1) * block
+
+    def side_by_side(pages, own, width):
+        rows = pages[at][np.where(live, table, 0)].reshape(lanes, n * block, 1, kv * width)
+        rows = jnp.pad(rows, ((0, 0), (0, block), (0, 0), (0, 0)))
+        rows = layers.write_rows(
+            rows, jnp.arange(lanes)[:, None], jnp.asarray(lengths)[:, None], own[:, None, None])
+        return rows.reshape(lanes, cap, kv, width)
+
+    mask = jnp.asarray(np.arange(cap)[None, None] <= lengths[:, None, None])
+    want = layers.plain_attend(
+        q[:, None], side_by_side(k_pages, k_own, d), side_by_side(v_pages, v_own, dv), mask,
+        1.0 / np.sqrt(d))[:, 0]
+    tol = dict(atol=2e-5, rtol=1e-4) if dtype == jnp.float32 else dict(atol=2e-2, rtol=2e-2)
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(want, np.float32), **tol)
+    # the padded lane gets its own row's value, each head its K/V head's columns
+    np.testing.assert_allclose(
+        np.asarray(out[1], np.float32),
+        np.broadcast_to(np.asarray(v_own[1], np.float32).reshape(kv, 1, dv), (kv, groups, dv)),
+        **tol)

@@ -64,6 +64,38 @@ def _extend_at(cfg, shaped, lanes, tc, cap):
 
 
 
+def _caches_as_the_engine_hands_them(cfg, engine, shaped, b, tc, cap):
+    """What ``_extend_call`` is handed for the caches, and its statics: a decode call of
+    a model whose ``extend`` reads pages (``llm.reads_pages``) the pool's arenas
+    themselves and how many pages a lane's table has; any other call the padded caches."""
+    if tc == 1 and llm.reads_pages(cfg.make_extend_fn()):
+        return [
+            shaped((cfg.cache_layers, engine["num_blocks"], engine["block_size"]) + tuple(each),
+                   cfg.dtype)
+            for each in cfg.cache_arrays], dict(tc=tc, pages=cap // engine["block_size"])
+    return [
+        shaped((cfg.cache_layers, b, cap) + tuple(each), cfg.dtype)
+        for each in cfg.cache_arrays], dict(tc=tc)
+
+
+def _attends_through_the_table(text, cfg, engine, sites):
+    """A compiled decode program that reads pages: its attend is ``paged_attention``
+    under ``extend.attention`` at ``sites`` call sites, and nothing in its text but a
+    parameter, a bitcast of one or a loop's hand-over has the shape of a K/V arena or
+    of a layer's slab of one: no copy, no slice, no re-layout of either."""
+    kernels = [
+        line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    attends = [k for k in kernels if "/paged_attention" in k]
+    assert len(attends) == sites and all("/extend.attention/paged_attention" in k for k in attends)
+    assert not any("/masked_attention/" in k for k in kernels)
+    of_an_arena = re.compile(
+        r" = bf16\[(%d,)?%d,%d,[\d,]+\]\S* (\w[\w-]*)\(" % (
+            cfg.cache_layers, engine["num_blocks"], engine["block_size"]))
+    made = {m[2] for m in map(of_an_arena.search, text.splitlines()) if m}
+    assert made and made <= {"parameter", "bitcast", "get-tuple-element"}, made
+    return [k for k in kernels if k not in attends]
+
+
 @pytest.mark.parametrize("lanes,tc", [(4, 1), (1, 128)], ids=["decode", "prefill"])
 def test_gptj_full_depth_extend_compiles(shaped, lanes, tc):
     """The server's step at full depth 28 in bf16, over a 1024-token cache:
@@ -540,7 +572,10 @@ def test_granite_hybrid_extend_compiles_at_its_largest_shapes(shaped, form, buil
     bucket, on the pool's state arenas themselves (49 slots of 76.4 MB: 3.75
     GB, donated): a decode call of eight lanes, whose recurrence is the kernel
     ``ssm_step`` straight under ``extend.ssm.scan`` (a lane's state fetched from
-    its slot and written back there), a prefill chunk through the chunked
+    its slot and written back there) and whose four attention layers read the
+    lanes' pages in the pool's arenas through the block table (``paged_attention``,
+    heads of 64: no padded cache, no copy of an arena or of a layer's slab of
+    one), a prefill chunk through the chunked
     recurrence and, in the four attention layers, the attention kernel. The
     arenas are aliased, all of them, and the program holds no temporary the size
     of one lane's state a lane, let alone an arena's; no layer's weights are
@@ -555,37 +590,40 @@ def test_granite_hybrid_extend_compiles_at_its_largest_shapes(shaped, form, buil
     b, tc = (lanes, 1) if form == "decode" else (1, engine["prefill_token_buckets"][-1])
     params = jax.tree.map(
         lambda x: shaped(x.shape, x.dtype), jax.eval_shape(lambda: cfg.init_params(0)))
-    caches = [
-        shaped((cfg.cache_layers, b, cap) + tuple(each), cfg.dtype) for each in cfg.cache_arrays]
+    caches, statics = _caches_as_the_engine_hands_them(cfg, engine, shaped, b, tc, cap)
     arenas = _granite_arenas(shaped, cfg, slots)
     operands = shaped(
         (b, llm._operand_width(
             engine["prefill_token_buckets"][-1], cap // engine["block_size"], True)), jnp.int32)
     compiled = llm._operand_extend(cfg.make_extend_fn(), len(caches), len(arenas)).lower(
         llm._extend_name(b, tc, cap), params, operands,
-        shaped((lanes + len(cfg.counters),), jnp.int32), *caches, *arenas, tc=tc
+        shaped((lanes + len(cfg.counters),), jnp.int32), *caches, *arenas, **statics
     ).compile()
     text = compiled.as_text()
     kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     if form == "prefill":
         assert kernels and all("/extend.attention/masked_attention/" in k for k in kernels)
     else:
-        # nine Mamba layers a period, in the one scan body
-        assert len(kernels) == 9 and all(
-            "/extend.ssm.scan/jit(ssm_step_slots)/ssm_step/" in k for k in kernels)
+        # nine Mamba layers a period and its attention layer, in the one scan body
+        others = _attends_through_the_table(text, cfg, engine, sites=1)
+        assert len(others) == 9 and all(
+            "/extend.ssm.scan/jit(ssm_step_slots)/ssm_step/" in k for k in others)
     memory = compiled.memory_analysis()
     assert GRANITE_STATE_BYTES == 76_437_504
     # (the compiler pads the convolution's three rows: 0.14 % more than the values)
     arena_bytes = slots * GRANITE_STATE_BYTES
     assert 0 <= memory.alias_size_in_bytes - arena_bytes < slots * 2**17
-    weights = memory.argument_size_in_bytes - memory.alias_size_in_bytes - b * (cap * 8192 + 2**17)
-    assert 6.38e9 < weights < 6.39e9
-    # 82 MB and 9 MB; the lanes' convolution inputs, 0.9 MB a lane, are the
-    # states' only part in them
-    assert memory.temp_size_in_bytes < {"decode": 0.09e9, "prefill": 0.02e9}[form]
-    assert memory.temp_size_in_bytes < b * GRANITE_STATE_BYTES
     resident = engine["num_blocks"] * engine["block_size"] * 8192
-    assert _device_bytes(compiled) + resident + lanes * cap * 8192 < HBM_BYTES
+    # the caches a call is handed: a chunk's gathered rows, a decode call's the pool itself
+    handed = resident if form == "decode" else b * cap * 8192
+    weights = memory.argument_size_in_bytes - memory.alias_size_in_bytes - handed - b * 2**17
+    assert 6.38e9 < weights < 6.39e9
+    # 4 MB (82 MB with eight lanes' padded caches re-laid out, before PR 61) and 9 MB;
+    # the lanes' convolution inputs, 0.9 MB a lane, are the states' only part in them
+    assert memory.temp_size_in_bytes < {"decode": 0.008e9, "prefill": 0.02e9}[form]
+    assert memory.temp_size_in_bytes < b * GRANITE_STATE_BYTES
+    # beside the pool's blocks (a decode call's argument) and a chunk's caches in flight
+    assert _device_bytes(compiled) + (0 if form == "decode" else resident) + cap * 8192 < HBM_BYTES
 
 
 def _granite_small_share():
@@ -616,8 +654,11 @@ def test_granite_small_share_extend_compiles_and_copies_no_layers_experts(
     of weights) over the largest cache bucket, on the pool's state arenas
     themselves (57 slots of 38.2 MB: 2.18 GB, donated and aliased): both
     families of mechanism in one program. A decode call of eight lanes runs the
-    kernel ``ssm_step`` in each of the nine Mamba layers and the grouped matmul
-    twice in each of the ten expert layers; a prefill chunk the chunked
+    kernel ``ssm_step`` in each of the nine Mamba layers, the grouped matmul
+    twice in each of the ten expert layers and, in the attention layer,
+    ``paged_attention`` over the lanes' pages where the pool keeps them (no padded
+    cache: its argument is the pool, its temporaries 21 MB where the re-laid-out
+    caches were 289); a prefill chunk the chunked
     recurrence, the attention kernel and the same grouped matmuls. The held
     experts are read in place in their stacks: the temporaries stay far under
     one layer's 680 MB of them, which a scan that sliced them out copied on
@@ -631,24 +672,25 @@ def test_granite_small_share_extend_compiles_and_copies_no_layers_experts(
     assert stated[form]["shape"] == [b, tc, cap]
     params = jax.tree.map(
         lambda x: shaped(x.shape, x.dtype), jax.eval_shape(lambda: cfg.init_params(0)))
-    caches = [
-        shaped((cfg.cache_layers, b, cap) + tuple(each), cfg.dtype) for each in cfg.cache_arrays]
+    caches, statics = _caches_as_the_engine_hands_them(cfg, engine, shaped, b, tc, cap)
     arenas = _granite_arenas(shaped, cfg, slots)
     operands = shaped(
         (b, llm._operand_width(
             engine["prefill_token_buckets"][-1], cap // engine["block_size"], True)), jnp.int32)
     compiled = llm._operand_extend(cfg.make_extend_fn(), len(caches), len(arenas)).lower(
         llm._extend_name(b, tc, cap), params, operands,
-        shaped((lanes + len(cfg.counters),), jnp.int32), *caches, *arenas, tc=tc
+        shaped((lanes + len(cfg.counters),), jnp.int32), *caches, *arenas, **statics
     ).compile()
     text = compiled.as_text()
     kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     experts = [line for line in kernels if "/extend.moe.experts/" in line]
     assert len(experts) == 2 * cfg.period                        # two grouped matmuls a layer
-    others = [line for line in kernels if line not in experts]
     if form == "prefill":
+        others = [line for line in kernels if line not in experts]
         assert others and all("/extend.attention/masked_attention/" in k for k in others)
     else:
+        others = [
+            k for k in _attends_through_the_table(text, cfg, engine, sites=1) if k not in experts]
         assert len(others) == 9 and all(
             "/extend.ssm.scan/jit(ssm_step_slots)/ssm_step/" in k for k in others)
     memory = compiled.memory_analysis()
@@ -657,17 +699,22 @@ def test_granite_small_share_extend_compiles_and_copies_no_layers_experts(
     assert 0 <= memory.alias_size_in_bytes - arena_bytes < slots * 2**17
     per_token = 2 * cfg.cache_layers * 8 * 128 * 2
     assert per_token == 4096
-    weights = memory.argument_size_in_bytes - memory.alias_size_in_bytes - b * (
-        cap * per_token + 2**17)
-    assert 9.51e9 < weights < 9.52e9
-    assert memory.argument_size_in_bytes == stated[form]["argument"]
-    assert _holds_no_more_than_stated(memory, stated[form])
-    # no copy of a layer's experts (a decode call's are its K and V, re-laid out by K/V
-    # head, a chunk's its pairs' rows and what lay in the all-rows logits' buffer)
-    assert memory.temp_size_in_bytes < (
-        GRANITE_SMALL_LAYER_EXPERTS_BYTES / 2 + _all_rows_bytes(cfg, b, tc))
     resident = engine["num_blocks"] * engine["block_size"] * per_token
-    assert _device_bytes(compiled) + resident + lanes * cap * per_token < HBM_BYTES
+    # the caches a call is handed: a chunk's gathered rows, a decode call's the pool
+    # itself (the file's figure is the gathered form's: the benchmark's to bring up to date)
+    handed = resident if form == "decode" else b * cap * per_token
+    weights = memory.argument_size_in_bytes - memory.alias_size_in_bytes - handed - b * 2**17
+    assert 9.51e9 < weights < 9.52e9
+    assert memory.argument_size_in_bytes == stated[form]["argument"] + handed - b * cap * per_token
+    assert _holds_no_more_than_stated(memory, stated[form])
+    # no copy of a layer's experts (a chunk's are its pairs' rows and what lay in the
+    # all-rows logits' buffer), and a decode call holds no padded cache: 21 MB
+    assert memory.temp_size_in_bytes < (
+        2**25 if form == "decode"
+        else GRANITE_SMALL_LAYER_EXPERTS_BYTES / 2 + _all_rows_bytes(cfg, b, tc))
+    # beside the pool's blocks (a decode call's argument) and a chunk's caches in flight
+    assert _device_bytes(compiled) + (0 if form == "decode" else resident) + (
+        cap * per_token) < HBM_BYTES
 
 
 def test_state_slots_are_read_and_written_without_a_whole_arena_temporary(shaped, built_for_tpu):
@@ -846,11 +893,17 @@ MIMO_EXPERTS_BYTES = 402_653_184 * 2
 def test_mimo_v2_flash_share_extend_compiles_and_copies_no_arena_and_no_expert(
         shaped, form, built_for_tpu):
     """One chip's share of MiMo-V2-Flash at its published widths (6.86 GB of weights)
-    over the largest cache bucket, the two full layers' rows gathered (5,120 B a token)
-    and the five sliding layers' windows read and written where the pool's state
+    over the largest cache bucket, the two full layers' rows (5,120 B a token) gathered
+    for a chunk and read where the pool keeps them by a decode call, and the five
+    sliding layers' windows read and written where the pool's state
     arenas lie (512 slots of 3.28 MB: 1.68 GB, donated and aliased). A decode call of
-    eight lanes attends in XLA alone, its kernels the grouped matmuls of the six
-    expert layers (in the period's scan and beside it); a prefill chunk attends in the
+    eight lanes is handed the pool's arenas (2.10 GB, not donated) and its block table:
+    its two full layers attend in ``paged_attention`` (layer 0's call site and the
+    scanned period's), keys of 192 beside values of 128, and its temporaries are 5.6 MB
+    where the padded caches, written into and re-laid out by K/V head, were 1.41 GB
+    beside 1.34 GB of gathered rows (compile, PR 61); its other kernels are the grouped
+    matmuls of the six expert layers (in the period's scan and beside it); the sliding
+    layers' decode attend stays XLA's; a prefill chunk attends in the
     kernel the other architectures call, three call sites (layer 0, the period's full
     layer, the scanned sliding layers' with ``sinks``), K wider than V. Neither holds
     a copy of a window arena (left free, the compiler carries all of it through the
@@ -865,7 +918,8 @@ def test_mimo_v2_flash_share_extend_compiles_and_copies_no_arena_and_no_expert(
     params = jax.tree.map(
         lambda x: shaped(x.shape, x.dtype), jax.eval_shape(lambda: cfg.init_params(0)))
     assert cfg.cache_arrays == ((1, 768), (1, 512)) and cfg.cache_layers == 2
-    caches = [shaped((cfg.cache_layers, b, cap) + each[:2], cfg.dtype) for each in cfg.cache_arrays]
+    caches, statics = _caches_as_the_engine_hands_them(cfg, engine, shaped, b, tc, cap)
+    assert (form == "decode") == ("pages" in statics)
     arenas = tuple(
         shaped((layers, slots) + shape, dtype) for layers, shape, dtype in cfg.state_arrays)
     assert [a.shape for a in arenas] == [(5, slots, 128, 1536), (5, slots, 128, 1024)]
@@ -874,20 +928,20 @@ def test_mimo_v2_flash_share_extend_compiles_and_copies_no_arena_and_no_expert(
             engine["prefill_token_buckets"][-1], cap // engine["block_size"], True)), jnp.int32)
     compiled = llm._operand_extend(cfg.make_extend_fn(), len(caches), len(arenas)).lower(
         llm._extend_name(b, tc, cap), params, operands,
-        shaped((lanes + len(cfg.counters),), jnp.int32), *caches, *arenas, tc=tc
+        shaped((lanes + len(cfg.counters),), jnp.int32), *caches, *arenas, **statics
     ).compile()
     text = compiled.as_text()
     kernels = [
         line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
-    attends = [k for k in kernels if "/masked_attention/" in k]
-    assert len(kernels) - len(attends) == 4 and all("/extend.moe.experts/" in k for k in kernels
-                                                    if k not in attends)
     if form == "prefill":
+        attends = [k for k in kernels if "/masked_attention/" in k]
         assert len(attends) == 3
         assert sum("/extend.attention.window/masked_attention/" in k for k in attends) == 1
         assert sum("/extend.attention/masked_attention/" in k for k in attends) == 2
+        others = [k for k in kernels if k not in attends]
     else:
-        assert not attends
+        others = _attends_through_the_table(text, cfg, engine, sites=2)
+    assert len(others) == 4 and all("/extend.moe.experts/" in k for k in others)
     # the arenas keep their layout through both scans: a row's features innermost
     assert set(re.findall(r"bf16\[5,%d,128,\d+\]\{([\d,]+)" % slots, text)) == {"3,2,1,0"}
     memory = compiled.memory_analysis()
@@ -896,18 +950,22 @@ def test_mimo_v2_flash_share_extend_compiles_and_copies_no_arena_and_no_expert(
     assert 0 <= memory.alias_size_in_bytes - arena_bytes < 2**20
     per_token = cfg.cache_layers * (768 + 512) * 2
     assert per_token == 5120
-    weights = memory.argument_size_in_bytes - memory.alias_size_in_bytes - b * (
-        cap * per_token + 2**17)
-    assert 6.85e9 < weights < 6.87e9
-    assert memory.argument_size_in_bytes == stated[form]["argument"]
-    assert _holds_no_more_than_stated(memory, stated[form])
-    # no copy of a window arena or of a layer's experts; a decode call's temporaries are
-    # its lanes' K and V written into and re-laid out by K/V head, a little over the
-    # gathered caches themselves
-    assert memory.temp_size_in_bytes < min(MIMO_EXPERTS_BYTES, arena_bytes * 0.6) + (
-        b * cap * per_token if form == "decode" else 0)
     resident = engine["num_blocks"] * engine["block_size"] * per_token
-    assert _device_bytes(compiled) + resident < HBM_BYTES
+    # the caches a call is handed: a chunk's gathered rows, a decode call's the pool
+    # itself (the file's figure is the gathered form's: the benchmark's to bring up to date)
+    handed = resident if form == "decode" else b * cap * per_token
+    weights = memory.argument_size_in_bytes - memory.alias_size_in_bytes - handed - b * 2**17
+    assert 6.85e9 < weights < 6.87e9
+    assert memory.argument_size_in_bytes == stated[form]["argument"] + handed - b * cap * per_token
+    assert _holds_no_more_than_stated(memory, stated[form])
+    # no copy of a window arena or of a layer's experts, and a decode call holds nothing
+    # of a padded cache's size: 5.6 MB, where 1.41 GB were its lanes' K and V written
+    # into and re-laid out by K/V head
+    assert memory.temp_size_in_bytes < (
+        2**23 if form == "decode" else min(MIMO_EXPERTS_BYTES, arena_bytes * 0.6))
+    assert stated["decode"]["temp"] - 2**23 > 1e9
+    # beside the pool's blocks, which a decode call's arguments hold
+    assert _device_bytes(compiled) + (0 if form == "decode" else resident) < HBM_BYTES
     assert 2 * cfg.num_params() + resident + arena_bytes >= 0.60 * HBM_BYTES
 
 
@@ -941,7 +999,10 @@ def test_qwen3_next_share_extend_compiles_and_copies_no_arena_and_no_expert(
     the largest cache bucket, the two full layers' rows gathered (4,096 B a token) and
     the six delta layers' state and convolution tail read and written where the pool's
     state arenas lie (160 slots of 12.9 MB: 2.06 GB, donated and aliased). A decode
-    call of sixteen lanes attends in XLA alone, its kernels the grouped matmuls of the
+    call of sixteen lanes is handed the pool's arenas (1.34 GB) and its block table and
+    attends at a head of 256 in ``paged_attention`` (one call site, the scanned period's
+    full layer; 49 MB of temporaries where the re-laid-out padded caches were 841),
+    beside the grouped matmuls of the
     eight expert layers (two a layer, a period's four layers in the scan's body); a
     prefill chunk of 1,024 attends at a head of 256 in the kernel the other architectures
     call, and runs the delta rule's sixteen sub-chunks as a loop of its own. Neither
@@ -957,7 +1018,8 @@ def test_qwen3_next_share_extend_compiles_and_copies_no_arena_and_no_expert(
     params = jax.tree.map(
         lambda x: shaped(x.shape, x.dtype), jax.eval_shape(lambda: cfg.init_params(0)))
     assert cfg.cache_arrays == ((1, 512), (1, 512)) and cfg.cache_layers == 2
-    caches = [shaped((cfg.cache_layers, b, cap) + each[:2], cfg.dtype) for each in cfg.cache_arrays]
+    caches, statics = _caches_as_the_engine_hands_them(cfg, engine, shaped, b, tc, cap)
+    assert (form == "decode") == ("pages" in statics)
     arenas = tuple(
         shaped((layers, slots) + shape, dtype) for layers, shape, dtype in cfg.state_arrays)
     assert [a.shape for a in arenas] == [(6, slots, 32, 128, 128), (6, slots, 3, 8192)]
@@ -966,16 +1028,18 @@ def test_qwen3_next_share_extend_compiles_and_copies_no_arena_and_no_expert(
             engine["prefill_token_buckets"][-1], cap // engine["block_size"], True)), jnp.int32)
     compiled = llm._operand_extend(cfg.make_extend_fn(), len(caches), len(arenas)).lower(
         llm._extend_name(b, tc, cap), params, operands,
-        shaped((lanes + len(cfg.counters),), jnp.int32), *caches, *arenas, tc=tc
+        shaped((lanes + len(cfg.counters),), jnp.int32), *caches, *arenas, **statics
     ).compile()
     text = compiled.as_text()
     kernels = [
         line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
-    attends = [k for k in kernels if "/masked_attention/" in k]
-    assert len(kernels) - len(attends) == 8 and all("/extend.moe.experts/" in k for k in kernels
-                                                    if k not in attends)
-    assert len(attends) == (1 if form == "prefill" else 0)
-    assert all("/extend.attention/masked_attention/" in k for k in attends)
+    if form == "prefill":
+        attends = [k for k in kernels if "/masked_attention/" in k]
+        assert len(attends) == 1 and "/extend.attention/masked_attention/" in attends[0]
+        others = [k for k in kernels if k not in attends]
+    else:
+        others = _attends_through_the_table(text, cfg, engine, sites=1)
+    assert len(others) == 8 and all("/extend.moe.experts/" in k for k in others)
     # the state arena keeps its layout through the scan: a head's 128 x 128 innermost
     assert set(re.findall(r"f32\[6,%d,32,128,128\]\{([\d,]+)" % slots, text)) == {"4,3,2,1,0"}
     memory = compiled.memory_analysis()
@@ -984,18 +1048,22 @@ def test_qwen3_next_share_extend_compiles_and_copies_no_arena_and_no_expert(
     assert 0 <= memory.alias_size_in_bytes - arena_bytes < 2**20
     per_token = cfg.cache_layers * (512 + 512) * 2
     assert per_token == 4096
-    weights = memory.argument_size_in_bytes - memory.alias_size_in_bytes - b * (
-        cap * per_token + 2**17)
+    resident = engine["num_blocks"] * engine["block_size"] * per_token
+    # the caches a call is handed: a chunk's gathered rows, a decode call's the pool
+    # itself (the file's figure is the gathered form's: the benchmark's to bring up to date)
+    handed = resident if form == "decode" else b * cap * per_token
+    weights = memory.argument_size_in_bytes - memory.alias_size_in_bytes - handed - b * 2**17
     assert 7.32e9 < weights < 7.35e9
-    assert memory.argument_size_in_bytes == stated[form]["argument"]
+    assert memory.argument_size_in_bytes == stated[form]["argument"] + handed - b * cap * per_token
     assert _holds_no_more_than_stated(memory, stated[form])
     # no copy of a state arena or of a layer's experts; a decode call's temporaries are
-    # its lanes' K and V written into and re-laid out by K/V head, under the gathered
-    # caches themselves, and its sixteen lanes' states (2 MB a lane and layer) in flight
-    assert memory.temp_size_in_bytes < min(QWEN3_NEXT_EXPERTS_BYTES, arena_bytes / 4) + (
-        b * cap * per_token if form == "decode" else 0)
-    resident = engine["num_blocks"] * engine["block_size"] * per_token
-    assert _device_bytes(compiled) + resident + lanes * cap * per_token < HBM_BYTES
+    # its sixteen lanes' states (2 MB a lane and layer) in flight and nothing of a padded
+    # cache's size: 49 MB
+    assert memory.temp_size_in_bytes < (
+        2**26 if form == "decode" else min(QWEN3_NEXT_EXPERTS_BYTES, arena_bytes / 4))
+    # beside the pool's blocks (a decode call's argument) and a chunk's caches in flight
+    assert _device_bytes(compiled) + (0 if form == "decode" else resident) + (
+        cap * per_token) < HBM_BYTES
     assert 2 * cfg.num_params() + resident + arena_bytes >= 0.60 * HBM_BYTES
 
 
@@ -1003,9 +1071,9 @@ def test_qwen3_next_share_extend_compiles_and_copies_no_arena_and_no_expert(
     "name,extends,pagings",
     [("gptj-6b-serve", 12, 11), ("command-a-plus-serve-ep8", 20, 25),
      ("keye-vl2-30b-a3b-serve", 16, 19), ("kimi-k2-instruct-serve-ep32", 16, 19),
-     ("granite-4.0-h-micro-serve", 20, 25), ("granite-4.0-h-small-serve-ep2", 20, 25),
-     ("minicpm-sala-serve-pp2", 16, 19), ("mimo-v2-flash-serve-ep16", 20, 25),
-     ("qwen3-next-80b-a3b-serve-ep4", 18, 26)],
+     ("granite-4.0-h-micro-serve", 20, 13), ("granite-4.0-h-small-serve-ep2", 20, 13),
+     ("minicpm-sala-serve-pp2", 16, 19), ("mimo-v2-flash-serve-ep16", 20, 13),
+     ("qwen3-next-80b-a3b-serve-ep4", 18, 14)],
 )
 def test_a_serve_configuration_compiles_no_more_programs_than_it_did(name, extends, pagings):
     """The programs an engine with the configuration's buckets compiles (a tiny
@@ -1014,7 +1082,10 @@ def test_a_serve_configuration_compiles_no_more_programs_than_it_did(name, exten
     per (lanes, cache), one page-back per (lanes, tokens) and the clone;
     ``warm()`` compiles one ``extend`` per shape of ``extend_shapes()``
     (``tests/test_llm.py`` holds it to that). ``setup_s`` is mostly these
-    compiles."""
+    compiles. A model whose decode call reads pages (``llm.reads_pages``: MiMo,
+    Qwen3-Next, both granites) compiles a gather for its chunks' one lane alone:
+    twelve programs fewer than the 25 and 26 they had (PR 61), and no
+    ``gather_<b>x<cap>`` that only a decode call would have used."""
     import json
 
     with open(os.path.join(
@@ -1044,5 +1115,10 @@ def test_a_serve_configuration_compiles_no_more_programs_than_it_did(name, exten
         assert sum(
             p._cache_size() for p in (programs.gather, programs.page_back, programs.clone)
         ) <= pagings
+        gathers = {f"gather_{b}x{cap}" for b, tc, cap in eng.extend_shapes() if not eng._paged(tc)}
+        assert set(programs.gather.names()) == gathers
+        adopts = name.split("-")[0] in ("granite", "mimo", "qwen3")
+        assert llm.reads_pages(eng._extend) == adopts
+        assert (gathers == {f"gather_1x{cap}" for cap in sizes["cache_buckets"]}) == adopts
     finally:
         llm._paging_programs.cache_clear()

@@ -106,6 +106,51 @@ def test_plain_attend_is_a_dense_softmax_under_the_mask(groups):
                 rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("on_the_chip", [False, True], ids=["off-the-chip", "kernel-interpreted"])
+def test_paged_attend_is_plain_attend_over_the_tables_pages_and_the_own_row(
+        on_the_chip, monkeypatch):
+    """A decode call's attend through the block table against ``plain_attend`` over
+    caches gathered by hand (the table's pages of the layer side by side, the own row
+    written at the lane's length): off the chip the same bits, on it (the kernel,
+    interpreted here) the same numbers; a table entry past a lane's pages is followed
+    by neither."""
+    from ray_tpu.ops import attention, backend
+
+    b, kv, groups, hd, vd, block, n, blocks, at = 3, 2, 4, 24, 16, 8, 4, 10, 1
+    k_pages = jnp.asarray(_random(0, (2, blocks, block, 1, kv * hd)))
+    v_pages = jnp.asarray(_random(1, (2, blocks, block, 1, kv * vd)))
+    q = jnp.asarray(_random(2, (b, 1, kv, groups, hd)))
+    k, v = jnp.asarray(_random(3, (b, 1, 1, kv * hd))), jnp.asarray(_random(4, (b, 1, 1, kv * vd)))
+    lengths = np.array([11, 0, 31], np.int32)
+    table = np.array([[4, 7, 0, 0], [0, 0, 0, 0], [2, 9, 1, 5]], np.int32)
+    positions = jnp.asarray(lengths)[:, None]
+    visible = layers.visible_keys(positions, jnp.ones((b, 1), bool), n * block)
+    if on_the_chip:
+        real = attention.paged_attention
+        monkeypatch.setattr(backend, "on_tpu", lambda: True)
+        monkeypatch.setattr(
+            attention, "paged_attention", lambda *a, **kw: real(*a, interpret=True, **kw))
+    got = layers.paged_attend(
+        q, k, v, k_pages, v_pages, jnp.int32(at), jnp.asarray(table), positions, visible, 0.2)
+
+    def gathered(pages, own, width):
+        rows = pages[at][table].reshape(b, n * block, 1, -1)
+        return layers.write_rows(rows, jnp.arange(b)[:, None], positions, own).reshape(
+            b, n * block, kv, width)
+
+    want = layers.plain_attend(q, gathered(k_pages, k, hd), gathered(v_pages, v, vd), visible, 0.2)
+    assert got.shape == want.shape == (b, 1, kv, groups, vd)
+    if on_the_chip:
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-4)
+    else:
+        assert np.array_equal(got, want)
+
+
+def test_cache_slots_are_the_padded_caches_or_the_tables_pages():
+    assert layers.cache_slots(jnp.zeros((2, 3, 64, 1, 8))) == 64
+    assert layers.cache_slots(jnp.zeros((2, 10, 8, 1, 8)), jnp.zeros((3, 4), jnp.int32)) == 32
+
+
 def test_write_rows_drops_a_position_past_the_capacity():
     cache = jnp.zeros((2, 4, 3))
     rows = jnp.asarray(_random(12, (2, 2, 3)))

@@ -13,8 +13,9 @@ import pytest
 
 import ray_tpu
 from ray_tpu import serve
-from ray_tpu.models import cohere2_moe, gpt, keye_vl2, mimo_v2_flash, qwen3_next
-from ray_tpu.serve import batching
+from ray_tpu.models import (
+    cohere2_moe, gpt, granitemoehybrid, keye_vl2, mimo_v2_flash, qwen3_next)
+from ray_tpu.serve import batching, llm
 from ray_tpu.serve.llm import (
     LANE_BUCKETS,
     MAX_LANES,
@@ -944,6 +945,79 @@ def test_sixteen_lanes_decode_in_one_call_and_each_gets_what_it_gets_alone():
         assert got._result["tokens"] == want["tokens"]
         np.testing.assert_allclose(got._result["logits"], want["logits"], rtol=2e-4, atol=2e-5)
     assert eng.pool.slots_in_use() == 0 and eng.pool.in_use() == 0
+
+
+# ---------------------------------------------------------------------------
+# a decode call that attends through the block table (no gather)
+# ---------------------------------------------------------------------------
+
+_READ_PAGES = {
+    "mimo-v2-flash": mimo_v2_flash.mimo_v2_flash_nano,
+    "qwen3-next": qwen3_next.qwen3_next_nano,
+    "granite-4h": granitemoehybrid.granite_hybrid_nano,
+    "granite-4h-experts": lambda: granitemoehybrid.granite_hybrid_nano(router_experts=8),
+}
+_PAGED = dict(
+    num_blocks=64, block_size=8, prefill_chunk=16, prefill_lanes=1, lane_buckets=(1, 2, 4),
+    prefill_token_buckets=(16,), cache_buckets=(32, 64), state_slots=12)
+
+
+@pytest.mark.parametrize("name", list(_READ_PAGES))
+def test_a_decode_call_through_the_block_table_decodes_what_the_gather_decodes(name, monkeypatch):
+    """A model whose ``extend`` offers ``table=`` against itself through the gather (the
+    parent's path: the same engine told that nothing reads pages, which hands every
+    call padded caches): three sequences of unlike lengths decode together across a
+    change of cache bucket (32 -> 64), one of them returning its logits (the host makes
+    its token: its call lands before the next is launched) while the others run a call
+    ahead, then a fourth hits the third's cached prefix. Token for token and, for the
+    logits, bit for bit the same; every decode call of the one ran no gather
+    (``paged`` = ``n``) and only a chunk did, none of the other's."""
+    cfg = _READ_PAGES[name]()
+    through_table = LLMEngine(cfg, **_PAGED)
+    monkeypatch.setattr(llm, "reads_pages", lambda extend: False)
+    through_gather = LLMEngine(cfg, **_PAGED)
+    monkeypatch.undo()
+    assert llm.reads_pages(through_table._extend)
+    assert through_table._reads_pages and not through_gather._reads_pages
+    # the temporaries ``_fits`` counts for any call are those of the largest shape that is
+    # handed padded caches: a chunk's, where the decode calls hold none
+    assert through_table.warm()["compiled"]["shape"] == [1, 16, 64]
+    assert through_gather.warm()["compiled"]["shape"] == [4, 1, 64]
+    asks = [
+        {"prompt": [int(t) for t in np.random.RandomState(40 + i).randint(0, cfg.vocab_size, 9 + 7 * i)],
+         "max_new_tokens": 24, "return_logits": i == 1}
+        for i in range(3)]
+    late = {"prompt": asks[2]["prompt"][:16] + [3, 1, 4, 1, 5], "max_new_tokens": 24}
+
+    def decoded(eng):
+        gathers, gather = [], eng.pool.gather
+        monkeypatch.setattr(eng.pool, "gather", lambda *a: gathers.append(1) or gather(*a))
+        seqs = [batching._Sequence(dict(ask)) for ask in asks]
+        _drive(eng, seqs)
+        seqs.append(batching._Sequence(dict(late)))
+        _drive(eng, seqs[-1:])
+        stats = eng.stats()
+        # every sequence's slot is back; the prefix cache keeps its snapshots'
+        assert stats["state_slots_in_use"] == stats["state_snapshots"]
+        return [s._result for s in seqs], stats, len(gathers)
+
+    got, stats, gathers = decoded(through_table)
+    want, plain, plain_gathers = decoded(through_gather)
+    assert [r["tokens"] for r in got] == [r["tokens"] for r in want]
+    assert np.array_equal(got[1]["logits"], want[1]["logits"])
+    for s in (stats, plain):
+        assert s["prefix_hits"] >= 2 and s["calls_ahead"] > 0       # the hit's two blocks
+        assert {"extend_decode_4x1x32", "extend_decode_4x1x64"} <= {
+            name for name, program in s["programs"].items() if program["n"]}
+    calls = stats["calls"]
+    assert calls["decode"]["paged"] == calls["decode"]["n"] > 40 and calls["prefill"]["paged"] == 0
+    assert gathers == calls["prefill"]["n"] == stats["phase_n"]["kv_gather"]
+    assert plain["calls"]["decode"]["paged"] == 0
+    assert plain_gathers == plain["calls"]["decode"]["n"] + plain["calls"]["prefill"]["n"]
+    # what the calls carried is counted alike, gathered or read in place
+    for key in ("n", "lanes_used", "lane_slots", "cache_tokens", "cache_slots"):
+        assert calls["decode"][key] == plain["calls"]["decode"][key], key
+    assert {k: stats[k] for k in cfg.counters} == {k: plain[k] for k in cfg.counters}
 
 
 def test_a_deployment_takes_its_executing_slots_from_what_its_callable_runs_at_once():

@@ -83,6 +83,7 @@ def _by_form(calls, tokens):
         "token_slots": sum(b * tc for _, b, tc, _, _ in calls),
         "cache_tokens": sum(c[4] for c in calls),
         "cache_slots": sum(b * cap for _, b, _, cap, _ in calls),
+        "paged": 0,     # a GPT's extend reads no pages: every call gathers
     }
 
 
@@ -475,7 +476,7 @@ def test_dispatch_and_fetch_say_which_call_and_what_it_is(xplane, planes):
             "call": first + i, "form": "prefill" if call in PREFILL else "decode",
             "lanes": call[0], "lane_slots": call[1], "heads": heads, "tokens": tokens,
             "token_slots": call[1] * call[2], "cache_tokens": call[4],
-            "cache_slots": call[1] * call[3], "ahead": int(i > 0),
+            "cache_slots": call[1] * call[3], "paged": 0, "ahead": int(i > 0),
             "program": llm._extend_name(*call[1:4]),
         }
         for i, (call, tokens, heads) in enumerate(zip(CALLS, (20 + 32, 1, 8 + 9, 3, 2), HEADS))
@@ -489,6 +490,48 @@ def test_dispatch_and_fetch_say_which_call_and_what_it_is(xplane, planes):
     assert tuple(map(step_of, dispatched)) == LAUNCHED_IN
     assert tuple(map(step_of, fetched)) == LANDED_IN
     assert sum(a != b for a, b in zip(LAUNCHED_IN, LANDED_IN)) == STEPS - 1
+
+
+def test_a_call_that_reads_pages_has_no_gather_phase_and_says_paged(tmp_path):
+    """A model whose ``extend`` offers ``table=`` (MiMo-V2-Flash): a decode call's launch
+    is ``upload``, ``dispatch``, ``kv_scatter`` with no ``kv_gather`` between them, its
+    ``llm.dispatch`` says ``paged`` 1, and ``stats()["calls"]["decode"]["paged"]`` counts
+    every decode call (``traced`` too); a chunk gathers as ever and says 0."""
+    from ray_tpu.models import mimo_v2_flash
+
+    cfg = mimo_v2_flash.mimo_v2_flash_nano()
+    eng = llm.LLMEngine(
+        cfg, num_blocks=32, block_size=8, prefill_chunk=16, prefill_lanes=1,
+        lane_buckets=(1, 2), prefill_token_buckets=(16,), cache_buckets=(32, 64),
+        prefix_caching=False, state_slots=8)
+    assert llm.reads_pages(eng._extend) and not llm.reads_pages(llm.LLMEngine(NANO, **ENGINE)._extend)
+    eng.warm()
+    before = eng.stats()
+    seqs = _requests(lengths=(20, 9), new=4, vocab=cfg.vocab_size)
+    with _session(tmp_path):
+        _drive(eng, seqs)
+    after = eng.stats()
+    path = _xplane_of(tmp_path)
+    dispatched = [what for _, _, what in _recorded(path, "llm.dispatch")]
+    assert {what["form"] for what in dispatched} == {"prefill", "decode"}
+    assert all(what["paged"] == (what["form"] == "decode") for what in dispatched)
+    decodes = sum(what["form"] == "decode" for what in dispatched)
+    for book in (lambda s: s, lambda s: s["traced"]):
+        calls = _delta(book(after), book(before), "calls")
+        assert calls["decode"]["paged"] == calls["decode"]["n"] == decodes > 0
+        assert calls["prefill"]["paged"] == 0 < calls["prefill"]["n"]
+    # a gather a chunk and none a decode call, in the counts and among the spans
+    chunks = len(dispatched) - decodes
+    assert _delta(after, before, "phase_n")["kv_gather"] == chunks
+    assert _delta(after, before, "phase_n")["dispatch"] == len(dispatched)
+    launches = sorted(
+        (start, end, name[4:]) for start, end, name in _ran(path, r"llm\.\w+")
+        if name[4:] in LAUNCH_PHASES)
+    order = "".join(name[0] for _, _, name in launches)       # u(pload) k(v_..) d(ispatch) k
+    assert order.count("ukdk") == chunks and order.replace("ukdk", "") == "udk" * decodes
+    # and no gather program ran for a decode call: the chunks' one lane alone
+    gathers = [name for _, _, name in _ran(path, r"PjitFunction\(gather_\w+\)")]
+    assert gathers and all(name.startswith("PjitFunction(gather_1x") for name in gathers)
 
 
 def test_heads_says_how_many_lanes_of_a_call_have_their_row_of_logits_read(tmp_path):
@@ -524,7 +567,7 @@ def test_two_shapes_are_two_modules_and_one_shape_twice_is_one():
 
     def lowered(b, tc, cap):
         return family.lower(
-            llm._extend_name(b, tc, cap), *eng._extend_args(jax.ShapeDtypeStruct, b, cap), tc=tc)
+            llm._extend_name(b, tc, cap), *eng._extend_args(jax.ShapeDtypeStruct, b, tc, cap), tc=tc)
 
     assert _module(lowered(1, 1, 64)) == "jit_extend_decode_1x1x64"
     assert _module(lowered(2, 16, 128)) == "jit_extend_prefill_2x16x128"
@@ -567,7 +610,7 @@ def test_a_dispatch_names_the_program_its_call_runs_as(engine, xplane):
         assert what["program"] == llm._extend_name(*call[1:4]) and "cold" not in what
         b, tc, cap = call[1:4]
         assert _module(engine._extend_call.lower(
-            what["program"], *engine._extend_args(jax.ShapeDtypeStruct, b, cap), tc=tc)
+            what["program"], *engine._extend_args(jax.ShapeDtypeStruct, b, tc, cap), tc=tc)
         ) == "jit_" + what["program"]
     # every other program of a step has a name of its own too
     others = {name for _, _, name in _ran(xplane, r"PjitFunction\(\w+\)")}
