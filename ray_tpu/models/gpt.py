@@ -55,7 +55,11 @@ class TrainModel(NamedTuple):
     loss ``aux_weight`` times, and the scalars the step reports beside its own
     (a dict, empty where the model counts nothing). ``buffers`` names the top-level
     entries of the parameters that are no parameters: they get no gradient and no
-    optimizer state, and a step hands them on as they are. ``scatters(rules, seq)``
+    optimizer state, and a step hands them on as they are, or as ``update_buffers``
+    leaves them: ``update_buffers(buffers, scalars)`` gives ``(buffers, scalars)`` after
+    the step, from what ``apply`` counted in this step's forward (a rule that is no
+    gradient's: an expert layer's correction bias follows its experts' loads; what the
+    rule alone reads, it takes out of the scalars). ``scatters(rules, seq)``
     says whether ``apply`` on ``seq`` tokens a sequence, under the logical axis
     ``rules``, takes its layers' products apart round the mesh's tp axis
     (``GPTConfig.scatter_axis``): what the step is compiled with follows from it."""
@@ -65,6 +69,7 @@ class TrainModel(NamedTuple):
     aux_weight: float = 0.0
     buffers: Tuple[str, ...] = ()
     scatters: Callable = lambda rules, seq: False
+    update_buffers: Optional[Callable] = None
 
 
 @dataclasses.dataclass(frozen=True)

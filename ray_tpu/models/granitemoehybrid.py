@@ -391,21 +391,47 @@ def ssm_step_slots(arena, at, slots, real, fresh, x, dt, a, b, c, *,
     return y.swapaxes(2, 3).reshape(lanes, all_heads, p), arena
 
 
-def ssm_chunked(state, x, dt, a, b, c, chunk: int, dtype):
+def ssm_chunked(state, x, dt, a, b, c, chunk: int, dtype, rebuild: bool = False):
     """The recurrence over ``t`` tokens in sub-chunks of ``chunk`` (``t`` a whole
     number of them), as matmuls: ``x`` [lanes, t, heads, p], ``dt`` [lanes, t,
-    heads] float32 (0 for a padded token), ``b``, ``c`` [lanes, t, n]. Inside a
+    heads] float32 (0 for a padded token), ``b``, ``c`` [lanes, t, n], or in
+    **groups** ``[lanes, t, groups, n]``: head ``h`` reads group ``h // (heads /
+    groups)`` (``models/nemotron_h.py``: eight; this file's models have one and
+    hand over no group axis). Inside a
     sub-chunk ``y_t = sum_{s<=t} exp(a_t - a_s) (c_t . b_s) dt_s x_s`` with ``a``
     the running sum of ``dt A``; from the state before it ``exp(a_t) S c_t``.
     The matmuls take ``dtype`` operands and sum in float32; the state's own
     read-out is float32 throughout. Returns ``y`` [lanes, t, heads, p] float32,
     the last state, and the state after each sub-chunk ``[t / chunk, lanes,
     ...]``. One ``lax.scan`` body: a sub-chunk's result does not depend on where
-    in the call it lies."""
+    in the call it lies. Differentiable (``jax.grad`` through the ``lax.scan``: a
+    masked pair's weight is ``exp(-inf)``, whose gradient is 0 as its value is),
+    with the state's path float32 in the backward as in the forward. A train
+    step says ``rebuild``: the body is then a ``jax.checkpoint``, so that the
+    backward keeps the inputs and the states between sub-chunks alone (``heads x p x
+    n`` float32 a sub-chunk and lane) and is one reverse ``lax.scan`` that rebuilds a
+    sub-chunk's quantities before it differentiates them, where plain autodiff
+    keeps every sub-chunk's ``[lanes, heads, chunk, chunk]`` products."""
     lanes, t, heads, p = x.shape
     n, f32 = b.shape[-1], jnp.float32
     nc = t // chunk
     causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+    if b.ndim == 4:
+        # wherever the heads meet b or c they split [groups, heads a group]
+        pairs, read, feed = "bqgn,bsgn->bgqs", "bgrpn,bqgn->bgrqp", "bgrsp,bsgn->bgrpn"
+
+        def by_group(v):            # [lanes, heads, ...] -> [lanes, groups, heads a group, ...]
+            return v.reshape((lanes, b.shape[2], -1) + v.shape[2:])
+
+        def by_head(v):             # and back
+            return v.reshape((lanes, heads) + v.shape[3:])
+
+        def every_head(pair):       # a group's pairs [lanes, groups, q, s] beside its heads
+            return pair[:, :, None]
+    else:
+        pairs, read, feed = "bqn,bsn->bqs", "bhpn,bqn->bhqp", "bhsp,bsn->bhpn"
+        by_group = by_head = lambda v: v
+        every_head = lambda pair: pair[:, None]
 
     def split(v):                   # [lanes, t, ...] -> [nc, lanes, chunk, ...]
         return jnp.moveaxis(v.reshape((lanes, nc, chunk) + v.shape[2:]), 1, 0)
@@ -414,20 +440,21 @@ def ssm_chunked(state, x, dt, a, b, c, chunk: int, dtype):
         xq, dtq, bq, cq = xs        # [lanes, chunk, heads, p], [.., heads], [.., n], [.., n]
         run = jnp.cumsum(dtq * a, axis=1).transpose(0, 2, 1)        # [lanes, heads, chunk]
         fed = (dtq[..., None] * xq.astype(f32)).transpose(0, 2, 1, 3)   # [lanes, heads, chunk, p]
-        pair = jnp.einsum("bqn,bsn->bqs", cq, bq, preferred_element_type=f32)
+        pair = jnp.einsum(pairs, cq, bq, preferred_element_type=f32)
         # masked before the exponential: a later token's difference is positive
         span = jnp.where(causal, run[..., :, None] - run[..., None, :], -jnp.inf)
-        weight = (pair[:, None] * jnp.exp(span)).astype(dtype)      # [lanes, heads, q, s]
+        weight = by_head(every_head(pair) * by_group(jnp.exp(span))).astype(dtype)  # [lanes, heads, q, s]
         y = jnp.einsum("bhqs,bhsp->bhqp", weight, fed.astype(dtype), preferred_element_type=f32)
-        y = y + jnp.exp(run)[..., None] * jnp.einsum(
-            "bhpn,bqn->bhqp", state, cq.astype(f32), precision=jax.lax.Precision.HIGHEST)
+        y = y + jnp.exp(run)[..., None] * by_head(jnp.einsum(
+            read, by_group(state), cq.astype(f32), precision=jax.lax.Precision.HIGHEST))
         to_end = jnp.exp(run[..., -1:] - run)                       # [lanes, heads, chunk]
-        state = jnp.exp(run[..., -1])[..., None, None] * state + jnp.einsum(
-            "bhsp,bsn->bhpn", (fed * to_end[..., None]).astype(dtype), bq,
-            preferred_element_type=f32)
+        state = jnp.exp(run[..., -1])[..., None, None] * state + by_head(jnp.einsum(
+            feed, by_group((fed * to_end[..., None]).astype(dtype)), bq,
+            preferred_element_type=f32))
         return state, (y.transpose(0, 2, 1, 3), state)
 
-    state, (y, between) = jax.lax.scan(one, state, tuple(map(split, (x, dt, b, c))))
+    state, (y, between) = jax.lax.scan(
+        jax.checkpoint(one) if rebuild else one, state, tuple(map(split, (x, dt, b, c))))
     return jnp.moveaxis(y, 0, 1).reshape(lanes, t, heads, p), state, between
 
 
@@ -472,7 +499,7 @@ def make_extend_fn(cfg: GraniteMoeHybridConfig):
     # An arena is read and written one slot at a time, with a dynamic slice and
     # an in-place dynamic update: indexed with the slots (``arena[at, slots]``)
     # the TPU compiler first copies all of it (3.75 GB: ``serve/llm.py``
-    # ``_paging_programs``; ``tests/test_chip_compile.py`` holds ``extend`` to this).
+    # ``_paging_programs``; ``tests/test_chip_compile_serve.py`` holds ``extend`` to this).
 
     def _take(arena, slots, at=0, layers=1):
         """``arena[at:at + layers, slots]``: [layers, lanes, ...]."""

@@ -12,8 +12,10 @@ the expert dim, tp can still shard the mlp dim inside each expert.
 
 Beside it, for serving (``models/cohere2_moe.py``, ``models/kimi_k2.py``,
 ``models/keye_vl2.py`` and, beside a shared MLP in every layer of a period of Mamba-2
-and attention mixers, ``models/granitemoehybrid.py``) and for the train step (``models/lfm2_moe.py``, under
-``jax.grad``): a **dropless** layer for one chip's share of an expert-parallel
+and attention mixers, ``models/granitemoehybrid.py``) and for the train step (``models/lfm2_moe.py``, and
+``models/nemotron_h.py``, whose experts have **no gate**: ``W_down relu(W_up x)^2``, two
+matrices an expert, :func:`relu_squared` for :func:`trained_experts_ffn`'s
+``activation``; both under ``jax.grad``): a **dropless** layer for one chip's share of an expert-parallel
 deployment, or every expert on one chip. :func:`sigmoid_top_k`, :func:`softmax_top_k` or
 :func:`sigmoid_bias_top_k` scores every routed expert, :func:`held_experts_ffn` is told which experts
 live here and computes their part of the result for the tokens routed to
@@ -55,14 +57,23 @@ def _logits(h, router):
         precision=jax.lax.Precision.HIGHEST)
 
 
-def _top_k_of(scores, k: int, bias=None):
+#: the name :func:`sigmoid_bias_top_k` gives the experts it chose, for a remat that
+#: asks for it (``kept=True``) to keep: a layer's replay that chose again could choose
+#: otherwise where two experts lie an ulp apart, and would then sort the pairs into
+#: other rows than the kept results of :func:`trained_experts_ffn` lie in
+ROUTED = "moe_chosen"
+
+
+def _top_k_of(scores, k: int, bias=None, kept: bool = False):
     """The ``k`` largest of ``scores`` [n, R], normalised over themselves; with
     a ``bias`` [R], the ``k`` whose ``scores + bias`` are largest, which the
-    bias chooses and does not weigh."""
+    bias chooses and does not weigh. ``kept``: the chosen experts carry
+    :data:`ROUTED`'s name, and the weights are read at the named ones."""
     if bias is None:
         top, experts = jax.lax.top_k(scores, k)
     else:
         _, experts = jax.lax.top_k(scores + bias.astype(scores.dtype), k)
+        experts = checkpoint_name(experts, ROUTED) if kept else experts
         top = jnp.take_along_axis(scores, experts, axis=-1)
     return top / top.sum(-1, keepdims=True), experts.astype(jnp.int32)
 
@@ -81,13 +92,15 @@ def sigmoid_top_k(h: jax.Array, router: jax.Array, k: int):
     return _top_k_of(jax.nn.sigmoid(_logits(h, router)), k)
 
 
-def sigmoid_bias_top_k(h: jax.Array, router: jax.Array, bias: jax.Array, k: int, scale: float):
+def sigmoid_bias_top_k(h: jax.Array, router: jax.Array, bias: jax.Array, k: int, scale: float,
+                       kept: bool = False):
     """As :func:`sigmoid_top_k`, but a ``bias`` [R] on the scores decides which
     ``k`` are chosen and weighs nothing (``topk_method`` ``noaux_tc``: the
     correction that balances the experts' load without a loss): the weights
     are the chosen experts' own scores over their sum, times ``scale``
-    (``routed_scaling_factor``)."""
-    weights, experts = _top_k_of(jax.nn.sigmoid(_logits(h, router)), k, bias)
+    (``routed_scaling_factor``). ``kept``: the chosen experts are named
+    :data:`ROUTED` for the layer's remat to keep."""
+    weights, experts = _top_k_of(jax.nn.sigmoid(_logits(h, router)), k, bias, kept)
     return weights * scale, experts
 
 
@@ -112,6 +125,14 @@ GMM_TILING = (32, 4096, 512)
 GMM_TRAIN_TILING = (512, 2048, 512)
 
 
+def _whole_lanes(tile: int, size: int) -> int:
+    """``tile``, or for a smaller dimension the dimension in whole 128-lane tiles: the
+    kernel's transposes (its ``custom_vjp``) reuse a tile on the other side of the
+    weights, where a width that is no whole number of lanes (Nemotron-3-Nano's 1856)
+    is no legal block of a wider dimension; the kernel masks what overhangs."""
+    return min(tile, -(-size // 128) * 128)
+
+
 def grouped_matmul(rows, w, group_sizes, interpret: bool = False, tiling=GMM_TILING):
     """``rows`` [m, k], sorted by group, times ``w`` [groups, k, n]: row ``i``
     is multiplied with the matrix of its group, the first ``group_sizes[0]``
@@ -132,7 +153,8 @@ def grouped_matmul(rows, w, group_sizes, interpret: bool = False, tiling=GMM_TIL
     padded = jnp.pad(rows, ((0, -m % tm), (0, 0)))
     out = gmm(
         padded, w, group_sizes, preferred_element_type=rows.dtype,
-        tiling=(tm, min(tk, w.shape[1]), min(tn, w.shape[2])), interpret=interpret)
+        tiling=(tm, _whole_lanes(tk, w.shape[1]), _whole_lanes(tn, w.shape[2])),
+        interpret=interpret)
     return out[:m]
 
 
@@ -201,7 +223,7 @@ def held_experts_ffn(x, weights, experts, valid, wi, wo, offset: int = 0, layer=
 #
 # **The backward's work follows the pairs held.** The shapes are static for the
 # worst case, ``k n`` rows, but the held pairs sort first, and the backward's passes
-# over sorted rows (the un-sort's gradient, the gate's gradient) are loops over
+# over sorted rows (the un-sort's gradient, the activation's gradient) are loops over
 # blocks of ``row_block`` rows whose trip count is read from ``group_sizes.sum()``
 # on the device. A block's gradient takes the block's place in the buffer of the
 # value it is the gradient of, dead by then; the blocks that hold no pair stay
@@ -268,32 +290,40 @@ def _sorted_rows_bwd(kept, g):
 _sorted_rows.defvjp(_sorted_rows_fwd, _sorted_rows_bwd)
 
 
-def _gate_of(gate_up):
+def gated_silu(gate_up):
+    """``silu(gate) * up`` of ``gate_up`` [rows, 2f], gate and up side by side: [rows, f]."""
     f = gate_up.shape[1] // 2
     return jax.nn.silu(gate_up[:, :f]) * gate_up[:, f:]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _gated(block, gate_up, held_rows):
-    """``silu(gate) * up`` of the sorted rows ``gate_up`` [k n, 2f]: [k n, f]."""
-    return _gate_of(gate_up)
+def relu_squared(up):
+    """``relu(up)^2`` of ``up`` [rows, f]: an expert with no gate (``mlp_hidden_act``
+    ``relu2``: ``models/nemotron_h.py``), whose ``wi`` is one matrix of width f."""
+    return jnp.square(jax.nn.relu(up))
 
 
-def _gated_fwd(block, gate_up, held_rows):
-    return _gate_of(gate_up), (gate_up, held_rows)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _activated(activation, block, first, held_rows):
+    """``activation`` of the sorted rows ``first`` [k n, ...], the first grouped
+    matmul's result: [k n, f]."""
+    return activation(first)
 
 
-def _gated_bwd(block, kept, g):
-    gate_up, held_rows = kept
-
-    def gate(start, gate_up):
-        _, vjp = jax.vjp(_gate_of, _rows_at(gate_up, start, block))
-        return (_set_rows(gate_up, start, vjp(_rows_at(g, start, block))[0]),)
-
-    return _over_held_blocks(block, held_rows, gate, gate_up)[0], None
+def _activated_fwd(activation, block, first, held_rows):
+    return activation(first), (first, held_rows)
 
 
-_gated.defvjp(_gated_fwd, _gated_bwd)
+def _activated_bwd(activation, block, kept, g):
+    first, held_rows = kept
+
+    def one(start, first):
+        _, vjp = jax.vjp(activation, _rows_at(first, start, block))
+        return (_set_rows(first, start, vjp(_rows_at(g, start, block))[0]),)
+
+    return _over_held_blocks(block, held_rows, one, first)[0], None
+
+
+_activated.defvjp(_activated_fwd, _activated_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -340,7 +370,8 @@ TRAINED_COUNTERS = COUNTERS + ("moe_rows_visited",)
 
 
 #: what :func:`trained_experts_ffn` names for a remat round it: the first grouped
-#: matmul's result ``gate_up`` [k n, 2f] and the second's ``out`` [k n, d]. A remat
+#: matmul's result ``gate_up`` [k n, 2f] (an un-gated expert's: ``up`` [k n, f], under
+#: the same name) and the second's ``out`` [k n, d]. A remat
 #: that keeps both (``models/lfm2_moe.py`` ``forward``) hands them to the backward,
 #: which writes its gradients over them; one that keeps neither runs both kernels
 #: again for them
@@ -373,12 +404,15 @@ def _named_jvp(name, primals, tangents):
     return _bits_named(primals[0], name), tangents[0]
 
 
-def trained_experts_ffn(x, weights, experts, wi, wo, offset: int = 0, tiling=GMM_TRAIN_TILING):
+def trained_experts_ffn(x, weights, experts, wi, wo, offset: int = 0, tiling=GMM_TRAIN_TILING,
+                        activation=gated_silu):
     """:func:`held_experts_ffn` for a train step: the same part of the layer, the
     same counters and one more (:data:`TRAINED_COUNTERS`), every pair whose expert
     is held computed, and a gradient for ``x``, ``weights`` (through which the
     router is trained), ``wi`` and ``wo``; ``experts`` are integers and pass none.
-    Every token is real.
+    Every token is real. ``activation`` stands between the two grouped matmuls, a
+    function of the first one's rows: :func:`gated_silu` (``wi`` [E, d, 2f]: gate and
+    up side by side) or :func:`relu_squared` (``wi`` [E, d, f]: no gate).
 
     What differs is what a backward pass and some thousand rows an expert ask
     for. The grouped matmuls run with ``tiling``. The pairs are laid out
@@ -405,7 +439,7 @@ def trained_experts_ffn(x, weights, experts, wi, wo, offset: int = 0, tiling=GMM
     rows = _sorted_rows(x, held, order, place)                    # [k n, d]
     gate_up = _named(
         grouped_matmul(rows, wi.astype(x.dtype), group_sizes, tiling=tiling), TRAINED_RESIDUALS[0])
-    act = _gated(block, gate_up, held_rows)
+    act = _activated(activation, block, gate_up, held_rows)
     out = _named(
         grouped_matmul(act, wo.astype(x.dtype), group_sizes, tiling=tiling), TRAINED_RESIDUALS[1])
     y = _combined(block, out, weights, held, order, place, held_rows)

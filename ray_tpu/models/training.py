@@ -151,7 +151,8 @@ def make_train_step(
     ``cfg`` is any configuration that answers ``train_model(mesh)``
     (``gpt.TrainModel``): its model's forward up to the head, its auxiliary loss
     and the scalars it counts, which the step reports beside ``loss``,
-    ``grad_norm`` and ``step``; what it names as buffers is handed on untouched.
+    ``grad_norm`` and ``step``; what it names as buffers is handed on untouched, or as
+    its ``update_buffers`` leaves it.
 
     Its parts carry the scopes ``train.forward``, ``train.loss`` and
     ``train.optimizer`` (the backward pass inherits the forward's; a model may
@@ -189,6 +190,8 @@ def make_train_step(
             updates, new_opt = optimizer.update(
                 grads, state.opt_state, trained)
             new_params = optax.apply_updates(trained, updates)
+            if model.update_buffers is not None:
+                buffers, scalars = model.update_buffers(buffers, scalars)
             if model.buffers:
                 new_params = {**new_params, **buffers}
         metrics = {

@@ -1,5 +1,6 @@
 """What the chip's compiler says about the train path, asked without a chip:
-the flash kernels, the GPT-J and LFM2 steps, the collectives a mesh's step holds.
+the flash kernels, the GPT-J, LFM2 and Nemotron-3-Nano steps, the collectives a
+mesh's step holds.
 
 The TPU compiler is installed wherever jax[tpu] is, and compiles for a
 described (not attached) ``v5e:2x2``: it refuses what the chip would refuse
@@ -104,6 +105,34 @@ def test_one_chip_step_fits_the_chip_at_the_cells_own_size(v5e, built_for_tpu):
 _COLLECTIVE = re.compile(r"all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all")
 
 
+def _cell_step(v5e, built_for_tpu, config_name):
+    """A train cell's step as it runs: the configuration's file at its ``job.batch``,
+    compiled for one described chip: ``(cfg, file, compiled)``."""
+    import importlib
+    import json
+
+    from benchmark.manifest import published_keys
+
+    built_for_tpu(True)
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "configs", config_name + ".json")) as f:
+        file = json.load(f)
+    architecture = importlib.import_module("benchmark.models." + file["model_type"])
+    cfg = architecture.program_config(published_keys(file))
+    batch = tuple(file["job"]["batch"])
+    mesh = MeshSpec().build(v5e[:1])
+    opt = default_optimizer(file["job"]["learning_rate"])
+    _, abstract = abstract_state(cfg, opt, jax.ShapeDtypeStruct(batch, jnp.int32))
+    shardings = nn.meta.unbox(state_shardings(mesh, abstract))
+    state = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        nn.meta.unbox(abstract), shardings,
+    )
+    tokens = jax.ShapeDtypeStruct(batch, jnp.int32, sharding=shd.batch_sharding(mesh))
+    step = make_train_step(cfg, opt, mesh, state_shardings_tree=shardings)
+    return cfg, file, step.lower(state, tokens).compile()
+
+
 def test_lfm2_moe_share_train_step_compiles_and_copies_no_expert_stack(v5e, built_for_tpu):
     """``lfm2-24b-a2b-train-1chip-fixed-batch`` as it runs: the configuration's
     file (a dense layer and one period at the published widths, 32 of 64 experts
@@ -123,31 +152,11 @@ def test_lfm2_moe_share_train_step_compiles_and_copies_no_expert_stack(v5e, buil
     their bits: kept as floats each goes through a ``reduce-precision`` that XLA
     cannot alias through (9 in the text, 16,156,333,056 B). The step compiles to
     15,820,434,432 B, where the step that replayed both took 16,613,009,408."""
-    import json
-
-    from benchmark.manifest import published_keys
-    from benchmark.models import lfm2_moe as architecture
     from benchmark.traffic import train_fixed_batch
     from ray_tpu.models import moe
 
-    built_for_tpu(True)
-    with open(os.path.join(
-            os.path.dirname(__file__), "..", "benchmark", "configs",
-            "lfm2-24b-a2b-train-ep2.json")) as f:
-        file = json.load(f)
-    cfg = architecture.program_config(published_keys(file))
+    cfg, file, compiled = _cell_step(v5e, built_for_tpu, "lfm2-24b-a2b-train-ep2")
     batch = tuple(file["job"]["batch"])
-    mesh = MeshSpec().build(v5e[:1])
-    opt = default_optimizer(file["job"]["learning_rate"])
-    _, abstract = abstract_state(cfg, opt, jax.ShapeDtypeStruct(batch, jnp.int32))
-    shardings = nn.meta.unbox(state_shardings(mesh, abstract))
-    state = jax.tree.map(
-        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
-        nn.meta.unbox(abstract), shardings,
-    )
-    tokens = jax.ShapeDtypeStruct(batch, jnp.int32, sharding=shd.batch_sharding(mesh))
-    step = make_train_step(cfg, opt, mesh, state_shardings_tree=shardings)
-    compiled = step.lower(state, tokens).compile()
     text = compiled.as_text()
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes > 6 * cfg.num_params()        # weights and two moments
@@ -169,6 +178,59 @@ def test_lfm2_moe_share_train_step_compiles_and_copies_no_expert_stack(v5e, buil
     moved = re.findall(
         rf"= {stack}\S* (?:copy|transpose|dynamic-slice|dynamic-update-slice)\(", text)
     assert not moved, moved[:3]
+
+
+def test_nemotron_h_share_train_step_fits_the_chip_with_its_kernels(v5e, built_for_tpu):
+    """``nemotron-3-nano-train-1chip-fixed-batch`` as it runs: the configuration's file
+    (four Mamba-2 layers, four expert layers of 16 held experts, one attention layer at
+    the published widths, 986,254,848 parameters: 5.9 GB of bfloat16 weights and
+    moments as arguments), 2 x 8192 tokens. It fits the chip with the chunked scan's
+    backward rebuilt a sub-chunk at a time (``ssm_chunked(rebuild=True)``: plain
+    autodiff's residuals of 64 sub-chunks a sequence asked for 20.5 GB) and both grouped
+    matmuls' results kept across the four expert layers; the three flash kernels and the
+    grouped matmuls are in it, what the configuration's ``job.min_kernels`` asks for and
+    no more (a layer's remat replays neither kernel); and the file's
+    ``compiled_bytes_per_device`` is what the compiler says."""
+    from benchmark.traffic import train_fixed_batch
+
+    cfg, file, compiled = _cell_step(v5e, built_for_tpu, "nemotron-3-nano-30b-a3b-train-ep8")
+    memory = compiled.memory_analysis()
+    assert cfg.num_params() == 986_254_848
+    assert memory.argument_size_in_bytes > 6 * cfg.num_params()        # weights and two moments
+    assert _device_bytes(compiled) < HBM_BYTES
+    kernels = train_fixed_batch.kernels_of(compiled.as_text())
+    assert kernels == file["job"]["min_kernels"]
+    assert not train_fixed_batch.missing_kernels(kernels, file["job"]["min_kernels"])
+    stated = file["compiled_bytes_per_device"]
+    assert _device_bytes(compiled) <= 1.01 * stated["total"]
+    assert stated["total"] == (
+        stated["argument"] + stated["temp"] + stated["output"] - stated["alias"])
+
+
+def test_lfm2_step_is_the_parents_under_the_default_activation(capsys, built_for_tpu):
+    """``moe.trained_experts_ffn``'s activation is an argument since PR 62 and LFM2 names
+    none: its step, lowered for the TPU as ``scripts/program_digest.py`` lowers it
+    (operation names and scopes kept, source lines dropped), is text for text PR 61's.
+    A change that means to move LFM2's step replaces the digest, and says so."""
+    import importlib.util
+    import json
+
+    from benchmark.manifest import published_keys
+    from benchmark.models import lfm2_moe as architecture
+
+    root = os.path.join(os.path.dirname(__file__), "..")
+    spec = importlib.util.spec_from_file_location(
+        "program_digest", os.path.join(root, "scripts", "program_digest.py"))
+    program_digest = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(program_digest)
+    with open(os.path.join(root, "benchmark", "configs", "lfm2-24b-a2b-train-ep2.json")) as f:
+        file = json.load(f)
+    built_for_tpu(True)
+    program_digest.train_program(
+        "lfm2", architecture.program_config(published_keys(file)), file["job"], 1)
+    assert capsys.readouterr().out.split() == [
+        "lfm2", "train", "step", "4x4096", "on", "1",
+        "21522d9971a76161b6a4bb0efd8172ab21ed660b17b3e2ae3c7f8720d921ca4a"]
 
 
 def _computations(text):

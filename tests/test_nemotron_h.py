@@ -1,0 +1,407 @@
+"""Nemotron-3-Nano's block on the train path at a small size, in float32 on the CPU,
+against the plain reference (``benchmark/reference/nemotron_h_reference.py``, which
+knows nothing of ``models/nemotron_h.py``, ``models/moe.py`` or the chunked scan): loss
+and every parameter family's gradient, the chunked scan with and without groups
+against the recurrence a token at a time, the eight shares tied to the uncut layer, the
+un-gated expert layer against a loop over experts, one whole step.
+
+Tolerances: both sides compute in float32 at the CPU's full matmul precision and
+differ in the order of their sums alone (a chunked scan against a token at a time; a
+sort and a ragged dot against a loop over experts with dense masks; a blockwise loss
+against full logits; a remat), so a loss agrees to a few float32 roundings (2e-6 of
+itself) and a gradient to 1e-5 of its largest entry. The chunked scan sums a
+sub-chunk's 8 tokens in another order than the recurrence and passes ``exp`` of
+differences where the recurrence multiplies decays: 1e-5 of the largest entry."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import nemotron_h_reference as reference
+from ray_tpu.models import moe, nemotron_h
+from ray_tpu.models.gpt import blockwise_next_token_loss
+from ray_tpu.models.granitemoehybrid import ssm_chunked
+from ray_tpu.models.training import default_optimizer, init_sharded_state, make_train_step
+from ray_tpu.parallel.mesh import MeshSpec
+
+BATCH = (2, 32)
+
+
+def model_keys(cfg):
+    """The reference's view of ``cfg``: the published keys it reads."""
+    return {
+        "hybrid_override_pattern": cfg.pattern, "mamba_num_heads": cfg.ssm_heads,
+        "mamba_head_dim": cfg.ssm_head_dim, "n_groups": cfg.ssm_groups,
+        "ssm_state_size": cfg.ssm_state, "num_attention_heads": cfg.num_heads,
+        "num_key_value_heads": cfg.kv_heads, "head_dim": cfg.head_dim,
+        "num_experts_per_tok": cfg.experts_per_token, "routed_scaling_factor": cfg.routed_scale,
+        "norm_topk_prob": True, "expert_offset": cfg.expert_offset, "norm_eps": cfg.norm_eps,
+        "layer_norm_epsilon": cfg.norm_eps,
+    }
+
+
+def program_loss(cfg, params, tokens):
+    (hidden, kernel, bias), aux, counters = nemotron_h.forward(cfg, params, tokens)
+    return blockwise_next_token_loss(hidden, kernel, bias, tokens) + aux, counters
+
+
+def close(got, want, rel):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    assert np.abs(got - want).max() <= rel * max(np.abs(want).max(), 1e-30), (
+        np.abs(got - want).max(), np.abs(want).max())
+
+
+@pytest.fixture(scope="module")
+def nano():
+    cfg = nemotron_h.nemotron_h_nano()
+    params = jax.jit(lambda rng: nemotron_h.init_params(cfg, rng))(jax.random.PRNGKey(7))
+    tokens = jax.random.randint(jax.random.PRNGKey(8), BATCH, 0, cfg.vocab_size)
+    return cfg, params, tokens
+
+
+@pytest.fixture(scope="module")
+def both_gradients(nano):
+    """Loss and gradients of the program and of the reference, each by ``jax.grad`` of
+    its own forward, laid out as the program's parameters."""
+    cfg, params, tokens = nano
+    bias = params["expert_bias"]
+    trained = {k: v for k, v in params.items() if k != "expert_bias"}
+    (loss, _), grads = jax.value_and_grad(
+        lambda t: program_loss(cfg, {**t, "expert_bias": bias}, tokens), has_aux=True)(trained)
+    want, ref_grads = jax.value_and_grad(lambda t: reference.loss(
+        reference.from_program_params({**t, "expert_bias": bias}), tokens, model_keys(cfg)))(trained)
+    return (loss, grads), (want, ref_grads)
+
+
+def test_the_loss_is_the_references(both_gradients):
+    (loss, _), (want, _) = both_gradients
+    assert float(loss) == pytest.approx(float(want), rel=2e-6)
+
+
+# every parameter family, by the names its arrays have in a layer's tree (or at the top)
+FAMILIES = {
+    "W_in": ("in",), "taps": ("conv", "conv_bias"), "dt_bias": ("dt_bias",), "A_log": ("A_log",),
+    "D": ("D",), "group_norm": ("norm",), "W_out": ("out",), "layer_norms": ("ln",),
+    "router": ("router",), "held_experts": ("wi", "wo"),
+    "shared_expert": ("shared_wi", "shared_wo"), "attention": ("q", "k", "v", "o"),
+    "embedding": ("wte",), "head": ("head",), "final_norm": ("ln_f",),
+}
+
+
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_a_familys_gradient_is_the_references(both_gradients, family):
+    (_, grads), (_, ref_grads) = both_gradients
+    leaves = jax.tree_util.tree_leaves_with_path(grads)
+    compared = 0
+    for (path, got), want in zip(leaves, jax.tree.leaves(ref_grads)):
+        if path[-1].key in FAMILIES[family]:
+            assert float(jnp.abs(want).max()) > 0, jax.tree_util.keystr(path)
+            close(got, want, 1e-5)
+            compared += 1
+    assert compared >= len(FAMILIES[family])
+    # and no array of the model is of no family
+    assert {path[-1].key for path, _ in leaves} == {n for names in FAMILIES.values() for n in names}
+
+
+def test_the_counts_are_the_published_models_and_the_cuts(nano):
+    cfg, params, _ = nano
+    assert cfg.num_params() == sum(x.size for x in jax.tree.leaves(params))
+    assert nemotron_h.NemotronHConfig().num_params() == 31_577_940_288
+    cut = nemotron_h.NemotronHConfig(vocab_size=16384, pattern="MEMEM*EME", num_experts=16)
+    assert cut.num_params() == 986_254_848
+    with pytest.raises(ValueError, match="not among the 128"):
+        dataclasses.replace(cut, expert_offset=113)
+    with pytest.raises(ValueError, match="not M, E or"):
+        dataclasses.replace(cut, pattern="ME-")
+
+
+# -- the chunked scan ------------------------------------------------------------------
+
+
+def scan_inputs(groups, lanes=2, t=32, heads=8, p=8, n=16, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    x = jax.random.normal(keys[0], (lanes, t, heads, p))
+    dt = jax.nn.softplus(jax.random.normal(keys[1], (lanes, t, heads)) - 1.0)
+    a = -jnp.exp(jax.random.normal(keys[2], (heads,)))
+    shape = (lanes, t, n) if groups is None else (lanes, t, groups, n)
+    b, c = jax.random.normal(keys[3], shape), jax.random.normal(keys[4], shape)
+    return x, dt, a, b, c, jax.random.normal(keys[5], (lanes, t, heads, p))
+
+
+def chunked(x, dt, a, b, c, rebuild, chunk=8):
+    state = jnp.zeros((x.shape[0], x.shape[2], x.shape[3], b.shape[-1]), jnp.float32)
+    return ssm_chunked(state, x, dt, a, b, c, chunk, jnp.float32, rebuild=rebuild)[0]
+
+
+def token_by_token(x, dt, a, b, c):
+    """The reference's recurrence, each head handed its group's B and C."""
+    heads = x.shape[2]
+    if b.ndim == 3:
+        b, c = b[:, :, None], c[:, :, None]
+    b, c = (jnp.repeat(v, heads // v.shape[2], axis=2) for v in (b, c))
+    return reference.recurrence(x, dt, a, b, c)
+
+
+@pytest.mark.parametrize("rebuild", [False, True], ids=["autodiff", "rebuilt"])
+@pytest.mark.parametrize("groups", [None, 1, 4, 8], ids=["no-axis", "1-group", "4-groups", "8-groups"])
+def test_the_chunked_scan_is_the_recurrence_a_token_at_a_time(groups, rebuild):
+    """Values and the gradient of every input, with B and C in groups, in one group and
+    as granite hands them over (no group axis)."""
+    *inputs, weigh = scan_inputs(groups)
+    got, got_grads = jax.value_and_grad(
+        lambda *v: (chunked(*v, rebuild) * weigh).sum(), argnums=range(5))(*inputs)
+    want, want_grads = jax.value_and_grad(
+        lambda *v: (token_by_token(*v) * weigh).sum(), argnums=range(5))(*inputs)
+    close(chunked(*inputs, rebuild), token_by_token(*inputs), 1e-5)
+    assert float(got) == pytest.approx(float(want), rel=1e-5, abs=1e-4)
+    for g, w in zip(got_grads, want_grads):
+        close(g, w, 2e-5)
+
+
+def test_groups_matter_and_one_group_is_no_axis():
+    x, dt, a, b, c, _ = scan_inputs(4)
+    first = tuple(jnp.broadcast_to(v[:, :, :1], v.shape) for v in (b, c))
+    assert float(jnp.abs(chunked(x, dt, a, b, c, False) - chunked(x, dt, a, *first, False)).max()) > 0.1
+    # all heads on group 0 is what the form without the axis computes, bit for bit
+    np.testing.assert_array_equal(
+        chunked(x, dt, a, b[:, :, :1], c[:, :, :1], False), chunked(x, dt, a, b[:, :, 0], c[:, :, 0], False))
+
+
+@pytest.mark.parametrize("groups", [None, 4], ids=["no-axis", "4-groups"])
+def test_a_sub_chunks_result_does_not_depend_on_where_in_the_call_it_lies(groups):
+    x, dt, a, b, c, _ = scan_inputs(groups)
+    state = jnp.zeros((2, 8, 8, 16), jnp.float32)
+    y, _, between = ssm_chunked(state, x, dt, a, b, c, 8, jnp.float32)
+    third = slice(16, 24)
+    alone, after, _ = ssm_chunked(
+        between[1], x[:, third], dt[:, third], a, b[:, third], c[:, third], 8, jnp.float32)
+    np.testing.assert_array_equal(alone, y[:, third])
+    np.testing.assert_array_equal(after, between[2])
+
+
+def test_a_state_kept_in_bfloat16_moves_the_recurrence_past_the_tolerance():
+    x, dt, a, b, c, _ = scan_inputs(8)
+    want = token_by_token(x, dt, a, b, c)
+    rounded = reference.recurrence(x, dt, a, b, c, round_state=True)    # 8 heads, 8 groups
+    assert float(jnp.abs(rounded - want).max()) > 100 * 1e-5 * float(jnp.abs(want).max())
+
+
+# -- the expert layer ------------------------------------------------------------------
+
+
+def loop_over_experts(x, weights, chosen, wi, wo, offset, activation):
+    out = jnp.zeros((x.shape[0], wo.shape[-1]), jnp.float32)
+    for e in range(wi.shape[0]):
+        mask = (weights * (chosen == offset + e)).sum(-1)
+        out = out + mask[:, None] * (activation(x @ wi[e]) @ wo[e])
+    return out
+
+
+@pytest.mark.parametrize("offset", [0, 8], ids=["first-share", "a-middle-share"])
+@pytest.mark.parametrize(
+    "activation,columns", [(moe.relu_squared, 1), (moe.gated_silu, 2)], ids=["relu2", "gated"])
+def test_the_trained_expert_layer_is_a_loop_over_the_held_experts(activation, columns, offset):
+    """Values and gradients, with pairs of absent experts present: 4 of 16 held."""
+    n, d, f, k = 48, 16, 24, 3
+    keys = jax.random.split(jax.random.PRNGKey(offset), 5)
+    x = jax.random.normal(keys[0], (n, d))
+    wi = 0.3 * jax.random.normal(keys[1], (4, d, columns * f))
+    wo = 0.3 * jax.random.normal(keys[2], (4, f, d))
+    weights, chosen = moe.sigmoid_bias_top_k(
+        x, jax.random.normal(keys[3], (d, 16)), 0.1 * jax.random.normal(keys[4], (16,)), k, 2.5)
+    held = (chosen >= offset) & (chosen < offset + 4)
+    assert 0 < int(held.sum()) < n * k          # some pairs are held here, some elsewhere
+
+    def program(x, weights, wi, wo):
+        return moe.trained_experts_ffn(x, weights, chosen, wi, wo, offset, activation=activation)[0]
+
+    def plain(x, weights, wi, wo):
+        return loop_over_experts(x, weights, chosen, wi, wo, offset, activation)
+
+    weigh = jax.random.normal(jax.random.PRNGKey(9), (n, d))
+    close(program(x, weights, wi, wo), plain(x, weights, wi, wo), 1e-5)
+    got = jax.grad(lambda *v: (program(*v) * weigh).sum(), argnums=range(4))(x, weights, wi, wo)
+    want = jax.grad(lambda *v: (plain(*v) * weigh).sum(), argnums=range(4))(x, weights, wi, wo)
+    for g, w in zip(got, want):
+        close(g, w, 1e-5)
+    counters = moe.trained_experts_ffn(x, weights, chosen, wi, wo, offset, activation=activation)[1]
+    assert int(counters[1]) == int(held.sum())
+
+
+def test_the_eight_shares_add_up_to_the_uncut_layer_and_so_do_their_gradients():
+    """ep8 at a small size: eight configurations that differ in ``expert_offset`` alone
+    (0, 16, ..., 112), each with its 16 of the 128 experts; the shared expert and the
+    residual counted once, their parts sum to what the reference gives with all 128."""
+    cfg = nemotron_h.nemotron_h_nano(router_experts=128, num_experts=16, experts_per_token=6)
+    keys = jax.random.split(jax.random.PRNGKey(3), 7)
+    d, f = cfg.embed_dim, cfg.expert_dim
+    x = jax.random.normal(keys[0], (2, 24, d))
+    whole = {
+        "ln": 1.0 + 0.1 * jax.random.normal(keys[1], (d,)),
+        "router": jax.random.normal(keys[2], (d, 128)),
+        "wi": 0.2 * jax.random.normal(keys[3], (128, d, f)),
+        "wo": 0.2 * jax.random.normal(keys[4], (128, f, d)),
+        "shared_wi": 0.2 * jax.random.normal(keys[5], (d, cfg.shared_dim)),
+        "shared_wo": 0.2 * jax.random.normal(keys[6], (cfg.shared_dim, d)),
+    }
+    bias = 0.05 * jax.random.normal(jax.random.PRNGKey(4), (128,))
+
+    def shared_of(x):
+        r = nemotron_h.layers.rms_norm(x, whole["ln"], cfg.norm_eps)
+        return moe.relu_squared(r @ whole["shared_wi"]) @ whole["shared_wo"]
+
+    def shares(x):
+        total, pairs = x + shared_of(x), 0
+        for offset in range(0, 128, 16):
+            share = dataclasses.replace(cfg, expert_offset=offset)
+            held = slice(offset, offset + 16)
+            p = {**whole, "wi": whole["wi"][held], "wo": whole["wo"][held]}
+            y, counters, _ = nemotron_h._layer(share, nemotron_h.EXPERTS, x, p, bias)
+            total, pairs = total + (y - x - shared_of(x)), pairs + counters[1]
+        return total, pairs
+
+    def uncut(x):
+        model = {**model_keys(cfg), "expert_offset": 0}
+        with jax.default_matmul_precision("highest"):
+            return reference.layer_of("E", x, whole, bias, model)
+
+    total, pairs = shares(x)
+    assert int(pairs) == 2 * 24 * 6              # every pair is some share's, once
+    close(total, uncut(x), 1e-5)
+    weigh = jax.random.normal(jax.random.PRNGKey(5), x.shape)
+    close(
+        jax.grad(lambda x: (shares(x)[0] * weigh).sum())(x),
+        jax.grad(lambda x: (uncut(x) * weigh).sum())(x), 1e-5)
+
+
+def test_the_seeded_bias_moves_the_chosen_set_of_many_tokens(nano):
+    cfg, params, tokens = nano
+    p = params["layers"][1]
+    x = jax.random.normal(jax.random.PRNGKey(2), (512, cfg.embed_dim))
+    args = (x, p["router"], params["expert_bias"][0], cfg.experts_per_token, cfg.routed_scale)
+    _, with_bias = moe.sigmoid_bias_top_k(*args)
+    _, without = moe.sigmoid_bias_top_k(args[0], args[1], jnp.zeros_like(args[2]), *args[3:])
+    moved = (jnp.sort(with_bias, -1) != jnp.sort(without, -1)).any(-1).mean()
+    assert float(moved) > 0.1
+
+
+@pytest.mark.parametrize("wrong", [w for w in reference.WRONG if w != "bf16_state"])
+def test_each_left_out_mechanism_moves_the_references_loss(nano, wrong):
+    """Past twice the tolerance the program's loss is held to (a state kept in bfloat16
+    moves this small model's loss less than that: the scan's own test holds it)."""
+    cfg, params, tokens = nano
+    ref_params = reference.from_program_params(params)
+    want = float(reference.loss(ref_params, tokens, model_keys(cfg)))
+    reads = float(reference.loss(ref_params, tokens, model_keys(cfg), wrong=wrong))
+    assert abs(reads - want) > 2 * 2e-6 * want, (wrong, reads, want)
+
+
+@pytest.mark.parametrize("rate", [0.0, 0.01], ids=["bias-handed-on", "bias-balanced"])
+def test_a_step_trains_counts_and_hands_the_bias_on_or_moves_it_by_the_loads(nano, rate):
+    """With no ``bias_update_rate`` the bias leaves a step as it came; with one, every
+    scored expert's bias has moved that far towards an even load, by the loads the
+    step's own forward counted (the first step's are the initial weights' own), and
+    the loads are no scalar the step reports."""
+    cfg, _, tokens = nano
+    cfg = dataclasses.replace(cfg, bias_update_rate=rate)
+    mesh = MeshSpec().build(jax.devices()[:1])
+    opt = default_optimizer(1e-3)
+    state, shardings = init_sharded_state(cfg, mesh, opt, jax.random.PRNGKey(1), BATCH)
+    bias = jax.device_get(state.params["expert_bias"])
+    counted = jax.device_get(nemotron_h.forward(cfg, state.params, tokens)[2])
+    step = make_train_step(cfg, opt, mesh, state_shardings_tree=shardings)
+    losses, after_one = [], None
+    with mesh:
+        for _ in range(3):
+            state, metrics = step(state, tokens)
+            after_one = after_one or jax.device_get(state.params["expert_bias"])
+            losses.append(float(metrics["loss"]))
+    assert losses[2] < losses[1] < losses[0]
+    tokens_a_step = BATCH[0] * BATCH[1]
+    assert set(moe.TRAINED_COUNTERS) <= set(metrics) and "moe_loads" not in metrics
+    assert float(metrics["moe_tokens"]) == 4 * tokens_a_step           # four expert layers
+    assert 0 < float(metrics["moe_assignments"]) < 4 * tokens_a_step * cfg.experts_per_token
+    if not rate:
+        assert "moe_loads" not in counted
+        for before, after in zip(bias, jax.device_get(state.params["expert_bias"])):
+            np.testing.assert_array_equal(before, after)
+    else:
+        loads = counted["moe_loads"]
+        assert loads.shape == (4, cfg.router_experts)
+        assert (loads.sum(-1) == tokens_a_step * cfg.experts_per_token).all()
+        held = slice(cfg.expert_offset, cfg.expert_offset + cfg.num_experts)
+        assert loads[:, held].sum() == counted["moe_assignments"]
+        mean = tokens_a_step * cfg.experts_per_token / cfg.router_experts
+        for before, after, load in zip(bias, after_one, loads):
+            np.testing.assert_allclose(after - before, rate * np.sign(mean - load), atol=1e-7)
+    # the bias has no moment: the optimizer's state is of the trained parameters alone
+    trained = sum(x.size for k, v in state.params.items() if k != "expert_bias"
+                  for x in jax.tree.leaves(v))
+    moments = [x.size for x in jax.tree.leaves(state.opt_state) if x.ndim]
+    assert sum(moments) == 2 * trained
+
+
+@pytest.mark.parametrize("rate", [0.001, 0.01])
+def test_the_rule_moves_a_bias_against_its_experts_load_and_not_where_it_is_even(rate):
+    loads = jnp.asarray([[4, 0, 2, 2], [1, 1, 1, 5]], jnp.int32)
+    buffers = {"expert_bias": [jnp.zeros(4), jnp.full(4, 0.5)], "kept": 3}
+    after, counted = nemotron_h.balance_bias(rate, buffers, {"moe_loads": loads, "moe_tokens": 8})
+    assert counted == {"moe_tokens": 8} and after["kept"] == 3
+    np.testing.assert_allclose(after["expert_bias"][0], rate * np.array([-1, 1, 0, 0]), atol=1e-9)
+    np.testing.assert_allclose(
+        after["expert_bias"][1], 0.5 + rate * np.array([1, 1, 1, -1]), atol=1e-7)
+
+
+def test_the_rule_evens_a_lopsided_load_and_with_it_the_held_share():
+    """A router whose logits share an offset an expert (what a random block's hidden
+    rows give it) sends a few experts most of the pairs, and the share of the pairs
+    that the first experts hold is whatever those offsets are (0.45 of its expectation
+    here); some hundred steps of the rule at 0.01 bring every expert to the mean as
+    nearly as steps of 0.01 can (the loads then swing between two states, the busiest
+    expert at 1.4 means) and the held share to its expectation."""
+    n, scored, k, held = 4096, 32, 4, 4
+    keys = jax.random.split(jax.random.PRNGKey(11), 3)
+    h = jax.random.normal(keys[0], (n, 16))
+    router = jax.random.normal(keys[1], (16, scored)) / 4.0
+    h = h + 2.0 * jax.random.normal(keys[2], (16,))            # a part every token shares
+
+    @jax.jit
+    def loads_under(bias):
+        _, chosen = moe.sigmoid_bias_top_k(h, router, bias, k, 2.5)
+        return (chosen.reshape(-1, 1) == jnp.arange(scored)).sum(0, dtype=jnp.int32)
+
+    buffers = {"expert_bias": [jnp.zeros(scored)]}
+    mean = n * k / scored
+    first = loads_under(buffers["expert_bias"][0])
+    assert float(first.max()) > 3 * mean and float(first[:held].sum()) < 0.6 * held * mean
+    for _ in range(150):
+        loads = loads_under(buffers["expert_bias"][0])
+        buffers, _ = nemotron_h.balance_bias(0.01, buffers, {"moe_loads": loads[None]})
+    last = loads_under(buffers["expert_bias"][0])
+    assert float(last.max()) < 1.6 * mean and float(last.min()) > 0.4 * mean
+    assert abs(float(last[:held].sum()) / (held * mean) - 1) < 0.15
+
+
+def test_the_remat_keeps_the_routers_choice_beside_the_grouped_matmuls_results(nano):
+    """An expert layer's backward reads the two kept results in the rows the forward's
+    choice sorted the pairs into. A replay that ran ``top_k`` again could choose
+    otherwise where two experts' biased scores lie an ulp apart, every later group's
+    rows would lie one off, and a row of no group, which holds whatever the buffer
+    held, would be read as the last group's (on the chip: gradients of 1e29 and NaN
+    at one seed's 78th step). So the choice is kept, once a layer, as integers."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    cfg, params, tokens = nano
+    bias = params["expert_bias"]
+    trained = {k: v for k, v in params.items() if k != "expert_bias"}
+    kept = saved_residuals(
+        lambda t: program_loss(cfg, {**t, "expert_bias": bias}, tokens)[0], trained)
+    named = [(str(aval), why.split("'")[1]) for aval, why in kept if "named '" in why]
+    pairs = BATCH[0] * BATCH[1] * cfg.experts_per_token
+    chosen = (f"int32[{BATCH[0] * BATCH[1]},{cfg.experts_per_token}]", moe.ROUTED)
+    assert named.count(chosen) == cfg.pattern.count("E")
+    for name in moe.TRAINED_RESIDUALS:
+        assert sum(n == name and a.startswith(f"uint32[{pairs},") for a, n in named) == 4
