@@ -391,7 +391,7 @@ def ssm_step_slots(arena, at, slots, real, fresh, x, dt, a, b, c, *,
     return y.swapaxes(2, 3).reshape(lanes, all_heads, p), arena
 
 
-def ssm_chunked(state, x, dt, a, b, c, chunk: int, dtype, rebuild: bool = False):
+def ssm_chunked(state, x, dt, a, b, c, chunk: int, dtype):
     """The recurrence over ``t`` tokens in sub-chunks of ``chunk`` (``t`` a whole
     number of them), as matmuls: ``x`` [lanes, t, heads, p], ``dt`` [lanes, t,
     heads] float32 (0 for a padded token), ``b``, ``c`` [lanes, t, n], or in
@@ -406,12 +406,9 @@ def ssm_chunked(state, x, dt, a, b, c, chunk: int, dtype, rebuild: bool = False)
     ...]``. One ``lax.scan`` body: a sub-chunk's result does not depend on where
     in the call it lies. Differentiable (``jax.grad`` through the ``lax.scan``: a
     masked pair's weight is ``exp(-inf)``, whose gradient is 0 as its value is),
-    with the state's path float32 in the backward as in the forward. A train
-    step says ``rebuild``: the body is then a ``jax.checkpoint``, so that the
-    backward keeps the inputs and the states between sub-chunks alone (``heads x p x
-    n`` float32 a sub-chunk and lane) and is one reverse ``lax.scan`` that rebuilds a
-    sub-chunk's quantities before it differentiates them, where plain autodiff
-    keeps every sub-chunk's ``[lanes, heads, chunk, chunk]`` products."""
+    with the state's path float32 in the backward as in the forward; plain
+    autodiff keeps every sub-chunk's ``[lanes, heads, chunk, chunk]`` products, which
+    a train step at full size has no room for: that is :func:`ssm_scan`'s."""
     lanes, t, heads, p = x.shape
     n, f32 = b.shape[-1], jnp.float32
     nc = t // chunk
@@ -453,9 +450,338 @@ def ssm_chunked(state, x, dt, a, b, c, chunk: int, dtype, rebuild: bool = False)
             preferred_element_type=f32))
         return state, (y.transpose(0, 2, 1, 3), state)
 
-    state, (y, between) = jax.lax.scan(
-        jax.checkpoint(one) if rebuild else one, state, tuple(map(split, (x, dt, b, c))))
+    state, (y, between) = jax.lax.scan(one, state, tuple(map(split, (x, dt, b, c))))
     return jnp.moveaxis(y, 0, 1).reshape(lanes, t, heads, p), state, between
+
+
+SSM_SCAN_HEADS = 8      # heads of one B/C group that a grid step of the scan's kernels holds
+
+_NT = (((1,), (1,)), ((), ()))      # [m, k] x [n, k] -> [m, n]
+_TN = (((0,), (0,)), ((), ()))      # [k, m] x [k, n] -> [m, n]
+
+
+def _scan_head_lanes(heads, width):
+    """``[heads, width]`` float32: 1 where a lane of ``[.., heads x p]`` is the head's."""
+    p = width // heads
+    lane = jax.lax.broadcasted_iota(jnp.int32, (heads, width), 1)
+    first = p * jax.lax.broadcasted_iota(jnp.int32, (heads, width), 0)
+    return ((lane >= first) & (lane < first + p)).astype(jnp.float32)
+
+
+def _scan_spread(rows, mine):
+    """``rows`` [heads, chunk] float32 -> ``[chunk, heads x p]``: what a token has of a
+    head over the head's lanes, **exactly**, as one pass of the MXU: a float32 is the sum
+    of three bfloat16s, the three stand side by side along the product's inner axis
+    against ``mine`` three times over (0s and 1s: exact in bfloat16), and each sum has
+    three terms that float32 adds without rounding. (At the highest precision the same
+    product is six passes a 128 x 128 tile, as many as the state's read-out.)"""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    first = rows.astype(bf16).astype(f32)
+    second = (rows - first).astype(bf16).astype(f32)
+    third = rows - first - second
+    parts = jnp.concatenate([first, second, third], axis=0).T.astype(bf16)   # [chunk, 3 x heads]
+    return jnp.dot(
+        parts, jnp.concatenate([mine] * 3, axis=0).astype(bf16), preferred_element_type=f32)
+
+
+def _scan_tiles(heads, width):
+    """The heads of ``[.., heads x p]`` by vector tile of 128 lanes: ``(the tile's lanes,
+    its heads)``. A head's ``[.., p]`` is taken out of its tile by its 0s and 1s
+    (:func:`_scan_head_lanes`), not by a slice: a product over the masked tile is the
+    head's, and lands where the head lies."""
+    tile = min(128, width)
+    per = tile * heads // width
+    assert per >= 1, f"a head of {width // heads} lanes spans several vector tiles"
+    return [(slice(k * tile, (k + 1) * tile), range(k * per, (k + 1) * per))
+            for k in range(width // tile)]
+
+
+def _ssm_scan_fwd_kernel(state0, x, dt, run, b, c, y, last, *rest, heads, dtype):
+    """One sub-chunk of one block of ``heads`` heads that share a B and a C: ``x``
+    [chunk, heads x p], ``dt`` and ``run`` (the sub-chunk's running sum of ``dt A``)
+    [heads, chunk] float32, ``b``, ``c`` [chunk, n]. The state, ``[n, heads x p]``
+    float32 (a head's ``[p, n]`` transposed, the heads side by side), stays in
+    ``state`` (scratch) from one sub-chunk to the next, ``state0`` at the first. Writes
+    ``y`` [chunk, heads x p] float32, the state before the sub-chunk (``between``, where
+    the caller keeps it) and the one after the last (``last``). The C.B pairs are made
+    once for the block's heads and the state is read out for all of them in one
+    product; what a token has of its head alone (``dt``, the decays) is spread over the
+    head's lanes (:func:`_scan_spread`), so that everything ``[chunk, heads x p]`` is
+    whole vectors; the masked decay and the weights ``[chunk, chunk]`` a head are
+    made, used and dropped here."""
+    from jax.experimental import pallas as pl
+
+    between, state = rest if len(rest) == 2 else (None, *rest)
+    i, f32, (chunk, width) = pl.program_id(2), jnp.float32, x.shape
+    highest = jax.lax.Precision.HIGHEST
+
+    @pl.when(i == 0)
+    def _():
+        state[...] = state0[...]
+
+    before = state[...]
+    if between is not None:
+        between[...] = before
+    bq, cq = b[...].astype(dtype), c[...].astype(dtype)
+    pair = jax.lax.dot_general(cq, bq, _NT, preferred_element_type=f32)      # [q, s]
+    read = jnp.dot(cq.astype(f32), before, precision=highest, preferred_element_type=f32)
+    mine = _scan_head_lanes(heads, width)
+    run_col = run[...].T                                                     # [chunk, heads]
+    step, ran = _scan_spread(dt[...], mine), _scan_spread(run[...], mine)    # [chunk, width]
+    fed, end = step * x[...].astype(f32), ran[chunk - 1:]                    # [chunk, width], [1, width]
+    causal = (jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+              >= jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1))
+    from_state = jnp.exp(ran) * read
+    for lanes, tile in _scan_tiles(heads, width):
+        out = from_state[:, lanes]
+        for h in tile:
+            # masked before the exponential: a later token's difference is positive
+            weight = pair * jnp.exp(jnp.where(causal, run_col[:, h:h + 1] - run[h:h + 1, :], -jnp.inf))
+            theirs = fed[:, lanes] * mine[h:h + 1, lanes]
+            out = out + jnp.dot(
+                weight.astype(dtype), theirs.astype(dtype), preferred_element_type=f32)
+        y[:, lanes] = out
+    state[...] = jnp.exp(end) * before + jax.lax.dot_general(
+        bq, (fed * jnp.exp(end - ran)).astype(dtype), _TN, preferred_element_type=f32)
+
+    @pl.when(i == pl.num_programs(2) - 1)
+    def _():
+        last[...] = state[...]
+
+
+def _ssm_scan_bwd_kernel(x, dt, run, b, c, between, dy, dlast,
+                         dx, ddt, drun, db, dc, dstate0, dstate, *, heads, dtype):
+    """:func:`_ssm_scan_fwd_kernel`'s sub-chunk differentiated, the sub-chunks walked
+    from the last to the first: ``between`` is the state before this one, ``dstate``
+    (scratch) the gradient of the state after it (``dlast`` at the first step taken),
+    ``dy`` [chunk, heads x p] float32. The sub-chunk's pairs, decays and weights are
+    rebuilt here, both ways up (``[q, s]`` and ``[s, q]``, so that no ``[chunk, chunk]``
+    is transposed and what they give ``run`` is summed down their rows, a head a row as
+    it is written), and dropped. Writes ``dx``, the gradients of ``dt`` and ``run``
+    [heads, chunk], ``db`` and ``dc`` [chunk, n] summed over the block's heads, and at
+    the last step the gradient of the state the call began from. Operands as the
+    forward's: ``dtype`` with float32 sums, the read-out's three products float32 at
+    the highest precision."""
+    from jax.experimental import pallas as pl
+
+    i, f32, (chunk, width) = pl.program_id(2), jnp.float32, x.shape
+    p, highest = width // heads, jax.lax.Precision.HIGHEST
+    mine = _scan_head_lanes(heads, width)
+
+    def a_head_a_row(v):            # [chunk, width] -> [heads, chunk]: a head's lanes summed
+        return jnp.concatenate(
+            [v[:, h * p:(h + 1) * p].sum(axis=1, keepdims=True) for h in range(heads)], axis=1).T
+
+    @pl.when(i == 0)
+    def _():
+        dstate[...] = dlast[...]
+
+    before, after = between[...], dstate[...]                                # [n, width]
+    bq, cq = b[...].astype(dtype), c[...].astype(dtype)
+    pair = jax.lax.dot_general(cq, bq, _NT, preferred_element_type=f32)      # [q, s]
+    pair_t = jax.lax.dot_general(bq, cq, _NT, preferred_element_type=f32)    # [s, q]
+    read = jnp.dot(cq.astype(f32), before, precision=highest, preferred_element_type=f32)
+    run_col = run[...].T
+    step, ran = _scan_spread(dt[...], mine), _scan_spread(run[...], mine)    # [chunk, width]
+    xf, g = x[...].astype(f32), dy[...]
+    fed, end = step * xf, ran[chunk - 1:]
+    fedb = fed.astype(dtype)
+    to_end, kept = jnp.exp(end - ran), jnp.exp(end)
+    fed_to_end, read_grad = fed * to_end, jnp.exp(ran) * g
+    # the gradient of what each token fed the state, ``fed x to_end``
+    from_state = jnp.dot(bq, after.astype(dtype), preferred_element_type=f32)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    dpair, dpair_t = jnp.zeros((chunk, chunk), f32), jnp.zeros((chunk, chunk), f32)
+    inside, dfed = [], []
+    for lanes, tile in _scan_tiles(heads, width):
+        into = from_state[:, lanes] * to_end[:, lanes]
+        for h in tile:
+            col, row = run_col[:, h:h + 1], run[h:h + 1, :]
+            decay = jnp.exp(jnp.where(rows >= cols, col - row, -jnp.inf))       # [q, s]
+            decay_t = jnp.exp(jnp.where(rows <= cols, row - col, -jnp.inf))     # [s, q]
+            gb = (g[:, lanes] * mine[h:h + 1, lanes]).astype(dtype)
+            dweight = jax.lax.dot_general(gb, fedb[:, lanes], _NT, preferred_element_type=f32)
+            dweight_t = jax.lax.dot_general(fedb[:, lanes], gb, _NT, preferred_element_type=f32)
+            weight_t = pair_t * decay_t
+            into = into + jnp.dot(weight_t.astype(dtype), gb, preferred_element_type=f32)
+            # what the decays give ``run``: + at the later token, - at the earlier one
+            inside.append(
+                (dweight_t * weight_t - dweight * (pair * decay)).sum(axis=0, keepdims=True))
+            dpair, dpair_t = dpair + dweight * decay, dpair_t + dweight_t * decay_t
+        dfed.append(into)
+    dfed = jnp.concatenate(dfed, axis=1)
+    dx[...] = (dfed * step).astype(dx.dtype)
+    ddt[...] = a_head_a_row(dfed * xf)
+    moved = from_state * fed_to_end
+    # ``run`` at the sub-chunk's end decays the old state and weighs what was fed
+    at_end = (mine * (
+        moved.sum(axis=0, keepdims=True) + kept * (after * before).sum(axis=0, keepdims=True)
+    )).sum(axis=1, keepdims=True)
+    is_end = jax.lax.broadcasted_iota(jnp.int32, (heads, chunk), 1) == chunk - 1
+    drun[...] = (
+        jnp.concatenate(inside, axis=0) + a_head_a_row(read_grad * read - moved)
+        + jnp.where(is_end, at_end, 0.0))
+    dc[...] = (
+        jnp.dot(dpair.astype(dtype), bq, preferred_element_type=f32)
+        + jax.lax.dot_general(read_grad, before, _NT, precision=highest, preferred_element_type=f32)
+    ).astype(dc.dtype)
+    db[...] = (
+        jnp.dot(dpair_t.astype(dtype), cq, preferred_element_type=f32)
+        + jax.lax.dot_general(
+            fed_to_end.astype(dtype), after.astype(dtype), _NT, preferred_element_type=f32)
+    ).astype(db.dtype)
+    dstate[...] = kept * after + jax.lax.dot_general(
+        cq.astype(f32), read_grad, _TN, precision=highest, preferred_element_type=f32)
+
+    @pl.when(i == pl.num_programs(2) - 1)
+    def _():
+        dstate0[...] = dstate[...]
+
+
+def _ssm_scan_call(state, x, dt, run, b, c, grads=None, *, chunk, dtype, keep=False,
+                   interpret=False):
+    """The scan's forward kernel (``y``, the last state and, with ``keep``, the states
+    before each sub-chunk) or, given ``grads`` (those states, ``dy`` and the last
+    state's gradient, which then stands where ``state`` does), its backward kernel (the
+    gradients of ``x``, ``dt``, ``run``, ``b``, ``c`` and the first state), over the
+    kernels' own layout: a state ``[lanes, blocks, n, heads x p]`` float32, ``x``
+    [lanes, t, blocks x heads x p], ``dt``, ``run`` [lanes, blocks, heads, t] float32,
+    ``b``, ``c`` [lanes, t, groups x n], a block of heads inside one group. Grid
+    ``(lanes, blocks, sub-chunks)``, the last axis in turn (the backward's from the last
+    sub-chunk to the first)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    lanes, blocks, n, width = state.shape
+    heads, t = dt.shape[2:]
+    nc, per_group, f32 = t // chunk, blocks * n // b.shape[-1], jnp.float32
+
+    def at(i):
+        return i if grads is None else nc - 1 - i
+
+    tokens = pl.BlockSpec((None, chunk, width), lambda l, h, i: (l, at(i), h))
+    steps = pl.BlockSpec((None, None, heads, chunk), lambda l, h, i: (l, h, 0, at(i)))
+    group = pl.BlockSpec((None, chunk, n), lambda l, h, i: (l, at(i), h // per_group))
+    whole = pl.BlockSpec((None, None, n, width), lambda l, h, i: (l, h, 0, 0))
+    each = pl.BlockSpec((None, None, None, n, width), lambda l, h, i: (l, h, at(i), 0, 0))
+    options = dict(
+        grid=(lanes, blocks, nc),
+        scratch_shapes=[pltpu.VMEM((n, width), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret)
+    shaped = jax.ShapeDtypeStruct
+    if grads is None:
+        return pl.pallas_call(
+            functools.partial(_ssm_scan_fwd_kernel, heads=heads, dtype=dtype),
+            name="ssm_scan_fwd",
+            in_specs=[whole, tokens, steps, steps, group, group],
+            out_specs=[tokens, whole] + [each] * keep,
+            out_shape=[shaped(x.shape, f32), shaped(state.shape, f32)]
+            + [shaped((lanes, blocks, nc, n, width), f32)] * keep,
+            **options)(state, x, dt, run, b, c)
+    # a block's share of its group's gradient: float32 where a group is several blocks,
+    # which the caller sums
+    block = pl.BlockSpec((None, chunk, n), lambda l, h, i: (l, at(i), h))
+    shared = shaped((lanes, t, blocks * n), b.dtype if per_group == 1 else f32)
+    return pl.pallas_call(
+        functools.partial(_ssm_scan_bwd_kernel, heads=heads, dtype=dtype),
+        name="ssm_scan_bwd",
+        in_specs=[tokens, steps, steps, group, group, each, tokens, whole],
+        out_specs=[tokens, steps, steps, block, block, whole],
+        out_shape=[shaped(x.shape, x.dtype), shaped(dt.shape, f32), shaped(run.shape, f32),
+                   shared, shared, shaped(state.shape, f32)],
+        **options)(x, dt, run, b, c, *grads, state)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _ssm_scan_blocks(state, x, dt, run, b, c, chunk, dtype, interpret):
+    """``y`` and the last state (:func:`_ssm_scan_call`'s layout), differentiable in
+    all six: the kernel pair. What the backward keeps is the operands and the states
+    before each sub-chunk, ``[lanes, blocks, sub-chunks, n, heads x p]`` float32."""
+    return tuple(_ssm_scan_call(
+        state, x, dt, run, b, c, chunk=chunk, dtype=dtype, interpret=interpret))
+
+
+def _ssm_scan_blocks_fwd(state, x, dt, run, b, c, chunk, dtype, interpret):
+    y, last, between = _ssm_scan_call(
+        state, x, dt, run, b, c, chunk=chunk, dtype=dtype, keep=True, interpret=interpret)
+    return (y, last), (x, dt, run, b, c, between)
+
+
+def _ssm_scan_blocks_bwd(chunk, dtype, interpret, kept, grads):
+    x, dt, run, b, c, between = kept
+    dy, dlast = grads
+    dx, ddt, drun, db, dc, dstate = _ssm_scan_call(
+        dlast, x, dt, run, b, c, (between, dy), chunk=chunk, dtype=dtype, interpret=interpret)
+    if db.shape != b.shape:         # a group in several blocks: [lanes, t, groups x blocks x n]
+        n = dlast.shape[2]
+        db, dc = (
+            v.reshape(v.shape[:2] + (b.shape[-1] // n, -1, n)).sum(3).reshape(b.shape).astype(b.dtype)
+            for v in (db, dc))
+    return dstate, dx, ddt, drun, db, dc
+
+
+_ssm_scan_blocks.defvjp(_ssm_scan_blocks_fwd, _ssm_scan_blocks_bwd)
+
+
+def _scan_blocks_of(state, heads):
+    """A state ``[..., all heads, p, n]`` as the scan's kernels hold it: ``[..., blocks,
+    n, heads x p]``, a block's heads side by side, each transposed."""
+    *lead, all_heads, p, n = state.shape
+    blocked = state.reshape(*lead, all_heads // heads, heads, p, n)
+    return jnp.moveaxis(blocked, -1, -3).reshape(*lead, all_heads // heads, n, heads * p)
+
+
+def _scan_heads_of(blocks, heads):
+    """:func:`_scan_blocks_of` back: ``[..., blocks, n, heads x p]`` -> ``[..., all
+    heads, p, n]``."""
+    *lead, count, n, width = blocks.shape
+    split = jnp.moveaxis(blocks.reshape(*lead, count, n, heads, width // heads), -3, -1)
+    return split.reshape(*lead, count * heads, width // heads, n)
+
+
+def _ssm_scan_operands(state, x, dt, a, b, c, chunk, heads):
+    """What :func:`_ssm_scan_call` takes, from :func:`ssm_scan`'s arguments: the running
+    sum of ``dt A`` inside each sub-chunk, ``dt`` and it a head a row, the state in
+    blocks, the rest flat."""
+    lanes, t, all_heads, _ = x.shape
+    run = jnp.cumsum((dt * a).reshape(lanes, t // chunk, chunk, all_heads), axis=2)
+
+    def a_head_a_row(v):            # [lanes, t, all heads] -> [lanes, blocks, heads, t]
+        return v.reshape(lanes, t, -1, heads).transpose(0, 2, 3, 1)
+
+    return (
+        _scan_blocks_of(state, heads), x.reshape(lanes, t, -1), a_head_a_row(dt),
+        a_head_a_row(run), b.reshape(lanes, t, -1), c.reshape(lanes, t, -1))
+
+
+def ssm_scan(state, x, dt, a, b, c, chunk: int, dtype, *, heads: int = SSM_SCAN_HEADS,
+             interpret: bool = False):
+    """:func:`ssm_chunked` for a train step: the same recurrence in the same sub-chunks
+    at the same precisions (``b``, ``c`` with their group axis, ``[lanes, t, groups,
+    n]``), returning ``(y, last state)``, differentiable in ``state``, ``x``, ``dt``,
+    ``a``, ``b`` and ``c``. On the TPU (``interpret`` reaches it elsewhere) it is a
+    kernel pair under one ``jax.custom_vjp``: grid ``(lanes, blocks of heads,
+    sub-chunks)``, a block the ``heads`` heads (all of a group's where they are no
+    more) that share a B and a C, so that their ``C B^T`` is made once; the state
+    stays in VMEM from one sub-chunk to the next, and a sub-chunk's ``[chunk, chunk]``
+    pairs, decays and weights are made, used and dropped there, forward and backward:
+    none is ever an array in HBM. The backward keeps the operands and the state before
+    each sub-chunk (``heads x p x n`` float32 a sub-chunk and lane), walks the
+    sub-chunks from the last to the first with the state's gradient in VMEM and
+    rebuilds a sub-chunk's quantities from those. The running sum of ``dt A`` inside a
+    sub-chunk and the kernels' layouts of ``dt`` (a head a row) and of the state are
+    made outside them, on arrays of ``lanes x t x heads``, and ``jax.grad``
+    differentiates that as it finds it (``a``'s gradient is summed there). Off the TPU
+    it is :func:`ssm_chunked` under plain autodiff."""
+    if not (backend.on_tpu() or interpret):
+        return ssm_chunked(state, x, dt, a, b, c, chunk, dtype)[:2]
+    heads = min(heads, x.shape[2] // b.shape[2])
+    y, last = _ssm_scan_blocks(
+        *_ssm_scan_operands(state, x, dt, a, b, c, chunk, heads), chunk, dtype, interpret)
+    return y.reshape(x.shape), _scan_heads_of(last, heads)
 
 
 def make_extend_fn(cfg: GraniteMoeHybridConfig):

@@ -3,7 +3,7 @@
 ``minicpm_sala.py``, ``mimo_v2_flash.py``, whose per-sequence state is made of
 cached rows: a window's K and V; ``qwen3_next.py``, whose norms are zero-centred: it
 hands :func:`rms_norm` the scale ``1 + g``; ``nemotron_h.py``, which also takes
-``granitemoehybrid.ssm_chunked``, the one function an architecture has from a sibling:
+``granitemoehybrid.ssm_scan``, the one function an architecture has from a sibling:
 ROADMAP.md D19 moves the chunked recurrences here): every
 decision they share, written once. Plain functions of their arguments: no
 configuration, no ``jit`` and no scope of their own but ``extend.logits`` round

@@ -14,8 +14,9 @@ or a feed-forward part alone, chosen by letter ``l`` of ``pattern``
   ``A = -exp(A_log)``; on the state ``S_h`` ``[ssm_head_dim x ssm_state]``, float32:
   ``S_t = exp(D_t A_h) S_(t-1) + D_t x_t (x) B_t^g``, ``y_t = S_t C_t^g + D_h x_t``,
   from zeros at the sequence's start, computed in sub-chunks of ``ssm_chunk`` tokens
-  (``granitemoehybrid.ssm_chunked``, given the group axis; its backward is
-  ``jax.grad``'s through its ``lax.scan``, a sub-chunk rebuilt at a time). Out: ``W_out [RMSNorm_group(y * silu(z)) *
+  (``granitemoehybrid.ssm_scan``: on the TPU a kernel pair, forward and backward, in
+  which a sub-chunk's pairs, decays and weights never leave VMEM; elsewhere
+  ``ssm_chunked``'s loop under ``jax.grad``). Out: ``W_out [RMSNorm_group(y * silu(z)) *
   w]``, **the norm over each group's** ``ssm_inner / ssm_groups`` **channels**;
 * ``*``, **attention**: ``num_heads`` query heads over ``kv_heads`` K/V heads of
   ``head_dim``, no bias, a causal softmax of ``q . k / sqrt(head_dim)`` and **no
@@ -43,9 +44,9 @@ their shapes are static for the worst case, all ``experts_per_token x tokens`` p
 held here, 0.86 GB a layer at the benchmark's cut, where an eighth of the pairs is)
 and the experts its router chose (``moe.ROUTED``: the kept results' rows lie as that
 choice sorted the pairs, so the replay reads the choice and does not make it again).
-A Mamba layer's replay runs the chunked scan again, and the scan's own backward
-rebuilds a sub-chunk at a time (``ssm_chunked(rebuild=True)``) from the states
-between sub-chunks, 268 MB a layer at [2, 8192].
+A Mamba layer's replay runs the scan's forward kernel again, and the scan's backward
+kernel rebuilds a sub-chunk at a time from the states between sub-chunks, which are
+all it keeps beside its operands: 268 MB a layer at [2, 8192].
 
 Scopes, inside ``train.forward``: ``train.ssm.proj`` (the in and out projections),
 ``train.ssm.conv`` (the taps, their activation and the step's softplus),
@@ -67,7 +68,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import layers, moe
 from ray_tpu.models.gpt import TrainModel
-from ray_tpu.models.granitemoehybrid import ssm_chunked  # granite's serve path chunks with it too
+from ray_tpu.models.granitemoehybrid import ssm_scan    # ssm_chunked, as the TPU's kernel pair
 from ray_tpu.ops.attention import FLASH_RESIDUALS, dot_product_attention
 
 MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
@@ -257,9 +258,9 @@ def mamba_mixer(cfg: NemotronHConfig, p, r):
             for part in jnp.split(mixed[..., inner:], 2, axis=-1))
         step = jax.nn.softplus(dt.astype(f32) + p["dt_bias"])
     with jax.named_scope("train.ssm.scan"):
-        y, _, _ = ssm_chunked(
+        y, _ = ssm_scan(
             jnp.zeros((b, heads, cfg.ssm_head_dim, cfg.ssm_state), f32), x, step,
-            -jnp.exp(p["A_log"]), bm, cm, cfg.ssm_chunk, dtype, rebuild=True)
+            -jnp.exp(p["A_log"]), bm, cm, cfg.ssm_chunk, dtype)
     with jax.named_scope("train.ssm.norm"):
         y = (y + p["D"][:, None] * x.astype(f32)).reshape(b, t, inner) * jax.nn.silu(z.astype(f32))
         # a norm a group: over the channels of the heads that share a B and a C

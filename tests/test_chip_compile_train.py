@@ -184,13 +184,17 @@ def test_nemotron_h_share_train_step_fits_the_chip_with_its_kernels(v5e, built_f
     """``nemotron-3-nano-train-1chip-fixed-batch`` as it runs: the configuration's file
     (four Mamba-2 layers, four expert layers of 16 held experts, one attention layer at
     the published widths, 986,254,848 parameters: 5.9 GB of bfloat16 weights and
-    moments as arguments), 2 x 8192 tokens. It fits the chip with the chunked scan's
-    backward rebuilt a sub-chunk at a time (``ssm_chunked(rebuild=True)``: plain
-    autodiff's residuals of 64 sub-chunks a sequence asked for 20.5 GB) and both grouped
-    matmuls' results kept across the four expert layers; the three flash kernels and the
-    grouped matmuls are in it, what the configuration's ``job.min_kernels`` asks for and
-    no more (a layer's remat replays neither kernel); and the file's
-    ``compiled_bytes_per_device`` is what the compiler says."""
+    moments as arguments), 2 x 8192 tokens. It fits the chip with both grouped
+    matmuls' results kept across the four expert layers and the chunked scan as its
+    kernel pair (``granitemoehybrid.ssm_scan``), whose backward keeps the states
+    between sub-chunks alone (268 MB a layer; plain autodiff's residuals of 64
+    sub-chunks a sequence asked for 20.5 GB). The three flash kernels and the grouped
+    matmuls are in it at the floors the configuration's ``job.min_kernels`` asks for
+    and no more (a layer's remat replays neither kernel), and beside them the scan's
+    two by name: a forward in each of the four layers' forward and replay, one backward
+    each. No array a head and sub-chunk wide (``f32[64,2,64,128,128]`` and kin: the
+    pairs, decays and weights of a sub-chunk) exists outside a kernel. And the file's
+    ``compiled_bytes_per_device`` still bounds what the compiler says."""
     from benchmark.traffic import train_fixed_batch
 
     cfg, file, compiled = _cell_step(v5e, built_for_tpu, "nemotron-3-nano-30b-a3b-train-ep8")
@@ -198,9 +202,16 @@ def test_nemotron_h_share_train_step_fits_the_chip_with_its_kernels(v5e, built_f
     assert cfg.num_params() == 986_254_848
     assert memory.argument_size_in_bytes > 6 * cfg.num_params()        # weights and two moments
     assert _device_bytes(compiled) < HBM_BYTES
-    kernels = train_fixed_batch.kernels_of(compiled.as_text())
-    assert kernels == file["job"]["min_kernels"]
+    text = compiled.as_text()
+    kernels = train_fixed_batch.kernels_of(text)
+    mamba_layers = cfg.pattern.count("M")
+    assert kernels == {
+        **file["job"]["min_kernels"],
+        "ssm_scan_fwd": 2 * mamba_layers, "ssm_scan_bwd": mamba_layers}
     assert not train_fixed_batch.missing_kernels(kernels, file["job"]["min_kernels"])
+    chunk = cfg.ssm_chunk
+    a_sub_chunk_wide = re.findall(rf"(?:f32|bf16)\[(?:\d+,){{2,}}{chunk},{chunk}\]", text)
+    assert not a_sub_chunk_wide, sorted(set(a_sub_chunk_wide))
     stated = file["compiled_bytes_per_device"]
     assert _device_bytes(compiled) <= 1.01 * stated["total"]
     assert stated["total"] == (

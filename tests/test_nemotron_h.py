@@ -1,9 +1,10 @@
 """Nemotron-3-Nano's block on the train path at a small size, in float32 on the CPU,
 against the plain reference (``benchmark/reference/nemotron_h_reference.py``, which
 knows nothing of ``models/nemotron_h.py``, ``models/moe.py`` or the chunked scan): loss
-and every parameter family's gradient, the chunked scan with and without groups
-against the recurrence a token at a time, the eight shares tied to the uncut layer, the
-un-gated expert layer against a loop over experts, one whole step.
+and every parameter family's gradient, the chunked scan with and without groups and the
+TPU's kernel pair for it (``ssm_scan``, interpreted) against the recurrence a token at a
+time, the eight shares tied to the uncut layer, the un-gated expert layer against a loop
+over experts, one whole step.
 
 Tolerances: both sides compute in float32 at the CPU's full matmul precision and
 differ in the order of their sums alone (a chunked scan against a token at a time; a
@@ -23,7 +24,8 @@ import pytest
 from benchmark.reference import nemotron_h_reference as reference
 from ray_tpu.models import moe, nemotron_h
 from ray_tpu.models.gpt import blockwise_next_token_loss
-from ray_tpu.models.granitemoehybrid import ssm_chunked
+from ray_tpu.models import granitemoehybrid
+from ray_tpu.models.granitemoehybrid import ssm_chunked, ssm_scan
 from ray_tpu.models.training import default_optimizer, init_sharded_state, make_train_step
 from ray_tpu.parallel.mesh import MeshSpec
 
@@ -131,9 +133,9 @@ def scan_inputs(groups, lanes=2, t=32, heads=8, p=8, n=16, seed=0):
     return x, dt, a, b, c, jax.random.normal(keys[5], (lanes, t, heads, p))
 
 
-def chunked(x, dt, a, b, c, rebuild, chunk=8):
+def chunked(x, dt, a, b, c, chunk=8):
     state = jnp.zeros((x.shape[0], x.shape[2], x.shape[3], b.shape[-1]), jnp.float32)
-    return ssm_chunked(state, x, dt, a, b, c, chunk, jnp.float32, rebuild=rebuild)[0]
+    return ssm_chunked(state, x, dt, a, b, c, chunk, jnp.float32)[0]
 
 
 def token_by_token(x, dt, a, b, c):
@@ -145,29 +147,151 @@ def token_by_token(x, dt, a, b, c):
     return reference.recurrence(x, dt, a, b, c)
 
 
-@pytest.mark.parametrize("rebuild", [False, True], ids=["autodiff", "rebuilt"])
-@pytest.mark.parametrize("groups", [None, 1, 4, 8], ids=["no-axis", "1-group", "4-groups", "8-groups"])
-def test_the_chunked_scan_is_the_recurrence_a_token_at_a_time(groups, rebuild):
-    """Values and the gradient of every input, with B and C in groups, in one group and
-    as granite hands them over (no group axis)."""
-    *inputs, weigh = scan_inputs(groups)
+def is_the_recurrence(scan, inputs, weigh):
+    """Values and the gradient of every input (``x``, ``dt``, ``A``, ``B``, ``C``)."""
     got, got_grads = jax.value_and_grad(
-        lambda *v: (chunked(*v, rebuild) * weigh).sum(), argnums=range(5))(*inputs)
+        lambda *v: (scan(*v) * weigh).sum(), argnums=range(5))(*inputs)
     want, want_grads = jax.value_and_grad(
         lambda *v: (token_by_token(*v) * weigh).sum(), argnums=range(5))(*inputs)
-    close(chunked(*inputs, rebuild), token_by_token(*inputs), 1e-5)
+    close(scan(*inputs), token_by_token(*inputs), 1e-5)
     assert float(got) == pytest.approx(float(want), rel=1e-5, abs=1e-4)
     for g, w in zip(got_grads, want_grads):
         close(g, w, 2e-5)
 
 
+@pytest.mark.parametrize("groups", [None, 1, 4, 8], ids=["no-axis", "1-group", "4-groups", "8-groups"])
+def test_the_chunked_scan_is_the_recurrence_a_token_at_a_time(groups):
+    """With B and C in groups, in one group and as granite hands them over (no group
+    axis)."""
+    *inputs, weigh = scan_inputs(groups)
+    is_the_recurrence(chunked, inputs, weigh)
+
+
+# the TPU's kernel pair, interpreted: what a train step runs on the chip
+
+
+def kernels(x, dt, a, b, c, state=None, heads=8):
+    """``(y, last)`` of ``ssm_scan``'s kernels, sub-chunks of 8, from zeros unless told."""
+    if state is None:
+        state = jnp.zeros((x.shape[0], x.shape[2], x.shape[3], b.shape[-1]), jnp.float32)
+    return ssm_scan(state, x, dt, a, b, c, 8, jnp.float32, heads=heads, interpret=True)
+
+
+def in_two_calls(x, dt, a, b, c):
+    """The second half from the state the first left: a state that is not zeros comes
+    in, and its gradient carries the second half's back into the first."""
+    first, state = kernels(x[:, :16], dt[:, :16], a, b[:, :16], c[:, :16])
+    second, _ = kernels(x[:, 16:], dt[:, 16:], a, b[:, 16:], c[:, 16:], state)
+    return jnp.concatenate([first, second], axis=1)
+
+
+@pytest.mark.parametrize("calls", [1, 2], ids=["from-zeros", "from-a-state"])
+@pytest.mark.parametrize("groups", [1, 4, 8], ids=["1-group", "4-groups", "8-groups"])
+def test_the_scan_kernels_are_the_recurrence_a_token_at_a_time(groups, calls):
+    *inputs, weigh = scan_inputs(groups)
+    is_the_recurrence(in_two_calls if calls == 2 else lambda *v: kernels(*v)[0], inputs, weigh)
+
+
+@pytest.mark.parametrize("heads", [4, 2], ids=["2-blocks-a-group", "4-blocks-a-group"])
+def test_a_group_in_several_blocks_of_heads_is_the_group_in_one(heads):
+    """A grid step holds at most ``heads`` heads: a group of more is several blocks,
+    each with the group's B and C, whose gradients are summed over the blocks."""
+    *inputs, weigh = scan_inputs(1)
+    state = jax.random.normal(jax.random.PRNGKey(3), (2, 8, 8, 16))
+
+    def loss(heads, state, *v):
+        y, last = kernels(*v, state, heads)
+        return (y * weigh).sum() + (last * state).sum()
+
+    got = jax.grad(loss, argnums=range(1, 7))(heads, state, *inputs)
+    want = jax.grad(loss, argnums=range(1, 7))(8, state, *inputs)
+    for g, w in zip(got, want):
+        close(g, w, 1e-6)
+    for g, w in zip(kernels(*inputs, state, heads), kernels(*inputs, state)):
+        close(g, w, 1e-6)
+
+
+def test_the_scan_kernels_in_bfloat16_are_the_loop_in_bfloat16():
+    """The compute type a train step states: the kernels round where the loop rounds
+    (the operands of the pairs, of the masked product and of the state's feed), so the
+    two stay a rounding of bfloat16 apart, values and gradients."""
+    x, dt, a, b, c, weigh = scan_inputs(4)
+    x, b, c = (v.astype(jnp.bfloat16) for v in (x, b, c))
+    state = jnp.zeros((2, 8, 8, 16), jnp.float32)
+
+    def loss(scan, *v):
+        y, last = scan(state, *v, 8, jnp.bfloat16)[:2]
+        return (y * weigh).sum() + last.sum()
+
+    got = jax.value_and_grad(
+        lambda *v: loss(lambda *w: ssm_scan(*w, interpret=True), *v), argnums=range(5))(x, dt, a, b, c)
+    want = jax.value_and_grad(lambda *v: loss(ssm_chunked, *v), argnums=range(5))(x, dt, a, b, c)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        close(g.astype(jnp.float32), w.astype(jnp.float32), 2e-2)
+
+
+def test_a_padded_token_changes_no_state_in_the_kernels():
+    x, dt, a, b, c, _ = scan_inputs(4)
+    padded = jnp.zeros((32,), bool).at[jnp.array([5, 6, 16, 31])].set(True)
+    y, last = kernels(x, jnp.where(padded[:, None], 0.0, dt), a, b, c)
+    real = ~np.asarray(padded)
+    # the 28 real tokens alone, as 32 with the padding at the end (dt = 0 there too)
+    alone = tuple(
+        jnp.concatenate([v[:, real], jnp.zeros_like(v[:, :4])], axis=1) for v in (x, dt, b, c))
+    y_alone, last_alone = kernels(alone[0], alone[1], a, *alone[2:])
+    close(last, last_alone, 1e-5)
+    close(y[:, real], y_alone[:, :28], 1e-5)
+
+
+@pytest.mark.parametrize("groups", [1, 4], ids=["1-group", "4-groups"])
+def test_the_kernels_keep_the_state_before_each_sub_chunk(groups):
+    """What the backward rebuilds a sub-chunk from: ``between[i]`` is the state before
+    sub-chunk ``i``, the caller's before the first."""
+    x, dt, a, b, c, _ = scan_inputs(groups)
+    state = jax.random.normal(jax.random.PRNGKey(3), (2, 8, 8, 16))
+    y, last, between = granitemoehybrid._ssm_scan_call(
+        *granitemoehybrid._ssm_scan_operands(state, x, dt, a, b, c, 8, 8 // groups),
+        chunk=8, dtype=jnp.float32, keep=True, interpret=True)
+    want_y, want_last, after = ssm_chunked(state, x, dt, a, b, c, 8, jnp.float32)
+    # [lanes, blocks, sub-chunks, n, heads x p] -> [sub-chunks, lanes, heads, p, n]
+    before = granitemoehybrid._scan_heads_of(jnp.moveaxis(between, 2, 0), 8 // groups)
+    np.testing.assert_array_equal(before[0], state)
+    close(before[1:], after[:-1], 1e-6)
+    close(granitemoehybrid._scan_heads_of(last, 8 // groups), want_last, 1e-6)
+    close(y.reshape(x.shape), want_y, 1e-6)
+
+
+def test_the_mixer_runs_the_kernels_on_the_tpu_and_the_loop_elsewhere(nano, built_for_tpu):
+    """``mamba_mixer`` asks the platform, nobody else: built for the TPU its scan is
+    the kernel pair (interpreted here), forward and backward, and agrees with the loop
+    it is built with on the CPU."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    cfg, params, _ = nano
+    p = params["layers"][0]
+    r = jax.random.normal(jax.random.PRNGKey(5), (2, 32, cfg.embed_dim))
+
+    def loss(p, r):
+        return (nemotron_h.mamba_mixer(cfg, p, r) ** 2).sum()
+
+    want = jax.value_and_grad(loss, argnums=(0, 1))(p, r)
+    assert "pallas_call" not in str(jax.make_jaxpr(jax.grad(loss))(p, r))
+    built_for_tpu(True)
+    with pltpu.force_tpu_interpret_mode():
+        text = str(jax.make_jaxpr(jax.grad(loss))(p, r))
+        assert text.count("ssm_scan_fwd") >= 1 and text.count("ssm_scan_bwd") >= 1
+        got = jax.value_and_grad(loss, argnums=(0, 1))(p, r)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        close(g, w, 1e-5)
+
+
 def test_groups_matter_and_one_group_is_no_axis():
     x, dt, a, b, c, _ = scan_inputs(4)
     first = tuple(jnp.broadcast_to(v[:, :, :1], v.shape) for v in (b, c))
-    assert float(jnp.abs(chunked(x, dt, a, b, c, False) - chunked(x, dt, a, *first, False)).max()) > 0.1
+    assert float(jnp.abs(chunked(x, dt, a, b, c) - chunked(x, dt, a, *first)).max()) > 0.1
     # all heads on group 0 is what the form without the axis computes, bit for bit
     np.testing.assert_array_equal(
-        chunked(x, dt, a, b[:, :, :1], c[:, :, :1], False), chunked(x, dt, a, b[:, :, 0], c[:, :, 0], False))
+        chunked(x, dt, a, b[:, :, :1], c[:, :, :1]), chunked(x, dt, a, b[:, :, 0], c[:, :, 0]))
 
 
 @pytest.mark.parametrize("groups", [None, 4], ids=["no-axis", "4-groups"])
