@@ -253,7 +253,8 @@ def expert_ffn(cfg: Lfm2MoeConfig, p, bias, r):
             flat, p["router"], bias, cfg.experts_per_token, cfg.routed_scale)
     with jax.named_scope("train.moe.experts"):
         y, counters = moe.trained_experts_ffn(
-            flat.astype(cfg.dtype), weights, chosen, p["wi"], p["wo"], cfg.expert_offset)
+            flat.astype(cfg.dtype), weights, chosen, p["wi"], p["wo"], cfg.expert_offset,
+            routed=cfg.router_experts)
     return y.astype(cfg.dtype).reshape(b, t, d), counters
 
 

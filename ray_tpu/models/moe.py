@@ -212,28 +212,55 @@ def held_experts_ffn(x, weights, experts, valid, wi, wo, offset: int = 0, layer=
     return y, counters
 
 
-# A trained layer's passes between the sort and the un-sort. ``held`` [k, n] marks
-# the pairs whose expert is held here, pair ``(c, t)`` being token ``t``'s choice
-# ``c``; ``order`` sorts the ``k n`` pairs by expert, those not held last, and
-# ``place`` is its inverse. Both are permutations, so their gradients are gathers,
-# where autodiff, which cannot know that an index hits every row once, scatters
-# and adds row by row. And both keep what the grouped matmul leaves undefined, the
-# rows of the pairs not held, from reaching a token: in the result and in the
-# gradient.
+# A trained layer's passes between the sort and the un-sort. Pair ``c n + t`` is token
+# ``t``'s choice ``c``; ``held`` [k, n] marks the pairs whose expert is held here;
+# ``order`` sorts the ``k n`` pairs by expert, those not held last, and ``place`` is its
+# inverse. The grouped matmul leaves the rows of the pairs not held undefined, in its
+# result and in the gradient of its rows alike, and nothing here lets such a row reach a
+# token: in the result or in a gradient.
 #
-# **The backward's work follows the pairs held.** The shapes are static for the
-# worst case, ``k n`` rows, but the held pairs sort first, and the backward's passes
-# over sorted rows (the un-sort's gradient, the activation's gradient) are loops over
-# blocks of ``row_block`` rows whose trip count is read from ``group_sizes.sum()``
-# on the device. A block's gradient takes the block's place in the buffer of the
-# value it is the gradient of, dead by then; the blocks that hold no pair stay
-# as they were, undefined as the grouped matmul leaves the row tiles of no group.
-# The forward's passes make buffers of their own, and a buffer XLA makes it also
-# clears, a pass over all ``k n`` rows: they stay whole passes.
+# **The work follows the pairs held.** The shapes are static for the worst case, ``k n``
+# rows, but the held pairs sort first, and a pass over sorted rows can be a loop over
+# blocks of ``row_block`` rows whose trip count is read from ``group_sizes.sum()`` on
+# the device: it stops at the last block that holds a pair. What a loop needs is
+# somewhere to write, and there are four answers:
+#
+# * a pass that makes one row a **token** (the combine, the sort's gradient) runs in the
+#   sorted rows' own order: a block's rows are added, weighted, into an ``[n, d]``
+#   float32 result at the block's tokens. No ``k n`` buffer, no mask over the pairs not
+#   held, nothing cleared but ``[n, d]``;
+# * a pass that makes one row a **sorted row** from that row alone (the activation and
+#   its gradient, the casts that keep a result as its bits) is :func:`_held_rows`: on
+#   the TPU a kernel whose grid is the row tiles of the held blocks, whose result is
+#   undefined past them as the grouped matmul's is past the last group;
+# * the gather of the tokens into sorted rows fills a buffer that XLA made
+#   (:func:`_unfilled`), and XLA clears what it makes: one streamed write of ``k n``
+#   rows;
+# * a backward pass whose value is dead by then writes a block's gradient where the
+#   block lay (the un-sort's gradient).
+#
+# **Two forms, chosen by the share of the routed experts that is held here**
+# (:data:`WALKED_SHARE`, read from the shapes and ``routed``: what a configuration
+# states, nothing a user sets). At a small share **every pass** outside the kernels,
+# forward and backward, stops at the last held block (:func:`_sorted_rows`,
+# :func:`_activated`, :func:`_combined`). At a large one the loops would walk most
+# blocks, a cleared buffer costs what it saves, and a replay merged with its forward
+# (``prevent_cse=False`` round an unrolled period: ``models/lfm2_moe.py``) holds what
+# the loops and kernels made from the forward to the backward, where XLA makes a whole
+# pass again instead (LFM2's step: 17.5 GB for 15.8): there the forward's passes stay
+# whole passes (:func:`_all_sorted_rows`, :func:`_all_activated`,
+# :func:`_all_combined`, :func:`_named`) and the backward's alone are loops, each
+# writing a block's gradient where the value it is the gradient of lay.
 
 
 #: blocks a trained layer's sorted rows are walked in: sixteenths of the ``k n`` pairs
 ROW_BLOCKS = 16
+
+
+#: the largest share of the routed experts held here at which every pass of a trained
+#: layer walks the held blocks alone: a quarter (Nemotron-3-Nano's chip of ep8 holds an
+#: eighth, 3 blocks of 16 walked; LFM2's of ep2 a half, 8 to 9 of 16)
+WALKED_SHARE = 0.25
 
 
 def row_block(pairs: int, tile: int) -> int:
@@ -271,20 +298,117 @@ def _set_rows(rows, start, values):
     return jax.lax.dynamic_update_slice_in_dim(rows, values, start, axis=0)
 
 
-@jax.custom_vjp
-def _sorted_rows(x, held, order, place):
-    """The tokens ``x`` [n, d] as the rows of their pairs, sorted: [k n, d]."""
-    return x[order % x.shape[0]]
+def _own_rows(start, block: int, total: int, held_rows):
+    """Which rows of the block at ``start`` are a held pair's and this block's to add:
+    not the rows past the last pair, which hold nothing defined, nor those a last block
+    that starts earlier shares with the block before it."""
+    at = jnp.minimum(start, total - block) + jnp.arange(block)
+    return (at >= start) & (at < held_rows)
 
 
-def _sorted_rows_fwd(x, held, order, place):
-    return x[order % x.shape[0]], (held, place)
+def _unfilled(shape, dtype):
+    """A buffer for a loop over the held blocks to fill: cleared, because XLA makes no
+    other. No pass reads a row of it that no loop filled. (A kernel that writes nothing
+    hands one out as it lies, and the gather into it takes 0.45 ms a layer for 1.32: but
+    Nemotron-3-Nano's step then packs into 15.01 GB where the configuration states
+    14.59, though nothing lives longer; with the parent's whole gather, 0.81 ms, 15.16:
+    ``PERF.md``, PR 64.)"""
+    return jnp.zeros(shape, dtype)
 
 
-def _sorted_rows_bwd(kept, g):
-    held, place = kept
-    g = jnp.where(held[:, :, None], g[place].reshape(held.shape + g.shape[1:]), 0)
-    return g.sum(0).astype(g.dtype), None, None, None
+#: rows of one grid step of :func:`_held_rows`' kernel
+HELD_ROWS_TILE = 256
+
+
+def _held_rows(fn, held_rows, block: int, *rows, interpret: bool = False):
+    """``fn`` of the sorted ``rows`` (each [k n, w]), a function of each row's own values
+    (``[rows, w], ... -> [rows, v]``), for the blocks of ``block`` rows that hold a pair:
+    [k n, v], **undefined past the last held block**. On the TPU one kernel whose grid
+    is the row tiles of those blocks, so it reads and writes nothing else and needs no
+    buffer cleared for it; elsewhere a loop over the blocks."""
+    total = rows[0].shape[0]
+    made = jax.eval_shape(
+        fn, *(jax.ShapeDtypeStruct((block,) + r.shape[1:], r.dtype) for r in rows))
+    if not (backend.on_tpu() or interpret):
+        return _over_held_blocks(
+            block, held_rows,
+            lambda start, filled: (jax.lax.dynamic_update_slice_in_dim(
+                filled, fn(*(_rows_at(r, start, block) for r in rows)), start, axis=0),),
+            _unfilled((total,) + made.shape[1:], made.dtype))[0]
+    from jax.experimental import pallas as pl
+
+    tile = math.gcd(block, HELD_ROWS_TILE)
+    tiles = jnp.minimum(_held_blocks(block, held_rows) * (block // tile), -(-total // tile))
+
+    def moe_held_rows(*refs):
+        refs[-1][...] = fn(*(ref[...] for ref in refs[:-1]))
+
+    return pl.pallas_call(
+        moe_held_rows, grid=(tiles,),
+        in_specs=[pl.BlockSpec((tile,) + r.shape[1:], lambda i: (i, 0)) for r in rows],
+        out_specs=pl.BlockSpec((tile,) + made.shape[1:], lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((total,) + made.shape[1:], made.dtype),
+        interpret=interpret, name="moe_held_rows")(*rows)
+
+
+#: what :func:`trained_experts_ffn` names for a remat round it: the first grouped
+#: matmul's result ``gate_up`` [k n, 2f] (an un-gated expert's: ``up`` [k n, f], under
+#: the same name) and the second's ``out`` [k n, d], both **as their bits**: the
+#: unsigned integers of their width, every value as it was, NaN and -0.0 too. JAX's
+#: remat puts a ``reduce_precision`` to the value's own type on the producer of every
+#: floating-point value it keeps (against XLA's excess precision between the forward
+#: and the replay): on a kernel's bfloat16 result that rounds nothing, and XLA, which
+#: cannot alias through it, copies the value to keep it. Integers get none. A remat
+#: that keeps both (``models/lfm2_moe.py`` and ``models/nemotron_h.py`` ``forward``)
+#: hands them to the backward; one that keeps neither runs both kernels again for them
+TRAINED_RESIDUALS = ("moe_gate_up", "moe_out")
+
+
+def _bits_of(x):
+    """``x`` as the unsigned integers of its width: every value's own bits."""
+    return jax.lax.bitcast_convert_type(x, jnp.dtype(f"uint{8 * x.dtype.itemsize}"))
+
+
+def _kept_bits(x, name: str, held_rows, block: int):
+    """The sorted rows ``x`` for the backward pass, under ``name``, as their bits
+    (:data:`TRAINED_RESIDUALS`), which ``jax.lax.bitcast_convert_type`` reads back.
+    Outside a scan over layers the cast is no free view to XLA but a pass, so it is
+    made over the held blocks alone (:func:`_held_rows`), and what is kept is undefined
+    past them as ``x`` was past the last group. Who reads the bits reads them block by
+    block where it computes (:func:`_activated`, :func:`_combined`): nothing casts all
+    ``k n`` rows back."""
+    return checkpoint_name(_held_rows(_bits_of, held_rows, block, x), name)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _sorted_rows(block, n, x, tokens, held_rows):
+    """The ``n`` tokens ``x`` [n, d] as the rows of their pairs, sorted (``tokens``
+    [k n]: the token of each sorted row): [k n, d], filled as far as the last held
+    block."""
+    def gather(start, rows):
+        return (jax.lax.dynamic_update_slice_in_dim(
+            rows, x[_rows_at(tokens, start, block)], start, axis=0),)
+
+    return _over_held_blocks(
+        block, held_rows, gather, _unfilled(tokens.shape + x.shape[1:], x.dtype))[0]
+
+
+def _sorted_rows_fwd(block, n, x, tokens, held_rows):
+    return _sorted_rows(block, n, x, tokens, held_rows), (tokens, held_rows)
+
+
+def _sorted_rows_bwd(block, n, kept, g):
+    """In the sorted rows' own order: a held pair's row of ``g`` is added to its token's
+    gradient, in float32."""
+    tokens, held_rows = kept
+
+    def add(start, dx):
+        own = _own_rows(start, block, g.shape[0], held_rows)
+        rows = jnp.where(own[:, None], _rows_at(g, start, block), 0).astype(jnp.float32)
+        return (dx.at[_rows_at(tokens, start, block)].add(rows),)
+
+    dx, = _over_held_blocks(block, held_rows, add, jnp.zeros((n,) + g.shape[1:], jnp.float32))
+    return dx.astype(g.dtype), None, None
 
 
 _sorted_rows.defvjp(_sorted_rows_fwd, _sorted_rows_bwd)
@@ -305,47 +429,40 @@ def relu_squared(up):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
 def _activated(activation, block, first, held_rows):
     """``activation`` of the sorted rows ``first`` [k n, ...], the first grouped
-    matmul's result: [k n, f]."""
-    return activation(first)
+    matmul's result: [k n, f], as far as the last held block. ``first`` is kept for the
+    backward pass under :data:`TRAINED_RESIDUALS`' first name."""
+    return _activated_fwd(activation, block, first, held_rows)[0]
 
 
 def _activated_fwd(activation, block, first, held_rows):
-    return activation(first), (first, held_rows)
+    bits = _kept_bits(first, TRAINED_RESIDUALS[0], held_rows, block)
+
+    def activated(bits):        # in float32: what a fusion of XLA's computes in, and a kernel has to
+        first_rows = jax.lax.bitcast_convert_type(bits, first.dtype)
+        return activation(first_rows.astype(jnp.float32)).astype(first.dtype)
+
+    return _held_rows(activated, held_rows, block, bits), (bits, held_rows)
 
 
 def _activated_bwd(activation, block, kept, g):
-    first, held_rows = kept
+    bits, held_rows = kept
 
-    def one(start, first):
-        _, vjp = jax.vjp(activation, _rows_at(first, start, block))
-        return (_set_rows(first, start, vjp(_rows_at(g, start, block))[0]),)
+    def gradient(bits, g):
+        _, vjp = jax.vjp(activation, jax.lax.bitcast_convert_type(bits, g.dtype).astype(jnp.float32))
+        return vjp(g.astype(jnp.float32))[0].astype(g.dtype)
 
-    return _over_held_blocks(block, held_rows, one, first)[0], None
+    return _held_rows(gradient, held_rows, block, bits, g), None
 
 
 _activated.defvjp(_activated_fwd, _activated_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _combined(block, out, weights, held, order, place, held_rows):
-    """The sorted rows ``out`` [k n, d] back pair by pair, zeros for the pairs not
-    held, and their sum over a token's choices under its ``weights`` [n, k]: [n, d]
-    float32."""
-    per_pair = jnp.where(held[:, :, None], out[place].reshape(held.shape + out.shape[1:]), 0)
-    # one pass over the bfloat16 rows: the cast, the weight and the sum over the choices fuse
-    return (weights.T[:, :, None] * per_pair.astype(jnp.float32)).sum(0)
-
-
-def _combined_fwd(block, out, weights, held, order, place, held_rows):
-    return _combined(block, out, weights, held, order, place, held_rows), (
-        out, weights, held, order, place, held_rows)
-
-
-def _combined_bwd(block, kept, g):
-    """In the sorted rows' own order: a row's gradient is its pair's weight times its
-    token's ``g``, a pair's weight's gradient the row's product with that ``g``; a row
-    of no group is read by neither of the grouped matmul's gradients."""
-    out, weights, held, order, place, held_rows = kept
+def _unsorted_gradient(block, out, weights, held, order, place, held_rows, g):
+    """The gradient of the un-sort and the combine, in the sorted rows' own order: a
+    row's gradient is its pair's weight times its token's ``g``, a pair's weight's
+    gradient the row's product with that ``g``; a row of no group is read by neither
+    of the grouped matmul's gradients. A block's gradient is written where the block
+    of ``out`` lay: ``(d out, d weights)``."""
     by_pair = weights.T.reshape(-1)
 
     def unsort(start, out, d_by_row):
@@ -358,44 +475,127 @@ def _combined_bwd(block, kept, g):
     d_out, d_by_row = _over_held_blocks(
         block, held_rows, unsort, out, jnp.zeros(out.shape[:1], jnp.float32))
     d_weights = jnp.where(held, d_by_row[place].reshape(held.shape), 0).T
-    return d_out, d_weights.astype(weights.dtype), None, None, None, None
+    return d_out, d_weights.astype(weights.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _combined(block, out, weights, held, order, place, held_rows):
+    """The sorted rows ``out`` [k n, d] summed token by token under the ``weights``
+    [n, k] of their pairs, in the rows' own order: a held pair's row is added, weighted,
+    to its token's: [n, d] float32, zeros for a token none of whose choices is held.
+    ``out`` is kept for the backward pass under :data:`TRAINED_RESIDUALS`' second name."""
+    return _combined_fwd(block, out, weights, held, order, place, held_rows)[0]
+
+
+def _combined_fwd(block, out, weights, held, order, place, held_rows):
+    n = weights.shape[0]
+    by_pair = weights.T.reshape(-1)
+    bits = _kept_bits(out, TRAINED_RESIDUALS[1], held_rows, block)
+
+    def add(start, y):
+        pairs = _rows_at(order, start, block)
+        own = _own_rows(start, block, out.shape[0], held_rows)
+        rows = jax.lax.bitcast_convert_type(_rows_at(bits, start, block), out.dtype)
+        rows = jnp.where(own[:, None], rows, 0).astype(jnp.float32)
+        return (y.at[pairs % n].add(jnp.where(own, by_pair[pairs], 0)[:, None] * rows),)
+
+    y, = _over_held_blocks(block, held_rows, add, jnp.zeros((n,) + out.shape[1:], jnp.float32))
+    # (a scalar of ``out``'s type: the bits' width alone does not say which float they are)
+    return y, (bits, jnp.zeros((), out.dtype), weights, held, order, place, held_rows)
+
+
+def _combined_bwd(block, kept, g):
+    bits, like, weights, held, order, place, held_rows = kept
+    out = _held_rows(
+        lambda bits: jax.lax.bitcast_convert_type(bits, like.dtype), held_rows, block, bits)
+    return _unsorted_gradient(
+        block, out, weights, held, order, place, held_rows, g) + (None, None, None, None)
 
 
 _combined.defvjp(_combined_fwd, _combined_bwd)
 
 
-#: what :func:`trained_experts_ffn` counts: :data:`COUNTERS`' four and the sorted rows
-#: its backward's loops walk (the blocks that hold a pair, in rows)
-TRAINED_COUNTERS = COUNTERS + ("moe_rows_visited",)
+# -- the form whose forward passes are whole passes (above: a large held share) ------
 
 
-#: what :func:`trained_experts_ffn` names for a remat round it: the first grouped
-#: matmul's result ``gate_up`` [k n, 2f] (an un-gated expert's: ``up`` [k n, f], under
-#: the same name) and the second's ``out`` [k n, d]. A remat
-#: that keeps both (``models/lfm2_moe.py`` ``forward``) hands them to the backward,
-#: which writes its gradients over them; one that keeps neither runs both kernels
-#: again for them
-TRAINED_RESIDUALS = ("moe_gate_up", "moe_out")
+@jax.custom_vjp
+def _all_sorted_rows(x, held, order, place):
+    """The tokens ``x`` [n, d] as the rows of their pairs, sorted: [k n, d]."""
+    return x[order % x.shape[0]]
+
+
+def _all_sorted_rows_fwd(x, held, order, place):
+    return x[order % x.shape[0]], (held, place)
+
+
+def _all_sorted_rows_bwd(kept, g):
+    held, place = kept
+    g = jnp.where(held[:, :, None], g[place].reshape(held.shape + g.shape[1:]), 0)
+    return g.sum(0).astype(g.dtype), None, None, None
+
+
+_all_sorted_rows.defvjp(_all_sorted_rows_fwd, _all_sorted_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _all_activated(activation, block, first, held_rows):
+    """``activation`` of the sorted rows ``first`` [k n, ...], the first grouped
+    matmul's result: [k n, f]."""
+    return activation(first)
+
+
+def _all_activated_fwd(activation, block, first, held_rows):
+    return activation(first), (first, held_rows)
+
+
+def _all_activated_bwd(activation, block, kept, g):
+    first, held_rows = kept
+
+    def one(start, first):
+        _, vjp = jax.vjp(activation, _rows_at(first, start, block))
+        return (_set_rows(first, start, vjp(_rows_at(g, start, block))[0]),)
+
+    return _over_held_blocks(block, held_rows, one, first)[0], None
+
+
+_all_activated.defvjp(_all_activated_fwd, _all_activated_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _all_combined(block, out, weights, held, order, place, held_rows):
+    """The sorted rows ``out`` [k n, d] back pair by pair, zeros for the pairs not
+    held, and their sum over a token's choices under its ``weights`` [n, k]: [n, d]
+    float32."""
+    per_pair = jnp.where(held[:, :, None], out[place].reshape(held.shape + out.shape[1:]), 0)
+    # one pass over the bfloat16 rows: the cast, the weight and the sum over the choices fuse
+    return (weights.T[:, :, None] * per_pair.astype(jnp.float32)).sum(0)
+
+
+def _all_combined_fwd(block, out, weights, held, order, place, held_rows):
+    return _all_combined(block, out, weights, held, order, place, held_rows), (
+        out, weights, held, order, place, held_rows)
+
+
+def _all_combined_bwd(block, kept, g):
+    return _unsorted_gradient(block, *kept, g) + (None, None, None, None)
+
+
+_all_combined.defvjp(_all_combined_fwd, _all_combined_bwd)
 
 
 def _bits_named(x, name: str):
     """:func:`_named`'s value, and what its tangent rule traces: the name has to be an
     equation of the layer's own jaxpr for a policy to see it."""
-    bits =jnp.dtype(f"uint{8 * x.dtype.itemsize}")
-    kept = checkpoint_name(jax.lax.bitcast_convert_type(x, bits), name)
-    return jax.lax.bitcast_convert_type(kept, x.dtype)
+    return jax.lax.bitcast_convert_type(checkpoint_name(_bits_of(x), name), x.dtype)
 
 
 @functools.partial(jax.custom_jvp, nondiff_argnums=(1,))
 def _named(x, name: str):
-    """``x`` under ``name`` for a remat's policy, named **by its bits**: cast to the
-    unsigned integers of its width, named, cast back. The identity on every value,
-    NaN and -0.0 too, and on its tangent (a cast to integers alone would pass none).
-    JAX's remat puts a ``reduce_precision`` to the value's own type on the producer of
-    every floating-point value it keeps (against XLA's excess precision between the
-    forward and the replay): on a kernel's bfloat16 result that rounds nothing, and
-    XLA, which cannot alias through it, copies the value to keep it. Integers get
-    none."""
+    """``x`` under ``name`` for a remat's policy, named by its bits
+    (:data:`TRAINED_RESIDUALS`): cast, named, cast back, all ``k n`` rows. The identity
+    on every value and on its tangent (a cast to integers alone would pass none).
+    Inside a scan over layers XLA aliases through both casts; outside one each is a
+    copy (:func:`_kept_bits`)."""
     return _bits_named(x, name)
 
 
@@ -404,15 +604,24 @@ def _named_jvp(name, primals, tangents):
     return _bits_named(primals[0], name), tangents[0]
 
 
+#: what :func:`trained_experts_ffn` counts: :data:`COUNTERS`' four and the sorted rows
+#: its loops walk (the blocks that hold a pair, in rows): every pass outside the
+#: kernels, forward and backward, at a held share of :data:`WALKED_SHARE` or less, the
+#: backward's passes at a larger one
+TRAINED_COUNTERS = COUNTERS + ("moe_rows_visited",)
+
+
 def trained_experts_ffn(x, weights, experts, wi, wo, offset: int = 0, tiling=GMM_TRAIN_TILING,
-                        activation=gated_silu):
+                        activation=gated_silu, routed=None):
     """:func:`held_experts_ffn` for a train step: the same part of the layer, the
     same counters and one more (:data:`TRAINED_COUNTERS`), every pair whose expert
     is held computed, and a gradient for ``x``, ``weights`` (through which the
     router is trained), ``wi`` and ``wo``; ``experts`` are integers and pass none.
     Every token is real. ``activation`` stands between the two grouped matmuls, a
     function of the first one's rows: :func:`gated_silu` (``wi`` [E, d, 2f]: gate and
-    up side by side) or :func:`relu_squared` (``wi`` [E, d, f]: no gate).
+    up side by side) or :func:`relu_squared` (``wi`` [E, d, f]: no gate). ``routed`` is
+    the number of experts the router scores, of which ``E`` are held here (None: not
+    said, as many as are held).
 
     What differs is what a backward pass and some thousand rows an expert ask
     for. The grouped matmuls run with ``tiling``. The pairs are laid out
@@ -420,11 +629,14 @@ def trained_experts_ffn(x, weights, experts, wi, wo, offset: int = 0, tiling=GMM
     ``[k n, d]`` rows split into ``[k, n, d]`` without a copy (``[n, k, d]`` pads
     its ``k`` to a whole tile of 8). The pairs of absent experts are sorted last
     and lie in no group: the kernel leaves their rows undefined, in its result
-    and in the gradient of its rows alike; between the sort and the un-sort,
-    which mask them, a row meets only its own values. And the backward's passes
-    between them stop at the last block of :func:`row_block` rows that holds a
-    pair (above); with every pair held that is all ``k n`` rows. Nothing is dropped.
-    The two grouped matmuls' results carry :data:`TRAINED_RESIDUALS`' names."""
+    and in the gradient of its rows alike; between the sort and the un-sort a row
+    meets only its own values, and a row of no group reaches no token. The passes
+    outside the kernels stop at the last block of :func:`row_block` rows that holds a
+    pair (above): **all of them, forward and backward, where the experts held are**
+    :data:`WALKED_SHARE` **of the routed ones or fewer**, the backward's where they are
+    more; with every pair held that is all ``k n`` rows, with none the result is zeros
+    and so is every gradient. Nothing is dropped. The two grouped matmuls' results
+    carry :data:`TRAINED_RESIDUALS`' names."""
     n, k = experts.shape
     num_held = wo.shape[-3]
     local = experts.T - offset
@@ -436,13 +648,20 @@ def trained_experts_ffn(x, weights, experts, wi, wo, offset: int = 0, tiling=GMM
     ).sum(0, dtype=jnp.int32)
     place = jnp.zeros_like(order).at[order].set(jnp.arange(k * n, dtype=order.dtype))
     held_rows, block = group_sizes.sum(dtype=jnp.int32), row_block(k * n, tiling[0])
-    rows = _sorted_rows(x, held, order, place)                    # [k n, d]
-    gate_up = _named(
-        grouped_matmul(rows, wi.astype(x.dtype), group_sizes, tiling=tiling), TRAINED_RESIDUALS[0])
-    act = _activated(activation, block, gate_up, held_rows)
-    out = _named(
-        grouped_matmul(act, wo.astype(x.dtype), group_sizes, tiling=tiling), TRAINED_RESIDUALS[1])
-    y = _combined(block, out, weights, held, order, place, held_rows)
+    if num_held <= WALKED_SHARE * (routed or num_held):
+        rows = _sorted_rows(block, n, x, order % n, held_rows)    # [k n, d]
+        gate_up = grouped_matmul(rows, wi.astype(x.dtype), group_sizes, tiling=tiling)
+        act = _activated(activation, block, gate_up, held_rows)
+        out = grouped_matmul(act, wo.astype(x.dtype), group_sizes, tiling=tiling)
+        y = _combined(block, out, weights, held, order, place, held_rows)
+    else:
+        rows = _all_sorted_rows(x, held, order, place)
+        gate_up = _named(
+            grouped_matmul(rows, wi.astype(x.dtype), group_sizes, tiling=tiling), TRAINED_RESIDUALS[0])
+        act = _all_activated(activation, block, gate_up, held_rows)
+        out = _named(
+            grouped_matmul(act, wo.astype(x.dtype), group_sizes, tiling=tiling), TRAINED_RESIDUALS[1])
+        y = _all_combined(block, out, weights, held, order, place, held_rows)
     counters = jnp.stack([
         jnp.int32(n), held_rows, (group_sizes > 0).sum(dtype=jnp.int32), group_sizes.max(),
         jnp.minimum(_held_blocks(block, held_rows) * block, k * n),

@@ -41,7 +41,12 @@ layers). Every layer is rematerialized in the backward pass but for its input, t
 flash kernel's own residuals (``FLASH_RESIDUALS``) and an expert layer's two grouped
 matmuls' results (``moe.TRAINED_RESIDUALS``: the backward runs neither kernel again;
 their shapes are static for the worst case, all ``experts_per_token x tokens`` pairs
-held here, 0.86 GB a layer at the benchmark's cut, where an eighth of the pairs is)
+held here, 0.86 GB a layer at the benchmark's cut, where an eighth of the pairs is:
+kept as their bits, which a kernel over the row tiles of the blocks that hold a pair
+writes and nothing copies, undefined past those blocks; the layer says how many
+experts its router scores, ``routed=``, and at a share this small every pass of
+``moe.trained_experts_ffn`` outside its kernels, forward, replay and backward, walks
+the held blocks alone)
 and the experts its router chose (``moe.ROUTED``: the kept results' rows lie as that
 choice sorted the pairs, so the replay reads the choice and does not make it again).
 A Mamba layer's replay runs the scan's forward kernel again, and the scan's backward
@@ -302,7 +307,7 @@ def expert_layer(cfg: NemotronHConfig, p, bias, r):
     with jax.named_scope("train.moe.experts"):
         y, counters = moe.trained_experts_ffn(
             flat.astype(cfg.dtype), weights, chosen, p["wi"], p["wo"], cfg.expert_offset,
-            activation=moe.relu_squared)
+            activation=moe.relu_squared, routed=cfg.router_experts)
     with jax.named_scope("train.moe.shared"):
         up = flat.astype(cfg.dtype) @ p["shared_wi"].astype(cfg.dtype)
         shared = moe.relu_squared(up) @ p["shared_wo"].astype(cfg.dtype)

@@ -194,8 +194,19 @@ def test_nemotron_h_share_train_step_fits_the_chip_with_its_kernels(v5e, built_f
     two by name: a forward in each of the four layers' forward and replay, one backward
     each. No array a head and sub-chunk wide (``f32[64,2,64,128,128]`` and kin: the
     pairs, decays and weights of a sub-chunk) exists outside a kernel. And the file's
-    ``compiled_bytes_per_device`` still bounds what the compiler says."""
+    ``compiled_bytes_per_device`` still bounds what the compiler says.
+
+    An expert layer here holds 16 of 128 experts, an eighth of the 98,304 worst-case
+    pairs, and **no pass of it outside its kernels walks all 98,304 sorted rows**
+    (``moe.WALKED_SHARE``): six times a layer the kernel over the held blocks' row tiles
+    (``moe_held_rows``: the bits of ``up`` kept, the activation from them and again in
+    the replay, the bits of ``out`` kept; the activation's gradient, ``out`` read back);
+    no instruction under ``train.moe.experts`` copies or bit-casts a ``[98304, 1856]`` or
+    ``[98304, 2688]`` array (the parent's two whole copies a kept result: 17.6 ms a
+    step), and none outside a ``while`` makes one but a kernel: the gather, the combine
+    and both their gradients are loops over blocks of 6,144 rows."""
     from benchmark.traffic import train_fixed_batch
+    from ray_tpu.models import moe
 
     cfg, file, compiled = _cell_step(v5e, built_for_tpu, "nemotron-3-nano-30b-a3b-train-ep8")
     memory = compiled.memory_analysis()
@@ -204,11 +215,29 @@ def test_nemotron_h_share_train_step_fits_the_chip_with_its_kernels(v5e, built_f
     assert _device_bytes(compiled) < HBM_BYTES
     text = compiled.as_text()
     kernels = train_fixed_batch.kernels_of(text)
-    mamba_layers = cfg.pattern.count("M")
+    mamba_layers, expert_layers = cfg.pattern.count("M"), cfg.pattern.count("E")
     assert kernels == {
         **file["job"]["min_kernels"],
-        "ssm_scan_fwd": 2 * mamba_layers, "ssm_scan_bwd": mamba_layers}
+        "ssm_scan_fwd": 2 * mamba_layers, "ssm_scan_bwd": mamba_layers,
+        "moe_held_rows": 6 * expert_layers}
     assert not train_fixed_batch.missing_kernels(kernels, file["job"]["min_kernels"])
+    batch = tuple(file["job"]["batch"])
+    pairs = batch[0] * batch[1] * cfg.experts_per_token
+    assert cfg.num_experts <= moe.WALKED_SHARE * cfg.router_experts
+    assert moe.row_block(pairs, moe.GMM_TRAIN_TILING[0]) * moe.ROW_BLOCKS == pairs == 98304
+    sorted_rows = rf"(?:bf16|u16|f32)\[{pairs},(?:{cfg.expert_dim}|{cfg.embed_dim})\]"
+    experts = [line for line in text.splitlines() if "train.moe.experts" in line]
+    moved = [line for line in experts
+             if re.search(rf"= {sorted_rows}\S* (?:copy|bitcast-convert)\(", line)]
+    assert not moved, moved[:3]
+    entry = text[text.index("ENTRY "):].splitlines()
+    whole = [line for line in entry if "train.moe.experts" in line and re.match(
+        rf"\s*(?:ROOT )?%\S+ = {sorted_rows}\S* (?!custom-call\(|get-tuple-element\()", line)]
+    assert not whole, whole[:3]
+    loops = [line for line in entry if re.search(r" while\(", line) and "train.moe.experts" in line]
+    for carried, least in ((f"bf16[{pairs},{cfg.embed_dim}]", 3), (f"f32[{batch[0] * batch[1]},{cfg.embed_dim}]", 2)):
+        # the gather, its replay and the un-sort's gradient; the combine and the sort's gradient
+        assert sum(carried in line for line in loops) >= least * expert_layers, carried
     chunk = cfg.ssm_chunk
     a_sub_chunk_wide = re.findall(rf"(?:f32|bf16)\[(?:\d+,){{2,}}{chunk},{chunk}\]", text)
     assert not a_sub_chunk_wide, sorted(set(a_sub_chunk_wide))

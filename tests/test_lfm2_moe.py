@@ -233,38 +233,74 @@ def test_each_left_out_mechanism_moves_the_references_loss(nano, wrong):
     assert abs(float(reference.loss(ref_params, tokens, keys, wrong=wrong)) - right) > 4e-6 * right
 
 
-def _poisoned_ragged_dot(rows, w, sizes, **_):
+def poisoned_grouped_matmul(poison):
     """A grouped matmul that treats the rows of no group as the TPU's kernel may:
-    NaN in its result and in the gradient of its rows; its gradient of the
-    weights reads the rows of a group alone, as ``tgmm`` does."""
-    in_a_group = (jnp.arange(rows.shape[0]) < sizes.sum())[:, None]
+    ``poison`` (NaN, or a number whose sum with itself is not finite) in its result
+    and in the gradient of its rows; its gradient of the weights reads the rows of a
+    group alone, as ``tgmm`` does."""
+    def grouped(rows, w, sizes, **_):
+        in_a_group = (jnp.arange(rows.shape[0]) < sizes.sum())[:, None]
 
-    @jax.custom_vjp
-    def dot(rows, w):
-        return jnp.where(in_a_group, jax.lax.ragged_dot(rows, w, sizes), jnp.nan)
+        @jax.custom_vjp
+        def dot(rows, w):
+            return jnp.where(in_a_group, jax.lax.ragged_dot(rows, w, sizes), poison)
 
-    def fwd(rows, w):
-        return dot(rows, w), (rows, w)
+        def fwd(rows, w):
+            return dot(rows, w), (rows, w)
 
-    def bwd(kept, g):
-        rows, w = kept
-        _, vjp = jax.vjp(
-            lambda rows, w: jax.lax.ragged_dot(rows, w, sizes),
-            jnp.where(in_a_group, rows, 0), w)
-        d_rows, d_w = vjp(jnp.where(in_a_group, g, 0))
-        return jnp.where(in_a_group, d_rows, jnp.nan), d_w
+        def bwd(kept, g):
+            rows, w = kept
+            _, vjp = jax.vjp(
+                lambda rows, w: jax.lax.ragged_dot(rows, w, sizes),
+                jnp.where(in_a_group, rows, 0), w)
+            d_rows, d_w = vjp(jnp.where(in_a_group, g, 0))
+            return jnp.where(in_a_group, d_rows, poison), d_w
 
-    dot.defvjp(fwd, bwd)
-    return dot(rows, w)
+        dot.defvjp(fwd, bwd)
+        return dot(rows, w)
+
+    return grouped
 
 
-@pytest.mark.parametrize("kernel", ["interpreted", "poisoned"])
-def test_rows_of_no_group_reach_no_token_through_the_kernels_path(kernel, monkeypatch):
+def poison_the_rows_of_no_group(m, poison):
+    """Under the patch ``m``: every row that lies in no group holds ``poison`` in every
+    intermediate of the trained layer before a pass could read it: both grouped
+    matmuls' results and the gradients of their rows (:func:`poisoned_grouped_matmul`),
+    and every row of a buffer that a loop over the held blocks has not filled
+    (``moe._unfilled``: the gathered tokens, the activation's result, the kept results'
+    bits and what is cast back from them)."""
+    m.setattr(moe, "grouped_matmul", poisoned_grouped_matmul(poison))
+    m.setattr(moe, "_unfilled", lambda shape, dtype: (
+        jnp.full(shape, poison, dtype) if jnp.issubdtype(dtype, jnp.floating)
+        else jnp.full(shape, jnp.iinfo(dtype).max, dtype)))      # bits that are a NaN
+
+
+def interpret_the_kernels(m):
+    """Under the patch ``m``: the TPU's kernels themselves, interpreted: megablox's
+    grouped matmul and the pass over the held blocks' row tiles."""
+    grouped, held_rows = moe.grouped_matmul, moe._held_rows
+    m.setattr(moe, "grouped_matmul", lambda *a, **kw: grouped(*a, interpret=True, **kw))
+    m.setattr(moe, "_held_rows", lambda *a: held_rows(*a, interpret=True))
+
+
+def loop_over_experts(x, weights, experts, wi, wo, offset=0, activation=moe.gated_silu):
+    """The held experts' part of the layer, one expert at a time over every token."""
+    y = jnp.zeros((x.shape[0], wo.shape[-1]), jnp.float32)
+    for e in range(wi.shape[0]):
+        mask = (weights * (experts == offset + e)).sum(-1)
+        y = y + mask[:, None] * (activation(x @ wi[e]) @ wo[e])
+    return y
+
+
+@pytest.mark.parametrize("form", ["whole-forward", "held-blocks"])
+@pytest.mark.parametrize("kernel", ["interpreted", "poisoned", "poisoned-3e38"])
+def test_rows_of_no_group_reach_no_token_through_the_kernels_path(kernel, form, monkeypatch):
     """Half the pairs are an absent expert's and lie in no group, where the TPU's
     grouped matmul leaves its result and the gradient of its rows undefined. The
-    expert layer through the megablox kernel itself (interpreted, a train tile)
-    and through a grouped matmul that writes NaN there gives the ragged dot's
-    result and its gradients of the tokens, the weights and both expert stacks."""
+    expert layer through the kernels themselves (interpreted, a train tile) and with
+    every row of no group poisoned in every intermediate (NaN; 3e38, whose sums are
+    not finite) gives the ragged dot's result and its gradients of the tokens, the
+    weights and both expert stacks."""
     n, k, d, f, held = 40, 2, 32, 64, 3
     x = jax.random.normal(jax.random.PRNGKey(0), (n, d))
     weights = jax.random.uniform(jax.random.PRNGKey(1), (n, k), minval=0.2)
@@ -277,14 +313,11 @@ def test_rows_of_no_group_reach_no_token_through_the_kernels_path(kernel, monkey
     def through():
         return jax.value_and_grad(
             lambda x, weights, wi, wo: (moe.trained_experts_ffn(
-                x, weights, experts, wi, wo, tiling=(16, 32, 128))[0] * up).sum(),
+                x, weights, experts, wi, wo, tiling=(16, 32, 128), routed=FORMS[form])[0] * up).sum(),
             (0, 1, 2, 3))(x, weights, wi, wo)
 
     want = through()
-    plain = moe.grouped_matmul
-    monkeypatch.setattr(moe, "grouped_matmul", {
-        "interpreted": lambda *a, **kw: plain(*a, interpret=True, **kw),
-        "poisoned": _poisoned_ragged_dot}[kernel])
+    KERNELS[kernel](monkeypatch)
     got = through()
     assert 0 < int(((experts >= held).sum())) < n * k
     close(got[0], want[0], 1e-5)
@@ -298,10 +331,15 @@ def test_rows_of_no_group_reach_no_token_through_the_kernels_path(kernel, monkey
 
 PAIRS_N, PAIRS_K, HELD_EXPERTS, TILE = 32, 2, 3, (16, 32, 128)
 BLOCK, BLOCK_ENDS = 16, (16, 32, 48, 64)
+# ``routed`` for each of the layer's two forms: not said (every routed expert may be held
+# here: the forward's passes are whole passes, LFM2's cell), and a quarter of them held
+# (every pass walks the held blocks alone, Nemotron-3-Nano's cell)
+FORMS = {"whole-forward": None, "held-blocks": 4 * HELD_EXPERTS}
 KERNELS = {
-    "ragged": None,
-    "interpreted": lambda plain: lambda *a, **kw: plain(*a, interpret=True, **kw),
-    "poisoned": lambda plain: _poisoned_ragged_dot,
+    "ragged": lambda m: None,
+    "interpreted": interpret_the_kernels,
+    "poisoned": lambda m: poison_the_rows_of_no_group(m, jnp.nan),
+    "poisoned-3e38": lambda m: poison_the_rows_of_no_group(m, 3e38),
 }
 
 
@@ -315,23 +353,38 @@ def routing_that_holds(pairs):
     return jnp.asarray(experts.reshape(PAIRS_N, PAIRS_K), jnp.int32)
 
 
-def blocked_layer(experts, kernel, monkeypatch, blocks=None):
-    """Value, counters and the four gradients of one trained layer through
-    ``kernel``'s grouped matmul, its rows in ``blocks`` blocks (None: the program's own)."""
+def blocked_layers_operands():
     d, f = 32, 64
     x = jax.random.normal(jax.random.PRNGKey(0), (PAIRS_N, d))
     weights = jax.random.uniform(jax.random.PRNGKey(1), (PAIRS_N, PAIRS_K), minval=0.2)
     wi = jax.random.normal(jax.random.PRNGKey(3), (HELD_EXPERTS, d, 2 * f)) * 0.1
     wo = jax.random.normal(jax.random.PRNGKey(4), (HELD_EXPERTS, f, d)) * 0.1
     up = jax.random.normal(jax.random.PRNGKey(5), (PAIRS_N, d))
+    return (x, weights, wi, wo), up
+
+
+def dense_layer(experts):
+    """Value and the four gradients of :func:`blocked_layer`'s layer, one expert at a
+    time over every token: no sort, no block, no row of no group."""
+    operands, up = blocked_layers_operands()
+    (_, y), grads = jax.value_and_grad(
+        lambda x, weights, wi, wo: (lambda y: ((y * up).sum(), y))(
+            loop_over_experts(x, weights, experts, wi, wo)), (0, 1, 2, 3), has_aux=True)(*operands)
+    return y, grads
+
+
+def blocked_layer(experts, kernel, monkeypatch, blocks=None, form="whole-forward"):
+    """Value, counters and the four gradients of one trained layer of ``form`` through
+    ``kernel``'s grouped matmul, its rows in ``blocks`` blocks (None: the program's own)."""
+    (x, weights, wi, wo), up = blocked_layers_operands()
 
     def scalar(x, weights, wi, wo):
-        y, counters = moe.trained_experts_ffn(x, weights, experts, wi, wo, tiling=TILE)
+        y, counters = moe.trained_experts_ffn(
+            x, weights, experts, wi, wo, tiling=TILE, routed=FORMS[form])
         return (y * up).sum(), (y, counters)
 
     with monkeypatch.context() as m:
-        if KERNELS[kernel]:
-            m.setattr(moe, "grouped_matmul", KERNELS[kernel](moe.grouped_matmul))
+        KERNELS[kernel](m)
         if blocks:
             m.setattr(moe, "ROW_BLOCKS", blocks)
         (_, (y, counters)), grads = jax.value_and_grad(scalar, (0, 1, 2, 3), has_aux=True)(
@@ -346,19 +399,28 @@ def test_a_block_is_whole_row_tiles_and_never_more_than_every_pair():
     assert moe.row_block(24, 512) == 24            # fewer rows than a tile: one block
 
 
+@pytest.mark.parametrize("form", list(FORMS))
 @pytest.mark.parametrize("kernel", list(KERNELS))
 @pytest.mark.parametrize("pairs", sorted(
-    {b + by for b in BLOCK_ENDS for by in (-1, 0, 1)} - {PAIRS_N * PAIRS_K + 1}))
-def test_a_blocks_edge_gives_what_every_row_gives(pairs, kernel, monkeypatch):
-    """One pair less than whole blocks, whole blocks, and one pair more: the
-    result, the four counters and the gradients of the tokens, the weights and
-    both expert stacks are those of the layer that walks all ``k n`` rows, the
-    parent's."""
+    {0, PAIRS_N * PAIRS_K // 8} | {b + by for b in BLOCK_ENDS for by in (-1, 0, 1)}
+    - {PAIRS_N * PAIRS_K + 1}))
+def test_a_blocks_edge_gives_what_every_row_gives(pairs, kernel, form, monkeypatch):
+    """No pair held, an eighth of them, then one pair less than whole blocks, whole
+    blocks (a half and all among them), and one pair more: the result, the four
+    counters and the gradients of the tokens, the weights and both expert stacks are
+    those of the layer that walks all ``k n`` rows in one block, and those of one
+    expert at a time over every token. ``poisoned``: with every row of no group
+    poisoned in every intermediate, so a pass that read one would not be finite."""
     experts = routing_that_holds(pairs)
-    y, counters, grads = blocked_layer(experts, kernel, monkeypatch)
-    want_y, want_counters, want_grads = blocked_layer(experts, kernel, monkeypatch, blocks=1)
-    assert want_counters["moe_rows_visited"] == PAIRS_N * PAIRS_K
-    assert counters["moe_rows_visited"] == next(b for b in BLOCK_ENDS if b >= pairs)
+    y, counters, grads = blocked_layer(experts, kernel, monkeypatch, form=form)
+    want_y, want_counters, want_grads = blocked_layer(
+        experts, kernel, monkeypatch, blocks=1, form=form)
+    dense_y, dense_grads = dense_layer(experts)
+    close(y, dense_y, 1e-5)
+    for g, w in zip(grads, dense_grads):
+        close(g, w, 1e-5)
+    assert want_counters["moe_rows_visited"] == (PAIRS_N * PAIRS_K if pairs else 0)
+    assert counters["moe_rows_visited"] == next(b for b in (0,) + BLOCK_ENDS if b >= pairs)
     assert counters["moe_assignments"] == pairs
     assert {k: counters[k] for k in moe.COUNTERS} == {k: want_counters[k] for k in moe.COUNTERS}
     # a row meets its own values alone, whatever the rows beside it; the CPU's
@@ -369,12 +431,13 @@ def test_a_blocks_edge_gives_what_every_row_gives(pairs, kernel, monkeypatch):
         close(g, w, 1e-5)
 
 
+@pytest.mark.parametrize("form", list(FORMS))
 @pytest.mark.parametrize("kernel", list(KERNELS))
-def test_every_pair_held_walks_every_row_and_drops_nothing(kernel, monkeypatch):
+def test_every_pair_held_walks_every_row_and_drops_nothing(kernel, form, monkeypatch):
     """The worst case the shapes are made for: all chosen experts live here."""
     experts = routing_that_holds(PAIRS_N * PAIRS_K)
     assert int((experts < HELD_EXPERTS).sum()) == PAIRS_N * PAIRS_K
-    y, counters, grads = blocked_layer(experts, kernel, monkeypatch)
+    y, counters, grads = blocked_layer(experts, kernel, monkeypatch, form=form)
     assert counters["moe_assignments"] == counters["moe_rows_visited"] == PAIRS_N * PAIRS_K
     want_y, _, want_grads = blocked_layer(experts, "ragged", monkeypatch, blocks=1)
     close(y, want_y, 1e-5)
@@ -383,9 +446,11 @@ def test_every_pair_held_walks_every_row_and_drops_nothing(kernel, monkeypatch):
     assert all(np.abs(np.asarray(g)).max() > 0 for g in grads)
 
 
+@pytest.mark.parametrize("form", list(FORMS))
 @pytest.mark.parametrize("pairs", [0, 1, 15, 16, 17, 31, 33, 40, 48, 49, 63, 64])
-def test_rows_visited_are_the_blocks_that_hold_a_pair(pairs, monkeypatch):
-    y, counters, grads = blocked_layer(routing_that_holds(pairs), "ragged", monkeypatch)
+def test_rows_visited_are_the_blocks_that_hold_a_pair(pairs, form, monkeypatch):
+    y, counters, grads = blocked_layer(
+        routing_that_holds(pairs), "ragged", monkeypatch, form=form)
     visited = counters["moe_rows_visited"]
     assert visited % BLOCK == 0 and visited >= counters["moe_assignments"] == pairs
     assert visited - pairs < BLOCK                      # under a block's rows in vain
@@ -393,8 +458,9 @@ def test_rows_visited_are_the_blocks_that_hold_a_pair(pairs, monkeypatch):
         assert not np.asarray(y).any() and not any(np.asarray(g).any() for g in grads)
 
 
+@pytest.mark.parametrize("form", list(FORMS))
 @pytest.mark.parametrize("pairs", [70, 80])
-def test_a_last_block_that_starts_early_leaves_the_rows_before_it_alone(pairs, monkeypatch):
+def test_a_last_block_that_starts_early_leaves_the_rows_before_it_alone(pairs, form, monkeypatch):
     """80 pairs in blocks of 32 (``ROW_BLOCKS`` 3): the third block is rows 48..79."""
     n, k, d, f = 40, 2, 32, 64
     x = jax.random.normal(jax.random.PRNGKey(0), (n, d))
@@ -407,7 +473,8 @@ def test_a_last_block_that_starts_early_leaves_the_rows_before_it_alone(pairs, m
         monkeypatch.setattr(moe, "ROW_BLOCKS", blocks)
         assert moe.row_block(n * k, TILE[0]) == {3: 32, 1: 80}[blocks]
         return jax.value_and_grad(
-            lambda *a: (moe.trained_experts_ffn(a[0], a[1], experts, a[2], a[3], tiling=TILE)[0]
+            lambda *a: (moe.trained_experts_ffn(
+                a[0], a[1], experts, a[2], a[3], tiling=TILE, routed=FORMS[form])[0]
                         ** 2).sum(), (0, 1, 2, 3))(x, weights, wi, wo)
 
     got, want = through(3), through(1)
@@ -444,7 +511,7 @@ def replayed(monkeypatch):
     before the names). ``jax.checkpoint`` keeps the layer's trace: dropped, both ways."""
     def answer():
         jax.clear_caches()
-        monkeypatch.setattr(moe, "_named", lambda x, name: x)
+        monkeypatch.setattr(moe, "checkpoint_name", lambda x, name: x)
 
     yield answer
     jax.clear_caches()
@@ -521,6 +588,35 @@ def test_a_value_named_by_its_bits_is_the_value_and_so_is_its_gradient(dtype):
     (handed,) = vjp(up)
     assert handed.dtype == up.dtype and np.asarray(handed).tobytes() == np.asarray(up).tobytes()
     assert "a_name" in str(jax.make_jaxpr(jax.grad(lambda x: moe._named(x, "a_name").sum()))(x))
+
+
+@pytest.mark.parametrize("kernel", ["loop", "interpreted"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bfloat16", "float32"])
+def test_a_value_kept_as_its_bits_is_the_value_and_its_gradient_passes(dtype, kernel, monkeypatch):
+    """Over the blocks that hold a pair (two of three here: the cast walks no other),
+    bit for bit; the name is in the gradient's jaxpr, and the gradient passes the
+    integers (the activation's rule reads the kept bits back: the identity's is ``g``)."""
+    if kernel == "interpreted":
+        interpret_the_kernels(monkeypatch)
+    # (the CPU moves a bfloat16 as a float32 and flushes what is denormal there)
+    tiny = 1e-39 if dtype == jnp.float32 else 1e-30
+    row = [1.5, -0.0, 0.0, jnp.nan, -jnp.nan, jnp.inf, -jnp.inf, tiny, 3.0]
+    x = jnp.asarray([row] * 48, dtype).reshape(48, 9)
+    held_rows, block, held = jnp.int32(17), 16, 32
+    kept = jax.jit(lambda x: moe._kept_bits(x, "a_name", held_rows, block))(x)
+    assert kept.dtype == {2: jnp.uint16, 4: jnp.uint32}[x.dtype.itemsize]
+    back = jax.lax.bitcast_convert_type(kept, x.dtype)
+    assert np.asarray(back[:held]).tobytes() == np.asarray(x[:held]).tobytes()
+
+    def through(x):
+        return moe._activated(lambda rows: rows, block, x, held_rows)
+
+    assert np.asarray(through(x)[:held]).tobytes() == np.asarray(x[:held]).tobytes()
+    up = jnp.asarray([[2.0, -0.0, jnp.nan, 1.0, 1.0, jnp.inf, 1.0, 1e-30, -3.0]] * 48, dtype)
+    (handed,) = jax.vjp(through, x)[1](up)
+    assert handed.dtype == up.dtype
+    assert np.asarray(handed[:held]).tobytes() == np.asarray(up[:held]).tobytes()
+    assert moe.TRAINED_RESIDUALS[0] in str(jax.make_jaxpr(jax.grad(lambda x: through(x).sum()))(x))
 
 
 def test_the_published_layout_scans_nine_periods_and_runs_two_layers_behind_them():

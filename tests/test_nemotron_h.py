@@ -340,7 +340,8 @@ def test_the_trained_expert_layer_is_a_loop_over_the_held_experts(activation, co
     assert 0 < int(held.sum()) < n * k          # some pairs are held here, some elsewhere
 
     def program(x, weights, wi, wo):
-        return moe.trained_experts_ffn(x, weights, chosen, wi, wo, offset, activation=activation)[0]
+        return moe.trained_experts_ffn(
+            x, weights, chosen, wi, wo, offset, activation=activation, routed=16)[0]
 
     def plain(x, weights, wi, wo):
         return loop_over_experts(x, weights, chosen, wi, wo, offset, activation)
@@ -351,8 +352,73 @@ def test_the_trained_expert_layer_is_a_loop_over_the_held_experts(activation, co
     want = jax.grad(lambda *v: (plain(*v) * weigh).sum(), argnums=range(4))(x, weights, wi, wo)
     for g, w in zip(got, want):
         close(g, w, 1e-5)
-    counters = moe.trained_experts_ffn(x, weights, chosen, wi, wo, offset, activation=activation)[1]
+    counters = moe.trained_experts_ffn(
+        x, weights, chosen, wi, wo, offset, activation=activation, routed=16)[1]
     assert int(counters[1]) == int(held.sum())
+
+
+SHARES = {"none": 0, "an-eighth": 1, "a-half": 4, "all": 8}        # held, of 8 experts routed
+
+
+@pytest.mark.parametrize("form", ["whole-forward", "held-blocks"])
+@pytest.mark.parametrize("kernel", ["ragged", "interpreted", "poisoned", "poisoned-3e38"])
+@pytest.mark.parametrize("share", list(SHARES))
+@pytest.mark.parametrize(
+    "activation,columns", [(moe.relu_squared, 1), (moe.gated_silu, 2)], ids=["relu2", "gated"])
+def test_at_every_held_share_the_layer_is_one_expert_at_a_time(
+        activation, columns, share, kernel, form, monkeypatch):
+    """Every token chooses 2 of 8 experts evenly, and the experts from 0 on are held:
+    none of them (no block walked: zeros, and every gradient zero and finite), an eighth
+    of the pairs, a half, all. The result and the gradients of the tokens, the weights
+    and both expert stacks are those of one expert at a time over every token, through
+    the ragged dot, through the TPU's kernels interpreted, and with every row of no
+    group poisoned in every intermediate before a pass could read it (NaN; 3e38, whose
+    sums are not finite), which is how a pass that walked a row of no group would show.
+    In both forms of the layer, whatever share of the pairs the routing then holds:
+    ``routed`` says a quarter of the scored experts is held here (every pass walks the
+    held blocks alone, this model's cell), or says nothing (the forward's are whole)."""
+    from test_lfm2_moe import KERNELS
+
+    n, d, f, k, routed = 64, 16, 24, 2, 8
+    held = SHARES[share]
+    keys = jax.random.split(jax.random.PRNGKey(held), 5)
+    x = jax.random.normal(keys[0], (n, d))
+    wi = 0.3 * jax.random.normal(keys[1], (max(held, 1), d, columns * f))
+    wo = 0.3 * jax.random.normal(keys[2], (max(held, 1), f, d))
+    weights = jax.random.uniform(keys[3], (n, k), minval=0.2)
+    at = jax.random.permutation(keys[4], n)
+    first = at % routed
+    chosen = jnp.stack([first, (first + 1 + at // routed % (routed - 1)) % routed], 1)
+    offset = 0 if held else routed                   # no expert held: the one here is nobody's
+    pairs = int(((chosen >= offset) & (chosen < offset + wi.shape[0])).sum())
+    assert pairs == n * k * held // routed
+
+    def program(x, weights, wi, wo):
+        return moe.trained_experts_ffn(
+            x, weights, chosen, wi, wo, offset, tiling=(16, 32, 128), activation=activation,
+            routed=4 * wi.shape[0] if form == "held-blocks" else None)
+
+    def plain(x, weights, wi, wo):
+        return loop_over_experts(x, weights, chosen, wi, wo, offset, activation)
+
+    weigh = jax.random.normal(jax.random.PRNGKey(9), (n, d))
+    want = jax.value_and_grad(
+        lambda *v: (lambda y: ((y * weigh).sum(), y))(plain(*v)), range(4), has_aux=True)(
+            x, weights, wi, wo)
+    KERNELS[kernel](monkeypatch)
+    (_, (y, counters)), grads = jax.value_and_grad(
+        lambda *v: (lambda y, c: ((y * weigh).sum(), (y, c)))(*program(*v)), range(4),
+        has_aux=True)(x, weights, wi, wo)
+    close(y, want[0][1], 1e-5)
+    for g, w in zip(grads, want[1]):
+        assert np.isfinite(np.asarray(g)).all()
+        close(g, w, 1e-5)
+    counted = dict(zip(moe.TRAINED_COUNTERS, map(int, counters)))
+    block = moe.row_block(n * k, 16)
+    assert counted["moe_assignments"] == pairs
+    assert counted["moe_rows_visited"] == -(-pairs // block) * block
+    if not pairs:
+        assert not np.asarray(y).any() and not any(np.asarray(g).any() for g in grads)
 
 
 def test_the_eight_shares_add_up_to_the_uncut_layer_and_so_do_their_gradients():
@@ -529,3 +595,20 @@ def test_the_remat_keeps_the_routers_choice_beside_the_grouped_matmuls_results(n
     assert named.count(chosen) == cfg.pattern.count("E")
     for name in moe.TRAINED_RESIDUALS:
         assert sum(n == name and a.startswith(f"uint32[{pairs},") for a, n in named) == 4
+
+
+def test_the_backward_runs_no_grouped_matmul_again_and_keeps_no_float_of_a_sorted_row(nano):
+    """Four expert layers whose every pass walks the held blocks alone: a forward pair
+    of grouped matmuls, the rows' two gradients and the weights' two each, 6, none made
+    again by the replay, which reads both results back from the bits it kept; no kept
+    value goes through ``reduce_precision`` (they are integers to the remat)."""
+    from test_lfm2_moe import _eqns
+
+    cfg, params, tokens = nano
+    bias = params["expert_bias"]
+    trained = {k: v for k, v in params.items() if k != "expert_bias"}
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda t: program_loss(cfg, {**t, "expert_bias": bias}, tokens)[0]))(trained).jaxpr
+    assert cfg.num_experts <= moe.WALKED_SHARE * cfg.router_experts
+    assert _eqns(jaxpr, "ragged_dot_general") == cfg.pattern.count("E") * 6
+    assert _eqns(jaxpr, "reduce_precision") == 0
