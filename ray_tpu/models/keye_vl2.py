@@ -50,14 +50,6 @@ import numpy as np
 from ray_tpu.models import layers, moe
 from ray_tpu.ops import attention, backend
 
-#: what the indexer counts over the real queries of a device call, summed over
-#: the layers: queries that passed an indexer, live causal query-key pairs it
-#: scored, keys attended (``min(topk, visible)`` a query), and cache slots of
-#: the call's padded caches that at least one query selected
-SPARSE_COUNTERS = (
-    "sparse_queries", "sparse_keys_scored", "sparse_keys_attended", "sparse_slots_read")
-
-
 @dataclasses.dataclass(frozen=True)
 class KeyeVL2Config:
     vocab_size: int = 151936
@@ -94,7 +86,7 @@ class KeyeVL2Config:
     # -- what the serving engine asks of a configuration (``serve/llm.py``) --
 
     #: what ``extend`` counts, in the order of its last output
-    counters = moe.COUNTERS + SPARSE_COUNTERS
+    counters = moe.COUNTERS + layers.SPARSE_COUNTERS
 
     @property
     def cache_arrays(self):
@@ -167,57 +159,6 @@ def init_params(cfg: KeyeVL2Config, seed: int = 0):
     return jax.block_until_ready(init(jax.random.PRNGKey(seed)))
 
 
-# -- the selection ------------------------------------------------------------
-
-
-def _one_zero(scores, visible):
-    """``scores`` with -0 as +0 (equal, so a tie) and -inf where not visible."""
-    return jnp.where(visible, jnp.where(scores == 0, 0.0, scores), -jnp.inf)
-
-
-def _ordered_bits(scores):
-    """Float32 ``scores`` as uint32 that order as the scores do."""
-    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
-    ordered = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
-    return jax.lax.bitcast_convert_type(ordered, jnp.uint32) ^ jnp.uint32(0x80000000)
-
-
-def select_mask(scores, visible, k: int):
-    """For each query (a row of ``scores`` [..., cache], float32) the ``k``
-    visible keys with the largest score, ties to the lower position, as a mask
-    [..., cache]; all the visible ones where they are at most ``k``. No sort:
-    the ``k``-th largest score is found bit by bit (the largest value that at
-    least ``k`` scores reach), then the ties at it are counted off."""
-    u = _ordered_bits(_one_zero(scores, visible))
-
-    def next_bit(i, kth):
-        candidate = kth | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
-        reached = (u >= candidate[..., None]).sum(-1, dtype=jnp.int32)
-        return jnp.where(reached >= k, candidate, kth)
-
-    kth = jax.lax.fori_loop(
-        0, 32, next_bit, jnp.zeros(scores.shape[:-1], jnp.uint32))[..., None]
-    above, ties = u > kth, u == kth
-    room = k - above.sum(-1, dtype=jnp.int32, keepdims=True)
-    # only a row with more ties than room needs them counted off, and a
-    # running count over the cache costs as much as the search: skip it where
-    # no row of the call does
-    tied = jax.lax.cond(
-        (ties.sum(-1, dtype=jnp.int32, keepdims=True) > room).any(),
-        lambda: ties & (jnp.cumsum(ties, axis=-1, dtype=jnp.int32) <= room),
-        lambda: ties)
-    return (above | tied) & visible
-
-
-def select_rows(scores, visible, k: int):
-    """The same keys as positions: ``(positions [..., k'], chosen [..., k'])``
-    with ``k' = min(k, cache)``; where fewer than ``k'`` keys are visible the
-    rest are not ``chosen`` (``jax.lax.top_k`` puts the lower position first
-    among equals)."""
-    top, positions = jax.lax.top_k(_one_zero(scores, visible), min(k, scores.shape[-1]))
-    return positions, top > -jnp.inf
-
-
 # -- extend ---------------------------------------------------------------------
 
 
@@ -227,7 +168,7 @@ def make_extend_fn(cfg: KeyeVL2Config):
     lanes, cache, heads, dim]`` each, ``cfg.cache_arrays``): ``(logits, hidden,
     k_new, v_new, i_new, counters)``. ``counters`` (int32, ``cfg.counters``,
     summed over the layers) are ``moe.held_experts_ffn``'s four and the
-    indexer's (``SPARSE_COUNTERS``), over real tokens only. A negative token id
+    indexer's (``layers.SPARSE_COUNTERS``), over real tokens only. A negative token id
     marks padding: it computes no expert, selects no key and is not counted.
 
     Scopes: ``extend.embed``, ``extend.attention`` (projections, norms, rotary,
@@ -302,7 +243,7 @@ def _make_extend(cfg: KeyeVL2Config, probe: bool):
         if tc == 1:
             # a decode lane: the chosen rows of K and V, and no other
             with jax.named_scope("extend.attention.select"):
-                rows, chosen = select_rows(scores, visible, cfg.topk)   # [b, 1, k']
+                rows, chosen = layers.select_rows(scores, visible, cfg.topk)   # [b, 1, k']
                 at = lane[:, :, None]
                 k_rows, v_rows = kc[at, rows], vc[at, rows]             # [b, 1, k', kv, hd]
                 slots_read = chosen.sum(dtype=jnp.int32)
@@ -318,7 +259,7 @@ def _make_extend(cfg: KeyeVL2Config, probe: bool):
         else:
             # a prefill chunk: every row of the cache, under each query's mask
             with jax.named_scope("extend.attention.select"):
-                selected = select_mask(scores, visible, cfg.topk)       # [b, tc, cache]
+                selected = layers.select_mask(scores, visible, cfg.topk)       # [b, tc, cache]
                 slots_read = selected.any(1).sum(dtype=jnp.int32)
 
             if backend.on_tpu():

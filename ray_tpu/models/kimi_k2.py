@@ -66,14 +66,6 @@ import numpy as np
 from ray_tpu.models import layers, moe
 from ray_tpu.ops import attention, backend
 
-#: what the attention counts over the real queries of a device call, summed
-#: over the layers: queries, live causal query-key pairs attended in the
-#: absorbed and in the expanded form (a call's pairs all under the form it
-#: attends in), and latent rows put through ``W_kvb``: the live slots of every
-#: lane of a chunk on the chip, 0 for any other call
-MLA_COUNTERS = ("mla_queries", "mla_pairs_absorbed", "mla_pairs_expanded", "mla_rows_expanded")
-
-
 def yarn_mscale(factor: float, mscale: float) -> float:
     return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
@@ -185,7 +177,7 @@ class KimiK2Config:
     # -- what the serving engine asks of a configuration (``serve/llm.py``) --
 
     #: what ``extend`` counts, in the order of its last output
-    counters = moe.COUNTERS + MLA_COUNTERS
+    counters = moe.COUNTERS + layers.MLA_COUNTERS
 
     @property
     def cache_arrays(self):
@@ -283,7 +275,7 @@ def make_extend_fn(cfg: KimiK2Config):
     ``gpt.make_extend_fn`` over one cache (``[layers, lanes, cache, 1,
     row_dim]``, ``cfg.cache_arrays``): ``(logits, hidden, rows, counters)``.
     ``counters`` (int32, ``cfg.counters``, summed over the layers) are
-    ``moe.held_experts_ffn``'s four and the attention's (``MLA_COUNTERS``), over
+    ``moe.held_experts_ffn``'s four and the attention's (``layers.MLA_COUNTERS``), over
     real tokens only. A negative token id marks padding: it computes no expert
     and is not counted.
 

@@ -1,6 +1,6 @@
 """The kit the architecture files compose (``gpt.py``, ``cohere2_moe.py``,
 ``keye_vl2.py``, ``kimi_k2.py``, ``granitemoehybrid.py``, ``lfm2_moe.py``,
-``minicpm_sala.py``, ``mimo_v2_flash.py``, whose per-sequence state is made of
+``minicpm_sala.py``, ``glm_moe_dsa.py``, ``mimo_v2_flash.py``, whose per-sequence state is made of
 cached rows: a window's K and V; ``qwen3_next.py``, whose norms are zero-centred: it
 hands :func:`rms_norm` the scale ``1 + g``; ``nemotron_h.py``, which also takes
 ``granitemoehybrid.ssm_scan``, the one function an architecture has from a sibling:
@@ -172,6 +172,71 @@ def paged_attend(q, k, v, k_pages, v_pages, at, table, positions, visible, scale
         return write_rows(cache, lane, positions, new).reshape(b, cache.shape[1], kv, -1)
 
     return plain_attend(q, taken(k_pages, k), taken(v_pages, v), visible, scale)
+
+
+# -- a learned indexer's selection (``keye_vl2.py``, ``glm_moe_dsa.py``) -------------
+
+#: what an indexer counts over the real queries of a device call, summed over
+#: the layers: queries that passed an indexer, live causal query-key pairs it
+#: scored, keys attended (``min(topk, visible)`` a query), and cache slots of
+#: the call's padded caches that at least one query selected
+SPARSE_COUNTERS = (
+    "sparse_queries", "sparse_keys_scored", "sparse_keys_attended", "sparse_slots_read")
+
+#: what latent attention counts over the real queries of a device call, summed
+#: over the layers (``kimi_k2.py``, ``glm_moe_dsa.py``): queries, query-key pairs
+#: attended in the absorbed and in the expanded form (a call's pairs all under the
+#: form it attends in), and latent rows put through ``W_kvb``: the live slots of
+#: every lane of a chunk on the chip, 0 for any other call
+MLA_COUNTERS = ("mla_queries", "mla_pairs_absorbed", "mla_pairs_expanded", "mla_rows_expanded")
+
+
+def _one_zero(scores, visible):
+    """``scores`` with -0 as +0 (equal, so a tie) and -inf where not visible."""
+    return jnp.where(visible, jnp.where(scores == 0, 0.0, scores), -jnp.inf)
+
+
+def _ordered_bits(scores):
+    """Float32 ``scores`` as uint32 that order as the scores do."""
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    ordered = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+    return jax.lax.bitcast_convert_type(ordered, jnp.uint32) ^ jnp.uint32(0x80000000)
+
+
+def select_mask(scores, visible, k: int):
+    """For each query (a row of ``scores`` [..., cache], float32) the ``k``
+    visible keys with the largest score, ties to the lower position, as a mask
+    [..., cache]; all the visible ones where they are at most ``k``. No sort:
+    the ``k``-th largest score is found bit by bit (the largest value that at
+    least ``k`` scores reach), then the ties at it are counted off."""
+    u = _ordered_bits(_one_zero(scores, visible))
+
+    def next_bit(i, kth):
+        candidate = kth | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        reached = (u >= candidate[..., None]).sum(-1, dtype=jnp.int32)
+        return jnp.where(reached >= k, candidate, kth)
+
+    kth = jax.lax.fori_loop(
+        0, 32, next_bit, jnp.zeros(scores.shape[:-1], jnp.uint32))[..., None]
+    above, ties = u > kth, u == kth
+    room = k - above.sum(-1, dtype=jnp.int32, keepdims=True)
+    # only a row with more ties than room needs them counted off, and a
+    # running count over the cache costs as much as the search: skip it where
+    # no row of the call does
+    tied = jax.lax.cond(
+        (ties.sum(-1, dtype=jnp.int32, keepdims=True) > room).any(),
+        lambda: ties & (jnp.cumsum(ties, axis=-1, dtype=jnp.int32) <= room),
+        lambda: ties)
+    return (above | tied) & visible
+
+
+def select_rows(scores, visible, k: int):
+    """The same keys as positions: ``(positions [..., k'], chosen [..., k'])``
+    with ``k' = min(k, cache)``; where fewer than ``k'`` keys are visible the
+    rest are not ``chosen`` (``jax.lax.top_k`` puts the lower position first
+    among equals)."""
+    top, positions = jax.lax.top_k(_one_zero(scores, visible), min(k, scores.shape[-1]))
+    return positions, top > -jnp.inf
 
 
 def gated_mlp(x, wi, wo):

@@ -445,7 +445,7 @@ def _make_extend(cfg: MiniCPMSALAConfig, probe: bool):
 
     # An arena is read and written one slot at a time, with a dynamic slice and an
     # in-place dynamic update: indexed with the slots the TPU compiler first copies all
-    # of it (``models/granitemoehybrid.py``; ``tests/test_chip_compile.py`` holds
+    # of it (``models/granitemoehybrid.py``; ``tests/test_chip_compile_serve.py`` holds
     # ``extend`` to this).
 
     def _take(arena, slots, at):

@@ -457,7 +457,7 @@ def make_extend_fn(cfg: Qwen3NextConfig):
 
     # An arena is read and written one slot at a time, with a dynamic slice and an
     # in-place dynamic update: indexed with the slots the TPU compiler first copies all
-    # of it (``models/granitemoehybrid.py``; ``tests/test_chip_compile.py`` holds
+    # of it (``models/granitemoehybrid.py``; ``tests/test_chip_compile_serve.py`` holds
     # ``extend`` to this).
 
     def _take(arena, slots, at=0, layers=1):

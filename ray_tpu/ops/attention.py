@@ -809,7 +809,15 @@ def _latent_fwd_kernel(
 
     ki = pl.program_id(3)
     heads, nope, rope = qn_ref.shape[2], qn_ref.shape[-1], qr_ref.shape[-1]
-    rank, dv = wk_ref.shape[0], o_ref.shape[-1]
+    rank, dv = wk_ref.shape[-2], o_ref.shape[-1]
+
+    def of_head(ref, g, width):
+        """Head ``g``'s [rank, width] of one half of W_kvb: its columns of the heads
+        side by side, or its slab where the heads lie outermost (a width that is no
+        whole number of lane tiles: ``latent_attention``'s ``up_spec``)."""
+        if len(ref.shape) == 3:
+            return ref[g]
+        return ref[:, pl.ds(pl.multiple_of(g * width, width), width)]
 
     @pl.when(ki == 0)
     def _init():
@@ -827,10 +835,10 @@ def _latent_fwd_kernel(
             # the head's own key and value of this tile, made here and gone
             # with it: W_kvb's two halves as they are stored, the head's columns
             k_nope = jnp.dot(
-                latent, wk_ref[:, pl.ds(pl.multiple_of(g * nope, nope), nope)],
+                latent, of_head(wk_ref, g, nope),
                 preferred_element_type=jnp.float32).astype(rows.dtype)
             v = jnp.dot(
-                latent, wv_ref[:, pl.ds(pl.multiple_of(g * dv, dv), dv)],
+                latent, of_head(wv_ref, g, dv),
                 preferred_element_type=jnp.float32).astype(rows.dtype)
             s = (
                 jax.lax.dot_general(
@@ -934,7 +942,15 @@ def latent_attention(
             (1, 1, heads, block_q, width), lambda bi, hi, qi, ki, blocks: (bi, hi, 0, qi, 0))
 
     def up_spec(width):                 # the block's heads' columns of one half of W_kvb
+        if width % 128:
+            # a head's columns would start inside a 128-lane tile, which the kernel
+            # cannot slice (GLM-5's nope of 192): the heads outermost, a head a slab
+            return pl.BlockSpec((heads, rank, width), lambda bi, hi, qi, ki, blocks: (hi, 0, 0))
         return pl.BlockSpec((rank, heads * width), lambda bi, hi, qi, ki, blocks: (0, hi))
+
+    def by_columns(up):                 # [rank, n_heads, width] as ``up_spec`` reads it
+        width = up.shape[-1]
+        return up.transpose(1, 0, 2) if width % 128 else up.reshape(rank, n_heads * width)
 
     def by_head(q):
         return q.transpose(0, 2, 1, 3).reshape(b, tiles, heads, t + pad_q, q.shape[-1])
@@ -969,7 +985,7 @@ def latent_attention(
         interpret=interpret,
     )(
         blocks, by_head(q_nope), by_head(q_rope), rows,
-        k_up.reshape(rank, n_heads * nope), v_up.reshape(rank, n_heads * dv),
+        by_columns(k_up), by_columns(v_up),
         mask.astype(jnp.int8))
     return out.reshape(b, n_heads, t + pad_q, dv).transpose(0, 2, 1, 3)[:, :t]
 

@@ -11,8 +11,10 @@ where it keeps state per sequence and not per token, ``state_arrays`` names
 that, ``state_chunk`` how often a state can be kept, and ``cache_layers`` in how
 many layers a token is cached (``cached_layers``: in which); ``models/cohere2_moe.py``,
 ``models/keye_vl2.py``, ``models/kimi_k2.py``, ``models/granitemoehybrid.py``,
-``models/minicpm_sala.py``, ``models/mimo_v2_flash.py``, ``models/qwen3_next.py``). A
-state need not be a recurrence's: ``mimo_v2_flash.py``'s is made of **cached rows**,
+``models/minicpm_sala.py``, ``models/mimo_v2_flash.py``, ``models/qwen3_next.py``,
+``models/glm_moe_dsa.py``: two arenas a token, a latent row all heads share and an
+indexer's key, of which a query reads the rows its indexer selects; nothing new in this
+file). A state need not be a recurrence's: ``mimo_v2_flash.py``'s is made of **cached rows**,
 the newest 128 rows of K and V of each layer that sees a window and nothing else, which
 the engine keeps, hands over, snapshots and restores as it does any state, and never
 pages. Nor need a recurrence be additive: ``qwen3_next.py``'s gated delta rule writes
@@ -270,7 +272,7 @@ def _paging_programs():
     # dynamic update. Written as ``arena[:, table]`` / ``.at[:, slots].set``
     # the TPU compiler first copies a whole arena into a temporary, on every
     # call: 1.17 GB at GPT-J's serve sizes, where 1 GB is free beside the
-    # weights (``tests/test_chip_compile.py`` holds the programs to this).
+    # weights (``tests/test_chip_compile_serve.py`` holds the programs to this).
 
     def lies_tokens_last(a):
         """Whether the runtime lays the arena out with a block's tokens along the

@@ -54,9 +54,9 @@ def selection_of(probe, cfg, params, fed, chunk: int, cap: int):
     while at < seq:
         n = chunk if at < whole else 1
         tokens = jnp.asarray([fed[at:at + n]], jnp.int32)
-        *_, k, v, i, _, selected = probe(params, tokens, jnp.full((1,), at, jnp.int32), *caches)
-        caches = [
-            c.at[:, :, at:at + n].set(new) for c, new in zip(caches, (k, v, i))]
+        # (logits, hidden, a new row for every cache, counters, selected)
+        _, _, *news, _, selected = probe(params, tokens, jnp.full((1,), at, jnp.int32), *caches)
+        caches = [c.at[:, :, at:at + n].set(new) for c, new in zip(caches, news)]
         out[:, at:at + n] = np.asarray(selected)[:, 0, :, :seq]
         at += n
     return out

@@ -25,8 +25,8 @@ import pytest
 from chip_compile_helpers import (  # noqa: F401 — shaped and v5e are fixtures
     HBM_BYTES, _device_bytes, _gptj, shaped, v5e)
 from ray_tpu.models import (
-    cohere2_moe, gpt, granitemoehybrid, keye_vl2, kimi_k2, layers, mimo_v2_flash, minicpm_sala,
-    qwen3_next)
+    cohere2_moe, glm_moe_dsa, gpt, granitemoehybrid, keye_vl2, kimi_k2, layers, mimo_v2_flash,
+    minicpm_sala, qwen3_next)
 from ray_tpu.serve import llm
 
 
@@ -1067,13 +1067,83 @@ def test_qwen3_next_share_extend_compiles_and_copies_no_arena_and_no_expert(
     assert 2 * cfg.num_params() + resident + arena_bytes >= 0.60 * HBM_BYTES
 
 
+def _glm_share():
+    """The served cut of GLM-5 (layer 0 and five expert layers, 16 of 256 experts, an
+    eighth of the vocabulary) and its engine sizes, from the configuration's file."""
+    import json
+
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "configs", "glm-5-serve-ep16.json")) as f:
+        config = json.load(f)
+    return glm_moe_dsa.GlmMoeDsaConfig(
+        vocab_size=config["vocab_size"], num_layers=config["num_hidden_layers"],
+        dense_layers=config["first_k_dense_replace"],
+        num_experts=config["n_routed_experts"]), config
+
+
+@pytest.mark.parametrize("form", ["decode", "prefill"])
+def test_glm_5_share_extend_compiles_at_its_largest_shapes(shaped, form, built_for_tpu):
+    """One chip's share of GLM-5 at its published widths (9.45 GB of weights) over the
+    largest cache bucket, both arenas: a decode call scores its lanes' indexer keys, takes
+    2,048 rows a lane and attends those in the absorbed form, all in XLA; a prefill chunk
+    attends in the expanded form in ``latent_attention`` (a head's ``W_kvb^K`` columns are
+    192 wide and start inside a lane tile: the heads' slabs) under the selection mask, and
+    neither a float32 score of all a chunk's queries and heads over the cache nor a head's
+    keys or values of the cache's slots is left in the program; it fits beside the pool
+    and a second call's caches, and holds the memory the configuration's file states."""
+    built_for_tpu(True)     # the chip's grouped matmul and attention kernel
+    cfg, config = _glm_share()
+    engine, stated = config["engine"], config["compiled_bytes_per_device"]
+    cap, lanes = engine["cache_buckets"][-1], engine["lane_buckets"][-1]
+    b, tc = (lanes, 1) if form == "decode" else (1, engine["prefill_token_buckets"][-1])
+    assert stated[form]["shape"] == [b, tc, cap]
+    compiled = _kimi_extend_at(shaped, cfg, engine, b, tc, cap)
+    text = compiled.as_text()
+    assert text.startswith(f"HloModule jit_extend_{form}_{b}x{tc}x{cap},")
+    kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    experts = [line for line in kernels if "extend.moe.experts" in line]
+    assert len(experts) >= 2                                     # the kernel is there
+    if form == "prefill":
+        # layer 0's attend and the scanned layers': straight under the scope the readers count
+        attends = [line for line in kernels if line not in experts]
+        assert len(attends) == 2 and all(
+            "/extend.attention/latent_attention/" in line for line in attends)
+
+        def over_cache(types):
+            return max((
+                math.prod(map(int, dims.split(",")))
+                for dims in re.findall(rf"(?:{types})\[([0-9,]+)\]", text)
+                if str(cap) in dims.split(",")), default=0)
+
+        # the largest float32 array over the cache: 32 queries' dots of the indexer's heads
+        assert over_cache("f32") <= cfg.index_heads * layers.QUERY_BLOCK * cap
+        # ... and no head's keys or values of the cache's slots, in any type
+        assert over_cache("f32|bf16") < cap * cfg.num_heads * cfg.v_dim
+    else:
+        assert kernels == experts
+        # a decode lane's index scores over its cache, and its attend over the rows it took
+        assert f"f32[{lanes},{cfg.index_heads},{cap}]" in text
+        assert f"f32[{lanes},{cfg.num_heads},1,{cfg.topk}]" in text
+        assert f"f32[{lanes},{cfg.num_heads},1,{cap}]" not in text
+    memory = compiled.memory_analysis()
+    per_token = 2 * cfg.num_layers * sum(h * d for h, d in cfg.cache_arrays)
+    assert per_token == 6 * (1280 + 256)
+    weights = memory.argument_size_in_bytes - per_token * b * cap
+    assert 9.45e9 < weights < 9.46e9
+    assert memory.argument_size_in_bytes == stated[form]["argument"]
+    assert memory.temp_size_in_bytes <= stated[form]["temp"] * 1.05 < 0.5e9
+    # beside the pool and the caches of the call in flight
+    pool = per_token * engine["num_blocks"] * engine["block_size"]
+    assert _device_bytes(compiled) + pool + per_token * lanes * cap < HBM_BYTES
+
+
 @pytest.mark.parametrize(
     "name,extends,pagings",
     [("gptj-6b-serve", 12, 11), ("command-a-plus-serve-ep8", 20, 25),
      ("keye-vl2-30b-a3b-serve", 16, 19), ("kimi-k2-instruct-serve-ep32", 16, 19),
      ("granite-4.0-h-micro-serve", 20, 13), ("granite-4.0-h-small-serve-ep2", 20, 13),
      ("minicpm-sala-serve-pp2", 16, 19), ("mimo-v2-flash-serve-ep16", 20, 13),
-     ("qwen3-next-80b-a3b-serve-ep4", 18, 14)],
+     ("qwen3-next-80b-a3b-serve-ep4", 18, 14), ("glm-5-serve-ep16", 16, 19)],
 )
 def test_a_serve_configuration_compiles_no_more_programs_than_it_did(name, extends, pagings):
     """The programs an engine with the configuration's buckets compiles (a tiny
@@ -1097,6 +1167,7 @@ def test_a_serve_configuration_compiles_no_more_programs_than_it_did(name, exten
         if name.startswith("command-a-plus")
         else keye_vl2.keye_vl2_nano(max_seq_len=context) if name.startswith("keye")
         else kimi_k2.kimi_k2_nano(max_seq_len=context) if name.startswith("kimi")
+        else glm_moe_dsa.glm_moe_dsa_nano(max_seq_len=context) if name.startswith("glm")
         else granitemoehybrid.granite_hybrid_nano(
             max_seq_len=context, ssm_chunk=256, router_experts=8 * name.count("small"))
         if name.startswith("granite")

@@ -42,8 +42,8 @@ def test_the_mask_and_the_rows_are_the_same_keys(case, k):
     seen = rng.integers(0, 65, size=scores.shape[0])
     seen[0], seen[1] = 0, 64                     # a row that sees nothing, one that sees all
     visible = np.arange(64)[None, :] < seen[:, None]
-    mask = np.asarray(jax.jit(keye_vl2.select_mask, static_argnums=2)(scores, visible, k))
-    rows, chosen = (np.asarray(x) for x in keye_vl2.select_rows(
+    mask = np.asarray(jax.jit(layers.select_mask, static_argnums=2)(scores, visible, k))
+    rows, chosen = (np.asarray(x) for x in layers.select_rows(
         jnp.asarray(scores), jnp.asarray(visible), k))
     assert rows.shape == chosen.shape == (scores.shape[0], min(k, 64))
     for r in range(scores.shape[0]):
@@ -299,7 +299,7 @@ def test_the_configuration_counts_its_parameters_and_states_what_a_token_holds()
     params = CFG.init_params(0)
     assert CFG.num_params() == sum(a.size for a in jax.tree.leaves(params))
     assert CFG.cache_arrays == ((2, 16), (2, 16), (1, 8))
-    assert CFG.counters == moe.COUNTERS + keye_vl2.SPARSE_COUNTERS
+    assert CFG.counters == moe.COUNTERS + layers.SPARSE_COUNTERS
     assert CFG.count_gathered(4, 128) == {"sparse_slots_gathered": 3 * 4 * 128}
     full = keye_vl2.KeyeVL2Config(num_layers=6)
     assert full.num_params() == pytest.approx(4.375e9, rel=1e-3)

@@ -14,7 +14,7 @@ import pytest
 import ray_tpu
 from ray_tpu import serve
 from ray_tpu.models import (
-    cohere2_moe, gpt, granitemoehybrid, keye_vl2, mimo_v2_flash, qwen3_next)
+    cohere2_moe, glm_moe_dsa, gpt, granitemoehybrid, keye_vl2, mimo_v2_flash, qwen3_next)
 from ray_tpu.serve import batching, llm
 from ray_tpu.serve.llm import (
     LANE_BUCKETS,
@@ -1043,3 +1043,102 @@ def test_a_deployment_takes_its_executing_slots_from_what_its_callable_runs_at_o
     assert serve._executing_slots(named.deployment, named.init_args, named.init_kwargs) == 3
     plain = serve.deployment(lambda x: x, name="plain").bind()
     assert serve._executing_slots(plain.deployment, plain.init_args, plain.init_kwargs) == 8
+
+
+# ---------------------------------------------------------------------------
+# latent rows under a learned selection: two arenas, two forms of attend
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def glm_engine():
+    """One engine over the GLM-5 nano (``topk`` 16 rows a query, a latent row of 128 and an
+    indexer key of 16 a token and layer) shared by the tests below; the projections scaled
+    up so that the logits are of order 1 and the selection matters."""
+    import jax
+
+    cfg = glm_moe_dsa.glm_moe_dsa_nano()
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, a: a if path[-1].key in ("scale", "bias") else a * 8.0, cfg.init_params(7))
+    return cfg, params, LLMEngine(
+        cfg, params, num_blocks=64, block_size=16, prefill_chunk=32, prefill_lanes=1,
+        lane_buckets=(1, 2, 4), prefill_token_buckets=(32,), cache_buckets=(64, 128))
+
+
+def _glm_file(cfg):
+    """The keys the benchmark's plain reference reads, for the nano's sizes."""
+    import json
+    import os
+
+    with open(os.path.join(
+            os.path.dirname(__file__), "benchmark", "tiny", "glm_moe_dsa.json")) as f:
+        model = json.load(f)["model"]
+    assert (model["index_topk"], model["num_hidden_layers"]) == (cfg.topk, cfg.num_layers)
+    return model
+
+
+def test_lanes_under_and_past_index_topk_decode_what_the_reference_computes(glm_engine):
+    """Two sequences at once, one whose whole context stays under ``index_topk`` (10 + 5
+    tokens: causal attention, the selection decides nothing) and one far past it (75 + 5:
+    three chunks under the selection mask, then decode lanes over 16 gathered rows, across
+    the 64-slot bucket into the 128-slot one), in decode calls of two lanes of unlike
+    lengths: each one's logits are the plain reference's full forward over its own tokens
+    (float32 on both sides: 2e-4 of logits of order 1, the roundings of sums in another
+    order), and every query attended ``min(topk, what it sees)`` rows."""
+    from benchmark.reference import glm_moe_dsa_reference as ref
+
+    cfg, params, eng = glm_engine
+    asks = [
+        {"prompt": _prompt(90 + i, n), "max_new_tokens": 5, "return_logits": True}
+        for i, n in enumerate((10, 75))]
+    before = eng.stats()
+    seqs = [batching._Sequence(dict(ask)) for ask in asks]
+    while not all(s.done for s in seqs):
+        eng.step([s for s in seqs if not s.done])
+    assert all(s._error is None for s in seqs)
+    after = eng.stats()
+    decode = {k: after["calls"]["decode"][k] - before["calls"]["decode"][k] for k in ("n", "lanes_used")}
+    assert decode["lanes_used"] > decode["n"]                   # some calls carried both lanes
+    model = _glm_file(cfg)
+    for ask, seq in zip(asks, seqs):
+        out = seq._result
+        fed = ask["prompt"] + out["tokens"][:-1]
+        want = np.asarray(ref.program_logits(params, fed, model, 5))
+        assert float(np.abs(want).max()) > 0.3
+        np.testing.assert_allclose(out["logits"], want, atol=2e-4, rtol=2e-4)
+        assert out["tokens"] == [int(t) for t in want.argmax(-1)]
+    d = {k: after[k] - before[k] for k in after if k.startswith(("sparse_", "mla_"))}
+    seen = [t + 1 for n in (10, 75) for t in range(n + 4)]
+    assert d["sparse_keys_scored"] == cfg.num_layers * sum(seen)
+    assert d["sparse_keys_attended"] == cfg.num_layers * sum(min(s, cfg.topk) for s in seen)
+    assert d["mla_pairs_absorbed"] == d["sparse_keys_attended"] and d["mla_pairs_expanded"] == 0
+    assert 0 < d["sparse_slots_read"] < d["sparse_slots_gathered"]
+    assert eng.pool.in_use() == 0 or eng.prefix is not None
+
+
+def test_a_prefix_hit_restores_both_arenas_and_decodes_the_same_bits(glm_engine):
+    """The same 75-token prompt twice: the second time four blocks of 16 come from the
+    prefix cache, the latent rows and the indexer's keys with them (the repeat's first
+    chunk scores its queries against keys it never made), and tokens and logits are the
+    first run's to the bit."""
+    cfg, _, eng = glm_engine
+    assert [a.shape for a in eng.pool.arenas] == [
+        (cfg.num_layers, 64, 16, 1, cfg.row_dim), (cfg.num_layers, 64, 16, 1, cfg.index_dim)]
+    ask = {"prompt": _prompt(123, 75), "max_new_tokens": 6, "return_logits": True}
+    results = []
+    for _ in range(2):
+        seq = batching._Sequence(dict(ask))
+        while not seq.done:
+            eng.step([seq])
+        assert seq._error is None
+        results.append(seq._result)
+    first, again = results
+    assert (first["prefix_cached_tokens"], again["prefix_cached_tokens"]) == (0, 64)
+    assert again["tokens"] == first["tokens"]
+    assert np.array_equal(again["logits"], first["logits"])
+    # the blocks that were reused hold rows in both arenas, and no block is all zeros there
+    blocks = eng.prefix.match(chain_hashes(ask["prompt"], 16))
+    assert len(blocks) == 4
+    held = [np.asarray(a) for a in eng.pool.read_block(blocks[0])]
+    assert [h.shape[-1] for h in held] == [cfg.row_dim, cfg.index_dim]
+    assert all(np.abs(h).max() > 0 for h in held)
