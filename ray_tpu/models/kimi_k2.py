@@ -25,9 +25,17 @@ a token leaves behind and in how a query reads it:
 * a decode call attends in the **absorbed** form: ``q' = q_nope W_kvb^K[h]``
   lies in the latent's space, the score is ``(q' . c_kv + q_rope . k_rope) * s``,
   the weighted sum of latents is taken through ``W_kvb^V[h]`` afterwards. No key
-  or value of a head is made, whatever the length of the cache: a lane does it
-  over its padded cache in ``jax.numpy``, 2 x 64 x (576 + 512) operations a
-  query-key pair. So does a prefill chunk off the chip, 32 queries at a time;
+  or value of a head is made, whatever the length of the cache, 2 x 64 x (576 +
+  512) operations a query-key pair. The engine hands such a call the pool's arena
+  itself and the lanes' block table (``extend`` offers ``table=``, which
+  ``serve/llm.py`` ``reads_pages`` looks for): on the chip
+  ``ops/attention.paged_attention`` reads a lane's live pages once where they lie
+  (a page is fetched once: its rows are scored whole and their first ``kv_rank``
+  columns summed, no value arena) beside the call's own row, which is in no page
+  yet; nothing is gathered, no slab of a padded cache is copied to write a row
+  into it, and no score is made over the bucket. Off the chip the table's pages
+  side by side are the padded cache, attended in ``jax.numpy`` as a prefill chunk
+  off the chip is, 32 queries at a time: bit for bit what a gathered call gives;
 * a prefill chunk on the chip attends in the **expanded** form (``[k_nope ; v] =
   c_kv W_kvb``, a head's own 192-wide key and 128-wide value: 2 x 64 x 320
   operations a pair), in ``ops/attention.latent_attention``: the kernel puts
@@ -274,14 +282,18 @@ def make_extend_fn(cfg: KimiK2Config):
     """A jitted ``extend(params, tokens, lengths, cache)`` with the contract of
     ``gpt.make_extend_fn`` over one cache (``[layers, lanes, cache, 1,
     row_dim]``, ``cfg.cache_arrays``): ``(logits, hidden, rows, counters)``.
+    With ``table`` [lanes, n] (a call of one token a lane) ``cache`` is the pool's
+    arena ``[layers, blocks, block, 1, row_dim]`` itself, neither written nor
+    copied: each layer attends over the rows that the lane's first live pages of
+    ``table`` hold and over the call's own row, which goes back in ``rows`` as ever.
     ``counters`` (int32, ``cfg.counters``, summed over the layers) are
     ``moe.held_experts_ffn``'s four and the attention's (``layers.MLA_COUNTERS``), over
     real tokens only. A negative token id marks padding: it computes no expert
     and is not counted.
 
     Scopes: ``extend.embed``; ``extend.attention`` (cache update, the attend
-    (a chunk's on the chip the kernel ``latent_attention`` straight under it),
-    ``W_o``) with ``extend.attention.latent`` inside it (both down-projections,
+    (on the chip a chunk's the kernel ``latent_attention`` and a call's through the
+    table ``paged_attention``, straight under it), ``W_o``) with ``extend.attention.latent`` inside it (both down-projections,
     their norms, ``W_qb``, the rotations and, in the absorbed form, the
     absorption and the un-absorption);
     ``extend.mlp`` (a dense layer's); ``extend.moe.route``, ``extend.moe.experts``,
@@ -331,14 +343,17 @@ def make_extend_fn(cfg: KimiK2Config):
             q = row(absorbed, _rope(q[..., cfg.nope_dim:], positions))
         return q, row(c_kv[:, :, None], _rope(both[:, :, None, rank:], positions))
 
-    @jax.named_scope("extend.attention")
-    def _attend(p, hidden, positions, visible, live, kc):
-        """``visible`` [b, t, cache] is what each query may read, ``live`` [b]
-        a bound past the lane's farthest real query: the same in every layer."""
-        b, tc = positions.shape
-        expanded = _expands(tc)
-        q, row = _latents(p, hidden, positions, expanded)
-        kc = layers.write_rows(kc, jnp.arange(b)[:, None], positions, row)
+    def _through_v_up(p, attended):
+        """The absorbed form's un-absorption: each head's sum of latents [b, t, heads,
+        rank] through its ``W_kvb^V``."""
+        with jax.named_scope("extend.attention.latent"):
+            return jnp.einsum("bthc,chv->bthv", attended, _kernel(p, "v_up"))
+
+    def _over_padded(p, q, expanded, row, positions, visible, live, kc):
+        """The attend over a lane's padded cache ``kc`` [b, cache, 1, row_dim], the call's
+        rows written into it, in the form :func:`_latents` made ``q`` for: [b, t, heads,
+        v_dim]."""
+        kc = layers.write_rows(kc, jnp.arange(kc.shape[0])[:, None], positions, row)
 
         def attend_block(qb, mask):             # [b, n, heads, row_dim], [b, n, cache]
             logit = jnp.einsum(
@@ -350,15 +365,32 @@ def make_extend_fn(cfg: KimiK2Config):
 
         if expanded:
             # a head's own key and value, made of each tile of rows inside the kernel
-            out = attention.latent_attention(
+            return attention.latent_attention(
                 *q, kc[:, :, 0], _kernel(p, "k_up"), _kernel(p, "v_up"), visible, live,
                 scale=scale)
+        return _through_v_up(p, layers.by_query_block(attend_block, q, visible))
+
+    @jax.named_scope("extend.attention")
+    def _attend(p, hidden, positions, visible, live, kc, paged=None):
+        """``visible`` [b, t, cache] is what each query may read, ``live`` [b]
+        a bound past the lane's farthest real query: the same in every layer.
+        ``kc`` is the layer's slab of the padded cache; or, with ``paged`` (the layer's
+        index and the lanes' block table: a call of one token a lane), the pool's
+        arena itself, read where it lies, not written and not copied."""
+        expanded = _expands(positions.shape[1])
+        q, row = _latents(p, hidden, positions, expanded)
+        if paged is not None and backend.on_tpu():
+            # one K/V head of all the query heads over the lanes' pages and the call's
+            # own row; a row is the key and, in its first ``rank`` features, the value
+            out = _through_v_up(p, attention.paged_attention(
+                q[:, 0, None], kc, None, *paged, positions[:, 0], row[:, 0, 0],
+                row[:, 0, 0, :rank], scale=scale)[:, None, 0])
         else:
-            attended = (
-                attend_block(q, visible) if tc == 1
-                else layers.by_query_block(attend_block, q, visible))
-            with jax.named_scope("extend.attention.latent"):
-                out = jnp.einsum("bthc,chv->bthv", attended, _kernel(p, "v_up"))
+            # padded caches; or, off the chip, the same contract densely: the table's pages
+            # side by side are the lane's padded cache, to the bit what a gather hands over
+            out = _over_padded(
+                p, q, expanded, row, positions, visible, live,
+                kc if paged is None else layers.table_pages(kc, *paged))
         return jnp.einsum("bthv,hvd->btd", out, _kernel(p, "o")), row
 
     def _experts(p, experts, layer, normed, valid):
@@ -378,18 +410,22 @@ def make_extend_fn(cfg: KimiK2Config):
         return (routed + shared).astype(dtype).reshape(b, tc, d), counters
 
     def _block(x, p, positions, reads, kc, ffn):
-        a, row = _attend(p["attn"], _normed(x, p, "ln_1").astype(dtype), positions, *reads, kc)
+        a, row = _attend(p["attn"], _normed(x, p, "ln_1").astype(dtype), positions, *reads, *kc)
         x = x + a
         return x, row, ffn(_normed(x, p, "ln_2"))
 
     @jax.jit
-    def extend(params, tokens, lengths, cache, *, last=None):
+    def extend(params, tokens, lengths, cache, *, last=None, table=None):
         positions, valid = layers.frame(tokens, lengths)
-        reads = (
-            layers.visible_keys(positions, valid, cache.shape[2]),
-            layers.live_keys(positions, valid))
+        cap = layers.cache_slots(cache, table)
+        reads = (layers.visible_keys(positions, valid, cap), layers.live_keys(positions, valid))
         with jax.named_scope("extend.embed"):
             x = layers.look_up(params["wte"]["embedding"].astype(dtype), tokens)
+
+        def held(at, slab):
+            """What layer ``at`` attends over: its slab of the padded cache where it
+            lies; or the arena itself, in which the kernel finds the layer's pages."""
+            return (slab(),) if table is None else (cache, (at, table))
 
         rows = []
         for at in range(cfg.dense_layers):
@@ -400,7 +436,7 @@ def make_extend_fn(cfg: KimiK2Config):
                     return layers.gated_mlp(
                         normed.astype(dtype), p["mlp"]["wi"], p["mlp"]["wo"])
 
-            x, row, f = _block(x, p, positions, reads, cache[at], mlp)
+            x, row, f = _block(x, p, positions, reads, held(at, lambda: cache[at]), mlp)
             x = x + f.astype(dtype)
             rows.append(row)
 
@@ -409,8 +445,8 @@ def make_extend_fn(cfg: KimiK2Config):
 
         def body(carry, xs):
             p, layer = xs
-            # the layer's slab of the cache where it lies, behind the dense layers'
-            kc = jax.lax.dynamic_index_in_dim(cache, cfg.dense_layers + layer, 0, keepdims=False)
+            at = cfg.dense_layers + layer           # behind the dense layers'
+            kc = held(at, lambda: jax.lax.dynamic_index_in_dim(cache, at, 0, keepdims=False))
             carry, row, (f, counters) = _block(
                 carry, p, positions, reads, kc,
                 lambda normed: _experts(p, experts, layer, normed, valid))
@@ -420,11 +456,11 @@ def make_extend_fn(cfg: KimiK2Config):
             body, x, (scanned, jnp.arange(cfg.expert_layers, dtype=jnp.int32)))
         logits, x = layers.rms_head(
             x, params["ln_f"]["scale"], cfg.norm_eps, params["head"]["kernel"], dtype, last)
-        seen = jnp.where(valid, jnp.minimum(positions + 1, cache.shape[2]), 0)
+        seen = jnp.where(valid, jnp.minimum(positions + 1, cap), 0)
         queries, pairs = valid.sum(dtype=jnp.int32), seen.sum(dtype=jnp.int32)
         if _expands(tokens.shape[1]):
             # every live slot of a lane goes through W_kvb once a layer
-            slots = jnp.minimum(reads[1], cache.shape[2]).sum(dtype=jnp.int32)
+            slots = jnp.minimum(reads[1], cap).sum(dtype=jnp.int32)
             by_form = (jnp.int32(0), pairs, slots)
         else:
             by_form = (pairs, jnp.int32(0), jnp.int32(0))

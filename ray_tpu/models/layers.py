@@ -147,6 +147,15 @@ def plain_attend(q, k, v, mask, scale):
     return jnp.einsum("bhgqk,bkhd->bqhgd", weight.astype(v.dtype), v)
 
 
+def table_pages(pages, at, table):
+    """The pages that ``table`` [b, n] names in layer ``at`` of the pool's arena ``pages``
+    ``[layers, blocks, block, ...]``, side by side: ``[b, n x block, ...]``, a lane's
+    padded cache as a gather would hand it over. The dense form of a read through the
+    block table, off the chip."""
+    slab = jax.lax.dynamic_index_in_dim(pages, at, 0, keepdims=False)
+    return slab[table].reshape((table.shape[0], -1) + slab.shape[2:])
+
+
 def paged_attend(q, k, v, k_pages, v_pages, at, table, positions, visible, scale):
     """A decode call's attend (one token a lane) **through the block table**: ``q`` [b, 1,
     kv, g, hd] over the rows that ``table`` [b, n] names in layer ``at`` of the pool's
@@ -167,8 +176,7 @@ def paged_attend(q, k, v, k_pages, v_pages, at, table, positions, visible, scale
     lane = jnp.arange(b)[:, None]
 
     def taken(pages, new):
-        slab = jax.lax.dynamic_index_in_dim(pages, at, 0, keepdims=False)
-        cache = slab[table].reshape((b, -1) + slab.shape[2:])
+        cache = table_pages(pages, at, table)
         return write_rows(cache, lane, positions, new).reshape(b, cache.shape[1], kv, -1)
 
     return plain_attend(q, taken(k_pages, k), taken(v_pages, v), visible, scale)

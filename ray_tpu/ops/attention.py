@@ -1035,10 +1035,22 @@ def _paged_fwd_kernel(
         o_ref[0] = (acc_ref[:] / l_ref[:]).astype(o_ref.dtype)
 
 
+def _paged_latent_kernel(
+    at_ref, table_ref, pages_ref, lengths_ref, q_ref, rows_ref, k_own_ref, v_own_ref, *rest,
+    scale
+):
+    """:func:`_paged_fwd_kernel` over pages whose rows are key and value at once: the value
+    block is the first columns of the key block where it lies in VMEM (whole 128-lane
+    tiles: a view, no copy), as wide as the own row's value."""
+    _paged_fwd_kernel(
+        at_ref, table_ref, pages_ref, lengths_ref, q_ref, rows_ref,
+        rows_ref.at[:, :v_own_ref.shape[-1]], k_own_ref, v_own_ref, *rest, scale=scale)
+
+
 def paged_attention(
     q: jax.Array,
     k_pages: jax.Array,
-    v_pages: jax.Array,
+    v_pages: Optional[jax.Array],
     at,
     table: jax.Array,
     lengths: jax.Array,
@@ -1078,13 +1090,22 @@ def paged_attention(
     tiles either way; the zeros cost passes of at most 64 rows. Scores, softmax and
     accumulator float32, the weights in ``v``'s type, as :func:`masked_attention`.
     Returns [lanes, kv, groups, dv] in ``q``'s type. A lane of length 0 (padding)
-    gets its own row's value."""
+    gets its own row's value.
+
+    **A row that is key and value at once** (``v_pages`` None: a latent row, one K/V
+    head; ``models/kimi_k2.py``): every head scores the whole row of ``k_pages`` and sums
+    its first ``dv`` features, ``dv`` the width of ``v_own``, a whole number of the
+    chip's 128 lanes. The same grid and the same running softmax over **one** fetch of
+    a page a step (256 x 640 bfloat16 at Kimi's sizes: 328 KB; the arena handed in
+    twice would be two): the value block is a view of the key block in VMEM, and the
+    accumulator is ``[heads, dv]``, not a row wide and cut afterwards."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     lanes, kv, groups, d = q.shape
     block, n = k_pages.shape[2], table.shape[1]
-    dv = v_pages.shape[-1] // kv
+    latent = v_pages is None
+    dv = v_own.shape[-1] // kv
     heads = kv * groups
     scale = float(scale) if scale is not None else 1.0 / float(np.sqrt(d))
     lengths = lengths.astype(jnp.int32)
@@ -1104,14 +1125,20 @@ def paged_attention(
     def paged(width):
         return pl.BlockSpec((None, None, block, width), page_of)
 
+    def as_it_lies(pages):
+        # an arena without its axis of one, which the device keeps behind a block's
+        # tokens: the same bytes
+        return pages.reshape(pages.shape[:3] + pages.shape[4:])
+
+    arenas = (k_pages,) if latent else (k_pages, v_pages)
     out = pl.pallas_call(
-        functools.partial(_paged_fwd_kernel, scale=scale),
+        functools.partial(_paged_latent_kernel if latent else _paged_fwd_kernel, scale=scale),
         name="paged_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(lanes, n),
             in_specs=[
-                per_lane(heads, kv * d), paged(kv * d), paged(kv * dv),
+                per_lane(heads, kv * d), *(paged(a.shape[-1]) for a in arenas),
                 per_lane(1, kv * d), per_lane(1, kv * dv)],
             out_specs=per_lane(heads, kv * dv),
             scratch_shapes=[
@@ -1125,11 +1152,7 @@ def paged_attention(
         interpret=interpret,
     )(
         jnp.asarray(at, jnp.int32).reshape(1), table.astype(jnp.int32).reshape(lanes * n),
-        pages, lengths, diagonal,
-        # the arenas without their axis of one, which the device keeps behind a block's
-        # tokens: the same bytes
-        k_pages.reshape(k_pages.shape[:3] + k_pages.shape[4:]),
-        v_pages.reshape(v_pages.shape[:3] + v_pages.shape[4:]),
+        pages, lengths, diagonal, *map(as_it_lies, arenas),
         k_own.reshape(lanes, 1, kv * d), v_own.reshape(lanes, 1, kv * dv))
     # a head's own columns of what it summed over all heads' values
     out = out.reshape(lanes, kv, groups, kv, dv)

@@ -57,7 +57,8 @@ serving setup from PAPERS.md):
   built.
 * a decode call attends through the block table — where a model's ``extend``
   offers ``table=`` (:func:`reads_pages`: ``models/mimo_v2_flash.py``,
-  ``models/qwen3_next.py``, ``models/granitemoehybrid.py``), a call of one token
+  ``models/qwen3_next.py``, ``models/granitemoehybrid.py``, ``models/kimi_k2.py``,
+  whose page holds latent rows: key and value in one arena), a call of one token
   a lane is handed the pool's arenas themselves (not donated: the page-back
   donates them after, in launch order) and the ``table`` section of the operand
   buffer it already uploads, and runs no gather: each full-attention layer reads a
@@ -1023,7 +1024,9 @@ def _operand_extend(extend, caches: int = 0, states: int = 0):
 def reads_pages(extend) -> bool:
     """Whether a decode call of ``extend`` attends through the block table: what the
     model's ``extend`` offers, a ``table=`` keyword (``models/mimo_v2_flash.py``,
-    ``models/qwen3_next.py``, ``models/granitemoehybrid.py``). Such a call is handed
+    ``models/qwen3_next.py``, ``models/granitemoehybrid.py``, ``models/kimi_k2.py``;
+    not ``models/keye_vl2.py`` and ``models/glm_moe_dsa.py``, whose decode lanes read
+    selected rows). Such a call is handed
     the pool's arenas where another is handed padded caches, and runs no gather.
     Nothing else decides it: no key of a configuration, no keyword of the engine."""
     return "table" in inspect.signature(extend).parameters

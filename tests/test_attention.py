@@ -132,6 +132,8 @@ PAGED_SHAPES = {
     "qwen3-next": (2, 8, 256, 256),
     "granite-4h-micro": (8, 4, 64, 64),      # a head of half a lane tile
     "granite-4h-small": (8, 4, 128, 128),
+    # a latent row: the key and, in its first 512 features, the value (no value arena)
+    "kimi-k2": (1, 64, 640, 512),
 }
 
 
@@ -146,8 +148,10 @@ def test_paged_attention_reads_a_lanes_live_pages_and_its_own_row(shape, dtype):
     table entry past a lane's live pages names a block of NaN, as does all of layer 0:
     the result is finite and equal, so they are neither fetched into the sum nor read.
     Slots past a lane's length inside its last page hold other rows' finite values,
-    which the mask weighs 0."""
+    which the mask weighs 0. Kimi's shape has no value arena: the kernel is handed the
+    one arena once and sums the first ``dv`` features of the rows it scored."""
     kv, groups, d, dv = PAGED_SHAPES[shape]
+    latent = shape == "kimi-k2"
     block, n, blocks, lanes, at = 16, 4, 12, 5, 1
     rng = np.random.default_rng(61)
     nan = blocks - 1
@@ -162,9 +166,11 @@ def test_paged_attention_reads_a_lanes_live_pages_and_its_own_row(shape, dtype):
     k_own = jnp.asarray(rng.normal(size=(lanes, kv * d)), dtype)
     v_own = jnp.asarray(rng.normal(size=(lanes, kv * dv)), dtype)
     k_pages, v_pages = jnp.asarray(k_pages, dtype), jnp.asarray(v_pages, dtype)
+    if latent:
+        v_pages, v_own = k_pages[..., :dv], k_own[:, :dv]
     out = paged_attention(
-        q, k_pages, v_pages, jnp.int32(at), jnp.asarray(table), jnp.asarray(lengths),
-        k_own, v_own, interpret=True)
+        q, k_pages, None if latent else v_pages, jnp.int32(at), jnp.asarray(table),
+        jnp.asarray(lengths), k_own, v_own, interpret=True)
     assert out.shape == (lanes, kv, groups, dv) and out.dtype == dtype
     assert bool(jnp.isfinite(out.astype(jnp.float32)).all())
 

@@ -1,7 +1,10 @@
 """``models/kimi_k2.py`` on the CPU at a tiny size: the absorbed attend against
 the expanded form written out here, a decode lane and a prefill chunk and the
 chip's chunk (the expanded form's kernel, interpreted) give the same row and
-count their pairs each under its form, padding changes and counts nothing,
+count their pairs each under its form, a decode lane through the block table (the
+pool's arena, read where it lies: off the chip densely and to the bit what padded
+caches give, on it ``paged_attention`` with no value arena, interpreted),
+padding changes and counts nothing,
 YaRN's frequencies and scale against the closed form at the published keys, the
 router's bias chooses and does not weigh, the attention kernel with ``d_k !=
 d_v`` and one K/V head under several blocks of query heads, the kernel that
@@ -143,9 +146,35 @@ def chunked(program):
     return extend, tokens, logits, held, counters
 
 
+BLOCK = 16
+
+
+def _paged(held, rubbish):
+    """``held`` [layers, 1, 64, 1, row] as a pool would keep it: an arena of nine blocks
+    of 16 whose blocks 5, 2, 7, 0 are the lane's pages, the others ``rubbish``, and
+    the lane's table with one more entry than it has pages, which names rubbish."""
+    arena = np.full((CFG.num_layers, 9, BLOCK, 1, ROW), rubbish, np.float32)
+    table = np.array([[5, 2, 7, 0, 8]], np.int32)
+    arena[:, table[0, :4]] = np.asarray(held)[:, 0].reshape(CFG.num_layers, 4, BLOCK, 1, ROW)
+    return jnp.asarray(arena), jnp.asarray(table)
+
+
 def test_a_decode_lane_and_a_prefill_chunk_give_the_same_row(program, chunked):
+    """The decode lane as the engine calls it: handed the pool's arena and the lane's
+    block table. Off the chip that is to the bit what the padded cache gives (the
+    table's pages side by side are the padded cache), whatever the table names past
+    the lane's live pages (something finite, as a pool's arena holds: the dense form
+    weighs it 0, the kernel never fetches it)."""
     extend, tokens, _, held, first = chunked
-    one, _, row, counters = extend(program, tokens[:, 24:], jnp.full((1,), 24, jnp.int32), held)
+    lengths = jnp.full((1,), 24, jnp.int32)
+    arena, table = _paged(held, 1e3)
+    one, hidden, row, counters = extend(program, tokens[:, 24:], lengths, arena, table=table[:, :4])
+    padded = extend(program, tokens[:, 24:], lengths, held)
+    for got, want in zip((one, hidden, row, counters), padded):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # a longer table: the same lane in a larger bucket, its last entry never followed
+    wider = extend(program, tokens[:, 24:], lengths, arena, table=table)
+    np.testing.assert_allclose(np.asarray(wider[0]), np.asarray(one), atol=2e-6, rtol=2e-6)
     whole, _, rows, _ = extend(program, tokens, jnp.zeros((1,), jnp.int32), _cache(1, 64))
     np.testing.assert_allclose(np.asarray(one)[0, 0], np.asarray(whole)[0, 24], atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(np.asarray(row)[:, 0, 0], np.asarray(rows)[:, 0, 24], atol=1e-5)
@@ -162,12 +191,18 @@ def test_a_decode_lane_and_a_prefill_chunk_give_the_same_row(program, chunked):
 def on_the_chip(monkeypatch):
     """``extend`` as the chip traces it, on the CPU: ``backend.on_tpu`` answers
     yes, the chunk's attention kernel runs interpreted at small tiles with its
-    64 heads' budget cut to two blocks of four, the grouped matmul is XLA's.
-    Yields the shapes the kernel was called with."""
+    64 heads' budget cut to two blocks of four, a decode call's runs interpreted over
+    the pages it is handed, the grouped matmul is XLA's.
+    Yields the shapes the chunk's kernel was called with and, as a pair whose first
+    is ``"paged"``, those of the decode call's."""
     from ray_tpu.ops import backend
 
-    real = attention.latent_attention
+    real, real_paged = attention.latent_attention, attention.paged_attention
     seen = []
+
+    def paged(q, k_pages, v_pages, at, table, lengths, k_own, v_own, **kw):
+        seen.append(("paged", (q.shape, k_pages.shape, v_pages, table.shape, k_own.shape, v_own.shape)))
+        return real_paged(q, k_pages, v_pages, at, table, lengths, k_own, v_own, interpret=True, **kw)
 
     def interpreted(q_nope, q_rope, rows, k_up, v_up, mask, kv_len, **kw):
         seen.append((q_nope.shape, q_rope.shape, rows.shape, k_up.shape, v_up.shape))
@@ -181,6 +216,7 @@ def on_the_chip(monkeypatch):
     monkeypatch.setattr(backend, "on_tpu", lambda: True)
     monkeypatch.setattr(moe, "grouped_matmul", lambda rows, w, sizes: jax.lax.ragged_dot(rows, w, sizes))
     monkeypatch.setattr(attention, "latent_attention", interpreted)
+    monkeypatch.setattr(attention, "paged_attention", paged)
     monkeypatch.setattr(attention, "masked_attention", never)
     monkeypatch.setattr(attention, "MASKED_ACC_BYTES", 4 * 16 * (CFG.v_dim + 256) * 4)
     jax.clear_caches()
@@ -215,14 +251,23 @@ def test_the_chips_kernel_gives_the_chunks_rows(program, chunked, on_the_chip):
 
 def test_on_the_chip_a_decode_call_stays_absorbed_and_a_chunk_counts_its_live_slots(
         program, chunked, on_the_chip):
-    """Under the chip's answer a decode call is the program it was (no kernel,
-    its pairs counted as absorbed), and a second chunk behind the first counts
-    every live slot of the lane through ``W_kvb``, and no pair of padding."""
+    """Under the chip's answer a decode call through the block table stays absorbed
+    (its pairs counted so) and attends in ``paged_attention``, once a layer, over the
+    one arena as it lies and no value arena, the own row's first ``kv_rank`` features
+    its value; a table entry past the lane's pages names NaN and is never fetched.
+    A second chunk behind the first counts every live slot of the lane through
+    ``W_kvb``, and no pair of padding."""
     extend, tokens, _, held, _ = chunked
-    want, _, _, counted = extend(program, tokens[:, 24:], jnp.full((1,), 24, jnp.int32), held)
+    lengths = jnp.full((1,), 24, jnp.int32)
+    want, _, row, counted = extend(program, tokens[:, 24:], lengths, held)
     chip = CFG.make_extend_fn()
-    one, _, _, counters = chip(program, tokens[:, 24:], jnp.full((1,), 24, jnp.int32), held)
-    assert not on_the_chip
+    arena, table = _paged(held, np.nan)
+    one, _, own, counters = chip(program, tokens[:, 24:], lengths, arena, table=table)
+    h = CFG.num_heads
+    assert set(on_the_chip) == {("paged", (
+        (1, 1, h, ROW), arena.shape, None, (1, 5), (1, ROW), (1, RANK)))}
+    on_the_chip.clear()
+    np.testing.assert_array_equal(np.asarray(own)[0], np.asarray(row)[0])   # layer 0's, to the bit
     assert _named(counters) == _named(counted)
     assert _named(counters)["mla_pairs_absorbed"] == CFG.num_layers * 25
     assert _named(counters)["mla_pairs_expanded"] == _named(counters)["mla_rows_expanded"] == 0
