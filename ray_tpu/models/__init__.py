@@ -3,8 +3,8 @@
 ``gpt`` is imported here; the served and trained architectures beside it are
 modules of their own, each composed from ``layers`` (and ``moe``) and imported by
 name where a configuration asks for one: ``cohere2_moe``, ``keye_vl2``, ``kimi_k2``,
-``granitemoehybrid``, ``minicpm_sala``, ``mimo_v2_flash``, ``qwen3_next``, ``glm_moe_dsa``
-(serving) and
+``granitemoehybrid``, ``minicpm_sala``, ``mimo_v2_flash``, ``qwen3_next``, ``glm_moe_dsa``,
+``longcat_flash`` (serving) and
 ``lfm2_moe``, ``nemotron_h`` (training)."""
 
 from ray_tpu.models.gpt import (

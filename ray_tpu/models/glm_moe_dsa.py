@@ -61,7 +61,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import layers, moe
-from ray_tpu.ops import attention, backend
+from ray_tpu.ops import attention
 
 
 @dataclasses.dataclass(frozen=True)
@@ -301,11 +301,6 @@ def _make_extend(cfg: GlmMoeDsaConfig, probe: bool):
     rank = cfg.kv_rank
     scale = float(cfg.softmax_scale)
 
-    def _expands(tc):
-        """Whether a call of ``tc`` tokens a lane attends in the expanded form: a
-        chunk on the chip (``kimi_k2.py`` has the arithmetic)."""
-        return tc > 1 and backend.on_tpu()
-
     def _normed(x, p, name):
         return layers.rms_norm(x, p[name]["scale"], cfg.norm_eps)
 
@@ -318,26 +313,11 @@ def _make_extend(cfg: GlmMoeDsaConfig, probe: bool):
 
     @jax.named_scope("extend.attention.latent")
     def _latents(p, hidden, positions, expanded):
-        """The query latent ``c_q`` [b, t, q_rank], the queries and the token's own
-        row [b, t, 1, row_dim]. The queries as they meet a cached row [b, t, heads,
-        row_dim] (in the latent's space, their rotary features behind, zeros), or for
-        the ``expanded`` form as ``W_qb`` leaves them: ``(q_nope, q_rope)``, the second
-        rotated."""
-        c_q = _normed(hidden @ _kernel(p, "q_a"), p, "q_norm").astype(dtype)
-        q = jnp.einsum("btr,rhk->bthk", c_q, _kernel(p, "q_b"))
-        both = hidden @ _kernel(p, "kv_a")
-        c_kv = _normed(both[..., :rank], p, "kv_norm").astype(dtype)
-
-        def row(latent, rotary):
-            spare = jnp.zeros(latent.shape[:-1] + (cfg.row_dim - rank - cfg.rope_dim,), dtype)
-            return jnp.concatenate([latent, rotary, spare], -1)
-
-        if expanded:
-            q = (q[..., :cfg.nope_dim], _rope(q[..., cfg.nope_dim:], positions))
-        else:
-            absorbed = jnp.einsum("bthn,chn->bthc", q[..., :cfg.nope_dim], _kernel(p, "k_up"))
-            q = row(absorbed, _rope(q[..., cfg.nope_dim:], positions))
-        return c_q, q, row(c_kv[:, :, None], _rope(both[:, :, None, rank:], positions))
+        """The query latent ``c_q`` [b, t, q_rank], which the indexer reads too, the
+        queries and the token's own row [b, t, 1, row_dim] (``layers.latent_queries``)."""
+        return layers.latent_queries(
+            p, hidden, positions, _rope, nope_dim=cfg.nope_dim, rank=rank, row_dim=cfg.row_dim,
+            eps=cfg.norm_eps, expanded=expanded)
 
     @jax.named_scope("extend.attention.index")
     def _index_scores(p, hidden, c_q, positions, ic):
@@ -366,7 +346,7 @@ def _make_extend(cfg: GlmMoeDsaConfig, probe: bool):
         past the lane's farthest real query: the same in every layer."""
         b, tc = positions.shape
         cap = kc.shape[1]
-        expanded = _expands(tc)
+        expanded = layers.latent_expands(tc)
         c_q, q, row = _latents(p, hidden, positions, expanded)
         lane = jnp.arange(b)[:, None]
         kc = layers.write_rows(kc, lane, positions, row)
@@ -476,7 +456,7 @@ def _make_extend(cfg: GlmMoeDsaConfig, probe: bool):
         seen = jnp.where(valid, jnp.minimum(positions + 1, cap), 0)
         queries, scored = valid.sum(dtype=jnp.int32), seen.sum(dtype=jnp.int32)
         attended = jnp.minimum(seen, cfg.topk).sum(dtype=jnp.int32)
-        if _expands(tokens.shape[1]):
+        if layers.latent_expands(tokens.shape[1]):
             # every live slot of a lane goes through W_kvb once a layer, selected or not
             slots = jnp.minimum(reads[1], cap).sum(dtype=jnp.int32)
             by_form = (jnp.int32(0), attended, slots)

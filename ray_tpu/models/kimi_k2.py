@@ -72,7 +72,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models import layers, moe
-from ray_tpu.ops import attention, backend
 
 def yarn_mscale(factor: float, mscale: float) -> float:
     return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
@@ -305,70 +304,12 @@ def make_extend_fn(cfg: KimiK2Config):
     scale = float(cfg.softmax_scale)
     freqs = jnp.asarray(cfg.rope_frequencies, f32)
 
-    def _expands(tc):
-        """Whether a call of ``tc`` tokens a lane attends in the expanded form: a
-        chunk on the chip. (Its 512 queries a slot are three times the 171 at
-        which ``W_kvb`` over the slot is paid for; a decode call's one is not.)"""
-        return tc > 1 and backend.on_tpu()
-
     def _normed(x, p, name):
         return layers.rms_norm(x, p[name]["scale"], cfg.norm_eps)
-
-    def _kernel(p, name):
-        return p[name]["kernel"].astype(dtype)
 
     def _rope(x, positions):
         """``x`` [b, t, heads, rope_dim], rotated in float32."""
         return layers.rotary(x.astype(f32), positions, cfg.rope_dim, freqs=freqs).astype(dtype)
-
-    @jax.named_scope("extend.attention.latent")
-    def _latents(p, hidden, positions, expanded):
-        """The queries and the token's own row [b, t, 1, row_dim]. The queries as
-        they meet a cached row [b, t, heads, row_dim] (in the latent's space,
-        their rotary features behind, zeros), or for the ``expanded`` form as
-        ``W_qb`` leaves them: ``(q_nope, q_rope)``, the second rotated."""
-        c_q = _normed(hidden @ _kernel(p, "q_a"), p, "q_norm").astype(dtype)
-        q = jnp.einsum("btr,rhk->bthk", c_q, _kernel(p, "q_b"))
-        both = hidden @ _kernel(p, "kv_a")
-        c_kv = _normed(both[..., :rank], p, "kv_norm").astype(dtype)
-
-        def row(latent, rotary):
-            spare = jnp.zeros(latent.shape[:-1] + (cfg.row_dim - rank - cfg.rope_dim,), dtype)
-            return jnp.concatenate([latent, rotary, spare], -1)
-
-        if expanded:
-            q = (q[..., :cfg.nope_dim], _rope(q[..., cfg.nope_dim:], positions))
-        else:
-            absorbed = jnp.einsum("bthn,chn->bthc", q[..., :cfg.nope_dim], _kernel(p, "k_up"))
-            q = row(absorbed, _rope(q[..., cfg.nope_dim:], positions))
-        return q, row(c_kv[:, :, None], _rope(both[:, :, None, rank:], positions))
-
-    def _through_v_up(p, attended):
-        """The absorbed form's un-absorption: each head's sum of latents [b, t, heads,
-        rank] through its ``W_kvb^V``."""
-        with jax.named_scope("extend.attention.latent"):
-            return jnp.einsum("bthc,chv->bthv", attended, _kernel(p, "v_up"))
-
-    def _over_padded(p, q, expanded, row, positions, visible, live, kc):
-        """The attend over a lane's padded cache ``kc`` [b, cache, 1, row_dim], the call's
-        rows written into it, in the form :func:`_latents` made ``q`` for: [b, t, heads,
-        v_dim]."""
-        kc = layers.write_rows(kc, jnp.arange(kc.shape[0])[:, None], positions, row)
-
-        def attend_block(qb, mask):             # [b, n, heads, row_dim], [b, n, cache]
-            logit = jnp.einsum(
-                "bqhc,bkc->bhqk", qb, kc[:, :, 0], preferred_element_type=f32) * scale
-            weight = jax.nn.softmax(jnp.where(mask[:, None], logit, f32(layers.MASKED)), axis=-1)
-            # over the whole row: what is behind the latent is cut from the
-            # result and not from the cache, which would be copied for it
-            return jnp.einsum("bhqk,bkc->bqhc", weight.astype(dtype), kc[:, :, 0])[..., :rank]
-
-        if expanded:
-            # a head's own key and value, made of each tile of rows inside the kernel
-            return attention.latent_attention(
-                *q, kc[:, :, 0], _kernel(p, "k_up"), _kernel(p, "v_up"), visible, live,
-                scale=scale)
-        return _through_v_up(p, layers.by_query_block(attend_block, q, visible))
 
     @jax.named_scope("extend.attention")
     def _attend(p, hidden, positions, visible, live, kc, paged=None):
@@ -377,21 +318,14 @@ def make_extend_fn(cfg: KimiK2Config):
         ``kc`` is the layer's slab of the padded cache; or, with ``paged`` (the layer's
         index and the lanes' block table: a call of one token a lane), the pool's
         arena itself, read where it lies, not written and not copied."""
-        expanded = _expands(positions.shape[1])
-        q, row = _latents(p, hidden, positions, expanded)
-        if paged is not None and backend.on_tpu():
-            # one K/V head of all the query heads over the lanes' pages and the call's
-            # own row; a row is the key and, in its first ``rank`` features, the value
-            out = _through_v_up(p, attention.paged_attention(
-                q[:, 0, None], kc, None, *paged, positions[:, 0], row[:, 0, 0],
-                row[:, 0, 0, :rank], scale=scale)[:, None, 0])
-        else:
-            # padded caches; or, off the chip, the same contract densely: the table's pages
-            # side by side are the lane's padded cache, to the bit what a gather hands over
-            out = _over_padded(
-                p, q, expanded, row, positions, visible, live,
-                kc if paged is None else layers.table_pages(kc, *paged))
-        return jnp.einsum("bthv,hvd->btd", out, _kernel(p, "o")), row
+        with jax.named_scope("extend.attention.latent"):
+            _, q, row = layers.latent_queries(
+                p, hidden, positions, _rope, nope_dim=cfg.nope_dim, rank=rank,
+                row_dim=cfg.row_dim, eps=cfg.norm_eps,
+                expanded=layers.latent_expands(positions.shape[1]))
+        out = layers.latent_attend(
+            p, q, row, positions, visible, live, kc, paged, rank=rank, scale=scale)
+        return jnp.einsum("bthv,hvd->btd", out, p["o"]["kernel"].astype(dtype)), row
 
     def _experts(p, experts, layer, normed, valid):
         b, tc, d = normed.shape
@@ -456,15 +390,9 @@ def make_extend_fn(cfg: KimiK2Config):
             body, x, (scanned, jnp.arange(cfg.expert_layers, dtype=jnp.int32)))
         logits, x = layers.rms_head(
             x, params["ln_f"]["scale"], cfg.norm_eps, params["head"]["kernel"], dtype, last)
-        seen = jnp.where(valid, jnp.minimum(positions + 1, cap), 0)
-        queries, pairs = valid.sum(dtype=jnp.int32), seen.sum(dtype=jnp.int32)
-        if _expands(tokens.shape[1]):
-            # every live slot of a lane goes through W_kvb once a layer
-            slots = jnp.minimum(reads[1], cap).sum(dtype=jnp.int32)
-            by_form = (jnp.int32(0), pairs, slots)
-        else:
-            by_form = (pairs, jnp.int32(0), jnp.int32(0))
-        attended = cfg.num_layers * jnp.stack([queries, *by_form])
+        attended = layers.latent_counted(
+            cfg.num_layers, positions, valid, cap, reads[1],
+            layers.latent_expands(tokens.shape[1]))
         return (
             logits, x, jnp.concatenate([jnp.stack(rows), scanned]),
             jnp.concatenate([routed.sum(0), attended]))

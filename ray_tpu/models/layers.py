@@ -1,13 +1,14 @@
 """The kit the architecture files compose (``gpt.py``, ``cohere2_moe.py``,
 ``keye_vl2.py``, ``kimi_k2.py``, ``granitemoehybrid.py``, ``lfm2_moe.py``,
-``minicpm_sala.py``, ``glm_moe_dsa.py``, ``mimo_v2_flash.py``, whose per-sequence state is made of
+``minicpm_sala.py``, ``glm_moe_dsa.py``, ``longcat_flash.py``, ``mimo_v2_flash.py``, whose per-sequence state is made of
 cached rows: a window's K and V; ``qwen3_next.py``, whose norms are zero-centred: it
 hands :func:`rms_norm` the scale ``1 + g``; ``nemotron_h.py``, which also takes
 ``granitemoehybrid.ssm_scan``, the one function an architecture has from a sibling:
 ROADMAP.md D19 moves the chunked recurrences here): every
 decision they share, written once. Plain functions of their arguments: no
 configuration, no ``jit`` and no scope of their own but ``extend.logits`` round
-:func:`rms_head`, because the readers of a device trace key on the path of scopes
+:func:`rms_head` and ``extend.attention.latent`` round :func:`latent_attend`'s
+un-absorption, because the readers of a device trace key on the path of scopes
 the *caller* builds (``jit(extend)/extend.attention/...``). An architecture imports
 from here and from ``moe.py``, never from a sibling. What only one file does, or
 two do in different operations, stays in its file (``CHANGES.md``, PR 46).
@@ -182,14 +183,8 @@ def paged_attend(q, k, v, k_pages, v_pages, at, table, positions, visible, scale
     return plain_attend(q, taken(k_pages, k), taken(v_pages, v), visible, scale)
 
 
-# -- a learned indexer's selection (``keye_vl2.py``, ``glm_moe_dsa.py``) -------------
+# -- latent attention (``kimi_k2.py``, ``longcat_flash.py``; ``glm_moe_dsa.py``'s queries) --
 
-#: what an indexer counts over the real queries of a device call, summed over
-#: the layers: queries that passed an indexer, live causal query-key pairs it
-#: scored, keys attended (``min(topk, visible)`` a query), and cache slots of
-#: the call's padded caches that at least one query selected
-SPARSE_COUNTERS = (
-    "sparse_queries", "sparse_keys_scored", "sparse_keys_attended", "sparse_slots_read")
 
 #: what latent attention counts over the real queries of a device call, summed
 #: over the layers (``kimi_k2.py``, ``glm_moe_dsa.py``): queries, query-key pairs
@@ -198,6 +193,122 @@ SPARSE_COUNTERS = (
 #: every lane of a chunk on the chip, 0 for any other call
 MLA_COUNTERS = ("mla_queries", "mla_pairs_absorbed", "mla_pairs_expanded", "mla_rows_expanded")
 
+
+def latent_expands(tc: int) -> bool:
+    """Whether a call of ``tc`` tokens a lane attends its cached latent rows in the
+    expanded form: a chunk on the chip. (Its 512 queries a slot are three times the 171
+    at which ``W_kvb`` over the slot is paid for; a decode call's one is not.)"""
+    return tc > 1 and backend.on_tpu()
+
+
+def latent_row(latent, rotary, row_dim: int):
+    """``[latent ; rotary ; zeros]`` up to ``row_dim`` features: a cached row, or a query
+    as it meets one in the absorbed form."""
+    spare = jnp.zeros(
+        latent.shape[:-1] + (row_dim - latent.shape[-1] - rotary.shape[-1],), latent.dtype)
+    return jnp.concatenate([latent, rotary, spare], -1)
+
+
+def latent_queries(p, hidden, positions, rotate, *, nope_dim: int, rank: int, row_dim: int,
+                   eps: float, expanded: bool, q_scale=None, kv_scale=None):
+    """Latent attention's projections of the normed ``hidden`` [b, t, d] under the
+    parameters ``p`` (``q_a``, ``q_norm``, ``q_b``, ``kv_a``, ``kv_norm``, ``k_up``):
+    ``(c_q, q, row)``. ``c_q`` [b, t, q_rank] is the normed query latent; ``row`` [b, t, 1,
+    row_dim] the token's own cached row, the normed latent and the rotated rotary key
+    behind it (``rotate(x, positions)``, the model's own rotation); ``q`` the queries as
+    they meet a cached row [b, t, heads, row_dim] (``q_nope`` through ``W_kvb^K`` into the
+    latent's space, the rotated rotary features behind, zeros), or for the ``expanded``
+    form as ``W_qb`` leaves them: ``(q_nope, q_rope)``, the second rotated. ``q_scale``
+    multiplies the normed query latent and ``kv_scale`` the normed cached one, in float32
+    before the cast (``longcat_flash.py``'s two constants: folded into what is cached,
+    whichever form reads it). The caller's scope (``extend.attention.latent``)."""
+    dtype = hidden.dtype
+
+    def kernel(name):
+        return p[name]["kernel"].astype(dtype)
+
+    def normed(x, name, by):
+        x = rms_norm(x, p[name]["scale"], eps)
+        return (x if by is None else x * jnp.float32(by)).astype(dtype)
+
+    c_q = normed(hidden @ kernel("q_a"), "q_norm", q_scale)
+    q = jnp.einsum("btr,rhk->bthk", c_q, kernel("q_b"))
+    both = hidden @ kernel("kv_a")
+    c_kv = normed(both[..., :rank], "kv_norm", kv_scale)
+    if expanded:
+        q = (q[..., :nope_dim], rotate(q[..., nope_dim:], positions))
+    else:
+        absorbed = jnp.einsum("bthn,chn->bthc", q[..., :nope_dim], kernel("k_up"))
+        q = latent_row(absorbed, rotate(q[..., nope_dim:], positions), row_dim)
+    return c_q, q, latent_row(
+        c_kv[:, :, None], rotate(both[:, :, None, rank:], positions), row_dim)
+
+
+def latent_attend(p, q, row, positions, visible, live, kc, paged, *, rank: int, scale: float):
+    """The attend of :func:`latent_queries`' ``q`` (a pair: the expanded form) and the
+    call's own ``row`` over a lane's cached rows, before ``W_o``: [b, t, heads, v_dim].
+    ``kc`` is the layer's slab of the padded cache [b, cache, 1, row_dim], into which the
+    call's rows are written; or, with ``paged`` (the layer's index and the lanes' block
+    table: a call of one token a lane), the pool's arena itself, read where it lies, not
+    written and not copied: on the chip ``ops/attention.paged_attention`` (one K/V head
+    of all the query heads over the lanes' pages and the call's own row; a row is the key
+    and, in its first ``rank`` features, the value), off it the table's pages side by side,
+    to the bit what a gather hands over. ``visible`` [b, t, cache] is what each query may
+    read, ``live`` [b] a bound past the lane's farthest real query. A chunk's expanded
+    form is the kernel ``ops/attention.latent_attention`` (a head's own key and value,
+    made of each tile of rows inside it); the absorbed form's un-absorption through
+    ``W_kvb^V`` stands under ``extend.attention.latent``."""
+    dtype = row.dtype
+
+    def through_v_up(attended):             # each head's sum of latents [b, t, heads, rank]
+        with jax.named_scope("extend.attention.latent"):
+            return jnp.einsum("bthc,chv->bthv", attended, p["v_up"]["kernel"].astype(dtype))
+
+    if paged is not None and backend.on_tpu():
+        return through_v_up(attention.paged_attention(
+            q[:, 0, None], kc, None, *paged, positions[:, 0], row[:, 0, 0],
+            row[:, 0, 0, :rank], scale=scale)[:, None, 0])
+    if paged is not None:
+        kc = table_pages(kc, *paged)
+    kc = write_rows(kc, jnp.arange(kc.shape[0])[:, None], positions, row)
+    if isinstance(q, tuple):
+        return attention.latent_attention(
+            *q, kc[:, :, 0], p["k_up"]["kernel"].astype(dtype), p["v_up"]["kernel"].astype(dtype),
+            visible, live, scale=scale)
+
+    def attend_block(qb, mask):             # [b, n, heads, row_dim], [b, n, cache]
+        logit = jnp.einsum(
+            "bqhc,bkc->bhqk", qb, kc[:, :, 0], preferred_element_type=jnp.float32) * scale
+        weight = jax.nn.softmax(jnp.where(mask[:, None], logit, jnp.float32(MASKED)), axis=-1)
+        # over the whole row: what is behind the latent is cut from the
+        # result and not from the cache, which would be copied for it
+        return jnp.einsum("bhqk,bkc->bqhc", weight.astype(dtype), kc[:, :, 0])[..., :rank]
+
+    return through_v_up(by_query_block(attend_block, q, visible))
+
+
+def latent_counted(sites: int, positions, valid, cap: int, live, expanded: bool):
+    """:data:`MLA_COUNTERS` of a call whose every one of ``sites`` attention sites reads
+    every visible row: int32 [4]."""
+    seen = jnp.where(valid, jnp.minimum(positions + 1, cap), 0)
+    queries, pairs = valid.sum(dtype=jnp.int32), seen.sum(dtype=jnp.int32)
+    if expanded:
+        # every live slot of a lane goes through W_kvb once a site
+        slots = jnp.minimum(live, cap).sum(dtype=jnp.int32)
+        by_form = (jnp.int32(0), pairs, slots)
+    else:
+        by_form = (pairs, jnp.int32(0), jnp.int32(0))
+    return sites * jnp.stack([queries, *by_form])
+
+
+# -- a learned indexer's selection (``keye_vl2.py``, ``glm_moe_dsa.py``) -------------
+
+#: what an indexer counts over the real queries of a device call, summed over
+#: the layers: queries that passed an indexer, live causal query-key pairs it
+#: scored, keys attended (``min(topk, visible)`` a query), and cache slots of
+#: the call's padded caches that at least one query selected
+SPARSE_COUNTERS = (
+    "sparse_queries", "sparse_keys_scored", "sparse_keys_attended", "sparse_slots_read")
 
 def _one_zero(scores, visible):
     """``scores`` with -0 as +0 (equal, so a tie) and -inf where not visible."""

@@ -1,29 +1,45 @@
-"""Mixture-of-experts MLP with expert parallelism over the ``ep`` mesh axis.
+"""Expert layers: a capacity-based one for the ``ep`` mesh axis, and the dropless
+share of an expert-parallel deployment that the served and trained models run.
 
-The reference has no MoE at all (SURVEY.md §2.6 EP row: absent); this is a
-TPU-first implementation of the GShard/Switch dispatch: top-k routing with a
-STATIC per-expert capacity (XLA-friendly — no dynamic shapes), dispatch and
-combine as einsums whose expert dimension is sharded over ``ep`` so XLA
-inserts the all-to-all, and a load-balancing auxiliary loss sown into the
-``losses`` collection (summed per layer by the scanned block stack).
+**The capacity-based layer** (:class:`MoeMlp`; the reference has no MoE at all,
+SURVEY.md §2.6 EP row: absent) is a TPU-first implementation of the GShard/Switch
+dispatch: top-k routing with a STATIC per-expert capacity (XLA-friendly — no dynamic
+shapes), dispatch and combine as einsums whose expert dimension is sharded over ``ep``
+so XLA inserts the all-to-all, and a load-balancing auxiliary loss sown into the
+``losses`` collection (summed per layer by the scanned block stack). Expert weights
+carry the ("expert", "embed", "mlp") logical axes: ep shards the expert dim, tp can
+still shard the mlp dim inside each expert.
 
-Expert weights carry the ("expert", "embed", "mlp") logical axes: ep shards
-the expert dim, tp can still shard the mlp dim inside each expert.
+**The dropless layer**, for one chip's share of an expert-parallel deployment, or every
+expert on one chip. A router scores every routed expert; there are four, by what the
+models publish:
 
-Beside it, for serving (``models/cohere2_moe.py``, ``models/kimi_k2.py``,
-``models/keye_vl2.py`` and, beside a shared MLP in every layer of a period of Mamba-2
-and attention mixers, ``models/granitemoehybrid.py``) and for the train step (``models/lfm2_moe.py``, and
-``models/nemotron_h.py``, whose experts have **no gate**: ``W_down relu(W_up x)^2``, two
-matrices an expert, :func:`relu_squared` for :func:`trained_experts_ffn`'s
-``activation``; both under ``jax.grad``): a **dropless** layer for one chip's share of an expert-parallel
-deployment, or every expert on one chip. :func:`sigmoid_top_k`, :func:`softmax_top_k` or
-:func:`sigmoid_bias_top_k` scores every routed expert, :func:`held_experts_ffn` is told which experts
-live here and computes their part of the result for the tokens routed to
-them (:func:`trained_experts_ffn` under ``jax.grad``): the token-expert pairs are sorted by expert and run through a grouped
-matmul (:func:`grouped_matmul`: one kernel that walks the groups and reads an
-expert's weights only if it has rows). No capacity, no dropped token; shapes
-are static from the worst case, in which every choice of every token is held
-here. What the absent experts would add is left out.
+* :func:`sigmoid_top_k` — sigmoid scores, the ``k`` largest over their sum
+  (``models/cohere2_moe.py``);
+* :func:`softmax_top_k` — a softmax over all experts, the ``k`` largest over their sum
+  (``norm_topk_prob``: ``models/keye_vl2.py``, ``models/qwen3_next.py``, and
+  ``models/granitemoehybrid.py`` beside a shared MLP in every layer of a period of
+  Mamba-2 and attention mixers);
+* :func:`sigmoid_bias_top_k` — sigmoid scores, a bias that chooses and does not weigh,
+  the chosen scores over their sum times a scale (``noaux_tc``: ``models/kimi_k2.py``,
+  ``models/glm_moe_dsa.py``, ``models/mimo_v2_flash.py``, and under ``jax.grad``
+  ``models/lfm2_moe.py`` and ``models/nemotron_h.py``, whose experts have **no gate**:
+  ``W_down relu(W_up x)^2``, two matrices an expert, :func:`relu_squared` for
+  :func:`trained_experts_ffn`'s ``activation``);
+* :func:`softmax_bias_top_k` — a softmax over all outputs, a bias that chooses and does
+  not weigh, the chosen probabilities themselves times a scale, **not** over their sum
+  (``models/longcat_flash.py``), over a router whose last outputs are **zero-compute
+  experts**: experts with no weights that give the token back
+  (:func:`zero_experts_part`).
+
+:func:`held_experts_ffn` is told which experts live here and computes their part of the
+result for the tokens routed to them (:func:`trained_experts_ffn` under ``jax.grad``):
+the token-expert pairs are sorted by expert and run through a grouped matmul
+(:func:`grouped_matmul`: one kernel that walks the groups and reads an expert's weights
+only if it has rows). No capacity, no dropped token; shapes are static from the worst
+case, in which every choice of every token is held here. What the absent experts would
+add is left out. A pair on a zero-compute expert is of no group, held or absent: every
+chip adds that part for its own tokens (:func:`zero_experts_part`), once.
 """
 
 from __future__ import annotations
@@ -104,6 +120,35 @@ def sigmoid_bias_top_k(h: jax.Array, router: jax.Array, bias: jax.Array, k: int,
     return weights * scale, experts
 
 
+def softmax_bias_top_k(h: jax.Array, router: jax.Array, bias: jax.Array, k: int, scale: float):
+    """Route tokens ``h`` [n, d] over every output of the ``router`` [d, R]: a float32
+    softmax over all ``R``, the ``k`` with the largest ``p + bias`` chosen (``bias`` [R],
+    ``e_score_correction_bias``: it chooses and does not weigh), and the chosen
+    probabilities **themselves** times ``scale`` (``routed_scaling_factor``) for weights:
+    not divided by their sum, so a token's weights add up to ``scale`` times the mass its
+    choices hold, not to ``scale`` (``models/longcat_flash.py``, whose router's last
+    outputs are experts with no weights: :func:`zero_experts_part`). Returns ``(weights
+    [n, k] float32, experts [n, k] int32)``."""
+    scores = jax.nn.softmax(_logits(h, router), axis=-1)
+    _, experts = jax.lax.top_k(scores + bias.astype(scores.dtype), k)
+    return jnp.take_along_axis(scores, experts, axis=-1) * scale, experts.astype(jnp.int32)
+
+
+def zero_experts_part(x, weights, experts, valid, routed: int):
+    """The part of a routed layer that its **zero-compute experts** give: a pick whose
+    index is ``routed`` or more (``experts`` [n, k] over the router's ``R > routed``
+    outputs) names an expert with no weights, the identity, whose result is the token
+    ``x`` [n, d] itself times the pick's weight. Such an expert lives on no chip and on
+    every chip: each computes this part for the tokens it owns, so of the shares of an
+    expert-parallel layer it is counted once, like a shared expert.
+    :func:`held_experts_ffn` sorts such a pair into no group, whatever the offset: it is
+    neither held nor absent. Returns ``(y [n, d] float32, the pairs of real tokens that
+    fell on such an expert, int32)``; ``valid`` [n] marks the real tokens."""
+    zero = (experts >= routed) & valid[:, None]
+    weight = jnp.where(zero, weights, 0.0).sum(-1, keepdims=True)
+    return weight * x.astype(jnp.float32), zero.sum(dtype=jnp.int32)
+
+
 #: (rows, contraction, columns) tile of the TPU's grouped matmul. Measured on a
 #: v5e at Command A+'s widths, one layer's 16 held experts (PERF.md, PR 28):
 #: 2.7 ms for a 256-token chunk and 1.6 ms for 8 decode tokens, where
@@ -180,7 +225,10 @@ def held_experts_ffn(x, weights, experts, valid, wi, wo, offset: int = 0, layer=
 
     Every pair whose expert is held is computed: the pairs are sorted by
     expert, those of absent experts and of padding last and in no group, so
-    the grouped matmul neither computes them nor reads weights for them."""
+    the grouped matmul neither computes them nor reads weights for them. A pair
+    that names a zero-compute expert (an index past the routed experts, which no
+    share's ``offset .. offset + E - 1`` reaches: :func:`zero_experts_part`) sorts
+    there too, behind every group: its part is not this function's."""
     n, k = experts.shape
     num_held, f = wo.shape[-3], wo.shape[-2]
     local = experts - offset

@@ -14,7 +14,10 @@ many layers a token is cached (``cached_layers``: in which); ``models/cohere2_mo
 ``models/minicpm_sala.py``, ``models/mimo_v2_flash.py``, ``models/qwen3_next.py``,
 ``models/glm_moe_dsa.py``: two arenas a token, a latent row all heads share and an
 indexer's key, of which a query reads the rows its indexer selects; nothing new in this
-file). A state need not be a recurrence's: ``mimo_v2_flash.py``'s is made of **cached rows**,
+file; ``models/longcat_flash.py``: a layer of two attention sub-blocks, so a token is
+cached in **more** slabs than the model has layers, ``cache_layers`` = 2 x ``num_layers``,
+and the pool's arenas, the gathers and the page-back have that many: nothing new here
+either). A state need not be a recurrence's: ``mimo_v2_flash.py``'s is made of **cached rows**,
 the newest 128 rows of K and V of each layer that sees a window and nothing else, which
 the engine keeps, hands over, snapshots and restores as it does any state, and never
 pages. Nor need a recurrence be additive: ``qwen3_next.py``'s gated delta rule writes
@@ -57,8 +60,9 @@ serving setup from PAPERS.md):
   built.
 * a decode call attends through the block table — where a model's ``extend``
   offers ``table=`` (:func:`reads_pages`: ``models/mimo_v2_flash.py``,
-  ``models/qwen3_next.py``, ``models/granitemoehybrid.py``, ``models/kimi_k2.py``,
-  whose page holds latent rows: key and value in one arena), a call of one token
+  ``models/qwen3_next.py``, ``models/granitemoehybrid.py``, ``models/kimi_k2.py`` and
+  ``models/longcat_flash.py``, whose pages hold latent rows: key and value in one
+  arena), a call of one token
   a lane is handed the pool's arenas themselves (not donated: the page-back
   donates them after, in launch order) and the ``table`` section of the operand
   buffer it already uploads, and runs no gather: each full-attention layer reads a
@@ -471,7 +475,8 @@ class KVBlockPool:
     ``extend`` (which is handed them, donated, with its lanes' slots, and whose
     returned arenas take their place) and the copy of :func:`_state_programs`
     alone. Slot 0 is never handed out: a padded lane's. And where its tokens are
-    cached in some layers only (``cache_layers``), the arenas hold those."""
+    cached in some layers only, or in several places a layer (``cache_layers``: fewer
+    than ``num_layers``, or more), the arenas hold that many slabs."""
 
     def __init__(self, cfg, *, num_blocks: int = 128, block_size: int = 16,
                  state_slots: int = 0, deployment: str = "llm"):

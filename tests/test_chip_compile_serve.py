@@ -25,8 +25,8 @@ import pytest
 from chip_compile_helpers import (  # noqa: F401 — shaped and v5e are fixtures
     HBM_BYTES, _device_bytes, _gptj, shaped, v5e)
 from ray_tpu.models import (
-    cohere2_moe, glm_moe_dsa, gpt, granitemoehybrid, keye_vl2, kimi_k2, layers, mimo_v2_flash,
-    minicpm_sala, qwen3_next)
+    cohere2_moe, glm_moe_dsa, gpt, granitemoehybrid, keye_vl2, kimi_k2, layers, longcat_flash,
+    mimo_v2_flash, minicpm_sala, qwen3_next)
 from ray_tpu.serve import llm
 
 
@@ -1156,13 +1156,86 @@ def test_glm_5_share_extend_compiles_at_its_largest_shapes(shaped, form, built_f
     assert _device_bytes(compiled) + pool + per_token * lanes * cap < HBM_BYTES
 
 
+def _longcat_share():
+    """The served cut of LongCat-Flash-Chat (four layers of two sub-blocks, 16 of 512
+    routed experts beside the 256 zero-compute outputs, an eighth of the vocabulary) and
+    its engine sizes, from the configuration's file."""
+    import json
+
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "configs",
+            "longcat-flash-chat-serve-ep32.json")) as f:
+        config = json.load(f)
+    return longcat_flash.LongcatFlashConfig(
+        vocab_size=config["vocab_size"], num_layers=config["num_layers"],
+        num_experts=config["n_routed_experts"]), config
+
+
+@pytest.mark.parametrize("form", ["decode", "prefill"])
+def test_longcat_flash_share_extend_compiles_at_its_largest_shapes(shaped, form, built_for_tpu):
+    """One chip's share of LongCat-Flash-Chat at its published widths (10.35 GB of
+    weights) over the largest cache bucket: a decode call of **sixteen** lanes is handed
+    the pool's arena of eight slabs (two a layer, not donated) and its block table and
+    attends in the absorbed form in ``paged_attention``, one call site a sub-block, so it
+    holds no score over the bucket and nothing of a padded cache's size; a prefill chunk
+    attends in the expanded form in ``latent_attention``, one call site a sub-block, over
+    rows that hold the scaled latent (no kernel knows of ``mla_scale_kv_lora``); the held
+    experts run in the grouped matmul's kernel and the zero-compute picks in none; neither
+    copies a layer's experts (1.2 GB) or holds more than the configuration's file states."""
+    built_for_tpu(True)     # the chip's grouped matmul and attention kernels
+    cfg, config = _longcat_share()
+    engine, stated = config["engine"], config["compiled_bytes_per_device"]
+    cap, lanes = engine["cache_buckets"][-1], engine["lane_buckets"][-1]
+    b, tc = (lanes, 1) if form == "decode" else (1, engine["prefill_token_buckets"][-1])
+    assert (lanes, cap) == (16, 8192) and stated[form]["shape"] == [b, tc, cap]
+    compiled = _kimi_extend_at(shaped, cfg, engine, b, tc, cap)
+    text = compiled.as_text()
+    assert text.startswith(f"HloModule jit_extend_{form}_{b}x{tc}x{cap},")
+    kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    experts = [line for line in kernels if "extend.moe.experts" in line]
+    assert len(experts) == 2 and not any("extend.moe.zero" in line for line in kernels)
+    if form == "prefill":
+        attends = [line for line in kernels if line not in experts]
+        assert len(attends) == 2 and all(
+            "/extend.attention/latent_attention/" in line for line in attends)
+
+        def over_cache(types):
+            return max((
+                math.prod(map(int, dims.split(",")))
+                for dims in re.findall(rf"(?:{types})\[([0-9,]+)\]", text)
+                if str(cap) in dims.split(",")), default=0)
+
+        assert over_cache("f32") < cfg.num_heads * layers.QUERY_BLOCK * cap
+        assert over_cache("f32|bf16") < cap * cfg.num_heads * cfg.v_dim
+    else:
+        assert _attends_through_the_table(text, cfg, engine, sites=2) == experts
+        assert f"f32[{lanes},{cfg.num_heads},1,{cap}]" not in text
+        assert not re.search(rf"bf16\[(\d+,)*{cap},(\d+,)*{cfg.row_dim}\]", text)
+    memory = compiled.memory_analysis()
+    per_token = 2 * cfg.cache_layers * sum(h * d for h, d in cfg.cache_arrays)
+    assert per_token == 8 * 1280
+    pool = per_token * engine["num_blocks"] * engine["block_size"]
+    handed = pool if form == "decode" else per_token * b * cap
+    weights = memory.argument_size_in_bytes - handed
+    assert 10.34e9 < weights < 10.36e9 and abs(weights - 2 * cfg.num_params()) < 2**20
+    assert memory.argument_size_in_bytes == stated[form]["argument"]
+    assert _holds_no_more_than_stated(memory, stated[form])
+    assert memory.temp_size_in_bytes < (2**23 if form == "decode" else 0.4e9)
+    # beside the pool (a decode call's arguments hold it) and the caches of a chunk in flight
+    assert _device_bytes(compiled) + (0 if form == "decode" else pool) + (
+        per_token * engine["prefill_lanes"] * cap) < HBM_BYTES
+    # the fullest device holds what a deployment would: weights and pool past 60 %
+    assert 2 * cfg.num_params() + pool > 0.70 * HBM_BYTES
+
+
 @pytest.mark.parametrize(
     "name,extends,pagings",
     [("gptj-6b-serve", 12, 11), ("command-a-plus-serve-ep8", 20, 25),
      ("keye-vl2-30b-a3b-serve", 16, 19), ("kimi-k2-instruct-serve-ep32", 16, 11),
      ("granite-4.0-h-micro-serve", 20, 13), ("granite-4.0-h-small-serve-ep2", 20, 13),
      ("minicpm-sala-serve-pp2", 16, 19), ("mimo-v2-flash-serve-ep16", 20, 13),
-     ("qwen3-next-80b-a3b-serve-ep4", 18, 14), ("glm-5-serve-ep16", 16, 19)],
+     ("qwen3-next-80b-a3b-serve-ep4", 18, 14), ("glm-5-serve-ep16", 16, 19),
+     ("longcat-flash-chat-serve-ep32", 18, 14)],
 )
 def test_a_serve_configuration_compiles_no_more_programs_than_it_did(name, extends, pagings):
     """The programs an engine with the configuration's buckets compiles (a tiny
@@ -1172,7 +1245,7 @@ def test_a_serve_configuration_compiles_no_more_programs_than_it_did(name, exten
     ``warm()`` compiles one ``extend`` per shape of ``extend_shapes()``
     (``tests/test_llm.py`` holds it to that). ``setup_s`` is mostly these
     compiles. A model whose decode call reads pages (``llm.reads_pages``: MiMo,
-    Qwen3-Next, both granites, and since PR 66 Kimi K2) compiles a gather for its
+    Qwen3-Next, both granites, since PR 66 Kimi K2, LongCat-Flash) compiles a gather for its
     chunks' one lane alone: twelve programs fewer than the 25 and 26 they had (PR 61;
     Kimi's eight ``gather_{2,4}x<cap>`` fewer than 19), and no ``gather_<b>x<cap>``
     that only a decode call would have used."""
@@ -1188,6 +1261,7 @@ def test_a_serve_configuration_compiles_no_more_programs_than_it_did(name, exten
         else keye_vl2.keye_vl2_nano(max_seq_len=context) if name.startswith("keye")
         else kimi_k2.kimi_k2_nano(max_seq_len=context) if name.startswith("kimi")
         else glm_moe_dsa.glm_moe_dsa_nano(max_seq_len=context) if name.startswith("glm")
+        else longcat_flash.longcat_flash_nano(max_seq_len=context) if name.startswith("longcat")
         else granitemoehybrid.granite_hybrid_nano(
             max_seq_len=context, ssm_chunk=256, router_experts=8 * name.count("small"))
         if name.startswith("granite")
@@ -1208,7 +1282,7 @@ def test_a_serve_configuration_compiles_no_more_programs_than_it_did(name, exten
         ) <= pagings
         gathers = {f"gather_{b}x{cap}" for b, tc, cap in eng.extend_shapes() if not eng._paged(tc)}
         assert set(programs.gather.names()) == gathers
-        adopts = name.split("-")[0] in ("granite", "mimo", "qwen3", "kimi")
+        adopts = name.split("-")[0] in ("granite", "mimo", "qwen3", "kimi", "longcat")
         assert llm.reads_pages(eng._extend) == adopts
         assert (gathers == {f"gather_1x{cap}" for cap in sizes["cache_buckets"]}) == adopts
     finally:

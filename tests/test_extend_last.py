@@ -11,8 +11,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import (
-    cohere2_moe, glm_moe_dsa, gpt, granitemoehybrid, keye_vl2, kimi_k2, mimo_v2_flash,
-    minicpm_sala, qwen3_next)
+    cohere2_moe, glm_moe_dsa, gpt, granitemoehybrid, keye_vl2, kimi_k2, longcat_flash,
+    mimo_v2_flash, minicpm_sala, qwen3_next)
 from ray_tpu.serve import batching, llm
 
 LANES, TOKENS, CACHE, SLOTS = 2, 16, 32, 4
@@ -28,6 +28,7 @@ ARCHITECTURES = {
     "mimo_v2_flash": mimo_v2_flash.mimo_v2_flash_nano,
     "qwen3_next": qwen3_next.qwen3_next_nano,
     "glm_moe_dsa": glm_moe_dsa.glm_moe_dsa_nano,
+    "longcat_flash": longcat_flash.longcat_flash_nano,
 }
 
 

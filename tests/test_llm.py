@@ -14,7 +14,8 @@ import pytest
 import ray_tpu
 from ray_tpu import serve
 from ray_tpu.models import (
-    cohere2_moe, glm_moe_dsa, gpt, granitemoehybrid, keye_vl2, kimi_k2, mimo_v2_flash, qwen3_next)
+    cohere2_moe, glm_moe_dsa, gpt, granitemoehybrid, keye_vl2, kimi_k2, longcat_flash,
+    mimo_v2_flash, qwen3_next)
 from ray_tpu.serve import batching, llm
 from ray_tpu.serve.llm import (
     LANE_BUCKETS,
@@ -958,6 +959,8 @@ _READ_PAGES = {
     "granite-4h-experts": lambda: granitemoehybrid.granite_hybrid_nano(router_experts=8),
     # no state: one arena of latent rows, a row the key and in its first features the value
     "kimi-k2": kimi_k2.kimi_k2_nano,
+    # two such rows a layer: an arena of more slabs than the model has layers
+    "longcat-flash": longcat_flash.longcat_flash_nano,
 }
 _PAGED = dict(
     num_blocks=64, block_size=8, prefill_chunk=16, prefill_lanes=1, lane_buckets=(1, 2, 4),
@@ -1021,6 +1024,41 @@ def test_a_decode_call_through_the_block_table_decodes_what_the_gather_decodes(n
     for key in ("n", "lanes_used", "lane_slots", "cache_tokens", "cache_slots"):
         assert calls["decode"][key] == plain["calls"]["decode"][key], key
     assert {k: stats[k] for k in cfg.counters} == {k: plain[k] for k in cfg.counters}
+
+
+def test_sixteen_lanes_decode_through_the_table_over_latent_pages():
+    """Sixteen sequences of unlike lengths over one arena of latent rows (LongCat-Flash's:
+    two slabs a layer): once all have their prompt in, a decode call carries all sixteen
+    through ``table=`` (``extend_decode_16x1x64``, no gather: sixteen lanes' tables of up to
+    eight pages each), and every sequence gets the tokens it gets with the engine to
+    itself."""
+    cfg = longcat_flash.longcat_flash_nano()
+    eng = LLMEngine(cfg, **{**_PAGED, "num_blocks": 160, "lane_buckets": (1, 2, 4, 8, 16)})
+    assert eng._reads_pages and eng.pool.layers == cfg.cache_layers == 6
+    asks = [
+        {"prompt": _prompt(40 + i, 6 + 2 * i), "max_new_tokens": 20, "return_logits": True}
+        for i in range(16)]
+    alone = []
+    for ask in asks[::5]:
+        seq = batching._Sequence(dict(ask))
+        _drive(eng, [seq])
+        alone.append(seq._result)
+    before = eng.stats()
+    seqs = [batching._Sequence(dict(ask)) for ask in asks]
+    _drive(eng, seqs)
+    after = eng.stats()
+    name = _extend_name(16, 1, 64)
+    assert name == "extend_decode_16x1x64" and name not in before["programs"]
+    assert after["programs"][name]["n"] > 0
+    decode = {
+        k: after["calls"]["decode"][k] - before["calls"]["decode"][k]
+        for k in ("n", "paged", "lanes_used", "lane_slots")}
+    assert decode["paged"] == decode["n"] and decode["lanes_used"] == 16 * 19
+    assert decode["lanes_used"] / decode["n"] > 6
+    for got, want in zip(seqs[::5], alone):
+        assert got._result["tokens"] == want["tokens"]
+        np.testing.assert_allclose(got._result["logits"], want["logits"], rtol=2e-4, atol=2e-5)
+    assert eng.pool.in_use() == eng.stats()["prefix_cached_blocks"]
 
 
 def test_a_deployment_takes_its_executing_slots_from_what_its_callable_runs_at_once():
