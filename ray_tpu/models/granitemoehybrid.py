@@ -57,6 +57,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any
 
 import jax
@@ -782,6 +783,367 @@ def ssm_scan(state, x, dt, a, b, c, chunk: int, dtype, *, heads: int = SSM_SCAN_
     y, last = _ssm_scan_blocks(
         *_ssm_scan_operands(state, x, dt, a, b, c, chunk, heads), chunk, dtype, interpret)
     return y.reshape(x.shape), _scan_heads_of(last, heads)
+
+
+SSM_STAGE_ROWS = 512    # tokens a grid step of the two pointwise stages' kernels holds,
+SSM_STAGE_SUB = 32      # walked this many at a time: a chain of float32 values stays in registers
+
+
+def _sublanes_summed(v):
+    """``[rows, width]`` -> ``[8, width]``: the rows added eight apart, so whole vectors
+    are added and no row of a vector to another; a grid step sums the eight once."""
+    return sum(v[at:at + 8] for at in range(0, v.shape[0], 8))
+
+
+def _silu_and_slope(v):
+    """``silu(v)`` and its derivative from one logistic, float32."""
+    sig = jax.nn.sigmoid(v)
+    return v * sig, sig * (1.0 + v * (1.0 - sig))
+
+
+def _conv_taps(taps, last, now):
+    """The ``k`` shifted views of ``now`` [sub, width] behind the 8 rows before it
+    (``last``) and their sum under ``taps`` (a list of ``[1, width]``): row ``t`` of
+    view ``m`` is row ``t - (k - 1) + m``."""
+    k, sub = len(taps), now.shape[0]
+    seen = jnp.concatenate([last, now], axis=0)
+    views = [seen[8 - (k - 1) + m:8 - (k - 1) + m + sub] for m in range(k)]
+    return views, sum(taps[m] * views[m] for m in range(k))
+
+
+def _ssm_conv_fwd_kernel(taps, bias, before, xbc, x, b, c, *, nx, nb, sub):
+    """One block of rows of one block of channels of the causal depthwise convolution,
+    its bias and ``silu``: ``xbc`` [rows, width], ``before`` the 16 rows before it (zeros
+    at a sequence's start: the grid's middle axis counts one lane's blocks), ``taps`` [k,
+    width] and ``bias`` [1, width] float32. The rows are walked ``sub`` at a time with the
+    eight before them carried: the shifted products and the activation are float32 values
+    that never leave VMEM. The grid's last axis walks ``x``'s blocks of channels, then
+    ``b``'s, then ``c``'s; a block is written to the part it belongs to, and the other
+    parts' blocks stay where they were (their index does not move)."""
+    from jax.experimental import pallas as pl
+
+    i, j, f32 = pl.program_id(1), pl.program_id(2), jnp.float32
+    rows, weights, shift = xbc.shape[0], [taps[m:m + 1, :] for m in range(taps.shape[0])], bias[...]
+
+    def write(out):
+        def some(r, last):
+            at = pl.ds(pl.multiple_of(r * sub, sub), sub)
+            now = xbc[at, :].astype(f32)
+            out[at, :] = jax.nn.silu(shift + _conv_taps(weights, last, now)[1]).astype(out.dtype)
+            return now[sub - 8:]
+
+        jax.lax.fori_loop(
+            0, rows // sub, some, jnp.where(i == 0, 0.0, before[8:, :].astype(f32)))
+
+    pl.when(j < nx)(lambda: write(x))
+    pl.when((j >= nx) & (j < nx + nb))(lambda: write(b))
+    pl.when(j >= nx + nb)(lambda: write(c))
+
+
+def _ssm_conv_bwd_kernel(taps, bias, before, xbc, dx, db, dc, dxbc, sums, ahead, *, nx, nb,
+                         sub):
+    """:func:`_ssm_conv_fwd_kernel` differentiated, a lane's blocks of rows walked from
+    the last to the first and a block's rows from its last ``sub`` to its first: the
+    pre-activation is rebuilt from ``xbc`` and the rows before it, ``silu'`` times the
+    part's gradient (``dx``, ``db`` or ``dc``, by the block of channels) is the
+    pre-activation's, and ``dxbc`` is the taps' transposed sum of it over the rows
+    **after** a row: the first eight rows' of the later rows are carried, inside a block
+    by the loop and from a block to the one before it in ``ahead`` [blocks of channels,
+    8, width] (scratch; zeros at a sequence's end). ``sums`` [blocks of channels, 8,
+    width] float32 stays resident over the whole grid: rows ``0 .. k - 1`` the taps'
+    gradients and row ``k`` the bias's, summed over lanes and rows."""
+    from jax.experimental import pallas as pl
+
+    l, i, j, f32 = pl.program_id(0), pl.program_id(1), pl.program_id(2), jnp.float32
+    rows, k = xbc.shape[0], taps.shape[0]
+    steps = rows // sub
+    weights, shift = [taps[m:m + 1, :] for m in range(k)], bias[...]
+    start_of_lane = i == pl.num_programs(1) - 1
+    first = jnp.where(start_of_lane, 0.0, before[8:, :].astype(f32))
+
+    @pl.when((l == 0) & (i == 0))
+    def _():
+        sums[j] = jnp.zeros(sums.shape[1:], f32)
+
+    @pl.when(i == 0)
+    def _():
+        ahead[j] = jnp.zeros(ahead.shape[1:], f32)
+
+    def some(q, carried):
+        later, *totals = carried
+        r = steps - 1 - q
+        at = pl.ds(pl.multiple_of(r * sub, sub), sub)
+        now = xbc[at, :].astype(f32)
+        behind = xbc[pl.ds(pl.multiple_of(jnp.maximum(r * sub - 16, 0), 16), 16), :]
+        last = jnp.where(r == 0, first, behind[8:, :].astype(f32))
+        views, mixed = _conv_taps(weights, last, now)
+        grad = jnp.where(j < nx, dx[at, :], jnp.where(j < nx + nb, db[at, :], dc[at, :]))
+        dpre = grad.astype(f32) * _silu_and_slope(shift + mixed)[1]
+        after = jnp.concatenate([dpre, later], axis=0)
+        dxbc[at, :] = sum(
+            weights[m] * after[k - 1 - m:k - 1 - m + sub] for m in range(k)).astype(dxbc.dtype)
+        totals = [total + _sublanes_summed(dpre * view) for total, view in zip(totals, views)] + [
+            totals[k] + _sublanes_summed(dpre)]
+        return (dpre[:8], *totals)
+
+    zeros = jnp.zeros(sums.shape[1:], f32)
+    later, *totals = jax.lax.fori_loop(0, steps, some, (ahead[j], *[zeros] * (k + 1)))
+    ahead[j] = later
+    for m, total in enumerate(totals):
+        sums[j, m:m + 1, :] += total.sum(axis=0, keepdims=True)
+
+
+def _ssm_conv_call(within, taps, bias, grads=None, *, first, inner, rows, sub, interpret):
+    """The convolution's forward kernel (the parts ``x`` [lanes, t, inner] and ``b``, ``c``
+    [lanes, t, (channels - inner) / 2] of ``silu(conv(xbc) + bias)``) or, given ``grads``
+    (the three parts' gradients), its backward kernel (``dxbc``, the taps' gradient and
+    the bias's); ``xbc`` is columns ``first .. first + channels`` of ``within`` and is read
+    there. Grid ``(lanes, blocks of rows, blocks of channels)``, all in turn."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    (lanes, t, _), (k, channels) = within.shape, taps.shape
+    part = (channels - inner) // 2
+    width = math.gcd(inner, part, first, 512)
+    nx, nb, blocks, first = inner // width, part // width, t // rows, first // width
+    columns = nx + 2 * nb   # blocks of channels: x's, then b's, then c's
+    assert t % rows == 0 and rows % sub == 0 and sub % 16 == 0 and k <= 8, (t, rows, sub, k)
+
+    def at(i):
+        return i if grads is None else blocks - 1 - i
+
+    def parts_spec(first, count):   # a part's block, held where it was outside its own blocks
+        return pl.BlockSpec(
+            (None, rows, width), lambda l, i, j: (l, at(i), jnp.clip(j - first, 0, count - 1)))
+
+    weights = pl.BlockSpec((k, width), lambda l, i, j: (0, j))
+    shift = pl.BlockSpec((1, width), lambda l, i, j: (0, j))
+    before = pl.BlockSpec(
+        (None, 16, width), lambda l, i, j: (l, jnp.maximum(at(i) * (rows // 16) - 1, 0), first + j))
+    source = pl.BlockSpec((None, rows, width), lambda l, i, j: (l, at(i), first + j))
+    whole = pl.BlockSpec((None, rows, width), lambda l, i, j: (l, at(i), j))
+    parts = [parts_spec(0, nx), parts_spec(nx, nb), parts_spec(nx + nb, nb)]
+    options = dict(
+        grid=(lanes, blocks, columns),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        interpret=interpret)
+    shaped = jax.ShapeDtypeStruct
+    if grads is None:
+        return pl.pallas_call(
+            functools.partial(_ssm_conv_fwd_kernel, nx=nx, nb=nb, sub=sub), name="ssm_conv_fwd",
+            in_specs=[weights, shift, before, source], out_specs=parts,
+            out_shape=[shaped((lanes, t, inner), within.dtype)] + [shaped((lanes, t, part), within.dtype)] * 2,
+            **options)(taps, bias, within, within)
+    summed = pl.BlockSpec((columns, 8, width), lambda l, i, j: (0, 0, 0))
+    dxbc, sums = pl.pallas_call(
+        functools.partial(_ssm_conv_bwd_kernel, nx=nx, nb=nb, sub=sub), name="ssm_conv_bwd",
+        in_specs=[weights, shift, before, source] + parts, out_specs=[whole, summed],
+        out_shape=[shaped((lanes, t, channels), within.dtype), shaped((columns, 8, width), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((columns, 8, width), jnp.float32)],
+        **options)(taps, bias, within, within, *grads)
+    by_tap = sums.transpose(1, 0, 2).reshape(8, channels)
+    return dxbc, by_tap[:k], by_tap[k:k + 1]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _ssm_conv_parts(xbc, within, taps, bias, first, inner, rows, sub, interpret):
+    """``silu(conv(xbc) + bias)`` in its three parts: the kernel pair. ``xbc`` is read
+    where it lies, as columns of ``within`` (no gradient goes that way: the caller hands
+    it over under ``stop_gradient``), and its gradient is ``xbc``'s, which is otherwise
+    not looked at: a slice nobody reads is never copied out. The backward keeps
+    ``within``, the taps and the bias and rebuilds the pre-activation from them."""
+    del xbc
+    return tuple(_ssm_conv_call(
+        within, taps, bias, first=first, inner=inner, rows=rows, sub=sub, interpret=interpret))
+
+
+def _ssm_conv_parts_fwd(xbc, within, taps, bias, first, inner, rows, sub, interpret):
+    parts = _ssm_conv_parts(xbc, within, taps, bias, first, inner, rows, sub, interpret)
+    return parts, (within, taps, bias)
+
+
+def _ssm_conv_parts_bwd(first, inner, rows, sub, interpret, kept, grads):
+    dxbc, dtaps, dbias = _ssm_conv_call(
+        *kept, grads, first=first, inner=inner, rows=rows, sub=sub, interpret=interpret)
+    return dxbc, jnp.zeros_like(kept[0]), dtaps, dbias
+
+
+_ssm_conv_parts.defvjp(_ssm_conv_parts_fwd, _ssm_conv_parts_bwd)
+
+
+def ssm_conv(xbc, taps, bias, inner: int, *, within=None, rows: int = SSM_STAGE_ROWS,
+             sub: int = SSM_STAGE_SUB, interpret: bool = False):
+    """A trained Mamba-2 mixer's head: the causal depthwise convolution of ``xbc``
+    [lanes, t, channels] (zeros before a sequence) under ``taps`` [k, channels] with
+    ``bias`` [channels], both float32, then ``silu``, everything float32 and the result
+    cast to ``xbc``'s dtype, in the three parts the scan takes: ``x`` [lanes, t, inner]
+    and ``b``, ``c`` [lanes, t, (channels - inner) / 2]. Differentiable in all three
+    arguments. On the TPU (``interpret`` reaches it elsewhere) a kernel pair under one
+    ``jax.custom_vjp``: ``xbc`` is read once and each part written once where the scan
+    reads it, forward; ``xbc`` and the parts' gradients read once and ``dxbc`` written
+    once, backward, with the taps' and the bias's gradients summed in float32 in a block
+    that stays in VMEM; no float32 array a token and channel wide exists in HBM. A caller
+    that cut ``xbc`` out of a wider array says so, ``within=(array, first column)``: the
+    kernels then read the columns where they lie (the first a whole number of their
+    blocks of channels), and the cut is never copied out. Off the TPU the same lines in
+    ``jax.numpy`` under plain autodiff."""
+    t, k = xbc.shape[1], taps.shape[0]
+    if not (backend.on_tpu() or interpret):
+        f32 = jnp.float32
+        seen = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))
+        mixed = bias + sum(taps[j] * seen[:, j:j + t].astype(f32) for j in range(k))
+        mixed = jax.nn.silu(mixed).astype(xbc.dtype)
+        return (mixed[..., :inner], *jnp.split(mixed[..., inner:], 2, axis=-1))
+    rows, (source, first) = min(rows, t), within or (xbc, 0)
+    return _ssm_conv_parts(
+        xbc, jax.lax.stop_gradient(source), taps, bias[None], first, inner, rows, min(sub, rows),
+        interpret)
+
+
+def _ssm_gate_norm_fwd_kernel(skip, scale, y, x, z, out, *, eps, sub):
+    """One block of rows of one group's channels: ``(y + skip x) silu(z)``, float32,
+    normed over the group's channels and scaled; ``y`` [rows, width] float32, ``x`` and
+    ``z`` ``dtype``, ``skip`` (``D``, a head's over its channels) and ``scale`` [1, width]
+    float32. Walked ``sub`` rows at a time."""
+    from jax.experimental import pallas as pl
+
+    f32, d, w = jnp.float32, skip[...], scale[...]
+
+    def some(r, _):
+        at = pl.ds(pl.multiple_of(r * sub, sub), sub)
+        gated = (y[at, :] + d * x[at, :].astype(f32)) * jax.nn.silu(z[at, :].astype(f32))
+        normed = gated * jax.lax.rsqrt((gated * gated).mean(-1, keepdims=True) + eps) * w
+        out[at, :] = normed.astype(out.dtype)
+        return _
+
+    jax.lax.fori_loop(0, y.shape[0] // sub, some, None)
+
+
+def _ssm_gate_norm_bwd_kernel(skip, scale, y, x, z, grad, dy, dx, dz, sums, *, eps, sub):
+    """:func:`_ssm_gate_norm_fwd_kernel` differentiated: the forward's values are rebuilt
+    from the same three blocks, ``dy`` (float32), ``dx`` and ``dz`` written once, and
+    ``sums`` [8, width] float32 stays resident over the group's lanes and rows: row 0
+    ``skip``'s gradient, row 1 ``scale``'s."""
+    from jax.experimental import pallas as pl
+
+    f32, d, w = jnp.float32, skip[...], scale[...]
+
+    @pl.when((pl.program_id(1) == 0) & (pl.program_id(2) == 0))
+    def _():
+        sums[...] = jnp.zeros(sums.shape, f32)
+
+    def some(r, totals):
+        at = pl.ds(pl.multiple_of(r * sub, sub), sub)
+        xf, zf, g = x[at, :].astype(f32), z[at, :].astype(f32), grad[at, :].astype(f32)
+        gate, slope = _silu_and_slope(zf)
+        summed = y[at, :] + d * xf
+        gated = summed * gate
+        inverse = jax.lax.rsqrt((gated * gated).mean(-1, keepdims=True) + eps)
+        normed = gated * inverse
+        dnormed = g * w
+        dgated = inverse * (dnormed - normed * (dnormed * normed).mean(-1, keepdims=True))
+        dsummed = dgated * gate
+        dy[at, :] = dsummed
+        dx[at, :] = (dsummed * d).astype(dx.dtype)
+        dz[at, :] = (dgated * summed * slope).astype(dz.dtype)
+        return (totals[0] + _sublanes_summed(dsummed * xf), totals[1] + _sublanes_summed(g * normed))
+
+    zeros = jnp.zeros(sums.shape, f32)
+    for row, total in enumerate(jax.lax.fori_loop(0, y.shape[0] // sub, some, (zeros, zeros))):
+        sums[row:row + 1, :] += total.sum(axis=0, keepdims=True)
+
+
+def _ssm_gate_norm_call(y, x, within, skip, scale, grad=None, *, first, groups, eps, rows, sub,
+                        interpret):
+    """The tail's forward kernel (the normed, gated ``[lanes, t, inner]`` in ``x``'s
+    dtype) or, given ``grad`` (its gradient), its backward kernel (the gradients of the
+    five); ``z`` is columns ``first .. first + inner`` of ``within`` and is read there.
+    Grid ``(groups, lanes, blocks of rows)``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    lanes, t, inner = y.shape
+    width = inner // groups
+    assert t % rows == 0 and rows % sub == 0 and sub % 8 == 0 and first % width == 0, (t, rows, sub)
+    tokens = pl.BlockSpec((None, rows, width), lambda g, l, i: (l, i, g))
+    gates = pl.BlockSpec((None, rows, width), lambda g, l, i: (l, i, first // width + g))
+    channels = pl.BlockSpec((1, width), lambda g, l, i: (0, g))
+    options = dict(grid=(groups, lanes, t // rows), interpret=interpret)
+    shaped = jax.ShapeDtypeStruct
+    if grad is None:
+        return pl.pallas_call(
+            functools.partial(_ssm_gate_norm_fwd_kernel, eps=eps, sub=sub), name="ssm_gate_norm_fwd",
+            in_specs=[channels, channels, tokens, tokens, gates], out_specs=tokens,
+            out_shape=shaped(x.shape, x.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel")),
+            **options)(skip, scale, y, x, within)
+    dy, dx, dz, sums = pl.pallas_call(
+        functools.partial(_ssm_gate_norm_bwd_kernel, eps=eps, sub=sub), name="ssm_gate_norm_bwd",
+        in_specs=[channels, channels, tokens, tokens, gates, tokens],
+        out_specs=[tokens, tokens, tokens, pl.BlockSpec((8, width), lambda g, l, i: (0, g))],
+        out_shape=[shaped(y.shape, y.dtype), shaped(x.shape, x.dtype), shaped(x.shape, within.dtype),
+                   shaped((8, inner), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        **options)(skip, scale, y, x, within, grad)
+    return dy, dx, dz, sums[0:1], sums[1:2]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
+def _ssm_gate_normed(y, x, z, within, skip, scale, first, groups, eps, rows, sub, interpret):
+    """The mixer's tail: the kernel pair. ``z`` is read where it lies, as columns of
+    ``within``, and its gradient is ``z``'s (:func:`_ssm_conv_parts` says why). The
+    backward keeps the operands."""
+    del z
+    return _ssm_gate_norm_call(
+        y, x, within, skip, scale, first=first, groups=groups, eps=eps, rows=rows, sub=sub,
+        interpret=interpret)
+
+
+def _ssm_gate_normed_fwd(y, x, z, within, skip, scale, first, groups, eps, rows, sub, interpret):
+    out = _ssm_gate_normed(y, x, z, within, skip, scale, first, groups, eps, rows, sub, interpret)
+    return out, (y, x, within, skip, scale)
+
+
+def _ssm_gate_normed_bwd(first, groups, eps, rows, sub, interpret, kept, grad):
+    dy, dx, dz, dskip, dscale = _ssm_gate_norm_call(
+        *kept, grad, first=first, groups=groups, eps=eps, rows=rows, sub=sub, interpret=interpret)
+    return dy, dx, dz, jnp.zeros_like(kept[2]), dskip, dscale
+
+
+_ssm_gate_normed.defvjp(_ssm_gate_normed_fwd, _ssm_gate_normed_bwd)
+
+
+def ssm_gate_norm(y, x, z, skip, scale, groups: int, eps: float, *, within=None,
+                  rows: int = SSM_STAGE_ROWS, sub: int = SSM_STAGE_SUB, interpret: bool = False):
+    """A trained Mamba-2 mixer's tail: ``RMSNorm_group((y + skip x) silu(z)) scale`` for
+    the scan's ``y`` [lanes, t, inner] float32, ``x`` and ``z`` [lanes, t, inner] (the
+    result is in ``x``'s dtype), ``skip`` [heads] float32 (``D``: a head's over its
+    ``inner / heads`` channels), ``scale`` [inner], the norm over each of the ``groups``
+    groups' channels; float32 throughout. Differentiable in all five. On the TPU
+    (``interpret`` reaches it elsewhere) a kernel pair under one ``jax.custom_vjp``, a
+    grid step a block of rows of one group's channels: forward reads the three and
+    writes the result once; backward reads the three and the result's gradient, rebuilds
+    the forward's values in VMEM and writes ``dy`` (float32), ``dx`` and ``dz`` once,
+    with ``skip``'s and ``scale``'s gradients summed in float32 in a block that stays in
+    VMEM; ``within=(array, first column)`` says where ``z`` was cut from, as
+    :func:`ssm_conv`'s does. Off the TPU the same lines in ``jax.numpy`` under plain
+    autodiff."""
+    lanes, t, inner = y.shape
+    f32 = jnp.float32
+    if not (backend.on_tpu() or interpret):
+        heads = skip.shape[0]
+        summed = y.reshape(lanes, t, heads, -1) + skip[:, None] * x.reshape(lanes, t, heads, -1).astype(f32)
+        gated = summed.reshape(lanes, t, inner) * jax.nn.silu(z.astype(f32))
+        # a norm a group: over the channels of the heads that share a B and a C
+        normed = layers.rms_norm(gated.reshape(lanes, t, groups, -1), scale.reshape(groups, -1), eps)
+        return normed.reshape(lanes, t, inner).astype(x.dtype)
+    rows, (source, first) = min(rows, t), within or (z, 0)
+    return _ssm_gate_normed(
+        y, x, z, jax.lax.stop_gradient(source), jnp.repeat(skip, inner // skip.shape[0])[None],
+        scale.astype(f32)[None], first, groups, eps, rows, min(sub, rows), interpret)
 
 
 def make_extend_fn(cfg: GraniteMoeHybridConfig):

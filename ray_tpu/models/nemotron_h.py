@@ -8,7 +8,9 @@ or a feed-forward part alone, chosen by letter ``l`` of ``pattern``
 
 * ``M``, **Mamba-2**: ``[z, xBC, dt] = W_in n`` (no bias); ``xBC <- silu(conv(xBC))``,
   a causal depthwise convolution of ``conv_kernel`` taps with a bias over the ``x``,
-  ``B`` and ``C`` channels (zeros before the sequence); ``[x, B, C] = xBC`` with ``x``
+  ``B`` and ``C`` channels (zeros before the sequence; ``granitemoehybrid.ssm_conv``: on
+  the TPU a kernel pair that reads ``xBC`` once and writes the three parts where the
+  scan reads them); ``[x, B, C] = xBC`` with ``x``
   ``[ssm_heads, ssm_head_dim]`` and ``B``, ``C`` ``[ssm_groups, ssm_state]``: head ``h``
   reads group ``h // (ssm_heads / ssm_groups)``. ``D_t = softplus(dt_t + dt_bias)``,
   ``A = -exp(A_log)``; on the state ``S_h`` ``[ssm_head_dim x ssm_state]``, float32:
@@ -17,7 +19,10 @@ or a feed-forward part alone, chosen by letter ``l`` of ``pattern``
   (``granitemoehybrid.ssm_scan``: on the TPU a kernel pair, forward and backward, in
   which a sub-chunk's pairs, decays and weights never leave VMEM; elsewhere
   ``ssm_chunked``'s loop under ``jax.grad``). Out: ``W_out [RMSNorm_group(y * silu(z)) *
-  w]``, **the norm over each group's** ``ssm_inner / ssm_groups`` **channels**;
+  w]``, **the norm over each group's** ``ssm_inner / ssm_groups`` **channels**
+  (``granitemoehybrid.ssm_gate_norm``: on the TPU a kernel pair a group's channels wide;
+  ``y`` stays float32 from the scan to it). Off the TPU both stages are their
+  ``jax.numpy`` lines under plain autodiff, float32 inside either way;
 * ``*``, **attention**: ``num_heads`` query heads over ``kv_heads`` K/V heads of
   ``head_dim``, no bias, a causal softmax of ``q . k / sqrt(head_dim)`` and **no
   position encoding**. The flash kernel takes one K/V head a query head, so K and V
@@ -49,14 +54,19 @@ experts its router scores, ``routed=``, and at a share this small every pass of
 the held blocks alone)
 and the experts its router chose (``moe.ROUTED``: the kept results' rows lie as that
 choice sorted the pairs, so the replay reads the choice and does not make it again).
-A Mamba layer's replay runs the scan's forward kernel again, and the scan's backward
-kernel rebuilds a sub-chunk at a time from the states between sub-chunks, which are
-all it keeps beside its operands: 268 MB a layer at [2, 8192].
+A Mamba layer's replay runs the three forward kernels again (the convolution's, the
+scan's, the gated norm's: none of their results is kept), and the three backward
+kernels rebuild what they need in VMEM: the scan's a sub-chunk at a time from the states
+between sub-chunks, which are all it keeps beside its operands (268 MB a layer at [2,
+8192]); the convolution's its pre-activation from ``xBC``; the gated norm's its forward's
+values from ``y``, ``x`` and ``z``.
 
-Scopes, inside ``train.forward``: ``train.ssm.proj`` (the in and out projections),
-``train.ssm.conv`` (the taps, their activation and the step's softplus),
-``train.ssm.scan`` (the recurrence alone), ``train.ssm.norm`` (``D x``, the gate and
-the grouped norm); ``train.attention``; ``train.moe.route``,
+Scopes, inside ``train.forward``: ``train.ssm.proj`` (the in and out projections, and
+the copies of ``z`` and ``xBC`` out of the in-projection's result that a kernel's operand
+has to be), ``train.ssm.conv`` (the taps, their activation and the split: ``ssm_conv_fwd``,
+``ssm_conv_bwd``; and the step's softplus), ``train.ssm.scan`` (the recurrence alone),
+``train.ssm.norm`` (``D x``, the gate and the grouped norm: ``ssm_gate_norm_fwd``,
+``ssm_gate_norm_bwd``); ``train.attention``; ``train.moe.route``,
 ``train.moe.experts``, ``train.moe.shared``. The step reports
 ``moe.TRAINED_COUNTERS``, summed over the expert layers.
 """
@@ -73,7 +83,11 @@ import jax.numpy as jnp
 
 from ray_tpu.models import layers, moe
 from ray_tpu.models.gpt import TrainModel
-from ray_tpu.models.granitemoehybrid import ssm_scan    # ssm_chunked, as the TPU's kernel pair
+from ray_tpu.models.granitemoehybrid import (   # each a kernel pair on the TPU, jax.numpy off it
+    ssm_conv,
+    ssm_gate_norm,
+    ssm_scan,
+)
 from ray_tpu.ops.attention import FLASH_RESIDUALS, dot_product_attention
 
 MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
@@ -249,29 +263,24 @@ def mamba_mixer(cfg: NemotronHConfig, p, r):
     dtype, f32, (b, t, _) = cfg.dtype, jnp.float32, r.shape
     heads, inner, groups = cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_groups
     with jax.named_scope("train.ssm.proj"):
-        z, xbc, dt = jnp.split(
-            r @ p["in"].astype(dtype), (inner, inner + cfg.conv_dim), axis=-1)
+        # the kernels read z and xbc where the projection wrote them: neither is copied out
+        projected = r @ p["in"].astype(dtype)
+        z, xbc, dt = jnp.split(projected, (inner, inner + cfg.conv_dim), axis=-1)
     with jax.named_scope("train.ssm.conv"):
-        seen = jnp.pad(xbc, ((0, 0), (cfg.conv_kernel - 1, 0), (0, 0)))
-        taps = p["conv"].astype(f32)
-        mixed = p["conv_bias"].astype(f32) + sum(
-            taps[j] * seen[:, j:j + t].astype(f32) for j in range(cfg.conv_kernel))
-        mixed = jax.nn.silu(mixed).astype(dtype)
-        x = mixed[..., :inner].reshape(b, t, heads, cfg.ssm_head_dim)
-        bm, cm = (
-            part.reshape(b, t, groups, cfg.ssm_state)
-            for part in jnp.split(mixed[..., inner:], 2, axis=-1))
+        x, bm, cm = ssm_conv(
+            xbc, p["conv"].astype(f32), p["conv_bias"].astype(f32), inner, within=(projected, inner))
+        x = x.reshape(b, t, heads, cfg.ssm_head_dim)
+        bm, cm = (part.reshape(b, t, groups, cfg.ssm_state) for part in (bm, cm))
         step = jax.nn.softplus(dt.astype(f32) + p["dt_bias"])
     with jax.named_scope("train.ssm.scan"):
         y, _ = ssm_scan(
             jnp.zeros((b, heads, cfg.ssm_head_dim, cfg.ssm_state), f32), x, step,
             -jnp.exp(p["A_log"]), bm, cm, cfg.ssm_chunk, dtype)
     with jax.named_scope("train.ssm.norm"):
-        y = (y + p["D"][:, None] * x.astype(f32)).reshape(b, t, inner) * jax.nn.silu(z.astype(f32))
         # a norm a group: over the channels of the heads that share a B and a C
-        y = layers.rms_norm(
-            y.reshape(b, t, groups, -1), p["norm"].reshape(groups, -1), cfg.norm_eps)
-        y = y.reshape(b, t, inner).astype(dtype)
+        y = ssm_gate_norm(
+            y.reshape(b, t, inner), x.reshape(b, t, inner), z, p["D"], p["norm"], groups,
+            cfg.norm_eps, within=(projected, 0))
     with jax.named_scope("train.ssm.proj"):
         return y @ p["out"].astype(dtype)
 

@@ -193,7 +193,14 @@ def test_nemotron_h_share_train_step_fits_the_chip_with_its_kernels(v5e, built_f
     and no more (a layer's remat replays neither kernel), and beside them the scan's
     two by name: a forward in each of the four layers' forward and replay, one backward
     each. No array a head and sub-chunk wide (``f32[64,2,64,128,128]`` and kin: the
-    pairs, decays and weights of a sub-chunk) exists outside a kernel. And the file's
+    pairs, decays and weights of a sub-chunk) exists outside a kernel. The pointwise
+    stage on either side of the scan is a kernel pair too (``ssm_conv``: the taps, the
+    bias, ``silu`` and the split; ``ssm_gate_norm``: ``D x``, the gate, the grouped norm),
+    called as often as the scan's, and under ``train.ssm.conv`` and ``train.ssm.norm``
+    nothing but a kernel makes a float32 ``[2, 8192, 6144]`` or ``[2, 8192, 4096]`` (the
+    parent's passes between fusions; ``y`` itself is the scan kernel's, under its own
+    scope), and ``z`` and ``xbc`` are read as columns of the in-projection's result, not
+    copied out of it: 14,248,942,592 B for the parent's 14,401,249,792. And the file's
     ``compiled_bytes_per_device`` still bounds what the compiler says.
 
     An expert layer here holds 16 of 128 experts, an eighth of the 98,304 worst-case
@@ -218,7 +225,8 @@ def test_nemotron_h_share_train_step_fits_the_chip_with_its_kernels(v5e, built_f
     mamba_layers, expert_layers = cfg.pattern.count("M"), cfg.pattern.count("E")
     assert kernels == {
         **file["job"]["min_kernels"],
-        "ssm_scan_fwd": 2 * mamba_layers, "ssm_scan_bwd": mamba_layers,
+        **{stage + "_fwd": 2 * mamba_layers for stage in ("ssm_conv", "ssm_scan", "ssm_gate_norm")},
+        **{stage + "_bwd": mamba_layers for stage in ("ssm_conv", "ssm_scan", "ssm_gate_norm")},
         "moe_held_rows": 6 * expert_layers}
     assert not train_fixed_batch.missing_kernels(kernels, file["job"]["min_kernels"])
     batch = tuple(file["job"]["batch"])
@@ -241,6 +249,19 @@ def test_nemotron_h_share_train_step_fits_the_chip_with_its_kernels(v5e, built_f
     chunk = cfg.ssm_chunk
     a_sub_chunk_wide = re.findall(rf"(?:f32|bf16)\[(?:\d+,){{2,}}{chunk},{chunk}\]", text)
     assert not a_sub_chunk_wide, sorted(set(a_sub_chunk_wide))
+    # the stages on either side of the scan: float32 a token and channel wide lives in VMEM
+    wide = rf"f32\[{batch[0]},{batch[1]},(?:{cfg.conv_dim}|{cfg.ssm_inner})\]"
+    made = []
+    for line in text.splitlines():
+        instruction = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) ([\w-]+)\(", line)   # its shape, its operation
+        if instruction and re.search(r"train\.ssm\.(?:conv|norm)", line) and re.search(
+                wide, instruction[1]) and instruction[2] not in ("custom-call", "get-tuple-element"):
+            made.append(line[:200])
+    assert not made, made[:3]
+    # and they read z and xbc where the in-projection wrote them: neither is cut out as a copy
+    cut = rf"= bf16\[{batch[0]},{batch[1]},(?:{cfg.conv_dim}|{cfg.ssm_inner})\]\S* (?:slice|copy)\("
+    copied = [line[:200] for line in text.splitlines() if "train.ssm." in line and re.search(cut, line)]
+    assert not copied, copied[:3]
     stated = file["compiled_bytes_per_device"]
     assert _device_bytes(compiled) <= 1.01 * stated["total"]
     assert stated["total"] == (

@@ -25,7 +25,7 @@ from benchmark.reference import nemotron_h_reference as reference
 from ray_tpu.models import moe, nemotron_h
 from ray_tpu.models.gpt import blockwise_next_token_loss
 from ray_tpu.models import granitemoehybrid
-from ray_tpu.models.granitemoehybrid import ssm_chunked, ssm_scan
+from ray_tpu.models.granitemoehybrid import ssm_chunked, ssm_conv, ssm_gate_norm, ssm_scan
 from ray_tpu.models.training import default_optimizer, init_sharded_state, make_train_step
 from ray_tpu.parallel.mesh import MeshSpec
 
@@ -262,9 +262,10 @@ def test_the_kernels_keep_the_state_before_each_sub_chunk(groups):
 
 
 def test_the_mixer_runs_the_kernels_on_the_tpu_and_the_loop_elsewhere(nano, built_for_tpu):
-    """``mamba_mixer`` asks the platform, nobody else: built for the TPU its scan is
-    the kernel pair (interpreted here), forward and backward, and agrees with the loop
-    it is built with on the CPU."""
+    """``mamba_mixer`` asks the platform, nobody else: built for the TPU its scan and
+    the pointwise stage on either side of it are kernel pairs (interpreted here), forward
+    and backward, and agree with the loop and the ``jax.numpy`` lines it is built with on
+    the CPU."""
     from jax.experimental.pallas import tpu as pltpu
 
     cfg, params, _ = nano
@@ -279,10 +280,96 @@ def test_the_mixer_runs_the_kernels_on_the_tpu_and_the_loop_elsewhere(nano, buil
     built_for_tpu(True)
     with pltpu.force_tpu_interpret_mode():
         text = str(jax.make_jaxpr(jax.grad(loss))(p, r))
-        assert text.count("ssm_scan_fwd") >= 1 and text.count("ssm_scan_bwd") >= 1
+        for stage in ("ssm_conv", "ssm_scan", "ssm_gate_norm"):     # the head, the scan, the tail
+            assert text.count(stage + "_fwd") >= 1 and text.count(stage + "_bwd") >= 1, stage
         got = jax.value_and_grad(loss, argnums=(0, 1))(p, r)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         close(g, w, 1e-5)
+
+
+def stage_inputs(stage, groups, dtype=jnp.float32, lanes=2, t=32, heads=8, p=8, n=16, seed=0):
+    """One pointwise stage of the mixer as ``(fn(*operands, **blocks), operands)``: the
+    ``head`` (``ssm_conv``: ``xbc``, the taps, the bias -> ``x``, ``b``, ``c``) or the
+    ``tail`` (``ssm_gate_norm``: ``y``, ``x``, ``z``, ``D``, the norm's weight)."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    inner, channels = heads * p, heads * p + 2 * groups * n
+    if stage == "head":
+        bound = 4 ** -0.5
+        return (lambda *v, **blocks: ssm_conv(*v, inner, **blocks)), (
+            jax.random.normal(keys[0], (lanes, t, channels)).astype(dtype),
+            jax.random.uniform(keys[1], (4, channels), jnp.float32, -bound, bound),
+            jax.random.uniform(keys[2], (channels,), jnp.float32, -bound, bound))
+    return (lambda *v, **blocks: (ssm_gate_norm(*v, groups, 1e-5, **blocks),)), (
+        jax.random.normal(keys[0], (lanes, t, inner)),
+        *(jax.random.normal(k, (lanes, t, inner)).astype(dtype) for k in keys[1:3]),
+        1.0 + 0.1 * jax.random.normal(keys[3], (heads,)),
+        (1.0 + 0.1 * jax.random.normal(keys[4], (inner,))).astype(dtype))
+
+
+STAGE_CASES = {     # groups, operands' dtype, rows a grid step, the most apart (of the largest entry)
+    "1-group": (1, jnp.float32, 32, 2e-6),
+    "8-groups": (8, jnp.float32, 32, 2e-6),
+    # two blocks of rows a lane: the head's halo crosses a block's edge, forward (the
+    # three rows before a block) and backward (the three after it), and the parameters'
+    # gradients are summed over the blocks
+    "blocks-of-rows": (4, jnp.float32, 16, 2e-6),
+    # the compute type a train step states. The kernels sum float32 and round once where
+    # plain autodiff rounds each tap's product of the head's ``dxbc`` to bfloat16 first
+    "bfloat16": (4, jnp.bfloat16, 16, 1e-2),
+    # as the mixer calls them: ``xbc`` and ``z`` are columns of the in-projection's result
+    # and the kernels read them there, so the gradient of the cut is the kernels' own
+    "cut-from-a-wider-array": (4, jnp.float32, 16, 2e-6),
+}
+
+
+@pytest.mark.parametrize("case", list(STAGE_CASES))
+@pytest.mark.parametrize("stage", ["head", "tail"])
+def test_a_stages_kernel_pair_is_its_jax_numpy_lines(stage, case):
+    """Each pointwise stage of the mixer, the kernel pair (interpreted) against the
+    ``jax.numpy`` lines it replaces on the TPU: every result and the gradient of every
+    input, the taps, the bias, ``D`` and the norm's weight among them, two lanes."""
+    groups, dtype, rows, apart = STAGE_CASES[case]
+    fn, operands = stage_inputs(stage, groups, dtype)
+    weigh = [jax.random.normal(jax.random.PRNGKey(7 + i), out.shape)
+             for i, out in enumerate(jax.eval_shape(fn, *operands))]
+
+    def loss(*v, **blocks):
+        outs = fn(*v, **blocks)
+        return sum((out.astype(jnp.float32) * w).sum() for out, w in zip(outs, weigh)), outs
+
+    def results_and_gradients(**blocks):
+        (_, outs), grads = jax.value_and_grad(
+            lambda *v: loss(*v, **blocks), argnums=range(len(operands)), has_aux=True)(*operands)
+        return outs, grads
+
+    blocks = dict(rows=rows, interpret=True)
+    if case == "cut-from-a-wider-array":
+        cut = operands[0 if stage == "head" else 2]
+        wide = jnp.concatenate([cut[..., :64] + 1.0, cut, cut[..., :16] - 1.0], axis=-1)
+        blocks["within"] = (wide, 64)
+    want, got = results_and_gradients(), results_and_gradients(**blocks)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        close(g.astype(jnp.float32), w.astype(jnp.float32), apart)
+
+
+@pytest.mark.parametrize("rows", [32, 16], ids=["one-block", "blocks-of-rows"])
+def test_a_lanes_first_rows_read_zeros_and_not_the_lane_befores_last(rows):
+    """The head's taps reach three rows back: at a sequence's start those are zeros, in
+    the second lane too, whose rows lie behind the first lane's in the array; and the
+    backward's taps reach three rows on, past a sequence's end to nothing. A lane alone
+    gives the bits it gives beside another."""
+    fn, (xbc, taps, bias) = stage_inputs("head", 4)
+
+    def run(xbc):
+        loss = lambda xbc: sum((out ** 2).sum() for out in fn(xbc, taps, bias, rows=rows, interpret=True))
+        return jax.grad(loss)(xbc), fn(xbc, taps, bias, rows=rows, interpret=True)
+
+    both = run(xbc)
+    for lane in range(2):
+        alone = run(xbc[lane:lane + 1])
+        for g, w in zip(jax.tree.leaves(both), jax.tree.leaves(alone)):
+            np.testing.assert_array_equal(g[lane:lane + 1], w)
 
 
 def test_groups_matter_and_one_group_is_no_axis():
