@@ -15,12 +15,21 @@ asks :func:`recording` whether anything keeps them.
 
 from __future__ import annotations
 
+import atexit
+import collections
+import contextlib
 import dataclasses
 import functools
+import gc
 import glob
+import logging
 import os
 import re
+import sys
+import threading
+import time
 import types
+import weakref
 from typing import Any, Dict, List, Optional
 
 _DEV_ROOT = "/dev"
@@ -225,6 +234,523 @@ class Programs:
         """The programs the family has compiled (traced, where nothing ran):
         the sum of its members' own counts."""
         return sum(p._cache_size() for p in self._members.values())
+
+
+def _profiler():
+    """``jax.profiler`` in a process that has imported jax whole, else None: the
+    collector's hook runs inside ``import jax`` too."""
+    return getattr(sys.modules.get("jax"), "profiler", None)
+
+
+def quiet_span(name: str, **what):
+    """:func:`span` in a process that already runs jax, and nothing in one that
+    does not: for code that processes without jax run too (the batcher, the
+    train session, the host's watch), which must not import it for a span."""
+    profiler = _profiler()
+    if profiler is None:
+        return contextlib.nullcontext()
+    return profiler.TraceAnnotation(name, **what)
+
+
+def _recording_quietly() -> bool:
+    """:func:`recording`, and no in a process that does not run jax."""
+    profiler = _profiler()
+    return profiler is not None and profiler.TraceAnnotation.is_enabled()
+
+
+def fold_stack(frame, innermost: Optional[int] = None) -> List[str]:
+    """The stack that ends in ``frame``, outermost first, a frame as
+    ``file:function:line`` (a flamegraph's folded form, joined with ``;``);
+    with ``innermost`` only that many of its innermost frames."""
+    parts = []
+    while frame is not None and (innermost is None or len(parts) < innermost):
+        code = frame.f_code
+        parts.append(f"{code.co_filename.rsplit('/', 1)[-1]}:{code.co_name}:{frame.f_lineno}")
+        frame = frame.f_back
+    parts.reverse()
+    return parts
+
+
+# ---------------------------------------------------------------------------
+# what held the host
+# ---------------------------------------------------------------------------
+
+#: the least a unit's unexplained part must come to before it counts as held, and
+#: how many times its usual size. The floor decides wherever a unit's own part is
+#: under 2.5 ms (every serve cell's step: 0.2-3 ms): the smallest idle gap the ledger
+#: could not explain was 24 ms (PR 68's lines: Qwen3-Next, under a dispatch), so the
+#: floor lies under it; the watcher looks every 50 ms, so a hold is seen while it
+#: lasts, with its stack, from 50-100 ms on, and a shorter one has its cause alone.
+HELD_FLOOR_S = 0.02
+HELD_TIMES = 8.0
+_RING = 256                 # the unit's last parts, for their median
+_HELD_KEPT = 32             # held records a book keeps
+_WATCH_PERIOD_S = 0.05
+#: a reading this soon after another reads the wall clock alone: the three other
+#: clocks are system calls (a microsecond apiece between other work), and a thread
+#: that has just read them has not run long enough since for them to tell
+NEAR_S = 0.0005
+_STACK_FRAMES = 12
+CAUSES = ("gc", "python", "threads", "machine")
+#: what a unit measures beside its wall, in the order ``_Unit.measured`` holds them
+MEASURES = ("cpu_s", "others_cpu_s", "gc_s", "switched", "faults")
+_NOTHING_MEASURED = (0.0, 0.0, 0.0, 0, 0)
+
+try:
+    import resource as _resource
+
+    #: this thread's CPU seconds, involuntary switches and major faults in one read
+    _thread_usage = functools.partial(_resource.getrusage, _resource.RUSAGE_THREAD)
+except (ImportError, AttributeError):       # no per-thread rusage: its CPU seconds alone
+    _resource, _thread_usage = None, time.thread_time
+
+logger = logging.getLogger(__name__)
+
+
+def _median(values) -> float:
+    """The lower median: of two sizes the smaller, so one outlier is no usual size."""
+    ordered = sorted(values)
+    return ordered[(len(ordered) - 1) // 2] if ordered else 0.0
+
+
+class _Kind:
+    """What the watch knows of one unit name: its last ``_RING`` parts (a unit's
+    wall less what its caller explained) and, computed when somebody needs them
+    (a unit past the floor, the watcher, every sixteenth close of a unit that
+    explains itself), their median and the threshold that follows from it."""
+
+    __slots__ = (
+        "name", "usual", "ring", "at", "median_s", "threshold_s", "fresh", "current", "__weakref__")
+
+    def __init__(self, name: str, usual: bool):
+        self.name, self.usual = name, usual
+        #: the unit of this kind that is open and that the watcher has not seen yet
+        #: (the newest, where threads open several at once)
+        self.current: Optional[_Unit] = None
+        self.ring: List[float] = []
+        self.at = 0
+        self.median_s, self.threshold_s, self.fresh = 0.0, HELD_FLOOR_S, 0
+
+    def keep(self, part_s: float) -> None:
+        if len(self.ring) < _RING:
+            self.ring.append(part_s)
+        else:
+            self.ring[self.at & (_RING - 1)] = part_s
+        self.at += 1
+
+    def refresh(self) -> None:
+        ring = list(self.ring)
+        self.median_s = _median(ring)
+        # a unit that explains itself is held by what it is over its usual size,
+        # against how far its sizes usually lie from it
+        size = _median(abs(x - self.median_s) for x in ring) if self.usual else self.median_s
+        self.threshold_s = max(HELD_FLOOR_S, HELD_TIMES * size)
+        self.fresh = self.at
+
+
+def _no_totals() -> Dict[str, Any]:
+    return dict(n=0, wall_s=0.0, cpu_s=0.0, others_cpu_s=0.0, gc_s=0.0, switched=0, faults=0)
+
+
+class HostBook:
+    """One owner's totals of its units: ``host`` (a unit name: ``n``, ``wall_s``,
+    ``cpu_s`` this thread on a CPU, ``others_cpu_s`` every other thread of the
+    process, ``gc_s``, ``switched`` involuntarily, major ``faults``), ``held`` (how
+    many units were held and by how much, by cause) and ``steps``, the last 32 held
+    records. All of it counts up from zero; read it as deltas."""
+
+    def __init__(self, *units: str):
+        self.host: Dict[str, Dict[str, Any]] = {name: _no_totals() for name in units}
+        self.held: Dict[str, Any] = {
+            "n": 0, "excess_s": 0.0, **{cause: dict(n=0, s=0.0) for cause in CAUSES}}
+        self.steps: collections.deque = collections.deque(maxlen=_HELD_KEPT)
+        #: a unit name's usual size, as this owner's units have it
+        self.kinds: Dict[str, _Kind] = {}
+
+    def totals(self) -> Dict[str, Any]:
+        """``host`` and ``held``, copied group by group."""
+        return {
+            "host": {name: dict(t) for name, t in self.host.items()},
+            "held": {k: dict(v) if isinstance(v, dict) else v for k, v in self.held.items()},
+        }
+
+
+class _Unit:
+    """One unit of host work, open: where its clocks stood when it began, what
+    its caller has explained of it so far (``explained_s``: raise it before a
+    wait whose length is known, so the watcher does not take the wait for a
+    hold) and, once the watcher has found it overdue, what it read then."""
+
+    __slots__ = (
+        "kind", "book", "thread", "began", "explained_s", "seen", "open", "wall_s", "measured",
+        "record")
+
+    def __init__(self, kind: _Kind, book: HostBook, began: tuple):
+        self.kind, self.book, self.began = kind, book, began
+        self.thread = threading.get_ident()
+        self.explained_s, self.open = 0.0, True
+        self.seen: Optional[Dict[str, Any]] = None      # the watcher's reading, while held
+        self.wall_s, self.measured = 0.0, _NOTHING_MEASURED    # after ``close``: ``MEASURES``
+        self.record: Optional[Dict[str, Any]] = None    # the held record, after ``close``
+
+
+class HostWatch:
+    """What a thread's host work cost and, where it stood still, why.
+
+    A caller brackets a *unit* of work between two :meth:`read`\\ s
+    (:meth:`open`, :meth:`close`; a reading ends one unit and begins the next):
+    four clocks a reading, the wall, this thread's own ``getrusage`` (CPU
+    seconds, involuntary switches, major faults), the process's CPU and the
+    collector's running total. The differences go to the owner's
+    :class:`HostBook`. A unit whose unexplained part (its wall less
+    ``explained_s``) passes ``max(HELD_FLOOR_S, HELD_TIMES x that part's running
+    median)`` is *held*: it leaves a record with its cause, by one rule over the
+    excess ``e``: ``gc`` where the collector ran for ``e / 2`` or more, else
+    ``python`` where this thread was on a CPU that long (it computed), else
+    ``threads`` where the process's other threads were (the interpreter lock, or a
+    native thread; ``busiest`` names three), else ``machine``: nobody in the
+    process ran. ``machine`` names the remainder and is no verdict: a thread that
+    waits for a lock whose holder waits for the network reads so too, and the
+    record's ``stack`` and ``switched`` are what tell them apart.
+
+    One daemon thread (``host-watch``, from the first unit on, every 50 ms) looks
+    at the open units. One that is overdue gets, while it is still held, the
+    innermost twelve frames of its thread, a first reading of every python thread's
+    CPU clock and of the machine's counters (``/proc/stat``'s steal, the control
+    group's throttled time, ``/proc/pressure``), and, while a profiler session
+    records, a span ``host.held`` from then to the unit's end. Nothing is sampled
+    before that. A record's ``machine`` also says how late the watcher itself was
+    (``watch_late_s``): it sleeps and needs nothing but the interpreter lock, so
+    where it is late too the whole process stood still, not one thread of it. (A
+    collection holds the interpreter lock from its start to its end, so the
+    watcher cannot look while one lasts: a unit held by ``gc`` alone has no stack,
+    and its cause is the answer.) One ``gc.callbacks`` hook times every collection
+    (``gc``; a span ``host.gc`` on the collecting thread while a session records)."""
+
+    def __init__(self):
+        self.own = HostBook()           # units whose caller names no book
+        self.gc = {"n": 0, "s": 0.0, "longest_s": 0.0,
+                   "generations": {str(g): dict(n=0, s=0.0) for g in range(3)}}
+        #: every kind of every book, for the watcher; a book that goes takes its own along
+        self._kinds: "weakref.WeakSet[_Kind]" = weakref.WeakSet()
+        self._gc_began = 0.0
+        self._gc_span = None
+        self._thread: Optional[threading.Thread] = None
+        self._closed = threading.Event()        # a unit the watcher has seen has ended
+        self._woke_at = time.perf_counter()     # when the watcher last looked
+        self._logged_at = 0.0
+        gc.callbacks.append(self._on_gc)
+        atexit.register(self._forget)
+
+    def _forget(self) -> None:
+        """Take the collector's hook back: at exit, and in a forked child."""
+        with contextlib.suppress(ValueError):
+            gc.callbacks.remove(self._on_gc)
+
+    # -- the fast path: a reading, a unit between two -------------------------
+
+    def read(self, after: Optional[tuple] = None) -> tuple:
+        """``(wall, this thread's usage, process CPU, collector seconds)`` now;
+        within ``NEAR_S`` of the reading ``after``, the wall with ``after``'s others."""
+        now = time.perf_counter()
+        if after is not None and now - after[0] < NEAR_S:
+            return (now, after[1], after[2], after[3])
+        return (now, _thread_usage(), time.process_time(), self.gc["s"])
+
+    def open(self, name: str, book: Optional[HostBook] = None, at: Optional[tuple] = None,
+             usual: bool = False, again: Optional[_Unit] = None) -> _Unit:
+        """Begin a unit ``name`` at the reading ``at`` (now, without one) for
+        ``book`` (the watch's own, without one). ``usual``: nobody can say what
+        explains this unit (a step of a user's loop), so its own median does.
+        ``again``: a unit of this name and book that has ended, to be this one
+        (a caller that opens one a millisecond keeps its own)."""
+        if again is not None and again.seen is None:
+            unit, kind = again, again.kind
+            unit.began, unit.explained_s, unit.open, unit.record = at or self.read(), 0.0, True, None
+            kind.current = unit
+            return unit
+        book = book or self.own
+        try:
+            kind = book.kinds[name]
+        except KeyError:
+            kind = book.kinds[name] = _Kind(name, usual)
+            book.host.setdefault(name, _no_totals())
+            self._kinds.add(kind)
+        if self._thread is None:
+            self._start()
+        unit = kind.current = _Unit(kind, book, at or self.read())
+        return unit
+
+    def drop(self, unit: _Unit) -> None:
+        """End ``unit`` uncounted: what it bracketed turned out to be nobody's."""
+        unit.open, unit.kind.current = False, None
+        if unit.seen is not None:
+            self._closed.set()
+
+    def close(self, unit: _Unit, at: Optional[tuple] = None, where=None) -> Optional[Dict[str, Any]]:
+        """End ``unit`` at the reading ``at``: its totals to its book, and its
+        record where it was held (``unit.record``, returned; None otherwise).
+        ``where()`` is asked only then, for the record's ``where``."""
+        wall0, usage0, process0, gc0 = unit.began
+        wall1, usage1, process1, gc1 = at or self.read()
+        unit.open = False
+        unit.wall_s = wall_s = wall1 - wall0
+        kind = unit.kind
+        if usage1 is usage0 and unit.seen is None:
+            # it ended within ``NEAR_S`` of its beginning (``read(after)``): its wall alone
+            kind.current = None
+            totals = unit.book.host[kind.name]
+            totals["n"] += 1
+            totals["wall_s"] += wall_s
+            unit.measured = _NOTHING_MEASURED
+            kind.keep(wall_s - unit.explained_s)
+            return None
+        if _resource is not None:
+            # ru_utime, ru_stime, ru_majflt, ru_nivcsw
+            cpu_s = usage1[0] - usage0[0] + usage1[1] - usage0[1]
+            faults, switched = usage1[7] - usage0[7], usage1[15] - usage0[15]
+        else:
+            cpu_s, faults, switched = usage1 - usage0, 0, 0
+        others_cpu_s, gc_s = process1 - process0 - cpu_s, gc1 - gc0
+        if others_cpu_s < 0.0:              # two clocks of one CPU time: they differ by a tick
+            others_cpu_s = 0.0
+        unit.measured = (cpu_s, others_cpu_s, gc_s, switched, faults)
+        kind.current = None
+        totals = unit.book.host[kind.name]
+        totals["n"] += 1
+        totals["wall_s"] += wall_s
+        totals["cpu_s"] += cpu_s
+        totals["others_cpu_s"] += others_cpu_s
+        totals["gc_s"] += gc_s
+        totals["switched"] += switched
+        totals["faults"] += faults
+        part_s = own_s = wall_s - unit.explained_s
+        if kind.usual:
+            if kind.at - kind.fresh >= min(16, kind.fresh):    # often while it has few
+                kind.refresh()
+            # against its usual size, once it has one
+            part_s = own_s - kind.median_s if kind.at else 0.0
+        if part_s > HELD_FLOOR_S or unit.seen is not None:
+            # -- the slow path: past the floor, or the watcher has taken it for held
+            if not kind.usual:
+                kind.refresh()
+            if part_s > kind.threshold_s:
+                unit.record = self._held(unit, part_s, where)
+            if unit.seen is not None:
+                self._closed.set()
+        kind.keep(own_s)
+        return unit.record
+
+    @contextlib.contextmanager
+    def unit(self, name: str, book: Optional[HostBook] = None, where=None, usual: bool = False):
+        """One unit round the block; the unit is yielded for ``explained_s``, and
+        holds its ``record`` afterwards. A block that raises is dropped."""
+        unit = self.open(name, book, usual=usual)
+        try:
+            yield unit
+        except BaseException:
+            self.drop(unit)
+            raise
+        self.close(unit, where=where)
+
+    # -- a held unit -----------------------------------------------------------
+
+    def _held(self, unit: _Unit, excess_s: float, where) -> Dict[str, Any]:
+        measured = {"wall_s": unit.wall_s, **dict(zip(MEASURES, unit.measured))}
+        half = excess_s / 2
+        if measured["gc_s"] >= half:
+            cause = "gc"
+        elif measured["cpu_s"] >= half:
+            cause = "python"
+        elif measured["others_cpu_s"] >= half:
+            cause = "threads"
+        else:
+            cause = "machine"
+        seen = unit.seen or {}
+        busiest, machine = [], {}
+        if seen:
+            seen["cause"] = cause
+        # the watcher sleeps 50 ms and needs nothing but the interpreter lock: where it
+        # is late itself, the whole process (or the lock) stood still, not this thread alone
+        late_s = time.perf_counter() - self._woke_at - _WATCH_PERIOD_S
+        if late_s > 0:
+            machine["watch_late_s"] = late_s
+        if "machine" in seen:               # the watcher's first reading is whole
+            spent = _differences(_thread_clocks(), seen["threads"])
+            for own in (seen["thread"], "host-watch"):      # the unit's thread, and the instrument
+                spent.pop(own, None)
+            busiest = [
+                [name, s] for name, s in sorted(spent.items(), key=lambda kv: -kv[1])[:3] if s > 0]
+            machine.update(_differences(_machine_clocks(), seen["machine"]))
+        record = {
+            "at": time.time(), "unit": unit.kind.name, **measured, "excess_s": excess_s,
+            "where": where() if callable(where) else where, "cause": cause,
+            "stack": seen.get("stack", []), "busiest": busiest, "machine": machine,
+        }
+        held = unit.book.held
+        held["n"] += 1
+        held["excess_s"] += excess_s
+        held[cause]["n"] += 1
+        held[cause]["s"] += excess_s
+        unit.book.steps.append(record)
+        now = time.monotonic()
+        if now - self._logged_at >= 1.0:                # the log plane has it: one line a second
+            self._logged_at = now
+            logger.warning(
+                "host held: %s stood %.3f s over what explains it (wall %.3f s), cause %s, in %s; "
+                "cpu %.3f s, other threads %.3f s, gc %.3f s, switched %d, faults %d; busiest %s; "
+                "machine %s; stack %s",
+                record["unit"], excess_s, record["wall_s"], cause, record["where"],
+                record["cpu_s"], record["others_cpu_s"], record["gc_s"], record["switched"],
+                record["faults"], busiest, machine, ";".join(record["stack"]))
+        return record
+
+    # -- the watcher -------------------------------------------------------------
+
+    def _start(self) -> None:
+        with _watch_lock:
+            if self._thread is None:
+                self._woke_at = time.perf_counter()
+                self._thread = threading.Thread(target=self._watch, daemon=True, name="host-watch")
+                self._thread.start()
+
+    def _watch(self) -> None:
+        held: Optional[_Unit] = None        # the unit under the open ``host.held`` span
+        held_span = None
+        while True:
+            self._closed.wait(_WATCH_PERIOD_S)
+            self._closed.clear()
+            if held is not None and not held.open:
+                # the unit has ended: so does its span, with what the unit was found to be
+                with contextlib.suppress(Exception):
+                    held_span.set_metadata(cause=held.seen.get("cause", "none"))
+                    held_span.__exit__(None, None, None)
+                held = held_span = None
+            now = self._woke_at = time.perf_counter()
+            for kind in list(self._kinds):
+                unit = kind.current
+                if unit is None:
+                    continue
+                over_s = now - unit.began[0] - unit.explained_s - (kind.median_s if kind.usual else 0.0)
+                if over_s <= HELD_FLOOR_S or (kind.usual and not kind.at):
+                    continue
+                if not kind.usual:
+                    kind.refresh()
+                if over_s <= kind.threshold_s:
+                    continue
+                # the stack first, and the unit has it at once: what follows reads files
+                frame = sys._current_frames().get(unit.thread)
+                unit.seen = seen = {"stack": fold_stack(frame, _STACK_FRAMES)}
+                del frame
+                if kind.current is unit:            # seen once: the watcher looks at it no more
+                    kind.current = None
+                seen["thread"] = next(
+                    (t.name for t in threading.enumerate() if t.ident == unit.thread), "")
+                seen["threads"], seen["machine"] = _thread_clocks(), _machine_clocks()
+                if held is None and _recording_quietly():
+                    held, held_span = unit, span("host.held", unit=kind.name)
+                    held_span.__enter__()
+
+    # -- the collector -----------------------------------------------------------
+
+    def _on_gc(self, phase: str, info: Dict[str, Any]) -> None:
+        if phase == "start":
+            if _recording_quietly():
+                self._gc_span = span("host.gc", generation=info["generation"])
+                self._gc_span.__enter__()
+            self._gc_began = time.perf_counter()
+            return
+        spent = time.perf_counter() - self._gc_began
+        totals = self.gc
+        totals["n"] += 1
+        totals["s"] += spent
+        if spent > totals["longest_s"]:
+            totals["longest_s"] = spent
+        generation = totals["generations"][str(info["generation"])]
+        generation["n"] += 1
+        generation["s"] += spent
+        if self._gc_span is not None:
+            gc_span, self._gc_span = self._gc_span, None
+            gc_span.set_metadata(collected=info["collected"])
+            gc_span.__exit__(None, None, None)
+
+    def gc_totals(self) -> Dict[str, Any]:
+        return {**self.gc, "generations": {g: dict(t) for g, t in self.gc["generations"].items()}}
+
+    def stats(self) -> Dict[str, Any]:
+        """The watch's own book (the units that named none) with the collector's
+        totals: ``host``, ``held``, ``gc`` and ``held_steps``."""
+        return {**self.own.totals(), "gc": self.gc_totals(), "held_steps": list(self.own.steps)}
+
+
+def _differences(after: Dict[str, float], before: Dict[str, float]) -> Dict[str, float]:
+    """``after - before`` by key; what began since (a thread) counts from zero."""
+    return {k: v - before.get(k, 0.0) for k, v in after.items()}
+
+
+#: in ``busiest``: the process's threads that are not python's, together
+NATIVE = "(native threads)"
+
+
+def _thread_clocks() -> Dict[str, float]:
+    """CPU seconds of every python thread of this process by name
+    (``time.pthread_getcpuclockid``: a clock read apiece, and the interpreter lock
+    is never let go, so a busy process cannot stretch the reading) and, under
+    ``NATIVE``, of every other thread together: the process's less theirs."""
+    out: Dict[str, float] = {}
+    if hasattr(time, "pthread_getcpuclockid"):
+        for t in threading.enumerate():
+            with contextlib.suppress(Exception):    # a thread that ended meanwhile
+                out[t.name] = time.clock_gettime(time.pthread_getcpuclockid(t.ident))
+        out[NATIVE] = time.process_time() - sum(out.values())
+    return out
+
+
+def _machine_clocks() -> Dict[str, float]:
+    """What the machine says of itself, in seconds, where it says anything: the
+    time this guest's CPUs were stolen (``/proc/stat``), the time this process's
+    control group stood throttled at its CPU quota (``cpu.stat``) and the time
+    some task stood stalled for a CPU, for io and for memory (``/proc/pressure``)."""
+    out: Dict[str, float] = {}
+    cpu = _read("/proc/stat").split("\n", 1)[0].split()
+    if len(cpu) > 8 and cpu[0] == "cpu":
+        out["steal_s"] = int(cpu[8]) / os.sysconf("SC_CLK_TCK")
+    for path, unit in (("/sys/fs/cgroup/cpu.stat", 1e6), ("/sys/fs/cgroup/cpu/cpu.stat", 1e9)):
+        found = re.search(r"throttled_(?:usec|time) (\d+)", _read(path))
+        if found:
+            out["throttled_s"] = int(found.group(1)) / unit
+            break
+    for what in ("cpu", "io", "memory"):
+        found = re.search(r"some .*total=(\d+)", _read(f"/proc/pressure/{what}"))
+        if found:
+            out[f"pressure_{what}_s"] = int(found.group(1)) / 1e6
+    return out
+
+
+_watch: Optional[HostWatch] = None
+_watch_lock = threading.Lock()
+
+
+def _forget_watch() -> None:
+    """A forked child has none of the parent's threads: it makes its own watch."""
+    global _watch
+    if _watch is not None:
+        _watch._forget()
+        _watch = None
+
+
+if hasattr(os, "register_at_fork"):
+    os.register_at_fork(after_in_child=_forget_watch)
+
+
+def host_watch() -> HostWatch:
+    """This process's :class:`HostWatch`, made on first use."""
+    global _watch
+    if _watch is None:
+        with _watch_lock:
+            if _watch is None:
+                _watch = HostWatch()
+    return _watch
 
 
 def device_report() -> Dict[str, Any]:

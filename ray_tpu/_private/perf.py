@@ -43,6 +43,8 @@ import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from ray_tpu._private import accelerator
+
 # ---------------------------------------------------------------------------
 # RPC phase accumulators
 # ---------------------------------------------------------------------------
@@ -315,20 +317,11 @@ def sample_self(
         for tid, frame in sys._current_frames().items():
             if tid == me:
                 continue  # don't profile the profiler
-            parts = []
-            f = frame
-            while f is not None:
-                code = f.f_code
-                parts.append(
-                    f"{code.co_filename.rsplit('/', 1)[-1]}:"
-                    f"{code.co_name}:{f.f_lineno}"
-                )
-                f = f.f_back
             name = names.get(tid)
             if name is None:
                 names = {t.ident: t.name for t in threading.enumerate()}
                 name = names.get(tid, f"tid-{tid}")
-            stack = f"{name};" + ";".join(reversed(parts))
+            stack = f"{name};" + ";".join(accelerator.fold_stack(frame))
             folded[stack] = folded.get(stack, 0) + 1
         samples += 1
         time.sleep(interval)

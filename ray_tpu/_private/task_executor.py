@@ -16,7 +16,7 @@ import time
 import traceback
 from typing import Any, Dict, List, Optional, Tuple
 
-from ray_tpu._private import internal_metrics
+from ray_tpu._private import accelerator, internal_metrics
 from ray_tpu._private import serialization
 from ray_tpu._private import trace as _trace
 from ray_tpu._private.config import GlobalConfig
@@ -652,14 +652,7 @@ class TaskExecutor:
             for tid, frame in _sys._current_frames().items():
                 if tid == my_thread:
                     continue  # don't profile the profiler
-                parts = []
-                f = frame
-                while f is not None:
-                    code = f.f_code
-                    parts.append(f"{code.co_filename.rsplit('/', 1)[-1]}:"
-                                 f"{code.co_name}:{f.f_lineno}")
-                    f = f.f_back
-                stack = ";".join(reversed(parts))
+                stack = ";".join(accelerator.fold_stack(frame))
                 folded[stack] = folded.get(stack, 0) + 1
             samples += 1
             _time.sleep(interval)

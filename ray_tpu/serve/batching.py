@@ -28,9 +28,7 @@ drained, flusher thread stopped — when the instance is collected or
 
 from __future__ import annotations
 
-import contextlib
 import functools
-import sys
 import threading
 import time
 import weakref
@@ -213,9 +211,7 @@ def _idle_span():
     of a replica shows it as ``serve.batch_idle`` and not as a hole. Only in
     a process that already runs jax: the batcher imports it for no
     deployment."""
-    if "jax" in sys.modules:
-        return accelerator.span("serve.batch_idle")
-    return contextlib.nullcontext()
+    return accelerator.quiet_span("serve.batch_idle")
 
 
 def _caller_cancelled() -> bool:

@@ -1114,6 +1114,15 @@ FORMS = ("prefill", "decode")
 #: a no-op (2.7 us on the sandbox's CPU): under 0.3 ms for the <= 30 phases of
 #: a step. ``tests/test_llm_spans.py`` holds the engine to it.
 PHASE_BUDGET_NS = 10_000.0
+#: the units of host work an engine brackets for ``accelerator.HostWatch``: a
+#: step, and the time from one step's return to the next step while a sequence
+#: is active (the batcher's loop and the wake-up of finished callers; with
+#: nothing active it is nobody's)
+HOST_UNITS = ("llm.step", "llm.between")
+#: what the two units of a step may add to it outside a profiler session: two
+#: readings of four clocks, shared between the units, and their arithmetic
+#: (``tests/test_llm_spans.py`` holds the engine to it, with the phases' budget)
+HOST_BUDGET_NS = 5_000.0
 
 
 class LLMEngine:
@@ -1290,6 +1299,20 @@ class LLMEngine:
         #: long it took, its lanes and its own seconds per phase. Time in
         #: ``fetch`` is the device or the runtime; anywhere else, the host.
         self.slowest_step: Optional[Dict[str, Any]] = None
+        #: what the host's own part of a step and of the time between two steps
+        #: cost this thread, and the steps that stood still with their cause and
+        #: their stack (``accelerator.HostWatch``): ``stats()``'s ``host``, ``held``
+        #: and ``held_steps``, the engine's own; ``gc`` is the process's
+        self._watch = accelerator.host_watch()
+        self._book = accelerator.HostBook(*HOST_UNITS)
+        self._unit = None               # the step under way while it is ``open``, else the last
+        self._between = None            # from a step's return while a sequence is active, likewise
+        self._late_s = 0.0              # of the step's landings, what their programs do not explain
+        #: a program's usual call, which explains a landing's wait: ``[calls landed,
+        #: the sum of their busy_s]``, a call counted at no more than twice the usual
+        #: once four have landed, so that a landing that was held does not explain
+        #: the next one
+        self._usual: Dict[str, List[float]] = {}
         #: the call launched last, until its successor is (or nothing can be)
         self._flight: Optional[_Call] = None
         #: what the first call of an idle engine is handed for ``home``
@@ -1465,6 +1488,8 @@ class LLMEngine:
             **self._work(),
             "traced": copy.deepcopy(self.traced),
             "slowest_step": slowest,
+            "gc": self._watch.gc_totals(),
+            "held_steps": list(self._book.steps),
         }
 
     @property
@@ -1520,6 +1545,7 @@ class LLMEngine:
             "programs": {name: dict(counts) for name, counts in self.programs.items()},
             "programs_cold": self.programs_cold,
             "programs_cold_s": self.programs_cold_s,
+            **self._book.totals(),
         }
 
     @contextlib.contextmanager
@@ -1539,12 +1565,27 @@ class LLMEngine:
     # -- scheduling --------------------------------------------------------
 
     def step(self, seqs: List[Any]) -> None:
-        if self._flight is not None and self._flight.home.is_ready():
-            self._away_s += time.perf_counter() - self._left_at
-        before, lanes0, this = dict(self.phase_s), self.lanes_used, self.steps
+        watch = self._watch
+        between = self._between
+        # one reading of the clocks ends ``llm.between`` and begins ``llm.step``; right
+        # after the last step's own (the batcher's loop takes microseconds), the wall alone
+        began = watch.read(between.began if between is not None and between.open else None)
         self._step_began = self._work() if accelerator.recording() else None
+        if between is not None and between.open:
+            if seqs and seqs[0].state is not None:
+                watch.close(between, began, "between")
+            else:
+                # the sequences it waited with have gone (cancelled; those that
+                # stay come first): nobody's time
+                watch.drop(between)
+        if self._flight is not None and self._flight.home.is_ready():
+            self._away_s += began[0] - self._left_at
+        before, lanes0, this = dict(self.phase_s), self.lanes_used, self.steps
+        self._late_s = 0.0
+        unit = self._unit = watch.open("llm.step", self._book, began, again=self._unit)
         try:
-            with self._phase("step"):
+            # the phase ``step``: its span, and its seconds and count from the unit's clock
+            with accelerator.span("llm.step"):
                 if self.step_delay_s:
                     time.sleep(self.step_delay_s)
                 if self._admit_phase(seqs):
@@ -1567,21 +1608,31 @@ class LLMEngine:
             # a crashed forward poisons the batch (the batcher fails every
             # caller) — the leases must not ride down with it, nor those of
             # the call in flight, which is dropped: nothing will land it
+            self.phase_s["step"] += time.perf_counter() - began[0]
+            self.phase_n["step"] += 1
+            watch.drop(unit)
             flight, self._flight, self._step_began = self._flight, None, None
             for st in [s.state for s in seqs] + [
                     st for _, st in (flight.lanes if flight else ())]:
                 if isinstance(st, _SeqState) and st.lease is not None:
                     st.lease.release()
             raise
-        wall_s = self.phase_s["step"] - before["step"]
+        ended = watch.read()
+        # the one place a step's wall and its phases' split are computed: the
+        # held record's ``where`` and ``slowest_step`` read the same numbers
+        held = watch.close(unit, ended, lambda: self._where(before))
+        wall_s = unit.wall_s
+        self.phase_s["step"] += wall_s
+        self.phase_n["step"] += 1
         if self.slowest_step is None or wall_s > self.slowest_step["wall_s"]:
             self.slowest_step = {
                 "at": time.time(), "wall_s": wall_s,
                 "lanes": self.lanes_used - lanes0,
-                "phase_s": {
-                    k: v - before[k] for k, v in self.phase_s.items()
-                    if v > before[k]
-                },
+                "phase_s": {**self._split(before), "step": wall_s},
+                # what the step cost this thread and, where the watch found it
+                # held, why: the record's fields
+                **dict(zip(accelerator.MEASURES, unit.measured)),
+                **{k: (held or {}).get(k) for k in ("excess_s", "where", "cause", "stack")},
             }
         began, self._step_began = self._step_began, None
         if began is not None:
@@ -1591,7 +1642,20 @@ class LLMEngine:
             if self._flight is not None and self._flight.step == this:
                 # every other call this step launched has landed in it
                 self._flight.recorded = recorded
-        self._left_at = time.perf_counter()
+        self._left_at = ended[0]
+        if self._flight is not None:            # a sequence waits for the next step
+            self._between = watch.open("llm.between", self._book, ended, again=between)
+
+    def _split(self, before: Dict[str, float]) -> Dict[str, float]:
+        """The step's own seconds a phase: ``phase_s`` now less ``before``."""
+        return {k: v - before[k] for k, v in self.phase_s.items() if v > before[k]}
+
+    def _where(self, before: Dict[str, float]) -> str:
+        """The leaf phase that holds most of what nothing explains of the step
+        under way: a phase's whole time, but of ``fetch`` only what its calls'
+        programs do not explain (``_land``)."""
+        split = {**self._split(before), "fetch": self._late_s}
+        return max(LEAF_PHASES, key=lambda p: split.get(p, 0.0))
 
     def _now(self) -> float:
         """The clock of a call's launch and landing: the host's, less the
@@ -1985,12 +2049,34 @@ class LLMEngine:
         call, self._flight = self._flight, None
         states = [st for _, st in call.lanes]
         with self._phase("fetch", call=call.seq) as span:
+            # what explains the wait: the program's usual call (one program's calls
+            # spread under 1 %), counted from where this call could start. The unit
+            # is told before the wait, so that the watcher takes no usual wait for a hold
+            unit, known = self._unit, self._usual.get(call.program)
+            started = max(call.launched_at, self._landed_at)
+            asked = self._now()
+            usual_s = known[1] / known[0] if known else None
+            expected_s = 0.0 if usual_s is None else max(0.0, started + usual_s - asked)
+            if unit is not None:
+                unit.explained_s += expected_s
             # waits for the device; then the ids are home
             home = np.asarray(call.home)
             fetched = [home]
             now = self._now()
-            busy_s = now - max(call.launched_at, self._landed_at)
+            busy_s = now - started
             self._landed_at = now
+            if known is None:
+                self._usual[call.program] = [1, busy_s]
+            else:
+                known[0] += 1
+                known[1] += busy_s if known[0] <= 4 else min(busy_s, 2 * usual_s)
+            if unit is not None:
+                # a landing later than its program explains is held in ``fetch``:
+                # of the wait, what the call took over its usual time
+                waited_s = now - asked
+                late_s = 0.0 if usual_s is None else min(waited_s, max(0.0, busy_s - usual_s))
+                unit.explained_s += waited_s - late_s - expected_s
+                self._late_s += late_s
             # behind the ids: what ``extend`` counted, in its names' order
             counted = {
                 name: int(n)

@@ -172,6 +172,11 @@ class BackendExecutor:
             self.group.workers[rank].poll_reports.remote(start), timeout=60.0
         )
 
+    def host(self, rank: int) -> Dict[str, Any]:
+        """Rank ``rank``'s record of its host: what its loop's steps cost its
+        thread, and the steps that stood still (``session.host``)."""
+        return ray_tpu.get(self.group.workers[rank].host.remote(), timeout=60.0)
+
     def shutdown(self):
         if self.group is not None:
             self.group.shutdown()

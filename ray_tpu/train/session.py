@@ -11,6 +11,7 @@ from __future__ import annotations
 import threading
 from typing import Any, Dict, List, Optional
 
+from ray_tpu._private import accelerator
 from ray_tpu.train.checkpoint import Checkpoint
 
 
@@ -37,6 +38,16 @@ class _Session:
         self.reports: List[Dict[str, Any]] = []
         self.lock = threading.Lock()
         self.finished = threading.Event()
+        #: the step of the user's loop under way, as ``accelerator.HostWatch``
+        #: brackets it: from one ``report`` to the next
+        self.unit = None
+
+    def end(self) -> None:
+        """The loop has returned: what follows its last report is nobody's step."""
+        self.finished.set()
+        unit, self.unit = self.unit, None
+        if unit is not None:
+            accelerator.host_watch().drop(unit)
 
 
 _session: Optional[_Session] = None
@@ -54,7 +65,7 @@ def _shutdown_session():
     global _session
     with _session_lock:
         if _session is not None:
-            _session.finished.set()
+            _session.end()
         _session = None
 
 
@@ -68,13 +79,33 @@ def _get_session() -> _Session:
 
 
 def report(metrics: Dict[str, Any], checkpoint: Optional[Checkpoint] = None) -> None:
-    """Report metrics (and optionally a checkpoint) to the driver."""
+    """Report metrics (and optionally a checkpoint) to the driver.
+
+    From one report to the next is a step of the user's loop, which is all the
+    program sees of it: a unit ``train.report`` of this process's
+    ``accelerator.HostWatch`` (what the step cost the loop's thread; a step that
+    stood still over its usual time keeps its cause and its stack: ``host()``),
+    and the call itself a span ``train.report`` on the profiler's clock."""
     s = _get_session()
-    entry: Dict[str, Any] = {"metrics": dict(metrics)}
-    if checkpoint is not None:
-        entry["checkpoint"] = checkpoint
-    with s.lock:
-        s.reports.append(entry)
+    watch = accelerator.host_watch()
+    now = watch.read()              # ends the step that reports, begins the next
+    if s.unit is not None:
+        watch.close(s.unit, now, "the loop, from its last report to this one")
+    with accelerator.quiet_span("train.report"):
+        entry: Dict[str, Any] = {"metrics": dict(metrics)}
+        if checkpoint is not None:
+            entry["checkpoint"] = checkpoint
+        with s.lock:
+            s.reports.append(entry)
+    s.unit = watch.open("train.report", at=now, usual=True)
+
+
+def host() -> Dict[str, Any]:
+    """What this process's host work cost and what held it: the totals of the
+    loop's steps (``host["train.report"]``), the collector's (``gc``), how many
+    steps were held and by which cause (``held``) and the last 32 of them with
+    their stacks (``held_steps``)."""
+    return accelerator.host_watch().stats()
 
 
 def get_checkpoint() -> Optional[Checkpoint]:

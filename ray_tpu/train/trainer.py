@@ -139,6 +139,7 @@ class DataParallelTrainer(BaseTrainer):
                 sharded_update=self.sharded_update,
             )
             error: Optional[BaseException] = None
+            host: Dict[str, Any] = {}
             try:
                 executor.start()
                 run_refs = executor.start_training(
@@ -149,6 +150,7 @@ class DataParallelTrainer(BaseTrainer):
                     experiment_name=self.run_config.name or "",
                 )
                 self._drive(executor, run_refs, ckpt_manager, history)
+                host = executor.host(0)
             except Exception as e:  # noqa: BLE001
                 error = e
             finally:
@@ -159,6 +161,7 @@ class DataParallelTrainer(BaseTrainer):
                     checkpoint=ckpt_manager.latest,
                     metrics_history=history,
                     path=ckpt_manager.storage_path,
+                    host=host,
                 )
             if failures_allowed != 0 and (
                 failures_allowed < 0 or attempt <= failures_allowed

@@ -131,7 +131,7 @@ class TrainWorker:
             ]
             return train_fn(config or {}) if params else train_fn()
         finally:
-            self._session.finished.set()
+            self._session.end()
 
     def init_torch_distributed(self, backend: str = "gloo") -> bool:
         """torch.distributed bring-up over the gang's coordinator
@@ -176,6 +176,10 @@ class TrainWorker:
             return []
         with s.lock:
             return s.reports[start:]
+
+    def host(self) -> Dict[str, Any]:
+        """What this worker's host work cost and what held it (``session.host``)."""
+        return session_mod.host()
 
     def ping(self) -> int:
         return self.rank

@@ -29,6 +29,7 @@ leaves it.
 
 import os
 import shutil
+import signal
 import sys
 import tempfile
 
@@ -59,6 +60,8 @@ def pytest_configure(config):
         "markers",
         "slow: long-running chaos/soak tests, excluded from the tier-1 run",
     )
+    config.addinivalue_line(
+        "markers", "limit(seconds): the test fails where it runs longer (``_a_tests_own_limit``)")
 
 
 def pytest_unconfigure(config):
@@ -78,6 +81,27 @@ def _system_config_ends_with_the_test():
     yield
     with GlobalConfig._lock:
         GlobalConfig._values = saved
+
+
+@pytest.fixture(autouse=True)
+def _a_tests_own_limit(request):
+    """``@pytest.mark.limit(seconds)``: a time limit of the test's own, so that one
+    that hangs fails by itself and does not ride the whole run to its cut (D8)."""
+    marker = request.node.get_closest_marker("limit")
+    if marker is None or not hasattr(signal, "setitimer"):
+        yield
+        return
+
+    def late(signum, frame):
+        raise TimeoutError(f"{request.node.nodeid} ran past its {marker.args[0]} s")
+
+    before = signal.signal(signal.SIGALRM, late)
+    signal.setitimer(signal.ITIMER_REAL, marker.args[0])
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, before)
 
 
 @pytest.fixture

@@ -10,10 +10,12 @@ outside a session, and the stable device names (kernels, scopes)."""
 
 import contextlib
 import dataclasses
+import gc
 import glob
 import os
 import re
 import sys
+import threading
 import time
 import types
 
@@ -24,7 +26,7 @@ import numpy as np
 import pytest
 
 from benchmark import trace_reduce
-from ray_tpu._private import internal_metrics
+from ray_tpu._private import accelerator, internal_metrics
 from ray_tpu.models import gpt
 from ray_tpu.models.training import (
     abstract_state,
@@ -386,8 +388,19 @@ def test_kv_stats_has_every_key_from_construction():
         llm._extend_name(*shape): {"n": 0, "busy_s": 0.0} for shape in eng.extend_shapes()}
     assert (built["programs_cold"], built["programs_cold_s"]) == (0, 0.0)
     assert set(built["traced"]) == set(eng._work()) < set(built)
+    # what the host's work cost and what held it: the engine's own units at zero, no
+    # step held, the ring empty; the collector's totals are the process's
+    units = dict.fromkeys(llm.HOST_UNITS, dict(
+        n=0, wall_s=0.0, cpu_s=0.0, others_cpu_s=0.0, gc_s=0.0, switched=0, faults=0))
+    nothing_held = {"n": 0, "excess_s": 0.0, **{c: {"n": 0, "s": 0.0} for c in accelerator.CAUSES}}
+    assert built["host"] == units == built["traced"]["host"]
+    assert built["held"] == nothing_held == built["traced"]["held"]
+    assert built["held_steps"] == [] and "held_steps" not in built["traced"]
+    assert set(built["gc"]) == {"n", "s", "longest_s", "generations"} and "gc" not in built["traced"]
     _drive(eng, _requests())
     assert _key_tree(eng.stats()) == _key_tree(built)
+    after = eng.stats()["host"]
+    assert after["llm.step"]["n"] == STEPS and after["llm.between"]["n"] == STEPS - 1
 
 
 # -- (c') the same counters over the steps a session recorded, and the calls' records
@@ -762,16 +775,316 @@ def test_two_reads_bound_the_slowest_step(engine, monkeypatch):
     assert engine.stats()["slowest_step"] is None           # no step since
 
 
+# -- (e') a step that stood still keeps its cause and its stack --------------------
+
+
+class _Node:
+    pass
+
+
+def _cycles(n=500_000):
+    """A large graph of cycles built with the collector off: dropping it leaves
+    the collector some 0.1 s of work."""
+    graph = []
+    for _ in range(n):
+        a, b = _Node(), _Node()
+        a.other, b.other = b, a
+        graph.append(a)
+    return graph
+
+
+def _spin(seconds):
+    until = time.perf_counter() + seconds
+    while time.perf_counter() < until:
+        pass
+
+
+def _plant_gc(state):
+    state["graph"].clear()
+    gc.collect()
+
+
+def _plant_spinner(state):
+    """The engine's thread waits for a thread that spins."""
+    done = threading.Event()
+    state["over"] = threading.Event()
+
+    def spins():
+        _spin(0.25)
+        done.set()
+        state["over"].wait(5.0)         # alive when the step ends: its clock can be read
+
+    threading.Thread(target=spins, name="spinner", daemon=True).start()
+    done.wait(5.0)
+
+
+def _sleeps_here(state):
+    time.sleep(0.2)
+
+
+@pytest.mark.limit(120)
+@pytest.mark.parametrize("cause, method, plant, where", [
+    ("gc", "_extend_call", _plant_gc, "dispatch"),
+    ("python", "_admit", lambda state: _spin(0.2), "admit"),
+    ("threads", "_extend_call", _plant_spinner, "dispatch"),
+    ("machine", "_extend_call", _sleeps_here, "dispatch"),
+])
+def test_a_held_step_keeps_its_cause_its_phase_and_its_stack(
+        engine, monkeypatch, cause, method, plant, where):
+    real, calls, state = getattr(engine, method), [], {}
+
+    def held_once(*args, **kwargs):
+        calls.append(None)
+        if len(calls) == 3:
+            plant(state)
+        return real(*args, **kwargs)
+
+    for _ in range(3):                      # the engine's usual step: warm, a millisecond
+        _drive(engine, _requests())
+    gc.collect()
+    before = engine.stats()
+    gc.disable()                            # nothing but the planted collection collects
+    try:
+        state["graph"] = _cycles() if cause == "gc" else []
+        monkeypatch.setattr(engine, method, held_once)
+        _drive(engine, _requests())
+    finally:
+        gc.enable()
+        state.get("over", threading.Event()).set()
+    after = engine.stats()
+    held = _delta(after, before, "held")
+    # (the planted one, and whatever a loaded machine held beside it)
+    new = [r for r in after["held_steps"] if r not in before["held_steps"]]
+    assert 1 <= held["n"] == len(new) <= 3
+    record = max(new, key=lambda r: r["excess_s"])
+    assert record["unit"] == "llm.step" and record["where"] == where
+    if record["cause"] != cause:
+        # a machine with more to run than CPUs gives the busy thread under half of one,
+        # and then says so itself: the thread was switched out, or its CPU is the most there was
+        assert record["cause"] == "machine" and cause in ("python", "threads")
+        assert record["switched"] > 0 if cause == "python" else record["busiest"][0][0] == "spinner"
+        pytest.skip("the machine took the CPU the planted cause was to burn: it reads `machine`")
+    assert held[cause]["n"] >= 1
+    assert held["excess_s"] == pytest.approx(sum(r["excess_s"] for r in new))
+    assert 0.05 < record["excess_s"] <= record["wall_s"] < 1.0
+    # (a collection holds the interpreter lock from its start to its end: the watcher
+    # cannot look while it lasts, and the cause is the answer there)
+    assert len(record["stack"]) <= 12
+    assert cause == "gc" or "held_once" in ";".join(record["stack"])
+    if cause == "threads":
+        assert record["busiest"][0][0] == "spinner"
+    if cause == "machine":
+        assert record["stack"][-1].startswith("test_llm_spans.py:_sleeps_here:")
+    if cause == "gc":
+        assert _delta(after, before, "gc")["generations"]["2"]["n"] == 1
+        assert record["gc_s"] == pytest.approx(_delta(after, before, "gc")["s"], rel=0.05)
+    # the slowest step since the read before is that step, with the record's fields
+    slow = after["slowest_step"]
+    assert slow["cause"] == cause and slow["where"] == where and slow["stack"] == record["stack"]
+    assert slow["wall_s"] == record["wall_s"] and slow["excess_s"] == record["excess_s"]
+    assert slow["cpu_s"] == record["cpu_s"] and slow["gc_s"] == record["gc_s"]
+
+
+@pytest.mark.limit(60)
+def test_a_landing_its_program_does_not_explain_is_held_in_fetch(engine, monkeypatch):
+    """A call's landing later than its program's usual call explains: the wait is
+    the device's (or the runtime's), so the step is held in ``fetch``; the usual
+    wait for a call is explained, and no step of a plain load is held."""
+    before = engine.stats()
+    _drive(engine, _requests())
+    assert _delta(engine.stats(), before, "held")["python"]["n"] == 0
+    real, landed = np.asarray, []
+
+    def lands_late(a, *args, **kwargs):
+        if isinstance(a, jax.Array) and a.dtype == jnp.int32:
+            landed.append(None)
+            if len(landed) == 2:
+                time.sleep(0.4)
+        return real(a, *args, **kwargs)
+
+    monkeypatch.setattr(llm.np, "asarray", lands_late)
+    _drive(engine, _requests())
+    monkeypatch.undo()
+    after = engine.stats()
+    assert 1 <= _delta(after, before, "held")["n"] <= 3
+    record = max(
+        (r for r in after["held_steps"] if r not in before["held_steps"]), key=lambda r: r["excess_s"])
+    assert record["where"] == "fetch" and record["cause"] == "machine"
+    assert 0.15 <= record["excess_s"] <= record["wall_s"]
+    assert "lands_late" in ";".join(record["stack"])
+    assert after["slowest_step"]["where"] == "fetch"
+
+
+@pytest.mark.limit(60)
+def test_the_time_between_two_steps_is_a_unit_while_a_sequence_is_active(engine):
+    """From a step's return to the next step: the batcher's loop. Held there, it
+    says so; with nothing active (the last step landed everything) it is nobody's."""
+    before = engine.stats()
+    seqs = _requests()
+    engine.step(seqs)
+
+    def the_batcher_stands_still():
+        time.sleep(0.15)
+
+    the_batcher_stands_still()
+    _drive(engine, seqs)
+    time.sleep(0.15)                        # nothing is active: no unit is open
+    _drive(engine, _requests())
+    after = engine.stats()
+    host = _delta(after, before, "host")
+    assert host["llm.step"]["n"] == 2 * STEPS and host["llm.between"]["n"] == 2 * (STEPS - 1)
+    assert 1 <= _delta(after, before, "held")["n"] <= 3
+    (record,) = [
+        r for r in after["held_steps"] if r not in before["held_steps"] and r["unit"] == "llm.between"]
+    assert record["where"] == "between"
+    assert record["cause"] == "machine" and 0.1 < record["excess_s"] < 0.3
+    assert record["stack"][-1].startswith("test_llm_spans.py:the_batcher_stands_still:")
+    assert 0.15 < host["llm.between"]["wall_s"] < 0.3
+    # and the steps' seconds are the phase's: one clock serves both
+    assert host["llm.step"]["wall_s"] == pytest.approx(_delta(after, before, "phase_s")["step"])
+
+
+@pytest.mark.limit(240)
+def test_a_recorded_hold_is_a_span_and_an_idle_gap_under_it_is_filed_there(
+        engine, monkeypatch, tmp_path):
+    """Inside a real profiler session a planted collection and a planted sleep, each
+    in a step of its own, and a third hold outside it: ``traced.held`` counts the
+    two; the collection is a span ``host.gc`` inside ``llm.dispatch`` on the engine's
+    thread, the sleep a span ``host.held`` from the moment the watcher saw it to the
+    step's end, on the watcher's thread, which ``load_xplane`` merges with every
+    python thread's; and the reducer files a device's idle gap under either as
+    ``bench.engine_step:host.gc`` / ``:host.held``, not under the call they interrupted."""
+    for _ in range(3):
+        _drive(engine, _requests())
+    real, calls, graph = engine._extend_call, [], []
+
+    def held(*args, **kwargs):
+        calls.append(None)
+        if len(calls) == 2:                 # the first step's decode call
+            graph.clear()
+            gc.collect()
+        if len(calls) in (4, 9):            # the second step's; and the fourth of the load after
+            time.sleep(0.25)
+        return real(*args, **kwargs)
+
+    def steps_as_the_benchmark_runs_them(seqs):
+        while not all(s.done for s in seqs):
+            with accelerator.span("bench.engine_step"):
+                engine.step([s for s in seqs if not s.done])
+
+    monkeypatch.setattr(engine, "_extend_call", held)
+    gc.collect()
+    before = engine.stats()
+    gc.disable()
+    try:
+        graph.extend(_cycles())
+        with _session(tmp_path):
+            steps_as_the_benchmark_runs_them(_requests())
+        inside = engine.stats()
+        steps_as_the_benchmark_runs_them(_requests())
+    finally:
+        gc.enable()
+    after = engine.stats()
+    # (the three planted, and whatever a loaded machine held beside them)
+    assert 3 <= _delta(after, before, "held")["n"] <= 5
+    traced = _delta(after["traced"], before["traced"], "held")
+    assert traced == _delta(inside, before, "held") and 2 <= traced["n"] <= 4
+    assert traced["gc"]["n"] == 1 <= traced["machine"]["n"]
+    assert _delta(after["traced"], before["traced"], "host")["llm.step"]["n"] == STEPS
+
+    path = _xplane_of(tmp_path)
+    (collection,) = [g for g in _recorded(path, "host.gc") if g[1] - g[0] > 50e6]
+    assert collection[2]["generation"] == 2 and collection[2]["collected"] >= 500_000
+    # (the watcher gets to look at the collecting step when the collection lets go
+    # of the interpreter lock: a short span of its own, cause ``gc``)
+    seen = max(
+        (h for h in _recorded(path, "host.held") if h[2]["cause"] == "machine"),
+        key=lambda h: h[1] - h[0])
+    assert seen[2]["unit"] == "llm.step"
+    assert 0.1e9 < seen[1] - seen[0] < 0.25e9        # from 50-100 ms into the sleep on
+
+    def inside_one(span, others):
+        return any(a <= span[0] and span[1] <= b for a, b, *_ in others)
+
+    dispatches, steps = _recorded(path, "llm.dispatch"), _recorded(path, "llm.step")
+    assert inside_one(collection, dispatches) and inside_one(collection, steps)
+    assert not inside_one(seen, dispatches)          # it ends with the step, after the call
+    # on the threads they ran on: the collection on the engine's, the watcher's span on its own
+    from jax.profiler import ProfileData
+
+    host = next(p for p in ProfileData.from_file(path).planes if p.name == trace_reduce.HOST_PLANE)
+    threads = [{e.name for e in line.events} for line in host.lines]
+    (engines,) = [names for names in threads if "llm.step" in names]
+    assert "host.gc" in engines and "host.held" not in engines
+
+    # a device that is busy but for the middle third of either span
+    planes = trace_reduce.load_xplane(path)
+    events = _engine_line(planes)
+    assert {"host.gc", "host.held", "bench.engine_step"} <= {n for n, _, _ in events}
+    lo, hi = trace_reduce.annotation_window(planes, "bench.engine_step")
+    holes = [(a + (b - a) / 3, a + 2 * (b - a) / 3) for a, b, _ in (collection, seen)]
+    ops = [
+        (f"fusion.{i}", a, b - a)
+        for i, (a, b) in enumerate(trace_reduce.subtract([(lo, hi)], sorted(holes)))
+    ]
+    reduced = trace_reduce.reduce(
+        {**planes, "/device:TPU:0": {trace_reduce.OPS_LINE: ops}}, "bench.engine_step")
+    gaps = dict(reduced["idle_gaps"])
+    assert set(gaps) == {"bench.engine_step:host.gc", "bench.engine_step:host.held"}
+    assert gaps["bench.engine_step:host.gc"] == pytest.approx((collection[1] - collection[0]) / 3e9)
+    assert gaps["bench.engine_step:host.held"] == pytest.approx((seen[1] - seen[0]) / 3e9)
+
+
 # -- (f) what the spans cost outside a session -------------------------------
 
 
+class _NoWatch:
+    """The parent's step in the watch's place: it opened its phase ``step`` through
+    ``_phase`` (a generator round the span's place and two reads of the wall clock),
+    which the unit ``llm.step`` has taken over, and had nothing between two steps."""
+
+    def __init__(self):
+        self._unit = types.SimpleNamespace(
+            began=(0.0,), explained_s=0.0, wall_s=0.0, measured=(0.0, 0.0, 0.0, 0, 0), open=False)
+        self._phase = None
+
+    @contextlib.contextmanager
+    def _the_parents_phase(self, unit):
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            unit.wall_s = time.perf_counter() - t0
+
+    def read(self, after=None):
+        return (0.0,)
+
+    def open(self, name, book=None, at=None, again=None):
+        if name == "llm.step":
+            self._phase = self._the_parents_phase(self._unit)
+            self._phase.__enter__()
+        return self._unit
+
+    def close(self, unit, at=None, where=None):
+        if self._phase is not None:
+            phase, self._phase = self._phase, None
+            phase.__exit__(None, None, None)
+
+    def drop(self, unit):
+        pass
+
+
+@pytest.mark.limit(240)
 def test_phases_cost_less_than_their_budget_outside_a_session(engine, monkeypatch):
     """1,000 engine steps with ``_phase`` as it is (a call's ``dispatch`` and
-    ``fetch`` with their metadata) and 1,000 with it swapped for a bare no-op
-    and the step's ``recording()`` for a constant, turn about, the device
-    programs and the upload stubbed so that a step is the engine's own python:
-    the difference per step stays under ``PHASE_BUDGET_NS`` for each phase the
-    step opens."""
+    ``fetch`` with their metadata) and the host's watch as it is (a step's two
+    units, ``llm.step`` and ``llm.between``), and 1,000 with ``_phase`` swapped for a
+    bare no-op, the step's ``recording()`` for a constant and the watch for the
+    parent's phase ``step``, turn about, the device programs and the upload
+    stubbed so that a step is the engine's own python: the difference per step
+    stays under ``PHASE_BUDGET_NS`` for each phase the step opens and
+    ``HOST_BUDGET_NS`` for its units. None of the watched steps is held, and
+    nothing of them was sampled."""
     class Home(np.ndarray):
         """What a call leaves for the host, already there."""
 
@@ -787,9 +1100,10 @@ def test_phases_cost_less_than_their_budget_outside_a_session(engine, monkeypatc
     monkeypatch.setattr(jax, "device_put", lambda a: a)
     as_it_is, nothing = engine._phase, contextlib.nullcontext()
     asks, never = llm.accelerator.recording, lambda: False
+    watch, no_watch = engine._watch, _NoWatch()
 
-    def thousand_steps(phase, recording=asks):
-        engine._phase = phase
+    def thousand_steps(phase, recording=asks, watch=watch):
+        engine._phase, engine._watch = phase, watch
         monkeypatch.setattr(llm.accelerator, "recording", recording)
         # this thread's own time: a stubbed step waits for nothing, and the
         # other workers of a loaded machine are not the spans' cost
@@ -800,21 +1114,35 @@ def test_phases_cost_less_than_their_budget_outside_a_session(engine, monkeypatc
         spent = time.thread_time_ns() - t0
         for s in seqs:
             s._release()                    # the unfinished give their blocks back
+        # nothing is in flight (the stubbed call lands nowhere): nobody waits for a step
+        engine._watch.drop(engine._between)
+        engine._flight = engine._between = engine._unit = None
         return spent
 
     try:
         thousand_steps(as_it_is)
         before = engine.stats()
         runs = [
-            (thousand_steps(as_it_is), thousand_steps(lambda name, **what: nothing, never))
+            (thousand_steps(as_it_is),
+             thousand_steps(lambda name, **what: nothing, never, no_watch))
             for _ in range(5)
         ]
     finally:
         del engine._phase
-    opened = sum(_delta(engine.stats(), before, "phase_n").values()) / 5000
+        engine._watch = watch
+    after = engine.stats()
+    # (the phase ``step`` counts in the other five thousand steps too)
+    opened = (sum(_delta(after, before, "phase_n").values()) - 5000) / 5000
     assert 8 <= opened <= 30
     with_phases, without = (min(r[i] for r in runs) for i in (0, 1))
-    assert (with_phases - without) / 1000 < opened * llm.PHASE_BUDGET_NS
+    assert (with_phases - without) / 1000 < opened * llm.PHASE_BUDGET_NS + llm.HOST_BUDGET_NS
+    # 5,000 watched steps, every one between two others: none held, none sampled
+    assert _delta(after, before, "host")["llm.step"]["n"] == 5000 == _delta(after, before, "steps") - 5000
+    # (a step that lands its last call leaves nothing to wait for the next)
+    assert 4500 < _delta(after, before, "host")["llm.between"]["n"] < 5000
+    # (a loaded machine takes a CPU away for 20 ms now and then: the watch's to say)
+    held = _delta(after, before, "held")
+    assert held["n"] == held["machine"]["n"] <= 3
 
 
 # -- (g) stable device names --------------------------------------------------
